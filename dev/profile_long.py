@@ -4,8 +4,8 @@ Replays exactly what engine._long_step dispatches for a 32k llama-3.1-8b
 prompt (int8 weights + int8 KV): 16 segments of 2048 through
 _prefill_segment_and_sample with the pow2 kv_bound ladder. Prints
 per-segment wall time (warm, forced fetch) and the attention kernel's
-share, so the 32k TTFT (19.0s in BENCH_r04 vs a ~4-6s roofline) can be
-attributed.
+share, so the 32k TTFT can be attributed (19.0s against a ~4-6s roofline
+is a figure from a deleted chip record — a claim to check).
 """
 
 from __future__ import annotations

@@ -610,7 +610,8 @@ class _BaseCompletionsStep(Step):
         opts = {
             k: self.config[k]
             for k in (
-                "max-tokens", "temperature", "top-p", "top-k", "stop",
+                "max-tokens", "max-new-tokens", "temperature", "top-p",
+                "top-k", "stop",
                 "logit-bias", "user", "presence-penalty", "frequency-penalty",
                 "options", "deadline", "max-queue-wait",
                 # the agentic tier (docs/SERVING.md §15): per-request

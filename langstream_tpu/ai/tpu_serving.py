@@ -176,14 +176,18 @@ Resource configuration:
     granted resync stays fatal (transient wire loss does not repeat)
   compile-cache-dir: persistent XLA compile cache directory — a scale-up
     replica pointed at a warm (shared) cache dir skips the warmup
-    ladder's compile wall and serves in seconds (fleet cold-start lever)
+    ladder's compile wall and serves in seconds (fleet cold-start lever).
+    JAX_COMPILATION_CACHE_DIR wins when set, then this knob, then (on an
+    accelerator; never on the CPU backend) a fixed `.jax_compile_cache/`
+    beside the package (serving/engine.enable_persistent_compile_cache)
   mesh: {model: N, data: M, expert: K} → shard weights over the local mesh
   quantization: "int8" → weight-only int8 (halves weight HBM traffic; big
     models stage on the host so the bf16 tree never needs device HBM)
   kv-cache-quantization: "int8" → int8 KV cache with per-token per-head
     scales (int8×int8 MXU attention; ~halves decode cache bandwidth —
     the lever that matters for GQA models like llama, see PERF.md)
-  hbm-bytes: device HBM budget for that staging decision (default 16GiB)
+  hbm-bytes: device HBM budget for that staging decision (default: the
+    device's own reported limit, 16GiB where it reports none)
 
 Streaming follows the reference's growth batching (OpenAICompletionService:
 "start from 1 chunk, then double the size until min-chunks-per-message"), so
@@ -212,6 +216,16 @@ from langstream_tpu.ai.provider import (
 from langstream_tpu.models.configs import MODEL_PRESETS, GenerationOptions, ModelConfig
 
 log = logging.getLogger(__name__)
+
+
+def _device_hbm_bytes() -> int:
+    """The first device's own memory limit where it reports one (TPUs do,
+    through ``memory_stats()``), else 16GiB — the v5e figure, for backends
+    that report nothing (the CPU one)."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats() or {}
+    return int(stats.get("bytes_limit") or 16 * 1024**3)
 
 
 class _EngineHolder:
@@ -328,7 +342,7 @@ class _EngineHolder:
             # path never materializes the full-precision tree, so it skips
             # the host stage entirely — unless quantize-on-load is forced
             # off, which reinstates the eager host-staged economics.
-            hbm_budget = int(self.config.get("hbm-bytes", 16 * 1024**3))
+            hbm_budget = int(self.config.get("hbm-bytes") or _device_hbm_bytes())
             needs_host = quantize and mc.approx_params * 2 > hbm_budget // 2
             if stream_on and needs_host and quantize and not qol_on:
                 stream_on = False
@@ -407,17 +421,18 @@ class _EngineHolder:
         from langstream_tpu.parallel.multihost import DistributedConfig
         from langstream_tpu.serving.engine import ServingEngine
 
+        from langstream_tpu.serving.engine import (
+            enable_persistent_compile_cache,
+        )
+
         # persistent XLA compile cache (fleet fast cold start): a scale-up
         # replica pointed at a warm shared cache dir deserializes every
         # warmup program instead of recompiling — seconds instead of the
         # compile wall. Must be set BEFORE any jit below runs.
-        cache_dir = self.config.get("compile-cache-dir")
-        if cache_dir:
-            from langstream_tpu.serving.engine import (
-                enable_persistent_compile_cache,
-            )
-
-            enable_persistent_compile_cache(str(cache_dir))
+        cache_dir = enable_persistent_compile_cache(
+            self.config.get("compile-cache-dir")
+        )
+        log.info("persistent compile cache: %s", cache_dir or "off")
         mc = self.model_config()
         layout = str(self.config.get("kv-layout", "paged")).lower()
         if layout not in ("paged", "dense"):
@@ -497,8 +512,8 @@ class _EngineHolder:
                 # behavior). Default 30s: it must exceed the worst single
                 # warmup family's compile stall (seconds against a warm
                 # persistent compile cache; set higher — or 0 — for cold
-                # caches through a slow tunnel). The resync window is the
-                # follower's repeat-divergence fatality rule.
+                # caches). The resync window is the follower's
+                # repeat-divergence fatality rule.
                 watchdog_s=float(self.config.get("spmd-watchdog-s", 30.0)),
                 resync_window_s=float(
                     self.config.get("spmd-resync-window-s", 60.0)
@@ -647,6 +662,9 @@ class _EngineHolder:
         )
         if start:
             engine.start()
+            # a program the compiler refuses during warm-up fails the BUILD
+            # (and with it the agent's start), not the first request
+            engine.wait_ready()
             # publish this engine's state beacon + fleet dispatch endpoint
             # on the runtime HTTP server (serving/fleet.py registry): GET
             # /state and POST /fleet/generate work in every topology, not
@@ -1328,6 +1346,10 @@ class TpuServingProvider(ServiceProvider):
 
     def get_embeddings_service(self, config: dict[str, Any]) -> EmbeddingsService:
         return TpuEmbeddingsService(self.holder, config)
+
+    def engine(self):
+        """The provider's one ServingEngine, built and warmed on first use."""
+        return self.holder.engine()
 
     async def close(self) -> None:
         # holder.close() drains synchronously for up to drain-grace-s —

@@ -9,7 +9,7 @@ map checkpoints mechanically.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import Any, Optional
 
 
 @dataclass(frozen=True)
@@ -42,6 +42,10 @@ class ModelConfig:
     # shard_map axis (sequence/context parallelism for long inputs); set via
     # parallel.sp.sequence_parallel_forward, never directly in presets
     ring_axis: Optional[str] = None
+    # when set, the Pallas attention kernels run under a shard_map over this
+    # jax Mesh's "model" axis (ops/attention._per_kv_head); set by
+    # ServingEngine from its own mesh, never directly in presets
+    kernel_mesh: Optional[Any] = None
     # attention kernel choice: "auto" (pallas on TPU when shapes fit),
     # "pallas" (force, interpret-mode off-TPU), "jnp" (reference path)
     attention_impl: str = "auto"
@@ -272,7 +276,14 @@ class GenerationOptions:
             )
         cost = d.get("max-cost-tokens", d.get("max_cost_tokens"))
         return GenerationOptions(
-            max_new_tokens=int(d.get("max-tokens", d.get("max_new_tokens", 256))),
+            # `max-new-tokens` is the spelling every example pipeline uses;
+            # the remote providers read both, so this one must too
+            max_new_tokens=int(
+                d.get("max-tokens")
+                or d.get("max-new-tokens")
+                or d.get("max_new_tokens")
+                or 256
+            ),
             temperature=float(d.get("temperature", 0.0)),
             top_k=int(d.get("top-k", d.get("top_k", 0))),
             top_p=float(d.get("top-p", d.get("top_p", 1.0))),

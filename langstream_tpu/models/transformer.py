@@ -364,6 +364,7 @@ def _dispatch_attention(
     reference path. Semantics identical; ops/attention has the kernels."""
     from langstream_tpu.ops.attention import (
         flash_prefill_attention,
+        note_path,
         pallas_ok,
         ragged_decode_attention,
     )
@@ -394,13 +395,11 @@ def _dispatch_attention(
         if quantized:
             from langstream_tpu.ops.attention import ragged_decode_attention_int8
 
-            out = ragged_decode_attention_int8(
-                q[:, 0], k_all, v_all, lengths, config, interpret=interpret
-            )
+            kernel = ragged_decode_attention_int8
         else:
-            out = ragged_decode_attention(
-                q[:, 0], k_all, v_all, lengths, config, interpret=interpret
-            )
+            kernel = ragged_decode_attention
+        note_path("decode", kernel.__name__, config, s=s, t=t)
+        out = kernel(q[:, 0], k_all, v_all, lengths, config, interpret=interpret)
         return out[:, None, :]
     if s > 1 and kv_offset is not None and verify:
         # speculative verify chunk: S = k+1 draft tokens per row, decode-
@@ -411,6 +410,7 @@ def _dispatch_attention(
         # frontier verify_step_inplace built (already bound-sliced above).
         from langstream_tpu.ops.attention import multitoken_verify_attention
 
+        note_path("verify", "jnp", config, s=s, t=t)
         return multitoken_verify_attention(q, k_all, v_all, mask, config)
     if s > 1 and kv_offset is not None:
         # chunked prefill: the segment attends to the whole written cache
@@ -421,16 +421,18 @@ def _dispatch_attention(
         )
 
         if pallas_ok(config, s, t):
-            if quantized:
-                # int8 cache rides into the kernel unconverted: the r5
-                # dequantize-then-kernel path materialized a cache-sized
-                # bf16 temp and paid its HBM round trip per segment
-                return flash_segment_attention_int8(
-                    q, k_all, v_all, kv_offset, config, interpret=interpret
-                )
-            return flash_segment_attention(
+            # int8 cache rides into its kernel unconverted: the r5
+            # dequantize-then-kernel path materialized a cache-sized
+            # bf16 temp and paid its HBM round trip per segment
+            kernel = (
+                flash_segment_attention_int8 if quantized
+                else flash_segment_attention
+            )
+            note_path("segment", kernel.__name__, config, s=s, t=t)
+            return kernel(
                 q, k_all, v_all, kv_offset, config, interpret=interpret
             )
+        note_path("segment", "jnp", config, s=s, t=t)
         return attention(q, k_all, v_all, mask, config)
     if s > 1 and causal and pallas_ok(config, s):
         # prefill/full forward: causal over the first s cache columns (int8
@@ -438,6 +440,7 @@ def _dispatch_attention(
         # compute-bound, the materialized slice is small)
         ksl = jax.tree.map(lambda x: x[:, :, :s], k_all)
         vsl = jax.tree.map(lambda x: x[:, :, :s], v_all)
+        note_path("prefill", "flash_prefill_attention", config, s=s, t=t)
         return flash_prefill_attention(
             q,
             _dequantize_kv(ksl, q.dtype),
@@ -446,6 +449,8 @@ def _dispatch_attention(
             interpret=interpret,
         )
     # jnp path handles int8 cache dicts natively (hoisted-scale einsums)
+    kind = "decode" if s == 1 else "prefill" if causal else "encode"
+    note_path(kind, "jnp", config, s=s, t=t)
     return attention(q, k_all, v_all, mask, config)
 
 
@@ -600,26 +605,28 @@ def _layer(
         cv = _paged_scatter_entry(cv, vt, paged_table, cache_positions, page_size)
         new_cache = (ck, cv)
         from langstream_tpu.ops.attention import (
+            note_path,
             paged_pallas_ok,
             ragged_paged_decode_attention,
             ragged_paged_decode_attention_int8,
         )
 
+        t = paged_table.shape[1] * page_size
         if s == 1 and paged_pallas_ok(config, page_size):
             lengths = cache_positions[:, 0] + 1
-            interpret = jax.default_backend() != "tpu"
-            if isinstance(ck, dict):
-                out = ragged_paged_decode_attention_int8(
-                    q[:, 0], ck, cv, lengths, paged_table, config, page_size,
-                    interpret=interpret,
-                )
-            else:
-                out = ragged_paged_decode_attention(
-                    q[:, 0], ck, cv, lengths, paged_table, config, page_size,
-                    interpret=interpret,
-                )
+            kernel = (
+                ragged_paged_decode_attention_int8 if isinstance(ck, dict)
+                else ragged_paged_decode_attention
+            )
+            note_path("paged-decode", kernel.__name__, config, s=s, t=t)
+            out = kernel(
+                q[:, 0], ck, cv, lengths, paged_table, config, page_size,
+                interpret=jax.default_backend() != "tpu",
+            )
             attn = out[:, None, :]
         else:
+            kind = "decode" if s == 1 else "verify" if verify else "segment"
+            note_path(f"paged-{kind}", "jnp", config, s=s, t=t)
             k_all = _paged_gather_entry(ck, paged_table, page_size)
             v_all = _paged_gather_entry(cv, paged_table, page_size)
             attn = attention(q, k_all, v_all, mask, config)

@@ -22,16 +22,26 @@ Pallas TPU guide.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from langstream_tpu.models.configs import ModelConfig
 
 _NEG = -1e30
+
+# Scoped VMEM the prefill/segment kernels are sized against AND the limit
+# stated to Mosaic (CompilerParams.vmem_limit_bytes): one number on both
+# sides, so the block-size choice below cannot drift from what the compiler
+# enforces (its unstated default, 16MiB, refused gemma-2b's 256-row q blocks
+# at 16.99MiB). A v5e core has 128MiB of VMEM.
+_VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
 def _fit_block(block: int, n: int) -> int:
@@ -45,21 +55,105 @@ def _fit_block(block: int, n: int) -> int:
     return block
 
 
-def _vmem_block_q(block_q: int, group: int, d: int, itemsize: int) -> int:
-    """Shrink block_q until the kernel's VMEM footprint fits the ~16MB
-    scoped budget. The prefill/segment kernels hold double-buffered q/out
-    blocks [G, block_q, D] plus f32 m/l/acc scratch [G, block_q, 128|D]:
-    at the 512 default that is ~17MB for fat-head models (gemma G=8
-    D=256 — Mosaic refused to compile exactly this in the r5 bench) but
-    ~5MB for llama (G=4 D=128), so the cap must be shape-aware rather
-    than a smaller global default that would slow llama down."""
+def _vmem_block_q(
+    block_q: int, block_k: int, group: int, d: int, itemsize: int,
+    int8_kv: bool = False,
+) -> int:
+    """Shrink block_q until one grid step of the prefill/segment kernels
+    fits ``_VMEM_LIMIT_BYTES``. Counted per step: the double-buffered q/out
+    blocks [G, block_q, D] and K/V blocks [block_k, D] (int8 caches add
+    their f32 scale columns, lane-padded to 128), the f32 m/l/acc scratch
+    [G, block_q, 128|128|D], and the [G, block_q, block_k] score and
+    probability tiles (f32 each, plus the probabilities' model-dtype copy
+    that feeds the PV dot). Shape-aware rather than a smaller global
+    default: fat-head models (gemma G=8 D=256) step down to 256 rows while
+    llama (G=4 D=128) keeps the full 512."""
+    kv_row = d + 128 * 4 if int8_kv else d * itemsize
+    kv = 2 * 2 * block_k * kv_row  # k + v, ×2 buffers
     while block_q > 128:
         io = 2 * 2 * group * block_q * d * itemsize  # q + out, ×2 buffers
         scratch = group * block_q * (128 + 128 + d) * 4
-        if io + scratch <= 11 * 1024 * 1024:
+        tiles = group * block_q * block_k * (4 + 4 + itemsize)
+        if io + kv + scratch + tiles <= _VMEM_LIMIT_BYTES:
             break
         block_q //= 2
     return block_q
+
+
+def _model_on(ndim: int, axis: int) -> P:
+    return P(*("model" if i == axis else None for i in range(ndim)))
+
+
+def _per_kv_head(n_replicated: int):
+    """Decorator for kernels of signature ``fn(q, k, v, *replicated, config,
+    ...)``. Mosaic kernels cannot be partitioned by GSPMD, so when
+    ``config.kernel_mesh`` is set (the engine runs under a mesh) the call
+    is wrapped in a fully manual shard_map that splits the head axis of q
+    and of the K/V cache or page pool over "model" — what param_specs and
+    serving_cache_specs/page_pool_specs already produce. Every kernel here
+    is independent per kv head (each q-head group reads only its own kv
+    head), so the body needs no collective; the ``replicated`` operands
+    (offsets, lengths, page tables) and every other mesh axis stay
+    replicated. ``pallas_ok``/``paged_pallas_ok`` only admit meshes whose
+    "model" axis divides the kv heads."""
+
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(q, k, v, *args, **kwargs):
+            config = args[n_replicated]
+            mesh = config.kernel_mesh
+            if mesh is None:
+                return fn(q, k, v, *args, **kwargs)
+            replicated, tail = args[:n_replicated], args[n_replicated + 1:]
+            local = dataclasses.replace(config, kernel_mesh=None)
+
+            def body(q, k, v, *replicated):
+                return fn(q, k, v, *replicated, local, *tail, **kwargs)
+
+            kv_spec = jax.tree.map(lambda x: _model_on(x.ndim, 1), k)
+            return jax.shard_map(
+                body,
+                mesh=mesh,
+                in_specs=(_model_on(q.ndim, q.ndim - 2), kv_spec, kv_spec)
+                + (P(),) * n_replicated,
+                out_specs=_model_on(q.ndim - 1, q.ndim - 2),
+                check_vma=False,
+            )(q, k, v, *replicated)
+
+        return wrapper
+
+    return deco
+
+
+def _mesh_ok(config: ModelConfig) -> bool:
+    """Kernels run under a mesh only when its "model" axis divides the kv
+    heads (the per-kv-head split of ``_per_kv_head``); otherwise the cache
+    is replicated (serving_cache_specs) and attention stays on the jnp
+    path, which GSPMD partitions by itself."""
+    mesh = config.kernel_mesh
+    return mesh is None or config.n_kv_heads % mesh.shape.get("model", 1) == 0
+
+
+# Which implementation each attention call shape was traced with, keyed by
+# a readable name — what tells a kernel from a reference that quietly took
+# its place. Process-wide like the jit cache it describes: a shape traces
+# once per process, whichever engine dispatched it first.
+_PATHS: dict[str, str] = {}
+
+
+def note_path(kind: str, impl: str, config: ModelConfig, s: int, t: int) -> None:
+    """Record (at trace time) that a ``kind`` call of ``s`` queries per row
+    against ``t`` cache columns took ``impl`` (a kernel's name, or "jnp")."""
+    mesh = config.kernel_mesh
+    if mesh is not None and impl != "jnp":
+        impl += f"/shard_map[model={mesh.shape.get('model', 1)}]"
+    _PATHS[f"{kind}[s={s},t={t}]"] = impl
+
+
+def attention_paths() -> dict[str, str]:
+    """Snapshot of the trace-time log: ``{"prefill[s=512,t=512]":
+    "flash_prefill_attention", "paged-segment[s=64,t=2048]": "jnp", ...}``."""
+    return dict(_PATHS)
 
 
 # ---------------------------------------------------------------------------
@@ -140,6 +234,7 @@ def _prefill_kernel(
         o_ref[0, 0, :, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
+@_per_kv_head(0)
 def flash_prefill_attention(
     q: jax.Array,  # [B, S, H, D]
     k: jax.Array,  # [B, Hkv, S, D] head-major
@@ -153,10 +248,10 @@ def flash_prefill_attention(
     b, s, h, d = q.shape
     hkv = k.shape[1]
     group = h // hkv
-    block_q = _fit_block(
-        _vmem_block_q(block_q, group, d, jnp.dtype(q.dtype).itemsize), s
-    )
     block_k = _fit_block(block_k, s)
+    block_q = _fit_block(
+        _vmem_block_q(block_q, block_k, group, d, jnp.dtype(q.dtype).itemsize), s
+    )
     assert s % block_q == 0 and s % block_k == 0, "caller gates divisibility"
     # head-major queries: [B, Hkv, G, S, D] so the blocked dims are (S, D)
     qg = q.reshape(b, s, hkv, group, d).transpose(0, 2, 3, 1, 4)
@@ -187,6 +282,7 @@ def flash_prefill_attention(
             pltpu.VMEM((group, block_q, 128), jnp.float32),
             pltpu.VMEM((group, block_q, d), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(qg, k, v)
     # [B, Hkv, G, S, D] → [B, S, H*D]
@@ -286,6 +382,7 @@ def _segment_kernel(
     )
 
 
+@_per_kv_head(1)
 def flash_segment_attention(
     q: jax.Array,  # [B, S, H, D] — segment queries
     k: jax.Array,  # [B, Hkv, T, D] cache (head-major), T >= offset + S
@@ -303,10 +400,10 @@ def flash_segment_attention(
     hkv = k.shape[1]
     t = k.shape[2]
     group = h // hkv
-    block_q = _fit_block(
-        _vmem_block_q(block_q, group, d, jnp.dtype(q.dtype).itemsize), s
-    )
     block_k = _fit_block(block_k, t)
+    block_q = _fit_block(
+        _vmem_block_q(block_q, block_k, group, d, jnp.dtype(q.dtype).itemsize), s
+    )
     assert s % block_q == 0 and t % block_k == 0, "caller gates divisibility"
     qg = q.reshape(b, s, hkv, group, d).transpose(0, 2, 3, 1, 4)
 
@@ -348,6 +445,7 @@ def flash_segment_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(offset.astype(jnp.int32), qg, k, v)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * d)
@@ -380,6 +478,7 @@ def _segment_int8_kernel(
     _segment_body(off_ref, q_ref, load_kv, o_ref, m_scr, l_scr, acc_scr, **opts)
 
 
+@_per_kv_head(1)
 def flash_segment_attention_int8(
     q: jax.Array,  # [B, S, H, D] — segment queries
     k: dict,  # int8 cache entry {"q": [B,Hkv,T,D] i8, "s": [B,Hkv,T] f32}
@@ -396,10 +495,13 @@ def flash_segment_attention_int8(
     hkv = k["q"].shape[1]
     t = k["q"].shape[2]
     group = h // hkv
-    block_q = _fit_block(
-        _vmem_block_q(block_q, group, d, jnp.dtype(q.dtype).itemsize), s
-    )
     block_k = _fit_block(block_k, t)
+    block_q = _fit_block(
+        _vmem_block_q(
+            block_q, block_k, group, d, jnp.dtype(q.dtype).itemsize, int8_kv=True
+        ),
+        s,
+    )
     assert s % block_q == 0 and t % block_k == 0, "caller gates divisibility"
     qg = q.reshape(b, s, hkv, group, d).transpose(0, 2, 3, 1, 4)
 
@@ -444,6 +546,7 @@ def flash_segment_attention_int8(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(
         offset.astype(jnp.int32),
@@ -523,6 +626,7 @@ def _decode_kernel(
         o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
+@_per_kv_head(1)
 def ragged_decode_attention(
     q: jax.Array,  # [B, H, D] single query per row
     k: jax.Array,  # [B, Hkv, T, D] cache (head-major)
@@ -667,6 +771,7 @@ def _decode_int8_kernel(
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
+@_per_kv_head(1)
 def ragged_decode_attention_int8(
     q: jax.Array,  # [B, H, D] single query per row
     k: dict,  # int8 cache entry {"q": [B,Hkv,T,D] i8, "s": [B,Hkv,T] f32}
@@ -846,6 +951,7 @@ def _paged_kv_index(num_pages: int, page_size: int, table_len: int):
     return kv_index
 
 
+@_per_kv_head(2)
 def ragged_paged_decode_attention(
     q: jax.Array,  # [B, H, D] single query per row
     k: jax.Array,  # page pool entry [P, Hkv, ps, D]
@@ -976,6 +1082,7 @@ def _paged_decode_int8_kernel(
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
+@_per_kv_head(2)
 def ragged_paged_decode_attention_int8(
     q: jax.Array,  # [B, H, D]
     k: dict,  # int8 pool entry {"q": [P,Hkv,ps,D] i8, "s": [P,Hkv,ps] f32}
@@ -1046,10 +1153,11 @@ def paged_pallas_ok(config: ModelConfig, page_size: int) -> bool:
     off-TPU, for exactness tests); ``"auto"`` requires a real TPU plus the
     Mosaic tiling constraints on the (page_size, D) block dims — off-TPU
     the gathered masked-jnp view is both exact and faster. ``"jnp"``
-    disables it outright (the tier-1 reference path)."""
+    disables it outright (the tier-1 reference path). Under a mesh the
+    "model" axis must divide the kv heads (``_mesh_ok``)."""
     if config.attention_impl == "jnp":
         return False
-    if config.ring_axis is not None:
+    if config.ring_axis is not None or not _mesh_ok(config):
         return False
     if config.attention_impl == "pallas":
         return page_size % 8 == 0
@@ -1180,7 +1288,8 @@ def multitoken_verify_attention(
 
 def pallas_ok(config: ModelConfig, seq_len: int, cache_len: int | None = None) -> bool:
     """True when the pallas kernels apply; no ring axis (ring attention owns
-    the sequence-parallel path).
+    the sequence-parallel path), and under a mesh only when its "model"
+    axis divides the kv heads (``_mesh_ok``).
 
     ``attention_impl="pallas"`` forces the kernels (interpret mode off-TPU,
     for tests) gated only on block divisibility; ``"auto"`` additionally
@@ -1189,7 +1298,7 @@ def pallas_ok(config: ModelConfig, seq_len: int, cache_len: int | None = None) -
     production."""
     if config.attention_impl == "jnp":
         return False
-    if config.ring_axis is not None:
+    if config.ring_axis is not None or not _mesh_ok(config):
         return False
     force = config.attention_impl == "pallas"
     if force:

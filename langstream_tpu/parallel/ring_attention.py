@@ -56,11 +56,7 @@ def ring_attention(
     q_pos = my * sl + jnp.arange(sl)  # global positions of local queries
 
     def _varying(x):
-        if hasattr(lax, "pcast"):
-            return lax.pcast(x, (axis,), to="varying")
-        if hasattr(lax, "pvary"):
-            return lax.pvary(x, (axis,))
-        return x  # jax 0.4.x: no varying-type system, arrays are plain
+        return lax.pcast(x, (axis,), to="varying")
 
     # fp32 online-softmax state (cast device-varying on the ring axis: the
     # carry becomes varying the moment block data folds in)

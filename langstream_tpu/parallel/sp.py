@@ -11,36 +11,10 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import inspect
 
 import jax
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-
-def _manual_axes_kwargs(mesh: Mesh, axis: str) -> dict:
-    """Version-portable kwargs restricting shard_map's MANUAL axes to
-    ``axis`` while every other mesh axis stays AUTO (GSPMD keeps
-    tensor-parallel params sharded inside the body).
-
-    jax ≥ 0.8 spells this ``axis_names={axis}``. On 0.4.x the complementary
-    ``auto = mesh axes - {axis}`` spelling exists but lowers
-    ``lax.axis_index`` inside the body to a PartitionId instruction the
-    SPMD partitioner rejects (UNIMPLEMENTED) — so the 0.4.x fallback is
-    FULLY MANUAL shard_map over every mesh axis: ``in_specs=P()`` then
-    all-gathers the weight tree onto each device and the matmuls run
-    full-width per sequence block. Numerically identical, but it holds a
-    full weight copy per device — fine for the CPU test tier and small
-    models; keeping tensor-parallel weights sharded through the ring needs
-    the ``axis_names`` form (jax ≥ 0.8)."""
-    params = inspect.signature(shard_map).parameters
-    if "axis_names" in params:
-        return {"axis_names": frozenset({axis})}
-    return {}
 
 from langstream_tpu.models.configs import ModelConfig
 from langstream_tpu.models.transformer import Params, forward
@@ -141,7 +115,7 @@ def ring_prefill(
             mesh=mesh,
             in_specs=(P(), P(None, axis), P()),
             out_specs=(P(), {"k": kv_spec, "v": kv_spec}),
-            **_manual_axes_kwargs(mesh, axis),
+            axis_names=frozenset({axis}),
         )
     )
     return fwd(params, tokens, lengths)

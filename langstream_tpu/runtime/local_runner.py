@@ -98,6 +98,11 @@ class LocalApplicationRunner:
         """The app's topic-connections runtime (available after deploy())."""
         return self._topic_runtime
 
+    @property
+    def service_registry(self):
+        """The app's AI service providers (available after deploy())."""
+        return self._service_registry
+
     async def serve_metrics(self, host: str = "127.0.0.1", port: int = 0):
         """Start the /metrics + /info observability server (reference
         AgentRunner.java:96-110 Jetty on :8080)."""

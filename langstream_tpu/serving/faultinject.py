@@ -36,7 +36,7 @@ Sites (where the engine asks ``fires(site)``):
             shard came up short mid-read (models/streamload.py) — the
             engine build must abort loudly with the shard + tensor named,
             never retry the poisoned bytes, never serve partial weights
-  fetch     stall the device→host fetch thread (slow-tunnel simulation)
+  fetch     stall the device→host fetch thread (slow-device-link simulation)
   client    stall token delivery before the on_token callback (slow-client
             backpressure simulation)
 

@@ -1,7 +1,7 @@
 """Fleet router: radix-prefix-affinity routing + cache-aware load balancing
 across N serving-engine replicas (ROADMAP item 3).
 
-One engine is fast (BENCH_r05), but a second replica placed blindly HALVES
+One engine is fast, but a second replica placed blindly HALVES
 the prefix hit rate: requests sharing a preamble land on whichever replica
 the balancer felt like, each replica re-prefills the preamble cold, and the
 paged pool's zero-copy aliasing (PR 5) never fires. This module is the tier

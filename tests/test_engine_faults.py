@@ -294,6 +294,54 @@ def test_restart_budget_exhausted_fails_engine():
         engine.stop()
 
 
+def test_warmup_failure_is_a_failed_start_not_a_counted_restart(monkeypatch):
+    """A program the compiler refuses during warm-up used to be swallowed
+    by crash recovery: one counted restart, then serving WITHOUT warm-up
+    (restarts skip it) and the same refusal mid-traffic. It must end the
+    engine before it serves: wait_ready() raises for whoever built it,
+    submit() refuses, a request queued meanwhile fails with the error, and
+    no restart is counted."""
+    refusal = RuntimeError("RESOURCE_EXHAUSTED: scoped vmem limit exceeded")
+
+    def refuse(self):
+        raise refusal
+
+    monkeypatch.setattr(ServingEngine, "_warmup_paged", refuse)
+    engine = ServingEngine(
+        CFG, PARAMS, max_batch=2, max_seq_len=128, decode_chunk=4, precompile=True
+    )
+    queued = GenerationRequest(
+        prompt_tokens=[3, 4], options=GenerationOptions(max_new_tokens=2)
+    )
+    engine.submit(queued)  # before start(): waits in the queue
+    engine.start()
+    try:
+        with pytest.raises(RuntimeError, match="failed to start") as raised:
+            engine.wait_ready(timeout=60)
+        assert raised.value.__cause__ is refusal
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            queued.result(timeout=10)
+        assert engine.stats()["engine-restarts-total"] == 0
+        with pytest.raises(RuntimeError, match="stopped"):
+            engine.submit(GenerationRequest(
+                prompt_tokens=[1], options=GenerationOptions(max_new_tokens=2)
+            ))
+    finally:
+        engine.stop()
+
+
+def test_wait_ready_returns_once_warm():
+    engine = make_engine(precompile=True, prefill_buckets=(16,))
+    try:
+        engine.wait_ready(timeout=120)
+        warmed = engine.stats()["compiled_programs"]
+        assert warmed > 0
+        engine.generate([5, 6, 7], GenerationOptions(max_new_tokens=4), timeout=120)
+        assert engine.stats()["compiled_programs"] == warmed
+    finally:
+        engine.stop()
+
+
 # ---------------------------------------------------------------------------
 # load shedding
 # ---------------------------------------------------------------------------

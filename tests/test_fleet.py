@@ -1033,15 +1033,15 @@ def test_completions_service_fleet_auto_routes_local_and_remote():
 # ---------------------------------------------------------------------------
 
 
-def test_compile_cache_warm_dir_compiles_zero_new_programs(tmp_path):
+def test_compile_cache_warm_dir_compiles_zero_new_programs(tmp_path, monkeypatch):
     """The scale-up story: engine #1 populates the cache dir; engine #2
     (fresh jit closures — normally a full recompile) must add ZERO new
     cache entries and register at least one persistent-cache hit."""
-    from jax._src import compilation_cache as cc
-    from jax._src import monitoring
-
     from langstream_tpu.ai.tpu_serving import _EngineHolder
 
+    # the knob decides only where the variable is unset (the variable's own
+    # case is tests/test_compile_cache.py) — whatever the caller exported
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cache_dir = tmp_path / "xla-cache"
     config = {
         "model": "tiny-test",
@@ -1057,7 +1057,7 @@ def test_compile_cache_warm_dir_compiles_zero_new_programs(tmp_path):
         if "compilation_cache/cache_hits" in event:
             hits.append(event)
 
-    monitoring.register_event_listener(listener)
+    jax.monitoring.register_event_listener(listener)
     try:
         h1 = _EngineHolder(dict(config))
         e1 = h1.engine()
@@ -1077,6 +1077,5 @@ def test_compile_cache_warm_dir_compiles_zero_new_programs(tmp_path):
         )
         assert hits, "no persistent-cache hits recorded on the warm build"
     finally:
-        monitoring._unregister_event_listener_by_callback(listener)
-        jax.config.update("jax_compilation_cache_dir", None)
-        cc.reset_cache()
+        # conftest's _restore_compile_cache puts the cache settings back
+        jax.monitoring.unregister_event_listener(listener)
