@@ -1,0 +1,417 @@
+"""The correctness check behind `correct`.
+
+Runs in set-up, outside the window, on a sample fixed in the configuration's
+`check` block (its own `check_seed`), through an otherwise idle engine, one
+request at a time: same program, same weights, same sample, same batch
+composition in every run of every seed. Nothing drawn from `--seed` and
+nothing observed in the timed window enters the verdict.
+
+Three levels, all against the plain float32 reference on the same dequantised
+int8 weights:
+
+1. *Model level, teacher-forced.* The program's own block (`_layer`, with the
+   engine's config, so its attention dispatch) is applied layer by layer to
+   each sample sequence. At every layer the reference block is given the
+   SYSTEM's input to that layer and the two outputs are compared per
+   position: `e = |sys - ref|_inf / |ref|_inf`. Teacher forcing keeps one
+   layer's rounding, and one token's router tie, from spreading to every
+   later layer and position, so the tolerance can be tight and a tie is
+   local: a (layer, position) is *tie-exposed* where the reference's gap
+   between the k-th and (k+1)-th router logit is under `eps_router`. Pass:
+   median `e` <= `tol_med`, and every (layer, position) with `e > tol_max`
+   is tie-exposed. A dense model has no router, so none may exceed. The
+   sequence sits in row 0 of a group of `rows` rows, the others padding, as
+   the engine fills a prefill group for a lone request: the program's expert
+   capacity is per dispatch (`ceil(T*k*2/E)`, T = rows x width), and the
+   check says that the model agrees with the published equations where that
+   rule does not bind.
+2. *Hot path, logits.* The model functions the engine's own programs are
+   made of, called as the engine calls them: `prefill` of the group into a
+   local cache (the flash prefill kernel), `paged_insert_cache` into a page
+   pool of the engine's page size and KV type, then one
+   `paged_decode_step_inplace` per generated token through the page table
+   (the ragged paged decode kernel), teacher-forced on the engine's own
+   tokens. Per generated position `h = |hot - chain|_inf / |chain|_inf`
+   against the logits of step 1's chain on the same sequence, every layer of
+   which was just held to the reference (and, for a dense model, its logits
+   to the free-running reference by `tol_e2e_max`). Both run in bf16 on the
+   same weights and part by one more bf16 rounding a layer, like two runs of
+   one kernel in another order; a sequence's first generated position comes
+   from the prefill alone, the same kernel on the same values as the chain,
+   and sits far closer. Pass: the median `h` over positions not tie-exposed
+   <= `tol_hot_med`, and every position with `h > tol_hot_max` (a first
+   position: `tol_hot_first`) is one whose OWN token is tie-exposed (router
+   gap under `eps_router` in some layer at that position: its own routing
+   decides its logits; an earlier token's flip reaches it only through
+   attention, diluted by the context length). A KV
+   cache of fewer bits, a paged read of the wrong page or a decode kernel of
+   lower precision shows here as a number, whatever types the engine reports.
+3. *Engine level, margins.* Each sample prompt goes through the engine itself
+   (its fused admit and decode programs, sampling, scheduling), greedy,
+   `new_tokens` tokens. Per generated position the margin is `max(ref) -
+   ref[engine's token]`, where `ref` is the free-running float32 reference's
+   logits (`engine_scores: reference`) or, where router ties make a
+   free-running reference part ways with any bf16 run, the chain's
+   (`engine_scores: verified_chain`). Random weights give near-flat logits,
+   so tokens are never compared, only margins. Pass:
+   every margin <= `tol_margin`, except at positions whose own token is
+   tie-exposed.
+
+The engine's state is also held to the file: int8 weights, the KV dtype.
+"""
+
+from __future__ import annotations
+
+import functools
+import importlib
+from typing import Any, Callable, Optional
+
+import numpy as np
+
+
+def reference_dims(spec: dict) -> dict:
+    dims = {
+        "n_heads": spec["num_attention_heads"],
+        "n_kv_heads": spec["num_key_value_heads"],
+        "head_dim": spec.get("head_dim") or spec["hidden_size"] // spec["num_attention_heads"],
+        "rope_theta": float(spec["rope_theta"]),
+        "eps": float(spec["rms_norm_eps"]),
+    }
+    if spec.get("num_local_experts"):
+        dims["n_experts"] = spec["num_local_experts"]
+        dims["top_k"] = spec["num_experts_per_tok"]
+    return dims
+
+
+def sample_prompts(check: dict, vocab_size: int) -> list[list[int]]:
+    """The fixed sample: uniform ids below the unknown-word id, from `check_seed`."""
+    rng = np.random.default_rng(int(check["check_seed"]))
+    return [rng.integers(0, vocab_size - 1, int(n)).tolist() for n in check["lengths"]]
+
+
+class _Scorer:
+    """Layer-by-layer system chain, teacher-forced reference and
+    free-running reference over one padded sequence."""
+
+    def __init__(self, config, dims: dict, family: str, width: int, rows: int = 1,
+                 scores: str = "reference") -> None:
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+
+        from langstream_tpu.models import transformer as program
+
+        ref = importlib.import_module(f"reference.{family}")
+        self.width, self.rows = width, rows
+        self.judge = {"reference": "free", "verified_chain": "chain"}[scores]
+        positions = jnp.broadcast_to(jnp.arange(width), (rows, width))
+
+        def layer_slice(layers, index):
+            return jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, index, 0, keepdims=False), layers
+            )
+
+        @jax.jit
+        def sys_embed(params, tokens):
+            # the sequence in row 0 of a group of `rows`, the other rows all
+            # padding (id 0), as the engine fills a prefill group for one
+            # request: the program's expert capacity is per dispatch
+            group = jnp.zeros((rows, width), jnp.int32).at[0].set(tokens)
+            return program._embed(params, group, config)
+
+        @jax.jit
+        def sys_layer(layers, index, x):
+            # the body of transformer.forward, one layer at a time
+            sin, cos = program._rope_freqs(positions, config)
+            mask = jnp.broadcast_to(
+                jnp.tril(jnp.ones((width, width), jnp.bool_)), (rows, width, width)
+            )
+            y, _ = program._layer(x, layer_slice(layers, index), sin, cos, mask, config)
+            return y
+
+        @jax.jit
+        def sys_unembed(params, x):
+            return program._unembed(params, x[:1], config)[0]
+
+        def rel_err(got, want):
+            diff = jnp.max(jnp.abs(got.astype(jnp.float32) - want), axis=-1)
+            return diff / jnp.max(jnp.abs(want), axis=-1)
+
+        @jax.jit
+        def ref_layer(layers, index, sys_in, sys_out, free_in):
+            lp = layer_slice(layers, index)
+            forced, info = ref.layer(sys_in[0].astype(jnp.float32), lp, dims)
+            free, _ = ref.layer(free_in, lp, dims)
+            gap = info.get("router_gap", jnp.full((width,), jnp.inf))
+            load = info.get("expert_load", jnp.zeros((1,), jnp.int32))
+            return rel_err(sys_out[0], forced), gap, load, free
+
+        @jax.jit
+        def ref_head(params, sys_x, sys_logits, free_x):
+            forced = ref.unembed(params, sys_x[0].astype(jnp.float32), dims)
+            free = ref.unembed(params, free_x, dims)
+            return rel_err(sys_logits, forced), rel_err(sys_logits, free), free
+
+        @jax.jit
+        def margins(logits, tokens):
+            # logits at position p score the token at p + 1
+            picked = jnp.take_along_axis(logits[:-1], tokens[1:, None], axis=-1)[:, 0]
+            return jnp.max(logits[:-1], axis=-1) - picked
+
+        @jax.jit
+        def hot_err(logits, hot, start):
+            # hot[j] is the hot path's distribution for generated token j,
+            # which the sequence-wide logits hold at position start + j
+            want = lax.dynamic_slice_in_dim(logits, start, hot.shape[0], axis=0)
+            return rel_err(hot, want.astype(jnp.float32))
+
+        self._fns = (sys_embed, sys_layer, sys_unembed, ref_layer, ref_head, margins, hot_err)
+        self._ref_embed = jax.jit(ref.embed)
+        self._n_layers = config.n_layers
+
+    def score(self, sys_params, ref_params, sequence: list[int], n_prompt: int, hot) -> dict:
+        """All the per-position numbers for one sequence (host arrays).
+        `hot` [generated, V]: the hot path's logits for the generated tokens."""
+        import jax
+        import jax.numpy as jnp
+
+        sys_embed, sys_layer, sys_unembed, ref_layer, ref_head, margins, hot_err = self._fns
+        n = len(sequence)
+        if n > self.width:
+            raise ValueError(f"check sequence of {n} tokens exceeds width {self.width}")
+        tokens = jnp.asarray(sequence + [0] * (self.width - n), jnp.int32)
+        x = sys_embed(sys_params, tokens)
+        free = self._ref_embed(ref_params, tokens)
+        errs, gaps, loads = [], [], []
+        for index in range(self._n_layers):
+            y = sys_layer(sys_params["layers"], index, x)
+            err, gap, load, free = ref_layer(ref_params["layers"], index, x, y, free)
+            errs.append(err)
+            gaps.append(gap)
+            loads.append(load)
+            x = y
+        chain_logits = sys_unembed(sys_params, x)
+        head_err, e2e_err, free_logits = ref_head(ref_params, x, chain_logits, free)
+        out = {
+            "layer_err": jnp.stack(errs + [head_err])[:, :n],  # [L + 1, n]
+            "router_gap": jnp.stack(gaps)[:, :n],  # [L, n]
+            "expert_load_max": jnp.max(jnp.stack(loads)),
+            "e2e_err": e2e_err[:n],
+            "margin": margins(chain_logits if self.judge == "chain" else free_logits, tokens)[: n - 1],
+            "hot_err": hot_err(chain_logits, hot, n_prompt - 1),  # [generated]
+        }
+        return {k: np.asarray(v) for k, v in jax.device_get(out).items()}
+
+
+class _HotPath:
+    """The model functions the engine's programs are made of, called as the
+    engine calls them, with its config (so its kernels and its KV type) and
+    its page size, on a page pool of this check's own: the logits the engine
+    samples from, which it does not hand out."""
+
+    def __init__(self, engine, width: int, rows: int, new_tokens: int) -> None:
+        import jax
+        import jax.numpy as jnp
+
+        from langstream_tpu.models import transformer as program
+
+        config = engine.config
+        page_size = engine._pagepool.page_size
+        n_pages = -(-(width + new_tokens) // page_size)
+        self.width = width
+        # the sequence in row 0 of the group, its pages 0..n_pages-1; the
+        # padding rows' tables are all out of bounds, so their writes drop
+        tables = jnp.full((rows, n_pages), n_pages, jnp.int32).at[0].set(jnp.arange(n_pages))
+
+        @jax.jit
+        def prefill_group(params, tokens, length):
+            group = jnp.zeros((rows, width), jnp.int32).at[0].set(tokens)
+            lengths = jnp.ones((rows,), jnp.int32).at[0].set(length)
+            logits, local = program.prefill(
+                params, group, lengths, program.make_kv_cache(config, rows, width), config
+            )
+            pool = program.make_page_pool(config, n_pages, page_size)
+            return logits[0], program.paged_insert_cache(pool, local, tables, page_size)
+
+        @functools.partial(jax.jit, donate_argnames=("pool",))
+        def decode(params, token, position, pool):
+            logits, pool = program.paged_decode_step_inplace(
+                params, token[None], position[None], pool, tables[:1], config, page_size
+            )
+            return logits[0], pool
+
+        self._fns = (prefill_group, decode)
+
+    def logits(self, params, prompt: list[int], generated: list[int]):
+        """[len(generated), V]: row j is the distribution generated token j
+        was drawn from, token j - 1 having gone through the paged cache."""
+        import jax.numpy as jnp
+
+        prefill_group, decode = self._fns
+        n = len(prompt)
+        tokens = jnp.asarray(prompt + [0] * (self.width - n), jnp.int32)
+        first, pool = prefill_group(params, tokens, jnp.int32(n))
+        rows = [first]
+        for j, token in enumerate(generated[:-1]):
+            step, pool = decode(params, jnp.int32(token), jnp.int32(n + j), pool)
+            rows.append(step)
+        return jnp.stack(rows).astype(jnp.float32)
+
+
+def _engine_state(engine, check: dict) -> dict:
+    """What the engine holds, against what the file says it should."""
+    from langstream_tpu.models.quant import is_quantized
+
+    layers = engine.params["layers"]
+    weights = "int8" if all(
+        is_quantized(layers[k]) and layers[k]["q"].dtype == np.int8
+        for k in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+    ) else "unquantized"
+    pool = engine._pagepool.dev["k"]
+    kv = "int8" if isinstance(pool, dict) else str(pool.dtype)
+    found = {"weights": weights, "kv_dtype": kv}
+    wanted = {"weights": check["weights"], "kv_dtype": check["kv_dtype"]}
+    return {"found": found, "wanted": wanted, "ok": found == wanted}
+
+
+def _generate(engine, prompts: list[list[int]], new_tokens: int, together: bool) -> list[list[int]]:
+    from langstream_tpu.models.configs import GenerationOptions
+    from langstream_tpu.serving.engine import GenerationRequest
+
+    greedy = GenerationOptions(max_new_tokens=new_tokens, temperature=0.0)
+    if not together:
+        return [list(engine.generate(p, greedy, timeout=600).tokens) for p in prompts]
+    requests = [
+        engine.submit(GenerationRequest(prompt_tokens=list(p), options=greedy))
+        for p in prompts
+    ]
+    return [list(r.result(600).tokens) for r in requests]
+
+
+def _judge(scores: list[dict], prompt_lens: list[int], check: dict) -> dict:
+    """Tolerances of the file over the per-position numbers."""
+    eps = float(check.get("eps_router", 0.0))
+    layer_errs, unexplained, exposed, flipped = [], 0, 0, 0
+    gen_n, gen_exposed, margin_bad, hot_bad, first_bad, first_max = 0, 0, 0, 0, 0, 0.0
+    margins, hot_errs, e2e, over_gap_max = [], [], [], 0.0
+    for s, n_prompt in zip(scores, prompt_lens):
+        err, gap = s["layer_err"], s["router_gap"]
+        if not (np.isfinite(err).all() and np.isfinite(s["hot_err"]).all()):
+            return {"ok": False, "reason": "non-finite activations"}
+        tie = np.zeros_like(err, bool)
+        tie[:-1] = gap < eps  # the head has no router
+        over = err > float(check["tol_max"])
+        if over[:-1].any():
+            finite = gap[over[:-1]][np.isfinite(gap[over[:-1]])]
+            over_gap_max = max(over_gap_max, float(finite.max(initial=0.0)))
+        layer_errs.append(err.ravel())
+        exposed += int(tie.sum())
+        flipped += int((over & tie).sum())
+        unexplained += int((over & ~tie).sum())
+        e2e.append(s["e2e_err"])
+        # generated token j sits at index n_prompt + j and is drawn from the
+        # logits at n_prompt + j - 1: the token THERE is the one whose own
+        # routing decides them
+        generated = s["margin"][n_prompt - 1 :]
+        own_tie = tie[:-1].any(axis=0)[n_prompt - 1 : n_prompt - 1 + len(generated)]
+        gen_n += len(generated)
+        gen_exposed += int(own_tie.sum())
+        margin_bad += int(((generated > float(check["tol_margin"])) & ~own_tie).sum())
+        hot_bad += int(((s["hot_err"] > float(check["tol_hot_max"])) & ~own_tie).sum())
+        if not own_tie[0]:  # the prefill's own logits, before any paged read
+            first_max = max(first_max, float(s["hot_err"][0]))
+            first_bad += int(s["hot_err"][0] > float(check["tol_hot_first"]))
+        margins.append(generated)
+        hot_errs.append(np.where(own_tie, -s["hot_err"], s["hot_err"]))
+    all_err = np.concatenate(layer_errs)
+    e2e_all = np.concatenate(e2e)
+    hot_all = np.concatenate(hot_errs)  # a tie-exposed position is written negative
+    median = float(np.median(all_err))
+    verdict = {
+        "layer_err_median": median,
+        "layer_err_max": float(all_err.max()),
+        "layer_positions": int(all_err.size),
+        "tie_exposed": exposed,
+        "tie_exposed_over_tol": flipped,
+        "unexplained_over_tol": unexplained,
+        "over_tol_router_gap_max": over_gap_max,
+        "e2e_err_median": float(np.median(e2e_all)),
+        "e2e_err_max": float(e2e_all.max()),
+        "engine_positions": gen_n,
+        "engine_positions_tie_exposed": gen_exposed,
+        "hot_err_median_unexposed": float(np.median(hot_all[hot_all >= 0])) if (hot_all >= 0).any() else 0.0,
+        "hot_err_max_unexposed": float(hot_all.max(initial=0.0)),
+        "hot_err_first_max_unexposed": first_max,
+        "hot_err_over_tol": hot_bad + first_bad,
+        "engine_margin_max": float(np.concatenate(margins).max(initial=0.0)),
+        "engine_margin_over_tol": margin_bad,
+        "hot_err_by_position": [round(float(v), 5) for v in hot_all],
+    }
+    ok = median <= float(check["tol_med"]) and unexplained == 0 and margin_bad == 0
+    ok = ok and hot_bad == 0 and first_bad == 0
+    if check.get("tol_e2e_max") is not None:
+        ok = ok and verdict["e2e_err_max"] <= float(check["tol_e2e_max"])
+    if check.get("tol_hot_med") is not None:
+        ok = ok and verdict["hot_err_median_unexposed"] <= float(check["tol_hot_med"])
+    verdict["ok"] = bool(ok)
+    return verdict
+
+
+def run_check(
+    engine,
+    spec: dict,
+    *,
+    ref_params: Optional[Any] = None,
+    emit: Callable[..., None] = lambda **_: None,
+) -> dict:
+    """The verdict and its evidence. `ref_params` is the tree the reference
+    reads; it is the engine's own except in the harness's tests, which hand
+    the engine a faulted copy to show that the check can fail."""
+    check = spec["check"]
+    config = engine.config
+    dims = reference_dims(spec)
+    ref_params = engine.params if ref_params is None else ref_params
+    prompts = sample_prompts(check, config.vocab_size)
+    new_tokens = int(check["new_tokens"])
+
+    state = _engine_state(engine, check)
+    width, rows = int(check["width"]), int(check.get("rows", 1))
+    scorer = _Scorer(config, dims, spec["family"], width, rows, check["engine_scores"])
+    hot_path = _HotPath(engine, width, rows, new_tokens)
+
+    def score(prompt: list[int], tokens: list[int]) -> dict:
+        hot = hot_path.logits(engine.params, prompt, tokens)
+        return scorer.score(engine.params, ref_params, prompt + tokens, len(prompt), hot)
+
+    generated = _generate(engine, prompts, new_tokens, together=False)
+    answered = all(len(g) > 0 for g in generated)
+    if not answered:  # a prompt that produced nothing was not checked at all
+        verdict = {"ok": False, "reason": "a check prompt produced no token"}
+        emit(phase="check", **verdict)
+        return verdict
+    scores = [score(p, g) for p, g in zip(prompts, generated)]
+    verdict = _judge(scores, [len(p) for p in prompts], check)
+    verdict["engine_state"] = state
+    verdict["generated_tokens"] = [len(g) for g in generated]
+    verdict["expert_load_max"] = int(max(s["expert_load_max"] for s in scores))
+    verdict["ok"] = bool(verdict["ok"] and state["ok"])
+    emit(phase="check", **verdict)
+
+    if check.get("batched_probe"):
+        # the same prompts submitted at once share prefill groups, where the
+        # program's expert capacity can drop real tokens (PERF.md, fault 1).
+        # A finding to print, never part of the verdict.
+        together = _generate(engine, prompts, new_tokens, together=True)
+        probe = _judge(
+            [score(p, g) for p, g in zip(prompts, together)],
+            [len(p) for p in prompts], check,
+        )
+        emit(
+            phase="check-batched-probe",
+            engine_positions=probe.get("engine_positions"),
+            engine_margin_over_tol=probe.get("engine_margin_over_tol"),
+            engine_margin_max=probe.get("engine_margin_max"),
+            hot_err_over_tol=probe.get("hot_err_over_tol"),
+            same_tokens_as_alone=[a == b for a, b in zip(generated, together)],
+        )
+    return verdict
