@@ -35,6 +35,27 @@ from langstream_tpu.models.quant import dequantize_weight, is_quantized, quantiz
 Params = dict
 KVCache = dict
 
+# The one vocabulary of `jax.named_scope` names inside the device programs
+# (docs/SERVING.md §12). Metadata only: a profile's device operations carry
+# the scope path, so a reader finds "the time inside attention" after any
+# refactor renumbers the fusions. `sample` wraps the engine's sampling and
+# grammar mask (serving/engine.py); every other name is entered here.
+SCOPES = (
+    "embed", "attention", "ffn", "moe_ffn", "moe_ffn.route",
+    "moe_ffn.dispatch", "moe_ffn.experts", "moe_ffn.combine",
+    "kv_pool.read", "kv_pool.write", "head", "sample",
+)
+
+# What `moe_ffn_counted` counts, per call, as one int32 vector: expert
+# assignments made (tokens x top-k), those dropped past `capacity`, and the
+# same two over REAL tokens only (``token_valid``; without it every token
+# counts as real). A dense model's programs return zeros.
+MOE_COUNTS = ("routed", "dropped", "routed_real", "dropped_real")
+
+
+def _no_moe_counts() -> jax.Array:
+    return jnp.zeros(len(MOE_COUNTS), jnp.int32)
+
 
 def _dtype(config: ModelConfig):
     return jnp.dtype(config.dtype)
@@ -486,14 +507,25 @@ def moe_ffn(x: jax.Array, lp: dict, config: ModelConfig) -> jax.Array:
     ICI with no data-dependent control flow. Overflowing tokens fall back to
     their residual stream (standard token-dropping).
     """
+    return moe_ffn_counted(x, lp, config)[0]
+
+
+def moe_ffn_counted(
+    x: jax.Array, lp: dict, config: ModelConfig,
+    token_valid: Optional[jax.Array] = None,  # [B, S] bool — real tokens
+) -> tuple[jax.Array, jax.Array]:
+    """`moe_ffn` plus its MOE_COUNTS vector. The counts read ``keep`` and
+    change nothing the output is computed from: padding still routes and
+    still takes capacity (masking it out is ROADMAP S5, not this)."""
     b, s, d = x.shape
     t = b * s
     e, k = config.n_experts, config.n_experts_per_tok
     xf = x.reshape(t, d)
 
-    logits = (xf @ lp["router"]).astype(jnp.float32)  # [T, E]
-    weights, chosen = lax.top_k(logits, k)  # [T, k]
-    weights = jax.nn.softmax(weights, axis=-1)
+    with jax.named_scope("moe_ffn.route"):
+        logits = (xf @ lp["router"]).astype(jnp.float32)  # [T, E]
+        weights, chosen = lax.top_k(logits, k)  # [T, k]
+        weights = jax.nn.softmax(weights, axis=-1)
 
     # Capacity bounds the [T,E,C] dispatch tensor to linear in T. factor<=0
     # restores lossless C=T (exactness tests); the floor keeps tiny decode
@@ -503,41 +535,59 @@ def moe_ffn(x: jax.Array, lp: dict, config: ModelConfig) -> jax.Array:
         capacity = min(t, max(math.ceil(t * k * factor / e), min(t, 64)))
     else:
         capacity = t
-    # position of each (token, slot) within its expert's capacity buffer
-    onehot = jax.nn.one_hot(chosen, e, dtype=jnp.int32)  # [T, k, E]
-    flat = onehot.reshape(t * k, e)
-    pos_in_expert = jnp.cumsum(flat, axis=0) - 1  # [T*k, E]
-    pos = (pos_in_expert * flat).sum(-1).reshape(t, k)  # [T, k]
-    keep = pos < capacity
+    with jax.named_scope("moe_ffn.dispatch"):
+        # position of each (token, slot) within its expert's capacity buffer
+        onehot = jax.nn.one_hot(chosen, e, dtype=jnp.int32)  # [T, k, E]
+        flat = onehot.reshape(t * k, e)
+        pos_in_expert = jnp.cumsum(flat, axis=0) - 1  # [T*k, E]
+        pos = (pos_in_expert * flat).sum(-1).reshape(t, k)  # [T, k]
+        keep = pos < capacity
 
-    # dispatch: [T, E, C]
-    dispatch = (
-        jax.nn.one_hot(chosen, e, dtype=xf.dtype)[..., None]
-        * jax.nn.one_hot(jnp.where(keep, pos, capacity), capacity, dtype=xf.dtype)[
-            :, :, None, :
-        ]
-    ).sum(axis=1)
-    # combine weights per (token, expert, cap-slot)
-    combine = (
-        jax.nn.one_hot(chosen, e, dtype=jnp.float32)[..., None]
-        * jax.nn.one_hot(jnp.where(keep, pos, capacity), capacity, dtype=jnp.float32)[
-            :, :, None, :
-        ]
-        * weights[..., None, None]
-    ).sum(axis=1)
+        # dispatch: [T, E, C]
+        dispatch = (
+            jax.nn.one_hot(chosen, e, dtype=xf.dtype)[..., None]
+            * jax.nn.one_hot(jnp.where(keep, pos, capacity), capacity, dtype=xf.dtype)[
+                :, :, None, :
+            ]
+        ).sum(axis=1)
+        # combine weights per (token, expert, cap-slot)
+        combine = (
+            jax.nn.one_hot(chosen, e, dtype=jnp.float32)[..., None]
+            * jax.nn.one_hot(jnp.where(keep, pos, capacity), capacity, dtype=jnp.float32)[
+                :, :, None, :
+            ]
+            * weights[..., None, None]
+        ).sum(axis=1)
+        dropped = ~keep  # [T, k]
+        if token_valid is None:
+            routed_real = jnp.int32(t * k)
+            dropped_real = dropped.sum(dtype=jnp.int32)
+        else:
+            real = token_valid.reshape(t)
+            routed_real = real.sum(dtype=jnp.int32) * k
+            dropped_real = (dropped & real[:, None]).sum(dtype=jnp.int32)
+        counts = jnp.stack([
+            jnp.int32(t * k), dropped.sum(dtype=jnp.int32), routed_real,
+            dropped_real,
+        ])
 
     def expert_w(name: str) -> jax.Array:
         w = lp[name]
         return dequantize_weight(w, xf.dtype) if is_quantized(w) else w
 
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, xf)  # [E, C, D]
-    gate = _activation(
-        jnp.einsum("ecd,edf->ecf", expert_in, expert_w("w_gate")), config.activation
-    )
-    up = jnp.einsum("ecd,edf->ecf", expert_in, expert_w("w_up"))
-    expert_out = jnp.einsum("ecf,efd->ecd", gate * up, expert_w("w_down"))  # [E, C, D]
-    out = jnp.einsum("tec,ecd->td", combine.astype(xf.dtype), expert_out)
-    return out.reshape(b, s, d)
+    with jax.named_scope("moe_ffn.experts"):
+        expert_in = jnp.einsum("tec,td->ecd", dispatch, xf)  # [E, C, D]
+        gate = _activation(
+            jnp.einsum("ecd,edf->ecf", expert_in, expert_w("w_gate")),
+            config.activation,
+        )
+        up = jnp.einsum("ecd,edf->ecf", expert_in, expert_w("w_up"))
+        expert_out = jnp.einsum(
+            "ecf,efd->ecd", gate * up, expert_w("w_down")
+        )  # [E, C, D]
+    with jax.named_scope("moe_ffn.combine"):
+        out = jnp.einsum("tec,ecd->td", combine.astype(xf.dtype), expert_out)
+    return out.reshape(b, s, d), counts
 
 
 # ---------------------------------------------------------------------------
@@ -545,7 +595,7 @@ def moe_ffn(x: jax.Array, lp: dict, config: ModelConfig) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 
-def _layer(
+def _attention_block(
     x: jax.Array,
     lp: dict,
     sin: jax.Array,
@@ -565,18 +615,9 @@ def _layer(
     lora_scale: Optional[jax.Array] = None,  # [R] per-adapter scale
     adapter_rows: Optional[jax.Array] = None,  # [B] pool row per slot
 ) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]]]:
-    """One transformer block. If cache_kv given, k/v are written at
-    cache_positions and attention runs over the full cache width. With
-    ``collect_kv`` (cache-less paths) the layer's roped K/V come back
-    head-major so a caller can build a cache from a full forward — the
-    ring-prefill serving path (parallel.sp.ring_prefill). With
-    ``paged_table`` set, cache_kv are per-layer PAGE-POOL entries
-    ([P, Hkv, ps, D]): K/V scatter to the slot's pages and attention reads
-    through the table (Pallas ragged-paged kernel on decode shapes when it
-    applies, else the gathered masked-jnp view — same math either way).
-    With ``lora`` set, every projection adds its slot-gathered low-rank
-    adapter term (``_lora_delta``) — K/V written to the cache INCLUDE the
-    wk/wv adapter deltas, which is why prefill must be adapter-aware too."""
+    """The attention half of a block (norm, QKV, rotary, cache write, the
+    kernel or jnp path, output projection, residual): the layer's input in,
+    the FFN's input and the layer's new cache entry out."""
     b, s, d = x.shape
     hd = config.resolved_head_dim
 
@@ -633,16 +674,7 @@ def _layer(
         x = x + quantized_matmul(attn, lp["wo"]) + _lora_proj(
             attn, "wo", lora, lora_scale, adapter_rows
         )
-        ffn_in = rms_norm(x, lp["ffn_norm"], config.rms_norm_eps)
-        ffn_out = (
-            moe_ffn(ffn_in, lp, config)
-            if config.is_moe
-            else dense_ffn(
-                ffn_in, lp, config, lora=lora, lora_scale=lora_scale,
-                adapter_rows=adapter_rows,
-            )
-        )
-        return x + ffn_out, new_cache
+        return x, new_cache
     if cache_kv is not None:
         ck, cv = cache_kv  # [B, Hkv, T, D] head-major (maybe int8-quantized)
         # scatter this step's k/v into the cache at cache_positions [B, S]
@@ -687,43 +719,98 @@ def _layer(
         attn_out = quantized_matmul(attn, lp["wo"]) + _lora_proj(
             attn, "wo", lora, lora_scale, adapter_rows
         )
-    x = x + attn_out
+    return x + attn_out, new_cache
 
-    ffn_in = rms_norm(x, lp["ffn_norm"], config.rms_norm_eps)
-    if config.is_moe:
-        ffn_out = moe_ffn(ffn_in, lp, config)
-    else:
-        ffn_out = dense_ffn(
-            ffn_in, lp, config, lora=lora, lora_scale=lora_scale,
-            adapter_rows=adapter_rows,
+
+
+
+def _layer_counted(
+    x: jax.Array,
+    lp: dict,
+    sin: jax.Array,
+    cos: jax.Array,
+    mask: jax.Array,
+    config: ModelConfig,
+    cache_kv: Optional[tuple[jax.Array, jax.Array]] = None,
+    cache_positions: Optional[jax.Array] = None,
+    causal: bool = True,
+    kv_offset: Optional[jax.Array] = None,
+    kv_bound: Optional[int] = None,
+    collect_kv: bool = False,
+    verify: bool = False,
+    paged_table: Optional[jax.Array] = None,  # [B, Tp] physical pages
+    page_size: int = 0,
+    lora: Optional[dict] = None,  # per-layer adapter slices {proj: {a, b}}
+    lora_scale: Optional[jax.Array] = None,  # [R] per-adapter scale
+    adapter_rows: Optional[jax.Array] = None,  # [B] pool row per slot
+    token_valid: Optional[jax.Array] = None,  # [B, S] bool — real tokens
+) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]], jax.Array]:
+    """One transformer block, and its MOE_COUNTS (zeros when dense; only
+    ``token_valid`` feeds them). If cache_kv given, k/v are written at
+    cache_positions and attention runs over the full cache width. With
+    ``collect_kv`` (cache-less paths) the layer's roped K/V come back
+    head-major so a caller can build a cache from a full forward — the
+    ring-prefill serving path (parallel.sp.ring_prefill). With
+    ``paged_table`` set, cache_kv are per-layer PAGE-POOL entries
+    ([P, Hkv, ps, D]): K/V scatter to the slot's pages and attention reads
+    through the table (Pallas ragged-paged kernel on decode shapes when it
+    applies, else the gathered masked-jnp view — same math either way).
+    With ``lora`` set, every projection adds its slot-gathered low-rank
+    adapter term (``_lora_delta``) — K/V written to the cache INCLUDE the
+    wk/wv adapter deltas, which is why prefill must be adapter-aware too."""
+    with jax.named_scope("attention"):
+        x, new_cache = _attention_block(
+            x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal,
+            kv_offset, kv_bound, collect_kv, verify, paged_table, page_size,
+            lora, lora_scale, adapter_rows,
         )
-    return x + ffn_out, new_cache
+    if config.is_moe:
+        with jax.named_scope("moe_ffn"):
+            ffn_in = rms_norm(x, lp["ffn_norm"], config.rms_norm_eps)
+            ffn_out, counts = moe_ffn_counted(ffn_in, lp, config, token_valid)
+    else:
+        with jax.named_scope("ffn"):
+            ffn_in = rms_norm(x, lp["ffn_norm"], config.rms_norm_eps)
+            ffn_out = dense_ffn(
+                ffn_in, lp, config, lora=lora, lora_scale=lora_scale,
+                adapter_rows=adapter_rows,
+            )
+        counts = _no_moe_counts()
+    return x + ffn_out, new_cache, counts
+
+
+def _layer(*args, **kwargs):
+    """`_layer_counted` without the counts: (output, new cache entry)."""
+    y, new_cache, _ = _layer_counted(*args, **kwargs)
+    return y, new_cache
 
 
 def _embed(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array:
     table = params["embed"]
-    if is_quantized(table):
-        x = (
-            table["q"][tokens].astype(jnp.float32) * table["s"][tokens]
-        ).astype(_dtype(config))
-    else:
-        x = table[tokens]
-    if config.embedding_scale:
-        x = x * jnp.sqrt(jnp.float32(config.d_model)).astype(x.dtype)
+    with jax.named_scope("embed"):
+        if is_quantized(table):
+            x = (
+                table["q"][tokens].astype(jnp.float32) * table["s"][tokens]
+            ).astype(_dtype(config))
+        else:
+            x = table[tokens]
+        if config.embedding_scale:
+            x = x * jnp.sqrt(jnp.float32(config.d_model)).astype(x.dtype)
     return x
 
 
 def _unembed(params: Params, x: jax.Array, config: ModelConfig) -> jax.Array:
-    x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
-    if config.tie_embeddings:
-        table = params["embed"]
-        head = (
-            dequantize_weight(table, x.dtype) if is_quantized(table) else table
-        ).T
-        logits = (x @ head).astype(jnp.float32)
-    else:
-        logits = quantized_matmul(x, params["lm_head"]).astype(jnp.float32)
-    return _softcap(logits, config.final_logit_softcap)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        if config.tie_embeddings:
+            table = params["embed"]
+            head = (
+                dequantize_weight(table, x.dtype) if is_quantized(table) else table
+            ).T
+            logits = (x @ head).astype(jnp.float32)
+        else:
+            logits = quantized_matmul(x, params["lm_head"]).astype(jnp.float32)
+        return _softcap(logits, config.final_logit_softcap)
 
 
 def _split_lora(lora: Optional[dict]):
@@ -739,42 +826,47 @@ def _split_lora(lora: Optional[dict]):
 def _scan_layers(
     params, x, sin, cos, mask, config, cache=None, cache_positions=None, causal=True,
     kv_offset=None, kv_bound=None, collect_kv=False,
-    lora=None, adapter_rows=None,
+    lora=None, adapter_rows=None, token_valid=None,
 ):
-    """lax.scan over stacked layer params; carries (x, cache). With
+    """lax.scan over stacked layer params; carries (x, cache) and returns
+    them with the layers' summed MOE_COUNTS as a third element. With
     ``collect_kv`` (cache-less) the scan stacks each layer's roped K/V into
     [L, B, Hkv, S, D] arrays — the makings of a serving cache. ``lora``
     (the stacked adapter pool) joins the scan xs so each layer body sees
     its own [R, din, r] slices."""
     layers = params["layers"]
     lora_layers, lora_scale = _split_lora(lora)
+    # a dense model's zeros stay out of the scan: its programs are the
+    # ones they were, and the counts a constant beside them
+    moe = config.is_moe
 
     if cache is None:
 
         def body(carry, lp):
-            y, kv = _layer(
+            y, kv, counts = _layer_counted(
                 carry, lp, sin, cos, mask, config, causal=causal,
-                collect_kv=collect_kv,
+                collect_kv=collect_kv, token_valid=token_valid,
             )
-            return y, kv
+            return y, (kv, counts if moe else None)
 
-        x, kvs = lax.scan(body, x, layers)
-        return x, kvs
+        x, (kvs, counts) = lax.scan(body, x, layers)
+        return x, kvs, counts.sum(0) if moe else _no_moe_counts()
 
     def body_cached(carry, inputs):
         lp, (ck, cv), ll = inputs
-        y, new_kv = _layer(
+        y, new_kv, counts = _layer_counted(
             carry, lp, sin, cos, mask, config, cache_kv=(ck, cv),
             cache_positions=cache_positions, kv_offset=kv_offset,
             kv_bound=kv_bound, lora=ll, lora_scale=lora_scale,
-            adapter_rows=adapter_rows,
+            adapter_rows=adapter_rows, token_valid=token_valid,
         )
-        return y, new_kv
+        return y, (new_kv, counts if moe else None)
 
-    x, new_kv = lax.scan(
+    x, (new_kv, counts) = lax.scan(
         body_cached, x, (layers, (cache["k"], cache["v"]), lora_layers)
     )
-    return x, {"k": new_kv[0], "v": new_kv[1]}
+    counts = counts.sum(0) if moe else _no_moe_counts()
+    return x, {"k": new_kv[0], "v": new_kv[1]}, counts
 
 
 def _scan_layers_inplace(
@@ -792,7 +884,8 @@ def _scan_layers_inplace(
     llama-3-8b at B=48 on a 16GiB chip (serving/memory.py scan_buffer term).
     A while-loop carry is aliased in place by XLA, and the per-layer
     dynamic-update-slice back into the carried buffer is in-place too, so
-    peak cache memory here is 1x cache + one layer slice."""
+    peak cache memory here is 1x cache + one layer slice. Returns (x,
+    cache, the layers' summed MOE_COUNTS)."""
     layers = params["layers"]
 
     def read(full, l):
@@ -810,9 +903,10 @@ def _scan_layers_inplace(
     def body(carry, inputs):
         x, cache = carry
         lp, l, ll = inputs
-        ck = read(cache["k"], l)
-        cv = read(cache["v"], l)
-        y, new_kv = _layer(
+        with jax.named_scope("kv_pool.read"):
+            ck = read(cache["k"], l)
+            cv = read(cache["v"], l)
+        y, new_kv, counts = _layer_counted(
             x, lp, sin, cos, mask, config, cache_kv=(ck, cv),
             cache_positions=cache_positions, kv_offset=kv_offset,
             kv_bound=kv_bound, verify=verify, paged_table=paged_table,
@@ -820,13 +914,18 @@ def _scan_layers_inplace(
             adapter_rows=adapter_rows,
         )
         nck, ncv = new_kv
-        cache = {"k": write(cache["k"], nck, l), "v": write(cache["v"], ncv, l)}
-        return (y, cache), None
+        with jax.named_scope("kv_pool.write"):
+            cache = {
+                "k": write(cache["k"], nck, l), "v": write(cache["v"], ncv, l)
+            }
+        return (y, cache), (counts if config.is_moe else None)
 
-    (x, cache), _ = lax.scan(
+    (x, cache), counts = lax.scan(
         body, (x, cache), (layers, jnp.arange(config.n_layers), lora_layers)
     )
-    return x, cache
+    # a dense model's zeros stay out of the scan (see _scan_layers)
+    counts = counts.sum(0) if config.is_moe else _no_moe_counts()
+    return x, cache, counts
 
 
 # ---------------------------------------------------------------------------
@@ -850,7 +949,7 @@ def forward(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array
     mask = jnp.tril(jnp.ones((s, s), jnp.bool_))[None, :, :]
     mask = jnp.broadcast_to(mask, (b, s, s))
     x = _embed(params, tokens, config)
-    x, _ = _scan_layers(params, x, sin, cos, mask, config)
+    x, _, _ = _scan_layers(params, x, sin, cos, mask, config)
     return _unembed(params, x, config)
 
 
@@ -872,7 +971,7 @@ def encode(
     valid = positions < lengths[:, None]  # [B, S]
     mask = valid[:, None, :] & valid[:, :, None]  # full attention over real tokens
     x = _embed(params, tokens, config)
-    x, _ = _scan_layers(params, x, sin, cos, mask, config, causal=False)
+    x, _, _ = _scan_layers(params, x, sin, cos, mask, config, causal=False)
     x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
     w = valid[:, :, None].astype(jnp.float32)
     pooled = (x.astype(jnp.float32) * w).sum(1) / jnp.maximum(w.sum(1), 1.0)
@@ -898,7 +997,9 @@ def make_kv_cache(config: ModelConfig, batch: int, max_len: int, dtype=None) -> 
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-@functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
+@functools.partial(
+    jax.jit, static_argnames=("config", "moe_counts"), donate_argnames=("cache",)
+)
 def prefill(
     params: Params,
     tokens: jax.Array,  # [B, S] padded prompts
@@ -907,10 +1008,17 @@ def prefill(
     config: ModelConfig,
     lora: Optional[dict] = None,  # stacked adapter pool (serving/adapters.py)
     adapter_rows: Optional[jax.Array] = None,  # [B] pool row per prompt
-) -> tuple[jax.Array, KVCache]:
+    moe_counts: bool = False,
+    real_lengths: Optional[jax.Array] = None,  # [B]; 0 for a padding row
+):
     """Process prompts, fill cache slots 0..len, return logits at the last
     real token of each prompt ([B, V]). With adapters, the prompt's K/V
-    carry the wk/wv deltas — a tenant's cache is its own from token 0."""
+    carry the wk/wv deltas — a tenant's cache is its own from token 0.
+    ``moe_counts`` (here and on the decode and verify steps below; the
+    segment entry points return none) appends the summed MOE_COUNTS of the
+    call to the returned tuple; positions past a row's
+    length are the padding its `*_real` counts leave out (``real_lengths``
+    where a whole row is padding: the engine gives such a row length 1)."""
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     sin, cos = _rope_freqs(positions, config)
@@ -921,14 +1029,16 @@ def prefill(
     mask = kv_pos <= q_pos[:, :, None]
     mask = mask & (kv_pos < s)
     x = _embed(params, tokens, config)
-    x, cache = _scan_layers(
+    x, cache, counts = _scan_layers(
         params, x, sin, cos, mask, config, cache=cache, cache_positions=positions,
         lora=lora, adapter_rows=adapter_rows,
+        token_valid=positions
+        < (lengths if real_lengths is None else real_lengths)[:, None],
     )
     last = jnp.clip(lengths - 1, 0, s - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [B, D]
     logits = _unembed(params, x_last[:, None, :], config)[:, 0]
-    return logits, cache
+    return (logits, cache, counts) if moe_counts else (logits, cache)
 
 
 @functools.partial(
@@ -966,7 +1076,7 @@ def prefill_segment(
     kv_pos = jnp.arange(t)[None, None, :]
     mask = kv_pos <= positions[:, :, None]
     x = _embed(params, tokens, config)
-    x, cache = _scan_layers(
+    x, cache, _ = _scan_layers(
         params, x, sin, cos, mask, config, cache=cache,
         cache_positions=positions, kv_offset=offsets, kv_bound=kv_bound,
         lora=lora, adapter_rows=adapter_rows,
@@ -993,7 +1103,7 @@ def decode_step(
     kv_pos = jnp.arange(t)[None, None, :]
     mask = kv_pos <= pos2[:, :, None]  # attend to everything written ≤ position
     x = _embed(params, tokens[:, None], config)
-    x, cache = _scan_layers(
+    x, cache, _ = _scan_layers(
         params, x, sin, cos, mask, config, cache=cache, cache_positions=pos2
     )
     return _unembed(params, x, config)[:, 0], cache
@@ -1008,7 +1118,8 @@ def decode_step_inplace(
     kv_bound: Optional[int] = None,  # static cap on readable cache columns
     lora: Optional[dict] = None,
     adapter_rows: Optional[jax.Array] = None,
-) -> tuple[jax.Array, KVCache]:
+    moe_counts: bool = False,
+):
     """decode_step with the in-place layer scan (_scan_layers_inplace) —
     NOT separately jitted: intended as the body of a fused multi-step chunk
     (engine `_decode_chunk`) where the xs/ys cache double-buffer would
@@ -1025,11 +1136,12 @@ def decode_step_inplace(
     kv_pos = jnp.arange(t)[None, None, :]
     mask = kv_pos <= pos2[:, :, None]
     x = _embed(params, tokens[:, None], config)
-    x, cache = _scan_layers_inplace(
+    x, cache, counts = _scan_layers_inplace(
         params, x, sin, cos, mask, config, cache=cache, cache_positions=pos2,
         kv_bound=kv_bound, lora=lora, adapter_rows=adapter_rows,
     )
-    return _unembed(params, x, config)[:, 0], cache
+    logits = _unembed(params, x, config)[:, 0]
+    return (logits, cache, counts) if moe_counts else (logits, cache)
 
 
 def verify_step_inplace(
@@ -1040,7 +1152,8 @@ def verify_step_inplace(
     config: ModelConfig,
     lora: Optional[dict] = None,
     adapter_rows: Optional[jax.Array] = None,
-) -> tuple[jax.Array, KVCache]:
+    moe_counts: bool = False,
+):
     """Multi-token speculative verify: score K drafts per slot in ONE
     forward — logits at EVERY position come back ([B, K+1, V], unlike
     prefill_segment's last-token-only), so the engine's rejection sampler
@@ -1066,11 +1179,12 @@ def verify_step_inplace(
     kv_pos = jnp.arange(t)[None, None, :]
     mask = kv_pos <= pos[:, :, None]  # per-slot causal over global positions
     x = _embed(params, tokens, config)
-    x, cache = _scan_layers_inplace(
+    x, cache, counts = _scan_layers_inplace(
         params, x, sin, cos, mask, config, cache=cache, cache_positions=pos,
         kv_offset=positions, verify=True, lora=lora, adapter_rows=adapter_rows,
     )
-    return _unembed(params, x, config), cache
+    logits = _unembed(params, x, config)
+    return (logits, cache, counts) if moe_counts else (logits, cache)
 
 
 # ---------------------------------------------------------------------------
@@ -1102,7 +1216,8 @@ def paged_decode_step_inplace(
     page_size: int,
     lora: Optional[dict] = None,
     adapter_rows: Optional[jax.Array] = None,
-) -> tuple[jax.Array, KVCache]:
+    moe_counts: bool = False,
+):
     """decode_step through the page table: ONE compiled program for every
     sequence-length mix (the dense path's (steps × kv_bound) ladder is
     gone — a slot reads exactly its mapped pages). With adapters, the
@@ -1112,12 +1227,13 @@ def paged_decode_step_inplace(
     sin, cos = _rope_freqs(pos2, config)
     mask = _paged_mask(table, page_size, pos2)
     x = _embed(params, tokens[:, None], config)
-    x, pool = _scan_layers_inplace(
+    x, pool, counts = _scan_layers_inplace(
         params, x, sin, cos, mask, config, cache=pool, cache_positions=pos2,
         paged_table=table, page_size=page_size, lora=lora,
         adapter_rows=adapter_rows,
     )
-    return _unembed(params, x, config)[:, 0], pool
+    logits = _unembed(params, x, config)[:, 0]
+    return (logits, pool, counts) if moe_counts else (logits, pool)
 
 
 def paged_verify_step_inplace(
@@ -1130,7 +1246,8 @@ def paged_verify_step_inplace(
     page_size: int,
     lora: Optional[dict] = None,
     adapter_rows: Optional[jax.Array] = None,
-) -> tuple[jax.Array, KVCache]:
+    moe_counts: bool = False,
+):
     """verify_step through the page table → logits [B, K+1, V]. Same
     stale-rejected-rows invariant as the dense verify: positions advance
     only past ACCEPTED tokens and the next dispatch overwrites the stale
@@ -1140,12 +1257,13 @@ def paged_verify_step_inplace(
     sin, cos = _rope_freqs(pos, config)
     mask = _paged_mask(table, page_size, pos)
     x = _embed(params, tokens, config)
-    x, pool = _scan_layers_inplace(
+    x, pool, counts = _scan_layers_inplace(
         params, x, sin, cos, mask, config, cache=pool, cache_positions=pos,
         verify=True, paged_table=table, page_size=page_size, lora=lora,
         adapter_rows=adapter_rows,
     )
-    return _unembed(params, x, config), pool
+    logits = _unembed(params, x, config)
+    return (logits, pool, counts) if moe_counts else (logits, pool)
 
 
 def paged_prefill_segment_inplace(
@@ -1171,7 +1289,7 @@ def paged_prefill_segment_inplace(
     sin, cos = _rope_freqs(positions, config)
     mask = _paged_mask(table, page_size, positions)
     x = _embed(params, tokens, config)
-    x, pool = _scan_layers_inplace(
+    x, pool, _ = _scan_layers_inplace(
         params, x, sin, cos, mask, config, cache=pool,
         cache_positions=positions, kv_offset=offsets,
         paged_table=table, page_size=page_size, lora=lora,
@@ -1206,7 +1324,8 @@ def paged_insert_cache(
             loc.astype(pl_entry.dtype), mode="drop"
         )
 
-    return jax.tree.map(put, pool, local_cache)
+    with jax.named_scope("kv_pool.write"):
+        return jax.tree.map(put, pool, local_cache)
 
 
 # ---------------------------------------------------------------------------

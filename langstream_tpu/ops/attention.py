@@ -265,6 +265,7 @@ def flash_prefill_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_prefill_attention",
         grid=(b, hkv, s // block_q, s // block_k),
         in_specs=[
             pl.BlockSpec(
@@ -443,6 +444,7 @@ def flash_segment_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_segment_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
         compiler_params=_COMPILER_PARAMS,
@@ -544,6 +546,7 @@ def flash_segment_attention_int8(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_segment_attention_int8",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
         compiler_params=_COMPILER_PARAMS,
@@ -680,6 +683,7 @@ def ragged_decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="ragged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
@@ -835,6 +839,7 @@ def ragged_decode_attention_int8(
     )
     out = pl.pallas_call(
         kernel,
+        name="ragged_decode_attention_int8",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
@@ -997,6 +1002,7 @@ def ragged_paged_decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="ragged_paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,
@@ -1132,6 +1138,7 @@ def ragged_paged_decode_attention_int8(
     )
     out = pl.pallas_call(
         kernel,
+        name="ragged_paged_decode_attention_int8",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
         interpret=interpret,

@@ -89,7 +89,7 @@ def ring_prefill(
         x = _embed(params, tok_local, ring_config)
         # mask is unused on the ring path (causality lives inside
         # ring_attention's global block positions)
-        x, (k, v) = _scan_layers(
+        x, (k, v), _ = _scan_layers(
             params, x, sin, cos, None, ring_config, collect_kv=True
         )
         # last real token lives in exactly one device's block: that device

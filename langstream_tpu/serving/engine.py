@@ -34,6 +34,7 @@ from jax import lax
 
 from langstream_tpu.models.configs import GenerationOptions, ModelConfig
 from langstream_tpu.models.transformer import (
+    MOE_COUNTS,
     cache_width,
     decode_step_inplace,
     make_kv_cache,
@@ -48,7 +49,9 @@ from langstream_tpu.models.transformer import (
 from langstream_tpu.parallel import spmd_serving as wire
 from langstream_tpu.serving.faultinject import FaultInjector
 from langstream_tpu.serving.observability import (
+    Dispatch,
     EngineObservability,
+    emit_dispatch_span,
     emit_request_spans,
     load_score,
 )
@@ -258,17 +261,24 @@ class _Slot:
     prefill_chunks: int = 0
     decode_iters: int = 0
     verify_iters: int = 0
+    # `seq` of the dispatch that prefilled this request (its span)
+    group_seq: int = 0
+    # decode steps dispatched for THIS request and not processed yet: the
+    # device's position leads ``position`` by as many (kv_tokens_read)
+    ahead: int = 0
 
     @property
     def active(self) -> bool:
         return self.request is not None
 
-    def reset_obs(self, path: str, chunks: int) -> None:
+    def reset_obs(self, path: str, chunks: int, group_seq: int = 0) -> None:
         self.last_token_at = 0.0
         self.path = path
         self.prefill_chunks = chunks
         self.decode_iters = 0
         self.verify_iters = 0
+        self.group_seq = group_seq
+        self.ahead = 0
 
 
 def _dfa_mask(dfa, g, state):
@@ -303,6 +313,49 @@ def _dfa_advance(dfa, g, tokens, state, vocab_size):
     hit_next = jnp.take_along_axis(exc_next[g], idx[:, None], axis=1)[:, 0]
     nxt = jnp.where(hit_key == key, hit_next, defaults[g, state])
     return jnp.maximum(nxt, 0).astype(state.dtype)
+
+
+def _sample_step(logits, key, temp, top_k, top_p, dfa, g, dstate, vocab_size):
+    """One decode step's sampling under the `sample` scope: split the key,
+    mask by each slot's grammar state when there is one, sample, advance the
+    state past the sampled token. Returns (tokens, key, dstate)."""
+    with jax.named_scope("sample"):
+        key, sub = jax.random.split(key)
+        if dfa is None:
+            return sample(logits, sub, temp, top_k, top_p), key, dstate
+        tokens = sample(
+            logits, sub, temp, top_k, top_p, _dfa_mask(dfa, g, dstate)
+        )
+        return tokens, key, _dfa_advance(dfa, g, tokens, dstate, vocab_size)
+
+
+def _sample_verify(logits, drafts, key, temp, top_k, top_p, dfa, g, vstates):
+    """A verify iteration's accept/reject under the `sample` scope, each
+    draft position masked by its own grammar state when there is one.
+    Returns (emitted tokens [B, k+1], accepted count [B], key)."""
+    with jax.named_scope("sample"):
+        key, sub = jax.random.split(key)
+        allowed = None
+        if dfa is not None:
+            allowed = dfa[0][g[:, None], vstates]  # [B, K+1, W] packed uint32
+        out, accept = speculative_verify(
+            logits, drafts, sub, temp, top_k, top_p, allowed
+        )
+    return out, accept, key
+
+
+def _sample_first(logits, key, temps, top_ks, top_ps, dfa, g, state0, vocab_size):
+    """An admission's first-token sample under the `sample` scope; with a
+    grammar the token is masked by each row's INITIAL state (``state0``: 0
+    fresh, the carried state on a mid-derivation resume, §18). Returns
+    (first tokens, key, each row's advanced state or None)."""
+    with jax.named_scope("sample"):
+        key, sub = jax.random.split(key)
+        if dfa is None:
+            return sample(logits, sub, temps, top_ks, top_ps), key, None
+        s0 = state0 if state0 is not None else jnp.zeros_like(g)
+        first = sample(logits, sub, temps, top_ks, top_ps, _dfa_mask(dfa, g, s0))
+        return first, key, _dfa_advance(dfa, g, first, s0, vocab_size)
 
 
 @functools.partial(
@@ -340,30 +393,28 @@ def _decode_chunk(
 
     def body(carry, _):
         tokens, positions, cache, key, dstate = carry
-        logits, cache = decode_step_inplace(
+        logits, cache, moe = decode_step_inplace(
             params, tokens, positions, cache, config,
-            lora=lora, adapter_rows=arows,
+            lora=lora, adapter_rows=arows, moe_counts=True,
         )
-        key, sub = jax.random.split(key)
-        if dfa is not None:
-            # constrained decoding rides the FUSED chunk: mask this step's
-            # logits with each slot's packed bitmask row, then advance the
-            # state past the sampled token ON DEVICE (default-successor +
-            # exceptions probe) — the host mirror replays the dense table
-            # per delivered token, so a 16-step chunk stays one dispatch
-            # with both sides in lockstep
-            allowed = _dfa_mask(dfa, g, dstate)
-            next_tokens = sample(logits, sub, temp, top_k, top_p, allowed)
-            dstate = _dfa_advance(
-                dfa, g, next_tokens, dstate, config.vocab_size
-            )
-        else:
-            next_tokens = sample(logits, sub, temp, top_k, top_p)
-        return (next_tokens, positions + 1, cache, key, dstate), next_tokens
+        # constrained decoding rides the FUSED chunk: mask this step's
+        # logits with each slot's packed bitmask row, then advance the
+        # state past the sampled token ON DEVICE (default-successor +
+        # exceptions probe) — the host mirror replays the dense table
+        # per delivered token, so a 16-step chunk stays one dispatch
+        # with both sides in lockstep
+        next_tokens, key, dstate = _sample_step(
+            logits, key, temp, top_k, top_p, dfa, g, dstate, config.vocab_size
+        )
+        return (next_tokens, positions + 1, cache, key, dstate), (
+            next_tokens, moe if config.is_moe else None,
+        )
 
-    (tokens, positions, cache, key, dstate), chunk = lax.scan(
+    (tokens, positions, cache, key, dstate), (chunk, moe) = lax.scan(
         body, (tokens, positions, cache, key, dstate), None, length=steps
     )
+    # a dense model's zeros stay out of the step scan: one constant
+    moe = moe.sum(0) if config.is_moe else jnp.zeros(len(MOE_COUNTS), jnp.int32)
     if full is not None:
         cache = jax.tree.map(
             lambda big, small: lax.dynamic_update_slice(
@@ -372,7 +423,7 @@ def _decode_chunk(
             full,
             cache,
         )
-    return chunk, tokens, positions, cache, key, dstate
+    return chunk, tokens, positions, cache, key, dstate, moe
 
 
 @functools.partial(
@@ -404,20 +455,16 @@ def _verify_chunk(
         full = cache
         cache = jax.tree.map(lambda a: a[:, :, :, :kv_bound], cache)
     inputs = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [B, k+1]
-    logits, cache = verify_step_inplace(
+    logits, cache, moe = verify_step_inplace(
         params, inputs, positions, cache, config,
-        lora=lora, adapter_rows=arows,
+        lora=lora, adapter_rows=arows, moe_counts=True,
     )
-    key, sub = jax.random.split(key)
-    allowed = None
-    if dfa is not None:
-        # ``vstates`` [B, K+1]: the host-computed DFA state at every verify
-        # position (state after consuming drafts 0..j-1 — the same mask
-        # plain masked decode would apply, the exactness invariant under
-        # constraints; serving/constrain.py verify_states)
-        allowed = dfa[0][g[:, None], vstates]  # [B, K+1, W] packed uint32
-    out, accept = speculative_verify(
-        logits, drafts, sub, temp, top_k, top_p, allowed
+    # ``vstates`` [B, K+1]: the host-computed DFA state at every verify
+    # position (state after consuming drafts 0..j-1 — the same mask
+    # plain masked decode would apply, the exactness invariant under
+    # constraints; serving/constrain.py verify_states)
+    out, accept, key = _sample_verify(
+        logits, drafts, key, temp, top_k, top_p, dfa, g, vstates
     )
     # the last emitted token (correction or bonus) is the next chunk's input
     tokens = jnp.take_along_axis(out, accept[:, None], axis=1)[:, 0]
@@ -437,7 +484,7 @@ def _verify_chunk(
             cache,
         )
     packed = jnp.concatenate([out, accept[:, None]], axis=1)  # [B, k+2]
-    return packed, tokens, positions, cache, key, dstate
+    return packed, tokens, positions, cache, key, dstate, moe
 
 
 @functools.partial(
@@ -502,14 +549,11 @@ def _prefill_segment_and_sample(
         params, tokens, offsets, seg_lengths, local_cache, config, kv_bound,
         lora=lora, adapter_rows=arows,
     )
-    key, sub = jax.random.split(key)
-    if dfa is not None:
-        s0 = state0 if state0 is not None else jnp.zeros_like(g)
-        first = sample(logits, sub, temp, top_k, top_p, _dfa_mask(dfa, g, s0))
-        s1 = _dfa_advance(dfa, g, first, s0, config.vocab_size)
+    first, key, s1 = _sample_first(
+        logits, key, temp, top_k, top_p, dfa, g, state0, config.vocab_size
+    )
+    if s1 is not None:
         state_dev = state_dev.at[state_slot].set(s1[0], mode="drop")
-    else:
-        first = sample(logits, sub, temp, top_k, top_p)
     return first, local_cache, key, state_dev
 
 
@@ -532,25 +576,23 @@ def _paged_decode_chunk(
 
     def body(carry, _):
         tokens, positions, pool, key, dstate = carry
-        logits, pool = paged_decode_step_inplace(
+        logits, pool, moe = paged_decode_step_inplace(
             params, tokens, positions, pool, table, config, page_size,
-            lora=lora, adapter_rows=arows,
+            lora=lora, adapter_rows=arows, moe_counts=True,
         )
-        key, sub = jax.random.split(key)
-        if dfa is not None:
-            allowed = _dfa_mask(dfa, g, dstate)
-            next_tokens = sample(logits, sub, temp, top_k, top_p, allowed)
-            dstate = _dfa_advance(
-                dfa, g, next_tokens, dstate, config.vocab_size
-            )
-        else:
-            next_tokens = sample(logits, sub, temp, top_k, top_p)
-        return (next_tokens, positions + 1, pool, key, dstate), next_tokens
+        next_tokens, key, dstate = _sample_step(
+            logits, key, temp, top_k, top_p, dfa, g, dstate, config.vocab_size
+        )
+        return (next_tokens, positions + 1, pool, key, dstate), (
+            next_tokens, moe if config.is_moe else None,
+        )
 
-    (tokens, positions, pool, key, dstate), chunk = lax.scan(
+    (tokens, positions, pool, key, dstate), (chunk, moe) = lax.scan(
         body, (tokens, positions, pool, key, dstate), None, length=steps
     )
-    return chunk, tokens, positions, pool, key, dstate
+    # a dense model's zeros stay out of the step scan: one constant
+    moe = moe.sum(0) if config.is_moe else jnp.zeros(len(MOE_COUNTS), jnp.int32)
+    return chunk, tokens, positions, pool, key, dstate, moe
 
 
 @functools.partial(
@@ -568,16 +610,12 @@ def _paged_verify_chunk(
     masked with the host-shipped per-position DFA states (``vstates``) so
     speculative verify stays token-exact under constraints."""
     inputs = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [B, k+1]
-    logits, pool = paged_verify_step_inplace(
+    logits, pool, moe = paged_verify_step_inplace(
         params, inputs, positions, pool, table, config, page_size,
-        lora=lora, adapter_rows=arows,
+        lora=lora, adapter_rows=arows, moe_counts=True,
     )
-    key, sub = jax.random.split(key)
-    allowed = None
-    if dfa is not None:
-        allowed = dfa[0][g[:, None], vstates]  # [B, K+1, W] packed uint32
-    out, accept = speculative_verify(
-        logits, drafts, sub, temp, top_k, top_p, allowed
+    out, accept, key = _sample_verify(
+        logits, drafts, key, temp, top_k, top_p, dfa, g, vstates
     )
     tokens = jnp.take_along_axis(out, accept[:, None], axis=1)[:, 0]
     positions = positions + accept + 1
@@ -586,7 +624,7 @@ def _paged_verify_chunk(
         pre = jnp.take_along_axis(vstates, accept[:, None], axis=1)[:, 0]
         dstate = _dfa_advance(dfa, g, tokens, pre, config.vocab_size)
     packed = jnp.concatenate([out, accept[:, None]], axis=1)  # [B, k+2]
-    return packed, tokens, positions, pool, key, dstate
+    return packed, tokens, positions, pool, key, dstate, moe
 
 
 @functools.partial(
@@ -608,14 +646,11 @@ def _paged_segment_and_sample(
         params, tokens, offsets, seg_lengths, pool, table, config, page_size,
         lora=lora, adapter_rows=arows,
     )
-    key, sub = jax.random.split(key)
-    if dfa is not None:
-        s0 = state0 if state0 is not None else jnp.zeros_like(g)
-        first = sample(logits, sub, temp, top_k, top_p, _dfa_mask(dfa, g, s0))
-        s1 = _dfa_advance(dfa, g, first, s0, config.vocab_size)
+    first, key, s1 = _sample_first(
+        logits, key, temp, top_k, top_p, dfa, g, state0, config.vocab_size
+    )
+    if s1 is not None:
         state_dev = state_dev.at[state_slot].set(s1[0], mode="drop")
-    else:
-        first = sample(logits, sub, temp, top_k, top_p)
     return first, pool, key, state_dev
 
 
@@ -710,26 +745,21 @@ def _make_admit_group(mesh):
             local_cache = constrain_serving_local_cache(
                 local_cache, config.n_kv_heads, mesh
             )
-        logits, local_cache = prefill(
+        logits, local_cache, moe = prefill(
             params, tokens, lengths, local_cache, config,
-            lora=lora, adapter_rows=arows,
+            lora=lora, adapter_rows=arows, moe_counts=True,
+            # a padding row's slot is out of bounds: none of it is real
+            real_lengths=jnp.where(slots < tokens_dev.shape[0], lengths, 0),
         )
-        key, sub = jax.random.split(key)
-        if dfa is not None:
-            # constrained rows: first generated token masked by each row's
-            # INITIAL DFA state (g_state0 — 0 for fresh derivations, the
-            # carried state for a mid-derivation fleet resume, §18), the
-            # advanced state scattered into the decode chain alongside the
-            # token — the NEXT decode chunk (often dispatched before this
-            # fetch even lands) reads a coherent state
-            s0 = g_state0 if g_state0 is not None else jnp.zeros_like(g_rows)
-            first = sample(
-                logits, sub, temps, top_ks, top_ps, _dfa_mask(dfa, g_rows, s0)
-            )
-            s1 = _dfa_advance(dfa, g_rows, first, s0, config.vocab_size)
+        # constrained rows: the advanced state scatters into the decode
+        # chain alongside the token — the NEXT decode chunk (often
+        # dispatched before this fetch even lands) reads a coherent state
+        first, key, s1 = _sample_first(
+            logits, key, temps, top_ks, top_ps, dfa, g_rows, g_state0,
+            config.vocab_size,
+        )
+        if s1 is not None:
             state_dev = state_dev.at[slots].set(s1, mode="drop")
-        else:
-            first = sample(logits, sub, temps, top_ks, top_ps)
 
         def put(big, small):
             w = small.shape[3]
@@ -743,7 +773,7 @@ def _make_admit_group(mesh):
         top_p_dev = top_p_dev.at[slots].set(top_ps, mode="drop")
         return (
             first, cache, tokens_dev, positions_dev, temp_dev, top_k_dev,
-            top_p_dev, key, state_dev,
+            top_p_dev, key, state_dev, moe,
         )
 
     return admit_group
@@ -788,21 +818,18 @@ def _make_paged_admit_group(mesh=None):
             local_cache = constrain_serving_local_cache(
                 local_cache, config.n_kv_heads, mesh
             )
-        logits, local_cache = prefill(
+        logits, local_cache, moe = prefill(
             params, tokens, lengths, local_cache, config,
-            lora=lora, adapter_rows=arows,
+            lora=lora, adapter_rows=arows, moe_counts=True,
+            # a padding row's slot is out of bounds: none of it is real
+            real_lengths=jnp.where(slots < tokens_dev.shape[0], lengths, 0),
         )
-        key, sub = jax.random.split(key)
-        if dfa is not None:
-            # initial state per row (g_state0): 0 fresh, carried on resume
-            s0 = g_state0 if g_state0 is not None else jnp.zeros_like(g_rows)
-            first = sample(
-                logits, sub, temps, top_ks, top_ps, _dfa_mask(dfa, g_rows, s0)
-            )
-            s1 = _dfa_advance(dfa, g_rows, first, s0, config.vocab_size)
+        first, key, s1 = _sample_first(
+            logits, key, temps, top_ks, top_ps, dfa, g_rows, g_state0,
+            config.vocab_size,
+        )
+        if s1 is not None:
             state_dev = state_dev.at[slots].set(s1, mode="drop")
-        else:
-            first = sample(logits, sub, temps, top_ks, top_ps)
         pool = paged_insert_cache(pool, local_cache, tables, page_size)
         tokens_dev = tokens_dev.at[slots].set(first, mode="drop")
         positions_dev = positions_dev.at[slots].set(lengths, mode="drop")
@@ -811,7 +838,7 @@ def _make_paged_admit_group(mesh=None):
         top_p_dev = top_p_dev.at[slots].set(top_ps, mode="drop")
         return (
             first, pool, tokens_dev, positions_dev, temp_dev, top_k_dev,
-            top_p_dev, key, state_dev,
+            top_p_dev, key, state_dev, moe,
         )
 
     return admit_group
@@ -845,8 +872,10 @@ def _make_ring_admit(mesh):
         top_ks = meta[2].astype(jnp.int32)
         top_ps = meta[3]
         logits, kv = ring_prefill(params, tokens, lengths, config, mesh)
-        key, sub = jax.random.split(key)
-        first = sample(logits, sub, temps, top_ks, top_ps)
+        first, key, _ = _sample_first(
+            logits, key, temps, top_ks, top_ps, None, None, None,
+            config.vocab_size,
+        )
         if isinstance(cache["k"], dict):  # int8 big cache
             kq, ks = _quantize_kv(kv["k"])
             vq, vs = _quantize_kv(kv["v"])
@@ -891,13 +920,36 @@ class _Fetch:
     falls back to an inline ``device_get`` when no fetch thread is running
     (tests drive the loop by hand; engine drain after stop)."""
 
-    __slots__ = ("array", "_fetcher", "_event", "_value")
+    __slots__ = ("array", "_fetcher", "_event", "_value", "seq", "ready_at",
+                 "counts")
 
-    def __init__(self, array, fetcher: "_TokenFetcher") -> None:
+    def __init__(
+        self, array, fetcher: "_TokenFetcher", seq: int = 0, counts=None
+    ) -> None:
         self.array = array
+        # the dispatch's MoE counts (a device int32[4]; None for a dense
+        # model): they ride the tokens' transfer, so `get` leaves them
+        # here as host values and no thread makes a fetch of their own
+        self.counts = counts
         self._fetcher = fetcher
         self._event = threading.Event()
         self._value = None
+        # the dispatch's number (0: untracked): `get` waits inside an
+        # `engine.fetch` annotation that carries it, which is how a
+        # profile's device executions are told which dispatch they were
+        self.seq = seq
+        # monotonic instant the bytes were on the host: the end of the
+        # dispatch's span (the fetch thread is FIFO, so these are in
+        # dispatch order)
+        self.ready_at = 0.0
+
+    def get(self):
+        """Block until the array is on the host (fetch thread, or inline
+        when none runs)."""
+        with jax.profiler.TraceAnnotation("engine.fetch", seq=self.seq):
+            value, self.counts = jax.device_get((self.array, self.counts))
+        self.ready_at = time.monotonic()
+        return np.asarray(value)
 
     @property
     def done(self) -> bool:
@@ -909,7 +961,7 @@ class _Fetch:
         which the loop supervisor escalates to a coordinated OP_RECOVER.
         None (single-host default) keeps the unbounded wait."""
         if not self._event.is_set() and not self._fetcher.alive():
-            return np.asarray(jax.device_get(self.array))
+            return self.get()
         deadline = (
             time.monotonic() + timeout_s
             if timeout_s is not None and timeout_s > 0
@@ -919,7 +971,7 @@ class _Fetch:
         while not self._event.wait(poll):
             if not self._fetcher.alive():
                 # fetch thread went away before reaching this handle
-                return np.asarray(jax.device_get(self.array))
+                return self.get()
             if deadline is not None and time.monotonic() > deadline:
                 raise EngineWedgedError(
                     f"device fetch exceeded the {timeout_s:.1f}s dispatch "
@@ -966,8 +1018,8 @@ class _TokenFetcher:
             self._thread.join(timeout=30)
             self._thread = None
 
-    def submit(self, array) -> _Fetch:
-        handle = _Fetch(array, self)
+    def submit(self, array, seq: int = 0, counts=None) -> _Fetch:
+        handle = _Fetch(array, self, seq, counts)
         if self.alive():
             self._queue.put(handle)
         return handle
@@ -981,7 +1033,7 @@ class _TokenFetcher:
                 if self._injector is not None:
                     self._injector.stall("fetch")
                 t0 = time.monotonic()
-                handle._value = np.asarray(jax.device_get(handle.array))
+                handle._value = handle.get()
                 if self._obs is not None and self._obs.on:
                     # the fetch is a latency tail source — its
                     # distribution belongs on /metrics
@@ -1468,10 +1520,31 @@ class ServingEngine:
         # True while a durable restore is serving an admission — the
         # /healthz "restoring" readiness signal during resurrection
         self._durable_restoring = False
-        # tokens covered by landed prefill dispatches: with the dispatch
-        # histogram's wall-time sum this yields the landed prefill
-        # throughput the router's fetch-vs-prefill cost model consumes
-        self._prefill_tokens_dispatched = 0
+        # real prompt tokens of the prefill groups and segment streams
+        # whose first tokens have landed, and the dispatch→ready seconds
+        # they took (one sample each, the same one engine_prefill_group_s
+        # records): the landed prefill throughput the router's
+        # fetch-vs-prefill cost model consumes (prefill_tps_estimate)
+        self._prefill_tokens_landed = 0
+        self._prefill_landed_s = 0.0
+        # -- dispatch spans (docs/SERVING.md §12): every tracked device
+        # dispatch takes the next number; the MoE counts its program
+        # returned wait on the device until its entry is processed
+        self._dispatch_seq = 0
+        self._moe_dev = None
+        self.moe_routed_total = 0
+        self.moe_dropped_total = 0
+        # ready instant of the newest processed dispatch: with a span's
+        # own stamps, device-side time = end - max(start, this)
+        self._last_ready_t = 0.0
+        # groups already on the in-order stream at a dispatch: those in
+        # the pending pipeline at the iteration's top plus this
+        # iteration's own (``_inflight_steps`` counts the decode steps)
+        self._inflight_groups = 0
+        self._iter_groups = 0
+        # host seconds spent waiting for device results, cumulative: an
+        # iteration's share is the difference across its process phase
+        self._wait_s_iter = 0.0
         # -- KV-page migration (disaggregated serving, docs/SERVING.md §18):
         # commands from migration threads (HTTP handlers, the fleet
         # router's dispatch executors) executed at iteration top on the
@@ -2543,6 +2616,11 @@ class ServingEngine:
             "long-prefill-queued": len(self._long_queue),
             "total-requests": self.total_requests,
             "total-generated-tokens": self.total_generated,
+            # expert assignments the device programs made and dropped past
+            # capacity (transformer.MOE_COUNTS; 0 for a dense model),
+            # summed over the dispatches processed so far
+            "moe-routed-assignments-total": self.moe_routed_total,
+            "moe-dropped-assignments-total": self.moe_dropped_total,
             "busy-steps": self._busy_steps,
             "overlap": self.overlap,
             "prefill-token-budget": self.prefill_token_budget,
@@ -3445,6 +3523,8 @@ class ServingEngine:
         self._pending_row_resets.clear()
         self._step_time_ema_s = 0.0
         self._last_chunk_ready_t = 0.0
+        self._last_ready_t = 0.0
+        self._moe_dev = None
         # fresh device state (same shapes → no recompiles on restart)
         if self._paged:
             # pool buffer is donation-suspect like the dense cache; the
@@ -3546,138 +3626,150 @@ class ServingEngine:
         obs_on = self._obs.on
         self._iterations_total += 1
         t0 = time.monotonic() if obs_on else 0.0
-        # SPMD slice resilience (§20): the spmd-crash drill site, the
-        # divergence-resync poll, and the idle heartbeat — all at the
-        # iteration top, OUTSIDE any dispatch's announce sequence
-        if self._spmd is not None:
-            self._spmd_tick()
-        if self._pending_row_resets:
-            self._flush_row_resets()
-        if self._pending_page_zero:
-            self._flush_page_zeros()
-        # tiered KV: fold completed spills in and start hibernation spills
-        # for idle prefixes — bounded per iteration, O(1) when idle; the
-        # restore half runs inside admission (_paged_bind) where it gates
-        self._spill_ms_iter = 0.0
-        self._restore_ms_iter = 0.0
-        if self._spill_on:
-            self._spill_tick()
-        # KV-page migration commands (snapshot/bind/release — §18) cross
-        # into the engine-thread domain here; O(1) when idle (one
-        # SimpleQueue emptiness check), and the idle loop spins at ~1ms so
-        # a migration never waits behind more than one iteration
-        self._drain_migrations()
-        self._sweep_waiting()
-        # brownout ladder (docs/SERVING.md §19): throttled load check on
-        # the engine thread — transitions count, dump and log here
-        if self._brownout is not None:
-            self._brownout_tick()
-        # deterministic noisy-neighbor drill: the `tenant-burst` fault
-        # site injects a synthetic aggressor burst at the iteration top
-        if self._injector is not None:
-            self._tenant_burst_tick()
+        # each phase is also a `jax.profiler.TraceAnnotation` (a no-op
+        # while no profile runs): a profile holds them on the host plane
+        # beside the device's lines, so an idle gap on the device can be put
+        # down to the phase the engine thread was in
+        with jax.profiler.TraceAnnotation("engine.sweep"):
+            # SPMD slice resilience (§20): the spmd-crash drill site, the
+            # divergence-resync poll, and the idle heartbeat — all at the
+            # iteration top, OUTSIDE any dispatch's announce sequence
+            if self._spmd is not None:
+                self._spmd_tick()
+            if self._pending_row_resets:
+                self._flush_row_resets()
+            if self._pending_page_zero:
+                self._flush_page_zeros()
+            # tiered KV: fold completed spills in and start hibernation spills
+            # for idle prefixes — bounded per iteration, O(1) when idle; the
+            # restore half runs inside admission (_paged_bind) where it gates
+            self._spill_ms_iter = 0.0
+            self._restore_ms_iter = 0.0
+            if self._spill_on:
+                self._spill_tick()
+            # KV-page migration commands (snapshot/bind/release — §18) cross
+            # into the engine-thread domain here; O(1) when idle (one
+            # SimpleQueue emptiness check), and the idle loop spins at ~1ms so
+            # a migration never waits behind more than one iteration
+            self._drain_migrations()
+            self._sweep_waiting()
+            # brownout ladder (docs/SERVING.md §19): throttled load check on
+            # the engine thread — transitions count, dump and log here
+            if self._brownout is not None:
+                self._brownout_tick()
+            # deterministic noisy-neighbor drill: the `tenant-burst` fault
+            # site injects a synthetic aggressor burst at the iteration top
+            if self._injector is not None:
+                self._tenant_burst_tick()
         t_sweep = time.monotonic() if obs_on else 0.0
-        # chunks dispatched in previous iterations are still unfetched when
-        # this iteration's dispatch computes its headroom bound — subtract
-        # ALL of them
-        self._inflight_steps = sum(
-            e[3] for batch in pending for e in batch if e[0] == "chunk"
-        )
-        had_active = any(s.active for s in self._slots)
-        # the fused-iteration prefill budget (overlap off: unbounded, the
-        # pre-overlap whole-backlog admission). Long prefill FIRST: it
-        # claims a freed slot before _admit hands them all to short
-        # requests, so a long prompt can't be starved forever under
-        # sustained short traffic.
-        budget = self.prefill_token_budget if self.overlap else None
-        # _mid_iteration marks drain()'s pop-to-slot blind spot: a request
-        # get_nowait()'d here but not yet visible as an active slot exists
-        # only inside this admission phase, so _quiesced() (sampling from
-        # the drain caller's thread) must not report quiet during it —
-        # while staying False on idle iterations, which never pop anything
-        self._mid_iteration = True
-        try:
-            new_pending, spent = self._long_step(budget)
-            n_long_entries = len(new_pending)
-            if budget is not None:
-                budget = max(0, budget - spent)
-            new_pending.extend(self._admit(budget))  # deferred first-token fetches
-        finally:
-            self._mid_iteration = False
-        # prefill dispatched this iteration rides the in-order stream AHEAD
-        # of the chunk below — its chunk must not feed the step-time gauge
-        prefill_ahead = bool(new_pending) or spent > 0
+        with jax.profiler.TraceAnnotation("engine.admit"):
+            # chunks dispatched in previous iterations are still unfetched when
+            # this iteration's dispatch computes its headroom bound — subtract
+            # ALL of them
+            self._inflight_steps = sum(
+                e[3] for batch in pending for e in batch if e[0] == "chunk"
+            )
+            self._inflight_groups = sum(
+                1 for batch in pending for e in batch if e[0] == "prefill"
+            )
+            self._iter_groups = 0
+            had_active = any(s.active for s in self._slots)
+            # the fused-iteration prefill budget (overlap off: unbounded, the
+            # pre-overlap whole-backlog admission). Long prefill FIRST: it
+            # claims a freed slot before _admit hands them all to short
+            # requests, so a long prompt can't be starved forever under
+            # sustained short traffic.
+            budget = self.prefill_token_budget if self.overlap else None
+            # _mid_iteration marks drain()'s pop-to-slot blind spot: a request
+            # get_nowait()'d here but not yet visible as an active slot exists
+            # only inside this admission phase, so _quiesced() (sampling from
+            # the drain caller's thread) must not report quiet during it —
+            # while staying False on idle iterations, which never pop anything
+            self._mid_iteration = True
+            try:
+                new_pending, spent = self._long_step(budget)
+                n_long_entries = len(new_pending)
+                if budget is not None:
+                    budget = max(0, budget - spent)
+                new_pending.extend(self._admit(budget))  # deferred first-token fetches
+            finally:
+                self._mid_iteration = False
+            # prefill dispatched this iteration rides the in-order stream AHEAD
+            # of the chunk below — its chunk must not feed the step-time gauge
+            prefill_ahead = bool(new_pending) or spent > 0
         t_prefill = time.monotonic() if obs_on else 0.0
-        n_admitted = sum(
-            len(e[2]) for e in new_pending if e[0] == "prefill"
-        )
-        # prefill tokens this iteration = long-segment tokens (``spent``) +
-        # the ADMISSION groups' prompts (entries past the _long_step slice
-        # — a long prompt's final-segment entry must not double-count the
-        # segments already in ``spent``)
-        prefill_tokens = spent + sum(
-            len(req.prompt_tokens)
-            for e in new_pending[n_long_entries:]
-            if e[0] == "prefill"
-            for _, req in e[2]
-        )
-        if new_pending and not had_active:
-            # cold start (nothing was decoding): there is no compute
-            # to overlap the deferred fetch with, and the fetch would
-            # otherwise queue BEHIND the first decode chunk dispatched
-            # below (~a full chunk of extra TTFT).
-            # Do NOT widen this to low-but-nonzero occupancy: an
-            # inline fetch under ANY active decode serializes the
-            # loop on the in-flight chunk and collapsed the chat
-            # bench to 740 tok/s / 14.8s p50 TTFT when tried (r4)
-            for entry in new_pending:
-                self._process_entry(entry)
-            new_pending = []
-        if (
-            self._spec_enabled
-            # brownout level 2 (spec-off) falls back to plain decode
-            # chunks — token-exact for greedy streams by the round-9
-            # invariant, so in-flight work is never degraded in
-            # correctness, only in weight-read amortization
-            and not (self._brownout is not None and self._brownout.spec_off)
-            and (new_pending or pending or any(s.active for s in self._slots))
-        ):
-            # self-speculation serializes the host loop on fetched results:
-            # the next iteration's drafts must CONTINUE from the last
-            # accepted token, which only the previous verify's (and this
-            # iteration's prefill entries') fetch knows. Drain everything
-            # before proposing — the conscious pipelining trade the verify
-            # dispatch's k+1-tokens-per-weight-read amortization buys back
-            # (docs/SERVING.md §10 has the tuning story).
-            while pending:
-                for entry in pending.popleft():
+        with jax.profiler.TraceAnnotation("engine.dispatch"):
+            n_admitted = sum(
+                len(e[2]) for e in new_pending if e[0] == "prefill"
+            )
+            # prefill tokens this iteration = long-segment tokens (``spent``) +
+            # the ADMISSION groups' prompts (entries past the _long_step slice
+            # — a long prompt's final-segment entry must not double-count the
+            # segments already in ``spent``)
+            prefill_tokens = spent + sum(
+                len(req.prompt_tokens)
+                for e in new_pending[n_long_entries:]
+                if e[0] == "prefill"
+                for _, req in e[2]
+            )
+            if new_pending and not had_active:
+                # cold start (nothing was decoding): there is no compute
+                # to overlap the deferred fetch with, and the fetch would
+                # otherwise queue BEHIND the first decode chunk dispatched
+                # below (~a full chunk of extra TTFT).
+                # Do NOT widen this to low-but-nonzero occupancy: an
+                # inline fetch under ANY active decode serializes the
+                # loop on the in-flight chunk and collapsed the chat
+                # bench to 740 tok/s / 14.8s p50 TTFT when tried (r4)
+                for entry in new_pending:
                     self._process_entry(entry)
-            for entry in new_pending:
-                self._process_entry(entry)
-            new_pending = []
-            if any(s.active for s in self._slots):
-                new_pending.append(self._dispatch_verify(
-                    clean=not prefill_ahead
+                new_pending = []
+            if (
+                self._spec_enabled
+                # brownout level 2 (spec-off) falls back to plain decode
+                # chunks — token-exact for greedy streams by the round-9
+                # invariant, so in-flight work is never degraded in
+                # correctness, only in weight-read amortization
+                and not (self._brownout is not None and self._brownout.spec_off)
+                and (new_pending or pending or any(s.active for s in self._slots))
+            ):
+                # self-speculation serializes the host loop on fetched results:
+                # the next iteration's drafts must CONTINUE from the last
+                # accepted token, which only the previous verify's (and this
+                # iteration's prefill entries') fetch knows. Drain everything
+                # before proposing — the conscious pipelining trade the verify
+                # dispatch's k+1-tokens-per-weight-read amortization buys back
+                # (docs/SERVING.md §10 has the tuning story).
+                while pending:
+                    for entry in pending.popleft():
+                        self._process_entry(entry)
+                for entry in new_pending:
+                    self._process_entry(entry)
+                new_pending = []
+                if any(s.active for s in self._slots):
+                    new_pending.append(self._dispatch_verify(
+                        clean=not prefill_ahead
+                    ))
+                    disp_kind, disp_steps = "verify", self.spec_tokens + 1
+                else:
+                    disp_kind, disp_steps = "", 0
+            elif any(s.active for s in self._slots):
+                new_pending.append(self._dispatch_chunk(
+                    clean=not prefill_ahead,
+                    # a chunk dispatched while earlier chunks are still in
+                    # flight executes back-to-back with them on the in-order
+                    # stream — its step time is the inter-COMPLETION interval,
+                    # not dispatch→ready wall (which would double-count the
+                    # predecessor still running at dispatch time)
+                    pipelined=self._inflight_steps > 0,
                 ))
-                disp_kind, disp_steps = "verify", self.spec_tokens + 1
+                disp_kind, disp_steps = "decode", new_pending[-1][3]
             else:
                 disp_kind, disp_steps = "", 0
-        elif any(s.active for s in self._slots):
-            new_pending.append(self._dispatch_chunk(
-                clean=not prefill_ahead,
-                # a chunk dispatched while earlier chunks are still in
-                # flight executes back-to-back with them on the in-order
-                # stream — its step time is the inter-COMPLETION interval,
-                # not dispatch→ready wall (which would double-count the
-                # predecessor still running at dispatch time)
-                pipelined=self._inflight_steps > 0,
-            ))
-            disp_kind, disp_steps = "decode", new_pending[-1][3]
-        else:
-            disp_kind, disp_steps = "", 0
-            if not new_pending and not pending and not self._longs:
-                time.sleep(0.001)
+                if not new_pending and not pending and not self._longs:
+                    time.sleep(0.001)
         t_dispatch = time.monotonic() if obs_on else 0.0
+        waited_before = self._wait_s_iter
         pending.append(new_pending)
         # process the oldest batch when its device arrays are READY
         # (no host block, completions/first tokens discovered at
@@ -3694,9 +3786,13 @@ class ServingEngine:
             # flight-recorder frame — idle iterations (nothing active,
             # nothing dispatched) are skipped so the ring holds ~N frames
             # of actual WORK leading up to an incident, not sleep noise.
-            # One dict build + deque append per iteration (not per token).
+            # One dict build per iteration (not per token), handed to both
+            # the ring and — as the `engine.iteration` span's attributes —
+            # the tracer.
             t_end = time.monotonic()
-            self._obs.flight.record({
+            process_ms = (t_end - t_dispatch) * 1e3
+            wait_ms = (self._wait_s_iter - waited_before) * 1e3
+            frame = {
                 "i": self._iterations_total,
                 "t": round(time.time(), 3),
                 "active": sum(1 for s in self._slots if s.active),
@@ -3724,14 +3820,21 @@ class ServingEngine:
                     "sweep": round((t_sweep - t0) * 1e3, 3),
                     "prefill": round((t_prefill - t_sweep) * 1e3, 3),
                     "dispatch": round((t_dispatch - t_prefill) * 1e3, 3),
-                    "process": round((t_end - t_dispatch) * 1e3, 3),
+                    "process": round(process_ms, 3),
+                    # process = waiting for the device's results (the
+                    # fetches of the entries processed after the dispatch)
+                    # + delivering their tokens
+                    "wait": round(wait_ms, 3),
+                    "deliver": round(max(0.0, process_ms - wait_ms), 3),
                     # spill = this iteration's hibernation bookkeeping
                     # (snapshot dispatch + drain); restore = host→device
                     # upload time inside admissions. Both host-wall ms.
                     "spill": round(self._spill_ms_iter, 3),
                     "restore": round(self._restore_ms_iter, 3),
                 },
-            })
+            }
+            self._obs.flight.record(frame)
+            emit_dispatch_span("engine.iteration", t0, t_end, frame)
 
     def _sweep_waiting(self) -> None:
         """Resolve queued-but-unadmitted requests that died while waiting
@@ -3904,45 +4007,119 @@ class ServingEngine:
         coordinated OP_RECOVER — a leader must never hang the whole slice
         on one dispatch (docs/SERVING.md §20). Single-host keeps the
         unbounded wait (a pod-local hang has pod-local blast radius)."""
-        if not isinstance(handle, _Fetch):
-            return np.asarray(jax.device_get(handle))
-        bound = getattr(self._spmd, "watchdog_s", 0) if self._spmd else 0
-        return handle.result(timeout_s=bound if bound > 0 else None)
+        t0 = time.monotonic() if self._obs.on else 0.0
+        with jax.profiler.TraceAnnotation("engine.process.wait"):
+            if not isinstance(handle, _Fetch):
+                value = np.asarray(jax.device_get(handle))
+            else:
+                bound = getattr(self._spmd, "watchdog_s", 0) if self._spmd else 0
+                value = handle.result(timeout_s=bound if bound > 0 else None)
+        if self._obs.on:
+            self._wait_s_iter += time.monotonic() - t0
+        return value
 
     def _process_entry(self, entry: tuple) -> None:
         kind = entry[0]
         if kind == "prefill":
             # ONE fetch for the whole prefill group, not one per request;
             # the fetch thread has usually landed the bytes already
-            _, first_dev, group = entry
+            _, first_dev, group, disp = entry
             first = self._fetch_result(first_dev)
+            self._land_dispatch(disp, first_dev)
             now = time.monotonic()
-            for j, (idx, request) in enumerate(group):
-                slot = self._slots[idx]
-                if slot.request is not request:
-                    continue
-                slot.first_token_at = now
-                slot.last_token_at = now  # inter-token clock starts here
-                if self._obs.on:
-                    self._obs.record(
-                        "engine_ttft_s", now - request.submitted_at
+            with jax.profiler.TraceAnnotation("engine.process.deliver"):
+                for j, (idx, request) in enumerate(group):
+                    slot = self._slots[idx]
+                    if slot.request is not request:
+                        continue
+                    slot.first_token_at = now
+                    slot.last_token_at = now  # inter-token clock starts here
+                    if self._obs.on:
+                        self._obs.record(
+                            "engine_ttft_s", now - request.submitted_at
+                        )
+                    # per-tenant TTFT (the noisy-neighbor drill's victim-p99
+                    # evidence — docs/SERVING.md §19); engine thread only,
+                    # the histogram single-writer contract
+                    self._tenants.note_ttft(
+                        getattr(request.options, "tenant", None)
+                        or DEFAULT_TENANT,
+                        now - request.submitted_at,
                     )
-                # per-tenant TTFT (the noisy-neighbor drill's victim-p99
-                # evidence — docs/SERVING.md §19); engine thread only,
-                # the histogram single-writer contract
-                self._tenants.note_ttft(
-                    getattr(request.options, "tenant", None)
-                    or DEFAULT_TENANT,
-                    now - request.submitted_at,
-                )
-                self._deliver_token(idx, int(first[j]))
+                    self._deliver_token(idx, int(first[j]))
         elif kind == "verify":
             self._process_verify(entry)
         else:
-            _, chunk, snapshot, steps, t_dispatch, clean, pipelined = entry
+            _, chunk, snapshot, steps, t_dispatch, clean, pipelined, disp = entry
             self._process_chunk(
-                chunk, snapshot, steps, t_dispatch, clean, pipelined
+                chunk, snapshot, steps, t_dispatch, clean, pipelined, disp
             )
+
+    def _new_dispatch(self, name: str, **attrs: Any) -> Optional[Dispatch]:
+        """Number one device dispatch and start its span's record (None
+        when nothing will read it: observability off and a dense model).
+        Call just before the launch; the entry's fetch takes
+        ``_moe_counts`` right after it."""
+        self._dispatch_seq += 1
+        if not (self._obs.on or self.config.is_moe):
+            return None
+        attrs["seq"] = self._dispatch_seq
+        attrs["behind_steps"] = self._inflight_steps
+        attrs["behind_groups"] = self._inflight_groups + self._iter_groups
+        return Dispatch(name, time.monotonic(), attrs)
+
+    def _moe_counts(self):
+        """The MoE counts the launch just returned, for the fetch that
+        carries the dispatch's result (None: a dense model's constant
+        zeros are not fetched)."""
+        return self._moe_dev if self.config.is_moe else None
+
+    def _new_segment_dispatch(
+        self, program: str, width: int, real_tokens: int,
+        request: GenerationRequest,
+    ) -> Optional[Dispatch]:
+        """The record of an `engine.prefill_segment` span: a warm suffix,
+        a ring admit, or the FIRST segment of a long prompt's stream
+        (``_segment_step`` adds each later segment to it and the span is
+        emitted once, when the final segment's first token lands — only
+        that segment has a fetch to time). The segment programs return no
+        MoE counts."""
+        disp = self._new_dispatch(
+            "engine.prefill_segment", program=program, rows=1, real_rows=1,
+            width=width, segments=1, real_tokens=real_tokens,
+            computed_tokens=width, trace_ids=[request.trace_id],
+        )
+        self._iter_groups += 1
+        return disp
+
+    def _land_dispatch(self, disp: Optional[Dispatch], handle) -> None:
+        """The dispatch's result is on the host: fold its MoE counts into
+        the totals and emit its span (once, here — the request spans'
+        rule). ``handle`` is the entry's fetch; the fetch thread stamped
+        when its bytes landed and brought the counts with them."""
+        if disp is None:
+            return
+        end = getattr(handle, "ready_at", 0.0) or time.monotonic()
+        prev, self._last_ready_t = self._last_ready_t, end
+        attrs = disp.attrs
+        counts = getattr(handle, "counts", None)
+        if counts is not None:
+            counts = [int(v) for v in counts]
+            attrs.update((f"moe_{n}", c) for n, c in zip(MOE_COUNTS, counts))
+            with self._stats_lock:
+                self.moe_routed_total += counts[0]
+                self.moe_dropped_total += counts[1]
+        if not self._obs.on:
+            return
+        # the in-order stream ran this dispatch after the one before it:
+        # device-side time is end - max(start, the previous result's ready)
+        attrs["prev_ready_ms"] = round((prev - disp.start) * 1e3, 3) if prev else None
+        attrs["device_ms"] = round((end - max(disp.start, prev)) * 1e3, 3)
+        if "real_tokens" in attrs:  # a prefill group or segment stream
+            self._obs.record("engine_prefill_group_s", end - disp.start)
+            self._prefill_tokens_landed += attrs["real_tokens"]
+            self._prefill_landed_s += end - disp.start
+        emit_dispatch_span(disp.name, disp.start, end, attrs)
 
     def _sample_step_time(
         self, snapshot, steps: int, t_dispatch: float, clean: bool,
@@ -4528,17 +4705,21 @@ class ServingEngine:
         arows, g_rows, g_state0 = self._agentic_row_args(
             [r for _, r in group]
         )
-        first = self._dev_prefill(
-            width, tokens, lengths, temps, top_ks, top_ps, slots,
-            arows=arows, g_rows=g_rows, g_state0=g_state0,
+        disp = self._new_dispatch(
+            "engine.admit_group", program="admit_group", rows=n_pad,
+            real_rows=len(group), width=width,
+            real_tokens=sum(len(r.prompt_tokens) for _, r in group),
+            computed_tokens=n_pad * width,
+            trace_ids=[r.trace_id for _, r in group],
         )
-        if self._obs.on:
-            self._obs.record(
-                "engine_prefill_dispatch_s", time.monotonic() - started
+        seq = self._dispatch_seq
+        with jax.profiler.TraceAnnotation("engine.admit_group", seq=seq):
+            first = self._dev_prefill(
+                width, tokens, lengths, temps, top_ks, top_ps, slots,
+                arows=arows, g_rows=g_rows, g_state0=g_state0,
             )
-        self._prefill_tokens_dispatched += sum(
-            len(r.prompt_tokens) for _, r in group
-        )
+        counts = self._moe_counts()
+        self._iter_groups += 1
 
         for idx, request in group:
             slot = self._slots[idx]
@@ -4547,14 +4728,16 @@ class ServingEngine:
             slot.generated = []
             slot.started_at = started
             slot.first_token_at = 0.0  # stamped when the deferred fetch lands
-            slot.reset_obs("cold", 1)
+            slot.reset_obs("cold", 1, seq)
             self._slot_bind_agentic(idx, request)
             with self._stats_lock:
                 self.total_requests += 1
             self._note_tenant_admitted(request)
             self._spec_admit(idx, request.prompt_tokens)
             self._maybe_publish(idx, request.prompt_tokens)
-        return [("prefill", self._fetcher.submit(first), list(group))]
+        return [(
+            "prefill", self._fetcher.submit(first, seq, counts), list(group), disp,
+        )]
 
     def _agentic_admit_kwargs(
         self, n: int, arows, g_rows, g_state0=None,
@@ -4613,6 +4796,7 @@ class ServingEngine:
             self._top_p_dev,
             self._key,
             state_dev,
+            self._moe_dev,
         ) = self._admit_group(
             self.params,
             self._cache,
@@ -4660,6 +4844,7 @@ class ServingEngine:
             self._top_p_dev,
             self._key,
             state_dev,
+            self._moe_dev,
         ) = self._paged_admit_group(
             self.params,
             pool.dev,
@@ -4730,6 +4915,9 @@ class ServingEngine:
         tokens[0, : len(suffix)] = suffix
         opts = request.options
         started = time.monotonic()
+        disp = self._new_segment_dispatch(
+            "_prefill_segment_and_sample", ws, len(suffix), request
+        )
         pool.acquire(entry)
         if self._spmd is not None:
             # warm admission on the wire: the follower replays the same
@@ -4761,18 +4949,13 @@ class ServingEngine:
         finally:
             pool.release(entry)
         pool.tokens_saved += p
-        if self._obs.on:
-            self._obs.record(
-                "engine_prefill_dispatch_s", time.monotonic() - started
-            )
-        self._prefill_tokens_dispatched += len(suffix)
         slot = self._slots[idx]
         slot.request = request
         slot.position = len(prompt)
         slot.generated = []
         slot.started_at = started
         slot.first_token_at = 0.0
-        slot.reset_obs("warm", 1)
+        slot.reset_obs("warm", 1, self._dispatch_seq)
         self._slot_bind_agentic(idx, request)
         with self._stats_lock:
             self.total_requests += 1
@@ -4781,7 +4964,10 @@ class ServingEngine:
         # the prompt may extend past the reused prefix's bucket boundary:
         # publish the deeper prefix so the next lookup reuses more
         self._maybe_publish(idx, prompt)
-        return [("prefill", self._fetcher.submit(first), [(idx, request)])]
+        return [(
+            "prefill", self._fetcher.submit(first, self._dispatch_seq),
+            [(idx, request)], disp,
+        )]
 
     def _segment_agentic_kwargs(self, agentic_rows, state_slot) -> dict:
         """Agentic kwargs for the batch-1 segment programs (warm suffix /
@@ -5128,6 +5314,9 @@ class ServingEngine:
         tokens[0, : len(suffix)] = suffix
         opts = request.options
         started = time.monotonic()
+        disp = self._new_segment_dispatch(
+            "_paged_segment_and_sample", ws, len(suffix), request
+        )
         if self._spmd is not None:
             # one warm paged admission = one suffix segment against pages
             # the preceding OP_PAGE_BIND already aliased on every host
@@ -5156,25 +5345,23 @@ class ServingEngine:
                 ttft_s=0, total_s=0, error=e,
             ))
             return
-        if self._obs.on:
-            self._obs.record(
-                "engine_prefill_dispatch_s", time.monotonic() - started
-            )
-        self._prefill_tokens_dispatched += len(suffix)
         slot = self._slots[idx]
         slot.request = request
         slot.position = len(prompt)
         slot.generated = []
         slot.started_at = started
         slot.first_token_at = 0.0
-        slot.reset_obs("warm", 1)
+        slot.reset_obs("warm", 1, self._dispatch_seq)
         self._slot_bind_agentic(idx, request)
         with self._stats_lock:
             self.total_requests += 1
         self._note_tenant_admitted(request)
         self._spec_admit(idx, prompt)
         self._maybe_publish(idx, prompt)
-        entries.append(("prefill", self._fetcher.submit(first), [(idx, request)]))
+        entries.append(
+            ("prefill", self._fetcher.submit(first, self._dispatch_seq),
+             [(idx, request)], disp)
+        )
 
     def _dev_paged_segment(
         self, tokens, s0, seg_len, idx, temperature, top_k, top_p,
@@ -5944,22 +6131,18 @@ class ServingEngine:
         return self._durable_restoring
 
     def prefill_tps_estimate(self) -> float:
-        """Landed prefill throughput (tokens/s) off the prefill-dispatch
-        histogram: tokens covered by landed dispatches over their summed
-        wall time. The fleet beacon ships this for the router's
-        fetch-vs-prefill cost model (docs/SERVING.md §21/§23); 0.0 until
-        a dispatch lands (the router then falls back to its flat
-        threshold)."""
-        if not self._obs.on:
+        """Landed prefill throughput (tokens/s): the real prompt tokens of
+        the prefill groups and segment streams whose first tokens have
+        landed, over their summed dispatch→ready time — one sample per
+        dispatch span, the same one `engine_prefill_group_s` records, so
+        waiting behind the decode chunk in flight counts (a fetch that
+        replaces a prefill waits there too). The fleet beacon ships this
+        for the router's fetch-vs-prefill cost model (docs/SERVING.md
+        §21/§23); 0.0 until a group lands, or with observability off (the
+        router then falls back to its flat threshold)."""
+        if self._prefill_landed_s <= 0.0:
             return 0.0
-        h = self._obs.hist.get("engine_prefill_dispatch_s")
-        if h is None:
-            return 0.0
-        snap = h.snapshot()
-        total_s = float(snap.get("sum", 0.0))
-        if total_s <= 0.0:
-            return 0.0
-        return round(self._prefill_tokens_dispatched / total_s, 1)
+        return round(self._prefill_tokens_landed / self._prefill_landed_s, 1)
 
     # -- KV-page migration (disaggregated serving, docs/SERVING.md §18) ------
 
@@ -6641,7 +6824,17 @@ class ServingEngine:
                 top_ks=np.asarray([opts.top_k], np.int32),
                 top_ps=np.asarray([opts.top_p], np.float32),
             ))
-        t_disp = time.monotonic()
+        disp = st.get("disp")
+        if start:
+            disp = st["disp"] = self._new_segment_dispatch(
+                "_paged_segment_and_sample" if self._paged
+                else "_prefill_segment_and_sample",
+                width, len(seg), request,
+            )
+        elif disp is not None:
+            disp.attrs["segments"] += 1
+            disp.attrs["real_tokens"] += len(seg)
+            disp.attrs["computed_tokens"] += width
         try:
             if self._paged:
                 # straight into the slot's pages: no local cache, no final
@@ -6683,11 +6876,6 @@ class ServingEngine:
         if prefix_entry is not None:
             self._prefix_pool.tokens_saved += st.get("base", 0)
         st["seg"] += 1
-        if self._obs.on:
-            self._obs.record(
-                "engine_prefill_dispatch_s", time.monotonic() - t_disp
-            )
-        self._prefill_tokens_dispatched += len(seg)
         if not final:
             return []  # more segments to go
 
@@ -6700,14 +6888,22 @@ class ServingEngine:
         slot.generated = []
         slot.started_at = time.monotonic()
         slot.first_token_at = 0.0
-        slot.reset_obs("long", st["seg"])
+        slot.reset_obs(
+            "long", st["seg"], disp.attrs["seq"] if disp is not None else 0
+        )
         self._slot_bind_agentic(idx, request)
         with self._stats_lock:
             self.total_requests += 1
         self._note_tenant_admitted(request)
         self._spec_admit(idx, prompt)
         self._maybe_publish(idx, prompt)
-        return [("prefill", self._fetcher.submit(first), [(idx, request)])]
+        return [(
+            "prefill",
+            self._fetcher.submit(
+                first, disp.attrs["seq"] if disp is not None else 0
+            ),
+            [(idx, request)], disp,
+        )]
 
     def _ring_pad(self, prompt_len: int) -> Optional[int]:
         """Padded width for the ring path: |seq| pow2-sized blocks (O(log)
@@ -6735,6 +6931,9 @@ class ServingEngine:
         opts = request.options
         if self._spmd is not None:
             self._announce_ring(tokens, len(prompt), opts, idx)
+        disp = self._new_segment_dispatch(
+            "ring_admit", s_pad, len(prompt), request
+        )
         try:
             first = self._dev_ring(
                 tokens, len(prompt),
@@ -6755,13 +6954,16 @@ class ServingEngine:
         slot.generated = []
         slot.started_at = time.monotonic()
         slot.first_token_at = 0.0
-        slot.reset_obs("ring", 1)
+        slot.reset_obs("ring", 1, self._dispatch_seq)
         with self._stats_lock:
             self.total_requests += 1
         self._note_tenant_admitted(request)
         self._spec_admit(idx, prompt)
         self._maybe_publish(idx, prompt)
-        return [("prefill", self._fetcher.submit(first), [(idx, request)])]
+        return [(
+            "prefill", self._fetcher.submit(first, self._dispatch_seq),
+            [(idx, request)], disp,
+        )]
 
     def _announce_ring(self, tokens: np.ndarray, prompt_len: int, opts, idx: int) -> None:
         """Stream the PROMPT (not its pow2 padding — the follower derives
@@ -6944,10 +7146,24 @@ class ServingEngine:
                 # sentinel the same page-table rows
                 mask=mask,
             ))
-        chunk = self._dev_decode(steps, stale, kv_bound, mask=mask)
+        live = [slot for slot in self._slots if slot.active]
+        disp = self._new_dispatch(
+            "engine.decode_chunk",
+            program="_paged_decode_chunk" if self._paged else "_decode_chunk",
+            steps=steps, active_rows=len(live),
+            kv_tokens_read=self._kv_tokens_read(live, steps),
+            clean=clean, pipelined=pipelined,
+        )
+        with jax.profiler.TraceAnnotation(
+            "engine.decode_chunk", seq=self._dispatch_seq, steps=steps
+        ):
+            chunk = self._dev_decode(steps, stale, kv_bound, mask=mask)
+        counts = self._moe_counts()
         snapshot = [
             (i, slot.request) for i, slot in enumerate(self._slots) if slot.active
         ]
+        for slot in live:
+            slot.ahead += steps
         with self._stats_lock:
             self._busy_steps += steps
         self._last_kv_bound = kv_bound or self.max_seq_len
@@ -6955,8 +7171,19 @@ class ServingEngine:
         # while this thread keeps dispatching — the fetch is hidden at
         # every chunk size, not only when chunk compute covers it
         return (
-            "chunk", self._fetcher.submit(chunk), snapshot, steps,
-            time.monotonic(), clean, pipelined,
+            "chunk", self._fetcher.submit(chunk, self._dispatch_seq, counts),
+            snapshot, steps, time.monotonic(), clean, pipelined, disp,
+        )
+
+    @staticmethod
+    def _kv_tokens_read(live: list, steps: int) -> int:
+        """KV tokens the attention of one dispatch has to read: over its
+        ``steps`` and its active rows, the row's live length at that step
+        (the position being written, plus one). The device's position
+        leads the host's by the row's steps still in flight (``ahead``)."""
+        return sum(
+            steps * (slot.position + slot.ahead + 1) + steps * (steps - 1) // 2
+            for slot in live
         )
 
     def _collect_stale(self) -> list[int]:
@@ -7025,6 +7252,7 @@ class ServingEngine:
                 pool.dev,
                 self._key,
                 dstate,
+                self._moe_dev,
             ) = _paged_decode_chunk(
                 self.params,
                 self._tokens_dev,
@@ -7052,7 +7280,7 @@ class ServingEngine:
             self._reset_stale_temps(stale)
         (
             chunk, self._tokens_dev, self._positions_dev, self._cache,
-            self._key, dstate,
+            self._key, dstate, self._moe_dev,
         ) = _decode_chunk(
             self.params,
             self._tokens_dev,
@@ -7139,9 +7367,19 @@ class ServingEngine:
                 slots=np.asarray(stale, np.int32), kv_bound=kv_bound,
                 drafts=drafts, mask=mask,
             ))
+        live = [slot for slot in self._slots if slot.active]
+        disp = self._new_dispatch(
+            "engine.verify",
+            program="_paged_verify_chunk" if self._paged else "_verify_chunk",
+            steps=k + 1, active_rows=len(live),
+            # a verify scores k+1 positions a row, each over its own prefix
+            kv_tokens_read=self._kv_tokens_read(live, k + 1),
+            clean=clean, pipelined=False,
+        )
         packed = self._dev_verify(
             drafts, stale, kv_bound, mask=mask, vstates=vstates
         )
+        counts = self._moe_counts()
         snapshot = [
             (i, slot.request) for i, slot in enumerate(self._slots) if slot.active
         ]
@@ -7150,8 +7388,8 @@ class ServingEngine:
             self.spec_dispatches_total += 1
         self._last_kv_bound = kv_bound
         return (
-            "verify", self._fetcher.submit(packed), snapshot, proposed,
-            time.monotonic(), clean,
+            "verify", self._fetcher.submit(packed, self._dispatch_seq, counts),
+            snapshot, proposed, time.monotonic(), clean, disp,
         )
 
     def _dev_verify(
@@ -7187,6 +7425,7 @@ class ServingEngine:
                 pool.dev,
                 self._key,
                 dstate,
+                self._moe_dev,
             ) = _paged_verify_chunk(
                 self.params,
                 self._tokens_dev,
@@ -7219,6 +7458,7 @@ class ServingEngine:
             self._cache,
             self._key,
             dstate,
+            self._moe_dev,
         ) = _verify_chunk(
             self.params,
             self._tokens_dev,
@@ -7247,8 +7487,9 @@ class ServingEngine:
         accepted+1 tokens through the same _deliver_token path as decode
         chunks (stop/length/cancel/deadline/NaN-sentinel all behave
         identically mid-verify)."""
-        _, packed, snapshot, proposed, t_dispatch, clean = entry
+        _, packed, snapshot, proposed, t_dispatch, clean, disp = entry
         host = self._fetch_result(packed)
+        self._land_dispatch(disp, packed)
         # divergence echo BEFORE the injector's host-side corruption: the
         # echo is the DEVICE truth both sides must agree on — a leader-host
         # corruption drill must not read as an SPMD divergence
@@ -7271,69 +7512,74 @@ class ServingEngine:
                 self._obs.record("engine_decode_step_s", step_s)
         self._last_chunk_ready_t = now
         out, accept = host[:, :-1], host[:, -1]
-        for idx, request in snapshot:
-            slot = self._slots[idx]
-            if slot.request is not request:  # freed/reassigned meanwhile
-                continue
-            slot.verify_iters += 1
-            t_prev = slot.last_token_at
-            n_acc = int(accept[idx])
-            with self._stats_lock:
-                if proposed[idx] > 0:
-                    # capped at the real proposal length: padding zeros that
-                    # happen to match the model are luck, not draft quality,
-                    # and would push the acceptance gauge past 1.0
-                    self.spec_accepted_tokens_total += min(
-                        n_acc, int(proposed[idx])
-                    )
-                self.spec_slot_steps_total += 1
-            delivered = 0
-            for j in range(n_acc + 1):
-                slot.position += 1
-                token = int(out[idx, j])
-                if token >= 0:
-                    # counted per token actually DELIVERED — a request that
-                    # finishes mid-verify (length/stop/deadline) drops the
-                    # rest, and the NaN sentinel is a quarantine, not a
-                    # token; counting n_acc+1 up front overstated the
-                    # amortization gauge exactly on short-generation,
-                    # high-acceptance traffic
-                    with self._stats_lock:
-                        self.spec_emitted_tokens_total += 1
-                    delivered += 1
-                self._deliver_token(idx, token)
-                if slot.request is not request:  # finished mid-verify
-                    break
-            if self._obs.on and delivered:
-                self._obs.record("engine_accepted_tokens_per_step", delivered)
-            self._record_intertoken(slot, request, t_prev, delivered)
+        with jax.profiler.TraceAnnotation("engine.process.deliver"):
+            for idx, request in snapshot:
+                slot = self._slots[idx]
+                if slot.request is not request:  # freed/reassigned meanwhile
+                    continue
+                slot.verify_iters += 1
+                t_prev = slot.last_token_at
+                n_acc = int(accept[idx])
+                with self._stats_lock:
+                    if proposed[idx] > 0:
+                        # capped at the real proposal length: padding zeros that
+                        # happen to match the model are luck, not draft quality,
+                        # and would push the acceptance gauge past 1.0
+                        self.spec_accepted_tokens_total += min(
+                            n_acc, int(proposed[idx])
+                        )
+                    self.spec_slot_steps_total += 1
+                delivered = 0
+                for j in range(n_acc + 1):
+                    slot.position += 1
+                    token = int(out[idx, j])
+                    if token >= 0:
+                        # counted per token actually DELIVERED — a request that
+                        # finishes mid-verify (length/stop/deadline) drops the
+                        # rest, and the NaN sentinel is a quarantine, not a
+                        # token; counting n_acc+1 up front overstated the
+                        # amortization gauge exactly on short-generation,
+                        # high-acceptance traffic
+                        with self._stats_lock:
+                            self.spec_emitted_tokens_total += 1
+                        delivered += 1
+                    self._deliver_token(idx, token)
+                    if slot.request is not request:  # finished mid-verify
+                        break
+                if self._obs.on and delivered:
+                    self._obs.record("engine_accepted_tokens_per_step", delivered)
+                self._record_intertoken(slot, request, t_prev, delivered)
 
     def _process_chunk(
         self, chunk, snapshot, steps: int, t_dispatch: float = 0.0,
         clean: bool = False, pipelined: bool = False,
+        disp: Optional[Dispatch] = None,
     ) -> None:
         # [steps, B], fetched by the fetch thread (wait watchdog-bounded
         # under SPMD — see _fetch_result)
         host = self._fetch_result(chunk)
+        self._land_dispatch(disp, chunk)
         # gauge BEFORE delivery: see _sample_step_time's rationale
         self._sample_step_time(snapshot, steps, t_dispatch, clean, pipelined)
         self._spmd_echo(wire.ECHO_DECODE, host)  # before host-side corruption
         if self._injector is not None:
             host, _ = self._injector.corrupt_tokens(host, snapshot)
-        for idx, request in snapshot:
-            slot = self._slots[idx]
-            if slot.request is not request:  # freed/reassigned meanwhile
-                continue
-            slot.decode_iters += 1
-            t_prev = slot.last_token_at
-            delivered = 0
-            for s in range(steps):
-                slot.position += 1
-                self._deliver_token(idx, int(host[s, idx]))
-                delivered += 1
-                if slot.request is not request:  # finished mid-chunk
-                    break
-            self._record_intertoken(slot, request, t_prev, delivered)
+        with jax.profiler.TraceAnnotation("engine.process.deliver"):
+            for idx, request in snapshot:
+                slot = self._slots[idx]
+                if slot.request is not request:  # freed/reassigned meanwhile
+                    continue
+                slot.decode_iters += 1
+                slot.ahead -= steps
+                t_prev = slot.last_token_at
+                delivered = 0
+                for s in range(steps):
+                    slot.position += 1
+                    self._deliver_token(idx, int(host[s, idx]))
+                    delivered += 1
+                    if slot.request is not request:  # finished mid-chunk
+                        break
+                self._record_intertoken(slot, request, t_prev, delivered)
 
     def _record_intertoken(
         self, slot: _Slot, request: GenerationRequest, t_prev: float,
@@ -7523,6 +7769,7 @@ class ServingEngine:
             "generated_tokens": len(slot.generated),
             "finish_reason": reason,
             "prefill_chunks": slot.prefill_chunks,
+            "group_seq": slot.group_seq,
             "decode_iterations": slot.decode_iters,
             "verify_dispatches": slot.verify_iters,
             "kv_pages": pages_held,

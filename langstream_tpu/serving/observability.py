@@ -23,6 +23,14 @@ per-request tail telemetry as the *control signal* for scheduling):
   children, assembled from phase timestamps at completion (one emission
   point — nothing on the token hot loop) and joined to the gateway trace
   via the propagated ``ls-trace-id``.
+- **Dispatch and iteration spans** (`Dispatch`, `emit_dispatch_span`): one
+  `engine.admit_group` / `engine.prefill_segment` / `engine.decode_chunk` /
+  `engine.verify` span per device dispatch, built like the request spans
+  from stamps its pending entry already carries and emitted once when its
+  result lands, with the work it did (rows, real and computed tokens, KV
+  tokens read, expert assignments routed and dropped); one
+  `engine.iteration` span per working iteration, the flight recorder's
+  frame itself. A few a second, never per token.
 - **Flight recorder** (`FlightRecorder`): a lock-cheap ring of the last N
   engine iterations (phase timings, batch composition, pages in use,
   compiled-program count, injector firings). Snapshotted and dumped as
@@ -67,8 +75,9 @@ ENGINE_HISTOGRAMS: dict[str, dict[str, Any]] = {
         "help": "admission queue wait, submit to queue exit (s)",
         "buckets": log_buckets(1e-4, 120.0, 4),
     },
-    "engine_prefill_dispatch_s": {
-        "help": "host wall time of one prefill/segment dispatch (s)",
+    "engine_prefill_group_s": {
+        "help": "one prefill group or segment stream, dispatch to its "
+                "first tokens ready on the host (s)",
         "buckets": log_buckets(1e-4, 60.0, 4),
     },
     "engine_decode_step_s": {
@@ -244,6 +253,9 @@ def emit_request_spans(
             slot=attributes.get("slot", -1),
             path=attributes.get("path", ""),
             prefill_chunks=attributes.get("prefill_chunks", 0),
+            # `seq` of the engine.admit_group / engine.prefill_segment span
+            # whose dispatch prefilled this request (0: none was emitted)
+            group_seq=attributes.get("group_seq", 0),
         )
     if first_token is not None:
         child(
@@ -260,6 +272,45 @@ def emit_request_spans(
         TRACER.emit(span)
     TRACER.emit(root)
     return trace_id
+
+
+# ---------------------------------------------------------------------------
+# Dispatch and iteration spans
+# ---------------------------------------------------------------------------
+
+
+class Dispatch:
+    """One device dispatch between its launch and its result: the span's
+    name, its start (monotonic, at the launch) and the attributes known at
+    launch. Rides the dispatch's pending entry; the engine completes the
+    attributes (timing, the MoE counts its fetch brought) and emits the
+    span when the entry's fetch has landed."""
+
+    __slots__ = ("name", "start", "attrs")
+
+    def __init__(self, name: str, start: float, attrs: dict[str, Any]) -> None:
+        self.name = name
+        self.start = start
+        self.attrs = attrs
+
+
+def emit_dispatch_span(
+    name: str, start: float, end: float, attributes: dict[str, Any]
+) -> None:
+    """A root span of its own trace from two monotonic stamps: a dispatch
+    (start = launch, end = result ready on the host) or an engine
+    iteration. ``attributes`` is kept, not copied."""
+    if not TRACER.enabled:
+        return
+    TRACER.emit(Span(
+        name=name,
+        trace_id=_span_id(),
+        span_id=_span_id(),
+        parent_id=None,
+        start_s=start + (time.time() - time.monotonic()),
+        duration_s=max(0.0, end - start),
+        attributes=attributes,
+    ))
 
 
 # ---------------------------------------------------------------------------
@@ -280,8 +331,10 @@ ITERATION_FIELDS = (
     "kv_pages", # physical pages in use (0 under the dense layout)
     "host_pages", # host-tier arena slots in use (0 with the tier off)
     "programs", # distinct compiled device programs so far
-    "phase_ms", # {"sweep","prefill","dispatch","process","spill","restore"}
-                # host-wall ms (spill/restore are 0 with the tier off)
+    "phase_ms", # {"sweep","prefill","dispatch","process","wait","deliver",
+                # "spill","restore"} host-wall ms: process = wait (for the
+                # device's results) + deliver; spill/restore are 0 with the
+                # tier off
 )
 
 # token content must never reach a dump: dumps travel to incident channels

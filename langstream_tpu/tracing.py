@@ -1,4 +1,4 @@
-"""Lightweight distributed tracing + JAX profiler hooks.
+"""Lightweight distributed tracing.
 
 The reference has NO tracing (SURVEY §5: "no OpenTelemetry; observability =
 prometheus + logs"); this is one of the rebuild's additions. Spans are
@@ -7,8 +7,9 @@ the runtime HTTP server (``/traces``) in a jaeger-ish JSON shape, and
 propagated ACROSS agents through a record header (``ls-trace-id``) so a
 record's path through a pipeline stitches into one trace.
 
-``device_trace`` wraps ``jax.profiler`` (xprof) for TPU-side profiling —
-point TensorBoard at the output dir.
+The device's side is `jax.profiler`'s own: the serving engine wraps its
+phases and dispatches in `jax.profiler.TraceAnnotation`s, so a profile of
+a replica holds them beside the device's lines (docs/SERVING.md §12).
 """
 
 from __future__ import annotations
@@ -139,15 +140,3 @@ def record_trace_id(record: Any) -> Optional[str]:
         if h.key == TRACE_HEADER:
             return h.value_as_string()
     return None
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str) -> Iterator[None]:
-    """TPU-side profiling via jax.profiler (xprof); view with TensorBoard."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

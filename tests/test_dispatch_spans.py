@@ -1,0 +1,411 @@
+"""What the device programs and the dispatch loop say they did (ISSUE 24,
+docs/SERVING.md §12): one span per dispatch with its work counts, the
+iteration span, the scope vocabulary in the lowered programs, the MoE
+routed/dropped counts against a reference, and that none of it changes a
+logit."""
+
+import dataclasses
+import math
+import re
+import time
+from collections import deque
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from langstream_tpu.models import transformer as T
+from langstream_tpu.models.configs import MODEL_PRESETS, GenerationOptions
+from langstream_tpu.serving import engine as E
+from langstream_tpu.serving.engine import GenerationRequest, ServingEngine
+from langstream_tpu.tracing import TRACER
+
+DENSE = dataclasses.replace(MODEL_PRESETS["tiny-test"], dtype="float32")
+MOE = dataclasses.replace(MODEL_PRESETS["tiny-moe-test"], dtype="float32")
+DISPATCH_SPANS = (
+    "engine.admit_group", "engine.prefill_segment", "engine.decode_chunk",
+    "engine.verify", "engine.iteration",
+)
+
+
+@pytest.fixture(scope="module")
+def dense_params():
+    return T.init_params(DENSE, jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def moe_params():
+    return T.init_params(MOE, jax.random.PRNGKey(1))
+
+
+def spans_named(name):
+    return [s for s in TRACER.spans(4096) if s["name"] == name]
+
+
+def drain(engine, pending):
+    engine._stop.set()
+    while pending:
+        for entry in pending.popleft():
+            engine._process_entry(entry)
+    engine._fail_all(RuntimeError("test torn down"))
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_one_span_per_group_and_chunk_with_work_counts(dense_params, paged):
+    TRACER.clear()
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=4, max_seq_len=128, decode_chunk=4,
+        prefill_buckets=(16,), prefill_batch=2,
+        kv_layout="paged" if paged else "dense",
+    )
+    engine.start()
+    try:
+        reqs = [
+            engine.submit(GenerationRequest(
+                prompt_tokens=[5 + i] * (3 + i),
+                options=GenerationOptions(max_new_tokens=10),
+                trace_id=f"dispatchspan{i:04d}",
+            ))
+            for i in range(3)
+        ]
+        for r in reqs:
+            r.result(timeout=300)
+    finally:
+        engine.stop()
+    groups = spans_named("engine.admit_group")
+    chunks = spans_named("engine.decode_chunk")
+    assert groups and chunks
+    seqs = [s["attributes"]["seq"] for s in groups + chunks]
+    assert len(set(seqs)) == len(seqs), "a dispatch was emitted twice"
+    assert sum(g["attributes"]["real_rows"] for g in groups) == 3
+    for g in groups:
+        a = g["attributes"]
+        assert a["program"] == "admit_group" and a["rows"] == 2 and a["width"] == 16
+        assert 0 < a["real_tokens"] <= a["computed_tokens"] == 32
+        assert len(a["trace_ids"]) == a["real_rows"]
+        assert a["device_ms"] <= g["durationMs"] + 1e-3
+    assert sum(g["attributes"]["real_tokens"] for g in groups) == 3 + 4 + 5
+    for c in chunks:
+        a = c["attributes"]
+        assert a["program"] == ("_paged_decode_chunk" if paged else "_decode_chunk")
+        assert a["steps"] >= 1 and 1 <= a["active_rows"] <= 3
+        assert a["kv_tokens_read"] >= a["steps"] * a["active_rows"]
+        assert "moe_routed" not in a  # a dense model fetches no counts
+    # device time per request class is a join: prefill child -> its group
+    by_seq = {g["attributes"]["seq"]: g for g in groups}
+    for i in range(3):
+        prefill = [
+            s for s in spans_named("engine.prefill")
+            if s["traceId"] == f"dispatchspan{i:04d}"
+        ]
+        assert len(prefill) == 1
+        group = by_seq[prefill[0]["attributes"]["group_seq"]]
+        assert f"dispatchspan{i:04d}" in group["attributes"]["trace_ids"]
+    iterations = spans_named("engine.iteration")
+    assert iterations
+    phases = iterations[-1]["attributes"]["phase_ms"]
+    assert {"sweep", "prefill", "dispatch", "process", "wait", "deliver"} <= set(phases)
+    assert phases["wait"] + phases["deliver"] == pytest.approx(phases["process"], abs=2e-3)
+    # the flight recorder's frame IS the span's attributes: one dict
+    assert engine._obs.flight.iterations()[-1] is not None
+    assert any(
+        f["i"] == iterations[-1]["attributes"]["i"]
+        for f in engine._obs.flight.iterations()
+    )
+    assert engine.stats()["moe-routed-assignments-total"] == 0
+
+
+def test_kv_tokens_read_matches_a_hand_count(dense_params):
+    """Two requests, driven one iteration at a time: A (3 tokens) decodes
+    alone in chunk 1; B (2 tokens) joins for chunk 2, where A's device
+    position leads by chunk 1's eight steps whether or not the host has
+    processed them yet."""
+    TRACER.clear()
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=2, max_seq_len=128, decode_chunk=8,
+        overlap=True,
+    )
+    pending: deque = deque()
+    opts = GenerationOptions(max_new_tokens=60, temperature=0.0)
+    engine.submit(GenerationRequest(prompt_tokens=[4, 5, 6], options=opts))
+    engine._iterate(pending)
+    engine.submit(GenerationRequest(prompt_tokens=[7, 8], options=opts))
+    engine._iterate(pending)
+    drain(engine, pending)
+    chunks = sorted(spans_named("engine.decode_chunk"), key=lambda s: s["attributes"]["seq"])
+    assert [c["attributes"]["steps"] for c in chunks[:2]] == [8, 8]
+    assert [c["attributes"]["active_rows"] for c in chunks[:2]] == [1, 2]
+    lengths = lambda first: sum(first + j for j in range(8))  # noqa: E731
+    # step j of a row whose token is written at position p attends p+1+j keys
+    assert chunks[0]["attributes"]["kv_tokens_read"] == lengths(3 + 1)
+    assert chunks[1]["attributes"]["kv_tokens_read"] == lengths(3 + 8 + 1) + lengths(2 + 1)
+
+
+def test_nothing_is_emitted_with_observability_off(dense_params):
+    TRACER.clear()
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=2, max_seq_len=64, decode_chunk=4,
+        observability=False,
+    )
+    engine.start()
+    try:
+        r = engine.generate([5, 6, 7], GenerationOptions(max_new_tokens=8), timeout=120)
+        assert len(r.tokens) == 8
+        assert engine.prefill_tps_estimate() == 0.0
+    finally:
+        engine.stop()
+    names = {s["name"] for s in TRACER.spans(4096)}
+    assert not names.intersection(DISPATCH_SPANS + ("engine.request",))
+
+
+def test_prefill_tps_estimate_is_tokens_over_dispatch_to_ready(dense_params):
+    """On a blocked run (one request at a time, so every group's first
+    tokens are waited for) the estimate has to sit within 2x of the real
+    prompt tokens over the groups' dispatch→ready time, which the spans
+    give independently — not two orders above it, as a launch time read."""
+    TRACER.clear()
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=2, max_seq_len=128, decode_chunk=4,
+        prefill_buckets=(64,),
+    )
+    engine.start()
+    try:
+        for i in range(4):
+            engine.generate([3 + i] * 40, GenerationOptions(max_new_tokens=2), timeout=120)
+        estimate = engine.prefill_tps_estimate()
+        hist = engine.stats()["histograms"]["engine_prefill_group_s"]
+    finally:
+        engine.stop()
+    groups = spans_named("engine.admit_group")
+    assert len(groups) == 4 == hist["count"]
+    seconds = sum(g["durationMs"] for g in groups) / 1e3
+    assert hist["sum"] == pytest.approx(seconds, rel=0.05, abs=1e-3)
+    truth = 4 * 40 / seconds
+    assert truth / 2 <= estimate <= truth * 2
+
+
+# ---------------------------------------------------------------------------
+# MoE counts
+# ---------------------------------------------------------------------------
+
+
+def reference_counts(chosen: np.ndarray, valid: np.ndarray, capacity: int, e: int):
+    """Assignments in (token, slot) order; an expert keeps its first
+    `capacity` and drops the rest."""
+    taken = np.zeros(e, int)
+    dropped = dropped_real = 0
+    for t in range(chosen.shape[0]):
+        for expert in chosen[t]:
+            taken[expert] += 1
+            if taken[expert] > capacity:
+                dropped += 1
+                dropped_real += bool(valid[t])
+    k = chosen.shape[1]
+    return [chosen.size, dropped, int(valid.sum()) * k, dropped_real]
+
+
+def skewed_layer(moe_params, bias=50.0):
+    """One layer's params with the router pushed onto expert 0."""
+    lp = jax.tree.map(lambda a: a[0], moe_params["layers"])
+    router = np.array(lp["router"])
+    router[:, 0] += bias * np.sign(router[:, 0].sum() or 1.0)
+    return {**lp, "router": jnp.asarray(router)}
+
+
+@pytest.mark.parametrize("factor", [0.25, 1.0, 0.0, -1.0])
+def test_forced_skew_drops_what_the_reference_counts(moe_params, factor):
+    config = dataclasses.replace(MOE, moe_capacity_factor=factor)
+    b, s = 2, 128
+    x = jax.random.normal(jax.random.PRNGKey(2), (b, s, MOE.d_model), jnp.float32)
+    x = jnp.abs(x)  # every token leans the same way: all choose expert 0
+    lp = skewed_layer(moe_params)
+    valid = jnp.arange(s)[None, :] < jnp.asarray([100, 7])[:, None]
+    out, counts = T.moe_ffn_counted(x, lp, config, valid)
+    logits = np.asarray((x.reshape(b * s, -1) @ lp["router"]).astype(jnp.float32))
+    chosen = np.argsort(-logits, axis=-1, kind="stable")[:, : MOE.n_experts_per_tok]
+    t, k, e = b * s, MOE.n_experts_per_tok, MOE.n_experts
+    capacity = (
+        min(t, max(math.ceil(t * k * factor / e), min(t, 64))) if factor > 0 else t
+    )
+    want = reference_counts(chosen, np.asarray(valid).reshape(t), capacity, e)
+    assert [int(c) for c in counts] == want
+    if factor > 0:
+        assert want[1] > 0 and 0 < want[3] < want[1]  # skew binds; padding drops too
+    else:
+        assert want[1] == 0 == want[3]  # lossless: capacity = T
+    # the counting path changes no output
+    assert np.array_equal(np.asarray(out), np.asarray(T.moe_ffn(x, lp, config)))
+
+
+def test_logits_are_bit_identical_with_and_without_the_counts(moe_params):
+    config = dataclasses.replace(MOE, moe_capacity_factor=0.25)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 32), 1, MOE.vocab_size)
+    lengths = jnp.asarray([32, 9])
+    plain, cache = T.prefill(
+        moe_params, tokens, lengths, T.make_kv_cache(config, 2, 32), config
+    )
+    counted, cache2, counts = T.prefill(
+        moe_params, tokens, lengths, T.make_kv_cache(config, 2, 32), config,
+        moe_counts=True,
+    )
+    assert np.array_equal(np.asarray(plain), np.asarray(counted))
+    assert jax.tree.all(jax.tree.map(lambda a, b: bool((a == b).all()), cache, cache2))
+    routed, dropped, routed_real, dropped_real = (int(c) for c in counts)
+    assert routed == 2 * 32 * MOE.n_experts_per_tok * MOE.n_layers
+    assert routed_real == (32 + 9) * MOE.n_experts_per_tok * MOE.n_layers
+    assert 0 <= dropped_real <= dropped <= routed
+    page = 16
+    pool = T.make_page_pool(config, 8, page)
+    table = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
+    step = jax.jit(
+        T.paged_decode_step_inplace,
+        static_argnames=("config", "page_size", "moe_counts"),
+    )
+    args = (moe_params, tokens[:, 0], jnp.asarray([3, 5]), pool, table)
+    a, _ = step(*args, config=config, page_size=page)
+    b, _, decode_counts = step(*args, config=config, page_size=page, moe_counts=True)
+    assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert int(decode_counts[1]) == 0  # capacity = T at two rows
+
+
+def test_engine_reports_moe_counts_on_spans_and_in_stats(moe_params):
+    TRACER.clear()
+    config = dataclasses.replace(MOE, moe_capacity_factor=0.25)
+    engine = ServingEngine(
+        config, moe_params, max_batch=2, max_seq_len=128, decode_chunk=4,
+        prefill_buckets=(64,), prefill_batch=2,
+    )
+    engine.start()
+    try:
+        engine.generate([7] * 40, GenerationOptions(max_new_tokens=6), timeout=300)
+    finally:
+        engine.stop()  # lands the chunk still in flight behind the last token
+    stats = engine.stats()
+    spans = spans_named("engine.admit_group") + spans_named("engine.decode_chunk")
+    assert stats["moe-routed-assignments-total"] == sum(
+        s["attributes"]["moe_routed"] for s in spans
+    ) > 0
+    assert stats["moe-dropped-assignments-total"] == sum(
+        s["attributes"]["moe_dropped"] for s in spans
+    )
+    group = spans_named("engine.admit_group")[0]["attributes"]
+    k, layers = MOE.n_experts_per_tok, MOE.n_layers
+    assert group["moe_routed"] == 2 * 64 * k * layers
+    assert group["moe_routed_real"] == 40 * k * layers
+    for chunk in spans_named("engine.decode_chunk"):
+        a = chunk["attributes"]
+        assert a["moe_routed"] == a["steps"] * 2 * k * layers and a["moe_dropped"] == 0
+
+
+# ---------------------------------------------------------------------------
+# names inside the device programs
+# ---------------------------------------------------------------------------
+
+
+def lowered_scopes(fn, *args) -> set:
+    """Every name-stack component in the lowered program's locations
+    (`loc("attention/dot_general")`, nested calls each with their own)."""
+    text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    return {
+        part for loc in re.findall(r'loc\("([^"]+)"', text) for part in loc.split("/")
+    }
+
+
+def test_lowered_programs_carry_every_scope(dense_params, moe_params):
+    """The union over a dense and a MoE model of the admission path
+    (prefill + page scatter + first-token sample) and the paged decode
+    chunk names every scope of the vocabulary — and each model's own."""
+    page, b = 16, 2
+    tokens = jnp.ones((b, 32), jnp.int32)
+    lengths = jnp.asarray([32, 9])
+    table = jnp.arange(8, dtype=jnp.int32).reshape(b, 4)
+    key = jax.random.PRNGKey(0)
+    ones, zeros = jnp.ones(b), jnp.zeros(b, jnp.int32)
+    texts = {}
+    for name, config, params in (("dense", DENSE, dense_params), ("moe", MOE, moe_params)):
+        pool = T.make_page_pool(config, 8, page)
+
+        def admit(params, pool, config=config):
+            logits, local = T.prefill(
+                params, tokens, lengths, T.make_kv_cache(config, b, 32), config
+            )
+            first, _, _ = E._sample_first(
+                logits, key, ones, zeros, ones, None, None, None, config.vocab_size
+            )
+            return first, T.paged_insert_cache(pool, local, table, page)
+
+        def decode(params, pool, config=config):
+            return E._paged_decode_chunk(
+                params, tokens[:, 0], lengths, pool, table, key, ones, zeros,
+                ones, 2, config, page,
+            )
+
+        texts[name] = lowered_scopes(admit, params, pool) | lowered_scopes(
+            decode, params, pool
+        )
+    assert set(T.SCOPES) <= texts["dense"] | texts["moe"]
+    assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
+    assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",
+            "moe_ffn.combine"} <= texts["moe"] and "ffn" not in texts["moe"]
+    decode_only = lowered_scopes(decode, moe_params, T.make_page_pool(MOE, 8, page))
+    assert {"embed", "attention", "moe_ffn", "kv_pool.read", "kv_pool.write", "head",
+            "sample"} <= decode_only
+
+
+def test_hot_loop_cost_of_dispatch_spans_and_annotations(dense_params):
+    """What this PR adds per dispatch (the record, the landing, one span)
+    and per iteration (five phase annotations, the iteration span), each
+    best-of-N, against a decode step: a chunk of `decode_chunk` steps pays
+    them once. Same 1% contract as the per-token instrumentation
+    (test_observability.py), against the same worst case: tiny-test's CPU
+    step."""
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=4, max_seq_len=256, decode_chunk=8,
+    )
+    engine.start()
+    try:
+        reqs = [
+            engine.submit(GenerationRequest(
+                prompt_tokens=[3 + i] * 24, options=GenerationOptions(max_new_tokens=96),
+            ))
+            for i in range(4)
+        ]
+        for r in reqs:
+            r.result(timeout=300)
+        stats = engine.stats()
+        step_s = stats["decode-step-ms"] / 1e3 or stats["histograms"]["engine_decode_step_s"]["p50"]
+        assert step_s > 0
+        live = [s for s in engine._slots]
+        handle = E._Fetch(None, engine._fetcher)
+        handle.ready_at = time.monotonic()
+        per_dispatch = per_iteration = float("inf")
+        for _ in range(5):
+            n = 2_000
+            t0 = time.perf_counter()
+            for _ in range(n):
+                disp = engine._new_dispatch(
+                    "engine.decode_chunk", program="_paged_decode_chunk", steps=8,
+                    active_rows=4, kv_tokens_read=engine._kv_tokens_read(live, 8),
+                    clean=True, pipelined=True,
+                )
+                with jax.profiler.TraceAnnotation("engine.decode_chunk", seq=1, steps=8):
+                    pass
+                handle.counts = engine._moe_counts()
+                engine._land_dispatch(disp, handle)
+            per_dispatch = min(per_dispatch, (time.perf_counter() - t0) / n)
+            t0 = time.perf_counter()
+            for _ in range(n):
+                for name in ("engine.sweep", "engine.admit", "engine.dispatch",
+                             "engine.process.wait", "engine.process.deliver"):
+                    with jax.profiler.TraceAnnotation(name):
+                        pass
+                E.emit_dispatch_span("engine.iteration", 0.0, 1.0, {})
+            per_iteration = min(per_iteration, (time.perf_counter() - t0) / n)
+    finally:
+        engine.stop()
+    per_step = (per_dispatch + per_iteration) / engine.decode_chunk
+    assert per_step / step_s <= 0.01, (
+        f"dispatch spans and annotations cost {per_step * 1e6:.2f}us a step, "
+        f"{per_step / step_s * 100:.2f}% of the {step_s * 1e3:.3f}ms decode step"
+    )

@@ -6,6 +6,7 @@ nothing about results or times (chip_smoke.py checks results on a chip)."""
 
 import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
@@ -133,12 +134,27 @@ def _placed(args, shardings):
     )
 
 
+def _kernel_of(case: str) -> str:
+    """The public function a case calls, which is its pallas_call's name=."""
+    kind = re.sub(r"-\d+$", "", case.split("-", 1)[1])  # drop the width
+    return {
+        "prefill": "flash_prefill_attention",
+        "segment": "flash_segment_attention",
+        "segment-int8": "flash_segment_attention_int8",
+        "paged-decode": "ragged_paged_decode_attention",
+        "paged-decode-int8": "ragged_paged_decode_attention_int8",
+    }[kind]
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(v5e, case):
     fn, args = CASES[case]
     one_chip = SingleDeviceSharding(v5e[0])
-    compiled = jax.jit(fn).lower(*_placed(args, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*_placed(args, one_chip)).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the kernel's instruction is named after it, which is how a profile's
+    # device events are told apart (`_int8` is not a suffix of the match)
+    assert re.search(rf"%{_kernel_of(case)}(\.\d+)? = ", text), _kernel_of(case)
 
 
 def test_model_sharded_paged_decode_compiles_for_four_chips(v5e):
@@ -162,6 +178,7 @@ def test_model_sharded_paged_decode_compiles_for_four_chips(v5e):
     compiled = jax.jit(fn).lower(*_placed(args, shardings)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert "%ragged_paged_decode_attention_int8" in text
     # independent per kv head: the shard_map body needs no collective
     assert "all-reduce" not in text and "all-gather" not in text
     # each chip holds a quarter of the pool (k and v: int8 values + scales)
