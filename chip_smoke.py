@@ -93,7 +93,7 @@ def kernel_checks(
 
     from langstream_tpu.models.transformer import (
         _dequantize_kv,
-        _paged_gather_entry,
+        _paged_gather,
         _quantize_kv,
         attention,
     )
@@ -165,27 +165,31 @@ def kernel_checks(
     lengths, table = jnp.asarray(lengths), jnp.asarray(table)
     q = rand(b, h, d)
     mask = jnp.arange(per_row * page)[None, None, :] < lengths[:, None, None]
-    kp, vp = rand(pages, hkv, page, d), rand(pages, hkv, page, d)
+    # the kernels read the pool [L, P, Hkv, ps, D] where it lies, at a
+    # layer index: the second of two layers, so a wrong layer shows
+    layer = jnp.int32(1)
+    kp, vp = rand(2, pages, hkv, page, d), rand(2, pages, hkv, page, d)
     check(
         f"ragged_paged_decode_attention[b={b},page={page}]",
         ragged_paged_decode_attention(
-            q, kp, vp, lengths, table, config, page, interpret=interpret
+            q, kp, vp, lengths, table, layer, config, page, interpret=interpret
         ),
         reference(
-            q[:, None], _paged_gather_entry(kp, table, page),
-            _paged_gather_entry(vp, table, page), mask,
+            q[:, None], _paged_gather(kp, layer, table, page),
+            _paged_gather(vp, layer, table, page), mask,
         )[:, 0],
     )
     kp8, vp8 = (dict(zip("qs", _quantize_kv(x))) for x in (kp, vp))
     check(
         f"ragged_paged_decode_attention_int8[b={b},page={page}]",
         ragged_paged_decode_attention_int8(
-            q, kp8, vp8, lengths, table, config, page, interpret=interpret
+            q, kp8, vp8, lengths, table, layer, config, page,
+            interpret=interpret,
         ),
         reference(
             q[:, None],
-            _dequantize_kv(_paged_gather_entry(kp8, table, page), jnp.float32),
-            _dequantize_kv(_paged_gather_entry(vp8, table, page), jnp.float32),
+            _dequantize_kv(_paged_gather(kp8, layer, table, page), jnp.float32),
+            _dequantize_kv(_paged_gather(vp8, layer, table, page), jnp.float32),
             mask,
         )[:, 0],
     )

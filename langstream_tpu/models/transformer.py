@@ -282,44 +282,50 @@ def _page_index(table: jax.Array, positions: jax.Array, page_size: int,
     return pages, positions % page_size
 
 
-def _paged_scatter_entry(entry, vals: jax.Array, table: jax.Array,
-                         positions: jax.Array, page_size: int):
-    """Scatter per-token K/V ``vals`` [B, Hkv, S, D] into a per-layer pool
-    entry [P, Hkv, ps, D] (or its int8 dict) at the physical pages
-    ``table[b, pos // ps]``, offset ``pos % ps``. Unmapped (out-of-bounds
-    sentinel) pages drop the write — padding rows, warmups, and steps past a
+def _paged_scatter(pool, layer, vals: jax.Array, table: jax.Array,
+                   positions: jax.Array, page_size: int):
+    """Scatter per-token K/V ``vals`` [B, Hkv, S, D] into the pool
+    [L, P, Hkv, ps, D] (or its int8 dict) where it lies, at
+    ``[layer, table[b, pos // ps], head, pos % ps]``. The layer is an index
+    of its own, never folded into the page: the drop sentinel (= P) stays
+    out of bounds on the page axis instead of naming layer + 1's page 0.
+    Unmapped pages drop the write — padding rows, warmups, and steps past a
     slot's reservation all ride the same drop."""
-    num_pages = (entry["q"] if isinstance(entry, dict) else entry).shape[0]
+    num_pages = (pool["q"] if isinstance(pool, dict) else pool).shape[1]
     pages, offs = _page_index(table, positions, page_size, num_pages)
     hkv = vals.shape[1]
     pidx = pages[:, None, :]  # [B, 1, S]
     oidx = offs[:, None, :]
     hidx = jnp.arange(hkv)[None, :, None]
-    if isinstance(entry, dict):
+    if isinstance(pool, dict):
         q, s = _quantize_kv(vals)
         return {
-            "q": entry["q"].at[pidx, hidx, oidx].set(q, mode="drop"),
-            "s": entry["s"].at[pidx, hidx, oidx].set(s, mode="drop"),
+            "q": pool["q"].at[layer, pidx, hidx, oidx].set(q, mode="drop"),
+            "s": pool["s"].at[layer, pidx, hidx, oidx].set(s, mode="drop"),
         }
-    return entry.at[pidx, hidx, oidx].set(vals.astype(entry.dtype), mode="drop")
+    return pool.at[layer, pidx, hidx, oidx].set(
+        vals.astype(pool.dtype), mode="drop"
+    )
 
 
-def _paged_gather_entry(entry, table: jax.Array, page_size: int):
+def _paged_gather(pool, layer, table: jax.Array, page_size: int):
     """Materialize the dense head-major view of every slot's logical columns
-    from a per-layer pool entry: [P, Hkv, ps, D] gathered through ``table``
-    [B, Tp] → [B, Hkv, Tp×ps, D] (int8 dicts gather q and s alike, feeding
-    the existing hoisted-scale attention math untouched). This is the
-    masked-jnp fallback read — exactness-bearing on CPU; on TPU the Pallas
-    ragged-paged kernel reads pages in place instead (ops/attention.py)."""
+    of one layer: [L, P, Hkv, ps, D] gathered through ``(layer, table)``
+    [B, Tp] in ONE gather → [B, Hkv, Tp×ps, D] (int8 dicts gather q and s
+    alike, feeding the existing hoisted-scale attention math untouched).
+    Sentinel table entries clamp to the last page, which the mask hides.
+    This is the masked-jnp fallback read — exactness-bearing on CPU; on TPU
+    the Pallas ragged-paged kernel reads pages in place instead
+    (ops/attention.py)."""
     def gather(a):
         b, tp = table.shape
-        g = jnp.take(a, table, axis=0, mode="clip")  # [B, Tp, Hkv, ps, ...]
+        g = a.at[layer, table].get(mode="clip")  # [B, Tp, Hkv, ps, ...]
         g = jnp.moveaxis(g, 2, 1)  # [B, Hkv, Tp, ps, ...]
-        return g.reshape((b, a.shape[1], tp * page_size) + a.shape[3:])
+        return g.reshape((b, a.shape[2], tp * page_size) + a.shape[4:])
 
-    if isinstance(entry, dict):
-        return {"q": gather(entry["q"]), "s": gather(entry["s"])}
-    return gather(entry)
+    if isinstance(pool, dict):
+        return {"q": gather(pool["q"]), "s": gather(pool["s"])}
+    return gather(pool)
 
 
 def attention(
@@ -614,10 +620,68 @@ def _attention_block(
     lora: Optional[dict] = None,  # per-layer adapter slices {proj: {a, b}}
     lora_scale: Optional[jax.Array] = None,  # [R] per-adapter scale
     adapter_rows: Optional[jax.Array] = None,  # [B] pool row per slot
+    layer: Optional[jax.Array] = None,  # scalar: this block's layer of the pool
 ) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]]]:
     """The attention half of a block (norm, QKV, rotary, cache write, the
     kernel or jnp path, output projection, residual): the layer's input in,
-    the FFN's input and the layer's new cache entry out."""
+    the FFN's input and the layer's new cache entry out. It names its own
+    scopes: all of it is ``attention``, but for the paged branch's scatter
+    of the new K/V rows, the pool's only write, which is ``kv_pool.write``
+    and (a scope cannot be left from inside) outside ``attention``. With
+    ``paged_table`` set, ``cache_kv`` is the WHOLE pool and comes back
+    whole: nothing of a layer's size is formed."""
+    if paged_table is None:
+        with jax.named_scope("attention"):
+            return _dense_attention(
+                x, lp, sin, cos, mask, config, cache_kv, cache_positions,
+                causal, kv_offset, kv_bound, collect_kv, verify, lora,
+                lora_scale, adapter_rows,
+            )
+    assert cache_kv is not None and cache_positions is not None
+    from langstream_tpu.ops.attention import (
+        note_path,
+        paged_pallas_ok,
+        ragged_paged_decode_attention,
+        ragged_paged_decode_attention_int8,
+    )
+
+    s = x.shape[1]
+    with jax.named_scope("attention"):
+        q, k, v = _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows)
+        kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    pk, pv = cache_kv  # [L, P, Hkv, ps, D], read and written at `layer`
+    with jax.named_scope("kv_pool.write"):
+        pk = _paged_scatter(pk, layer, kt, paged_table, cache_positions, page_size)
+        pv = _paged_scatter(pv, layer, vt, paged_table, cache_positions, page_size)
+    with jax.named_scope("attention"):
+        t = paged_table.shape[1] * page_size
+        if s == 1 and paged_pallas_ok(config, page_size):
+            lengths = cache_positions[:, 0] + 1
+            kernel = (
+                ragged_paged_decode_attention_int8 if isinstance(pk, dict)
+                else ragged_paged_decode_attention
+            )
+            note_path("paged-decode", kernel.__name__, config, s=s, t=t)
+            out = kernel(
+                q[:, 0], pk, pv, lengths, paged_table, layer, config, page_size,
+                interpret=jax.default_backend() != "tpu",
+            )
+            attn = out[:, None, :]
+        else:
+            kind = "decode" if s == 1 else "verify" if verify else "segment"
+            note_path(f"paged-{kind}", "jnp", config, s=s, t=t)
+            k_all = _paged_gather(pk, layer, paged_table, page_size)
+            v_all = _paged_gather(pv, layer, paged_table, page_size)
+            attn = attention(q, k_all, v_all, mask, config)
+        x = x + quantized_matmul(attn, lp["wo"]) + _lora_proj(
+            attn, "wo", lora, lora_scale, adapter_rows
+        )
+    return x, (pk, pv)
+
+
+def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
+    """Norm, the three projections with their adapter terms, rotary:
+    q [B, S, H, D], k and v [B, S, Hkv, D]."""
     b, s, d = x.shape
     hd = config.resolved_head_dim
 
@@ -637,44 +701,19 @@ def _attention_block(
     q = apply_rope(q, sin, cos)
     k = apply_rope(k, sin, cos)
 
-    new_cache = None
-    if paged_table is not None:
-        assert cache_kv is not None and cache_positions is not None
-        ck, cv = cache_kv  # per-layer pool entries [P, Hkv, ps, D]
-        kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-        ck = _paged_scatter_entry(ck, kt, paged_table, cache_positions, page_size)
-        cv = _paged_scatter_entry(cv, vt, paged_table, cache_positions, page_size)
-        new_cache = (ck, cv)
-        from langstream_tpu.ops.attention import (
-            note_path,
-            paged_pallas_ok,
-            ragged_paged_decode_attention,
-            ragged_paged_decode_attention_int8,
-        )
+    return q, k, v
 
-        t = paged_table.shape[1] * page_size
-        if s == 1 and paged_pallas_ok(config, page_size):
-            lengths = cache_positions[:, 0] + 1
-            kernel = (
-                ragged_paged_decode_attention_int8 if isinstance(ck, dict)
-                else ragged_paged_decode_attention
-            )
-            note_path("paged-decode", kernel.__name__, config, s=s, t=t)
-            out = kernel(
-                q[:, 0], ck, cv, lengths, paged_table, config, page_size,
-                interpret=jax.default_backend() != "tpu",
-            )
-            attn = out[:, None, :]
-        else:
-            kind = "decode" if s == 1 else "verify" if verify else "segment"
-            note_path(f"paged-{kind}", "jnp", config, s=s, t=t)
-            k_all = _paged_gather_entry(ck, paged_table, page_size)
-            v_all = _paged_gather_entry(cv, paged_table, page_size)
-            attn = attention(q, k_all, v_all, mask, config)
-        x = x + quantized_matmul(attn, lp["wo"]) + _lora_proj(
-            attn, "wo", lora, lora_scale, adapter_rows
-        )
-        return x, new_cache
+
+def _dense_attention(
+    x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal,
+    kv_offset, kv_bound, collect_kv, verify, lora, lora_scale, adapter_rows,
+):
+    """`_attention_block` without a page table: a dense cache entry
+    [B, Hkv, T, D] written at ``cache_positions`` and read whole, or no
+    cache at all."""
+    b = x.shape[0]
+    q, k, v = _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows)
+    new_cache = None
     if cache_kv is not None:
         ck, cv = cache_kv  # [B, Hkv, T, D] head-major (maybe int8-quantized)
         # scatter this step's k/v into the cache at cache_positions [B, S]
@@ -722,8 +761,6 @@ def _attention_block(
     return x + attn_out, new_cache
 
 
-
-
 def _layer_counted(
     x: jax.Array,
     lp: dict,
@@ -744,6 +781,7 @@ def _layer_counted(
     lora_scale: Optional[jax.Array] = None,  # [R] per-adapter scale
     adapter_rows: Optional[jax.Array] = None,  # [B] pool row per slot
     token_valid: Optional[jax.Array] = None,  # [B, S] bool — real tokens
+    layer: Optional[jax.Array] = None,  # scalar layer index (paged only)
 ) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]], jax.Array]:
     """One transformer block, and its MOE_COUNTS (zeros when dense; only
     ``token_valid`` feeds them). If cache_kv given, k/v are written at
@@ -751,19 +789,20 @@ def _layer_counted(
     ``collect_kv`` (cache-less paths) the layer's roped K/V come back
     head-major so a caller can build a cache from a full forward — the
     ring-prefill serving path (parallel.sp.ring_prefill). With
-    ``paged_table`` set, cache_kv are per-layer PAGE-POOL entries
-    ([P, Hkv, ps, D]): K/V scatter to the slot's pages and attention reads
-    through the table (Pallas ragged-paged kernel on decode shapes when it
-    applies, else the gathered masked-jnp view — same math either way).
+    ``paged_table`` set, cache_kv is the whole PAGE POOL
+    ([L, P, Hkv, ps, D]) and ``layer`` this block's index into it: K/V
+    scatter to the slot's pages of that layer and attention reads through
+    (layer, table) (Pallas ragged-paged kernel on decode shapes when it
+    applies, else the gathered masked-jnp view — same math either way);
+    the pool comes back whole, no per-layer entry is ever formed.
     With ``lora`` set, every projection adds its slot-gathered low-rank
     adapter term (``_lora_delta``) — K/V written to the cache INCLUDE the
     wk/wv adapter deltas, which is why prefill must be adapter-aware too."""
-    with jax.named_scope("attention"):
-        x, new_cache = _attention_block(
-            x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal,
-            kv_offset, kv_bound, collect_kv, verify, paged_table, page_size,
-            lora, lora_scale, adapter_rows,
-        )
+    x, new_cache = _attention_block(
+        x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal,
+        kv_offset, kv_bound, collect_kv, verify, paged_table, page_size,
+        lora, lora_scale, adapter_rows, layer,
+    )
     if config.is_moe:
         with jax.named_scope("moe_ffn"):
             ffn_in = rms_norm(x, lp["ffn_norm"], config.rms_norm_eps)
@@ -874,19 +913,31 @@ def _scan_layers_inplace(
     kv_offset=None, verify=False, paged_table=None, page_size=0,
     lora=None, adapter_rows=None,
 ):
-    """Layer loop with the cache updated IN PLACE via a scan carry +
-    dynamic-update-slice at the layer index, instead of consuming the cache
-    as scan ``xs`` and stacking fresh ``ys``.
+    """Layer loop with the cache carried through the scan and updated IN
+    PLACE, instead of consumed as scan ``xs`` and stacked as fresh ``ys``.
 
     The xs/ys form allocates a second cache-sized buffer every call — inside
     an outer step loop (engine `_decode_chunk`'s lax.scan) that temp is live
     across the whole chunk, which is exactly the double-buffer that capped
-    llama-3-8b at B=48 on a 16GiB chip (serving/memory.py scan_buffer term).
-    A while-loop carry is aliased in place by XLA, and the per-layer
-    dynamic-update-slice back into the carried buffer is in-place too, so
-    peak cache memory here is 1x cache + one layer slice. Returns (x,
-    cache, the layers' summed MOE_COUNTS)."""
+    llama-3-8b at B=48 on a 16GiB chip. A while-loop carry is aliased in
+    place by XLA. What a layer does to the carry depends on what is there:
+
+    - a page table (``paged_table``): the pool [L, P, Hkv, ps, D] goes down
+      to the attention block whole with the layer index, which scatters the
+      new K/V rows at ``[l, page, head, offset]`` and reads the row's pages
+      at ``(l, page)``. No per-layer entry is formed: the step's device
+      program holds no operand of the shape [P, Hkv, ps, D]
+      (tests/test_tpu_compile.py asserts it on the compiled HLO).
+    - a dense cache: the layer's entry [B, Hkv, T, D] is sliced out of the
+      carry (``kv_pool.read``) and written back with a dynamic-update-slice
+      (``kv_pool.write``): 1x cache + two layer slices at the peak. On a
+      v5e such a pair is two real copies of the entry (seen on the paged
+      pool before it took the branch above: PERF.md §6, PR 25); the dense
+      pair has not been traced, no cell runs it (ROADMAP D3).
+
+    Returns (x, cache, the layers' summed MOE_COUNTS)."""
     layers = params["layers"]
+    lora_layers, lora_scale = _split_lora(lora)
 
     def read(full, l):
         return jax.tree.map(
@@ -898,26 +949,30 @@ def _scan_layers_inplace(
             lambda a, n: lax.dynamic_update_index_in_dim(a, n, l, 0), full, new
         )
 
-    lora_layers, lora_scale = _split_lora(lora)
+    def layer(x, kv, l, lp, ll):
+        return _layer_counted(
+            x, lp, sin, cos, mask, config, cache_kv=kv,
+            cache_positions=cache_positions, kv_offset=kv_offset,
+            kv_bound=kv_bound, verify=verify, paged_table=paged_table,
+            page_size=page_size, lora=ll, lora_scale=lora_scale,
+            adapter_rows=adapter_rows, layer=l,
+        )
 
     def body(carry, inputs):
         x, cache = carry
         lp, l, ll = inputs
-        with jax.named_scope("kv_pool.read"):
-            ck = read(cache["k"], l)
-            cv = read(cache["v"], l)
-        y, new_kv, counts = _layer_counted(
-            x, lp, sin, cos, mask, config, cache_kv=(ck, cv),
-            cache_positions=cache_positions, kv_offset=kv_offset,
-            kv_bound=kv_bound, verify=verify, paged_table=paged_table,
-            page_size=page_size, lora=ll, lora_scale=lora_scale,
-            adapter_rows=adapter_rows,
-        )
-        nck, ncv = new_kv
-        with jax.named_scope("kv_pool.write"):
-            cache = {
-                "k": write(cache["k"], nck, l), "v": write(cache["v"], ncv, l)
-            }
+        if paged_table is not None:
+            y, (nk, nv), counts = layer(x, (cache["k"], cache["v"]), l, lp, ll)
+            cache = {"k": nk, "v": nv}
+        else:
+            with jax.named_scope("kv_pool.read"):
+                ck = read(cache["k"], l)
+                cv = read(cache["v"], l)
+            y, (nck, ncv), counts = layer(x, (ck, cv), None, lp, ll)
+            with jax.named_scope("kv_pool.write"):
+                cache = {
+                    "k": write(cache["k"], nck, l), "v": write(cache["v"], ncv, l)
+                }
         return (y, cache), (counts if config.is_moe else None)
 
     (x, cache), counts = lax.scan(

@@ -84,13 +84,15 @@ def _model_on(ndim: int, axis: int) -> P:
     return P(*("model" if i == axis else None for i in range(ndim)))
 
 
-def _per_kv_head(n_replicated: int):
+def _per_kv_head(n_replicated: int, kv_head_axis: int = 1):
     """Decorator for kernels of signature ``fn(q, k, v, *replicated, config,
     ...)``. Mosaic kernels cannot be partitioned by GSPMD, so when
     ``config.kernel_mesh`` is set (the engine runs under a mesh) the call
     is wrapped in a fully manual shard_map that splits the head axis of q
     and of the K/V cache or page pool over "model" — what param_specs and
-    serving_cache_specs/page_pool_specs already produce. Every kernel here
+    serving_cache_specs/page_pool_specs already produce. ``kv_head_axis``
+    is where the kv heads lie in every K/V leaf: 1 in a dense cache
+    [B, Hkv, T(, D)], 2 in the page pool [L, P, Hkv, ps(, D)]. Every kernel here
     is independent per kv head (each q-head group reads only its own kv
     head), so the body needs no collective; the ``replicated`` operands
     (offsets, lengths, page tables) and every other mesh axis stay
@@ -110,7 +112,7 @@ def _per_kv_head(n_replicated: int):
             def body(q, k, v, *replicated):
                 return fn(q, k, v, *replicated, local, *tail, **kwargs)
 
-            kv_spec = jax.tree.map(lambda x: _model_on(x.ndim, 1), k)
+            kv_spec = jax.tree.map(lambda x: _model_on(x.ndim, kv_head_axis), k)
             return jax.shard_map(
                 body,
                 mesh=mesh,
@@ -867,9 +869,18 @@ def ragged_decode_attention_int8(
 # the last valid page past the row's length so Pallas elides the HBM→VMEM
 # copy. HBM traffic therefore scales with CONTENT (sum of lengths), and no
 # kv_bound ladder is needed: the table IS the bound, one compiled program
-# for every sequence-length mix. The masked-jnp fallback (gather through
-# the table, then the stock attention math) lives in
-# models/transformer._paged_gather_entry and carries tier-1 exactness.
+# for every sequence-length mix. The kernels take the WHOLE pool
+# [L, P, Hkv, ps, D] and a layer index, so the caller's layer scan never
+# slices a per-layer entry out of the pool to hand one over (a custom
+# call's operand is materialised: that slice was 39.7% of a chat decode
+# step, PERF.md §6 PR 25). The layer costs the kernel nothing: the pool is
+# seen as [L·P, ...] (merging two major dimensions moves no byte) and the
+# layer's offset is added to the table before the call, so the index maps
+# do per grid step what they did for one layer's entry (a third
+# scalar-prefetch operand and a (layer, page) block index read 561 against
+# 557 us a call, my chip runs, PR 25). The masked-jnp fallback (gather
+# through (layer, table), then the stock attention math) lives in
+# models/transformer._paged_gather and carries tier-1 exactness.
 # ---------------------------------------------------------------------------
 
 
@@ -940,39 +951,57 @@ def _paged_decode_kernel(
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _paged_kv_index(num_pages: int, page_size: int, table_len: int):
+def _paged_kv_index(page_size: int, table_len: int):
     """Index map factory for page-pool blocks: grid step (b, j) loads the
-    physical page ``table[b, j]``, with j clamped to the row's last valid
-    logical page (re-referencing the same block elides the DMA — the ragged
-    bandwidth saving) and the physical index clamped in-range so an
-    unmapped sentinel entry (possible only on masked-out pages) reads SOME
-    page instead of faulting."""
+    page ``table[b, j]``, with j clamped to the row's last valid logical
+    page (re-referencing the same block elides the DMA — the ragged
+    bandwidth saving). ``table`` is `_layer_pages`': in range already."""
 
     def kv_index(b, j, lens, table):
         last = jnp.maximum(pl.cdiv(lens[b], page_size) - 1, 0)
-        page = table[b * table_len + jnp.minimum(j, last)]
-        return (jnp.clip(page, 0, num_pages - 1), 0, 0, 0)
+        return (table[b * table_len + jnp.minimum(j, last)], 0, 0, 0)
 
     return kv_index
 
 
-@_per_kv_head(2)
+def _paged_q_index(b, j, lens, table):
+    return (b, 0, 0, 0)
+
+
+def _layer_pages(table: jax.Array, layer: jax.Array, num_pages: int) -> jax.Array:
+    """The flattened table as pages of the pool seen as [L·P, ...]
+    (`_flat_pool`), inside ``layer``: each physical index is clamped into
+    the layer first, so an unmapped sentinel entry (possible only on
+    masked-out pages) reads SOME page of this layer instead of faulting or
+    reaching into the next one."""
+    pages = jnp.clip(table.astype(jnp.int32), 0, num_pages - 1)
+    return (jnp.asarray(layer, jnp.int32) * num_pages + pages).reshape(-1)
+
+
+def _flat_pool(leaf: jax.Array) -> jax.Array:
+    """[L, P, ...] → [L·P, ...]: two major dimensions merged, no byte moved."""
+    return leaf.reshape((-1,) + leaf.shape[2:])
+
+
+@_per_kv_head(3, kv_head_axis=2)
 def ragged_paged_decode_attention(
     q: jax.Array,  # [B, H, D] single query per row
-    k: jax.Array,  # page pool entry [P, Hkv, ps, D]
+    k: jax.Array,  # the page pool [L, P, Hkv, ps, D], read at `layer`
     v: jax.Array,
     lengths: jax.Array,  # [B] valid logical columns per row
     table: jax.Array,  # [B, Tp] physical page per logical page
+    layer: jax.Array,  # scalar: which layer of the pool
     config: ModelConfig,
     page_size: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """GQA paged decode attention → [B, H*D]."""
+    """GQA paged decode attention over one layer of the pool → [B, H*D]."""
     b, h, d = q.shape
-    num_pages, hkv = k.shape[0], k.shape[1]
+    hkv = k.shape[2]
     tp = table.shape[1]
     group = h // hkv
     qg = q.reshape(b, hkv, group, d)
+    pages = _layer_pages(table, layer, k.shape[1])
 
     kernel = functools.partial(
         _paged_decode_kernel,
@@ -980,20 +1009,16 @@ def ragged_paged_decode_attention(
         scale=1.0 / (d**0.5),
         softcap=config.attn_logit_softcap,
     )
-    kv_index = _paged_kv_index(num_pages, page_size, tp)
+    kv_index = _paged_kv_index(page_size, tp)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, tp),
         in_specs=[
-            pl.BlockSpec(
-                (1, hkv, group, d), lambda b, j, lens, table: (b, 0, 0, 0)
-            ),
+            pl.BlockSpec((1, hkv, group, d), _paged_q_index),
             pl.BlockSpec((1, hkv, page_size, d), kv_index),
             pl.BlockSpec((1, hkv, page_size, d), kv_index),
         ],
-        out_specs=pl.BlockSpec(
-            (1, hkv, group, d), lambda b, j, lens, table: (b, 0, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, hkv, group, d), _paged_q_index),
         scratch_shapes=[
             pltpu.VMEM((hkv, group, 128), jnp.float32),
             pltpu.VMEM((hkv, group, 128), jnp.float32),
@@ -1008,10 +1033,10 @@ def ragged_paged_decode_attention(
         interpret=interpret,
     )(
         lengths.astype(jnp.int32),
-        table.astype(jnp.int32).reshape(-1),
+        pages,
         qg,
-        k,
-        v,
+        _flat_pool(k),
+        _flat_pool(v),
     )
     return out.reshape(b, h * d)
 
@@ -1021,9 +1046,9 @@ def _paged_decode_int8_kernel(
     table_ref,  # scalar-prefetch [B * Tp]
     q_ref,  # [1, Hkv, G, D]
     kq_ref,  # [1, Hkv, ps, D] int8 — one physical page
-    ks_ref,  # [1, Hkv, ps, 1] f32 per-token scales
+    ks_ref,  # [1, Hkv, ps] f32 per-token scales
     vq_ref,  # [1, Hkv, ps, D] int8
-    vs_ref,  # [1, Hkv, ps, 1] f32
+    vs_ref,  # [1, Hkv, ps] f32
     o_ref,  # [1, Hkv, G, D]
     m_scr,
     l_scr,
@@ -1035,7 +1060,15 @@ def _paged_decode_int8_kernel(
 ):
     """_paged_decode_kernel over the int8 pool: pages read raw int8 from
     HBM (+f32 scales), dequantized in VMEM — the same wire format as the
-    dense int8 ragged kernel, per page instead of per cache block."""
+    dense int8 ragged kernel, per page instead of per cache block. The
+    scales come as the pool holds them, [.., Hkv, ps]: a trailing unit
+    dimension on the whole pool's scales is a relayout of all of them
+    (f32[L,P,Hkv,ps,1] pads 128-fold: 4 GB at 32 layers × 512 pages).
+    Still owed (PERF.md §7 d): a v5e keeps f32[L, P, Hkv, ps < 128] with
+    the PAGES minor while a custom call's operand is row-major, so each
+    layer's call still relays the scale leaves (33.5 MB each at that size;
+    sandbox AOT, PR 25) — the int8 values, 128 times the bytes, are read
+    where they lie."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     nk = pl.num_programs(1)
@@ -1051,8 +1084,11 @@ def _paged_decode_int8_kernel(
     @pl.when(k_start < length)
     def _body():
         q = q_ref[0].astype(jnp.float32)
-        k = kq_ref[0].astype(jnp.float32) * ks_ref[0]  # [Hkv, ps, D]
-        v = vq_ref[0].astype(jnp.float32) * vs_ref[0]
+        k = kq_ref[0].astype(jnp.float32)  # [Hkv, ps, D]
+        v = vq_ref[0].astype(jnp.float32)
+        # the per-token scales ride the [.., ps]-shaped scores and
+        # probabilities (tokens on lanes, as the pool stores them), not the
+        # [.., ps, D] operands: the same product, D times less scale math
         s = (
             jax.lax.dot_general(
                 q,
@@ -1061,6 +1097,7 @@ def _paged_decode_int8_kernel(
                 preferred_element_type=jnp.float32,
             )
             * scale
+            * ks_ref[0][:, None, :]
         )
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
@@ -1074,7 +1111,7 @@ def _paged_decode_int8_kernel(
         corr = jnp.exp(m_prev - m_new)
         l_scr[:, :, 0] = l_scr[:, :, 0] * corr + p.sum(axis=-1)
         pv = jax.lax.dot_general(
-            p,
+            p * vs_ref[0][:, None, :],
             v,
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -1088,23 +1125,26 @@ def _paged_decode_int8_kernel(
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-@_per_kv_head(2)
+@_per_kv_head(3, kv_head_axis=2)
 def ragged_paged_decode_attention_int8(
     q: jax.Array,  # [B, H, D]
-    k: dict,  # int8 pool entry {"q": [P,Hkv,ps,D] i8, "s": [P,Hkv,ps] f32}
+    k: dict,  # int8 pool {"q": [L,P,Hkv,ps,D] i8, "s": [L,P,Hkv,ps] f32}
     v: dict,
     lengths: jax.Array,  # [B]
     table: jax.Array,  # [B, Tp]
+    layer: jax.Array,  # scalar: which layer of the pool
     config: ModelConfig,
     page_size: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """GQA paged decode attention over the int8 page pool → [B, H*D]."""
+    """GQA paged decode attention over one layer of the int8 page pool →
+    [B, H*D]."""
     b, h, d = q.shape
-    num_pages, hkv = k["q"].shape[0], k["q"].shape[1]
+    hkv = k["q"].shape[2]
     tp = table.shape[1]
     group = h // hkv
     qg = q.reshape(b, hkv, group, d)
+    pages = _layer_pages(table, layer, k["q"].shape[1])
 
     kernel = functools.partial(
         _paged_decode_int8_kernel,
@@ -1112,24 +1152,24 @@ def ragged_paged_decode_attention_int8(
         scale=1.0 / (d**0.5),
         softcap=config.attn_logit_softcap,
     )
-    kv_index = _paged_kv_index(num_pages, page_size, tp)
+    kv_index = _paged_kv_index(page_size, tp)
+
+    def scale_index(*args):
+        return kv_index(*args)[:-1]
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, tp),
         in_specs=[
-            pl.BlockSpec(
-                (1, hkv, group, d), lambda b, j, lens, table: (b, 0, 0, 0)
-            ),
+            pl.BlockSpec((1, hkv, group, d), _paged_q_index),
             pl.BlockSpec((1, hkv, page_size, d), kv_index),
-            # trailing singleton: Mosaic wants the block's last two dims
-            # (8,128)-divisible or equal to the array's — [.., ps, 1]
-            pl.BlockSpec((1, hkv, page_size, 1), kv_index),
+            # Mosaic wants a block's last two dims (8,128)-divisible or
+            # equal to the array's: (Hkv, ps) are the scales' own
+            pl.BlockSpec((1, hkv, page_size), scale_index),
             pl.BlockSpec((1, hkv, page_size, d), kv_index),
-            pl.BlockSpec((1, hkv, page_size, 1), kv_index),
+            pl.BlockSpec((1, hkv, page_size), scale_index),
         ],
-        out_specs=pl.BlockSpec(
-            (1, hkv, group, d), lambda b, j, lens, table: (b, 0, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, hkv, group, d), _paged_q_index),
         scratch_shapes=[
             pltpu.VMEM((hkv, group, 128), jnp.float32),
             pltpu.VMEM((hkv, group, 128), jnp.float32),
@@ -1144,12 +1184,9 @@ def ragged_paged_decode_attention_int8(
         interpret=interpret,
     )(
         lengths.astype(jnp.int32),
-        table.astype(jnp.int32).reshape(-1),
+        pages,
         qg,
-        k["q"],
-        k["s"][..., None],
-        v["q"],
-        v["s"][..., None],
+        *(_flat_pool(leaf) for leaf in (k["q"], k["s"], v["q"], v["s"])),
     )
     return out.reshape(b, h * d)
 

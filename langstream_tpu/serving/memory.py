@@ -38,11 +38,12 @@ class ServingMemoryPlan:
     cache_bytes: int  # decode cache: max_batch × max_seq_len
     long_cache_bytes: int  # chunked-prefill local cache (one prompt wide)
     workspace_bytes: int  # XLA scratch / activation headroom estimate
-    # Residual decode-chunk temp: ONE LAYER's cache slice. The layer scan
-    # carries the cache and updates it in place via dynamic-update-slice
-    # (transformer._scan_layers_inplace), so the old cache-sized xs/ys
-    # double-buffer is gone (r4 it OOMed llama-3-8b past B=48); what
+    # Residual decode-chunk temp of the DENSE layout: the layer scan carries
+    # the cache (transformer._scan_layers_inplace), so the old cache-sized
+    # xs/ys double-buffer is gone (r4 it OOMed llama-3-8b past B=48); what
     # remains live is the current layer's read slice + its updated copy.
+    # The paged layout has no such term: its scan addresses the pool by
+    # (layer, page) and forms no per-layer entry (0 there).
     scan_buffer_bytes: int = 0
     # kv_bound slice+splice peak: a decode chunk at a SLICED bound copies
     # the cache's first `bound` columns out and back (engine._decode_chunk),
@@ -195,8 +196,7 @@ class ServingMemoryPlan:
             host += self._weight_load_suffix()
             return (
                 f"weights {self.weights_bytes / gib:.2f}GiB + "
-                f"page-pool {self.page_pool_bytes / gib:.2f}GiB "
-                f"(+{self.scan_buffer_bytes / gib:.2f}GiB layer slices) + "
+                f"page-pool {self.page_pool_bytes / gib:.2f}GiB + "
                 f"fused-prefill {self.fused_prefill_bytes / gib:.2f}GiB + "
                 f"verify-chunk {self.verify_chunk_bytes / gib:.2f}GiB + "
                 f"{self._agentic_summary()}"
@@ -375,8 +375,9 @@ def plan_serving_memory(
             cache_bytes=0,
             long_cache_bytes=0,  # paged segments write straight into pages
             workspace_bytes=workspace_bytes,
-            # 2 layer slices (read + updated copy) live inside the step scan
-            scan_buffer_bytes=2 * pool_bytes // max(config.n_layers, 1),
+            # the scan reads and writes the pool where it lies: the step's
+            # program holds nothing of a layer's size beside the pool
+            scan_buffer_bytes=0,
             bound_slice_bytes=0,  # the table IS the bound — no slice/splice
             fused_prefill_bytes=_tree_bytes(fused_shape) if fused_shape else 0,
             prefix_pool_bytes=0,  # aliasing shares the one pool

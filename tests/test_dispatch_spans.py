@@ -314,8 +314,9 @@ def lowered_scopes(fn, *args) -> set:
 
 def test_lowered_programs_carry_every_scope(dense_params, moe_params):
     """The union over a dense and a MoE model of the admission path
-    (prefill + page scatter + first-token sample) and the paged decode
-    chunk names every scope of the vocabulary — and each model's own."""
+    (prefill + page scatter + first-token sample), the paged decode chunk
+    and the dense-layout decode step names every scope of the vocabulary —
+    and each model's own."""
     page, b = 16, 2
     tokens = jnp.ones((b, 32), jnp.int32)
     lengths = jnp.asarray([32, 9])
@@ -344,13 +345,28 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
         texts[name] = lowered_scopes(admit, params, pool) | lowered_scopes(
             decode, params, pool
         )
-    assert set(T.SCOPES) <= texts["dense"] | texts["moe"]
+    # the dense layout's scan slices its layer's cache entry out of the carry
+    # and back: the one place `kv_pool.read` is left
+    dense_step = lowered_scopes(
+        lambda p, c: T.decode_step_inplace(p, tokens[:, 0], lengths, c, DENSE),
+        dense_params, T.make_kv_cache(DENSE, b, 64),
+    )
+    assert {"kv_pool.read", "kv_pool.write"} <= dense_step
+    assert set(T.SCOPES) <= texts["dense"] | texts["moe"] | dense_step
     assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
     assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",
             "moe_ffn.combine"} <= texts["moe"] and "ffn" not in texts["moe"]
     decode_only = lowered_scopes(decode, moe_params, T.make_page_pool(MOE, 8, page))
-    assert {"embed", "attention", "moe_ffn", "kv_pool.read", "kv_pool.write", "head",
+    assert {"embed", "attention", "moe_ffn", "kv_pool.write", "head",
             "sample"} <= decode_only
+    # the paged scan reads the pool where it lies: nothing is sliced out
+    assert "kv_pool.read" not in decode_only
+    # and the new rows' scatter, the pool's only write, is not under `attention`
+    text = jax.jit(decode).lower(moe_params, T.make_page_pool(MOE, 8, page)).as_text(
+        debug_info=True
+    )
+    writes = [loc for loc in re.findall(r'loc\("([^"]+)"', text) if "kv_pool.write" in loc]
+    assert writes and not any("attention" in loc.split("/") for loc in writes)
 
 
 def test_hot_loop_cost_of_dispatch_spans_and_annotations(dense_params):

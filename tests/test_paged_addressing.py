@@ -1,0 +1,157 @@
+"""The paged layer scan addresses the pool by (layer, page) and forms no
+per-layer entry. Here, against the way it was: a reference that slices each
+layer's entry [P, Hkv, ps, D] out of the pool, scatters and gathers on that
+entry, and writes the entry back must give the same logits and the same
+pool, bit for bit — the arithmetic is the same, on the same bytes."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from langstream_tpu.models import transformer as T
+from langstream_tpu.models.configs import MODEL_PRESETS
+
+PS, PAGES, TP, B = 8, 12, 3, 4  # page size, pool pages, table length, rows
+OOB = PAGES  # the table's unmapped sentinel
+# Row 0 is live. Row 1 is a free slot: position 0 behind a cleared table.
+# Row 2's position lies past its (fully mapped) table: `_page_index` turns
+# it into the sentinel, which must DROP, not land on the next layer's page
+# 0. Row 3 is padding with an all-out-of-bounds table. Pages 0, 4, 8-11 are
+# in no row's table.
+TABLE = np.array(
+    [[3, 1, OOB], [OOB, OOB, OOB], [5, 2, 7], [OOB, OOB, OOB]], np.int32
+)
+POSITIONS = np.array([11, 0, TP * PS + 3, 5], np.int32)
+UNMAPPED = sorted(set(range(PAGES)) - set(TABLE[TABLE < OOB].tolist()))
+
+
+# -- the reference: the per-entry addressing, as it was before ---------------
+
+
+def _entry_scatter(entry, vals, table, positions, page_size):
+    num_pages = (entry["q"] if isinstance(entry, dict) else entry).shape[0]
+    pages, offs = T._page_index(table, positions, page_size, num_pages)
+    pidx, oidx = pages[:, None, :], offs[:, None, :]
+    hidx = jnp.arange(vals.shape[1])[None, :, None]
+    if isinstance(entry, dict):
+        q, s = T._quantize_kv(vals)
+        return {
+            "q": entry["q"].at[pidx, hidx, oidx].set(q, mode="drop"),
+            "s": entry["s"].at[pidx, hidx, oidx].set(s, mode="drop"),
+        }
+    return entry.at[pidx, hidx, oidx].set(vals.astype(entry.dtype), mode="drop")
+
+
+def _entry_gather(entry, table, page_size):
+    def gather(a):
+        b, tp = table.shape
+        g = jnp.moveaxis(jnp.take(a, table, axis=0, mode="clip"), 2, 1)
+        return g.reshape((b, a.shape[1], tp * page_size) + a.shape[3:])
+
+    return jax.tree.map(gather, entry)
+
+
+def _sliced_scatter(pool, layer, vals, table, positions, page_size):
+    entry = jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), pool
+    )
+    entry = _entry_scatter(entry, vals, table, positions, page_size)
+    return jax.tree.map(
+        lambda a, n: lax.dynamic_update_index_in_dim(a, n, layer, 0), pool, entry
+    )
+
+
+def _sliced_gather(pool, layer, table, page_size):
+    entry = jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), pool
+    )
+    return _entry_gather(entry, table, page_size)
+
+
+# -- the three entry points ---------------------------------------------------
+
+
+def _decode(params, pool, config):
+    tokens = jnp.asarray([7, 0, 9, 0], jnp.int32)
+    return T.paged_decode_step_inplace(
+        params, tokens, jnp.asarray(POSITIONS), pool, jnp.asarray(TABLE), config, PS
+    )
+
+
+def _verify(params, pool, config):
+    tokens = jnp.asarray(np.arange(B * 3).reshape(B, 3) % 50 + 1, jnp.int32)
+    # row 2 starts two columns before its table's end: its last token drops
+    positions = jnp.asarray(POSITIONS).at[2].set(TP * PS - 2)
+    return T.paged_verify_step_inplace(
+        params, tokens, positions, pool, jnp.asarray(TABLE), config, PS
+    )
+
+
+def _segment(params, pool, config):
+    w = 8
+    tokens = jnp.asarray(np.arange(B * w).reshape(B, w) % 60 + 1, jnp.int32)
+    # row 0 fills its second page from mid-page; row 2's segment straddles
+    # the end of its table; rows 1 and 3 are padding (length 0)
+    offsets = jnp.asarray([PS + 3, 0, TP * PS - 3, 0], jnp.int32)
+    seg_lengths = jnp.asarray([w, 0, w, 0], jnp.int32)
+    return T.paged_prefill_segment_inplace(
+        params, tokens, offsets, seg_lengths, pool, jnp.asarray(TABLE), config, PS
+    )
+
+
+ENTRY_POINTS = {"decode": _decode, "verify": _verify, "segment": _segment}
+
+
+def _random_pool(config, key):
+    """A pool with something in every page, so an untouched page shows."""
+    shapes = jax.eval_shape(lambda: T.make_page_pool(config, PAGES, PS))
+    leaves, tree = jax.tree.flatten(shapes)
+    out = []
+    for leaf, k in zip(leaves, jax.random.split(key, len(leaves))):
+        if leaf.dtype == jnp.int8:
+            out.append(jax.random.randint(k, leaf.shape, -127, 128, jnp.int8))
+        else:
+            scale = 0.02 if leaf.ndim == 4 else 1.0  # int8 scales are small
+            out.append(
+                (jax.random.uniform(k, leaf.shape, jnp.float32) * scale + 0.001)
+                .astype(leaf.dtype)
+            )
+    return jax.tree.unflatten(tree, out)
+
+
+@pytest.mark.parametrize("entry", sorted(ENTRY_POINTS))
+@pytest.mark.parametrize("kv", ["model", "int8"])
+@pytest.mark.parametrize("preset", ["tiny-test", "tiny-moe-test"])
+def test_pool_addressed_in_place_matches_per_entry_reference(
+    preset, kv, entry, monkeypatch
+):
+    config = dataclasses.replace(
+        MODEL_PRESETS[preset], n_layers=3, kv_cache_dtype=kv, attention_impl="jnp"
+    )
+    params = T.init_params(config, jax.random.PRNGKey(0))
+    pool = _random_pool(config, jax.random.PRNGKey(1))
+    before = jax.tree.map(np.asarray, pool)
+    run = ENTRY_POINTS[entry]
+
+    logits, new_pool = jax.jit(lambda p, c: run(p, c, config))(params, pool)
+    with monkeypatch.context() as m:
+        m.setattr(T, "_paged_scatter", _sliced_scatter)
+        m.setattr(T, "_paged_gather", _sliced_gather)
+        ref_logits, ref_pool = jax.jit(lambda p, c: run(p, c, config))(params, pool)
+
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+    assert np.isfinite(np.asarray(logits)).all()
+    for got, want, was in zip(
+        jax.tree.leaves(new_pool), jax.tree.leaves(ref_pool), jax.tree.leaves(before)
+    ):
+        got = np.asarray(got)
+        np.testing.assert_array_equal(got, np.asarray(want))
+        # no row maps them: page 0 (where a sentinel folded into a flat
+        # [L*P] index would land, one layer on) and the rest, in every layer
+        np.testing.assert_array_equal(got[:, UNMAPPED], was[:, UNMAPPED])
+        # the live row did write: its page differs in every layer
+        assert all((got[l, TABLE[0, 1]] != was[l, TABLE[0, 1]]).any() for l in range(3))

@@ -224,6 +224,8 @@ def test_pages_for_fraction_and_plan_term():
     assert plan.bound_slice_bytes == 0  # the ladder's slice peak is gone
     assert plan.long_cache_bytes == 0  # segments write straight into pages
     assert plan.prefix_pool_bytes == 0  # aliasing shares the one pool
+    # the paged scan forms no per-layer entry beside the pool
+    assert plan.scan_buffer_bytes == 0
     dense = plan_serving_memory(CFG, 4, 128)
     # dense parity + 25% alias headroom, in page-granular arithmetic
     assert plan.page_pool_bytes == dense.cache_bytes * 10 // 8

@@ -7,6 +7,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from langstream_tpu.models.configs import MODEL_PRESETS, ModelConfig
 from langstream_tpu.models.transformer import (
@@ -252,17 +253,32 @@ def test_fused_segment_decode_batch_matches_both_references():
         )
 
 
-def test_ragged_paged_decode_matches_gathered_reference():
+def _gather_entry(entry, table, ps):
+    """The gathered view of ONE layer's entry [P, Hkv, ps(, D)], as the
+    paged path formed it before it addressed the pool by (layer, page)."""
+    b, tp = table.shape
+    g = jnp.moveaxis(jnp.take(entry, table, axis=0, mode="clip"), 2, 1)
+    return g.reshape((b, entry.shape[1], tp * ps) + entry.shape[3:])
+
+
+# (layers in the pool, the layer the call reads): the kernels take the whole
+# pool and a layer index; every other layer holds other values
+PAGED_LAYERS = [(1, 0), (3, 2), (3, 0)]
+
+
+@pytest.mark.parametrize("n_layers,layer", PAGED_LAYERS)
+def test_ragged_paged_decode_matches_gathered_reference(n_layers, layer):
     """The ragged-paged decode kernel (interpret mode) must match the
-    gathered masked-jnp view bit-for-bit-ish: same pages, same logical
-    order, same mask — the kernel only changes WHERE the read happens."""
-    from langstream_tpu.models.transformer import _paged_gather_entry
+    gathered masked-jnp view bit-for-bit-ish: same layer, same pages, same
+    logical order, same mask — the kernel only changes WHERE the read
+    happens."""
+    from langstream_tpu.models.transformer import _paged_gather
     from langstream_tpu.ops.attention import ragged_paged_decode_attention
 
     b, h, hkv, d, ps, pages, tp = 3, 8, 4, 8, 8, 16, 4
     q = rand(0, b, h, d)
-    k = rand(1, pages, hkv, ps, d)
-    v = rand(2, pages, hkv, ps, d)
+    k = rand(1, n_layers, pages, hkv, ps, d)
+    v = rand(2, n_layers, pages, hkv, ps, d)
     # ragged tables: unmapped entries carry the OOB sentinel (= pages)
     table = jnp.asarray(
         np.array(
@@ -271,42 +287,95 @@ def test_ragged_paged_decode_matches_gathered_reference():
         )
     )
     lengths = jnp.asarray([13, 26, 5], jnp.int32)
-    k_all = _paged_gather_entry(k, table, ps)
-    v_all = _paged_gather_entry(v, table, ps)
+    k_all = _gather_entry(k[layer], table, ps)
+    v_all = _gather_entry(v[layer], table, ps)
+    # the jnp fallback's one gather through (layer, table) is that view
+    np.testing.assert_array_equal(
+        np.asarray(_paged_gather(k, jnp.int32(layer), table, ps)), np.asarray(k_all)
+    )
     mask = jnp.arange(tp * ps)[None, None, :] < lengths[:, None, None]
     ref = attention(q[:, None], k_all, v_all, mask, CFG)[:, 0]
-    out = ragged_paged_decode_attention(
-        q, k, v, lengths, table, CFG, ps, interpret=True
-    )
+    out = jax.jit(
+        lambda l: ragged_paged_decode_attention(
+            q, k, v, lengths, table, l, CFG, ps, interpret=True
+        )
+    )(jnp.int32(layer))  # traced, as the layer scan hands it down
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
 
 
-def test_ragged_paged_decode_int8_matches_dequantized_reference():
+@pytest.mark.parametrize("n_layers,layer", PAGED_LAYERS)
+def test_ragged_paged_decode_int8_matches_dequantized_reference(n_layers, layer):
     """int8 paged kernel vs attention over the dequantized gathered view.
     Like the dense int8 ragged kernel, q stays full-precision in the
     kernel (the jnp int8 path re-quantizes q), so the comparison is
     against the dequantized-K/V reference with a quantization tolerance."""
-    from langstream_tpu.models.transformer import _paged_gather_entry
     from langstream_tpu.ops.attention import ragged_paged_decode_attention_int8
 
     b, h, hkv, d, ps, pages, tp = 2, 8, 4, 8, 8, 8, 3
+    shape = (n_layers, pages, hkv, ps)
     q = rand(0, b, h, d)
-    kq = jax.random.randint(jax.random.PRNGKey(1), (pages, hkv, ps, d), -127, 127, jnp.int8)
-    ks = jax.random.uniform(jax.random.PRNGKey(2), (pages, hkv, ps)) * 0.05 + 0.01
-    vq = jax.random.randint(jax.random.PRNGKey(3), (pages, hkv, ps, d), -127, 127, jnp.int8)
-    vs = jax.random.uniform(jax.random.PRNGKey(4), (pages, hkv, ps)) * 0.05 + 0.01
+    kq = jax.random.randint(jax.random.PRNGKey(1), shape + (d,), -127, 127, jnp.int8)
+    ks = jax.random.uniform(jax.random.PRNGKey(2), shape) * 0.05 + 0.01
+    vq = jax.random.randint(jax.random.PRNGKey(3), shape + (d,), -127, 127, jnp.int8)
+    vs = jax.random.uniform(jax.random.PRNGKey(4), shape) * 0.05 + 0.01
     k = {"q": kq, "s": ks}
     v = {"q": vq, "s": vs}
     table = jnp.asarray(np.array([[2, 0, pages], [5, 4, 1]], np.int32))
     lengths = jnp.asarray([11, 22], jnp.int32)
 
-    def dense(entry):
-        g = _paged_gather_entry(entry, table, ps)
+    def dense(pool):
+        g = {n: _gather_entry(a[layer], table, ps) for n, a in pool.items()}
         return g["q"].astype(jnp.float32) * g["s"][..., None]
 
     mask = jnp.arange(tp * ps)[None, None, :] < lengths[:, None, None]
     ref = attention(q[:, None], dense(k), dense(v), mask, CFG)[:, 0]
-    out = ragged_paged_decode_attention_int8(
-        q, k, v, lengths, table, CFG, ps, interpret=True
-    )
+    out = jax.jit(
+        lambda l: ragged_paged_decode_attention_int8(
+            q, k, v, lengths, table, l, CFG, ps, interpret=True
+        )
+    )(jnp.int32(layer))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_ragged_paged_decode_under_a_model_mesh_matches_one_device(int8):
+    """Under `config.kernel_mesh` the paged kernels shard_map themselves
+    over the pool's kv heads, which lie on axis 2 of [L, P, Hkv, ps(, D)]:
+    each of the four shards runs the kernel on its own head, and the
+    stitched output is the unsharded kernel's."""
+    from jax.sharding import Mesh
+
+    from langstream_tpu.ops.attention import (
+        ragged_paged_decode_attention,
+        ragged_paged_decode_attention_int8,
+    )
+    from langstream_tpu.parallel.mesh import AXIS_ORDER
+
+    b, h, hkv, d, ps, pages, layers = 2, 8, 4, 8, 8, 8, 2
+    shape = (layers, pages, hkv, ps)
+    q = rand(0, b, h, d)
+    if int8:
+        kernel = ragged_paged_decode_attention_int8
+        k, v = (
+            {
+                "q": jax.random.randint(jax.random.PRNGKey(n), shape + (d,), -127, 127, jnp.int8),
+                "s": jax.random.uniform(jax.random.PRNGKey(n + 1), shape) * 0.05 + 0.01,
+            }
+            for n in (1, 3)
+        )
+    else:
+        kernel = ragged_paged_decode_attention
+        k, v = rand(1, *shape, d), rand(2, *shape, d)
+    table = jnp.asarray(np.array([[2, 0, pages], [5, 4, 1]], np.int32))
+    lengths = jnp.asarray([11, 22], jnp.int32)
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 1, 1, 4), AXIS_ORDER)
+
+    def run(config):
+        return jax.jit(
+            lambda l: kernel(q, k, v, lengths, table, l, config, ps, interpret=True)
+        )(jnp.int32(1))
+
+    np.testing.assert_allclose(
+        np.asarray(run(dataclasses.replace(CFG, kernel_mesh=mesh))),
+        np.asarray(run(CFG)), atol=1e-6,
+    )

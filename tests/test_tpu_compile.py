@@ -24,6 +24,7 @@ SDS = jax.ShapeDtypeStruct
 GEMMA = MODEL_PRESETS["gemma-2b"]
 LLAMA = MODEL_PRESETS["llama-3-8b"]
 PAGE, PAGES, TABLE, BATCH = 64, 2048, 32, 192
+POOL_LAYERS = 2  # the kernels take the whole pool and a layer index
 
 
 @pytest.fixture(scope="module")
@@ -69,17 +70,18 @@ def _dense_args(config, s, t, int8):
 
 
 def _paged_args(config, int8):
-    """(q, k, v, lengths, table) shapes of a paged decode call."""
+    """(q, k, v, lengths, table, layer) shapes of a paged decode call."""
     h, hkv, d = config.n_heads, config.n_kv_heads, config.resolved_head_dim
     q = SDS((BATCH, h, d), jnp.bfloat16)
+    pool = (POOL_LAYERS, PAGES, hkv, PAGE)
     if int8:
-        kv = {
-            "q": SDS((PAGES, hkv, PAGE, d), jnp.int8),
-            "s": SDS((PAGES, hkv, PAGE), jnp.float32),
-        }
+        kv = {"q": SDS(pool + (d,), jnp.int8), "s": SDS(pool, jnp.float32)}
     else:
-        kv = SDS((PAGES, hkv, PAGE, d), jnp.bfloat16)
-    return q, kv, kv, SDS((BATCH,), jnp.int32), SDS((BATCH, TABLE), jnp.int32)
+        kv = SDS(pool + (d,), jnp.bfloat16)
+    return (
+        q, kv, kv, SDS((BATCH,), jnp.int32), SDS((BATCH, TABLE), jnp.int32),
+        SDS((), jnp.int32),
+    )
 
 
 def _prefill(config, s):
@@ -103,7 +105,9 @@ def _paged(config, int8):
         else A.ragged_paged_decode_attention
     )
     return (
-        lambda q, k, v, lens, table: fn(q, k, v, lens, table, config, PAGE),
+        lambda q, k, v, lens, table, layer: fn(
+            q, k, v, lens, table, layer, config, PAGE
+        ),
         _paged_args(config, int8),
     )
 
@@ -172,9 +176,9 @@ def test_model_sharded_paged_decode_compiles_for_four_chips(v5e):
     def on(*spec):
         return NamedSharding(mesh, P(*spec))
 
-    pool = page_pool_specs(config.n_kv_heads, mesh)[1:]  # one layer's entry
+    pool = page_pool_specs(config.n_kv_heads, mesh)
     kv = {"q": on(*pool), "s": on(*pool[:-1])}
-    shardings = (on(None, "model", None), kv, kv, on(), on())
+    shardings = (on(None, "model", None), kv, kv, on(), on(), on())
     compiled = jax.jit(fn).lower(*_placed(args, shardings)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
@@ -182,8 +186,132 @@ def test_model_sharded_paged_decode_compiles_for_four_chips(v5e):
     # independent per kv head: the shard_map body needs no collective
     assert "all-reduce" not in text and "all-gather" not in text
     # each chip holds a quarter of the pool (k and v: int8 values + scales)
-    pool_bytes = 2 * PAGES * config.n_kv_heads * PAGE * (config.resolved_head_dim + 4)
+    pool_bytes = (
+        2 * POOL_LAYERS * PAGES * config.n_kv_heads * PAGE
+        * (config.resolved_head_dim + 4)
+    )
     assert compiled.memory_analysis().argument_size_in_bytes < 1.1 * pool_bytes / 4
+
+
+# ---------------------------------------------------------------------------
+# The paged decode-side PROGRAMS: the layer scan reads and writes the pool
+# where it lies. A per-layer entry [P, Hkv, ps, D] sliced out of the scan's
+# carry and written back was two real copies a layer on the chip (39.7% of a
+# Mistral-7B decode step, PERF.md section 6, PR 25); only the compiled
+# program says whether one is there.
+# ---------------------------------------------------------------------------
+
+# llama's attention (8 kv heads of 128: the kernel's tiling, a 4-way head
+# split), narrow and shallow elsewhere so a whole program compiles in seconds
+STEP_CFG = dataclasses.replace(
+    LLAMA, name="llama-attn-narrow", vocab_size=2048, d_model=1024, d_ff=2048,
+    n_layers=3, n_heads=8, n_kv_heads=8, head_dim=128,
+)
+STEP_PAGES, STEP_TABLE, STEP_BATCH = 48, 4, 16
+
+
+def _step_program(program: str, config, page):
+    """(jitted engine program, its arguments' shapes) at STEP_* sizes."""
+    from langstream_tpu.models.transformer import init_params, make_page_pool
+    from langstream_tpu.serving import engine as E
+
+    b = STEP_BATCH
+    params = jax.eval_shape(lambda k: init_params(config, k), SDS((2,), jnp.uint32))
+    pool = jax.eval_shape(lambda: make_page_pool(config, STEP_PAGES, page))
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    key = SDS((2,), jnp.uint32)
+    if program == "_paged_decode_chunk":
+        args = (params, i32(b), i32(b), pool, i32(b, STEP_TABLE), key,
+                f32(b), i32(b), f32(b))
+        static = (4, config, page)  # steps
+    elif program == "_paged_verify_chunk":
+        args = (params, i32(b), i32(b), pool, i32(b, STEP_TABLE), key,
+                f32(b), i32(b), f32(b), i32(b, 3))
+        static = (config, page)
+    else:
+        args = (params, i32(1, 128), i32(1), i32(1), pool, i32(1, STEP_TABLE), key,
+                f32(1), i32(1), f32(1))
+        static = (config, page)
+    return getattr(E, program), args, static
+
+
+def _pool_shapes(config, page):
+    """(per-layer entry shapes, whole-pool shapes) as HLO prints them, for
+    every leaf of the pool: values and, in int8, scales."""
+    hkv, d = config.n_kv_heads, config.resolved_head_dim
+    entry = [f"[{STEP_PAGES},{hkv},{page},{d}]"]
+    if config.kv_cache_dtype == "int8":
+        entry.append(f"[{STEP_PAGES},{hkv},{page}]")
+    return entry, [f"[{config.n_layers},{e[1:]}" for e in entry]
+
+
+def _compile_as_on_chip(monkeypatch, fn, args, static):
+    """Lower with the gates a chip process passes (`paged_pallas_ok`'s
+    "auto" and the kernels' `interpret=` ask `jax.default_backend()`, which
+    here still says cpu: the test steers it, the program has no knob)."""
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = fn.lower(*args, *static)
+    return lowered.compile()
+
+
+STEP_PROGRAMS = ("_paged_decode_chunk", "_paged_verify_chunk", "_paged_segment_and_sample")
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+@pytest.mark.parametrize("program", STEP_PROGRAMS)
+def test_paged_program_holds_no_per_layer_pool_entry(v5e, monkeypatch, program, kv):
+    config = dataclasses.replace(STEP_CFG, kv_cache_dtype=kv)
+    fn, args, static = _step_program(program, config, PAGE)
+    one_chip = SingleDeviceSharding(v5e[0])
+    text = _compile_as_on_chip(monkeypatch, fn, _placed(args, one_chip), static).as_text()
+    entry_shapes, pool_shapes = _pool_shapes(config, PAGE)
+    for line in text.splitlines():
+        bare = line
+        for shape in pool_shapes:
+            bare = bare.replace(shape, "")
+        assert not any(e in bare for e in entry_shapes), line.strip()[:300]
+    # no copy of the VALUES' pool. The int8 pool's scale leaf is the open
+    # end (ops/attention._paged_decode_int8_kernel, PERF.md section 7 d):
+    # the chip keeps f32[L, P, Hkv, ps < 128] pages-minor and the custom
+    # call wants it row-major
+    assert not re.search(rf"= \w+{re.escape(pool_shapes[0])}\S* copy\(", text)
+    if program == "_paged_decode_chunk":
+        kernel = "ragged_paged_decode_attention" + ("_int8" if kv == "int8" else "")
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text)
+        t = STEP_TABLE * PAGE
+        assert A.attention_paths()[f"paged-decode[s=1,t={t}]"] == kernel
+    else:
+        assert "tpu_custom_call" not in text  # verify and segments: jnp
+
+
+def test_model_sharded_paged_decode_chunk_holds_no_pool_entry(v5e, monkeypatch):
+    """The same on the four-chip `model`-sharded mesh: the kernel's
+    shard_map splits the pool's kv heads where they now lie (axis 2)."""
+    import numpy as np
+
+    from langstream_tpu.parallel.sharding import _kv_entry_specs, param_specs
+
+    mesh = Mesh(np.array(v5e).reshape(1, 1, 1, 4), AXIS_ORDER)
+    config = dataclasses.replace(STEP_CFG, kv_cache_dtype="int8", kernel_mesh=mesh)
+    fn, args, static = _step_program("_paged_decode_chunk", config, PAGE)
+    named = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
+    entry = _kv_entry_specs(page_pool_specs(config.n_kv_heads, mesh), True)
+    is_spec = lambda x: isinstance(x, P)  # noqa: E731
+    shardings = (
+        jax.tree.map(named, param_specs(config), is_leaf=is_spec),
+        named(P()), named(P()),
+        jax.tree.map(named, {"k": entry, "v": entry}, is_leaf=is_spec),
+    ) + (named(P()),) * 5
+    compiled = _compile_as_on_chip(monkeypatch, fn, _placed(args, shardings), static)
+    text = compiled.as_text()
+    assert re.search(r"%ragged_paged_decode_attention_int8(\.\d+)? = ", text)
+    hkv, d = config.n_kv_heads // 4, config.resolved_head_dim
+    local_entry = f"[{STEP_PAGES},{hkv},{PAGE},{d}]"
+    local_pool = f"[{config.n_layers},{STEP_PAGES},{hkv},{PAGE},{d}]"
+    assert local_pool in text  # each chip holds a quarter of the heads
+    assert all(local_entry not in l.replace(local_pool, "") for l in text.splitlines())
+    assert not re.search(rf"= \w+{re.escape(local_pool)}\S* copy\(", text)
 
 
 def test_mesh_that_does_not_divide_kv_heads_keeps_the_jnp_path():
