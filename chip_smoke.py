@@ -86,7 +86,8 @@ def kernel_checks(
     jax.numpy reference of tests/test_pallas_ops.py (``attention`` over an
     explicit mask, in float32 at "highest" matmul precision; int8 caches
     dequantized first). ``segment`` = (S, T); ``paged`` = (B, page, pages
-    per row). Returns one ``{kernel, max_abs_err}`` per check."""
+    per row): rows of unequal length, every third one inactive. Returns
+    one ``{kernel, max_abs_err}`` per check."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -94,6 +95,7 @@ def kernel_checks(
     from langstream_tpu.models.transformer import (
         _dequantize_kv,
         _paged_gather,
+        _paged_lengths,
         _quantize_kv,
         attention,
     )
@@ -162,7 +164,19 @@ def kernel_checks(
     # unmapped tail pages carry the out-of-bounds sentinel, as in the engine
     used = -(-lengths // page)
     table[np.arange(per_row)[None, :] >= used[:, None]] = pages
-    lengths, table = jnp.asarray(lengths), jnp.asarray(table)
+    # every third row is INACTIVE as a decode dispatch leaves one: the
+    # table cleared to the sentinel, the device's position stale and still
+    # advancing. The kernels get their lengths by the caller's rule (what
+    # the table maps caps the position), which makes those rows 0 long:
+    # they must come back zeros, and the others unmoved by them
+    positions = lengths - 1
+    idle = np.arange(1, b - 1, 3)
+    table[idle] = pages
+    positions[idle] = rng.integers(page, per_row * page, len(idle))
+    table = jnp.asarray(table)
+    lengths = _paged_lengths(table, jnp.asarray(positions), page, pages)
+    assert (np.asarray(lengths)[idle] == 0).all() and int(lengths[-1]) == per_row * page
+    live = (lengths > 0)[:, None]
     q = rand(b, h, d)
     mask = jnp.arange(per_row * page)[None, None, :] < lengths[:, None, None]
     # the kernels read the pool [L, P, Hkv, ps, D] where it lies, at a
@@ -174,10 +188,10 @@ def kernel_checks(
         ragged_paged_decode_attention(
             q, kp, vp, lengths, table, layer, config, page, interpret=interpret
         ),
-        reference(
+        jnp.where(live, reference(
             q[:, None], _paged_gather(kp, layer, table, page),
             _paged_gather(vp, layer, table, page), mask,
-        )[:, 0],
+        )[:, 0], 0.0),
     )
     kp8, vp8 = (dict(zip("qs", _quantize_kv(x))) for x in (kp, vp))
     check(
@@ -186,12 +200,12 @@ def kernel_checks(
             q, kp8, vp8, lengths, table, layer, config, page,
             interpret=interpret,
         ),
-        reference(
+        jnp.where(live, reference(
             q[:, None],
             _dequantize_kv(_paged_gather(kp8, layer, table, page), jnp.float32),
             _dequantize_kv(_paged_gather(vp8, layer, table, page), jnp.float32),
             mask,
-        )[:, 0],
+        )[:, 0], 0.0),
     )
     return out
 

@@ -282,6 +282,21 @@ def _page_index(table: jax.Array, positions: jax.Array, page_size: int,
     return pages, positions % page_size
 
 
+def _paged_lengths(table: jax.Array, positions: jax.Array, page_size: int,
+                   num_pages: int) -> jax.Array:
+    """The live length [B] of each row of a decode step, for the paged
+    kernel: the position being written plus one, capped by what the row's
+    table MAPS (a row's pages are a prefix of its table; the rest carry the
+    sentinel). The device's positions advance for every row with every
+    step, so without the cap an inactive, padding or warm-up row (table all
+    sentinel) would read as long as its stale position says, and a row that
+    steps past its reservation inside a chunk would keep growing; with it
+    the first has length 0 and the second stops where its pages do. The
+    kernel does work only for a length's pages."""
+    mapped = (table < num_pages).sum(axis=1, dtype=jnp.int32)
+    return jnp.minimum(positions.astype(jnp.int32) + 1, mapped * page_size)
+
+
 def _paged_scatter(pool, layer, vals: jax.Array, table: jax.Array,
                    positions: jax.Array, page_size: int):
     """Scatter per-token K/V ``vals`` [B, Hkv, S, D] into the pool
@@ -656,7 +671,10 @@ def _attention_block(
     with jax.named_scope("attention"):
         t = paged_table.shape[1] * page_size
         if s == 1 and paged_pallas_ok(config, page_size):
-            lengths = cache_positions[:, 0] + 1
+            num_pages = (pk["q"] if isinstance(pk, dict) else pk).shape[1]
+            lengths = _paged_lengths(
+                paged_table, cache_positions[:, 0], page_size, num_pages
+            )
             kernel = (
                 ragged_paged_decode_attention_int8 if isinstance(pk, dict)
                 else ragged_paged_decode_attention

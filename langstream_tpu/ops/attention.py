@@ -858,113 +858,182 @@ def ragged_decode_attention_int8(
 
 # ---------------------------------------------------------------------------
 # Ragged PAGED decode: one query per row against a page-table-indexed KV
-# pool [P, Hkv, page_size, D] (arxiv 2502.10490 "Ragged Paged Attention" —
-# the paper's block layout: per-slot sequence lengths index pages through a
-# table, the last page clamps, (8,128) tiling on the (page_size, D) trailing
-# dims, model-dtype/int8 MXU dots with f32 accumulation). The grid is
-# (B, table_len) with every kv head inside the block — the same fat-block
-# shape that made the dense int8 ragged kernel competitive (r5: a per-head
-# grid had 8× the steps and lost) — and the index map DMAs exactly the
-# slot's mapped pages: block j loads physical page table[b, j], clamped to
-# the last valid page past the row's length so Pallas elides the HBM→VMEM
-# copy. HBM traffic therefore scales with CONTENT (sum of lengths), and no
-# kv_bound ladder is needed: the table IS the bound, one compiled program
-# for every sequence-length mix. The kernels take the WHOLE pool
-# [L, P, Hkv, ps, D] and a layer index, so the caller's layer scan never
-# slices a per-layer entry out of the pool to hand one over (a custom
-# call's operand is materialised: that slice was 39.7% of a chat decode
-# step, PERF.md §6 PR 25). The layer costs the kernel nothing: the pool is
-# seen as [L·P, ...] (merging two major dimensions moves no byte) and the
-# layer's offset is added to the table before the call, so the index maps
-# do per grid step what they did for one layer's entry (a third
-# scalar-prefetch operand and a (layer, page) block index read 561 against
-# 557 us a call, my chip runs, PR 25). The masked-jnp fallback (gather
-# through (layer, table), then the stock attention math) lives in
-# models/transformer._paged_gather and carries tier-1 exactness.
+# pool [P, Hkv, page_size, D] (arxiv 2502.10490 "Ragged Paged Attention":
+# per-slot sequence lengths index pages through a table, (8,128) tiling on
+# the (page_size, D) trailing dims, f32 accumulation). What the kernel
+# EXECUTES follows the live pages, not the table's width: the grid is over
+# rows only, the pool's values stay in HBM, and a row walks its own
+# `cdiv(length, page_size)` pages in a loop inside the kernel. The fetches
+# (page table[b, j], every kv head of it, one fat block, by async copy into
+# `_PAGE_SLOTS` VMEM slots) run ahead of the arithmetic along the batch's
+# live pages taken as ONE sequence: over a row's end into the next row
+# that holds anything, so a row's first page is there when its grid step
+# starts. A row of length 0 (the caller gives one to every row whose table
+# maps nothing: inactive, padding, warm-up; models/transformer
+# `_paged_lengths`) costs its grid step, some 0.3 us, and
+# returns zeros. Before PR 28 the grid was (B, table_len): 0.20 us for
+# every table entry of every row whatever the rows held, with the fetch
+# (not the step) elided past a row's length, so bytes scaled with content
+# and time did not (PERF.md §5). No kv_bound ladder is needed: the table
+# IS the bound, one compiled program for every sequence-length mix. The
+# kernels take the WHOLE pool [L, P, Hkv, ps, D] and a layer index, so the
+# caller's layer scan never slices a per-layer entry out of the pool to
+# hand one over (a custom call's operand is materialised: that slice was
+# 39.7% of a chat decode step, PERF.md §6 PR 25). The layer costs the
+# kernel nothing: the pool is seen as [L·P, ...] (merging two major
+# dimensions moves no byte) and the layer's offset is added to the table
+# before the call. The bf16 and the int8 kernel are ONE skeleton
+# (`_paged_decode_kernel`) with two page loads (`_page_bf16`,
+# `_page_int8`). The masked-jnp fallback (gather through (layer, table),
+# then the stock attention math) lives in models/transformer._paged_gather
+# and carries tier-1 exactness.
 # ---------------------------------------------------------------------------
+
+
+def _page_bf16(q, page, scales, j, scale):
+    """Scores of one page and its values: ``page`` is the (k, v) pair of
+    VMEM blocks [Hkv, ps, D] an iteration waited for."""
+    k, v = (leaf.astype(jnp.float32) for leaf in page)
+    s = jax.lax.dot_general(
+        q, k, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [Hkv, G, ps]
+    return s, None, v
+
+
+def _page_int8(q, page, scales, j, scale):
+    """`_page_bf16` over the int8 pool: a page is (kq, vq), read raw int8
+    from HBM, with the row's per-token f32 scales (ks, vs) [1, Tp, Hkv, ps]
+    beside it, page ``j`` of them. The scales ride the [.., ps]-shaped
+    scores and probabilities (tokens on lanes, as the pool stores them),
+    not the [.., ps, D] operands: the same product, D times less scale
+    math."""
+    kq, vq = page
+    ks, vs = (ref[0, j][:, None, :] for ref in scales)  # [Hkv, 1, ps]
+    s = jax.lax.dot_general(
+        q, kq.astype(jnp.float32),
+        dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * scale * ks
+    return s, vs, vq.astype(jnp.float32)
+
+
+# Pages of K and V held in VMEM at once: the one computed on and the next
+# three live pages of the batch in flight behind it (1 MB of bf16 at 8 kv
+# heads x 64 x 128). One page in flight left the copy's latency in the
+# open: 196 us a call at two slots, 146 at three, 140 at four (decode
+# drain's shape: 64 rows, 330 live pages; my chip runs, PR 28).
+_PAGE_SLOTS = 4
 
 
 def _paged_decode_kernel(
     lengths_ref,  # scalar-prefetch [B]
-    table_ref,  # scalar-prefetch [B * Tp] flattened page table
+    pages_ref,  # scalar-prefetch [B * Tp]: the flattened table, layer added
     q_ref,  # [1, Hkv, G, D]
-    k_ref,  # [1, Hkv, ps, D] — ONE physical page, all kv heads
-    v_ref,  # [1, Hkv, ps, D]
-    o_ref,  # [1, Hkv, G, D]
-    m_scr,  # [Hkv, G, 128] f32
-    l_scr,  # [Hkv, G, 128] f32
-    acc_scr,  # [Hkv, G, D] f32
-    *,
+    *refs,  # n_scales row blocks, n_leaves pool leaves in HBM, o_ref, scratch
+    load,  # _page_bf16 | _page_int8
+    n_scales: int,
+    n_leaves: int,
     page_size: int,
+    table_len: int,
     scale: float,
     softcap,
 ):
+    scales, refs = refs[:n_scales], refs[n_scales:]
+    pool, o_ref = refs[:n_leaves], refs[n_leaves]
+    bufs = refs[n_leaves + 1: 2 * n_leaves + 1]  # per leaf [_PAGE_SLOTS, a page]
+    sems, walk = refs[2 * n_leaves + 1:]
     b = pl.program_id(0)
-    j = pl.program_id(1)  # logical page index
-    nk = pl.num_programs(1)
+    nb = pl.num_programs(0)
+
+    def pages_of(row):
+        length = lengths_ref[jnp.minimum(row, nb - 1)]
+        return jnp.minimum(pl.cdiv(length, page_size), table_len)
+
+    def copies(page, slot):
+        return [
+            pltpu.make_async_copy(src.at[page], buf.at[slot], sems.at[i, slot])
+            for i, (src, buf) in enumerate(zip(pool, bufs))
+        ]
+
+    # The batch's live pages form one sequence, row after row, and the
+    # fetches run ahead of the arithmetic along it, across the rows' ends
+    # and over rows that hold nothing: `walk` (SMEM, carried from one grid
+    # step to the next: the grid runs in order on one core) holds how many
+    # pages were computed on, how many fetched, and the row and the page
+    # the next fetch is at. The n-th page of the sequence lands in slot
+    # n % _PAGE_SLOTS.
+    computed, fetched, at_row, at_page = range(4)
+
+    def fetch_next():
+        row, j = jax.lax.while_loop(  # over the rows that are exhausted
+            lambda at: (at[0] < nb) & (at[1] >= pages_of(at[0])),
+            lambda at: (at[0] + 1, jnp.int32(0)),
+            (walk[at_row], walk[at_page]),
+        )
+
+        @pl.when(row < nb)
+        def _start():
+            page = pages_ref[row * table_len + j]
+            for copy in copies(page, walk[fetched] % _PAGE_SLOTS):
+                copy.start()
+            walk[fetched] = walk[fetched] + 1
+
+        walk[at_row] = row
+        walk[at_page] = j + 1
+
+    @pl.when(b == 0)
+    def _first_row():
+        for i in range(4):
+            walk[i] = 0
+        for _ in range(_PAGE_SLOTS - 1):
+            fetch_next()
+
     length = lengths_ref[b]
-    k_start = j * page_size
+    q = q_ref[0].astype(jnp.float32)  # [Hkv, G, D]
+    hkv, group, d = q.shape
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(k_start < length)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)  # [Hkv, G, D]
-        k = k_ref[0].astype(jnp.float32)  # [Hkv, ps, D]
-        v = v_ref[0].astype(jnp.float32)
-        s = (
-            jax.lax.dot_general(
-                q,
-                k,
-                dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [Hkv, G, ps]
+    def body(j, carry):
+        m_prev, l_prev, acc = carry  # [Hkv, G, 1] twice, [Hkv, G, D]
+        fetch_next()
+        slot = walk[computed] % _PAGE_SLOTS
+        for copy in copies(0, slot):  # a wait names the slot, not the page
+            copy.wait()
+        walk[computed] = walk[computed] + 1
+        s, p_scale, v = load(q, [buf[slot] for buf in bufs], scales, j, scale)
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size), 2)
-        s = jnp.where(k_pos < length, s, _NEG)
+        k_pos = j * page_size + jax.lax.broadcasted_iota(
+            jnp.int32, (1, 1, page_size), 2
+        )
+        s = jnp.where(k_pos < length, s, _NEG)  # the last page's tail
 
-        m_prev = m_scr[:, :, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, :, None])
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(s <= _NEG, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[:, :, 0] = l_scr[:, :, 0] * corr + p.sum(axis=-1)
         pv = jax.lax.dot_general(
-            p,
+            p if p_scale is None else p * p_scale,
             v,
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )  # [Hkv, G, D]
-        acc_scr[...] = acc_scr[...] * corr[:, :, None] + pv
-        m_scr[:, :, 0] = m_new
+        return m_new, l_prev * corr + p.sum(axis=-1, keepdims=True), acc * corr + pv
 
-    @pl.when(j == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:, :, 0], 1e-30)[:, :, None]
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-
-
-def _paged_kv_index(page_size: int, table_len: int):
-    """Index map factory for page-pool blocks: grid step (b, j) loads the
-    page ``table[b, j]``, with j clamped to the row's last valid logical
-    page (re-referencing the same block elides the DMA — the ragged
-    bandwidth saving). ``table`` is `_layer_pages`': in range already."""
-
-    def kv_index(b, j, lens, table):
-        last = jnp.maximum(pl.cdiv(lens[b], page_size) - 1, 0)
-        return (table[b * table_len + jnp.minimum(j, last)], 0, 0, 0)
-
-    return kv_index
+    _, l, acc = jax.lax.fori_loop(
+        0, pages_of(b), body,
+        (
+            jnp.full((hkv, group, 1), _NEG, jnp.float32),
+            jnp.zeros((hkv, group, 1), jnp.float32),
+            jnp.zeros((hkv, group, d), jnp.float32),
+        ),
+    )
+    # a row of no pages: acc 0 over the floor of l, zeros and not NaN
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_q_index(b, j, lens, table):
+def _paged_row_index(b, lens, pages):
+    """Row ``b``'s block of q, of the output and of the int8 scales."""
     return (b, 0, 0, 0)
 
 
@@ -983,146 +1052,85 @@ def _flat_pool(leaf: jax.Array) -> jax.Array:
     return leaf.reshape((-1,) + leaf.shape[2:])
 
 
+def _paged_decode_call(
+    name: str, load, q: jax.Array, leaves: list, scales: list,
+    lengths: jax.Array, table: jax.Array, layer: jax.Array,
+    config: ModelConfig, page_size: int, interpret: bool,
+) -> jax.Array:
+    """The one `pallas_call` of both paged kernels. ``leaves`` are the
+    pool's arrays [L, P, Hkv, ps, D] whose pages the kernel fetches itself;
+    ``scales`` are the int8 pool's [L, P, Hkv, ps], which reach it as each
+    row's own [Tp, Hkv, ps] block, gathered through (layer, table) here: a
+    page of them is [Hkv, ps < 128] f32, which Mosaic cannot slice out of
+    HBM (the minor dimension is narrower than a tile), and they are 1/32 of
+    the pool's bytes."""
+    b, h, d = q.shape
+    tp = table.shape[1]
+    hkv = leaves[0].shape[2]
+    group = h // hkv
+    kernel = functools.partial(
+        _paged_decode_kernel,
+        load=load,
+        n_scales=len(scales),
+        n_leaves=len(leaves),
+        page_size=page_size,
+        table_len=tp,
+        scale=1.0 / (d**0.5),
+        softcap=config.attn_logit_softcap,
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, hkv, group, d), _paged_row_index)]
+        + [pl.BlockSpec((1, tp, hkv, page_size), _paged_row_index)] * len(scales)
+        + [pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)] * len(leaves),
+        out_specs=pl.BlockSpec((1, hkv, group, d), _paged_row_index),
+        scratch_shapes=[
+            pltpu.VMEM((_PAGE_SLOTS,) + leaf.shape[2:], leaf.dtype)
+            for leaf in leaves
+        ] + [
+            pltpu.SemaphoreType.DMA((len(leaves), _PAGE_SLOTS)),
+            pltpu.SMEM((4,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        name=name,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
+        # rows in order on one core: the walk along the batch's live pages
+        # is carried from one row to the next
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(
+        lengths.astype(jnp.int32),
+        _layer_pages(table, layer, leaves[0].shape[1]),
+        q.reshape(b, hkv, group, d),
+        *(leaf.at[layer, table].get(mode="clip") for leaf in scales),
+        *(_flat_pool(leaf) for leaf in leaves),
+    )
+    return out.reshape(b, h * d)
+
+
 @_per_kv_head(3, kv_head_axis=2)
 def ragged_paged_decode_attention(
     q: jax.Array,  # [B, H, D] single query per row
     k: jax.Array,  # the page pool [L, P, Hkv, ps, D], read at `layer`
     v: jax.Array,
-    lengths: jax.Array,  # [B] valid logical columns per row
+    lengths: jax.Array,  # [B] valid logical columns per row; 0 = no work
     table: jax.Array,  # [B, Tp] physical page per logical page
     layer: jax.Array,  # scalar: which layer of the pool
     config: ModelConfig,
     page_size: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """GQA paged decode attention over one layer of the pool → [B, H*D]."""
-    b, h, d = q.shape
-    hkv = k.shape[2]
-    tp = table.shape[1]
-    group = h // hkv
-    qg = q.reshape(b, hkv, group, d)
-    pages = _layer_pages(table, layer, k.shape[1])
-
-    kernel = functools.partial(
-        _paged_decode_kernel,
-        page_size=page_size,
-        scale=1.0 / (d**0.5),
-        softcap=config.attn_logit_softcap,
+    """GQA paged decode attention over one layer of the pool → [B, H*D].
+    A row reads its first ``cdiv(length, page_size)`` table entries and no
+    other; a row of length 0 comes back zeros."""
+    return _paged_decode_call(
+        "ragged_paged_decode_attention", _page_bf16, q, [k, v], [], lengths,
+        table, layer, config, page_size, interpret,
     )
-    kv_index = _paged_kv_index(page_size, tp)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, tp),
-        in_specs=[
-            pl.BlockSpec((1, hkv, group, d), _paged_q_index),
-            pl.BlockSpec((1, hkv, page_size, d), kv_index),
-            pl.BlockSpec((1, hkv, page_size, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, hkv, group, d), _paged_q_index),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, group, 128), jnp.float32),
-            pltpu.VMEM((hkv, group, 128), jnp.float32),
-            pltpu.VMEM((hkv, group, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="ragged_paged_decode_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        pages,
-        qg,
-        _flat_pool(k),
-        _flat_pool(v),
-    )
-    return out.reshape(b, h * d)
-
-
-def _paged_decode_int8_kernel(
-    lengths_ref,  # scalar-prefetch [B]
-    table_ref,  # scalar-prefetch [B * Tp]
-    q_ref,  # [1, Hkv, G, D]
-    kq_ref,  # [1, Hkv, ps, D] int8 — one physical page
-    ks_ref,  # [1, Hkv, ps] f32 per-token scales
-    vq_ref,  # [1, Hkv, ps, D] int8
-    vs_ref,  # [1, Hkv, ps] f32
-    o_ref,  # [1, Hkv, G, D]
-    m_scr,
-    l_scr,
-    acc_scr,
-    *,
-    page_size: int,
-    scale: float,
-    softcap,
-):
-    """_paged_decode_kernel over the int8 pool: pages read raw int8 from
-    HBM (+f32 scales), dequantized in VMEM — the same wire format as the
-    dense int8 ragged kernel, per page instead of per cache block. The
-    scales come as the pool holds them, [.., Hkv, ps]: a trailing unit
-    dimension on the whole pool's scales is a relayout of all of them
-    (f32[L,P,Hkv,ps,1] pads 128-fold: 4 GB at 32 layers × 512 pages).
-    Still owed (PERF.md §7 d): a v5e keeps f32[L, P, Hkv, ps < 128] with
-    the PAGES minor while a custom call's operand is row-major, so each
-    layer's call still relays the scale leaves (33.5 MB each at that size;
-    sandbox AOT, PR 25) — the int8 values, 128 times the bytes, are read
-    where they lie."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nk = pl.num_programs(1)
-    length = lengths_ref[b]
-    k_start = j * page_size
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(k_start < length)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)
-        k = kq_ref[0].astype(jnp.float32)  # [Hkv, ps, D]
-        v = vq_ref[0].astype(jnp.float32)
-        # the per-token scales ride the [.., ps]-shaped scores and
-        # probabilities (tokens on lanes, as the pool stores them), not the
-        # [.., ps, D] operands: the same product, D times less scale math
-        s = (
-            jax.lax.dot_general(
-                q,
-                k,
-                dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-            * ks_ref[0][:, None, :]
-        )
-        if softcap is not None:
-            s = jnp.tanh(s / softcap) * softcap
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page_size), 2)
-        s = jnp.where(k_pos < length, s, _NEG)
-
-        m_prev = m_scr[:, :, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, :, None])
-        p = jnp.where(s <= _NEG, 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:, :, 0] = l_scr[:, :, 0] * corr + p.sum(axis=-1)
-        pv = jax.lax.dot_general(
-            p * vs_ref[0][:, None, :],
-            v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[...] = acc_scr[...] * corr[:, :, None] + pv
-        m_scr[:, :, 0] = m_new
-
-    @pl.when(j == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:, :, 0], 1e-30)[:, :, None]
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @_per_kv_head(3, kv_head_axis=2)
@@ -1138,57 +1146,12 @@ def ragged_paged_decode_attention_int8(
     interpret: bool = False,
 ) -> jax.Array:
     """GQA paged decode attention over one layer of the int8 page pool →
-    [B, H*D]."""
-    b, h, d = q.shape
-    hkv = k["q"].shape[2]
-    tp = table.shape[1]
-    group = h // hkv
-    qg = q.reshape(b, hkv, group, d)
-    pages = _layer_pages(table, layer, k["q"].shape[1])
-
-    kernel = functools.partial(
-        _paged_decode_int8_kernel,
-        page_size=page_size,
-        scale=1.0 / (d**0.5),
-        softcap=config.attn_logit_softcap,
+    [B, H*D]: the bf16 kernel's skeleton with the int8 page load."""
+    return _paged_decode_call(
+        "ragged_paged_decode_attention_int8", _page_int8, q,
+        [k["q"], v["q"]], [k["s"], v["s"]], lengths, table, layer, config,
+        page_size, interpret,
     )
-    kv_index = _paged_kv_index(page_size, tp)
-
-    def scale_index(*args):
-        return kv_index(*args)[:-1]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, tp),
-        in_specs=[
-            pl.BlockSpec((1, hkv, group, d), _paged_q_index),
-            pl.BlockSpec((1, hkv, page_size, d), kv_index),
-            # Mosaic wants a block's last two dims (8,128)-divisible or
-            # equal to the array's: (Hkv, ps) are the scales' own
-            pl.BlockSpec((1, hkv, page_size), scale_index),
-            pl.BlockSpec((1, hkv, page_size, d), kv_index),
-            pl.BlockSpec((1, hkv, page_size), scale_index),
-        ],
-        out_specs=pl.BlockSpec((1, hkv, group, d), _paged_q_index),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, group, 128), jnp.float32),
-            pltpu.VMEM((hkv, group, 128), jnp.float32),
-            pltpu.VMEM((hkv, group, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="ragged_paged_decode_attention_int8",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        pages,
-        qg,
-        *(_flat_pool(leaf) for leaf in (k["q"], k["s"], v["q"], v["s"])),
-    )
-    return out.reshape(b, h * d)
 
 
 def paged_pallas_ok(config: ModelConfig, page_size: int) -> bool:

@@ -7153,6 +7153,8 @@ class ServingEngine:
             steps=steps, active_rows=len(live),
             kv_tokens_read=self._kv_tokens_read(live, steps),
             clean=clean, pipelined=pipelined,
+            **({"kv_pages_visited": self._kv_pages_visited(steps)}
+               if self._paged else {}),
         )
         with jax.profiler.TraceAnnotation(
             "engine.decode_chunk", seq=self._dispatch_seq, steps=steps
@@ -7185,6 +7187,24 @@ class ServingEngine:
             steps * (slot.position + slot.ahead + 1) + steps * (steps - 1) // 2
             for slot in live
         )
+
+    def _kv_pages_visited(self, steps: int) -> int:
+        """Page iterations the paged decode kernel runs, per layer, in one
+        dispatch: over its ``steps`` and its active rows, the pages of the
+        row's live length at that step, `_kv_tokens_read`'s lengths capped
+        by what the row's table maps at dispatch (a row that steps past its
+        reservation inside the chunk stops growing: models/transformer
+        `_paged_lengths`). Inactive rows have no table and visit none."""
+        rows = [i for i, slot in enumerate(self._slots) if slot.active]
+        pool = self._pagepool
+        first = np.asarray(
+            [self._slots[i].position + self._slots[i].ahead + 1 for i in rows],
+            np.int64,
+        )
+        mapped = (pool.tables[rows] != pool.oob).sum(axis=1)
+        lengths = first[:, None] + np.arange(steps)[None, :]
+        pages = np.minimum(-(-lengths // self.page_size), mapped[:, None])
+        return int(pages.sum())
 
     def _collect_stale(self) -> list[int]:
         """Slots freed since the last dispatch whose device temperature

@@ -91,6 +91,10 @@ def test_one_span_per_group_and_chunk_with_work_counts(dense_params, paged):
         assert a["program"] == ("_paged_decode_chunk" if paged else "_decode_chunk")
         assert a["steps"] >= 1 and 1 <= a["active_rows"] <= 3
         assert a["kv_tokens_read"] >= a["steps"] * a["active_rows"]
+        # the paged kernel's page iterations; the dense layout has no pages
+        assert ("kv_pages_visited" in a) == paged
+        if paged:
+            assert a["steps"] * a["active_rows"] <= a["kv_pages_visited"] <= a["kv_tokens_read"]
         assert "moe_routed" not in a  # a dense model fetches no counts
     # device time per request class is a join: prefill child -> its group
     by_seq = {g["attributes"]["seq"]: g for g in groups}
@@ -140,6 +144,81 @@ def test_kv_tokens_read_matches_a_hand_count(dense_params):
     # step j of a row whose token is written at position p attends p+1+j keys
     assert chunks[0]["attributes"]["kv_tokens_read"] == lengths(3 + 1)
     assert chunks[1]["attributes"]["kv_tokens_read"] == lengths(3 + 8 + 1) + lengths(2 + 1)
+
+
+def test_kv_pages_visited_matches_a_hand_count(dense_params):
+    """The same two requests over pages of 8 tokens: A (3 tokens, 60 to
+    come: 8 pages reserved) decodes alone in chunk 1; B (2 tokens, 5 to
+    come: ONE page reserved) joins for chunk 2 and steps past its
+    reservation inside it, where its length, and so the kernel's walk,
+    stops at the page it has."""
+    TRACER.clear()
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=3, max_seq_len=128, decode_chunk=8,
+        overlap=True, kv_layout="paged", page_size=8,
+    )
+    pending: deque = deque()
+    engine.submit(GenerationRequest(
+        prompt_tokens=[4, 5, 6],
+        options=GenerationOptions(max_new_tokens=60, temperature=0.0),
+    ))
+    engine._iterate(pending)
+    engine.submit(GenerationRequest(
+        prompt_tokens=[7, 8],
+        options=GenerationOptions(max_new_tokens=5, temperature=0.0),
+    ))
+    engine._iterate(pending)
+    mapped = sorted((engine._pagepool.tables != engine._pagepool.oob).sum(axis=1))
+    drain(engine, pending)
+    assert mapped == [0, 1, 8]  # a free slot, B, A
+    chunks = sorted(spans_named("engine.decode_chunk"), key=lambda s: s["attributes"]["seq"])
+    assert [c["attributes"]["active_rows"] for c in chunks[:2]] == [1, 2]
+    pages = lambda first, cap: sum(  # noqa: E731
+        min(math.ceil((first + j) / 8), cap) for j in range(8)
+    )
+    assert pages(4, 8) == 5 * 1 + 3 * 2 and pages(3, 1) == 8 < pages(3, 8) == 10
+    assert chunks[0]["attributes"]["kv_pages_visited"] == pages(4, 8)
+    assert chunks[1]["attributes"]["kv_pages_visited"] == pages(12, 8) + pages(3, 1)
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+def test_a_chunk_with_idle_and_finished_rows_is_token_exact_through_the_kernel(
+    dense_params, kv
+):
+    """Six slots, three requests of unequal caps: every decode chunk
+    carries rows without a table (never admitted: length 0 for the kernel)
+    and, from the shortest request's end on, rows that finished inside an
+    earlier chunk while their device positions run on. The kernel path
+    (interpret mode) must deliver the jnp path's tokens for every
+    request."""
+    def tokens(impl):
+        config = dataclasses.replace(DENSE, attention_impl=impl, kv_cache_dtype=kv)
+        engine = ServingEngine(
+            config, dense_params, max_batch=6, max_seq_len=64, decode_chunk=8,
+            prefill_buckets=(16,), kv_layout="paged", page_size=8,
+        )
+        engine.start()
+        try:
+            reqs = [
+                engine.submit(GenerationRequest(
+                    prompt_tokens=[11 + 3 * i] * (4 + 5 * i),
+                    options=GenerationOptions(max_new_tokens=cap, temperature=0.0),
+                ))
+                for i, cap in enumerate((5, 19, 30))
+            ]
+            return [r.result(timeout=300).tokens for r in reqs]
+        finally:
+            engine.stop()
+
+    from langstream_tpu.ops.attention import attention_paths
+
+    got = tokens("pallas")
+    assert any(
+        k.startswith("paged-decode[s=1,t=64]") and v.startswith("ragged_paged_decode")
+        for k, v in attention_paths().items()
+    )
+    assert [len(t) for t in got] == [5, 19, 30]
+    assert got == tokens("jnp")
 
 
 def test_nothing_is_emitted_with_observability_off(dense_params):

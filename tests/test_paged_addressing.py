@@ -155,3 +155,79 @@ def test_pool_addressed_in_place_matches_per_entry_reference(
         np.testing.assert_array_equal(got[:, UNMAPPED], was[:, UNMAPPED])
         # the live row did write: its page differs in every layer
         assert all((got[l, TABLE[0, 1]] != was[l, TABLE[0, 1]]).any() for l in range(3))
+
+
+# -- the decode kernel's lengths: what the table maps (PR 28) -----------------
+
+LENGTH_CASES = {
+    # name: (table row, position, the kernel's length)
+    "live-mid-page": ([3, 1, OOB], 11, 12),
+    "live-page-boundary": ([3, 1, OOB], PS - 1, PS),
+    "live-first-column-of-a-page": ([3, 1, OOB], PS, PS + 1),
+    "free-slot-stale-position": ([OOB, OOB, OOB], 2 * PS + 5, 0),
+    "padding-row": ([OOB, OOB, OOB], 0, 0),
+    "past-its-reservation": ([3, OOB, OOB], PS + 4, PS),
+    "reserved-ahead-of-the-position": ([3, 1, 7], 2, 3),
+    "full-table": ([5, 2, 7], TP * PS - 1, TP * PS),
+    "past-the-table": ([5, 2, 7], TP * PS + 3, TP * PS),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LENGTH_CASES))
+def test_a_decode_rows_length_is_what_its_table_maps(case):
+    row, position, want = LENGTH_CASES[case]
+    got = T._paged_lengths(
+        jnp.asarray([row], jnp.int32), jnp.asarray([position], jnp.int32), PS, PAGES
+    )
+    assert got.dtype == jnp.int32 and got.tolist() == [want]
+
+
+@pytest.mark.parametrize("kv", ["model", "int8"])
+@pytest.mark.parametrize("preset", ["tiny-test", "tiny-moe-test"])
+def test_decode_through_the_kernel_matches_the_jnp_path_on_live_rows(preset, kv):
+    """`paged_decode_step_inplace` with the kernel forced (interpret mode)
+    beside the masked-jnp path, on a batch with a free slot behind a stale
+    position, a padding row and a row whose position has left its table:
+    the same writes to the pool, finite logits everywhere (an inactive row
+    reads nothing, so no garbage page can reach it), and the live rows'
+    logits the jnp path's (MoE: rows share expert capacity, so a dead row's
+    zeros against the jnp path's garbage may route differently; the dense
+    model's rows are independent)."""
+    config = dataclasses.replace(
+        MODEL_PRESETS[preset], n_layers=3, kv_cache_dtype=kv, dtype="float32",
+        moe_capacity_factor=0.0,
+    )
+    params = T.init_params(config, jax.random.PRNGKey(0))
+    pool = _random_pool(config, jax.random.PRNGKey(1))
+    table = TABLE.copy()
+    positions = jnp.asarray(POSITIONS).at[1].set(2 * PS + 1)  # stale, not 0
+
+    def run(impl):
+        cfg = dataclasses.replace(config, attention_impl=impl)
+        tokens = jnp.asarray([7, 0, 9, 0], jnp.int32)
+        return jax.jit(lambda p, c: T.paged_decode_step_inplace(
+            p, tokens, positions, c, jnp.asarray(table), cfg, PS
+        ))(params, pool)
+
+    from langstream_tpu.ops.attention import attention_paths
+
+    logits, new_pool = run("pallas")
+    assert any(
+        k.startswith("paged-decode") and v.startswith("ragged_paged_decode_attention")
+        for k, v in attention_paths().items()
+    )
+    ref_logits, ref_pool = run("jnp")
+    # layer 0's rows are computed before any attention: the same bytes;
+    # later layers' follow the attention's output, which is close, not equal
+    for got, want in zip(jax.tree.leaves(new_pool), jax.tree.leaves(ref_pool)):
+        np.testing.assert_array_equal(np.asarray(got)[0], np.asarray(want)[0])
+    logits, ref_logits = np.asarray(logits), np.asarray(ref_logits)
+    assert np.isfinite(logits).all()
+    # rows 0 (live) and 2 (its whole table, position past it). The int8
+    # jnp path quantises q and the kernel does not (tests/test_pallas_ops.py
+    # holds the int8 kernel to the dequantised reference): over row 2's 24
+    # columns of random int8 that alone moves a logit by more than 1
+    if kv == "int8":
+        np.testing.assert_allclose(logits[0], ref_logits[0], atol=0.1)
+    else:
+        np.testing.assert_allclose(logits[[0, 2]], ref_logits[[0, 2]], atol=2e-4)

@@ -337,6 +337,138 @@ def test_ragged_paged_decode_int8_matches_dequantized_reference(n_layers, layer)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
 
 
+# -- the kernel does work only for the pages that are live (PR 28) -----------
+
+RAGGED_PS, RAGGED_PAGES, RAGGED_TP = 8, 24, 4
+
+
+def _ragged_pool(int8: bool, n_layers: int):
+    """A pool whose LAST page no table names and which holds NaN (inf for
+    the int8 scales): the page a clamped sentinel entry reads. A kernel
+    that computes on a page past a row's length, even fully masked
+    (0 × NaN), shows it."""
+    shape = (n_layers, RAGGED_PAGES, 4, RAGGED_PS)
+    if not int8:
+        k, v = (rand(n, *shape, 8).at[:, -1].set(jnp.nan) for n in (1, 2))
+        return k, v, lambda pool: pool
+    k, v = (
+        {
+            "q": jax.random.randint(jax.random.PRNGKey(n), shape + (8,), -127, 127, jnp.int8),
+            "s": (jax.random.uniform(jax.random.PRNGKey(n + 1), shape) * 0.05 + 0.01)
+            .at[:, -1].set(jnp.inf),
+        }
+        for n in (1, 3)
+    )
+    return k, v, lambda pool: pool["q"].astype(jnp.float32) * pool["s"][..., None]
+
+
+def _ragged_table(pages_per_row):
+    """Row r maps its first ``pages_per_row[r]`` table entries to pages no
+    other row holds; the rest carry the sentinel."""
+    oob = RAGGED_PAGES
+    table = np.full((len(pages_per_row), RAGGED_TP), oob, np.int32)
+    free = iter(np.random.default_rng(0).permutation(RAGGED_PAGES - 1))
+    for r, n in enumerate(pages_per_row):
+        table[r, :n] = [next(free) for _ in range(n)]
+    return table
+
+
+# name: (mapped pages per row, length per row). A row of length 0 has an
+# all-sentinel table, as `_dispatch_tables` leaves an inactive row.
+P_ = RAGGED_PS
+RAGGED_CASES = {
+    # live rows between, before and after rows without a table: every way a
+    # row's first page is started (by the row before it, or by itself)
+    "empty-rows-between": ([2, 0, 0, 3, 1, 0], [P_ + 5, 0, 0, 2 * P_ + 1, 3, 0]),
+    "empty-first-and-last": ([0, 4, 2, 0], [0, 3 * P_ + 7, P_ + 1, 0]),
+    "all-empty": ([0, 0, 0], [0, 0, 0]),
+    "one-live-row": ([0, 0, 3], [0, 0, 2 * P_ + 2]),
+    # lengths of exactly one page, on and just past a page boundary
+    "exactly-one-page": ([1, 1, 2], [P_, 1, P_ + 1]),
+    "page-boundaries": ([2, 3, 3, 1], [2 * P_, 2 * P_ + 1, 3 * P_, P_ - 1]),
+    # every row holds its whole table
+    "full-table": ([4, 4, 4], [4 * P_, 4 * P_, 4 * P_ - 1]),
+    # more pages mapped (reserved) than the length has reached: not read
+    "reserved-ahead": ([4, 3, 0, 2], [P_ + 2, 5, 0, P_]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RAGGED_CASES))
+@pytest.mark.parametrize("n_layers,layer", [(1, 0), (3, 1)])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_ragged_paged_decode_follows_the_live_pages(int8, n_layers, layer, case):
+    """Rows of length 0 (all-sentinel table) come back exact zeros, never
+    NaN, beside live rows that match the gathered float32 reference; no row
+    computes on a page past its length (the clamped sentinel's page holds
+    NaN)."""
+    from langstream_tpu.ops.attention import (
+        ragged_paged_decode_attention,
+        ragged_paged_decode_attention_int8,
+    )
+
+    mapped, lengths = RAGGED_CASES[case]
+    table = _ragged_table(mapped)
+    k, v, dense = _ragged_pool(int8, n_layers)
+    q = rand(0, len(lengths), 8, 8)
+    kernel = ragged_paged_decode_attention_int8 if int8 else ragged_paged_decode_attention
+    out = np.asarray(jax.jit(
+        lambda l: kernel(
+            q, k, v, jnp.asarray(lengths, jnp.int32), jnp.asarray(table), l, CFG,
+            RAGGED_PS, interpret=True,
+        )
+    )(jnp.int32(layer)))
+    assert np.isfinite(out).all()
+    live = [r for r, n in enumerate(lengths) if n > 0]
+    empty = [r for r, n in enumerate(lengths) if n == 0]
+    np.testing.assert_array_equal(out[empty], 0.0)
+    if live:
+        # the reference sees the live rows only, gathered through tables
+        # whose unread entries name a finite page
+        t = jnp.asarray(np.where(table[live] < RAGGED_PAGES, table[live], 0))
+        k_all = _gather_entry(dense(k)[layer], t, RAGGED_PS)
+        v_all = _gather_entry(dense(v)[layer], t, RAGGED_PS)
+        lens = jnp.asarray(lengths, jnp.int32)[jnp.asarray(live)]
+        mask = jnp.arange(RAGGED_TP * RAGGED_PS)[None, None, :] < lens[:, None, None]
+        ref = attention(q[jnp.asarray(live), None], k_all, v_all, mask, CFG)[:, 0]
+        np.testing.assert_allclose(out[live], np.asarray(ref), atol=1e-4 if int8 else 1e-5)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_a_row_past_its_mapped_pages_reads_only_the_mapped_ones(int8):
+    """The caller's length rule with the kernel: a position that has run
+    past what the table maps (a row stepping past its reservation inside a
+    chunk) reads the mapped pages and no other; a stale position behind a
+    cleared table reads nothing."""
+    from langstream_tpu.models.transformer import _paged_lengths
+    from langstream_tpu.ops.attention import (
+        ragged_paged_decode_attention,
+        ragged_paged_decode_attention_int8,
+    )
+
+    table = _ragged_table([2, 0, 4, 1])
+    positions = jnp.asarray([3 * P_ + 2, 2 * P_ + 5, 9 * P_, 4], jnp.int32)
+    lengths = _paged_lengths(jnp.asarray(table), positions, RAGGED_PS, RAGGED_PAGES)
+    assert lengths.tolist() == [2 * P_, 0, 4 * P_, 5]
+    k, v, dense = _ragged_pool(int8, 2)
+    q = rand(0, 4, 8, 8)
+    kernel = ragged_paged_decode_attention_int8 if int8 else ragged_paged_decode_attention
+    out = np.asarray(kernel(
+        q, k, v, lengths, jnp.asarray(table), jnp.int32(1), CFG, RAGGED_PS,
+        interpret=True,
+    ))
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[1], 0.0)
+    t = jnp.asarray(np.where(table < RAGGED_PAGES, table, 0))
+    mask = jnp.arange(RAGGED_TP * RAGGED_PS)[None, None, :] < lengths[:, None, None]
+    ref = attention(
+        q[:, None], _gather_entry(dense(k)[1], t, RAGGED_PS),
+        _gather_entry(dense(v)[1], t, RAGGED_PS), mask, CFG,
+    )[:, 0]
+    np.testing.assert_allclose(
+        out[[0, 2, 3]], np.asarray(ref)[[0, 2, 3]], atol=1e-4 if int8 else 1e-5
+    )
+
+
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 def test_ragged_paged_decode_under_a_model_mesh_matches_one_device(int8):
     """Under `config.kernel_mesh` the paged kernels shard_map themselves
@@ -351,7 +483,7 @@ def test_ragged_paged_decode_under_a_model_mesh_matches_one_device(int8):
     )
     from langstream_tpu.parallel.mesh import AXIS_ORDER
 
-    b, h, hkv, d, ps, pages, layers = 2, 8, 4, 8, 8, 8, 2
+    b, h, hkv, d, ps, pages, layers = 3, 8, 4, 8, 8, 8, 2
     shape = (layers, pages, hkv, ps)
     q = rand(0, b, h, d)
     if int8:
@@ -366,8 +498,11 @@ def test_ragged_paged_decode_under_a_model_mesh_matches_one_device(int8):
     else:
         kernel = ragged_paged_decode_attention
         k, v = rand(1, *shape, d), rand(2, *shape, d)
-    table = jnp.asarray(np.array([[2, 0, pages], [5, 4, 1]], np.int32))
-    lengths = jnp.asarray([11, 22], jnp.int32)
+    # a live row, a row without a table (length 0), a row with a full one
+    table = jnp.asarray(
+        np.array([[2, 0, pages], [pages, pages, pages], [5, 4, 1]], np.int32)
+    )
+    lengths = jnp.asarray([11, 0, 24], jnp.int32)
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 1, 1, 4), AXIS_ORDER)
 
     def run(config):
@@ -375,7 +510,6 @@ def test_ragged_paged_decode_under_a_model_mesh_matches_one_device(int8):
             lambda l: kernel(q, k, v, lengths, table, l, config, ps, interpret=True)
         )(jnp.int32(1))
 
-    np.testing.assert_allclose(
-        np.asarray(run(dataclasses.replace(CFG, kernel_mesh=mesh))),
-        np.asarray(run(CFG)), atol=1e-6,
-    )
+    sharded = np.asarray(run(dataclasses.replace(CFG, kernel_mesh=mesh)))
+    np.testing.assert_allclose(sharded, np.asarray(run(CFG)), atol=1e-6)
+    np.testing.assert_array_equal(sharded[1], 0.0)

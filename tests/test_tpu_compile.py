@@ -69,17 +69,17 @@ def _dense_args(config, s, t, int8):
     return q, kv, kv, SDS((1,), jnp.int32)
 
 
-def _paged_args(config, int8):
+def _paged_args(config, int8, batch=BATCH, table=TABLE, pages=PAGES, layers=POOL_LAYERS):
     """(q, k, v, lengths, table, layer) shapes of a paged decode call."""
     h, hkv, d = config.n_heads, config.n_kv_heads, config.resolved_head_dim
-    q = SDS((BATCH, h, d), jnp.bfloat16)
-    pool = (POOL_LAYERS, PAGES, hkv, PAGE)
+    q = SDS((batch, h, d), jnp.bfloat16)
+    pool = (layers, pages, hkv, PAGE)
     if int8:
         kv = {"q": SDS(pool + (d,), jnp.int8), "s": SDS(pool, jnp.float32)}
     else:
         kv = SDS(pool + (d,), jnp.bfloat16)
     return (
-        q, kv, kv, SDS((BATCH,), jnp.int32), SDS((BATCH, TABLE), jnp.int32),
+        q, kv, kv, SDS((batch,), jnp.int32), SDS((batch, table), jnp.int32),
         SDS((), jnp.int32),
     )
 
@@ -99,7 +99,7 @@ def _segment(config, s, t, int8):
     )
 
 
-def _paged(config, int8):
+def _paged(config, int8, **sizes):
     fn = (
         A.ragged_paged_decode_attention_int8 if int8
         else A.ragged_paged_decode_attention
@@ -108,8 +108,18 @@ def _paged(config, int8):
         lambda q, k, v, lens, table, layer: fn(
             q, k, v, lens, table, layer, config, PAGE
         ),
-        _paged_args(config, int8),
+        _paged_args(config, int8, **sizes),
     )
+
+
+# The benchmark's three cells (BENCHMARK.json; benchmark/workloads/*.json):
+# slots x table pages, the pool's pages, the layers. Mistral-7B and Mixtral
+# have llama-3-8b's attention (32 q / 8 kv heads of 128).
+CELLS = {
+    "chat64x20": dict(batch=64, table=20, pages=512, layers=32),
+    "docs16x33": dict(batch=16, table=33, pages=528, layers=32),
+    "drain64x10": dict(batch=64, table=10, pages=640, layers=6),
+}
 
 
 CASES = {
@@ -125,6 +135,8 @@ CASES = {
     "gemma-paged-decode-int8": _paged(GEMMA, True),
     "llama-paged-decode": _paged(LLAMA, False),
     "llama-paged-decode-int8": _paged(LLAMA, True),
+    **{f"{cell}-paged-decode": _paged(LLAMA, False, **sizes) for cell, sizes in CELLS.items()},
+    **{f"{cell}-paged-decode-int8": _paged(LLAMA, True, **sizes) for cell, sizes in CELLS.items()},
 }
 
 
@@ -271,11 +283,13 @@ def test_paged_program_holds_no_per_layer_pool_entry(v5e, monkeypatch, program, 
         for shape in pool_shapes:
             bare = bare.replace(shape, "")
         assert not any(e in bare for e in entry_shapes), line.strip()[:300]
-    # no copy of the VALUES' pool. The int8 pool's scale leaf is the open
-    # end (ops/attention._paged_decode_int8_kernel, PERF.md section 7 d):
-    # the chip keeps f32[L, P, Hkv, ps < 128] pages-minor and the custom
-    # call wants it row-major
-    assert not re.search(rf"= \w+{re.escape(pool_shapes[0])}\S* copy\(", text)
+    # no copy of the pool, values or (int8) scales: the decode kernel
+    # fetches the values' pages from HBM itself and takes the scales
+    # gathered through the table (before PR 28 the scale leaf was its
+    # operand, and the chip, which keeps f32[L, P, Hkv, ps < 128]
+    # pages-minor, relaid all of it row-major for every layer's call)
+    for shape in pool_shapes:
+        assert not re.search(rf"= \w+{re.escape(shape)}\S* copy\(", text)
     if program == "_paged_decode_chunk":
         kernel = "ragged_paged_decode_attention" + ("_int8" if kv == "int8" else "")
         assert re.search(rf"%{kernel}(\.\d+)? = ", text)
