@@ -80,12 +80,12 @@ class CacheCounts:
 
 
 def kernel_checks(
-    config, *, prefill_lens, segment, paged, interpret: bool = False
+    config, *, prefill_lens, paged, interpret: bool = False
 ) -> list[dict]:
     """Each main-path kernel at ``config``'s head widths against the
     jax.numpy reference of tests/test_pallas_ops.py (``attention`` over an
     explicit mask, in float32 at "highest" matmul precision; int8 caches
-    dequantized first). ``segment`` = (S, T); ``paged`` = (B, page, pages
+    dequantized first). ``paged`` = (B, page, pages
     per row): rows of unequal length, every third one inactive. Returns
     one ``{kernel, max_abs_err}`` per check."""
     import jax
@@ -101,7 +101,6 @@ def kernel_checks(
     )
     from langstream_tpu.ops.attention import (
         flash_prefill_attention,
-        flash_segment_attention_int8,
         ragged_paged_decode_attention,
         ragged_paged_decode_attention_int8,
     )
@@ -138,22 +137,6 @@ def kernel_checks(
             flash_prefill_attention(q, k, v, config, interpret=interpret),
             reference(q, k, v, causal),
         )
-
-    s, t = segment
-    q = rand(1, s, h, d)
-    k8, v8 = (dict(zip("qs", _quantize_kv(rand(1, hkv, t, d)))) for _ in "kv")
-    offset = jnp.asarray([t - s], jnp.int32)  # the last segment: reads all of T
-    q_pos = offset[:, None, None] + jnp.arange(s)[None, :, None]
-    check(
-        f"flash_segment_attention_int8[s={s},t={t}]",
-        flash_segment_attention_int8(
-            q, k8, v8, offset, config, interpret=interpret
-        ),
-        reference(
-            q, _dequantize_kv(k8, jnp.float32), _dequantize_kv(v8, jnp.float32),
-            jnp.arange(t)[None, None, :] <= q_pos,
-        ),
-    )
 
     b, page, per_row = paged
     pages = b * per_row
@@ -218,7 +201,7 @@ def kernels_phase() -> None:
     for name in ("gemma-2b", "llama-3-8b"):
         checks += kernel_checks(
             MODEL_PRESETS[name], prefill_lens=(512, 2048),
-            segment=(2048, 8192), paged=(32, 64, 32),
+            paged=(32, 64, 32),
         )
     emit(
         phase="kernels", compiled=True, err_bound=KERNEL_ERR_BOUND,
@@ -253,7 +236,7 @@ def _write_app(spec: ServeSpec, root: Path) -> tuple[Path, Path]:
     """The example application with this spec's tpu-serving resource: the
     example's own pipeline.yaml and gateways.yaml, a configuration.yaml that
     names the model, and everything the issue does not name left at its
-    default (kv-layout paged, attention-impl auto, precompile on a TPU)."""
+    default (attention-impl auto, precompile on a TPU)."""
     app = root / "app"
     app.mkdir()
     for name in ("pipeline.yaml", "gateways.yaml"):

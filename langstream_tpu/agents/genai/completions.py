@@ -76,7 +76,7 @@ class _BaseCompletionsStep(Step):
             "distinct device programs dispatched (growth after warmup = "
             "a mid-traffic XLA compile stall)",
         )
-        # prefix KV reuse (serving/prefix_cache.py) — all sourced from the
+        # prefix KV reuse (serving/pagepool.PrefixPageIndex) — all sourced from the
         # engine's cumulative stats, so gauges (not counters) carry them
         self._m_prefix_hit = metrics.gauge(
             "engine_prefix_cache_hit_rate",
@@ -95,7 +95,7 @@ class _BaseCompletionsStep(Step):
             "engine_prefix_cache_evictions_total",
             "prefix-cache LRU evictions (cumulative)",
         )
-        # self-speculative decoding (serving/engine.py _verify_chunk):
+        # self-speculative decoding (serving/engine.py _paged_verify_chunk):
         # engine-cumulative ratios, so gauges carry them like the prefix set
         self._m_spec_accept = metrics.gauge(
             "engine_spec_acceptance_rate",
@@ -115,17 +115,17 @@ class _BaseCompletionsStep(Step):
         # aliasing effectiveness, and the copy traffic aliasing eliminated
         self._m_kv_pages = metrics.gauge(
             "engine_kv_pages_in_use",
-            "physical KV pages currently allocated (paged layout; 0 dense)",
+            "physical KV pages currently allocated",
         )
         self._m_kv_alias = metrics.gauge(
             "engine_kv_page_alias_rate",
             "fraction of reserved KV pages satisfied by prefix aliasing "
-            "instead of fresh allocation (cumulative; 0 when dense)",
+            "instead of fresh allocation (cumulative)",
         )
         self._m_prefix_copy_saved = metrics.gauge(
             "engine_prefix_copy_bytes_saved_total",
-            "bytes of KV copy eliminated by page aliasing vs the dense "
-            "gather-per-hit design (cumulative)",
+            "bytes of KV copy eliminated by page aliasing against a "
+            "gather per hit (cumulative)",
         )
         # tiered KV: host-RAM spill + session hibernation (serving/
         # pagepool.HostPageTier, docs/SERVING.md §16) — arena occupancy,

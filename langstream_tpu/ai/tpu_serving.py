@@ -32,36 +32,32 @@ Resource configuration:
     the eager quantize_params pass (big models fall back to the
     host-staged eager path). Identical numerics either way
   max-batch / max-seq-len / prefill-buckets / decode-chunk: engine knobs
-  kv-layout: paged (default) | dense → KV memory layout. "paged" is the
-    unified page-table-indexed device pool (serving/pagepool.py): decode,
-    chunked prefill and speculative verify all attend through per-slot
-    page tables (ONE compiled program each — the kv_bound compile ladder
-    is gone), and prefix reuse aliases pages zero-copy. Legal under
-    multi-host SPMD (allocator events ride the leader→follower wire,
-    docs/SERVING.md §14) and sharded meshes (the pool shards kv heads on
-    "model"). "dense" is the per-slot big-cache layout, kept ONE release
-    as the escape hatch (it also carries the ring long-prefill path,
-    which paged does not speak yet). `page-size` (default 64 tokens)
-    sizes a page;
-    `kv-pages` overrides the pool's page count (default: dense-parity
-    capacity + `prefix-cache-fraction` alias headroom — see
-    docs/SERVING.md §11 for the memory-plan math and migration notes)
+  page-size / kv-pages: the engine's KV state is ONE page-table-indexed
+    device pool (serving/pagepool.py): decode, chunked prefill and
+    speculative verify all attend through per-slot page tables (ONE
+    compiled program each), and prefix reuse aliases pages zero-copy.
+    Legal under multi-host SPMD (allocator events ride the
+    leader→follower wire, docs/SERVING.md §14) and sharded meshes (the
+    pool shards kv heads on "model"). `page-size` (default 64 tokens)
+    sizes a page; `kv-pages` overrides the pool's page count (default:
+    every slot's max-seq-len + `prefix-cache-fraction` alias headroom —
+    see docs/SERVING.md §11 for the memory-plan math)
   overlap: true (default) → fused prefill–decode iterations (every device
     dispatch carries a token-budgeted slice of pending prefill work plus
     the decode chunk — the gateway-TTFT lever, PERF.md round 6)
   prefill-token-budget: prefill tokens per fused iteration (default: the
     chunked-prefill segment width = the largest prefill bucket)
-  max-prefill-streams: concurrent chunked-prefill local caches (default 2
-    with overlap, 1 without; each costs one long-prefill cache of HBM)
+  max-prefill-streams: concurrent chunked-prefill streams (default 2
+    with overlap, 1 without; each holds its reserved slot's pages)
   prefix-cache: auto | off (default off) → automatic cross-request prefix
-    KV reuse (serving/prefix_cache.py): shared prompt preambles prefill
-    once, later admissions gather the cached KV and prefill only the
-    suffix. `prefix-cache-fraction` (default 0.25) sizes the device pool
-    relative to the decode cache; `prefix-cache-entries` overrides the
-    row count directly (0 disables the pool entirely). The memory plan
+    KV reuse (serving/pagepool.PrefixPageIndex): shared prompt preambles
+    prefill once, later admissions alias the cached pages and prefill
+    only the suffix. `prefix-cache-fraction` (default 0.25) adds alias
+    headroom to the pool's default page count; `prefix-cache-entries`
+    caps the index (default 512; 0 disables reuse). The memory plan
     accounts the pool before warmup.
-  host-kv-fraction: tiered KV (docs/SERVING.md §16; paged layout +
-    prefix-cache only) — sizes a pinned host-RAM page arena relative to
+  host-kv-fraction: tiered KV (docs/SERVING.md §16; prefix-cache
+    only) — sizes a pinned host-RAM page arena relative to
     the device pool (e.g. 8.0 = 8× the pool in host RAM; 0, the default,
     disables the tier). Idle published prefixes spill into it off the hot
     loop (`spill-idle-s`, default 0 = as soon as published) and under HBM
@@ -73,11 +69,11 @@ Resource configuration:
     state: construction-disabled under SPMD (an explicit warning, like
     adapters in round 14).
   speculation: auto | off (default off) → self-speculative decoding
-    (serving/speculation.py + engine._verify_chunk): host-side n-gram
+    (serving/speculation.py + engine._paged_verify_chunk): host-side n-gram
     prompt-lookup drafts verified k+1-at-a-time in one device dispatch —
     one weight read emits up to k+1 tokens per slot on repetitive text.
     `speculation-tokens` (default 4) is k, fixed engine-wide (one compiled
-    verify ladder). Runs under SPMD too (drafts ride the wire, §14);
+    verify program). Runs under SPMD too (drafts ride the wire, §14);
     composes with overlap, prefix-cache, and both KV dtypes
     (docs/SERVING.md §10).
   adapters: list of LoRA adapters to register at startup — each entry
@@ -434,10 +430,10 @@ class _EngineHolder:
         )
         log.info("persistent compile cache: %s", cache_dir or "off")
         mc = self.model_config()
-        layout = str(self.config.get("kv-layout", "paged")).lower()
-        if layout not in ("paged", "dense"):
+        if str(self.config.get("kv-layout", "paged")).lower() != "paged":
             raise ValueError(
-                f"unknown kv-layout {layout!r}; supported: paged, dense"
+                "kv-layout: dense is gone: the page pool is the engine's "
+                "only KV state (remove the key)"
             )
         page_size = int(self.config.get("page-size", 64))
         if page_size < 1:
@@ -555,7 +551,6 @@ class _EngineHolder:
                 if self.config.get("max-prefill-streams") is not None
                 else None
             ),
-            kv_layout=layout,  # validated at the top of this method
             page_size=page_size,
             kv_pages=(
                 int(self.config["kv-pages"])

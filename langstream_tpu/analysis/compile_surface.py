@@ -38,12 +38,11 @@ WARMED_MODULES: dict[str, int] = {
     "langstream_tpu/agents/vector/__init__.py": 1,   # in-memory top-k probe
     "langstream_tpu/ai/tpu_serving.py": 1,           # embedding encode
     "langstream_tpu/models/streamload.py": 2,        # build-once loaders
-    "langstream_tpu/models/transformer.py": 4,       # prefill/decode core
-    "langstream_tpu/ops/kvcopy.py": 2,               # prefix publish/gather
+    "langstream_tpu/models/transformer.py": 3,       # forward/prefill/decode_step
     "langstream_tpu/parallel/sp.py": 1,              # long-context ring
     "langstream_tpu/serving/adapters.py": 1,         # LoRA row swap
     "langstream_tpu/serving/constrain.py": 1,        # grammar mask load
-    "langstream_tpu/serving/engine.py": 16,          # the warmed ladder
+    "langstream_tpu/serving/engine.py": 9,           # the warmed paged programs
     "langstream_tpu/serving/sampling.py": 2,         # sample/verify kernels
 }
 

@@ -43,7 +43,7 @@ KVCache = dict
 SCOPES = (
     "embed", "attention", "ffn", "moe_ffn", "moe_ffn.route",
     "moe_ffn.dispatch", "moe_ffn.experts", "moe_ffn.combine",
-    "kv_pool.read", "kv_pool.write", "head", "sample",
+    "kv_pool.write", "head", "sample",
 )
 
 # What `moe_ffn_counted` counts, per call, as one int32 vector: expert
@@ -243,11 +243,10 @@ def _lora_proj(
 
 
 # ---------------------------------------------------------------------------
-# Paged KV pool (ROADMAP item 1: ONE page-table-indexed device pool replaces
-# the per-slot dense caches, the prefix pool, and the kv_bound compile
-# ladder). Layout [L, P, Hkv, page_size, D] — the same head-major trailing
-# (T, D) tiling as the dense cache, with T = one page, so the Pallas paged
-# kernel blocks are (page_size, D) slices exactly like the dense kernels'.
+# Paged KV pool: ONE page-table-indexed device pool is the serving engine's
+# only KV state. Layout [L, P, Hkv, page_size, D] — the same head-major
+# trailing (T, D) tiling as make_kv_cache's local cache, with T = one page,
+# so the Pallas paged kernel blocks are (page_size, D) slices.
 # Slots own PAGES through a host-side table; logical column t of slot b
 # lives at (table[b, t // ps], t % ps). Unmapped table entries carry the
 # out-of-bounds sentinel (= num_pages), so scatters DROP and gathers CLAMP —
@@ -272,8 +271,8 @@ def _page_index(table: jax.Array, positions: jax.Array, page_size: int,
     """Logical position → (physical page, in-page offset), the ONE
     definition of the table lookup rule: positions past the table
     (pipelined-chunk overshoot at the cache end) map to the out-of-bounds
-    sentinel so scatters DROP — like the dense cache's OOB scatter did —
-    instead of clamp-landing on the slot's LAST real page."""
+    sentinel so scatters DROP instead of clamp-landing on the slot's LAST
+    real page."""
     lidx = positions // page_size  # [B, S] logical page per token
     pages = jnp.take_along_axis(
         table, jnp.clip(lidx, 0, table.shape[1] - 1), axis=1
@@ -396,86 +395,18 @@ def _dispatch_attention(
     v_all,
     mask: jax.Array,
     config: ModelConfig,
-    cache_positions: Optional[jax.Array],
     causal: bool,
-    kv_offset: Optional[jax.Array] = None,  # [B] — segment prefill at offset
-    kv_bound: Optional[int] = None,  # static cap on readable cache columns
-    verify: bool = False,  # speculative multi-token verify (decode-shaped S>1)
 ) -> jax.Array:
-    """Route to the Pallas kernels when shapes fit TPU tiling, else the jnp
-    reference path. Semantics identical; ops/attention has the kernels."""
+    """The prefill kernel where the shapes fit TPU tiling, else the jnp
+    reference path. Semantics identical; ops/attention has the kernel."""
     from langstream_tpu.ops.attention import (
         flash_prefill_attention,
         note_path,
         pallas_ok,
-        ragged_decode_attention,
     )
 
-    b, s, _, _ = q.shape
-    quantized = isinstance(k_all, dict)
-    t = (k_all["q"] if quantized else k_all).shape[2]
-    interpret = jax.default_backend() != "tpu"
-    if kv_bound is not None and kv_bound < t:
-        # static pow2 cap on readable cache columns (decode chunks bound it
-        # by max position + in-flight steps; chunked-prefill segments by
-        # offset + W): the masked read then streams only the valid prefix.
-        # Measured r5 (llama-3-8b int8 B=96): step time scales with cache
-        # WIDTH (27.9ms at T=256 vs 61.8 at T=1024), so this is decode's
-        # main bandwidth lever. The pallas ragged int8 kernel lost to it
-        # (592 tok/s engine — per-block DMA/grid overhead at decode shapes).
-        k_all = jax.tree.map(lambda x: x[:, :, :kv_bound], k_all)
-        v_all = jax.tree.map(lambda x: x[:, :, :kv_bound], v_all)
-        mask = mask[:, :, :kv_bound]
-        t = kv_bound
-    # decode kernels stay opt-in ("pallas"): XLA's fused masked path over
-    # the kv_bound-sliced cache beat both (bf16: 10.4 vs 11.3ms/step on
-    # gemma B=96; int8: the ragged-int8 kernel regressed 1322 → 592 tok/s)
-    use_decode_kernel = config.attention_impl == "pallas"
-    if s == 1 and use_decode_kernel and cache_positions is not None and pallas_ok(config, s, t):
-        # decode: single query per row, ragged valid prefix = position + 1
-        lengths = cache_positions[:, 0] + 1
-        if quantized:
-            from langstream_tpu.ops.attention import ragged_decode_attention_int8
-
-            kernel = ragged_decode_attention_int8
-        else:
-            kernel = ragged_decode_attention
-        note_path("decode", kernel.__name__, config, s=s, t=t)
-        out = kernel(q[:, 0], k_all, v_all, lengths, config, interpret=interpret)
-        return out[:, None, :]
-    if s > 1 and kv_offset is not None and verify:
-        # speculative verify chunk: S = k+1 draft tokens per row, decode-
-        # shaped (tiny, never 128-aligned) — the dense masked read over the
-        # (already kv_bound-sliced) cache is both the r5-measured winner at
-        # these shapes AND the same jnp math as single-token decode, the
-        # greedy token-exactness invariant. ``mask`` is the per-slot causal
-        # frontier verify_step_inplace built (already bound-sliced above).
-        from langstream_tpu.ops.attention import multitoken_verify_attention
-
-        note_path("verify", "jnp", config, s=s, t=t)
-        return multitoken_verify_attention(q, k_all, v_all, mask, config)
-    if s > 1 and kv_offset is not None:
-        # chunked prefill: the segment attends to the whole written cache
-        # prefix plus its own lower triangle (global-position causal)
-        from langstream_tpu.ops.attention import (
-            flash_segment_attention,
-            flash_segment_attention_int8,
-        )
-
-        if pallas_ok(config, s, t):
-            # int8 cache rides into its kernel unconverted: the r5
-            # dequantize-then-kernel path materialized a cache-sized
-            # bf16 temp and paid its HBM round trip per segment
-            kernel = (
-                flash_segment_attention_int8 if quantized
-                else flash_segment_attention
-            )
-            note_path("segment", kernel.__name__, config, s=s, t=t)
-            return kernel(
-                q, k_all, v_all, kv_offset, config, interpret=interpret
-            )
-        note_path("segment", "jnp", config, s=s, t=t)
-        return attention(q, k_all, v_all, mask, config)
+    s = q.shape[1]
+    t = (k_all["q"] if isinstance(k_all, dict) else k_all).shape[2]
     if s > 1 and causal and pallas_ok(config, s):
         # prefill/full forward: causal over the first s cache columns (int8
         # caches dequantize just the prompt-wide slice — prefill is
@@ -488,7 +419,7 @@ def _dispatch_attention(
             _dequantize_kv(ksl, q.dtype),
             _dequantize_kv(vsl, q.dtype),
             config,
-            interpret=interpret,
+            interpret=jax.default_backend() != "tpu",
         )
     # jnp path handles int8 cache dicts natively (hoisted-scale einsums)
     kind = "decode" if s == 1 else "prefill" if causal else "encode"
@@ -626,8 +557,6 @@ def _attention_block(
     cache_kv: Optional[tuple[jax.Array, jax.Array]] = None,
     cache_positions: Optional[jax.Array] = None,
     causal: bool = True,
-    kv_offset: Optional[jax.Array] = None,
-    kv_bound: Optional[int] = None,
     collect_kv: bool = False,
     verify: bool = False,
     paged_table: Optional[jax.Array] = None,  # [B, Tp] physical pages
@@ -649,8 +578,7 @@ def _attention_block(
         with jax.named_scope("attention"):
             return _dense_attention(
                 x, lp, sin, cos, mask, config, cache_kv, cache_positions,
-                causal, kv_offset, kv_bound, collect_kv, verify, lora,
-                lora_scale, adapter_rows,
+                causal, collect_kv, lora, lora_scale, adapter_rows,
             )
     assert cache_kv is not None and cache_positions is not None
     from langstream_tpu.ops.attention import (
@@ -724,7 +652,7 @@ def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
 
 def _dense_attention(
     x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal,
-    kv_offset, kv_bound, collect_kv, verify, lora, lora_scale, adapter_rows,
+    collect_kv, lora, lora_scale, adapter_rows,
 ):
     """`_attention_block` without a page table: a dense cache entry
     [B, Hkv, T, D] written at ``cache_positions`` and read whole, or no
@@ -769,10 +697,7 @@ def _dense_attention(
 
         attn_out = quantized_matmul(ring_attention(q, k, v, config), lp["wo"])
     else:
-        attn = _dispatch_attention(
-            q, k_all, v_all, mask, config, cache_positions, causal,
-            kv_offset, kv_bound, verify,
-        )
+        attn = _dispatch_attention(q, k_all, v_all, mask, config, causal)
         attn_out = quantized_matmul(attn, lp["wo"]) + _lora_proj(
             attn, "wo", lora, lora_scale, adapter_rows
         )
@@ -789,8 +714,6 @@ def _layer_counted(
     cache_kv: Optional[tuple[jax.Array, jax.Array]] = None,
     cache_positions: Optional[jax.Array] = None,
     causal: bool = True,
-    kv_offset: Optional[jax.Array] = None,
-    kv_bound: Optional[int] = None,
     collect_kv: bool = False,
     verify: bool = False,
     paged_table: Optional[jax.Array] = None,  # [B, Tp] physical pages
@@ -818,7 +741,7 @@ def _layer_counted(
     wk/wv adapter deltas, which is why prefill must be adapter-aware too."""
     x, new_cache = _attention_block(
         x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal,
-        kv_offset, kv_bound, collect_kv, verify, paged_table, page_size,
+        collect_kv, verify, paged_table, page_size,
         lora, lora_scale, adapter_rows, layer,
     )
     if config.is_moe:
@@ -882,8 +805,7 @@ def _split_lora(lora: Optional[dict]):
 
 def _scan_layers(
     params, x, sin, cos, mask, config, cache=None, cache_positions=None, causal=True,
-    kv_offset=None, kv_bound=None, collect_kv=False,
-    lora=None, adapter_rows=None, token_valid=None,
+    collect_kv=False, lora=None, adapter_rows=None, token_valid=None,
 ):
     """lax.scan over stacked layer params; carries (x, cache) and returns
     them with the layers' summed MOE_COUNTS as a third element. With
@@ -913,8 +835,7 @@ def _scan_layers(
         lp, (ck, cv), ll = inputs
         y, new_kv, counts = _layer_counted(
             carry, lp, sin, cos, mask, config, cache_kv=(ck, cv),
-            cache_positions=cache_positions, kv_offset=kv_offset,
-            kv_bound=kv_bound, lora=ll, lora_scale=lora_scale,
+            cache_positions=cache_positions, lora=ll, lora_scale=lora_scale,
             adapter_rows=adapter_rows, token_valid=token_valid,
         )
         return y, (new_kv, counts if moe else None)
@@ -927,78 +848,44 @@ def _scan_layers(
 
 
 def _scan_layers_inplace(
-    params, x, sin, cos, mask, config, cache, cache_positions, kv_bound=None,
-    kv_offset=None, verify=False, paged_table=None, page_size=0,
-    lora=None, adapter_rows=None,
+    params, x, sin, cos, mask, config, pool, cache_positions, paged_table,
+    page_size, verify=False, lora=None, adapter_rows=None,
 ):
-    """Layer loop with the cache carried through the scan and updated IN
-    PLACE, instead of consumed as scan ``xs`` and stacked as fresh ``ys``.
+    """Layer loop with the page pool carried through the scan and updated
+    IN PLACE, instead of consumed as scan ``xs`` and stacked as fresh ``ys``.
 
-    The xs/ys form allocates a second cache-sized buffer every call — inside
-    an outer step loop (engine `_decode_chunk`'s lax.scan) that temp is live
-    across the whole chunk, which is exactly the double-buffer that capped
-    llama-3-8b at B=48 on a 16GiB chip. A while-loop carry is aliased in
-    place by XLA. What a layer does to the carry depends on what is there:
+    The xs/ys form allocates a second pool-sized buffer every call — inside
+    an outer step loop (engine `_paged_decode_chunk`'s lax.scan) that temp
+    is live across the whole chunk. A while-loop carry is aliased in place
+    by XLA. The pool [L, P, Hkv, ps, D] goes down to the attention block
+    whole with the layer index, which scatters the new K/V rows at
+    ``[l, page, head, offset]`` and reads the row's pages at ``(l, page)``.
+    No per-layer entry is formed: the step's device program holds no operand
+    of the shape [P, Hkv, ps, D] (tests/test_tpu_compile.py asserts it on
+    the compiled HLO; slicing the entry out of the carry and writing it back
+    was two real copies of it a layer on a v5e: PERF.md §6, PR 25).
 
-    - a page table (``paged_table``): the pool [L, P, Hkv, ps, D] goes down
-      to the attention block whole with the layer index, which scatters the
-      new K/V rows at ``[l, page, head, offset]`` and reads the row's pages
-      at ``(l, page)``. No per-layer entry is formed: the step's device
-      program holds no operand of the shape [P, Hkv, ps, D]
-      (tests/test_tpu_compile.py asserts it on the compiled HLO).
-    - a dense cache: the layer's entry [B, Hkv, T, D] is sliced out of the
-      carry (``kv_pool.read``) and written back with a dynamic-update-slice
-      (``kv_pool.write``): 1x cache + two layer slices at the peak. On a
-      v5e such a pair is two real copies of the entry (seen on the paged
-      pool before it took the branch above: PERF.md §6, PR 25); the dense
-      pair has not been traced, no cell runs it (ROADMAP D3).
-
-    Returns (x, cache, the layers' summed MOE_COUNTS)."""
+    Returns (x, pool, the layers' summed MOE_COUNTS)."""
     layers = params["layers"]
     lora_layers, lora_scale = _split_lora(lora)
 
-    def read(full, l):
-        return jax.tree.map(
-            lambda a: lax.dynamic_index_in_dim(a, l, 0, keepdims=False), full
-        )
-
-    def write(full, new, l):
-        return jax.tree.map(
-            lambda a, n: lax.dynamic_update_index_in_dim(a, n, l, 0), full, new
-        )
-
-    def layer(x, kv, l, lp, ll):
-        return _layer_counted(
-            x, lp, sin, cos, mask, config, cache_kv=kv,
-            cache_positions=cache_positions, kv_offset=kv_offset,
-            kv_bound=kv_bound, verify=verify, paged_table=paged_table,
-            page_size=page_size, lora=ll, lora_scale=lora_scale,
-            adapter_rows=adapter_rows, layer=l,
-        )
-
     def body(carry, inputs):
-        x, cache = carry
+        x, pool = carry
         lp, l, ll = inputs
-        if paged_table is not None:
-            y, (nk, nv), counts = layer(x, (cache["k"], cache["v"]), l, lp, ll)
-            cache = {"k": nk, "v": nv}
-        else:
-            with jax.named_scope("kv_pool.read"):
-                ck = read(cache["k"], l)
-                cv = read(cache["v"], l)
-            y, (nck, ncv), counts = layer(x, (ck, cv), None, lp, ll)
-            with jax.named_scope("kv_pool.write"):
-                cache = {
-                    "k": write(cache["k"], nck, l), "v": write(cache["v"], ncv, l)
-                }
-        return (y, cache), (counts if config.is_moe else None)
+        y, (nk, nv), counts = _layer_counted(
+            x, lp, sin, cos, mask, config, cache_kv=(pool["k"], pool["v"]),
+            cache_positions=cache_positions, verify=verify,
+            paged_table=paged_table, page_size=page_size, lora=ll,
+            lora_scale=lora_scale, adapter_rows=adapter_rows, layer=l,
+        )
+        return (y, {"k": nk, "v": nv}), (counts if config.is_moe else None)
 
-    (x, cache), counts = lax.scan(
-        body, (x, cache), (layers, jnp.arange(config.n_layers), lora_layers)
+    (x, pool), counts = lax.scan(
+        body, (x, pool), (layers, jnp.arange(config.n_layers), lora_layers)
     )
     # a dense model's zeros stay out of the scan (see _scan_layers)
     counts = counts.sum(0) if config.is_moe else _no_moe_counts()
-    return x, cache, counts
+    return x, pool, counts
 
 
 # ---------------------------------------------------------------------------
@@ -1114,52 +1001,6 @@ def prefill(
     return (logits, cache, counts) if moe_counts else (logits, cache)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("config", "kv_bound"), donate_argnames=("cache",)
-)
-def prefill_segment(
-    params: Params,
-    tokens: jax.Array,  # [B, W] one padded prompt SEGMENT per row
-    offsets: jax.Array,  # [B] global position of each row's segment start
-    seg_lengths: jax.Array,  # [B] true token count within the segment
-    cache: KVCache,
-    config: ModelConfig,
-    kv_bound: Optional[int] = None,  # static pow2 cap ≥ offset+W (bandwidth)
-    lora: Optional[dict] = None,
-    adapter_rows: Optional[jax.Array] = None,
-) -> tuple[jax.Array, KVCache]:
-    """Chunked prefill: process one segment of a longer prompt against a
-    cache whose columns [0, offsets) were written by earlier segments.
-    Writes the segment's K/V at global positions [offsets, offsets+W) and
-    attends causally over prefix + segment. Returns logits at the last real
-    token of the segment ([B, V]) — meaningful only on the final segment.
-
-    The reference has no counterpart (its only long-input handling is
-    TextSplitter.java chunking BEFORE the model); this is what makes the
-    128k-context presets actually servable with bounded activation memory.
-    """
-    b, s = tokens.shape
-    positions = offsets[:, None] + jnp.arange(s)[None, :]  # [B, W] global
-    sin, cos = _rope_freqs(positions, config)
-    t = cache_width(cache)
-    # causal over global positions: full prefix + lower triangle of segment.
-    # Columns beyond each row's written frontier are masked (stale zeros /
-    # padding K/V are overwritten by later segments or decode before they
-    # ever enter the mask — same invariant as the short prefill path).
-    kv_pos = jnp.arange(t)[None, None, :]
-    mask = kv_pos <= positions[:, :, None]
-    x = _embed(params, tokens, config)
-    x, cache, _ = _scan_layers(
-        params, x, sin, cos, mask, config, cache=cache,
-        cache_positions=positions, kv_offset=offsets, kv_bound=kv_bound,
-        lora=lora, adapter_rows=adapter_rows,
-    )
-    last = jnp.clip(seg_lengths - 1, 0, s - 1)
-    x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
-    logits = _unembed(params, x_last[:, None, :], config)[:, 0]
-    return logits, cache
-
-
 @functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("cache",))
 def decode_step(
     params: Params,
@@ -1182,90 +1023,13 @@ def decode_step(
     return _unembed(params, x, config)[:, 0], cache
 
 
-def decode_step_inplace(
-    params: Params,
-    tokens: jax.Array,  # [B]
-    positions: jax.Array,  # [B]
-    cache: KVCache,
-    config: ModelConfig,
-    kv_bound: Optional[int] = None,  # static cap on readable cache columns
-    lora: Optional[dict] = None,
-    adapter_rows: Optional[jax.Array] = None,
-    moe_counts: bool = False,
-):
-    """decode_step with the in-place layer scan (_scan_layers_inplace) —
-    NOT separately jitted: intended as the body of a fused multi-step chunk
-    (engine `_decode_chunk`) where the xs/ys cache double-buffer would
-    otherwise persist for the whole chunk.
-
-    ``kv_bound``: static pow2 ≥ every row's position + chunk steps (the
-    engine derives it from host positions). Attention reads only the first
-    kv_bound cache columns — decode is cache-bandwidth-bound, so this is
-    the width≫content lever (see _dispatch_attention)."""
-    b = tokens.shape[0]
-    t = cache_width(cache)
-    pos2 = positions[:, None]  # [B, 1]
-    sin, cos = _rope_freqs(pos2, config)
-    kv_pos = jnp.arange(t)[None, None, :]
-    mask = kv_pos <= pos2[:, :, None]
-    x = _embed(params, tokens[:, None], config)
-    x, cache, counts = _scan_layers_inplace(
-        params, x, sin, cos, mask, config, cache=cache, cache_positions=pos2,
-        kv_bound=kv_bound, lora=lora, adapter_rows=adapter_rows,
-    )
-    logits = _unembed(params, x, config)[:, 0]
-    return (logits, cache, counts) if moe_counts else (logits, cache)
-
-
-def verify_step_inplace(
-    params: Params,
-    tokens: jax.Array,  # [B, K+1] — current token + K drafts per slot
-    positions: jax.Array,  # [B] position of each row's FIRST token
-    cache: KVCache,
-    config: ModelConfig,
-    lora: Optional[dict] = None,
-    adapter_rows: Optional[jax.Array] = None,
-    moe_counts: bool = False,
-):
-    """Multi-token speculative verify: score K drafts per slot in ONE
-    forward — logits at EVERY position come back ([B, K+1, V], unlike
-    prefill_segment's last-token-only), so the engine's rejection sampler
-    can accept the longest valid prefix. Writes K/V for all K+1 tokens at
-    [positions, positions+K+1); rows past the accepted length hold stale
-    draft K/V, which is safe because positions only advance past ACCEPTED
-    tokens and the next dispatch overwrites the stale rows before any
-    query's causal mask can reach them (the same invariant stale freed-slot
-    rows already rely on).
-
-    Bandwidth bounding is the CALLER's job: engine._verify_chunk slices the
-    cache to its kv_bound before calling (and splices after), the same
-    shape _decode_chunk uses — no kv_bound parameter here, so there is
-    exactly ONE bounding mechanism on the verify path.
-
-    Like decode_step_inplace, NOT separately jitted — it is the body of
-    engine._verify_chunk, and the in-place layer scan keeps the chunk from
-    materializing a second cache-sized buffer."""
-    b, s = tokens.shape
-    t = cache_width(cache)
-    pos = positions[:, None] + jnp.arange(s)[None, :]  # [B, K+1] global
-    sin, cos = _rope_freqs(pos, config)
-    kv_pos = jnp.arange(t)[None, None, :]
-    mask = kv_pos <= pos[:, :, None]  # per-slot causal over global positions
-    x = _embed(params, tokens, config)
-    x, cache, counts = _scan_layers_inplace(
-        params, x, sin, cos, mask, config, cache=cache, cache_positions=pos,
-        kv_offset=positions, verify=True, lora=lora, adapter_rows=adapter_rows,
-    )
-    logits = _unembed(params, x, config)
-    return (logits, cache, counts) if moe_counts else (logits, cache)
-
-
 # ---------------------------------------------------------------------------
 # Paged entry points — the bodies of the engine's ONE-program-each decode /
-# verify / segment dispatches (serving/engine.py paged mode). None of these
-# take a kv_bound: the page table already bounds what a slot can read (its
-# mapped pages), which is what deletes the pow2 compile ladder. Like the
-# *_inplace twins above, none are separately jitted.
+# verify / segment dispatches (serving/engine.py). The page table bounds
+# what a slot can read (its mapped pages), so each is one program for every
+# sequence-length mix. None are separately jitted: they are the bodies of
+# the engine's fused chunks, where the in-place layer scan keeps a chunk
+# from materializing a second pool-sized buffer.
 # ---------------------------------------------------------------------------
 
 
@@ -1292,8 +1056,8 @@ def paged_decode_step_inplace(
     moe_counts: bool = False,
 ):
     """decode_step through the page table: ONE compiled program for every
-    sequence-length mix (the dense path's (steps × kv_bound) ladder is
-    gone — a slot reads exactly its mapped pages). With adapters, the
+    sequence-length mix (a slot reads exactly its mapped pages). With
+    adapters, the
     per-slot gathered low-rank terms keep it ONE program for every
     base/adapter mix too — adapter_rows is data, never a shape."""
     pos2 = positions[:, None]
@@ -1301,9 +1065,8 @@ def paged_decode_step_inplace(
     mask = _paged_mask(table, page_size, pos2)
     x = _embed(params, tokens[:, None], config)
     x, pool, counts = _scan_layers_inplace(
-        params, x, sin, cos, mask, config, cache=pool, cache_positions=pos2,
-        paged_table=table, page_size=page_size, lora=lora,
-        adapter_rows=adapter_rows,
+        params, x, sin, cos, mask, config, pool, pos2, table, page_size,
+        lora=lora, adapter_rows=adapter_rows,
     )
     logits = _unembed(params, x, config)[:, 0]
     return (logits, pool, counts) if moe_counts else (logits, pool)
@@ -1321,8 +1084,12 @@ def paged_verify_step_inplace(
     adapter_rows: Optional[jax.Array] = None,
     moe_counts: bool = False,
 ):
-    """verify_step through the page table → logits [B, K+1, V]. Same
-    stale-rejected-rows invariant as the dense verify: positions advance
+    """Multi-token speculative verify through the page table: score K
+    drafts per slot in ONE forward — logits at EVERY position come back
+    ([B, K+1, V], unlike the segment's last-token-only), so the engine's
+    rejection sampler can accept the longest valid prefix. Writes K/V for
+    all K+1 tokens at [positions, positions+K+1); rows past the accepted
+    length hold stale draft K/V, which is safe because positions advance
     only past ACCEPTED tokens and the next dispatch overwrites the stale
     page columns before any causal mask can reach them."""
     b, s = tokens.shape
@@ -1331,9 +1098,8 @@ def paged_verify_step_inplace(
     mask = _paged_mask(table, page_size, pos)
     x = _embed(params, tokens, config)
     x, pool, counts = _scan_layers_inplace(
-        params, x, sin, cos, mask, config, cache=pool, cache_positions=pos,
-        verify=True, paged_table=table, page_size=page_size, lora=lora,
-        adapter_rows=adapter_rows,
+        params, x, sin, cos, mask, config, pool, pos, table, page_size,
+        verify=True, lora=lora, adapter_rows=adapter_rows,
     )
     logits = _unembed(params, x, config)
     return (logits, pool, counts) if moe_counts else (logits, pool)
@@ -1351,22 +1117,27 @@ def paged_prefill_segment_inplace(
     lora: Optional[dict] = None,
     adapter_rows: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, KVCache]:
-    """Chunked/suffix prefill straight into the slot's pages: K/V for the
-    segment scatter at global positions [offsets, offsets+W) and attention
-    reads the prefix THROUGH THE TABLE — which is what makes prefix reuse
-    zero-copy (aliased pages are simply visible; the dense path had to
-    gather them into a local cache first). offsets=0 with a fresh table is
-    a cold prefill. Returns logits at the last real token of the segment."""
+    """Chunked/suffix prefill straight into the slot's pages: process one
+    segment of a longer prompt against pages whose columns [0, offsets) were
+    written by earlier segments (or aliased from the prefix index). K/V for
+    the segment scatter at global positions [offsets, offsets+W) and
+    attention reads the prefix THROUGH THE TABLE, causally over prefix +
+    segment — which is what makes prefix reuse zero-copy (aliased pages are
+    simply visible). offsets=0 with a fresh table is a cold prefill. Returns
+    logits at the last real token of the segment ([B, V]) — meaningful only
+    on the final segment.
+
+    The reference has no counterpart (its only long-input handling is
+    TextSplitter.java chunking BEFORE the model); this is what makes the
+    128k-context presets actually servable with bounded activation memory."""
     b, s = tokens.shape
     positions = offsets[:, None] + jnp.arange(s)[None, :]
     sin, cos = _rope_freqs(positions, config)
     mask = _paged_mask(table, page_size, positions)
     x = _embed(params, tokens, config)
     x, pool, _ = _scan_layers_inplace(
-        params, x, sin, cos, mask, config, cache=pool,
-        cache_positions=positions, kv_offset=offsets,
-        paged_table=table, page_size=page_size, lora=lora,
-        adapter_rows=adapter_rows,
+        params, x, sin, cos, mask, config, pool, positions, table, page_size,
+        lora=lora, adapter_rows=adapter_rows,
     )
     last = jnp.clip(seg_lengths - 1, 0, s - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
@@ -1378,8 +1149,7 @@ def paged_insert_cache(
     pool: KVCache, local_cache: KVCache, tables: jax.Array, page_size: int
 ) -> KVCache:
     """Scatter a batched prefill's local cache ([L, n, Hkv, W, D], the
-    admit-group temporary) into each row's pages — the paged counterpart of
-    the dense big-cache insert. Positions are [0, W) per row; rows whose
+    admit-group temporary) into each row's pages. Positions are [0, W) per row; rows whose
     table is all out-of-bounds (padding) drop every write."""
     n = tables.shape[0]
 

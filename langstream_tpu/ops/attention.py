@@ -10,10 +10,11 @@ blocks are naturally aligned, and a per-head kv block is a contiguous
 - ``flash_prefill_attention``: causal blocked attention with fp32
   online-softmax scratch accumulators — O(block_q x block_k) VMEM instead of
   the O(S^2) masked score tensor the jnp path materializes.
-- ``ragged_decode_attention``: one query per sequence against a KV cache,
-  skipping cache blocks past each row's true length (the continuous batcher
-  packs rows of very different lengths into one step, so the dense masked
-  read wastes bandwidth proportional to max_len - mean_len).
+- ``ragged_paged_decode_attention`` (and its int8 twin): one query per
+  sequence against the page pool, walking only each row's live pages (the
+  continuous batcher packs rows of very different lengths into one step, so
+  a masked read over a fixed width wastes bandwidth proportional to
+  max_len - mean_len).
 
 No reference counterpart (the reference's compute is remote HTTP calls);
 kernel structure follows the public flash/paged-attention pattern from the
@@ -35,7 +36,7 @@ from langstream_tpu.models.configs import ModelConfig
 
 _NEG = -1e30
 
-# Scoped VMEM the prefill/segment kernels are sized against AND the limit
+# Scoped VMEM the prefill kernel is sized against AND the limit
 # stated to Mosaic (CompilerParams.vmem_limit_bytes): one number on both
 # sides, so the block-size choice below cannot drift from what the compiler
 # enforces (its unstated default, 16MiB, refused gemma-2b's 256-row q blocks
@@ -57,19 +58,16 @@ def _fit_block(block: int, n: int) -> int:
 
 def _vmem_block_q(
     block_q: int, block_k: int, group: int, d: int, itemsize: int,
-    int8_kv: bool = False,
 ) -> int:
-    """Shrink block_q until one grid step of the prefill/segment kernels
-    fits ``_VMEM_LIMIT_BYTES``. Counted per step: the double-buffered q/out
-    blocks [G, block_q, D] and K/V blocks [block_k, D] (int8 caches add
-    their f32 scale columns, lane-padded to 128), the f32 m/l/acc scratch
+    """Shrink block_q until one grid step of the prefill kernel fits
+    ``_VMEM_LIMIT_BYTES``. Counted per step: the double-buffered q/out
+    blocks [G, block_q, D] and K/V blocks [block_k, D], the f32 m/l/acc scratch
     [G, block_q, 128|128|D], and the [G, block_q, block_k] score and
     probability tiles (f32 each, plus the probabilities' model-dtype copy
     that feeds the PV dot). Shape-aware rather than a smaller global
     default: fat-head models (gemma G=8 D=256) step down to 256 rows while
     llama (G=4 D=128) keeps the full 512."""
-    kv_row = d + 128 * 4 if int8_kv else d * itemsize
-    kv = 2 * 2 * block_k * kv_row  # k + v, ×2 buffers
+    kv = 2 * 2 * block_k * d * itemsize  # k + v, ×2 buffers
     while block_q > 128:
         io = 2 * 2 * group * block_q * d * itemsize  # q + out, ×2 buffers
         scratch = group * block_q * (128 + 128 + d) * 4
@@ -293,570 +291,6 @@ def flash_prefill_attention(
 
 
 # ---------------------------------------------------------------------------
-# Chunked prefill: a prompt SEGMENT at a global offset attending to the
-# already-written cache prefix (long-context serving; the engine loops this
-# over 2k-token segments so any prompt <= max_seq_len serves with bounded
-# activation memory — the O(S^2) single-shot prefill never materializes)
-# ---------------------------------------------------------------------------
-
-
-def _segment_body(
-    off_ref,  # [B] int32 scalar-prefetch: global position of segment start
-    q_ref,  # [1, 1, G, block_q, D]
-    load_kv,  # (q_dtype) -> ([block_k, D], [block_k, D]) in model dtype
-    o_ref,  # [1, 1, G, block_q, D]
-    m_scr,  # [G, block_q, 128] f32
-    l_scr,  # [G, block_q, 128] f32
-    acc_scr,  # [G, block_q, D] f32
-    *,
-    block_q: int,
-    block_k: int,
-    scale: float,
-    softcap,
-):
-    """Shared online-softmax body of the two segment kernels (bf16 cache
-    and int8 cache differ only in how the K/V block materializes)."""
-    b = pl.program_id(0)
-    i = pl.program_id(2)  # query block (within the segment)
-    j = pl.program_id(3)  # key block (over the full cache width)
-    nk = pl.num_programs(3)
-    off = off_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    q_start = off + i * block_q  # GLOBAL position of this q block's first row
-    k_start = j * block_k
-
-    # causal against global positions: the whole prefix (k < off) is visible,
-    # plus the lower triangle within the segment
-    @pl.when(k_start <= q_start + block_q - 1)
-    def _body():
-        # model-dtype dots, fp32 accumulation (see _prefill_kernel note:
-        # f32-cast operands ran the MXU at ~14 TFLOPS — the 32k TTFT)
-        q = q_ref[0, 0, :, :, :]  # [G, block_q, D]
-        k, v = load_kv(q.dtype)
-        s = (
-            jax.lax.dot_general(
-                q,
-                k,
-                dimension_numbers=(((2,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
-        if softcap is not None:
-            s = jnp.tanh(s / softcap) * softcap
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_q, block_k), 1)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_q, block_k), 2)
-        s = jnp.where(k_pos <= q_pos, s, _NEG)
-
-        m_prev = m_scr[:, :, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, :, None])
-        p = jnp.where(s <= _NEG, 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:, :, 0] = l_scr[:, :, 0] * corr + p.sum(axis=-1)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype),
-            v,
-            dimension_numbers=(((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_scr[...] = acc_scr[...] * corr[:, :, None] + pv
-        m_scr[:, :, 0] = m_new
-
-    @pl.when(j == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:, :, 0], 1e-30)[:, :, None]
-        o_ref[0, 0, :, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
-
-
-def _segment_kernel(
-    off_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, **opts
-):
-    _segment_body(
-        off_ref, q_ref,
-        lambda _dt: (k_ref[0, 0, :, :], v_ref[0, 0, :, :]),
-        o_ref, m_scr, l_scr, acc_scr, **opts,
-    )
-
-
-@_per_kv_head(1)
-def flash_segment_attention(
-    q: jax.Array,  # [B, S, H, D] — segment queries
-    k: jax.Array,  # [B, Hkv, T, D] cache (head-major), T >= offset + S
-    v: jax.Array,  # [B, Hkv, T, D]
-    offset: jax.Array,  # [B] int32 global position of the segment start
-    config: ModelConfig,
-    block_q: int = 512,
-    block_k: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """Causal GQA attention of a segment against cache prefix + itself
-    → [B, S, H*D]. The segment's own K/V must already be scattered into the
-    cache at [offset, offset+S)."""
-    b, s, h, d = q.shape
-    hkv = k.shape[1]
-    t = k.shape[2]
-    group = h // hkv
-    block_k = _fit_block(block_k, t)
-    block_q = _fit_block(
-        _vmem_block_q(block_q, block_k, group, d, jnp.dtype(q.dtype).itemsize), s
-    )
-    assert s % block_q == 0 and t % block_k == 0, "caller gates divisibility"
-    qg = q.reshape(b, s, hkv, group, d).transpose(0, 2, 3, 1, 4)
-
-    kernel = functools.partial(
-        _segment_kernel,
-        block_q=block_q,
-        block_k=block_k,
-        scale=1.0 / (d**0.5),
-        softcap=config.attn_logit_softcap,
-    )
-
-    def kv_index(b, h, i, j, off):
-        # clamp past-diagonal blocks to the last block this q block needs:
-        # Pallas re-references the SAME block and elides the HBM→VMEM DMA,
-        # so early segments don't stream the whole (mostly-unwritten) cache
-        last = jnp.maximum(pl.cdiv(off[b] + (i + 1) * block_q, block_k) - 1, 0)
-        return (b, h, jnp.minimum(j, last), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, s // block_q, t // block_k),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, group, block_q, d), lambda b, h, i, j, off: (b, h, 0, i, 0)
-            ),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, group, block_q, d), lambda b, h, i, j, off: (b, h, 0, i, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((group, block_q, 128), jnp.float32),
-            pltpu.VMEM((group, block_q, 128), jnp.float32),
-            pltpu.VMEM((group, block_q, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="flash_segment_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-    )(offset.astype(jnp.int32), qg, k, v)
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * d)
-
-
-def _segment_int8_kernel(
-    off_ref,  # [B] int32 scalar-prefetch: global position of segment start
-    q_ref,  # [1, 1, G, block_q, D]
-    kq_ref,  # [1, 1, block_k, D] int8
-    ks_ref,  # [1, 1, block_k, 1] f32 per-token scales
-    vq_ref,  # [1, 1, block_k, D] int8
-    vs_ref,  # [1, 1, block_k, 1] f32
-    o_ref,  # [1, 1, G, block_q, D]
-    m_scr,  # [G, block_q, 128] f32
-    l_scr,  # [G, block_q, 128] f32
-    acc_scr,  # [G, block_q, D] f32
-    **opts,
-):
-    """_segment_body over an int8 KV cache: the HBM read stays int8
-    (the r5 32k-TTFT residual was the materialized bf16 cache copy the
-    non-quantized kernel forced — ~8.6GB of traffic per late segment);
-    K/V dequantize in VMEM to the model dtype so the dots still ride the
-    MXU at bf16 rate (f32 operands measured 14 vs 34.8 TFLOPS)."""
-
-    def load_kv(dtype):
-        k = (kq_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0]).astype(dtype)
-        v = (vq_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0]).astype(dtype)
-        return k, v
-
-    _segment_body(off_ref, q_ref, load_kv, o_ref, m_scr, l_scr, acc_scr, **opts)
-
-
-@_per_kv_head(1)
-def flash_segment_attention_int8(
-    q: jax.Array,  # [B, S, H, D] — segment queries
-    k: dict,  # int8 cache entry {"q": [B,Hkv,T,D] i8, "s": [B,Hkv,T] f32}
-    v: dict,
-    offset: jax.Array,  # [B] int32 global position of the segment start
-    config: ModelConfig,
-    block_q: int = 512,
-    block_k: int = 512,
-    interpret: bool = False,
-) -> jax.Array:
-    """flash_segment_attention directly over the int8 KV cache → no
-    cache-sized bf16 temp, int8 on the HBM wire. Same causal/GQA math."""
-    b, s, h, d = q.shape
-    hkv = k["q"].shape[1]
-    t = k["q"].shape[2]
-    group = h // hkv
-    block_k = _fit_block(block_k, t)
-    block_q = _fit_block(
-        _vmem_block_q(
-            block_q, block_k, group, d, jnp.dtype(q.dtype).itemsize, int8_kv=True
-        ),
-        s,
-    )
-    assert s % block_q == 0 and t % block_k == 0, "caller gates divisibility"
-    qg = q.reshape(b, s, hkv, group, d).transpose(0, 2, 3, 1, 4)
-
-    kernel = functools.partial(
-        _segment_int8_kernel,
-        block_q=block_q,
-        block_k=block_k,
-        scale=1.0 / (d**0.5),
-        softcap=config.attn_logit_softcap,
-    )
-
-    def kv_index(b, h, i, j, off):
-        # clamp past-diagonal blocks to the last block this q block needs
-        # (same DMA-eliding trick as the bf16 segment kernel)
-        last = jnp.maximum(pl.cdiv(off[b] + (i + 1) * block_q, block_k) - 1, 0)
-        return (b, h, jnp.minimum(j, last), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, s // block_q, t // block_k),
-        in_specs=[
-            pl.BlockSpec(
-                (1, 1, group, block_q, d), lambda b, h, i, j, off: (b, h, 0, i, 0)
-            ),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-            # trailing singleton: Mosaic needs the block's last two dims
-            # (8,128)-divisible or equal to the array's — [.., block_k, 1]
-            pl.BlockSpec((1, 1, block_k, 1), kv_index),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-            pl.BlockSpec((1, 1, block_k, 1), kv_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, group, block_q, d), lambda b, h, i, j, off: (b, h, 0, i, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((group, block_q, 128), jnp.float32),
-            pltpu.VMEM((group, block_q, 128), jnp.float32),
-            pltpu.VMEM((group, block_q, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="flash_segment_attention_int8",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
-        compiler_params=_COMPILER_PARAMS,
-        interpret=interpret,
-    )(
-        offset.astype(jnp.int32),
-        qg,
-        k["q"],
-        k["s"][..., None],
-        v["q"],
-        v["s"][..., None],
-    )
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * d)
-
-
-# ---------------------------------------------------------------------------
-# Decode: one query per row against a ragged KV cache
-# ---------------------------------------------------------------------------
-
-
-def _decode_kernel(
-    lengths_ref,  # scalar-prefetch [B]
-    q_ref,  # [1, 1, G, D]
-    k_ref,  # [1, 1, block_k, D]
-    v_ref,  # [1, 1, block_k, D]
-    o_ref,  # [1, 1, G, D]
-    m_scr,  # [G, 128] f32
-    l_scr,  # [G, 128] f32
-    acc_scr,  # [G, D] f32
-    *,
-    block_k: int,
-    scale: float,
-    softcap,
-):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nk = pl.num_programs(2)
-    length = lengths_ref[b]
-    k_start = j * block_k
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    # skip cache blocks entirely past this row's written length
-    @pl.when(k_start < length)
-    def _body():
-        q = q_ref[0, 0, :, :].astype(jnp.float32)  # [G, D]
-        k = k_ref[0, 0, :, :].astype(jnp.float32)  # [block_k, D]
-        v = v_ref[0, 0, :, :].astype(jnp.float32)
-        s = (
-            jax.lax.dot_general(
-                q,
-                k,
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [G, block_k]
-        if softcap is not None:
-            s = jnp.tanh(s / softcap) * softcap
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-        s = jnp.where(k_pos < length, s, _NEG)
-
-        m_prev = m_scr[:, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(s <= _NEG, 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:, 0] = l_scr[:, 0] * corr + p.sum(axis=-1)
-        pv = jnp.dot(p, v, preferred_element_type=jnp.float32)  # [G, D]
-        acc_scr[...] = acc_scr[...] * corr[:, None] + pv
-        m_scr[:, 0] = m_new
-
-    @pl.when(j == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:, 0], 1e-30)[:, None]
-        o_ref[0, 0, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
-
-
-@_per_kv_head(1)
-def ragged_decode_attention(
-    q: jax.Array,  # [B, H, D] single query per row
-    k: jax.Array,  # [B, Hkv, T, D] cache (head-major)
-    v: jax.Array,  # [B, Hkv, T, D]
-    lengths: jax.Array,  # [B] int32 — valid cache prefix per row
-    config: ModelConfig,
-    block_k: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
-    """GQA decode attention → [B, H*D]."""
-    b, h, d = q.shape
-    hkv = k.shape[1]
-    t = k.shape[2]
-    group = h // hkv
-    block_k = _fit_block(block_k, t)
-    assert t % block_k == 0, "caller gates divisibility"
-    qg = q.reshape(b, hkv, group, d)
-
-    kernel = functools.partial(
-        _decode_kernel,
-        block_k=block_k,
-        scale=1.0 / (d**0.5),
-        softcap=config.attn_logit_softcap,
-    )
-    def kv_index(b, h, j, lens):
-        # paged-attention trick: clamp the block index at this row's last
-        # valid block, so grid steps past the length re-reference the SAME
-        # block and Pallas elides the HBM→VMEM copy — the DMA skip is where
-        # the ragged bandwidth saving actually comes from (the pl.when only
-        # skips the FLOPs)
-        last = jnp.maximum(pl.cdiv(lens[b], block_k) - 1, 0)
-        return (b, h, jnp.minimum(j, last), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, hkv, t // block_k),
-        in_specs=[
-            # index maps receive the scalar-prefetch ref as a trailing arg
-            pl.BlockSpec((1, 1, group, d), lambda b, h, j, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-            pl.BlockSpec((1, 1, block_k, d), kv_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 1, group, d), lambda b, h, j, lens: (b, h, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, 128), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="ragged_decode_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), qg, k, v)
-    return out.reshape(b, h * d)
-
-
-# ---------------------------------------------------------------------------
-# Decode over an INT8 cache: same ragged structure, but k/v blocks are read
-# raw int8 (+ per-token f32 scales) straight from HBM — cache bandwidth is
-# the decode bottleneck (measured r5: llama-3-8b B=96 step time 27.9ms at
-# T=256 vs 61.8ms at T=1024 — the dense masked read scales with cache WIDTH,
-# not content), and the block-skip makes it scale with the longest row
-# instead.
-# ---------------------------------------------------------------------------
-
-
-def _decode_int8_kernel(
-    lengths_ref,  # scalar-prefetch [B]
-    q_ref,  # [1, Hkv, G, D]
-    kq_ref,  # [1, Hkv, block_k, D] int8
-    ks_ref,  # [1, Hkv, block_k, 1] f32 per-token scales
-    vq_ref,  # [1, Hkv, block_k, D] int8
-    vs_ref,  # [1, Hkv, block_k, 1] f32
-    o_ref,  # [1, Hkv, G, D]
-    m_scr,  # [Hkv, G, 128] f32
-    l_scr,  # [Hkv, G, 128] f32
-    acc_scr,  # [Hkv, G, D] f32
-    *,
-    block_k: int,
-    scale: float,
-    softcap,
-):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    nk = pl.num_programs(1)
-    length = lengths_ref[b]
-    k_start = j * block_k
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-
-    @pl.when(k_start < length)
-    def _body():
-        # ALL kv heads ride one grid step (batched dots): an [B,Hkv,·]
-        # grid needed 8x the steps, and per-step grid overhead made the
-        # kernel LOSE to the dense masked path (592 vs 1322 tok/s, r5)
-        q = q_ref[0].astype(jnp.float32)  # [Hkv, G, D]
-        # dequantize IN VMEM: the HBM read stays int8 (the bandwidth win)
-        k = kq_ref[0].astype(jnp.float32) * ks_ref[0]  # [Hkv, block_k, D]
-        v = vq_ref[0].astype(jnp.float32) * vs_ref[0]
-        s = (
-            jax.lax.dot_general(
-                q,
-                k,
-                dimension_numbers=(((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [Hkv, G, block_k]
-        if softcap is not None:
-            s = jnp.tanh(s / softcap) * softcap
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, block_k), 2
-        )
-        s = jnp.where(k_pos < length, s, _NEG)
-
-        m_prev = m_scr[:, :, 0]  # [Hkv, G]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, :, None])
-        p = jnp.where(s <= _NEG, 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[:, :, 0] = l_scr[:, :, 0] * corr + p.sum(axis=-1)
-        pv = jax.lax.dot_general(
-            p,
-            v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [Hkv, G, D]
-        acc_scr[...] = acc_scr[...] * corr[:, :, None] + pv
-        m_scr[:, :, 0] = m_new
-
-    @pl.when(j == nk - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:, :, 0], 1e-30)[:, :, None]
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-
-
-@_per_kv_head(1)
-def ragged_decode_attention_int8(
-    q: jax.Array,  # [B, H, D] single query per row
-    k: dict,  # int8 cache entry {"q": [B,Hkv,T,D] i8, "s": [B,Hkv,T] f32}
-    v: dict,
-    lengths: jax.Array,  # [B]
-    config: ModelConfig,
-    block_k: int = 128,
-    interpret: bool = False,
-) -> jax.Array:
-    """GQA decode attention over an int8 KV cache → [B, H*D].
-
-    Grid is (B, T/block_k) with every kv head inside the block — fewer,
-    fatter grid steps and ~1MB DMAs. Blocks past a row's length clamp to
-    its last valid block (DMA elided), so HBM traffic scales with CONTENT
-    (sum of lengths), not cache width, and stays int8 on the wire.
-
-    Differs from the jnp int8 path in q handling (q stays full precision
-    here; the jnp path re-quantizes q to ride the int8 MXU) — slightly MORE
-    accurate, same K/V math."""
-    b, h, d = q.shape
-    hkv = k["q"].shape[1]
-    t = k["q"].shape[2]
-    group = h // hkv
-    block_k = _fit_block(block_k, t)
-    assert t % block_k == 0, "caller gates divisibility"
-    qg = q.reshape(b, hkv, group, d)
-
-    kernel = functools.partial(
-        _decode_int8_kernel,
-        block_k=block_k,
-        scale=1.0 / (d**0.5),
-        softcap=config.attn_logit_softcap,
-    )
-
-    def kv_index(b, j, lens):
-        # clamp past-length blocks to the row's last valid block: Pallas
-        # re-references the same block and elides the HBM→VMEM DMA
-        last = jnp.maximum(pl.cdiv(lens[b], block_k) - 1, 0)
-        return (b, 0, jnp.minimum(j, last), 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, t // block_k),
-        in_specs=[
-            pl.BlockSpec((1, hkv, group, d), lambda b, j, lens: (b, 0, 0, 0)),
-            pl.BlockSpec((1, hkv, block_k, d), kv_index),
-            # trailing singleton: Mosaic needs the block's last two dims
-            # (8,128)-divisible or equal to the array's — [.., block_k, 1]
-            pl.BlockSpec((1, hkv, block_k, 1), kv_index),
-            pl.BlockSpec((1, hkv, block_k, d), kv_index),
-            pl.BlockSpec((1, hkv, block_k, 1), kv_index),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, hkv, group, d), lambda b, j, lens: (b, 0, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((hkv, group, 128), jnp.float32),
-            pltpu.VMEM((hkv, group, 128), jnp.float32),
-            pltpu.VMEM((hkv, group, d), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        kernel,
-        name="ragged_decode_attention_int8",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-        interpret=interpret,
-    )(
-        lengths.astype(jnp.int32),
-        qg,
-        k["q"],
-        k["s"][..., None],
-        v["q"],
-        v["s"][..., None],
-    )
-    return out.reshape(b, h * d)
-
-
-# ---------------------------------------------------------------------------
 # Ragged PAGED decode: one query per row against a page-table-indexed KV
 # pool [P, Hkv, page_size, D] (arxiv 2502.10490 "Ragged Paged Attention":
 # per-slot sequence lengths index pages through a table, (8,128) tiling on
@@ -874,8 +308,8 @@ def ragged_decode_attention_int8(
 # returns zeros. Before PR 28 the grid was (B, table_len): 0.20 us for
 # every table entry of every row whatever the rows held, with the fetch
 # (not the step) elided past a row's length, so bytes scaled with content
-# and time did not (PERF.md §5). No kv_bound ladder is needed: the table
-# IS the bound, one compiled program for every sequence-length mix. The
+# and time did not (PERF.md §5). The table IS the bound on what a row
+# reads: one compiled program for every sequence-length mix. The
 # kernels take the WHOLE pool [L, P, Hkv, ps, D] and a layer index, so the
 # caller's layer scan never slices a per-layer entry out of the pool to
 # hand one over (a custom call's operand is materialised: that slice was
@@ -1176,148 +610,29 @@ def paged_pallas_ok(config: ModelConfig, page_size: int) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# Fused prefill+decode batch: one attention call whose rows mix S-token
-# prompt SEGMENTS (chunked prefill at a global offset) with single-token
-# decode queries against the same big KV cache (arxiv 2604.15464's ragged
-# mixed batch, expressed as a dispatch over the two existing paths rather
-# than a third kernel: prefill rows ride the segment kernel, decode rows
-# the kv_bound-sliced dense read that beat both ragged decode kernels in
-# r5). This is the attention-layer BUILDING BLOCK for a true single-program
-# fused iteration; the shipped engine runs two back-to-back dispatches
-# instead (PERF.md round 6 records the decision), so nothing calls this in
-# production yet — it is exactness-tested and kept for the revisit.
-# ---------------------------------------------------------------------------
-
-
-def fused_segment_decode_attention(
-    q_seg: jax.Array,  # [P, S, H, D] segment queries (prefill rows)
-    seg_offsets: jax.Array,  # [P] int32 global position of each segment start
-    q_dec: jax.Array,  # [Bd, H, D] one query per decode row
-    k,  # [B, Hkv, T, D] shared head-major cache (array or int8 {"q","s"})
-    v,
-    seg_rows: jax.Array,  # [P] int32 cache row of each prefill row
-    dec_rows: jax.Array,  # [Bd] int32 cache row of each decode row
-    dec_lengths: jax.Array,  # [Bd] int32 valid cache prefix per decode row
-    config: ModelConfig,
-    kv_bound: int | None = None,  # static cap on decode rows' readable columns
-    interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Mixed prefill-segment + decode attention over ONE cache
-    → ([P, S, H*D] segment out, [Bd, H*D] decode out).
-
-    The segment rows' own K/V must already be scattered into the cache at
-    [offset, offset+S) (same contract as flash_segment_attention); decode
-    rows attend to their first ``dec_lengths`` columns. Exactness: each half
-    is bit-identical to its standalone path — this function only routes, it
-    never re-derives math — so a fused iteration built on it matches the
-    serialized prefill-then-decode reference token for token."""
-    from langstream_tpu.models.transformer import attention as jnp_attention
-
-    quantized = isinstance(k, dict)
-    t = (k["q"] if quantized else k).shape[2]
-
-    # prefill rows → the segment path (Pallas kernel when shapes fit)
-    k_seg = jax.tree.map(lambda x: x[seg_rows], k)
-    v_seg = jax.tree.map(lambda x: x[seg_rows], v)
-    p, s = q_seg.shape[0], q_seg.shape[1]
-    if pallas_ok(config, s, t):
-        if quantized:
-            seg_out = flash_segment_attention_int8(
-                q_seg, k_seg, v_seg, seg_offsets, config, interpret=interpret
-            )
-        else:
-            seg_out = flash_segment_attention(
-                q_seg, k_seg, v_seg, seg_offsets, config, interpret=interpret
-            )
-    else:
-        positions = seg_offsets[:, None] + jnp.arange(s)[None, :]  # [P, S]
-        kv_pos = jnp.arange(t)[None, None, :]
-        seg_mask = kv_pos <= positions[:, :, None]
-        seg_out = jnp_attention(q_seg, k_seg, v_seg, seg_mask, config)
-
-    # decode rows → the dense masked read over the kv_bound-sliced cache
-    # (r5 measured this beating both ragged kernels at decode shapes)
-    k_dec = jax.tree.map(lambda x: x[dec_rows], k)
-    v_dec = jax.tree.map(lambda x: x[dec_rows], v)
-    t_dec = t
-    if kv_bound is not None and kv_bound < t:
-        k_dec = jax.tree.map(lambda x: x[:, :, :kv_bound], k_dec)
-        v_dec = jax.tree.map(lambda x: x[:, :, :kv_bound], v_dec)
-        t_dec = kv_bound
-    dec_mask = (
-        jnp.arange(t_dec)[None, None, :] < dec_lengths[:, None, None]
-    )  # [Bd, 1, T]
-    dec_out = jnp_attention(q_dec[:, None], k_dec, v_dec, dec_mask, config)
-    return seg_out, dec_out[:, 0]
-
-
-# ---------------------------------------------------------------------------
-# Multi-token verify: K+1 speculative-draft queries per row against the big
-# cache (self-speculative decoding, engine._verify_chunk). Decode-shaped
-# work, not prefill-shaped: S is tiny (k+1 ≤ ~9) and never 128-aligned, so
-# the segment kernels' tiling can't apply — and r5 measured the dense masked
-# read over the kv_bound-sliced cache beating the ragged kernels at exactly
-# these shapes. One routing function for both cache dtypes keeps the verify
-# path on the SAME jnp attention math as single-token decode, which is what
-# makes greedy speculation token-exact with non-speculative greedy.
-# ---------------------------------------------------------------------------
-
-
-def multitoken_verify_attention(
-    q: jax.Array,  # [B, S, H, D] — current token + S-1 draft queries per row
-    k,  # [B, Hkv, T, D] cache (head-major array, or int8 {"q","s"} entry)
-    v,
-    mask: jax.Array,  # [B, S, T] bool — per-slot causal, built by the caller
-    config: ModelConfig,
-) -> jax.Array:
-    """Per-slot causal attention of a draft chunk against the cache
-    → [B, S, H*D]. Query j of row b attends columns ≤ position[b] + j (the
-    prefix written by earlier steps plus the drafts' own lower triangle —
-    their K/V must already be scattered at the query positions, the
-    prefill_segment contract). The mask comes from verify_step_inplace,
-    which owns the ONLY definition of the verify causal frontier — columns
-    past a row's frontier may hold stale rejected-draft K/V from a
-    previous verify, and the mask is what makes that harmless.
-
-    Deliberately a named entry point here rather than an inlined call in
-    transformer._dispatch_attention: this is the seam a Pallas multi-token
-    verify kernel would replace if a chip measurement ever justified one
-    (r5's data says it won't at small S — the dense path won)."""
-    from langstream_tpu.models.transformer import attention as jnp_attention
-
-    return jnp_attention(q, k, v, mask, config)
-
-
-# ---------------------------------------------------------------------------
 # Dispatch gate
 # ---------------------------------------------------------------------------
 
 
-def pallas_ok(config: ModelConfig, seq_len: int, cache_len: int | None = None) -> bool:
-    """True when the pallas kernels apply; no ring axis (ring attention owns
-    the sequence-parallel path), and under a mesh only when its "model"
+def pallas_ok(config: ModelConfig, seq_len: int) -> bool:
+    """True when the prefill kernel applies: no ring axis (ring attention
+    owns the sequence-parallel path), and under a mesh only when its "model"
     axis divides the kv heads (``_mesh_ok``).
 
-    ``attention_impl="pallas"`` forces the kernels (interpret mode off-TPU,
+    ``attention_impl="pallas"`` forces the kernel (interpret mode off-TPU,
     for tests) gated only on block divisibility; ``"auto"`` additionally
-    requires a real TPU backend and lane-aligned (128) head dim / lengths —
-    the engine's prefill buckets and cache widths guarantee those in
-    production."""
+    requires a real TPU backend and lane-aligned (128) head dim / length —
+    the engine's prefill buckets guarantee those in production."""
     if config.attention_impl == "jnp":
         return False
     if config.ring_axis is not None or not _mesh_ok(config):
         return False
-    force = config.attention_impl == "pallas"
-    if force:
-        ok_seq = seq_len == 1 or seq_len % min(128, seq_len) == 0
-        ok_cache = cache_len is None or cache_len % min(128, cache_len) == 0
-        return ok_seq and ok_cache
+    if config.attention_impl == "pallas":
+        return seq_len == 1 or seq_len % min(128, seq_len) == 0
     if jax.default_backend() != "tpu":
         return False
     if config.resolved_head_dim % 128 != 0:
         return False
     if seq_len > 1 and seq_len % 128 != 0:
-        return False
-    if cache_len is not None and cache_len % 128 != 0:
         return False
     return True

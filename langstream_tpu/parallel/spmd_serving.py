@@ -11,16 +11,12 @@ identical `_dev_*` engine methods with the received inputs
 (`parallel/multihost.py` caveat), implemented in round 3.
 
 Protocol v2 (round 13 — docs/SERVING.md §14): every host-side decision the
-FAST paths make now rides the wire, so prefix reuse, self-speculative
-decoding and ``kv_layout="paged"`` run under SPMD instead of being
-construction-disabled:
+FAST paths make rides the wire, so prefix reuse, self-speculative decoding
+and the page pool run under SPMD instead of being construction-disabled:
 
 - ``OP_VERIFY`` ships the leader's n-gram drafts (the index itself is
   deterministic given the replayed token stream, so only the drafts need
   the wire — acceptance is computed ON DEVICE identically on every host).
-- ``OP_PREFIX_ADMIT`` / ``OP_PREFIX_PUBLISH`` replay the dense prefix
-  cache's gather+suffix-segment admissions and copy-on-publish rows (the
-  pool ROW index rides the wire; the radix trie stays leader-only).
 - ``OP_PAGE_BIND`` / ``OP_PAGE_FREE`` / ``OP_PAGE_ZERO`` replay the paged
   allocator's observable RESULTS — the page lists bound to a slot
   (aliased prefix pages included, plus the one copy-on-write pair), table
@@ -31,11 +27,11 @@ construction-disabled:
   mask: the leader's slot liveness (a host-side property followers cannot
   observe — completions are discovered at fetch time) masks non-active
   page-table rows to the out-of-bounds sentinel on every host.
-- ``OP_ROW_RESET`` replays the dense NaN-quarantine row zero, so an SPMD
-  replica quarantines a poisoned slot victim-only (round-8 semantics)
-  instead of crashing the whole replica.
-- ``OP_WARMUP`` replays a whole precompile family (decode ladder, verify
-  ladder, paged surface, prefill buckets, prefix programs) as ONE
+- ``OP_PAGE_FREE`` + ``OP_PAGE_ZERO`` also carry the NaN quarantine, so an
+  SPMD replica quarantines a poisoned slot victim-only instead of crashing
+  the whole replica.
+- ``OP_WARMUP`` replays a whole precompile family (the paged decode-phase
+  surface, the prefill buckets) as ONE
   announcement — both sides run the identical deterministic dispatch
   sequence from shared config, so the warmups stay off the hot wire.
 
@@ -111,14 +107,10 @@ OP_PREFILL = 1
 OP_LONG_SEG = 2
 OP_DECODE = 3
 OP_STOP = 4
-OP_RING = 5  # ring long-prefill: padded prompt streamed in token chunks
 OP_VERIFY = 6  # speculative verify dispatch (drafts payload)
-OP_PREFIX_ADMIT = 7  # dense warm admission: gather + suffix segment
-OP_PREFIX_PUBLISH = 8  # dense copy-on-publish into a pool row
 OP_PAGE_BIND = 9  # paged reservation result: slot's page list (+ COW pair)
 OP_PAGE_FREE = 10  # slot's table clears (completion / quarantine / abort)
 OP_PAGE_ZERO = 11  # quarantine page-zero dispatch
-OP_ROW_RESET = 12  # dense NaN-quarantine row zero dispatch
 OP_ECHO = 13  # leader's fetched chunk result (divergence check, optional)
 OP_WARMUP = 14  # replay a whole precompile family (count = WARMUP_* kind)
 OP_RECOVER = 15  # leader loop crashed: both sides rebuild (count = epoch)
@@ -126,11 +118,8 @@ OP_RESYNC = 16  # leader's authoritative tables/positions/mask (divergence
 #                 resync; long_idx = epoch, count = payload elements)
 
 # OP_WARMUP kinds (ControlBlock.count)
-WARMUP_DECODE_LADDER = 0
-WARMUP_VERIFY_LADDER = 1
 WARMUP_PAGED = 2
 WARMUP_PREFILL_BUCKETS = 3
-WARMUP_PREFIX_PROGRAMS = 4
 
 # OP_ECHO kinds (ControlBlock.long_idx)
 ECHO_DECODE = 0
@@ -143,18 +132,15 @@ _H_STEPS = 2
 _H_NROWS = 3
 _H_S0 = 4
 _H_SEG_LEN = 5
-_H_KV_BOUND = 6
-_H_LONG_START = 7
-_H_LONG_FINAL = 8
-_H_LONG_IDX = 9
-_H_PROMPT_LEN = 10
-_H_T_LONG = 11
-_H_ENTRY_ROW = 12  # prefix pool row (dense admit/publish, long warm start); -1 = none
-_H_COW_SRC = 13  # copy-on-write source page (paged bind); -1 = none
-_H_COW_DST = 14  # copy-on-write destination page; -1 = none
-_H_SEQ = 15  # announcement sequence number (follower verifies contiguity)
-_H_COUNT = 16  # page count / echo element count / warmup kind
-_HEAD_LEN = 17
+_H_LONG_START = 6
+_H_LONG_FINAL = 7
+_H_LONG_IDX = 8
+_H_PROMPT_LEN = 9
+_H_COW_SRC = 10  # copy-on-write source page (paged bind); -1 = none
+_H_COW_DST = 11  # copy-on-write destination page; -1 = none
+_H_SEQ = 12  # announcement sequence number (follower verifies contiguity)
+_H_COUNT = 13  # page count / echo element count / warmup kind
+_HEAD_LEN = 14
 
 
 @dataclass
@@ -167,13 +153,10 @@ class ControlBlock:
     n_rows: int = 0
     s0: int = 0
     seg_len: int = 0
-    kv_bound: int = 0
     long_start: bool = False
     long_final: bool = False
     long_idx: int = 0
     prompt_len: int = 0
-    t_long: int = 0
-    entry_row: int = -1
     cow_src: int = -1
     cow_dst: int = -1
     seq: int = 0
@@ -335,13 +318,10 @@ class SpmdChannel:
         head[_H_NROWS] = block.n_rows
         head[_H_S0] = block.s0
         head[_H_SEG_LEN] = block.seg_len
-        head[_H_KV_BOUND] = block.kv_bound
         head[_H_LONG_START] = int(block.long_start)
         head[_H_LONG_FINAL] = int(block.long_final)
         head[_H_LONG_IDX] = block.long_idx
         head[_H_PROMPT_LEN] = block.prompt_len
-        head[_H_T_LONG] = block.t_long
-        head[_H_ENTRY_ROW] = block.entry_row
         head[_H_COW_SRC] = block.cow_src
         head[_H_COW_DST] = block.cow_dst
         head[_H_SEQ] = block.seq
@@ -399,13 +379,10 @@ class SpmdChannel:
             n_rows=n,
             s0=int(head[_H_S0]),
             seg_len=int(head[_H_SEG_LEN]),
-            kv_bound=int(head[_H_KV_BOUND]),
             long_start=bool(head[_H_LONG_START]),
             long_final=bool(head[_H_LONG_FINAL]),
             long_idx=int(head[_H_LONG_IDX]),
             prompt_len=int(head[_H_PROMPT_LEN]),
-            t_long=int(head[_H_T_LONG]),
-            entry_row=int(head[_H_ENTRY_ROW]),
             cow_src=int(head[_H_COW_SRC]),
             cow_dst=int(head[_H_COW_DST]),
             seq=int(head[_H_SEQ]),
@@ -434,7 +411,7 @@ class SpmdChannel:
         """Which second-phase payload an op ships. DECODE/STOP/IDLE and the
         page/row bookkeeping ops carry everything in the head + phase-1
         vectors — two-phase keeps the per-decode-chunk hot path small."""
-        if op in (OP_PREFILL, OP_LONG_SEG, OP_RING, OP_PREFIX_ADMIT):
+        if op in (OP_PREFILL, OP_LONG_SEG):
             return "tokens"
         if op == OP_VERIFY:
             return "drafts"
@@ -1021,67 +998,21 @@ def _replay(
             block.slots,
         )
     elif block.op == OP_LONG_SEG:
-        if engine._paged:
-            # paged segments (long-prompt chunks AND warm suffix segments)
-            # write straight into the slot's wire-bound pages
-            engine._dev_paged_segment(
-                block.tokens,
-                block.s0,
-                block.seg_len,
-                block.long_idx,
-                float(block.temps[0]),
-                int(block.top_ks[0]),
-                float(block.top_ps[0]),
-                final=block.long_final,
-                prompt_len=block.prompt_len,
-            )
-        else:
-            engine._dev_long_segment(
-                block.tokens,
-                block.s0,
-                block.seg_len,
-                block.kv_bound,
-                block.t_long,
-                float(block.temps[0]),
-                int(block.top_ks[0]),
-                float(block.top_ps[0]),
-                start=block.long_start,
-                final=block.long_final,
-                idx=block.long_idx,
-                prompt_len=block.prompt_len,
-                prefix_row=block.entry_row if block.entry_row >= 0 else None,
-            )
-    elif block.op == OP_RING:
-        # the padded prompt streams in (prefill_batch*max_width)-token
-        # chunks; the final chunk triggers the one-dispatch ring admit,
-        # evolving the follower's sharded state in lockstep with the leader
-        if block.long_start:
-            engine._spmd_ring_buf = []
-        engine._spmd_ring_buf.append(
-            np.asarray(block.tokens, np.int32).reshape(-1)[: block.seg_len]
+        # segments (long-prompt chunks AND warm suffix segments) write
+        # straight into the slot's wire-bound pages
+        engine._dev_paged_segment(
+            block.tokens,
+            block.s0,
+            block.seg_len,
+            block.long_idx,
+            float(block.temps[0]),
+            int(block.top_ks[0]),
+            float(block.top_ps[0]),
+            final=block.long_final,
+            prompt_len=block.prompt_len,
         )
-        if block.long_final:
-            prompt = np.concatenate(engine._spmd_ring_buf)
-            engine._spmd_ring_buf = []
-            # reconstruct the leader's pow2 padding locally (deterministic
-            # from the shared mesh/max_seq_len config) — only the prompt
-            # itself rides the channel
-            s_pad = engine._ring_pad(block.prompt_len)
-            tokens = np.zeros((1, s_pad), np.int32)
-            tokens[0, : len(prompt)] = prompt
-            engine._dev_ring(
-                tokens,
-                block.prompt_len,
-                float(block.temps[0]),
-                int(block.top_ks[0]),
-                float(block.top_ps[0]),
-                block.long_idx,
-            )
     elif block.op == OP_DECODE:
-        # kv_bound=0 replays pre-bound announcements as unbounded
-        chunk = engine._dev_decode(
-            block.steps, block.slots, block.kv_bound or None, mask=block.mask
-        )
+        chunk = engine._dev_decode(block.steps, block.slots, mask=block.mask)
         if channel.echo:
             pending_echo.append((ECHO_DECODE, chunk))
     elif block.op == OP_VERIFY:
@@ -1089,25 +1020,10 @@ def _replay(
         packed = engine._dev_verify(
             np.asarray(block.drafts[:, :k], np.int32),
             block.slots,
-            block.kv_bound,
             mask=block.mask,
         )
         if channel.echo:
             pending_echo.append((ECHO_VERIFY, packed))
-    elif block.op == OP_PREFIX_ADMIT:
-        engine._dev_prefix_admit(
-            block.tokens,
-            block.s0,
-            block.seg_len,
-            block.kv_bound,
-            block.entry_row,
-            float(block.temps[0]),
-            int(block.top_ks[0]),
-            float(block.top_ps[0]),
-            block.long_idx,
-        )
-    elif block.op == OP_PREFIX_PUBLISH:
-        engine._dev_prefix_publish(block.long_idx, block.entry_row)
     elif block.op == OP_PAGE_BIND:
         engine._spmd_apply_bind(
             block.long_idx,
@@ -1121,8 +1037,6 @@ def _replay(
         engine._pagepool.free_slot(block.long_idx)
     elif block.op == OP_PAGE_ZERO:
         engine._dev_page_zero(list(block.pages))
-    elif block.op == OP_ROW_RESET:
-        engine._dev_row_reset(list(block.slots))
     elif block.op == OP_WARMUP:
         _replay_warmup(engine, block)
     elif block.op == OP_ECHO:
@@ -1136,16 +1050,10 @@ def _replay_warmup(engine: Any, block: ControlBlock) -> None:
     identical deterministic dispatch sequence (same config ⇒ same shapes,
     same PRNG consumption), so the warmups cost ONE announcement each."""
     kind = block.count
-    if kind == WARMUP_DECODE_LADDER:
-        engine._warmup_decode_ladder()
-    elif kind == WARMUP_VERIFY_LADDER:
-        engine._warmup_verify_ladder()
-    elif kind == WARMUP_PAGED:
+    if kind == WARMUP_PAGED:
         engine._warmup_paged()
     elif kind == WARMUP_PREFILL_BUCKETS:
         engine._warmup_prefill_buckets()
-    elif kind == WARMUP_PREFIX_PROGRAMS:
-        engine._warmup_prefix_programs()
     else:
         _fail_divergence(engine, block, f"unknown warmup kind {kind}")
 

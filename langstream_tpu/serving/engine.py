@@ -17,10 +17,12 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import gc
 import logging
 import math
 import os
 import queue
+import sys
 import threading
 import time
 from collections import deque
@@ -35,16 +37,12 @@ from jax import lax
 from langstream_tpu.models.configs import GenerationOptions, ModelConfig
 from langstream_tpu.models.transformer import (
     MOE_COUNTS,
-    cache_width,
-    decode_step_inplace,
     make_kv_cache,
     paged_decode_step_inplace,
     paged_insert_cache,
     paged_prefill_segment_inplace,
     paged_verify_step_inplace,
     prefill,
-    prefill_segment,
-    verify_step_inplace,
 )
 from langstream_tpu.parallel import spmd_serving as wire
 from langstream_tpu.serving.faultinject import FaultInjector
@@ -359,135 +357,6 @@ def _sample_first(logits, key, temps, top_ks, top_ps, dfa, g, state0, vocab_size
 
 
 @functools.partial(
-    jax.jit, static_argnames=("steps", "config", "kv_bound"), donate_argnames=("cache",)
-)
-def _decode_chunk(
-    params, tokens, positions, cache, key, temp, top_k, top_p, steps, config,
-    kv_bound=None, lora=None, arows=None, dfa=None, g=None, dstate=None,
-):
-    """``steps`` fused decode+sample iterations in ONE dispatch (lax.scan).
-
-    Every step would otherwise pay a host dispatch and a fetch; scanning K
-    steps on-device amortizes that overhead K-fold, and the
-    engine additionally pipelines: chunk k+1 is dispatched from chunk k's
-    DEVICE outputs before chunk k's tokens are fetched to the host.
-
-    The step body uses decode_step_inplace (layer scan carries the cache,
-    updated by dynamic-update-slice) so the chunk never materializes a
-    second cache-sized buffer — the xs/ys layer scan's stacked output was
-    live across the whole chunk, OOMing llama-3-8b past B=48 and costing
-    ~20% step time (measured r5: 39.1 → 31.3 ms/step at B=48).
-
-    ``kv_bound`` (static pow2 ≥ max position + steps, from host positions):
-    the chunk scans over a [.., :kv_bound]-sliced cache and splices it back
-    after — ONE pair of bound-wide copies per chunk instead of per-step
-    slicing (measured r5 llama-3-8b B=96: 51.8 ms/step sliced-per-step vs
-    27.9 native-narrow; decode is HBM-bound, and weights + cold cache
-    columns are most of the stream)."""
-
-    full = None
-    if kv_bound is not None and kv_bound < cache_width(cache):
-        full = cache
-        # axis 3 is T for both the value arrays and the int8 scale arrays
-        cache = jax.tree.map(lambda a: a[:, :, :, :kv_bound], cache)
-
-    def body(carry, _):
-        tokens, positions, cache, key, dstate = carry
-        logits, cache, moe = decode_step_inplace(
-            params, tokens, positions, cache, config,
-            lora=lora, adapter_rows=arows, moe_counts=True,
-        )
-        # constrained decoding rides the FUSED chunk: mask this step's
-        # logits with each slot's packed bitmask row, then advance the
-        # state past the sampled token ON DEVICE (default-successor +
-        # exceptions probe) — the host mirror replays the dense table
-        # per delivered token, so a 16-step chunk stays one dispatch
-        # with both sides in lockstep
-        next_tokens, key, dstate = _sample_step(
-            logits, key, temp, top_k, top_p, dfa, g, dstate, config.vocab_size
-        )
-        return (next_tokens, positions + 1, cache, key, dstate), (
-            next_tokens, moe if config.is_moe else None,
-        )
-
-    (tokens, positions, cache, key, dstate), (chunk, moe) = lax.scan(
-        body, (tokens, positions, cache, key, dstate), None, length=steps
-    )
-    # a dense model's zeros stay out of the step scan: one constant
-    moe = moe.sum(0) if config.is_moe else jnp.zeros(len(MOE_COUNTS), jnp.int32)
-    if full is not None:
-        cache = jax.tree.map(
-            lambda big, small: lax.dynamic_update_slice(
-                big, small.astype(big.dtype), (0,) * big.ndim
-            ),
-            full,
-            cache,
-        )
-    return chunk, tokens, positions, cache, key, dstate, moe
-
-
-@functools.partial(
-    jax.jit, static_argnames=("config", "kv_bound"), donate_argnames=("cache",)
-)
-def _verify_chunk(
-    params, tokens, positions, cache, key, temp, top_k, top_p, drafts, config,
-    kv_bound=None, lora=None, arows=None, dfa=None, g=None, vstates=None,
-):
-    """ONE self-speculative iteration in ONE dispatch: run the multi-token
-    verify forward over [current token ++ drafts] (k+1 positions per slot),
-    accept the longest valid draft prefix (greedy: argmax match; sampled:
-    rejection sampling — serving/sampling.py speculative_verify), and
-    advance the device decode chain by accepted+1. Decode is HBM-bound —
-    every step reads the full weights to emit one token per slot — so
-    scoring k+1 positions per weight read is the amortization lever after
-    int8, overlap and prefix reuse (PERF.md round 9). Rejected tokens need
-    no KV rewind: positions simply don't advance past the accepted length,
-    and the next dispatch overwrites the stale rows before any causal mask
-    can reach them.
-
-    ``kv_bound``: the same static pow2 slice/splice the decode chunk uses —
-    the verify read must not stream cold cache columns either. The fetched
-    result is ONE packed [B, k+2] array (emitted tokens ++ accepted count),
-    one fetch per iteration. Compile surface: one program per
-    (k, kv_bound) with k fixed engine-wide, so the ladder stays O(log2 T)."""
-    full = None
-    if kv_bound is not None and kv_bound < cache_width(cache):
-        full = cache
-        cache = jax.tree.map(lambda a: a[:, :, :, :kv_bound], cache)
-    inputs = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [B, k+1]
-    logits, cache, moe = verify_step_inplace(
-        params, inputs, positions, cache, config,
-        lora=lora, adapter_rows=arows, moe_counts=True,
-    )
-    # ``vstates`` [B, K+1]: the host-computed DFA state at every verify
-    # position (state after consuming drafts 0..j-1 — the same mask
-    # plain masked decode would apply, the exactness invariant under
-    # constraints; serving/constrain.py verify_states)
-    out, accept, key = _sample_verify(
-        logits, drafts, key, temp, top_k, top_p, dfa, g, vstates
-    )
-    # the last emitted token (correction or bonus) is the next chunk's input
-    tokens = jnp.take_along_axis(out, accept[:, None], axis=1)[:, 0]
-    positions = positions + accept + 1
-    dstate = None
-    if dfa is not None:
-        # state after the LAST emitted token: gather the pre-state at the
-        # accept position, advance past the emitted correction/bonus
-        pre = jnp.take_along_axis(vstates, accept[:, None], axis=1)[:, 0]
-        dstate = _dfa_advance(dfa, g, tokens, pre, config.vocab_size)
-    if full is not None:
-        cache = jax.tree.map(
-            lambda big, small: lax.dynamic_update_slice(
-                big, small.astype(big.dtype), (0,) * big.ndim
-            ),
-            full,
-            cache,
-        )
-    packed = jnp.concatenate([out, accept[:, None]], axis=1)  # [B, k+2]
-    return packed, tokens, positions, cache, key, dstate, moe
-
-
-@functools.partial(
     jax.jit,
     donate_argnames=(
         "tokens_dev", "positions_dev", "temp_dev", "top_k_dev", "top_p_dev"
@@ -511,52 +380,6 @@ def _chain_scatter(
     )
 
 
-@functools.partial(jax.jit, donate_argnames=("cache",))
-def _reset_rows(cache, slots):
-    """Zero the KV cache rows of quarantined slots — ONE fixed-shape
-    traced-index dispatch for any number of slots (``slots`` is a
-    max_batch-wide buffer, out-of-bounds padding rows drop). A NaN-poisoned
-    row must not survive slot reuse: admission only rewrites the prompt's
-    columns, and a NaN in a later column would flow back through attention
-    the moment a longer request decodes into it (NaN + the -inf mask is
-    still NaN through softmax)."""
-
-    def zero(a):
-        return a.at[:, slots].set(jnp.zeros((), a.dtype), mode="drop")
-
-    return jax.tree.map(zero, cache)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("config", "kv_bound"), donate_argnames=("local_cache",)
-)
-def _prefill_segment_and_sample(
-    params, tokens, offsets, seg_lengths, local_cache, key, temp, top_k, top_p,
-    config, kv_bound, lora=None, arows=None, dfa=None, g=None,
-    state_dev=None, state_slot=None, state0=None,
-):
-    """One chunked-prefill segment + a sample of its last-token logits.
-    Sampling every segment (vs only the last) keeps the compiled-shape count
-    at O(log2 segments) (the pow2 kv_bound); non-final samples are simply
-    never fetched. With a grammar, the first generated token is masked by
-    the request's INITIAL DFA state ``state0`` ([1] int32 — 0 for a fresh
-    derivation, the carried state for a mid-derivation fleet resume, §18)
-    and the advanced state scatters into ``state_dev`` at ``state_slot``
-    (out-of-bounds on non-final segments — dropped), so the decode chain
-    the engine dispatches NEXT iteration already carries the right state
-    without a host round trip."""
-    logits, local_cache = prefill_segment(
-        params, tokens, offsets, seg_lengths, local_cache, config, kv_bound,
-        lora=lora, adapter_rows=arows,
-    )
-    first, key, s1 = _sample_first(
-        logits, key, temp, top_k, top_p, dfa, g, state0, config.vocab_size
-    )
-    if s1 is not None:
-        state_dev = state_dev.at[state_slot].set(s1[0], mode="drop")
-    return first, local_cache, key, state_dev
-
-
 @functools.partial(
     jax.jit, static_argnames=("steps", "config", "page_size"),
     donate_argnames=("pool",),
@@ -565,11 +388,12 @@ def _paged_decode_chunk(
     params, tokens, positions, pool, table, key, temp, top_k, top_p, steps,
     config, page_size, lora=None, arows=None, dfa=None, g=None, dstate=None,
 ):
-    """``steps`` fused decode+sample iterations against the PAGED pool in
-    ONE dispatch — the paged twin of ``_decode_chunk`` with the kv_bound
-    slice/splice dance deleted: each slot reads exactly its mapped pages,
-    so this is ONE compiled program for every sequence-length mix (the
-    (steps × pow2-bound) ladder collapses; ROADMAP item 1). Adapter rows
+    """``steps`` fused decode+sample iterations against the page pool in
+    ONE dispatch (lax.scan): every step would otherwise pay a host dispatch
+    and a fetch, and the engine additionally pipelines — chunk k+1 is
+    dispatched from chunk k's DEVICE outputs before chunk k's tokens are
+    fetched to the host. Each slot reads exactly its mapped pages, so this
+    is ONE compiled program for every sequence-length mix. Adapter rows
     and grammar rows are DATA ([B] int32 gathers), so base + N adapters +
     constrained slots mixed in one batch is STILL that one program — the
     ISSUE-10 acceptance invariant."""
@@ -602,13 +426,21 @@ def _paged_verify_chunk(
     params, tokens, positions, pool, table, key, temp, top_k, top_p, drafts,
     config, page_size, lora=None, arows=None, dfa=None, g=None, vstates=None,
 ):
-    """ONE self-speculative verify iteration against the paged pool — the
-    paged twin of ``_verify_chunk``, and like the decode chunk a SINGLE
-    compiled program (no bound ladder). Same no-rewind invariant: positions
-    advance only past accepted tokens, stale draft page columns are
-    overwritten before any causal mask can reach them. Draft positions are
-    masked with the host-shipped per-position DFA states (``vstates``) so
-    speculative verify stays token-exact under constraints."""
+    """ONE self-speculative iteration in ONE dispatch: run the multi-token
+    verify forward over [current token ++ drafts] (k+1 positions per slot),
+    accept the longest valid draft prefix (greedy: argmax match; sampled:
+    rejection sampling — serving/sampling.py speculative_verify), and
+    advance the device decode chain by accepted+1. Decode is HBM-bound —
+    every step reads the full weights to emit one token per slot — so
+    scoring k+1 positions per weight read is the amortization lever. Like
+    the decode chunk a SINGLE compiled program (k is fixed engine-wide).
+    Rejected tokens need no KV rewind: positions advance only past accepted
+    tokens, and stale draft page columns are overwritten before any causal
+    mask can reach them. Draft positions are masked with the host-shipped
+    per-position DFA states (``vstates`` [B, k+1]: the state after consuming
+    drafts 0..j-1 — serving/constrain.py verify_states) so speculative
+    verify stays token-exact under constraints. The fetched result is ONE
+    packed [B, k+2] array (emitted tokens ++ accepted count)."""
     inputs = jnp.concatenate([tokens[:, None], drafts], axis=1)  # [B, k+1]
     logits, pool, moe = paged_verify_step_inplace(
         params, inputs, positions, pool, table, config, page_size,
@@ -636,12 +468,17 @@ def _paged_segment_and_sample(
     state_dev=None, state_slot=None, state0=None,
 ):
     """One chunked/suffix prefill segment straight into the slot's pages +
-    a sample of its last-token logits. Replaces the dense path's local
-    cache + final insert + (on warm admissions) the prefix gather: aliased
-    prefix pages are already visible through the table, so a warm admission
-    is ONE dispatch (plus at most one copy-on-write page copy). Grammar
-    handling as in ``_prefill_segment_and_sample`` (``state0`` seeds the
-    first-token mask — the mid-derivation resume hook)."""
+    a sample of its last-token logits: aliased prefix pages are already
+    visible through the table, so a warm admission is ONE dispatch (plus at
+    most one copy-on-write page copy). Sampling every segment (vs only the
+    last) keeps one compiled shape per width; non-final samples are simply
+    never fetched. With a grammar, the first generated token is masked by
+    the request's INITIAL DFA state ``state0`` ([1] int32 — 0 for a fresh
+    derivation, the carried state for a mid-derivation fleet resume, §18)
+    and the advanced state scatters into ``state_dev`` at ``state_slot``
+    (out-of-bounds on non-final segments — dropped), so the decode chain
+    the engine dispatches NEXT iteration already carries the right state
+    without a host round trip."""
     logits, pool = paged_prefill_segment_inplace(
         params, tokens, offsets, seg_lengths, pool, table, config, page_size,
         lora=lora, adapter_rows=arows,
@@ -710,85 +547,16 @@ def _page_restore(pool, block, dst):
     return jax.tree.map(put, pool, block)
 
 
-def _make_admit_group(mesh):
-    """Factory for the FUSED admission step: local-cache zeros + prefill +
-    first-token sample + big-cache insert + every decode-chain scatter in
-    ONE dispatch: the unfused path made ~14 host→device ops (7 uploads +
-    cache alloc + prefill + insert + 5 scatters); fused + packed uploads
-    ≈ 4 ops."""
-    @functools.partial(
-        jax.jit,
-        static_argnames=("config",),
-        donate_argnames=(
-            "cache", "tokens_dev", "positions_dev", "temp_dev",
-            "top_k_dev", "top_p_dev",
-        ),
-    )
-    def admit_group(
-        params, cache, tokens_dev, positions_dev, temp_dev, top_k_dev,
-        top_p_dev, key, tokens, meta, slots, config,
-        lora=None, arows=None, dfa=None, g_rows=None, state_dev=None,
-        g_state0=None,
-    ):
-        # tokens [P, W] int32; meta [4, P] f32 = lengths/temps/top_ks/top_ps
-        lengths = meta[0].astype(jnp.int32)
-        temps = meta[1]
-        top_ks = meta[2].astype(jnp.int32)
-        top_ps = meta[3]
-        n, width = tokens.shape
-        local_cache = make_kv_cache(config, n, width)  # traced zeros: free
-        if mesh is not None:
-            from langstream_tpu.parallel.sharding import (
-                constrain_serving_local_cache,
-            )
-
-            local_cache = constrain_serving_local_cache(
-                local_cache, config.n_kv_heads, mesh
-            )
-        logits, local_cache, moe = prefill(
-            params, tokens, lengths, local_cache, config,
-            lora=lora, adapter_rows=arows, moe_counts=True,
-            # a padding row's slot is out of bounds: none of it is real
-            real_lengths=jnp.where(slots < tokens_dev.shape[0], lengths, 0),
-        )
-        # constrained rows: the advanced state scatters into the decode
-        # chain alongside the token — the NEXT decode chunk (often
-        # dispatched before this fetch even lands) reads a coherent state
-        first, key, s1 = _sample_first(
-            logits, key, temps, top_ks, top_ps, dfa, g_rows, g_state0,
-            config.vocab_size,
-        )
-        if s1 is not None:
-            state_dev = state_dev.at[slots].set(s1, mode="drop")
-
-        def put(big, small):
-            w = small.shape[3]
-            return big.at[:, slots, :, :w].set(small.astype(big.dtype), mode="drop")
-
-        cache = jax.tree.map(put, cache, local_cache)
-        tokens_dev = tokens_dev.at[slots].set(first, mode="drop")
-        positions_dev = positions_dev.at[slots].set(lengths, mode="drop")
-        temp_dev = temp_dev.at[slots].set(temps, mode="drop")
-        top_k_dev = top_k_dev.at[slots].set(top_ks, mode="drop")
-        top_p_dev = top_p_dev.at[slots].set(top_ps, mode="drop")
-        return (
-            first, cache, tokens_dev, positions_dev, temp_dev, top_k_dev,
-            top_p_dev, key, state_dev, moe,
-        )
-
-    return admit_group
-
-
 def _make_paged_admit_group(mesh=None):
-    """Factory for the paged FUSED admission step: local-cache zeros +
-    batched prefill + first-token sample + PAGE scatter + every decode-chain
-    scatter in ONE dispatch. The prefill math is byte-identical to the dense
-    admit group (same local-cache forward — the token-exactness invariant);
-    only the insert differs: rows scatter into each slot's mapped pages
-    instead of big-cache rows. Padding rows carry all-out-of-bounds tables,
-    so their writes drop exactly like the dense path's OOB slots. Under a
-    mesh the transient local cache is constrained like the dense admit
-    group's, so the page scatter stays shard-local."""
+    """Factory for the FUSED admission step: local-cache zeros + batched
+    prefill + first-token sample + PAGE scatter + every decode-chain
+    scatter in ONE dispatch (the unfused path made ~14 host→device ops;
+    fused + packed uploads ≈ 4). The prefill is the model-level ``prefill``
+    over a local cache (the token-exactness reference); its rows then
+    scatter into each slot's mapped pages. Padding rows carry
+    all-out-of-bounds tables, so their writes drop. Under a mesh the
+    transient local cache is constrained so the page scatter stays
+    shard-local."""
     @functools.partial(
         jax.jit,
         static_argnames=("config", "page_size"),
@@ -842,76 +610,6 @@ def _make_paged_admit_group(mesh=None):
         )
 
     return admit_group
-
-
-def _make_ring_admit(mesh):
-    """Factory for the RING long-prompt admission: one dispatch runs the
-    sequence-sharded ring prefill (parallel.sp.ring_prefill — prompt blocks
-    spread over the mesh's "seq" axis, K/V rotating over ICI), quantizes the
-    returned K/V if the cache is int8, splices it into the big cache, and
-    samples the first token. The multi-chip counterpart of the single-chip
-    chunked-prefill segment loop: S/W sequential segment dispatches become
-    ONE compiled call whose attention memory stays O(S·S/n) per device."""
-    @functools.partial(
-        jax.jit,
-        static_argnames=("config",),
-        donate_argnames=(
-            "cache", "tokens_dev", "positions_dev", "temp_dev",
-            "top_k_dev", "top_p_dev",
-        ),
-    )
-    def ring_admit(
-        params, cache, tokens_dev, positions_dev, temp_dev, top_k_dev,
-        top_p_dev, key, tokens, meta, slots, config,
-    ):
-        from langstream_tpu.models.transformer import _quantize_kv
-        from langstream_tpu.parallel.sp import ring_prefill
-
-        lengths = meta[0].astype(jnp.int32)
-        temps = meta[1]
-        top_ks = meta[2].astype(jnp.int32)
-        top_ps = meta[3]
-        logits, kv = ring_prefill(params, tokens, lengths, config, mesh)
-        first, key, _ = _sample_first(
-            logits, key, temps, top_ks, top_ps, None, None, None,
-            config.vocab_size,
-        )
-        if isinstance(cache["k"], dict):  # int8 big cache
-            kq, ks = _quantize_kv(kv["k"])
-            vq, vs = _quantize_kv(kv["v"])
-            local = {"k": {"q": kq, "s": ks}, "v": {"q": vq, "s": vs}}
-        else:
-            local = kv
-
-        def put(big, small):
-            w = small.shape[3]
-            return big.at[:, slots, :, :w].set(small.astype(big.dtype), mode="drop")
-
-        cache = jax.tree.map(put, cache, local)
-        tokens_dev = tokens_dev.at[slots].set(first, mode="drop")
-        positions_dev = positions_dev.at[slots].set(lengths, mode="drop")
-        temp_dev = temp_dev.at[slots].set(temps, mode="drop")
-        top_k_dev = top_k_dev.at[slots].set(top_ks, mode="drop")
-        top_p_dev = top_p_dev.at[slots].set(top_ps, mode="drop")
-        return first, cache, tokens_dev, positions_dev, temp_dev, top_k_dev, top_p_dev, key
-
-    return ring_admit
-
-
-def _kv_bound_ladder(max_seq_len: int) -> list[int]:
-    """The pow2 kv_bound ladder: 64 doubling up to (and always including)
-    ``max_seq_len``. The ONE definition of the ladder rule — the decode and
-    verify warmups compile exactly these rungs and _decode_kv_bound picks
-    from them at dispatch time, so any drift between the three sites would
-    resurface the 15-23s mid-traffic compile stall the warmups exist to
-    prevent."""
-    bounds = []
-    bound = 64
-    while bound < max_seq_len:
-        bounds.append(bound)
-        bound *= 2
-    bounds.append(max_seq_len)
-    return list(dict.fromkeys(bounds))
 
 
 class _Fetch:
@@ -1230,27 +928,6 @@ class _DurableWorker:
                 log.exception("durable checkpoint crashed")
 
 
-def _make_insert_group():
-    @functools.partial(jax.jit, donate_argnames=("cache",))
-    def insert_group(cache, local_cache, slots):
-        """Scatter a whole prefill batch into the big cache in ONE op —
-        per-slot inserts each rewrote the full cache wherever buffer
-        donation degrades to copies. ``slots`` entries that
-        are out of bounds (padding rows) are dropped by the scatter."""
-
-        def put(big, small):
-            # [L, B, Hkv, T, ...] — T (dim 3) is the bucket width for both
-            # the value arrays and the int8 cache's rank-4 scale arrays
-            w = small.shape[3]
-            return big.at[:, slots, :, :w].set(
-                small.astype(big.dtype), mode="drop"
-            )
-
-        return jax.tree.map(put, cache, local_cache)
-
-    return insert_group
-
-
 class ServingEngine:
     """One engine per model per agent replica; owns the device loop."""
 
@@ -1290,7 +967,6 @@ class ServingEngine:
         overlap: bool = True,
         prefill_token_budget: Optional[int] = None,
         max_prefill_streams: Optional[int] = None,
-        kv_layout: str = "paged",
         page_size: int = 64,
         kv_pages: Optional[int] = None,
         host_kv_fraction: float = 0.0,
@@ -1404,24 +1080,15 @@ class ServingEngine:
             )
         self.shed_policy = shed_policy
         self._slots = [_Slot() for _ in range(max_batch)]
-        # KV memory layout (ROADMAP item 1): "paged" (default) = ONE
-        # page-table-indexed device pool for decode, prefill, verify and
-        # prefix reuse — no kv_bound compile ladder, prefix hits alias
-        # pages zero-copy. "dense" = the per-slot big cache, kept one
-        # release as the escape hatch. Paged is legal under multi-host
-        # SPMD (allocator events ride the leader→follower wire — round
-        # 13, docs/SERVING.md §14) and under sharded meshes (the pool
-        # shards its kv heads over "model" like the dense serving cache).
-        if kv_layout not in ("paged", "dense"):
-            raise ValueError(
-                f"unknown kv_layout {kv_layout!r}; supported: paged, dense"
-            )
-        self.kv_layout = kv_layout
-        self._paged = kv_layout == "paged"
+        # KV state: ONE page-table-indexed device pool for decode,
+        # prefill, verify and prefix reuse — one compiled program for every
+        # sequence-length mix, prefix hits alias pages zero-copy. Legal
+        # under multi-host SPMD (allocator events ride the leader→follower
+        # wire — docs/SERVING.md §14) and under sharded meshes (the pool
+        # shards its kv heads over "model").
         self.page_size = max(1, int(page_size))
         self._pagepool = None
         self._prefix_index = None
-        self._cache = None
         # deferred admissions: popped from the queue but waiting for pool
         # pages (allocator exhaustion defers — it never corrupts); retried
         # ahead of the queue every iteration, swept like the queue
@@ -1439,9 +1106,7 @@ class ServingEngine:
         self.host_kv_fraction = max(0.0, float(host_kv_fraction))
         self.spill_idle_s = max(0.0, float(spill_idle_s))
         self._restore_stall_s = max(0.0, float(restore_stall_dump_s))
-        spill_on = (
-            self._paged and not spill_off and self.host_kv_fraction > 0
-        )
+        spill_on = not spill_off and self.host_kv_fraction > 0
         if spmd is not None and spill_on:
             # spill/demote/restore decisions are leader-side host state
             # (arena free list, checksums, idle clocks) and the restore
@@ -1492,9 +1157,7 @@ class ServingEngine:
         self.durable_dir = str(durable_dir) if durable_dir else None
         self.durable_timeout_s = max(0.1, float(durable_timeout_s))
         self._durable_max_bytes = max(0, int(durable_max_bytes))
-        durable_on = (
-            self._paged and not durable_off and self.durable_dir is not None
-        )
+        durable_on = not durable_off and self.durable_dir is not None
         if spmd is not None and durable_on:
             # same wire gap as the host tier above: checkpoint/restore
             # decisions are leader-side host state and the restore upload
@@ -1508,8 +1171,8 @@ class ServingEngine:
             durable_on = False
         if durable_ask and not durable_on:
             log.warning(
-                "durable: on requested but unavailable (needs kv-layout: "
-                "paged + durable-dir, single-host) — tier stays off"
+                "durable: on requested but unavailable (needs durable-dir, "
+                "single-host) — tier stays off"
             )
         self._durable_on = durable_on
         self._durable = None  # DurableStore, built with the pool below
@@ -1559,33 +1222,7 @@ class ServingEngine:
         self.migrate_pages_in_total = 0
         self.migrate_bytes_in_total = 0
         self.migrate_failures_total = 0
-        if not self._paged:
-            self._cache = make_kv_cache(config, max_batch, self.max_seq_len)
-            if mesh is not None:
-                from langstream_tpu.parallel.sharding import shard_serving_cache
-
-                self._cache = shard_serving_cache(self._cache, mesh)
-        self._insert_group = _make_insert_group()
-        self._admit_group = _make_admit_group(mesh)
         self._paged_admit_group = _make_paged_admit_group(mesh)
-        # ring long-prefill: mesh spans a "seq" axis → long prompts run as
-        # ONE sequence-sharded dispatch instead of the segment loop. On a
-        # multi-host replica the leader streams the prompt to followers in
-        # fixed-shape chunks first (OP_RING), then every process makes the
-        # identical dispatch. DENSE layout only: the ring admit splices
-        # into the big cache; under the paged layout long prompts take the
-        # budgeted segment loop (which writes straight into pages and has
-        # no divisibility constraint) until a paged ring splice exists.
-        self._ring_admit = (
-            _make_ring_admit(mesh)
-            if mesh is not None
-            and not self._paged
-            and "seq" in getattr(mesh, "shape", {})
-            and mesh.shape["seq"] > 1
-            else None
-        )
-        # follower-side accumulation buffer for OP_RING token chunks
-        self._spmd_ring_buf: list = []
         # kept: the deterministic crash-recovery rebuild derives the fresh
         # PRNG key from seed + recovery epoch, identically on every host
         self._rng_seed = int(rng_seed)
@@ -1654,11 +1291,10 @@ class ServingEngine:
             1, int(max_prefill_streams or (2 if self.overlap else 1))
         )
         # chunked prefill (long-context): prompts wider than the largest
-        # bucket loop prefill_segment over bucket-width segments into a
-        # batch-1 local cache, budgeted segments per engine iteration so
-        # decode keeps flowing in between. One state dict + local cache per
-        # stream, keyed by the reserved slot index (the key also rides the
-        # SPMD wire, so followers evolve the same per-stream caches).
+        # bucket loop bucket-width segments straight into the reserved
+        # slot's pages, budgeted segments per engine iteration so decode
+        # keeps flowing in between. One state dict per stream, keyed by the
+        # reserved slot index (the key also rides the SPMD wire).
         self._longs: dict[int, dict] = {}
         self._long_rr: int = -1  # round-robin cursor over stream slots
         self._long_queue: list[GenerationRequest] = []
@@ -1671,23 +1307,17 @@ class ServingEngine:
         # maxsize/unfinished accounting (ADVICE r4)
         self._held_back: Optional[GenerationRequest] = None
         self._reserved: set[int] = set()
-        # long-prefill local caches keyed by slot index, kept on self (not
-        # the state dicts) so SPMD followers evolve the same attr through
-        # _dev_long_segment (the slot index rides every OP_LONG_SEG block)
-        self._long_caches: dict[int, Any] = {}
         # multi-host SPMD: the leader announces every device dispatch over
         # this channel before making it; followers replay via follower_loop
         # (parallel/spmd_serving.py). None = single-host, zero overhead.
         self._spmd = spmd
-        # automatic prefix KV reuse (serving/prefix_cache.py): radix index
-        # over bucket-aligned token prefixes + a device pool in the slot-
-        # cache layout. Warm admissions gather the cached prefix and prefill
-        # ONLY the suffix (one segment at the reuse offset); every completed
-        # prefill publishes its bucket-aligned prefix back (copy-on-publish,
-        # refcounted, LRU-evicted). Legal under SPMD since round 13: the
-        # admission (gather+segment) and publish dispatches ride the wire
-        # as OP_PREFIX_ADMIT/OP_PREFIX_PUBLISH with the pool ROW index —
-        # the radix trie itself stays leader-only host state.
+        # automatic prefix KV reuse (serving/pagepool.PrefixPageIndex): a
+        # radix index over bucket-aligned token prefixes whose entries pin
+        # pages of the one pool. Warm admissions alias the cached pages and
+        # prefill ONLY the suffix (one segment at the reuse offset); every
+        # completed prefill publishes its bucket-aligned prefix back
+        # (refcounted, LRU-evicted). The index stays leader-only host state
+        # under SPMD: only page ids ride the wire.
         enabled = (
             prefix_cache is True
             or str(prefix_cache).lower() in ("auto", "on", "true", "1")
@@ -1695,7 +1325,7 @@ class ServingEngine:
         # self-speculative decoding (prompt-lookup drafts + one-dispatch
         # multi-token verification): host-side per-slot n-gram indexes
         # propose up to ``speculation_tokens`` drafts per iteration; the
-        # _verify_chunk program scores them all in ONE weight read and
+        # _paged_verify_chunk program scores them all in ONE weight read and
         # advances each slot by accepted+1 tokens. Legal under SPMD since
         # round 13: drafts ride OP_VERIFY (acceptance is computed on
         # device, identically on every host — only the proposals need the
@@ -1706,8 +1336,8 @@ class ServingEngine:
         )
         self._spec_enabled = spec_on
         # ONE static k engine-wide: every distinct k is a separate compiled
-        # verify ladder (k × the pow2 bounds), and a 15-23s mid-traffic
-        # compile costs more than any per-request k tuning could win
+        # verify program, and a 15-23s mid-traffic compile costs more than
+        # any per-request k tuning could win
         self.spec_tokens = max(1, int(speculation_tokens)) if spec_on else 0
         self._spec_index: dict[int, NGramIndex] = {}
         self.spec_dispatches_total = 0
@@ -1837,61 +1467,35 @@ class ServingEngine:
                 self._record_program, "grammar-load"
             )
             self._dfa_state_dev = jnp.zeros(max_batch, jnp.int32)
-        self._prefix_pool = None
-        pool_entries, pool_width = 0, 0
-        if enabled and not self._paged:
-            from langstream_tpu.serving.prefix_cache import (
-                pool_entries_for_fraction,
-            )
-
-            pool_width = self.prefill_buckets[-1]
-            # an EXPLICIT entry count wins outright — including 0, which
-            # disables the pool (`or` would silently re-enable it)
-            pool_entries = (
-                int(prefix_cache_entries)
-                if prefix_cache_entries is not None
-                else pool_entries_for_fraction(
-                    max_batch, self.max_seq_len, pool_width,
-                    prefix_cache_fraction,
-                )
-            )
-        # paged pool sizing: dense-parity token capacity + the prefix-cache
+        # pool sizing: every slot's max_seq_len in pages + the prefix-cache
         # fraction as ALIAS headroom (shared pages pinned by the prefix
         # index). prefix_cache_entries caps the INDEX (0 disables reuse);
         # the pages themselves live in the one pool either way.
-        self._page_fraction = (
-            prefix_cache_fraction if (enabled and self._paged) else 0.0
-        )
-        self._kv_pages = 0
-        prefix_index_entries = 0
-        if self._paged:
-            from langstream_tpu.serving.pagepool import pages_for_fraction
+        from langstream_tpu.serving.pagepool import pages_for_fraction
 
-            self._kv_pages = (
-                int(kv_pages)
-                if kv_pages is not None
-                else pages_for_fraction(
-                    max_batch, self.max_seq_len, self.page_size,
-                    self._page_fraction,
-                )
+        self._page_fraction = prefix_cache_fraction if enabled else 0.0
+        self._kv_pages = (
+            int(kv_pages)
+            if kv_pages is not None
+            else pages_for_fraction(
+                max_batch, self.max_seq_len, self.page_size,
+                self._page_fraction,
             )
-            if enabled:
-                prefix_index_entries = (
-                    int(prefix_cache_entries)
-                    if prefix_cache_entries is not None
-                    else 512
-                )
-            # the device pool itself is allocated AFTER the memory plan
-            # below has logged its arithmetic — an over-committed pool
-            # then OOMs with the plan's numbers already on record instead
-            # of an unexplained RESOURCE_EXHAUSTED
-        # compile the decode kv_bound ladder up front (TPU default): a lazy
-        # ladder compile otherwise lands MID-TRAFFIC and stalls every
-        # active stream — measured as the r5
-        # gateway bench regression (96 sessions all at 23.1s p50 TTFT
-        # because the first admission wave pushed positions+inflight past
-        # the largest warmed bound). Off by default on CPU: tests build
-        # hundreds of engines.
+        )
+        prefix_index_entries = 0
+        if enabled:
+            prefix_index_entries = (
+                int(prefix_cache_entries)
+                if prefix_cache_entries is not None
+                else 512
+            )
+        # the device pool itself is allocated AFTER the memory plan below
+        # has logged its arithmetic — an over-committed pool then OOMs with
+        # the plan's numbers already on record instead of an unexplained
+        # RESOURCE_EXHAUSTED
+        # compile every device program up front (TPU default): a lazy
+        # compile otherwise lands MID-TRAFFIC and stalls every active
+        # stream. Off by default on CPU: tests build hundreds of engines.
         self._precompile = (
             precompile
             if precompile is not None
@@ -1927,9 +1531,6 @@ class ServingEngine:
         self.spmd_recoveries_total = 0
         self.spmd_resyncs_total = 0
         self.spmd_watchdog_trips_total = 0
-        # slots whose KV rows must be zeroed on the next iteration (NaN
-        # quarantine); coalesced into ONE row-reset dispatch
-        self._pending_row_resets: list[int] = []
         # fault injection (serving/faultinject.py): explicit injector wins,
         # else env activation (LSTPU_FAULTS) for staging drills
         self._injector = (
@@ -1996,13 +1597,12 @@ class ServingEngine:
         # tests assert it stays flat (stats()["compiled_programs"]).
         self._programs: set[tuple] = set()
         # achieved-bandwidth gauge: EMA of measured decode step time + the
-        # bytes-read model from the memory plan (weights + the kv_bound
-        # slice of the cache per step) → HBM GB/s actually sustained, so the
+        # bytes-read model (the plan's weights + the live pages per step)
+        # → HBM GB/s actually sustained, so the
         # gap to the chip's roofline is a shipped metric, not a PERF.md
         # footnote
         self._step_time_ema_s: float = 0.0
         self._last_chunk_ready_t: float = 0.0
-        self._last_kv_bound: int = 0
         self._plan = None
         # HBM accounting up front: an over-committed config should announce
         # its arithmetic here, not die in an opaque RESOURCE_EXHAUSTED
@@ -2042,11 +1642,7 @@ class ServingEngine:
                 config, max_batch, self.max_seq_len, quantized_weights=quantized,
                 prefill_batch=self.prefill_batch,
                 prefill_bucket=self.prefill_buckets[-1],
-                prefill_streams=self.max_prefill_streams,
-                prefix_pool_entries=pool_entries,
-                prefix_pool_width=pool_width,
                 speculation_tokens=self.spec_tokens,
-                kv_layout=self.kv_layout,
                 page_size=self.page_size,
                 kv_pages=self._kv_pages,
                 page_fraction=self._page_fraction,
@@ -2068,7 +1664,7 @@ class ServingEngine:
                 ),
                 # role-tagged replicas (§18): budget the host-RAM staging
                 # one in-flight KV migration claims on this end
-                migrate_staging=bool(migrate_staging) and self._paged,
+                migrate_staging=bool(migrate_staging),
                 # streamed weight load (§22): the measured host staging
                 # high-water mark, so the startup log's RSS story covers
                 # the load phase the pod was health-probed through
@@ -2094,96 +1690,87 @@ class ServingEngine:
             )
         except Exception:  # noqa: BLE001 — accounting must never block serving
             log.debug("serving memory plan unavailable", exc_info=True)
-        if pool_entries > 0:
-            from langstream_tpu.serving.prefix_cache import PrefixCachePool
+        from langstream_tpu.serving.pagepool import PagePool, PrefixPageIndex
 
-            self._prefix_pool = PrefixCachePool(
-                config, pool_entries, pool_width,
-                boundaries=self.prefill_buckets, mesh=mesh,
+        # allocated AFTER the memory plan logged its arithmetic: an
+        # over-committed pool OOMs with the numbers on record
+        self._pagepool = PagePool(
+            config, self._kv_pages, self.page_size, max_batch,
+            self.max_seq_len,
+        )
+        if mesh is not None:
+            # kv heads on "model" (replicated when they don't divide) —
+            # every paged program then propagates the sharding from the
+            # pool input
+            from langstream_tpu.parallel.sharding import shard_page_pool
+
+            self._pagepool.dev = shard_page_pool(self._pagepool.dev, mesh)
+        if prefix_index_entries > 0:
+            self._prefix_index = PrefixPageIndex(
+                self.prefill_buckets, max_entries=prefix_index_entries
             )
-        if self._paged:
-            from langstream_tpu.serving.pagepool import PagePool, PrefixPageIndex
+        if self._spill_on:
+            from langstream_tpu.serving.pagepool import HostPageTier
 
-            # allocated AFTER the memory plan logged its arithmetic, like
-            # the dense prefix pool: an over-committed pool OOMs with the
-            # numbers on record
-            self._pagepool = PagePool(
-                config, self._kv_pages, self.page_size, max_batch,
-                self.max_seq_len,
+            host_pages = max(
+                1, math.ceil(self._kv_pages * self.host_kv_fraction)
             )
-            if mesh is not None:
-                # kv heads on "model" (replicated when they don't divide),
-                # same policy as the dense serving cache — every paged
-                # program then propagates the sharding from the pool input
-                from langstream_tpu.parallel.sharding import shard_page_pool
+            self._host_tier = HostPageTier(self._pagepool.dev, host_pages)
+            self._prefix_index.host_tier = self._host_tier
+            # hibernation capacity is governed by the arena alone: the
+            # index's entry cap counts (and cap-evicts) only
+            # DEVICE-resident entries, so idle hibernated sessions are
+            # never dropped to make room for a publish
+            self._spill_worker = _SpillWorker(
+                self._host_tier, self._spill_done, self._obs
+            )
+            log.info(
+                "tiered KV host arena: %d host pages (%.2f GiB RAM, "
+                "%.2fx the device pool) — idle prefixes spill after "
+                "%.1fs, LRU eviction demotes before dropping",
+                host_pages, self._host_tier.bytes_total / 1024**3,
+                self.host_kv_fraction, self.spill_idle_s,
+            )
+        if self._durable_on and self._prefix_index is not None:
+            from langstream_tpu.serving.durable import DurableStore
 
-                self._pagepool.dev = shard_page_pool(self._pagepool.dev, mesh)
-            if prefix_index_entries > 0:
-                self._prefix_index = PrefixPageIndex(
-                    self.prefill_buckets, max_entries=prefix_index_entries
+            try:
+                self._durable = DurableStore(
+                    self.durable_dir,
+                    max_bytes=self._durable_max_bytes,
+                    injector=self._injector,
                 )
-            if self._spill_on:
-                from langstream_tpu.serving.pagepool import HostPageTier
-
-                host_pages = max(
-                    1, math.ceil(self._kv_pages * self.host_kv_fraction)
+                rehydrated = self._durable.rehydrate()
+            except OSError:
+                # an unwritable volume must not fail the boot — the
+                # tier degrades to off, sessions fall back to the
+                # host tier / re-prefill exactly as with durable: off
+                log.exception(
+                    "durable tier unavailable (%s) — off", self.durable_dir
                 )
-                self._host_tier = HostPageTier(self._pagepool.dev, host_pages)
-                self._prefix_index.host_tier = self._host_tier
-                # hibernation capacity is governed by the arena alone: the
-                # index's entry cap counts (and cap-evicts) only
-                # DEVICE-resident entries, so idle hibernated sessions are
-                # never dropped to make room for a publish
-                self._spill_worker = _SpillWorker(
-                    self._host_tier, self._spill_done, self._obs
+                self._durable = None
+            if self._durable is not None:
+                self._durable_worker = _DurableWorker(
+                    self._durable, self._obs
                 )
                 log.info(
-                    "tiered KV host arena: %d host pages (%.2f GiB RAM, "
-                    "%.2fx the device pool) — idle prefixes spill after "
-                    "%.1fs, LRU eviction demotes before dropping",
-                    host_pages, self._host_tier.bytes_total / 1024**3,
-                    self.host_kv_fraction, self.spill_idle_s,
+                    "durable KV tier: %s (%d checkpointed session "
+                    "prefix(es) rehydrated%s) — hibernated arenas "
+                    "checkpoint crash-safe; sessions survive replica "
+                    "death and scale-to-zero",
+                    self.durable_dir, rehydrated,
+                    (
+                        f", cap {self._durable_max_bytes / 1024**3:.2f} GiB"
+                        if self._durable_max_bytes
+                        else ""
+                    ),
                 )
-            if self._durable_on and self._prefix_index is not None:
-                from langstream_tpu.serving.durable import DurableStore
-
-                try:
-                    self._durable = DurableStore(
-                        self.durable_dir,
-                        max_bytes=self._durable_max_bytes,
-                        injector=self._injector,
-                    )
-                    rehydrated = self._durable.rehydrate()
-                except OSError:
-                    # an unwritable volume must not fail the boot — the
-                    # tier degrades to off, sessions fall back to the
-                    # host tier / re-prefill exactly as with durable: off
-                    log.exception(
-                        "durable tier unavailable (%s) — off", self.durable_dir
-                    )
-                    self._durable = None
-                if self._durable is not None:
-                    self._durable_worker = _DurableWorker(
-                        self._durable, self._obs
-                    )
-                    log.info(
-                        "durable KV tier: %s (%d checkpointed session "
-                        "prefix(es) rehydrated%s) — hibernated arenas "
-                        "checkpoint crash-safe; sessions survive replica "
-                        "death and scale-to-zero",
-                        self.durable_dir, rehydrated,
-                        (
-                            f", cap {self._durable_max_bytes / 1024**3:.2f} GiB"
-                            if self._durable_max_bytes
-                            else ""
-                        ),
-                    )
-            elif self._durable_on:
-                log.warning(
-                    "durable tier needs the prefix index (prefix-cache: "
-                    "auto) — off"
-                )
-                self._durable_on = False
+        elif self._durable_on:
+            log.warning(
+                "durable tier needs the prefix index (prefix-cache: "
+                "auto) — off"
+            )
+            self._durable_on = False
 
     # -- public API ---------------------------------------------------------
 
@@ -2216,6 +1803,8 @@ class ServingEngine:
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
+        if self._precompile:
+            gc.unfreeze()  # the warm-up froze the heap
         self._fetcher.stop()
         if self._spill_worker is not None:
             self._spill_worker.stop()
@@ -2510,11 +2099,11 @@ class ServingEngine:
         discount. Non-mutating and thread-safe — beacon building runs on
         the runtime HTTP thread and must neither touch LRU recency nor
         leak token content."""
-        index = self._prefix_index if self._prefix_index is not None else self._prefix_pool
+        index = self._prefix_index
         if index is None:
             return (), []
         ads = index.advertised(top_k)
-        if self._prefix_index is not None and self._durable is not None:
+        if self._durable is not None:
             # checkpoints that outlived their live entry still serve (the
             # snapshot path reads them off disk): beacon them at tier
             # "durable" so the router can prefetch/route onto them —
@@ -2632,61 +2221,34 @@ class ServingEngine:
             "compiled_programs": len(self._programs),
             "decode-step-ms": round(self._step_time_ema_s * 1e3, 3),
             "hbm-gbps-decode": self._achieved_hbm_gbps(),
-            # unified paged KV pool (zeros under the dense escape hatch, so
-            # the metrics exporter sets its gauges unconditionally)
-            "kv-layout": self.kv_layout,
-            "page-size": self.page_size if self._paged else 0,
-            "kv-pages-total": (
-                self._pagepool.num_pages if self._pagepool else 0
-            ),
-            "kv-pages-in-use": (
-                self._pagepool.pages_in_use if self._pagepool else 0
-            ),
-            "kv-bytes-per-page": (
-                self._pagepool.bytes_per_page if self._pagepool else 0
-            ),
-            "kv-page-alias-rate": (
-                round(
-                    self._pagepool.aliased_pages_total
-                    / max(1, self._pagepool.reserved_pages_total),
-                    4,
-                )
-                if self._pagepool
-                else 0.0
+            # the page pool, the engine's only KV state
+            "page-size": self.page_size,
+            "kv-pages-total": self._pagepool.num_pages,
+            "kv-pages-in-use": self._pagepool.pages_in_use,
+            "kv-bytes-per-page": self._pagepool.bytes_per_page,
+            "kv-page-alias-rate": round(
+                self._pagepool.aliased_pages_total
+                / max(1, self._pagepool.reserved_pages_total),
+                4,
             ),
             "prefix-copy-bytes-saved-total": (
                 self._prefix_index.copy_bytes_saved if self._prefix_index else 0
             ),
             # prefix KV reuse (zeros with the cache off, so the metrics
-            # exporter can set its gauges unconditionally); sourced from the
-            # dense pool or the paged alias index, whichever is live
-            "prefix-cache": (
-                self._prefix_pool is not None or self._prefix_index is not None
-            ),
+            # exporter can set its gauges unconditionally)
+            "prefix-cache": self._prefix_index is not None,
             "prefix-cache-hit-rate": (
-                self._prefix_pool.hit_rate()
-                if self._prefix_pool
-                else self._prefix_index.hit_rate() if self._prefix_index else 0.0
+                self._prefix_index.hit_rate() if self._prefix_index else 0.0
             ),
             "prefill-tokens-saved-total": (
-                self._prefix_pool.tokens_saved
-                if self._prefix_pool
-                else self._prefix_index.tokens_saved if self._prefix_index else 0
+                self._prefix_index.tokens_saved if self._prefix_index else 0
             ),
-            "prefix-pool-bytes-in-use": (
-                self._prefix_pool.bytes_in_use()
-                if self._prefix_pool
-                else self._prefix_index_bytes()
-            ),
+            "prefix-pool-bytes-in-use": self._prefix_index_bytes(),
             "prefix-cache-evictions-total": (
-                self._prefix_pool.evictions
-                if self._prefix_pool
-                else self._prefix_index.evictions if self._prefix_index else 0
+                self._prefix_index.evictions if self._prefix_index else 0
             ),
             "prefix-cache-entries": (
-                self._prefix_pool.live_entries
-                if self._prefix_pool
-                else self._prefix_index.live_entries if self._prefix_index else 0
+                self._prefix_index.live_entries if self._prefix_index else 0
             ),
             # tiered KV: host-RAM spill + session hibernation (zeros with
             # the tier off, so the metrics exporter sets its gauges
@@ -2888,143 +2450,40 @@ class ServingEngine:
         deeper entries share their shallower prefixes' pages). pages_held
         is a counter the ENGINE thread maintains, so reading it from the
         metrics thread never races a _live mutation."""
-        if self._prefix_index is None or self._pagepool is None:
+        if self._prefix_index is None:
             return 0
         return self._prefix_index.pages_held * self._pagepool.bytes_per_page
 
     def _achieved_hbm_gbps(self) -> float:
-        """Bytes-read model per decode step (weights + the kv_bound-sliced
-        cache columns, from the memory plan) over the measured step time —
-        the achieved-HBM-bandwidth gauge, PER CHIP. The plan's tree is
-        global, so on a sharded mesh each chip reads only its shard per
-        step — divided per AXIS (weights shard over model×expert but
-        replicate over data; the cache shards kv heads over model only
-        when they divide), else the gauge reads a multiple of a chip's
-        bandwidth and the roofline comparison goes >100% exactly on the
-        multi-chip configs it exists to diagnose. Decode is
-        bandwidth-bound, so this ÷ the chip's spec sheet IS the utilization
-        number (the ~25%-of-roofline gap the r5 verdict flagged becomes a
-        live metric)."""
+        """Bytes-read model per decode step (the plan's weights + the pages
+        the active slots read) over the measured step time — the
+        achieved-HBM-bandwidth gauge. Decode is bandwidth-bound, so this ÷
+        the chip's spec sheet IS the utilization number."""
         if self._plan is None or self._step_time_ema_s <= 0:
             return 0.0
-        if self._paged:
-            # pages actually READ per step: each active slot streams the
-            # pages covering its written prefix — content-proportional,
-            # which is the paged layout's whole bandwidth story
-            pages_read = sum(
-                -(-(s.position + 1) // self.page_size)
-                for s in self._slots
-                if s.active
-            )
-            read = (
-                self._plan.weights_bytes
-                + self._pagepool.bytes_per_page * pages_read
-            )
-            return round(read / self._step_time_ema_s / 1e9, 2)
-        bound = min(self._last_kv_bound or self.max_seq_len, self.max_seq_len)
-        weights = self._plan.weights_bytes
-        cache = self._plan.cache_bytes * bound // max(1, self.max_seq_len)
-        if self.mesh is not None:
-            shape = dict(getattr(self.mesh, "shape", {}))
-            model_ways = max(1, shape.get("model", 1))
-            expert_ways = max(1, shape.get("expert", 1))
-            # per-axis weight division (parallel/sharding.py param_specs):
-            # ONLY the MoE expert FFN tensors carry the "expert" axis —
-            # attention/norm/embed/router weights replicate across it, so
-            # flattening model×expert over all weights under-reports on
-            # exactly the MoE meshes this gauge exists to diagnose
-            expert_w = min(self._expert_weight_bytes, weights)
-            weights = (
-                expert_w // (model_ways * expert_ways)
-                + (weights - expert_w) // model_ways
-            )
-            # the serving cache shards its kv heads over model ONLY when
-            # they divide — else it replicates (serving_cache_specs)
-            if model_ways > 1 and self.config.n_kv_heads % model_ways == 0:
-                cache //= model_ways
-        return round((weights + cache) / self._step_time_ema_s / 1e9, 2)
+        # pages actually READ per step: each active slot streams the
+        # pages covering its written prefix — content-proportional,
+        # which is the paged layout's whole bandwidth story
+        pages_read = sum(
+            -(-(s.position + 1) // self.page_size)
+            for s in self._slots
+            if s.active
+        )
+        read = (
+            self._plan.weights_bytes
+            + self._pagepool.bytes_per_page * pages_read
+        )
+        return round(read / self._step_time_ema_s / 1e9, 2)
 
     def _record_program(self, *signature) -> None:
         self._programs.add(tuple(signature))
 
     # -- engine thread ------------------------------------------------------
 
-    def _warmup_decode_ladder(self) -> None:
-        """Run one throwaway decode chunk per kv_bound ladder step so every
-        decode shape is compiled BEFORE the first request is served. Runs on
-        the engine thread; slots are all free, so the garbage the warmup
-        writes into cache/token buffers is dead state (admission rewrites
-        every row it activates) — positions/tokens are reset anyway. SPMD:
-        the whole family is announced as ONE OP_WARMUP block and the
-        follower runs this same function — both sides make the identical
-        deterministic dispatch sequence (docs/SERVING.md §14)."""
-        def warm(steps: int, bound: Optional[int], stale=()) -> None:
-            self._dev_decode(steps, list(stale), bound).block_until_ready()
-
-        bounds = _kv_bound_ladder(self.max_seq_len)
-        for i, bound in enumerate(bounds):
-            if self._stop.is_set():
-                return
-            # the first rung also warms the stale-slot temp-reset scatter
-            # with an all-out-of-bounds index (every write drops): its
-            # first real use is the first completion under traffic, which
-            # must not be a compile
-            warm(self.decode_chunk, bound, stale=[self.max_batch] if i == 0 else ())
-        floor = min(self.ttft_chunk_floor, self.decode_chunk)
-        if floor != self.decode_chunk and not self.overlap:
-            # the TTFT-shrunk chunk is its own (steps, unbounded) program —
-            # only dispatched by the legacy (overlap off) scheduler; fused
-            # iterations run full chunks only, so warming it would add a
-            # compile the engine can never use
-            warm(floor, None)
-        # no buffer reset: admission rewrites every row it activates, and
-        # leaving the (deterministic) garbage in place keeps SPMD followers
-        # — which replay this same warmup — in exact lockstep
-        self._warmup_row_reset()
-        log.info(
-            "decode ladder precompiled: bounds %s, chunk %d",
-            bounds, self.decode_chunk,
-        )
-
-    def _warmup_row_reset(self) -> None:
-        """Quarantine row-reset, warmed all-out-of-bounds (every write
-        drops, state untouched) so the first NaN-guard trip under traffic
-        is never a compile. Under SPMD both sides warm it inside the
-        replayed warmup family — the quarantine dispatch itself rides the
-        wire as OP_ROW_RESET (round 13: victim-only quarantine replaced
-        the crash-only NaN contract)."""
-        self._record_program("row-reset")
-        idxs = np.full(self.max_batch, self.max_batch, np.int32)
-        self._cache = _reset_rows(self._cache, jnp.asarray(idxs))
-        jax.block_until_ready(jax.tree.leaves(self._cache)[0])
-
-    def _warmup_verify_ladder(self) -> None:
-        """Speculative twin of _warmup_decode_ladder: one throwaway verify
-        dispatch per kv_bound rung (all-zero drafts; slots are free so the
-        garbage KV the warmup writes is dead state, exactly like the decode
-        warmup), so the (k, bound) verify surface — the ONLY decode-phase
-        programs a speculative engine dispatches — is compiled before the
-        first request. The first rung also warms the stale-slot temp-reset
-        scatter and the tail warms the quarantine row-reset, both with
-        all-out-of-bounds indexes (every write drops). Under SPMD the
-        family replays whole (OP_WARMUP), like the decode ladder."""
-        drafts = np.zeros((self.max_batch, self.spec_tokens), np.int32)
-        bounds = _kv_bound_ladder(self.max_seq_len)
-        for i, bound in enumerate(bounds):
-            if self._stop.is_set():
-                return
-            stale = [self.max_batch] if i == 0 else []
-            self._dev_verify(drafts, stale, bound).block_until_ready()
-        self._warmup_row_reset()
-        log.info(
-            "verify ladder precompiled: bounds %s, k %d",
-            bounds, self.spec_tokens,
-        )
-
     def _warmup_paged(self) -> None:
-        """Precompile the PAGED program surface before the first request:
-        ONE decode (or verify) program — the ladder the dense layout warmed
-        rung by rung no longer exists — plus the batch-1 segment family
+        """Precompile the decode-phase program surface before the first
+        request: ONE decode (or verify) program for every sequence-length
+        mix, plus the batch-1 segment family
         (warm suffixes + long-prompt chunks, one per bucket width), the
         copy-on-write page copy, and the quarantine page-zero. Every
         throwaway dispatch runs against all-out-of-bounds tables/indices:
@@ -3034,16 +2493,16 @@ class ServingEngine:
         family is warmed by _warmup_prefill_buckets as usual."""
         if self._spec_enabled:
             drafts = np.zeros((self.max_batch, self.spec_tokens), np.int32)
-            self._dev_verify(drafts, [self.max_batch], 0).block_until_ready()
+            self._dev_verify(drafts, [self.max_batch]).block_until_ready()
         else:
             self._dev_decode(
-                self.decode_chunk, [self.max_batch], None
+                self.decode_chunk, [self.max_batch]
             ).block_until_ready()
             floor = min(self.ttft_chunk_floor, self.decode_chunk)
             if floor != self.decode_chunk and not self.overlap:
                 # the TTFT-shrunk chunk is its own (steps,) program, but
                 # only the legacy (overlap off) scheduler dispatches it
-                self._dev_decode(floor, [], None).block_until_ready()
+                self._dev_decode(floor, []).block_until_ready()
         for ws in self.prefill_buckets:
             if self._stop.is_set():
                 return
@@ -3076,7 +2535,7 @@ class ServingEngine:
         jax.block_until_ready(jax.tree.leaves(pool.dev)[0])
         log.info(
             "paged programs precompiled: ONE %s program (chunk %d), %d "
-            "segment widths, page-copy, page-zero — no kv_bound ladder",
+            "segment widths, page-copy, page-zero",
             "verify" if self._spec_enabled else "decode",
             self.spec_tokens + 1 if self._spec_enabled else self.decode_chunk,
             len(self.prefill_buckets),
@@ -3124,106 +2583,6 @@ class ServingEngine:
         log.info(
             "prefill buckets precompiled: widths %s, rows %d",
             list(self.prefill_buckets), n_pad,
-        )
-
-    def _warmup_prefix_programs(self) -> None:
-        """Warm every program a warm admission can dispatch — publish, the
-        gather at every local-cache width (pool width for short prompts
-        plus the pow2 long-prompt ladder), the pool-width insert, and all
-        reachable suffix-segment shapes — with all-dropped / throwaway
-        dispatches, so NO prefix-cache code path ever compiles
-        mid-traffic (the compiled_programs-flat guarantee; the
-        chain-scatter is warmed unconditionally in
-        _warmup_prefill_buckets)."""
-        from langstream_tpu.ops.kvcopy import gather_prefix_local, publish_prefix_rows
-
-        pool = self._prefix_pool
-        assert pool is not None
-        # publish with an out-of-bounds entry row: every write drops
-        self._record_program("prefix-publish")
-        pool.dev = publish_prefix_rows(
-            pool.dev, self._cache,
-            jnp.asarray(0, jnp.int32), jnp.asarray(pool.entries, jnp.int32),
-        )
-        # gather ladder: pool width (short warm admissions) + every
-        # _long_width value (warm long-prompt starts) — O(log) programs,
-        # the decode-ladder policy. Each throwaway local frees before the
-        # next, so peak transient = one long-prefill cache (plan term).
-        widths = [pool.width]
-        w = pool.width
-        while w < self.max_seq_len:
-            w *= 2
-            widths.append(min(w, self.max_seq_len))
-        local = None
-        for width in dict.fromkeys(widths):
-            if self._stop.is_set():
-                return
-            self._record_program("prefix-gather", width)
-            got = gather_prefix_local(
-                pool.dev, jnp.asarray(0, jnp.int32), self.config, width
-            )
-            if width == pool.width:
-                local = got
-            else:
-                jax.block_until_ready(got)
-        # the warm-admission insert at pool width; slot out of bounds → drop
-        self._record_program("insert", pool.width)
-        self._cache = self._insert_group(
-            self._cache, local, jnp.asarray(np.full(1, self.max_batch, np.int32))
-        )
-        jax.block_until_ready(self._cache)
-        # suffix-segment shapes: a warm SHORT admission prefills one
-        # (ws ∈ buckets) segment into a pool-width local cache at a
-        # kv_bound from ws's doubling ladder — shapes nothing else
-        # compiles (cold admissions use admit_group; long prompts use
-        # t_long ≥ 2× pool width). Warm every reachable pair so the first
-        # prefix HIT per shape is never the 15-23s stall that would make
-        # the cache slower than no cache until amortized. O(|buckets| ×
-        # log) programs, the same front-load-the-compiles policy as the
-        # decode ladder; offset/lengths are traced so one throwaway
-        # dispatch per shape covers all reuse offsets. The PRNG key
-        # advances per dispatch — before any request is served, like the
-        # bucket warmup.
-        segment_shapes = []
-        for ws in self.prefill_buckets:
-            bound = ws
-            while True:
-                segment_shapes.append((ws, min(bound, pool.width)))
-                if bound >= pool.width:
-                    break
-                bound *= 2
-        for ws, bound in dict.fromkeys(segment_shapes):
-            if self._stop.is_set():
-                return
-            throwaway = gather_prefix_local(
-                pool.dev, jnp.asarray(0, jnp.int32), self.config, pool.width
-            )
-            self._record_program("segment", ws, bound, pool.width)
-            kw = self._segment_agentic_kwargs(None, self.max_batch)
-            first, throwaway, self._key, state_dev = (
-                _prefill_segment_and_sample(
-                    self.params,
-                    jnp.zeros((1, ws), jnp.int32),
-                    jnp.zeros(1, jnp.int32),
-                    jnp.ones(1, jnp.int32),
-                    throwaway,
-                    self._key,
-                    jnp.zeros(1, jnp.float32),
-                    jnp.zeros(1, jnp.int32),
-                    jnp.ones(1, jnp.float32),
-                    self.config,
-                    bound,
-                    **kw,
-                )
-            )
-            if state_dev is not None:
-                self._dfa_state_dev = state_dev
-            jax.block_until_ready(first)
-        log.info(
-            "prefix-cache programs precompiled: pool %d×%d, gather widths %s, "
-            "%d suffix-segment shapes",
-            pool.entries, pool.width, list(dict.fromkeys(widths)),
-            len(dict.fromkeys(segment_shapes)),
         )
 
     def _run(self) -> None:
@@ -3395,29 +2754,24 @@ class ServingEngine:
                     wire.ControlBlock(op=wire.OP_WARMUP, count=kind)
                 )
 
-        if self._paged:
-            # the whole point of the paged layout: the decode-phase
-            # surface is ONE program (per step count), not a ladder
-            announce_warmup(wire.WARMUP_PAGED)
-            self._warmup_paged()
-        elif self._spec_enabled:
-            # a speculative engine dispatches the verify ladder instead
-            # of decode chunks — warming both would double startup time
-            # for programs it can never run
-            announce_warmup(wire.WARMUP_VERIFY_LADDER)
-            self._warmup_verify_ladder()
-        else:
-            announce_warmup(wire.WARMUP_DECODE_LADDER)
-            self._warmup_decode_ladder()
+        # the decode-phase surface is ONE program (per step count)
+        announce_warmup(wire.WARMUP_PAGED)
+        self._warmup_paged()
         announce_warmup(wire.WARMUP_PREFILL_BUCKETS)
         self._warmup_prefill_buckets()
-        if self._prefix_pool is not None:
-            announce_warmup(wire.WARMUP_PREFIX_PROGRAMS)
-            self._warmup_prefix_programs()
         if self._agentic:
             # no announce: the agentic tier is construction-disabled
             # under SPMD, so this warmup never runs on a replica
             self._warmup_agentic()
+        # what the process has built by now lives as long as it serves: put
+        # it out of the collector's reach, so that a full collection costs
+        # what was allocated since. A pass over the ~700,000 objects of a
+        # serving process stops every thread for 238 ms, once inside 50 s
+        # of chat traffic: each stream's tokens late, and the device idle
+        # for some 7 ms when it begins while a chunk's tokens are delivered
+        # (PERF.md §6, PR 29). stop() gives it back.
+        gc.collect()
+        gc.freeze()
 
     def _run_once(self) -> None:
         from collections import deque
@@ -3466,15 +2820,8 @@ class ServingEngine:
                 finished.append((request, result))
         for idx in list(self._longs):
             st = self._longs.pop(idx)
-            entry = st.pop("prefix", None)
-            if entry is not None and self._prefix_pool is not None:
-                try:
-                    self._prefix_pool.release(entry)
-                except Exception:  # noqa: BLE001 — pool resets below anyway
-                    pass
             quarantined += 1
             self._reserved.discard(idx)
-            self._long_caches.pop(idx, None)
             finished.append((st["request"], GenerationResult(
                 tokens=[], finish_reason="error", prompt_tokens=0,
                 ttft_s=0, total_s=0, error=error,
@@ -3482,7 +2829,6 @@ class ServingEngine:
         with self._stats_lock:
             self.quarantined_slots_total += quarantined
         self._longs.clear()
-        self._long_caches.clear()
         self._reserved.clear()
         for request, result in finished:
             request._finish(result)
@@ -3517,81 +2863,70 @@ class ServingEngine:
         epoch so even SAMPLED streams stay host-identical after a
         recovery (the crashed dispatch may have consumed the key on one
         side only)."""
-        self._spmd_ring_buf.clear()
         self._freed_slots.clear()
         self._spec_index.clear()
-        self._pending_row_resets.clear()
         self._step_time_ema_s = 0.0
         self._last_chunk_ready_t = 0.0
         self._last_ready_t = 0.0
         self._moe_dev = None
-        # fresh device state (same shapes → no recompiles on restart)
-        if self._paged:
-            # pool buffer is donation-suspect like the dense cache; the
-            # allocator and every table reset with it (the in-flight slots
-            # whose pages they tracked were just failed above). Queued and
-            # page-deferred admissions keep their backlog spots.
-            self._pending_page_zero.clear()
-            # tiered KV: quiesce the spill worker BEFORE resetting the
-            # arena (stop() completes in-flight copies first, so no thread
-            # writes a slot the fresh free list is about to re-issue);
-            # stale done-handles are fenced off by the generation bump
-            spill_quiesced = True
-            if self._spill_worker is not None:
-                spill_quiesced = self._spill_worker.stop()
-            self._spill_gen += 1
-            self._spill_candidates.clear()
-            while True:
-                try:
-                    self._spill_done.get_nowait()
-                except queue.Empty:
-                    break
-            if self._host_tier is not None:
-                if spill_quiesced:
-                    self._host_tier.reset()
-                else:
-                    # the worker is wedged past the join timeout (hung
-                    # device fetch — the very failure mode recovery
-                    # handles) and may still write into whatever arena it
-                    # holds a reference to. Resetting THAT arena would let
-                    # the late write land in a slot the fresh free list
-                    # re-issued, with a valid checksum: silent wrong KV at
-                    # a later restore. Abandon arena AND worker — the
-                    # straggler's writes land in orphaned memory
-                    log.error(
-                        "spill worker failed to quiesce — abandoning the "
-                        "host arena (%.2f GiB) and spawning a fresh one",
-                        self._host_tier.bytes_total / 1024**3,
-                    )
-                    from langstream_tpu.serving.pagepool import HostPageTier
-
-                    self._host_tier = HostPageTier(
-                        self._pagepool.dev, self._host_tier.num_pages
-                    )
-                    if self._prefix_index is not None:
-                        self._prefix_index.host_tier = self._host_tier
-                    self._spill_worker = _SpillWorker(
-                        self._host_tier, self._spill_done, self._obs
-                    )
-            self._pagepool.reset()
-            if self.mesh is not None:
-                from langstream_tpu.parallel.sharding import shard_page_pool
-
-                self._pagepool.dev = shard_page_pool(
-                    self._pagepool.dev, self.mesh
+        # fresh device state (same shapes → no recompiles on restart): the
+        # pool buffer is donation-suspect; the allocator and every table
+        # reset with it (the in-flight slots whose pages they tracked were
+        # just failed above). Queued and page-deferred admissions keep their
+        # backlog spots.
+        self._pending_page_zero.clear()
+        # tiered KV: quiesce the spill worker BEFORE resetting the
+        # arena (stop() completes in-flight copies first, so no thread
+        # writes a slot the fresh free list is about to re-issue);
+        # stale done-handles are fenced off by the generation bump
+        spill_quiesced = True
+        if self._spill_worker is not None:
+            spill_quiesced = self._spill_worker.stop()
+        self._spill_gen += 1
+        self._spill_candidates.clear()
+        while True:
+            try:
+                self._spill_done.get_nowait()
+            except queue.Empty:
+                break
+        if self._host_tier is not None:
+            if spill_quiesced:
+                self._host_tier.reset()
+            else:
+                # the worker is wedged past the join timeout (hung
+                # device fetch — the very failure mode recovery
+                # handles) and may still write into whatever arena it
+                # holds a reference to. Resetting THAT arena would let
+                # the late write land in a slot the fresh free list
+                # re-issued, with a valid checksum: silent wrong KV at
+                # a later restore. Abandon arena AND worker — the
+                # straggler's writes land in orphaned memory
+                log.error(
+                    "spill worker failed to quiesce — abandoning the "
+                    "host arena (%.2f GiB) and spawning a fresh one",
+                    self._host_tier.bytes_total / 1024**3,
                 )
-            if self._prefix_index is not None:
-                self._prefix_index.reset()
-            if self._spill_worker is not None:
-                self._spill_worker.start()
-        else:
-            self._cache = make_kv_cache(
-                self.config, self.max_batch, self.max_seq_len
-            )
-            if self.mesh is not None:
-                from langstream_tpu.parallel.sharding import shard_serving_cache
+                from langstream_tpu.serving.pagepool import HostPageTier
 
-                self._cache = shard_serving_cache(self._cache, self.mesh)
+                self._host_tier = HostPageTier(
+                    self._pagepool.dev, self._host_tier.num_pages
+                )
+                if self._prefix_index is not None:
+                    self._prefix_index.host_tier = self._host_tier
+                self._spill_worker = _SpillWorker(
+                    self._host_tier, self._spill_done, self._obs
+                )
+        self._pagepool.reset()
+        if self.mesh is not None:
+            from langstream_tpu.parallel.sharding import shard_page_pool
+
+            self._pagepool.dev = shard_page_pool(
+                self._pagepool.dev, self.mesh
+            )
+        if self._prefix_index is not None:
+            self._prefix_index.reset()
+        if self._spill_worker is not None:
+            self._spill_worker.start()
         self._tokens_dev = jnp.zeros(self.max_batch, jnp.int32)
         self._positions_dev = jnp.zeros(self.max_batch, jnp.int32)
         self._temp_dev = jnp.zeros(self.max_batch, jnp.float32)
@@ -3599,10 +2934,6 @@ class ServingEngine:
         self._top_p_dev = jnp.ones(self.max_batch, jnp.float32)
         if self._dfa_state_dev is not None:
             self._dfa_state_dev = jnp.zeros(self.max_batch, jnp.int32)
-        if self._prefix_pool is not None:
-            # pool rows may hold rows published from the poisoned cache (or
-            # the pool buffer itself may be donation-invalidated mid-publish)
-            self._prefix_pool.reset()
         if reset_key:
             self._key = jax.random.PRNGKey(self._rng_seed + self._spmd_epoch)
 
@@ -3636,8 +2967,6 @@ class ServingEngine:
             # iteration top, OUTSIDE any dispatch's announce sequence
             if self._spmd is not None:
                 self._spmd_tick()
-            if self._pending_row_resets:
-                self._flush_row_resets()
             if self._pending_page_zero:
                 self._flush_page_zeros()
             # tiered KV: fold completed spills in and start hibernation spills
@@ -3661,6 +2990,21 @@ class ServingEngine:
             # site injects a synthetic aggressor burst at the iteration top
             if self._injector is not None:
                 self._tenant_burst_tick()
+            # What is dispatched below queues behind the chunk in flight, so
+            # an admission decided now cannot start on the device for that
+            # chunk's whole length, and whoever reaches the queue a moment
+            # after the decision waits out another chunk. Spend a tenth of
+            # it waiting (25 ms of a 250 ms chat chunk; skipped where that
+            # is under one interpreter switch interval, so a fast model
+            # never waits, nor an idle engine, a cold start or the
+            # speculative loop, which hold nothing unfetched): the loop
+            # thread, held off the interpreter while this thread delivered,
+            # hands over the requests it holds, arrivals a few ms apart
+            # share one group, and a chat schedule no longer turns on which
+            # thread won the interpreter (PERF.md §6, PR 29).
+            grace = 0.1 * self._step_time_ema_s * self.decode_chunk
+            if pending and grace >= sys.getswitchinterval():
+                time.sleep(grace)
         t_sweep = time.monotonic() if obs_on else 0.0
         with jax.profiler.TraceAnnotation("engine.admit"):
             # chunks dispatched in previous iterations are still unfetched when
@@ -3953,29 +3297,6 @@ class ServingEngine:
                 with self._waiting_lock:
                     self._waiting.pop(id(request), None)
                 self._count_shed(self.BURST_TENANT)
-
-    def _flush_row_resets(self) -> None:
-        """Zero the KV rows of NaN-quarantined slots, coalesced into one
-        row-reset dispatch per iteration. SPMD: the dispatch rides the
-        wire (OP_ROW_RESET) so followers zero the same rows — victim-only
-        quarantine holds on every host (docs/SERVING.md §14)."""
-        stale = sorted(set(self._pending_row_resets))
-        self._pending_row_resets.clear()
-        if self._spmd is not None:
-            self._spmd.announce(wire.ControlBlock(
-                op=wire.OP_ROW_RESET, n_rows=len(stale),
-                slots=np.asarray(stale, np.int32),
-            ))
-        self._dev_row_reset(stale)
-
-    def _dev_row_reset(self, stale) -> None:
-        """Device layer of the coalesced quarantine row zero (leader + SPMD
-        followers): one fixed-shape traced-index dispatch, out-of-bounds
-        padding rows drop."""
-        idxs = np.full(self.max_batch, self.max_batch, np.int32)
-        idxs[: len(stale)] = list(stale)
-        self._record_program("row-reset")
-        self._cache = _reset_rows(self._cache, jnp.asarray(idxs))
 
     @staticmethod
     def _batch_ready(batch: list[tuple]) -> bool:
@@ -4454,10 +3775,7 @@ class ServingEngine:
                 self.quarantined_slots_total += 1
             # restore the dispatch-facing row before anything dispatches
             self._adapter_rows[i] = self._adapter_rows_auth[i]
-            if self._paged:
-                self._quarantine_pages(i)
-            else:
-                self._pending_row_resets.append(i)
+            self._quarantine_pages(i)
             self._flight_dump("adapter-quarantine", extra={"slot": i})
             self._finish_slot(
                 i, "error",
@@ -4529,7 +3847,7 @@ class ServingEngine:
         # page exhaustion gate, sampled ONCE per iteration: while deferred
         # admissions wait for pool pages, only they retry — the queue keeps
         # its entries (and its submit()-side backpressure/shedding)
-        allow_new = not (self._paged and self._page_deferred)
+        allow_new = not self._page_deferred
         # fair-share slot division (docs/SERVING.md §19): tenants admitted
         # THIS call count toward their share immediately, so one pop loop
         # cannot hand a bursting tenant every free slot before the skip
@@ -4601,35 +3919,15 @@ class ServingEngine:
         if not pairs:
             return []
         entries: list[tuple] = []
-        # paged: reserve every admission's worst-case pages up front (defer
+        # reserve every admission's worst-case pages up front (defer
         # on exhaustion — never corrupt) and peel prefix-ALIAS hits off to
         # their one-dispatch warm path; the rest take the batched cold
         # admission below with their pages already bound
-        if self._paged:
-            cold_paged: list[tuple[int, GenerationRequest]] = []
-            for idx, request in pairs:
-                if self._paged_admit_one(idx, request, entries) == "cold":
-                    cold_paged.append((idx, request))
-            pairs = cold_paged
-        # prefix reuse (dense): peel off requests whose longest cached prefix
-        # can be extended in place (gather + suffix-only segment prefill);
-        # the rest take the batched cold admission below
-        if self._prefix_pool is not None:
-            cold: list[tuple[int, GenerationRequest]] = []
-            for idx, request in pairs:
-                # an adapter tenant's prefix KV carries its wk/wv deltas —
-                # never publish it under the shared trie, never reuse the
-                # base trie for it (same rule on the paged alias path)
-                hit = (
-                    None
-                    if getattr(request.options, "adapter", None)
-                    else self._prefix_lookup(request.prompt_tokens)
-                )
-                if hit is not None:
-                    entries.extend(self._prefill_prefix(idx, request, *hit))
-                else:
-                    cold.append((idx, request))
-            pairs = cold
+        cold_paged: list[tuple[int, GenerationRequest]] = []
+        for idx, request in pairs:
+            if self._paged_admit_one(idx, request, entries) == "cold":
+                cold_paged.append((idx, request))
+        pairs = cold_paged
         groups: dict[int, list[tuple[int, GenerationRequest]]] = {}
         for idx, request in pairs:
             width = self._bucket(len(request.prompt_tokens))
@@ -4652,8 +3950,7 @@ class ServingEngine:
                         raise
                     log.exception("prefill failed for a batch of %d requests", len(sub))
                     for idx, request in sub:
-                        if self._paged:
-                            self._free_slot_pages(idx)  # reserved at admit
+                        self._free_slot_pages(idx)  # reserved at admit
                         request._finish(GenerationResult(
                             tokens=[], finish_reason="error", prompt_tokens=0,
                             ttft_s=0, total_s=0, error=e,
@@ -4777,52 +4074,18 @@ class ServingEngine:
             self._injector.fire("prefill")  # before any state mutates
         n = len(tokens)
         assert all(len(a) == n for a in (lengths, temps, top_ks, top_ps, slots))
-        if self._paged:
-            return self._dev_paged_prefill(
-                tokens, lengths, temps, top_ks, top_ps, slots,
-                arows=arows, g_rows=g_rows, g_state0=g_state0,
-            )
-        self._record_program("prefill", tokens.shape[1], n)
-        # pack the per-row scalars into one upload
-        meta = np.stack([lengths, temps, top_ks, top_ps]).astype(np.float32)
-        kw = self._agentic_admit_kwargs(n, arows, g_rows, g_state0)
-        (
-            first,
-            self._cache,
-            self._tokens_dev,
-            self._positions_dev,
-            self._temp_dev,
-            self._top_k_dev,
-            self._top_p_dev,
-            self._key,
-            state_dev,
-            self._moe_dev,
-        ) = self._admit_group(
-            self.params,
-            self._cache,
-            self._tokens_dev,
-            self._positions_dev,
-            self._temp_dev,
-            self._top_k_dev,
-            self._top_p_dev,
-            self._key,
-            jnp.asarray(tokens),
-            jnp.asarray(meta),
-            jnp.asarray(slots),
-            self.config,
-            **kw,
+        return self._dev_paged_prefill(
+            tokens, lengths, temps, top_ks, top_ps, slots,
+            arows=arows, g_rows=g_rows, g_state0=g_state0,
         )
-        if state_dev is not None:
-            self._dfa_state_dev = state_dev
-        return first
 
     def _dev_paged_prefill(
         self, tokens, lengths, temps, top_ks, top_ps, slots,
         arows=None, g_rows=None, g_state0=None,
     ):
-        """Paged device layer of a batched cold prefill: the SAME fused
-        local-cache forward as the dense admit group (token-exactness), but
-        the insert scatters into each row's reserved pages. Rows whose slot
+        """Paged device layer of a batched cold prefill: the fused
+        local-cache forward (``prefill``), whose insert scatters into each
+        row's reserved pages. Rows whose slot
         is out of bounds (padding, warmups) carry an all-sentinel table —
         every write drops."""
         pool = self._pagepool
@@ -4866,109 +4129,6 @@ class ServingEngine:
             self._dfa_state_dev = state_dev
         return first
 
-    # -- prefix KV reuse -----------------------------------------------------
-
-    def _prefix_lookup(
-        self, prompt: list[int], full_width_only: bool = False
-    ) -> Optional[tuple]:
-        """Longest usable cached prefix for this prompt as ``(p, entry)``,
-        recording the lookup in the pool's hit-rate stats. ``p`` may be
-        SHORTER than the entry (reusing the first p columns of a deeper
-        prefix). Short path: reject lengths where the suffix segment's
-        bucket padding would overhang the pool-width local cache (the
-        clamp-scatter would corrupt the last real column). Long path
-        (``full_width_only``): only a full-segment-width prefix keeps the
-        chunked-prefill segment grid aligned with the local cache."""
-        pool = self._prefix_pool
-        assert pool is not None
-        best = None
-        for p, entry in pool.candidates(prompt):  # ascending by p
-            if full_width_only:
-                if p == pool.width:
-                    best = (p, entry)
-            elif p + self._bucket(len(prompt) - p) <= pool.width:
-                best = (p, entry)
-        pool.record_lookup(best[1] if best else None)
-        return best
-
-    def _prefill_prefix(
-        self, idx: int, request: GenerationRequest, p: int, entry
-    ) -> list[tuple]:
-        """Warm admission: gather the cached prefix (pool row → pool-width
-        local cache), prefill ONLY the suffix as one segment at offset
-        ``p``, insert, and scatter the decode chain — the cold path minus
-        the prefix's prefill FLOPs and cache writes. The entry is pinned
-        for the span of the dispatch so eviction can never hand its row to
-        a concurrent publish mid-read."""
-        pool = self._prefix_pool
-        prompt = request.prompt_tokens
-        suffix = prompt[p:]
-        ws = self._bucket(len(suffix))
-        t_pool = pool.width
-        # static pow2-multiple cap on readable columns, same ladder as the
-        # chunked-prefill segments: the suffix never attends past p + ws
-        kv_bound = ws
-        while kv_bound < min(p + ws, t_pool):
-            kv_bound *= 2
-        kv_bound = min(kv_bound, t_pool)
-        tokens = np.zeros((1, ws), np.int32)
-        tokens[0, : len(suffix)] = suffix
-        opts = request.options
-        started = time.monotonic()
-        disp = self._new_segment_dispatch(
-            "_prefill_segment_and_sample", ws, len(suffix), request
-        )
-        pool.acquire(entry)
-        if self._spmd is not None:
-            # warm admission on the wire: the follower replays the same
-            # gather(entry.row) + suffix segment + insert + chain scatter
-            # (the radix lookup that CHOSE the entry stays leader-only)
-            self._spmd.announce(wire.ControlBlock(
-                op=wire.OP_PREFIX_ADMIT, width=ws, n_rows=1, tokens=tokens,
-                s0=p, seg_len=len(suffix), kv_bound=kv_bound,
-                entry_row=entry.row, long_idx=idx,
-                temps=np.asarray([opts.temperature], np.float32),
-                top_ks=np.asarray([opts.top_k], np.int32),
-                top_ps=np.asarray([opts.top_p], np.float32),
-            ))
-        try:
-            first = self._dev_prefix_admit(
-                tokens, p, len(suffix), kv_bound, entry.row,
-                opts.temperature, opts.top_k, opts.top_p, idx,
-                agentic_rows=request._agentic_rows,
-            )
-        except Exception as e:  # noqa: BLE001 — fail the request, not the engine
-            if self._spmd is not None:
-                raise  # multi-host: crash the replica (see _admit rationale)
-            log.exception("prefix-reuse prefill failed (p=%d)", p)
-            request._finish(GenerationResult(
-                tokens=[], finish_reason="error", prompt_tokens=0,
-                ttft_s=0, total_s=0, error=e,
-            ))
-            return []
-        finally:
-            pool.release(entry)
-        pool.tokens_saved += p
-        slot = self._slots[idx]
-        slot.request = request
-        slot.position = len(prompt)
-        slot.generated = []
-        slot.started_at = started
-        slot.first_token_at = 0.0
-        slot.reset_obs("warm", 1, self._dispatch_seq)
-        self._slot_bind_agentic(idx, request)
-        with self._stats_lock:
-            self.total_requests += 1
-        self._note_tenant_admitted(request)
-        self._spec_admit(idx, prompt)
-        # the prompt may extend past the reused prefix's bucket boundary:
-        # publish the deeper prefix so the next lookup reuses more
-        self._maybe_publish(idx, prompt)
-        return [(
-            "prefill", self._fetcher.submit(first, self._dispatch_seq),
-            [(idx, request)], disp,
-        )]
-
     def _segment_agentic_kwargs(self, agentic_rows, state_slot) -> dict:
         """Agentic kwargs for the batch-1 segment programs (warm suffix /
         long-prompt chunks). ``state_slot`` out of bounds (non-final
@@ -4987,61 +4147,6 @@ class ServingEngine:
             kw["state_slot"] = jnp.asarray(state_slot, jnp.int32)
             kw["state0"] = jnp.asarray([state0], jnp.int32)
         return kw
-
-    def _dev_prefix_admit(
-        self, tokens, offset, seg_len, kv_bound, entry_row,
-        temperature, top_k, top_p, idx, agentic_rows=None,
-    ):
-        """Device layer of a warm admission: prefix gather + suffix segment
-        + big-cache insert + decode-chain scatters. The segment and insert
-        programs are the SAME shapes the chunked-prefill path compiles
-        (local width = pool width = the largest bucket), so reuse adds only
-        the gather/publish pair to the program surface."""
-        from langstream_tpu.ops.kvcopy import gather_prefix_local
-
-        pool = self._prefix_pool
-        t_pool = pool.width
-        self._record_program("prefix-gather", t_pool)
-        local = gather_prefix_local(
-            pool.dev, jnp.asarray(entry_row, jnp.int32), self.config, t_pool
-        )
-        if self.mesh is not None:
-            from langstream_tpu.parallel.sharding import shard_serving_cache
-
-            local = shard_serving_cache(local, self.mesh)
-        self._record_program("segment", tokens.shape[1], kv_bound, t_pool)
-        kw = self._segment_agentic_kwargs(agentic_rows, idx)
-        first, local, self._key, state_dev = _prefill_segment_and_sample(
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray([offset], jnp.int32),
-            jnp.asarray([seg_len], jnp.int32),
-            local,
-            self._key,
-            jnp.asarray([temperature], jnp.float32),
-            jnp.asarray([top_k], jnp.int32),
-            jnp.asarray([top_p], jnp.float32),
-            self.config,
-            kv_bound,
-            **kw,
-        )
-        if state_dev is not None:
-            self._dfa_state_dev = state_dev
-        self._record_program("insert", t_pool)
-        self._cache = self._insert_group(
-            self._cache, local, jnp.asarray(np.full(1, idx, np.int32))
-        )
-        self._record_program("chain-scatter")
-        (
-            self._tokens_dev, self._positions_dev, self._temp_dev,
-            self._top_k_dev, self._top_p_dev,
-        ) = _chain_scatter(
-            self._tokens_dev, self._positions_dev, self._temp_dev,
-            self._top_k_dev, self._top_p_dev,
-            jnp.asarray(idx, jnp.int32), first, offset + seg_len,
-            temperature, top_k, top_p,
-        )
-        return first
 
     # -- paged admission / prefix aliasing -----------------------------------
 
@@ -5303,9 +4408,7 @@ class ServingEngine:
     ) -> None:
         """Warm paged admission: the aliased pages are ALREADY in the slot's
         table (_paged_bind), so all that runs on device is ONE fused
-        suffix-segment dispatch. Compare the dense warm path: pool-width
-        gather + segment + insert + chain scatter — four dispatches and a
-        pool-width row duplicated per hit."""
+        suffix-segment dispatch."""
         pool = self._pagepool
         prompt = request.prompt_tokens
         suffix = prompt[p:]
@@ -5426,9 +4529,8 @@ class ServingEngine:
     def _dispatch_tables(self, mask: Optional[np.ndarray] = None) -> np.ndarray:
         """Page tables for a decode/verify dispatch, with every non-ACTIVE
         slot's row masked to the out-of-bounds sentinel. A decode step
-        computes (garbage) K/V for inactive rows too; on the dense layout
-        those writes landed in the inactive slot's own cache row
-        (harmless), but a paged table row may belong to a RESERVED
+        computes (garbage) K/V for inactive rows too, and a
+        table row may belong to a RESERVED
         long-prefill stream whose pages are mid-prefill — an unmasked
         dispatch would scribble stale-position garbage straight into them.
         Masked rows drop their writes and read clamped (masked) garbage,
@@ -6173,10 +5275,10 @@ class ServingEngine:
         from langstream_tpu.serving.pagepool import prefix_digest
 
         pool, index = self._pagepool, self._prefix_index
-        if pool is None or index is None:
+        if index is None:
             raise MigrationError(
-                "KV-page migration needs the paged layout with a prefix "
-                "index (kv-layout: paged, prefix-cache: auto)"
+                "KV-page migration needs the prefix index "
+                "(prefix-cache: auto)"
             )
         if kind == "snapshot":
             hit = index.deepest_entry(payload["tokens"])
@@ -6372,10 +5474,6 @@ class ServingEngine:
 
         if self._dead is not None:
             raise MigrationError("engine is stopped") from self._dead
-        if not self._paged:
-            raise MigrationError(
-                "KV-page migration requires kv-layout: paged"
-            )
         if self._spmd is not None:
             raise MigrationError(
                 "KV-page migration is not on the SPMD wire yet (the bind/"
@@ -6475,21 +5573,15 @@ class ServingEngine:
             self._spec_index[idx] = index
 
     def _maybe_publish(self, idx: int, prompt: list[int]) -> None:
-        """Copy-on-publish after a completed prefill: the slot's bucket-
-        aligned prefix KV rows go into a pool row (one jitted gather-
-        scatter), unless that prefix is already cached or every row is
-        pinned by an in-flight admission (publish never blocks, never
-        evicts a row being read).
+        """Publish after a completed prefill, unless that prefix is already
+        indexed: pure HOST bookkeeping — the slot's leading pages join the
+        index with a refcount bump, no device copy at all.
 
         Speculation invariant: publish boundaries are PROMPT-prefix rows
         (p ≤ len(prompt)) written by prefill — never generated-region rows,
         where a verify chunk may have written past the ACCEPTED length and
         left stale rejected-draft K/V. Accepted-length, not written-length,
-        is the only boundary the pool may ever see.
-
-        Paged layout: publish is pure HOST bookkeeping — the slot's leading
-        pages join the index with a refcount bump, no device copy at all
-        (the dense path's copy-on-publish gather is gone).
+        is the only boundary the index may ever see.
 
         Adapter invariant: a tenant slot's prefix KV embeds its wk/wv
         adapter deltas — publishing it under the shared (base) trie would
@@ -6497,58 +5589,27 @@ class ServingEngine:
         never publish."""
         if self._adapters is not None and self._adapter_rows_auth[idx] != 0:
             return
-        if self._paged:
-            index = self._prefix_index
-            if index is None:
-                return
-            p = index.publish_length(len(prompt))
-            if p <= 0 or index.has(prompt, p):
-                return
-            import math as _math
-
-            pool = self._pagepool
-            n = _math.ceil(p / self.page_size)
-            owned = pool.slot_pages(idx)
-            if len(owned) < n:
-                return  # reservation narrower than the boundary (can't
-                # happen for a prompt that reached p; guard anyway)
-            entry = index.insert(pool, prompt, p, tuple(owned[:n]))
-            if entry is not None and self._spill_on:
-                # hibernation candidate: once idle past spill-idle-s the
-                # sweep spills its pages host-side (published prefix pages
-                # are stable — positions only grow — so the copy is valid
-                # even while the publisher keeps decoding)
-                self._spill_candidates.append(entry)
+        index = self._prefix_index
+        if index is None:
             return
-        pool = self._prefix_pool
-        if pool is None:
+        p = index.publish_length(len(prompt))
+        if p <= 0 or index.has(prompt, p):
             return
-        p = pool.publish_length(len(prompt))
-        if p <= 0 or pool.has(prompt, p):
-            return
-        row = pool.allocate()
-        if row is None:
-            return  # every row pinned — skip, don't stall admission
-        if self._spmd is not None:
-            # the allocate/evict decision above is leader-only host state;
-            # only the device copy (slot row → pool row) needs the wire
-            self._spmd.announce(wire.ControlBlock(
-                op=wire.OP_PREFIX_PUBLISH, long_idx=idx, entry_row=row,
-            ))
-        self._dev_prefix_publish(idx, row)
-        pool.insert(prompt, p, row)
+        import math as _math
 
-    def _dev_prefix_publish(self, idx: int, row: int) -> None:
-        """Device layer of the dense copy-on-publish (leader + SPMD
-        followers): one jitted gather-scatter, slot cache rows → pool row."""
-        from langstream_tpu.ops.kvcopy import publish_prefix_rows
-
-        pool = self._prefix_pool
-        self._record_program("prefix-publish")
-        pool.dev = publish_prefix_rows(
-            pool.dev, self._cache,
-            jnp.asarray(idx, jnp.int32), jnp.asarray(row, jnp.int32),
-        )
+        pool = self._pagepool
+        n = _math.ceil(p / self.page_size)
+        owned = pool.slot_pages(idx)
+        if len(owned) < n:
+            return  # reservation narrower than the boundary (can't
+            # happen for a prompt that reached p; guard anyway)
+        entry = index.insert(pool, prompt, p, tuple(owned[:n]))
+        if entry is not None and self._spill_on:
+            # hibernation candidate: once idle past spill-idle-s the
+            # sweep spills its pages host-side (published prefix pages
+            # are stable — positions only grow — so the copy is valid
+            # even while the publisher keeps decoding)
+            self._spill_candidates.append(entry)
 
     def _chunk_steps(self) -> int:
         """Power-of-two chunk bounded by every active slot's cache headroom.
@@ -6572,7 +5633,7 @@ class ServingEngine:
         whose first dispatch lands exactly when the first real burst does
         (the gateway bench's first burst sat behind ONE ('decode', 4, 0)
         compile). Full chunks
-        only ⇒ the decode compile surface is the kv_bound ladder, period —
+        only ⇒ the decode compile surface is ONE program, period —
         tail/headroom overshoot lands on OOB scatters XLA drops, and the
         host stops delivering at max_new_tokens / cache end as always.
         The conscious cost: the legacy remaining-tokens clamp is gone too,
@@ -6615,8 +5676,8 @@ class ServingEngine:
             for s in self._slots
             if s.active
         )
-        # QUANTIZE to exactly two step counts: every distinct (steps,
-        # kv_bound) pair is a separate XLA program, and a mid-traffic
+        # QUANTIZE to exactly two step counts: every distinct step count
+        # is a separate XLA program, and a mid-traffic
         # compile of a novel shrunk size stalled every active stream (the
         # 96-session gateway wave of r5 sat behind ONE steps=4 compile).
         # Tail/headroom overshoot is bounded by the floor and
@@ -6627,14 +5688,6 @@ class ServingEngine:
         return min(self.ttft_chunk_floor, self.decode_chunk)
 
     # -- chunked prefill (long-context) -------------------------------------
-
-    def _long_width(self, prompt_len: int) -> int:
-        """Local-cache width for a long prompt: next power of two ≥ the
-        prompt (128-aligned for the segment kernel), clamped to max_seq."""
-        w = self.prefill_buckets[-1]
-        while w < prompt_len:
-            w *= 2
-        return min(w, self.max_seq_len)
 
     def _long_step(self, budget: Optional[int] = None) -> tuple[list[tuple], int]:
         """Drive the chunked-prefill streams: start streams for queued long
@@ -6664,65 +5717,20 @@ class ServingEngine:
                 continue  # resolved in the long backlog
             if self._agentic and not self._resolve_agentic(request):
                 continue  # unknown adapter / pinned pool: request resolved
-            if self._paged:
-                # paged: reserve the whole prompt's pages up front, aliasing
-                # ANY cached prefix boundary (segments write at global
-                # offsets, so no full-segment-width alignment constraint —
-                # the dense path's local-cache grid is gone). Exhaustion
-                # defers the stream; the request keeps its backlog spot.
-                base = self._paged_bind(free, request)
-                if base is None:
-                    self._long_queue.insert(0, request)
-                    break
-                if base < 0:
-                    continue  # can-never-fit: _paged_bind resolved it
-                self._reserved.add(free)
-                self._longs[free] = {
-                    "idx": free, "request": request, "seg": 0, "base": base,
-                }
-                continue
-            # prefix reuse for long prompts (dense): a cached FULL-segment-
-            # width prefix lets chunked prefill start at the reuse point
-            # (the segment grid stays aligned). A hit prefers the segment
-            # loop over the ring path — skipping a whole segment of prefill
-            # saves more than the ring's single-dispatch latency win.
-            prefix = None
-            if self._prefix_pool is not None and not getattr(
-                request.options, "adapter", None
-            ):
-                prefix = self._prefix_lookup(
-                    request.prompt_tokens, full_width_only=True
-                )
-            if (
-                prefix is None
-                and self._ring_admit is not None
-                # the ring admit's fused splice predates adapters/grammars
-                # (no lora threading, no first-token mask): AGENTIC
-                # requests take the segment loop — which threads both —
-                # instead of growing a third ring variant; plain requests
-                # keep the one-dispatch ring path unchanged
-                and request._dfa is None
-                and not getattr(request.options, "adapter", None)
-                and self._ring_pad(len(request.prompt_tokens)) is not None
-            ):
-                # ring path: the whole prompt in ONE sequence-sharded
-                # dispatch — it never becomes a stream, but its tokens
-                # count against this iteration's prefill budget
-                entries.extend(self._ring_step(free, request))
-                spent += len(request.prompt_tokens)
-                if budget is None or spent >= budget:
-                    # overlap off keeps the pre-fusion one-ring-per-
-                    # iteration cadence; with a budget, stop once spent
-                    return entries, spent
-                continue
+            # reserve the whole prompt's pages up front, aliasing ANY
+            # cached prefix boundary (segments write at global offsets, so
+            # no full-segment-width alignment constraint). Exhaustion
+            # defers the stream; the request keeps its backlog spot.
+            base = self._paged_bind(free, request)
+            if base is None:
+                self._long_queue.insert(0, request)
+                break
+            if base < 0:
+                continue  # can-never-fit: _paged_bind resolved it
             self._reserved.add(free)
-            st: dict = {"idx": free, "request": request, "seg": 0, "base": 0}
-            if prefix is not None:
-                p, entry = prefix
-                self._prefix_pool.acquire(entry)  # pinned until the gather
-                st["base"] = p
-                st["prefix"] = entry
-            self._longs[free] = st
+            self._longs[free] = {
+                "idx": free, "request": request, "seg": 0, "base": base,
+            }
         if not self._longs:
             return entries, spent
         # round-robin so two concurrent streams alternate segments fairly
@@ -6750,14 +5758,9 @@ class ServingEngine:
         deadline = request.deadline_at()
         if request.cancelled or (deadline is not None and now >= deadline):
             idx = st["idx"]
-            entry = st.pop("prefix", None)
-            if entry is not None and self._prefix_pool is not None:
-                self._prefix_pool.release(entry)
-            if self._paged:
-                self._free_slot_pages(idx)
+            self._free_slot_pages(idx)
             self._reserved.discard(idx)
             self._longs.pop(idx, None)
-            self._long_caches.pop(idx, None)
             if request.cancelled:
                 with self._stats_lock:
                     self.cancelled_total += 1
@@ -6791,35 +5794,22 @@ class ServingEngine:
             return []
         prompt = request.prompt_tokens
         width = self.prefill_buckets[-1]
-        # ``base``: prefix-reuse offset (a full segment width when warm) —
-        # chunked prefill starts at the reuse point, segments stay aligned
+        # ``base``: prefix-reuse offset — chunked prefill starts at the
+        # reuse point
         s0 = st.get("base", 0) + st["seg"] * width
         seg = prompt[s0 : s0 + width]
         tokens = np.zeros((1, width), np.int32)
         tokens[0, : len(seg)] = seg
         opts = request.options
-        # static pow2 cap on readable cache columns: segment i never attends
-        # past offset+W, so early segments skip streaming the whole cache
-        t_long = self._long_width(len(prompt))
-        kv_bound = width
-        while kv_bound < min(s0 + width, t_long):
-            kv_bound *= 2
-        kv_bound = min(kv_bound, t_long)
         idx = st["idx"]
         start = st["seg"] == 0
         final = s0 + width >= len(prompt)
-        prefix_entry = st.pop("prefix", None)  # only present on start
         if self._spmd is not None:
             self._spmd.announce(wire.ControlBlock(
                 op=wire.OP_LONG_SEG, width=width, n_rows=1, tokens=tokens,
-                s0=s0, seg_len=len(seg), kv_bound=kv_bound, t_long=t_long,
+                s0=s0, seg_len=len(seg),
                 long_start=start, long_final=final, long_idx=idx,
                 prompt_len=len(prompt),
-                # dense warm start: the follower seeds its local cache from
-                # the same pool row (paged segments ignore this field)
-                entry_row=(
-                    prefix_entry.row if prefix_entry is not None else -1
-                ),
                 temps=np.asarray([opts.temperature], np.float32),
                 top_ks=np.asarray([opts.top_k], np.int32),
                 top_ps=np.asarray([opts.top_p], np.float32),
@@ -6827,54 +5817,34 @@ class ServingEngine:
         disp = st.get("disp")
         if start:
             disp = st["disp"] = self._new_segment_dispatch(
-                "_paged_segment_and_sample" if self._paged
-                else "_prefill_segment_and_sample",
-                width, len(seg), request,
+                "_paged_segment_and_sample", width, len(seg), request,
             )
         elif disp is not None:
             disp.attrs["segments"] += 1
             disp.attrs["real_tokens"] += len(seg)
             disp.attrs["computed_tokens"] += width
         try:
-            if self._paged:
-                # straight into the slot's pages: no local cache, no final
-                # insert/splice — the chain scatter on ``final`` is the only
-                # extra dispatch, and kv_bound/t_long do not exist here
-                first = self._dev_paged_segment(
-                    tokens, s0, len(seg), idx,
-                    opts.temperature, opts.top_k, opts.top_p,
-                    final=final, prompt_len=len(prompt),
-                    agentic_rows=request._agentic_rows,
-                )
-            else:
-                first = self._dev_long_segment(
-                    tokens, s0, len(seg), kv_bound, t_long,
-                    opts.temperature, opts.top_k, opts.top_p,
-                    start=start, final=final, idx=idx, prompt_len=len(prompt),
-                    prefix_row=(
-                        prefix_entry.row if prefix_entry is not None else None
-                    ),
-                    agentic_rows=request._agentic_rows,
-                )
+            # straight into the slot's pages: no local cache, no final
+            # insert/splice — the chain scatter on ``final`` is the only
+            # extra dispatch
+            first = self._dev_paged_segment(
+                tokens, s0, len(seg), idx,
+                opts.temperature, opts.top_k, opts.top_p,
+                final=final, prompt_len=len(prompt),
+                agentic_rows=request._agentic_rows,
+            )
         except Exception as e:  # noqa: BLE001 — fail the request, not the engine
             if self._spmd is not None:
                 raise  # multi-host: crash the replica (see _admit rationale)
             log.exception("chunked prefill failed at segment %d", st["seg"])
-            if self._paged:
-                self._free_slot_pages(idx)
+            self._free_slot_pages(idx)
             self._reserved.discard(idx)
             self._longs.pop(idx, None)
-            self._long_caches.pop(idx, None)
             request._finish(GenerationResult(
                 tokens=[], finish_reason="error", prompt_tokens=0,
                 ttft_s=0, total_s=0, error=e,
             ))
             return []
-        finally:
-            if prefix_entry is not None:
-                self._prefix_pool.release(prefix_entry)
-        if prefix_entry is not None:
-            self._prefix_pool.tokens_saved += st.get("base", 0)
         st["seg"] += 1
         if not final:
             return []  # more segments to go
@@ -6905,201 +5875,6 @@ class ServingEngine:
             [(idx, request)], disp,
         )]
 
-    def _ring_pad(self, prompt_len: int) -> Optional[int]:
-        """Padded width for the ring path: |seq| pow2-sized blocks (O(log)
-        compiled shapes). None when that padding cannot fit max_seq_len —
-        the caller falls back to the single-dispatch-per-segment loop, which
-        has no divisibility constraint."""
-        n = self.mesh.shape["seq"]
-        block = 128
-        while block * n < prompt_len:
-            block *= 2
-        s_pad = block * n
-        return s_pad if s_pad <= self.max_seq_len else None
-
-    def _ring_step(self, idx: int, request: GenerationRequest) -> list[tuple]:
-        """One-dispatch ring long-prefill: run the fused ring admit and
-        activate the slot. Decode chunks for other slots resume next
-        iteration. On a multi-host replica the leader first streams the
-        padded prompt to the followers in fixed-shape chunks (OP_RING) so
-        every process makes the identical dispatch."""
-        prompt = request.prompt_tokens
-        s_pad = self._ring_pad(len(prompt))
-        assert s_pad is not None  # caller checked
-        tokens = np.zeros((1, s_pad), np.int32)
-        tokens[0, : len(prompt)] = prompt
-        opts = request.options
-        if self._spmd is not None:
-            self._announce_ring(tokens, len(prompt), opts, idx)
-        disp = self._new_segment_dispatch(
-            "ring_admit", s_pad, len(prompt), request
-        )
-        try:
-            first = self._dev_ring(
-                tokens, len(prompt),
-                opts.temperature, opts.top_k, opts.top_p, idx,
-            )
-        except Exception as e:  # noqa: BLE001 — fail the request, not the engine
-            if self._spmd is not None:
-                raise  # multi-host: crash the replica (see _admit rationale)
-            log.exception("ring prefill failed")
-            request._finish(GenerationResult(
-                tokens=[], finish_reason="error", prompt_tokens=0,
-                ttft_s=0, total_s=0, error=e,
-            ))
-            return []
-        slot = self._slots[idx]
-        slot.request = request
-        slot.position = len(prompt)
-        slot.generated = []
-        slot.started_at = time.monotonic()
-        slot.first_token_at = 0.0
-        slot.reset_obs("ring", 1, self._dispatch_seq)
-        with self._stats_lock:
-            self.total_requests += 1
-        self._note_tenant_admitted(request)
-        self._spec_admit(idx, prompt)
-        self._maybe_publish(idx, prompt)
-        return [(
-            "prefill", self._fetcher.submit(first, self._dispatch_seq),
-            [(idx, request)], disp,
-        )]
-
-    def _announce_ring(self, tokens: np.ndarray, prompt_len: int, opts, idx: int) -> None:
-        """Stream the PROMPT (not its pow2 padding — the follower derives
-        the identical _ring_pad locally and zero-pads itself) over the
-        fixed-shape SPMD channel in (prefill_batch × max_width)-token
-        chunks; the final chunk carries the sampling params and fires the
-        follower's _dev_ring."""
-        flat = tokens.reshape(-1)[:prompt_len]
-        chunk_cap = self._spmd.prefill_batch * self._spmd.max_width
-        total = len(flat)
-        for start in range(0, total, chunk_cap):
-            piece = flat[start : start + chunk_cap]
-            rows = -(-len(piece) // self._spmd.max_width)
-            padded = np.zeros(rows * self._spmd.max_width, np.int32)
-            padded[: len(piece)] = piece
-            self._spmd.announce(wire.ControlBlock(
-                op=wire.OP_RING,
-                width=self._spmd.max_width,
-                n_rows=rows,
-                tokens=padded.reshape(rows, self._spmd.max_width),
-                seg_len=len(piece),
-                long_start=start == 0,
-                long_final=start + chunk_cap >= total,
-                long_idx=idx,
-                prompt_len=prompt_len,
-                temps=np.asarray([opts.temperature], np.float32),
-                top_ks=np.asarray([opts.top_k], np.int32),
-                top_ps=np.asarray([opts.top_p], np.float32),
-            ))
-
-    def _dev_ring(
-        self, tokens: np.ndarray, prompt_len: int,
-        temperature: float, top_k: int, top_p: float, idx: int,
-    ):
-        """Device layer of the ring admit (leader + SPMD followers): the
-        fused sequence-sharded prefill + cache splice + decode-chain
-        scatters, identical on every process."""
-        self._record_program("ring", tokens.shape[1])
-        meta = np.asarray(
-            [[prompt_len], [temperature], [top_k], [top_p]], np.float32
-        )
-        (
-            first,
-            self._cache,
-            self._tokens_dev,
-            self._positions_dev,
-            self._temp_dev,
-            self._top_k_dev,
-            self._top_p_dev,
-            self._key,
-        ) = self._ring_admit(
-            self.params,
-            self._cache,
-            self._tokens_dev,
-            self._positions_dev,
-            self._temp_dev,
-            self._top_k_dev,
-            self._top_p_dev,
-            self._key,
-            jnp.asarray(tokens),
-            jnp.asarray(meta),
-            jnp.asarray(np.full(1, idx, np.int32)),
-            self.config,
-        )
-        return first
-
-    def _dev_long_segment(
-        self, tokens, s0, seg_len, kv_bound, t_long, temperature, top_k, top_p,
-        *, start: bool, final: bool, idx: int, prompt_len: int,
-        prefix_row: Optional[int] = None, agentic_rows=None,
-    ):
-        """Device layer of one chunked-prefill segment (leader + SPMD
-        followers): fresh local cache on ``start`` (seeded from pool row
-        ``prefix_row`` on a warm start — the stream's first segment then
-        begins at the reuse offset), segment forward, and on ``final`` the
-        splice into the big cache + decode-chain scatters."""
-        if self._injector is not None:
-            self._injector.fire("segment")
-        if start:
-            if prefix_row is not None:
-                from langstream_tpu.ops.kvcopy import gather_prefix_local
-
-                self._record_program("prefix-gather", t_long)
-                local_cache = gather_prefix_local(
-                    self._prefix_pool.dev,
-                    jnp.asarray(prefix_row, jnp.int32),
-                    self.config,
-                    t_long,
-                )
-            else:
-                local_cache = make_kv_cache(self.config, 1, t_long)
-            if self.mesh is not None:
-                from langstream_tpu.parallel.sharding import shard_serving_cache
-
-                local_cache = shard_serving_cache(local_cache, self.mesh)
-            self._long_caches[idx] = local_cache
-        self._record_program("segment", tokens.shape[1], kv_bound, t_long)
-        kw = self._segment_agentic_kwargs(
-            agentic_rows, idx if final else self.max_batch
-        )
-        first, self._long_caches[idx], self._key, state_dev = (
-            _prefill_segment_and_sample(
-                self.params,
-                jnp.asarray(tokens),
-                jnp.asarray([s0], jnp.int32),
-                jnp.asarray([seg_len], jnp.int32),
-                self._long_caches[idx],
-                self._key,
-                jnp.asarray([temperature], jnp.float32),
-                jnp.asarray([top_k], jnp.int32),
-                jnp.asarray([top_p], jnp.float32),
-                self.config,
-                kv_bound,
-                **kw,
-            )
-        )
-        if state_dev is not None:
-            self._dfa_state_dev = state_dev
-        if final:
-            slots_dev = jnp.asarray(np.full(1, idx, np.int32))
-            self._record_program("insert", t_long)
-            self._cache = self._insert_group(
-                self._cache, self._long_caches.pop(idx), slots_dev
-            )
-            self._record_program("chain-scatter")
-            (
-                self._tokens_dev, self._positions_dev, self._temp_dev,
-                self._top_k_dev, self._top_p_dev,
-            ) = _chain_scatter(
-                self._tokens_dev, self._positions_dev, self._temp_dev,
-                self._top_k_dev, self._top_p_dev,
-                jnp.asarray(idx, jnp.int32), first, prompt_len,
-                temperature, top_k, top_p,
-            )
-        return first
-
     def _dispatch_chunk(self, clean: bool = True, pipelined: bool = False) -> tuple:
         """Dispatch one multi-step decode; returns (device tokens,
         per-slot request snapshot, steps, dispatch time, clean, pipelined)
@@ -7112,35 +5887,20 @@ class ServingEngine:
         inter-completion interval instead of dispatch→ready wall (which
         would read ~2× at steady state, the predecessor's remaining
         execution counted into this chunk's)."""
-        if self._paged:
-            # validate BEFORE the announce: a quarantine here frees pages
-            # (announced as OP_PAGE_FREE) and deactivates the slot, and the
-            # mask announced below must already reflect both
-            self._page_integrity_check()
+        # validate BEFORE the announce: a quarantine here frees pages
+        # (announced as OP_PAGE_FREE) and deactivates the slot, and the
+        # mask announced below must already reflect both
+        self._page_integrity_check()
         self._adapter_integrity_check()
+        # the page table is the bound on what a row reads: the decode
+        # surface is ONE program per step count
         steps = self._chunk_steps()
-        # shrunk (non-full) chunks run UNBOUNDED: pairing the occasional
-        # short chunk with the kv_bound ladder would multiply the compiled-
-        # program count (steps × bounds); a few full-width steps cost ~10ms
-        # extra read, a novel program costs a ~15-20s compile stall.
-        # Paged layout: no bound at all — the page table is the bound, and
-        # the decode surface is ONE program per step count.
-        kv_bound = (
-            None
-            if self._paged
-            else self._decode_kv_bound(steps)
-            if steps == self.decode_chunk
-            else None
-        )
         stale = self._collect_stale()
         mask = self._active_mask()
         if self._spmd is not None:
             self._spmd.announce(wire.ControlBlock(
                 op=wire.OP_DECODE, steps=steps, n_rows=len(stale),
                 slots=np.asarray(stale, np.int32),
-                # unbounded (shrunk) chunks ride as 0 — the int32 wire
-                # header can't carry None; followers decode 0 back to None
-                kv_bound=kv_bound or 0,
                 # slot liveness is leader-only host state (completions are
                 # discovered at fetch time): ship the mask so followers
                 # sentinel the same page-table rows
@@ -7149,17 +5909,16 @@ class ServingEngine:
         live = [slot for slot in self._slots if slot.active]
         disp = self._new_dispatch(
             "engine.decode_chunk",
-            program="_paged_decode_chunk" if self._paged else "_decode_chunk",
+            program="_paged_decode_chunk",
             steps=steps, active_rows=len(live),
             kv_tokens_read=self._kv_tokens_read(live, steps),
             clean=clean, pipelined=pipelined,
-            **({"kv_pages_visited": self._kv_pages_visited(steps)}
-               if self._paged else {}),
+            kv_pages_visited=self._kv_pages_visited(steps),
         )
         with jax.profiler.TraceAnnotation(
             "engine.decode_chunk", seq=self._dispatch_seq, steps=steps
         ):
-            chunk = self._dev_decode(steps, stale, kv_bound, mask=mask)
+            chunk = self._dev_decode(steps, stale, mask=mask)
         counts = self._moe_counts()
         snapshot = [
             (i, slot.request) for i, slot in enumerate(self._slots) if slot.active
@@ -7168,7 +5927,6 @@ class ServingEngine:
             slot.ahead += steps
         with self._stats_lock:
             self._busy_steps += steps
-        self._last_kv_bound = kv_bound or self.max_seq_len
         # hand the chunk to the fetch thread NOW: it blocks on the bytes
         # while this thread keeps dispatching — the fetch is hidden at
         # every chunk size, not only when chunk compute covers it
@@ -7230,27 +5988,8 @@ class ServingEngine:
         idxs[: len(stale)] = stale
         self._temp_dev = self._temp_dev.at[jnp.asarray(idxs)].set(0.0, mode="drop")
 
-    def _decode_kv_bound(self, steps: int) -> int:
-        """Static pow2 cap on readable cache columns for this chunk: decode
-        is cache-READ-bandwidth-bound and the masked read otherwise streams
-        the full max_seq_len width for every step (measured r5, llama-3-8b
-        int8 B=96: 27.9ms/step at T=256 vs 61.8 at T=1024). Device
-        positions lead host positions by the in-flight pipelined chunks, so
-        the bound covers max host position + inflight + this chunk. The
-        pow2 ladder (_kv_bound_ladder — the same rungs both warmups
-        compile) keeps the compile count at O(log2 T)."""
-        highest = max(
-            (s.position for s in self._slots if s.active), default=0
-        )
-        needed = highest + self._inflight_steps + steps
-        for bound in _kv_bound_ladder(self.max_seq_len):
-            if bound >= needed:
-                return bound
-        return self.max_seq_len
-
     def _dev_decode(
-        self, steps: int, stale, kv_bound: Optional[int] = None,
-        mask: Optional[np.ndarray] = None,
+        self, steps: int, stale, mask: Optional[np.ndarray] = None,
     ) -> Any:
         """Device layer of one decode chunk (leader + SPMD followers).
         ``mask``: the dispatch's active-slot liveness (paged table
@@ -7260,59 +5999,31 @@ class ServingEngine:
             self._injector.fire("decode")  # crashes the loop → restart path
         lora, arows, dfa, g = self._agentic_args()
         dstate = self._dfa_state_dev
-        if self._paged:
-            self._record_program("paged-decode", steps)
-            if len(stale):
-                self._reset_stale_temps(stale)
-            pool = self._pagepool
-            (
-                chunk,
-                self._tokens_dev,
-                self._positions_dev,
-                pool.dev,
-                self._key,
-                dstate,
-                self._moe_dev,
-            ) = _paged_decode_chunk(
-                self.params,
-                self._tokens_dev,
-                self._positions_dev,
-                pool.dev,
-                jnp.asarray(self._dispatch_tables(mask)),
-                self._key,
-                self._temp_dev,
-                self._top_k_dev,
-                self._top_p_dev,
-                steps,
-                self.config,
-                self.page_size,
-                lora,
-                arows,
-                dfa,
-                g,
-                dstate,
-            )
-            if dstate is not None:
-                self._dfa_state_dev = dstate
-            return chunk
-        self._record_program("decode", steps, kv_bound or 0)
+        self._record_program("paged-decode", steps)
         if len(stale):
             self._reset_stale_temps(stale)
+        pool = self._pagepool
         (
-            chunk, self._tokens_dev, self._positions_dev, self._cache,
-            self._key, dstate, self._moe_dev,
-        ) = _decode_chunk(
+            chunk,
+            self._tokens_dev,
+            self._positions_dev,
+            pool.dev,
+            self._key,
+            dstate,
+            self._moe_dev,
+        ) = _paged_decode_chunk(
             self.params,
             self._tokens_dev,
             self._positions_dev,
-            self._cache,
+            pool.dev,
+            jnp.asarray(self._dispatch_tables(mask)),
             self._key,
             self._temp_dev,
             self._top_k_dev,
             self._top_p_dev,
             steps,
             self.config,
-            kv_bound,
+            self.page_size,
             lora,
             arows,
             dfa,
@@ -7326,13 +6037,12 @@ class ServingEngine:
     def _dispatch_verify(self, clean: bool = True) -> tuple:
         """Dispatch one self-speculative verify iteration: collect up to k
         drafts per active slot from its n-gram index (host-side, free), run
-        _verify_chunk, and return the deferred-fetch entry. Slots whose
+        _paged_verify_chunk, and return the deferred-fetch entry. Slots whose
         index has no proposal ride the fixed-shape dispatch with zero
         drafts — their verify degenerates to a 1-token decode step (the
         accept test compares against the model's own outputs, so a bad or
         empty draft can never change what is emitted)."""
-        if self._paged:
-            self._page_integrity_check()  # before the announce (see chunk)
+        self._page_integrity_check()  # before the announce (see chunk)
         self._adapter_integrity_check()
         k = self.spec_tokens
         # brownout level 1 (spec-shrink) proposes fewer drafts — data,
@@ -7340,7 +6050,6 @@ class ServingEngine:
         k_prop = (
             self._brownout.draft_k(k) if self._brownout is not None else k
         )
-        kv_bound = 0 if self._paged else self._decode_kv_bound(k + 1)
         stale = self._collect_stale()
         drafts = np.zeros((self.max_batch, k), np.int32)
         proposed = np.zeros(self.max_batch, np.int32)
@@ -7384,21 +6093,19 @@ class ServingEngine:
             # identically on every host, so accepts need no forward wire
             self._spmd.announce(wire.ControlBlock(
                 op=wire.OP_VERIFY, steps=k, n_rows=len(stale),
-                slots=np.asarray(stale, np.int32), kv_bound=kv_bound,
+                slots=np.asarray(stale, np.int32),
                 drafts=drafts, mask=mask,
             ))
         live = [slot for slot in self._slots if slot.active]
         disp = self._new_dispatch(
             "engine.verify",
-            program="_paged_verify_chunk" if self._paged else "_verify_chunk",
+            program="_paged_verify_chunk",
             steps=k + 1, active_rows=len(live),
             # a verify scores k+1 positions a row, each over its own prefix
             kv_tokens_read=self._kv_tokens_read(live, k + 1),
             clean=clean, pipelined=False,
         )
-        packed = self._dev_verify(
-            drafts, stale, kv_bound, mask=mask, vstates=vstates
-        )
+        packed = self._dev_verify(drafts, stale, mask=mask, vstates=vstates)
         counts = self._moe_counts()
         snapshot = [
             (i, slot.request) for i, slot in enumerate(self._slots) if slot.active
@@ -7406,14 +6113,13 @@ class ServingEngine:
         with self._stats_lock:
             self._busy_steps += 1
             self.spec_dispatches_total += 1
-        self._last_kv_bound = kv_bound
         return (
             "verify", self._fetcher.submit(packed, self._dispatch_seq, counts),
             snapshot, proposed, time.monotonic(), clean, disp,
         )
 
     def _dev_verify(
-        self, drafts: np.ndarray, stale, kv_bound: int,
+        self, drafts: np.ndarray, stale,
         mask: Optional[np.ndarray] = None,
         vstates: Optional[np.ndarray] = None,
     ) -> Any:
@@ -7433,64 +6139,31 @@ class ServingEngine:
                     (self.max_batch, drafts.shape[1] + 1), np.int32
                 )
             vstates_dev = jnp.asarray(vstates)
-        if self._paged:
-            self._record_program("paged-verify", drafts.shape[1])
-            if len(stale):
-                self._reset_stale_temps(stale)
-            pool = self._pagepool
-            (
-                packed,
-                self._tokens_dev,
-                self._positions_dev,
-                pool.dev,
-                self._key,
-                dstate,
-                self._moe_dev,
-            ) = _paged_verify_chunk(
-                self.params,
-                self._tokens_dev,
-                self._positions_dev,
-                pool.dev,
-                jnp.asarray(self._dispatch_tables(mask)),
-                self._key,
-                self._temp_dev,
-                self._top_k_dev,
-                self._top_p_dev,
-                jnp.asarray(drafts),
-                self.config,
-                self.page_size,
-                lora,
-                arows,
-                dfa,
-                g,
-                vstates_dev,
-            )
-            if dstate is not None:
-                self._dfa_state_dev = dstate
-            return packed
-        self._record_program("verify", drafts.shape[1], kv_bound or 0)
+        self._record_program("paged-verify", drafts.shape[1])
         if len(stale):
             self._reset_stale_temps(stale)
+        pool = self._pagepool
         (
             packed,
             self._tokens_dev,
             self._positions_dev,
-            self._cache,
+            pool.dev,
             self._key,
             dstate,
             self._moe_dev,
-        ) = _verify_chunk(
+        ) = _paged_verify_chunk(
             self.params,
             self._tokens_dev,
             self._positions_dev,
-            self._cache,
+            pool.dev,
+            jnp.asarray(self._dispatch_tables(mask)),
             self._key,
             self._temp_dev,
             self._top_k_dev,
             self._top_p_dev,
             jnp.asarray(drafts),
             self.config,
-            kv_bound,
+            self.page_size,
             lora,
             arows,
             dfa,
@@ -7639,21 +6312,18 @@ class ServingEngine:
         if token < 0:
             # sampling's NaN guard sentinel: this slot's logits went
             # non-finite. Quarantine ONLY this slot — fail its request,
-            # zero its KV rows/pages (next iteration, one coalesced
+            # zero its pages (next iteration, one coalesced
             # dispatch) — while every other slot keeps decoding untouched.
             # SPMD replicas quarantine victim-only too since round 13: the
-            # row-reset / page-free / page-zero dispatches ride the wire,
+            # page-free / page-zero dispatches ride the wire,
             # so a poisoned slot degrades one request, not the replica
             # (docs/SERVING.md §14).
             with self._stats_lock:
                 self.nan_guard_total += 1
                 self.quarantined_slots_total += 1
-            if self._paged:
-                # pages, not rows: evict prefix entries sharing the slot's
-                # pages, free them through the owned list, zero next flush
-                self._quarantine_pages(idx)
-            else:
-                self._pending_row_resets.append(idx)
+            # pages, not rows: evict prefix entries sharing the slot's
+            # pages, free them through the owned list, zero next flush
+            self._quarantine_pages(idx)
             # the postmortem artifact: the last N iterations that LED here
             # (batch mix, pages, programs, injector firings) — the evidence
             # a counter bump discards
@@ -7761,9 +6431,7 @@ class ServingEngine:
         request = slot.request
         assert request is not None
         now = time.monotonic()
-        pages_held = (
-            len(self._pagepool.slot_pages(idx)) if self._paged else 0
-        )
+        pages_held = len(self._pagepool.slot_pages(idx))
         result = GenerationResult(
             tokens=list(slot.generated),
             finish_reason=reason,
@@ -7806,10 +6474,9 @@ class ServingEngine:
         self._spec_index.pop(idx, None)
         self._slot_clear_agentic(idx)
         self._freed_slots.append(idx)
-        if self._paged:
-            # slot reset = free its table (shared pages survive through the
-            # prefix index's refcounts; exclusive ones return to the pool)
-            self._free_slot_pages(idx)
+        # slot reset = free its table (shared pages survive through the
+        # prefix index's refcounts; exclusive ones return to the pool)
+        self._free_slot_pages(idx)
         request._finish(result)
         if self._obs.on:
             # the request's whole lifecycle becomes ONE span tree here —
@@ -7838,12 +6505,8 @@ class ServingEngine:
             doomed.append(self._held_back)
             self._held_back = None
         for st in self._longs.values():
-            entry = st.pop("prefix", None)
-            if entry is not None and self._prefix_pool is not None:
-                self._prefix_pool.release(entry)
             doomed.append(st["request"])
         self._longs.clear()
-        self._long_caches.clear()
         doomed.extend(self._long_queue)
         self._long_queue.clear()
         doomed.extend(self._page_deferred)

@@ -50,7 +50,7 @@ the bench control arm, not a production mode).
 
 Run ``python -m langstream_tpu.serving.fleet --config '<json>'`` to serve
 one replica (engine + /state + /fleet/generate) as a standalone process —
-the multi-process CPU fleet bench (bench.py bench_fleet) and the failure
+a multi-process fleet (tests/test_fleet.py's HTTP tier) and the failure
 drills are built on this.
 """
 

@@ -34,7 +34,7 @@ from typing import Any, Protocol
 
 log = logging.getLogger(__name__)
 
-# the chat-gateway convention header (examples, bench.py GATEWAYS) — the
+# the chat-gateway convention header (examples, benchmark/run.py's probe) — the
 # gateway resolves it from the client's ?param.sessionId, the completions
 # agent sees it as a record property
 SESSION_HEADER = "langstream-client-session-id"

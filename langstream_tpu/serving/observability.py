@@ -328,7 +328,7 @@ ITERATION_FIELDS = (
     "queued",   # admission queue depth
     "dispatch", # "decode" | "verify" | "" (nothing dispatched)
     "steps",    # decode steps (or k+1 verify width) dispatched
-    "kv_pages", # physical pages in use (0 under the dense layout)
+    "kv_pages", # physical pages in use
     "host_pages", # host-tier arena slots in use (0 with the tier off)
     "programs", # distinct compiled device programs so far
     "phase_ms", # {"sweep","prefill","dispatch","process","wait","deliver",

@@ -2,7 +2,7 @@
 
 Decode emits ONE token per weight read; speculation amortizes that read
 over k+1 tokens by proposing drafts cheaply on the HOST and verifying them
-in one multi-token device dispatch (engine._verify_chunk). This module is
+in one multi-token device dispatch (engine._paged_verify_chunk). This module is
 the proposer: no draft model, no extra weights — a per-slot n-gram index
 over prompt + generated tokens (the "prompt lookup" scheme: chat, RAG and
 code traffic constantly re-emits spans of its own context, and greedy

@@ -9,9 +9,9 @@ Both build IDENTICAL engine state (same params seed, same mesh over the
 GLOBAL device list).
 
 ``mode``:
-  basic (default) — the original dense-wire tier: one cold request.
-  fast — round-13 parity tier: prefix-cache auto + speculation auto +
-    kv_layout=paged, a cold+warm workload, result echo verification ON
+  basic (default) — the plain wire tier: one cold request.
+  fast — round-13 parity tier: prefix-cache auto + speculation auto,
+    a cold+warm workload, result echo verification ON
     (every processed chunk's tokens re-broadcast and checked on the
     follower — docs/SERVING.md §14).
 """
@@ -53,7 +53,7 @@ channel = SpmdChannel(
     prefill_batch=4,
     max_width=32,
     max_batch=3 if fast else 2,
-    table_len=table_len_for(MAX_SEQ, PAGE) if fast else 0,
+    table_len=table_len_for(MAX_SEQ, PAGE),
     spec_tokens=4 if fast else 0,
     echo=fast,
 )
@@ -67,7 +67,6 @@ engine = ServingEngine(
     prefill_batch=4,
     mesh=mesh,
     spmd=channel,
-    kv_layout="paged" if fast else "dense",
     page_size=PAGE,
     prefix_cache="auto" if fast else False,
     speculation="auto" if fast else False,

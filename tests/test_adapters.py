@@ -392,15 +392,13 @@ def test_adapter_fault_site_quarantines_victim_only():
 
 
 @pytest.mark.slow
-def test_adapter_prefill_kv_carries_deltas_dense_and_int8():
+def test_adapter_prefill_kv_carries_deltas_float_and_int8():
     """wk/wv adapters change the PROMPT's cache, not just logits: the same
     engine must produce different first tokens for base vs adapter on a
-    prompt long enough that prefill dominates — on both KV dtypes and both
-    layouts (dense exercises the dense admit group)."""
+    prompt long enough that prefill dominates — on both KV dtypes."""
     long_prompt = list(range(5, 45))
     for kw in (
         {},
-        {"kv_layout": "dense"},
         {"config": dataclasses.replace(CFG, kv_cache_dtype="int8")},
     ):
         cfg = kw.pop("config", CFG)

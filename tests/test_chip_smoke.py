@@ -51,10 +51,10 @@ def test_serve_phase_fails_when_state_is_on_the_wrong_device():
 def test_kernel_checks_in_interpret_mode():
     config = dataclasses.replace(MODEL_PRESETS["tiny-test"], dtype="float32")
     checks = chip_smoke.kernel_checks(
-        config, prefill_lens=(16,), segment=(8, 32), paged=(6, 8, 3),
+        config, prefill_lens=(16,), paged=(6, 8, 3),
         interpret=True,
     )
-    assert len(checks) == 4
+    assert len(checks) == 3
     assert all(c["max_abs_err"] <= chip_smoke.KERNEL_ERR_BOUND for c in checks)
 
 

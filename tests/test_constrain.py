@@ -26,7 +26,7 @@ import pytest
 
 from langstream_tpu.models.configs import MODEL_PRESETS, GenerationOptions
 from langstream_tpu.models.transformer import (
-    decode_step_inplace,
+    decode_step,
     init_params,
     make_kv_cache,
     prefill,
@@ -494,7 +494,7 @@ def _host_masked_reference(prompt, dfa: TokenDFA, max_new: int,
         state = dfa.advance(state, token)
         if dfa.is_complete(state):
             break
-        current, cache = decode_step_inplace(
+        current, cache = decode_step(
             PARAMS, jnp.asarray([token]), jnp.asarray([position]), cache,
             config,
         )

@@ -51,13 +51,11 @@ def drain(engine, pending):
     engine._fail_all(RuntimeError("test torn down"))
 
 
-@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
-def test_one_span_per_group_and_chunk_with_work_counts(dense_params, paged):
+def test_one_span_per_group_and_chunk_with_work_counts(dense_params):
     TRACER.clear()
     engine = ServingEngine(
         DENSE, dense_params, max_batch=4, max_seq_len=128, decode_chunk=4,
         prefill_buckets=(16,), prefill_batch=2,
-        kv_layout="paged" if paged else "dense",
     )
     engine.start()
     try:
@@ -88,13 +86,11 @@ def test_one_span_per_group_and_chunk_with_work_counts(dense_params, paged):
     assert sum(g["attributes"]["real_tokens"] for g in groups) == 3 + 4 + 5
     for c in chunks:
         a = c["attributes"]
-        assert a["program"] == ("_paged_decode_chunk" if paged else "_decode_chunk")
+        assert a["program"] == "_paged_decode_chunk"
         assert a["steps"] >= 1 and 1 <= a["active_rows"] <= 3
         assert a["kv_tokens_read"] >= a["steps"] * a["active_rows"]
-        # the paged kernel's page iterations; the dense layout has no pages
-        assert ("kv_pages_visited" in a) == paged
-        if paged:
-            assert a["steps"] * a["active_rows"] <= a["kv_pages_visited"] <= a["kv_tokens_read"]
+        # the paged kernel's page iterations
+        assert a["steps"] * a["active_rows"] <= a["kv_pages_visited"] <= a["kv_tokens_read"]
         assert "moe_routed" not in a  # a dense model fetches no counts
     # device time per request class is a join: prefill child -> its group
     by_seq = {g["attributes"]["seq"]: g for g in groups}
@@ -155,8 +151,12 @@ def test_kv_pages_visited_matches_a_hand_count(dense_params):
     TRACER.clear()
     engine = ServingEngine(
         DENSE, dense_params, max_batch=3, max_seq_len=128, decode_chunk=8,
-        overlap=True, kv_layout="paged", page_size=8,
+        overlap=True, page_size=8,
     )
+    # B's chunk must still be in flight when its table is read below: on a
+    # loaded host the device can finish it inside the second iteration,
+    # which would deliver B's five tokens and free its page
+    engine._batch_ready = lambda batch: False
     pending: deque = deque()
     engine.submit(GenerationRequest(
         prompt_tokens=[4, 5, 6],
@@ -195,7 +195,7 @@ def test_a_chunk_with_idle_and_finished_rows_is_token_exact_through_the_kernel(
         config = dataclasses.replace(DENSE, attention_impl=impl, kv_cache_dtype=kv)
         engine = ServingEngine(
             config, dense_params, max_batch=6, max_seq_len=64, decode_chunk=8,
-            prefill_buckets=(16,), kv_layout="paged", page_size=8,
+            prefill_buckets=(16,), page_size=8,
         )
         engine.start()
         try:
@@ -424,22 +424,15 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
         texts[name] = lowered_scopes(admit, params, pool) | lowered_scopes(
             decode, params, pool
         )
-    # the dense layout's scan slices its layer's cache entry out of the carry
-    # and back: the one place `kv_pool.read` is left
-    dense_step = lowered_scopes(
-        lambda p, c: T.decode_step_inplace(p, tokens[:, 0], lengths, c, DENSE),
-        dense_params, T.make_kv_cache(DENSE, b, 64),
-    )
-    assert {"kv_pool.read", "kv_pool.write"} <= dense_step
-    assert set(T.SCOPES) <= texts["dense"] | texts["moe"] | dense_step
+    assert set(T.SCOPES) <= texts["dense"] | texts["moe"]
     assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
     assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",
             "moe_ffn.combine"} <= texts["moe"] and "ffn" not in texts["moe"]
     decode_only = lowered_scopes(decode, moe_params, T.make_page_pool(MOE, 8, page))
     assert {"embed", "attention", "moe_ffn", "kv_pool.write", "head",
             "sample"} <= decode_only
-    # the paged scan reads the pool where it lies: nothing is sliced out
-    assert "kv_pool.read" not in decode_only
+    # the scan reads the pool where it lies: nothing is sliced out
+    assert "kv_pool.read" not in decode_only and "kv_pool.read" not in T.SCOPES
     # and the new rows' scatter, the pool's only write, is not under `attention`
     text = jax.jit(decode).lower(moe_params, T.make_page_pool(MOE, 8, page)).as_text(
         debug_info=True

@@ -111,7 +111,7 @@ def make_engine(durable_dir=None, tier=True, **kw):
     if durable_dir is not None:
         kw.setdefault("durable", "on")
         kw["durable_dir"] = str(durable_dir)
-    engine = ServingEngine(CFG, PARAMS, kv_layout="paged", **kw)
+    engine = ServingEngine(CFG, PARAMS, **kw)
     engine.start()
     return engine
 
@@ -621,14 +621,14 @@ def test_memory_plan_reports_durable_disk_budget():
     from langstream_tpu.serving.memory import plan_serving_memory
 
     plan = plan_serving_memory(
-        CFG, 2, 128, kv_layout="paged", page_size=16, kv_pages=12,
+        CFG, 2, 128, page_size=16, kv_pages=12,
         durable_max_bytes=2 << 30,
     )
     assert plan.durable_disk_bytes == 2 << 30
     assert "durable KV tier" in plan.summary()
     assert "disk" in plan.summary()
     flat = plan_serving_memory(
-        CFG, 2, 128, kv_layout="paged", page_size=16, kv_pages=12,
+        CFG, 2, 128, page_size=16, kv_pages=12,
     )
     assert flat.durable_disk_bytes == 0
     assert "durable" not in flat.summary()

@@ -460,25 +460,19 @@ def test_moe_engine_matches_unbatched_reference():
         engine.stop()
 
 
-def test_long_prompt_int8_kv_pallas_matches_jnp():
-    """The chunked-prefill (segment) path with an int8 KV cache through the
-    pallas int8 segment kernel (interpret off-TPU) must produce the same
-    greedy tokens as the jnp hoisted-scale path — the kernel is a pure
-    bandwidth optimization, not a math change."""
+def test_long_prompt_through_pages_pallas_matches_jnp():
+    """The chunked-prefill path into the slot's pages, then decode through
+    the paged kernel (interpret off-TPU), gives the greedy tokens of the
+    jnp path: segments gather through the table on either setting, the
+    decode read is the kernel's or the gathered view's — a pure bandwidth
+    choice, not a math change."""
     tokens_by_impl = {}
     for impl in ("jnp", "pallas"):
-        cfg = dataclasses.replace(
-            CFG, kv_cache_dtype="int8", attention_impl=impl
-        )
+        cfg = dataclasses.replace(CFG, attention_impl=impl)
         params = init_params(cfg, jax.random.PRNGKey(0))
-        # dense layout: this test pins the DENSE int8 segment kernel (the
-        # paged layout's long path writes straight into pages and has its
-        # own exactness suite in test_pagepool.py; its int8 decode kernel
-        # keeps q full-precision, so jnp-vs-pallas token identity is only
-        # guaranteed on the dense path this test was written for)
         engine = ServingEngine(
             cfg, params, max_batch=1, max_seq_len=256, decode_chunk=4,
-            prefill_buckets=(64,), kv_layout="dense",
+            prefill_buckets=(64,), page_size=16,
         )
         engine.start()
         try:
@@ -495,10 +489,10 @@ def test_long_prompt_int8_kv_pallas_matches_jnp():
     assert tokens_by_impl["jnp"] == tokens_by_impl["pallas"], tokens_by_impl
 
 
-def test_precompile_ladder_then_serve():
-    """precompile=True warms a decode chunk per kv_bound ladder step before
-    serving; the warmup garbage must not leak into real generations (same
-    greedy tokens as a cold engine)."""
+def test_precompile_then_serve():
+    """precompile=True runs every program once against out-of-bounds tables
+    before serving; nothing of the warm-up may leak into real generations
+    (same greedy tokens as a cold engine)."""
     cold = make_engine(max_batch=2, max_seq_len=256, decode_chunk=4)
     try:
         opts = GenerationOptions(max_new_tokens=12, temperature=0.0)
@@ -513,3 +507,25 @@ def test_precompile_ladder_then_serve():
     finally:
         warm.stop()
     assert got == expected
+
+
+def test_token_fetcher_preserves_order():
+    """The dedicated fetch thread returns results in submission (= chunk)
+    order, and handles resolve inline when no thread is running."""
+    import numpy as np
+
+    from langstream_tpu.serving.engine import _TokenFetcher
+
+    fetcher = _TokenFetcher()
+    # no thread: inline fallback
+    h = fetcher.submit(jax.numpy.arange(4))
+    assert h.result().tolist() == [0, 1, 2, 3]
+    fetcher.start()
+    try:
+        handles = [fetcher.submit(jax.numpy.full((2,), i)) for i in range(16)]
+        for i, h in enumerate(handles):
+            np.testing.assert_array_equal(h.result(), np.full((2,), i))
+    finally:
+        fetcher.stop()
+    # after stop: inline fallback again
+    assert fetcher.submit(jax.numpy.arange(2)).result().tolist() == [0, 1]

@@ -448,8 +448,8 @@ def test_deadline_in_long_prompt_backlog_resolves_promptly():
 
 
 def test_deadline_mid_decode_returns_partial_tokens():
-    # max_seq 4096: the deadline must fire MID-decode, and the paged layout
-    # (no kv_bound slice/splice per chunk) decodes a 1024-wide cache to its
+    # max_seq 4096: the deadline must fire MID-decode, and the engine
+    # decodes a 1024-wide cache to its
     # end in under the 1s deadline on CPU — reason "length" instead
     engine = make_engine(max_batch=1, max_seq_len=4096)
     try:

@@ -4,9 +4,11 @@ chunk — exactness vs the serialized path, one-iteration admission latency,
 and the no-mid-traffic-compiles guarantee via the compiled_programs stat."""
 
 import dataclasses
+import gc
 from collections import deque
 
 import jax
+import pytest
 
 from langstream_tpu.models.configs import MODEL_PRESETS, GenerationOptions
 from langstream_tpu.models.transformer import init_params
@@ -114,6 +116,53 @@ def test_prefill_token_budget_bounds_per_iteration_admission():
         for entry in pending.popleft():
             engine._process_entry(entry)
     engine._fail_all(RuntimeError("test torn down"))
+
+
+@pytest.mark.parametrize(
+    "chunk_s,in_flight,slept",
+    [(0.25, True, True), (0.25, False, False), (0.004, True, False)],
+    ids=["long-chunk-in-flight", "nothing-in-flight", "short-chunk-in-flight"],
+)
+def test_admission_grace_only_behind_a_long_chunk(monkeypatch, chunk_s, in_flight, slept):
+    """Before it decides an admission the engine thread waits a tenth of
+    the chunk in flight — only while a dispatched chunk is unfetched and
+    that tenth is worth an interpreter switch interval, so the device never
+    waits for it (an idle engine, a cold start and a fast model skip it)."""
+    from langstream_tpu.serving import engine as engine_mod
+
+    engine = make_engine(
+        start=False, max_batch=2, max_seq_len=128, decode_chunk=8, overlap=True,
+    )
+    engine._step_time_ema_s = chunk_s / engine.decode_chunk
+    sleeps = []
+    monkeypatch.setattr(engine_mod.time, "sleep", sleeps.append)
+    pending: deque = deque([[]] if in_flight else [])
+    opts = GenerationOptions(max_new_tokens=8, temperature=0.0)
+    engine.submit(GenerationRequest(prompt_tokens=[4, 5, 6], options=opts))
+    engine._iterate(pending)
+    assert sum(1 for s in engine._slots if s.active) == 1
+    assert (pytest.approx(chunk_s / 10) in sleeps) == slept
+    engine._stop.set()
+    while pending:
+        for entry in pending.popleft():
+            engine._process_entry(entry)
+    engine._fail_all(RuntimeError("test torn down"))
+
+
+def test_warmup_freezes_the_heap_and_stop_gives_it_back():
+    """A full collection over everything a serving process has built stops
+    every thread for a quarter of a second in the middle of traffic: the
+    warm-up ends by freezing what is alive, stop() unfreezes."""
+    engine = make_engine(
+        max_batch=2, max_seq_len=64, decode_chunk=4, prefill_buckets=(16,),
+        precompile=True,
+    )
+    try:
+        engine.wait_ready(timeout=300)
+        assert gc.get_freeze_count() > 100_000
+    finally:
+        engine.stop()
+    assert gc.get_freeze_count() == 0
 
 
 def test_compiled_programs_flat_after_warmup_mixed_load():
@@ -229,7 +278,7 @@ def test_bandwidth_gauge_reports_after_decode():
 def test_overlap_runs_full_chunks_only():
     """Fused scheduling retires the TTFT chunk shrink: queued work no
     longer shrinks the chunk (prefill rides every iteration instead), so
-    the decode compile surface is exactly the kv_bound ladder — the shrunk
+    the decode compile surface is exactly ONE program — the shrunk
     size was a whole extra program whose first dispatch landed on the first
     real burst (the r5b mid-traffic stall class)."""
     engine = make_engine(

@@ -42,7 +42,6 @@ from langstream_tpu.serving.fleet import (
     validate_beacon,
 )
 from langstream_tpu.serving.pagepool import PagePool, PrefixPageIndex
-from langstream_tpu.serving.prefix_cache import PrefixCachePool
 
 CFG = dataclasses.replace(MODEL_PRESETS["tiny-test"], dtype="float32")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0))
@@ -104,19 +103,6 @@ def test_paged_match_len_probe_preserves_eviction_order():
     assert index.evict_lru(pool)
     assert entry_b.node.entry is None
     assert entry_a2.node.entry is entry_a2
-
-
-def test_dense_match_len_probe_preserves_eviction_order():
-    pool = PrefixCachePool(CFG, entries=2, width=64, boundaries=(32, 64))
-    tok_a = [1 + i % 50 for i in range(40)]
-    tok_b = [7 + i % 50 for i in range(40)]
-    entry_a = pool.insert(tok_a, 32, pool.allocate())
-    pool.insert(tok_b, 32, pool.allocate())
-    for _ in range(20):
-        assert pool.match_len(tok_a) == 32
-    assert pool.match_len([9, 9, 9]) == 0
-    row = pool.allocate()  # full pool: evicts the LRU UNPROBED-or-probed?
-    assert row == entry_a.row, "probed entry should STILL be the LRU victim"
 
 
 def test_advertised_digests_track_insert_and_evict():
@@ -554,7 +540,7 @@ def test_beacon_splits_resident_and_hibernated_digests():
     import time as _time
 
     engine = make_engine(
-        kv_layout="paged", page_size=16, kv_pages=5,
+        page_size=16, kv_pages=5,
         prefix_cache_entries=8, host_kv_fraction=2.0, spill_idle_s=0.0,
     )
     try:

@@ -419,9 +419,9 @@ def test_beacon_role_validation():
 def test_memory_plan_migrate_staging_term():
     from langstream_tpu.serving.memory import plan_serving_memory
 
-    base = plan_serving_memory(CFG, 2, 128, kv_layout="paged")
+    base = plan_serving_memory(CFG, 2, 128)
     plan = plan_serving_memory(
-        CFG, 2, 128, kv_layout="paged", migrate_staging=True,
+        CFG, 2, 128, migrate_staging=True,
     )
     assert plan.migrate_staging_bytes > 0
     # HOST RAM: the staging term never inflates the HBM total

@@ -337,7 +337,7 @@ def test_flight_dump_on_injected_nan_fault(tmp_path):
 def test_flight_dump_on_injected_page_fault():
     injector = FaultInjector("page@2", seed=0)
     engine = make_engine(
-        max_batch=2, max_seq_len=64, decode_chunk=4, kv_layout="paged",
+        max_batch=2, max_seq_len=64, decode_chunk=4,
         fault_injector=injector,
     )
     try:

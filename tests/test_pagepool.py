@@ -1,13 +1,14 @@
 """Unified paged KV pool tests (ROADMAP item 1 / ISSUE 6).
 
-The paged layout is a memory/bandwidth reorganization, never a math change:
-greedy generations through the page-table must be token-for-token identical
-to the dense engine — cold and prefix-warm, short and chunked-long
-admissions, both KV dtypes, speculation on and off. Plus the host half's
+The page pool is a memory/bandwidth organization, never a math change:
+greedy generations through the page table must be token-for-token identical
+to the model-level reference (``prefill`` over a local cache, then
+``decode_step``: tests/reference_decode.py) — cold and prefix-warm, short
+and chunked-long admissions, both KV dtypes, speculation on and off. Plus the host half's
 contracts: alias refcounts (a shared page is never freed while referenced;
 a mid-page prefix tail is copy-on-write), allocator exhaustion DEFERS and
 sheds instead of corrupting, the decode compile surface is ONE program
-across mixed sequence lengths (the kv_bound ladder is gone), and the
+across mixed sequence lengths, and the
 ``page`` fault site quarantines exactly one slot with zero leaked pages.
 """
 
@@ -20,6 +21,8 @@ import pytest
 from langstream_tpu.models.configs import MODEL_PRESETS, GenerationOptions
 from langstream_tpu.models.transformer import init_params
 from langstream_tpu.serving.engine import GenerationRequest, ServingEngine
+from reference_decode import reference_greedy
+
 from langstream_tpu.serving.pagepool import (
     PagePool,
     PrefixPageIndex,
@@ -34,14 +37,13 @@ PARAMS = init_params(CFG, jax.random.PRNGKey(0))
 GREEDY = GenerationOptions(max_new_tokens=10, temperature=0.0)
 
 
-def make_engine(config=CFG, layout="paged", prefix=False, **kw):
+def make_engine(config=CFG, prefix=False, **kw):
     kw.setdefault("max_batch", 2)
     kw.setdefault("max_seq_len", 128)
     kw.setdefault("decode_chunk", 4)
     engine = ServingEngine(
         config,
         PARAMS,
-        kv_layout=layout,
         prefix_cache="auto" if prefix else "off",
         **kw,
     )
@@ -50,7 +52,7 @@ def make_engine(config=CFG, layout="paged", prefix=False, **kw):
 
 
 # ---------------------------------------------------------------------------
-# Token-exactness: paged vs dense
+# Token-exactness: the engine vs the model-level reference
 # ---------------------------------------------------------------------------
 
 
@@ -70,9 +72,9 @@ def make_engine(config=CFG, layout="paged", prefix=False, **kw):
 )
 def test_warm_prefix_exact_short_path(config, spec, page_size):
     """Admit-group path: a generation admitted against an ALIASED prefix is
-    bit-identical to a cold run on the DENSE engine — one comparison
-    carries both halves of the acceptance bar (paged==dense cold, since the
-    paged engine's first generation is itself cold, AND warm==cold).
+    bit-identical to the model-level reference — one comparison carries
+    both halves of the acceptance bar (engine==reference cold, since the
+    engine's first generation is itself cold, AND warm==cold).
     page_size=16 makes the 32-boundary prefix two pure-alias pages (zero
     copies — bytes saved must show up); page_size=64 makes it a mid-page
     tail, exercising the copy-on-write page. Speculation on top must stay
@@ -83,12 +85,8 @@ def test_warm_prefix_exact_short_path(config, spec, page_size):
         prefill_buckets=(16, 32, 64), page_size=page_size,
         speculation="auto" if spec else "off", speculation_tokens=3,
     )
-    cold_engine = make_engine(config, layout="dense", **kw)
-    try:
-        cold = cold_engine.generate(prompt, GREEDY, timeout=120).tokens
-        cold2 = cold_engine.generate(other, GREEDY, timeout=120).tokens
-    finally:
-        cold_engine.stop()
+    cold = reference_greedy(config, PARAMS, prompt, GREEDY.max_new_tokens)
+    cold2 = reference_greedy(config, PARAMS, other, GREEDY.max_new_tokens)
 
     engine = make_engine(config, prefix=True, **kw)
     try:
@@ -109,8 +107,8 @@ def test_warm_prefix_exact_short_path(config, spec, page_size):
     else:
         # mid-page prefix: exactly the copy-on-write path
         assert any(sig[0] == "page-copy" for sig in engine._programs)
-    # zero-copy means zero gather/publish programs: the dense warm path's
-    # device copies must not exist on the paged engine
+    # zero-copy means zero gather/publish programs: a warm admission makes
+    # no device copy of the prefix
     assert not any(
         str(sig[0]).startswith("prefix-") for sig in engine._programs
     ), engine._programs
@@ -121,17 +119,13 @@ def test_warm_prefix_exact_long_path(config):
     """Chunked-prefill path: a long prompt whose prefix is cached starts
     its segment loop at the reuse offset (ANY boundary — the paged segment
     writes at global positions, no full-segment-width constraint) and stays
-    token-exact with a cold run on the DENSE engine (one comparison =
-    paged==dense cold + warm==cold, as in the short-path test)."""
+    token-exact with the model-level reference (one comparison =
+    engine==reference cold + warm==cold, as in the short-path test)."""
     prompt = [(5 + 2 * i) % CFG.vocab_size for i in range(150)]  # > largest bucket
     kw = dict(
         max_seq_len=256, prefill_buckets=(16, 32, 64), page_size=64,
     )
-    cold_engine = make_engine(config, layout="dense", **kw)
-    try:
-        cold = cold_engine.generate(prompt, GREEDY, timeout=240).tokens
-    finally:
-        cold_engine.stop()
+    cold = reference_greedy(config, PARAMS, prompt, GREEDY.max_new_tokens)
     engine = make_engine(config, prefix=True, **kw)
     try:
         # publish via a SHORT admission sharing the preamble, then the long
@@ -159,6 +153,99 @@ def test_paged_speculation_matches_plain_decode():
         finally:
             engine.stop()
     assert outs["auto"] == outs["off"], outs
+
+
+# ---------------------------------------------------------------------------
+# The paged model functions against the full forward (float32)
+# ---------------------------------------------------------------------------
+
+
+def _prefill_through_pages(config, tokens, upto, width, page):
+    """Rows [0, upto) of ``tokens`` through ``paged_prefill_segment_inplace``
+    in ``width``-wide segments into row 0's pages; returns the pool, the
+    table and each segment's logits (at its last real token)."""
+    import jax.numpy as jnp
+
+    from langstream_tpu.models import transformer as T
+
+    n_pages = -(-(len(tokens) + 8) // page)
+    pool = T.make_page_pool(config, n_pages + 1, page)
+    table = jnp.arange(n_pages, dtype=jnp.int32)[None]
+    ends, logits = [], []
+    for s0 in range(0, upto, width):
+        seg = tokens[s0 : min(s0 + width, upto)]
+        padded = jnp.zeros((1, width), jnp.int32).at[0, : len(seg)].set(
+            jnp.asarray(seg)
+        )
+        out, pool = T.paged_prefill_segment_inplace(
+            PARAMS, padded, jnp.asarray([s0]), jnp.asarray([len(seg)]), pool,
+            table, config, page,
+        )
+        ends.append(s0 + len(seg) - 1)
+        logits.append(out[0])
+    return pool, table, ends, logits
+
+
+def _reference_logits(config, prompt, positions):
+    """Logits at ``positions`` of ``prompt`` without a page: the full causal
+    ``forward`` for a float cache; for int8 KV (which ``forward``, having
+    no cache, does not quantize) the one-shot ``prefill`` of the prompt up
+    to each position into an int8 local cache."""
+    import jax.numpy as jnp
+
+    from langstream_tpu.models.transformer import forward, make_kv_cache, prefill
+
+    if config.kv_cache_dtype != "int8":
+        full = forward(PARAMS, jnp.asarray([prompt]), config)[0]
+        return [full[p] for p in positions]
+    out = []
+    for p in positions:
+        logits, _ = prefill(
+            PARAMS, jnp.asarray([prompt[: p + 1]]), jnp.asarray([p + 1]),
+            make_kv_cache(config, 1, p + 1), config,
+        )
+        out.append(logits[0])
+    return out
+
+
+@pytest.mark.parametrize("config", [CFG, CFG_INT8], ids=["float", "int8kv"])
+def test_paged_segments_match_forward_past_the_largest_bucket(config):
+    """A prompt longer than the segment width, chunked through the slot's
+    pages at global offsets (the last segment padded), gives at every
+    segment's end the logits the unchunked reference gives there: what the
+    dense segment kernels' tests used to hold the long path to."""
+    import numpy as np
+
+    prompt = [(5 + 7 * i) % CFG.vocab_size for i in range(150)]
+    _, _, ends, logits = _prefill_through_pages(config, prompt, 150, 64, 16)
+    assert ends == [63, 127, 149]
+    for got, want in zip(logits, _reference_logits(config, prompt, ends)):
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4
+        )
+
+
+@pytest.mark.parametrize("config", [CFG, CFG_INT8], ids=["float", "int8kv"])
+def test_paged_verify_matches_forward_at_every_draft_position(config):
+    """The multi-token verify through the page table scores K+1 positions
+    in one call: each row of its logits is the reference's at that
+    position, over a prefix written by earlier segments."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from langstream_tpu.models.transformer import paged_verify_step_inplace
+
+    prompt = [(3 + 11 * i) % CFG.vocab_size for i in range(145)]
+    want = jnp.stack(_reference_logits(config, prompt, range(140, 145)))
+    pool, table, _, _ = _prefill_through_pages(config, prompt, 140, 64, 16)
+    logits, _ = paged_verify_step_inplace(
+        PARAMS, jnp.asarray([prompt[140:145]]), jnp.asarray([140]), pool,
+        table, config, 16,
+    )
+    assert logits.shape == (1, 5, config.vocab_size)
+    np.testing.assert_allclose(
+        np.asarray(logits[0]), np.asarray(want), rtol=2e-4, atol=2e-4
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -217,18 +304,21 @@ def test_pages_for_fraction_and_plan_term():
     from langstream_tpu.serving.memory import plan_serving_memory
 
     plan = plan_serving_memory(
-        CFG, 4, 128, kv_layout="paged", page_size=64, page_fraction=0.25
+        CFG, 4, 128, page_size=64, page_fraction=0.25
     )
-    assert plan.page_pool_bytes > 0
-    assert plan.cache_bytes == 0
-    assert plan.bound_slice_bytes == 0  # the ladder's slice peak is gone
-    assert plan.long_cache_bytes == 0  # segments write straight into pages
-    assert plan.prefix_pool_bytes == 0  # aliasing shares the one pool
-    # the paged scan forms no per-layer entry beside the pool
-    assert plan.scan_buffer_bytes == 0
-    dense = plan_serving_memory(CFG, 4, 128)
-    # dense parity + 25% alias headroom, in page-granular arithmetic
-    assert plan.page_pool_bytes == dense.cache_bytes * 10 // 8
+    from langstream_tpu.models.transformer import make_kv_cache
+
+    # every slot's max_seq_len + 25% alias headroom, in page-granular
+    # arithmetic: 10 pages of 64 tokens against 4 rows of 128
+    rows = jax.eval_shape(lambda: make_kv_cache(CFG, 4, 128))
+    row_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(rows))
+    assert plan.page_pool_bytes == row_bytes * 10 // 8
+    # the pool is the plan's only KV term: the total is the sum of what
+    # the summary names
+    assert plan.total_bytes == (
+        plan.weights_bytes + plan.page_pool_bytes + plan.workspace_bytes
+    )
+    assert "page-pool" in plan.summary() and "cache " not in plan.summary()
 
 
 # ---------------------------------------------------------------------------
@@ -298,10 +388,9 @@ def test_allocator_exhaustion_sheds_reject_policy():
 
 
 def test_compiled_programs_flat_across_mixed_lengths():
-    """Dense decode compiled one program per (steps, kv_bound) rung as
-    positions grew; paged decode is ONE program. Serve prompts/generations
-    crossing what used to be several ladder rungs and assert the program
-    count never moves after the first completed mix."""
+    """Decode is ONE program whatever the rows hold. Serve prompts and
+    generations of very different lengths and assert the program count
+    never moves after the first completed mix."""
     engine = make_engine(
         max_batch=2, max_seq_len=256, decode_chunk=4, prefill_buckets=(32,),
         precompile=True,
@@ -310,8 +399,7 @@ def test_compiled_programs_flat_across_mixed_lengths():
         opts_short = GenerationOptions(max_new_tokens=4, temperature=0.0)
         engine.generate([1, 2, 3], opts_short, timeout=120)
         warmed = engine.stats()["compiled_programs"]
-        # long generation pushes positions across the 64/128 rungs the
-        # dense ladder would have compiled separately
+        # a long generation pushes positions across several pages
         engine.generate(
             list(range(2, 30)),
             GenerationOptions(max_new_tokens=130, temperature=0.0),
@@ -321,9 +409,9 @@ def test_compiled_programs_flat_across_mixed_lengths():
         assert engine.stats()["compiled_programs"] == warmed, (
             engine._programs
         )
-        # and the ladder really is gone: no (decode, steps, bound) entries
-        assert not any(sig[0] == "decode" for sig in engine._programs)
-        assert any(sig[0] == "paged-decode" for sig in engine._programs)
+        assert [sig for sig in engine._programs if "decode" in sig[0]] == [
+            ("paged-decode", 4)
+        ]
     finally:
         engine.stop()
 

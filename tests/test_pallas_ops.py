@@ -18,11 +18,7 @@ from langstream_tpu.models.transformer import (
     make_kv_cache,
     prefill,
 )
-from langstream_tpu.ops.attention import (
-    flash_prefill_attention,
-    pallas_ok,
-    ragged_decode_attention,
-)
+from langstream_tpu.ops.attention import flash_prefill_attention, pallas_ok
 
 CFG = ModelConfig(
     name="k", vocab_size=128, d_model=64, n_layers=1, n_heads=8, n_kv_heads=4,
@@ -47,21 +43,6 @@ def test_flash_prefill_matches_reference():
         np.testing.assert_allclose(np.asarray(ref), np.asarray(out), rtol=1e-5, atol=1e-5)
 
 
-def test_ragged_decode_matches_reference():
-    b, t, h, hkv, d = 4, 64, 8, 4, 8
-    q = rand(0, b, 1, h, d)
-    k, v = rand(1, b, hkv, t, d), rand(2, b, hkv, t, d)
-    lengths = jnp.asarray([1, 17, 40, 64], jnp.int32)
-    kv_pos = jnp.arange(t)[None, None, :]
-    mask = kv_pos < lengths[:, None, None]
-    for config in (CFG, SOFTCAP_CFG):
-        ref = attention(q, k, v, mask, config)[:, 0]
-        out = ragged_decode_attention(
-            q[:, 0], k, v, lengths, config, block_k=16, interpret=True
-        )
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out), rtol=1e-5, atol=1e-5)
-
-
 def test_forward_with_pallas_matches_jnp():
     base = dataclasses.replace(
         MODEL_PRESETS["tiny-test"], dtype="float32", attention_impl="jnp"
@@ -75,6 +56,10 @@ def test_forward_with_pallas_matches_jnp():
 
 
 def test_prefill_decode_with_pallas_matches_jnp():
+    """The model-level pair the paged admission runs (``prefill`` into a
+    local cache) and the tests' reference step (``decode_step``): forcing
+    the kernel changes the prefill's attention only, and nothing the cache
+    or the next step's logits hold beyond rounding."""
     base = dataclasses.replace(
         MODEL_PRESETS["tiny-test"], dtype="float32", attention_impl="jnp"
     )
@@ -136,121 +121,6 @@ def _int8_cache(key, b, hkv, t, d):
 
     q8, s = _quantize_kv(rand(key, b, hkv, t, d))
     return {"q": q8, "s": s}
-
-
-def test_flash_segment_matches_reference():
-    """Chunked-prefill segment kernel: global-position causal against the
-    cache prefix + the segment's own lower triangle."""
-    from langstream_tpu.ops.attention import flash_segment_attention
-
-    b, s, t, h, hkv, d = 2, 16, 64, 8, 4, 8
-    q = rand(0, b, s, h, d)
-    k, v = rand(1, b, hkv, t, d), rand(2, b, hkv, t, d)
-    offset = jnp.asarray([0, 32], jnp.int32)
-    q_pos = offset[:, None, None] + jnp.arange(s)[None, :, None]
-    mask = jnp.arange(t)[None, None, :] <= q_pos
-    for config in (CFG, SOFTCAP_CFG):
-        ref = attention(q, k, v, mask, config)
-        out = flash_segment_attention(
-            q, k, v, offset, config, block_q=8, block_k=16, interpret=True
-        )
-        np.testing.assert_allclose(np.asarray(ref), np.asarray(out), rtol=1e-5, atol=1e-5)
-
-
-def test_flash_segment_int8_matches_dequantized_reference():
-    """The int8 segment kernel computes dequantize-then-attend with the
-    dequantize in VMEM — so the EXACT reference is attention over the
-    explicitly dequantized cache (the jnp int8 path hoists scales instead,
-    which rounds differently; it is checked loosely below)."""
-    from langstream_tpu.models.transformer import _dequantize_kv
-    from langstream_tpu.ops.attention import flash_segment_attention_int8
-
-    b, s, t, h, hkv, d = 2, 16, 64, 8, 4, 8
-    q = rand(0, b, s, h, d)
-    k8, v8 = _int8_cache(1, b, hkv, t, d), _int8_cache(2, b, hkv, t, d)
-    offset = jnp.asarray([16, 48], jnp.int32)
-    q_pos = offset[:, None, None] + jnp.arange(s)[None, :, None]
-    mask = jnp.arange(t)[None, None, :] <= q_pos
-    kd, vd = _dequantize_kv(k8, q.dtype), _dequantize_kv(v8, q.dtype)
-    for config in (CFG, SOFTCAP_CFG):
-        ref = attention(q, kd, vd, mask, config)
-        out = flash_segment_attention_int8(
-            q, k8, v8, offset, config, block_q=8, block_k=16, interpret=True
-        )
-        np.testing.assert_allclose(
-            np.asarray(ref), np.asarray(out), rtol=1e-5, atol=1e-5
-        )
-        # and the hoisted-scale jnp int8 path agrees to quantization noise
-        loose = attention(q, k8, v8, mask, config)
-        np.testing.assert_allclose(
-            np.asarray(loose), np.asarray(out), rtol=1e-1, atol=3e-2
-        )
-
-
-def test_ragged_decode_int8_matches_int8_reference():
-    from langstream_tpu.ops.attention import ragged_decode_attention_int8
-
-    b, t, h, hkv, d = 4, 64, 8, 4, 8
-    q = rand(0, b, 1, h, d)
-    k8, v8 = _int8_cache(1, b, hkv, t, d), _int8_cache(2, b, hkv, t, d)
-    lengths = jnp.asarray([1, 17, 40, 64], jnp.int32)
-    mask = jnp.arange(t)[None, None, :] < lengths[:, None, None]
-    for config in (CFG, SOFTCAP_CFG):
-        ref = attention(q, k8, v8, mask, config)[:, 0]
-        out = ragged_decode_attention_int8(
-            q[:, 0], k8, v8, lengths, config, block_k=16, interpret=True
-        )
-        np.testing.assert_allclose(
-            np.asarray(ref), np.asarray(out), rtol=2e-2, atol=2e-2
-        )
-
-
-def test_fused_segment_decode_batch_matches_both_references():
-    """The fused mixed-batch dispatch (prefill segments + single-token
-    decode rows against ONE cache) is bit-identical to each half's
-    standalone path — it routes, it never re-derives math. This is the
-    attention layer of a fused engine iteration (segment kernel for the
-    prefill rows, kv_bound-sliced dense read for the decode rows)."""
-    from langstream_tpu.ops.attention import fused_segment_decode_attention
-
-    b, s, t, h, hkv, d = 4, 16, 64, 8, 4, 8
-    k, v = rand(1, b, hkv, t, d), rand(2, b, hkv, t, d)
-    # rows 1 and 3 are mid-prefill segments at different offsets; rows 0
-    # and 2 are decoding at different lengths
-    seg_rows = jnp.asarray([1, 3], jnp.int32)
-    seg_offsets = jnp.asarray([0, 32], jnp.int32)
-    q_seg = rand(3, 2, s, h, d)
-    dec_rows = jnp.asarray([0, 2], jnp.int32)
-    dec_lengths = jnp.asarray([7, 29], jnp.int32)
-    q_dec = rand(4, 2, h, d)
-
-    for config, kv_bound in ((CFG, None), (CFG, 32), (SOFTCAP_CFG, None)):
-        seg_out, dec_out = fused_segment_decode_attention(
-            q_seg, seg_offsets, q_dec, k, v, seg_rows, dec_rows,
-            dec_lengths, config, kv_bound=kv_bound, interpret=True,
-        )
-        # prefill half ≡ the standalone segment path on the gathered rows
-        q_pos = seg_offsets[:, None, None] + jnp.arange(s)[None, :, None]
-        seg_mask = jnp.arange(t)[None, None, :] <= q_pos
-        seg_ref = attention(q_seg, k[seg_rows], v[seg_rows], seg_mask, config)
-        np.testing.assert_allclose(
-            np.asarray(seg_ref), np.asarray(seg_out), rtol=1e-5, atol=1e-5
-        )
-        # decode half ≡ the dense masked read over the (sliced) cache
-        tb = kv_bound or t
-        dec_mask = (
-            jnp.arange(tb)[None, None, :] < dec_lengths[:, None, None]
-        )
-        dec_ref = attention(
-            q_dec[:, None],
-            k[dec_rows][:, :, :tb],
-            v[dec_rows][:, :, :tb],
-            dec_mask,
-            config,
-        )[:, 0]
-        np.testing.assert_allclose(
-            np.asarray(dec_ref), np.asarray(dec_out), rtol=1e-5, atol=1e-5
-        )
 
 
 def _gather_entry(entry, table, ps):

@@ -132,39 +132,3 @@ def test_ring_prefill_matches_dense_prefill():
         rtol=2e-4,
         atol=2e-4,
     )
-
-
-def test_ring_long_prefill_engine_matches_single_device():
-    """A long prompt (wider than every prefill bucket) served on a
-    model×seq mesh takes the one-dispatch ring path and generates the same
-    greedy tokens as the single-device chunked-prefill segment loop."""
-    from langstream_tpu.models.configs import GenerationOptions
-    from langstream_tpu.serving.engine import ServingEngine
-
-    config = fp32_config("tiny-test")
-    params = init_params(config, jax.random.PRNGKey(0))
-    prompt = [7 + (i % 23) for i in range(100)]  # > largest bucket (32)
-    options = GenerationOptions(max_new_tokens=10, temperature=0.0)
-    kw = dict(max_batch=2, max_seq_len=512, prefill_buckets=(16, 32), decode_chunk=4)
-
-    single = ServingEngine(config, params, **kw)
-    single.start()
-    try:
-        ref = single.generate(prompt, options, timeout=300)
-    finally:
-        single.stop()
-
-    mesh = build_mesh({"model": 2, "seq": 4})
-    sharded = shard_params(params, mesh, config)
-    # ring long-prefill is a dense-layout path (the admit splices into the
-    # big cache); the paged default takes the segment loop instead
-    ring = ServingEngine(config, sharded, mesh=mesh, kv_layout="dense", **kw)
-    assert ring._ring_admit is not None, "seq mesh axis must enable ring admit"
-    ring.start()
-    try:
-        out = ring.generate(prompt, options, timeout=300)
-    finally:
-        ring.stop()
-
-    assert ref.tokens == out.tokens
-    assert out.finish_reason == ref.finish_reason

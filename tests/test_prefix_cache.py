@@ -2,8 +2,10 @@
 token-for-token identical to cold runs (the cache is a scheduling/bandwidth
 optimization, never a math change) for both the short admit-group path and
 the chunked-prefill long-prompt path, on float (bf16-on-TPU) and int8
-caches; plus radix-index semantics, refcounted LRU eviction, and the memory
-plan's pool term."""
+pools; plus the page index's radix semantics, its refcounted LRU eviction
+and publish dedupe. The prefix cache is ``pagepool.PrefixPageIndex``: entries
+pin pages of the engine's one pool (tests/test_pagepool.py has the
+allocator's and the aliasing contracts)."""
 
 import dataclasses
 
@@ -13,10 +15,7 @@ import pytest
 from langstream_tpu.models.configs import MODEL_PRESETS, GenerationOptions
 from langstream_tpu.models.transformer import init_params
 from langstream_tpu.serving.engine import GenerationRequest, ServingEngine
-from langstream_tpu.serving.prefix_cache import (
-    PrefixCachePool,
-    pool_entries_for_fraction,
-)
+from langstream_tpu.serving.pagepool import PagePool, PrefixPageIndex
 
 CFG = dataclasses.replace(MODEL_PRESETS["tiny-test"], dtype="float32")
 CFG_INT8 = dataclasses.replace(CFG, kv_cache_dtype="int8")
@@ -100,29 +99,31 @@ def test_warm_prefix_exact_long_path(config):
         assert first == cold
         assert warm == cold
         stats = engine.stats()
-        # long-path reuse is full-segment-width only (pool width = 32)
+        # the long path aliases at the deepest published boundary
         assert stats["prefill-tokens-saved-total"] == 32
         assert stats["prefix-cache-entries"] >= 1
     finally:
         engine.stop()
 
 
-def test_deeper_entry_serves_shorter_prompt():
+@pytest.mark.parametrize("config", [CFG, CFG_INT8], ids=["float", "int8kv"])
+def test_deeper_entry_serves_shorter_prompt(config):
     """A preamble published as part of a LONGER prompt serves shorter
-    prompts sharing it: the pool row's leading columns ARE that prefix's
-    KV, and the radix walk reuses them at the matched depth."""
+    prompts sharing it: the entry's leading pages ARE that prefix's KV,
+    and the radix walk reuses them at the matched depth."""
     preamble = [(9 + i) % CFG.vocab_size for i in range(32)]
     long_prompt = preamble + [(5 * i) % CFG.vocab_size for i in range(20)]
     short_prompt = preamble + [7, 8, 9]
     cold_engine = make_engine(
-        max_batch=2, max_seq_len=128, decode_chunk=4, prefill_buckets=(16, 32, 64),
+        config, max_batch=2, max_seq_len=128, decode_chunk=4,
+        prefill_buckets=(16, 32, 64),
     )
     try:
         cold = cold_engine.generate(short_prompt, GREEDY, timeout=120).tokens
     finally:
         cold_engine.stop()
     engine = make_engine(
-        prefix=True, max_batch=2, max_seq_len=128, decode_chunk=4,
+        config, prefix=True, max_batch=2, max_seq_len=128, decode_chunk=4,
         prefill_buckets=(16, 32, 64),
     )
     try:
@@ -134,7 +135,8 @@ def test_deeper_entry_serves_shorter_prompt():
         engine.stop()
 
 
-def test_concurrent_shared_preamble_burst_hits():
+@pytest.mark.parametrize("config", [CFG, CFG_INT8], ids=["float", "int8kv"])
+def test_concurrent_shared_preamble_burst_hits(config):
     """The workload the cache exists for: after one warmup chat, a burst of
     chats sharing the preamble all reuse it (hit rate counts the warmup
     miss) and every completion matches the cold engine's output."""
@@ -143,7 +145,8 @@ def test_concurrent_shared_preamble_burst_hits():
     opts = GenerationOptions(max_new_tokens=8, temperature=0.0)
 
     cold_engine = make_engine(
-        max_batch=4, max_seq_len=128, decode_chunk=4, prefill_buckets=(16, 32, 64),
+        config, max_batch=4, max_seq_len=128, decode_chunk=4,
+        prefill_buckets=(16, 32, 64),
     )
     try:
         cold = [
@@ -154,7 +157,7 @@ def test_concurrent_shared_preamble_burst_hits():
         cold_engine.stop()
 
     engine = make_engine(
-        prefix=True, max_batch=4, max_seq_len=128, decode_chunk=4,
+        config, prefix=True, max_batch=4, max_seq_len=128, decode_chunk=4,
         prefill_buckets=(16, 32, 64),
     )
     try:
@@ -173,39 +176,16 @@ def test_concurrent_shared_preamble_burst_hits():
         engine.stop()
 
 
-def test_lru_eviction_under_pressure_skips_referenced():
-    """Refcounted blocks in use are never evicted: with the pool full, the
-    LRU *unreferenced* entry is evicted; with every entry pinned, allocate
-    refuses (publish skips) instead of corrupting an in-flight read."""
-    pool = PrefixCachePool(CFG, entries=2, width=32, boundaries=(16, 32))
-    a = list(range(100, 132))
-    b = list(range(200, 232))
-    c = list(range(300, 332))
-    ea = pool.insert(a, 32, pool.allocate())
-    eb = pool.insert(b, 32, pool.allocate())
-    # touch A so B is the LRU entry
-    pool.record_lookup(ea)
-    pool.acquire(eb)  # ...but B is pinned by an in-flight admission
-    row = pool.allocate()  # must evict A (LRU among unreferenced), not B
-    assert row == ea.row
-    assert pool.evictions == 1
-    assert pool._live[eb.row] is eb  # B untouched
-    ec = pool.insert(c, 32, row)
-    pool.acquire(ec)
-    assert pool.allocate() is None  # everything pinned → refuse, don't evict
-    pool.release(eb)
-    assert pool.allocate() == eb.row  # released entry becomes evictable
-    assert pool.evictions == 2
-
-
-def test_engine_eviction_pressure_stays_exact():
+@pytest.mark.parametrize("config", [CFG, CFG_INT8], ids=["float", "int8kv"])
+def test_engine_eviction_pressure_stays_exact(config):
     """Cycling more distinct preambles than the pool holds forces LRU
     evictions mid-traffic; generations stay bit-exact throughout."""
     cold_engine = make_engine(
-        max_batch=2, max_seq_len=128, decode_chunk=4, prefill_buckets=(16, 32),
+        config, max_batch=2, max_seq_len=128, decode_chunk=4,
+        prefill_buckets=(16, 32),
     )
     engine = ServingEngine(
-        CFG, PARAMS, max_batch=2, max_seq_len=128, decode_chunk=4,
+        config, PARAMS, max_batch=2, max_seq_len=128, decode_chunk=4,
         prefill_buckets=(16, 32), prefix_cache="auto", prefix_cache_entries=2,
     )
     engine.start()
@@ -225,70 +205,87 @@ def test_engine_eviction_pressure_stays_exact():
         cold_engine.stop()
 
 
-def test_radix_candidates_and_publish_dedupe():
-    pool = PrefixCachePool(CFG, entries=4, width=32, boundaries=(8, 16, 32))
+def test_lru_eviction_skips_referenced_pages():
+    """Entries in use are never evicted: with the index full, the LRU
+    UNPINNED entry goes; with every entry pinned, eviction refuses instead
+    of handing an in-flight admission's pages to someone else. Pages an
+    evicted entry shared with a live slot stay allocated."""
+    pool = PagePool(CFG, num_pages=8, page_size=16, max_batch=2, max_seq_len=64)
+    index = PrefixPageIndex(boundaries=(16, 32), max_entries=2)
+    a, b, c = (list(range(k, k + 32)) for k in (100, 200, 300))
+
+    def publish(tokens):
+        # a publisher's slot reserves, publishes its leading pages, ends
+        pool.reserve(1, 2)
+        entry = index.insert(pool, tokens, 32, tuple(pool.slot_pages(1)))
+        pool.free_slot(1)
+        return entry
+
+    ea, eb = publish(a), publish(b)
+    # touch A so B is the LRU entry ... but B is pinned by an admission
+    index.record_lookup(ea)
+    index.acquire(eb)
+    # a third publish at the cap must evict A (LRU among the unpinned)
+    ec = publish(c)
+    assert ec is not None and index.evictions == 1
+    assert ea.node.entry is None and eb.node.entry is eb
+    assert index.has(b, 32) and index.has(c, 32) and not index.has(a, 32)
+    index.acquire(ec)
+    assert not index.evict_lru(pool)  # everything pinned: refuse
+    # a slot aliasing B's pages keeps them allocated past B's eviction
+    b_pages = list(eb.pages)
+    assert pool.reserve(0, 2, shared=tuple(b_pages)) is not None
+    index.release(eb)
+    assert index.evict_lru(pool) and index.evictions == 2
+    assert pool.slot_pages(0) == b_pages
+    assert pool.pages_in_use == 4  # C's two pages + the slot's two
+
+
+def test_radix_candidates_and_publish_length():
+    pool = PagePool(CFG, num_pages=8, page_size=8, max_batch=2, max_seq_len=64)
+    index = PrefixPageIndex(boundaries=(8, 16, 32), max_entries=4)
     tokens = list(range(40))
-    assert pool.candidates(tokens) == []
-    assert pool.publish_length(40) == 32
-    assert pool.publish_length(20) == 16
-    assert pool.publish_length(4) == 0
-    e = pool.insert(tokens, 32, pool.allocate())
-    assert pool.has(tokens, 32)
+    assert index.candidates(tokens) == []
+    assert index.publish_length(40) == 32
+    assert index.publish_length(20) == 16
+    assert index.publish_length(4) == 0
+    e = index.insert(pool, tokens, 32, tuple(pool.alloc_pages(4)))
+    assert index.has(tokens, 32)
     # full-depth candidate for a longer prompt...
-    assert pool.candidates(tokens + [99]) == [(32, e)]
+    assert index.candidates(tokens + [99]) == [(32, e)]
     # ...partial reuse at the matched depth for a prompt diverging at 20
     divergent = tokens[:16] + [500] * 16
-    assert pool.candidates(divergent) == [(16, e)]
+    assert index.candidates(divergent) == [(16, e)]
     # the lookup cap: at least one suffix token must remain to prefill
-    assert pool.candidates(tokens[:32]) == [(16, e)]
-    assert not pool.candidates(tokens[:8])
+    assert index.candidates(tokens[:32]) == [(16, e)]
+    assert not index.candidates(tokens[:8])
 
 
-def test_memory_plan_accounts_prefix_pool():
-    from langstream_tpu.serving.memory import plan_serving_memory
-
-    base = plan_serving_memory(CFG, 4, 256)
-    with_pool = plan_serving_memory(
-        CFG, 4, 256, prefix_pool_entries=4, prefix_pool_width=64
+@pytest.mark.parametrize("config", [CFG, CFG_INT8], ids=["float", "int8kv"])
+def test_publish_dedupe_through_engine(config):
+    """A prefix already indexed is not published again: the same prompt
+    twice, then a prompt sharing its first boundary only, leave one entry
+    per distinct boundary-aligned prefix and hold each page once."""
+    prompt = [(7 + 3 * i) % CFG.vocab_size for i in range(45)]
+    branch = prompt[:20] + [(5 * i + 2) % CFG.vocab_size for i in range(25)]
+    engine = make_engine(
+        config, prefix=True, max_batch=2, max_seq_len=128, decode_chunk=4,
+        prefill_buckets=(16, 32, 64), page_size=16,
     )
-    assert with_pool.prefix_pool_bytes > 0
-    assert with_pool.total_bytes == base.total_bytes + with_pool.prefix_pool_bytes
-    assert "prefix-pool" in with_pool.summary()
-    # engine surfaces the pool in its own plan (dense layout: the paged
-    # layout folds prefix reuse into the one page pool — test_pagepool.py)
-    engine = ServingEngine(
-        CFG, PARAMS, max_batch=2, max_seq_len=128, prefill_buckets=(16, 32),
-        prefix_cache="auto", prefix_cache_entries=3, kv_layout="dense",
-    )
-    assert engine._plan is not None
-    assert engine._plan.prefix_pool_bytes > 0
-    engine._fail_all(RuntimeError("never started"))
-
-
-def test_pool_sizing_fraction():
-    assert pool_entries_for_fraction(8, 2048, 2048, 0.0) == 0
-    assert pool_entries_for_fraction(8, 2048, 2048, 0.25) == 2
-    assert pool_entries_for_fraction(192, 512, 64, 0.25) == 384
-    assert pool_entries_for_fraction(192, 512, 1, 1.0) == 512  # capped
-
-
-def test_token_fetcher_preserves_order():
-    """The dedicated fetch thread returns results in submission (= chunk)
-    order, and handles resolve inline when no thread is running."""
-    import numpy as np
-
-    from langstream_tpu.serving.engine import _TokenFetcher
-
-    fetcher = _TokenFetcher()
-    # no thread: inline fallback
-    h = fetcher.submit(jax.numpy.arange(4))
-    assert h.result().tolist() == [0, 1, 2, 3]
-    fetcher.start()
     try:
-        handles = [fetcher.submit(jax.numpy.full((2,), i)) for i in range(16)]
-        for i, h in enumerate(handles):
-            np.testing.assert_array_equal(h.result(), np.full((2,), i))
+        engine.generate(prompt, GREEDY, timeout=120)
+        index = engine._prefix_index
+        assert index.live_entries == 1 and index.pages_held == 2
+        engine.generate(prompt, GREEDY, timeout=120)  # warm: nothing new
+        assert index.live_entries == 1 and index.pages_held == 2
+        # diverges at 20: aliases the first 16 tokens, publishes its own 32
+        engine.generate(branch, GREEDY, timeout=120)
+        assert index.live_entries == 2
+        assert index.has(prompt, 32) and index.has(branch, 32)
+        # the shared first page is held once, not once per entry
+        assert index.pages_held == 3
+        assert engine.stats()["prefix-pool-bytes-in-use"] == (
+            3 * engine._pagepool.bytes_per_page
+        )
     finally:
-        fetcher.stop()
-    # after stop: inline fallback again
-    assert fetcher.submit(jax.numpy.arange(2)).result().tolist() == [0, 1]
+        engine.stop()

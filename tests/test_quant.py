@@ -93,6 +93,27 @@ def test_tpu_serving_quantization_config(run):
     run(scenario())
 
 
+def test_tpu_serving_refuses_the_dense_layout_by_name():
+    """A configuration that still asks for the deleted KV layout fails at
+    build with a sentence that says so; naming the one layout is accepted."""
+    import pytest
+
+    from langstream_tpu.ai.tpu_serving import TpuServingProvider
+
+    base = {"model": "tiny-test", "tokenizer": "byte", "max-seq-len": 64}
+    provider = TpuServingProvider({**base, "kv-layout": "dense"})
+    with pytest.raises(ValueError, match="kv-layout: dense is gone"):
+        provider.engine()
+    kept = TpuServingProvider({**base, "kv-layout": "paged"})
+    try:
+        assert kept.engine().stats()["kv-pages-total"] > 0
+        assert "kv-layout" not in kept.engine().stats()
+    finally:
+        import asyncio
+
+        asyncio.run(kept.close())
+
+
 def test_int8_kv_cache_matches_bf16_cache():
     """Prefill + decode with the int8 KV cache tracks the fp32 cache closely
     (per-token per-head symmetric quant; rtol bounded by 1/127)."""

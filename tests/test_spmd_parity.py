@@ -48,14 +48,13 @@ GREEDY = GenerationOptions(max_new_tokens=5, temperature=0.0)
 PREAMBLE = [(7 + i) % CFG.vocab_size for i in range(16)]
 
 
-def _engine_kwargs(layout: str, prefix: bool, spec: bool) -> dict:
+def _engine_kwargs(prefix: bool, spec: bool) -> dict:
     return dict(
         max_batch=3,
         max_seq_len=MAX_SEQ,
         decode_chunk=4,
         prefill_buckets=BUCKETS,
         prefill_batch=4,
-        kv_layout=layout,
         page_size=PAGE,
         prefix_cache="auto" if prefix else False,
         speculation="auto" if spec else False,
@@ -63,12 +62,12 @@ def _engine_kwargs(layout: str, prefix: bool, spec: bool) -> dict:
     )
 
 
-def _channel(layout: str, spec: bool, echo: bool = True) -> LoopbackChannel:
+def _channel(spec: bool, echo: bool = True) -> LoopbackChannel:
     return LoopbackChannel(
         prefill_batch=4,
         max_width=max(BUCKETS),
         max_batch=3,
-        table_len=table_len_for(MAX_SEQ, PAGE) if layout == "paged" else 0,
+        table_len=table_len_for(MAX_SEQ, PAGE),
         spec_tokens=4 if spec else 0,
         echo=echo,
     )
@@ -78,11 +77,11 @@ class _Pair:
     """A loopback leader+follower sharing params, with the follower's
     crash (if any) captured for assertion."""
 
-    def __init__(self, config, layout, prefix, spec, *, echo=True,
+    def __init__(self, config, prefix, spec, *, echo=True,
                  injector=None, follower_params=None):
         self.params = init_params(config, jax.random.PRNGKey(0))
-        self.channel = _channel(layout, spec, echo=echo)
-        kw = _engine_kwargs(layout, prefix, spec)
+        self.channel = _channel(spec, echo=echo)
+        kw = _engine_kwargs(prefix, spec)
         self.leader = ServingEngine(
             config, self.params, spmd=self.channel,
             fault_injector=injector, **kw,
@@ -114,11 +113,8 @@ class _Pair:
                 np.asarray(jax.device_get(getattr(self.leader, attr))),
                 np.asarray(jax.device_get(getattr(self.follower, attr))),
             )
-        store = lambda e: (  # noqa: E731
-            e._pagepool.dev if e._paged else e._cache
-        )
-        leaves_a = jax.tree.leaves(jax.device_get(store(self.leader)))
-        leaves_b = jax.tree.leaves(jax.device_get(store(self.follower)))
+        leaves_a = jax.tree.leaves(jax.device_get(self.leader._pagepool.dev))
+        leaves_b = jax.tree.leaves(jax.device_get(self.follower._pagepool.dev))
         assert leaves_a and len(leaves_a) == len(leaves_b)
         for a, b in zip(leaves_a, leaves_b):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -156,13 +152,13 @@ def _concurrent_batch(engine, prompts, opts=GREEDY) -> list[list[int]]:
 
 @pytest.mark.parametrize("config", [CFG, CFG_INT8], ids=["f32kv", "int8kv"])
 def test_paged_prefix_parity_cold_warm_long(config):
-    """kv_layout=paged + prefix-cache=auto under loopback SPMD: page binds,
+    """prefix-cache=auto under loopback SPMD: page binds,
     aliased warm admissions, segment prefill and frees all replay; tokens
     equal the single-host engine's and device state stays bit-identical.
     Echo divergence checking is ON throughout (no false positives)."""
     ref = ServingEngine(
         config, init_params(config, jax.random.PRNGKey(0)),
-        **_engine_kwargs("paged", prefix=True, spec=False),
+        **_engine_kwargs(prefix=True, spec=False),
     )
     ref.start()
     try:
@@ -171,7 +167,7 @@ def test_paged_prefix_parity_cold_warm_long(config):
     finally:
         ref.stop()
 
-    pair = _Pair(config, "paged", prefix=True, spec=False)
+    pair = _Pair(config, prefix=True, spec=False)
     try:
         got = _mixed_workload(pair.leader)
         stats = pair.leader.stats()
@@ -201,7 +197,7 @@ def test_paged_speculation_parity_mixed_batch(config):
 
     ref = ServingEngine(
         config, init_params(config, jax.random.PRNGKey(0)),
-        **_engine_kwargs("paged", prefix=True, spec=True),
+        **_engine_kwargs(prefix=True, spec=True),
     )
     ref.start()
     try:
@@ -209,7 +205,7 @@ def test_paged_speculation_parity_mixed_batch(config):
     finally:
         ref.stop()
 
-    pair = _Pair(config, "paged", prefix=True, spec=True)
+    pair = _Pair(config, prefix=True, spec=True)
     try:
         got = sorted(_concurrent_batch(pair.leader, prompts, opts))
         stats = pair.leader.stats()
@@ -224,45 +220,17 @@ def test_paged_speculation_parity_mixed_batch(config):
     pair.assert_lockstep()
 
 
-def test_dense_prefix_and_speculation_parity():
-    """The dense layout's wire tier with both fast paths ON: gather/publish
-    admissions (OP_PREFIX_ADMIT/OP_PREFIX_PUBLISH) and verify dispatches
-    replay; token-exact vs single-host, state bit-identical."""
-    ref = ServingEngine(
-        CFG, init_params(CFG, jax.random.PRNGKey(0)),
-        **_engine_kwargs("dense", prefix=True, spec=True),
-    )
-    ref.start()
-    try:
-        want = _mixed_workload(ref)
-        assert ref.stats()["prefix-cache-hit-rate"] > 0
-    finally:
-        ref.stop()
-
-    pair = _Pair(CFG, "dense", prefix=True, spec=True)
-    try:
-        got = _mixed_workload(pair.leader)
-        stats = pair.leader.stats()
-        assert stats["prefix-cache-hit-rate"] > 0
-        assert stats["spec-verify-dispatches-total"] > 0
-    finally:
-        pair.stop()
-    assert not pair.follower_error, pair.follower_error
-    assert got == want
-    pair.assert_lockstep()
-
-
 def test_no_construction_disable_warnings(caplog):
     """The three construction-time SPMD disables are GONE: building an
     engine with prefix-cache + speculation + paged on an SPMD channel
     must not warn about falling back or disabling anything."""
     import logging
 
-    channel = _channel("paged", spec=True)
+    channel = _channel(spec=True)
     with caplog.at_level(logging.WARNING, logger="langstream_tpu.serving.engine"):
         engine = ServingEngine(
             CFG, init_params(CFG, jax.random.PRNGKey(0)), spmd=channel,
-            **_engine_kwargs("paged", prefix=True, spec=True),
+            **_engine_kwargs(prefix=True, spec=True),
         )
     assert engine._paged and engine._spec_enabled
     assert engine._prefix_index is not None
@@ -282,7 +250,7 @@ def test_page_fault_quarantines_victim_only_on_both():
     opts = GenerationOptions(max_new_tokens=6, temperature=0.0)
     ref = ServingEngine(
         CFG, init_params(CFG, jax.random.PRNGKey(0)),
-        **_engine_kwargs("paged", prefix=True, spec=False),
+        **_engine_kwargs(prefix=True, spec=False),
     )
     ref.start()
     try:
@@ -293,7 +261,7 @@ def test_page_fault_quarantines_victim_only_on_both():
         ref.stop()
 
     pair = _Pair(
-        CFG, "paged", prefix=True, spec=False,
+        CFG, prefix=True, spec=False,
         injector=FaultInjector("page@1", seed=0),
     )
     try:
@@ -335,7 +303,7 @@ def test_nan_fault_quarantines_victim_only_on_both():
     prompts = [[5, 6, 7], [8, 9, 1, 2]]
     opts = GenerationOptions(max_new_tokens=6, temperature=0.0)
     pair = _Pair(
-        CFG, "paged", prefix=False, spec=False,
+        CFG, prefix=False, spec=False,
         injector=FaultInjector("nan@2", seed=0),
     )
     try:
@@ -380,7 +348,7 @@ def test_divergence_detected_dumped_and_fatal():
     )
 
     pair = _Pair(
-        CFG, "paged", prefix=False, spec=False,
+        CFG, prefix=False, spec=False,
         follower_params=init_params(CFG, jax.random.PRNGKey(99)),
     )
     try:
@@ -409,7 +377,7 @@ def test_wire_bytes_accounted():
     """The channel measures its own overhead (announces + bytes) — the
     PERF.md round-13 ControlBlock-bytes-per-iteration number is read off
     these counters, not estimated."""
-    pair = _Pair(CFG, "paged", prefix=True, spec=False, echo=False)
+    pair = _Pair(CFG, prefix=True, spec=False, echo=False)
     try:
         pair.leader.generate([5, 6, 7], GREEDY, timeout=120)
         ch = pair.channel
@@ -438,7 +406,7 @@ def test_two_process_full_fast_path_parity():
 
     ref = ServingEngine(
         CFG, init_params(CFG, jax.random.PRNGKey(0)),
-        **_engine_kwargs("paged", prefix=True, spec=True),
+        **_engine_kwargs(prefix=True, spec=True),
     )
     ref.start()
     try:

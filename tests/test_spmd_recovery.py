@@ -73,7 +73,6 @@ def _engine_kwargs(**over) -> dict:
         decode_chunk=4,
         prefill_buckets=BUCKETS,
         prefill_batch=2,
-        kv_layout="paged",
         page_size=PAGE,
         prefix_cache=False,
         speculation=False,

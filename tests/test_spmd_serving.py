@@ -3,7 +3,7 @@
 Two tiers:
 1. LoopbackChannel in one process: a leader engine and a follower engine
    share the device mesh; after a generation their device-resident state
-   (KV cache, decode chain) must be bit-identical — the lockstep property
+   (page pool, decode chain) must be bit-identical — the lockstep property
    the real multi-host replica depends on.
 2. A REAL 2-process ``jax.distributed`` run (subprocesses, real
    coordinator, broadcast_one_to_all over the global mesh): only the
@@ -22,50 +22,51 @@ from pathlib import Path
 
 import jax
 import numpy as np
+import pytest
 
 from langstream_tpu.models.configs import MODEL_PRESETS, GenerationOptions
 from langstream_tpu.models.transformer import init_params
 from langstream_tpu.parallel.spmd_serving import LoopbackChannel, follower_loop
 from langstream_tpu.serving.engine import GenerationRequest, ServingEngine
+from langstream_tpu.serving.pagepool import table_len_for
 
 CFG = dataclasses.replace(MODEL_PRESETS["tiny-test"], dtype="float32")
+PAGE = 8
+TABLE_LEN = table_len_for(64, PAGE)
 
 
 
 def _assert_lockstep(leader, follower) -> None:
     """Leader/follower device state must be bit-identical (the property
     every multi-host replica depends on). Compares the decode chain plus
-    whichever KV store the layout uses (dense big cache or the page
-    pool)."""
+    the page pool."""
     for attr in ("_tokens_dev", "_positions_dev"):
         np.testing.assert_array_equal(
             np.asarray(jax.device_get(getattr(leader, attr))),
             np.asarray(jax.device_get(getattr(follower, attr))),
         )
-    store = lambda e: (  # noqa: E731
-        e._pagepool.dev if e._paged else e._cache
-    )
-    assert leader._paged == follower._paged
-    leaves_a = jax.tree.leaves(jax.device_get(store(leader)))
-    leaves_b = jax.tree.leaves(jax.device_get(store(follower)))
+    leaves_a = jax.tree.leaves(jax.device_get(leader._pagepool.dev))
+    leaves_b = jax.tree.leaves(jax.device_get(follower._pagepool.dev))
     assert leaves_a and len(leaves_a) == len(leaves_b)
     for a, b in zip(leaves_a, leaves_b):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_loopback_follower_stays_in_lockstep():
-    # this file is the DENSE-wire tier (pinned on both sides); the paged /
-    # prefix / speculation wire is covered by tests/test_spmd_parity.py
+    # the plain wire tier; prefix reuse and speculation on the wire are
+    # covered by tests/test_spmd_parity.py
     params = init_params(CFG, jax.random.PRNGKey(0))
-    channel = LoopbackChannel(prefill_batch=4, max_width=32, max_batch=2)
+    channel = LoopbackChannel(
+        prefill_batch=4, max_width=32, max_batch=2, table_len=TABLE_LEN
+    )
     leader = ServingEngine(
         CFG, params, max_batch=2, max_seq_len=64, decode_chunk=4,
         prefill_buckets=(16, 32), prefill_batch=4, spmd=channel,
-        kv_layout="dense",
+        page_size=PAGE,
     )
     follower = ServingEngine(
         CFG, params, max_batch=2, max_seq_len=64, decode_chunk=4,
-        prefill_buckets=(16, 32), prefill_batch=4, kv_layout="dense",
+        prefill_buckets=(16, 32), prefill_batch=4, page_size=PAGE,
     )
     follower_thread = threading.Thread(
         target=follower_loop, args=(follower, channel), daemon=True
@@ -153,58 +154,6 @@ def test_two_process_jax_distributed_serving():
     )
 
 
-def test_loopback_ring_prefill_lockstep():
-    """Ring long-prefill on an SPMD replica: the leader streams the padded
-    prompt over the channel (OP_RING chunks) and both engines make the
-    identical one-dispatch sequence-sharded admit — device state must stay
-    bit-identical afterwards."""
-    from langstream_tpu.parallel.mesh import build_mesh
-    from langstream_tpu.parallel.sharding import shard_params
-
-    mesh = build_mesh({"model": 2, "seq": 4})
-    params = shard_params(init_params(CFG, jax.random.PRNGKey(1)), mesh, CFG)
-    channel = LoopbackChannel(prefill_batch=2, max_width=32, max_batch=2)
-    # ring long-prefill is a dense-layout path (the admit splices into the
-    # big cache); paged long prompts take the segment loop instead
-    mk = lambda spmd: ServingEngine(  # noqa: E731
-        CFG, params, max_batch=2, max_seq_len=512, decode_chunk=4,
-        prefill_buckets=(16, 32), prefill_batch=2, mesh=mesh, spmd=spmd,
-        kv_layout="dense",
-    )
-    leader, follower = mk(channel), mk(None)
-    assert leader._ring_admit is not None and follower._ring_admit is not None
-    follower_thread = threading.Thread(
-        target=follower_loop, args=(follower, channel), daemon=True
-    )
-    follower_thread.start()
-    leader.start()
-    try:
-        opts = GenerationOptions(max_new_tokens=4, temperature=0.0)
-        # > largest bucket (32) → the ring path; > one OP_RING chunk
-        # (prefill_batch×max_width = 64 tokens) → multi-chunk streaming
-        prompt = [(5 + i) % CFG.vocab_size for i in range(100)]
-        result = leader.generate(prompt, opts, timeout=300)
-        assert len(result.tokens) == 4
-    finally:
-        leader.stop()
-    follower_thread.join(timeout=60)
-    assert not follower_thread.is_alive(), "follower never saw STOP"
-
-    np.testing.assert_array_equal(
-        np.asarray(jax.device_get(leader._tokens_dev)),
-        np.asarray(jax.device_get(follower._tokens_dev)),
-    )
-    np.testing.assert_array_equal(
-        np.asarray(jax.device_get(leader._positions_dev)),
-        np.asarray(jax.device_get(follower._positions_dev)),
-    )
-    for a, b in zip(
-        jax.tree.leaves(jax.device_get(leader._cache)),
-        jax.tree.leaves(jax.device_get(follower._cache)),
-    ):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
 def test_loopback_moe_lockstep_on_expert_mesh():
     """MoE decode under SPMD: leader + follower engines on the SAME
     expert×model mesh (mixtral-style ep×tp sharding), every dispatch
@@ -216,11 +165,13 @@ def test_loopback_moe_lockstep_on_expert_mesh():
     config = dataclasses.replace(MODEL_PRESETS["tiny-moe-test"], dtype="float32")
     mesh = build_mesh({"expert": 4, "model": 2})
     params = shard_params(init_params(config, jax.random.PRNGKey(2)), mesh, config)
-    channel = LoopbackChannel(prefill_batch=2, max_width=32, max_batch=2)
+    channel = LoopbackChannel(
+        prefill_batch=2, max_width=32, max_batch=2, table_len=TABLE_LEN
+    )
     mk = lambda spmd: ServingEngine(  # noqa: E731
         config, params, max_batch=2, max_seq_len=64, decode_chunk=4,
         prefill_buckets=(16, 32), prefill_batch=2, mesh=mesh, spmd=spmd,
-        kv_layout="dense",  # the dense-wire tier; paged → test_spmd_parity
+        page_size=PAGE,
     )
     leader, follower = mk(channel), mk(None)
     follower_thread = threading.Thread(
@@ -240,10 +191,10 @@ def test_loopback_moe_lockstep_on_expert_mesh():
     _assert_lockstep(leader, follower)
 
 
-def test_announce_unbounded_decode_packs():
-    """Shrunk (TTFT-floor) chunks dispatch with kv_bound=None; the wire
-    header is int32, so the announce layer must carry it as 0 and the
-    follower must decode 0 back to None (regression: None crashed _pack)."""
+def test_announce_decode_packs_head_and_mask():
+    """A decode announcement is head-only (no second-phase payload): its
+    step count, an empty stale list and the active-slot mask survive the
+    pack/unpack round trip."""
     import numpy as np
 
     from langstream_tpu.parallel.spmd_serving import (
@@ -255,29 +206,31 @@ def test_announce_unbounded_decode_packs():
     channel = LoopbackChannel(prefill_batch=4, max_width=64, max_batch=4)
     channel.announce(ControlBlock(
         op=OP_DECODE, steps=4, n_rows=0,
-        slots=np.zeros(0, np.int32), kv_bound=0,
+        slots=np.zeros(0, np.int32), mask=np.asarray([1, 0, 1, 0], np.int32),
     ))
     block = channel.recv()
-    assert block.op == OP_DECODE and block.steps == 4
-    assert (block.kv_bound or None) is None
+    assert block.op == OP_DECODE and block.steps == 4 and block.n_rows == 0
+    assert list(block.mask) == [1, 0, 1, 0]
 
 
 def test_loopback_lockstep_with_precompiled_ladder():
-    """precompile=True on the leader announces every warmup decode over the
+    """precompile=True on the leader announces every warmup family over the
     channel; the follower replays them and must STAY bit-identical through
-    real generations afterwards (the warmup intentionally leaves
-    deterministic garbage in the buffers — see _warmup_decode_ladder)."""
+    real generations afterwards (the warmups write only to out-of-bounds
+    pages and advance the PRNG key identically on both sides)."""
     params = init_params(CFG, jax.random.PRNGKey(0))
-    channel = LoopbackChannel(prefill_batch=4, max_width=32, max_batch=2)
+    channel = LoopbackChannel(
+        prefill_batch=4, max_width=32, max_batch=2, table_len=TABLE_LEN
+    )
     leader = ServingEngine(
         CFG, params, max_batch=2, max_seq_len=64, decode_chunk=4,
         prefill_buckets=(16, 32), prefill_batch=4, spmd=channel,
-        precompile=True, ttft_chunk_floor=2, kv_layout="dense",
+        precompile=True, ttft_chunk_floor=2, page_size=PAGE,
     )
     follower = ServingEngine(
         CFG, params, max_batch=2, max_seq_len=64, decode_chunk=4,
         prefill_buckets=(16, 32), prefill_batch=4,
-        ttft_chunk_floor=2, kv_layout="dense",
+        ttft_chunk_floor=2, page_size=PAGE,
     )
     follower_thread = threading.Thread(
         target=follower_loop, args=(follower, channel), daemon=True
@@ -293,3 +246,101 @@ def test_loopback_lockstep_with_precompiled_ladder():
     follower_thread.join(timeout=60)
     assert not follower_thread.is_alive(), "follower never saw STOP"
     _assert_lockstep(leader, follower)
+
+
+def _wire_blocks():
+    from langstream_tpu.parallel import spmd_serving as w
+
+    return {
+        "prefill": w.ControlBlock(
+            op=w.OP_PREFILL, width=16, n_rows=2,
+            tokens=np.arange(32, dtype=np.int32).reshape(2, 16),
+            lengths=np.asarray([16, 9], np.int32), slots=np.asarray([1, 4], np.int32),
+            temps=np.asarray([0.0, 0.7], np.float32),
+            top_ks=np.asarray([0, 5], np.int32), top_ps=np.asarray([1.0, 0.9], np.float32),
+        ),
+        "long-seg": w.ControlBlock(
+            op=w.OP_LONG_SEG, width=32, n_rows=1,
+            tokens=np.arange(32, dtype=np.int32).reshape(1, 32),
+            s0=64, seg_len=21, long_start=False, long_final=True, long_idx=3,
+            prompt_len=85, temps=np.asarray([0.0], np.float32),
+            top_ks=np.asarray([0], np.int32), top_ps=np.asarray([1.0], np.float32),
+        ),
+        "verify": w.ControlBlock(
+            op=w.OP_VERIFY, steps=4, n_rows=1, slots=np.asarray([2], np.int32),
+            drafts=np.arange(16, dtype=np.int32).reshape(4, 4),
+            mask=np.asarray([1, 1, 0, 1], np.int32),
+        ),
+        "page-bind": w.ControlBlock(
+            op=w.OP_PAGE_BIND, long_idx=2, count=3,
+            pages=np.asarray([7, 1, 5], np.int32), cow_src=7, cow_dst=9,
+        ),
+    }
+
+
+@pytest.mark.parametrize("kind", ["prefill", "long-seg", "verify", "page-bind"])
+def test_wire_block_survives_the_fourteen_field_header(kind):
+    """The header lost the dense layout's three fields (kv_bound, t_long,
+    entry_row) and was renumbered: every field an op still ships, and its
+    payload, comes back as announced."""
+    channel = LoopbackChannel(
+        prefill_batch=2, max_width=32, max_batch=4, table_len=8, spec_tokens=4,
+    )
+    sent = _wire_blocks()[kind]
+    channel.announce(sent)
+    got = channel.recv()
+    for field in ("op", "width", "steps", "n_rows", "s0", "seg_len", "long_start",
+                  "long_final", "long_idx", "prompt_len", "cow_src", "cow_dst", "count"):
+        assert getattr(got, field) == getattr(sent, field), field
+    assert got.seq == 1
+    for field in ("tokens", "lengths", "slots", "temps", "top_ks", "top_ps", "mask",
+                  "drafts", "pages"):
+        want = getattr(sent, field)
+        if want is not None:
+            np.testing.assert_array_equal(np.asarray(getattr(got, field))[
+                tuple(slice(0, n) for n in np.shape(want))
+            ], want)
+    assert not hasattr(got, "kv_bound") and not hasattr(got, "entry_row")
+
+
+def test_remaining_wire_ops_keep_their_numbers():
+    """Four ops went with the dense layout (5 ring, 7 / 8 prefix admit and
+    publish, 12 row reset); a mixed-version slice must not see an old
+    number mean something new, so the survivors keep theirs."""
+    from langstream_tpu.parallel import spmd_serving as w
+
+    ops = {n: getattr(w, n) for n in dir(w) if n.startswith("OP_")}
+    assert ops == {
+        "OP_IDLE": 0, "OP_PREFILL": 1, "OP_LONG_SEG": 2, "OP_DECODE": 3,
+        "OP_STOP": 4, "OP_VERIFY": 6, "OP_PAGE_BIND": 9, "OP_PAGE_FREE": 10,
+        "OP_PAGE_ZERO": 11, "OP_ECHO": 13, "OP_WARMUP": 14, "OP_RECOVER": 15,
+        "OP_RESYNC": 16,
+    }
+    assert (w.WARMUP_PAGED, w.WARMUP_PREFILL_BUCKETS) == (2, 3)
+
+
+@pytest.mark.parametrize(
+    "block_kw", [dict(op=5), dict(op=7), dict(op=12), dict(op=14, count=0)],
+    ids=["ring", "prefix-admit", "row-reset", "warmup-decode-ladder"],
+)
+def test_follower_refuses_a_deleted_op_as_a_divergence(block_kw):
+    """A leader that still announces a dense-layout op (or the dense
+    ladder's warm-up family) is a structural divergence: the follower
+    stops with a dump, it does not guess."""
+    from langstream_tpu.parallel.spmd_serving import (
+        ControlBlock,
+        SpmdDivergenceError,
+        _replay,
+    )
+
+    params = init_params(CFG, jax.random.PRNGKey(0))
+    engine = ServingEngine(
+        CFG, params, max_batch=2, max_seq_len=64, prefill_buckets=(16,),
+        page_size=PAGE,
+    )
+    channel = LoopbackChannel(
+        prefill_batch=2, max_width=16, max_batch=2, table_len=TABLE_LEN
+    )
+    with pytest.raises(SpmdDivergenceError, match="unknown"):
+        _replay(engine, ControlBlock(**block_kw), channel, [])
+    engine._fail_all(RuntimeError("never started"))

@@ -67,7 +67,7 @@ def make_engine(config=CFG, tier=True, **kw):
     else:
         kw.setdefault("prefix_cache", "off")
         kw.setdefault("host_kv_fraction", 0.0)
-    engine = ServingEngine(config, PARAMS, kv_layout="paged", **kw)
+    engine = ServingEngine(config, PARAMS, **kw)
     engine.start()
     return engine
 
@@ -479,7 +479,7 @@ def test_spill_needs_prefix_index_and_paged_layout(caplog):
     finally:
         engine.stop()
     with pytest.raises(ValueError):
-        ServingEngine(CFG, PARAMS, kv_layout="paged", spill="sometimes")
+        ServingEngine(CFG, PARAMS, spill="sometimes")
 
 
 def test_plan_host_spill_term():
@@ -489,10 +489,10 @@ def test_plan_host_spill_term():
     from langstream_tpu.serving.memory import plan_serving_memory
 
     base = plan_serving_memory(
-        CFG, 4, 128, kv_layout="paged", page_size=16, kv_pages=8,
+        CFG, 4, 128, page_size=16, kv_pages=8,
     )
     tiered = plan_serving_memory(
-        CFG, 4, 128, kv_layout="paged", page_size=16, kv_pages=8,
+        CFG, 4, 128, page_size=16, kv_pages=8,
         host_kv_fraction=4.0,
     )
     assert base.host_spill_bytes == 0
@@ -504,7 +504,7 @@ def test_plan_host_spill_term():
     assert "host KV tier" not in base.summary()
     # int8 KV halves the arena like it halves the pool
     tiered_int8 = plan_serving_memory(
-        CFG_INT8, 4, 128, kv_layout="paged", page_size=16, kv_pages=8,
+        CFG_INT8, 4, 128, page_size=16, kv_pages=8,
         host_kv_fraction=4.0,
     )
     assert tiered_int8.host_spill_bytes < tiered.host_spill_bytes

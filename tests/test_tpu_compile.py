@@ -58,15 +58,11 @@ def _as_on_the_chip():
     cc.reset_cache()
 
 
-def _dense_args(config, s, t, int8):
-    """(q, k, v, offset) shapes of a prefill (t == s) or segment call."""
+def _prefill_args(config, s):
+    """(q, k, v) shapes of a prefill call."""
     h, hkv, d = config.n_heads, config.n_kv_heads, config.resolved_head_dim
-    q = SDS((1, s, h, d), jnp.bfloat16)
-    if int8:
-        kv = {"q": SDS((1, hkv, t, d), jnp.int8), "s": SDS((1, hkv, t), jnp.float32)}
-    else:
-        kv = SDS((1, hkv, t, d), jnp.bfloat16)
-    return q, kv, kv, SDS((1,), jnp.int32)
+    kv = SDS((1, hkv, s, d), jnp.bfloat16)
+    return SDS((1, s, h, d), jnp.bfloat16), kv, kv
 
 
 def _paged_args(config, int8, batch=BATCH, table=TABLE, pages=PAGES, layers=POOL_LAYERS):
@@ -86,16 +82,8 @@ def _paged_args(config, int8, batch=BATCH, table=TABLE, pages=PAGES, layers=POOL
 
 def _prefill(config, s):
     return (
-        lambda q, k, v, _off: A.flash_prefill_attention(q, k, v, config),
-        _dense_args(config, s, s, int8=False),
-    )
-
-
-def _segment(config, s, t, int8):
-    fn = A.flash_segment_attention_int8 if int8 else A.flash_segment_attention
-    return (
-        lambda q, k, v, off: fn(q, k, v, off, config),
-        _dense_args(config, s, t, int8),
+        lambda q, k, v: A.flash_prefill_attention(q, k, v, config),
+        _prefill_args(config, s),
     )
 
 
@@ -126,11 +114,9 @@ CASES = {
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
     **{f"gemma-prefill-{s}": _prefill(GEMMA, s) for s in (512, 1024, 2048)},
-    **{f"gemma-segment-{s}": _segment(GEMMA, s, 4 * s, False) for s in (512, 1024, 2048)},
-    **{f"gemma-segment-int8-{s}": _segment(GEMMA, s, 4 * s, True) for s in (512, 1024, 2048)},
-    "llama-prefill-2048": _prefill(LLAMA, 2048),
-    "llama-segment-2048": _segment(LLAMA, 2048, 8192, False),
-    "llama-segment-int8-2048": _segment(LLAMA, 2048, 8192, True),
+    # every bucket width of the benchmark's cells that takes the kernel
+    # (128-multiples; the 64 bucket runs jnp)
+    **{f"llama-prefill-{s}": _prefill(LLAMA, s) for s in (128, 256, 512, 1024, 2048)},
     "gemma-paged-decode": _paged(GEMMA, False),
     "gemma-paged-decode-int8": _paged(GEMMA, True),
     "llama-paged-decode": _paged(LLAMA, False),
@@ -155,8 +141,6 @@ def _kernel_of(case: str) -> str:
     kind = re.sub(r"-\d+$", "", case.split("-", 1)[1])  # drop the width
     return {
         "prefill": "flash_prefill_attention",
-        "segment": "flash_segment_attention",
-        "segment-int8": "flash_segment_attention_int8",
         "paged-decode": "ragged_paged_decode_attention",
         "paged-decode-int8": "ragged_paged_decode_attention_int8",
     }[kind]
