@@ -85,7 +85,8 @@ def kernel_checks(
     """Each main-path kernel at ``config``'s head widths against the
     jax.numpy reference of tests/test_pallas_ops.py (``attention`` over an
     explicit mask, in float32 at "highest" matmul precision; int8 caches
-    dequantized first). ``paged`` = (B, page, pages
+    dequantized first; the pool's write against ``_paged_scatter``, where
+    any difference is an error). ``paged`` = (B, page, pages
     per row): rows of unequal length, every third one inactive. Returns
     one ``{kernel, max_abs_err}`` per check."""
     import jax
@@ -94,13 +95,16 @@ def kernel_checks(
 
     from langstream_tpu.models.transformer import (
         _dequantize_kv,
+        _page_index,
         _paged_gather,
         _paged_lengths,
+        _paged_scatter,
         _quantize_kv,
         attention,
     )
     from langstream_tpu.ops.attention import (
         flash_prefill_attention,
+        paged_kv_write,
         ragged_paged_decode_attention,
         ragged_paged_decode_attention_int8,
     )
@@ -176,6 +180,21 @@ def kernel_checks(
             _paged_gather(vp, layer, table, page), mask,
         )[:, 0], 0.0),
     )
+    # the step's new rows into that pool: the kernel's copies against the
+    # scatter it replaced, every byte of both leaves (the idle rows drop)
+    pos = jnp.asarray(positions)[:, None]
+    kn, vn = rand(b, hkv, d), rand(b, hkv, d)
+    write_pages, write_offs = _page_index(table, pos, page, pages)
+    wrote = paged_kv_write(
+        (kn, vn), kp, vp, write_pages[:, 0], write_offs[:, 0], layer, config,
+        interpret=interpret,
+    )
+    for leaf, got, pool, rows in zip("kv", wrote, (kp, vp), (kn, vn)):
+        check(
+            f"paged_kv_write[{leaf},b={b},page={page}]", got,
+            _paged_scatter(pool, layer, rows[:, :, None], table, pos, page)
+            .astype(jnp.float32),
+        )
     kp8, vp8 = (dict(zip("qs", _quantize_kv(x))) for x in (kp, vp))
     check(
         f"ragged_paged_decode_attention_int8[b={b},page={page}]",

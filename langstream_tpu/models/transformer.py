@@ -304,7 +304,13 @@ def _paged_scatter(pool, layer, vals: jax.Array, table: jax.Array,
     of its own, never folded into the page: the drop sentinel (= P) stays
     out of bounds on the page axis instead of naming layer + 1's page 0.
     Unmapped pages drop the write — padding rows, warmups, and steps past a
-    slot's reservation all ride the same drop."""
+    slot's reservation all ride the same drop. The kv head is an index of
+    its own, so the chip runs B × Hkv × S point updates of [D] one after
+    another, dropped or not (45.8 us a leaf for 512 on a v5e; a window over
+    the heads would need the pool relaid heads-minor: PERF.md §6, PR 30):
+    a decode step into a bf16 pool goes through ``ops/attention.
+    paged_kv_write`` instead, and this is that write's reference, the int8
+    pool's write and the multi-token writers' (verify, segments)."""
     num_pages = (pool["q"] if isinstance(pool, dict) else pool).shape[1]
     pages, offs = _page_index(table, positions, page_size, num_pages)
     hkv = vals.shape[1]
@@ -569,9 +575,11 @@ def _attention_block(
     """The attention half of a block (norm, QKV, rotary, cache write, the
     kernel or jnp path, output projection, residual): the layer's input in,
     the FFN's input and the layer's new cache entry out. It names its own
-    scopes: all of it is ``attention``, but for the paged branch's scatter
-    of the new K/V rows, the pool's only write, which is ``kv_pool.write``
-    and (a scope cannot be left from inside) outside ``attention``. With
+    scopes: all of it is ``attention``, but for the paged branch's write
+    of the new K/V rows (``paged_kv_write`` where the decode kernel runs
+    over a bf16 pool, else ``_paged_scatter``), the pool's only write,
+    which is ``kv_pool.write`` and (a scope cannot be left from inside)
+    outside ``attention``. With
     ``paged_table`` set, ``cache_kv`` is the WHOLE pool and comes back
     whole: nothing of a layer's size is formed."""
     if paged_table is None:
@@ -583,6 +591,7 @@ def _attention_block(
     assert cache_kv is not None and cache_positions is not None
     from langstream_tpu.ops.attention import (
         note_path,
+        paged_kv_write,
         paged_pallas_ok,
         ragged_paged_decode_attention,
         ragged_paged_decode_attention_int8,
@@ -591,15 +600,28 @@ def _attention_block(
     s = x.shape[1]
     with jax.named_scope("attention"):
         q, k, v = _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows)
-        kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
     pk, pv = cache_kv  # [L, P, Hkv, ps, D], read and written at `layer`
+    num_pages = (pk["q"] if isinstance(pk, dict) else pk).shape[1]
+    decode_kernels = s == 1 and paged_pallas_ok(config, page_size)
     with jax.named_scope("kv_pool.write"):
-        pk = _paged_scatter(pk, layer, kt, paged_table, cache_positions, page_size)
-        pv = _paged_scatter(pv, layer, vt, paged_table, cache_positions, page_size)
+        if decode_kernels and not isinstance(pk, dict):
+            # a decode step into the bf16 pool: a copy per LIVE row. The
+            # int8 pool (a token's scales are Hkv scattered words, no DMA
+            # Mosaic takes) and the S > 1 writers keep the scatter
+            pages, offs = _page_index(
+                paged_table, cache_positions, page_size, num_pages
+            )
+            pk, pv = paged_kv_write(
+                (k[:, 0], v[:, 0]), pk, pv, pages[:, 0], offs[:, 0], layer,
+                config, interpret=jax.default_backend() != "tpu",
+            )
+        else:
+            kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            pk = _paged_scatter(pk, layer, kt, paged_table, cache_positions, page_size)
+            pv = _paged_scatter(pv, layer, vt, paged_table, cache_positions, page_size)
     with jax.named_scope("attention"):
         t = paged_table.shape[1] * page_size
-        if s == 1 and paged_pallas_ok(config, page_size):
-            num_pages = (pk["q"] if isinstance(pk, dict) else pk).shape[1]
+        if decode_kernels:
             lengths = _paged_lengths(
                 paged_table, cache_positions[:, 0], page_size, num_pages
             )

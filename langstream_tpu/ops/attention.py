@@ -1,7 +1,7 @@
 """Flash-attention Pallas kernels.
 
-Two hot paths, both GQA-aware (queries grouped per kv head so K/V blocks are
-read once per group, not once per query head). K/V come in HEAD-MAJOR layout
+Two attention hot paths, both GQA-aware (queries grouped per kv head so K/V
+blocks are read once per group, not once per query head). K/V come in HEAD-MAJOR layout
 [B, Hkv, T, D] — the kv-head axis stays out of the trailing two dims, so the
 Mosaic TPU lowering's (8, 128) block-tiling constraint falls on (T, D) where
 blocks are naturally aligned, and a per-head kv block is a contiguous
@@ -15,6 +15,9 @@ blocks are naturally aligned, and a per-head kv block is a contiguous
   continuous batcher packs rows of very different lengths into one step, so
   a masked read over a fixed width wastes bandwidth proportional to
   max_len - mean_len).
+- ``paged_kv_write``: that step's new K and V rows into the bf16 pool where
+  it lies, a copy per live row (a scatter pays per (row, kv head), dropped
+  rows included).
 
 No reference counterpart (the reference's compute is remote HTTP calls);
 kernel structure follows the public flash/paged-attention pattern from the
@@ -82,7 +85,7 @@ def _model_on(ndim: int, axis: int) -> P:
     return P(*("model" if i == axis else None for i in range(ndim)))
 
 
-def _per_kv_head(n_replicated: int, kv_head_axis: int = 1):
+def _per_kv_head(n_replicated: int, kv_head_axis: int = 1, returns_pool: bool = False):
     """Decorator for kernels of signature ``fn(q, k, v, *replicated, config,
     ...)``. Mosaic kernels cannot be partitioned by GSPMD, so when
     ``config.kernel_mesh`` is set (the engine runs under a mesh) the call
@@ -95,7 +98,10 @@ def _per_kv_head(n_replicated: int, kv_head_axis: int = 1):
     head), so the body needs no collective; the ``replicated`` operands
     (offsets, lengths, page tables) and every other mesh axis stay
     replicated. ``pallas_ok``/``paged_pallas_ok`` only admit meshes whose
-    "model" axis divides the kv heads."""
+    "model" axis divides the kv heads. ``q`` may be a tuple of arrays with
+    the heads on their last axis but one (the new K and V rows of
+    ``paged_kv_write``); ``returns_pool``: the result is the (k, v) pool,
+    split as it came in, not an attention output."""
 
     def deco(fn):
         @functools.wraps(fn)
@@ -111,12 +117,13 @@ def _per_kv_head(n_replicated: int, kv_head_axis: int = 1):
                 return fn(q, k, v, *replicated, local, *tail, **kwargs)
 
             kv_spec = jax.tree.map(lambda x: _model_on(x.ndim, kv_head_axis), k)
+            q_spec = jax.tree.map(lambda x: _model_on(x.ndim, x.ndim - 2), q)
             return jax.shard_map(
                 body,
                 mesh=mesh,
-                in_specs=(_model_on(q.ndim, q.ndim - 2), kv_spec, kv_spec)
-                + (P(),) * n_replicated,
-                out_specs=_model_on(q.ndim - 1, q.ndim - 2),
+                in_specs=(q_spec, kv_spec, kv_spec) + (P(),) * n_replicated,
+                out_specs=(kv_spec, kv_spec) if returns_pool
+                else _model_on(q.ndim - 1, q.ndim - 2),
                 check_vma=False,
             )(q, k, v, *replicated)
 
@@ -586,6 +593,158 @@ def ragged_paged_decode_attention_int8(
         [k["q"], v["q"]], [k["s"], v["s"]], lengths, table, layer, config,
         page_size, interpret,
     )
+
+
+# ---------------------------------------------------------------------------
+# Paged decode write: a step's new K/V rows into the pool, where it lies
+# ---------------------------------------------------------------------------
+
+# Rows of a page that one copy moves: the bf16 pool's tile in HBM is
+# (8, 128) with row pairs packed, and Mosaic refuses a window narrower than
+# the tile ("Slice shape along dimension 2 must be aligned to tiling"), so
+# a token's row travels inside the aligned 8 rows that hold it.
+_WRITE_ROWS = 8
+# Rows of the batch whose tiles are in VMEM together: one grid step's
+# (64 x 8 kv heads x 8 x 128 bf16 is 1 MB a leaf).
+_WRITE_BLOCK = 64
+
+
+def _paged_kv_write_kernel(
+    pages_ref,  # scalar-prefetch [B]: each row's write page; >= num_pages drops
+    offs_ref,  # scalar-prefetch [B]: the row's offset inside that page
+    layer_ref,  # scalar-prefetch [1]
+    k_ref,  # [block, Hkv, D]: this grid step's new rows
+    v_ref,
+    _k_in,  # the pool leaves [L*P, Hkv, ps, D] in HBM, aliased to the outputs
+    _v_in,
+    k_pool,
+    v_pool,
+    k_buf,  # [block, Hkv, _WRITE_ROWS, D]
+    v_buf,
+    read_sems,  # DMA [2, block]
+    write_sems,
+    live_rows,  # SMEM [block]
+    *,
+    num_pages: int,
+):
+    block, hkv, d = k_ref.shape
+    first = pl.program_id(0) * block
+    base = layer_ref[0] * num_pages
+    leaves = ((k_pool, k_buf, k_ref), (v_pool, v_buf, v_ref))
+
+    # the block's live rows, compacted: a dropped row (its page the
+    # sentinel) costs this loop's iteration and nothing else
+    def collect(i, n):
+        live_rows[n] = i
+        page = pages_ref[first + i]
+        return n + ((page >= 0) & (page < num_pages)).astype(jnp.int32)
+
+    n_live = jax.lax.fori_loop(0, block, collect, jnp.int32(0), unroll=True)
+
+    def tile(pool, i):
+        # the layer is added only to a page that is inside the pool
+        off = offs_ref[first + i]
+        start = pl.multiple_of(off - off % _WRITE_ROWS, _WRITE_ROWS)
+        return pool.at[
+            base + pages_ref[first + i], :, pl.ds(start, _WRITE_ROWS), :
+        ]
+
+    def over_live_rows(fn):
+        def body(j, carry):
+            fn(live_rows[j])
+            return carry
+
+        jax.lax.fori_loop(0, n_live, body, 0)
+
+    def read(i):
+        for leaf, (pool, buf, _) in enumerate(leaves):
+            pltpu.make_async_copy(
+                tile(pool, i), buf.at[i], read_sems.at[leaf, i]
+            ).start()
+
+    def replace_row(i):
+        at = jax.lax.broadcasted_iota(jnp.int32, (_WRITE_ROWS, d), 0) == (
+            offs_ref[first + i] % _WRITE_ROWS
+        )
+        for leaf, (pool, buf, new) in enumerate(leaves):
+            pltpu.make_async_copy(
+                tile(pool, i), buf.at[i], read_sems.at[leaf, i]
+            ).wait()
+            for h in range(hkv):
+                row = jnp.broadcast_to(new[i, pl.ds(h, 1), :], (_WRITE_ROWS, d))
+                buf[i, h] = jnp.where(at, row, buf[i, h])
+            pltpu.make_async_copy(
+                buf.at[i], tile(pool, i), write_sems.at[leaf, i]
+            ).start()
+
+    def written(i):
+        for leaf, (pool, buf, _) in enumerate(leaves):
+            pltpu.make_async_copy(
+                buf.at[i], tile(pool, i), write_sems.at[leaf, i]
+            ).wait()
+
+    # no two live rows write one page, so their tiles never overlap: every
+    # row's read is in flight before the first is waited for, and every
+    # write before the first is
+    over_live_rows(read)
+    over_live_rows(replace_row)
+    over_live_rows(written)
+
+
+@_per_kv_head(3, kv_head_axis=2, returns_pool=True)
+def paged_kv_write(
+    new: tuple[jax.Array, jax.Array],  # a decode step's K and V rows [B, Hkv, D]
+    k: jax.Array,  # the page pool [L, P, Hkv, ps, D], written at `layer`
+    v: jax.Array,
+    pages: jax.Array,  # [B] each row's write page (models/transformer `_page_index`)
+    offsets: jax.Array,  # [B] and the offset inside it
+    layer: jax.Array,  # scalar: which layer of the pool
+    config: ModelConfig,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """Write row ``b``'s K and V at ``[layer, pages[b], :, offsets[b]]`` of
+    the pool where it lies (the leaves are aliased to the outputs and seen
+    as [L·P, ...], as the decode kernel sees them) and give both leaves
+    back. A row whose page is the sentinel (>= P) DROPS, at no copy at all:
+    the cost is per live row. The write is read-modify-write of the
+    ``_WRITE_ROWS`` aligned rows that hold the offset; every other byte of
+    the pool is untouched."""
+    del config  # `_per_kv_head`'s: the mesh to split the heads over
+    b, hkv, d = new[0].shape
+    num_pages = k.shape[1]
+    block = _fit_block(_WRITE_BLOCK, b)
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
+    row_block = pl.BlockSpec((block, hkv, d), lambda i, *_: (i, 0, 0))
+    flat = [_flat_pool(leaf) for leaf in (k, v)]
+    out = pl.pallas_call(
+        functools.partial(_paged_kv_write_kernel, num_pages=num_pages),
+        name="paged_kv_write",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b // block,),
+            in_specs=[row_block, row_block, hbm, hbm],
+            out_specs=[hbm, hbm],
+            scratch_shapes=[
+                pltpu.VMEM((block, hkv, _WRITE_ROWS, d), leaf.dtype)
+                for leaf in flat
+            ] + [
+                pltpu.SemaphoreType.DMA((2, block)),
+                pltpu.SemaphoreType.DMA((2, block)),
+                pltpu.SMEM((block,), jnp.int32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype) for leaf in flat],
+        # operands 5 and 6 (after the three prefetched scalars and the two
+        # row blocks) are the pool: written in place
+        input_output_aliases={5: 0, 6: 1},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(
+        pages.astype(jnp.int32), offsets.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        *(rows.astype(k.dtype) for rows in new), *flat,
+    )
+    return out[0].reshape(k.shape), out[1].reshape(v.shape)
 
 
 def paged_pallas_ok(config: ModelConfig, page_size: int) -> bool:

@@ -5907,13 +5907,14 @@ class ServingEngine:
                 mask=mask,
             ))
         live = [slot for slot in self._slots if slot.active]
+        pages_visited, rows_written = self._kv_page_counts(steps)
         disp = self._new_dispatch(
             "engine.decode_chunk",
             program="_paged_decode_chunk",
             steps=steps, active_rows=len(live),
             kv_tokens_read=self._kv_tokens_read(live, steps),
             clean=clean, pipelined=pipelined,
-            kv_pages_visited=self._kv_pages_visited(steps),
+            kv_pages_visited=pages_visited, kv_rows_written=rows_written,
         )
         with jax.profiler.TraceAnnotation(
             "engine.decode_chunk", seq=self._dispatch_seq, steps=steps
@@ -5946,23 +5947,31 @@ class ServingEngine:
             for slot in live
         )
 
-    def _kv_pages_visited(self, steps: int) -> int:
-        """Page iterations the paged decode kernel runs, per layer, in one
-        dispatch: over its ``steps`` and its active rows, the pages of the
-        row's live length at that step, `_kv_tokens_read`'s lengths capped
-        by what the row's table maps at dispatch (a row that steps past its
-        reservation inside the chunk stops growing: models/transformer
-        `_paged_lengths`). Inactive rows have no table and visit none."""
+    def _kv_page_counts(self, steps: int) -> tuple[int, int]:
+        """(kv_pages_visited, kv_rows_written) of a decode chunk dispatched
+        now, per layer, from one set of lengths: each active row's live
+        length at every step (`_kv_tokens_read`'s: the position being
+        written, plus one) and the pages its table maps at dispatch.
+        Inactive rows have no table and count for nothing.
+
+        Pages visited: the page iterations the paged decode kernel runs,
+        the pages of that length capped by what the table maps (a row that
+        steps past its reservation inside the chunk stops growing:
+        models/transformer `_paged_lengths`). Rows written: the (row, step)
+        pairs whose write page is mapped (`_page_index`); every other pair
+        DROPS. It is the copies `paged_kv_write` issues a leaf, which costs
+        per live row; a warm-up chunk writes none."""
         rows = [i for i, slot in enumerate(self._slots) if slot.active]
         pool = self._pagepool
         first = np.asarray(
             [self._slots[i].position + self._slots[i].ahead + 1 for i in rows],
             np.int64,
         )
-        mapped = (pool.tables[rows] != pool.oob).sum(axis=1)
+        mapped = (pool.tables[rows] != pool.oob).sum(axis=1)[:, None]
         lengths = first[:, None] + np.arange(steps)[None, :]
-        pages = np.minimum(-(-lengths // self.page_size), mapped[:, None])
-        return int(pages.sum())
+        pages = np.minimum(-(-lengths // self.page_size), mapped)
+        written = (lengths - 1) // self.page_size < mapped
+        return int(pages.sum()), int(written.sum())
 
     def _collect_stale(self) -> list[int]:
         """Slots freed since the last dispatch whose device temperature

@@ -91,6 +91,8 @@ def test_one_span_per_group_and_chunk_with_work_counts(dense_params):
         assert a["kv_tokens_read"] >= a["steps"] * a["active_rows"]
         # the paged kernel's page iterations
         assert a["steps"] * a["active_rows"] <= a["kv_pages_visited"] <= a["kv_tokens_read"]
+        # every live row's write page is mapped here: a row a step, no drop
+        assert a["kv_rows_written"] == a["steps"] * a["active_rows"]
         assert "moe_routed" not in a  # a dense model fetches no counts
     # device time per request class is a join: prefill child -> its group
     by_seq = {g["attributes"]["seq"]: g for g in groups}
@@ -140,6 +142,10 @@ def test_kv_tokens_read_matches_a_hand_count(dense_params):
     # step j of a row whose token is written at position p attends p+1+j keys
     assert chunks[0]["attributes"]["kv_tokens_read"] == lengths(3 + 1)
     assert chunks[1]["attributes"]["kv_tokens_read"] == lengths(3 + 8 + 1) + lengths(2 + 1)
+    # live rows x steps: one row, then two, a row written at every step
+    assert [c["attributes"]["kv_rows_written"] for c in chunks[:2]] == [8, 16]
+    # a warm-up chunk runs with no slot active: every row drops
+    assert engine._kv_page_counts(8) == (0, 0) and not any(s.active for s in engine._slots)
 
 
 def test_kv_pages_visited_matches_a_hand_count(dense_params):
@@ -179,6 +185,9 @@ def test_kv_pages_visited_matches_a_hand_count(dense_params):
     assert pages(4, 8) == 5 * 1 + 3 * 2 and pages(3, 1) == 8 < pages(3, 8) == 10
     assert chunks[0]["attributes"]["kv_pages_visited"] == pages(4, 8)
     assert chunks[1]["attributes"]["kv_pages_visited"] == pages(12, 8) + pages(3, 1)
+    # the rows written: A's eight a chunk; B writes positions 2..7 into its
+    # one page and DROPS the two steps past it
+    assert [c["attributes"]["kv_rows_written"] for c in chunks[:2]] == [8, 8 + 6]
 
 
 @pytest.mark.parametrize("kv", ["model", "int8"])

@@ -107,8 +107,12 @@ ENTRY_POINTS = {"decode": _decode, "verify": _verify, "segment": _segment}
 
 
 def _random_pool(config, key):
+    return _random_pool_of(config, PAGES, key)
+
+
+def _random_pool_of(config, pages, key):
     """A pool with something in every page, so an untouched page shows."""
-    shapes = jax.eval_shape(lambda: T.make_page_pool(config, PAGES, PS))
+    shapes = jax.eval_shape(lambda: T.make_page_pool(config, pages, PS))
     leaves, tree = jax.tree.flatten(shapes)
     out = []
     for leaf, k in zip(leaves, jax.random.split(key, len(leaves))):
@@ -231,3 +235,116 @@ def test_decode_through_the_kernel_matches_the_jnp_path_on_live_rows(preset, kv)
         np.testing.assert_allclose(logits[0], ref_logits[0], atol=0.1)
     else:
         np.testing.assert_allclose(logits[[0, 2]], ref_logits[[0, 2]], atol=2e-4)
+
+
+# -- the pool's write: a decode step's kernel, the scatter for the rest ------
+
+W_PAGES, W_TP, W_LAYERS = 128, 17, 3  # pages of PS; a row's table holds 136 tokens
+
+
+def _rows(b, mapped, starts):
+    """A table with ``mapped`` = {row: its pages} and each row's first
+    position; every other row sits at the sentinel behind a stale position."""
+    table = np.full((b, W_TP), W_PAGES, np.int32)
+    for row, pages in mapped.items():
+        table[row, : len(pages)] = pages
+    first = (np.arange(b, dtype=np.int32) * 7) % (W_TP * PS)  # stale positions
+    for row, start in starts.items():
+        first[row] = start
+    return table, first
+
+
+def _write_case(name, s):
+    """(table [B, Tp], first positions [B], layer) of a named drop case for
+    ``s`` tokens a row. Where a case is about an edge (the table's end, the
+    last mapped page), the row starts ``s // 2`` before it: one token of a
+    decode step is past it, a longer write straddles it."""
+    full = list(range(40, 40 + W_TP))  # a whole table of pages
+    if name == "all-rows-at-the-sentinel":
+        return *_rows(4, {}, {0: 0, 1: 5, 2: W_TP * PS + 1}), 1
+    if name == "a-position-past-the-table":
+        return *_rows(4, {0: full, 2: [3, 9, 4]}, {0: W_TP * PS - s // 2, 2: 1}), 1
+    if name == "last-mapped-page-full-next-unmapped":
+        return *_rows(3, {1: [7, 2]}, {1: max(0, 2 * PS - s // 2)}), 1
+    if name == "6-of-64-rows-mapped":
+        live = [3, 10, 17, 30, 41, 63]
+        pages = np.random.default_rng(0).permutation(W_PAGES)[: 6 * W_TP].reshape(6, W_TP)
+        return *_rows(
+            64, dict(zip(live, pages.tolist())), {r: i for i, r in enumerate(live)}
+        ), 1
+    if name == "adjacent-offsets-of-different-pages":
+        return *_rows(2, {0: [3] + full[1:], 1: [4] + full[:-1][::-1]}, {0: 5, 1: 6}), 1
+    if name == "layer-last":  # a sentinel folded into the page would leave the array
+        return *_rows(4, {1: full, 3: [0, W_PAGES - 1]}, {1: 13, 2: 3, 3: PS}), W_LAYERS - 1
+    raise KeyError(name)
+
+
+WRITE_CASES = [
+    "all-rows-at-the-sentinel", "a-position-past-the-table",
+    "last-mapped-page-full-next-unmapped", "6-of-64-rows-mapped",
+    "adjacent-offsets-of-different-pages", "layer-last",
+]
+
+
+@pytest.mark.parametrize("s", [1, 3, 128])
+@pytest.mark.parametrize("kv", ["model", "int8"])
+@pytest.mark.parametrize("case", WRITE_CASES)
+def test_the_pool_write_lands_and_drops_as_the_scatter_did(case, kv, s):
+    """`_attention_block`'s write of the new K/V rows, with the kernels
+    forced (interpret mode): `paged_kv_write` for a decode step into the
+    bf16 pool, `_paged_scatter` for the int8 pool and for S > 1. Against
+    the per-entry scatter above the pools are bit-equal, and the slots
+    that changed are exactly the tokens a hand count lands: nothing of a
+    dropped token reaches any page of any layer."""
+    config = dataclasses.replace(
+        MODEL_PRESETS["tiny-test"], n_layers=W_LAYERS, kv_cache_dtype=kv,
+        attention_impl="pallas",
+    )
+    table, first, layer = _write_case(case, s)
+    b = len(first)
+    positions = first[:, None] + np.arange(s, dtype=np.int32)[None, :]
+    params = T.init_params(config, jax.random.PRNGKey(0))
+    lp = jax.tree.map(lambda a: a[layer], params["layers"])
+    pool = _random_pool_of(config, W_PAGES, jax.random.PRNGKey(1))
+    x = jax.random.normal(jax.random.PRNGKey(2), (b, s, config.d_model), jnp.bfloat16)
+
+    @jax.jit
+    def run(x, pool):
+        pos = jnp.asarray(positions)
+        sin, cos = T._rope_freqs(pos, config)
+        mask = T._paged_mask(jnp.asarray(table), PS, pos)
+        _, k, v = T._qkv(x, lp, sin, cos, config, None, None, None)
+        _, (pk, pv) = T._attention_block(
+            x, lp, sin, cos, mask, config, cache_kv=(pool["k"], pool["v"]),
+            cache_positions=pos, paged_table=jnp.asarray(table), page_size=PS,
+            layer=jnp.asarray(layer, jnp.int32),
+        )
+        want = tuple(
+            _sliced_scatter(
+                leaf, layer, vals.transpose(0, 2, 1, 3), jnp.asarray(table), pos, PS
+            )
+            for leaf, vals in ((pool["k"], k), (pool["v"], v))
+        )
+        return (pk, pv), want
+
+    # the kernel carries exactly the decode step into the bf16 pool
+    traced = str(jax.make_jaxpr(run)(x, pool))
+    assert ("paged_kv_write" in traced) == (kv == "model" and s == 1)
+    got, want = run(x, pool)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # the hand count: token (b, j) lands iff its logical page is mapped
+    landed = set()
+    for row in range(b):
+        for pos in positions[row]:
+            lpage = pos // PS
+            if lpage < W_TP and table[row, lpage] < W_PAGES:
+                landed.add((int(table[row, lpage]), int(pos % PS)))
+    for leaf, was in zip(jax.tree.leaves(got), jax.tree.leaves((pool["k"], pool["v"]))):
+        leaf, was = np.asarray(leaf).astype(np.float32), np.asarray(was).astype(np.float32)
+        differs = leaf != was  # [L, P, Hkv, ps(, D)]
+        while differs.ndim > 4:
+            differs = differs.any(-1)
+        assert not np.delete(differs, layer, axis=0).any(), "another layer was touched"
+        changed = {(int(p), int(o)) for p, _, o in zip(*np.nonzero(differs[layer]))}
+        assert changed == landed

@@ -100,6 +100,22 @@ def _paged(config, int8, **sizes):
     )
 
 
+def _kv_write(config, batch, pages, layers, table=None):
+    """(the decode step's pool write, its arguments' shapes): K and V rows
+    [B, Hkv, D], both bf16 pool leaves, a write page, an offset a row, and
+    the layer (``table`` is the attention kernel's, not an operand here)."""
+    hkv, d = config.n_kv_heads, config.resolved_head_dim
+    rows = SDS((batch, hkv, d), jnp.bfloat16)
+    pool = SDS((layers, pages, hkv, PAGE, d), jnp.bfloat16)
+    at = SDS((batch,), jnp.int32)
+    return (
+        lambda k, v, pk, pv, page, offset, layer: A.paged_kv_write(
+            (k, v), pk, pv, page, offset, layer, config
+        ),
+        (rows, rows, pool, pool, at, at, SDS((), jnp.int32)),
+    )
+
+
 # The benchmark's three cells (BENCHMARK.json; benchmark/workloads/*.json):
 # slots x table pages, the pool's pages, the layers. Mistral-7B and Mixtral
 # have llama-3-8b's attention (32 q / 8 kv heads of 128).
@@ -123,6 +139,10 @@ CASES = {
     "llama-paged-decode-int8": _paged(LLAMA, True),
     **{f"{cell}-paged-decode": _paged(LLAMA, False, **sizes) for cell, sizes in CELLS.items()},
     **{f"{cell}-paged-decode-int8": _paged(LLAMA, True, **sizes) for cell, sizes in CELLS.items()},
+    # the write of a decode step's new rows into a bf16 pool
+    "gemma-paged-kv-write": _kv_write(GEMMA, BATCH, PAGES, POOL_LAYERS),
+    "llama-paged-kv-write": _kv_write(LLAMA, BATCH, PAGES, POOL_LAYERS),
+    **{f"{cell}-paged-kv-write": _kv_write(LLAMA, **sizes) for cell, sizes in CELLS.items()},
 }
 
 
@@ -143,6 +163,7 @@ def _kernel_of(case: str) -> str:
         "prefill": "flash_prefill_attention",
         "paged-decode": "ragged_paged_decode_attention",
         "paged-decode-int8": "ragged_paged_decode_attention_int8",
+        "paged-kv-write": "paged_kv_write",
     }[kind]
 
 
@@ -254,6 +275,22 @@ def _compile_as_on_chip(monkeypatch, fn, args, static):
 STEP_PROGRAMS = ("_paged_decode_chunk", "_paged_verify_chunk", "_paged_segment_and_sample")
 
 
+def _assert_decode_write(text: str, pool_shapes: list, bf16_pool: bool) -> None:
+    """How a decode step's new K/V rows reach the pool: into a bf16 pool by
+    the `paged_kv_write` kernel (a copy per live row) and by no scatter;
+    the int8 pool keeps the scatter of its values and scales."""
+    scatters = [
+        shape for shape in pool_shapes
+        if re.search(rf"= \w+{re.escape(shape)}\S* scatter\(", text)
+    ]
+    if bf16_pool:
+        assert re.search(r"%paged_kv_write(\.\d+)? = ", text)
+        assert not scatters
+    else:
+        assert "%paged_kv_write" not in text
+        assert scatters == pool_shapes
+
+
 @pytest.mark.parametrize("kv", ["model", "int8"])
 @pytest.mark.parametrize("program", STEP_PROGRAMS)
 def test_paged_program_holds_no_per_layer_pool_entry(v5e, monkeypatch, program, kv):
@@ -279,22 +316,25 @@ def test_paged_program_holds_no_per_layer_pool_entry(v5e, monkeypatch, program, 
         assert re.search(rf"%{kernel}(\.\d+)? = ", text)
         t = STEP_TABLE * PAGE
         assert A.attention_paths()[f"paged-decode[s=1,t={t}]"] == kernel
+        _assert_decode_write(text, pool_shapes, bf16_pool=kv == "model")
     else:
         assert "tpu_custom_call" not in text  # verify and segments: jnp
 
 
-def test_model_sharded_paged_decode_chunk_holds_no_pool_entry(v5e, monkeypatch):
-    """The same on the four-chip `model`-sharded mesh: the kernel's
-    shard_map splits the pool's kv heads where they now lie (axis 2)."""
+@pytest.mark.parametrize("kv", ["model", "int8"])
+def test_model_sharded_paged_decode_chunk_holds_no_pool_entry(v5e, monkeypatch, kv):
+    """The same on the four-chip `model`-sharded mesh: the kernels'
+    shard_map splits the pool's kv heads where they now lie (axis 2), the
+    write's as the read's: each chip writes its own heads' slab."""
     import numpy as np
 
     from langstream_tpu.parallel.sharding import _kv_entry_specs, param_specs
 
     mesh = Mesh(np.array(v5e).reshape(1, 1, 1, 4), AXIS_ORDER)
-    config = dataclasses.replace(STEP_CFG, kv_cache_dtype="int8", kernel_mesh=mesh)
+    config = dataclasses.replace(STEP_CFG, kv_cache_dtype=kv, kernel_mesh=mesh)
     fn, args, static = _step_program("_paged_decode_chunk", config, PAGE)
     named = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
-    entry = _kv_entry_specs(page_pool_specs(config.n_kv_heads, mesh), True)
+    entry = _kv_entry_specs(page_pool_specs(config.n_kv_heads, mesh), kv == "int8")
     is_spec = lambda x: isinstance(x, P)  # noqa: E731
     shardings = (
         jax.tree.map(named, param_specs(config), is_leaf=is_spec),
@@ -303,13 +343,18 @@ def test_model_sharded_paged_decode_chunk_holds_no_pool_entry(v5e, monkeypatch):
     ) + (named(P()),) * 5
     compiled = _compile_as_on_chip(monkeypatch, fn, _placed(args, shardings), static)
     text = compiled.as_text()
-    assert re.search(r"%ragged_paged_decode_attention_int8(\.\d+)? = ", text)
+    kernel = "ragged_paged_decode_attention" + ("_int8" if kv == "int8" else "")
+    assert re.search(rf"%{kernel}(\.\d+)? = ", text)
     hkv, d = config.n_kv_heads // 4, config.resolved_head_dim
     local_entry = f"[{STEP_PAGES},{hkv},{PAGE},{d}]"
     local_pool = f"[{config.n_layers},{STEP_PAGES},{hkv},{PAGE},{d}]"
     assert local_pool in text  # each chip holds a quarter of the heads
     assert all(local_entry not in l.replace(local_pool, "") for l in text.splitlines())
     assert not re.search(rf"= \w+{re.escape(local_pool)}\S* copy\(", text)
+    local_scales = f"[{config.n_layers},{STEP_PAGES},{hkv},{PAGE}]"
+    _assert_decode_write(
+        text, [local_pool] + [local_scales] * (kv == "int8"), bf16_pool=kv == "model"
+    )
 
 
 def test_mesh_that_does_not_divide_kv_heads_keeps_the_jnp_path():
