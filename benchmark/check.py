@@ -9,9 +9,10 @@ nothing observed in the timed window enters the verdict.
 Three levels, all against the plain float32 reference on the same dequantised
 int8 weights:
 
-1. *Model level, teacher-forced.* The program's own block (`_layer`, with the
-   engine's config, so its attention dispatch) is applied layer by layer to
-   each sample sequence. At every layer the reference block is given the
+1. *Model level, teacher-forced.* The program's own block (with the engine's
+   config, so its attention dispatch: the family's `system_chain`,
+   `families/<family>.py`) is applied layer by layer to each sample
+   sequence. At every layer the reference block is given the
    SYSTEM's input to that layer and the two outputs are compared per
    position: `e = |sys - ref|_inf / |ref|_inf`. Teacher forcing keeps one
    layer's rounding, and one token's router tie, from spreading to every
@@ -26,12 +27,13 @@ int8 weights:
    check says that the model agrees with the published equations where that
    rule does not bind.
 2. *Hot path, logits.* The model functions the engine's own programs are
-   made of, called as the engine calls them: `prefill` of the group into a
-   local cache (the flash prefill kernel), `paged_insert_cache` into a page
-   pool of the engine's page size and KV type, then one
-   `paged_decode_step_inplace` per generated token through the page table
-   (the ragged paged decode kernel), teacher-forced on the engine's own
-   tokens. Per generated position `h = |hot - chain|_inf / |chain|_inf`
+   made of, called as the engine calls them (the family's `hot_path`): for
+   the two families there are, `prefill` of the group into a local cache (the
+   flash prefill kernel), `paged_insert_cache` into a page pool of the
+   engine's page size and KV type, then one `paged_decode_step_inplace` per
+   generated token through the page table (the ragged paged decode kernel),
+   teacher-forced on the engine's own tokens. Per generated position
+   `h = |hot - chain|_inf / |chain|_inf`
    against the logits of step 1's chain on the same sequence, every layer of
    which was just held to the reference (and, for a dense model, its logits
    to the free-running reference by `tol_e2e_max`). Both run in bf16 on the
@@ -57,30 +59,23 @@ int8 weights:
    every margin <= `tol_margin`, except at positions whose own token is
    tie-exposed.
 
-The engine's state is also held to the file: int8 weights, the KV dtype.
+The engine's state is also held to the file: whatever the family's
+`engine_state` finds (int8 weights, the KV dtype) against the same keys of the
+`check` block.
+
+This file keeps the loop, the comparison, the tie logic, the judge and what
+every tolerance means; it knows no published key, no weight leaf and no model
+function: a family's file does.
 """
 
 from __future__ import annotations
 
-import functools
-import importlib
+from pathlib import Path
 from typing import Any, Callable, Optional
 
 import numpy as np
 
-
-def reference_dims(spec: dict) -> dict:
-    dims = {
-        "n_heads": spec["num_attention_heads"],
-        "n_kv_heads": spec["num_key_value_heads"],
-        "head_dim": spec.get("head_dim") or spec["hidden_size"] // spec["num_attention_heads"],
-        "rope_theta": float(spec["rope_theta"]),
-        "eps": float(spec["rms_norm_eps"]),
-    }
-    if spec.get("num_local_experts"):
-        dims["n_experts"] = spec["num_local_experts"]
-        dims["top_k"] = spec["num_experts_per_tok"]
-    return dims
+from modelcfg import HERE, load_module
 
 
 def sample_prompts(check: dict, vocab_size: int) -> list[list[int]]:
@@ -93,45 +88,16 @@ class _Scorer:
     """Layer-by-layer system chain, teacher-forced reference and
     free-running reference over one padded sequence."""
 
-    def __init__(self, config, dims: dict, family: str, width: int, rows: int = 1,
+    def __init__(self, family, ref, config, dims: dict, width: int, rows: int = 1,
                  scores: str = "reference") -> None:
         import jax
         import jax.numpy as jnp
         from jax import lax
 
-        from langstream_tpu.models import transformer as program
-
-        ref = importlib.import_module(f"reference.{family}")
         self.width, self.rows = width, rows
         self.judge = {"reference": "free", "verified_chain": "chain"}[scores]
-        positions = jnp.broadcast_to(jnp.arange(width), (rows, width))
-
-        def layer_slice(layers, index):
-            return jax.tree.map(
-                lambda a: lax.dynamic_index_in_dim(a, index, 0, keepdims=False), layers
-            )
-
-        @jax.jit
-        def sys_embed(params, tokens):
-            # the sequence in row 0 of a group of `rows`, the other rows all
-            # padding (id 0), as the engine fills a prefill group for one
-            # request: the program's expert capacity is per dispatch
-            group = jnp.zeros((rows, width), jnp.int32).at[0].set(tokens)
-            return program._embed(params, group, config)
-
-        @jax.jit
-        def sys_layer(layers, index, x):
-            # the body of transformer.forward, one layer at a time
-            sin, cos = program._rope_freqs(positions, config)
-            mask = jnp.broadcast_to(
-                jnp.tril(jnp.ones((width, width), jnp.bool_)), (rows, width, width)
-            )
-            y, _ = program._layer(x, layer_slice(layers, index), sin, cos, mask, config)
-            return y
-
-        @jax.jit
-        def sys_unembed(params, x):
-            return program._unembed(params, x[:1], config)[0]
+        self._chain = family.system_chain(config, width, rows)
+        self._ref_stack = family.ref_layer_params
 
         def rel_err(got, want):
             diff = jnp.max(jnp.abs(got.astype(jnp.float32) - want), axis=-1)
@@ -139,7 +105,9 @@ class _Scorer:
 
         @jax.jit
         def ref_layer(layers, index, sys_in, sys_out, free_in):
-            lp = layer_slice(layers, index)
+            lp = jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, index, 0, keepdims=False), layers
+            )
             forced, info = ref.layer(sys_in[0].astype(jnp.float32), lp, dims)
             free, _ = ref.layer(free_in, lp, dims)
             gap = info.get("router_gap", jnp.full((width,), jnp.inf))
@@ -165,9 +133,8 @@ class _Scorer:
             want = lax.dynamic_slice_in_dim(logits, start, hot.shape[0], axis=0)
             return rel_err(hot, want.astype(jnp.float32))
 
-        self._fns = (sys_embed, sys_layer, sys_unembed, ref_layer, ref_head, margins, hot_err)
+        self._fns = (ref_layer, ref_head, margins, hot_err)
         self._ref_embed = jax.jit(ref.embed)
-        self._n_layers = config.n_layers
 
     def score(self, sys_params, ref_params, sequence: list[int], n_prompt: int, hot) -> dict:
         """All the per-position numbers for one sequence (host arrays).
@@ -175,22 +142,25 @@ class _Scorer:
         import jax
         import jax.numpy as jnp
 
-        sys_embed, sys_layer, sys_unembed, ref_layer, ref_head, margins, hot_err = self._fns
+        chain = self._chain
+        ref_layer, ref_head, margins, hot_err = self._fns
         n = len(sequence)
         if n > self.width:
             raise ValueError(f"check sequence of {n} tokens exceeds width {self.width}")
         tokens = jnp.asarray(sequence + [0] * (self.width - n), jnp.int32)
-        x = sys_embed(sys_params, tokens)
+        x = chain.embed(sys_params, tokens)
         free = self._ref_embed(ref_params, tokens)
         errs, gaps, loads = [], [], []
-        for index in range(self._n_layers):
-            y = sys_layer(sys_params["layers"], index, x)
-            err, gap, load, free = ref_layer(ref_params["layers"], index, x, y, free)
+        for index in range(chain.n_layers):
+            y = chain.layer(sys_params, index, x)
+            # the stack of like layers that holds this one, and its place
+            # there: sliced inside the compiled program, never copied out
+            err, gap, load, free = ref_layer(*self._ref_stack(ref_params, index), x, y, free)
             errs.append(err)
             gaps.append(gap)
             loads.append(load)
             x = y
-        chain_logits = sys_unembed(sys_params, x)
+        chain_logits = chain.unembed(sys_params, x)
         head_err, e2e_err, free_logits = ref_head(ref_params, x, chain_logits, free)
         out = {
             "layer_err": jnp.stack(errs + [head_err])[:, :n],  # [L + 1, n]
@@ -203,74 +173,10 @@ class _Scorer:
         return {k: np.asarray(v) for k, v in jax.device_get(out).items()}
 
 
-class _HotPath:
-    """The model functions the engine's programs are made of, called as the
-    engine calls them, with its config (so its kernels and its KV type) and
-    its page size, on a page pool of this check's own: the logits the engine
-    samples from, which it does not hand out."""
-
-    def __init__(self, engine, width: int, rows: int, new_tokens: int) -> None:
-        import jax
-        import jax.numpy as jnp
-
-        from langstream_tpu.models import transformer as program
-
-        config = engine.config
-        page_size = engine._pagepool.page_size
-        n_pages = -(-(width + new_tokens) // page_size)
-        self.width = width
-        # the sequence in row 0 of the group, its pages 0..n_pages-1; the
-        # padding rows' tables are all out of bounds, so their writes drop
-        tables = jnp.full((rows, n_pages), n_pages, jnp.int32).at[0].set(jnp.arange(n_pages))
-
-        @jax.jit
-        def prefill_group(params, tokens, length):
-            group = jnp.zeros((rows, width), jnp.int32).at[0].set(tokens)
-            lengths = jnp.ones((rows,), jnp.int32).at[0].set(length)
-            logits, local = program.prefill(
-                params, group, lengths, program.make_kv_cache(config, rows, width), config
-            )
-            pool = program.make_page_pool(config, n_pages, page_size)
-            return logits[0], program.paged_insert_cache(pool, local, tables, page_size)
-
-        @functools.partial(jax.jit, donate_argnames=("pool",))
-        def decode(params, token, position, pool):
-            logits, pool = program.paged_decode_step_inplace(
-                params, token[None], position[None], pool, tables[:1], config, page_size
-            )
-            return logits[0], pool
-
-        self._fns = (prefill_group, decode)
-
-    def logits(self, params, prompt: list[int], generated: list[int]):
-        """[len(generated), V]: row j is the distribution generated token j
-        was drawn from, token j - 1 having gone through the paged cache."""
-        import jax.numpy as jnp
-
-        prefill_group, decode = self._fns
-        n = len(prompt)
-        tokens = jnp.asarray(prompt + [0] * (self.width - n), jnp.int32)
-        first, pool = prefill_group(params, tokens, jnp.int32(n))
-        rows = [first]
-        for j, token in enumerate(generated[:-1]):
-            step, pool = decode(params, jnp.int32(token), jnp.int32(n + j), pool)
-            rows.append(step)
-        return jnp.stack(rows).astype(jnp.float32)
-
-
-def _engine_state(engine, check: dict) -> dict:
+def _engine_state(family, engine, check: dict) -> dict:
     """What the engine holds, against what the file says it should."""
-    from langstream_tpu.models.quant import is_quantized
-
-    layers = engine.params["layers"]
-    weights = "int8" if all(
-        is_quantized(layers[k]) and layers[k]["q"].dtype == np.int8
-        for k in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
-    ) else "unquantized"
-    pool = engine._pagepool.dev["k"]
-    kv = "int8" if isinstance(pool, dict) else str(pool.dtype)
-    found = {"weights": weights, "kv_dtype": kv}
-    wanted = {"weights": check["weights"], "kv_dtype": check["kv_dtype"]}
+    found = family.engine_state(engine)
+    wanted = {key: check.get(key) for key in found}
     return {"found": found, "wanted": wanted, "ok": found == wanted}
 
 
@@ -297,7 +203,8 @@ def _judge(scores: list[dict], prompt_lens: list[int], check: dict) -> dict:
     for s, n_prompt in zip(scores, prompt_lens):
         err, gap = s["layer_err"], s["router_gap"]
         if not (np.isfinite(err).all() and np.isfinite(s["hot_err"]).all()):
-            return {"ok": False, "reason": "non-finite activations"}
+            return {"ok": False, "reason": "non-finite activations",
+                    "compared": {"non_finite_sequences": [1, 0]}}
         tie = np.zeros_like(err, bool)
         tie[:-1] = gap < eps  # the head has no router
         over = err > float(check["tol_max"])
@@ -347,13 +254,20 @@ def _judge(scores: list[dict], prompt_lens: list[int], check: dict) -> dict:
         "engine_margin_over_tol": margin_bad,
         "hot_err_by_position": [round(float(v), 5) for v in hot_all],
     }
-    ok = median <= float(check["tol_med"]) and unexplained == 0 and margin_bad == 0
-    ok = ok and hot_bad == 0 and first_bad == 0
+    # every number the verdict rests on, beside its limit
+    compared = {
+        "layer_err_median": [median, float(check["tol_med"])],
+        "layer_err_over_tol_untied": [unexplained, 0],
+        "hot_err_over_tol_untied": [hot_bad + first_bad, 0],
+        "engine_margin_over_tol_untied": [margin_bad, 0],
+    }
     if check.get("tol_e2e_max") is not None:
-        ok = ok and verdict["e2e_err_max"] <= float(check["tol_e2e_max"])
+        compared["e2e_err_max"] = [verdict["e2e_err_max"], float(check["tol_e2e_max"])]
     if check.get("tol_hot_med") is not None:
-        ok = ok and verdict["hot_err_median_unexposed"] <= float(check["tol_hot_med"])
-    verdict["ok"] = bool(ok)
+        compared["hot_err_median_untied"] = [
+            verdict["hot_err_median_unexposed"], float(check["tol_hot_med"])]
+    verdict["compared"] = compared
+    verdict["ok"] = all(value <= limit for value, limit in compared.values())
     return verdict
 
 
@@ -363,21 +277,25 @@ def run_check(
     *,
     ref_params: Optional[Any] = None,
     emit: Callable[..., None] = lambda **_: None,
+    files: Path = HERE,
 ) -> dict:
     """The verdict and its evidence. `ref_params` is the tree the reference
     reads; it is the engine's own except in the harness's tests, which hand
-    the engine a faulted copy to show that the check can fail."""
+    the engine a faulted copy to show that the check can fail. `files` is
+    where the cell's files are, the family's and its reference among them."""
     check = spec["check"]
     config = engine.config
-    dims = reference_dims(spec)
+    family = load_module("families", spec["family"], files)
+    ref = load_module("reference", spec["family"], files)
+    dims = family.reference_dims(spec)
     ref_params = engine.params if ref_params is None else ref_params
     prompts = sample_prompts(check, config.vocab_size)
     new_tokens = int(check["new_tokens"])
 
-    state = _engine_state(engine, check)
+    state = _engine_state(family, engine, check)
     width, rows = int(check["width"]), int(check.get("rows", 1))
-    scorer = _Scorer(config, dims, spec["family"], width, rows, check["engine_scores"])
-    hot_path = _HotPath(engine, width, rows, new_tokens)
+    scorer = _Scorer(family, ref, config, dims, width, rows, check["engine_scores"])
+    hot_path = family.hot_path(engine, width, rows, new_tokens)
 
     def score(prompt: list[int], tokens: list[int]) -> dict:
         hot = hot_path.logits(engine.params, prompt, tokens)
@@ -386,7 +304,8 @@ def run_check(
     generated = _generate(engine, prompts, new_tokens, together=False)
     answered = all(len(g) > 0 for g in generated)
     if not answered:  # a prompt that produced nothing was not checked at all
-        verdict = {"ok": False, "reason": "a check prompt produced no token"}
+        verdict = {"ok": False, "reason": "a check prompt produced no token",
+                   "compared": {"check_prompts_unanswered": [sum(not g for g in generated), 0]}}
         emit(phase="check", **verdict)
         return verdict
     scores = [score(p, g) for p, g in zip(prompts, generated)]
@@ -394,6 +313,8 @@ def run_check(
     verdict["engine_state"] = state
     verdict["generated_tokens"] = [len(g) for g in generated]
     verdict["expert_load_max"] = int(max(s["expert_load_max"] for s in scores))
+    verdict["compared"]["engine_state_mismatches"] = [
+        sum(state["found"][key] != state["wanted"][key] for key in state["found"]), 0]
     verdict["ok"] = bool(verdict["ok"] and state["ok"])
     emit(phase="check", **verdict)
 

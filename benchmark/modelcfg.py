@@ -1,36 +1,22 @@
 """Configuration files: `benchmark/configs/<name>.json` → the program's
-`ModelConfig`, registered under the configuration's name."""
+`ModelConfig`, registered under the configuration's name. Which published
+keys a configuration has, and what each means to the program, is its
+family's to say: `families/<family>.py`, found by the file's `family` key."""
 
 from __future__ import annotations
 
+import functools
+import importlib
+import importlib.util
 import json
 from pathlib import Path
+from typing import Iterable
 
 HERE = Path(__file__).resolve().parent
 
-# published config.json key → ModelConfig field
-_FIELDS = {
-    "vocab_size": "vocab_size",
-    "hidden_size": "d_model",
-    "num_hidden_layers": "n_layers",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff",
-    "head_dim": "head_dim",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "rms_norm_eps",
-    "max_position_embeddings": "max_seq_len",
-    "hidden_act": "activation",
-    "tie_word_embeddings": "tie_embeddings",
-    "num_local_experts": "n_experts",
-    "num_experts_per_tok": "n_experts_per_tok",
-}
-
-
 # what a configuration file holds besides published keys
 _HARNESS_KEYS = {
-    "source", "family", "assumed", "reduced", "deployment", "serving", "weights",
-    "check", "sliding_window",
+    "source", "family", "assumed", "reduced", "deployment", "serving", "weights", "check",
 }
 
 
@@ -41,25 +27,38 @@ def load_json(kind: str, name: str, root: Path = HERE) -> dict:
     return json.loads(path.read_text())
 
 
-def model_config(spec: dict, name: str):
-    from langstream_tpu.models.configs import ModelConfig
+@functools.lru_cache(maxsize=None)
+def load_module(kind: str, name: str, root: Path = HERE):
+    """`<root>/<kind>/<name>.py` where a cell's files bring their own (the
+    harness's tests do), else the harness's `<kind>/<name>.py`."""
+    path = root / kind / f"{name}.py"
+    if root == HERE or not path.is_file():
+        return importlib.import_module(f"{kind}.{name}")
+    found = importlib.util.spec_from_file_location(f"{root.name}_{kind}_{name}", path)
+    module = importlib.util.module_from_spec(found)
+    found.loader.exec_module(module)
+    return module
 
-    unknown = sorted(set(spec) - set(_FIELDS) - _HARNESS_KEYS)
+
+def refuse_unmapped(spec: dict, mapped: Iterable[str], name: str) -> None:
+    """A family calls this with the published keys it maps: any other key of
+    the file that is not the harness's own raises, by name."""
+    unknown = sorted(set(spec) - set(mapped) - _HARNESS_KEYS)
     if unknown:
         raise ValueError(f"{name}: keys that map onto no ModelConfig field: {unknown}")
-    if spec.get("sliding_window") is not None:
-        raise ValueError(f"{name}: the program's block has no sliding window")
-    fields = {ours: spec[theirs] for theirs, ours in _FIELDS.items() if theirs in spec}
-    return ModelConfig(name=name, **fields)
 
 
-def register_preset(spec: dict, name: str):
+def model_config(spec: dict, name: str, root: Path = HERE):
+    return load_module("families", spec["family"], root).model_config(spec, name)
+
+
+def register_preset(spec: dict, name: str, root: Path = HERE):
     """`tpu-serving` takes `model:` only from MODEL_PRESETS, so the
     configuration is entered there under its own name: the one place the
     harness writes into the program's tables (PERF.md lists "the resource
     reads a model configuration file" for a later PR)."""
     from langstream_tpu.models.configs import MODEL_PRESETS
 
-    config = model_config(spec, name)
+    config = model_config(spec, name, root)
     MODEL_PRESETS[name] = config
     return config

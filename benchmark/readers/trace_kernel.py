@@ -1,26 +1,23 @@
 """A kernel's share of its roofline, from the device trace: the least time
 the chip could take for the calls it made (the larger of operations over
 peak and bytes over peak, from the shapes each event's line carries) over
-the device time those events took. `reduce/kernel_names.json` says how a
-kernel's events are recognised; a kernel it does not list is not read."""
+the device time those events took. `reduce/kernels/<kernel>.json`, the file
+the metric's definition names, says how the kernel's events are recognised."""
 
 from __future__ import annotations
 
 import importlib
-import json
 import re
-from pathlib import Path
 from typing import Optional
 
+from modelcfg import load_json
 from reduce import costs
-
-NAMES = Path(__file__).resolve().parents[1] / "reduce" / "kernel_names.json"
 
 
 def read(definition: dict, ctx: dict) -> Optional[float]:
-    entry = json.loads(NAMES.read_text())["kernels"].get(definition["kernel"])
-    if entry is None or not ctx.get("trace"):
+    if not ctx.get("trace"):
         return None
+    entry = load_json("reduce/kernels", definition["kernel"])
     module, function = entry["cost"].split(".")
     cost = getattr(importlib.import_module(f"reduce.{module}"), function)
     shape = re.compile(entry["shape"])

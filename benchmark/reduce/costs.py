@@ -2,7 +2,7 @@
 
 The least work the algorithm requires, not what an implementation happens to
 do: a causal prefill attends to half the square. A later PR adds a kernel's
-cost as a module of its own beside this one (`kernel_names.json` names the
+cost as a module of its own beside this one (`kernels/<kernel>.json` names the
 function as `<module>.<function>`).
 """
 

@@ -42,7 +42,7 @@ for path in (str(ROOT), str(HERE)):
         sys.path.insert(0, path)
 
 import metrics  # noqa: E402
-from modelcfg import load_json, register_preset  # noqa: E402
+from modelcfg import load_json, load_module, register_preset  # noqa: E402
 
 APP_ID = "bench"
 STATS_SAMPLE_S = 0.1
@@ -70,20 +70,6 @@ def cell_entry(bench: dict, name: str) -> dict:
 
 def metrics_of(entries: list[dict], cell: str) -> list[dict]:
     return [m for m in entries if "workloads" not in m or cell in m["workloads"]]
-
-
-def expected_kernels(engine) -> dict:
-    """`auto` on a TPU: a kernel that quietly gave way to the jnp path is a
-    failure here, not a footnote (as chip_smoke.py checks)."""
-    pool = engine._pagepool
-    return {
-        f"paged-decode[s=1,t={pool.table_len * pool.page_size}]": "ragged_paged_decode_attention",
-        **{
-            f"prefill[s={w},t={w}]": "flash_prefill_attention"
-            for w in engine.prefill_buckets
-            if w % 128 == 0
-        },
-    }
 
 
 class SpanSink:
@@ -330,7 +316,8 @@ async def run_cell(
     workload = load_json("workloads", cell_name, files)
     spec = load_json("configs", cell["config"], files)
     traffic = load_json("traffic", cell["traffic"], files)
-    config = register_preset(spec, cell["config"])
+    family = load_module("families", spec["family"], files)
+    config = register_preset(spec, cell["config"], files)
     caps = sorted(int(c) for c in traffic["output_caps"])
     devices = jax.devices()[: cell["chips"]]
 
@@ -357,10 +344,8 @@ async def run_cell(
             provider = runner.service_registry.get_provider()
             ref_params = None
             if spec["weights"]["init"] == "device":
-                from weights import make_int8_params
-
                 t = time.monotonic()
-                params = make_int8_params(config, int(spec["weights"]["seed"]))
+                params = family.make_params(config, int(spec["weights"]["seed"]))
                 jax.block_until_ready(params)
                 if ref_params_fault is not None:
                     ref_params, params = params, ref_params_fault(params)
@@ -378,13 +363,16 @@ async def run_cell(
                 compile_cache_dir=jax.config.jax_compilation_cache_dir,
             )
             if platform == "tpu":
+                # `auto` on a TPU: a kernel that quietly gave way to the jnp
+                # path is a failure here, not a footnote (as chip_smoke.py checks)
                 gave_way = {
-                    k: paths.get(k) for k, v in expected_kernels(engine).items() if paths.get(k) != v
+                    k: paths.get(k) for k, v in family.expected_kernels(engine).items()
+                    if paths.get(k) != v
                 }
                 if gave_way:
                     raise RuntimeError(f"expected kernels, traced: {gave_way}")
             placed = {
-                d.platform for leaf in jax.tree.leaves((engine.params, engine._pagepool.dev))
+                d.platform for leaf in jax.tree.leaves(family.state_leaves(engine))
                 for d in leaf.devices()
             }
             if placed != {platform}:
@@ -392,7 +380,8 @@ async def run_cell(
 
             t = time.monotonic()
             verdict = await loop.run_in_executor(
-                None, lambda: run_check(engine, spec, ref_params=ref_params, emit=emit)
+                None, lambda: run_check(
+                    engine, spec, ref_params=ref_params, emit=emit, files=files)
             )
             emit(phase="check-time", seconds=round(time.monotonic() - t, 2))
 
@@ -507,6 +496,7 @@ async def run_cell(
                     if m["name"] not in values:
                         raise RuntimeError(f"the window gave no {m['name']}")
                     result["metrics"][m["name"]] = {"value": values[m["name"]], "unit": m["unit"]}
+            result["compared"] = verdict.get("compared", {})  # last: what `correct` rests on
             return result
         finally:
             try:
@@ -552,6 +542,8 @@ def main(argv: Optional[list[str]] = None) -> int:
         run_cell(bench, args.workload, args.seed, args.seconds, bool(args.trace), sweep=sweep)
     )
     print(json.dumps(result), flush=True)
+    for name, (value, limit) in result["compared"].items():
+        print(f"benchmark: compared {name} = {value}, limit {limit}", file=sys.stderr)
     # engine and agent threads may outlive a timed-out teardown; the result
     # is out and every child has been waited for
     sys.stdout.flush()

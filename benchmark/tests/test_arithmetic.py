@@ -93,4 +93,4 @@ def test_a_configuration_key_that_maps_onto_nothing_is_an_error():
     from modelcfg import model_config
 
     with pytest.raises(ValueError, match="num_hidden_layer"):
-        model_config({"hidden_size": 64, "num_hidden_layer": 2}, "typo")
+        model_config({"family": "mistral", "hidden_size": 64, "num_hidden_layer": 2}, "typo")

@@ -13,9 +13,8 @@ import jax
 import pytest
 
 from check import run_check
-from modelcfg import register_preset
+from modelcfg import load_module, register_preset
 from traffic_kinds import open_poisson
-from weights import make_int8_params
 
 DATA = Path(__file__).parent / "data"
 ENGINE = {"max-batch": 8, "max-seq-len": 128, "prefill-buckets": [32, 64],
@@ -27,7 +26,8 @@ def build(name: str, fault=None):
 
     spec = json.loads((DATA / "configs" / f"{name}.json").read_text())
     config = register_preset(spec, name)
-    sound = make_int8_params(config, int(spec["weights"]["seed"]))
+    family = load_module("families", spec["family"])
+    sound = family.make_params(config, int(spec["weights"]["seed"]))
     provider = TpuServingProvider({**spec["serving"], **ENGINE, "model": name})
     provider.holder._params = fault(sound) if fault else sound
     return provider, spec, sound

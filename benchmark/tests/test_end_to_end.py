@@ -48,6 +48,9 @@ def test_dense_chat_cell_end_to_end():
     assert set(out["metrics"]) == {"ttft_p50_ms", "ttft_mean_ms", "tpot_mean_ms", "setup_s"}
     assert all(m["value"] > 0 for m in out["metrics"].values())
     assert "busy_s" not in out["device"]
+    # what `correct` rests on, each number beside its limit, as the line's last key
+    assert list(out)[-1] == "compared" and len(out["compared"]) >= 6
+    assert all(value <= limit for value, limit in out["compared"].values())
 
 
 def test_moe_drain_cell_traced():
@@ -79,8 +82,11 @@ def test_every_named_file_exists():
         traffic = json.loads((BENCH / "traffic" / f"{cell['traffic']}.json").read_text())
         assert (BENCH / "traffic_kinds" / f"{traffic['kind']}.py").is_file()
         config = json.loads((BENCH / "configs" / f"{cell['config']}.json").read_text())
-        assert (BENCH / "reference" / f"{config['family']}.py").is_file()
+        for kind in ("families", "reference"):
+            assert (BENCH / kind / f"{config['family']}.py").is_file(), (kind, config["family"])
     for metric in REAL["per_layer"]:
         definition = run.metric_definition(metric["name"])
         assert (BENCH / "readers" / f"{definition['reader']}.py").is_file()
+        if "kernel" in definition:
+            assert (BENCH / "reduce" / "kernels" / f"{definition['kernel']}.json").is_file()
         assert metric["moves"] in {m["name"] for m in REAL["end_to_end"]}

@@ -67,7 +67,8 @@ def test_hlo_lines_are_shortened_and_the_prefill_kernel_is_read_from_its_shapes(
     }}}
     definition = {"kernel": "flash_prefill_attention"}
     assert trace_kernel.read(definition, ctx) == pytest.approx(25.0)
-    assert trace_kernel.read({"kernel": "no_such_kernel"}, ctx) is None
+    with pytest.raises(FileNotFoundError, match="no_such_kernel"):  # reduce/kernels/<kernel>.json
+        trace_kernel.read({"kernel": "no_such_kernel"}, ctx)
     assert trace_kernel.read(definition, {"trace": None}) is None
 
 
