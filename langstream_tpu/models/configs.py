@@ -60,6 +60,60 @@ class ModelConfig:
     rope_scaling_low_freq_factor: float = 1.0
     rope_scaling_high_freq_factor: float = 4.0
     rope_scaling_original_max_seq_len: int = 8192
+    # layer pattern (hybrid models): the kinds of one PERIOD of layers,
+    # each "linear_attention" (gated delta rule, a recurrent state per
+    # sequence: ops/gated_delta.py) or "full_attention"; n_layers is a whole
+    # number of periods and every kind is a stack of its own under
+    # params["layers"][kind]. Empty: every layer is the one block above, in
+    # one stack. The full layers of a pattern are the OLMo block: the norm
+    # on each sublayer's OUTPUT, RMSNorm over the whole width of q and of k
+    # before the heads are split (``qk_norm``), rotary or none (``rope``).
+    layer_pattern: tuple = ()
+    linear_n_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel: int = 4
+    # beta in (0, 2) instead of (0, 1): the state's transition may flip sign
+    linear_allow_neg_eigval: bool = False
+    output_norm: bool = False  # full layers: x + norm(f(x)), not x + f(norm(x))
+    qk_norm: bool = False
+    rope: bool = True  # False: attention turns nothing
+
+    @property
+    def is_recurrent(self) -> bool:
+        return "linear_attention" in self.layer_pattern
+
+    @property
+    def n_periods(self) -> int:
+        return self.n_layers // len(self.layer_pattern) if self.layer_pattern else 0
+
+    def n_layers_of(self, kind: str) -> int:
+        """Layers of ``kind`` in the model; the page pool's layer axis is
+        ``n_layers_of("full_attention")``."""
+        if not self.layer_pattern:
+            return self.n_layers if kind == "full_attention" else 0
+        return self.n_periods * self.layer_pattern.count(kind)
+
+    @property
+    def linear_key_dim(self) -> int:
+        return self.linear_n_heads * self.linear_key_head_dim
+
+    @property
+    def linear_value_dim(self) -> int:
+        return self.linear_n_heads * self.linear_value_head_dim
+
+    @property
+    def linear_conv_dim(self) -> int:
+        return 2 * self.linear_key_dim + self.linear_value_dim
+
+    def __post_init__(self) -> None:
+        if self.layer_pattern:
+            unknown = set(self.layer_pattern) - {"linear_attention", "full_attention"}
+            if unknown or self.n_layers % len(self.layer_pattern):
+                raise ValueError(
+                    f"{self.name}: layer_pattern {self.layer_pattern} over "
+                    f"{self.n_layers} layers"
+                )
 
     @property
     def resolved_head_dim(self) -> int:
@@ -70,6 +124,14 @@ class ModelConfig:
         """Rough parameter count (placement decisions, not accounting)."""
         d, hd = self.d_model, self.resolved_head_dim
         attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        if self.layer_pattern:
+            linear = 2 * d * (self.linear_key_dim + self.linear_value_dim) + (
+                self.linear_value_dim * d
+            )
+            n_lin = self.n_layers_of("linear_attention")
+            mixers = n_lin * linear + (self.n_layers - n_lin) * attn
+            embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+            return mixers + self.n_layers * 3 * d * self.d_ff + embed
         if self.is_moe:
             ffn = self.n_experts * 3 * d * self.d_ff + d * self.n_experts
         else:
@@ -200,6 +262,50 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         max_seq_len=32768,
         n_experts=8,
         n_experts_per_tok=2,
+    ),
+    "tiny-hybrid-test": _preset(
+        # the olmo-hybrid layer pattern at test size: d 64, 8 layers,
+        # linear heads 4 x 8/16, conv 4 (tests/test_olmo_hybrid.py)
+        name="tiny-hybrid-test",
+        vocab_size=512,
+        d_model=64,
+        n_layers=8,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=128,
+        max_seq_len=1024,
+        layer_pattern=("linear_attention",) * 3 + ("full_attention",),
+        linear_n_heads=4,
+        linear_key_head_dim=8,
+        linear_value_head_dim=16,
+        linear_conv_kernel=4,
+        linear_allow_neg_eigval=True,
+        output_norm=True,
+        qk_norm=True,
+        rope=False,
+    ),
+    "olmo-hybrid-7b": _preset(
+        # allenai/Olmo-Hybrid-7B config.json: (gated delta-rule x3, full
+        # attention) x8; full layers MHA 30 x 128 without rotary
+        name="olmo-hybrid-7b",
+        vocab_size=100352,
+        d_model=3840,
+        n_layers=32,
+        n_heads=30,
+        n_kv_heads=30,
+        d_ff=11008,
+        head_dim=128,
+        rms_norm_eps=1e-6,
+        max_seq_len=65536,
+        layer_pattern=("linear_attention",) * 3 + ("full_attention",),
+        linear_n_heads=30,
+        linear_key_head_dim=96,
+        linear_value_head_dim=192,
+        linear_conv_kernel=4,
+        linear_allow_neg_eigval=True,
+        output_norm=True,
+        qk_norm=True,
+        rope=False,
     ),
 }
 

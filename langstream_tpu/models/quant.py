@@ -23,7 +23,8 @@ from langstream_tpu.models.configs import ModelConfig
 Params = dict
 
 # stacked-layer matmul weights that dominate HBM traffic
-_QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+# "wqkv", "wg": a linear-attention layer's q, k, v side by side and its output gate
+_QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wqkv", "wg", "wo", "w_gate", "w_up", "w_down")
 
 
 def is_quantized(leaf: Any) -> bool:
@@ -61,11 +62,18 @@ def quantize_row_wise(w: jax.Array) -> dict[str, jax.Array]:
 def quantize_params(params: Params, config: ModelConfig) -> Params:
     """Quantize the serving-dominant weights; everything else passes through."""
     out: Params = dict(params)
-    layers = dict(params["layers"])
-    for key in _QUANT_LAYER_KEYS:
-        if key in layers:
-            layers[key] = quantize_weight(layers[key])
-    out["layers"] = layers
+
+    def stack(layers: Params) -> Params:
+        layers = dict(layers)
+        for key in _QUANT_LAYER_KEYS:
+            if key in layers:
+                layers[key] = quantize_weight(layers[key])
+        return layers
+
+    if config.layer_pattern:  # one stack a kind of layer
+        out["layers"] = {kind: stack(s) for kind, s in params["layers"].items()}
+    else:
+        out["layers"] = stack(params["layers"])
     if "lm_head" in params:
         out["lm_head"] = quantize_weight(params["lm_head"])
     if config.tie_embeddings:

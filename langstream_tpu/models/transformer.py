@@ -44,6 +44,8 @@ SCOPES = (
     "embed", "attention", "ffn", "moe_ffn", "moe_ffn.route",
     "moe_ffn.dispatch", "moe_ffn.experts", "moe_ffn.combine",
     "kv_pool.write", "head", "sample",
+    "linear_attention", "linear_attention.proj", "linear_attention.conv",
+    "linear_attention.state", "linear_attention.out",
 )
 
 # What `moe_ffn_counted` counts, per call, as one int32 vector: expert
@@ -73,6 +75,8 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
     hd = config.resolved_head_dim
     f, L, v = config.d_ff, config.n_layers, config.vocab_size
 
+    if config.layer_pattern:
+        return _init_pattern_params(config, key, dtype)
     keys = jax.random.split(key, 12)
 
     def norm(k, *shape, scale=None):
@@ -105,6 +109,77 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
     }
     if not config.tie_embeddings:
         params["lm_head"] = norm(keys[9], d, v, scale=d)
+    return params
+
+
+def _init_pattern_params(config: ModelConfig, key: jax.Array, dtype) -> Params:
+    """A model with a layer pattern: ``params["layers"]`` holds one stack a
+    KIND of layer, ``{"linear_attention": [n of them, ...],
+    "full_attention": [...]}``, each in the model's layer order."""
+    d, f, v = config.d_model, config.d_ff, config.vocab_size
+    h, hkv, hd = config.n_heads, config.n_kv_heads, config.resolved_head_dim
+    lh, kd, vd = config.linear_n_heads, config.linear_key_dim, config.linear_value_dim
+    keys = iter(jax.random.split(key, 24))
+
+    def normal(*shape):  # [..., in, out]: N(0, 1 / in)
+        w = jax.random.normal(next(keys), shape, jnp.float32) * shape[-2] ** -0.5
+        return w.astype(dtype)
+
+    def ffn(n):
+        return {
+            "ffn_norm": jnp.ones((n, d), dtype),
+            "w_gate": normal(n, d, f), "w_up": normal(n, d, f), "w_down": normal(n, f, d),
+        }
+
+    n_lin = config.n_layers_of("linear_attention")
+    n_full = config.n_layers_of("full_attention")
+    # the published initialisation's draw (fla's GatedDeltaNet): A uniform
+    # in (0, 16), the step log-uniform in (0.001, 0.1) through the inverse
+    # softplus, so that the decay spans a real range
+    step = jnp.exp(
+        jax.random.uniform(next(keys), (n_lin, lh), jnp.float32)
+        * (math.log(0.1) - math.log(0.001)) + math.log(0.001)
+    )
+    layers = {}
+    if n_lin:
+        layers["linear_attention"] = {
+            "attn_norm": jnp.ones((n_lin, d), dtype),
+            # q, k and v side by side, [d, 2 kd + vd]: one product, and a
+            # width that is whole lanes where kd alone (2880) is not
+            "wqkv": normal(n_lin, d, 2 * kd + vd), "wg": normal(n_lin, d, vd),
+            "wa": normal(n_lin, d, lh), "wb": normal(n_lin, d, lh),
+            "conv_w": (
+                jax.random.normal(
+                    next(keys), (n_lin, config.linear_conv_kernel, config.linear_conv_dim),
+                    jnp.float32,
+                ) * config.linear_conv_kernel ** -0.5
+            ).astype(dtype),
+            "A_log": jnp.log(
+                jax.random.uniform(next(keys), (n_lin, lh), jnp.float32, 1e-3, 16.0)
+            ),
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "out_norm": jnp.ones((n_lin, config.linear_value_head_dim), dtype),
+            "wo": normal(n_lin, vd, d),
+            **ffn(n_lin),
+        }
+    if n_full:
+        full = {
+            "attn_norm": jnp.ones((n_full, d), dtype),
+            "wq": normal(n_full, d, h * hd), "wk": normal(n_full, d, hkv * hd),
+            "wv": normal(n_full, d, hkv * hd), "wo": normal(n_full, h * hd, d),
+            **ffn(n_full),
+        }
+        if config.qk_norm:
+            full["q_norm"] = jnp.ones((n_full, h * hd), dtype)
+            full["k_norm"] = jnp.ones((n_full, hkv * hd), dtype)
+        layers["full_attention"] = full
+    params: Params = {
+        "embed": (jax.random.normal(next(keys), (v, d), jnp.float32) * d**-0.5).astype(dtype),
+        "layers": layers,
+        "final_norm": jnp.ones((d,), dtype),
+    }
+    if not config.tie_embeddings:
+        params["lm_head"] = normal(d, v)
     return params
 
 
@@ -257,13 +332,36 @@ def _lora_proj(
 
 
 def make_page_pool(
-    config: ModelConfig, num_pages: int, page_size: int, dtype=None
+    config: ModelConfig, num_pages: int, page_size: int, dtype=None,
+    state_rows: int = 0,
 ) -> KVCache:
     """Device page pool: ``{"k","v"}`` with leaves [L, P, Hkv, ps, D] (or the
     int8 ``{"q","s"}`` dicts with scales [L, P, Hkv, ps]) — structurally a
     make_kv_cache with B = pages and T = page_size, so every tree-shaped
-    helper (sharding specs, byte accounting, donation) applies unchanged."""
-    return make_kv_cache(config, num_pages, page_size, dtype=dtype)
+    helper (sharding specs, byte accounting, donation) applies unchanged.
+    L counts the full-attention layers. A model with recurrent layers keeps
+    their state beside the pages, ``"rec"`` (`make_recurrent_state`), one
+    row a slot for ``state_rows`` slots."""
+    pool = make_kv_cache(config, num_pages, page_size, dtype=dtype)
+    if config.is_recurrent:
+        pool["rec"] = make_recurrent_state(config, max(1, state_rows), dtype)
+    return pool
+
+
+def split_rec(pool: KVCache):
+    """The pool's pages and its recurrent state (None for a model without)."""
+    return {"k": pool["k"], "v": pool["v"]}, pool.get("rec")
+
+
+def join_rec(kv: KVCache, rec) -> KVCache:
+    return kv if rec is None else {**kv, "rec": rec}
+
+
+def _no_pattern(config: ModelConfig, what: str) -> None:
+    if config.layer_pattern:
+        raise NotImplementedError(
+            f"{what}: not for a model with a layer pattern ({config.name})"
+        )
 
 
 def _page_index(table: jax.Array, positions: jax.Array, page_size: int,
@@ -641,19 +739,23 @@ def _attention_block(
             k_all = _paged_gather(pk, layer, paged_table, page_size)
             v_all = _paged_gather(pv, layer, paged_table, page_size)
             attn = attention(q, k_all, v_all, mask, config)
-        x = x + quantized_matmul(attn, lp["wo"]) + _lora_proj(
-            attn, "wo", lora, lora_scale, adapter_rows
+        x = _attn_residual(
+            x, quantized_matmul(attn, lp["wo"]), lp, config,
+            _lora_proj(attn, "wo", lora, lora_scale, adapter_rows),
         )
     return x, (pk, pv)
 
 
 def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
     """Norm, the three projections with their adapter terms, rotary:
-    q [B, S, H, D], k and v [B, S, Hkv, D]."""
+    q [B, S, H, D], k and v [B, S, Hkv, D]. A block with its norm on the
+    sublayer's output (``output_norm``) projects the bare input; with
+    ``qk_norm`` q and k pass an RMSNorm over their whole width before the
+    heads are split; without ``rope`` nothing is turned."""
     b, s, d = x.shape
     hd = config.resolved_head_dim
 
-    attn_in = rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
+    attn_in = x if config.output_norm else rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
     q = quantized_matmul(attn_in, lp["wq"]) + _lora_proj(
         attn_in, "wq", lora, lora_scale, adapter_rows
     )
@@ -663,13 +765,28 @@ def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
     v = quantized_matmul(attn_in, lp["wv"]) + _lora_proj(
         attn_in, "wv", lora, lora_scale, adapter_rows
     )
+    if config.qk_norm:
+        q = rms_norm(q, lp["q_norm"], config.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], config.rms_norm_eps)
     q = q.reshape(b, s, config.n_heads, hd)
     k = k.reshape(b, s, config.n_kv_heads, hd)
     v = v.reshape(b, s, config.n_kv_heads, hd)
-    q = apply_rope(q, sin, cos)
-    k = apply_rope(k, sin, cos)
+    if config.rope:
+        q = apply_rope(q, sin, cos)
+        k = apply_rope(k, sin, cos)
 
     return q, k, v
+
+
+def _attn_residual(x, attn_out, lp, config, adapter_term=None):
+    """x + the attention sublayer's output (+ its adapter term), through the
+    block's norm where that sits on the output."""
+    if config.output_norm:
+        if adapter_term is not None:
+            attn_out = attn_out + adapter_term
+        return x + rms_norm(attn_out, lp["attn_norm"], config.rms_norm_eps)
+    x = x + attn_out
+    return x if adapter_term is None else x + adapter_term
 
 
 def _dense_attention(
@@ -723,7 +840,7 @@ def _dense_attention(
         attn_out = quantized_matmul(attn, lp["wo"]) + _lora_proj(
             attn, "wo", lora, lora_scale, adapter_rows
         )
-    return x + attn_out, new_cache
+    return _attn_residual(x, attn_out, lp, config), new_cache
 
 
 def _layer_counted(
@@ -766,25 +883,259 @@ def _layer_counted(
         collect_kv, verify, paged_table, page_size,
         lora, lora_scale, adapter_rows, layer,
     )
+    y, counts = _ffn_half(
+        x, lp, config, config.output_norm, token_valid, lora, lora_scale, adapter_rows
+    )
+    return y, new_cache, counts
+
+
+def _ffn_half(
+    x, lp, config, output_norm=False, token_valid=None, lora=None, lora_scale=None,
+    adapter_rows=None,
+):
+    """The feed-forward half of a block and its MOE_COUNTS: x + f(norm(x)),
+    or, for a dense FFN, x + norm(f(x)) with ``output_norm``."""
+    eps = config.rms_norm_eps
+    if config.is_moe and output_norm:
+        raise NotImplementedError(f"an expert FFN under an output norm ({config.name})")
     if config.is_moe:
         with jax.named_scope("moe_ffn"):
-            ffn_in = rms_norm(x, lp["ffn_norm"], config.rms_norm_eps)
+            ffn_in = rms_norm(x, lp["ffn_norm"], eps)
             ffn_out, counts = moe_ffn_counted(ffn_in, lp, config, token_valid)
     else:
         with jax.named_scope("ffn"):
-            ffn_in = rms_norm(x, lp["ffn_norm"], config.rms_norm_eps)
+            ffn_in = x if output_norm else rms_norm(x, lp["ffn_norm"], eps)
             ffn_out = dense_ffn(
                 ffn_in, lp, config, lora=lora, lora_scale=lora_scale,
                 adapter_rows=adapter_rows,
             )
+            if output_norm:
+                ffn_out = rms_norm(ffn_out, lp["ffn_norm"], eps)
         counts = _no_moe_counts()
-    return x + ffn_out, new_cache, counts
+    return x + ffn_out, counts
 
 
 def _layer(*args, **kwargs):
     """`_layer_counted` without the counts: (output, new cache entry)."""
     y, new_cache, _ = _layer_counted(*args, **kwargs)
     return y, new_cache
+
+
+# ---------------------------------------------------------------------------
+# Linear attention (the gated delta rule) and the layer pattern. A model with
+# ``config.layer_pattern`` keeps, beside the pages of its full-attention
+# layers, a RECURRENT STATE a sequence: ``{"s": [Ll, R, dk, H * dv] float32,
+# "conv": [Ll, R, (K - 1) * C]}`` over its Ll linear layers and R rows (a row
+# belongs to a serving slot), the rule's state and the last K - 1 inputs of
+# the short convolution. It travels as ``pool["rec"]``, beside the pool's
+# "k" and "v", donated and carried like them. What a call does to it is told
+# by ``rctx``: ``rows`` [B] (each batch row's state row; out of bounds drops
+# the write; None: batch row b IS state row b), ``valid`` [B, S] (a prefix of
+# real tokens a row: the rest change neither S nor the convolution's tail)
+# and ``fresh`` (True: every row starts from the zero state; [B] bool: those
+# rows do; None: every row carries on from its state).
+# ---------------------------------------------------------------------------
+
+
+def make_recurrent_state(config: ModelConfig, rows: int, dtype=None) -> dict:
+    n = config.n_layers_of("linear_attention")
+    return {
+        "s": jnp.zeros(
+            (n, rows, config.linear_key_head_dim, config.linear_value_dim), jnp.float32
+        ),
+        # [K - 1, C] a row, flat: a minor dimension of K - 1 = 3 invites a
+        # layout that pads it to a tile (42.7 x, the chip's compiler, PR 32)
+        "conv": jnp.zeros(
+            (n, rows, (config.linear_conv_kernel - 1) * config.linear_conv_dim),
+            dtype or _dtype(config),
+        ),
+    }
+
+
+def _rows_of(leaf, layer, rows):
+    """A layer's rows of a state leaf [L, R, ...]: the whole layer where the
+    batch IS the rows (``rows`` None: a slice that fuses into its reader),
+    else a gather (out of bounds clips: such a row's write drops)."""
+    if rows is None:
+        return lax.dynamic_index_in_dim(leaf, layer, 0, keepdims=False)
+    return leaf.at[layer, rows].get(mode="clip")
+
+
+def _set_rows(leaf, layer, rows, new):
+    """The inverse: one update of the layer's slab, or a scatter that drops
+    out-of-bounds rows (a scatter runs row after row: 4.5 ms of a 40-row
+    decode step over 24 layers on a v5e, PERF.md section 6, PR 32)."""
+    if rows is None:
+        return lax.dynamic_update_index_in_dim(leaf, new.astype(leaf.dtype), layer, 0)
+    return leaf.at[layer, rows].set(new.astype(leaf.dtype), mode="drop")
+
+
+def _linear_attention_block(x, lp, config, rec, layer, rctx):
+    """x + GDN(norm(x)) for [B, S, d], and the state with this layer's rows
+    written (``rec`` None: from the zero state, nothing kept)."""
+    from langstream_tpu.ops import gated_delta as gd
+    from langstream_tpu.ops.attention import note_path
+
+    b, s, _ = x.shape
+    h, dk, dv = config.linear_n_heads, config.linear_key_head_dim, config.linear_value_head_dim
+    kd, width = config.linear_key_dim, config.linear_conv_kernel
+    valid = rctx["valid"] if rctx else jnp.ones((b, s), jnp.bool_)
+    rows = rctx["rows"] if rctx else None
+    # True: every row starts from the zero state; [B] bool: those rows do
+    fresh = rctx.get("fresh") if rctx else True
+    keep_old = rec is not None and fresh is not True
+    with jax.named_scope("linear_attention.proj"):
+        a_in = rms_norm(x, lp["attn_norm"], config.rms_norm_eps)
+        qkv = quantized_matmul(a_in, lp["wqkv"])  # [B, S, q | k | v]
+        gate = quantized_matmul(a_in, lp["wg"])
+        # the two gates in float32: exp(A_log) up to 16 multiplies a's
+        # rounding into the decay (2 x 30 columns: no cost)
+        a32 = a_in.astype(jnp.float32)
+        g, beta = gd.gates(
+            a32 @ lp["wa"].astype(jnp.float32), a32 @ lp["wb"].astype(jnp.float32),
+            lp["A_log"], lp["dt_bias"], config.linear_allow_neg_eigval,
+        )
+        # padding: decay 1, write strength 0
+        g = jnp.where(valid[..., None], g, 0.0)
+        beta = jnp.where(valid[..., None], beta, 0.0)
+    with jax.named_scope("linear_attention.conv"):
+        # causal depthwise convolution over the last K - 1 inputs and these
+        tail = jnp.zeros((b, width - 1, qkv.shape[-1]), qkv.dtype)
+        if keep_old:
+            tail = _rows_of(rec["conv"], layer, rows).reshape(tail.shape)
+            if fresh is not None:
+                tail = jnp.where(fresh[:, None, None], jnp.zeros((), tail.dtype), tail)
+        window = jnp.concatenate([tail, qkv], axis=1)  # [B, K - 1 + S, C]
+        taps = lp["conv_w"].astype(jnp.float32)
+        mixed = sum(
+            window[:, i : i + s].astype(jnp.float32) * taps[i] for i in range(width)
+        )
+        mixed = jax.nn.silu(mixed)
+        if rec is not None:
+            # the last K - 1 REAL inputs: the window from the row's count on
+            if s == 1:  # a live row's window moves on by one, an idle row's stays
+                new_tail = jnp.where(valid[:, :, None], window[:, 1:], window[:, :-1])
+            else:
+                at = valid.sum(axis=1, dtype=jnp.int32)[:, None] + jnp.arange(width - 1)
+                new_tail = jnp.take_along_axis(window, at[:, :, None], axis=1)
+            rec = {**rec, "conv": _set_rows(rec["conv"], layer, rows, new_tail.reshape(b, -1))}
+        q = gd.l2norm(mixed[..., :kd].reshape(b, s, h, dk)) * dk**-0.5
+        k = gd.l2norm(mixed[..., kd : 2 * kd].reshape(b, s, h, dk))
+        v = mixed[..., 2 * kd :].reshape(b, s, h, dv)
+    with jax.named_scope("linear_attention.state"):
+        if s == 1 and rec is not None:
+            live = valid[:, 0]
+            kernel = gd.gated_delta_pallas_ok(config)
+            note_path(
+                "linear-decode", "gated_delta_update" if kernel else "jnp", config, s=1, t=0
+            )
+            args = (
+                q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], rec["s"], layer,
+                jnp.arange(b) if rows is None else rows, live,
+            )
+            if kernel:
+                o, state = gd.gated_delta_update(
+                    *args, interpret=jax.default_backend() != "tpu"
+                )
+            else:
+                o, state = gd.gated_delta_update_jnp(*args)
+            rec = {**rec, "s": state}
+            o = o[:, None]
+        else:
+            note_path("linear-prefill", "gated_delta_chunk_prefill", config, s=s, t=s)
+            s0 = jnp.zeros((b, dk, h * dv), jnp.float32)
+            if keep_old:
+                s0 = _rows_of(rec["s"], layer, rows)
+                if fresh is not None:
+                    s0 = jnp.where(fresh[:, None, None], 0.0, s0)
+            o, final = gd.gated_delta_chunk_prefill(q, k, v, g, beta, s0)
+            if rec is not None:
+                rec = {**rec, "s": _set_rows(rec["s"], layer, rows, final)}
+    with jax.named_scope("linear_attention.out"):
+        o = rms_norm(
+            o.reshape(b, s, h, dv).astype(x.dtype), lp["out_norm"], config.rms_norm_eps
+        )
+        o = o.reshape(b, s, h * dv) * jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
+        x = x + quantized_matmul(o, lp["wo"])
+    return x, rec
+
+
+def _linear_layer(x, lp, config, rec, layer, rctx):
+    """A ``linear_attention`` layer: the mixer above, then the pre-norm FFN."""
+    with jax.named_scope("linear_attention"):
+        x, rec = _linear_attention_block(x, lp, config, rec, layer, rctx)
+    y, _ = _ffn_half(x, lp, config)
+    return y, rec
+
+
+def _scan_periods(
+    params, x, sin, cos, mask, config, *, cache=None, pool=None, rec=None,
+    rctx=None, cache_positions=None, paged_table=None, page_size=0,
+):
+    """The layer loop of a model with a layer pattern: a scan over its
+    periods whose body runs the period's layers in order, each kind from a
+    stack of its own. The carry is x, the page pool of the full-attention
+    layers (``pool``: updated in place, as in `_scan_layers_inplace`) and
+    the recurrent state of the linear ones; a local cache (``cache``, the
+    admit group's temporary) rides the xs and comes back as ys. Returns
+    (x, cache or pool's {"k", "v"}, rec)."""
+    pattern = config.layer_pattern
+    per = {kind: pattern.count(kind) for kind in set(pattern)}
+    periods = config.n_periods
+    stacks = params["layers"]
+    if cache is not None:
+        cache = jax.tree.map(
+            lambda a: a.reshape(periods, per["full_attention"], *a.shape[1:]), cache
+        )
+
+    def body(carry, inputs):
+        x, kv, rec = carry
+        cache_p, p = inputs
+        at = dict.fromkeys(per, 0)
+        new_cache = []
+        for kind in pattern:
+            i = at[kind]
+            at[kind] += 1
+            layer = p * per[kind] + i
+            # ONE layer's weights, sliced where they are used: the stacks are
+            # closed over, not scanned. A period's slice [per, ...] of a
+            # scanned stack is a buffer of its own, all of a period's weights
+            # copied once more a step (a third of the decode step on a v5e,
+            # PERF.md section 6, PR 32)
+            lp = jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), stacks[kind]
+            )
+            if kind == "linear_attention":
+                x, rec = _linear_layer(x, lp, config, rec, layer, rctx)
+            elif kv is not None:
+                x, (nk, nv), _ = _layer_counted(
+                    x, lp, sin, cos, mask, config, cache_kv=(kv["k"], kv["v"]),
+                    cache_positions=cache_positions, paged_table=paged_table,
+                    page_size=page_size, layer=layer,
+                )
+                kv = {"k": nk, "v": nv}
+            else:
+                entry = None if cache_p is None else (
+                    jax.tree.map(lambda a: a[i], cache_p["k"]),
+                    jax.tree.map(lambda a: a[i], cache_p["v"]),
+                )
+                x, entry, _ = _layer_counted(
+                    x, lp, sin, cos, mask, config, cache_kv=entry,
+                    cache_positions=cache_positions,
+                )
+                new_cache.append(entry)
+        ys = None
+        if cache_p is not None:
+            ys = {
+                "k": jax.tree.map(lambda *a: jnp.stack(a), *[e[0] for e in new_cache]),
+                "v": jax.tree.map(lambda *a: jnp.stack(a), *[e[1] for e in new_cache]),
+            }
+        return (x, kv, rec), ys
+
+    (x, kv, rec), ys = lax.scan(body, (x, pool, rec), (cache, jnp.arange(periods)))
+    if cache is not None:
+        kv = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
+    return x, kv, rec
 
 
 def _embed(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array:
@@ -931,7 +1282,10 @@ def forward(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array
     mask = jnp.tril(jnp.ones((s, s), jnp.bool_))[None, :, :]
     mask = jnp.broadcast_to(mask, (b, s, s))
     x = _embed(params, tokens, config)
-    x, _, _ = _scan_layers(params, x, sin, cos, mask, config)
+    if config.layer_pattern:
+        x, _, _ = _scan_periods(params, x, sin, cos, mask, config)
+    else:
+        x, _, _ = _scan_layers(params, x, sin, cos, mask, config)
     return _unembed(params, x, config)
 
 
@@ -947,6 +1301,7 @@ def encode(
     embedding providers — EmbeddingsService.java:24-36). Bidirectional
     attention within each prompt (encoder-style pooling, not causal LM).
     """
+    _no_pattern(config, "encode")
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     sin, cos = _rope_freqs(positions, config)
@@ -969,7 +1324,10 @@ def make_kv_cache(config: ModelConfig, batch: int, max_len: int, dtype=None) -> 
     symmetric scales; ~2x less decode cache bandwidth).
     """
     dtype = dtype or _dtype(config)
-    shape = (config.n_layers, batch, config.n_kv_heads, max_len, config.resolved_head_dim)
+    shape = (
+        config.n_layers_of("full_attention"), batch, config.n_kv_heads, max_len,
+        config.resolved_head_dim,
+    )
     if config.kv_cache_dtype == "int8":
         entry = lambda: {  # noqa: E731
             "q": jnp.zeros(shape, jnp.int8),
@@ -992,6 +1350,7 @@ def prefill(
     adapter_rows: Optional[jax.Array] = None,  # [B] pool row per prompt
     moe_counts: bool = False,
     real_lengths: Optional[jax.Array] = None,  # [B]; 0 for a padding row
+    rec_rows: Optional[jax.Array] = None,  # [B] each prompt's row of ``cache["rec"]``
 ):
     """Process prompts, fill cache slots 0..len, return logits at the last
     real token of each prompt ([B, V]). With adapters, the prompt's K/V
@@ -1000,7 +1359,10 @@ def prefill(
     segment entry points return none) appends the summed MOE_COUNTS of the
     call to the returned tuple; positions past a row's
     length are the padding its `*_real` counts leave out (``real_lengths``
-    where a whole row is padding: the engine gives such a row length 1)."""
+    where a whole row is padding: the engine gives such a row length 1).
+    A model with recurrent layers takes its state as ``cache["rec"]``
+    (`join_rec`) and hands it back there, each prompt's final state, taken at
+    its true length, written to row ``rec_rows[b]`` (out of bounds: dropped)."""
     b, s = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     sin, cos = _rope_freqs(positions, config)
@@ -1011,12 +1373,23 @@ def prefill(
     mask = kv_pos <= q_pos[:, :, None]
     mask = mask & (kv_pos < s)
     x = _embed(params, tokens, config)
-    x, cache, counts = _scan_layers(
-        params, x, sin, cos, mask, config, cache=cache, cache_positions=positions,
-        lora=lora, adapter_rows=adapter_rows,
-        token_valid=positions
-        < (lengths if real_lengths is None else real_lengths)[:, None],
-    )
+    if config.layer_pattern:
+        # the recurrent state rides in with the cache and out with it, as it
+        # rides with the page pool: ``cache["rec"]`` (`join_rec`)
+        cache, rec = split_rec(cache)
+        rctx = {"rows": rec_rows, "valid": positions < lengths[:, None], "fresh": True}
+        x, cache, rec = _scan_periods(
+            params, x, sin, cos, mask, config, cache=cache, rec=rec, rctx=rctx,
+            cache_positions=positions,
+        )
+        cache, counts = join_rec(cache, rec), _no_moe_counts()
+    else:
+        x, cache, counts = _scan_layers(
+            params, x, sin, cos, mask, config, cache=cache, cache_positions=positions,
+            lora=lora, adapter_rows=adapter_rows,
+            token_valid=positions
+            < (lengths if real_lengths is None else real_lengths)[:, None],
+        )
     last = jnp.clip(lengths - 1, 0, s - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [B, D]
     logits = _unembed(params, x_last[:, None, :], config)[:, 0]
@@ -1032,6 +1405,7 @@ def decode_step(
     config: ModelConfig,
 ) -> tuple[jax.Array, KVCache]:
     """One decode step for every active slot → logits [B, V], updated cache."""
+    _no_pattern(config, "decode_step")
     b = tokens.shape[0]
     t = cache_width(cache)
     pos2 = positions[:, None]  # [B, 1]
@@ -1086,10 +1460,23 @@ def paged_decode_step_inplace(
     sin, cos = _rope_freqs(pos2, config)
     mask = _paged_mask(table, page_size, pos2)
     x = _embed(params, tokens[:, None], config)
-    x, pool, counts = _scan_layers_inplace(
-        params, x, sin, cos, mask, config, pool, pos2, table, page_size,
-        lora=lora, adapter_rows=adapter_rows,
-    )
+    if config.layer_pattern:
+        # batch row b steps state row b. A row whose table maps nothing, or
+        # that has stepped past its pages, is idle: its state stays as it is
+        kv, rec = split_rec(pool)
+        num_pages = (kv["k"]["q"] if isinstance(kv["k"], dict) else kv["k"]).shape[1]
+        live = _paged_lengths(table, positions, page_size, num_pages) > positions
+        x, kv, rec = _scan_periods(
+            params, x, sin, cos, mask, config, pool=kv, rec=rec,
+            rctx={"rows": None, "valid": live[:, None], "fresh": None},
+            cache_positions=pos2, paged_table=table, page_size=page_size,
+        )
+        pool, counts = join_rec(kv, rec), _no_moe_counts()
+    else:
+        x, pool, counts = _scan_layers_inplace(
+            params, x, sin, cos, mask, config, pool, pos2, table, page_size,
+            lora=lora, adapter_rows=adapter_rows,
+        )
     logits = _unembed(params, x, config)[:, 0]
     return (logits, pool, counts) if moe_counts else (logits, pool)
 
@@ -1114,6 +1501,7 @@ def paged_verify_step_inplace(
     length hold stale draft K/V, which is safe because positions advance
     only past ACCEPTED tokens and the next dispatch overwrites the stale
     page columns before any causal mask can reach them."""
+    _no_pattern(config, "paged_verify_step_inplace")
     b, s = tokens.shape
     pos = positions[:, None] + jnp.arange(s)[None, :]
     sin, cos = _rope_freqs(pos, config)
@@ -1138,6 +1526,7 @@ def paged_prefill_segment_inplace(
     page_size: int,
     lora: Optional[dict] = None,
     adapter_rows: Optional[jax.Array] = None,
+    state_rows: Optional[jax.Array] = None,  # [B] each row's recurrent state row
 ) -> tuple[jax.Array, KVCache]:
     """Chunked/suffix prefill straight into the slot's pages: process one
     segment of a longer prompt against pages whose columns [0, offsets) were
@@ -1157,10 +1546,25 @@ def paged_prefill_segment_inplace(
     sin, cos = _rope_freqs(positions, config)
     mask = _paged_mask(table, page_size, positions)
     x = _embed(params, tokens, config)
-    x, pool, _ = _scan_layers_inplace(
-        params, x, sin, cos, mask, config, pool, positions, table, page_size,
-        lora=lora, adapter_rows=adapter_rows,
-    )
+    if config.layer_pattern:
+        # the recurrent state carries over from the row's earlier segments;
+        # a segment at offset 0 starts it from zero
+        kv, rec = split_rec(pool)
+        rctx = {
+            "rows": state_rows,
+            "valid": jnp.arange(s)[None, :] < seg_lengths[:, None],
+            "fresh": offsets == 0,
+        }
+        x, kv, rec = _scan_periods(
+            params, x, sin, cos, mask, config, pool=kv, rec=rec, rctx=rctx,
+            cache_positions=positions, paged_table=table, page_size=page_size,
+        )
+        pool = join_rec(kv, rec)
+    else:
+        x, pool, _ = _scan_layers_inplace(
+            params, x, sin, cos, mask, config, pool, positions, table, page_size,
+            lora=lora, adapter_rows=adapter_rows,
+        )
     last = jnp.clip(seg_lengths - 1, 0, s - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     logits = _unembed(params, x_last[:, None, :], config)[:, 0]
@@ -1189,8 +1593,9 @@ def paged_insert_cache(
             loc.astype(pl_entry.dtype), mode="drop"
         )
 
+    kv, rec = split_rec(pool)
     with jax.named_scope("kv_pool.write"):
-        return jax.tree.map(put, pool, local_cache)
+        return join_rec(jax.tree.map(put, kv, local_cache), rec)
 
 
 # ---------------------------------------------------------------------------

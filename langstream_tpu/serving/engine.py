@@ -37,12 +37,14 @@ from jax import lax
 from langstream_tpu.models.configs import GenerationOptions, ModelConfig
 from langstream_tpu.models.transformer import (
     MOE_COUNTS,
+    join_rec,
     make_kv_cache,
     paged_decode_step_inplace,
     paged_insert_cache,
     paged_prefill_segment_inplace,
     paged_verify_step_inplace,
     prefill,
+    split_rec,
 )
 from langstream_tpu.parallel import spmd_serving as wire
 from langstream_tpu.serving.faultinject import FaultInjector
@@ -465,7 +467,7 @@ def _paged_verify_chunk(
 def _paged_segment_and_sample(
     params, tokens, offsets, seg_lengths, pool, table, key, temp, top_k, top_p,
     config, page_size, lora=None, arows=None, dfa=None, g=None,
-    state_dev=None, state_slot=None, state0=None,
+    state_dev=None, state_slot=None, state0=None, state_rows=None,
 ):
     """One chunked/suffix prefill segment straight into the slot's pages +
     a sample of its last-token logits: aliased prefix pages are already
@@ -481,7 +483,7 @@ def _paged_segment_and_sample(
     without a host round trip."""
     logits, pool = paged_prefill_segment_inplace(
         params, tokens, offsets, seg_lengths, pool, table, config, page_size,
-        lora=lora, adapter_rows=arows,
+        lora=lora, adapter_rows=arows, state_rows=state_rows,
     )
     first, key, s1 = _sample_first(
         logits, key, temp, top_k, top_p, dfa, g, state0, config.vocab_size
@@ -489,6 +491,13 @@ def _paged_segment_and_sample(
     if s1 is not None:
         state_dev = state_dev.at[state_slot].set(s1[0], mode="drop")
     return first, pool, key, state_dev
+
+
+def _on_pages(fn, pool, *rest):
+    """``fn`` over the pool's PAGE leaves ("k", "v"): a recurrent state
+    beside them ("rec", a row a slot, no page axis) passes through."""
+    kv, rec = split_rec(pool)
+    return join_rec(jax.tree.map(fn, kv, *rest), rec)
 
 
 @functools.partial(jax.jit, donate_argnames=("pool",))
@@ -503,7 +512,7 @@ def _page_copy(pool, src, dst):
         row = lax.dynamic_index_in_dim(a, src, 1, keepdims=False)
         return a.at[:, dst].set(row, mode="drop")
 
-    return jax.tree.map(put, pool)
+    return _on_pages(put, pool)
 
 
 @functools.partial(jax.jit, donate_argnames=("pool",))
@@ -516,7 +525,7 @@ def _page_zero(pool, pages):
     def zero(a):
         return a.at[:, pages].set(jnp.zeros((), a.dtype), mode="drop")
 
-    return jax.tree.map(zero, pool)
+    return _on_pages(zero, pool)
 
 
 @jax.jit
@@ -531,7 +540,7 @@ def _page_snapshot(pool, src):
     def take(a):
         return lax.dynamic_index_in_dim(a, src, 1, keepdims=False)
 
-    return jax.tree.map(take, pool)
+    return jax.tree.map(take, split_rec(pool)[0])
 
 
 @functools.partial(jax.jit, donate_argnames=("pool",))
@@ -544,7 +553,7 @@ def _page_restore(pool, block, dst):
     def put(a, b):
         return a.at[:, dst].set(b.astype(a.dtype), mode="drop")
 
-    return jax.tree.map(put, pool, block)
+    return _on_pages(put, pool, block)
 
 
 def _make_paged_admit_group(mesh=None):
@@ -586,12 +595,19 @@ def _make_paged_admit_group(mesh=None):
             local_cache = constrain_serving_local_cache(
                 local_cache, config.n_kv_heads, mesh
             )
+        kv, rec = split_rec(pool)
         logits, local_cache, moe = prefill(
-            params, tokens, lengths, local_cache, config,
+            # a recurrent model's state rides with the local cache: a row of
+            # it is its slot's, written from the zero state run over the
+            # prompt's TRUE length
+            params, tokens, lengths, join_rec(local_cache, rec), config,
             lora=lora, adapter_rows=arows, moe_counts=True,
             # a padding row's slot is out of bounds: none of it is real
             real_lengths=jnp.where(slots < tokens_dev.shape[0], lengths, 0),
+            rec_rows=slots,
         )
+        local_cache, rec = split_rec(local_cache)
+        pool = join_rec(kv, rec)
         first, key, s1 = _sample_first(
             logits, key, temps, top_ks, top_ps, dfa, g_rows, g_state0,
             config.vocab_size,
@@ -1012,6 +1028,31 @@ class ServingEngine:
         the KV cache is sharded to match (kv heads on "model") so every
         decode step partitions over ICI with XLA-inserted collectives —
         one psum per layer, the Megatron schedule."""
+        if config.is_recurrent:
+            # a recurrent state is overwritten in place: it cannot be
+            # aliased between slots (prefix reuse), has no spill, migrate or
+            # durable format, cannot be rolled back past a rejected draft,
+            # takes no adapter terms and is not sharded. Refused at build,
+            # by the option's name (docs/SERVING.md §11)
+            refused = {
+                "prefix_cache": prefix_cache is True
+                or str(prefix_cache).lower() in ("auto", "on", "true", "1"),
+                "host_kv_fraction": float(host_kv_fraction) > 0,
+                "migrate_staging": bool(migrate_staging),
+                "durable_dir": bool(durable_dir),
+                "speculation": speculation is True
+                or str(speculation).lower() in ("auto", "on", "true", "1"),
+                "adapters": bool(adapters),
+                "mesh": mesh is not None,
+                "spmd": spmd is not None,
+                "ring_axis": config.ring_axis is not None,
+            }
+            asked = [name for name, on in refused.items() if on]
+            if asked:
+                raise ValueError(
+                    f"{config.name} has recurrent layers: {', '.join(asked)} "
+                    "cannot be used with a recurrent state"
+                )
         if mesh is not None:
             # the Pallas kernels cannot be partitioned by GSPMD: they read
             # the mesh off the (static) config and shard_map themselves
@@ -2226,6 +2267,10 @@ class ServingEngine:
             "kv-pages-total": self._pagepool.num_pages,
             "kv-pages-in-use": self._pagepool.pages_in_use,
             "kv-bytes-per-page": self._pagepool.bytes_per_page,
+            # the recurrent state beside the pages: one row a slot (zeros
+            # for a model without recurrent layers)
+            "recurrent-state-bytes": self._pagepool.state_bytes_total,
+            "recurrent-state-rows-in-use": self._pagepool.state_rows_in_use,
             "kv-page-alias-rate": round(
                 self._pagepool.aliased_pages_total
                 / max(1, self._pagepool.reserved_pages_total),
@@ -4008,6 +4053,8 @@ class ServingEngine:
             real_tokens=sum(len(r.prompt_tokens) for _, r in group),
             computed_tokens=n_pad * width,
             trace_ids=[r.trace_id for _, r in group],
+            # rows of recurrent state the group writes: one a real prompt
+            **({"state_rows_written": len(group)} if self.config.is_recurrent else {}),
         )
         seq = self._dispatch_seq
         with jax.profiler.TraceAnnotation("engine.admit_group", seq=seq):
@@ -4486,6 +4533,10 @@ class ServingEngine:
         kw = self._segment_agentic_kwargs(
             agentic_rows, idx if final else self.max_batch
         )
+        if self.config.is_recurrent:
+            # the slot's row of recurrent state carries from segment to
+            # segment (an out-of-bounds ``idx``, the warm-up's, drops)
+            kw["state_rows"] = jnp.asarray([idx], jnp.int32)
         first, pool.dev, self._key, state_dev = _paged_segment_and_sample(
             self.params,
             jnp.asarray(tokens),
@@ -5479,6 +5530,11 @@ class ServingEngine:
                 "KV-page migration is not on the SPMD wire yet (the bind/"
                 "restore dispatches would need follower replay)"
             )
+        if self.config.is_recurrent:
+            raise MigrationError(
+                "KV-page migration carries pages only: a recurrent state "
+                "row has no wire format yet"
+            )
         reply: "queue.SimpleQueue" = queue.SimpleQueue()
         self._migrate_cmds.put((kind, payload, reply))
         try:
@@ -5915,6 +5971,9 @@ class ServingEngine:
             kv_tokens_read=self._kv_tokens_read(live, steps),
             clean=clean, pipelined=pipelined,
             kv_pages_visited=pages_visited, kv_rows_written=rows_written,
+            # (row, step) pairs whose recurrent state is updated, a linear
+            # layer: the pairs that write a K/V row, idle rows move none
+            **({"state_rows": rows_written} if self.config.is_recurrent else {}),
         )
         with jax.profiler.TraceAnnotation(
             "engine.decode_chunk", seq=self._dispatch_seq, steps=steps

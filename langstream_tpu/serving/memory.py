@@ -45,6 +45,11 @@ class ServingMemoryPlan:
     # Sized by pages_for_fraction: every slot's max_seq_len plus the
     # prefix-cache-fraction alias headroom.
     page_pool_bytes: int = 0
+    # a model with recurrent layers keeps, beside the pages of its
+    # full-attention layers, one ROW of recurrent state a slot (the delta
+    # rule's float32 state and the short convolution's tail, over its
+    # linear layers): max_batch rows, whatever the sequences' lengths
+    recurrent_state_bytes: int = 0
     # fused-iteration peak: with overlapped prefill–decode scheduling the
     # admission local cache (prefill_batch rows × the largest bucket width)
     # is live WHILE a decode chunk runs.
@@ -108,6 +113,7 @@ class ServingMemoryPlan:
             + self.workspace_bytes
             + self.fused_prefill_bytes
             + self.page_pool_bytes
+            + self.recurrent_state_bytes
             + self.verify_chunk_bytes
             + self.adapter_pool_bytes
             + self.grammar_pool_bytes
@@ -133,6 +139,8 @@ class ServingMemoryPlan:
             parts.append(f"adapter-pool {self.adapter_pool_bytes / gib:.2f}GiB + ")
         if self.grammar_pool_bytes:
             parts.append(f"grammar-pool {self.grammar_pool_bytes / gib:.2f}GiB + ")
+        if self.recurrent_state_bytes:
+            parts.append(f"recurrent-state {self.recurrent_state_bytes / gib:.2f}GiB + ")
         return "".join(parts)
 
     def _weight_load_suffix(self) -> str:
@@ -259,8 +267,9 @@ def plan_serving_memory(
         max_batch, max_seq_len, page_size, page_fraction
     )
     pool_shape = jax.eval_shape(
-        lambda: make_page_pool(config, num_pages, page_size)
+        lambda: make_page_pool(config, num_pages, page_size, state_rows=max_batch)
     )
+    state_bytes = _tree_bytes(pool_shape.pop("rec", None))
     pool_bytes = _tree_bytes(pool_shape)
     host_spill_bytes = 0
     if host_kv_fraction > 0:
@@ -292,7 +301,13 @@ def plan_serving_memory(
         else None
     )
     key = jax.ShapeDtypeStruct((2,), jax.numpy.uint32)
-    if quantized_weights:
+    if quantized_weights and config.layer_pattern:
+        from langstream_tpu.models.quant import quantize_params
+
+        params_shape = jax.eval_shape(
+            lambda k: quantize_params(init_params(config, k), config), key
+        )
+    elif quantized_weights:
         params_shape = jax.eval_shape(
             lambda k: init_random_quantized_params(config, k), key
         )
@@ -303,6 +318,7 @@ def plan_serving_memory(
         workspace_bytes=workspace_bytes,
         fused_prefill_bytes=_tree_bytes(fused_shape) if fused_shape else 0,
         page_pool_bytes=pool_bytes,
+        recurrent_state_bytes=state_bytes,
         host_spill_bytes=host_spill_bytes,
         migrate_staging_bytes=migrate_staging_bytes,
         weight_load_staging_bytes=max(0, int(weight_load_staging)),

@@ -163,10 +163,23 @@ class PagePool:
         self.max_batch = int(max_batch)
         self.table_len = table_len_for(max_seq_len, page_size)
         self.oob = self.num_pages  # sentinel: scatters drop, gathers clamp
-        self.dev = make_page_pool(config, self.num_pages, self.page_size)
-        self.bytes_total = sum(
-            leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(self.dev)
+        # a model with recurrent layers: ``dev["rec"]``, one row of
+        # recurrent state a SLOT beside the pages (models/transformer
+        # `make_recurrent_state`). The row is the slot's index: `reserve`
+        # hands it out with the pages and `free_slot` takes both back. No
+        # dispatch zeroes a freed row: the next admission writes it whole
+        # from the zero state (the admit group) or starts its first
+        # segment from zero, so nothing of the old state is ever read
+        self.dev = make_page_pool(
+            config, self.num_pages, self.page_size, state_rows=self.max_batch
         )
+
+        def nbytes(tree) -> int:
+            return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+        self.state_bytes_total = nbytes(self.dev.get("rec"))
+        self.state_bytes_per_row = self.state_bytes_total // self.max_batch
+        self.bytes_total = nbytes(self.dev) - self.state_bytes_total
         self.bytes_per_page = self.bytes_total // self.num_pages
         self.tables = np.full(
             (self.max_batch, self.table_len), self.oob, np.int32
@@ -204,6 +217,11 @@ class PagePool:
     @property
     def pages_in_use(self) -> int:
         return self.num_pages - len(self._free)
+
+    @property
+    def state_rows_in_use(self) -> int:
+        """Rows of recurrent state bound to a slot (0 for a model without)."""
+        return len(self._owned) if self.state_bytes_total else 0
 
     @property
     def shared_pages(self) -> int:
@@ -300,7 +318,9 @@ class PagePool:
         their index)."""
         from langstream_tpu.models.transformer import make_page_pool
 
-        self.dev = make_page_pool(self.config, self.num_pages, self.page_size)
+        self.dev = make_page_pool(
+            self.config, self.num_pages, self.page_size, state_rows=self.max_batch
+        )
         self.tables[:] = self.oob
         self._refs[:] = 0
         self._free = list(range(self.num_pages - 1, -1, -1))

@@ -1,0 +1,34 @@
+"""The benchmark's families, guarded by tier-1: the fast cases of
+`benchmark/tests/test_families.py` (Mistral, Mixtral: the fields each
+configuration maps onto, the seeded trees bit-equal to what they were, what
+the program's block cannot express refused, every family's two files) and of
+`benchmark/tests/test_olmo_hybrid_family.py` (Olmo-Hybrid: the README's
+contract, the mapping, the refusals, the seeded tree, the update's cost, the
+state probe) run here as they stand there. The check's verdicts (an engine a case) stay with the
+harness's own suite, run by hand: `python -m pytest benchmark/tests`."""
+
+import importlib.util
+import sys
+from pathlib import Path
+
+BENCH = Path(__file__).resolve().parents[1] / "benchmark"
+for path in (str(BENCH.parent), str(BENCH)):
+    if path not in sys.path:
+        sys.path.insert(0, path)
+
+# the cases that build an engine and run the whole check: minutes, by hand
+BY_HAND = ("test_sound_system_passes_with_room", "test_known_fault_fails_by_a_number")
+
+
+def _cases(file: str) -> dict:
+    spec = importlib.util.spec_from_file_location(f"benchmark_{file}", BENCH / "tests" / f"{file}.py")
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return {
+        name: case for name, case in vars(module).items()
+        if name.startswith("test_") and name not in BY_HAND
+    }
+
+
+globals().update(_cases("test_families"))
+globals().update(_cases("test_olmo_hybrid_family"))

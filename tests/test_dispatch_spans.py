@@ -433,7 +433,31 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
         texts[name] = lowered_scopes(admit, params, pool) | lowered_scopes(
             decode, params, pool
         )
-    assert set(T.SCOPES) <= texts["dense"] | texts["moe"]
+    # a model with a layer pattern: the linear layers' scopes, prefill and decode
+    hybrid = MODEL_PRESETS["tiny-hybrid-test"]
+    hybrid_params = T.init_params(hybrid, jax.random.PRNGKey(2))
+    hybrid_pool = T.make_page_pool(hybrid, 8, page, state_rows=b)
+
+    def hybrid_admit(params, pool):
+        kv, rec = T.split_rec(pool)
+        return T.prefill(
+            params, tokens, lengths, T.join_rec(T.make_kv_cache(hybrid, b, 32), rec), hybrid,
+            rec_rows=jnp.arange(b),
+        )
+
+    def hybrid_decode(params, pool):
+        return E._paged_decode_chunk(
+            params, tokens[:, 0], lengths, pool, table, key, ones, zeros, ones, 2, hybrid, page,
+        )
+
+    texts["hybrid"] = lowered_scopes(hybrid_admit, hybrid_params, hybrid_pool) | lowered_scopes(
+        hybrid_decode, hybrid_params, hybrid_pool
+    )
+    linear = {s for s in T.SCOPES if s.startswith("linear_attention")}
+    assert len(linear) == 5 and linear <= texts["hybrid"]
+    assert {"attention", "ffn", "kv_pool.write"} <= texts["hybrid"]  # its full layers
+    assert not linear & (texts["dense"] | texts["moe"])
+    assert set(T.SCOPES) <= texts["dense"] | texts["moe"] | texts["hybrid"]
     assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
     assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",
             "moe_ffn.combine"} <= texts["moe"] and "ffn" not in texts["moe"]

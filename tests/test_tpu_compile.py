@@ -126,6 +126,27 @@ CELLS = {
 }
 
 
+OLMO = MODEL_PRESETS["olmo-hybrid-7b"]
+
+
+def _delta_update(config, batch, layers):
+    """(the decode step's recurrent-state update, its arguments' shapes):
+    q and k [B, H, dk], v [B, H, dv], the two gates [B, H], the whole state
+    [L, rows, dk, H * dv], the layer, each row's state row, and who is live."""
+    from langstream_tpu.ops import gated_delta as gd
+
+    h, dk, dv = config.linear_n_heads, config.linear_key_head_dim, config.linear_value_head_dim
+    f32 = lambda *s: SDS(s, jnp.float32)  # noqa: E731
+    return (
+        lambda q, k, v, g, beta, state, layer, rows, live: gd.gated_delta_update(
+            q, k, v, g, beta, state, layer, rows, live
+        ),
+        (f32(batch, h, dk), f32(batch, h, dk), f32(batch, h, dv), f32(batch, h), f32(batch, h),
+         f32(layers, batch, dk, h * dv), SDS((), jnp.int32), SDS((batch,), jnp.int32),
+         SDS((batch,), jnp.bool_)),
+    )
+
+
 CASES = {
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
@@ -143,6 +164,12 @@ CASES = {
     "gemma-paged-kv-write": _kv_write(GEMMA, BATCH, PAGES, POOL_LAYERS),
     "llama-paged-kv-write": _kv_write(LLAMA, BATCH, PAGES, POOL_LAYERS),
     **{f"{cell}-paged-kv-write": _kv_write(LLAMA, **sizes) for cell, sizes in CELLS.items()},
+    # the Olmo-Hybrid cell (40 slots x 10 pages, 400 pages, 8 full layers of
+    # 30 kv heads in groups of ONE; 24 linear layers of 30 x 96 x 192)
+    "olmodrain40x10-paged-decode": _paged(OLMO, False, batch=40, table=10, pages=400, layers=8),
+    "olmodrain40x10-paged-kv-write": _kv_write(OLMO, batch=40, pages=400, layers=8),
+    **{f"olmo-prefill-{s}": _prefill(OLMO, s) for s in (128, 256, 384)},
+    "olmodrain40-gated-delta-update": _delta_update(OLMO, 40, 24),
 }
 
 
@@ -164,6 +191,7 @@ def _kernel_of(case: str) -> str:
         "paged-decode": "ragged_paged_decode_attention",
         "paged-decode-int8": "ragged_paged_decode_attention_int8",
         "paged-kv-write": "paged_kv_write",
+        "gated-delta-update": "gated_delta_update",
     }[kind]
 
 
@@ -176,6 +204,24 @@ def test_kernel_compiles_for_v5e(v5e, case):
     # the kernel's instruction is named after it, which is how a profile's
     # device events are told apart (`_int8` is not a suffix of the match)
     assert re.search(rf"%{_kernel_of(case)}(\.\d+)? = ", text), _kernel_of(case)
+
+
+def test_chunked_delta_rule_compiles_for_v5e_under_its_scope(v5e):
+    """`gated_delta_chunk_prefill` is matrix products in XLA, no Pallas
+    kernel: it compiles at the cell's widest admit group (8 x 256) and its
+    operations carry the scope a profile finds them by."""
+    from langstream_tpu.ops import gated_delta as gd
+
+    b, s, h, dk, dv = 8, 256, OLMO.linear_n_heads, OLMO.linear_key_head_dim, OLMO.linear_value_head_dim
+    f32 = lambda *shape: SDS(shape, jnp.float32)  # noqa: E731
+    args = (f32(b, s, h, dk), f32(b, s, h, dk), f32(b, s, h, dv), f32(b, s, h), f32(b, s, h),
+            f32(b, dk, h * dv))
+    compiled = jax.jit(gd.gated_delta_chunk_prefill).lower(
+        *_placed(args, SingleDeviceSharding(v5e[0]))
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and "gated_delta_chunk_prefill/" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 def test_model_sharded_paged_decode_compiles_for_four_chips(v5e):
@@ -319,6 +365,50 @@ def test_paged_program_holds_no_per_layer_pool_entry(v5e, monkeypatch, program, 
         _assert_decode_write(text, pool_shapes, bf16_pool=kv == "model")
     else:
         assert "tpu_custom_call" not in text  # verify and segments: jnp
+
+
+# olmo-hybrid's linear layers (heads of 96 x 192, the folded state whole
+# lanes: 8 x 192 = 12 x 128) and its full layers' attention, narrow elsewhere
+HYBRID_CFG = dataclasses.replace(
+    OLMO, name="olmo-hybrid-narrow", vocab_size=2048, d_model=1024, d_ff=2048, n_layers=8,
+    n_heads=8, n_kv_heads=8, head_dim=128, linear_n_heads=8,
+)
+
+
+def test_recurrent_decode_chunk_holds_one_copy_of_the_state(v5e, monkeypatch):
+    """The recurrent state is donated and carried through the decode
+    chunk's step scan like the pool: the compiled chunk holds ONE copy of
+    it (the argument, aliased to the result), forms no per-layer entry of it
+    and updates it by the kernel where it lies (a second 2.1 GB copy would
+    not fit beside the Olmo-Hybrid cell's weights and pool)."""
+    config = HYBRID_CFG
+    from langstream_tpu.models.transformer import init_params, make_page_pool
+    from langstream_tpu.serving import engine as E
+
+    b = STEP_BATCH
+    params = jax.eval_shape(lambda k: init_params(config, k), SDS((2,), jnp.uint32))
+    pool = jax.eval_shape(lambda: make_page_pool(config, STEP_PAGES, PAGE, state_rows=b))
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    args = (params, i32(b), i32(b), pool, i32(b, STEP_TABLE), SDS((2,), jnp.uint32),
+            f32(b), i32(b), f32(b))
+    one_chip = SingleDeviceSharding(v5e[0])
+    compiled = _compile_as_on_chip(
+        monkeypatch, E._paged_decode_chunk, _placed(args, one_chip), (4, config, PAGE)
+    )
+    text = compiled.as_text()
+    layers, dk, hv = config.n_layers_of("linear_attention"), config.linear_key_head_dim, config.linear_value_dim
+    state, entry = f"f32[{layers},{b},{dk},{hv}]", f"f32[{b},{dk},{hv}]"
+    assert state in text and re.search(r"%gated_delta_update(\.\d+)? = ", text)
+    assert A.attention_paths()["linear-decode[s=1,t=0]"] == "gated_delta_update"
+    assert not re.search(rf"= {re.escape(state)}\S* copy\(", text)
+    assert all(entry not in line.replace(state, "") for line in text.splitlines())
+    # the convolution's tail beside it: flat rows, no copy either
+    conv = f"[{layers},{b},{(config.linear_conv_kernel - 1) * config.linear_conv_dim}]"
+    assert not re.search(rf"= \w+{re.escape(conv)}\S* copy\(", text)
+    memory = compiled.memory_analysis()
+    state_bytes = layers * b * dk * hv * 4
+    assert memory.alias_size_in_bytes >= state_bytes  # updated in place
+    assert memory.temp_size_in_bytes < state_bytes  # and no second copy among the temporaries
 
 
 @pytest.mark.parametrize("kv", ["model", "int8"])
