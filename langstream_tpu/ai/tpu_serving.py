@@ -476,6 +476,9 @@ class _EngineHolder:
             self.config.get("prefill-buckets", (32, 64, 128, 256, 512, 1024, 2048))
         )
         max_batch = int(self.config.get("max-batch", 8))
+        # rows of the LARGEST admission group (default 8): a lone prompt
+        # rides a group of ONE row, an expert model always prefill-batch
+        # rows (engine.admit_rungs, docs/SERVING.md §11)
         prefill_batch = self.config.get("prefill-batch")
         max_seq = int(self.config.get("max-seq-len", min(2048, mc.max_seq_len)))
         spmd = None

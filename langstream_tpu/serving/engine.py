@@ -556,6 +556,20 @@ def _page_restore(pool, block, dst):
     return _on_pages(put, pool, block)
 
 
+def admit_rungs(prefill_batch: int) -> tuple[int, ...]:
+    """The row counts an admission group may compile at: one row, and
+    ``prefill_batch``. A group dispatches at the smallest rung that holds its
+    real rows, so a lone prompt computes one row of its bucket and not
+    ``prefill_batch``; the row count is a compiled shape of ``admit_group``
+    and nothing else. A constant of the code: the warm-up compiles every
+    (rung, width), and each compiled shape costs a replica 1.4–1.9 s of
+    every warm start and 11–14 s of a cold one (v5e, PERF.md §6, PR 33),
+    which is why the powers of two between the two rungs are not here:
+    groups of one prompt are what open-loop arrivals and a drain's freed
+    slots make, and they carry nearly all of the padding."""
+    return (1, prefill_batch) if prefill_batch > 1 else (1,)
+
+
 def _make_paged_admit_group(mesh=None):
     """Factory for the FUSED admission step: local-cache zeros + batched
     prefill + first-token sample + PAGE scatter + every decode-chain
@@ -947,7 +961,8 @@ class _DurableWorker:
 class ServingEngine:
     """One engine per model per agent replica; owns the device loop."""
 
-    # default rows per prefill call — fixed so each width bucket compiles ONCE
+    # default rows of the LARGEST admission group (admit_rungs has the
+    # smaller shapes a group of fewer prompts dispatches at)
     PREFILL_BATCH = 8
 
     # lock discipline registry (analysis pass `locks`, docs/ANALYSIS.md):
@@ -1304,10 +1319,22 @@ class ServingEngine:
         # total steps of the currently in-flight (dispatched, unfetched)
         # chunks, summed over the pipeline
         self._inflight_steps = 0
-        # rows per prefill dispatch: bigger = fewer serial prefill calls
-        # under a burst, at the price of one compile per (prefill_batch,
-        # width) shape
+        # rows of the LARGEST prefill dispatch: bigger = fewer serial
+        # prefill calls under a burst. A group dispatches at the smallest
+        # rung that holds it (a lone prompt at ONE row), at the price of one
+        # compile per (rung, width) shape in the warm-up. An expert model
+        # keeps the one shape: moe_ffn sizes every expert's capacity from
+        # rows × width, padding included, and hands it out real rows first,
+        # so padding rows buy the real ones their capacity and a smaller
+        # group would drop assignments the full one keeps (ROADMAP S5 lifts
+        # this)
         self.prefill_batch = int(prefill_batch or self.PREFILL_BATCH)
+        self._admit_rungs = (
+            (self.prefill_batch,) if config.is_moe
+            else admit_rungs(self.prefill_batch)
+        )
+        # admission groups dispatched at each rung (stats "admit-group-rows")
+        self._admit_group_rows = dict.fromkeys(self._admit_rungs, 0)
         # fused prefill–decode scheduling: every iteration dispatches a
         # token-budgeted slice of pending prefill work (admission groups +
         # chunked-prefill segments) IMMEDIATELY followed by the decode chunk
@@ -2260,6 +2287,9 @@ class ServingEngine:
             # — tests and the metrics exporter consume it by this exact
             # name; do not "fix" the spelling
             "compiled_programs": len(self._programs),
+            # admission groups dispatched at each row count of the ladder
+            # (admit_rungs): how far groups shrink to the prompts they hold
+            "admit-group-rows": dict(self._admit_group_rows),
             "decode-step-ms": round(self._step_time_ema_s * 1e3, 3),
             "hbm-gbps-decode": self._achieved_hbm_gbps(),
             # the page pool, the engine's only KV state
@@ -2529,7 +2559,8 @@ class ServingEngine:
         """Precompile the decode-phase program surface before the first
         request: ONE decode (or verify) program for every sequence-length
         mix, plus the batch-1 segment family
-        (warm suffixes + long-prompt chunks, one per bucket width), the
+        (long-prompt chunks at the largest bucket width and, with a prefix
+        index, warm suffixes at every bucket width), the
         copy-on-write page copy, and the quarantine page-zero. Every
         throwaway dispatch runs against all-out-of-bounds tables/indices:
         writes drop, reads clamp into masked columns, so engine state is
@@ -2548,7 +2579,14 @@ class ServingEngine:
                 # the TTFT-shrunk chunk is its own (steps,) program, but
                 # only the legacy (overlap off) scheduler dispatches it
                 self._dev_decode(floor, []).block_until_ready()
-        for ws in self.prefill_buckets:
+        # a long prompt's chunks run at the LARGEST bucket width; the
+        # narrower widths serve only warm suffixes behind a prefix hit,
+        # which an engine without a prefix index never makes
+        segment_widths = (
+            self.prefill_buckets if self._prefix_index is not None
+            else self.prefill_buckets[-1:]
+        )
+        for ws in segment_widths:
             if self._stop.is_set():
                 return
             first = self._dev_paged_segment(
@@ -2583,12 +2621,14 @@ class ServingEngine:
             "segment widths, page-copy, page-zero",
             "verify" if self._spec_enabled else "decode",
             self.spec_tokens + 1 if self._spec_enabled else self.decode_chunk,
-            len(self.prefill_buckets),
+            len(segment_widths),
         )
 
     def _warmup_prefill_buckets(self) -> None:
-        """Precompile one admission program per prefill bucket width so the
-        fused iterations' prefill halves quantize into the warmed set too —
+        """Precompile one admission program per (rung, prefill bucket
+        width), every shape ``_prefill_group`` can dispatch (``admit_rungs``;
+        an expert model has the one rung), so the fused iterations' prefill
+        halves quantize into the warmed set too —
         before this, the first admission wave at each width compiled
         admit_group MID-TRAFFIC (the same 15-23s stall class the decode
         ladder warmup closed; the gateway bench only dodged it because its
@@ -2597,19 +2637,21 @@ class ServingEngine:
         state is untouched except the PRNG key, which advances before any
         request is served. SPMD: the family replays whole (OP_WARMUP) so
         followers warm and key-advance identically."""
-        n_pad = self.prefill_batch
+        started = time.monotonic()
         for width in self.prefill_buckets:
-            if self._stop.is_set():
-                return
-            tokens = np.zeros((n_pad, width), np.int32)
-            lengths = np.ones(n_pad, np.int32)
-            temps = np.zeros(n_pad, np.float32)
-            top_ks = np.zeros(n_pad, np.int32)
-            top_ps = np.ones(n_pad, np.float32)
-            slots = np.full(n_pad, self.max_batch, np.int32)  # all dropped
-            self._dev_prefill(
-                width, tokens, lengths, temps, top_ks, top_ps, slots
-            ).block_until_ready()
+            for n_pad in self._admit_rungs:
+                if self._stop.is_set():
+                    return
+                tokens = np.zeros((n_pad, width), np.int32)
+                lengths = np.ones(n_pad, np.int32)
+                temps = np.zeros(n_pad, np.float32)
+                top_ks = np.zeros(n_pad, np.int32)
+                top_ps = np.ones(n_pad, np.float32)
+                slots = np.full(n_pad, self.max_batch, np.int32)  # all dropped
+                self._dev_prefill(
+                    width, tokens, lengths, temps, top_ks, top_ps, slots
+                ).block_until_ready()
+        warmed_s = time.monotonic() - started
         # the decode-chain scatter (warm prefix admissions AND the final
         # chunked-prefill segment dispatch it): one traced-index program,
         # warmed with an all-dropped slot so its first real use — the first
@@ -2626,8 +2668,8 @@ class ServingEngine:
         )
         jax.block_until_ready(self._tokens_dev)
         log.info(
-            "prefill buckets precompiled: widths %s, rows %d",
-            list(self.prefill_buckets), n_pad,
+            "prefill buckets precompiled: widths %s, rows %s, %.1fs",
+            list(self.prefill_buckets), list(self._admit_rungs), warmed_s,
         )
 
     def _run(self) -> None:
@@ -3777,13 +3819,12 @@ class ServingEngine:
         )
         return lora, arows, dfa, g
 
-    def _agentic_row_args(self, requests: list) -> tuple:
+    def _agentic_row_args(self, requests: list, n: int) -> tuple:
         """Per-ROW (not per-slot) adapter/grammar row + initial-DFA-state
-        vectors for a batched admission: entry j serves requests[j];
-        padding rows ride as base (state 0)."""
+        vectors for a batched admission of ``n`` rows: entry j serves
+        requests[j]; padding rows ride as base (state 0)."""
         if not self._agentic:
             return None, None, None
-        n = self.prefill_batch
         arows = np.zeros(n, np.int32)
         g_rows = np.zeros(n, np.int32)
         g_state0 = np.zeros(n, np.int32)
@@ -3880,7 +3921,12 @@ class ServingEngine:
         further queued requests stay queued so the decode chunk dispatched
         right after is never separated from its predecessor by more than
         ~max(budget, one group) of prefill work. None = unbounded
-        (overlap off)."""
+        (overlap off).
+
+        ``prefill_batch`` is the LARGEST group: each width's admissions are
+        cut into sub-batches of at most that many, and ``_prefill_group``
+        dispatches each at the smallest rung of ``admit_rungs`` that holds
+        it."""
         free = [
             i
             for i, slot in enumerate(self._slots)
@@ -3978,9 +4024,9 @@ class ServingEngine:
             width = self._bucket(len(request.prompt_tokens))
             groups.setdefault(width, []).append((idx, request))
         for width, group in sorted(groups.items()):
-            # fixed sub-batch size: each distinct (batch, width) shape is a
-            # separate XLA compile, so every prefill call uses exactly
-            # prefill_batch rows
+            # each distinct (rows, width) shape is a separate XLA compile:
+            # a sub-batch holds at most prefill_batch rows and dispatches at
+            # one of the warmed rungs
             for start in range(0, len(group), self.prefill_batch):
                 sub = group[start : start + self.prefill_batch]
                 try:
@@ -4015,10 +4061,13 @@ class ServingEngine:
         self, width: int, group: list[tuple[int, GenerationRequest]]
     ) -> list[tuple]:
         """One batched prefill for every (slot, request) pair of one prompt
-        bucket; always padded to prefill_batch rows (single compiled shape
-        per width bucket)."""
-        n_pad = self.prefill_batch
-        assert len(group) <= n_pad
+        bucket, padded to the smallest rung of the ladder that holds the
+        group (``admit_rungs``: one row, or prefill_batch; one compiled
+        shape per (rung, width), all warmed), so a lone prompt computes one
+        row of its bucket. An expert model's ladder is the one rung
+        ``prefill_batch`` (``__init__`` says why)."""
+        assert len(group) <= self.prefill_batch
+        n_pad = next(r for r in self._admit_rungs if r >= len(group))
         tokens = np.zeros((n_pad, width), np.int32)
         lengths = np.ones(n_pad, np.int32)
         temps = np.zeros(n_pad, np.float32)
@@ -4045,8 +4094,10 @@ class ServingEngine:
                 top_ps=top_ps,
             ))
         arows, g_rows, g_state0 = self._agentic_row_args(
-            [r for _, r in group]
+            [r for _, r in group], n_pad
         )
+        with self._stats_lock:
+            self._admit_group_rows[n_pad] += 1
         disp = self._new_dispatch(
             "engine.admit_group", program="admit_group", rows=n_pad,
             real_rows=len(group), width=width,

@@ -79,8 +79,9 @@ def test_one_span_per_group_and_chunk_with_work_counts(dense_params):
     assert sum(g["attributes"]["real_rows"] for g in groups) == 3
     for g in groups:
         a = g["attributes"]
-        assert a["program"] == "admit_group" and a["rows"] == 2 and a["width"] == 16
-        assert 0 < a["real_tokens"] <= a["computed_tokens"] == 32
+        # the smallest rung of (1, 2) that holds the group's prompts: all of it real
+        assert a["program"] == "admit_group" and a["rows"] == a["real_rows"] and a["width"] == 16
+        assert 0 < a["real_tokens"] <= a["computed_tokens"] == 16 * a["rows"]
         assert len(a["trace_ids"]) == a["real_rows"]
         assert a["device_ms"] <= g["durationMs"] + 1e-3
     assert sum(g["attributes"]["real_tokens"] for g in groups) == 3 + 4 + 5
@@ -116,6 +117,40 @@ def test_one_span_per_group_and_chunk_with_work_counts(dense_params):
         for f in engine._obs.flight.iterations()
     )
     assert engine.stats()["moe-routed-assignments-total"] == 0
+
+
+def test_admit_group_rows_stat_matches_a_hand_count(dense_params, moe_params):
+    """`admit-group-rows` counts the groups dispatched at each rung of the
+    ladder (1 and 4 under a `prefill_batch` of 4). Seven prompts of one
+    width queued before an iteration: four ride a full group, three the
+    next at 4 rows; then two at 4 rows and one at 1. An expert model has
+    the one rung and counts every group there."""
+    opts = GenerationOptions(max_new_tokens=40, temperature=0.0)
+
+    def admit(engine, n):
+        for _ in range(n):
+            engine.submit(GenerationRequest(prompt_tokens=[9, 9, 9], options=opts))
+        return engine._admit()
+
+    TRACER.clear()
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=10, max_seq_len=64, decode_chunk=4,
+        prefill_buckets=(16,), prefill_batch=4,
+    )
+    assert engine.stats()["admit-group-rows"] == {1: 0, 4: 0}
+    entries = admit(engine, 7) + admit(engine, 2) + admit(engine, 1)
+    assert engine.stats()["admit-group-rows"] == {1: 1, 4: 3}
+    drain(engine, deque([entries]))
+    groups = [g["attributes"] for g in spans_named("engine.admit_group")]
+    assert sorted((g["rows"], g["real_rows"]) for g in groups) == [(1, 1), (4, 2), (4, 3), (4, 4)]
+
+    experts = ServingEngine(
+        MOE, moe_params, max_batch=10, max_seq_len=64, decode_chunk=4,
+        prefill_buckets=(16,), prefill_batch=4,
+    )
+    entries = admit(experts, 3) + admit(experts, 1)
+    assert experts.stats()["admit-group-rows"] == {4: 2}
+    drain(experts, deque([entries]))
 
 
 def test_kv_tokens_read_matches_a_hand_count(dense_params):

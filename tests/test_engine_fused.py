@@ -12,10 +12,13 @@ import pytest
 
 from langstream_tpu.models.configs import MODEL_PRESETS, GenerationOptions
 from langstream_tpu.models.transformer import init_params
-from langstream_tpu.serving.engine import GenerationRequest, ServingEngine
+from langstream_tpu.serving.engine import GenerationRequest, ServingEngine, admit_rungs
+from langstream_tpu.tracing import TRACER
 
 CFG = dataclasses.replace(MODEL_PRESETS["tiny-test"], dtype="float32")
 PARAMS = init_params(CFG, jax.random.PRNGKey(0))
+MOE_CFG = dataclasses.replace(MODEL_PRESETS["tiny-moe-test"], dtype="float32")
+MOE_PARAMS = init_params(MOE_CFG, jax.random.PRNGKey(1))
 
 
 def make_engine(start=True, **kw):
@@ -203,6 +206,103 @@ def test_compiled_programs_flat_after_warmup_mixed_load():
         assert engine.stats()["compiled_programs"] == warmed, (
             "mixed load dispatched a device program the warmup missed"
         )
+    finally:
+        engine.stop()
+
+
+@pytest.mark.parametrize("prefill_batch,rungs", [(1, (1,)), (2, (1, 2)), (4, (1, 4)), (8, (1, 8))])
+def test_the_ladder_is_one_row_and_the_largest_group(prefill_batch, rungs):
+    assert admit_rungs(prefill_batch) == rungs
+
+
+def _serve_burst(config, params, k, full_rows=False):
+    """k prompts of one bucket width queued before ONE iteration of a warmed
+    engine, driven by hand so that they form one admission group whatever
+    the host's timing; served to the end. ``full_rows`` holds the engine to
+    the one shape of `prefill_batch` rows (what every engine did before the
+    ladder; no option selects it). Returns (tokens, the groups' span
+    attributes, programs after warm-up, programs after the burst)."""
+    engine = ServingEngine(
+        config, params, max_batch=8, max_seq_len=64, decode_chunk=4,
+        prefill_buckets=(16,), prefill_batch=8, overlap=True, precompile=True,
+    )
+    if full_rows:
+        engine._admit_rungs = (engine.prefill_batch,)
+    engine._warmup()  # what the engine thread runs before it serves
+    warmed = engine.stats()["compiled_programs"]
+    TRACER.clear()
+    opts = GenerationOptions(max_new_tokens=9, temperature=0.0)
+    requests = [
+        engine.submit(GenerationRequest(
+            prompt_tokens=[(5 * i + j) % config.vocab_size for j in range(3 + i)],
+            options=opts,
+        ))
+        for i in range(k)
+    ]
+    pending: deque = deque()
+    try:
+        while not all(r._done.is_set() for r in requests):
+            engine._iterate(pending)
+    finally:
+        engine.stop()  # gives the heap the warm-up froze back
+    groups = [
+        s["attributes"] for s in TRACER.spans(4096) if s["name"] == "engine.admit_group"
+    ]
+    return (
+        [r.result(timeout=1).tokens for r in requests], groups, warmed,
+        engine.stats()["compiled_programs"],
+    )
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 5, 8])
+def test_a_burst_dispatches_at_the_smallest_rung_that_holds_it(k):
+    """An admission group computes the rows it holds: k same-width arrivals
+    ride ONE group of rung(k) rows of the ladder (1, 8), every request's
+    tokens are those of the same engine held to `prefill_batch` rows, and no
+    program is dispatched that the warm-up had not compiled."""
+    rung = 1 if k == 1 else 8
+    tokens, groups, warmed, after = _serve_burst(CFG, PARAMS, k)
+    assert [(g["rows"], g["real_rows"], g["computed_tokens"]) for g in groups] == [
+        (rung, k, rung * 16)
+    ]
+    assert groups[0]["real_tokens"] == sum(3 + i for i in range(k))
+    assert after == warmed, "a rung was dispatched that the warm-up had not compiled"
+    full_tokens, full_groups, full_warmed, full_after = _serve_burst(CFG, PARAMS, k, full_rows=True)
+    assert [(g["rows"], g["real_rows"]) for g in full_groups] == [(8, k)]
+    assert tokens == full_tokens and all(len(t) == 9 for t in tokens)
+    # one admit program a rung and width: one more than the one shape
+    assert warmed == full_warmed + 1 and full_after == full_warmed
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 5, 8])
+def test_an_expert_model_keeps_prefill_batch_rows(k):
+    """`moe_ffn` sizes every expert's capacity from rows x width, padding
+    included, and hands it out real rows first: the padding rows buy the real
+    ones their capacity, so a model with expert layers keeps the one shape."""
+    tokens, groups, warmed, after = _serve_burst(MOE_CFG, MOE_PARAMS, k)
+    assert [(g["rows"], g["real_rows"], g["computed_tokens"]) for g in groups] == [(8, k, 128)]
+    assert after == warmed and all(len(t) == 9 for t in tokens)
+
+
+@pytest.mark.parametrize("prefix_cache,widths", [(False, {32}), ("auto", {16, 32})])
+def test_segment_programs_warmed_are_the_ones_the_engine_can_dispatch(prefix_cache, widths):
+    """A long prompt's chunks run at the largest bucket width; the narrower
+    segment widths serve only warm suffixes behind a prefix hit. An engine
+    without a prefix index warms the one, and a long prompt afterwards
+    compiles nothing."""
+    engine = make_engine(
+        max_batch=2, max_seq_len=128, decode_chunk=4, prefill_buckets=(16, 32),
+        precompile=True, prefix_cache=prefix_cache,
+    )
+    try:
+        engine.wait_ready(timeout=300)
+        assert {p[1] for p in engine._programs if p[0] == "paged-segment"} == widths
+        warmed = engine.stats()["compiled_programs"]
+        long_prompt = [(3 + i) % CFG.vocab_size for i in range(70)]  # three segments
+        opts = GenerationOptions(max_new_tokens=4, temperature=0.0)
+        assert len(engine.generate(long_prompt, opts, timeout=120).tokens) == 4
+        assert len(engine.generate(long_prompt[:9], opts, timeout=120).tokens) == 4
+        assert engine.stats()["compiled_programs"] == warmed
     finally:
         engine.stop()
 

@@ -279,6 +279,61 @@ def test_engine_tokens_are_the_full_forward_s_and_spans_count_state_rows(params)
     assert any(g.get("state_rows_written") == g["real_rows"] for g in groups)
 
 
+@pytest.mark.parametrize("k", [1, 3])
+def test_the_ladder_admits_state_rows_of_the_model_s_own_prefill(params, k):
+    """One prompt rides an admission group of one row, three a group of four
+    (the ladder 1, 4 under a `prefill_batch` of 4): each slot's row of
+    recurrent state is what `prefill` writes for that prompt alone over its
+    true length, the other rows stay zero, and the tokens served are the
+    full forward's."""
+    from collections import deque
+
+    from langstream_tpu.tracing import TRACER
+
+    TRACER.clear()
+    engine = E.ServingEngine(
+        CFG, params, max_batch=4, max_seq_len=256, prefill_buckets=(32, 64), page_size=PAGE,
+        decode_chunk=4, prefill_batch=4, precompile=False,
+    )
+    assert engine._admit_rungs == (1, 4)
+    prompts = [_tokens(60 + i, n).tolist() for i, n in enumerate([37, 64, 41][:k])]
+    requests = [
+        engine.submit(E.GenerationRequest(
+            prompt_tokens=p, options=GenerationOptions(max_new_tokens=5, temperature=0.0)
+        ))
+        for p in prompts
+    ]
+    pending = deque([engine._admit()])  # the group alone: no decode step has touched the state
+    rec = jax.tree.map(np.asarray, engine._pagepool.dev["rec"])
+    slots = {id(s.request): i for i, s in enumerate(engine._slots) if s.request is not None}
+    for request, prompt in zip(requests, prompts):
+        _, cache = T.prefill(
+            params, jnp.asarray([prompt + [0] * (64 - len(prompt))], jnp.int32),
+            jnp.asarray([len(prompt)]),
+            T.join_rec(T.make_kv_cache(CFG, 1, 64), T.make_recurrent_state(CFG, 1)), CFG,
+            rec_rows=jnp.asarray([0]),
+        )
+        for leaf in ("s", "conv"):
+            np.testing.assert_allclose(
+                rec[leaf][:, slots[id(request)]], cache["rec"][leaf][:, 0], atol=1e-5
+            )
+    idle = sorted(set(range(4)) - set(slots.values()))
+    assert float(np.abs(rec["s"][:, idle]).max()) == 0.0
+    try:
+        while not all(r._done.is_set() for r in requests):
+            engine._iterate(pending)
+    finally:
+        engine.stop()
+    for request, prompt in zip(requests, prompts):
+        got = list(request.result(timeout=1).tokens)
+        logits = _forward_all(params, prompt + got)[len(prompt) - 1 : -1]
+        assert [int(row.argmax()) for row in logits] == got
+    groups = [s["attributes"] for s in TRACER.spans(4096) if s["name"] == "engine.admit_group"]
+    assert [(g["rows"], g["real_rows"], g["state_rows_written"]) for g in groups] == [
+        (1 if k == 1 else 4, k, k)
+    ]
+
+
 # -- (v) what a recurrent state refuses ---------------------------------------
 
 

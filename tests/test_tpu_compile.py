@@ -459,3 +459,56 @@ def test_mesh_that_does_not_divide_kv_heads_keeps_the_jnp_path():
     assert not A.pallas_ok(forced, 512)
     assert not A.paged_pallas_ok(forced, PAGE)
     assert A.pallas_ok(dataclasses.replace(forced, kernel_mesh=None), 512)
+
+
+# ---------------------------------------------------------------------------
+# The admission group at the SMALLEST row count of its ladder, at the sizes
+# the benchmark's cells serve (engine.admit_rungs: a lone prompt rides a group
+# of one row). The whole 32-layer program with int8 weights, the cell's pool
+# donated: the compiler refuses what does not fit the chip beside them.
+# ---------------------------------------------------------------------------
+
+V5E_HBM_BYTES = int(15.75 * 2**30)  # what the chip's compiler grants a program
+# Mistral-7B has llama-3-8b's block at a vocabulary of 32768
+DENSE_7B = dataclasses.replace(LLAMA, name="dense-7b", vocab_size=32768, rope_theta=1e6)
+ONE_ROW_GROUPS = {
+    # cell: (config, slots, max-seq-len, kv-pages, the cell's widest bucket)
+    "chat-1x1024": (DENSE_7B, 64, 1280, 512, 1024),
+    "docs-1x2048": (DENSE_7B, 16, 2112, 528, 2048),
+    "olmodrain-1x256": (OLMO, 48, 640, 480, 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ONE_ROW_GROUPS))
+def test_one_row_admit_group_compiles_for_v5e_beside_the_cell_s_state(v5e, monkeypatch, case):
+    from langstream_tpu.models.quant import init_random_quantized_params, quantize_params
+    from langstream_tpu.models.transformer import init_params, make_page_pool
+    from langstream_tpu.serving import engine as E
+    from langstream_tpu.serving.pagepool import table_len_for
+
+    config, slots, seq_len, pages, width = ONE_ROW_GROUPS[case]
+    key = SDS((2,), jnp.uint32)
+    if config.layer_pattern:
+        params = jax.eval_shape(lambda k: quantize_params(init_params(config, k), config), key)
+    else:
+        params = jax.eval_shape(lambda k: init_random_quantized_params(config, k), key)
+    pool = jax.eval_shape(lambda: make_page_pool(config, pages, PAGE, state_rows=slots))
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    rows = E.admit_rungs(8)[0]
+    args = (
+        params, pool, i32(slots), i32(slots), f32(slots), i32(slots), f32(slots), key,
+        i32(rows, width), f32(4, rows), i32(rows), i32(rows, table_len_for(seq_len, PAGE)),
+    )
+    compiled = _compile_as_on_chip(
+        monkeypatch, E._make_paged_admit_group(),
+        _placed(args, SingleDeviceSharding(v5e[0])), (config, PAGE),
+    )
+    assert f"prefill[s={width},t={width}]" in A.attention_paths()
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pool and the state, updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    assert held <= V5E_HBM_BYTES

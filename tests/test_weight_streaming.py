@@ -225,7 +225,7 @@ def test_holder_stats_carry_weight_load_block(tmp_path):
             load_params(tmp_path, holder.model_config()), holder.params()
         )
     finally:
-        engine.stop()
+        holder.close()  # stops the engine AND takes it out of the process's fleet registry
 
 
 def test_holder_weight_streaming_off_still_reports(tmp_path):
@@ -244,7 +244,7 @@ def test_holder_weight_streaming_off_still_reports(tmp_path):
         assert st["weight-load-s"] > 0
         assert st["weight-load-bytes-total"] > 0
     finally:
-        engine.stop()
+        holder.close()  # stops the engine AND takes it out of the process's fleet registry
 
 
 def test_holder_rejects_bad_knobs():
