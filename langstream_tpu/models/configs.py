@@ -78,6 +78,53 @@ class ModelConfig:
     output_norm: bool = False  # full layers: x + norm(f(x)), not x + f(norm(x))
     qk_norm: bool = False
     rope: bool = True  # False: attention turns nothing
+    # window layers (a "sliding_attention" kind in ``layer_pattern``): query
+    # i sees keys i - sliding_window + 1 .. i, turned by rotary; beside them
+    # the "full_attention" layers of such a model are causal over everything
+    # and turn NOTHING (no positional turn at all). Each kind keeps pages of
+    # its own (serving/pagepool.py): a window row holds a ring of the last
+    # ``sliding_window`` tokens plus the dispatch in flight
+    sliding_window: int = 0
+    rope_interleaved: bool = False  # pairs (2i, 2i+1), not (i, i + D/2)
+    # "rms" | "layer" (subtract the mean, divide by sqrt(var + eps), scale,
+    # no bias, in float32); the eps is ``rms_norm_eps`` either way
+    norm: str = "rms"
+    logit_scale: float = 1.0  # logits = logit_scale * h @ E^T
+    # The block of a model with window layers is ONE block (transformer
+    # `_scan_window_periods`): parallel, x + Attn(u) + MoE(u) with u =
+    # norm(x), one norm a layer, and an expert layer that holds a share
+    # (`moe_ffn_held`). ``sliding_window``, ``rope_interleaved``, ``norm`` and
+    # the four fields below are read by that block alone, so
+    # ``__post_init__`` refuses them without window layers, and window
+    # layers without experts. The expert layer's: the router's scoring
+    # ("softmax" over the chosen logits | "sigmoid" of every logit, the
+    # chosen weights divided by their sum), an expert width apart from
+    # ``d_ff`` (0: ``d_ff``), shared experts whose MEAN is added once, and
+    # the chip's share: the router is ``n_experts`` wide, this program holds
+    # experts ``experts_held`` = (first, count) of them and computes their
+    # part of the result for the tokens routed to them, dropping nothing.
+    # (): all of them
+    moe_scoring: str = "softmax"
+    moe_d_ff: int = 0
+    n_shared_experts: int = 0
+    experts_held: tuple = ()
+
+    @property
+    def has_window(self) -> bool:
+        """Window layers, and with them (``__post_init__``) the parallel
+        block whose expert layer holds a share: the one predicate of that
+        path, of its second page group and of the expert counts its decode
+        chunks AND prefill segments return (MOE_HELD_COUNTS)."""
+        return "sliding_attention" in self.layer_pattern
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def held_experts(self) -> tuple:
+        """(first, count) of the routed experts this program holds."""
+        return tuple(self.experts_held) or (0, self.n_experts)
 
     @property
     def is_recurrent(self) -> bool:
@@ -89,7 +136,8 @@ class ModelConfig:
 
     def n_layers_of(self, kind: str) -> int:
         """Layers of ``kind`` in the model; the page pool's layer axis is
-        ``n_layers_of("full_attention")``."""
+        ``n_layers_of("full_attention")``, the window group's
+        ``n_layers_of("sliding_attention")``."""
         if not self.layer_pattern:
             return self.n_layers if kind == "full_attention" else 0
         return self.n_periods * self.layer_pattern.count(kind)
@@ -108,11 +156,42 @@ class ModelConfig:
 
     def __post_init__(self) -> None:
         if self.layer_pattern:
-            unknown = set(self.layer_pattern) - {"linear_attention", "full_attention"}
+            unknown = set(self.layer_pattern) - {
+                "linear_attention", "full_attention", "sliding_attention"
+            }
             if unknown or self.n_layers % len(self.layer_pattern):
                 raise ValueError(
                     f"{self.name}: layer_pattern {self.layer_pattern} over "
                     f"{self.n_layers} layers"
+                )
+            if self.has_window and (
+                self.sliding_window < 1 or self.is_recurrent or not self.is_moe
+            ):
+                raise ValueError(
+                    f"{self.name}: window layers need sliding_window >= 1, no "
+                    "recurrent layer beside them and an expert layer "
+                    "(n_experts > 0): their block is the parallel one"
+                )
+        window_only = {
+            "sliding_window": self.sliding_window > 0,
+            "rope_interleaved": self.rope_interleaved,
+            "norm": self.norm != "rms",
+            "moe_scoring": self.moe_scoring != "softmax",
+            "moe_d_ff": self.moe_d_ff > 0,
+            "n_shared_experts": self.n_shared_experts > 0,
+            "experts_held": bool(self.experts_held),
+        }
+        if not self.has_window and any(window_only.values()):
+            raise ValueError(
+                f"{self.name}: {', '.join(k for k, on in window_only.items() if on)} "
+                "belong to the block of a model with window layers "
+                "(layer_pattern with sliding_attention); no other block reads them"
+            )
+        if self.experts_held:
+            first, count = self.experts_held
+            if first < 0 or count < 1 or first + count > self.n_experts:
+                raise ValueError(
+                    f"{self.name}: experts_held {self.experts_held} of {self.n_experts}"
                 )
 
     @property
@@ -131,7 +210,12 @@ class ModelConfig:
             n_lin = self.n_layers_of("linear_attention")
             mixers = n_lin * linear + (self.n_layers - n_lin) * attn
             embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-            return mixers + self.n_layers * 3 * d * self.d_ff + embed
+            ffn = 3 * d * self.d_ff
+            if self.is_moe:  # what is HELD here, not the published count
+                ffn = 3 * d * self.expert_d_ff * (
+                    self.held_experts[1] + self.n_shared_experts
+                ) + d * self.n_experts
+            return mixers + self.n_layers * ffn + embed
         if self.is_moe:
             ffn = self.n_experts * 3 * d * self.d_ff + d * self.n_experts
         else:
@@ -283,6 +367,33 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         output_norm=True,
         qk_norm=True,
         rope=False,
+    ),
+    "tiny-window-moe-test": _preset(
+        # the command-a-plus block at test size (tests/test_cohere2_moe.py):
+        # (window x3, full) x2, a window of 2 pages of 8, 16 sigmoid-routed
+        # experts top-4 of which this share holds 4, two averaged shared
+        # experts, LayerNorm, the parallel block, a tied head
+        name="tiny-window-moe-test",
+        vocab_size=512,
+        d_model=64,
+        n_layers=8,
+        n_heads=8,
+        n_kv_heads=2,
+        d_ff=32,
+        head_dim=16,
+        rope_theta=50000.0,
+        rms_norm_eps=1e-5,
+        max_seq_len=256,
+        tie_embeddings=True,
+        layer_pattern=("sliding_attention",) * 3 + ("full_attention",),
+        sliding_window=16,
+        rope_interleaved=True,
+        norm="layer",
+        n_experts=16,
+        n_experts_per_tok=4,
+        moe_scoring="sigmoid",
+        n_shared_experts=2,
+        experts_held=(0, 4),
     ),
     "olmo-hybrid-7b": _preset(
         # allenai/Olmo-Hybrid-7B config.json: (gated delta-rule x3, full

@@ -24,7 +24,10 @@ Params = dict
 
 # stacked-layer matmul weights that dominate HBM traffic
 # "wqkv", "wg": a linear-attention layer's q, k, v side by side and its output gate
-_QUANT_LAYER_KEYS = ("wq", "wk", "wv", "wqkv", "wg", "wo", "w_gate", "w_up", "w_down")
+_QUANT_LAYER_KEYS = (
+    "wq", "wk", "wv", "wqkv", "wg", "wo", "w_gate", "w_up", "w_down",
+    "ws_gate", "ws_up", "ws_down",
+)
 
 
 def is_quantized(leaf: Any) -> bool:

@@ -21,6 +21,7 @@ there is deliberately no architectural counterpart.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import math
 from typing import Any, Optional
@@ -46,6 +47,7 @@ SCOPES = (
     "kv_pool.write", "head", "sample",
     "linear_attention", "linear_attention.proj", "linear_attention.conv",
     "linear_attention.state", "linear_attention.out",
+    "attention.window", "attention.full", "moe_ffn.shared",
 )
 
 # What `moe_ffn_counted` counts, per call, as one int32 vector: expert
@@ -53,6 +55,19 @@ SCOPES = (
 # same two over REAL tokens only (``token_valid``; without it every token
 # counts as real). A dense model's programs return zeros.
 MOE_COUNTS = ("routed", "dropped", "routed_real", "dropped_real")
+
+
+# An expert layer that holds a share (`moe_ffn_held`) counts two more: the
+# real tokens' assignments that fell on the experts it holds, and the held
+# experts that got at least one of them (a layer: whose weights the grouped
+# product has to read).
+MOE_HELD_COUNTS = MOE_COUNTS + ("local", "touched")
+
+
+def moe_count_names(config: ModelConfig) -> tuple:
+    """What the programs of ``config`` count: `_scan_window_periods` runs
+    `moe_ffn_held` in every layer, every other loop `moe_ffn_counted`."""
+    return MOE_HELD_COUNTS if config.has_window else MOE_COUNTS
 
 
 def _no_moe_counts() -> jax.Array:
@@ -75,6 +90,8 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
     hd = config.resolved_head_dim
     f, L, v = config.d_ff, config.n_layers, config.vocab_size
 
+    if config.has_window:
+        return _init_window_params(config, key, dtype)
     if config.layer_pattern:
         return _init_pattern_params(config, key, dtype)
     keys = jax.random.split(key, 12)
@@ -183,6 +200,54 @@ def _init_pattern_params(config: ModelConfig, key: jax.Array, dtype) -> Params:
     return params
 
 
+def _init_window_params(config: ModelConfig, key: jax.Array, dtype) -> Params:
+    """A model of window and full attention layers in a parallel block with
+    an expert layer that holds a share: one stack a kind, each layer
+    ``attn_norm`` (the block's one norm), the four attention projections,
+    the router over ALL experts [d, n_experts], the HELD experts
+    ``w_gate/w_up`` [held, d, f] and ``w_down`` [held, f, d], and the shared
+    experts side by side, ``ws_gate/ws_up`` [d, n_shared * f] and ``ws_down``
+    [n_shared * f, d]: their sum is one SwiGLU of that width."""
+    d, v, f = config.d_model, config.vocab_size, config.expert_d_ff
+    h, hkv, hd = config.n_heads, config.n_kv_heads, config.resolved_head_dim
+    held, ns = config.held_experts[1], config.n_shared_experts
+    keys = iter(jax.random.split(key, 32))
+
+    def normal(*shape):  # [..., in, out]: N(0, 1 / in)
+        w = jax.random.normal(next(keys), shape, jnp.float32) * shape[-2] ** -0.5
+        return w.astype(dtype)
+
+    def stack(n):
+        layer = {
+            "attn_norm": jnp.ones((n, d), dtype),
+            "wq": normal(n, d, h * hd), "wk": normal(n, d, hkv * hd),
+            "wv": normal(n, d, hkv * hd), "wo": normal(n, h * hd, d),
+            "router": normal(n, d, config.n_experts),
+            "w_gate": normal(n, held, d, f), "w_up": normal(n, held, d, f),
+            "w_down": normal(n, held, f, d),
+        }
+        if ns:
+            layer.update(
+                ws_gate=normal(n, d, ns * f), ws_up=normal(n, d, ns * f),
+                # a shared expert's down projection has fan-in f, not ns * f
+                ws_down=(normal(n, ns * f, d).astype(jnp.float32) * ns**0.5).astype(dtype),
+            )
+        return layer
+
+    params: Params = {
+        "embed": (jax.random.normal(next(keys), (v, d), jnp.float32) * d**-0.5).astype(dtype),
+        "layers": {
+            kind: stack(config.n_layers_of(kind))
+            for kind in ("sliding_attention", "full_attention")
+            if config.n_layers_of(kind)
+        },
+        "final_norm": jnp.ones((d,), dtype),
+    }
+    if not config.tie_embeddings:
+        params["lm_head"] = normal(d, v)
+    return params
+
+
 # ---------------------------------------------------------------------------
 # Building blocks
 # ---------------------------------------------------------------------------
@@ -192,6 +257,19 @@ def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     xf = x.astype(jnp.float32)
     normed = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
     return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def layer_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
+    """Subtract the mean, divide by sqrt(var + eps), scale; no bias; float32."""
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    normed = xc * lax.rsqrt(jnp.mean(xc * xc, axis=-1, keepdims=True) + eps)
+    return (normed * weight.astype(jnp.float32)).astype(x.dtype)
+
+
+def _norm(x: jax.Array, weight: jax.Array, config: ModelConfig) -> jax.Array:
+    fn = layer_norm if config.norm == "layer" else rms_norm
+    return fn(x, weight, config.rms_norm_eps)
 
 
 def _rope_freqs(
@@ -239,6 +317,20 @@ def apply_rope(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
     out1 = xf1 * cos - xf2 * sin
     out2 = xf2 * cos + xf1 * sin
     return jnp.concatenate([out1, out2], axis=-1).astype(x.dtype)
+
+
+def apply_rope_interleaved(x: jax.Array, sin: jax.Array, cos: jax.Array) -> jax.Array:
+    """x: [B, S, H, D]; pairs (2i, 2i + 1) turned by angle i (GPT-J's
+    layout): out[2i] = x[2i] cos_i - x[2i+1] sin_i, out[2i+1] = x[2i+1] cos_i
+    + x[2i] sin_i. Each lane takes its pair's other half from a lane roll: a
+    reshape to [..., D/2, 2] puts 2 in the lane axis and cost three relayouts
+    of q a layer (4% of a prefill segment on a v5e)."""
+    xf = x.astype(jnp.float32)
+    sin2 = jnp.repeat(sin, 2, axis=-1)[:, :, None, :]
+    cos2 = jnp.repeat(cos, 2, axis=-1)[:, :, None, :]
+    even = jnp.arange(x.shape[-1]) % 2 == 0
+    other = jnp.where(even, -jnp.roll(xf, -1, axis=-1), jnp.roll(xf, 1, axis=-1))
+    return (xf * cos2 + other * sin2).astype(x.dtype)
 
 
 def _softcap(x: jax.Array, cap: Optional[float]) -> jax.Array:
@@ -333,7 +425,7 @@ def _lora_proj(
 
 def make_page_pool(
     config: ModelConfig, num_pages: int, page_size: int, dtype=None,
-    state_rows: int = 0,
+    state_rows: int = 0, window_pages: int = 0,
 ) -> KVCache:
     """Device page pool: ``{"k","v"}`` with leaves [L, P, Hkv, ps, D] (or the
     int8 ``{"q","s"}`` dicts with scales [L, P, Hkv, ps]) — structurally a
@@ -341,16 +433,22 @@ def make_page_pool(
     helper (sharding specs, byte accounting, donation) applies unchanged.
     L counts the full-attention layers. A model with recurrent layers keeps
     their state beside the pages, ``"rec"`` (`make_recurrent_state`), one
-    row a slot for ``state_rows`` slots."""
-    pool = make_kv_cache(config, num_pages, page_size, dtype=dtype)
+    row a slot for ``state_rows`` slots. A model with window layers keeps
+    THEIR pages in a group of its own, ``"win"``: ``{"k", "v"}`` over the
+    window layers and ``window_pages`` pages (0: as many as the full group),
+    addressed through a table of its own (`WINDOW`)."""
+    pool = make_kv_cache(
+        config, num_pages, page_size, dtype=dtype, window_batch=window_pages
+    )
     if config.is_recurrent:
         pool["rec"] = make_recurrent_state(config, max(1, state_rows), dtype)
     return pool
 
 
 def split_rec(pool: KVCache):
-    """The pool's pages and its recurrent state (None for a model without)."""
-    return {"k": pool["k"], "v": pool["v"]}, pool.get("rec")
+    """The pool's pages (a window model's second group, ``"win"``, with
+    them) and its recurrent state (None for a model without)."""
+    return {k: v for k, v in pool.items() if k != "rec"}, pool.get("rec")
 
 
 def join_rec(kv: KVCache, rec) -> KVCache:
@@ -1138,6 +1236,318 @@ def _scan_periods(
     return x, kv, rec
 
 
+# ---------------------------------------------------------------------------
+# Window and full attention layers in a parallel block, an expert layer that
+# holds a share (``config.has_window``; command-a-plus is the model). Each
+# KIND of layer keeps cache entries of its own: the full layers' as every
+# model's (``"k"``, ``"v"``), the window layers' beside them under ``"win"``,
+# in a local cache [L, B, Hkv, T, D] as in the page pool [L, P, Hkv, ps, D],
+# where the window group has its own number of pages and its own table: the
+# paged entry points take ``table`` [2, B, Tp], `FULL` and `WINDOW`. Both
+# tables are indexed by the LOGICAL page (position // page_size); a window
+# row maps only the pages its queries can still see and those the dispatch
+# writes (serving/pagepool.py recycles the ones behind), the rest carry the
+# sentinel. The whole state rides the layer scan's carry and is written in
+# place at (layer, ...), as `_scan_layers_inplace` does it.
+# ---------------------------------------------------------------------------
+
+FULL, WINDOW = 0, 1
+_HELD_EXPERTS = ("w_gate", "w_up", "w_down")
+_KIND_KEY = {"full_attention": None, "sliding_attention": "win"}
+
+
+def _kind_entry(state, kind):
+    tree = state if _KIND_KEY[kind] is None else state[_KIND_KEY[kind]]
+    return tree["k"], tree["v"]
+
+
+def _with_entry(state, kind, entry):
+    new = {"k": entry[0], "v": entry[1]}
+    return {**state, **new} if _KIND_KEY[kind] is None else {**state, _KIND_KEY[kind]: new}
+
+
+def _route_all(xf: jax.Array, router: jax.Array, config: ModelConfig):
+    """(weights [T, k] float32, chosen [T, k]) over ALL ``n_experts``, held
+    here or not: the scores in float32 at the highest matmul precision (a
+    bf16 product moves which experts a token near a tie takes), the k
+    largest chosen, their weights normalised over the k chosen."""
+    logits = jnp.dot(
+        xf.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )  # [T, E]
+    if config.moe_scoring == "sigmoid":
+        top, chosen = lax.top_k(jax.nn.sigmoid(logits), config.n_experts_per_tok)
+        return top / jnp.sum(top, axis=-1, keepdims=True), chosen
+    top, chosen = lax.top_k(logits, config.n_experts_per_tok)
+    return jax.nn.softmax(top, axis=-1), chosen
+
+
+def moe_ffn_held(
+    x: jax.Array, lp: dict, config: ModelConfig,
+    token_valid: Optional[jax.Array] = None,  # [B, S] bool — real tokens
+    layer: Optional[jax.Array] = None,  # with ``lp``'s held experts the whole stack
+    route_on: Optional[jax.Array] = None,  # [B, S, d] float32: ``x`` before its rounding
+) -> tuple[jax.Array, jax.Array]:
+    """The expert layer of a program that holds a SHARE of the experts
+    (``config.held_experts``), and its MOE_HELD_COUNTS. The router is
+    ``n_experts`` wide and scores in float32; each token's top k are chosen
+    among ALL experts and weighted as published (sigmoid scores divided by
+    the chosen ones' sum, or a softmax over the chosen logits); the
+    assignments that fall on held experts are computed, every one of a real
+    token (none is dropped: rows sorted by expert, one grouped product over
+    the held experts, ops/grouped_matmul.py); what an absent expert would
+    add is left out, as on the chip that holds the others its own part is.
+    The shared experts' mean is added once. A padding token is routed but
+    holds no row: it gets the shared part only."""
+    from langstream_tpu.ops import grouped_matmul as gm
+
+    b, s, d = x.shape
+    t, k = b * s, config.n_experts_per_tok
+    first, held = config.held_experts
+    xf = x.reshape(t, d)
+    real = jnp.ones((t,), jnp.bool_) if token_valid is None else token_valid.reshape(t)
+
+    with jax.named_scope("moe_ffn.route"):
+        weights, chosen = _route_all(
+            xf if route_on is None else route_on.reshape(t, d), lp["router"], config
+        )
+
+    tile = gm.row_tile(t, k, config.n_experts)
+    tiles = gm.buffer_tiles(t, k, held, tile)
+    with jax.named_scope("moe_ffn.dispatch"):
+        local = (chosen >= first) & (chosen < first + held) & real[:, None]  # [T, k]
+        expert = jnp.where(local, chosen - first, held).reshape(t * k)
+        dest, tile_expert, used, sizes = gm.plan_groups(expert, held, tile, tiles)
+        rows = jnp.zeros((tiles * tile, d), xf.dtype).at[dest].set(
+            jnp.repeat(xf, k, axis=0), mode="drop"
+        )
+        counts = jnp.stack([
+            jnp.int32(t * k), jnp.int32(0), real.sum(dtype=jnp.int32) * k,
+            jnp.int32(0), local.sum(dtype=jnp.int32), (sizes > 0).sum(dtype=jnp.int32),
+        ])
+
+    def held_w(name: str) -> dict:
+        """[L, held, K, N]: the stack as `_scan_window_periods` hands it on
+        (the product finds its layer there), or one layer's experts as a
+        stack of one."""
+        w = lp[name]
+        if not is_quantized(w):
+            w = {"q": w, "s": jnp.ones((*w.shape[:-2], 1, w.shape[-1]), jnp.float32)}
+        return w if w["q"].ndim == 4 else jax.tree.map(lambda a: a[None], w)
+
+    w_gate, w_up, w_down = held_w("w_gate"), held_w("w_up"), held_w("w_down")
+    if layer is None or w_gate["q"].shape[0] == 1:
+        layer = jnp.int32(0)
+
+    f = config.expert_d_ff
+    kernel = gm.grouped_matmul_ok(tile, d, f, config.attention_impl) and (
+        gm.grouped_matmul_ok(tile, f, d, config.attention_impl)
+    )
+    product = functools.partial(
+        gm.grouped_matmul, layer=layer, tile_expert=tile_expert, used=used, tile=tile,
+        kernel=kernel, interpret=jax.default_backend() != "tpu",
+    )
+    with jax.named_scope("moe_ffn.experts"):
+        gate = _activation(product(rows, w_gate), config.activation)
+        hidden = gate * product(rows, w_up)
+        out_rows = product(hidden, w_down)  # [tiles * tile, d]
+    with jax.named_scope("moe_ffn.combine"):
+        # an assignment without a row reads out of bounds: zero
+        picked = out_rows.at[dest].get(mode="fill", fill_value=0).reshape(t, k, d)
+        w = jnp.where(local, weights, 0.0)
+        out = jnp.einsum("tkd,tk->td", picked.astype(jnp.float32), w).astype(xf.dtype)
+    if config.n_shared_experts:
+        with jax.named_scope("moe_ffn.shared"):
+            gate = _activation(quantized_matmul(xf, lp["ws_gate"]), config.activation)
+            hidden = gate * quantized_matmul(xf, lp["ws_up"])
+            shared = quantized_matmul(hidden, lp["ws_down"])
+            out = out + (
+                shared.astype(jnp.float32) * (1.0 / config.n_shared_experts)
+            ).astype(xf.dtype)
+    return out.reshape(b, s, d), counts
+
+
+def _kind_attention(u, lp, kind, sin, cos, config, positions, entry, layer, ctx):
+    """One layer's attention over the block's normed input ``u`` [B, S, d]:
+    (the output projection's result, before any residual; the kind's cache
+    leaves, written). A window layer turns q and k and sees keys
+    ``position - sliding_window + 1 .. position``; a full layer turns
+    nothing and sees everything behind it. ``entry`` None: no cache; with
+    ``ctx["table"]`` None a local cache [L, B, Hkv, T, D] written at
+    ``positions``; else the kind's page group, read and written through
+    ``ctx["table"]`` [B, Tp]."""
+    from langstream_tpu.ops import attention as ops
+
+    b, s, _ = u.shape
+    hd, h, hkv = config.resolved_head_dim, config.n_heads, config.n_kv_heads
+    window = config.sliding_window if kind == "sliding_attention" else 0
+    table, page_size = ctx.get("table"), ctx.get("page_size", 0)
+    interpret = jax.default_backend() != "tpu"
+
+    @contextlib.contextmanager
+    def scope():
+        with jax.named_scope("attention"), jax.named_scope(
+            "attention.window" if window else "attention.full"
+        ):
+            yield
+
+    def seen(t):  # [B, S, T]: what the jnp paths mask by
+        kv_pos = jnp.arange(t)[None, None, :]
+        mask = kv_pos <= positions[:, :, None]
+        if window:
+            mask = mask & (kv_pos > positions[:, :, None] - window)
+        if ctx.get("kv_limit") is not None:
+            mask = mask & (kv_pos < ctx["kv_limit"])
+        return mask
+
+    def blocked(k_all, v_all, what):
+        """S > 1 queries over a row's columns [B, Hkv, T, D]: a kernel that
+        never holds the scores where the shapes fit, else the masked jnp."""
+        t = k_all.shape[2]
+        if ops.pallas_ok(config, s) and t % min(128, t) == 0:
+            from_zero = ctx.get("from_zero", False)
+            if from_zero and (not window or s <= window) and t >= s:
+                ops.note_path(what, "flash_prefill_attention", config, s=s, t=t)
+                return ops.flash_prefill_attention(
+                    q, k_all[:, :, :s], v_all[:, :, :s], config, interpret=interpret
+                )
+            ops.note_path(what, "flash_segment_attention", config, s=s, t=t)
+            return ops.flash_segment_attention(
+                q, k_all, v_all, positions[:, 0], config, window=window,
+                interpret=interpret,
+            )
+        ops.note_path(what, "jnp", config, s=s, t=t)
+        return attention(q, k_all, v_all, seen(t), config)
+
+    with scope():
+        q = quantized_matmul(u, lp["wq"]).reshape(b, s, h, hd)
+        k = quantized_matmul(u, lp["wk"]).reshape(b, s, hkv, hd)
+        v = quantized_matmul(u, lp["wv"]).reshape(b, s, hkv, hd)
+        if window:
+            turn = apply_rope_interleaved if config.rope_interleaved else apply_rope
+            q, k = turn(q, sin, cos), turn(k, sin, cos)
+        kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+    if entry is None:
+        with scope():
+            attn = blocked(kt, vt, "prefill")
+    elif table is None:
+        ck, cv = entry
+        with scope():
+            at = (
+                layer, jnp.arange(b)[:, None, None], jnp.arange(hkv)[None, :, None],
+                positions[:, None, :],
+            )
+            ck, cv = ck.at[at].set(kt), cv.at[at].set(vt)
+            k_all = lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
+            v_all = lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
+            attn = blocked(k_all, v_all, "prefill") if s > 1 else attention(
+                q, k_all, v_all, seen(k_all.shape[2]), config
+            )
+        entry = (ck, cv)
+    else:
+        pk, pv = entry
+        num_pages = pk.shape[1]
+        decode = s == 1 and ops.paged_pallas_ok(config, page_size)
+        with jax.named_scope("kv_pool.write"):
+            if decode:
+                pages, offs = _page_index(table, positions, page_size, num_pages)
+                pk, pv = ops.paged_kv_write(
+                    (k[:, 0], v[:, 0]), pk, pv, pages[:, 0], offs[:, 0], layer, config,
+                    interpret=interpret,
+                )
+            else:
+                pk = _paged_scatter(pk, layer, kt, table, positions, page_size)
+                pv = _paged_scatter(pv, layer, vt, table, positions, page_size)
+        with scope():
+            t = table.shape[1] * page_size
+            if decode:
+                lengths = ctx["lengths"]
+                ops.note_path(
+                    "paged-decode", "ragged_paged_decode_attention", config, s=s, t=t
+                )
+                attn = ops.ragged_paged_decode_attention(
+                    q[:, 0], pk, pv, lengths, table, layer, config, page_size,
+                    interpret=interpret,
+                    lower=jnp.maximum(lengths - window, 0) if window else None,
+                )[:, None, :]
+            else:
+                k_all = _paged_gather(pk, layer, table, page_size)
+                v_all = _paged_gather(pv, layer, table, page_size)
+                if s > 1:
+                    attn = blocked(k_all, v_all, "paged-segment")
+                else:
+                    ops.note_path("paged-decode", "jnp", config, s=s, t=t)
+                    attn = attention(q, k_all, v_all, seen(t), config)
+        entry = (pk, pv)
+    with scope():
+        return quantized_matmul(attn, lp["wo"]), entry
+
+
+def _parallel_layer(x, lp, kind, sin, cos, config, positions, entry, layer, ctx,
+                    token_valid=None):
+    """x + Attn(u) + MoE(u), u = norm(x): the one norm of a parallel block."""
+    # the norm is computed in float32 either way: the router reads it before
+    # it is rounded to the activation dtype (a bf16 input moves a router logit
+    # by 0.002, enough to swap the 8th and 9th of 128 experts for one token in
+    # 65), every matrix product after it
+    u32 = _norm(x.astype(jnp.float32), lp["attn_norm"], config)
+    u = u32.astype(x.dtype)
+    attn, entry = _kind_attention(u, lp, kind, sin, cos, config, positions, entry, layer, ctx)
+    with jax.named_scope("moe_ffn"):
+        ffn, counts = moe_ffn_held(u, lp, config, token_valid, layer, route_on=u32)
+    return x + attn + ffn, entry, counts
+
+
+def _scan_window_periods(
+    params, x, sin, cos, config, positions, state=None, tables=None,
+    page_size=0, token_valid=None, lengths=None, kv_limit=None, from_zero=False,
+):
+    """The layer loop of a model with window layers: a scan over its periods
+    whose body runs the period's layers in order, each kind from a stack of
+    its own (sliced where it is used, as `_scan_periods` does). ``state``:
+    None, a local cache or the page pool, both kinds' entries, carried and
+    written in place. Returns (x, state, the layers' summed
+    MOE_HELD_COUNTS)."""
+    pattern = config.layer_pattern
+    per = {kind: pattern.count(kind) for kind in set(pattern)}
+    stacks = params["layers"]
+    group = {"full_attention": FULL, "sliding_attention": WINDOW}
+
+    def body(carry, p):
+        x, state, counts = carry
+        at = dict.fromkeys(per, 0)
+        for kind in pattern:
+            layer = p * per[kind] + at[kind]
+            at[kind] += 1
+            # the held experts' weights go on as the stack: the grouped
+            # product reads its blocks at (layer, expert) where they lie
+            lp = {
+                key: leaf if key in _HELD_EXPERTS else jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), leaf
+                )
+                for key, leaf in stacks[kind].items()
+            }
+            ctx = {
+                "table": None if tables is None else tables[group[kind]],
+                "page_size": page_size, "lengths": lengths, "kv_limit": kv_limit,
+                "from_zero": from_zero,
+            }
+            entry = None if state is None else _kind_entry(state, kind)
+            x, entry, c = _parallel_layer(
+                x, lp, kind, sin, cos, config, positions, entry, layer, ctx, token_valid
+            )
+            if state is not None:
+                state = _with_entry(state, kind, entry)
+            counts = counts + c
+        return (x, state, counts), None
+
+    zero = jnp.zeros(len(MOE_HELD_COUNTS), jnp.int32)
+    (x, state, counts), _ = lax.scan(
+        body, (x, state, zero), jnp.arange(config.n_periods)
+    )
+    return x, state, counts
+
+
 def _embed(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array:
     table = params["embed"]
     with jax.named_scope("embed"):
@@ -1154,7 +1564,7 @@ def _embed(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array:
 
 def _unembed(params: Params, x: jax.Array, config: ModelConfig) -> jax.Array:
     with jax.named_scope("head"):
-        x = rms_norm(x, params["final_norm"], config.rms_norm_eps)
+        x = _norm(x, params["final_norm"], config)
         if config.tie_embeddings:
             table = params["embed"]
             head = (
@@ -1163,6 +1573,8 @@ def _unembed(params: Params, x: jax.Array, config: ModelConfig) -> jax.Array:
             logits = (x @ head).astype(jnp.float32)
         else:
             logits = quantized_matmul(x, params["lm_head"]).astype(jnp.float32)
+        if config.logit_scale != 1.0:
+            logits = logits * config.logit_scale
         return _softcap(logits, config.final_logit_softcap)
 
 
@@ -1282,7 +1694,11 @@ def forward(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array
     mask = jnp.tril(jnp.ones((s, s), jnp.bool_))[None, :, :]
     mask = jnp.broadcast_to(mask, (b, s, s))
     x = _embed(params, tokens, config)
-    if config.layer_pattern:
+    if config.has_window:
+        x, _, _ = _scan_window_periods(
+            params, x, sin, cos, config, positions, from_zero=True
+        )
+    elif config.layer_pattern:
         x, _, _ = _scan_periods(params, x, sin, cos, mask, config)
     else:
         x, _, _ = _scan_layers(params, x, sin, cos, mask, config)
@@ -1315,7 +1731,9 @@ def encode(
     return pooled / jnp.maximum(jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)
 
 
-def make_kv_cache(config: ModelConfig, batch: int, max_len: int, dtype=None) -> KVCache:
+def make_kv_cache(
+    config: ModelConfig, batch: int, max_len: int, dtype=None, window_batch: int = 0,
+) -> KVCache:
     """Head-major cache: [L, B, Hkv, T, D] — (T, D) are the tiled trailing
     dims, so Pallas kv blocks are (block_k, D) slices with no relayout.
 
@@ -1328,6 +1746,17 @@ def make_kv_cache(config: ModelConfig, batch: int, max_len: int, dtype=None) -> 
         config.n_layers_of("full_attention"), batch, config.n_kv_heads, max_len,
         config.resolved_head_dim,
     )
+    if config.has_window:
+        # the window layers' entries beside the full layers', ``"win"``: the
+        # same leaves over ``window_batch`` rows (a page pool's window group
+        # has its own number of pages)
+        if config.kv_cache_dtype == "int8":
+            raise NotImplementedError(f"an int8 KV cache for window layers ({config.name})")
+        win = (config.n_layers_of("sliding_attention"), window_batch or batch) + shape[2:]
+        return {
+            "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            "win": {"k": jnp.zeros(win, dtype), "v": jnp.zeros(win, dtype)},
+        }
     if config.kv_cache_dtype == "int8":
         entry = lambda: {  # noqa: E731
             "q": jnp.zeros(shape, jnp.int8),
@@ -1373,7 +1802,14 @@ def prefill(
     mask = kv_pos <= q_pos[:, :, None]
     mask = mask & (kv_pos < s)
     x = _embed(params, tokens, config)
-    if config.layer_pattern:
+    if config.has_window:
+        x, cache, counts = _scan_window_periods(
+            params, x, sin, cos, config, positions, state=cache, kv_limit=s,
+            from_zero=True,
+            token_valid=positions
+            < (lengths if real_lengths is None else real_lengths)[:, None],
+        )
+    elif config.layer_pattern:
         # the recurrent state rides in with the cache and out with it, as it
         # rides with the page pool: ``cache["rec"]`` (`join_rec`)
         cache, rec = split_rec(cache)
@@ -1460,7 +1896,16 @@ def paged_decode_step_inplace(
     sin, cos = _rope_freqs(pos2, config)
     mask = _paged_mask(table, page_size, pos2)
     x = _embed(params, tokens[:, None], config)
-    if config.layer_pattern:
+    if config.has_window:
+        # the row's live length, from the full group's table (a prefix of
+        # mapped pages); a window layer reads its last ``sliding_window``
+        lengths = _paged_lengths(table[FULL], positions, page_size, pool["k"].shape[1])
+        x, pool, counts = _scan_window_periods(
+            params, x, sin, cos, config, pos2, state=pool, tables=table,
+            page_size=page_size, lengths=lengths,
+            token_valid=(lengths > positions)[:, None],
+        )
+    elif config.layer_pattern:
         # batch row b steps state row b. A row whose table maps nothing, or
         # that has stepped past its pages, is idle: its state stays as it is
         kv, rec = split_rec(pool)
@@ -1527,7 +1972,8 @@ def paged_prefill_segment_inplace(
     lora: Optional[dict] = None,
     adapter_rows: Optional[jax.Array] = None,
     state_rows: Optional[jax.Array] = None,  # [B] each row's recurrent state row
-) -> tuple[jax.Array, KVCache]:
+    moe_counts: bool = False,  # the window model's segment returns its counts
+):
     """Chunked/suffix prefill straight into the slot's pages: process one
     segment of a longer prompt against pages whose columns [0, offsets) were
     written by earlier segments (or aliased from the prefix index). K/V for
@@ -1546,7 +1992,14 @@ def paged_prefill_segment_inplace(
     sin, cos = _rope_freqs(positions, config)
     mask = _paged_mask(table, page_size, positions)
     x = _embed(params, tokens, config)
-    if config.layer_pattern:
+    counts = None
+    if config.has_window:
+        x, pool, counts = _scan_window_periods(
+            params, x, sin, cos, config, positions, state=pool, tables=table,
+            page_size=page_size,
+            token_valid=jnp.arange(s)[None, :] < seg_lengths[:, None],
+        )
+    elif config.layer_pattern:
         # the recurrent state carries over from the row's earlier segments;
         # a segment at offset 0 starts it from zero
         kv, rec = split_rec(pool)
@@ -1568,6 +2021,8 @@ def paged_prefill_segment_inplace(
     last = jnp.clip(seg_lengths - 1, 0, s - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     logits = _unembed(params, x_last[:, None, :], config)[:, 0]
+    if moe_counts:
+        return logits, pool, _no_moe_counts() if counts is None else counts
     return logits, pool
 
 
@@ -1593,6 +2048,15 @@ def paged_insert_cache(
             loc.astype(pl_entry.dtype), mode="drop"
         )
 
+    if "win" in pool:
+        # both kinds' rows, each through its own table ([2, n, Tp])
+        both, n = tables, tables.shape[1]
+        with jax.named_scope("kv_pool.write"):
+            tables = both[FULL]
+            out = {leaf: put(pool[leaf], local_cache[leaf]) for leaf in ("k", "v")}
+            tables = both[WINDOW]
+            out["win"] = jax.tree.map(put, pool["win"], local_cache["win"])
+        return out
     kv, rec = split_rec(pool)
     with jax.named_scope("kv_pool.write"):
         return join_rec(jax.tree.map(put, kv, local_cache), rec)

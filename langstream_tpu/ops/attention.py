@@ -298,6 +298,155 @@ def flash_prefill_attention(
 
 
 # ---------------------------------------------------------------------------
+# Segment: S queries a row at global positions offset .. offset + S - 1 against
+# T cache columns of that row, causal, optionally inside a sliding window. The
+# prefill kernel's body with a query offset and a lower bound: the scores are
+# never held (128 heads x 2048 queries x 12.5k keys are 13.4 GB in float32),
+# and a query block visits only the key blocks its rows can see: the index map
+# clamps the key block into that range (a block index that does not change is
+# not fetched again) and the body runs inside it. Over a window the grid's key
+# axis is as long as the widest range any query block has, not T.
+# ---------------------------------------------------------------------------
+
+
+def _segment_kernel(
+    offsets_ref,  # scalar-prefetch [B]
+    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+    *, block_q: int, block_k: int, window: int, n_t: int, scale: float, softcap,
+):
+    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    q_start = offsets_ref[b] + i * block_q
+    first, last = _segment_blocks(q_start, block_q, block_k, window, n_t)
+    at = first + j  # the key block this step is at
+
+    @pl.when(at <= last)
+    def _body():
+        q = q_ref[0, 0, :, :, :]  # [G, block_q, D]
+        k = k_ref[0, 0, :, :]  # [block_k, D]
+        v = v_ref[0, 0, :, :]
+        s = jax.lax.dot_general(
+            q, k, dimension_numbers=(((2,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [G, block_q, block_k] f32
+        if softcap is not None:
+            s = jnp.tanh(s / softcap) * softcap
+        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_q, block_k), 1)
+        k_pos = at * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_q, block_k), 2)
+        seen = k_pos <= q_pos
+        if window:
+            seen = seen & (k_pos > q_pos - window)
+        s = jnp.where(seen, s, _NEG)
+        m_prev = m_scr[:, :, 0]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1))
+        p = jnp.exp(s - m_new[:, :, None])
+        p = jnp.where(s <= _NEG, 0.0, p)
+        corr = jnp.exp(m_prev - m_new)
+        l_scr[:, :, 0] = l_scr[:, :, 0] * corr + p.sum(axis=-1)
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, dimension_numbers=(((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc_scr[...] = acc_scr[...] * corr[:, :, None] + pv
+        m_scr[:, :, 0] = m_new
+
+    @pl.when(j == nk - 1)
+    def _finalize():
+        l = jnp.maximum(l_scr[:, :, 0], 1e-30)[:, :, None]
+        o_ref[0, 0, :, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _segment_blocks(q_start, block_q: int, block_k: int, window: int, n_t: int):
+    """First and last key block, of ``n_t``, that the query block at
+    ``q_start`` sees."""
+    last = jnp.minimum((q_start + block_q - 1) // block_k, n_t - 1)
+    if not window:
+        return jnp.int32(0), last
+    return jnp.maximum(q_start - window + 1, 0) // block_k, last
+
+
+def segment_key_blocks(s: int, t: int, d: int, group: int, window: int,
+                       itemsize: int = 2) -> tuple[int, int, int]:
+    """(block_q, block_k, key blocks a query block's grid axis has) of a
+    segment call: what the kernel runs at, for its callers' arithmetic
+    (benchmark/reduce) as for the call itself."""
+    block_k = _fit_block(512, t)
+    block_q = _fit_block(_vmem_block_q(512, block_k, group, d, itemsize), s)
+    n_k = t // block_k
+    if window:
+        n_k = min(n_k, (window + block_q - 2) // block_k + 2)
+    return block_q, block_k, n_k
+
+
+@_per_kv_head(1)
+def flash_segment_attention(
+    q: jax.Array,  # [B, S, H, D] at positions offsets[b] + (0 .. S-1)
+    k: jax.Array,  # [B, Hkv, T, D] the row's cache columns 0 .. T-1
+    v: jax.Array,
+    offsets: jax.Array,  # [B]
+    config: ModelConfig,
+    window: int = 0,  # > 0: query i sees keys i - window + 1 .. i
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal (windowed) GQA attention of a segment over its row's cache →
+    [B, S, H*D]. Every column a query can see has to hold its key: the
+    caller writes the segment's own K/V first. A column past T is never
+    seen (a query past T sees its window's part below T)."""
+    b, s, h, d = q.shape
+    hkv, t = k.shape[1], k.shape[2]
+    group = h // hkv
+    block_q, block_k, n_k = segment_key_blocks(
+        s, t, d, group, window, jnp.dtype(q.dtype).itemsize
+    )
+    assert s % block_q == 0 and t % block_k == 0, "caller gates divisibility"
+    qg = q.reshape(b, s, hkv, group, d).transpose(0, 2, 3, 1, 4)
+    n_t = t // block_k
+
+    def kv_index(b, h, i, j, offsets):
+        first, last = _segment_blocks(
+            offsets[b] + i * block_q, block_q, block_k, window, n_t
+        )
+        return (b, h, jnp.clip(first + j, 0, last), 0)
+
+    def q_index(b, h, i, j, offsets):
+        return (b, h, 0, i, 0)
+
+    out = pl.pallas_call(
+        functools.partial(
+            _segment_kernel, block_q=block_q, block_k=block_k, window=window,
+            n_t=n_t, scale=1.0 / (d**0.5), softcap=config.attn_logit_softcap,
+        ),
+        name="flash_segment_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, hkv, s // block_q, n_k),
+            in_specs=[
+                pl.BlockSpec((1, 1, group, block_q, d), q_index),
+                pl.BlockSpec((1, 1, block_k, d), kv_index),
+                pl.BlockSpec((1, 1, block_k, d), kv_index),
+            ],
+            out_specs=pl.BlockSpec((1, 1, group, block_q, d), q_index),
+            scratch_shapes=[
+                pltpu.VMEM((group, block_q, 128), jnp.float32),
+                pltpu.VMEM((group, block_q, 128), jnp.float32),
+                pltpu.VMEM((group, block_q, d), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(offsets.astype(jnp.int32), qg, k, v)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * d)
+
+
+# ---------------------------------------------------------------------------
 # Ragged PAGED decode: one query per row against a page-table-indexed KV
 # pool [P, Hkv, page_size, D] (arxiv 2502.10490 "Ragged Paged Attention":
 # per-slot sequence lengths index pages through a table, (8,128) tiling on
@@ -369,9 +518,8 @@ _PAGE_SLOTS = 4
 
 def _paged_decode_kernel(
     lengths_ref,  # scalar-prefetch [B]
-    pages_ref,  # scalar-prefetch [B * Tp]: the flattened table, layer added
-    q_ref,  # [1, Hkv, G, D]
-    *refs,  # n_scales row blocks, n_leaves pool leaves in HBM, o_ref, scratch
+    *refs,  # [lower_ref], pages_ref, q_ref, n_scales row blocks, n_leaves
+    # pool leaves in HBM, o_ref, scratch
     load,  # _page_bf16 | _page_int8
     n_scales: int,
     n_leaves: int,
@@ -379,7 +527,11 @@ def _paged_decode_kernel(
     table_len: int,
     scale: float,
     softcap,
+    windowed: bool = False,
 ):
+    # a window layer's rows read [lower, length): one more prefetched vector
+    lower_ref = refs[0] if windowed else None
+    pages_ref, q_ref, *refs = refs[1:] if windowed else refs
     scales, refs = refs[:n_scales], refs[n_scales:]
     pool, o_ref = refs[:n_leaves], refs[n_leaves]
     bufs = refs[n_leaves + 1: 2 * n_leaves + 1]  # per leaf [_PAGE_SLOTS, a page]
@@ -390,6 +542,11 @@ def _paged_decode_kernel(
     def pages_of(row):
         length = lengths_ref[jnp.minimum(row, nb - 1)]
         return jnp.minimum(pl.cdiv(length, page_size), table_len)
+
+    def first_of(row):  # the row's first page: that of its lower bound
+        if not windowed:
+            return jnp.int32(0)
+        return lower_ref[jnp.minimum(row, nb - 1)] // page_size
 
     def copies(page, slot):
         return [
@@ -409,7 +566,7 @@ def _paged_decode_kernel(
     def fetch_next():
         row, j = jax.lax.while_loop(  # over the rows that are exhausted
             lambda at: (at[0] < nb) & (at[1] >= pages_of(at[0])),
-            lambda at: (at[0] + 1, jnp.int32(0)),
+            lambda at: (at[0] + 1, first_of(at[0] + 1)),
             (walk[at_row], walk[at_page]),
         )
 
@@ -427,6 +584,8 @@ def _paged_decode_kernel(
     def _first_row():
         for i in range(4):
             walk[i] = 0
+        if windowed:
+            walk[at_page] = first_of(0)
         for _ in range(_PAGE_SLOTS - 1):
             fetch_next()
 
@@ -448,6 +607,8 @@ def _paged_decode_kernel(
             jnp.int32, (1, 1, page_size), 2
         )
         s = jnp.where(k_pos < length, s, _NEG)  # the last page's tail
+        if windowed:  # and the first page's head
+            s = jnp.where(k_pos >= lower_ref[b], s, _NEG)
 
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -462,7 +623,7 @@ def _paged_decode_kernel(
         return m_new, l_prev * corr + p.sum(axis=-1, keepdims=True), acc * corr + pv
 
     _, l, acc = jax.lax.fori_loop(
-        0, pages_of(b), body,
+        first_of(b), pages_of(b), body,
         (
             jnp.full((hkv, group, 1), _NEG, jnp.float32),
             jnp.zeros((hkv, group, 1), jnp.float32),
@@ -473,7 +634,7 @@ def _paged_decode_kernel(
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def _paged_row_index(b, lens, pages):
+def _paged_row_index(b, *_):
     """Row ``b``'s block of q, of the output and of the int8 scales."""
     return (b, 0, 0, 0)
 
@@ -497,6 +658,7 @@ def _paged_decode_call(
     name: str, load, q: jax.Array, leaves: list, scales: list,
     lengths: jax.Array, table: jax.Array, layer: jax.Array,
     config: ModelConfig, page_size: int, interpret: bool,
+    lower: jax.Array | None = None,
 ) -> jax.Array:
     """The one `pallas_call` of both paged kernels. ``leaves`` are the
     pool's arrays [L, P, Hkv, ps, D] whose pages the kernel fetches itself;
@@ -518,9 +680,13 @@ def _paged_decode_call(
         table_len=tp,
         scale=1.0 / (d**0.5),
         softcap=config.attn_logit_softcap,
+        windowed=lower is not None,
     )
+    bounds = [lengths.astype(jnp.int32)]
+    if lower is not None:
+        bounds.append(jnp.minimum(lower.astype(jnp.int32), bounds[0]))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(bounds) + 1,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, hkv, group, d), _paged_row_index)]
         + [pl.BlockSpec((1, tp, hkv, page_size), _paged_row_index)] * len(scales)
@@ -544,7 +710,7 @@ def _paged_decode_call(
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(
-        lengths.astype(jnp.int32),
+        *bounds,
         _layer_pages(table, layer, leaves[0].shape[1]),
         q.reshape(b, hkv, group, d),
         *(leaf.at[layer, table].get(mode="clip") for leaf in scales),
@@ -564,13 +730,17 @@ def ragged_paged_decode_attention(
     config: ModelConfig,
     page_size: int,
     interpret: bool = False,
+    lower: jax.Array | None = None,  # [B]: a window layer's first visible column
 ) -> jax.Array:
     """GQA paged decode attention over one layer of the pool → [B, H*D].
     A row reads its first ``cdiv(length, page_size)`` table entries and no
-    other; a row of length 0 comes back zeros."""
+    other; a row of length 0 comes back zeros. With ``lower`` the row reads
+    columns [lower, length): the walk starts at page ``lower // page_size``
+    (the pages behind it need not be mapped) and the first page's head is
+    masked."""
     return _paged_decode_call(
         "ragged_paged_decode_attention", _page_bf16, q, [k, v], [], lengths,
-        table, layer, config, page_size, interpret,
+        table, layer, config, page_size, interpret, lower,
     )
 
 

@@ -37,6 +37,7 @@ from jax import lax
 from langstream_tpu.models.configs import GenerationOptions, ModelConfig
 from langstream_tpu.models.transformer import (
     MOE_COUNTS,
+    moe_count_names,
     join_rec,
     make_kv_cache,
     paged_decode_step_inplace,
@@ -481,23 +482,30 @@ def _paged_segment_and_sample(
     (out-of-bounds on non-final segments — dropped), so the decode chain
     the engine dispatches NEXT iteration already carries the right state
     without a host round trip."""
-    logits, pool = paged_prefill_segment_inplace(
+    # a model with window layers counts its segments' expert assignments too
+    # (`moe_count_names`): each has a fetch of its own to bring the counts
+    # (`_segment_step`), and the program returns them as a fifth result
+    logits, pool, *moe = paged_prefill_segment_inplace(
         params, tokens, offsets, seg_lengths, pool, table, config, page_size,
         lora=lora, adapter_rows=arows, state_rows=state_rows,
+        moe_counts=config.has_window,
     )
     first, key, s1 = _sample_first(
         logits, key, temp, top_k, top_p, dfa, g, state0, config.vocab_size
     )
     if s1 is not None:
         state_dev = state_dev.at[state_slot].set(s1[0], mode="drop")
-    return first, pool, key, state_dev
+    return (first, pool, key, state_dev, *moe)
 
 
 def _on_pages(fn, pool, *rest):
-    """``fn`` over the pool's PAGE leaves ("k", "v"): a recurrent state
-    beside them ("rec", a row a slot, no page axis) passes through."""
-    kv, rec = split_rec(pool)
-    return join_rec(jax.tree.map(fn, kv, *rest), rec)
+    """``fn`` over the pool's PAGE leaves ("k", "v"). What lies beside them
+    passes through: a recurrent state ("rec", a row a slot, no page axis),
+    and a window group's pages ("win"), which are never copied or restored:
+    the page indices here are the full group's, and every option that moves
+    pages is refused for such a model (`_window_page_zero` scrubs them)."""
+    pages = {"k": pool["k"], "v": pool["v"]}
+    return {**pool, **jax.tree.map(fn, pages, *rest)}
 
 
 @functools.partial(jax.jit, donate_argnames=("pool",))
@@ -528,6 +536,15 @@ def _page_zero(pool, pages):
     return _on_pages(zero, pool)
 
 
+@functools.partial(jax.jit, donate_argnames=("pool",))
+def _window_page_zero(pool, pages):
+    """`_page_zero` for the window layers' group: a quarantined slot's ring
+    goes back to ITS free list, and a row that takes a poisoned page reads
+    the columns it has not written yet under a zero weight (0 x NaN)."""
+    zero = lambda a: a.at[:, pages].set(jnp.zeros((), a.dtype), mode="drop")  # noqa: E731
+    return {**pool, "win": jax.tree.map(zero, pool["win"])}
+
+
 @jax.jit
 def _page_snapshot(pool, src):
     """Slice ONE physical page (all layers/heads) out of the pool into
@@ -540,7 +557,7 @@ def _page_snapshot(pool, src):
     def take(a):
         return lax.dynamic_index_in_dim(a, src, 1, keepdims=False)
 
-    return jax.tree.map(take, split_rec(pool)[0])
+    return jax.tree.map(take, {"k": pool["k"], "v": pool["v"]})
 
 
 @functools.partial(jax.jit, donate_argnames=("pool",))
@@ -1043,12 +1060,17 @@ class ServingEngine:
         the KV cache is sharded to match (kv heads on "model") so every
         decode step partitions over ICI with XLA-inserted collectives —
         one psum per layer, the Megatron schedule."""
-        if config.is_recurrent:
-            # a recurrent state is overwritten in place: it cannot be
+        if config.is_recurrent or config.has_window:
+            # Refused at build, by the option's name (docs/SERVING.md §11).
+            # A recurrent state is overwritten in place: it cannot be
             # aliased between slots (prefix reuse), has no spill, migrate or
             # durable format, cannot be rolled back past a rejected draft,
-            # takes no adapter terms and is not sharded. Refused at build,
-            # by the option's name (docs/SERVING.md §11)
+            # takes no adapter terms and is not sharded. Two page groups, one
+            # a ring: a window row's pages behind the window are gone, so a
+            # prefix cannot be aliased out of them, spilled, migrated or
+            # checkpointed whole; a rejected draft's rows may already have
+            # recycled a page; the parallel block takes no adapter terms;
+            # the window group is not sharded and has no int8 pages
             refused = {
                 "prefix_cache": prefix_cache is True
                 or str(prefix_cache).lower() in ("auto", "on", "true", "1"),
@@ -1061,12 +1083,16 @@ class ServingEngine:
                 "mesh": mesh is not None,
                 "spmd": spmd is not None,
                 "ring_axis": config.ring_axis is not None,
+                "kv_cache_dtype": config.has_window and config.kv_cache_dtype == "int8",
             }
             asked = [name for name, on in refused.items() if on]
             if asked:
                 raise ValueError(
                     f"{config.name} has recurrent layers: {', '.join(asked)} "
                     "cannot be used with a recurrent state"
+                    if config.is_recurrent else
+                    f"{config.name} has window layers: {', '.join(asked)} "
+                    "cannot be used with two page groups"
                 )
         if mesh is not None:
             # the Pallas kernels cannot be partitioned by GSPMD: they read
@@ -1151,6 +1177,7 @@ class ServingEngine:
         self._page_deferred: list[GenerationRequest] = []
         # physical pages to zero on the next iteration (quarantine)
         self._pending_page_zero: list[int] = []
+        self._pending_window_zero: list[int] = []  # the window group's
         # -- tiered KV: host-RAM spill + session hibernation (ROADMAP 3) -----
         # host-kv-fraction sizes a pinned host arena RELATIVE to the device
         # pool (2.0 = twice the pool's pages in host RAM; host RAM is ~10×
@@ -1251,6 +1278,7 @@ class ServingEngine:
         # returned wait on the device until its entry is processed
         self._dispatch_seq = 0
         self._moe_dev = None
+        self._window_recycled = 0  # by the last segment's `window_advance`
         self.moe_routed_total = 0
         self.moe_dropped_total = 0
         # ready instant of the newest processed dispatch: with a span's
@@ -1542,6 +1570,9 @@ class ServingEngine:
         from langstream_tpu.serving.pagepool import pages_for_fraction
 
         self._page_fraction = prefix_cache_fraction if enabled else 0.0
+        # the most positions one dispatch writes a row: what a window row's
+        # ring holds beside its window (pagepool.window_ring_pages)
+        self._window_in_flight = max(self.prefill_buckets[-1], self.decode_chunk)
         self._kv_pages = (
             int(kv_pages)
             if kv_pages is not None
@@ -1714,6 +1745,7 @@ class ServingEngine:
                 page_size=self.page_size,
                 kv_pages=self._kv_pages,
                 page_fraction=self._page_fraction,
+                window_in_flight=self._window_in_flight,
                 host_kv_fraction=(
                     self.host_kv_fraction if self._spill_on else 0.0
                 ),
@@ -1764,7 +1796,7 @@ class ServingEngine:
         # over-committed pool OOMs with the numbers on record
         self._pagepool = PagePool(
             config, self._kv_pages, self.page_size, max_batch,
-            self.max_seq_len,
+            self.max_seq_len, window_in_flight=self._window_in_flight,
         )
         if mesh is not None:
             # kv heads on "model" (replicated when they don't divide) —
@@ -2155,6 +2187,9 @@ class ServingEngine:
         this after their warmup request so one compile-heavy cold TTFT
         doesn't own p99 of a steady-state distribution."""
         self._obs.reset_histograms()
+        window = self._pagepool.window if self._pagepool is not None else None
+        if window is not None:  # the peak gauge restarts with them
+            window.peak_in_use = window.pages_in_use
 
     def prefix_advertisement(
         self, top_k: int = 32,
@@ -2278,6 +2313,18 @@ class ServingEngine:
             # summed over the dispatches processed so far
             "moe-routed-assignments-total": self.moe_routed_total,
             "moe-dropped-assignments-total": self.moe_dropped_total,
+            # a model with window layers: its second page group's use
+            **(
+                {
+                    "kv-window-pages-total": self._pagepool.window.num_pages,
+                    "kv-window-pages-in-use": self._pagepool.window.pages_in_use,
+                    # the most in use since `reset_histograms`
+                    "kv-window-pages-peak": self._pagepool.window.peak_in_use,
+                    "kv-window-bytes-per-page": self._pagepool.window_bytes_per_page,
+                    "kv-window-pages-recycled-total": self._pagepool.window.recycled_total,
+                }
+                if self._pagepool.window is not None else {}
+            ),
             "busy-steps": self._busy_steps,
             "overlap": self.overlap,
             "prefill-token-budget": self.prefill_token_budget,
@@ -2603,6 +2650,8 @@ class ServingEngine:
         pool.dev = _page_zero(
             pool.dev, jnp.asarray(np.full(pool.table_len, pool.oob, np.int32))
         )
+        if pool.window is not None:
+            self._flush_window_zeros([])
         # the snapshot/restore pair serves BOTH the tiered-KV spill path
         # and the §18 migration wire (every paged engine can send/receive
         # a migration) — warmed so the first restore OR first migration is
@@ -2962,6 +3011,7 @@ class ServingEngine:
         # just failed above). Queued and page-deferred admissions keep their
         # backlog spots.
         self._pending_page_zero.clear()
+        self._pending_window_zero.clear()
         # tiered KV: quiesce the spill worker BEFORE resetting the
         # arena (stop() completes in-flight copies first, so no thread
         # writes a slot the fresh free list is about to re-issue);
@@ -3054,7 +3104,7 @@ class ServingEngine:
             # iteration top, OUTSIDE any dispatch's announce sequence
             if self._spmd is not None:
                 self._spmd_tick()
-            if self._pending_page_zero:
+            if self._pending_page_zero or self._pending_window_zero:
                 self._flush_page_zeros()
             # tiered KV: fold completed spills in and start hibernation spills
             # for idle prefixes — bounded per iteration, O(1) when idle; the
@@ -3101,7 +3151,8 @@ class ServingEngine:
                 e[3] for batch in pending for e in batch if e[0] == "chunk"
             )
             self._inflight_groups = sum(
-                1 for batch in pending for e in batch if e[0] == "prefill"
+                1 for batch in pending for e in batch
+                if e[0] in ("prefill", "segment")
             )
             self._iter_groups = 0
             had_active = any(s.active for s in self._slots)
@@ -3455,6 +3506,10 @@ class ServingEngine:
                         now - request.submitted_at,
                     )
                     self._deliver_token(idx, int(first[j]))
+        elif kind == "segment":
+            _, first_dev, disp = entry
+            self._fetch_result(first_dev)
+            self._land_dispatch(disp, first_dev)
         elif kind == "verify":
             self._process_verify(entry)
         else:
@@ -3491,7 +3546,9 @@ class ServingEngine:
         (``_segment_step`` adds each later segment to it and the span is
         emitted once, when the final segment's first token lands — only
         that segment has a fetch to time). The segment programs return no
-        MoE counts."""
+        MoE counts, except for a model with window layers (its expert layer
+        holds a share, ``config.has_window``): there every segment is
+        fetched for its counts and is a span of its own."""
         disp = self._new_dispatch(
             "engine.prefill_segment", program=program, rows=1, real_rows=1,
             width=width, segments=1, real_tokens=real_tokens,
@@ -3513,7 +3570,9 @@ class ServingEngine:
         counts = getattr(handle, "counts", None)
         if counts is not None:
             counts = [int(v) for v in counts]
-            attrs.update((f"moe_{n}", c) for n, c in zip(MOE_COUNTS, counts))
+            attrs.update(
+                (f"moe_{n}", c) for n, c in zip(moe_count_names(self.config), counts)
+            )
             with self._stats_lock:
                 self.moe_routed_total += counts[0]
                 self.moe_dropped_total += counts[1]
@@ -4188,10 +4247,9 @@ class ServingEngine:
         every write drops."""
         pool = self._pagepool
         n = len(tokens)
-        tables = np.full((n, pool.table_len), pool.oob, np.int32)
-        for j, s in enumerate(slots):
-            if 0 <= s < self.max_batch:
-                tables[j] = pool.tables[s]
+        for s in slots:  # a window row maps the pages the group writes
+            pool.window_advance(int(s), 0, tokens.shape[1] - 1)
+        tables = pool.rows_tables(slots)
         self._record_program("paged-prefill", tokens.shape[1], n)
         meta = np.stack([lengths, temps, top_ks, top_ps]).astype(np.float32)
         kw = self._agentic_admit_kwargs(n, arows, g_rows, g_state0)
@@ -4577,9 +4635,9 @@ class ServingEngine:
         if self._injector is not None:
             self._injector.fire("segment")
         pool = self._pagepool
-        table = np.full((1, pool.table_len), pool.oob, np.int32)
-        if 0 <= idx < self.max_batch:
-            table[0] = pool.tables[idx]
+        # a window row's pages behind the segment's window go ahead of it
+        self._window_recycled = pool.window_advance(idx, s0, s0 + tokens.shape[1] - 1)
+        table = pool.rows_tables([idx])
         self._record_program("paged-segment", tokens.shape[1])
         kw = self._segment_agentic_kwargs(
             agentic_rows, idx if final else self.max_batch
@@ -4588,7 +4646,7 @@ class ServingEngine:
             # the slot's row of recurrent state carries from segment to
             # segment (an out-of-bounds ``idx``, the warm-up's, drops)
             kw["state_rows"] = jnp.asarray([idx], jnp.int32)
-        first, pool.dev, self._key, state_dev = _paged_segment_and_sample(
+        first, pool.dev, self._key, state_dev, *moe = _paged_segment_and_sample(
             self.params,
             jnp.asarray(tokens),
             jnp.asarray([s0], jnp.int32),
@@ -4605,6 +4663,8 @@ class ServingEngine:
         )
         if state_dev is not None:
             self._dfa_state_dev = state_dev
+        if moe:
+            (self._moe_dev,) = moe
         if final:
             self._record_program("chain-scatter")
             (
@@ -4645,7 +4705,7 @@ class ServingEngine:
         inactive = [i for i in range(self.max_batch) if not mask[i]]
         if inactive:
             tables[inactive] = pool.oob
-        return tables
+        return pool.device_tables(tables)
 
     def _page_integrity_check(self) -> None:
         """Validate every active slot's table row against the allocator's
@@ -4688,6 +4748,8 @@ class ServingEngine:
             return
         if self._prefix_index is not None:
             self._prefix_index.evict_touching(pool, pages)
+        if pool.window is not None:  # its ring, before the free forgets it
+            self._pending_window_zero.extend(pool.window.slot_pages(idx))
         self._pending_page_zero.extend(self._free_slot_pages(idx))
 
     def _free_slot_pages(self, idx: int) -> list[int]:
@@ -4842,6 +4904,18 @@ class ServingEngine:
                     pages=np.asarray(chunk, np.int32),
                 ))
             self._dev_page_zero(chunk)
+        if self._pending_window_zero:
+            window, self._pending_window_zero = self._pending_window_zero, []
+            self._flush_window_zeros(window)
+
+    def _flush_window_zeros(self, pages: list[int]) -> None:
+        """One zero dispatch over the window group's quarantined pages: a
+        buffer as wide as every slot's ring, out-of-bounds padding drops."""
+        pool = self._pagepool
+        buf = np.full(self.max_batch * pool.window.ring, pool.window.oob, np.int32)
+        buf[: len(pages)] = pages
+        self._record_program("window-page-zero")
+        pool.dev = _window_page_zero(pool.dev, jnp.asarray(buf))
 
     def _dev_page_zero(self, pages) -> None:
         """Device layer of one quarantine page-zero dispatch (leader + SPMD
@@ -5922,7 +5996,10 @@ class ServingEngine:
                 top_ps=np.asarray([opts.top_p], np.float32),
             ))
         disp = st.get("disp")
-        if start:
+        # a model whose segments return expert counts fetches each one: a
+        # span a segment, with its own device_ms (else one for the stream)
+        per_segment = self.config.has_window
+        if start or per_segment:
             disp = st["disp"] = self._new_segment_dispatch(
                 "_paged_segment_and_sample", width, len(seg), request,
             )
@@ -5953,7 +6030,17 @@ class ServingEngine:
             ))
             return []
         st["seg"] += 1
+        if disp is not None and self.config.has_window:
+            disp.attrs.update(self._segment_window_attrs(s0, len(seg)))
         if not final:
+            if per_segment:  # nothing to deliver: the fetch lands the span
+                return [(
+                    "segment",
+                    self._fetcher.submit(
+                        first, self._dispatch_seq, self._moe_counts()
+                    ),
+                    disp,
+                )]
             return []  # more segments to go
 
         # final segment landed on device: activate the slot host-side
@@ -5977,7 +6064,8 @@ class ServingEngine:
         return [(
             "prefill",
             self._fetcher.submit(
-                first, disp.attrs["seq"] if disp is not None else 0
+                first, disp.attrs["seq"] if disp is not None else 0,
+                self._moe_counts() if per_segment else None,
             ),
             [(idx, request)], disp,
         )]
@@ -6015,6 +6103,8 @@ class ServingEngine:
             ))
         live = [slot for slot in self._slots if slot.active]
         pages_visited, rows_written = self._kv_page_counts(steps)
+        # a window row's pages move on to where this chunk's steps write
+        recycled = self._advance_window_rows(steps)
         disp = self._new_dispatch(
             "engine.decode_chunk",
             program="_paged_decode_chunk",
@@ -6025,6 +6115,7 @@ class ServingEngine:
             # (row, step) pairs whose recurrent state is updated, a linear
             # layer: the pairs that write a K/V row, idle rows move none
             **({"state_rows": rows_written} if self.config.is_recurrent else {}),
+            **self._decode_window_attrs(steps, recycled),
         )
         with jax.profiler.TraceAnnotation(
             "engine.decode_chunk", seq=self._dispatch_seq, steps=steps
@@ -6056,6 +6147,50 @@ class ServingEngine:
             steps * (slot.position + slot.ahead + 1) + steps * (steps - 1) // 2
             for slot in live
         )
+
+    def _advance_window_rows(self, steps: int) -> int:
+        """A model with window layers, before a decode chunk of ``steps``:
+        every active row's window pages move on to where the chunk writes
+        (`WindowPageGroup.advance`; the device's position leads the host's
+        by ``ahead``). Returns the pages recycled; 0 without window layers."""
+        pool = self._pagepool
+        if pool.window is None:
+            return 0
+        recycled = 0
+        for i, slot in enumerate(self._slots):
+            if slot.active:
+                first = slot.position + slot.ahead
+                recycled += pool.window_advance(i, first, first + steps - 1)
+        return recycled
+
+    def _decode_window_attrs(self, steps: int, recycled: int) -> dict:
+        """The decode chunk's span attributes of a model with window layers:
+        the pages `_advance_window_rows` recycled for it, and what the
+        WINDOW layers' attention has to read, the live length of
+        ``_kv_tokens_read`` capped by the window a (row, step)."""
+        if self._pagepool.window is None:
+            return {}
+        window = self._pagepool.window.window
+        read = sum(
+            int(np.minimum(slot.position + slot.ahead + 1 + np.arange(steps), window).sum())
+            for slot in self._slots if slot.active
+        )
+        return {"kv_tokens_read_window": read, "window_pages_recycled": recycled}
+
+    def _segment_window_attrs(self, s0: int, real: int) -> dict:
+        """The same two of a prefill segment of ``real`` tokens at offset
+        ``s0``, and the full layers' count beside them: real query i reads
+        s0 + i + 1 columns in a full layer, at most the window's in a window
+        layer (a last segment's padding queries are no work asked for)."""
+        lengths = s0 + 1 + np.arange(real)
+        return {
+            "offset": s0,
+            "kv_tokens_read": int(lengths.sum()),
+            "kv_tokens_read_window": int(
+                np.minimum(lengths, self._pagepool.window.window).sum()
+            ),
+            "window_pages_recycled": self._window_recycled,
+        }
 
     def _kv_page_counts(self, steps: int) -> tuple[int, int]:
         """(kv_pages_visited, kv_rows_written) of a decode chunk dispatched

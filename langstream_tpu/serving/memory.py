@@ -50,6 +50,12 @@ class ServingMemoryPlan:
     # rule's float32 state and the short convolution's tail, over its
     # linear layers): max_batch rows, whatever the sequences' lengths
     recurrent_state_bytes: int = 0
+    # a model with window layers keeps THEIR pages in a group of its own
+    # (pagepool.WindowPageGroup): a row holds a ring of the last
+    # ``sliding_window`` tokens and the dispatch in flight, whatever its
+    # length, so the group is max_batch rings and not max_batch contexts.
+    # ``page_pool_bytes`` is then the full layers' group alone
+    window_pool_bytes: int = 0
     # fused-iteration peak: with overlapped prefill–decode scheduling the
     # admission local cache (prefill_batch rows × the largest bucket width)
     # is live WHILE a decode chunk runs.
@@ -113,6 +119,7 @@ class ServingMemoryPlan:
             + self.workspace_bytes
             + self.fused_prefill_bytes
             + self.page_pool_bytes
+            + self.window_pool_bytes
             + self.recurrent_state_bytes
             + self.verify_chunk_bytes
             + self.adapter_pool_bytes
@@ -173,7 +180,11 @@ class ServingMemoryPlan:
         return (
             f"weights {self.weights_bytes / gib:.2f}GiB + "
             f"page-pool {self.page_pool_bytes / gib:.2f}GiB + "
-            f"fused-prefill {self.fused_prefill_bytes / gib:.2f}GiB + "
+            + (
+                f"window-pool {self.window_pool_bytes / gib:.2f}GiB + "
+                if self.window_pool_bytes else ""
+            )
+            + f"fused-prefill {self.fused_prefill_bytes / gib:.2f}GiB + "
             f"verify-chunk {self.verify_chunk_bytes / gib:.2f}GiB + "
             f"{self._agentic_summary()}"
             f"workspace {self.workspace_bytes / gib:.2f}GiB = "
@@ -194,6 +205,7 @@ def plan_serving_memory(
     page_size: int = 64,
     kv_pages: int = 0,
     page_fraction: float = 0.0,
+    window_in_flight: int = 0,
     host_kv_fraction: float = 0.0,
     adapter_pool_rows: int = 0,
     adapter_rank: int = 0,
@@ -219,6 +231,10 @@ def plan_serving_memory(
     The KV state is ONE page-pool term (serving/pagepool.py): ``kv_pages``
     pages of ``page_size`` tokens, or ``pages_for_fraction(max_batch,
     max_seq_len, page_size, page_fraction)`` when kv_pages is 0.
+    A model with window layers has a second term, their group:
+    ``pagepool.window_group_pages``, ``max_batch`` rings of the window and
+    ``window_in_flight`` positions (the widest prefill segment or decode
+    chunk: what one dispatch writes a row).
     ``host_kv_fraction``: tiered-KV host arena pages relative to the
     device pool (``ceil(pages × fraction)``, same per-page bytes) — the
     ``host_spill_bytes`` term is HOST RAM, reported but excluded from the
@@ -261,15 +277,22 @@ def plan_serving_memory(
     from langstream_tpu.serving.pagepool import (
         pages_for_fraction,
         table_len_for,
+        window_group_pages,
     )
 
     num_pages = kv_pages or pages_for_fraction(
         max_batch, max_seq_len, page_size, page_fraction
     )
+    window_pages = window_group_pages(
+        config, max_batch, max_seq_len, page_size, window_in_flight
+    )[0]
     pool_shape = jax.eval_shape(
-        lambda: make_page_pool(config, num_pages, page_size, state_rows=max_batch)
+        lambda: make_page_pool(
+            config, num_pages, page_size, state_rows=max_batch, window_pages=window_pages
+        )
     )
     state_bytes = _tree_bytes(pool_shape.pop("rec", None))
+    window_bytes = _tree_bytes(pool_shape.pop("win", None))
     pool_bytes = _tree_bytes(pool_shape)
     host_spill_bytes = 0
     if host_kv_fraction > 0:
@@ -319,6 +342,7 @@ def plan_serving_memory(
         fused_prefill_bytes=_tree_bytes(fused_shape) if fused_shape else 0,
         page_pool_bytes=pool_bytes,
         recurrent_state_bytes=state_bytes,
+        window_pool_bytes=window_bytes,
         host_spill_bytes=host_spill_bytes,
         migrate_staging_bytes=migrate_staging_bytes,
         weight_load_staging_bytes=max(0, int(weight_load_staging)),

@@ -142,6 +142,138 @@ def pages_for_fraction(
     return base + extra
 
 
+def window_ring_pages(window: int, in_flight: int, page_size: int) -> int:
+    """Pages a window row can need at once: the columns its dispatch's first
+    query can still see and those the dispatch writes, ``window + in_flight
+    - 1`` of them, wherever their first one lies in its page."""
+    return (window + in_flight + page_size - 3) // page_size + 1
+
+
+def window_group_pages(
+    config: Any, max_batch: int, max_seq_len: int, page_size: int, in_flight: int,
+) -> tuple[int, int]:
+    """(pages, ring) of a model's window group: ``max_batch`` rings, each
+    the window and the ``in_flight`` positions one dispatch writes a row, or
+    a row's whole table where that is shorter. (0, 0) without window layers.
+    The one sizing rule: the pool and the memory plan both read it."""
+    if not config.has_window:
+        return 0, 0
+    ring = window_ring_pages(config.sliding_window, max(1, int(in_flight)), page_size)
+    return max_batch * min(ring, table_len_for(max_seq_len, page_size)), ring
+
+
+class WindowPageGroup:
+    """The page group of a model's WINDOW layers: its own pages, free list
+    and table, beside the full layers' (`PagePool`). The table is indexed by
+    the logical page like theirs, but a row maps only a RING: the pages that
+    hold the last ``window`` positions and the dispatch in flight. A row is
+    bound with as many pages as it can ever hold at once (``ring`` of them,
+    or its whole length if that is less) and never allocates again: before
+    every dispatch `advance` unmaps the pages that lie wholly behind the
+    dispatch's first visible column and maps them again, ahead, where the
+    dispatch writes (a recycle; the page's old rows are overwritten before
+    the causal mask reaches them, as a fresh page's are). A row that never
+    passes the window never recycles."""
+
+    def __init__(self, num_pages: int, page_size: int, max_batch: int,
+                 table_len: int, window: int, ring: int) -> None:
+        if num_pages < 1 or ring < 1:
+            raise ValueError("a window page group needs >= 1 page and a ring of >= 1")
+        self.num_pages, self.page_size = int(num_pages), int(page_size)
+        self.window, self.ring = int(window), int(ring)
+        self.oob = self.num_pages
+        self.tables = np.full((max_batch, table_len), self.oob, np.int32)
+        self._free = list(range(self.num_pages - 1, -1, -1))
+        # authoritative, a slot: {logical page: physical page} of what is
+        # mapped, the pages held and never mapped yet, those unmapped behind
+        # the window, and the logical pages the row may ever write (its
+        # reservation's length)
+        self._mapped: dict[int, dict[int, int]] = {}
+        self._fresh: dict[int, list[int]] = {}
+        self._spare: dict[int, list[int]] = {}
+        self._limit: dict[int, int] = {}
+        self.recycled_total = 0
+        self.peak_in_use = 0  # since the engine last reset it (stats' gauge)
+
+    @property
+    def free_pages(self) -> int:
+        return len(self._free)
+
+    @property
+    def pages_in_use(self) -> int:
+        return self.num_pages - len(self._free)
+
+    def pages_needed(self, n_pages: int) -> int:
+        """Window pages for a row of ``n_pages`` logical pages."""
+        return min(int(n_pages), self.ring)
+
+    def reserve(self, slot: int, n_pages: int) -> bool:
+        """Hold ``pages_needed(n_pages)`` pages for ``slot``, none mapped yet
+        (`advance` maps them). False, nothing taken, if they are not free."""
+        assert slot not in self._mapped, slot
+        want = self.pages_needed(n_pages)
+        if want > len(self._free):
+            return False
+        self._fresh[slot] = [self._free.pop() for _ in range(want)]
+        self.peak_in_use = max(self.peak_in_use, self.pages_in_use)
+        self._spare[slot] = []
+        self._mapped[slot] = {}
+        self._limit[slot] = int(n_pages)
+        return True
+
+    def advance(self, slot: int, first_pos: int, last_pos: int) -> int:
+        """Before a dispatch whose queries sit at ``first_pos .. last_pos``:
+        map the pages of columns ``first_pos - window + 1 .. last_pos``
+        (inside the row's reservation), unmapping those behind first. Returns
+        the pages recycled: mapped again after having held other columns."""
+        mapped = self._mapped.get(slot)
+        if mapped is None:
+            return 0
+        fresh, spare, row = self._fresh[slot], self._spare[slot], self.tables[slot]
+        lo = max(first_pos - self.window + 1, 0) // self.page_size
+        hi = min(last_pos // self.page_size, self._limit[slot] - 1)
+        for logical in [p for p in mapped if p < lo]:
+            spare.append(mapped.pop(logical))
+            row[logical] = self.oob
+        recycled = 0
+        for logical in range(lo, hi + 1):
+            if logical in mapped:
+                continue
+            assert fresh or spare, (slot, first_pos, last_pos, self.ring)
+            # a row's first pass over its pages is no recycle
+            recycled += not fresh
+            mapped[logical] = row[logical] = (fresh or spare).pop()
+        self.recycled_total += recycled
+        return recycled
+
+    def slot_pages(self, slot: int) -> list[int]:
+        return [
+            *self._mapped.get(slot, {}).values(), *self._spare.get(slot, ()),
+            *self._fresh.get(slot, ()),
+        ]
+
+    def free_slot(self, slot: int) -> list[int]:
+        pages = self.slot_pages(slot)
+        for held in (self._mapped, self._fresh, self._spare, self._limit):
+            held.pop(slot, None)
+        self.tables[slot, :] = self.oob
+        self._free.extend(pages)
+        return pages
+
+    def validate(self, slot: int) -> bool:
+        """The device-facing row against what the allocator says is mapped."""
+        want = np.full(self.tables.shape[1], self.oob, np.int32)
+        for logical, page in self._mapped.get(slot, {}).items():
+            want[logical] = page
+        return bool(np.array_equal(self.tables[slot], want))
+
+    def reset(self) -> None:
+        self.tables[:] = self.oob
+        self._free = list(range(self.num_pages - 1, -1, -1))
+        for held in (self._mapped, self._fresh, self._spare, self._limit):
+            held.clear()
+
+
 class PagePool:
     """Device page pool + free-list allocator + per-slot page tables."""
 
@@ -152,8 +284,12 @@ class PagePool:
         page_size: int,
         max_batch: int,
         max_seq_len: int,
+        window_in_flight: int = 0,
     ) -> None:
-        from langstream_tpu.models.transformer import make_page_pool
+        """``window_in_flight``: for a model with window layers, the most
+        positions one dispatch writes a row (the widest prefill segment or
+        decode chunk): it sizes the ring of their group, which holds
+        ``max_batch`` of them (`window_group_pages`)."""
 
         if num_pages < 1 or page_size < 1:
             raise ValueError("page pool needs >= 1 page of >= 1 token")
@@ -170,16 +306,29 @@ class PagePool:
         # dispatch zeroes a freed row: the next admission writes it whole
         # from the zero state (the admit group) or starts its first
         # segment from zero, so nothing of the old state is ever read
-        self.dev = make_page_pool(
-            config, self.num_pages, self.page_size, state_rows=self.max_batch
-        )
+        # a model with window layers: their pages are a group of their own
+        # (``dev["win"]``, `WindowPageGroup`), reserved with the full
+        # group's in `reserve` and freed with them in `free_slot`
+        self.window: Optional[WindowPageGroup] = None
+        if config.has_window:
+            pages, ring = window_group_pages(
+                config, self.max_batch, max_seq_len, self.page_size, window_in_flight
+            )
+            self.window = WindowPageGroup(
+                pages, self.page_size, self.max_batch, self.table_len,
+                config.sliding_window, ring,
+            )
+        self._window_pages = self.window.num_pages if self.window else 0
+        self.dev = self._make_dev()
 
         def nbytes(tree) -> int:
             return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
 
         self.state_bytes_total = nbytes(self.dev.get("rec"))
         self.state_bytes_per_row = self.state_bytes_total // self.max_batch
-        self.bytes_total = nbytes(self.dev) - self.state_bytes_total
+        self.window_bytes_total = nbytes(self.dev.get("win"))
+        self.window_bytes_per_page = self.window_bytes_total // max(1, self._window_pages)
+        self.bytes_total = nbytes(self.dev) - self.state_bytes_total - self.window_bytes_total
         self.bytes_per_page = self.bytes_total // self.num_pages
         self.tables = np.full(
             (self.max_batch, self.table_len), self.oob, np.int32
@@ -195,6 +344,48 @@ class PagePool:
         # allocation (live refcounts read 0 the moment a burst drains)
         self.reserved_pages_total = 0
         self.aliased_pages_total = 0
+
+    def _make_dev(self):
+        from langstream_tpu.models.transformer import make_page_pool
+
+        return make_page_pool(
+            self.config, self.num_pages, self.page_size, state_rows=self.max_batch,
+            window_pages=self._window_pages,
+        )
+
+    def device_tables(self, full: np.ndarray) -> np.ndarray:
+        """What a decode dispatch takes as its table, from the full group's
+        rows as the caller masked them: those [B, Tp], or for a model with
+        window layers both groups' [2, B, Tp] (models/transformer `FULL`,
+        `WINDOW`), a row that is all sentinel masked in the window group
+        too."""
+        if self.window is None:
+            return full
+        masked = (full == self.oob).all(axis=1, keepdims=True)
+        return np.stack([full, np.where(masked, self.window.oob, self.window.tables)])
+
+    def rows_tables(self, slots) -> np.ndarray:
+        """The table of an admit group or a segment: row j is slot
+        ``slots[j]``'s, all sentinel where that is out of range (padding,
+        a warm-up); [n, Tp], or both groups' [2, n, Tp]."""
+        out = []
+        for group in (self, self.window):
+            if group is None:
+                continue
+            tables = np.full((len(slots), self.table_len), group.oob, np.int32)
+            for j, s in enumerate(slots):
+                if 0 <= s < self.max_batch:
+                    tables[j] = group.tables[s]
+            out.append(tables)
+        return out[0] if self.window is None else np.stack(out)
+
+    def window_advance(self, slot: int, first_pos: int, last_pos: int) -> int:
+        """`WindowPageGroup.advance` before a dispatch whose queries of
+        ``slot`` sit at ``first_pos .. last_pos``; 0 for a model without
+        window layers."""
+        if self.window is None:
+            return 0
+        return self.window.advance(slot, first_pos, last_pos)
 
     # -- sizing ---------------------------------------------------------------
 
@@ -274,9 +465,13 @@ class PagePool:
         assert n_pages <= self.table_len
         want = n_pages - len(shared)
         assert want >= 0, (n_pages, len(shared))
+        if self.window is not None and self.window.pages_needed(n_pages) > self.window.free_pages:
+            return None
         fresh = self._alloc(want)
         if fresh is None:
             return None
+        if self.window is not None:
+            self.window.reserve(slot, n_pages)
         self.reserved_pages_total += n_pages
         self.aliased_pages_total += len(shared)
         self.incref(shared)
@@ -295,6 +490,8 @@ class PagePool:
         Returns the pages whose refcount hit zero."""
         owned = self._owned.pop(slot, None)
         self.tables[slot, :] = self.oob
+        if self.window is not None:
+            self.window.free_slot(slot)
         if not owned:
             return []
         return self.decref(owned)
@@ -310,17 +507,16 @@ class PagePool:
         return bool(
             np.array_equal(row[:n], np.asarray(owned, np.int32))
             and np.all(row[n:] == self.oob)
+            and (self.window is None or self.window.validate(slot))
         )
 
     def reset(self) -> None:
         """Crash recovery: rebuild the device pool and forget every binding
         (the engine fails the in-flight slots; prefix entries are reset by
         their index)."""
-        from langstream_tpu.models.transformer import make_page_pool
-
-        self.dev = make_page_pool(
-            self.config, self.num_pages, self.page_size, state_rows=self.max_batch
-        )
+        self.dev = self._make_dev()
+        if self.window is not None:
+            self.window.reset()
         self.tables[:] = self.oob
         self._refs[:] = 0
         self._free = list(range(self.num_pages - 1, -1, -1))

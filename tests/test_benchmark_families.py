@@ -4,7 +4,10 @@ configuration maps onto, the seeded trees bit-equal to what they were, what
 the program's block cannot express refused, every family's two files) and of
 `benchmark/tests/test_olmo_hybrid_family.py` (Olmo-Hybrid: the README's
 contract, the mapping, the refusals, the seeded tree, the update's cost, the
-state probe) run here as they stand there. The check's verdicts (an engine a case) stay with the
+state probe) and of `benchmark/tests/test_cohere2_moe_family.py` (Command A+:
+the contract, the mapping and the stated cut, the cell's sizing, the
+refusals, the seeded tree, the costs and the readers) run here as they stand
+there. The check's verdicts (an engine a case) stay with the
 harness's own suite, run by hand: `python -m pytest benchmark/tests`."""
 
 import importlib.util
@@ -17,7 +20,8 @@ for path in (str(BENCH.parent), str(BENCH)):
         sys.path.insert(0, path)
 
 # the cases that build an engine and run the whole check: minutes, by hand
-BY_HAND = ("test_sound_system_passes_with_room", "test_known_fault_fails_by_a_number")
+BY_HAND = ("test_sound_system_passes_with_room", "test_known_fault_fails_by_a_number",
+           "test_the_tiny_cell_end_to_end_traced")
 
 
 def _cases(file: str) -> dict:
@@ -32,3 +36,4 @@ def _cases(file: str) -> dict:
 
 globals().update(_cases("test_families"))
 globals().update(_cases("test_olmo_hybrid_family"))
+globals().update(_cases("test_cohere2_moe_family"))

@@ -492,7 +492,31 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
     assert len(linear) == 5 and linear <= texts["hybrid"]
     assert {"attention", "ffn", "kv_pool.write"} <= texts["hybrid"]  # its full layers
     assert not linear & (texts["dense"] | texts["moe"])
-    assert set(T.SCOPES) <= texts["dense"] | texts["moe"] | texts["hybrid"]
+    # a model of window and full layers with an expert layer that holds a
+    # share: each kind's attention under its own scope, the shared experts'
+    window = MODEL_PRESETS["tiny-window-moe-test"]
+    window_params = T.init_params(window, jax.random.PRNGKey(3))
+    both = jnp.stack([table, table])
+
+    def window_segment(params, pool):
+        return T.paged_prefill_segment_inplace(
+            params, tokens[:1], zeros[:1], lengths[:1], pool, both[:, :1], window, page
+        )
+
+    def window_decode(params, pool):
+        return E._paged_decode_chunk(
+            params, tokens[:, 0], lengths, pool, both, key, ones, zeros, ones, 2, window, page,
+        )
+
+    window_pool = T.make_page_pool(window, 8, page, window_pages=8)
+    texts["window"] = lowered_scopes(window_segment, window_params, window_pool) | (
+        lowered_scopes(window_decode, window_params, window_pool)
+    )
+    kinds = {"attention.window", "attention.full", "moe_ffn.shared"}
+    assert kinds | {"attention", "moe_ffn", "moe_ffn.route", "moe_ffn.dispatch",
+                    "moe_ffn.experts", "moe_ffn.combine", "kv_pool.write"} <= texts["window"]
+    assert not kinds & (texts["dense"] | texts["moe"] | texts["hybrid"])
+    assert set(T.SCOPES) <= texts["dense"] | texts["moe"] | texts["hybrid"] | texts["window"]
     assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
     assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",
             "moe_ffn.combine"} <= texts["moe"] and "ffn" not in texts["moe"]

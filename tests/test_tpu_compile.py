@@ -147,7 +147,76 @@ def _delta_update(config, batch, layers):
     )
 
 
+# command-a-plus-05-2026 as the benchmark cuts it (`tiny-window-moe-test`'s
+# block at the published widths): 128 Q / 8 KV heads x 128, a window of 4096,
+# 16 held experts of 4096 x 4096 in int8; the cell: 16 slots x 196 pages
+CMDA = dataclasses.replace(
+    MODEL_PRESETS["tiny-window-moe-test"], name="cmdaplus-widths", d_model=4096, d_ff=4096,
+    n_heads=128, n_kv_heads=8, head_dim=128, sliding_window=4096, n_experts=128,
+    n_experts_per_tok=8, n_shared_experts=4, experts_held=(0, 16), vocab_size=32768,
+)
+
+
+def _windowed_decode(config, batch, table, pages, layers):
+    """The paged decode kernel over a window layer's page group: a lower
+    bound a row beside its length."""
+    fn, (q, k, v, lengths, tab, layer) = _paged(
+        config, False, batch=batch, table=table, pages=pages, layers=layers
+    )
+    return (
+        lambda q, k, v, lengths, lower, tab, layer: A.ragged_paged_decode_attention(
+            q, k, v, lengths, tab, layer, config, PAGE, lower=lower
+        ),
+        (q, k, v, lengths, lengths, tab, layer),
+    )
+
+
+def _segment(config, s, t, window):
+    """A prefill segment's attention over its row's gathered columns."""
+    bf16 = lambda *shape: SDS(shape, jnp.bfloat16)  # noqa: E731
+    hd = config.resolved_head_dim
+    return (
+        lambda q, k, v, offsets: A.flash_segment_attention(
+            q, k, v, offsets, config, window=window
+        ),
+        (bf16(1, s, config.n_heads, hd), bf16(1, config.n_kv_heads, t, hd),
+         bf16(1, config.n_kv_heads, t, hd), SDS((1,), jnp.int32)),
+    )
+
+
+def _grouped(config, tokens, layers, down=False):
+    """The held experts' product over the rows ``tokens`` tokens route here:
+    the whole int8 stack and a layer index, as the layer scan hands it on."""
+    from langstream_tpu.ops import grouped_matmul as gm
+
+    held, k = config.held_experts[1], config.n_experts_per_tok
+    tile = gm.row_tile(tokens, k, config.n_experts)
+    tiles = gm.buffer_tiles(tokens, k, held, tile)
+    d, f = (config.expert_d_ff, config.d_model) if down else (config.d_model, config.expert_d_ff)
+    w = {"q": SDS((layers, held, d, f), jnp.int8), "s": SDS((layers, held, 1, f), jnp.float32)}
+    return (
+        lambda x, w, layer, tile_expert, used: gm.grouped_matmul(
+            x, w, layer, tile_expert, used, tile, kernel=True
+        ),
+        (SDS((tiles * tile, d), jnp.bfloat16), w, SDS((), jnp.int32),
+         SDS((tiles,), jnp.int32), SDS((1,), jnp.int32)),
+    )
+
+
 CASES = {
+    # the command-a-plus cell: both page groups' decode (2 full layers x 3136
+    # pages; 6 window layers x 1552 pages with a lower bound), a 2048-token
+    # segment against the row's 12,544 columns with and without the window,
+    # and the grouped expert product of a decode step (16 tokens, tiles of 16
+    # rows) and of a segment (2048 tokens, tiles of 256)
+    "cmdaplus16x196-paged-decode": _paged(CMDA, False, batch=16, table=196, pages=3136, layers=2),
+    "cmdaplus16x196-windowed-decode": _windowed_decode(CMDA, 16, 196, 1552, 6),
+    "cmdaplus16x196-paged-kv-write": _kv_write(CMDA, batch=16, pages=1552, layers=6, table=196),
+    "cmdaplus-segment-2048": _segment(CMDA, 2048, 12544, 0),
+    "cmdaplus-window-segment-2048": _segment(CMDA, 2048, 12544, 4096),
+    "cmdaplus-grouped-matmul-16": _grouped(CMDA, 16, 6),
+    "cmdaplus-grouped-matmul-2048": _grouped(CMDA, 2048, 6),
+    "cmdaplus-down-grouped-matmul-2048": _grouped(CMDA, 2048, 2, down=True),
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
     **{f"gemma-prefill-{s}": _prefill(GEMMA, s) for s in (512, 1024, 2048)},
@@ -192,6 +261,11 @@ def _kernel_of(case: str) -> str:
         "paged-decode-int8": "ragged_paged_decode_attention_int8",
         "paged-kv-write": "paged_kv_write",
         "gated-delta-update": "gated_delta_update",
+        "windowed-decode": "ragged_paged_decode_attention",
+        "segment": "flash_segment_attention",
+        "window-segment": "flash_segment_attention",
+        "grouped-matmul": "moe_grouped_matmul",
+        "down-grouped-matmul": "moe_grouped_matmul",
     }[kind]
 
 
