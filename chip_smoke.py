@@ -85,8 +85,8 @@ def kernel_checks(
     """Each main-path kernel at ``config``'s head widths against the
     jax.numpy reference of tests/test_pallas_ops.py (``attention`` over an
     explicit mask, in float32 at "highest" matmul precision; int8 caches
-    dequantized first; the pool's write against ``_paged_scatter``, where
-    any difference is an error). ``paged`` = (B, page, pages
+    dequantized first; the pool's writes, a step's rows and an admission
+    group's pages, against the scatters, where any difference is an error). ``paged`` = (B, page, pages
     per row): rows of unequal length, every third one inactive. Returns
     one ``{kernel, max_abs_err}`` per check."""
     import jax
@@ -101,9 +101,11 @@ def kernel_checks(
         _paged_scatter,
         _quantize_kv,
         attention,
+        paged_insert_cache,
     )
     from langstream_tpu.ops.attention import (
         flash_prefill_attention,
+        paged_insert_pages,
         paged_kv_write,
         ragged_paged_decode_attention,
         ragged_paged_decode_attention_int8,
@@ -194,6 +196,20 @@ def kernel_checks(
             f"paged_kv_write[{leaf},b={b},page={page}]", got,
             _paged_scatter(pool, layer, rows[:, :, None], table, pos, page)
             .astype(jnp.float32),
+        )
+    # an admission group's prefill into that pool, every layer: the kernel's
+    # page copies against the scatter that stays its reference (the idle
+    # rows and the pages a row does not hold drop)
+    lk, lv = rand(2, b, hkv, per_row * page, d), rand(2, b, hkv, per_row * page, d)
+    inserted = paged_insert_pages((lk, lv), kp, vp, table, interpret=interpret)
+    scattered = paged_insert_cache(
+        {"k": kp, "v": vp}, {"k": lk, "v": lv}, table, page,
+        dataclasses.replace(config, attention_impl="jnp"),
+    )
+    for leaf, got in zip("kv", inserted):
+        check(
+            f"paged_insert_pages[{leaf},b={b},page={page}]", got,
+            scattered[leaf].astype(jnp.float32),
         )
     kp8, vp8 = (dict(zip("qs", _quantize_kv(x))) for x in (kp, vp))
     check(

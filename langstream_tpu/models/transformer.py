@@ -2026,13 +2026,53 @@ def paged_prefill_segment_inplace(
     return logits, pool
 
 
+def insert_copies_pages(
+    pool: KVCache, width: int, page_size: int, config: Optional[ModelConfig] = None
+) -> bool:
+    """Whether `paged_insert_cache` writes a local cache of ``width`` columns
+    into ``pool`` by whole pages (``ops/attention.paged_insert_pages``): one
+    page group whose leaves hold values alone (an int8 pool's scales are
+    words scattered over a page, no copy Mosaic takes), a width of whole
+    pages, no mesh (``config.kernel_mesh``: GSPMD cannot partition the
+    kernel), and where the paged decode kernel runs (``paged_pallas_ok``).
+    Without a ``config`` (a caller off the engine, which holds no mesh) the
+    gates are those of ``attention_impl: auto``."""
+    from langstream_tpu.ops.attention import paged_pallas_ok, paged_tiles_ok
+
+    k = pool["k"]
+    if "win" in pool or isinstance(k, dict) or width % page_size:
+        return False
+    if config is None:
+        return paged_tiles_ok(k.shape[-1], page_size)
+    return config.kernel_mesh is None and paged_pallas_ok(config, page_size)
+
+
 def paged_insert_cache(
-    pool: KVCache, local_cache: KVCache, tables: jax.Array, page_size: int
+    pool: KVCache, local_cache: KVCache, tables: jax.Array, page_size: int,
+    config: Optional[ModelConfig] = None,
 ) -> KVCache:
-    """Scatter a batched prefill's local cache ([L, n, Hkv, W, D], the
+    """Write a batched prefill's local cache ([L, n, Hkv, W, D], the
     admit-group temporary) into each row's pages. Positions are [0, W) per row; rows whose
-    table is all out-of-bounds (padding) drop every write."""
+    table is all out-of-bounds (padding) drop every write. Where
+    `insert_copies_pages` says so (on the chip: a bf16 pool of one page
+    group, no mesh) the write is a copy of each mapped page where the pool
+    lies, ``ops/attention.paged_insert_pages``; the int8 pool, a window
+    model's two groups, a mesh and every backend but the TPU keep the
+    scatter below, one update a (row, kv head, position), which is that
+    write's reference: the pools are bit-equal. ``config``: the engine's,
+    for its mesh and ``attention_impl``."""
     n = tables.shape[0]
+    width = jax.tree.leaves(local_cache)[0].shape[3]
+    if insert_copies_pages(pool, width, page_size, config):
+        from langstream_tpu.ops.attention import paged_insert_pages
+
+        kv, rec = split_rec(pool)
+        with jax.named_scope("kv_pool.write"):
+            k, v = paged_insert_pages(
+                (local_cache["k"], local_cache["v"]), kv["k"], kv["v"], tables,
+                interpret=jax.default_backend() != "tpu",
+            )
+        return join_rec({"k": k, "v": v}, rec)
 
     def put(pl_entry, loc):
         w = loc.shape[3]

@@ -18,6 +18,9 @@ blocks are naturally aligned, and a per-head kv block is a contiguous
 - ``paged_kv_write``: that step's new K and V rows into the bf16 pool where
   it lies, a copy per live row (a scatter pays per (row, kv head), dropped
   rows included).
+- ``paged_insert_pages``: an admission group's prefilled K and V into the
+  pool where it lies, one copy HBM → HBM a (layer, row, mapped page) and
+  leaf (a scatter has the whole pool relaid for its window, there and back).
 
 No reference counterpart (the reference's compute is remote HTTP calls);
 kernel structure follows the public flash/paged-attention pattern from the
@@ -917,6 +920,146 @@ def paged_kv_write(
     return out[0].reshape(k.shape), out[1].reshape(v.shape)
 
 
+# Page copies of a leaf in flight together in `paged_insert_pages`: a copy
+# waits for the one that used its semaphore this many copies earlier.
+_INSERT_IN_FLIGHT = 32
+
+
+def _paged_insert_pages_kernel(
+    table_ref,  # scalar-prefetch [n * W/ps]: row r's logical page p at r * W/ps + p
+    k_loc,  # the prefill's local cache [L, n, Hkv, W, D] in HBM
+    v_loc,
+    _k_in,  # the pool leaves [L*P, Hkv, ps, D] in HBM, aliased to the outputs
+    _v_in,
+    k_pool,
+    v_pool,
+    sems,  # DMA [2, _INSERT_IN_FLIGHT]
+    live,  # SMEM [n * W/ps]
+    *,
+    num_pages: int,
+):
+    layers, _, _, width, _ = k_loc.shape
+    page_size = k_pool.shape[2]
+    per_row = width // page_size
+    in_flight = sems.shape[1]
+    leaves = ((k_loc, k_pool), (v_loc, v_pool))
+
+    # the mapped entries, compacted: a page the row does not hold (or a
+    # padding row's, the sentinel) costs this loop's iteration and no byte
+    def collect(e, n):
+        live[n] = e
+        page = table_ref[e]
+        return n + ((page >= 0) & (page < num_pages)).astype(jnp.int32)
+
+    n_live = jax.lax.fori_loop(0, table_ref.shape[0], collect, jnp.int32(0))
+
+    def copies(layer, row, col, page, slot):
+        # a pool page is the contiguous [Hkv, ps, D]; its source is Hkv runs
+        # of ps x D, strided by the local cache's width
+        start = pl.multiple_of(col * page_size, page_size)
+        return [
+            pltpu.make_async_copy(
+                loc.at[layer, row, :, pl.ds(start, page_size), :],
+                pool.at[layer * num_pages + page],
+                sems.at[leaf, slot],
+            )
+            for leaf, (loc, pool) in enumerate(leaves)
+        ]
+
+    def wait(slot):
+        # every copy moves one page: any page's descriptor waits for a slot
+        for copy in copies(0, 0, 0, 0, slot):
+            copy.wait()
+
+    def entry(j, carry):
+        e = live[j]
+        page = table_ref[e]
+
+        def layer_copy(layer, carry):
+            issued = j * layers + layer
+            slot = issued % in_flight
+
+            @pl.when(issued >= in_flight)
+            def _():
+                wait(slot)
+
+            for copy in copies(layer, e // per_row, e % per_row, page, slot):
+                copy.start()
+            return carry
+
+        return jax.lax.fori_loop(0, layers, layer_copy, carry)
+
+    # no two mapped entries name one page, so no two copies overlap
+    jax.lax.fori_loop(0, n_live, entry, 0)
+
+    def drain(slot, carry):
+        wait(slot)
+        return carry
+
+    jax.lax.fori_loop(0, jnp.minimum(n_live * layers, in_flight), drain, 0)
+
+
+def paged_insert_pages(
+    new: tuple[jax.Array, jax.Array],  # a prefill's local K and V [L, n, Hkv, W, D]
+    k: jax.Array,  # the page pool [L, P, Hkv, ps, D], every layer written
+    v: jax.Array,
+    table: jax.Array,  # [n, Tp] each row's pages; outside [0, P) is unmapped
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """Write row ``r``'s columns ``[p * ps, (p + 1) * ps)`` of every layer to
+    page ``table[r, p]`` of the pool where it lies (the leaves are aliased
+    to the outputs and seen as [L·P, ...]) and give both leaves back: ONE
+    copy HBM → HBM a (layer, row, mapped page) and leaf, ``_INSERT_IN_FLIGHT``
+    of them in flight. ``W`` is a whole number of pages. An entry outside
+    the pool (a padding or warm-up row, a page the row does not hold, a
+    logical page past the table) DROPS at no copy; every byte of the pool
+    other than the mapped pages is neither read nor written. All layers in
+    one call: the caller holds the whole local cache, and a custom call's
+    operand is materialised, so no layer scan may hand this a slice."""
+    layers, n, hkv, width, d = new[0].shape
+    num_pages, page_size = k.shape[1], k.shape[3]
+    assert width % page_size == 0 and k.shape == (layers, num_pages, hkv, page_size, d)
+    per_row = width // page_size
+    # the logical pages the local cache covers, the sentinel past the table
+    table = table.astype(jnp.int32)[:, :per_row]
+    table = jnp.pad(
+        table, ((0, 0), (0, per_row - table.shape[1])), constant_values=num_pages
+    )
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
+    flat = [_flat_pool(leaf) for leaf in (k, v)]
+    out = pl.pallas_call(
+        functools.partial(_paged_insert_pages_kernel, num_pages=num_pages),
+        name="paged_insert_pages",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[hbm, hbm, hbm, hbm],
+            out_specs=[hbm, hbm],
+            scratch_shapes=[
+                pltpu.SemaphoreType.DMA((2, _INSERT_IN_FLIGHT)),
+                pltpu.SMEM((n * per_row,), jnp.int32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype) for leaf in flat],
+        # operands 3 and 4 (after the prefetched table and the two local
+        # leaves) are the pool: written in place
+        input_output_aliases={3: 0, 4: 1},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(table.reshape(-1), *(loc.astype(k.dtype) for loc in new), *flat)
+    return out[0].reshape(k.shape), out[1].reshape(v.shape)
+
+
+def paged_tiles_ok(head_dim: int, page_size: int) -> bool:
+    """``attention_impl: auto``'s gate of the paged kernels: a real TPU, and
+    the Mosaic tiling constraints on a page's (page_size, D) block dims."""
+    return (
+        jax.default_backend() == "tpu"
+        and head_dim % 128 == 0
+        and page_size % 16 == 0
+    )
+
+
 def paged_pallas_ok(config: ModelConfig, page_size: int) -> bool:
     """True when the ragged-paged decode kernel should carry the paged
     decode read. ``attention_impl="pallas"`` forces it (interpret mode
@@ -931,11 +1074,7 @@ def paged_pallas_ok(config: ModelConfig, page_size: int) -> bool:
         return False
     if config.attention_impl == "pallas":
         return page_size % 8 == 0
-    return (
-        jax.default_backend() == "tpu"
-        and config.resolved_head_dim % 128 == 0
-        and page_size % 16 == 0
-    )
+    return paged_tiles_ok(config.resolved_head_dim, page_size)
 
 
 # ---------------------------------------------------------------------------

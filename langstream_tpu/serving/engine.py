@@ -38,6 +38,7 @@ from langstream_tpu.models.configs import GenerationOptions, ModelConfig
 from langstream_tpu.models.transformer import (
     MOE_COUNTS,
     moe_count_names,
+    insert_copies_pages,
     join_rec,
     make_kv_cache,
     paged_decode_step_inplace,
@@ -589,14 +590,15 @@ def admit_rungs(prefill_batch: int) -> tuple[int, ...]:
 
 def _make_paged_admit_group(mesh=None):
     """Factory for the FUSED admission step: local-cache zeros + batched
-    prefill + first-token sample + PAGE scatter + every decode-chain
+    prefill + first-token sample + page copies + every decode-chain
     scatter in ONE dispatch (the unfused path made ~14 host→device ops;
     fused + packed uploads ≈ 4). The prefill is the model-level ``prefill``
-    over a local cache (the token-exactness reference); its rows then
-    scatter into each slot's mapped pages. Padding rows carry
-    all-out-of-bounds tables, so their writes drop. Under a mesh the
-    transient local cache is constrained so the page scatter stays
-    shard-local."""
+    over a local cache (the token-exactness reference); its rows' pages
+    are then copied into each slot's mapped pages (``paged_insert_cache``:
+    page copies on the chip, a scatter for the int8 pool, a window model
+    and under a mesh). Padding rows carry all-out-of-bounds tables, so
+    their writes drop. Under a mesh the transient local cache is
+    constrained so the page scatter stays shard-local."""
     @functools.partial(
         jax.jit,
         static_argnames=("config", "page_size"),
@@ -645,7 +647,7 @@ def _make_paged_admit_group(mesh=None):
         )
         if s1 is not None:
             state_dev = state_dev.at[slots].set(s1, mode="drop")
-        pool = paged_insert_cache(pool, local_cache, tables, page_size)
+        pool = paged_insert_cache(pool, local_cache, tables, page_size, config)
         tokens_dev = tokens_dev.at[slots].set(first, mode="drop")
         positions_dev = positions_dev.at[slots].set(lengths, mode="drop")
         temp_dev = temp_dev.at[slots].set(temps, mode="drop")
@@ -4163,6 +4165,7 @@ class ServingEngine:
             real_tokens=sum(len(r.prompt_tokens) for _, r in group),
             computed_tokens=n_pad * width,
             trace_ids=[r.trace_id for _, r in group],
+            kv_pages_written=self._kv_pages_written(slots, width),
             # rows of recurrent state the group writes: one a real prompt
             **({"state_rows_written": len(group)} if self.config.is_recurrent else {}),
         )
@@ -6191,6 +6194,18 @@ class ServingEngine:
             ),
             "window_pages_recycled": self._window_recycled,
         }
+
+    def _kv_pages_written(self, slots, width: int) -> int:
+        """kv_pages_written of an admission group dispatched now, per layer
+        and leaf: the entries of its rows' tables under ``width / page_size``
+        that are mapped, which are the copies `paged_insert_pages` issues (a
+        padding row maps none). 0 where the insert is the scatter
+        (models/transformer `insert_copies_pages`)."""
+        pool = self._pagepool
+        if not insert_copies_pages(pool.dev, width, self.page_size, self.config):
+            return 0
+        tables = pool.rows_tables(slots)[:, : width // self.page_size]
+        return int((tables != pool.oob).sum())
 
     def _kv_page_counts(self, steps: int) -> tuple[int, int]:
         """(kv_pages_visited, kv_rows_written) of a decode chunk dispatched

@@ -54,9 +54,11 @@ def test_kernel_checks_in_interpret_mode():
         config, prefill_lens=(16,), paged=(6, 8, 3),
         interpret=True,
     )
-    assert len(checks) == 5  # prefill, paged decode, the write's k and v, int8
+    # prefill, paged decode, the step's write and the group's insert (k and v), int8
+    assert len(checks) == 7
     assert all(c["max_abs_err"] <= chip_smoke.KERNEL_ERR_BOUND for c in checks)
     assert [c["max_abs_err"] for c in checks if "paged_kv_write" in c["kernel"]] == [0.0, 0.0]
+    assert [c["max_abs_err"] for c in checks if "paged_insert_pages" in c["kernel"]] == [0.0, 0.0]
 
 
 def test_without_a_tpu_it_fails_and_prints_no_result():

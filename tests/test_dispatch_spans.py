@@ -84,6 +84,7 @@ def test_one_span_per_group_and_chunk_with_work_counts(dense_params):
         assert 0 < a["real_tokens"] <= a["computed_tokens"] == 16 * a["rows"]
         assert len(a["trace_ids"]) == a["real_rows"]
         assert a["device_ms"] <= g["durationMs"] + 1e-3
+        assert a["kv_pages_written"] == 0  # off the TPU the insert is the scatter
     assert sum(g["attributes"]["real_tokens"] for g in groups) == 3 + 4 + 5
     for c in chunks:
         a = c["attributes"]
@@ -151,6 +152,29 @@ def test_admit_group_rows_stat_matches_a_hand_count(dense_params, moe_params):
     entries = admit(experts, 3) + admit(experts, 1)
     assert experts.stats()["admit-group-rows"] == {4: 2}
     drain(experts, deque([entries]))
+
+
+def test_kv_pages_written_is_the_mapped_pages_of_the_group_s_rows(dense_params):
+    """Where the insert copies pages (the kernels forced: interpret mode) a
+    group's span counts the copies a layer and leaf: Σ over its rows of the
+    table entries under width / page_size that are mapped. A bucket of 48 is
+    three pages of 16: a row that reserved 9 + 4 tokens maps one of them, one
+    that reserved 40 + 30 all three, a padding row none."""
+    TRACER.clear()
+    engine = ServingEngine(
+        dataclasses.replace(DENSE, attention_impl="pallas"), dense_params, max_batch=4,
+        max_seq_len=128, decode_chunk=4, page_size=16, prefill_buckets=(48,), prefill_batch=4,
+    )
+    for n, cap in ((9, 4), (40, 30)):
+        engine.submit(GenerationRequest(
+            prompt_tokens=[7] * n, options=GenerationOptions(max_new_tokens=cap, temperature=0.0),
+        ))
+    entries = engine._admit()
+    mapped = (engine._pagepool.tables[:, :3] != engine._pagepool.oob).sum()
+    drain(engine, deque([entries]))
+    (group,) = [g["attributes"] for g in spans_named("engine.admit_group")]
+    assert (group["rows"], group["real_rows"]) == (4, 2)
+    assert group["kv_pages_written"] == mapped == 1 + 3
 
 
 def test_kv_tokens_read_matches_a_hand_count(dense_params):

@@ -116,6 +116,19 @@ def _kv_write(config, batch, pages, layers, table=None):
     )
 
 
+def _insert_pages(config, rows, width, pages, layers, table):
+    """(an admission group's insert by page, its arguments' shapes): the
+    prefill's local K and V [L, rows, Hkv, width, D], both bf16 pool leaves
+    and the rows' tables."""
+    hkv, d = config.n_kv_heads, config.resolved_head_dim
+    local = SDS((layers, rows, hkv, width, d), jnp.bfloat16)
+    pool = SDS((layers, pages, hkv, PAGE, d), jnp.bfloat16)
+    return (
+        lambda k, v, pk, pv, table: A.paged_insert_pages((k, v), pk, pv, table),
+        (local, local, pool, pool, SDS((rows, table), jnp.int32)),
+    )
+
+
 # The benchmark's three cells (BENCHMARK.json; benchmark/workloads/*.json):
 # slots x table pages, the pool's pages, the layers. Mistral-7B and Mixtral
 # have llama-3-8b's attention (32 q / 8 kv heads of 128).
@@ -233,6 +246,12 @@ CASES = {
     "gemma-paged-kv-write": _kv_write(GEMMA, BATCH, PAGES, POOL_LAYERS),
     "llama-paged-kv-write": _kv_write(LLAMA, BATCH, PAGES, POOL_LAYERS),
     **{f"{cell}-paged-kv-write": _kv_write(LLAMA, **sizes) for cell, sizes in CELLS.items()},
+    # an admission group's insert at the cells' pools: chat's narrowest and
+    # widest lone prompt, docs' widest group, Olmo's at 30 kv heads
+    "chat1x64-paged-insert-pages": _insert_pages(LLAMA, 1, 64, 512, 32, 20),
+    "chat1x1024-paged-insert-pages": _insert_pages(LLAMA, 1, 1024, 512, 32, 20),
+    "docs4x2048-paged-insert-pages": _insert_pages(LLAMA, 4, 2048, 528, 32, 33),
+    "olmodrain8x256-paged-insert-pages": _insert_pages(OLMO, 8, 256, 480, 8, 10),
     # the Olmo-Hybrid cell (40 slots x 10 pages, 400 pages, 8 full layers of
     # 30 kv heads in groups of ONE; 24 linear layers of 30 x 96 x 192)
     "olmodrain40x10-paged-decode": _paged(OLMO, False, batch=40, table=10, pages=400, layers=8),
@@ -260,6 +279,7 @@ def _kernel_of(case: str) -> str:
         "paged-decode": "ragged_paged_decode_attention",
         "paged-decode-int8": "ragged_paged_decode_attention_int8",
         "paged-kv-write": "paged_kv_write",
+        "paged-insert-pages": "paged_insert_pages",
         "gated-delta-update": "gated_delta_update",
         "windowed-decode": "ragged_paged_decode_attention",
         "segment": "flash_segment_attention",
@@ -578,6 +598,18 @@ def test_one_row_admit_group_compiles_for_v5e_beside_the_cell_s_state(v5e, monke
         _placed(args, SingleDeviceSharding(v5e[0])), (config, PAGE),
     )
     assert f"prefill[s={width},t={width}]" in A.attention_paths()
+    # the insert is page copies where the pool lies (`paged_insert_pages`):
+    # no scatter has the compiler relay a whole leaf of the pool for its
+    # window and back (four copies of 2.15 GB in the chat cell: 26 of a
+    # group's 67.9 ms on the chip, PERF.md section 6, PR 37), and nothing
+    # relays the prefill's local cache in front of the kernel
+    text = compiled.as_text()
+    assert re.search(r"%paged_insert_pages(\.\d+)? = ", text)
+    kv = pool["k"].shape
+    local = (kv[0], rows) + kv[2:3] + (width,) + kv[4:]
+    for shape in (kv, local):
+        dims = re.escape("[" + ",".join(map(str, shape)) + "]")
+        assert not re.search(rf"= \w+{dims}\S* (copy|scatter)\(", text), shape
     memory = compiled.memory_analysis()
     pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
     assert memory.alias_size_in_bytes >= pool_bytes  # the pool and the state, updated in place
