@@ -265,6 +265,9 @@ class _Slot:
     verify_iters: int = 0
     # `seq` of the dispatch that prefilled this request (its span)
     group_seq: int = 0
+    # (behind, device, land) seconds of that dispatch, copied when its first
+    # token landed: `engine.prefill`'s stages (observability.emit_request_spans)
+    stages: Optional[tuple[float, float, float]] = None
     # decode steps dispatched for THIS request and not processed yet: the
     # device's position leads ``position`` by as many (kv_tokens_read)
     ahead: int = 0
@@ -280,6 +283,7 @@ class _Slot:
         self.decode_iters = 0
         self.verify_iters = 0
         self.group_seq = group_seq
+        self.stages = None
         self.ahead = 0
 
 
@@ -661,6 +665,18 @@ def _make_paged_admit_group(mesh=None):
     return admit_group
 
 
+# whether a profile is being recorded (annotations are no-ops otherwise); a
+# jaxlib without the probe reads as always
+_profiling = getattr(jax.profiler.TraceAnnotation, "is_enabled", lambda: True)
+
+
+def _mono_ns(disp: Optional[Dispatch]) -> int:
+    """A launch annotation's ``t_mono_ns``: the launch's own monotonic stamp
+    (`Dispatch.start`) in ns, so a profile that holds the annotation maps
+    its clock onto the spans' (0: no record was kept)."""
+    return int(disp.start * 1e9) if disp is not None else 0
+
+
 class _Fetch:
     """Handle for one deferred device→host token fetch. Created at dispatch
     time; the fetch thread fills ``_value`` in submission order. ``result``
@@ -994,8 +1010,9 @@ class ServingEngine:
             "deadline_decode_total", "quarantined_slots_total",
             "nan_guard_total", "engine_restarts_total", "total_generated",
             "total_requests", "_busy_steps", "_queue_wait_ema_s",
+            "_unfed_s", "_unfed_request_s", "_account_t0",
         ),
-        "_waiting_lock": ("_waiting",),
+        "_waiting_lock": ("_waiting", "_open"),
     }
 
     def __init__(
@@ -1286,11 +1303,21 @@ class ServingEngine:
         # ready instant of the newest processed dispatch: with a span's
         # own stamps, device-side time = end - max(start, this)
         self._last_ready_t = 0.0
-        # groups already on the in-order stream at a dispatch: those in
-        # the pending pipeline at the iteration's top plus this
-        # iteration's own (``_inflight_steps`` counts the decode steps)
-        self._inflight_groups = 0
-        self._iter_groups = 0
+        # The device's unfed account (docs/SERVING.md §12): seconds since
+        # `_account_t0` in which no dispatch was in flight, and the part of
+        # them with a request open in the engine. A stretch is closed by the
+        # launch that ends it; `_last_fetch` is the newest dispatch's fetch
+        # (its `ready_at` set: every earlier one's is too, the fetch thread
+        # is FIFO) and `_launch_unfetched` a launch that has none yet.
+        self._unfed_s = 0.0
+        self._unfed_request_s = 0.0
+        self._account_t0 = time.monotonic()
+        self._last_fetch: Optional[_Fetch] = None
+        self._launch_unfetched = False
+        # requests submitted and not yet seen finished by a launch, id() →
+        # request: what "open" means to the account (observability on only)
+        self._open: dict[int, GenerationRequest] = {}
+        self._open_prune_at = 16
         # host seconds spent waiting for device results, cumulative: an
         # iteration's share is the difference across its process phase
         self._wait_s_iter = 0.0
@@ -2080,6 +2107,8 @@ class ServingEngine:
                 )
         with self._waiting_lock:
             self._waiting[id(request)] = request
+            if self._obs.on:
+                self._open[id(request)] = request
         try:
             try:
                 if self.shed_policy == "reject":
@@ -2105,6 +2134,7 @@ class ServingEngine:
         except BaseException:
             with self._waiting_lock:
                 self._waiting.pop(id(request), None)
+                self._open.pop(id(request), None)
             raise
         return request
 
@@ -2192,6 +2222,9 @@ class ServingEngine:
         window = self._pagepool.window if self._pagepool is not None else None
         if window is not None:  # the peak gauge restarts with them
             window.peak_in_use = window.pages_in_use
+        with self._stats_lock:  # and the device's unfed account
+            self._unfed_s = self._unfed_request_s = 0.0
+            self._account_t0 = time.monotonic()
 
     def prefix_advertisement(
         self, top_k: int = 32,
@@ -2301,8 +2334,22 @@ class ServingEngine:
 
     def _stats_locked(self) -> dict[str, Any]:
         active = sum(1 for s in self._slots if s.active)
+        now = time.monotonic()
+        # the stretch still open counts: an idle tail is not lost
+        unfed, unfed_request = (
+            self._unfed_stretch(now) if self._obs.on else None
+        ) or (0.0, 0.0)
         return {
             "active-slots": active,
+            # the device's unfed account since the engine was built or
+            # `reset_histograms` (docs/SERVING.md §12): seconds with no
+            # dispatch in flight, the part of them with a request open in
+            # the engine, and the seconds they are a share of
+            "engine-loop-s": round(now - self._account_t0, 6),
+            "device-unfed-s": round(self._unfed_s + unfed, 6),
+            "device-unfed-with-request-s": round(
+                self._unfed_request_s + unfed_request, 6
+            ),
             "max-batch": self.max_batch,
             "queued": self._queue.qsize(),
             "long-prefill-active": bool(self._longs),
@@ -3006,6 +3053,7 @@ class ServingEngine:
         self._step_time_ema_s = 0.0
         self._last_chunk_ready_t = 0.0
         self._last_ready_t = 0.0
+        self._last_fetch, self._launch_unfetched = None, False
         self._moe_dev = None
         # fresh device state (same shapes → no recompiles on restart): the
         # pool buffer is donation-suspect; the allocator and every table
@@ -3099,8 +3147,11 @@ class ServingEngine:
         # each phase is also a `jax.profiler.TraceAnnotation` (a no-op
         # while no profile runs): a profile holds them on the host plane
         # beside the device's lines, so an idle gap on the device can be put
-        # down to the phase the engine thread was in
-        with jax.profiler.TraceAnnotation("engine.sweep"):
+        # down to the phase the engine thread was in, and to the engine's
+        # state there: live rows, queue depth, dispatches launched and not
+        # yet processed
+        state = self._loop_state(pending)
+        with jax.profiler.TraceAnnotation("engine.sweep", **state):
             # SPMD slice resilience (§20): the spmd-crash drill site, the
             # divergence-resync poll, and the idle heartbeat — all at the
             # iteration top, OUTSIDE any dispatch's announce sequence
@@ -3143,20 +3194,16 @@ class ServingEngine:
             # thread won the interpreter (PERF.md §6, PR 29).
             grace = 0.1 * self._step_time_ema_s * self.decode_chunk
             if pending and grace >= sys.getswitchinterval():
-                time.sleep(grace)
+                with jax.profiler.TraceAnnotation("engine.grace"):
+                    time.sleep(grace)
         t_sweep = time.monotonic() if obs_on else 0.0
-        with jax.profiler.TraceAnnotation("engine.admit"):
+        with jax.profiler.TraceAnnotation("engine.admit", **state):
             # chunks dispatched in previous iterations are still unfetched when
             # this iteration's dispatch computes its headroom bound — subtract
             # ALL of them
             self._inflight_steps = sum(
                 e[3] for batch in pending for e in batch if e[0] == "chunk"
             )
-            self._inflight_groups = sum(
-                1 for batch in pending for e in batch
-                if e[0] in ("prefill", "segment")
-            )
-            self._iter_groups = 0
             had_active = any(s.active for s in self._slots)
             # the fused-iteration prefill budget (overlap off: unbounded, the
             # pre-overlap whole-backlog admission). Long prefill FIRST: it
@@ -3182,7 +3229,10 @@ class ServingEngine:
             # of the chunk below — its chunk must not feed the step-time gauge
             prefill_ahead = bool(new_pending) or spent > 0
         t_prefill = time.monotonic() if obs_on else 0.0
-        with jax.profiler.TraceAnnotation("engine.dispatch"):
+        idle = False
+        with jax.profiler.TraceAnnotation(
+            "engine.dispatch", **self._loop_state(pending, len(new_pending))
+        ):
             n_admitted = sum(
                 len(e[2]) for e in new_pending if e[0] == "prefill"
             )
@@ -3250,8 +3300,12 @@ class ServingEngine:
                 disp_kind, disp_steps = "decode", new_pending[-1][3]
             else:
                 disp_kind, disp_steps = "", 0
-                if not new_pending and not pending and not self._longs:
-                    time.sleep(0.001)
+                idle = not new_pending and not pending and not self._longs
+        if idle:
+            # nothing to launch and nothing in flight: the idle millisecond
+            # under its own name, so `engine.dispatch` holds launches only
+            with jax.profiler.TraceAnnotation("engine.idle"):
+                time.sleep(0.001)
         t_dispatch = time.monotonic() if obs_on else 0.0
         waited_before = self._wait_s_iter
         pending.append(new_pending)
@@ -3431,11 +3485,14 @@ class ServingEngine:
                 continue
             with self._waiting_lock:
                 self._waiting[id(request)] = request
+                if self._obs.on:
+                    self._open[id(request)] = request
             try:
                 self._queue.put_nowait(request)
             except (queue.Full, TenantShareExceeded):
                 with self._waiting_lock:
                     self._waiting.pop(id(request), None)
+                    self._open.pop(id(request), None)
                 self._count_shed(self.BURST_TENANT)
 
     @staticmethod
@@ -3488,12 +3545,20 @@ class ServingEngine:
             first = self._fetch_result(first_dev)
             self._land_dispatch(disp, first_dev)
             now = time.monotonic()
+            stages = None
+            if disp is not None and self._obs.on:
+                # what the first token waited for past its admission: behind
+                # what was in flight, on the device, and (fetch thread's
+                # stamp → this one) on the host, undelivered
+                behind, device, ready = disp.stages
+                stages = (behind, device, now - ready)
             with jax.profiler.TraceAnnotation("engine.process.deliver"):
                 for j, (idx, request) in enumerate(group):
                     slot = self._slots[idx]
                     if slot.request is not request:
                         continue
                     slot.first_token_at = now
+                    slot.stages = stages
                     slot.last_token_at = now  # inter-token clock starts here
                     if self._obs.on:
                         self._obs.record(
@@ -3520,18 +3585,101 @@ class ServingEngine:
                 chunk, snapshot, steps, t_dispatch, clean, pipelined, disp
             )
 
-    def _new_dispatch(self, name: str, **attrs: Any) -> Optional[Dispatch]:
+    def _loop_state(self, pending, launched: int = 0) -> dict[str, int]:
+        """What a phase annotation of `_iterate` says of the engine: live
+        rows, queue depth, and the dispatches launched and not yet landed
+        (``launched`` of them this iteration). Counted only while a profile
+        runs: the idle loop turns a thousand times a second."""
+        if not _profiling():
+            return {}
+        return {
+            "active": sum(1 for s in self._slots if s.active),
+            "queued": self._queue.qsize(),
+            "inflight": launched + sum(len(batch) for batch in pending),
+        }
+
+    def _new_dispatch(
+        self, name: str, stages: Optional[list[float]] = None, **attrs: Any
+    ) -> Optional[Dispatch]:
         """Number one device dispatch and start its span's record (None
         when nothing will read it: observability off and a dense model).
         Call just before the launch; the entry's fetch takes
-        ``_moe_counts`` right after it."""
+        ``_moe_counts`` right after it. With observability on, the launch
+        also closes the device's unfed stretch, if one is open."""
         self._dispatch_seq += 1
         if not (self._obs.on or self.config.is_moe):
             return None
         attrs["seq"] = self._dispatch_seq
-        attrs["behind_steps"] = self._inflight_steps
-        attrs["behind_groups"] = self._inflight_groups + self._iter_groups
-        return Dispatch(name, time.monotonic(), attrs)
+        start = time.monotonic()
+        if self._obs.on:
+            self._account_launch(start, attrs)
+        return Dispatch(name, start, attrs, stages)
+
+    def _unfed_stretch(
+        self, now: float, closing: bool = False
+    ) -> Optional[tuple[float, float]]:
+        """The stretch the device has had nothing to do, if one is open at
+        ``now``: (its seconds, the part of them with a request open in the
+        engine). Open: every dispatch launched has its result on the host
+        (`_Fetch.ready_at`); the stretch began at the last of those, or
+        where the account did. A request is open from ``submitted_at``
+        until it resolved (``total_s`` later: the instant its spans end).
+        None while something is in flight. ``closing`` (a launch, which
+        ends the stretch) forgets the resolved; a launch behind work in
+        flight only once they have doubled what it keeps. O(open requests)."""
+        last = self._last_fetch
+        start = None
+        if not (self._launch_unfetched or (last is not None and not last.ready_at)):
+            start = max(last.ready_at if last is not None else 0.0, self._account_t0)
+        elif not (closing and len(self._open) > self._open_prune_at):
+            return None
+        with self._waiting_lock:
+            opened = list(self._open.items())
+        held, resolved = [], []
+        for key, request in opened:
+            end = now
+            if request._done.is_set():
+                resolved.append(key)
+                result = request._result
+                end = request.submitted_at + (result.total_s if result else 0.0)
+            if start is not None and end > start and request.submitted_at < now:
+                held.append((max(request.submitted_at, start), min(end, now)))
+        if closing:
+            with self._waiting_lock:
+                for key in resolved:
+                    self._open.pop(key, None)
+            self._open_prune_at = 2 * (len(opened) - len(resolved)) + 16
+        if start is None:
+            return None
+        covered, edge = 0.0, start
+        for lo, hi in sorted(held):
+            if hi > edge:
+                covered += hi - max(lo, edge)
+                edge = hi
+        return max(0.0, now - start), covered
+
+    def _account_launch(self, now: float, attrs: dict[str, Any]) -> None:
+        """A launch at ``now`` ends the unfed stretch, if one was open: its
+        seconds go to `device-unfed-s` / `-with-request-s` and onto the
+        launch's span. Until `_submit_fetch` has this launch's fetch it
+        counts as in flight (a segment that is never fetched: until a later
+        one is)."""
+        with self._stats_lock:
+            stretch = self._unfed_stretch(now, closing=True)
+            self._launch_unfetched = True
+            if stretch is not None:
+                self._unfed_s += stretch[0]
+                self._unfed_request_s += stretch[1]
+        if stretch is not None:
+            attrs["unfed_ms"] = round(stretch[0] * 1e3, 3)
+            attrs["unfed_with_request_ms"] = round(stretch[1] * 1e3, 3)
+
+    def _submit_fetch(self, array, seq: int = 0, counts=None) -> _Fetch:
+        """Hand a launch's result to the fetch thread; its ``ready_at``
+        is what says the device has finished everything launched so far."""
+        handle = self._fetcher.submit(array, seq, counts)
+        self._last_fetch, self._launch_unfetched = handle, False
+        return handle
 
     def _moe_counts(self):
         """The MoE counts the launch just returned, for the fetch that
@@ -3541,7 +3689,7 @@ class ServingEngine:
 
     def _new_segment_dispatch(
         self, program: str, width: int, real_tokens: int,
-        request: GenerationRequest,
+        request: GenerationRequest, stages: Optional[list[float]] = None,
     ) -> Optional[Dispatch]:
         """The record of an `engine.prefill_segment` span: a warm suffix,
         a ring admit, or the FIRST segment of a long prompt's stream
@@ -3550,14 +3698,13 @@ class ServingEngine:
         that segment has a fetch to time). The segment programs return no
         MoE counts, except for a model with window layers (its expert layer
         holds a share, ``config.has_window``): there every segment is
-        fetched for its counts and is a span of its own."""
-        disp = self._new_dispatch(
-            "engine.prefill_segment", program=program, rows=1, real_rows=1,
-            width=width, segments=1, real_tokens=real_tokens,
+        fetched for its counts and is a span of its own, and the stream's
+        dispatches sum their ``stages`` into one list."""
+        return self._new_dispatch(
+            "engine.prefill_segment", stages, program=program, rows=1,
+            real_rows=1, width=width, segments=1, real_tokens=real_tokens,
             computed_tokens=width, trace_ids=[request.trace_id],
         )
-        self._iter_groups += 1
-        return disp
 
     def _land_dispatch(self, disp: Optional[Dispatch], handle) -> None:
         """The dispatch's result is on the host: fold its MoE counts into
@@ -3581,9 +3728,15 @@ class ServingEngine:
         if not self._obs.on:
             return
         # the in-order stream ran this dispatch after the one before it:
+        # it waited `behind` what was in flight at its launch, and its own
         # device-side time is end - max(start, the previous result's ready)
-        attrs["prev_ready_ms"] = round((prev - disp.start) * 1e3, 3) if prev else None
-        attrs["device_ms"] = round((end - max(disp.start, prev)) * 1e3, 3)
+        behind = max(0.0, prev - disp.start)
+        device = end - disp.start - behind
+        attrs["behind_ms"] = round(behind * 1e3, 3)
+        attrs["device_ms"] = round(device * 1e3, 3)
+        disp.stages[0] += behind
+        disp.stages[1] += device
+        disp.stages[2] = end
         if "real_tokens" in attrs:  # a prefill group or segment stream
             self._obs.record("engine_prefill_group_s", end - disp.start)
             self._prefill_tokens_landed += attrs["real_tokens"]
@@ -4170,13 +4323,14 @@ class ServingEngine:
             **({"state_rows_written": len(group)} if self.config.is_recurrent else {}),
         )
         seq = self._dispatch_seq
-        with jax.profiler.TraceAnnotation("engine.admit_group", seq=seq):
+        with jax.profiler.TraceAnnotation(
+            "engine.admit_group", seq=seq, t_mono_ns=_mono_ns(disp)
+        ):
             first = self._dev_prefill(
                 width, tokens, lengths, temps, top_ks, top_ps, slots,
                 arows=arows, g_rows=g_rows, g_state0=g_state0,
             )
         counts = self._moe_counts()
-        self._iter_groups += 1
 
         for idx, request in group:
             slot = self._slots[idx]
@@ -4193,7 +4347,7 @@ class ServingEngine:
             self._spec_admit(idx, request.prompt_tokens)
             self._maybe_publish(idx, request.prompt_tokens)
         return [(
-            "prefill", self._fetcher.submit(first, seq, counts), list(group), disp,
+            "prefill", self._submit_fetch(first, seq, counts), list(group), disp,
         )]
 
     def _agentic_admit_kwargs(
@@ -4621,7 +4775,7 @@ class ServingEngine:
         self._spec_admit(idx, prompt)
         self._maybe_publish(idx, prompt)
         entries.append(
-            ("prefill", self._fetcher.submit(first, self._dispatch_seq),
+            ("prefill", self._submit_fetch(first, self._dispatch_seq),
              [(idx, request)], disp)
         )
 
@@ -6002,14 +6156,19 @@ class ServingEngine:
         # a model whose segments return expert counts fetches each one: a
         # span a segment, with its own device_ms (else one for the stream)
         per_segment = self.config.has_window
+        if start:
+            # the stream's admission: its `engine.prefill` runs from here
+            st["started"] = time.monotonic()
         if start or per_segment:
             disp = st["disp"] = self._new_segment_dispatch(
                 "_paged_segment_and_sample", width, len(seg), request,
+                st.setdefault("stages", [0.0, 0.0, 0.0]),
             )
         elif disp is not None:
             disp.attrs["segments"] += 1
             disp.attrs["real_tokens"] += len(seg)
             disp.attrs["computed_tokens"] += width
+            self._launch_unfetched = True  # a launch all the same (`_account_launch`)
         try:
             # straight into the slot's pages: no local cache, no final
             # insert/splice — the chain scatter on ``final`` is the only
@@ -6039,7 +6198,7 @@ class ServingEngine:
             if per_segment:  # nothing to deliver: the fetch lands the span
                 return [(
                     "segment",
-                    self._fetcher.submit(
+                    self._submit_fetch(
                         first, self._dispatch_seq, self._moe_counts()
                     ),
                     disp,
@@ -6053,7 +6212,7 @@ class ServingEngine:
         slot.request = request
         slot.position = len(prompt)
         slot.generated = []
-        slot.started_at = time.monotonic()
+        slot.started_at = st["started"]
         slot.first_token_at = 0.0
         slot.reset_obs(
             "long", st["seg"], disp.attrs["seq"] if disp is not None else 0
@@ -6066,7 +6225,7 @@ class ServingEngine:
         self._maybe_publish(idx, prompt)
         return [(
             "prefill",
-            self._fetcher.submit(
+            self._submit_fetch(
                 first, disp.attrs["seq"] if disp is not None else 0,
                 self._moe_counts() if per_segment else None,
             ),
@@ -6112,6 +6271,9 @@ class ServingEngine:
             "engine.decode_chunk",
             program="_paged_decode_chunk",
             steps=steps, active_rows=len(live),
+            # the (row, step) pairs the chunk computes: `tokens_delivered`
+            # (`_process_chunk`) is how many of them a request received
+            row_steps=steps * len(live),
             kv_tokens_read=self._kv_tokens_read(live, steps),
             clean=clean, pipelined=pipelined,
             kv_pages_visited=pages_visited, kv_rows_written=rows_written,
@@ -6121,7 +6283,8 @@ class ServingEngine:
             **self._decode_window_attrs(steps, recycled),
         )
         with jax.profiler.TraceAnnotation(
-            "engine.decode_chunk", seq=self._dispatch_seq, steps=steps
+            "engine.decode_chunk", seq=self._dispatch_seq, steps=steps,
+            t_mono_ns=_mono_ns(disp),
         ):
             chunk = self._dev_decode(steps, stale, mask=mask)
         counts = self._moe_counts()
@@ -6136,7 +6299,7 @@ class ServingEngine:
         # while this thread keeps dispatching — the fetch is hidden at
         # every chunk size, not only when chunk compute covers it
         return (
-            "chunk", self._fetcher.submit(chunk, self._dispatch_seq, counts),
+            "chunk", self._submit_fetch(chunk, self._dispatch_seq, counts),
             snapshot, steps, time.monotonic(), clean, pipelined, disp,
         )
 
@@ -6383,7 +6546,7 @@ class ServingEngine:
             self._busy_steps += 1
             self.spec_dispatches_total += 1
         return (
-            "verify", self._fetcher.submit(packed, self._dispatch_seq, counts),
+            "verify", self._submit_fetch(packed, self._dispatch_seq, counts),
             snapshot, proposed, time.monotonic(), clean, disp,
         )
 
@@ -6526,6 +6689,7 @@ class ServingEngine:
         self._spmd_echo(wire.ECHO_DECODE, host)  # before host-side corruption
         if self._injector is not None:
             host, _ = self._injector.corrupt_tokens(host, snapshot)
+        total = 0
         with jax.profiler.TraceAnnotation("engine.process.deliver"):
             for idx, request in snapshot:
                 slot = self._slots[idx]
@@ -6541,7 +6705,11 @@ class ServingEngine:
                     delivered += 1
                     if slot.request is not request:  # finished mid-chunk
                         break
+                total += delivered
                 self._record_intertoken(slot, request, t_prev, delivered)
+        if disp is not None:
+            # onto the span `_land_dispatch` emitted: it holds this dict
+            disp.attrs["tokens_delivered"] = total
 
     def _record_intertoken(
         self, slot: _Slot, request: GenerationRequest, t_prev: float,
@@ -6753,6 +6921,7 @@ class ServingEngine:
             emit_request_spans(
                 request.trace_id, stamps, attrs,
                 status="ok" if error is None else f"error: {type(error).__name__}",
+                stages=slot.stages,
             )
 
     def _fail_all(self, error: BaseException) -> None:
@@ -6796,5 +6965,6 @@ class ServingEngine:
                 break
         with self._waiting_lock:
             self._waiting.clear()
+            self._open.clear()
         for request in doomed:
             request._finish(dead_result())

@@ -49,12 +49,11 @@ import logging
 import os
 import threading
 import time
-import uuid
 from collections import deque
 from typing import Any, Optional
 
 from langstream_tpu.api.metrics import Histogram, log_buckets
-from langstream_tpu.tracing import TRACER, Span
+from langstream_tpu.tracing import MONO_TO_WALL_S, TRACER, Span
 
 log = logging.getLogger(__name__)
 
@@ -187,7 +186,9 @@ def load_score(
 
 
 def _span_id() -> str:
-    return uuid.uuid4().hex[:16]
+    # 64 bits of the randomness `uuid4` draws on, at a fifth of its cost:
+    # two a span, on every dispatch and iteration
+    return os.urandom(8).hex()
 
 
 def emit_request_spans(
@@ -195,11 +196,19 @@ def emit_request_spans(
     stamps: dict[str, float],
     attributes: dict[str, Any],
     status: str = "ok",
+    stages: Optional[tuple[float, float, float]] = None,
 ) -> Optional[str]:
     """Emit the per-request span tree from monotonic phase ``stamps``
     (``submitted`` required; ``admitted`` / ``first_token`` / ``finished``
     optional — missing phases collapse: a request cancelled in queue gets
     only the root + ``engine.queued``). Returns the trace id used.
+
+    ``stages``, where the engine kept them, are the seconds the request's
+    prefill spent behind what was in flight ahead of it, on
+    the device, and landed on the host but undelivered (``behind_ms``,
+    ``device_ms``, ``land_ms`` of `engine.prefill`, with ``launch_ms`` the
+    rest of that span: the host building and launching the dispatch), so
+    `engine.queued` and the four add up to submitted → first token.
 
     Called ONCE per request at completion, from the engine thread (or the
     expiry sweep) — never from the token delivery loop."""
@@ -208,10 +217,11 @@ def emit_request_spans(
     submitted = stamps.get("submitted")
     if submitted is None:
         return trace_id
-    now_mono = time.monotonic()
-    finished = stamps.get("finished", now_mono)
-    offset = time.time() - now_mono  # monotonic → wall conversion
-    trace_id = trace_id or uuid.uuid4().hex[:16]
+    finished = stamps.get("finished")
+    if finished is None:
+        finished = time.monotonic()
+    offset = MONO_TO_WALL_S  # monotonic → wall, the process's one
+    trace_id = trace_id or _span_id()
     root = Span(
         name="engine.request",
         trace_id=trace_id,
@@ -256,6 +266,7 @@ def emit_request_spans(
             # `seq` of the engine.admit_group / engine.prefill_segment span
             # whose dispatch prefilled this request (0: none was emitted)
             group_seq=attributes.get("group_seq", 0),
+            **(_stage_ms(stages, first_token - admitted) if stages else {}),
         )
     if first_token is not None:
         child(
@@ -274,6 +285,18 @@ def emit_request_spans(
     return trace_id
 
 
+def _stage_ms(stages, prefill_s: float) -> dict[str, float]:
+    """`engine.prefill`'s four stages in ms from the engine's (behind,
+    device, land) seconds, kept once the request has its first token."""
+    behind, device, land = stages
+    return {
+        "launch_ms": round((prefill_s - behind - device - land) * 1e3, 3),
+        "behind_ms": round(behind * 1e3, 3),
+        "device_ms": round(device * 1e3, 3),
+        "land_ms": round(land * 1e3, 3),
+    }
+
+
 # ---------------------------------------------------------------------------
 # Dispatch and iteration spans
 # ---------------------------------------------------------------------------
@@ -284,14 +307,22 @@ class Dispatch:
     name, its start (monotonic, at the launch) and the attributes known at
     launch. Rides the dispatch's pending entry; the engine completes the
     attributes (timing, the MoE counts its fetch brought) and emits the
-    span when the entry's fetch has landed."""
+    span when the entry's fetch has landed. ``stages`` is [seconds behind
+    what was in flight ahead, seconds on the device, instant the last
+    result was ready], summed as each result lands: the requests whose
+    first token the dispatch brings copy it (a chunked prompt whose every
+    segment is a dispatch of its own hands one list to all of them)."""
 
-    __slots__ = ("name", "start", "attrs")
+    __slots__ = ("name", "start", "attrs", "stages")
 
-    def __init__(self, name: str, start: float, attrs: dict[str, Any]) -> None:
+    def __init__(
+        self, name: str, start: float, attrs: dict[str, Any],
+        stages: Optional[list[float]] = None,
+    ) -> None:
         self.name = name
         self.start = start
         self.attrs = attrs
+        self.stages = [0.0, 0.0, 0.0] if stages is None else stages
 
 
 def emit_dispatch_span(
@@ -307,7 +338,7 @@ def emit_dispatch_span(
         trace_id=_span_id(),
         span_id=_span_id(),
         parent_id=None,
-        start_s=start + (time.time() - time.monotonic()),
+        start_s=start + MONO_TO_WALL_S,
         duration_s=max(0.0, end - start),
         attributes=attributes,
     ))

@@ -25,6 +25,12 @@ from typing import Any, Iterator, Optional
 
 TRACE_HEADER = "ls-trace-id"
 
+# The process's ONE monotonic → wall offset, taken once: every span's `start`
+# is a monotonic stamp plus this, so two spans' starts differ by exactly
+# their monotonic stamps, and a profile that holds a launch annotation's
+# `t_mono_ns` (docs/SERVING.md §12) puts spans and device lines on one clock.
+MONO_TO_WALL_S = time.time() - time.monotonic()
+
 _current_span: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
     "ls_current_span", default=None
 )
@@ -73,17 +79,17 @@ class Tracer:
             yield Span(name, "", "", None, 0.0)
             return
         parent = _current_span.get()
+        started = time.monotonic()
         span = Span(
             name=name,
             trace_id=trace_id
             or (parent.trace_id if parent is not None else uuid.uuid4().hex[:16]),
             span_id=uuid.uuid4().hex[:16],
             parent_id=parent.span_id if parent is not None else None,
-            start_s=time.time(),
+            start_s=started + MONO_TO_WALL_S,
             attributes=dict(attributes),
         )
         token = _current_span.set(span)
-        started = time.monotonic()
         try:
             yield span
         except BaseException as e:

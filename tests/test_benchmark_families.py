@@ -6,7 +6,10 @@ the program's block cannot express refused, every family's two files) and of
 contract, the mapping, the refusals, the seeded tree, the update's cost, the
 state probe) and of `benchmark/tests/test_cohere2_moe_family.py` (Command A+:
 the contract, the mapping and the stated cut, the cell's sizing, the
-refusals, the seeded tree, the costs and the readers) run here as they stand
+refusals, the seeded tree, the costs and the readers) and the cases of
+`benchmark/tests/test_request_readers.py` (the clock between a profile and the
+spans, a first token's stages, the device's idle by what the engine held; one
+of them records a profile of a small engine) run here as they stand
 there. The check's verdicts (an engine a case) stay with the
 harness's own suite, run by hand: `python -m pytest benchmark/tests`."""
 
@@ -37,3 +40,4 @@ def _cases(file: str) -> dict:
 globals().update(_cases("test_families"))
 globals().update(_cases("test_olmo_hybrid_family"))
 globals().update(_cases("test_cohere2_moe_family"))
+globals().update(_cases("test_request_readers"))

@@ -304,6 +304,10 @@ def test_nothing_is_emitted_with_observability_off(dense_params):
         engine.stop()
     names = {s["name"] for s in TRACER.spans(4096)}
     assert not names.intersection(DISPATCH_SPANS + ("engine.request",))
+    # nor counted: the device's unfed account stays where it began
+    stats = engine.stats()
+    assert stats["device-unfed-s"] == 0 == stats["device-unfed-with-request-s"]
+    assert not engine._open and engine._slots[0].stages is None
 
 
 def test_prefill_tps_estimate_is_tokens_over_dispatch_to_ready(dense_params):
@@ -330,6 +334,165 @@ def test_prefill_tps_estimate_is_tokens_over_dispatch_to_ready(dense_params):
     assert hist["sum"] == pytest.approx(seconds, rel=0.05, abs=1e-3)
     truth = 4 * 40 / seconds
     assert truth / 2 <= estimate <= truth * 2
+
+
+# ---------------------------------------------------------------------------
+# what a first token waits for, and when the device goes unfed
+# ---------------------------------------------------------------------------
+
+
+def request_children(trace_id):
+    spans = [s for s in TRACER.spans(4096) if s["traceId"] == trace_id]
+    return {s["name"]: s for s in spans}
+
+
+def test_ttft_stages_add_up_and_behind_is_what_was_in_flight(dense_params):
+    """Driven one iteration at a time: A meets an idle engine (nothing was
+    ever in flight: `behind_ms` 0), B is admitted while A's chunk is in
+    flight and rides behind it. For both, `engine.queued` and the four
+    stages of `engine.prefill` add up to submitted → first token."""
+    TRACER.clear()
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=2, max_seq_len=128, decode_chunk=4,
+        prefill_buckets=(16,), overlap=True,
+    )
+    in_flight = [True]
+    engine._batch_ready = lambda batch: not in_flight[0]
+    pending: deque = deque()
+    opts = GenerationOptions(max_new_tokens=6, temperature=0.0)
+    a = engine.submit(GenerationRequest(prompt_tokens=[4, 5, 6], options=opts, trace_id="stage-a"))
+    engine._iterate(pending)  # A's group lands inline (a cold start); chunk 1 goes out
+    b = engine.submit(GenerationRequest(prompt_tokens=[7, 8], options=opts, trace_id="stage-b"))
+    engine._iterate(pending)  # B's group is launched behind chunk 1
+    in_flight[0] = False
+    for _ in range(20):
+        if a._done.is_set() and b._done.is_set():
+            break
+        engine._iterate(pending)
+    drain(engine, pending)
+    assert len(a.result(1).tokens) == 6 == len(b.result(1).tokens)
+    groups = {g["attributes"]["seq"]: g for g in spans_named("engine.admit_group")}
+    for trace_id in ("stage-a", "stage-b"):
+        spans = request_children(trace_id)
+        stages = spans["engine.prefill"]["attributes"]
+        first_token_ms = (spans["engine.decode"]["start"] - spans["engine.request"]["start"]) * 1e3
+        parts = [stages[k] for k in ("launch_ms", "behind_ms", "device_ms", "land_ms")]
+        assert all(p >= 0 for p in parts)
+        assert spans["engine.queued"]["durationMs"] + sum(parts) == pytest.approx(
+            first_token_ms, abs=0.01
+        )
+        # the stages are the group's own: `group_seq` is the join
+        group = groups[stages["group_seq"]]["attributes"]
+        assert trace_id in group["trace_ids"]
+        assert (stages["behind_ms"], stages["device_ms"]) == (group["behind_ms"], group["device_ms"])
+        assert "prev_ready_ms" not in group and "behind_steps" not in group
+    assert request_children("stage-a")["engine.prefill"]["attributes"]["behind_ms"] == 0
+    assert request_children("stage-b")["engine.prefill"]["attributes"]["behind_ms"] > 0
+
+
+def union_ms(intervals, lo, hi):
+    covered, edge = 0.0, lo
+    for a, b in sorted((max(a, lo), min(b, hi)) for a, b in intervals):
+        if b > edge:
+            covered += b - max(a, edge)
+            edge = b
+    return covered
+
+
+def test_unfed_account_equals_a_hand_count_and_restarts_with_the_histograms(dense_params):
+    """Three requests a pause apart on a running engine. From the spans'
+    stamps alone: a launch finds the device unfed when every earlier
+    dispatch's result was ready before it; the stretch runs from the last of
+    those (or the account's start) to the launch, and its with-request part
+    is what the requests' own spans cover of it. Each such launch's span and
+    the two counters of `stats()` say the same; `reset_histograms` restarts
+    them with `engine-loop-s`."""
+    from langstream_tpu.tracing import MONO_TO_WALL_S
+
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=2, max_seq_len=64, decode_chunk=4,
+        prefill_buckets=(16,),
+    )
+    engine.start()
+    try:
+        engine.generate([3, 4, 5], GenerationOptions(max_new_tokens=4), timeout=300)  # compiles
+        time.sleep(0.05)
+        TRACER.clear()
+        engine.reset_histograms()
+        t_reset = (engine._account_t0 + MONO_TO_WALL_S) * 1e3
+        for i in range(3):
+            time.sleep(0.03)
+            engine.generate([6 + i] * 4, GenerationOptions(max_new_tokens=7), timeout=300)
+        time.sleep(0.03)
+        before = time.monotonic()
+        stats = engine.stats()
+        after = time.monotonic()
+    finally:
+        engine.stop()
+    ms = lambda s: (s["start"] * 1e3, s["start"] * 1e3 + s["durationMs"])  # noqa: E731
+    launches = sorted(
+        (s for name in DISPATCH_SPANS[:4] for s in spans_named(name)),
+        key=lambda s: s["attributes"]["seq"],
+    )
+    requests = [ms(s) for s in spans_named("engine.request")]
+    assert len(requests) == 3 and len(launches) >= 6
+    unfed = with_request = 0.0
+    last_ready = t_reset
+    for span in launches:
+        start, end = ms(span)
+        a = span["attributes"]
+        if start >= last_ready:  # nothing in flight: a stretch ends here
+            assert a["unfed_ms"] == pytest.approx(start - last_ready, abs=0.01)
+            assert a["unfed_with_request_ms"] == pytest.approx(
+                union_ms(requests, last_ready, start), abs=0.01
+            )
+            unfed += a["unfed_ms"]
+            with_request += a["unfed_with_request_ms"]
+        else:
+            assert "unfed_ms" not in a
+        last_ready = max(last_ready, end)
+    # three pauses of 30 ms with no request, and a request open while the
+    # engine decided, launched and delivered
+    assert with_request > 0 and unfed - with_request > 80
+    # the counters: those stretches and the idle tail open when they were read
+    tail = ((before + MONO_TO_WALL_S) * 1e3 - last_ready, (after + MONO_TO_WALL_S) * 1e3 - last_ready)
+    assert unfed + tail[0] - 0.05 <= stats["device-unfed-s"] * 1e3 <= unfed + tail[1] + 0.05
+    assert stats["device-unfed-with-request-s"] * 1e3 == pytest.approx(with_request, abs=0.05)
+    assert stats["device-unfed-s"] <= stats["engine-loop-s"] <= after - engine._account_t0 + 1e-3
+    engine.reset_histograms()
+    again = engine.stats()
+    assert again["engine-loop-s"] < 0.5 and again["device-unfed-with-request-s"] == 0
+    assert again["device-unfed-s"] <= again["engine-loop-s"]
+
+
+def test_tokens_delivered_is_what_the_requests_received_from_the_chunk(dense_params):
+    """Two requests of 6 and 11 tokens over chunks of 4 steps: the first
+    token comes from the group, the others from chunks, and a row that ends
+    inside a chunk leaves the rest of its steps computed for nobody."""
+    TRACER.clear()
+    engine = ServingEngine(
+        DENSE, dense_params, max_batch=2, max_seq_len=64, decode_chunk=4,
+        prefill_buckets=(16,),
+    )
+    engine.start()
+    try:
+        reqs = [
+            engine.submit(GenerationRequest(
+                prompt_tokens=[5 + i] * 3, options=GenerationOptions(max_new_tokens=cap),
+            ))
+            for i, cap in enumerate((6, 11))
+        ]
+        got = [len(r.result(timeout=300).tokens) for r in reqs]
+    finally:
+        engine.stop()  # lands the chunks still in flight
+    assert got == [6, 11]
+    chunks = [c["attributes"] for c in spans_named("engine.decode_chunk")]
+    for c in chunks:
+        assert c["row_steps"] == c["steps"] * c["active_rows"]
+        assert 0 <= c["tokens_delivered"] <= c["row_steps"]
+    assert sum(c["tokens_delivered"] for c in chunks) == (6 - 1) + (11 - 1)
+    # 5 and 10 tokens are no multiple of a chunk's steps: a row ended mid-chunk
+    assert any(0 < c["tokens_delivered"] < c["row_steps"] for c in chunks)
 
 
 # ---------------------------------------------------------------------------
@@ -558,9 +721,10 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
 
 
 def test_hot_loop_cost_of_dispatch_spans_and_annotations(dense_params):
-    """What this PR adds per dispatch (the record, the landing, one span)
-    and per iteration (five phase annotations, the iteration span), each
-    best-of-N, against a decode step: a chunk of `decode_chunk` steps pays
+    """What the tracing adds per dispatch (the record with the launch's
+    stamp, the unfed account's stretch, the landing with its stages, one
+    span) and per iteration (six phase annotations, three of them with the
+    loop's state, the iteration span), each best-of-N, against a decode step: a chunk of `decode_chunk` steps pays
     them once. Same 1% contract as the per-token instrumentation
     (test_observability.py), against the same worst case: tiny-test's CPU
     step."""
@@ -583,29 +747,50 @@ def test_hot_loop_cost_of_dispatch_spans_and_annotations(dense_params):
         live = [s for s in engine._slots]
         handle = E._Fetch(None, engine._fetcher)
         handle.ready_at = time.monotonic()
+        # the unfed account's worst case: every launch finds the device
+        # unfed and four requests open, so each closes a stretch over them
+        engine._open = {
+            i: GenerationRequest(prompt_tokens=[1], options=GenerationOptions())
+            for i in range(4)
+        }
+        pending = deque([[()], [()]])
         per_dispatch = per_iteration = float("inf")
         for _ in range(5):
             n = 2_000
             t0 = time.perf_counter()
             for _ in range(n):
+                engine._last_fetch, engine._launch_unfetched = handle, False
                 disp = engine._new_dispatch(
                     "engine.decode_chunk", program="_paged_decode_chunk", steps=8,
-                    active_rows=4, kv_tokens_read=engine._kv_tokens_read(live, 8),
+                    active_rows=4, row_steps=32,
+                    kv_tokens_read=engine._kv_tokens_read(live, 8),
                     clean=True, pipelined=True,
                 )
-                with jax.profiler.TraceAnnotation("engine.decode_chunk", seq=1, steps=8):
+                with jax.profiler.TraceAnnotation(
+                    "engine.decode_chunk", seq=1, steps=8, t_mono_ns=E._mono_ns(disp)
+                ):
                     pass
                 handle.counts = engine._moe_counts()
                 engine._land_dispatch(disp, handle)
+                disp.attrs["tokens_delivered"] = 32
             per_dispatch = min(per_dispatch, (time.perf_counter() - t0) / n)
+            assert disp.attrs["unfed_ms"] > 0 and "unfed_with_request_ms" in disp.attrs
             t0 = time.perf_counter()
             for _ in range(n):
-                for name in ("engine.sweep", "engine.admit", "engine.dispatch",
-                             "engine.process.wait", "engine.process.deliver"):
+                state = engine._loop_state(pending)
+                for name in ("engine.sweep", "engine.admit"):
+                    with jax.profiler.TraceAnnotation(name, **state):
+                        pass
+                with jax.profiler.TraceAnnotation(
+                    "engine.dispatch", **engine._loop_state(pending, 1)
+                ):
+                    pass
+                for name in ("engine.grace", "engine.process.wait", "engine.process.deliver"):
                     with jax.profiler.TraceAnnotation(name):
                         pass
                 E.emit_dispatch_span("engine.iteration", 0.0, 1.0, {})
             per_iteration = min(per_iteration, (time.perf_counter() - t0) / n)
+        engine._open = {}
     finally:
         engine.stop()
     per_step = (per_dispatch + per_iteration) / engine.decode_chunk
