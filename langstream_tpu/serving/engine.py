@@ -669,6 +669,9 @@ def _make_paged_admit_group(mesh=None):
 # jaxlib without the probe reads as always
 _profiling = getattr(jax.profiler.TraceAnnotation, "is_enabled", lambda: True)
 
+# how an iteration came to its launch (`ServingEngine._await_launch`)
+LAUNCH_REASONS = ("at-once", "arrival", "deadline")
+
 
 def _mono_ns(disp: Optional[Dispatch]) -> int:
     """A launch annotation's ``t_mono_ns``: the launch's own monotonic stamp
@@ -757,11 +760,15 @@ class _TokenFetcher:
         self,
         injector: Optional[FaultInjector] = None,
         obs: Optional[EngineObservability] = None,
+        landed: Optional[Callable[[], None]] = None,
     ) -> None:
         self._queue: "queue.SimpleQueue" = queue.SimpleQueue()
         self._thread: Optional[threading.Thread] = None
         self._injector = injector
         self._obs = obs
+        # called after each result is on the host: the engine thread may be
+        # waiting for one (`ServingEngine._await_launch`)
+        self._landed = landed
 
     def alive(self) -> bool:
         t = self._thread
@@ -804,6 +811,8 @@ class _TokenFetcher:
             except BaseException as e:  # noqa: BLE001 — surface at result()
                 handle._value = e
             handle._event.set()
+            if self._landed is not None:
+                self._landed()
 
 
 class _Spill:
@@ -1301,7 +1310,8 @@ class ServingEngine:
         self.moe_routed_total = 0
         self.moe_dropped_total = 0
         # ready instant of the newest processed dispatch: with a span's
-        # own stamps, device-side time = end - max(start, this)
+        # own stamps, device-side time = end - max(start, this); what is in
+        # flight behind it started there (`_launch_deadline`)
         self._last_ready_t = 0.0
         # The device's unfed account (docs/SERVING.md §12): seconds since
         # `_account_t0` in which no dispatch was in flight, and the part of
@@ -1364,12 +1374,14 @@ class ServingEngine:
         # decode chunk size (tokens per dispatch per slot); clamped to
         # powers of two to bound recompiles
         self.decode_chunk = max(1, int(decode_chunk))
-        # dispatch pipeline depth: how many decode chunks may stay in flight
-        # (dispatched, unfetched) at once. Depth 1 — dispatch chunk k+1,
-        # then fetch chunk k — already overlaps the fetch with compute;
-        # deeper pipelines delay completion discovery and first-token
-        # fetches by a full chunk. (Default tuned for a device link that
-        # is gone; not re-measured — ROADMAP D5.)
+        # dispatch pipeline depth: how many decode chunks are in flight
+        # (dispatched, unfetched) when the oldest lands. Depth 1: chunk k+1
+        # is launched before chunk k ends (a margin ahead of its expected
+        # end, or sooner when a request arrives: `_await_launch`), then
+        # chunk k is fetched, so the fetch overlaps compute and the device
+        # never runs dry; deeper pipelines delay completion discovery and
+        # first-token fetches by a full chunk. (Default tuned for a device
+        # link that is gone; not re-measured: ROADMAP D5.)
         self.pipeline_depth = max(1, int(pipeline_depth))
         # smallest chunk the TTFT shrink may pick when admissible work waits
         self.ttft_chunk_floor = max(1, int(ttft_chunk_floor))
@@ -1686,10 +1698,23 @@ class ServingEngine:
             )
         # engine iterations, idle included (the flight recorder's clock)
         self._iterations_total = 0
+        # What the engine thread waits on while a chunk is in flight and
+        # nothing waits for an admission (`_await_launch`): `submit`, the
+        # fetch thread (a result landed), a migration command and `stop`
+        # set it. And how each iteration that launched came to its launch
+        # (stats "launches", restarted by `reset_histograms`): at once (a
+        # request or a segment waited already, or nothing was in flight),
+        # on an arrival during the wait, at the wait's deadline; `late`
+        # counts those of them that found live rows and every earlier
+        # result already on the host, so the device had run dry.
+        self._wake = threading.Event()
+        self._launches = dict.fromkeys(LAUNCH_REASONS + ("late",), 0)
+        self._late_probe = False
+        self._launched_late = False
         # dedicated device→host token fetch thread (started with the loop);
         # carries the injector for the fetch-stall site and the fetch
         # histogram
-        self._fetcher = _TokenFetcher(self._injector, self._obs)
+        self._fetcher = _TokenFetcher(self._injector, self._obs, self._wake.set)
         # EMA of observed queue wait (submit → admission), feeding the
         # hopeless-deadline shed decision and ShedError.retry_after_s
         self._queue_wait_ema_s = 0.0
@@ -1730,6 +1755,8 @@ class ServingEngine:
         # gap to the chip's roofline is a shipped metric, not a PERF.md
         # footnote
         self._step_time_ema_s: float = 0.0
+        # the newest sample the EMA took, unsmoothed (`_launch_deadline`)
+        self._last_step_s: float = 0.0
         self._last_chunk_ready_t: float = 0.0
         self._plan = None
         # HBM accounting up front: an over-committed config should announce
@@ -1929,6 +1956,7 @@ class ServingEngine:
 
     def stop(self) -> None:
         self._stop.set()
+        self._wake.set()
         if self._thread is not None:
             self._thread.join(timeout=30)
             self._thread = None
@@ -2136,6 +2164,7 @@ class ServingEngine:
                 self._waiting.pop(id(request), None)
                 self._open.pop(id(request), None)
             raise
+        self._wake.set()  # the engine thread may be waiting for an arrival
         return request
 
     def _tenant_wait_estimate(self, tenant: str) -> float:
@@ -2225,6 +2254,7 @@ class ServingEngine:
         with self._stats_lock:  # and the device's unfed account
             self._unfed_s = self._unfed_request_s = 0.0
             self._account_t0 = time.monotonic()
+        self._launches = dict.fromkeys(self._launches, 0)
 
     def prefix_advertisement(
         self, top_k: int = 32,
@@ -2386,6 +2416,10 @@ class ServingEngine:
             # admission groups dispatched at each row count of the ladder
             # (admit_rungs): how far groups shrink to the prompts they hold
             "admit-group-rows": dict(self._admit_group_rows),
+            # iterations that launched, by how the launch was decided, and
+            # the late ones among them (docs/SERVING.md, "When the engine
+            # launches"), since the engine was built or `reset_histograms`
+            "launches": dict(self._launches),
             "decode-step-ms": round(self._step_time_ema_s * 1e3, 3),
             "hbm-gbps-decode": self._achieved_hbm_gbps(),
             # the page pool, the engine's only KV state
@@ -3030,7 +3064,7 @@ class ServingEngine:
             # outcome. Abandon it like the device arrays (its late
             # result lands in an orphaned handle) and start fresh.
             log.warning("abandoning the wedged fetch worker")
-            self._fetcher = _TokenFetcher(self._injector, self._obs)
+            self._fetcher = _TokenFetcher(self._injector, self._obs, self._wake.set)
         if not self._fetcher.alive():
             self._fetcher.start()
 
@@ -3050,7 +3084,7 @@ class ServingEngine:
         side only)."""
         self._freed_slots.clear()
         self._spec_index.clear()
-        self._step_time_ema_s = 0.0
+        self._step_time_ema_s = self._last_step_s = 0.0
         self._last_chunk_ready_t = 0.0
         self._last_ready_t = 0.0
         self._last_fetch, self._launch_unfetched = None, False
@@ -3151,52 +3185,49 @@ class ServingEngine:
         # state there: live rows, queue depth, dispatches launched and not
         # yet processed
         state = self._loop_state(pending)
+        launch = LAUNCH_REASONS[0]
+        spec_on = self._spec_enabled and not (
+            # brownout level 2 (spec-off) falls back to plain decode
+            # chunks — token-exact for greedy streams by the round-9
+            # invariant, so in-flight work is never degraded in
+            # correctness, only in weight-read amortization
+            self._brownout is not None and self._brownout.spec_off
+        )
+        self._spill_ms_iter = 0.0
+        self._restore_ms_iter = 0.0
         with jax.profiler.TraceAnnotation("engine.sweep", **state):
-            # SPMD slice resilience (§20): the spmd-crash drill site, the
-            # divergence-resync poll, and the idle heartbeat — all at the
-            # iteration top, OUTSIDE any dispatch's announce sequence
-            if self._spmd is not None:
-                self._spmd_tick()
-            if self._pending_page_zero or self._pending_window_zero:
-                self._flush_page_zeros()
-            # tiered KV: fold completed spills in and start hibernation spills
-            # for idle prefixes — bounded per iteration, O(1) when idle; the
-            # restore half runs inside admission (_paged_bind) where it gates
-            self._spill_ms_iter = 0.0
-            self._restore_ms_iter = 0.0
-            if self._spill_on:
-                self._spill_tick()
-            # KV-page migration commands (snapshot/bind/release — §18) cross
-            # into the engine-thread domain here; O(1) when idle (one
-            # SimpleQueue emptiness check), and the idle loop spins at ~1ms so
-            # a migration never waits behind more than one iteration
-            self._drain_migrations()
-            self._sweep_waiting()
-            # brownout ladder (docs/SERVING.md §19): throttled load check on
-            # the engine thread — transitions count, dump and log here
-            if self._brownout is not None:
-                self._brownout_tick()
-            # deterministic noisy-neighbor drill: the `tenant-burst` fault
-            # site injects a synthetic aggressor burst at the iteration top
-            if self._injector is not None:
-                self._tenant_burst_tick()
-            # What is dispatched below queues behind the chunk in flight, so
-            # an admission decided now cannot start on the device for that
-            # chunk's whole length, and whoever reaches the queue a moment
-            # after the decision waits out another chunk. Spend a tenth of
-            # it waiting (25 ms of a 250 ms chat chunk; skipped where that
-            # is under one interpreter switch interval, so a fast model
-            # never waits, nor an idle engine, a cold start or the
-            # speculative loop, which hold nothing unfetched): the loop
-            # thread, held off the interpreter while this thread delivered,
-            # hands over the requests it holds, arrivals a few ms apart
-            # share one group, and a chat schedule no longer turns on which
-            # thread won the interpreter (PERF.md §6, PR 29).
+            self._sweep_duties()
+            # What is dispatched below queues behind what is in flight, so
+            # the launch is decided as late as the device allows. While a
+            # dispatched chunk is unfetched, a tenth of it (`grace`; 19 ms
+            # of a 190 ms chat chunk) is both the pause before an admission
+            # and the margin ahead of the chunk's expected end at which the
+            # next launch is due. Under one interpreter switch interval it
+            # is not worth a sleep, so a fast model never waits, nor an
+            # idle engine or a cold start, which hold nothing unfetched.
             grace = 0.1 * self._step_time_ema_s * self.decode_chunk
-            if pending and grace >= sys.getswitchinterval():
+            waits = pending and grace >= sys.getswitchinterval()
+            if waits and (spec_on or self._admission_waits()):
+                # a request, or a long prompt's next segment, waits already
+                # (every iteration of a backlog), or the loop speculates
+                # (it drains its one verify before it proposes: there is no
+                # launch to hold back): pause, admit, launch. In
+                # the pause the loop thread, held off the interpreter while
+                # this thread delivered, hands over the requests it holds,
+                # arrivals a few ms apart share one group, and a schedule
+                # does not turn on which thread won the interpreter
+                # (PERF.md §6, PR 29).
                 with jax.profiler.TraceAnnotation("engine.grace"):
                     time.sleep(grace)
+                waits = False
         t_sweep = time.monotonic() if obs_on else 0.0
+        if waits:
+            # nothing to admit: launching now would only put the next chunk,
+            # and the group of whoever arrives next, a whole chunk early
+            with jax.profiler.TraceAnnotation("engine.await", **state):
+                launch = self._await_launch(pending, grace)
+            state = self._loop_state(pending)
+        t_await = time.monotonic() if obs_on else 0.0
         with jax.profiler.TraceAnnotation("engine.admit", **state):
             # chunks dispatched in previous iterations are still unfetched when
             # this iteration's dispatch computes its headroom bound — subtract
@@ -3205,6 +3236,10 @@ class ServingEngine:
                 e[3] for batch in pending for e in batch if e[0] == "chunk"
             )
             had_active = any(s.active for s in self._slots)
+            # the first launch below says whether the device had run dry
+            # under live rows (the speculative loop drains by design)
+            self._late_probe = had_active and not spec_on
+            self._launched_late = False
             # the fused-iteration prefill budget (overlap off: unbounded, the
             # pre-overlap whole-backlog admission). Long prefill FIRST: it
             # claims a freed slot before _admit hands them all to short
@@ -3258,14 +3293,8 @@ class ServingEngine:
                 for entry in new_pending:
                     self._process_entry(entry)
                 new_pending = []
-            if (
-                self._spec_enabled
-                # brownout level 2 (spec-off) falls back to plain decode
-                # chunks — token-exact for greedy streams by the round-9
-                # invariant, so in-flight work is never degraded in
-                # correctness, only in weight-read amortization
-                and not (self._brownout is not None and self._brownout.spec_off)
-                and (new_pending or pending or any(s.active for s in self._slots))
+            if spec_on and (
+                new_pending or pending or any(s.active for s in self._slots)
             ):
                 # self-speculation serializes the host loop on fetched results:
                 # the next iteration's drafts must CONTINUE from the last
@@ -3306,6 +3335,7 @@ class ServingEngine:
             # under its own name, so `engine.dispatch` holds launches only
             with jax.profiler.TraceAnnotation("engine.idle"):
                 time.sleep(0.001)
+        self._late_probe = False
         t_dispatch = time.monotonic() if obs_on else 0.0
         waited_before = self._wait_s_iter
         pending.append(new_pending)
@@ -3320,6 +3350,10 @@ class ServingEngine:
         ):
             for entry in pending.popleft():
                 self._process_entry(entry)
+        launched = bool(disp_kind) or prefill_ahead
+        if launched:
+            self._launches[launch] += 1
+            self._launches["late"] += self._launched_late
         if obs_on and (disp_kind or n_admitted or spent or had_active):
             # flight-recorder frame — idle iterations (nothing active,
             # nothing dispatched) are skipped so the ring holds ~N frames
@@ -3340,6 +3374,10 @@ class ServingEngine:
                 "prefill_tokens": prefill_tokens,
                 "dispatch": disp_kind,
                 "steps": disp_steps,
+                # how the launch was decided (LAUNCH_REASONS), and whether
+                # it found live rows and nothing in flight
+                "launch": launch if launched else "",
+                "late": self._launched_late,
                 "kv_pages": (
                     self._pagepool.pages_in_use if self._pagepool else 0
                 ),
@@ -3356,7 +3394,10 @@ class ServingEngine:
                 ),
                 "phase_ms": {
                     "sweep": round((t_sweep - t0) * 1e3, 3),
-                    "prefill": round((t_prefill - t_sweep) * 1e3, 3),
+                    # `_await_launch`: waiting for an arrival or the launch
+                    # deadline, and what landed meanwhile
+                    "await": round((t_await - t_sweep) * 1e3, 3),
+                    "prefill": round((t_prefill - t_await) * 1e3, 3),
                     "dispatch": round((t_dispatch - t_prefill) * 1e3, 3),
                     "process": round(process_ms, 3),
                     # process = waiting for the device's results (the
@@ -3373,6 +3414,111 @@ class ServingEngine:
             }
             self._obs.flight.record(frame)
             emit_dispatch_span("engine.iteration", t0, t_end, frame)
+
+    def _sweep_duties(self) -> None:
+        """What the engine thread owes at the top of an iteration and after
+        every wake-up of `_await_launch`, whether or not a launch follows;
+        each is O(1) when it has nothing to do."""
+        # SPMD slice resilience (§20): the spmd-crash drill site, the
+        # divergence-resync poll, and the idle heartbeat, OUTSIDE any
+        # dispatch's announce sequence
+        if self._spmd is not None:
+            self._spmd_tick()
+        if self._pending_page_zero or self._pending_window_zero:
+            self._flush_page_zeros()
+        # tiered KV: fold completed spills in and start hibernation spills
+        # for idle prefixes — bounded per call, O(1) when idle; the restore
+        # half runs inside admission (_paged_bind) where it gates
+        if self._spill_on:
+            self._spill_tick()
+        # KV-page migration commands (snapshot/bind/release — §18) cross
+        # into the engine-thread domain here; O(1) when idle (one
+        # SimpleQueue emptiness check). The idle loop spins at ~1ms and a
+        # command wakes `_await_launch`, so a migration waits behind a
+        # launch or a delivery, never behind a chunk's length
+        self._drain_migrations()
+        self._sweep_waiting()
+        # brownout ladder (docs/SERVING.md §19): throttled load check on
+        # the engine thread — transitions count, dump and log here
+        if self._brownout is not None:
+            self._brownout_tick()
+        # deterministic noisy-neighbor drill: the `tenant-burst` fault
+        # site injects a synthetic aggressor burst here
+        if self._injector is not None:
+            self._tenant_burst_tick()
+
+    def _admission_waits(self) -> bool:
+        """Something waits that the admission phase would take up: a queued
+        request, a long prompt's open stream or backlog, an admission
+        deferred for pages or held back."""
+        return bool(
+            self._queue.qsize() or self._longs or self._long_queue
+            or self._page_deferred or self._held_back is not None
+        )
+
+    def _await_launch(self, pending, grace: float) -> str:
+        """With work in flight and nothing to admit, wait for what decides
+        the next launch, on one event with three sources. An ARRIVAL
+        (`submit` sets the event): pause ``grace`` as before any admission,
+        but not past the deadline, and go on to admit and launch; the group
+        still queues behind the chunk in flight, for what is left of it. A
+        result that LANDED (the fetch thread sets it): processed here, in
+        order, without a launch, so a first token is delivered when it is
+        on the host and not at the next launch. The DEADLINE: the expected
+        end of the oldest batch in flight less ``grace``, when the next
+        chunk has to follow for the device not to run dry
+        (`_launch_deadline`). A wait lasts at most ``grace`` at a time and
+        the sweep's duties run after each, so none of them waits out a
+        chunk. Returns the reason to launch now, one of LAUNCH_REASONS[1:]."""
+        while pending and not self._stop.is_set():
+            rest = self._launch_deadline(pending, grace) - time.monotonic()
+            if self._admission_waits():
+                if rest > 0:
+                    with jax.profiler.TraceAnnotation("engine.grace"):
+                        time.sleep(min(grace, rest))
+                return LAUNCH_REASONS[1]
+            if rest <= 0:
+                break
+            self._wake.wait(min(grace, rest))
+            # cleared BEFORE the look at what it may have signalled: a
+            # signal after this makes the next wait return at once
+            self._wake.clear()
+            self._take_landed(pending)
+            self._sweep_duties()
+        return LAUNCH_REASONS[2]
+
+    def _take_landed(self, pending) -> None:
+        """Process the entries at the head of ``pending`` whose result the
+        fetch thread has on the host, in dispatch order. Never one that
+        would block: an inline fetch under a live decode serializes the
+        loop on the chunk in flight (the cold-start branch's r4 note)."""
+        while pending:
+            batch = pending[0]
+            while batch and getattr(batch[0][1], "done", False):
+                self._process_entry(batch.pop(0))
+            if batch:
+                return
+            pending.popleft()
+
+    def _launch_deadline(self, pending, margin: float) -> float:
+        """The monotonic instant by which the next chunk has to be launched:
+        the expected end of the oldest batch in flight, less ``margin``.
+        The batch's chunk started when the result before it was ready
+        (`_last_ready_t`), or at its own launch if that came later, and
+        runs its steps at the step-time EMA, or at the EMA's newest sample
+        where that is shorter: an estimate that runs short launches as
+        early as the loop used to, one that runs long leaves the device
+        dry, and the EMA forgets a stale level (another occupancy, a slow
+        first execution) by a tenth a sample. A prefill group ahead of the
+        chunk in the batch makes this early until the group lands and moves
+        `_last_ready_t`; a batch without a chunk is due now. The oldest
+        batch, not all of them: at `pipeline-depth` n the n−1 younger
+        chunks stay queued behind it, as they are today."""
+        step_s = min(self._step_time_ema_s, self._last_step_s or self._step_time_ema_s)
+        for entry in pending[0]:
+            if entry[0] == "chunk":
+                return max(self._last_ready_t, entry[4]) + entry[3] * step_s - margin
+        return 0.0
 
     def _sweep_waiting(self) -> None:
         """Resolve queued-but-unadmitted requests that died while waiting
@@ -3607,6 +3753,12 @@ class ServingEngine:
         ``_moe_counts`` right after it. With observability on, the launch
         also closes the device's unfed stretch, if one is open."""
         self._dispatch_seq += 1
+        if self._late_probe:
+            # the iteration's first launch, with rows live: late when every
+            # earlier result is on the host already (the device ran dry)
+            self._late_probe = False
+            last = self._last_fetch
+            self._launched_late = last is not None and last.ready_at > 0
         if not (self._obs.on or self.config.is_moe):
             return None
         attrs["seq"] = self._dispatch_seq
@@ -3711,10 +3863,10 @@ class ServingEngine:
         the totals and emit its span (once, here — the request spans'
         rule). ``handle`` is the entry's fetch; the fetch thread stamped
         when its bytes landed and brought the counts with them."""
-        if disp is None:
-            return
         end = getattr(handle, "ready_at", 0.0) or time.monotonic()
         prev, self._last_ready_t = self._last_ready_t, end
+        if disp is None:
+            return
         attrs = disp.attrs
         counts = getattr(handle, "counts", None)
         if counts is not None:
@@ -3769,6 +3921,7 @@ class ServingEngine:
             elif not pipelined:
                 step_s = (now - t_dispatch) / max(1, steps)
         if step_s is not None:
+            self._last_step_s = step_s
             self._step_time_ema_s = (
                 step_s
                 if self._step_time_ema_s == 0
@@ -5819,6 +5972,7 @@ class ServingEngine:
             )
         reply: "queue.SimpleQueue" = queue.SimpleQueue()
         self._migrate_cmds.put((kind, payload, reply))
+        self._wake.set()
         try:
             status, out = reply.get(timeout=max(0.05, float(timeout_s)))
         except queue.Empty:

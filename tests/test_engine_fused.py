@@ -121,16 +121,118 @@ def test_prefill_token_budget_bounds_per_iteration_admission():
     engine._fail_all(RuntimeError("test torn down"))
 
 
+class FakeTime:
+    """The engine module's clock, by hand: `sleep` and a FakeWake's `wait`
+    are what move it; everything else is the real module's."""
+
+    def __init__(self, t=5000.0):
+        self.t = t
+        self.sleeps = []
+
+    def monotonic(self):
+        return self.t
+
+    def sleep(self, seconds):
+        self.sleeps.append(seconds)
+        self.t += seconds
+
+    def __getattr__(self, name):
+        import time
+
+        return getattr(time, name)
+
+
+class FakeWake:
+    """The event `_await_launch` waits on. ``script`` holds (instant, what
+    happens then): a wait that reaches the next instant stops there, runs it
+    and returns as a signalled wait does; any other runs out its timeout."""
+
+    def __init__(self, clock, script=()):
+        self.clock = clock
+        self.script = list(script)
+        self.waits = []
+
+    def wait(self, timeout=None):
+        self.waits.append(timeout)
+        if self.script and self.script[0][0] <= self.clock.t + timeout:
+            at, happen = self.script.pop(0)
+            self.clock.t = max(self.clock.t, at)
+            happen()
+            return True
+        self.clock.t += timeout
+        return False
+
+    def set(self):
+        pass
+
+    def clear(self):
+        pass
+
+
+T0 = 5000.0
+CHUNK_S = 0.25  # the chunk in flight, by the EMA: its tenth is 25 ms
+OPTS = GenerationOptions(max_new_tokens=60, temperature=0.0)
+
+
+def land(entry):
+    """What the fetch thread does when a dispatch's result is on the host."""
+    handle = entry[1]
+    handle._value = handle.get()
+    handle._event.set()
+
+
+def tear_down(engine, pending):
+    engine._stop.set()
+    while pending:
+        for entry in pending.popleft():
+            engine._process_entry(entry)
+    engine._fail_all(RuntimeError("test torn down"))
+
+
+def decoding_engine(monkeypatch, chunk_s=CHUNK_S, **kw):
+    """One request admitted from a cold start on a hand-driven clock: its
+    group landed inline, its first chunk is in flight (launched at T0, after
+    a result ready at T0), nothing is queued. The wait's deadline is
+    T0 + chunk_s - chunk_s / 10."""
+    from langstream_tpu.serving import engine as engine_mod
+
+    clock = FakeTime(T0)
+    monkeypatch.setattr(engine_mod, "time", clock)
+    engine = make_engine(
+        start=False, max_batch=4, max_seq_len=128, decode_chunk=8, overlap=True,
+        prefill_buckets=(16,), **kw,
+    )
+    engine._wake = wake = FakeWake(clock)
+    # a result is on the host when the test lands it, not when the CPU is done
+    monkeypatch.setattr(engine, "_batch_ready", lambda batch: False)
+    pending: deque = deque()
+    seen = []
+    first = engine.submit(GenerationRequest(
+        prompt_tokens=[4, 5, 6], options=OPTS, on_token=lambda t: seen.append(clock.t),
+    ))
+    engine._iterate(pending)
+    assert wake.waits == [] and clock.sleeps == [] and len(seen) == 1
+    engine._step_time_ema_s = chunk_s / engine.decode_chunk
+    return engine, clock, wake, pending, first
+
+
+def launches(engine):
+    return engine.stats()["launches"]
+
+
 @pytest.mark.parametrize(
     "chunk_s,in_flight,slept",
-    [(0.25, True, True), (0.25, False, False), (0.004, True, False)],
-    ids=["long-chunk-in-flight", "nothing-in-flight", "short-chunk-in-flight"],
+    [(0.25, True, True), (0.25, False, False), (0.004, True, False), (0.0, True, False)],
+    ids=["long-chunk-in-flight", "nothing-in-flight", "short-chunk-in-flight", "no-step-time-yet"],
 )
 def test_admission_grace_only_behind_a_long_chunk(monkeypatch, chunk_s, in_flight, slept):
-    """Before it decides an admission the engine thread waits a tenth of
-    the chunk in flight — only while a dispatched chunk is unfetched and
-    that tenth is worth an interpreter switch interval, so the device never
-    waits for it (an idle engine, a cold start and a fast model skip it)."""
+    """A request queued at the top of the iteration takes the path it always
+    took: before it decides the admission the engine thread pauses a tenth
+    of the chunk in flight, once, and never waits on the launch event — the
+    pause only while a dispatched chunk is unfetched and that tenth is worth
+    an interpreter switch interval, so the device never waits for it (an
+    idle engine, a cold start, a fast model and an engine that has timed no
+    step yet skip it)."""
     from langstream_tpu.serving import engine as engine_mod
 
     engine = make_engine(
@@ -139,17 +241,224 @@ def test_admission_grace_only_behind_a_long_chunk(monkeypatch, chunk_s, in_fligh
     engine._step_time_ema_s = chunk_s / engine.decode_chunk
     sleeps = []
     monkeypatch.setattr(engine_mod.time, "sleep", sleeps.append)
+    engine._wake = wake = FakeWake(FakeTime())
     pending: deque = deque([[]] if in_flight else [])
     opts = GenerationOptions(max_new_tokens=8, temperature=0.0)
     engine.submit(GenerationRequest(prompt_tokens=[4, 5, 6], options=opts))
     engine._iterate(pending)
     assert sum(1 for s in engine._slots if s.active) == 1
-    assert (pytest.approx(chunk_s / 10) in sleeps) == slept
-    engine._stop.set()
-    while pending:
-        for entry in pending.popleft():
-            engine._process_entry(entry)
-    engine._fail_all(RuntimeError("test torn down"))
+    assert sleeps == ([pytest.approx(chunk_s / 10)] if slept else [])
+    assert wake.waits == []
+    assert launches(engine) == {"at-once": 1, "arrival": 0, "deadline": 0, "late": 0}
+    tear_down(engine, pending)
+
+
+def test_no_arrival_launches_the_plain_chunk_at_the_deadline_and_not_earlier(monkeypatch):
+    """A chunk in flight and nothing to admit: the thread waits, a tenth of
+    the chunk at a time with the sweep's duties after each, and launches the
+    next chunk a tenth ahead of the expected end of the one in flight. The
+    wait is no unfed stretch: something is in flight throughout."""
+    engine, clock, wake, pending, _ = decoding_engine(monkeypatch)
+    swept = []
+    drain = engine._drain_migrations
+    monkeypatch.setattr(engine, "_drain_migrations", lambda: (swept.append(clock.t), drain()))
+    before = engine.stats()
+    engine._iterate(pending)
+    deadline = T0 + CHUNK_S - CHUNK_S / 10
+    (chunk,) = pending[0]
+    assert chunk[0] == "chunk" and chunk[4] == pytest.approx(deadline, abs=1e-6)
+    assert chunk[4] >= deadline - 1e-9
+    assert clock.sleeps == []  # no pause: nothing was admitted
+    assert sum(wake.waits) == pytest.approx(CHUNK_S * 0.9, abs=1e-6)
+    assert max(wake.waits) <= CHUNK_S / 10 + 1e-9
+    # the top of the iteration, then once after every wait
+    assert len(swept) == 1 + len(wake.waits)
+    assert launches(engine) == {"at-once": 1, "arrival": 0, "deadline": 1, "late": 0}
+    after = engine.stats()
+    assert after["engine-loop-s"] - before["engine-loop-s"] == pytest.approx(CHUNK_S * 0.9, abs=1e-3)
+    assert after["device-unfed-s"] == before["device-unfed-s"]
+    assert after["device-unfed-with-request-s"] == before["device-unfed-with-request-s"]
+    frame = engine._obs.flight.iterations()[-1]
+    assert frame["launch"] == "deadline" and frame["late"] is False
+    assert frame["phase_ms"]["await"] == pytest.approx(CHUNK_S * 900, abs=0.01)
+    tear_down(engine, pending)
+
+
+def test_the_deadline_follows_the_newest_step_sample_where_the_ema_runs_long(monkeypatch):
+    """The EMA forgets a stale level by a tenth a sample (another occupancy,
+    a slow first execution); an estimate that runs long leaves the device
+    dry, one that runs short only launches as early as the loop used to. So
+    the deadline takes the shorter of the EMA and its newest sample; the
+    margin stays the pause's tenth of the EMA's chunk."""
+    engine, clock, wake, pending, _ = decoding_engine(monkeypatch, chunk_s=2 * CHUNK_S)
+    engine._last_step_s = CHUNK_S / engine.decode_chunk
+    engine._iterate(pending)
+    (chunk,) = pending[0]
+    assert chunk[4] == pytest.approx(T0 + CHUNK_S - 2 * CHUNK_S / 10, abs=1e-6)
+    assert launches(engine) == {"at-once": 1, "arrival": 0, "deadline": 1, "late": 0}
+    tear_down(engine, pending)
+
+
+@pytest.mark.parametrize(
+    "arrives,paused",
+    [(0.1, CHUNK_S / 10), (0.215, 0.01)],
+    ids=["mid-chunk", "inside-the-last-pause-before-the-deadline"],
+)
+def test_an_arrival_during_the_wait_launches_after_the_grace_and_before_the_deadline(
+    monkeypatch, arrives, paused
+):
+    """`submit` wakes the waiting thread: it pauses the admission's tenth of
+    a chunk, but never past the deadline, then admits and launches the group
+    with the next chunk behind what is left of the chunk in flight."""
+    engine, clock, wake, pending, _ = decoding_engine(monkeypatch)
+    second = GenerationRequest(prompt_tokens=[7, 8, 9], options=OPTS)
+    wake.script = [(T0 + arrives, lambda: engine.submit(second))]
+    engine._iterate(pending)
+    deadline = T0 + CHUNK_S - CHUNK_S / 10
+    assert clock.sleeps == [pytest.approx(paused, abs=1e-6)]
+    group, chunk = pending[-1]
+    assert group[0] == "prefill" and [r for _, r in group[2]] == [second]
+    assert chunk[0] == "chunk" and chunk[4] == pytest.approx(T0 + arrives + paused, abs=1e-6)
+    assert T0 + arrives < chunk[4] <= deadline + 1e-9
+    assert sum(1 for s in engine._slots if s.active) == 2
+    assert launches(engine) == {"at-once": 1, "arrival": 1, "deadline": 0, "late": 0}
+    assert engine._obs.flight.iterations()[-1]["launch"] == "arrival"
+    tear_down(engine, pending)
+
+
+def test_a_first_token_that_lands_during_the_wait_is_delivered_before_the_next_launch(monkeypatch):
+    """The trap: a batch used to be processed only after the next launch. A
+    group launched on an arrival lands while the thread waits for the next
+    deadline; the landing wakes it, the first token is delivered then, and
+    the deadline moves to the end of the chunk behind the group."""
+    engine, clock, wake, pending, _ = decoding_engine(monkeypatch)
+    got = []
+    second = GenerationRequest(
+        prompt_tokens=[7, 8, 9], options=OPTS, on_token=lambda t: got.append(clock.t),
+    )
+    wake.script = [(T0 + 0.1, lambda: engine.submit(second))]
+    engine._iterate(pending)  # group + chunk 2 launched at T0 + 0.125, chunk 1 processed
+    assert [e[0] for e in pending[0]] == ["prefill", "chunk"] and got == []
+    landed = T0 + 0.125 + 0.06
+    wake.script = [(landed, lambda: land(pending[0][0]))]
+    engine._iterate(pending)
+    assert got[0] == pytest.approx(landed)  # delivered at the landing
+    chunk = pending[-1][0]
+    # chunk 2 started when the group ahead of it was ready
+    assert chunk[4] == pytest.approx(landed + CHUNK_S - CHUNK_S / 10, abs=1e-6)
+    assert got[0] < chunk[4]
+    assert launches(engine) == {"at-once": 1, "arrival": 1, "deadline": 1, "late": 0}
+    tear_down(engine, pending)
+
+
+def test_a_chunk_that_lands_before_its_deadline_is_processed_and_the_launch_counts_late(monkeypatch):
+    """An estimate that runs long (here by a factor of two) cannot leave the
+    device idle to the deadline: the chunk's landing wakes the thread, its
+    tokens are delivered, and with nothing in flight the next chunk follows
+    at once, counted late (rows were live and the device had run dry)."""
+    engine, clock, wake, pending, first = decoding_engine(monkeypatch, chunk_s=2 * CHUNK_S)
+    wake.script = [(T0 + CHUNK_S, lambda: land(pending[0][0]))]
+    engine._iterate(pending)
+    (chunk,) = pending[0]
+    assert chunk[4] == pytest.approx(T0 + CHUNK_S)
+    slot = next(s for s in engine._slots if s.request is first)
+    assert len(slot.generated) == 1 + engine.decode_chunk
+    assert launches(engine) == {"at-once": 1, "arrival": 0, "deadline": 1, "late": 1}
+    assert engine._obs.flight.iterations()[-1]["late"] is True
+    tear_down(engine, pending)
+
+
+def test_a_request_cancelled_during_the_wait_is_resolved_within_one_slice(monkeypatch):
+    """The sweep's duties do not wait out the chunk: a request that arrives
+    and is cancelled while the thread waits is resolved by the sweep that
+    follows the wake-up, not at the next launch."""
+    engine, clock, wake, pending, _ = decoding_engine(monkeypatch)
+    resolved = []
+    second = GenerationRequest(
+        prompt_tokens=[7, 8, 9], options=OPTS, on_done=lambda r: resolved.append(clock.t),
+    )
+    wake.script = [(T0 + 0.06, lambda: (engine.submit(second), second.cancel()))]
+    engine._iterate(pending)
+    assert second.result(timeout=0).finish_reason == "cancelled"
+    assert resolved == [pytest.approx(T0 + 0.06)]
+    assert sum(1 for s in engine._slots if s.active) == 1
+    assert engine.stats()["cancelled-total"] == 1
+    tear_down(engine, pending)
+
+
+@pytest.mark.parametrize("case", ["no-step-time-yet", "short-chunk", "nothing-in-flight", "speculative"])
+def test_the_launch_wait_is_skipped_where_the_grace_is(monkeypatch, case):
+    """With nothing queued the thread waits only behind an unfetched chunk
+    whose tenth is worth an interpreter switch interval: not before the
+    first step was timed, not for a fast model, not with nothing in flight
+    (the launch is late there: rows live, the device dry), and never in the
+    speculative loop, which pauses before it drains its one verify, as it
+    did, and has no launch to hold back."""
+    kw = {"speculation": True, "speculation_tokens": 2} if case == "speculative" else {}
+    engine, clock, wake, pending, _ = decoding_engine(monkeypatch, **kw)
+    if case == "no-step-time-yet":
+        engine._step_time_ema_s = 0.0
+    elif case == "short-chunk":
+        engine._step_time_ema_s = 0.004 / engine.decode_chunk
+    elif case == "nothing-in-flight":
+        engine._take_landed(pending)  # nothing has landed: nothing is taken
+        assert len(pending) == 1
+        land(pending[0][0])
+        engine._take_landed(pending)
+    assert bool(pending) == (case != "nothing-in-flight")
+    engine._iterate(pending)
+    assert wake.waits == []
+    assert clock.sleeps == ([pytest.approx(CHUNK_S / 10)] if case == "speculative" else [])
+    counted = launches(engine)
+    assert counted["arrival"] == counted["deadline"] == 0 and counted["at-once"] == 2
+    assert counted["late"] == (case == "nothing-in-flight")
+    tear_down(engine, pending)
+
+
+def test_a_live_engine_that_waits_serves_the_same_tokens_and_counts_every_launch():
+    """The wait on real threads: with the interpreter's switch interval
+    lowered so that tiny-test's chunk is worth waiting behind, staggered
+    requests are served token for token as by an engine that never waits,
+    every first token and every end arrives, and the launches counted by
+    reason are the iterations that launched."""
+    import sys
+    import time
+
+    opts = GenerationOptions(max_new_tokens=40, temperature=0.0)
+    prompts = [[3 + i, 5 + i, 7 + i] for i in range(6)]
+
+    def serve():
+        engine = make_engine(
+            max_batch=4, max_seq_len=128, decode_chunk=16, prefill_buckets=(16,),
+            overlap=True,
+        )
+        try:
+            engine.generate(prompts[0], opts, timeout=300)  # compiles; times a step
+            engine.reset_histograms()
+            requests = []
+            for prompt in prompts:
+                requests.append(engine.submit(GenerationRequest(prompt_tokens=prompt, options=opts)))
+                time.sleep(0.003)
+            tokens = [r.result(timeout=300).tokens for r in requests]
+            return tokens, engine.stats()["launches"], engine._obs.flight.iterations()
+        finally:
+            engine.stop()
+
+    plain, counted, _ = serve()
+    assert counted["arrival"] == counted["deadline"] == 0  # a 10 ms chunk: never waits
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        waited, counted, frames = serve()
+    finally:
+        sys.setswitchinterval(interval)
+    assert waited == plain
+    assert counted["arrival"] + counted["deadline"] > 0
+    by_reason = [f["launch"] for f in frames if f.get("launch")]
+    # the ring also holds the compile request's iterations, before the reset
+    for reason in ("at-once", "arrival", "deadline"):
+        assert counted[reason] <= by_reason.count(reason)
+    assert counted["late"] <= sum(counted[r] for r in ("at-once", "arrival", "deadline"))
 
 
 def test_warmup_freezes_the_heap_and_stop_gives_it_back():
