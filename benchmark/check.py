@@ -6,13 +6,27 @@ request at a time: same program, same weights, same sample, same batch
 composition in every run of every seed. Nothing drawn from `--seed` and
 nothing observed in the timed window enters the verdict.
 
+The unit of the check is a *pass*: one forward of the model over a sequence
+as the model saw it, with the positions whose logits chose tokens in that
+forward (`read`), the tokens chosen there (`picked`) and, where the model
+chooses WHICH positions to fix, the positions that could have been chosen
+(`open`). A family whose engine yields one token a row and a step, left to
+right, says nothing and gets ONE pass, built here (`single_pass`): `tokens =
+prompt + generated`, `read = n_prompt-1 .. n-2` (the logits at position p
+score the token at p + 1), `picked = generated`. A family whose engine fills a
+block of tokens by denoising exports `trajectory(spec, prompt, result) -> passes`:
+the clean prefix and the block with its mask ids where positions were still
+open, once for every forward that chose tokens (and for a forward that only
+wrote its state, with an empty `read`); its hot path then returns
+`pass_logits(params, prompt, passes)`, one `[len(read), V]` a pass.
+
 Three levels, all against the plain float32 reference on the same dequantised
-int8 weights:
+int8 weights, each over every pass of a sequence and pooled over them:
 
 1. *Model level, teacher-forced.* The program's own block (with the engine's
-   config, so its attention dispatch: the family's `system_chain`,
-   `families/<family>.py`) is applied layer by layer to each sample
-   sequence. At every layer the reference block is given the
+   config, so its attention dispatch and its mask: the family's
+   `system_chain`, `families/<family>.py`) is applied layer by layer to each
+   pass's `tokens`. At every layer the reference block is given the
    SYSTEM's input to that layer and the two outputs are compared per
    position: `e = |sys - ref|_inf / |ref|_inf`. Teacher forcing keeps one
    layer's rounding, and one token's router tie, from spreading to every
@@ -28,18 +42,18 @@ int8 weights:
    rule does not bind.
 2. *Hot path, logits.* The model functions the engine's own programs are
    made of, called as the engine calls them (the family's `hot_path`): for
-   the two families there are, `prefill` of the group into a local cache (the
+   Mistral and Mixtral, `prefill` of the group into a local cache (the
    flash prefill kernel), `paged_insert_cache` into a page pool of the
    engine's page size and KV type, then one `paged_decode_step_inplace` per
    generated token through the page table (the ragged paged decode kernel),
-   teacher-forced on the engine's own tokens. Per generated position
+   teacher-forced on the engine's own tokens. Per read position
    `h = |hot - chain|_inf / |chain|_inf`
-   against the logits of step 1's chain on the same sequence, every layer of
+   against the logits of step 1's chain OF THAT PASS at that position, every layer of
    which was just held to the reference (and, for a dense model, its logits
    to the free-running reference by `tol_e2e_max`). Both run in bf16 on the
    same weights and part by one more bf16 rounding a layer, like two runs of
-   one kernel in another order; a sequence's first generated position comes
-   from the prefill alone, the same kernel on the same values as the chain,
+   one kernel in another order; a sequence's first read position (of its
+   first pass) comes from the prefill alone, the same kernel on the same values as the chain,
    and sits far closer. Pass: the median `h` over positions not tie-exposed
    <= `tol_hot_med`, and every position with `h > tol_hot_max` (a first
    position: `tol_hot_first`) is one whose OWN token is tie-exposed (router
@@ -50,14 +64,22 @@ int8 weights:
    lower precision shows here as a number, whatever types the engine reports.
 3. *Engine level, margins.* Each sample prompt goes through the engine itself
    (its fused admit and decode programs, sampling, scheduling), greedy,
-   `new_tokens` tokens. Per generated position the margin is `max(ref) -
-   ref[engine's token]`, where `ref` is the free-running float32 reference's
-   logits (`engine_scores: reference`) or, where router ties make a
+   `new_tokens` tokens. Per read position p of a pass the margin is
+   `max(ref[p]) - ref[p][picked]`, where `ref` is the free-running float32
+   reference's logits OF THE PASS THAT CHOSE THE TOKEN (`engine_scores:
+   reference`) or, where router ties make a
    free-running reference part ways with any bf16 run, the chain's
    (`engine_scores: verified_chain`). Random weights give near-flat logits,
    so tokens are never compared, only margins. Pass:
    every margin <= `tol_margin`, except at positions whose own token is
-   tie-exposed.
+   tie-exposed. Where a pass has `open` and the family exports
+   `choice_score(logits) -> one number a position` (for a model that fixes
+   its most confident positions: the log of the largest softmax probability),
+   the engine's CHOICE of positions is held too, from the same `ref`: the
+   highest score among the open positions it left may lie above the score of
+   a position it fixed by at most `tol_choice`
+   (`engine_choice_over_tol_untied`, a row of `compared` only for such a
+   family; a position is excused by the same own-token rule).
 
 The engine's state is also held to the file: whatever the family's
 `engine_state` finds (int8 weights, the KV dtype) against the same keys of the
@@ -65,7 +87,7 @@ The engine's state is also held to the file: whatever the family's
 
 This file keeps the loop, the comparison, the tie logic, the judge and what
 every tolerance means; it knows no published key, no weight leaf and no model
-function: a family's file does.
+function, and not how an engine came by its tokens: a family's file does.
 """
 
 from __future__ import annotations
@@ -121,33 +143,38 @@ class _Scorer:
             return rel_err(sys_logits, forced), rel_err(sys_logits, free), free
 
         @jax.jit
-        def margins(logits, tokens):
-            # logits at position p score the token at p + 1
-            picked = jnp.take_along_axis(logits[:-1], tokens[1:, None], axis=-1)[:, 0]
-            return jnp.max(logits[:-1], axis=-1) - picked
+        def margins(logits, read, picked):
+            # the logits AT a read position chose the token picked there
+            rows = logits[read]
+            return jnp.max(rows, axis=-1) - jnp.take_along_axis(rows, picked[:, None], axis=-1)[:, 0]
 
         @jax.jit
-        def hot_err(logits, hot, start):
-            # hot[j] is the hot path's distribution for generated token j,
-            # which the sequence-wide logits hold at position start + j
-            want = lax.dynamic_slice_in_dim(logits, start, hot.shape[0], axis=0)
-            return rel_err(hot, want.astype(jnp.float32))
+        def hot_err(logits, hot, read):
+            # hot[j] is the hot path's distribution at read position j of
+            # this pass, which the pass-wide logits hold at read[j]
+            return rel_err(hot, logits[read].astype(jnp.float32))
 
         self._fns = (ref_layer, ref_head, margins, hot_err)
         self._ref_embed = jax.jit(ref.embed)
+        choice = getattr(family, "choice_score", None)
+        self._choice = None if choice is None else jax.jit(choice)
 
-    def score(self, sys_params, ref_params, sequence: list[int], n_prompt: int, hot) -> dict:
-        """All the per-position numbers for one sequence (host arrays).
-        `hot` [generated, V]: the hot path's logits for the generated tokens."""
+    def score(self, sys_params, ref_params, a_pass: dict, hot) -> dict:
+        """All the per-position numbers for one pass (host arrays).
+        `hot` [len(read), V]: the hot path's logits at the pass's read positions."""
         import jax
         import jax.numpy as jnp
 
         chain = self._chain
         ref_layer, ref_head, margins, hot_err = self._fns
-        n = len(sequence)
+        sequence, n = list(a_pass["tokens"]), len(a_pass["tokens"])
         if n > self.width:
             raise ValueError(f"check sequence of {n} tokens exceeds width {self.width}")
+        if len(a_pass["read"]) != len(a_pass["picked"]) or not all(0 <= p < n for p in a_pass["read"]):
+            raise ValueError("a pass reads positions of its own tokens, one picked token each")
         tokens = jnp.asarray(sequence + [0] * (self.width - n), jnp.int32)
+        read = jnp.asarray(a_pass["read"], jnp.int32)
+        picked = jnp.asarray(a_pass["picked"], jnp.int32)
         x = chain.embed(sys_params, tokens)
         free = self._ref_embed(ref_params, tokens)
         errs, gaps, loads = [], [], []
@@ -162,15 +189,23 @@ class _Scorer:
             x = y
         chain_logits = chain.unembed(sys_params, x)
         head_err, e2e_err, free_logits = ref_head(ref_params, x, chain_logits, free)
+        judged = chain_logits if self.judge == "chain" else free_logits
         out = {
             "layer_err": jnp.stack(errs + [head_err])[:, :n],  # [L + 1, n]
             "router_gap": jnp.stack(gaps)[:, :n],  # [L, n]
             "expert_load_max": jnp.max(jnp.stack(loads)),
             "e2e_err": e2e_err[:n],
-            "margin": margins(chain_logits if self.judge == "chain" else free_logits, tokens)[: n - 1],
-            "hot_err": hot_err(chain_logits, hot, n_prompt - 1),  # [generated]
+            "margin": margins(judged, read, picked),  # [read]
+            "hot_err": hot_err(chain_logits, hot, read),  # [read]
         }
-        return {k: np.asarray(v) for k, v in jax.device_get(out).items()}
+        if self._choice is not None and "open" in a_pass:
+            out["choice"] = self._choice(judged)[:n]
+        out = {k: np.asarray(v) for k, v in jax.device_get(out).items()}
+        # which positions the numbers belong to travels with them to the judge
+        out["read"] = np.asarray(a_pass["read"], np.int64)
+        if "choice" in out:
+            out["left_open"] = np.asarray(sorted(set(a_pass["open"]) - set(a_pass["read"])), np.int64)
+        return out
 
 
 def _engine_state(family, engine, check: dict) -> dict:
@@ -180,56 +215,87 @@ def _engine_state(family, engine, check: dict) -> dict:
     return {"found": found, "wanted": wanted, "ok": found == wanted}
 
 
-def _generate(engine, prompts: list[list[int]], new_tokens: int, together: bool) -> list[list[int]]:
+def _generate(engine, prompts: list[list[int]], new_tokens: int, together: bool) -> list:
+    """Each request's whole result: its `.tokens`, and whatever else the
+    engine says of how it made them, which only a family's file can read."""
     from langstream_tpu.models.configs import GenerationOptions
     from langstream_tpu.serving.engine import GenerationRequest
 
     greedy = GenerationOptions(max_new_tokens=new_tokens, temperature=0.0)
     if not together:
-        return [list(engine.generate(p, greedy, timeout=600).tokens) for p in prompts]
+        return [engine.generate(p, greedy, timeout=600) for p in prompts]
     requests = [
         engine.submit(GenerationRequest(prompt_tokens=list(p), options=greedy))
         for p in prompts
     ]
-    return [list(r.result(600).tokens) for r in requests]
+    return [r.result(600) for r in requests]
 
 
-def _judge(scores: list[dict], prompt_lens: list[int], check: dict) -> dict:
-    """Tolerances of the file over the per-position numbers."""
+def single_pass(prompt: list[int], generated: list[int]) -> list[dict]:
+    """What an engine that yields one token a row and a step means, as ONE
+    pass: a forward over the clean sequence, whose logits at position p chose
+    the token at p + 1."""
+    n = len(prompt)
+    return [{
+        "tokens": list(prompt) + list(generated),
+        "read": list(range(n - 1, n - 1 + len(generated))),
+        "picked": list(generated),
+    }]
+
+
+def _judge(scores: list[list[dict]], check: dict) -> dict:
+    """Tolerances of the file over the per-position numbers: `scores` holds,
+    for each sequence, the numbers of each of its passes."""
     eps = float(check.get("eps_router", 0.0))
     layer_errs, unexplained, exposed, flipped = [], 0, 0, 0
     gen_n, gen_exposed, margin_bad, hot_bad, first_bad, first_max = 0, 0, 0, 0, 0, 0.0
     margins, hot_errs, e2e, over_gap_max = [], [], [], 0.0
-    for s, n_prompt in zip(scores, prompt_lens):
-        err, gap = s["layer_err"], s["router_gap"]
-        if not (np.isfinite(err).all() and np.isfinite(s["hot_err"]).all()):
-            return {"ok": False, "reason": "non-finite activations",
-                    "compared": {"non_finite_sequences": [1, 0]}}
-        tie = np.zeros_like(err, bool)
-        tie[:-1] = gap < eps  # the head has no router
-        over = err > float(check["tol_max"])
-        if over[:-1].any():
-            finite = gap[over[:-1]][np.isfinite(gap[over[:-1]])]
-            over_gap_max = max(over_gap_max, float(finite.max(initial=0.0)))
-        layer_errs.append(err.ravel())
-        exposed += int(tie.sum())
-        flipped += int((over & tie).sum())
-        unexplained += int((over & ~tie).sum())
-        e2e.append(s["e2e_err"])
-        # generated token j sits at index n_prompt + j and is drawn from the
-        # logits at n_prompt + j - 1: the token THERE is the one whose own
-        # routing decides them
-        generated = s["margin"][n_prompt - 1 :]
-        own_tie = tie[:-1].any(axis=0)[n_prompt - 1 : n_prompt - 1 + len(generated)]
-        gen_n += len(generated)
-        gen_exposed += int(own_tie.sum())
-        margin_bad += int(((generated > float(check["tol_margin"])) & ~own_tie).sum())
-        hot_bad += int(((s["hot_err"] > float(check["tol_hot_max"])) & ~own_tie).sum())
-        if not own_tie[0]:  # the prefill's own logits, before any paged read
-            first_max = max(first_max, float(s["hot_err"][0]))
-            first_bad += int(s["hot_err"][0] > float(check["tol_hot_first"]))
-        margins.append(generated)
-        hot_errs.append(np.where(own_tie, -s["hot_err"], s["hot_err"]))
+    choice_seen, choice_n, choice_bad, choice_max = False, 0, 0, 0.0
+    for passes in scores:
+        first = True  # the sequence's first read position is still to come
+        for s in passes:
+            err, gap, read = s["layer_err"], s["router_gap"], s["read"]
+            if not (np.isfinite(err).all() and np.isfinite(s["hot_err"]).all()):
+                return {"ok": False, "reason": "non-finite activations",
+                        "compared": {"non_finite_sequences": [1, 0]}}
+            tie = np.zeros_like(err, bool)
+            tie[:-1] = gap < eps  # the head has no router
+            over = err > float(check["tol_max"])
+            if over[:-1].any():
+                finite = gap[over[:-1]][np.isfinite(gap[over[:-1]])]
+                over_gap_max = max(over_gap_max, float(finite.max(initial=0.0)))
+            layer_errs.append(err.ravel())
+            exposed += int(tie.sum())
+            flipped += int((over & tie).sum())
+            unexplained += int((over & ~tie).sum())
+            e2e.append(s["e2e_err"])
+            # a token is drawn from the logits at its read position: the token
+            # THERE, as this pass saw it, is the one whose own routing decides them
+            tied_here = tie[:-1].any(axis=0)
+            margin, own_tie = s["margin"], tied_here[read]
+            gen_n += len(margin)
+            gen_exposed += int(own_tie.sum())
+            margin_bad += int(((margin > float(check["tol_margin"])) & ~own_tie).sum())
+            hot_bad += int(((s["hot_err"] > float(check["tol_hot_max"])) & ~own_tie).sum())
+            if first and len(read):
+                first = False
+                if not own_tie[0]:  # the prefill's own logits, before any paged read
+                    first_max = max(first_max, float(s["hot_err"][0]))
+                    first_bad += int(s["hot_err"][0] > float(check["tol_hot_first"]))
+            margins.append(margin)
+            hot_errs.append(np.where(own_tie, -s["hot_err"], s["hot_err"]))
+            if "choice" in s:
+                # the engine chose WHICH open positions to fix: the best it
+                # left may not outscore one it fixed by more than the tolerance
+                choice_seen = True
+                left = s["left_open"]
+                if len(left) and len(read):
+                    best = left[np.argmax(s["choice"][left])]
+                    behind = s["choice"][best] - s["choice"][read]
+                    excused = own_tie | tied_here[best]
+                    choice_n += len(read)
+                    choice_max = max(choice_max, float(behind.max()))
+                    choice_bad += int(((behind > float(check["tol_choice"])) & ~excused).sum())
     all_err = np.concatenate(layer_errs)
     e2e_all = np.concatenate(e2e)
     hot_all = np.concatenate(hot_errs)  # a tie-exposed position is written negative
@@ -266,6 +332,10 @@ def _judge(scores: list[dict], prompt_lens: list[int], check: dict) -> dict:
     if check.get("tol_hot_med") is not None:
         compared["hot_err_median_untied"] = [
             verdict["hot_err_median_unexposed"], float(check["tol_hot_med"])]
+    if choice_seen:
+        verdict.update(engine_choice_positions=choice_n, engine_choice_behind_max=choice_max,
+                       engine_choice_over_tol=choice_bad)
+        compared["engine_choice_over_tol_untied"] = [choice_bad, 0]
     verdict["compared"] = compared
     verdict["ok"] = all(value <= limit for value, limit in compared.values())
     return verdict
@@ -296,23 +366,36 @@ def run_check(
     width, rows = int(check["width"]), int(check.get("rows", 1))
     scorer = _Scorer(family, ref, config, dims, width, rows, check["engine_scores"])
     hot_path = family.hot_path(engine, width, rows, new_tokens)
+    if hasattr(family, "trajectory"):
+        def passes_of(prompt, result):
+            return family.trajectory(spec, prompt, result)
 
-    def score(prompt: list[int], tokens: list[int]) -> dict:
-        hot = hot_path.logits(engine.params, prompt, tokens)
-        return scorer.score(engine.params, ref_params, prompt + tokens, len(prompt), hot)
+        hot_of = hot_path.pass_logits
+    else:
+        def passes_of(prompt, result):
+            return single_pass(prompt, list(result.tokens))
 
-    generated = _generate(engine, prompts, new_tokens, together=False)
+        def hot_of(params, prompt, passes):
+            return [hot_path.logits(params, prompt, passes[0]["picked"])]
+
+    def score(prompt: list[int], result) -> list[dict]:
+        passes = passes_of(prompt, result)
+        hots = hot_of(engine.params, prompt, passes)
+        return [scorer.score(engine.params, ref_params, p, h) for p, h in zip(passes, hots, strict=True)]
+
+    results = _generate(engine, prompts, new_tokens, together=False)
+    generated = [list(r.tokens) for r in results]
     answered = all(len(g) > 0 for g in generated)
     if not answered:  # a prompt that produced nothing was not checked at all
         verdict = {"ok": False, "reason": "a check prompt produced no token",
                    "compared": {"check_prompts_unanswered": [sum(not g for g in generated), 0]}}
         emit(phase="check", **verdict)
         return verdict
-    scores = [score(p, g) for p, g in zip(prompts, generated)]
-    verdict = _judge(scores, [len(p) for p in prompts], check)
+    scores = [score(p, r) for p, r in zip(prompts, results)]
+    verdict = _judge(scores, check)
     verdict["engine_state"] = state
     verdict["generated_tokens"] = [len(g) for g in generated]
-    verdict["expert_load_max"] = int(max(s["expert_load_max"] for s in scores))
+    verdict["expert_load_max"] = int(max(s["expert_load_max"] for passes in scores for s in passes))
     verdict["compared"]["engine_state_mismatches"] = [
         sum(state["found"][key] != state["wanted"][key] for key in state["found"]), 0]
     verdict["ok"] = bool(verdict["ok"] and state["ok"])
@@ -323,16 +406,13 @@ def run_check(
         # program's expert capacity can drop real tokens (PERF.md, fault 1).
         # A finding to print, never part of the verdict.
         together = _generate(engine, prompts, new_tokens, together=True)
-        probe = _judge(
-            [score(p, g) for p, g in zip(prompts, together)],
-            [len(p) for p in prompts], check,
-        )
+        probe = _judge([score(p, r) for p, r in zip(prompts, together)], check)
         emit(
             phase="check-batched-probe",
             engine_positions=probe.get("engine_positions"),
             engine_margin_over_tol=probe.get("engine_margin_over_tol"),
             engine_margin_max=probe.get("engine_margin_max"),
             hot_err_over_tol=probe.get("hot_err_over_tol"),
-            same_tokens_as_alone=[a == b for a, b in zip(generated, together)],
+            same_tokens_as_alone=[a == list(r.tokens) for a, r in zip(generated, together)],
         )
     return verdict
