@@ -42,7 +42,7 @@ WARMED_MODULES: dict[str, int] = {
     "langstream_tpu/parallel/sp.py": 1,              # long-context ring
     "langstream_tpu/serving/adapters.py": 1,         # LoRA row swap
     "langstream_tpu/serving/constrain.py": 1,        # grammar mask load
-    "langstream_tpu/serving/engine.py": 10,          # the warmed paged programs (+ a window model's `_window_page_zero`, warmed with `_page_zero`)
+    "langstream_tpu/serving/engine.py": 12,          # the warmed paged programs (+ a window model's `_window_page_zero`, warmed with `_page_zero`; + a block-filling model's `_block_admit_group` and `_paged_block_chunk`, warmed where the admit group and the decode chunk are: `_dev_paged_prefill`, `_dev_decode`)
     "langstream_tpu/serving/sampling.py": 2,         # sample/verify kernels
 }
 

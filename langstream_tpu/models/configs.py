@@ -93,21 +93,43 @@ class ModelConfig:
     # The block of a model with window layers is ONE block (transformer
     # `_scan_window_periods`): parallel, x + Attn(u) + MoE(u) with u =
     # norm(x), one norm a layer, and an expert layer that holds a share
-    # (`moe_ffn_held`). ``sliding_window``, ``rope_interleaved``, ``norm`` and
-    # the four fields below are read by that block alone, so
-    # ``__post_init__`` refuses them without window layers, and window
+    # (`moe_ffn_held`). ``sliding_window``, ``rope_interleaved``, ``norm``,
+    # ``moe_scoring`` and ``n_shared_experts`` are read by that block alone,
+    # so ``__post_init__`` refuses them without window layers, and window
     # layers without experts. The expert layer's: the router's scoring
     # ("softmax" over the chosen logits | "sigmoid" of every logit, the
-    # chosen weights divided by their sum), an expert width apart from
-    # ``d_ff`` (0: ``d_ff``), shared experts whose MEAN is added once, and
-    # the chip's share: the router is ``n_experts`` wide, this program holds
-    # experts ``experts_held`` = (first, count) of them and computes their
-    # part of the result for the tokens routed to them, dropping nothing.
-    # (): all of them
+    # chosen weights divided by their sum) and shared experts whose MEAN is
+    # added once.
     moe_scoring: str = "softmax"
-    moe_d_ff: int = 0
     n_shared_experts: int = 0
+    # The no-drop expert layer (`moe_ffn_held`), read by the parallel block
+    # always and by the sequential block (`_ffn_half`) where ``experts_held``
+    # is set: the router is ``n_experts`` wide, this program holds experts
+    # ``experts_held`` = (first, count) of them and computes their part of
+    # the result for the tokens routed to them, dropping nothing, each an
+    # expert of width ``moe_d_ff`` (0: ``d_ff``). ``experts_held`` (): the
+    # parallel block holds all of them; the sequential block keeps `moe_ffn`
+    # and its capacity rule (``moe_capacity_factor``), which reads no
+    # ``moe_d_ff``: an expert width apart from ``d_ff`` is refused there
+    moe_d_ff: int = 0
     experts_held: tuple = ()
+    # RMSNorm of q and of k over each HEAD's ``head_dim`` (one weight vector
+    # shared by the heads), after the heads are split and before rotary;
+    # ``qk_norm`` is the norm over the whole width before the split
+    qk_norm_heads: bool = False
+    # A model that fills a BLOCK of tokens by denoising (docs/SERVING.md
+    # "A model that fills blocks"): attention is causal across blocks of
+    # ``block_length`` positions and two-way inside one, the logits at a
+    # position score the token AT it, and a row advances by a block:
+    # denoise passes fix the open positions whose confidence exceeds
+    # ``confidence_threshold``, and at least ``block_schedule[step]`` of the
+    # most confident, over ``denoise_steps`` steps; ``mask_token_id`` stands
+    # at an open position and is never an answer. 0: the model is
+    # autoregressive and none of the four is read
+    block_length: int = 0
+    denoise_steps: int = 0
+    confidence_threshold: float = 1.0
+    mask_token_id: Optional[int] = None
 
     @property
     def has_window(self) -> bool:
@@ -116,6 +138,25 @@ class ModelConfig:
         path, of its second page group and of the expert counts its decode
         chunks AND prefill segments return (MOE_HELD_COUNTS)."""
         return "sliding_attention" in self.layer_pattern
+
+    @property
+    def holds_experts(self) -> bool:
+        """The expert layer is `moe_ffn_held` (and the programs count
+        MOE_HELD_COUNTS): the parallel block's always, the sequential
+        block's where ``experts_held`` says so."""
+        return self.has_window or bool(self.experts_held)
+
+    @property
+    def fills_blocks(self) -> bool:
+        return self.block_length > 0
+
+    @property
+    def block_schedule(self) -> tuple:
+        """How many open positions each denoise step of a block fixes at
+        least: ``block_length // denoise_steps`` each, the remainder on the
+        first steps."""
+        b, t = self.block_length, self.denoise_steps
+        return tuple(b // t + (step < b % t) for step in range(t)) if t else ()
 
     @property
     def expert_d_ff(self) -> int:
@@ -177,9 +218,7 @@ class ModelConfig:
             "rope_interleaved": self.rope_interleaved,
             "norm": self.norm != "rms",
             "moe_scoring": self.moe_scoring != "softmax",
-            "moe_d_ff": self.moe_d_ff > 0,
             "n_shared_experts": self.n_shared_experts > 0,
-            "experts_held": bool(self.experts_held),
         }
         if not self.has_window and any(window_only.values()):
             raise ValueError(
@@ -193,6 +232,42 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name}: experts_held {self.experts_held} of {self.n_experts}"
                 )
+            if self.is_recurrent or self.output_norm:
+                raise ValueError(
+                    f"{self.name}: experts_held belongs to a pre-norm block "
+                    "(the sequential or the parallel one), not to a layer "
+                    "pattern with recurrent layers or an output norm"
+                )
+        if self.moe_d_ff > 0 and not self.holds_experts:
+            raise ValueError(
+                f"{self.name}: moe_d_ff is read by the no-drop expert layer "
+                "(window layers, or experts_held); moe_ffn's experts are d_ff wide"
+            )
+        if self.qk_norm and self.qk_norm_heads:
+            raise ValueError(f"{self.name}: qk_norm and qk_norm_heads are two norms, not one")
+        if self.fills_blocks:
+            contradicts = {
+                f"denoise_steps {self.denoise_steps} outside 1..{self.block_length}":
+                    not 1 <= self.denoise_steps <= self.block_length,
+                f"mask_token_id {self.mask_token_id} outside the vocabulary":
+                    self.mask_token_id is None
+                    or not 0 <= self.mask_token_id < self.vocab_size,
+                f"confidence_threshold {self.confidence_threshold} outside (0, 1]":
+                    not 0.0 < self.confidence_threshold <= 1.0,
+                "a layer pattern (a block-filling model has one kind of layer)":
+                    bool(self.layer_pattern),
+                "ring_axis": self.ring_axis is not None,
+            }
+            if any(contradicts.values()):
+                raise ValueError(
+                    f"{self.name}: block_length {self.block_length} with "
+                    + "; ".join(k for k, on in contradicts.items() if on)
+                )
+        elif self.denoise_steps or self.mask_token_id is not None:
+            raise ValueError(
+                f"{self.name}: denoise_steps and mask_token_id belong to a model "
+                "that fills blocks (block_length > 0)"
+            )
 
     @property
     def resolved_head_dim(self) -> int:
@@ -216,8 +291,9 @@ class ModelConfig:
                     self.held_experts[1] + self.n_shared_experts
                 ) + d * self.n_experts
             return mixers + self.n_layers * ffn + embed
-        if self.is_moe:
-            ffn = self.n_experts * 3 * d * self.d_ff + d * self.n_experts
+        if self.is_moe:  # what is HELD here, where the layer holds a share
+            held = self.held_experts[1] if self.experts_held else self.n_experts
+            ffn = held * 3 * d * self.expert_d_ff + d * self.n_experts
         else:
             ffn = 3 * d * self.d_ff
         embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
@@ -394,6 +470,32 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         moe_scoring="sigmoid",
         n_shared_experts=2,
         experts_held=(0, 4),
+    ),
+    "tiny-blockfill-moe-test": _preset(
+        # a model that fills blocks of 4 tokens by denoising, at test size
+        # (tests/test_block_diffusion.py, tests/test_sdar_moe.py): the
+        # sequential block under a block-causal mask, per-head q/k norm, 16
+        # softmax-routed experts top-4 of width 32 apart from d_ff, none
+        # dropped, an untied head
+        name="tiny-blockfill-moe-test",
+        vocab_size=512,
+        d_model=64,
+        n_layers=2,
+        n_heads=8,
+        n_kv_heads=2,
+        d_ff=128,
+        head_dim=16,
+        rope_theta=1000000.0,
+        max_seq_len=256,
+        n_experts=16,
+        n_experts_per_tok=4,
+        moe_d_ff=32,
+        experts_held=(0, 16),
+        qk_norm_heads=True,
+        block_length=4,
+        denoise_steps=4,
+        confidence_threshold=0.9,
+        mask_token_id=511,
     ),
     "olmo-hybrid-7b": _preset(
         # allenai/Olmo-Hybrid-7B config.json: (gated delta-rule x3, full

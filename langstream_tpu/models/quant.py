@@ -97,6 +97,8 @@ def init_random_quantized_params(config: ModelConfig, key: jax.Array) -> Params:
     d, h, hkv = config.d_model, config.n_heads, config.n_kv_heads
     hd = config.resolved_head_dim
     f, L, v = config.d_ff, config.n_layers, config.vocab_size
+    if config.is_moe:  # the no-drop layer's experts have a width of their own
+        f = config.expert_d_ff
     dtype = jnp.dtype(config.dtype)
     keys = iter(jax.random.split(key, 16))
 
@@ -142,11 +144,16 @@ def init_random_quantized_params(config: ModelConfig, key: jax.Array) -> Params:
         "wo": qw(L, h * hd, d),
         "ffn_norm": jnp.ones((L, d), dtype),
     }
+    if config.qk_norm_heads:
+        layers["q_norm"] = jnp.ones((L, hd), dtype)
+        layers["k_norm"] = jnp.ones((L, hd), dtype)
     if config.is_moe:
         e = config.n_experts
         layers["router"] = (
             jax.random.normal(next(keys), (L, d, e), jnp.float32) * d**-0.5
         ).astype(dtype)
+        if config.experts_held:  # the router is whole, the experts a share
+            e = config.held_experts[1]
         layers["w_gate"] = qw(L, e, d, f)
         layers["w_up"] = qw(L, e, d, f)
         layers["w_down"] = qw(L, e, f, d)

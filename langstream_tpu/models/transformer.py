@@ -48,6 +48,7 @@ SCOPES = (
     "linear_attention", "linear_attention.proj", "linear_attention.conv",
     "linear_attention.state", "linear_attention.out",
     "attention.window", "attention.full", "moe_ffn.shared",
+    "block_choice",
 )
 
 # What `moe_ffn_counted` counts, per call, as one int32 vector: expert
@@ -66,8 +67,9 @@ MOE_HELD_COUNTS = MOE_COUNTS + ("local", "touched")
 
 def moe_count_names(config: ModelConfig) -> tuple:
     """What the programs of ``config`` count: `_scan_window_periods` runs
-    `moe_ffn_held` in every layer, every other loop `moe_ffn_counted`."""
-    return MOE_HELD_COUNTS if config.has_window else MOE_COUNTS
+    `moe_ffn_held` in every layer, the sequential block's loops where the
+    model holds its experts so (``experts_held``), else `moe_ffn_counted`."""
+    return MOE_HELD_COUNTS if config.holds_experts else MOE_COUNTS
 
 
 def _no_moe_counts() -> jax.Array:
@@ -108,9 +110,14 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
         "wo": norm(keys[3], L, h * hd, d, scale=h * hd),
         "ffn_norm": jnp.ones((L, d), dtype),
     }
+    if config.qk_norm_heads:
+        layers["q_norm"] = jnp.ones((L, hd), dtype)
+        layers["k_norm"] = jnp.ones((L, hd), dtype)
     if config.is_moe:
         e = config.n_experts
         layers["router"] = norm(keys[4], L, d, e, scale=d)
+        if config.experts_held:  # the router is whole, the experts a share
+            e, f = config.held_experts[1], config.expert_d_ff
         layers["w_gate"] = norm(keys[5], L, e, d, f, scale=d)
         layers["w_up"] = norm(keys[6], L, e, d, f, scale=d)
         layers["w_down"] = norm(keys[7], L, e, f, d, scale=f)
@@ -767,6 +774,7 @@ def _attention_block(
     lora_scale: Optional[jax.Array] = None,  # [R] per-adapter scale
     adapter_rows: Optional[jax.Array] = None,  # [B] pool row per slot
     layer: Optional[jax.Array] = None,  # scalar: this block's layer of the pool
+    block: bool = False,  # a block pass (`paged_block_step_inplace`)
 ) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]]]:
     """The attention half of a block (norm, QKV, rotary, cache write, the
     kernel or jnp path, output projection, residual): the layer's input in,
@@ -777,7 +785,12 @@ def _attention_block(
     which is ``kv_pool.write`` and (a scope cannot be left from inside)
     outside ``attention``. With
     ``paged_table`` set, ``cache_kv`` is the WHOLE pool and comes back
-    whole: nothing of a layer's size is formed."""
+    whole: nothing of a layer's size is formed. ``block``: the S queries of
+    a row are one block of a model that fills blocks; all of them see keys
+    ``0 .. start + S - 1``, so where the decode kernel runs the read is that
+    kernel with S x group query rows a KV head and no mask among them
+    (``ragged_paged_block_attention``), and the write the decode write over
+    S rows of one aligned tile."""
     if paged_table is None:
         with jax.named_scope("attention"):
             return _dense_attention(
@@ -787,8 +800,10 @@ def _attention_block(
     assert cache_kv is not None and cache_positions is not None
     from langstream_tpu.ops.attention import (
         note_path,
+        block_write_ok,
         paged_kv_write,
         paged_pallas_ok,
+        ragged_paged_block_attention,
         ragged_paged_decode_attention,
         ragged_paged_decode_attention_int8,
     )
@@ -799,8 +814,23 @@ def _attention_block(
     pk, pv = cache_kv  # [L, P, Hkv, ps, D], read and written at `layer`
     num_pages = (pk["q"] if isinstance(pk, dict) else pk).shape[1]
     decode_kernels = s == 1 and paged_pallas_ok(config, page_size)
+    block_kernels = (
+        block and not isinstance(pk, dict) and paged_pallas_ok(config, page_size)
+    )
     with jax.named_scope("kv_pool.write"):
-        if decode_kernels and not isinstance(pk, dict):
+        if block_kernels and block_write_ok(s, page_size):
+            # the block starts on a multiple of S inside a page: its S rows
+            # lie in one aligned tile of the pool, one copy a live row
+            pages, offs = _page_index(
+                paged_table, cache_positions[:, :1], page_size, num_pages
+            )
+            pk, pv = paged_kv_write(
+                (k.reshape(k.shape[0], -1, k.shape[-1]),
+                 v.reshape(v.shape[0], -1, v.shape[-1])),
+                pk, pv, pages[:, 0], offs[:, 0], layer, config,
+                interpret=jax.default_backend() != "tpu",
+            )
+        elif decode_kernels and not isinstance(pk, dict):
             # a decode step into the bf16 pool: a copy per LIVE row. The
             # int8 pool (a token's scales are Hkv scattered words, no DMA
             # Mosaic takes) and the S > 1 writers keep the scatter
@@ -831,8 +861,20 @@ def _attention_block(
                 interpret=jax.default_backend() != "tpu",
             )
             attn = out[:, None, :]
+        elif block_kernels:
+            lengths = _paged_lengths(
+                paged_table, cache_positions[:, -1], page_size, num_pages
+            )
+            note_path("paged-block", "ragged_paged_block_attention", config, s=s, t=t)
+            attn = ragged_paged_block_attention(
+                q, pk, pv, lengths, paged_table, layer, config, page_size,
+                interpret=jax.default_backend() != "tpu",
+            )
         else:
-            kind = "decode" if s == 1 else "verify" if verify else "segment"
+            kind = (
+                "block" if block else "decode" if s == 1
+                else "verify" if verify else "segment"
+            )
             note_path(f"paged-{kind}", "jnp", config, s=s, t=t)
             k_all = _paged_gather(pk, layer, paged_table, page_size)
             v_all = _paged_gather(pv, layer, paged_table, page_size)
@@ -849,7 +891,8 @@ def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
     q [B, S, H, D], k and v [B, S, Hkv, D]. A block with its norm on the
     sublayer's output (``output_norm``) projects the bare input; with
     ``qk_norm`` q and k pass an RMSNorm over their whole width before the
-    heads are split; without ``rope`` nothing is turned."""
+    heads are split, with ``qk_norm_heads`` one over each head's
+    ``head_dim`` after it; without ``rope`` nothing is turned."""
     b, s, d = x.shape
     hd = config.resolved_head_dim
 
@@ -869,6 +912,9 @@ def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
     q = q.reshape(b, s, config.n_heads, hd)
     k = k.reshape(b, s, config.n_kv_heads, hd)
     v = v.reshape(b, s, config.n_kv_heads, hd)
+    if config.qk_norm_heads:
+        q = rms_norm(q, lp["q_norm"], config.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], config.rms_norm_eps)
     if config.rope:
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
@@ -960,6 +1006,8 @@ def _layer_counted(
     adapter_rows: Optional[jax.Array] = None,  # [B] pool row per slot
     token_valid: Optional[jax.Array] = None,  # [B, S] bool — real tokens
     layer: Optional[jax.Array] = None,  # scalar layer index (paged only)
+    block: bool = False,  # a block pass: S queries a row that see one another
+    moe_layer: Optional[jax.Array] = None,  # with held experts' stacks in ``lp``
 ) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]], jax.Array]:
     """One transformer block, and its MOE_COUNTS (zeros when dense; only
     ``token_valid`` feeds them). If cache_kv given, k/v are written at
@@ -979,24 +1027,36 @@ def _layer_counted(
     x, new_cache = _attention_block(
         x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal,
         collect_kv, verify, paged_table, page_size,
-        lora, lora_scale, adapter_rows, layer,
+        lora, lora_scale, adapter_rows, layer, block,
     )
     y, counts = _ffn_half(
-        x, lp, config, config.output_norm, token_valid, lora, lora_scale, adapter_rows
+        x, lp, config, config.output_norm, token_valid, lora, lora_scale,
+        adapter_rows, layer if moe_layer is None else moe_layer,
     )
     return y, new_cache, counts
 
 
 def _ffn_half(
     x, lp, config, output_norm=False, token_valid=None, lora=None, lora_scale=None,
-    adapter_rows=None,
+    adapter_rows=None, layer=None,
 ):
-    """The feed-forward half of a block and its MOE_COUNTS: x + f(norm(x)),
-    or, for a dense FFN, x + norm(f(x)) with ``output_norm``."""
+    """The feed-forward half of a block and its counts (`moe_count_names`):
+    x + f(norm(x)), or, for a dense FFN, x + norm(f(x)) with ``output_norm``.
+    An expert layer is `moe_ffn` and its capacity rule, or, where the model
+    holds its experts so (``experts_held``), `moe_ffn_held`, which drops
+    nothing: ``lp`` then carries the held experts' whole stacks and ``layer``
+    says which of them is this block's (`_split_held`)."""
     eps = config.rms_norm_eps
     if config.is_moe and output_norm:
         raise NotImplementedError(f"an expert FFN under an output norm ({config.name})")
-    if config.is_moe:
+    if config.is_moe and config.experts_held:
+        with jax.named_scope("moe_ffn"):
+            # the router reads the norm before it is rounded (`_parallel_layer`)
+            u32 = rms_norm(x.astype(jnp.float32), lp["ffn_norm"], eps)
+            ffn_out, counts = moe_ffn_held(
+                u32.astype(x.dtype), lp, config, token_valid, layer, route_on=u32
+            )
+    elif config.is_moe:
         with jax.named_scope("moe_ffn"):
             ffn_in = rms_norm(x, lp["ffn_norm"], eps)
             ffn_out, counts = moe_ffn_counted(ffn_in, lp, config, token_valid)
@@ -1588,6 +1648,21 @@ def _split_lora(lora: Optional[dict]):
     return (layers or None), lora.get("scale")
 
 
+def _split_held(layers: dict, config: ModelConfig):
+    """(the leaves a layer scan slices, the leaves it hands on whole). Where
+    the sequential block holds its experts (``experts_held``) their weights
+    go on as the stack [L, held, K, N], as `_scan_window_periods` hands them
+    on: the grouped product reads its blocks at (layer, expert) where they
+    lie, and a kernel's operand sliced by the scan would be copied first, a
+    layer's experts a layer. Every other model: all of them sliced, as ever."""
+    if not config.experts_held or config.has_window:
+        return layers, None
+    return (
+        {k: v for k, v in layers.items() if k not in _HELD_EXPERTS},
+        {k: layers[k] for k in _HELD_EXPERTS},
+    )
+
+
 def _scan_layers(
     params, x, sin, cos, mask, config, cache=None, cache_positions=None, causal=True,
     collect_kv=False, lora=None, adapter_rows=None, token_valid=None,
@@ -1598,35 +1673,41 @@ def _scan_layers(
     [L, B, Hkv, S, D] arrays — the makings of a serving cache. ``lora``
     (the stacked adapter pool) joins the scan xs so each layer body sees
     its own [R, din, r] slices."""
-    layers = params["layers"]
+    layers, held = _split_held(params["layers"], config)
     lora_layers, lora_scale = _split_lora(lora)
     # a dense model's zeros stay out of the scan: its programs are the
     # ones they were, and the counts a constant beside them
     moe = config.is_moe
+    # the layer's index rides the scan only where held experts need it
+    index = None if held is None else jnp.arange(config.n_layers)
+
+    def whole(lp):
+        return lp if held is None else {**lp, **held}
 
     if cache is None:
 
-        def body(carry, lp):
+        def body(carry, inputs):
+            lp, l = inputs
             y, kv, counts = _layer_counted(
-                carry, lp, sin, cos, mask, config, causal=causal,
-                collect_kv=collect_kv, token_valid=token_valid,
+                carry, whole(lp), sin, cos, mask, config, causal=causal,
+                collect_kv=collect_kv, token_valid=token_valid, moe_layer=l,
             )
             return y, (kv, counts if moe else None)
 
-        x, (kvs, counts) = lax.scan(body, x, layers)
+        x, (kvs, counts) = lax.scan(body, x, (layers, index))
         return x, kvs, counts.sum(0) if moe else _no_moe_counts()
 
     def body_cached(carry, inputs):
-        lp, (ck, cv), ll = inputs
+        lp, (ck, cv), ll, l = inputs
         y, new_kv, counts = _layer_counted(
-            carry, lp, sin, cos, mask, config, cache_kv=(ck, cv),
+            carry, whole(lp), sin, cos, mask, config, cache_kv=(ck, cv),
             cache_positions=cache_positions, lora=ll, lora_scale=lora_scale,
-            adapter_rows=adapter_rows, token_valid=token_valid,
+            adapter_rows=adapter_rows, token_valid=token_valid, moe_layer=l,
         )
         return y, (new_kv, counts if moe else None)
 
     x, (new_kv, counts) = lax.scan(
-        body_cached, x, (layers, (cache["k"], cache["v"]), lora_layers)
+        body_cached, x, (layers, (cache["k"], cache["v"]), lora_layers, index)
     )
     counts = counts.sum(0) if moe else _no_moe_counts()
     return x, {"k": new_kv[0], "v": new_kv[1]}, counts
@@ -1634,7 +1715,8 @@ def _scan_layers(
 
 def _scan_layers_inplace(
     params, x, sin, cos, mask, config, pool, cache_positions, paged_table,
-    page_size, verify=False, lora=None, adapter_rows=None,
+    page_size, verify=False, lora=None, adapter_rows=None, block=False,
+    token_valid=None,
 ):
     """Layer loop with the page pool carried through the scan and updated
     IN PLACE, instead of consumed as scan ``xs`` and stacked as fresh ``ys``.
@@ -1650,18 +1732,20 @@ def _scan_layers_inplace(
     the compiled HLO; slicing the entry out of the carry and writing it back
     was two real copies of it a layer on a v5e: PERF.md §6, PR 25).
 
-    Returns (x, pool, the layers' summed MOE_COUNTS)."""
-    layers = params["layers"]
+    Returns (x, pool, the layers' summed counts, `moe_count_names`)."""
+    layers, held = _split_held(params["layers"], config)
     lora_layers, lora_scale = _split_lora(lora)
 
     def body(carry, inputs):
         x, pool = carry
         lp, l, ll = inputs
         y, (nk, nv), counts = _layer_counted(
-            x, lp, sin, cos, mask, config, cache_kv=(pool["k"], pool["v"]),
+            x, lp if held is None else {**lp, **held}, sin, cos, mask, config,
+            cache_kv=(pool["k"], pool["v"]),
             cache_positions=cache_positions, verify=verify,
             paged_table=paged_table, page_size=page_size, lora=ll,
             lora_scale=lora_scale, adapter_rows=adapter_rows, layer=l,
+            block=block, token_valid=token_valid,
         )
         return (y, {"k": nk, "v": nv}), (counts if config.is_moe else None)
 
@@ -1678,9 +1762,20 @@ def _scan_layers_inplace(
 # ---------------------------------------------------------------------------
 
 
+def _visible(q_pos: jax.Array, kv_pos: jax.Array, config: ModelConfig) -> jax.Array:
+    """Whether the query at ``q_pos`` sees the key at ``kv_pos``: causal, or,
+    for a model that fills blocks, causal across blocks of ``block_length``
+    and two-way inside one."""
+    if config.fills_blocks:
+        return kv_pos // config.block_length <= q_pos // config.block_length
+    return kv_pos <= q_pos
+
+
 @functools.partial(jax.jit, static_argnames=("config",))
 def forward(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array:
-    """Full-sequence causal forward → logits [B, S, V] (training / scoring).
+    """Full-sequence causal forward → logits [B, S, V] (training / scoring);
+    causal across blocks and two-way inside one for a model that fills
+    blocks (`_visible`), whose logits at a position score the token AT it.
 
     With ``config.ring_axis`` set (under shard_map, parallel.sp), ``tokens``
     is the LOCAL sequence block; RoPE positions are globalised from the ring
@@ -1692,6 +1787,8 @@ def forward(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array
         positions = positions + lax.axis_index(config.ring_axis) * s
     sin, cos = _rope_freqs(positions, config)
     mask = jnp.tril(jnp.ones((s, s), jnp.bool_))[None, :, :]
+    if config.fills_blocks:
+        mask = _visible(jnp.arange(s)[:, None], jnp.arange(s)[None, :], config)[None]
     mask = jnp.broadcast_to(mask, (b, s, s))
     x = _embed(params, tokens, config)
     if config.has_window:
@@ -1799,7 +1896,7 @@ def prefill(
     # causal over the prompt, nothing beyond; cache cols ≥ S are masked out
     q_pos = positions  # [B, S]
     kv_pos = jnp.arange(t)[None, None, :]  # [1, 1, T]
-    mask = kv_pos <= q_pos[:, :, None]
+    mask = _visible(q_pos[:, :, None], kv_pos, config)
     mask = mask & (kv_pos < s)
     x = _embed(params, tokens, config)
     if config.has_window:
@@ -1957,6 +2054,53 @@ def paged_verify_step_inplace(
         verify=True, lora=lora, adapter_rows=adapter_rows,
     )
     logits = _unembed(params, x, config)
+    return (logits, pool, counts) if moe_counts else (logits, pool)
+
+
+def paged_block_step_inplace(
+    params: Params,
+    tokens: jax.Array,  # [B, S] each row's block, the mask id where it is open
+    starts: jax.Array,  # [B] the block's first position, a multiple of S
+    pool: KVCache,
+    table: jax.Array,
+    config: ModelConfig,
+    page_size: int,
+    moe_counts: bool = False,
+):
+    """One PASS of a model that fills blocks, through the page table: each
+    row's block of S = ``block_length`` tokens at ``starts .. starts + S - 1``
+    against the row's pages and itself, logits at all S ([B, S, V]: the
+    logits at a position score the token AT it). The S queries of a row see
+    one another and everything behind the block, so all of them see keys
+    ``0 .. starts + S - 1``. The block's K/V are written to the row's pages
+    in the pass that computes them, a denoise pass's too: positions advance
+    only at a commit (the pass over the clean block), whose write replaces
+    them before any later block can read them, and inside a pass the block
+    reads its own K/V as this pass wrote them, which is the arithmetic of a
+    forward over prefix and block (the argument `paged_verify_step_inplace`
+    makes for rejected drafts). So every pass is this one program, a commit
+    is a pass whose row has nothing open, and rows at different steps of
+    different blocks ride one dispatch. A row whose table maps nothing (idle,
+    padding, warm-up) writes nothing and reads nothing; its assignments count
+    as padding's."""
+    if not config.fills_blocks or tokens.shape[1] != config.block_length:
+        raise ValueError(f"{config.name}: a block pass takes block_length tokens a row")
+    b, s = tokens.shape
+    pos = starts[:, None] + jnp.arange(s)[None, :]
+    sin, cos = _rope_freqs(pos, config)
+    # the jnp path's mask: every key up to the block's end, for every query
+    mask = _paged_mask(table, page_size, jnp.broadcast_to(pos[:, -1:], (b, s)))
+    num_pages = (pool["k"]["q"] if isinstance(pool["k"], dict) else pool["k"]).shape[1]
+    live = _paged_lengths(table, pos[:, -1], page_size, num_pages) > pos[:, -1]
+    x = _embed(params, tokens, config)
+    x, pool, counts = _scan_layers_inplace(
+        params, x, sin, cos, mask, config, pool, pos, table, page_size, block=True,
+        token_valid=jnp.broadcast_to(live[:, None], (b, s)),
+    )
+    # the head over B x S rows of one matrix: the logits come out row-major,
+    # as `block_choice` reads them (over [B, S, d] the chip lays them out
+    # S-major and copies 156 MB of float32 four times a pass)
+    logits = _unembed(params, x.reshape(b * s, 1, -1), config).reshape(b, s, -1)
     return (logits, pool, counts) if moe_counts else (logits, pool)
 
 

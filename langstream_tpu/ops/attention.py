@@ -184,6 +184,7 @@ def _prefill_kernel(
     block_k: int,
     scale: float,
     softcap,
+    block_length: int = 0,
 ):
     i = pl.program_id(2)  # query block
     j = pl.program_id(3)  # key block
@@ -221,7 +222,13 @@ def _prefill_kernel(
             s = jnp.tanh(s / softcap) * softcap
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_q, block_k), 1)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_q, block_k), 2)
-        s = jnp.where(k_pos <= q_pos, s, _NEG)
+        if block_length:
+            # causal across blocks of ``block_length``, two-way inside one.
+            # Both tiles are whole blocks, so the key tiles a query tile
+            # visits are the causal ones (the `pl.when` above)
+            s = jnp.where(k_pos < (q_pos // block_length + 1) * block_length, s, _NEG)
+        else:
+            s = jnp.where(k_pos <= q_pos, s, _NEG)
 
         m_prev = m_scr[:, :, 0]  # [G, block_q]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -254,7 +261,8 @@ def flash_prefill_attention(
     block_k: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Causal GQA attention → [B, S, H*D]."""
+    """Causal GQA attention → [B, S, H*D]; for a model that fills blocks
+    (``config.block_length``) causal across blocks and two-way inside one."""
     b, s, h, d = q.shape
     hkv = k.shape[1]
     group = h // hkv
@@ -263,6 +271,10 @@ def flash_prefill_attention(
         _vmem_block_q(block_q, block_k, group, d, jnp.dtype(q.dtype).itemsize), s
     )
     assert s % block_q == 0 and s % block_k == 0, "caller gates divisibility"
+    extra = {}
+    if config.block_length:  # the causal program is the one it was
+        assert block_q % config.block_length == 0 and block_k % config.block_length == 0
+        extra["block_length"] = config.block_length
     # head-major queries: [B, Hkv, G, S, D] so the blocked dims are (S, D)
     qg = q.reshape(b, s, hkv, group, d).transpose(0, 2, 3, 1, 4)
 
@@ -272,6 +284,7 @@ def flash_prefill_attention(
         block_k=block_k,
         scale=1.0 / (d**0.5),
         softcap=config.attn_logit_softcap,
+        **extra,
     )
     out = pl.pallas_call(
         kernel,
@@ -747,6 +760,38 @@ def ragged_paged_decode_attention(
     )
 
 
+def ragged_paged_block_attention(
+    q: jax.Array,  # [B, S, H, D]: a block of S queries a row
+    k: jax.Array,  # the page pool [L, P, Hkv, ps, D], read at `layer`
+    v: jax.Array,
+    lengths: jax.Array,  # [B] the block's end: every query of the row sees [0, length)
+    table: jax.Array,  # [B, Tp]
+    layer: jax.Array,
+    config: ModelConfig,
+    page_size: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """A block pass's attention over one layer of the pool → [B, S, H*D]:
+    the S queries of a row see one another and everything behind them, so
+    all of them read the same keys and there is no mask among them. It is
+    the paged decode kernel with S x group query rows a KV head (32 for a
+    block of 4 under GQA 32/4, where a decode step has 8): one walk over the
+    row's pages serves the whole block. Under its own name on the
+    `pallas_call`; no mesh (`ServingEngine` refuses one for such a model)."""
+    b, s, h, d = q.shape
+    hkv = k.shape[2]
+    group = h // hkv
+    # [B, S, Hkv, G, D] -> [B, Hkv, S x G, D]: the kernel's query tile a head
+    tile = q.reshape(b, s, hkv, group, d).transpose(0, 2, 1, 3, 4)
+    out = _paged_decode_call(
+        "ragged_paged_block_attention", _page_bf16,
+        tile.reshape(b, hkv * s * group, d), [k, v], [], lengths, table, layer,
+        config, page_size, interpret,
+    )
+    out = out.reshape(b, hkv, s, group, d).transpose(0, 2, 1, 3, 4)
+    return out.reshape(b, s, h * d)
+
+
 @_per_kv_head(3, kv_head_axis=2)
 def ragged_paged_decode_attention_int8(
     q: jax.Array,  # [B, H, D]
@@ -784,9 +829,9 @@ _WRITE_BLOCK = 64
 
 def _paged_kv_write_kernel(
     pages_ref,  # scalar-prefetch [B]: each row's write page; >= num_pages drops
-    offs_ref,  # scalar-prefetch [B]: the row's offset inside that page
+    offs_ref,  # scalar-prefetch [B]: the row's (first) offset inside that page
     layer_ref,  # scalar-prefetch [1]
-    k_ref,  # [block, Hkv, D]: this grid step's new rows
+    k_ref,  # [block, span x Hkv, D]: this grid step's new rows, span a batch row
     v_ref,
     _k_in,  # the pool leaves [L*P, Hkv, ps, D] in HBM, aliased to the outputs
     _v_in,
@@ -800,7 +845,9 @@ def _paged_kv_write_kernel(
     *,
     num_pages: int,
 ):
-    block, hkv, d = k_ref.shape
+    block, rows, d = k_ref.shape
+    hkv = k_pool.shape[1]
+    span = rows // hkv  # consecutive offsets a batch row writes: 1 for a decode step
     first = pl.program_id(0) * block
     base = layer_ref[0] * num_pages
     leaves = ((k_pool, k_buf, k_ref), (v_pool, v_buf, v_ref))
@@ -836,16 +883,23 @@ def _paged_kv_write_kernel(
             ).start()
 
     def replace_row(i):
-        at = jax.lax.broadcasted_iota(jnp.int32, (_WRITE_ROWS, d), 0) == (
-            offs_ref[first + i] % _WRITE_ROWS
-        )
+        # (no `+ 0` for a decode step: its program is the one it was)
+        at = [
+            jax.lax.broadcasted_iota(jnp.int32, (_WRITE_ROWS, d), 0) == (
+                offs_ref[first + i] % _WRITE_ROWS + j if j else offs_ref[first + i] % _WRITE_ROWS
+            )
+            for j in range(span)
+        ]
         for leaf, (pool, buf, new) in enumerate(leaves):
             pltpu.make_async_copy(
                 tile(pool, i), buf.at[i], read_sems.at[leaf, i]
             ).wait()
             for h in range(hkv):
-                row = jnp.broadcast_to(new[i, pl.ds(h, 1), :], (_WRITE_ROWS, d))
-                buf[i, h] = jnp.where(at, row, buf[i, h])
+                for j in range(span):
+                    row = jnp.broadcast_to(
+                        new[i, pl.ds(j * hkv + h, 1), :], (_WRITE_ROWS, d)
+                    )
+                    buf[i, h] = jnp.where(at[j], row, buf[i, h])
             pltpu.make_async_copy(
                 buf.at[i], tile(pool, i), write_sems.at[leaf, i]
             ).start()
@@ -866,7 +920,8 @@ def _paged_kv_write_kernel(
 
 @_per_kv_head(3, kv_head_axis=2, returns_pool=True)
 def paged_kv_write(
-    new: tuple[jax.Array, jax.Array],  # a decode step's K and V rows [B, Hkv, D]
+    new: tuple[jax.Array, jax.Array],  # a decode step's K and V rows [B, Hkv, D],
+    # or a block pass's [B, S x Hkv, D] (position-major: `block_write_ok`)
     k: jax.Array,  # the page pool [L, P, Hkv, ps, D], written at `layer`
     v: jax.Array,
     pages: jax.Array,  # [B] each row's write page (models/transformer `_page_index`)
@@ -881,13 +936,15 @@ def paged_kv_write(
     back. A row whose page is the sentinel (>= P) DROPS, at no copy at all:
     the cost is per live row. The write is read-modify-write of the
     ``_WRITE_ROWS`` aligned rows that hold the offset; every other byte of
-    the pool is untouched."""
+    the pool is untouched. With S x Hkv rows a batch row (a block pass), row
+    ``b`` writes offsets ``offsets[b] .. offsets[b] + S - 1``, which lie in
+    one aligned tile (`block_write_ok`): still one copy in and one out."""
     del config  # `_per_kv_head`'s: the mesh to split the heads over
-    b, hkv, d = new[0].shape
-    num_pages = k.shape[1]
+    b, rows, d = new[0].shape
+    num_pages, hkv = k.shape[1], k.shape[2]
     block = _fit_block(_WRITE_BLOCK, b)
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
-    row_block = pl.BlockSpec((block, hkv, d), lambda i, *_: (i, 0, 0))
+    row_block = pl.BlockSpec((block, rows, d), lambda i, *_: (i, 0, 0))
     flat = [_flat_pool(leaf) for leaf in (k, v)]
     out = pl.pallas_call(
         functools.partial(_paged_kv_write_kernel, num_pages=num_pages),
@@ -915,7 +972,7 @@ def paged_kv_write(
     )(
         pages.astype(jnp.int32), offsets.astype(jnp.int32),
         jnp.asarray(layer, jnp.int32).reshape(1),
-        *(rows.astype(k.dtype) for rows in new), *flat,
+        *(leaf.astype(k.dtype) for leaf in new), *flat,
     )
     return out[0].reshape(k.shape), out[1].reshape(v.shape)
 
@@ -1048,6 +1105,13 @@ def paged_insert_pages(
         interpret=interpret,
     )(table.reshape(-1), *(loc.astype(k.dtype) for loc in new), *flat)
     return out[0].reshape(k.shape), out[1].reshape(v.shape)
+
+
+def block_write_ok(block_length: int, page_size: int) -> bool:
+    """Whether `paged_kv_write` can write a block of ``block_length`` rows
+    that starts on a multiple of it: the block lies in one aligned tile of
+    ``_WRITE_ROWS`` rows of one page."""
+    return _WRITE_ROWS % block_length == 0 and page_size % _WRITE_ROWS == 0
 
 
 def paged_tiles_ok(head_dim: int, page_size: int) -> bool:

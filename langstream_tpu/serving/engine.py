@@ -41,6 +41,7 @@ from langstream_tpu.models.transformer import (
     insert_copies_pages,
     join_rec,
     make_kv_cache,
+    paged_block_step_inplace,
     paged_decode_step_inplace,
     paged_insert_cache,
     paged_prefill_segment_inplace,
@@ -57,7 +58,7 @@ from langstream_tpu.serving.observability import (
     emit_request_spans,
     load_score,
 )
-from langstream_tpu.serving.sampling import sample, speculative_verify
+from langstream_tpu.serving.sampling import block_choice, sample, speculative_verify
 from langstream_tpu.serving.speculation import NGramIndex
 from langstream_tpu.serving.tenancy import (
     DEFAULT_TENANT,
@@ -247,6 +248,14 @@ class GenerationResult:
     ttft_s: float
     total_s: float
     error: Optional[BaseException] = None
+    # A model that fills blocks (docs/SERVING.md "A model that fills
+    # blocks"; None for every other): the denoise step of its block that
+    # fixed each of ``tokens``, and the rest of the last block, which the
+    # engine finished and did not deliver (``max_new_tokens`` reached, or a
+    # stop token, which stands first): (tokens, their steps). Tokens and
+    # labels are enough to rebuild every pass that made them.
+    fix_steps: Optional[list[int]] = None
+    block_rest: Optional[tuple[list[int], list[int]]] = None
 
 
 @dataclass
@@ -271,6 +280,18 @@ class _Slot:
     # decode steps dispatched for THIS request and not processed yet: the
     # device's position leads ``position`` by as many (kv_tokens_read)
     ahead: int = 0
+    # A model that fills blocks: ``position`` is the start of the block the
+    # row is at; ``block`` its tokens as the host has seen them fixed (None:
+    # open) and ``block_steps`` the denoise step that fixed each (-1: the
+    # prompt's tail, never open); ``fix_steps`` the steps of ``generated``;
+    # ``block_rest`` the (token, step) pairs of a clean block not delivered
+    # yet (`_deliver_token` moves one to ``fix_steps`` a token); and when the
+    # admission's prefill landed, where `engine.prefill` ends
+    block: list = field(default_factory=list)
+    block_steps: list = field(default_factory=list)
+    fix_steps: list = field(default_factory=list)
+    block_rest: list = field(default_factory=list)
+    prefill_landed_at: float = 0.0
 
     @property
     def active(self) -> bool:
@@ -425,6 +446,84 @@ def _paged_decode_chunk(
     # a dense model's zeros stay out of the step scan: one constant
     moe = moe.sum(0) if config.is_moe else jnp.zeros(len(MOE_COUNTS), jnp.int32)
     return chunk, tokens, positions, pool, key, dstate, moe
+
+
+# What a block pass reports a row, beside the block's S tokens: the denoise
+# step of the pass (BLOCK_COMMIT: the row had nothing open, the pass was its
+# block's commit) and how many open positions stood over the threshold. A
+# position the pass did not fix reads BLOCK_UNFIXED (-1 is `sample`'s NaN
+# sentinel, `block_choice`'s too).
+BLOCK_COMMIT = -1
+BLOCK_UNFIXED = -2
+# What `engine.block_chunk` counts beside ``passes`` and ``active_rows``, and
+# `stats()` totals as ``block-<name>`` (docs/SERVING.md §12). A row is LIVE
+# in a pass while its request holds the slot; the passes the device ran for
+# a row after its request's last block was clean (the chunk in flight cannot
+# know) are ``idle_row_passes``.
+BLOCK_COUNTERS = (
+    "passes", "row_passes", "idle_row_passes", "denoise_row_passes",
+    "commit_row_passes", "tokens_fixed", "tokens_delivered",
+    "fixed_over_threshold", "kv_tokens_read", "kv_rows_written",
+)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("passes", "config", "page_size"),
+    donate_argnames=("pool",),
+)
+def _paged_block_chunk(
+    params, block, starts, pool, table, key, temp, top_k, top_p, passes,
+    config, page_size,
+):
+    """``passes`` fused PASSES of a model that fills blocks, against the page
+    pool in ONE dispatch (lax.scan), pipelined from device outputs like the
+    decode chunk. A row's state is its block (``block["tokens"]`` [B, S],
+    the mask id where ``block["open"]`` [B, S]), the block's denoise step
+    (``block["step"]`` [B]) and its start (``starts`` [B]). A pass runs every
+    row's block through `paged_block_step_inplace`; a row with open positions
+    fixes some by confidence (`block_choice`), a row with none has just
+    committed its block (its K/V, written by this pass over the clean block,
+    are what later blocks read) and moves on: start + S, all S open, step 0.
+    One program and one shape whatever the rows are at. Returns the
+    per-pass report [passes, B, S + 2] (the tokens fixed, BLOCK_UNFIXED
+    elsewhere; the step or BLOCK_COMMIT; the count over the threshold), the
+    state, the pool, the key and the summed expert counts."""
+    s, mask_id = config.block_length, config.mask_token_id
+
+    def body(carry, _):
+        tokens, is_open, step, starts, pool, key = carry
+        logits, pool, moe = paged_block_step_inplace(
+            params, tokens, starts, pool, table, config, page_size, moe_counts=True,
+        )
+        with jax.named_scope("block_choice"):
+            key, sub = jax.random.split(key)
+            picked, fixed, over = block_choice(
+                logits, sub, temp, top_k, top_p, is_open, step, mask_id,
+                config.confidence_threshold, config.block_schedule,
+            )
+            commit = ~is_open.any(axis=-1)
+            report = jnp.concatenate([
+                jnp.where(fixed, picked, BLOCK_UNFIXED),
+                jnp.where(commit, BLOCK_COMMIT, step)[:, None], over[:, None],
+            ], axis=1)
+            tokens = jnp.where(fixed, picked, tokens)
+            tokens = jnp.where(commit[:, None], mask_id, tokens)
+            is_open = (is_open & ~fixed) | commit[:, None]
+            step = jnp.where(commit, 0, step + 1)
+            starts = jnp.where(commit, starts + s, starts)
+        return (tokens, is_open, step, starts, pool, key), (
+            report, moe if config.is_moe else None,
+        )
+
+    carry = (block["tokens"], block["open"], block["step"], starts, pool, key)
+    (tokens, is_open, step, starts, pool, key), (reports, moe) = lax.scan(
+        body, carry, None, length=passes
+    )
+    moe = moe.sum(0) if config.is_moe else jnp.zeros(len(MOE_COUNTS), jnp.int32)
+    return (
+        reports, {"tokens": tokens, "open": is_open, "step": step}, starts,
+        pool, key, moe,
+    )
 
 
 @functools.partial(
@@ -663,6 +762,52 @@ def _make_paged_admit_group(mesh=None):
         )
 
     return admit_group
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("config", "page_size"),
+    donate_argnames=(
+        "pool", "block", "positions_dev", "temp_dev", "top_k_dev", "top_p_dev",
+    ),
+)
+def _block_admit_group(
+    params, pool, block, positions_dev, temp_dev, top_k_dev, top_p_dev,
+    tokens, meta, first_block, slots, tables, config, page_size,
+):
+    """The admission of a model that fills blocks, in ONE dispatch: the
+    prefill of each prompt's WHOLE blocks (``meta[0]``: their length, a
+    multiple of the block length, possibly 0) under the block-causal mask
+    into a local cache and from there into the row's pages, and the row's
+    state: its first block (``first_block`` [P, S]: the prompt's tail, then
+    the mask id; ``meta[4]`` the tail's length, the rest open) at the whole
+    blocks' end, step 0. It yields NO token: the first tokens come from the
+    first block's passes (the unread logits, and the head with them, are not
+    computed). A bucket's padding starts on a block boundary, so no real
+    position sees it. Returns the whole lengths as the landed marker."""
+    lengths = meta[0].astype(jnp.int32)
+    n, width = tokens.shape
+    _, local_cache, moe = prefill(
+        params, tokens, lengths, make_kv_cache(config, n, width), config,
+        moe_counts=True,
+        real_lengths=jnp.where(slots < positions_dev.shape[0], lengths, 0),
+    )
+    pool = paged_insert_cache(pool, local_cache, tables, page_size, config)
+    tail = meta[4].astype(jnp.int32)
+    is_open = jnp.arange(config.block_length)[None, :] >= tail[:, None]
+    block = {
+        "tokens": block["tokens"].at[slots].set(first_block, mode="drop"),
+        "open": block["open"].at[slots].set(is_open, mode="drop"),
+        "step": block["step"].at[slots].set(0, mode="drop"),
+    }
+    return (
+        lengths, pool, block,
+        positions_dev.at[slots].set(lengths, mode="drop"),
+        temp_dev.at[slots].set(meta[1], mode="drop"),
+        top_k_dev.at[slots].set(meta[2].astype(jnp.int32), mode="drop"),
+        top_p_dev.at[slots].set(meta[3], mode="drop"),
+        moe,
+    )
 
 
 # whether a profile is being recorded (annotations are no-ops otherwise); a
@@ -1122,6 +1267,43 @@ class ServingEngine:
                     f"{config.name} has window layers: {', '.join(asked)} "
                     "cannot be used with two page groups"
                 )
+        if config.fills_blocks:
+            # Refused at build, by the option's name (docs/SERVING.md "A
+            # model that fills blocks"). A grammar advances left to right, a
+            # block's tokens are fixed out of order. A verify yields
+            # autoregressive tokens. The block pass reads and writes a bf16
+            # pool where it lies (an int8 page's scales are scattered words).
+            # A block may not straddle two pages (its K/V go down as one
+            # aligned tile), and only then is a page-aligned prefix whole
+            # blocks. Between passes a row's last block holds K/V of tokens
+            # not final yet, so the row's pages cannot be spilled, migrated
+            # or checkpointed; a prefix hit's warm suffix and a prompt beyond
+            # the largest bucket would go through the segment program, which
+            # has no block-causal mask. The block pass carries no adapter
+            # terms and its kernel call no mesh.
+            on = lambda v: v is True or str(v).lower() in ("on", "true", "1")  # noqa: E731
+            refused = {
+                "constrained_decoding": on(constrained_decoding),
+                "speculation": on(speculation) or str(speculation).lower() == "auto",
+                "kv_cache_dtype": config.kv_cache_dtype == "int8",
+                "page_size": int(page_size) % config.block_length != 0,
+                "prefix_cache": on(prefix_cache) or str(prefix_cache).lower() == "auto",
+                "host_kv_fraction": float(host_kv_fraction) > 0,
+                "migrate_staging": bool(migrate_staging),
+                "durable_dir": bool(durable_dir),
+                "adapters": bool(adapters),
+                "mesh": mesh is not None,
+                "spmd": spmd is not None,
+            }
+            asked = [name for name, is_on in refused.items() if is_on]
+            if asked:
+                raise ValueError(
+                    f"{config.name} fills blocks of {config.block_length} tokens by "
+                    f"denoising: {', '.join(asked)} cannot be used with a row "
+                    "that advances by a block"
+                    + (f" (page_size {page_size})" if "page_size" in asked else "")
+                )
+            constrained_decoding = "off"  # `auto`: on where it is supported
         if mesh is not None:
             # the Pallas kernels cannot be partitioned by GSPMD: they read
             # the mesh off the (static) config and shard_map themselves
@@ -1367,6 +1549,12 @@ class ServingEngine:
         # from chunk k's outputs without a host sync)
         self._tokens_dev = jnp.zeros(max_batch, jnp.int32)
         self._positions_dev = jnp.zeros(max_batch, jnp.int32)
+        # a model that fills blocks: each row's block on the device (tokens,
+        # which of them are open, the denoise step); ``_positions_dev`` is
+        # then the block's start. Totals of what the block chunks did
+        # (stats "block-*"; docs/SERVING.md §12)
+        self._block_dev = self._fresh_block_state(max_batch, config)
+        self._block_totals = dict.fromkeys(BLOCK_COUNTERS, 0)
         # slots freed since the last dispatch: their device temp must be
         # zeroed, else sample()'s batch-wide any_sample/any_filter predicates
         # keep paying the full-vocab sort for a slot that no longer exists
@@ -2026,6 +2214,15 @@ class ServingEngine:
                 f"prompt of {len(request.prompt_tokens)} tokens exceeds the "
                 f"engine limit of {limit} (max_seq_len - 1)"
             )
+        if self.config.fills_blocks and (
+            len(request.prompt_tokens) > self.prefill_buckets[-1]
+        ):
+            raise ValueError(
+                f"chunked prefill: a prompt of {len(request.prompt_tokens)} tokens "
+                f"is beyond the largest prefill bucket ({self.prefill_buckets[-1]}) "
+                f"and {self.config.name} fills blocks: the segment program has "
+                "no block-causal mask"
+            )
         opts = request.options
         cost_budget = getattr(opts, "max_cost_tokens", None)
         if cost_budget is not None:
@@ -2392,6 +2589,12 @@ class ServingEngine:
             # summed over the dispatches processed so far
             "moe-routed-assignments-total": self.moe_routed_total,
             "moe-dropped-assignments-total": self.moe_dropped_total,
+            # a model that fills blocks: what its block chunks did, summed
+            # over the chunks processed so far (BLOCK_COUNTERS)
+            **(
+                {f"block-{k.replace('_', '-')}": v for k, v in self._block_totals.items()}
+                if self.config.fills_blocks else {}
+            ),
             # a model with window layers: its second page group's use
             **(
                 {
@@ -2683,6 +2886,19 @@ class ServingEngine:
     def _record_program(self, *signature) -> None:
         self._programs.add(tuple(signature))
 
+    @staticmethod
+    def _fresh_block_state(rows: int, config: ModelConfig) -> Optional[dict]:
+        """The device state of a model that fills blocks: every row at a
+        block of mask ids, all open, step 0 (None for every other model)."""
+        if not config.fills_blocks:
+            return None
+        s = config.block_length
+        return {
+            "tokens": jnp.full((rows, s), config.mask_token_id, jnp.int32),
+            "open": jnp.ones((rows, s), jnp.bool_),
+            "step": jnp.zeros(rows, jnp.int32),
+        }
+
     # -- engine thread ------------------------------------------------------
 
     def _warmup_paged(self) -> None:
@@ -2716,6 +2932,8 @@ class ServingEngine:
             self.prefill_buckets if self._prefix_index is not None
             else self.prefill_buckets[-1:]
         )
+        if self.config.fills_blocks:
+            segment_widths = ()  # no prompt is chunked, no prefix reused
         for ws in segment_widths:
             if self._stop.is_set():
                 return
@@ -3150,6 +3368,7 @@ class ServingEngine:
             self._spill_worker.start()
         self._tokens_dev = jnp.zeros(self.max_batch, jnp.int32)
         self._positions_dev = jnp.zeros(self.max_batch, jnp.int32)
+        self._block_dev = self._fresh_block_state(self.max_batch, self.config)
         self._temp_dev = jnp.zeros(self.max_batch, jnp.float32)
         self._top_k_dev = jnp.zeros(self.max_batch, jnp.int32)
         self._top_p_dev = jnp.ones(self.max_batch, jnp.float32)
@@ -3691,6 +3910,17 @@ class ServingEngine:
             first = self._fetch_result(first_dev)
             self._land_dispatch(disp, first_dev)
             now = time.monotonic()
+            if self.config.fills_blocks:
+                # the prefill yields no token: `engine.prefill` ends here,
+                # the first block's passes belong to `engine.decode`
+                behind, device, ready = disp.stages if disp is not None else (0, 0, now)
+                for idx, request in group:
+                    slot = self._slots[idx]
+                    if slot.request is request:
+                        slot.prefill_landed_at = now
+                        if disp is not None and self._obs.on:
+                            slot.stages = (behind, device, now - ready)
+                return
             stages = None
             if disp is not None and self._obs.on:
                 # what the first token waited for past its admission: behind
@@ -4434,6 +4664,8 @@ class ServingEngine:
         row of its bucket. An expert model's ladder is the one rung
         ``prefill_batch`` (``__init__`` says why)."""
         assert len(group) <= self.prefill_batch
+        if self.config.fills_blocks:
+            return self._block_prefill_group(width, group)
         n_pad = next(r for r in self._admit_rungs if r >= len(group))
         tokens = np.zeros((n_pad, width), np.int32)
         lengths = np.ones(n_pad, np.int32)
@@ -4557,6 +4789,12 @@ class ServingEngine:
         every write drops."""
         pool = self._pagepool
         n = len(tokens)
+        if self.config.fills_blocks:  # the warm-up's rows: nothing real
+            return self._dev_block_prefill(
+                tokens, np.zeros(n, np.int32), temps, top_ks, top_ps, slots,
+                np.zeros(n, np.int32),
+                np.full((n, self.config.block_length), self.config.mask_token_id, np.int32),
+            )
         for s in slots:  # a window row maps the pages the group writes
             pool.window_advance(int(s), 0, tokens.shape[1] - 1)
         tables = pool.rows_tables(slots)
@@ -4713,7 +4951,9 @@ class ServingEngine:
         # worst-case page reservation too (§19)
         need = pool.pages_needed(
             len(prompt),
-            max(1, effective_max_new_tokens(request.options, len(prompt))),
+            max(1, effective_max_new_tokens(request.options, len(prompt)))
+            # the engine finishes the block it began
+            + max(0, self.config.block_length - 1),
         )
         if need > pool.num_pages:
             # only reachable with an explicit kv-pages override below the
@@ -5970,6 +6210,11 @@ class ServingEngine:
                 "KV-page migration carries pages only: a recurrent state "
                 "row has no wire format yet"
             )
+        if self.config.fills_blocks:
+            raise MigrationError(
+                "KV-page migration: a row that advances by a block holds, "
+                "between passes, K/V of tokens that are not final yet"
+            )
         reply: "queue.SimpleQueue" = queue.SimpleQueue()
         self._migrate_cmds.put((kind, payload, reply))
         self._wake.set()
@@ -6403,6 +6648,8 @@ class ServingEngine:
         # mask announced below must already reflect both
         self._page_integrity_check()
         self._adapter_integrity_check()
+        if self.config.fills_blocks:
+            return self._dispatch_block_chunk(clean, pipelined)
         # the page table is the bound on what a row reads: the decode
         # surface is ONE program per step count
         steps = self._chunk_steps()
@@ -6583,6 +6830,8 @@ class ServingEngine:
         pass the leader's wire-shipped mask."""
         if self._injector is not None:
             self._injector.fire("decode")  # crashes the loop → restart path
+        if self.config.fills_blocks:
+            return self._dev_block(steps, stale, mask)
         lora, arows, dfa, g = self._agentic_args()
         dstate = self._dfa_state_dev
         self._record_program("paged-decode", steps)
@@ -6619,6 +6868,236 @@ class ServingEngine:
         if dstate is not None:
             self._dfa_state_dev = dstate
         return chunk
+
+    # -- a model that fills blocks (docs/SERVING.md "A model that fills
+    # blocks"): the admission prefills whole blocks and yields no token, a
+    # chunk is a scan of PASSES, a row's tokens come a block at a time ------
+
+    def _block_prefill_group(
+        self, width: int, group: list[tuple[int, GenerationRequest]]
+    ) -> list[tuple]:
+        """`_prefill_group` for a model that fills blocks: each prompt's
+        whole blocks are prefilled; its tail (``n mod S`` tokens) rides as
+        the clean positions of the row's first block, whose other positions
+        are open. The group's fetch brings no token, only its landing."""
+        s_len, mask_id = self.config.block_length, self.config.mask_token_id
+        n_pad = next(r for r in self._admit_rungs if r >= len(group))
+        tokens = np.zeros((n_pad, width), np.int32)
+        whole = np.zeros(n_pad, np.int32)
+        tails = np.zeros(n_pad, np.int32)
+        first_block = np.full((n_pad, s_len), mask_id, np.int32)
+        temps = np.zeros(n_pad, np.float32)
+        top_ks = np.zeros(n_pad, np.int32)
+        top_ps = np.ones(n_pad, np.float32)
+        slots = np.full(n_pad, self.max_batch, np.int32)
+        started = time.monotonic()
+        for j, (idx, request) in enumerate(group):
+            prompt = request.prompt_tokens
+            whole[j] = len(prompt) // s_len * s_len
+            tails[j] = len(prompt) - whole[j]
+            tokens[j, : whole[j]] = prompt[: whole[j]]
+            first_block[j, : tails[j]] = prompt[whole[j]:]
+            temps[j] = request.options.temperature
+            top_ks[j] = request.options.top_k
+            top_ps[j] = request.options.top_p
+            slots[j] = idx
+        with self._stats_lock:
+            self._admit_group_rows[n_pad] += 1
+        disp = self._new_dispatch(
+            "engine.admit_group", program="_block_admit_group", rows=n_pad,
+            real_rows=len(group), width=width,
+            real_tokens=int(whole.sum()), computed_tokens=n_pad * width,
+            trace_ids=[r.trace_id for _, r in group],
+            kv_pages_written=self._kv_pages_written(slots, width),
+        )
+        seq = self._dispatch_seq
+        with jax.profiler.TraceAnnotation(
+            "engine.admit_group", seq=seq, t_mono_ns=_mono_ns(disp)
+        ):
+            if self._injector is not None:
+                self._injector.fire("prefill")  # before any state mutates
+            landed = self._dev_block_prefill(
+                tokens, whole, temps, top_ks, top_ps, slots, tails, first_block
+            )
+        counts = self._moe_counts()
+        for j, (idx, request) in enumerate(group):
+            slot = self._slots[idx]
+            slot.request = request
+            slot.position = int(whole[j])  # the start of the row's block
+            slot.generated = []
+            slot.block = [int(t) for t in first_block[j, : tails[j]]] + [None] * (
+                s_len - int(tails[j])
+            )
+            slot.block_steps = [-1] * int(tails[j]) + [None] * (s_len - int(tails[j]))
+            slot.fix_steps, slot.block_rest = [], []
+            slot.started_at = started
+            slot.first_token_at = slot.prefill_landed_at = 0.0
+            slot.reset_obs("cold", 1, seq)
+            with self._stats_lock:
+                self.total_requests += 1
+            self._note_tenant_admitted(request)
+        return [(
+            "prefill", self._submit_fetch(landed, seq, counts), list(group), disp,
+        )]
+
+    def _dev_block_prefill(
+        self, tokens, whole, temps, top_ks, top_ps, slots, tails, first_block
+    ):
+        """Device layer of a block model's batched prefill
+        (`_block_admit_group`); out-of-bounds slots (padding, the warm-up)
+        carry all-sentinel tables, so every write drops."""
+        pool = self._pagepool
+        self._record_program("block-prefill", tokens.shape[1], len(tokens))
+        meta = np.stack([whole, temps, top_ks, top_ps, tails]).astype(np.float32)
+        (
+            landed, pool.dev, self._block_dev, self._positions_dev,
+            self._temp_dev, self._top_k_dev, self._top_p_dev, self._moe_dev,
+        ) = _block_admit_group(
+            self.params, pool.dev, self._block_dev, self._positions_dev,
+            self._temp_dev, self._top_k_dev, self._top_p_dev,
+            jnp.asarray(tokens), jnp.asarray(meta), jnp.asarray(first_block),
+            jnp.asarray(slots), jnp.asarray(pool.rows_tables(slots)),
+            self.config, self.page_size,
+        )
+        return landed
+
+    def _dispatch_block_chunk(self, clean: bool, pipelined: bool) -> tuple:
+        """`_dispatch_chunk` for a model that fills blocks: ``decode_chunk``
+        passes over every live row in one dispatch. What the passes did
+        (which were commits, what they fixed and read) is known when the
+        chunk lands: `_process_block_chunk` adds it to the span."""
+        passes = self.decode_chunk
+        stale = self._collect_stale()
+        mask = self._active_mask()
+        live = [slot for slot in self._slots if slot.active]
+        disp = self._new_dispatch(
+            "engine.block_chunk", program="_paged_block_chunk",
+            passes=passes, active_rows=len(live), clean=clean, pipelined=pipelined,
+        )
+        with jax.profiler.TraceAnnotation(
+            "engine.block_chunk", seq=self._dispatch_seq, steps=passes,
+            t_mono_ns=_mono_ns(disp),
+        ):
+            chunk = self._dev_decode(passes, stale, mask=mask)
+        counts = self._moe_counts()
+        snapshot = [
+            (i, slot.request) for i, slot in enumerate(self._slots) if slot.active
+        ]
+        with self._stats_lock:
+            self._busy_steps += passes
+        return (
+            "chunk", self._submit_fetch(chunk, self._dispatch_seq, counts),
+            snapshot, passes, time.monotonic(), clean, pipelined, disp,
+        )
+
+    def _dev_block(self, passes: int, stale, mask: Optional[np.ndarray] = None):
+        """Device layer of one block chunk (`_paged_block_chunk`)."""
+        self._record_program("paged-block", passes)
+        if len(stale):
+            self._reset_stale_temps(stale)
+        pool = self._pagepool
+        (
+            reports, self._block_dev, self._positions_dev, pool.dev, self._key,
+            self._moe_dev,
+        ) = _paged_block_chunk(
+            self.params, self._block_dev, self._positions_dev, pool.dev,
+            jnp.asarray(self._dispatch_tables(mask)), self._key,
+            self._temp_dev, self._top_k_dev, self._top_p_dev,
+            passes, self.config, self.page_size,
+        )
+        return reports
+
+    def _process_block_chunk(
+        self, host, snapshot, passes: int, disp: Optional[Dispatch]
+    ) -> None:
+        """A landed block chunk: ``host`` [passes, B, S + 2] is each pass's
+        report a row (`_paged_block_chunk`). The host follows every row
+        through its passes: a denoise pass's fixed tokens and their step go
+        into the row's block, a block with nothing open is DELIVERED, in
+        order and whole (`_deliver_block`), and a commit moves the row on.
+        What the passes did is counted here, onto the chunk's span and into
+        `stats()`, a row's passes until its request left the slot."""
+        if self._injector is not None:
+            host, _ = self._injector.corrupt_tokens(host, snapshot)
+        s_len, pool = self.config.block_length, self._pagepool
+        counted = dict.fromkeys(BLOCK_COUNTERS, 0)
+        counted["passes"] = passes
+        with jax.profiler.TraceAnnotation("engine.process.deliver"):
+            for idx, request in snapshot:
+                slot = self._slots[idx]
+                if slot.request is not request:  # freed/reassigned meanwhile
+                    counted["idle_row_passes"] += passes
+                    continue
+                slot.decode_iters += 1
+                t_prev = slot.last_token_at
+                before = len(slot.generated)
+                mapped = int((pool.tables[idx] != pool.oob).sum()) * self.page_size
+                for p, row in enumerate(host[:, idx].tolist()):  # plain ints from here
+                    if slot.request is not request:  # finished mid-chunk
+                        counted["idle_row_passes"] += passes - p
+                        break
+                    counted["row_passes"] += 1
+                    # every query of the block reads [0, start + S); the pass
+                    # writes the block's S rows where its pages are mapped
+                    counted["kv_tokens_read"] += min(slot.position + s_len, mapped)
+                    if slot.position + s_len <= mapped:
+                        counted["kv_rows_written"] += s_len
+                    step = row[s_len]
+                    if step == BLOCK_COMMIT:
+                        counted["commit_row_passes"] += 1
+                        slot.position += s_len
+                        slot.block = [None] * s_len
+                        slot.block_steps = [None] * s_len
+                        continue
+                    counted["denoise_row_passes"] += 1
+                    counted["fixed_over_threshold"] += row[s_len + 1]
+                    for j in range(s_len):
+                        if row[j] != BLOCK_UNFIXED and slot.block[j] is None:
+                            slot.block[j], slot.block_steps[j] = row[j], step
+                            counted["tokens_fixed"] += 1
+                    if None not in slot.block and not slot.block_rest:
+                        self._deliver_block(idx, request)
+                # a request that finished in here took its tokens with it
+                done = request._result if slot.request is not request else None
+                delivered = len(done.tokens if done else slot.generated) - before
+                counted["tokens_delivered"] += delivered
+                self._record_intertoken(slot, request, t_prev, delivered)
+        with self._stats_lock:
+            for name, value in counted.items():
+                self._block_totals[name] += value
+        if disp is not None:
+            # onto the span `_land_dispatch` emitted: it holds this dict
+            disp.attrs.update(
+                {k: v for k, v in counted.items() if k != "passes"}
+            )
+
+    def _deliver_block(self, idx: int, request: GenerationRequest) -> None:
+        """The row's block is clean: deliver its generated tokens in order
+        (the prompt's tail is no answer), through `_deliver_token`, which
+        stops at ``max_new_tokens`` or a stop token and then finishes the
+        slot with the undelivered rest. A block past which the cache has no
+        room for another ends the request too."""
+        slot = self._slots[idx]
+        slot.block_rest = [
+            (t, st) for t, st in zip(slot.block, slot.block_steps) if st != -1
+        ]
+        if not slot.first_token_at and slot.block_rest:
+            now = time.monotonic()
+            slot.first_token_at = slot.last_token_at = now
+            if self._obs.on:
+                self._obs.record("engine_ttft_s", now - request.submitted_at)
+            self._tenants.note_ttft(
+                getattr(request.options, "tenant", None) or DEFAULT_TENANT,
+                now - request.submitted_at,
+            )
+        for token, _ in list(slot.block_rest):
+            if slot.request is not request:
+                return
+            self._deliver_token(idx, token)
+        if slot.request is request:
+            slot.block_rest = []
+            if slot.position + 2 * self.config.block_length > self.max_seq_len:
+                self._finish_slot(idx, "length")  # no room for another block
 
     def _dispatch_verify(self, clean: bool = True) -> tuple:
         """Dispatch one self-speculative verify iteration: collect up to k
@@ -6840,6 +7319,8 @@ class ServingEngine:
         self._land_dispatch(disp, chunk)
         # gauge BEFORE delivery: see _sample_step_time's rationale
         self._sample_step_time(snapshot, steps, t_dispatch, clean, pipelined)
+        if self.config.fills_blocks:
+            return self._process_block_chunk(host, snapshot, steps, disp)
         self._spmd_echo(wire.ECHO_DECODE, host)  # before host-side corruption
         if self._injector is not None:
             host, _ = self._injector.corrupt_tokens(host, snapshot)
@@ -6955,6 +7436,8 @@ class ServingEngine:
             finished_reason = "stop"
         else:
             slot.generated.append(token)
+            if slot.block_rest:  # a block's token: its denoise step goes with it
+                slot.fix_steps.append(slot.block_rest.pop(0)[1])
             index = self._spec_index.get(idx)
             if index is not None:
                 # the emitted token joins the slot's draft context — the
@@ -7035,10 +7518,17 @@ class ServingEngine:
             total_s=now - request.submitted_at,
             error=error,
         )
+        if self.config.fills_blocks:
+            result.fix_steps = list(slot.fix_steps)
+            result.block_rest = (
+                [t for t, _ in slot.block_rest], [st for _, st in slot.block_rest]
+            )
         stamps = {
             "submitted": request.submitted_at,
             "admitted": slot.started_at or None,
-            "first_token": slot.first_token_at or None,
+            # a block model's prefill yields no token: `engine.prefill` ends
+            # where the prefill landed
+            "first_token": (slot.prefill_landed_at or slot.first_token_at) or None,
             "finished": now,
         }
         attrs = {
@@ -7062,6 +7552,8 @@ class ServingEngine:
         slot.generated = []
         slot.position = 0
         slot.last_token_at = 0.0
+        slot.block, slot.block_steps, slot.fix_steps, slot.block_rest = [], [], [], []
+        slot.prefill_landed_at = 0.0
         self._spec_index.pop(idx, None)
         self._slot_clear_agentic(idx)
         self._freed_slots.append(idx)

@@ -260,3 +260,69 @@ def speculative_verify(
     accept = jnp.where(finite, accept, 0)
     out = jnp.where(finite[:, None], out, -1)
     return out.astype(jnp.int32), accept.astype(jnp.int32)
+
+
+def block_choice(
+    logits: jax.Array,  # [B, S, V] fp32: the logits AT each position of a block
+    key: jax.Array,
+    temperature: jax.Array,  # [B]
+    top_k: jax.Array,  # [B] int32, 0 = disabled
+    top_p: jax.Array,  # [B] fp32, 1.0 = disabled
+    is_open: jax.Array,  # [B, S] bool: the positions still to be fixed
+    step: jax.Array,  # [B] int32: the block's denoise step
+    mask_id: int,
+    threshold: float,
+    schedule: tuple,  # how many positions each denoise step fixes at least
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """What one denoise pass of a model that fills blocks fixes, on the
+    device: at every position the token (the argmax, or a draw at the row's
+    temperature / top-k / top-p, as `sample` takes them) and its CONFIDENCE,
+    the probability of that token under the softmax it was taken from; then,
+    among the row's open positions, every one whose confidence exceeds
+    ``threshold`` and at least ``schedule[step]`` of the most confident (all
+    that are open, where fewer are). The mask id is never an answer: its
+    logit is -inf before the softmax. Returns (tokens [B, S], fixed [B, S]
+    bool, how many open positions stood over the threshold [B]). A position
+    whose logits are not finite gets `sample`'s sentinel -1 and confidence 0:
+    it is fixed in its turn and the engine quarantines the row on sight."""
+    b, s, v = logits.shape
+    flat = logits.reshape(b * s, v)
+    finite = jnp.all(jnp.isfinite(flat), axis=-1)
+    # a select, which fuses into its readers (a column set is a copy of the whole)
+    flat = jnp.where(jnp.arange(v)[None, :] == mask_id, -jnp.inf, flat)
+    # a row's value at each of its S positions
+    temp, k_rows, p_rows = (jnp.repeat(a, s) for a in (temperature, top_k, top_p))
+
+    def confidence(scores, tokens):
+        picked = jnp.take_along_axis(scores, tokens[:, None], axis=-1)[:, 0]
+        return jnp.exp(picked - jax.nn.logsumexp(scores, axis=-1))
+
+    greedy = _greedy_argmax(flat)
+    any_sample = jnp.any(temperature > 0.0)
+    any_filter = jnp.any((temperature > 0.0) & ((top_k > 0) | (top_p < 1.0)))
+
+    def drawn(scores):
+        scaled = scores / jnp.maximum(temp, 1e-6)[:, None]
+        filtered = lax.cond(
+            any_filter, lambda x: _apply_filters(x, k_rows, p_rows), lambda x: x, scaled
+        )
+        tokens = jax.random.categorical(key, filtered, axis=-1)
+        tokens = jnp.where(temp <= 0.0, greedy, tokens)
+        # a greedy row's confidence is under the plain softmax, not the scaled
+        return tokens, jnp.where(
+            temp <= 0.0, confidence(scores, greedy), confidence(filtered, tokens)
+        )
+
+    tokens, conf = lax.cond(
+        any_sample, drawn, lambda scores: (greedy, confidence(scores, greedy)), flat
+    )
+    tokens = jnp.where(finite, tokens, -1).reshape(b, s).astype(jnp.int32)
+    conf = jnp.where(finite, conf, 0.0).reshape(b, s)
+
+    # the open positions ranked by confidence (the first of equals first)
+    order = jnp.argsort(jnp.where(is_open, -conf, jnp.inf), axis=-1, stable=True)
+    rank = jnp.argsort(order, axis=-1, stable=True)
+    over = is_open & (conf > threshold)
+    at_least = jnp.asarray(schedule, jnp.int32)[jnp.clip(step, 0, len(schedule) - 1)]
+    fixed = is_open & (over | (rank < at_least[:, None]))
+    return tokens, fixed, over.sum(axis=-1, dtype=jnp.int32)

@@ -6,7 +6,11 @@ the program's block cannot express refused, every family's two files) and of
 contract, the mapping, the refusals, the seeded tree, the update's cost, the
 state probe) and of `benchmark/tests/test_cohere2_moe_family.py` (Command A+:
 the contract, the mapping and the stated cut, the cell's sizing, the
-refusals, the seeded tree, the costs and the readers) and the cases of
+refusals, the seeded tree, the costs and the readers) and of
+`benchmark/tests/test_sdar_moe_family.py` (SDAR-MoE: the contract with the
+three exports of an engine that fills blocks, the catalog's keys and the
+stated cut, the cell's sizing, the refusals, the seeded tree, a trajectory
+from tokens and labels, the cost and the metric files) and the cases of
 `benchmark/tests/test_request_readers.py` (the clock between a profile and the
 spans, a first token's stages, the device's idle by what the engine held; one
 of them records a profile of a small engine) run here as they stand
@@ -40,4 +44,5 @@ def _cases(file: str) -> dict:
 globals().update(_cases("test_families"))
 globals().update(_cases("test_olmo_hybrid_family"))
 globals().update(_cases("test_cohere2_moe_family"))
+globals().update(_cases("test_sdar_moe_family"))
 globals().update(_cases("test_request_readers"))

@@ -711,7 +711,25 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
     assert kinds | {"attention", "moe_ffn", "moe_ffn.route", "moe_ffn.dispatch",
                     "moe_ffn.experts", "moe_ffn.combine", "kv_pool.write"} <= texts["window"]
     assert not kinds & (texts["dense"] | texts["moe"] | texts["hybrid"])
-    assert set(T.SCOPES) <= texts["dense"] | texts["moe"] | texts["hybrid"] | texts["window"]
+    # a model that fills blocks: the choice by confidence under its own scope
+    # beside the head's, its no-drop expert layer under the sequential
+    # block's `moe_ffn`
+    blocks = MODEL_PRESETS["tiny-blockfill-moe-test"]
+    block_state = {"tokens": jnp.zeros((b, 4), jnp.int32), "open": jnp.ones((b, 4), jnp.bool_),
+                   "step": zeros}
+
+    def block_chunk(params, pool):
+        return E._paged_block_chunk(
+            params, block_state, lengths, pool, table, key, ones, zeros, ones, 2, blocks, page,
+        )
+
+    texts["blocks"] = lowered_scopes(
+        block_chunk, T.init_params(blocks, jax.random.PRNGKey(4)), T.make_page_pool(blocks, 8, page)
+    )
+    assert {"block_choice", "head", "attention", "kv_pool.write", "moe_ffn", "moe_ffn.route",
+            "moe_ffn.dispatch", "moe_ffn.experts", "moe_ffn.combine"} <= texts["blocks"]
+    assert "block_choice" not in texts["dense"] | texts["moe"] | texts["hybrid"] | texts["window"]
+    assert set(T.SCOPES) <= set().union(*texts.values())
     assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
     assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",
             "moe_ffn.combine"} <= texts["moe"] and "ffn" not in texts["moe"]

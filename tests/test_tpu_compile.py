@@ -170,6 +170,41 @@ CMDA = dataclasses.replace(
 )
 
 
+# SDAR-30B-A3B-Chat as the benchmark cuts it (`tiny-blockfill-moe-test`'s block
+# at the published widths): 32 Q / 4 KV heads x 128, blocks of 4 tokens, 128
+# experts of 2048 x 768 in int8, 12 layers, the whole vocabulary; the cell:
+# 64 slots x 11 pages
+SDAR = dataclasses.replace(
+    MODEL_PRESETS["tiny-blockfill-moe-test"], name="sdar-widths", d_model=2048, d_ff=6144,
+    moe_d_ff=768, n_layers=12, n_heads=32, n_kv_heads=4, head_dim=128, n_experts=128,
+    n_experts_per_tok=8, experts_held=(0, 128), vocab_size=151936, mask_token_id=151669,
+    max_seq_len=32768,
+)
+
+
+def _paged_block(config, batch, table, pages, layers):
+    """A block pass's attention: `block_length` queries a row against the
+    row's pages, one walk for all of them."""
+    _, (_, k, v, lengths, tab, layer) = _paged(
+        config, False, batch=batch, table=table, pages=pages, layers=layers
+    )
+    q = SDS((batch, config.block_length, config.n_heads, config.resolved_head_dim), jnp.bfloat16)
+    return (
+        lambda q, k, v, lengths, tab, layer: A.ragged_paged_block_attention(
+            q, k, v, lengths, tab, layer, config, PAGE
+        ),
+        (q, k, v, lengths, tab, layer),
+    )
+
+
+def _block_kv_write(config, batch, pages, layers):
+    """The block pass's pool write: `block_length` x Hkv rows a batch row,
+    into one aligned tile of the row's page."""
+    fn, (rows, _, pool, _, at, _, layer) = _kv_write(config, batch, pages, layers)
+    rows = SDS((batch, config.block_length * config.n_kv_heads, rows.shape[-1]), jnp.bfloat16)
+    return fn, (rows, rows, pool, pool, at, at, layer)
+
+
 def _windowed_decode(config, batch, table, pages, layers):
     """The paged decode kernel over a window layer's page group: a lower
     bound a row beside its length."""
@@ -230,6 +265,15 @@ CASES = {
     "cmdaplus-grouped-matmul-16": _grouped(CMDA, 16, 6),
     "cmdaplus-grouped-matmul-2048": _grouped(CMDA, 2048, 6),
     "cmdaplus-down-grouped-matmul-2048": _grouped(CMDA, 2048, 2, down=True),
+    # the SDAR cell: the block pass's attention (32 query rows a KV head) and
+    # its write at 64 slots x 11 pages x 12 layers, the prefill kernel under
+    # the block mask at the cell's two kernel widths, and the grouped product
+    # of a pass (256 positions x top-8 over 128 experts: tiles of 32 rows)
+    "sdardrain64x11-paged-block": _paged_block(SDAR, 64, 11, 704, 12),
+    "sdardrain64x11-block-kv-write": _block_kv_write(SDAR, 64, 704, 12),
+    **{f"sdar-prefill-{s}": _prefill(SDAR, s) for s in (128, 256)},
+    "sdar-grouped-matmul-256": _grouped(SDAR, 256, 12),
+    "sdar-down-grouped-matmul-256": _grouped(SDAR, 256, 12, down=True),
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
     **{f"gemma-prefill-{s}": _prefill(GEMMA, s) for s in (512, 1024, 2048)},
@@ -279,6 +323,8 @@ def _kernel_of(case: str) -> str:
         "paged-decode": "ragged_paged_decode_attention",
         "paged-decode-int8": "ragged_paged_decode_attention_int8",
         "paged-kv-write": "paged_kv_write",
+        "paged-block": "ragged_paged_block_attention",
+        "block-kv-write": "paged_kv_write",
         "paged-insert-pages": "paged_insert_pages",
         "gated-delta-update": "gated_delta_update",
         "windowed-decode": "ragged_paged_decode_attention",
@@ -618,3 +664,54 @@ def test_one_row_admit_group_compiles_for_v5e_beside_the_cell_s_state(v5e, monke
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     )
     assert held <= V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("program", ["_paged_block_chunk", "_block_admit_group"])
+def test_block_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatch, program):
+    """The SDAR cell's two device programs whole, at its sizes (64 slots, 704
+    pages, 16 passes a chunk; a group of 8 x 256), int8 weights and the pool
+    donated: the kernels are in, nothing copies or scatters a leaf of the
+    pool or slices a layer's experts out of their stack, and the program fits
+    the chip beside its state."""
+    from langstream_tpu.models.quant import init_random_quantized_params
+    from langstream_tpu.models.transformer import make_page_pool
+    from langstream_tpu.serving import engine as E
+
+    slots, pages, table, passes = 64, 704, 11, 16
+    key = SDS((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: init_random_quantized_params(SDAR, k), key)
+    pool = jax.eval_shape(lambda: make_page_pool(SDAR, pages, PAGE))
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    b = SDAR.block_length
+    block = {"tokens": i32(slots, b), "open": SDS((slots, b), jnp.bool_), "step": i32(slots)}
+    if program == "_paged_block_chunk":
+        args = (params, block, i32(slots), pool, i32(slots, table), key,
+                f32(slots), i32(slots), f32(slots))
+        static, kernels = (passes, SDAR, PAGE), ("ragged_paged_block_attention", "paged_kv_write")
+        path = f"paged-block[s={b},t={table * PAGE}]"
+    else:
+        rows, width = 8, 256
+        args = (params, pool, block, i32(slots), f32(slots), i32(slots), f32(slots),
+                i32(rows, width), f32(5, rows), i32(rows, b), i32(rows), i32(rows, table))
+        static, kernels = (SDAR, PAGE), ("flash_prefill_attention", "paged_insert_pages")
+        path = f"prefill[s={width},t={width}]"
+    compiled = _compile_as_on_chip(
+        monkeypatch, getattr(E, program), _placed(args, SingleDeviceSharding(v5e[0])), static
+    )
+    text = compiled.as_text()
+    assert A.attention_paths()[path] == kernels[0]
+    for kernel in kernels + ("moe_grouped_matmul",):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    experts = [SDAR.n_experts, SDAR.d_model, SDAR.expert_d_ff]
+    for shape in (list(pool["k"].shape), experts, experts[:1] + experts[:0:-1]):
+        dims = re.escape("[" + ",".join(map(str, shape)) + "]")
+        assert not re.search(rf"= \w+{dims}\S* (copy|scatter|dynamic-slice)\(", text), shape
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pool, updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    assert held <= V5E_HBM_BYTES
+
