@@ -1360,6 +1360,7 @@ def moe_ffn_held(
     The shared experts' mean is added once. A padding token is routed but
     holds no row: it gets the shared part only."""
     from langstream_tpu.ops import grouped_matmul as gm
+    from langstream_tpu.ops.attention import note_grid
 
     b, s, d = x.shape
     t, k = b * s, config.n_experts_per_tok
@@ -1403,14 +1404,19 @@ def moe_ffn_held(
     kernel = gm.grouped_matmul_ok(tile, d, f, config.attention_impl) and (
         gm.grouped_matmul_ok(tile, f, d, config.attention_impl)
     )
-    product = functools.partial(
-        gm.grouped_matmul, layer=layer, tile_expert=tile_expert, used=used, tile=tile,
+    grouped = dict(
+        layer=layer, tile_expert=tile_expert, used=used, tile=tile,
         kernel=kernel, interpret=jax.default_backend() != "tpu",
     )
+    if kernel:  # which grid each product got, a fact of its shape
+        note_grid(*gm.grid_note(tile, d, f, gate_up=True))
+        note_grid(*gm.grid_note(tile, f, d))
     with jax.named_scope("moe_ffn.experts"):
-        gate = _activation(product(rows, w_gate), config.activation)
-        hidden = gate * product(rows, w_up)
-        out_rows = product(hidden, w_down)  # [tiles * tile, d]
+        hidden = gm.grouped_gate_up(
+            rows, w_gate, w_up, functools.partial(_activation, kind=config.activation),
+            **grouped,
+        )
+        out_rows = gm.grouped_matmul(hidden, w_down, **grouped)  # [tiles * tile, d]
     with jax.named_scope("moe_ffn.combine"):
         # an assignment without a row reads out of bounds: zero
         picked = out_rows.at[dest].get(mode="fill", fill_value=0).reshape(t, k, d)

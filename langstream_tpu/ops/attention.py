@@ -160,6 +160,13 @@ def note_path(kind: str, impl: str, config: ModelConfig, s: int, t: int) -> None
     _PATHS[f"{kind}[s={s},t={t}]"] = impl
 
 
+def note_grid(key: str, grid: str) -> None:
+    """Record (at trace time) the grid a kernel outside this file gave a
+    shape: ``moe-grouped[tile=32,k=2048,n=768]`` -> ``blocks 2048x768,
+    steps/tile 1, gate+up shared`` (ops/grouped_matmul.grid_note)."""
+    _PATHS[key] = grid
+
+
 def attention_paths() -> dict[str, str]:
     """Snapshot of the trace-time log: ``{"prefill[s=512,t=512]":
     "flash_prefill_attention", "paged-segment[s=64,t=2048]": "jnp", ...}``."""

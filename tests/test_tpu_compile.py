@@ -251,6 +251,22 @@ def _grouped(config, tokens, layers, down=False):
     )
 
 
+def _gate_up(config, tokens, layers):
+    """The gate's and the up's product and the activation over the same rows:
+    ONE call where a step holds an expert's whole matrix (`gate_up_shared`)."""
+    from langstream_tpu.ops import grouped_matmul as gm
+
+    fn, (x, w, *rest) = _grouped(config, tokens, layers)
+    tile = gm.row_tile(tokens, config.n_experts_per_tok, config.n_experts)
+    assert gm.gate_up_shared(tile, config.d_model, config.expert_d_ff)
+    return (
+        lambda x, w_gate, w_up, layer, tile_expert, used: gm.grouped_gate_up(
+            x, w_gate, w_up, jax.nn.silu, layer, tile_expert, used, tile, kernel=True
+        ),
+        (x, w, w, *rest),
+    )
+
+
 CASES = {
     # the command-a-plus cell: both page groups' decode (2 full layers x 3136
     # pages; 6 window layers x 1552 pages with a lower bound), a 2048-token
@@ -268,12 +284,17 @@ CASES = {
     # the SDAR cell: the block pass's attention (32 query rows a KV head) and
     # its write at 64 slots x 11 pages x 12 layers, the prefill kernel under
     # the block mask at the cell's two kernel widths, and the grouped product
-    # of a pass (256 positions x top-8 over 128 experts: tiles of 32 rows)
+    # of a pass (256 positions x top-8 over 128 experts: tiles of 32 rows, an
+    # expert's matrix ONE block, gate and up in one call) and of an admission
+    # group (8 rows x 256 tokens: tiles of 256, K = 768 whole)
     "sdardrain64x11-paged-block": _paged_block(SDAR, 64, 11, 704, 12),
     "sdardrain64x11-block-kv-write": _block_kv_write(SDAR, 64, 704, 12),
     **{f"sdar-prefill-{s}": _prefill(SDAR, s) for s in (128, 256)},
     "sdar-grouped-matmul-256": _grouped(SDAR, 256, 12),
     "sdar-down-grouped-matmul-256": _grouped(SDAR, 256, 12, down=True),
+    "sdar-gate-up-grouped-matmul-256": _gate_up(SDAR, 256, 12),
+    "sdar-grouped-matmul-2048": _grouped(SDAR, 2048, 12),
+    "sdar-down-grouped-matmul-2048": _grouped(SDAR, 2048, 12, down=True),
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
     **{f"gemma-prefill-{s}": _prefill(GEMMA, s) for s in (512, 1024, 2048)},
@@ -332,6 +353,7 @@ def _kernel_of(case: str) -> str:
         "window-segment": "flash_segment_attention",
         "grouped-matmul": "moe_grouped_matmul",
         "down-grouped-matmul": "moe_grouped_matmul",
+        "gate-up-grouped-matmul": "moe_grouped_matmul",
     }[kind]
 
 
