@@ -9,7 +9,7 @@ when configured with a `steps` list.
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 from langstream_tpu.agents.genai.completions import ChatCompletionsStep, TextCompletionsStep
 from langstream_tpu.agents.genai.embeddings import ComputeAIEmbeddingsStep
@@ -63,6 +63,9 @@ class GenAIToolKitAgent(AgentProcessor):
     async def close(self) -> None:
         for step in self.steps:
             await step.close()
+
+    def inflight_records(self) -> Optional[int]:
+        return max((n for step in self.steps if (n := step.inflight_records())), default=None)
 
     async def process(self, records: list[Record]) -> list[ProcessorResult]:
         # records fan out CONCURRENTLY (reference GenAIToolKitAgent processes

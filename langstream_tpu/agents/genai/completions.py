@@ -43,11 +43,16 @@ class _BaseCompletionsStep(Step):
         self.ai_service = config.get("ai-service")
         self._producer = None
         self._service = None
+        self._inflight_records: Optional[int] = None
+
+    def inflight_records(self) -> Optional[int]:
+        return self._inflight_records
 
     async def start(self, context: Any) -> None:
         registry = context.get_service_provider_registry()
         provider = registry.get_provider(self.ai_service)
         self._service = provider.get_completions_service(dict(self.config))
+        self._inflight_records = getattr(provider, "inflight_records", None)
         if self.stream_to_topic:
             self._producer = context.get_topic_producer(self.stream_to_topic)
             await self._producer.start()

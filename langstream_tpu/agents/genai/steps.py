@@ -44,6 +44,10 @@ class Step(abc.ABC):
     async def close(self) -> None:  # noqa: B027
         pass
 
+    def inflight_records(self) -> Optional[int]:
+        """`AgentProcessor.inflight_records`, for a step that calls a service."""
+        return None
+
 
 def _cast_scalar(val: Any, type_: str) -> Any:
     if val is None:

@@ -98,6 +98,13 @@ Resource configuration:
     (default 65536 — per-row capacity for non-default transitions) size
     the device pool; the memory plan logs the cost (≈0.3GiB at a 256k
     vocab with 64 slots — docs §15 has the sizing table)
+  inflight-records: how many records an agent that calls this service keeps
+    in flight (runtime/runner.py). Unset, the runner bounds BATCHES (four
+    queued), and records that arrive one by one are batches of one: six or
+    seven requests reach the engine whatever `max-batch` is. Set it where
+    requests are long and arrive as a trickle or a fresh backlog (a
+    pipeline of whole documents): at least `max-batch` plus what should
+    wait in the engine's queue, and `queue-depth` no smaller
   queue-depth / shed-policy: bounded admission queue; "block" (default)
     backpressures the broker poll loop, "reject" sheds with a retry-after
     (ShedError) so front doors degrade to fast 429s under overload
@@ -1348,6 +1355,14 @@ class TpuServingProvider(ServiceProvider):
     def engine(self):
         """The provider's one ServingEngine, built and warmed on first use."""
         return self.holder.engine()
+
+    @property
+    def inflight_records(self) -> Optional[int]:
+        """``inflight-records``: how many records an agent that calls this
+        service keeps in flight (`runtime/runner.py`); unset: the runner's
+        bound in batches."""
+        n = self.holder.config.get("inflight-records")
+        return int(n) if n else None
 
     async def close(self) -> None:
         # holder.close() drains synchronously for up to drain-grace-s —

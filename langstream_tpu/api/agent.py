@@ -174,6 +174,12 @@ class AgentProcessor(AgentCode):
     async def process(self, records: list[Record]) -> list[ProcessorResult]:
         """One ProcessorResult per input record, order-preserving."""
 
+    def inflight_records(self) -> Optional[int]:
+        """How many records the runner should keep in flight for this step's
+        sake, where the step knows (a completions step: its service's
+        ``inflight-records``); None: the runner's bound in batches."""
+        return None
+
 
 class SingleRecordProcessor(AgentProcessor):
     """Convenience base: per-record transform (reference SingleRecordAgentProcessor)."""

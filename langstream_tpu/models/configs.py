@@ -12,6 +12,12 @@ from dataclasses import dataclass, field
 from typing import Any, Optional
 
 
+# every leaf a page pool may hold a token's state in, by page: K, V and an
+# indexer's key (`ModelConfig.page_leaves` says which a model has; the engine's
+# page copies, zeroes and snapshots go over exactly these)
+PAGE_LEAVES = ("k", "v", "ik")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -130,6 +136,42 @@ class ModelConfig:
     denoise_steps: int = 0
     confidence_threshold: float = 1.0
     mask_token_id: Optional[int] = None
+    # A model whose attention reads a LEARNED SELECTION (docs/SERVING.md "A
+    # model whose attention reads a learned selection"): an indexer of
+    # ``index_n_heads`` heads of ``index_head_dim`` and ONE key head scores
+    # every visible token for a query, `sum_j w_j relu(qI_j . kI)` in
+    # float32, and the query attends to the ``index_topk`` tokens of largest
+    # score (a tie to the lower position), one selection a token and layer
+    # for all heads. The indexer's key is a third leaf a token of the cache
+    # and the page pool, ``"ik"``. ``index_topk`` 0: none, and neither other
+    # field is read
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # Rotary in three position streams (m-rope): of the head's
+    # ``head_dim / 2`` frequencies the first ``mrope_section[0]`` turn by a
+    # token's temporal position, the next by its height, the rest by its
+    # width. (): one stream. Text has the three equal, which is the plain
+    # rotary: a [B, S] position is that
+    mrope_section: tuple = ()
+
+    @property
+    def has_indexer(self) -> bool:
+        return self.index_topk > 0
+
+    @property
+    def index_key_width(self) -> int:
+        """The width the indexer's key is KEPT at in the cache and the page
+        pool: ``index_head_dim`` rounded up to whole 128-lane rows, the tail
+        zeros. At a width under a lane row (64) the chip's compiler lays the
+        leaf out pages-minor for the gather by page and relays the WHOLE leaf
+        every layer and step (PERF.md section 6, PR 43)."""
+        return -(-self.index_head_dim // 128) * 128
+
+    @property
+    def page_leaves(self) -> tuple:
+        """The leaves a token has in the page pool's full-attention group."""
+        return PAGE_LEAVES if self.has_indexer else PAGE_LEAVES[:2]
 
     @property
     def has_window(self) -> bool:
@@ -267,6 +309,37 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: denoise_steps and mask_token_id belong to a model "
                 "that fills blocks (block_length > 0)"
+            )
+
+        if self.has_indexer:
+            contradicts = {
+                "a layer pattern, a window or a recurrent layer": bool(self.layer_pattern),
+                "fills_blocks (block_length > 0)": self.fills_blocks,
+                "an output norm": self.output_norm,
+                "an int8 KV cache": self.kv_cache_dtype == "int8",
+                "ring_axis": self.ring_axis is not None,
+                f"index_n_heads {self.index_n_heads} or index_head_dim "
+                f"{self.index_head_dim} under 1, or an odd index_head_dim":
+                    self.index_n_heads < 1 or self.index_head_dim < 2
+                    or self.index_head_dim % 2 == 1,
+            }
+            if any(contradicts.values()):
+                raise ValueError(
+                    f"{self.name}: an indexer (index_topk {self.index_topk}) with "
+                    + "; ".join(k for k, on in contradicts.items() if on)
+                )
+        elif self.index_n_heads or self.index_head_dim:
+            raise ValueError(
+                f"{self.name}: index_n_heads and index_head_dim belong to a model "
+                "with an indexer (index_topk > 0)"
+            )
+        if self.mrope_section and (
+            len(self.mrope_section) != 3
+            or sum(self.mrope_section) != self.resolved_head_dim // 2
+        ):
+            raise ValueError(
+                f"{self.name}: mrope_section {self.mrope_section} is three sections "
+                f"that sum to half a head ({self.resolved_head_dim // 2})"
             )
 
     @property
@@ -496,6 +569,33 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         denoise_steps=4,
         confidence_threshold=0.9,
         mask_token_id=511,
+    ),
+    "tiny-sparse-moe-test": _preset(
+        # a model whose attention reads a learned selection, at test size
+        # (tests/test_sparse_attention.py): the sequential block, per-head
+        # q/k norm, m-rope, an indexer of 2 heads of 16 that keeps 8 tokens
+        # (a 40-token sequence selects), 16 softmax-routed experts top-4 of
+        # width 32, none dropped, an untied head
+        name="tiny-sparse-moe-test",
+        vocab_size=512,
+        d_model=64,
+        n_layers=4,
+        n_heads=8,
+        n_kv_heads=2,
+        d_ff=128,
+        head_dim=16,
+        rope_theta=10000000.0,
+        rms_norm_eps=1e-6,
+        max_seq_len=256,
+        n_experts=16,
+        n_experts_per_tok=4,
+        moe_d_ff=32,
+        experts_held=(0, 16),
+        qk_norm_heads=True,
+        index_n_heads=2,
+        index_head_dim=16,
+        index_topk=8,
+        mrope_section=(2, 3, 3),
     ),
     "olmo-hybrid-7b": _preset(
         # allenai/Olmo-Hybrid-7B config.json: (gated delta-rule x3, full

@@ -26,6 +26,7 @@ Params = dict
 # "wqkv", "wg": a linear-attention layer's q, k, v side by side and its output gate
 _QUANT_LAYER_KEYS = (
     "wq", "wk", "wv", "wqkv", "wg", "wo", "w_gate", "w_up", "w_down",
+    "wq_idx", "wk_idx",
     "ws_gate", "ws_up", "ws_down",
 )
 
@@ -147,6 +148,13 @@ def init_random_quantized_params(config: ModelConfig, key: jax.Array) -> Params:
     if config.qk_norm_heads:
         layers["q_norm"] = jnp.ones((L, hd), dtype)
         layers["k_norm"] = jnp.ones((L, hd), dtype)
+    if config.has_indexer:
+        hi, di = config.index_n_heads, config.index_head_dim
+        layers["wq_idx"] = qw(L, d, hi * di)
+        layers["wk_idx"] = qw(L, d, di)
+        layers["w_idx"] = jax.random.normal(next(keys), (L, d, hi), jnp.float32) * d**-0.5
+        layers["idx_norm"] = jnp.ones((L, di), dtype)
+        layers["idx_bias"] = jnp.zeros((L, di), dtype)
     if config.is_moe:
         e = config.n_experts
         layers["router"] = (

@@ -49,6 +49,7 @@ SCOPES = (
     "linear_attention.state", "linear_attention.out",
     "attention.window", "attention.full", "moe_ffn.shared",
     "block_choice",
+    "attention.index", "attention.index.scores", "attention.select", "attention.sparse",
 )
 
 # What `moe_ffn_counted` counts, per call, as one int32 vector: expert
@@ -113,6 +114,18 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
     if config.qk_norm_heads:
         layers["q_norm"] = jnp.ones((L, hd), dtype)
         layers["k_norm"] = jnp.ones((L, hd), dtype)
+    if config.has_indexer:
+        # the indexer: its queries' and its key's projection like every
+        # projection, the per-head weights float32 like a router, the
+        # LayerNorm of its key (the one bias of the model)
+        hi, di = config.index_n_heads, config.index_head_dim
+        layers["wq_idx"] = norm(keys[10], L, d, hi * di, scale=d)
+        layers["wk_idx"] = norm(keys[11], L, d, di, scale=d)
+        layers["w_idx"] = (
+            jax.random.normal(jax.random.fold_in(key, 12), (L, d, hi), jnp.float32) * d**-0.5
+        )
+        layers["idx_norm"] = jnp.ones((L, di), dtype)
+        layers["idx_bias"] = jnp.zeros((L, di), dtype)
     if config.is_moe:
         e = config.n_experts
         layers["router"] = norm(keys[4], L, d, e, scale=d)
@@ -282,13 +295,26 @@ def _norm(x: jax.Array, weight: jax.Array, config: ModelConfig) -> jax.Array:
 def _rope_freqs(
     positions: jax.Array, config: ModelConfig
 ) -> tuple[jax.Array, jax.Array]:
-    # positions: [B, S] → sin/cos [B, S, head_dim/2], fp32
+    # positions: [B, S] → sin/cos [B, S, head_dim/2], fp32. [3, B, S]: a
+    # position triple a token (m-rope): frequency i turns by the stream its
+    # section of ``config.mrope_section`` names; equal triples are [B, S]
     half = config.resolved_head_dim // 2
     freqs = config.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     if config.rope_scaling_factor:
         freqs = _llama3_rope_scale(freqs, config)
+    if positions.ndim == 3:
+        stream = jnp.repeat(jnp.arange(3), jnp.asarray(config.mrope_section), total_repeat_length=half)
+        by_stream = positions.astype(jnp.float32)[..., None] * freqs  # [3, B, S, half]
+        angles = jnp.take_along_axis(by_stream, stream[None, None, None, :], axis=0)[0]
+        return jnp.sin(angles), jnp.cos(angles)
     angles = positions.astype(jnp.float32)[..., None] * freqs  # [B, S, half]
     return jnp.sin(angles), jnp.cos(angles)
+
+
+def _text_positions(positions: jax.Array) -> jax.Array:
+    """[B, S] of a [3, B, S] triple: the temporal stream, which is a text
+    token's position (the indexer's rotary turns by it)."""
+    return positions[0] if positions.ndim == 3 else positions
 
 
 def _llama3_rope_scale(freqs: jax.Array, config: ModelConfig) -> jax.Array:
@@ -435,7 +461,10 @@ def make_page_pool(
     state_rows: int = 0, window_pages: int = 0,
 ) -> KVCache:
     """Device page pool: ``{"k","v"}`` with leaves [L, P, Hkv, ps, D] (or the
-    int8 ``{"q","s"}`` dicts with scales [L, P, Hkv, ps]) — structurally a
+    int8 ``{"q","s"}`` dicts with scales [L, P, Hkv, ps]); for a model with an
+    indexer a third leaf a token, ``"ik"`` [L, P, ps, index_key_width], the
+    indexer's key, addressed by the same table and page index as K and V and
+    written where they are (``config.page_leaves``) — structurally a
     make_kv_cache with B = pages and T = page_size, so every tree-shaped
     helper (sharding specs, byte accounting, donation) applies unchanged.
     L counts the full-attention layers. A model with recurrent layers keeps
@@ -756,6 +785,193 @@ def moe_ffn_counted(
 # ---------------------------------------------------------------------------
 
 
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention (docs/SERVING.md "A model whose attention reads a
+# learned selection"). An INDEXER beside the attention's projections scores
+# every visible token for a query, ``I[t, s] = sum_j w[t, j] relu(qI[t, j] .
+# kI[s])`` in float32, and the query attends to the ``index_topk`` tokens of
+# largest score (a tie to the lower position, `lax.top_k`'s rule), one
+# selection a token and layer for all heads. The indexer's key ``kI`` is a
+# third leaf a token of the cache and of the page pool, ``"ik"``. Three
+# scopes inside ``attention``: ``attention.index`` (projections, norm,
+# rotary, scores; the scores alone ``attention.index.scores`` inside it),
+# ``attention.select`` (the ranking), ``attention.sparse`` (the selected read
+# and the attention over it).
+# ---------------------------------------------------------------------------
+
+
+def _index_proj(u, lp, positions, config):
+    """The indexer's three projections of the attention's normed input ``u``
+    [B, S, d] at text positions [B, S]: queries [B, S, Hi, Di] and the key
+    [B, S, Di], both turned by the rotary rule over the indexer's whole head
+    (pairs (i, i + Di/2), frequencies theta^(-2i/Di)), the key through its
+    LayerNorm first; the heads' weights [B, S, Hi] in float32, scaled by
+    1 / sqrt(Hi Di)."""
+    b, s, _ = u.shape
+    hi, di = config.index_n_heads, config.index_head_dim
+    freqs = config.rope_theta ** (-jnp.arange(0, di // 2, dtype=jnp.float32) / (di // 2))
+    angles = positions.astype(jnp.float32)[..., None] * freqs
+    sin, cos = jnp.sin(angles), jnp.cos(angles)
+    q = quantized_matmul(u, lp["wq_idx"]).reshape(b, s, hi, di)
+    k = layer_norm(quantized_matmul(u, lp["wk_idx"]), lp["idx_norm"], config.rms_norm_eps)
+    k = k + lp["idx_bias"].astype(k.dtype)
+    w = jnp.dot(
+        u.astype(jnp.float32), lp["w_idx"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    ) * (hi * di) ** -0.5
+    return apply_rope(q, sin, cos), apply_rope(k[:, :, None, :], sin, cos)[:, :, 0], w
+
+
+def _selection_kernels(config, s: int, t: int) -> bool:
+    """Whether S > 1 queries over T columns take the selection's kernels
+    (`index_scores`, `sparse_segment_attention`): the prefill kernel's gate
+    and whole lane tiles of columns."""
+    from langstream_tpu.ops.attention import pallas_ok
+
+    return s > 1 and pallas_ok(config, s) and t % min(128, t) == 0
+
+
+def _index_scores(q_idx, w, k_idx, offsets, config):
+    """[B, S, T] float32: every query's score of every column. In tiles on
+    the chip (`ops/attention.index_scores`: nothing of [S, Hi, T] is formed),
+    one einsum where the kernel's tiles do not fit (the tests' sizes). A
+    score of -0.0 (every head's ReLU shut) reads +0.0: one order for floats
+    and for their bits."""
+    from langstream_tpu.ops import attention as ops
+
+    if _selection_kernels(config, q_idx.shape[1], k_idx.shape[1]):
+        return ops.index_scores(
+            q_idx, w, k_idx, offsets, interpret=jax.default_backend() != "tpu"
+        )
+    dots = jnp.einsum("bshd,btd->bsht", q_idx, k_idx, preferred_element_type=jnp.float32)
+    return jnp.einsum("bsht,bsh->bst", jax.nn.relu(dots), w) + 0.0
+
+
+def _select_mask(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
+    """[.., T] bool: of each row's ``visible`` columns the ``min(k, their
+    number)`` of largest ``scores`` (float32), a tie to the lower column:
+    `lax.top_k`'s set, found by counting. The k-th largest is bisected over
+    the float's 32 bits (a float's order is its bits' once the sign is
+    folded), 32 counts of the row against a sort's log^2; the tie rule costs
+    a running count along the row only where a row holds more columns AT the
+    threshold than it may keep."""
+    bits = lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32)
+    key = lax.bitcast_convert_type(bits ^ ((bits >> 31) & 0x7FFFFFFF), jnp.uint32)
+    key = jnp.where(visible, key ^ jnp.uint32(0x80000000), jnp.uint32(0))
+    keep = jnp.minimum(visible.sum(-1, dtype=jnp.int32), k)  # [..]
+
+    def count_at_least(threshold):
+        return (key >= threshold[..., None]).sum(-1, dtype=jnp.int32)
+
+    def bit(i, found):
+        tried = found | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
+        return jnp.where(count_at_least(tried) >= keep, tried, found)
+
+    kth = lax.fori_loop(0, 32, bit, jnp.zeros(keep.shape, jnp.uint32))
+    above = visible & (key > kth[..., None])
+
+    def with_ties(_):
+        at = visible & (key == kth[..., None])
+        room = keep - above.sum(-1, dtype=jnp.int32)
+        return above | (at & (jnp.cumsum(at, axis=-1, dtype=jnp.int32) <= room[..., None]))
+
+    def without(_):
+        return visible & (key >= kth[..., None])
+
+    return lax.cond(jnp.any(count_at_least(kth) > keep), with_ties, without, None)
+
+
+def _selected_attention(q, q_idx, w, k_idx_all, k_all, v_all, mask, positions, config, what):
+    """S > 1 queries a row under the selection: ``k_all``/``v_all``
+    [B, Hkv, T, D] and ``k_idx_all`` [B, T, Di] the row's columns, ``mask``
+    [B, S, T] what each query may see at all. The scores in tiles, the
+    ranking by counting, and the attention a walk over key blocks with the
+    selection as a packed mask (`ops/attention.sparse_segment_attention`:
+    the scores of [S, heads, T] are never held); masked jnp where the
+    kernels' tiles do not fit."""
+    from langstream_tpu.ops import attention as ops
+
+    s, t = q.shape[1], k_all.shape[2]
+    with jax.named_scope("attention.index"), jax.named_scope("attention.index.scores"):
+        scores = _index_scores(q_idx, w, k_idx_all, positions[:, 0], config)
+    with jax.named_scope("attention.select"):
+        chosen = _select_mask(scores, mask, config.index_topk)
+    with jax.named_scope("attention.sparse"):
+        if _selection_kernels(config, s, t):
+            ops.note_path(f"{what}-sparse", "sparse_segment_attention", config, s=s, t=t)
+            return ops.sparse_segment_attention(
+                q, k_all, v_all, positions[:, 0], chosen, config,
+                interpret=jax.default_backend() != "tpu",
+            )
+        ops.note_path(f"{what}-sparse", "jnp", config, s=s, t=t)
+        return attention(q, k_all, v_all, chosen, config)
+
+
+def _sparse_decode_attention(
+    q, q_idx, w, pk, pv, pik, table, layer, lengths, config, page_size
+):
+    """One query a row under the selection, through the page table → [B, H*D].
+    The row's cached indexer keys are read by whole pages (8 KiB a page and
+    layer at 64 x 64 bf16), scored and ranked (`lax.top_k`: the indices are
+    what the read needs), and ONLY the selected tokens' K and V rows are
+    gathered, [B, topk, Hkv, D]: the bytes follow ``min(length, topk)`` a
+    row, never its length. A row of length 0 (idle, or one the dense kernel
+    serves) comes back zeros."""
+    b, h, d = q.shape
+    hkv = pk.shape[2]
+    t = table.shape[1] * page_size
+    k = min(config.index_topk, t)
+    with jax.named_scope("attention.index"), jax.named_scope("attention.index.scores"):
+        k_idx = pik.at[layer, table].get(mode="clip").reshape(b, t, -1)[..., :q_idx.shape[-1]]
+        scores = _index_scores(q_idx[:, None], w[:, None], k_idx, None, config)[:, 0]
+    with jax.named_scope("attention.select"):
+        visible = jnp.arange(t)[None, :] < lengths[:, None]
+        _, chosen = lax.top_k(jnp.where(visible, scores, -jnp.inf), k)  # [B, k]
+        kept = jnp.arange(k)[None, :] < jnp.minimum(lengths, k)[:, None]
+    with jax.named_scope("attention.sparse"):
+        # the pool seen as rows [L x P x Hkv x ps, D] (merging major
+        # dimensions moves no byte) and ONE index a (token, head) row: 0.65
+        # ms a layer and leaf at 8 x 2,048 tokens on a v5e against 0.96 for
+        # the four-index form. A token's heads as one [Hkv, D] slice gathers
+        # in 0.67 alone, but inside the step the compiler then lays BOTH
+        # pool leaves out heads-minor and copies each whole, 1.6 GB, every
+        # layer and step (PERF.md section 6, PR 43)
+        pages = jnp.take_along_axis(table, chosen // page_size, axis=1)
+        rows = (
+            ((layer * pk.shape[1] + pages)[:, :, None] * hkv + jnp.arange(hkv)[None, None, :])
+            * page_size + (chosen % page_size)[:, :, None]
+        )  # [B, k, Hkv]
+        ks = jnp.take(pk.reshape(-1, d), rows, axis=0, mode="clip")  # [B, k, Hkv, D]
+        vs = jnp.take(pv.reshape(-1, d), rows, axis=0, mode="clip")
+        qg = q.reshape(b, hkv, h // hkv, d)
+        logits = jnp.einsum("bhgd,bkhd->bhgk", qg, ks, preferred_element_type=jnp.float32)
+        logits = _softcap(logits * d**-0.5, config.attn_logit_softcap)
+        logits = jnp.where(kept[:, None, None, :], logits, -1e30)
+        top = logits.max(axis=-1, keepdims=True)
+        probs = jnp.where(kept[:, None, None, :], jnp.exp(logits - top), 0.0)
+        out = jnp.einsum(
+            "bhgk,bkhd->bhgd", probs.astype(q.dtype), vs, preferred_element_type=jnp.float32
+        )
+        out = out / jnp.maximum(probs.sum(axis=-1, keepdims=True), 1e-30)
+    return out.astype(q.dtype).reshape(b, h * d)
+
+
+def _write_index_key(pik, layer, k_idx, table, positions, page_size):
+    """The indexer's key of each token [B, S, Di] into the pool's third leaf
+    [L, P, ps, Di] at ``[layer, table[b, pos // ps], pos % ps]``; an unmapped
+    page drops the write, as K's and V's does."""
+    pages, offs = _page_index(table, positions, page_size, pik.shape[1])
+    return pik.at[layer, pages, offs].set(_kept_index_key(k_idx, pik), mode="drop")
+
+
+def _kept_index_key(k_idx, leaf):
+    """The indexer's key [.., Di] as the cache keeps it: padded with zeros
+    to the leaf's width (``config.index_key_width``), in its dtype."""
+    pad = leaf.shape[-1] - k_idx.shape[-1]
+    return jnp.pad(k_idx.astype(leaf.dtype), [(0, 0)] * (k_idx.ndim - 1) + [(0, pad)])
+
+
 def _attention_block(
     x: jax.Array,
     lp: dict,
@@ -790,7 +1006,14 @@ def _attention_block(
     ``0 .. start + S - 1``, so where the decode kernel runs the read is that
     kernel with S x group query rows a KV head and no mask among them
     (``ragged_paged_block_attention``), and the write the decode write over
-    S rows of one aligned tile."""
+    S rows of one aligned tile. A model with an indexer
+    (``config.has_indexer``) carries a third pool leaf in ``cache_kv``, the
+    indexer's keys, written in ``kv_pool.write`` with K and V; its decode
+    step reads the selected tokens alone (`_sparse_decode_attention`; a row
+    of no more than ``index_topk`` tokens the dense kernel, where it runs)
+    and its segment goes through ``flash_segment_attention`` over the row's
+    gathered columns, under the selection once a query sees more than
+    ``index_topk`` keys (`_selected_attention`)."""
     if paged_table is None:
         with jax.named_scope("attention"):
             return _dense_attention(
@@ -811,7 +1034,13 @@ def _attention_block(
     s = x.shape[1]
     with jax.named_scope("attention"):
         q, k, v = _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows)
-    pk, pv = cache_kv  # [L, P, Hkv, ps, D], read and written at `layer`
+        if config.has_indexer:
+            with jax.named_scope("attention.index"):
+                q_idx, k_idx, w_idx = _index_proj(
+                    rms_norm(x, lp["attn_norm"], config.rms_norm_eps), lp,
+                    cache_positions, config,
+                )
+    pk, pv, *pik = cache_kv  # [L, P, Hkv, ps, D], read and written at `layer`
     num_pages = (pk["q"] if isinstance(pk, dict) else pk).shape[1]
     decode_kernels = s == 1 and paged_pallas_ok(config, page_size)
     block_kernels = (
@@ -845,9 +1074,18 @@ def _attention_block(
             kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             pk = _paged_scatter(pk, layer, kt, paged_table, cache_positions, page_size)
             pv = _paged_scatter(pv, layer, vt, paged_table, cache_positions, page_size)
+        if config.has_indexer:
+            pik = [_write_index_key(
+                pik[0], layer, k_idx, paged_table, cache_positions, page_size
+            )]
     with jax.named_scope("attention"):
         t = paged_table.shape[1] * page_size
-        if decode_kernels:
+        if config.has_indexer:
+            attn = _paged_selected_read(
+                q, q_idx, w_idx, pk, pv, pik[0], paged_table, layer, mask,
+                cache_positions, config, page_size, decode_kernels,
+            )
+        elif decode_kernels:
             lengths = _paged_lengths(
                 paged_table, cache_positions[:, 0], page_size, num_pages
             )
@@ -883,7 +1121,70 @@ def _attention_block(
             x, quantized_matmul(attn, lp["wo"]), lp, config,
             _lora_proj(attn, "wo", lora, lora_scale, adapter_rows),
         )
-    return x, (pk, pv)
+    return x, (pk, pv, *pik)
+
+
+def _paged_selected_read(
+    q, q_idx, w_idx, pk, pv, pik, table, layer, mask, positions, config,
+    page_size, decode_kernels,
+):
+    """The paged read of a model with an indexer → [B, S, H*D]. S = 1: rows
+    of no more than ``index_topk`` tokens take the dense decode kernel as
+    every model does (where it runs; the selection is the identity there),
+    longer rows `_sparse_decode_attention`, skipped whole while no row is
+    long. S > 1: the row's columns gathered through the table and
+    ``flash_segment_attention`` while no query sees more than ``index_topk``
+    keys, `_selected_attention` once one does."""
+    from langstream_tpu.ops import attention as ops
+
+    b, s = q.shape[:2]
+    t = table.shape[1] * page_size
+    topk = config.index_topk
+    interpret = jax.default_backend() != "tpu"
+    if s == 1:
+        lengths = _paged_lengths(table, positions[:, 0], page_size, pk.shape[1])
+        sparse = functools.partial(
+            _sparse_decode_attention, q[:, 0], q_idx[:, 0], w_idx[:, 0], pk, pv, pik,
+            table, layer, config=config, page_size=page_size,
+        )
+        if not decode_kernels:
+            ops.note_path("paged-decode-sparse", "xla top_k + gather", config, s=s, t=t)
+            return sparse(lengths=lengths)[:, None, :]
+        long = lengths > topk
+        ops.note_path(
+            "paged-decode-sparse",
+            "ragged_paged_decode_attention to index_topk, xla top_k + gather past it",
+            config, s=s, t=t,
+        )
+        dense = ops.ragged_paged_decode_attention(
+            q[:, 0], pk, pv, jnp.where(long, 0, lengths), table, layer, config,
+            page_size, interpret=interpret,
+        )
+        picked = lax.cond(
+            jnp.any(long), lambda: sparse(lengths=jnp.where(long, lengths, 0)),
+            lambda: jnp.zeros_like(dense),
+        )
+        return jnp.where(long[:, None], picked, dense)[:, None, :]
+    k_all = _paged_gather(pk, layer, table, page_size)
+    v_all = _paged_gather(pv, layer, table, page_size)
+    with jax.named_scope("attention.index"):
+        k_idx_all = pik.at[layer, table].get(mode="clip").reshape(b, t, -1)[
+            ..., :config.index_head_dim]
+    selected = functools.partial(
+        _selected_attention, q, q_idx, w_idx, k_idx_all, k_all, v_all, mask,
+        positions, config, "paged-segment",
+    )
+    if not _selection_kernels(config, s, t):
+        return selected()
+
+    def plain():
+        with jax.named_scope("attention.sparse"):
+            return ops.flash_segment_attention(
+                q, k_all, v_all, positions[:, 0], config, interpret=interpret
+            )
+
+    ops.note_path("paged-segment", "flash_segment_attention", config, s=s, t=t)
+    return lax.cond(jnp.max(positions) >= topk, selected, plain)
 
 
 def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
@@ -940,9 +1241,26 @@ def _dense_attention(
     """`_attention_block` without a page table: a dense cache entry
     [B, Hkv, T, D] written at ``cache_positions`` and read whole, or no
     cache at all."""
-    b = x.shape[0]
+    b, s = x.shape[:2]
     q, k, v = _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows)
     new_cache = None
+    cik = ()
+    if config.has_indexer:
+        positions = (
+            jnp.broadcast_to(jnp.arange(s), (b, s)) if cache_positions is None
+            else cache_positions
+        )
+        with jax.named_scope("attention.index"):
+            q_idx, k_idx, w_idx = _index_proj(
+                rms_norm(x, lp["attn_norm"], config.rms_norm_eps), lp, positions, config
+            )
+        if cache_kv is not None:  # the indexer's key [B, T, Di] beside K and V
+            *cache_kv, ik = cache_kv
+            ik = ik.at[jnp.arange(b)[:, None], cache_positions].set(_kept_index_key(k_idx, ik))
+            k_idx = ik[..., :config.index_head_dim]
+            cik = (ik,)
+        else:
+            cik = (k_idx,)
     if cache_kv is not None:
         ck, cv = cache_kv  # [B, Hkv, T, D] head-major (maybe int8-quantized)
         # scatter this step's k/v into the cache at cache_positions [B, S]
@@ -965,12 +1283,27 @@ def _dense_attention(
         else:
             ck = ck.at[bidx, hidx, pidx].set(kt)
             cv = cv.at[bidx, hidx, pidx].set(vt)
-        new_cache = (ck, cv)
+        new_cache = (ck, cv, *cik)
         k_all, v_all = ck, cv
     else:
         k_all, v_all = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
         if collect_kv:
-            new_cache = (k_all, v_all)
+            new_cache = (k_all, v_all, *cik)
+
+    if config.has_indexer and k_all.shape[2] > config.index_topk:
+        # a query can see more columns than it may keep; up to index_topk
+        # columns the selection is the identity and the paths below are as
+        # every model's
+        if not causal:
+            raise NotImplementedError(f"{config.name}: the indexer ranks what a causal query sees")
+        attn = _selected_attention(
+            q, q_idx, w_idx, k_idx, k_all, v_all, mask, positions, config,
+            "prefill" if s > 1 else "decode",
+        )
+        attn_out = quantized_matmul(attn, lp["wo"]) + _lora_proj(
+            attn, "wo", lora, lora_scale, adapter_rows
+        )
+        return _attn_residual(x, attn_out, lp, config), new_cache
 
     if config.ring_axis is not None and cache_kv is None:
         # sequence-parallel path: K/V blocks rotate around the ring; the
@@ -1697,26 +2030,29 @@ def _scan_layers(
             y, kv, counts = _layer_counted(
                 carry, whole(lp), sin, cos, mask, config, causal=causal,
                 collect_kv=collect_kv, token_valid=token_valid, moe_layer=l,
+                cache_positions=cache_positions,
             )
             return y, (kv, counts if moe else None)
 
         x, (kvs, counts) = lax.scan(body, x, (layers, index))
         return x, kvs, counts.sum(0) if moe else _no_moe_counts()
 
+    leaves = config.page_leaves
+
     def body_cached(carry, inputs):
-        lp, (ck, cv), ll, l = inputs
+        lp, entry, ll, l = inputs
         y, new_kv, counts = _layer_counted(
-            carry, whole(lp), sin, cos, mask, config, cache_kv=(ck, cv),
+            carry, whole(lp), sin, cos, mask, config, cache_kv=entry,
             cache_positions=cache_positions, lora=ll, lora_scale=lora_scale,
             adapter_rows=adapter_rows, token_valid=token_valid, moe_layer=l,
         )
         return y, (new_kv, counts if moe else None)
 
     x, (new_kv, counts) = lax.scan(
-        body_cached, x, (layers, (cache["k"], cache["v"]), lora_layers, index)
+        body_cached, x, (layers, tuple(cache[leaf] for leaf in leaves), lora_layers, index)
     )
     counts = counts.sum(0) if moe else _no_moe_counts()
-    return x, {"k": new_kv[0], "v": new_kv[1]}, counts
+    return x, dict(zip(leaves, new_kv)), counts
 
 
 def _scan_layers_inplace(
@@ -1741,19 +2077,20 @@ def _scan_layers_inplace(
     Returns (x, pool, the layers' summed counts, `moe_count_names`)."""
     layers, held = _split_held(params["layers"], config)
     lora_layers, lora_scale = _split_lora(lora)
+    leaves = config.page_leaves
 
     def body(carry, inputs):
         x, pool = carry
         lp, l, ll = inputs
-        y, (nk, nv), counts = _layer_counted(
+        y, entry, counts = _layer_counted(
             x, lp if held is None else {**lp, **held}, sin, cos, mask, config,
-            cache_kv=(pool["k"], pool["v"]),
+            cache_kv=tuple(pool[leaf] for leaf in leaves),
             cache_positions=cache_positions, verify=verify,
             paged_table=paged_table, page_size=page_size, lora=ll,
             lora_scale=lora_scale, adapter_rows=adapter_rows, layer=l,
             block=block, token_valid=token_valid,
         )
-        return (y, {"k": nk, "v": nv}), (counts if config.is_moe else None)
+        return (y, dict(zip(leaves, entry))), (counts if config.is_moe else None)
 
     (x, pool), counts = lax.scan(
         body, (x, pool), (layers, jnp.arange(config.n_layers), lora_layers)
@@ -1778,17 +2115,24 @@ def _visible(q_pos: jax.Array, kv_pos: jax.Array, config: ModelConfig) -> jax.Ar
 
 
 @functools.partial(jax.jit, static_argnames=("config",))
-def forward(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array:
+def forward(
+    params: Params, tokens: jax.Array, config: ModelConfig,
+    positions: Optional[jax.Array] = None,  # [3, B, S]: a position triple a token
+) -> jax.Array:
     """Full-sequence causal forward → logits [B, S, V] (training / scoring);
     causal across blocks and two-way inside one for a model that fills
     blocks (`_visible`), whose logits at a position score the token AT it.
 
     With ``config.ring_axis`` set (under shard_map, parallel.sp), ``tokens``
     is the LOCAL sequence block; RoPE positions are globalised from the ring
-    index and the causal mask is handled inside ring attention.
+    index and the causal mask is handled inside ring attention. ``positions``
+    (a model with ``mrope_section``): the rotary's three position streams,
+    text's being equal and the default; the mask stays causal in the order
+    of ``tokens`` and the indexer turns by the first stream.
     """
     b, s = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+    if positions is None:
+        positions = jnp.broadcast_to(jnp.arange(s), (b, s))
     if config.ring_axis is not None:
         positions = positions + lax.axis_index(config.ring_axis) * s
     sin, cos = _rope_freqs(positions, config)
@@ -1804,7 +2148,10 @@ def forward(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array
     elif config.layer_pattern:
         x, _, _ = _scan_periods(params, x, sin, cos, mask, config)
     else:
-        x, _, _ = _scan_layers(params, x, sin, cos, mask, config)
+        x, _, _ = _scan_layers(
+            params, x, sin, cos, mask, config,
+            cache_positions=_text_positions(positions) if config.has_indexer else None,
+        )
     return _unembed(params, x, config)
 
 
@@ -1859,6 +2206,12 @@ def make_kv_cache(
         return {
             "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
             "win": {"k": jnp.zeros(win, dtype), "v": jnp.zeros(win, dtype)},
+        }
+    if config.has_indexer:
+        # the indexer's key a token beside K and V: [L, B, T, Di], one head
+        return {
+            "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
+            "ik": jnp.zeros((*shape[:2], max_len, config.index_key_width), dtype),
         }
     if config.kv_cache_dtype == "int8":
         entry = lambda: {  # noqa: E731
@@ -2050,6 +2403,10 @@ def paged_verify_step_inplace(
     only past ACCEPTED tokens and the next dispatch overwrites the stale
     page columns before any causal mask can reach them."""
     _no_pattern(config, "paged_verify_step_inplace")
+    if config.has_indexer:
+        raise NotImplementedError(
+            f"paged_verify_step_inplace: no verify under a learned selection ({config.name})"
+        )
     b, s = tokens.shape
     pos = positions[:, None] + jnp.arange(s)[None, :]
     sin, cos = _rope_freqs(pos, config)
@@ -2122,7 +2479,7 @@ def paged_prefill_segment_inplace(
     lora: Optional[dict] = None,
     adapter_rows: Optional[jax.Array] = None,
     state_rows: Optional[jax.Array] = None,  # [B] each row's recurrent state row
-    moe_counts: bool = False,  # the window model's segment returns its counts
+    moe_counts: bool = False,  # a model that holds experts returns its segment's counts
 ):
     """Chunked/suffix prefill straight into the slot's pages: process one
     segment of a longer prompt against pages whose columns [0, offsets) were
@@ -2164,10 +2521,16 @@ def paged_prefill_segment_inplace(
         )
         pool = join_rec(kv, rec)
     else:
-        x, pool, _ = _scan_layers_inplace(
+        x, pool, held_counts = _scan_layers_inplace(
             params, x, sin, cos, mask, config, pool, positions, table, page_size,
             lora=lora, adapter_rows=adapter_rows,
+            # the sequential block's no-drop expert layer counts real tokens;
+            # every other model's segment is the program it was
+            token_valid=jnp.arange(s)[None, :] < seg_lengths[:, None]
+            if config.experts_held else None,
         )
+        if config.experts_held:
+            counts = held_counts
     last = jnp.clip(seg_lengths - 1, 0, s - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     logits = _unembed(params, x_last[:, None, :], config)[:, 0]
@@ -2211,6 +2574,21 @@ def paged_insert_cache(
     scatter below, one update a (row, kv head, position), which is that
     write's reference: the pools are bit-equal. ``config``: the engine's,
     for its mesh and ``attention_impl``."""
+    if "ik" in pool:
+        # the indexer's keys [L, n, W, Di] to [L, P, ps, Di] by the same table;
+        # K and V as for every model
+        w = local_cache["ik"].shape[2]
+        positions = jnp.broadcast_to(jnp.arange(w)[None, :], (tables.shape[0], w))
+        pages, offs = _page_index(tables, positions, page_size, pool["ik"].shape[1])
+        with jax.named_scope("kv_pool.write"):
+            ik = pool["ik"].at[:, pages, offs].set(
+                local_cache["ik"].astype(pool["ik"].dtype), mode="drop"
+            )
+        strip = lambda tree: {k: v for k, v in tree.items() if k != "ik"}  # noqa: E731
+        return {
+            **paged_insert_cache(strip(pool), strip(local_cache), tables, page_size, config),
+            "ik": ik,
+        }
     n = tables.shape[0]
     width = jax.tree.leaves(local_cache)[0].shape[3]
     if insert_copies_pages(pool, width, page_size, config):

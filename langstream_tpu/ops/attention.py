@@ -15,6 +15,12 @@ blocks are naturally aligned, and a per-head kv block is a contiguous
   continuous batcher packs rows of very different lengths into one step, so
   a masked read over a fixed width wastes bandwidth proportional to
   max_len - mean_len).
+- ``flash_segment_attention``: a prefill segment's queries over the row's
+  gathered columns, causal (windowed), a query block visiting only the key
+  blocks it can see; ``sparse_segment_attention`` is that walk under a
+  packed [S, T] selection (a model with an indexer), and ``index_scores``
+  the indexer's scores of a segment in tiles, so nothing of
+  [S, heads, T] is ever held.
 - ``paged_kv_write``: that step's new K and V rows into the bf16 pool where
   it lies, a copy per live row (a scatter pays per (row, kv head), dropped
   rows included).
@@ -334,9 +340,14 @@ def flash_prefill_attention(
 
 def _segment_kernel(
     offsets_ref,  # scalar-prefetch [B]
-    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, block_q: int, block_k: int, window: int, n_t: int, scale: float, softcap,
+    q_ref, k_ref, v_ref, *refs,  # [chosen_ref,] o_ref, m_scr, l_scr, acc_scr
+    block_q: int, block_k: int, window: int, n_t: int, scale: float, softcap,
+    selected: bool = False,
 ):
+    # under a selection one more block rides beside K and V: the (query, key)
+    # pairs that were chosen, int8 [1, block_q, block_k]
+    chosen_ref = refs[0] if selected else None
+    o_ref, m_scr, l_scr, acc_scr = refs[1:] if selected else refs
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -366,6 +377,8 @@ def _segment_kernel(
         seen = k_pos <= q_pos
         if window:
             seen = seen & (k_pos > q_pos - window)
+        if selected:
+            seen = seen & (chosen_ref[...].astype(jnp.int32) != 0)
         s = jnp.where(seen, s, _NEG)
         m_prev = m_scr[:, :, 0]
         m_new = jnp.maximum(m_prev, s.max(axis=-1))
@@ -417,6 +430,7 @@ def flash_segment_attention(
     config: ModelConfig,
     window: int = 0,  # > 0: query i sees keys i - window + 1 .. i
     interpret: bool = False,
+    chosen: jax.Array | None = None,  # `sparse_segment_attention`'s
 ) -> jax.Array:
     """Causal (windowed) GQA attention of a segment over its row's cache →
     [B, S, H*D]. Every column a query can see has to hold its key: the
@@ -441,12 +455,21 @@ def flash_segment_attention(
     def q_index(b, h, i, j, offsets):
         return (b, h, 0, i, 0)
 
+    selection, extra = [], {}
+    if chosen is not None:  # the window model's program is the one it was
+        def chosen_index(b, h, i, j, offsets):
+            return (b, i, kv_index(b, h, i, j, offsets)[2])
+
+        selection = [pl.BlockSpec((1, block_q, block_k), chosen_index)]
+        extra["selected"] = True
+
     out = pl.pallas_call(
         functools.partial(
             _segment_kernel, block_q=block_q, block_k=block_k, window=window,
             n_t=n_t, scale=1.0 / (d**0.5), softcap=config.attn_logit_softcap,
+            **extra,
         ),
-        name="flash_segment_attention",
+        name="flash_segment_attention" if chosen is None else "sparse_segment_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, hkv, s // block_q, n_k),
@@ -454,6 +477,7 @@ def flash_segment_attention(
                 pl.BlockSpec((1, 1, group, block_q, d), q_index),
                 pl.BlockSpec((1, 1, block_k, d), kv_index),
                 pl.BlockSpec((1, 1, block_k, d), kv_index),
+                *selection,
             ],
             out_specs=pl.BlockSpec((1, 1, group, block_q, d), q_index),
             scratch_shapes=[
@@ -465,8 +489,94 @@ def flash_segment_attention(
         out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-    )(offsets.astype(jnp.int32), qg, k, v)
+    )(offsets.astype(jnp.int32), qg, k, v, *([] if chosen is None else [chosen]))
     return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * d)
+
+
+def sparse_segment_attention(
+    q: jax.Array,  # [B, S, H, D] at positions offsets[b] + (0 .. S-1)
+    k: jax.Array,  # [B, Hkv, T, D]
+    v: jax.Array,
+    offsets: jax.Array,  # [B]
+    chosen: jax.Array,  # [B, S, T] int8: nonzero where the query attends to the key
+    config: ModelConfig,
+    interpret: bool = False,
+) -> jax.Array:
+    """`flash_segment_attention` under a learned selection → [B, S, H*D]: a
+    query attends to the keys ``chosen`` names among those behind it, one
+    selection for all heads. The same walk over the key blocks up to the
+    diagonal with one more int8 block a step; the work skipped is the
+    softmax's, not the walk's (a query's 2,048 chosen keys lie in every
+    block). A query that chose nothing comes back zeros. Under its own name
+    on the `pallas_call`."""
+    return flash_segment_attention(
+        q, k, v, offsets, config, interpret=interpret, chosen=chosen.astype(jnp.int8)
+    )
+
+
+def _index_score_kernel(
+    offsets_ref,  # scalar-prefetch [B]
+    q_ref,  # [1, Hi, block_q, Di]
+    w_ref,  # [1, block_q, Hi] float32
+    k_ref,  # [1, block_k, Di]
+    o_ref,  # [1, block_q, block_k] float32
+    *, block_q: int, block_k: int,
+):
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    last_query = offsets_ref[b] + (i + 1) * block_q - 1
+
+    @pl.when(j * block_k > last_query)
+    def _unseen():  # no query of the block sees a key of this one
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(j * block_k <= last_query)
+    def _body():
+        k = k_ref[0]
+        w = w_ref[0]
+        acc = jnp.zeros((block_q, block_k), jnp.float32)
+        for head in range(q_ref.shape[1]):
+            dots = jax.lax.dot_general(
+                q_ref[0, head], k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # [block_q, block_k]
+            acc = acc + jnp.maximum(dots, 0.0) * w[:, head:head + 1]
+        o_ref[0] = acc + 0.0  # -0.0 reads +0.0
+
+
+def index_scores(
+    q_idx: jax.Array,  # [B, S, Hi, Di] the indexer's queries at offsets[b] + (0 .. S-1)
+    w: jax.Array,  # [B, S, Hi] float32, the heads' weights
+    k_idx: jax.Array,  # [B, T, Di] the row's indexer keys, columns 0 .. T-1
+    offsets: jax.Array,  # [B]
+    interpret: bool = False,
+) -> jax.Array:
+    """The indexer's scores of a segment → [B, S, T] float32,
+    ``sum_h w[s, h] relu(q_idx[s, h] . k_idx[t])``, in tiles of (512, 512)
+    (the published ``q_chunk_size`` and ``kv_chunk_size``, fitted to S and
+    T): a tile's Hi products are summed where they are made, so nothing of
+    [S, Hi, T] is held. A tile no query of which sees a key of it (wholly
+    past the diagonal) is written zeros and not computed; the caller masks
+    what a query may not see."""
+    b, s, hi, di = q_idx.shape
+    t = k_idx.shape[1]
+    block_q, block_k = _fit_block(512, s), _fit_block(512, t)
+    return pl.pallas_call(
+        functools.partial(_index_score_kernel, block_q=block_q, block_k=block_k),
+        name="index_scores",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, s // block_q, t // block_k),
+            in_specs=[
+                pl.BlockSpec((1, hi, block_q, di), lambda b, i, j, off: (b, 0, i, 0)),
+                pl.BlockSpec((1, block_q, hi), lambda b, i, j, off: (b, i, 0)),
+                pl.BlockSpec((1, block_k, di), lambda b, i, j, off: (b, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, block_k), lambda b, i, j, off: (b, i, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, s, t), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(offsets.astype(jnp.int32), q_idx.transpose(0, 2, 1, 3), w.astype(jnp.float32), k_idx)
 
 
 # ---------------------------------------------------------------------------

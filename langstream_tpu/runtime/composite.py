@@ -8,6 +8,8 @@ ordered commit still works per source record.
 
 from __future__ import annotations
 
+from typing import Optional
+
 from langstream_tpu.api.agent import AgentContext, AgentProcessor, ProcessorResult
 from langstream_tpu.api.record import Record
 
@@ -30,6 +32,9 @@ class CompositeAgentProcessor(AgentProcessor):
     async def start(self) -> None:
         for p in self.processors:
             await p.start()
+
+    def inflight_records(self) -> Optional[int]:
+        return max((n for p in self.processors if (n := p.inflight_records())), default=None)
 
     async def close(self) -> None:
         for p in self.processors:

@@ -301,9 +301,23 @@ class AgentRunner:
         # batch k no longer stalls batch k+1's processing — the round-2 e2e
         # TTFT bottleneck: records arriving mid-generation waited out the
         # whole previous batch before the engine even saw them.
+        # The bound counts BATCHES, and a read returns what has arrived:
+        # records that trickle in come as batches of one, so a completions
+        # step then holds six requests in flight (one with the writer, four
+        # queued, one waiting to be queued) whatever its engine could take,
+        # or seven, by how the first reads split the arrivals. Where that
+        # starves the service, the step's own hint
+        # (`AgentProcessor.inflight_records`: the `tpu-serving` resource's
+        # ``inflight-records``) bounds the RECORDS in flight instead; a batch
+        # is never split, so one may overshoot it. One option and not a rule
+        # the runner works out, because as every agent's rule it moved a
+        # standing cell past its bound (PERF.md section 7 ah, ROADMAP S23).
         loops = 0
         depth = max(1, int(self.node.configuration.get("max-inflight-batches", 4)))
-        pending: asyncio.Queue = asyncio.Queue(maxsize=depth)
+        by_records = int(self.processor.inflight_records() or 0)
+        pending: asyncio.Queue = asyncio.Queue(maxsize=0 if by_records else depth)
+        in_flight = 0  # records, counted only under `by_records`
+        room = asyncio.Event()
 
         async def process_batch(records: list[Record], trace_id: str):
             # a batch-level span joins the FIRST record's trace (per-record
@@ -318,6 +332,7 @@ class AgentRunner:
                 return await self.processor.process(records)
 
         async def writer() -> None:
+            nonlocal in_flight
             while True:
                 item = await pending.get()
                 if item is None:
@@ -325,6 +340,8 @@ class AgentRunner:
                 task, trace_id = item
                 results = await task
                 await self._handle_results(results, trace_id)
+                in_flight -= len(results)  # one result a record
+                room.set()
 
         writer_task = asyncio.create_task(writer())
         try:
@@ -333,6 +350,15 @@ class AgentRunner:
                     break
                 if writer_task.done():
                     break  # writer hit a permanent failure; surfaced below
+                if by_records and in_flight >= by_records:
+                    # wait for the writer to finish a batch, or to die
+                    room.clear()
+                    waiting = asyncio.create_task(room.wait())
+                    await asyncio.wait(
+                        {waiting, writer_task}, return_when=asyncio.FIRST_COMPLETED
+                    )
+                    waiting.cancel()
+                    continue
                 loops += 1
                 # race the read against the writer so a sink/handler failure
                 # surfaces immediately instead of hanging behind a quiet topic
@@ -350,6 +376,7 @@ class AgentRunner:
                 self._m_in.count(len(records))
                 trace_id = record_trace_id(records[0]) or uuid.uuid4().hex[:16]
                 task = asyncio.create_task(process_batch(records, trace_id))
+                in_flight += len(records)
                 put = asyncio.create_task(pending.put((task, trace_id)))
                 # the put blocks at pipeline depth (backpressure toward the
                 # broker); racing it against the writer avoids a deadlock if

@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from langstream_tpu.models.configs import GenerationOptions, ModelConfig
+from langstream_tpu.models.configs import PAGE_LEAVES, GenerationOptions, ModelConfig
 from langstream_tpu.models.transformer import (
     MOE_COUNTS,
     moe_count_names,
@@ -586,13 +586,15 @@ def _paged_segment_and_sample(
     (out-of-bounds on non-final segments — dropped), so the decode chain
     the engine dispatches NEXT iteration already carries the right state
     without a host round trip."""
-    # a model with window layers counts its segments' expert assignments too
-    # (`moe_count_names`): each has a fetch of its own to bring the counts
-    # (`_segment_step`), and the program returns them as a fifth result
+    # a model that holds its experts (window layers' parallel block, or the
+    # sequential block with ``experts_held``) counts its segments' expert
+    # assignments too (`moe_count_names`): each has a fetch of its own to
+    # bring the counts (`_segment_step`), and the program returns them as a
+    # fifth result
     logits, pool, *moe = paged_prefill_segment_inplace(
         params, tokens, offsets, seg_lengths, pool, table, config, page_size,
         lora=lora, adapter_rows=arows, state_rows=state_rows,
-        moe_counts=config.has_window,
+        moe_counts=config.holds_experts,
     )
     first, key, s1 = _sample_first(
         logits, key, temp, top_k, top_p, dfa, g, state0, config.vocab_size
@@ -603,13 +605,22 @@ def _paged_segment_and_sample(
 
 
 def _on_pages(fn, pool, *rest):
-    """``fn`` over the pool's PAGE leaves ("k", "v"). What lies beside them
-    passes through: a recurrent state ("rec", a row a slot, no page axis),
-    and a window group's pages ("win"), which are never copied or restored:
-    the page indices here are the full group's, and every option that moves
+    """``fn`` over the pool's PAGE leaves: "k", "v" and, for a model with an
+    indexer, its keys "ik" (a copied or a zeroed page is whole: a page that
+    left its indexer keys behind would serve stale keys to the selection).
+    Every leaf has its pages on axis 1. What lies beside them passes
+    through: a recurrent state ("rec", a row a slot, no page axis), and a
+    window group's pages ("win"), which are never copied or restored: the
+    page indices here are the full group's, and every option that moves
     pages is refused for such a model (`_window_page_zero` scrubs them)."""
-    pages = {"k": pool["k"], "v": pool["v"]}
-    return {**pool, **jax.tree.map(fn, pages, *rest)}
+    return {**pool, **jax.tree.map(fn, _page_leaves(pool), *rest)}
+
+
+def _page_leaves(pool) -> dict:
+    """The pool's leaves that hold a token's state by page, by NAME
+    (`ModelConfig.page_leaves` chooses among them): a leaf this list does not
+    know is neither copied nor zeroed by accident."""
+    return {name: pool[name] for name in PAGE_LEAVES if name in pool}
 
 
 @functools.partial(jax.jit, donate_argnames=("pool",))
@@ -661,7 +672,7 @@ def _page_snapshot(pool, src):
     def take(a):
         return lax.dynamic_index_in_dim(a, src, 1, keepdims=False)
 
-    return jax.tree.map(take, {"k": pool["k"], "v": pool["v"]})
+    return jax.tree.map(take, _page_leaves(pool))
 
 
 @functools.partial(jax.jit, donate_argnames=("pool",))
@@ -1267,6 +1278,36 @@ class ServingEngine:
                     f"{config.name} has window layers: {', '.join(asked)} "
                     "cannot be used with two page groups"
                 )
+        if config.has_indexer:
+            # Refused at build, by the option's name (docs/SERVING.md "A
+            # model whose attention reads a learned selection"). The page
+            # pool holds a third leaf a token, the indexer's key: the host
+            # tier, migration and the durable checkpoint snapshot and
+            # restore K and V alone (`_page_snapshot`, serving/wire.py,
+            # serving/durable.py), and a page that came back without its
+            # indexer keys would be ranked by stale ones. The verify path
+            # is jnp over the whole table and knows no selection; an int8
+            # pool has no third leaf; the indexer takes no adapter terms and
+            # its gathers are not sharded. Prefix reuse is served: a cached
+            # page holds its tokens' indexer keys, which depend on nothing
+            # after them, and a copied page is whole (`_on_pages`).
+            on = lambda v: v is True or str(v).lower() in ("auto", "on", "true", "1")  # noqa: E731
+            refused = {
+                "host_kv_fraction": float(host_kv_fraction) > 0,
+                "migrate_staging": bool(migrate_staging),
+                "durable_dir": bool(durable_dir),
+                "speculation": on(speculation),
+                "kv_cache_dtype": config.kv_cache_dtype == "int8",
+                "adapters": bool(adapters),
+                "mesh": mesh is not None,
+                "spmd": spmd is not None,
+            }
+            asked = [name for name, is_on in refused.items() if is_on]
+            if asked:
+                raise ValueError(
+                    f"{config.name} reads a learned selection: {', '.join(asked)} "
+                    "cannot be used with an indexer's keys in the page pool"
+                )
         if config.fills_blocks:
             # Refused at build, by the option's name (docs/SERVING.md "A
             # model that fills blocks"). A grammar advances left to right, a
@@ -1555,6 +1596,10 @@ class ServingEngine:
         # (stats "block-*"; docs/SERVING.md §12)
         self._block_dev = self._fresh_block_state(max_batch, config)
         self._block_totals = dict.fromkeys(BLOCK_COUNTERS, 0)
+        # a model with an indexer: the tokens its dispatches scored and read
+        # a layer (stats "index-tokens-scored-total", "kv-tokens-selected-total")
+        self.index_tokens_scored_total = 0
+        self.kv_tokens_selected_total = 0
         # slots freed since the last dispatch: their device temp must be
         # zeroed, else sample()'s batch-wide any_sample/any_filter predicates
         # keep paying the full-vocab sort for a slot that no longer exists
@@ -2594,6 +2639,15 @@ class ServingEngine:
             **(
                 {f"block-{k.replace('_', '-')}": v for k, v in self._block_totals.items()}
                 if self.config.fills_blocks else {}
+            ),
+            # a model with an indexer: what its decode chunks and segments
+            # scored and read a layer, summed over the dispatches launched
+            **(
+                {
+                    "index-tokens-scored-total": self.index_tokens_scored_total,
+                    "kv-tokens-selected-total": self.kv_tokens_selected_total,
+                }
+                if self.config.has_indexer else {}
             ),
             # a model with window layers: its second page group's use
             **(
@@ -4078,8 +4132,9 @@ class ServingEngine:
         (``_segment_step`` adds each later segment to it and the span is
         emitted once, when the final segment's first token lands — only
         that segment has a fetch to time). The segment programs return no
-        MoE counts, except for a model with window layers (its expert layer
-        holds a share, ``config.has_window``): there every segment is
+        MoE counts, except for a model that holds its experts
+        (``config.holds_experts``: window layers' parallel block, or the
+        sequential block with ``experts_held``): there every segment is
         fetched for its counts and is a span of its own, and the stream's
         dispatches sum their ``stages`` into one list."""
         return self._new_dispatch(
@@ -6215,6 +6270,11 @@ class ServingEngine:
                 "KV-page migration: a row that advances by a block holds, "
                 "between passes, K/V of tokens that are not final yet"
             )
+        if self.config.has_indexer:
+            raise MigrationError(
+                "KV-page migration carries K and V only: a page's indexer "
+                "keys have no wire format yet"
+            )
         reply: "queue.SimpleQueue" = queue.SimpleQueue()
         self._migrate_cmds.put((kind, payload, reply))
         self._wake.set()
@@ -6554,7 +6614,7 @@ class ServingEngine:
         disp = st.get("disp")
         # a model whose segments return expert counts fetches each one: a
         # span a segment, with its own device_ms (else one for the stream)
-        per_segment = self.config.has_window
+        per_segment = self.config.holds_experts
         if start:
             # the stream's admission: its `engine.prefill` runs from here
             st["started"] = time.monotonic()
@@ -6593,6 +6653,14 @@ class ServingEngine:
         st["seg"] += 1
         if disp is not None and self.config.has_window:
             disp.attrs.update(self._segment_window_attrs(s0, len(seg)))
+        if self.config.has_indexer:
+            # counted whether or not a span carries them: `stats()` totals
+            scored, selected = self._index_counts(s0 + 1 + np.arange(len(seg)))
+            if disp is not None:
+                disp.attrs.update(
+                    offset=s0, kv_tokens_read=selected, index_tokens_scored=scored,
+                    kv_tokens_selected=selected,
+                )
         if not final:
             if per_segment:  # nothing to deliver: the fetch lands the span
                 return [(
@@ -6675,14 +6743,19 @@ class ServingEngine:
             # the (row, step) pairs the chunk computes: `tokens_delivered`
             # (`_process_chunk`) is how many of them a request received
             row_steps=steps * len(live),
-            kv_tokens_read=self._kv_tokens_read(live, steps),
             clean=clean, pipelined=pipelined,
             kv_pages_visited=pages_visited, kv_rows_written=rows_written,
+            kv_tokens_read=self._kv_tokens_read(live, steps),
             # (row, step) pairs whose recurrent state is updated, a linear
             # layer: the pairs that write a K/V row, idle rows move none
             **({"state_rows": rows_written} if self.config.is_recurrent else {}),
             **self._decode_window_attrs(steps, recycled),
         )
+        # a model with an indexer READS the selected: over `kv_tokens_read`;
+        # counted into `stats()` whether or not a span carries it
+        index_attrs = self._decode_index_attrs(live, steps)
+        if disp is not None:
+            disp.attrs.update(index_attrs)
         with jax.profiler.TraceAnnotation(
             "engine.decode_chunk", seq=self._dispatch_seq, steps=steps,
             t_mono_ns=_mono_ns(disp),
@@ -6714,6 +6787,33 @@ class ServingEngine:
             steps * (slot.position + slot.ahead + 1) + steps * (steps - 1) // 2
             for slot in live
         )
+
+    def _index_counts(self, lengths) -> tuple[int, int]:
+        """(index_tokens_scored, kv_tokens_selected) of queries that see
+        ``lengths`` columns each, a layer: the indexer scores every one, the
+        attention reads ``index_topk`` of them at most. Counted on the host
+        from positions, as ``kv_tokens_read`` is, and summed into `stats()`."""
+        lengths = np.asarray(lengths, np.int64)
+        scored = int(lengths.sum())
+        selected = int(np.minimum(lengths, self.config.index_topk).sum())
+        with self._stats_lock:
+            self.index_tokens_scored_total += scored
+            self.kv_tokens_selected_total += selected
+        return scored, selected
+
+    def _decode_index_attrs(self, live: list, steps: int) -> dict:
+        """The decode chunk's span attributes of a model with an indexer:
+        what it scores (every live row's length at every step, the dense
+        model's ``kv_tokens_read``) and what it READS, which is what
+        ``kv_tokens_read`` then says too."""
+        if not self.config.has_indexer:
+            return {}
+        first = np.asarray([slot.position + slot.ahead + 1 for slot in live], np.int64)
+        scored, selected = self._index_counts(first[:, None] + np.arange(steps)[None, :])
+        return {
+            "kv_tokens_read": selected, "index_tokens_scored": scored,
+            "kv_tokens_selected": selected,
+        }
 
     def _advance_window_rows(self, steps: int) -> int:
         """A model with window layers, before a decode chunk of ``steps``:

@@ -42,6 +42,9 @@ class ServingMemoryPlan:
     # chunked-prefill segments (they write straight into the slot's pages).
     # The layer scan addresses it by (layer, page) and forms no per-layer
     # entry, so a decode chunk holds nothing of a layer's size beside it.
+    # A model with an indexer keeps its indexer's key a token in the same
+    # pages (a third leaf, [L, P, page_size, index_key_width]): this term
+    # counts it, since it is `make_page_pool`'s whole tree.
     # Sized by pages_for_fraction: every slot's max_seq_len plus the
     # prefix-cache-fraction alias headroom.
     page_pool_bytes: int = 0

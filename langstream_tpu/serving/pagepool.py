@@ -306,6 +306,10 @@ class PagePool:
         # dispatch zeroes a freed row: the next admission writes it whole
         # from the zero state (the admit group) or starts its first
         # segment from zero, so nothing of the old state is ever read
+        # a model with an indexer: a third leaf a token beside K and V,
+        # ``dev["ik"]`` [L, P, ps, index_key_width], its indexer's keys, under
+        # the SAME table and page index: it is part of a page
+        # (``bytes_per_page`` counts it), so nothing here tells it apart
         # a model with window layers: their pages are a group of their own
         # (``dev["win"]``, `WindowPageGroup`), reserved with the full
         # group's in `reserve` and freed with them in `free_slot`

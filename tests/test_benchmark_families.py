@@ -10,7 +10,12 @@ refusals, the seeded tree, the costs and the readers) and of
 `benchmark/tests/test_sdar_moe_family.py` (SDAR-MoE: the contract with the
 three exports of an engine that fills blocks, the catalog's keys and the
 stated cut, the cell's sizing, the refusals, the seeded tree, a trajectory
-from tokens and labels, the cost and the metric files) and the cases of
+from tokens and labels, the cost and the metric files) and of
+`benchmark/tests/test_keye_vl2_family.py` (Keye-VL-2.0: the contract, the
+catalog's keys with the nested groups whole and the stated cut, the cell's
+sizing, the refusals, the seeded tree against the program's own, the costs
+and the metric files; its names all say `keye`, since the cases here share
+one namespace) and the cases of
 `benchmark/tests/test_request_readers.py` (the clock between a profile and the
 spans, a first token's stages, the device's idle by what the engine held; one
 of them records a profile of a small engine) run here as they stand
@@ -45,4 +50,5 @@ globals().update(_cases("test_families"))
 globals().update(_cases("test_olmo_hybrid_family"))
 globals().update(_cases("test_cohere2_moe_family"))
 globals().update(_cases("test_sdar_moe_family"))
+globals().update(_cases("test_keye_vl2_family"))
 globals().update(_cases("test_request_readers"))

@@ -729,6 +729,29 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
     assert {"block_choice", "head", "attention", "kv_pool.write", "moe_ffn", "moe_ffn.route",
             "moe_ffn.dispatch", "moe_ffn.experts", "moe_ffn.combine"} <= texts["blocks"]
     assert "block_choice" not in texts["dense"] | texts["moe"] | texts["hybrid"] | texts["window"]
+    # a model whose attention reads a learned selection: the indexer, the
+    # ranking and the selected read under their own scopes inside
+    # `attention`, in the decode chunk and in a segment past its top-k
+    sparse = MODEL_PRESETS["tiny-sparse-moe-test"]
+
+    def sparse_programs(params, pool):
+        chunk = E._paged_decode_chunk(
+            params, tokens[:, 0], lengths, pool, table, key, ones, zeros, ones, 2, sparse, page,
+        )
+        return chunk, E._paged_segment_and_sample(
+            params, tokens[:1, :16], lengths[:1], lengths[:1], chunk[3], table[:1], key,
+            ones[:1], zeros[:1], ones[:1], sparse, page,
+        )
+
+    texts["sparse"] = lowered_scopes(
+        sparse_programs, T.init_params(sparse, jax.random.PRNGKey(5)),
+        T.make_page_pool(sparse, 8, page),
+    )
+    selection = {"attention.index", "attention.index.scores", "attention.select", "attention.sparse"}
+    assert selection | {"attention", "kv_pool.write", "moe_ffn", "head"} <= texts["sparse"]
+    assert not selection & (
+        texts["dense"] | texts["moe"] | texts["hybrid"] | texts["window"] | texts["blocks"]
+    )
     assert set(T.SCOPES) <= set().union(*texts.values())
     assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
     assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",

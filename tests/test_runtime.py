@@ -443,6 +443,10 @@ class GatedProcessor(SingleRecordProcessor):
 
     gate = None  # asyncio.Event, installed by the test
     started: list = []
+    hint = None  # what `inflight_records` says, installed by the test
+
+    def inflight_records(self):
+        return GatedProcessor.hint
 
     async def process_record(self, record: Record) -> list[Record]:
         GatedProcessor.started.append(str(record.value))
@@ -511,3 +515,120 @@ pipeline:
             await runner.stop()
 
     run(main())
+
+
+TRICKLE_PIPELINE = """
+module: default
+id: app
+topics:
+  - name: in-t
+  - name: out-t
+pipeline:
+  - name: g
+    type: gated
+    input: in-t
+    output: out-t
+"""
+
+
+async def _trickle(runner, names, started_at_least):
+    """Produce `names` one by one, each once the one before has started (so
+    every read returns a batch of ONE), as far as processing starts."""
+    produced = 0
+    for name in names:
+        await runner.produce("in-t", name)
+        produced += 1
+        for _ in range(50):
+            if name in GatedProcessor.started:
+                break
+            await asyncio.sleep(0.02)
+        if name not in GatedProcessor.started:
+            break
+    assert len(GatedProcessor.started) >= started_at_least, GatedProcessor.started
+    return list(GatedProcessor.started), produced
+
+
+@pytest.mark.parametrize(
+    "hint,started", [(None, 6), (12, 12), (3, 3)],
+    ids=["batches-by-default", "twelve-records", "three-records"],
+)
+def test_inflight_bound_in_batches_or_in_records(run, hint, started):
+    """Records that trickle in come as batches of one. By default the bound
+    counts BATCHES (one with the writer, four queued, one waiting to be
+    queued: six slow records start, whatever the step could take); where the
+    step says how many RECORDS to keep in flight (`inflight_records`), a
+    dozen all start, and a bound of three holds the fourth back until a
+    result is written. Results land in source order either way."""
+    names = [f"slow-{i}" for i in range(12)]
+
+    async def main():
+        GatedProcessor.gate = asyncio.Event()
+        GatedProcessor.started = []
+        GatedProcessor.hint = hint
+        app = make_app(TRICKLE_PIPELINE)
+        runner = LocalApplicationRunner("test-app", app)
+        await runner.run()
+        try:
+            seen, produced = await _trickle(runner, names, started)
+            assert seen == names[:started]
+            await asyncio.sleep(0.2)
+            assert GatedProcessor.started == names[:started]  # the bound holds
+            GatedProcessor.gate.set()
+            for name in names[produced:]:
+                await runner.produce("in-t", name)
+            records = await runner.consume("out-t", 12, timeout=10)
+            assert [str(r.value) for r in records] == names
+        finally:
+            GatedProcessor.hint = None
+            await runner.stop()
+
+    run(main())
+
+
+def test_a_step_s_service_says_how_many_records_to_keep_in_flight():
+    """The hint's way up: the `tpu-serving` resource's ``inflight-records`` →
+    its provider → the completions step → the GenAI agent → a fused chain."""
+    from langstream_tpu.agents.genai.agent import GenAIToolKitAgent
+    from langstream_tpu.agents.genai.steps import Step
+    from langstream_tpu.ai.tpu_serving import TpuServingProvider
+    from langstream_tpu.runtime.composite import CompositeAgentProcessor
+
+    assert TpuServingProvider({"model": "tiny-test"}).inflight_records is None
+    assert TpuServingProvider({"model": "tiny-test", "inflight-records": 160}).inflight_records == 160
+
+    class Hinting(Step):
+        def __init__(self, n):
+            super().__init__({})
+            self.n = n
+
+        async def process(self, record, context):
+            pass
+
+        def inflight_records(self):
+            return self.n
+
+    agent, plain = GenAIToolKitAgent("compute"), GenAIToolKitAgent("compute")
+    agent.steps, plain.steps = [Hinting(None), Hinting(160), Hinting(32)], [Hinting(None)]
+    assert agent.inflight_records() == 160 and plain.inflight_records() is None
+    assert CompositeAgentProcessor([plain, agent]).inflight_records() == 160
+    assert CompositeAgentProcessor([plain]).inflight_records() is None
+
+    # the completions step asks its provider when it starts
+    import types
+
+    from langstream_tpu.agents.genai.completions import ChatCompletionsStep
+
+    class Anything:
+        def __getattr__(self, _):
+            return lambda *a, **k: Anything()
+
+    provider = TpuServingProvider({"model": "tiny-test", "inflight-records": 160})
+    context = types.SimpleNamespace(
+        get_service_provider_registry=lambda: types.SimpleNamespace(get_provider=lambda _: provider),
+        get_metrics_reporter=Anything, get_global_agent_id=lambda: "chat",
+    )
+    step = ChatCompletionsStep({"ai-service": "tpu", "model": "tiny-test"})
+    assert step.inflight_records() is None
+    asyncio.run(step.start(context))
+    assert step.inflight_records() == 160
+

@@ -182,6 +182,40 @@ SDAR = dataclasses.replace(
 )
 
 
+# Keye-VL-2.0-30B-A3B's language model as the benchmark cuts it
+# (`tiny-sparse-moe-test`'s block at the published widths): 32 Q / 4 KV heads
+# x 128, an indexer of 16 heads x 64 that keeps 2,048 tokens, 128 experts of
+# 2048 x 768 in int8, 12 layers, the whole vocabulary; the cell: 8 slots x
+# 272 pages
+KEYE = dataclasses.replace(
+    MODEL_PRESETS["tiny-sparse-moe-test"], name="keye-widths", d_model=2048, d_ff=6144,
+    moe_d_ff=768, n_layers=12, n_heads=32, n_kv_heads=4, head_dim=128, n_experts=128,
+    n_experts_per_tok=8, experts_held=(0, 128), vocab_size=151936, index_n_heads=16,
+    index_head_dim=64, index_topk=2048, mrope_section=(16, 24, 24), max_seq_len=262144,
+)
+
+
+def _index_scores(config, s, t):
+    """The indexer's scores of a segment, in tiles."""
+    hi, di = config.index_n_heads, config.index_head_dim
+    return (
+        lambda q, w, k, offsets: A.index_scores(q, w, k, offsets),
+        (SDS((1, s, hi, di), jnp.bfloat16), SDS((1, s, hi), jnp.float32),
+         SDS((1, t, di), jnp.bfloat16), SDS((1,), jnp.int32)),
+    )
+
+
+def _sparse_segment(config, s, t):
+    """A segment's attention under a packed selection."""
+    fn, (q, k, v, offsets) = _segment(config, s, t, 0)
+    return (
+        lambda q, k, v, offsets, chosen: A.sparse_segment_attention(
+            q, k, v, offsets, chosen, config
+        ),
+        (q, k, v, offsets, SDS((1, s, t), jnp.int8)),
+    )
+
+
 def _paged_block(config, batch, table, pages, layers):
     """A block pass's attention: `block_length` queries a row against the
     row's pages, one walk for all of them."""
@@ -295,6 +329,15 @@ CASES = {
     "sdar-gate-up-grouped-matmul-256": _gate_up(SDAR, 256, 12),
     "sdar-grouped-matmul-2048": _grouped(SDAR, 2048, 12),
     "sdar-down-grouped-matmul-2048": _grouped(SDAR, 2048, 12, down=True),
+    # the Keye cell: a 2048-token segment against the row's 17,408 columns,
+    # its indexer's scores in tiles and its walk under the packed selection,
+    # and the check's chain from offset 0: at its width, 2,432, and at 4,608
+    "keye-index-scores-2048": _index_scores(KEYE, 2048, 17408),
+    "keye-sparse-segment-2048": _sparse_segment(KEYE, 2048, 17408),
+    "keye-index-scores-4608": _index_scores(KEYE, 4608, 4608),
+    "keye-sparse-segment-4608": _sparse_segment(KEYE, 4608, 4608),
+    "keye-index-scores-2432": _index_scores(KEYE, 2432, 2432),
+    "keye-sparse-segment-2432": _sparse_segment(KEYE, 2432, 2432),
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
     **{f"gemma-prefill-{s}": _prefill(GEMMA, s) for s in (512, 1024, 2048)},
@@ -354,6 +397,8 @@ def _kernel_of(case: str) -> str:
         "grouped-matmul": "moe_grouped_matmul",
         "down-grouped-matmul": "moe_grouped_matmul",
         "gate-up-grouped-matmul": "moe_grouped_matmul",
+        "index-scores": "index_scores",
+        "sparse-segment": "sparse_segment_attention",
     }[kind]
 
 
@@ -737,3 +782,71 @@ def test_block_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatch
     )
     assert held <= V5E_HBM_BYTES
 
+
+
+@pytest.mark.parametrize("program", ["_paged_decode_chunk", "_paged_segment_and_sample"])
+def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatch, program):
+    """The Keye cell's two device programs whole, at its sizes (8 slots x 272
+    pages of a 2,176-page pool with the indexer's keys as a third leaf; a
+    decode chunk, and a 2,048-token segment against 17,408 columns), int8
+    weights and the pool donated. The decode step holds no operand of a row's
+    whole table of K or V (the selected read is a gather of index_topk rows a
+    row), the segment never forms scores of [S, heads, T], and each fits the
+    chip beside its state."""
+    from langstream_tpu.models.quant import init_random_quantized_params
+    from langstream_tpu.models.transformer import make_page_pool
+    from langstream_tpu.serving import engine as E
+
+    slots, pages, table, seg = 8, 2176, 272, 2048
+    t = table * PAGE
+    key = SDS((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: init_random_quantized_params(KEYE, k), key)
+    pool = jax.eval_shape(lambda: make_page_pool(KEYE, pages, PAGE))
+    assert pool["ik"].shape == (12, pages, PAGE, 128)  # 64 kept at a whole lane row
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    if program == "_paged_decode_chunk":
+        args = (params, i32(slots), i32(slots), pool, i32(slots, table), key,
+                f32(slots), i32(slots), f32(slots))
+        static = (8, KEYE, PAGE)
+        kernels = ("ragged_paged_decode_attention", "paged_kv_write", "moe_grouped_matmul")
+        path = f"paged-decode-sparse[s=1,t={t}]"
+    else:
+        args = (params, i32(1, seg), i32(1), i32(1), pool, i32(1, table), key,
+                f32(1), i32(1), f32(1))
+        static = (KEYE, PAGE)
+        kernels = (
+            "flash_segment_attention", "sparse_segment_attention", "index_scores",
+            "moe_grouped_matmul",
+        )
+        path = f"paged-segment-sparse[s={seg},t={t}]"
+    compiled = _compile_as_on_chip(
+        monkeypatch, getattr(E, program), _placed(args, SingleDeviceSharding(v5e[0])), static
+    )
+    text = compiled.as_text()
+    assert path in A.attention_paths()
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    h, hkv, d = KEYE.n_heads, KEYE.n_kv_heads, KEYE.resolved_head_dim
+    if program == "_paged_decode_chunk":
+        # K and V of a row's whole table, in either order of heads and columns
+        for shape in ([slots, hkv, t, d], [slots, t, hkv, d], [slots, table, hkv, PAGE, d]):
+            assert "[" + ",".join(map(str, shape)) + "]" not in text, shape
+        assert "[" + ",".join(map(str, [slots, KEYE.index_topk, hkv, d])) + "]" in text
+    else:
+        for heads in (h, hkv, KEYE.index_n_heads):
+            for shape in ([1, seg, heads, t], [1, heads, seg, t], [seg, heads, t], [heads, seg, t]):
+                assert "[" + ",".join(map(str, shape)) + "]" not in text, shape
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pool, updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    assert held <= V5E_HBM_BYTES
+    # no leaf of the pool is relaid or copied: at a width of 64 the compiler
+    # laid the indexer's keys out pages-minor and copied the whole leaf every
+    # layer and step (PERF.md section 6, PR 43)
+    for leaf in pool.values():
+        dims = re.escape("[" + ",".join(map(str, leaf.shape)) + "]")
+        assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text), leaf.shape
