@@ -4,12 +4,21 @@ no warm-up: only the check's shapes compile), `check.run_check` over it, and
 the rows of `compared` printed. By hand, through the chip tool; not part of
 the benchmark's command.
 
-    python3 dev/keye_check_faults.py [--tiny] [--samples W:n,n,n;W:n,n,n] [case ...]
+    python3 dev/keye_check_faults.py [--tiny] [--samples W:n,n,n;W:n,n,n]
+        [--check-seeds n,n] [--new-tokens n] [case ...]
 
 (`--tiny`: the test-size configuration and cell of `benchmark/tests/data`, a
 rehearsal on the CPU. `--samples`: widths with their prompt lengths in place
 of the file's, tried in turn by the first case until one fits the device:
-the check holds three float32 `[width, vocabulary]` arrays beside the engine.)
+the check holds three float32 `[width, vocabulary]` arrays beside the engine.
+`--check-seeds`: every case once a seed, each in place of the file's
+`check_seed`, which draws the sample's token ids: other prompts of the same
+lengths. A case's line then holds the hot path's error at each of the
+engine's positions, `hot_err_by_position`, a tie-exposed one negative: two
+commits' lines side by side say whether a moved median is a shift of every
+position or the scatter of a few, PERF.md section 6, PR 44. `--new-tokens`:
+tokens generated a sequence in place of the file's 8, for more such positions;
+the prompts of `--samples` must leave them room under the width.)
 
 Every case's per-position numbers go to `chiprun_out/keye_scores/<case>.npz`
 with the RAW gap between a query's topk-th and next indexer score in the
@@ -18,7 +27,11 @@ the harness judges: a query under the file's `eps_select` reads 0, every other
 infinity), so any `eps_select` and tolerance can be judged again from the
 files by `check._judge`, with no chip: `rejudge()`.
 
-Cases. `sound`. The selection (the engine, the chain and the hot path all run
+Cases. `sound`. `gather`: sound, with a decode step's selected read held to
+`_sparse_decode_attention` where the kernels would walk the row's pages under
+the mask (the rule's other side, the read of PR 43): the same selection read
+another way, so what differs from `sound` is the read's rounding alone. The
+selection (the engine, the chain and the hot path all run
 the fault; the reference keeps the file's arithmetic): `recent-keys` (the most
 RECENT top-k keys in place of the ranked ones), `dense` (no selection: every
 query attends to all it sees), `half-topk` (top-k halved), `bf16-scores` (the
@@ -58,9 +71,10 @@ from langstream_tpu.models import transformer as T  # noqa: E402
 from langstream_tpu.serving import engine as E  # noqa: E402
 
 CONFIG, CELL = "keye-vl-2.0-30b-a3b-int8-d12", "keyevl2-d12-longdoc-drain"
-CASES = ("sound", "recent-keys", "dense", "half-topk", "bf16-scores", "own-columns", "ik8",
+CASES = ("sound", "gather", "recent-keys", "dense", "half-topk", "bf16-scores", "own-columns", "ik8",
          "expert-skipped", "bf16-router", "stale-ik")
 SKIPPED_EXPERT = 5
+WALK_TABLE_PER_TOPK = getattr(T, "_WALK_TABLE_PER_TOPK", None)
 SELECT, SCORES, WRITE, SELECTED, ROUTE_ALL = (
     T._select_mask, T._index_scores, T._write_index_key, T._selected_attention, T._route_all)
 
@@ -162,7 +176,7 @@ def own_columns(q, q_idx, w, k_idx_all, k_all, v_all, mask, positions, config, w
     return SELECTED(q, q_idx, w, k_idx_all, k_all, v_all, mask, positions, config, what)
 
 
-def main(cases: list[str], tiny: bool = False, samples=()) -> int:
+def main(cases: list[str], tiny: bool = False, samples=(), check_seeds=(), new_tokens=0) -> int:
     files = ROOT / "benchmark" / ("tests/data" if tiny else "")
     name, cell = ("tiny-keye", "tiny-keye-drain") if tiny else (CONFIG, CELL)
     spec = load_json("configs", name, files)
@@ -186,13 +200,16 @@ def main(cases: list[str], tiny: bool = False, samples=()) -> int:
 
     check._judge = keeping
     check.load_module = raw_select_gap(check.load_module)
-    for case in cases:
+    for case, check_seed in [(c, n) for n in (check_seeds or [None]) for c in cases]:
         T._select_mask, T._index_scores, T._write_index_key, T._selected_attention, T._route_all = (
             SELECT, SCORES, WRITE, SELECTED, ROUTE_ALL)
+        T._WALK_TABLE_PER_TOPK = WALK_TABLE_PER_TOPK
         # a config of its own name: the case is traced into programs of its own
         named = dataclasses.replace(config, name=f"{name}-{case}")
         served = params
-        if case == "recent-keys":
+        if case == "gather":
+            T._WALK_TABLE_PER_TOPK = 0
+        elif case == "recent-keys":
             T._select_mask = recent_keys
         elif case == "dense":
             named = dataclasses.replace(named, index_topk=knobs["max-seq-len"])
@@ -215,6 +232,10 @@ def main(cases: list[str], tiny: bool = False, samples=()) -> int:
         while True:
             width, lengths = samples[0]
             sized = {**spec, "check": {**spec["check"], "width": width, "lengths": lengths}}
+            if check_seed is not None:
+                sized["check"]["check_seed"] = check_seed
+            if new_tokens:
+                sized["check"]["new_tokens"] = new_tokens
             in_use = _bytes_in_use()
             # the first case finds the width that fits: its engine is warmed as
             # the provider warms the cell's, so that what fits here fits there
@@ -222,7 +243,8 @@ def main(cases: list[str], tiny: bool = False, samples=()) -> int:
                 named, served, max_batch=knobs["max-batch"], max_seq_len=knobs["max-seq-len"],
                 prefill_buckets=tuple(knobs["prefill-buckets"]), kv_pages=knobs["kv-pages"],
                 page_size=knobs.get("page-size", 64), prefill_batch=knobs.get("prefill-batch", 1),
-                precompile=case == cases[0] and len(samples) > 1,
+                precompile=(case, check_seed) == (cases[0], (check_seeds or [None])[0])
+                and len(samples) > 1,
             )
             engine.start()
             engine.wait_ready()
@@ -246,17 +268,19 @@ def main(cases: list[str], tiny: bool = False, samples=()) -> int:
             say(case=case, width=width, does_not_fit=does_not_fit, bytes_in_use_before=in_use,
                 bytes_in_use_after=_bytes_in_use())
             samples.pop(0)
-        verdict.pop("hot_err_by_position", None)
+        by_position = verdict.pop("hot_err_by_position", None)
         peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0) for d in jax.devices())
         say(case=case, width=width, lengths=lengths, seconds=round(time.monotonic() - t, 1),
             ok=verdict["ok"], compared=verdict["compared"], memory_peak_bytes=peak,
             bytes_in_use_before=in_use,
+            **({} if check_seed is None else {
+                "check_seed": check_seed, "hot_err_by_position": by_position}),
             **{k: v for k, v in verdict.items() if isinstance(v, (int, float)) and k != "ok"})
         scores = kept.pop("scores", None)
         if scores is None:  # a check that ended before it judged
             continue
         np.savez_compressed(
-            out / f"{case}.npz",
+            out / (f"{case}.npz" if check_seed is None else f"{case}-{check_seed}.npz"),
             **{f"{i}.{j}.{k}": v for i, passes in enumerate(scores)
                for j, s in enumerate(passes) for k, v in s.items()})
         # the worst pairs, by (sequence, chain step, position): which half, where, how
@@ -279,6 +303,8 @@ if __name__ == "__main__":
     parser = argparse.ArgumentParser()
     parser.add_argument("--tiny", action="store_true")
     parser.add_argument("--samples", default="", help="W:n,n,n;W:n,n,n, tried in turn")
+    parser.add_argument("--check-seeds", default="", help="n,n: each in place of the file's")
+    parser.add_argument("--new-tokens", type=int, default=0, help="in place of the file's")
     parser.add_argument("cases", nargs="*", metavar="case", help=f"of {CASES}; none: all")
     args = parser.parse_args()
     if set(args.cases) - set(CASES):
@@ -287,4 +313,5 @@ if __name__ == "__main__":
         (int(w), [int(n) for n in ns.split(",")])
         for w, ns in (part.split(":") for part in args.samples.split(";") if part)
     ]
-    raise SystemExit(main(args.cases or list(CASES), args.tiny, samples))
+    seeds = [int(n) for n in args.check_seeds.split(",") if n]
+    raise SystemExit(main(args.cases or list(CASES), args.tiny, samples, seeds, args.new_tokens))

@@ -908,23 +908,32 @@ def _selected_attention(q, q_idx, w, k_idx_all, k_all, v_all, mask, positions, c
         return attention(q, k_all, v_all, chosen, config)
 
 
+def _decode_index_scores(q_idx, w, pik, table, layer, config, page_size):
+    """[B, T] float32: one query a row scores every column of its table. The
+    row's cached indexer keys are read by whole pages (8 KiB a page and
+    layer at 64 x 64 bf16)."""
+    b, t = table.shape[0], table.shape[1] * page_size
+    with jax.named_scope("attention.index"), jax.named_scope("attention.index.scores"):
+        k_idx = pik.at[layer, table].get(mode="clip").reshape(b, t, -1)[..., :q_idx.shape[-1]]
+        return _index_scores(q_idx[:, None], w[:, None], k_idx, None, config)[:, 0]
+
+
 def _sparse_decode_attention(
     q, q_idx, w, pk, pv, pik, table, layer, lengths, config, page_size
 ):
-    """One query a row under the selection, through the page table → [B, H*D].
-    The row's cached indexer keys are read by whole pages (8 KiB a page and
-    layer at 64 x 64 bf16), scored and ranked (`lax.top_k`: the indices are
-    what the read needs), and ONLY the selected tokens' K and V rows are
-    gathered, [B, topk, Hkv, D]: the bytes follow ``min(length, topk)`` a
-    row, never its length. A row of length 0 (idle, or one the dense kernel
-    serves) comes back zeros."""
+    """One query a row under the selection, through the page table → [B, H*D],
+    by a GATHER of what it selected: the row's columns are scored and ranked
+    (`lax.top_k`: the indices are what the read needs), and ONLY the
+    selected tokens' K and V rows are gathered, [B, topk, Hkv, D]: the bytes
+    follow ``min(length, topk)`` a row, never its length. The read of a table
+    too long to walk and of every backend without the kernels
+    (`_paged_selected_read` has the rule). A row of length 0 (idle) comes
+    back zeros."""
     b, h, d = q.shape
     hkv = pk.shape[2]
     t = table.shape[1] * page_size
     k = min(config.index_topk, t)
-    with jax.named_scope("attention.index"), jax.named_scope("attention.index.scores"):
-        k_idx = pik.at[layer, table].get(mode="clip").reshape(b, t, -1)[..., :q_idx.shape[-1]]
-        scores = _index_scores(q_idx[:, None], w[:, None], k_idx, None, config)[:, 0]
+    scores = _decode_index_scores(q_idx, w, pik, table, layer, config, page_size)
     with jax.named_scope("attention.select"):
         visible = jnp.arange(t)[None, :] < lengths[:, None]
         _, chosen = lax.top_k(jnp.where(visible, scores, -jnp.inf), k)  # [B, k]
@@ -1009,11 +1018,13 @@ def _attention_block(
     S rows of one aligned tile. A model with an indexer
     (``config.has_indexer``) carries a third pool leaf in ``cache_kv``, the
     indexer's keys, written in ``kv_pool.write`` with K and V; its decode
-    step reads the selected tokens alone (`_sparse_decode_attention`; a row
-    of no more than ``index_topk`` tokens the dense kernel, where it runs)
-    and its segment goes through ``flash_segment_attention`` over the row's
-    gathered columns, under the selection once a query sees more than
-    ``index_topk`` keys (`_selected_attention`)."""
+    step attends to the selected tokens alone (`_paged_selected_read`: the
+    decode kernel's walk under the selection as a mask where it runs, a
+    gather of the selected rows elsewhere and past 16 x ``index_topk``
+    columns of table) and its segment goes through
+    ``flash_segment_attention`` over the row's gathered columns, under the
+    selection once a query sees more than ``index_topk`` keys
+    (`_selected_attention`)."""
     if paged_table is None:
         with jax.named_scope("attention"):
             return _dense_attention(
@@ -1124,15 +1135,44 @@ def _attention_block(
     return x, (pk, pv, *pik)
 
 
+# Columns of table a selected token: up to here a decode step WALKS the row's
+# pages under its selection as a mask, past it it gathers the selected rows.
+# Two prices of one read, a layer, measured on a v5e at 8 rows x 4 KV heads
+# of 128 and a top-k of 2,048 over tables of 8.5, 17 and 34 x the top-k
+# (`dev/bench_selected_read.py`; PERF.md section 6, PRs 43 and 44). The
+# gather pays 10 ns a (token, KV head) row of 256 B whatever a row holds, and
+# `lax.top_k` over the table: 1.64 / 1.91 / 2.76 ms with the indexer's scores.
+# The walk pays the row's LENGTH at the decode kernel's rate, 0.05 ms + 4.8 ns
+# a token (380-415 GB/s of 2 KiB a token), and `_select_mask`'s counts: with
+# the scores 0.86 ms at 17,408 tokens a row, 1.66 at 34,816, 3.55 at 69,632.
+# Rows that fill their table cross at 21 x the top-k (the walk 13% ahead at
+# 17 x, 29% behind at 34 x; rows half as long as a table of 34 x still walk
+# 20% ahead). The program sees the table, not the lengths to come: at 16 the
+# walk is never behind. No benchmark cell has a table past it.
+_WALK_TABLE_PER_TOPK = 16
+
+# What `attention_paths()` says under "paged-decode-sparse[..]" where the walk
+# was traced: the HARNESS's string (benchmark/families/keye_vl2.py
+# `expected_kernels` holds a chip run to it, letter for letter, and only a
+# `benchmark` PR may edit it there), kept as the key it is and no longer a
+# description. What was traced is under "paged-decode-selected[..]".
+_WALK_LABEL = "ragged_paged_decode_attention to index_topk, xla top_k + gather past it"
+
+
 def _paged_selected_read(
     q, q_idx, w_idx, pk, pv, pik, table, layer, mask, positions, config,
     page_size, decode_kernels,
 ):
-    """The paged read of a model with an indexer → [B, S, H*D]. S = 1: rows
-    of no more than ``index_topk`` tokens take the dense decode kernel as
-    every model does (where it runs; the selection is the identity there),
-    longer rows `_sparse_decode_attention`, skipped whole while no row is
-    long. S > 1: the row's columns gathered through the table and
+    """The paged read of a model with an indexer → [B, S, H*D]. S = 1, where
+    the decode kernels run and the table holds no more than
+    ``_WALK_TABLE_PER_TOPK x index_topk`` columns: ONE call of the paged
+    decode kernel under each row's selection as a mask over its pages
+    (`ragged_paged_selected_attention`; `_select_mask`'s set is `lax.top_k`'s,
+    and the identity for a row of no more than ``index_topk`` tokens), the
+    scores and the ranking skipped whole while no row is past ``index_topk``.
+    A longer table, and every backend without the kernels:
+    `_sparse_decode_attention`, the same selection read by a gather. S > 1:
+    the row's columns gathered through the table and
     ``flash_segment_attention`` while no query sees more than ``index_topk``
     keys, `_selected_attention` once one does."""
     from langstream_tpu.ops import attention as ops
@@ -1143,28 +1183,31 @@ def _paged_selected_read(
     interpret = jax.default_backend() != "tpu"
     if s == 1:
         lengths = _paged_lengths(table, positions[:, 0], page_size, pk.shape[1])
-        sparse = functools.partial(
-            _sparse_decode_attention, q[:, 0], q_idx[:, 0], w_idx[:, 0], pk, pv, pik,
-            table, layer, config=config, page_size=page_size,
-        )
-        if not decode_kernels:
+        if not decode_kernels or t > _WALK_TABLE_PER_TOPK * topk:
             ops.note_path("paged-decode-sparse", "xla top_k + gather", config, s=s, t=t)
-            return sparse(lengths=lengths)[:, None, :]
-        long = lengths > topk
+            return _sparse_decode_attention(
+                q[:, 0], q_idx[:, 0], w_idx[:, 0], pk, pv, pik, table, layer,
+                lengths, config, page_size,
+            )[:, None, :]
+        ops.note_path("paged-decode-sparse", _WALK_LABEL, config, s=s, t=t)
         ops.note_path(
-            "paged-decode-sparse",
-            "ragged_paged_decode_attention to index_topk, xla top_k + gather past it",
-            config, s=s, t=t,
+            "paged-decode-selected", "ragged_paged_selected_attention", config, s=s, t=t
         )
-        dense = ops.ragged_paged_decode_attention(
-            q[:, 0], pk, pv, jnp.where(long, 0, lengths), table, layer, config,
-            page_size, interpret=interpret,
-        )
-        picked = lax.cond(
-            jnp.any(long), lambda: sparse(lengths=jnp.where(long, lengths, 0)),
-            lambda: jnp.zeros_like(dense),
-        )
-        return jnp.where(long[:, None], picked, dense)[:, None, :]
+        visible = jnp.arange(t)[None, :] < lengths[:, None]
+
+        def ranked():
+            scores = _decode_index_scores(
+                q_idx[:, 0], w_idx[:, 0], pik, table, layer, config, page_size
+            )
+            with jax.named_scope("attention.select"):
+                return _select_mask(scores, visible, topk)
+
+        chosen = lax.cond(jnp.any(lengths > topk), ranked, lambda: visible)
+        with jax.named_scope("attention.sparse"):
+            return ops.ragged_paged_selected_attention(
+                q[:, 0], pk, pv, lengths, table, layer, chosen, config, page_size,
+                interpret=interpret,
+            )[:, None, :]
     k_all = _paged_gather(pk, layer, table, page_size)
     v_all = _paged_gather(pv, layer, table, page_size)
     with jax.named_scope("attention.index"):

@@ -610,6 +610,22 @@ def index_scores(
 # `_page_int8`). The masked-jnp fallback (gather through (layer, table),
 # then the stock attention math) lives in models/transformer._paged_gather
 # and carries tier-1 exactness.
+# A model with an indexer reads a SELECTION of the row's tokens, and the
+# skeleton takes it as one more optional row block, a mask over the row's
+# pages (`ragged_paged_selected_attention`): the walk's bytes follow the
+# row's length where the selection's follow its top-k, but a page is what a
+# DMA can take from this pool. The decode read is that masked walk up to
+# 16 x top-k columns of table and an XLA gather of the selected rows past it
+# (models/transformer `_paged_selected_read` has the rule). The two prices,
+# a layer on a v5e at 8 rows x 4 KV heads x 128 (PERF.md section 6, PR 44):
+# the walk 0.05 ms + 4.8 ns a token of context (0.54 ms at 12,533 tokens a
+# row, 380 GB/s of the K and V it walks; the mask adds under 1%); the gather
+# 10 ns a (token, head) row of 256 B, 1.3 ms at a top-k of 2,048 whatever
+# the context, and `lax.top_k` over the table. With scores and ranking, rows
+# that fill their table cross at 21 x the top-k (walk 13% ahead at 17 x, 29%
+# behind at 34 x). Without the operand nothing of it is traced: the other
+# entry points hand Mosaic the modules they did (tests/test_tpu_compile.py
+# pins them, and their models' decode programs whole).
 # ---------------------------------------------------------------------------
 
 
@@ -651,8 +667,8 @@ _PAGE_SLOTS = 4
 
 def _paged_decode_kernel(
     lengths_ref,  # scalar-prefetch [B]
-    *refs,  # [lower_ref], pages_ref, q_ref, n_scales row blocks, n_leaves
-    # pool leaves in HBM, o_ref, scratch
+    *refs,  # [lower_ref], pages_ref, q_ref, n_scales row blocks, [chosen_ref],
+    # n_leaves pool leaves in HBM, o_ref, scratch
     load,  # _page_bf16 | _page_int8
     n_scales: int,
     n_leaves: int,
@@ -661,11 +677,14 @@ def _paged_decode_kernel(
     scale: float,
     softcap,
     windowed: bool = False,
+    selected: bool = False,
 ):
     # a window layer's rows read [lower, length): one more prefetched vector
     lower_ref = refs[0] if windowed else None
     pages_ref, q_ref, *refs = refs[1:] if windowed else refs
     scales, refs = refs[:n_scales], refs[n_scales:]
+    if selected:  # the row's selection [1, Tp, 1, ps], 1.0 where a token is read
+        chosen_ref, *refs = refs
     pool, o_ref = refs[:n_leaves], refs[n_leaves]
     bufs = refs[n_leaves + 1: 2 * n_leaves + 1]  # per leaf [_PAGE_SLOTS, a page]
     sems, walk = refs[2 * n_leaves + 1:]
@@ -742,6 +761,8 @@ def _paged_decode_kernel(
         s = jnp.where(k_pos < length, s, _NEG)  # the last page's tail
         if windowed:  # and the first page's head
             s = jnp.where(k_pos >= lower_ref[b], s, _NEG)
+        if selected:  # and every token the row's query did not choose
+            s = jnp.where(chosen_ref[0, j][None] > 0, s, _NEG)
 
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -792,14 +813,18 @@ def _paged_decode_call(
     lengths: jax.Array, table: jax.Array, layer: jax.Array,
     config: ModelConfig, page_size: int, interpret: bool,
     lower: jax.Array | None = None,
+    chosen: jax.Array | None = None,
 ) -> jax.Array:
-    """The one `pallas_call` of both paged kernels. ``leaves`` are the
+    """The one `pallas_call` of the paged kernels. ``leaves`` are the
     pool's arrays [L, P, Hkv, ps, D] whose pages the kernel fetches itself;
     ``scales`` are the int8 pool's [L, P, Hkv, ps], which reach it as each
     row's own [Tp, Hkv, ps] block, gathered through (layer, table) here: a
     page of them is [Hkv, ps < 128] f32, which Mosaic cannot slice out of
     HBM (the minor dimension is narrower than a tile), and they are 1/32 of
-    the pool's bytes."""
+    the pool's bytes. ``chosen`` [B, Tp x ps] bool is a row's selection
+    among its columns: it rides as one more row block, float32
+    [Tp, 1, ps] (the scales' form: a page of it is ``ref[0, j]``), and a
+    column it leaves out is masked like one past the row's length."""
     b, h, d = q.shape
     tp = table.shape[1]
     hkv = leaves[0].shape[2]
@@ -814,6 +839,7 @@ def _paged_decode_call(
         scale=1.0 / (d**0.5),
         softcap=config.attn_logit_softcap,
         windowed=lower is not None,
+        selected=chosen is not None,
     )
     bounds = [lengths.astype(jnp.int32)]
     if lower is not None:
@@ -823,6 +849,7 @@ def _paged_decode_call(
         grid=(b,),
         in_specs=[pl.BlockSpec((1, hkv, group, d), _paged_row_index)]
         + [pl.BlockSpec((1, tp, hkv, page_size), _paged_row_index)] * len(scales)
+        + [pl.BlockSpec((1, tp, 1, page_size), _paged_row_index)] * (chosen is not None)
         + [pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)] * len(leaves),
         out_specs=pl.BlockSpec((1, hkv, group, d), _paged_row_index),
         scratch_shapes=[
@@ -847,6 +874,8 @@ def _paged_decode_call(
         _layer_pages(table, layer, leaves[0].shape[1]),
         q.reshape(b, hkv, group, d),
         *(leaf.at[layer, table].get(mode="clip") for leaf in scales),
+        *([] if chosen is None
+          else [chosen.astype(jnp.float32).reshape(b, tp, 1, page_size)]),
         *(_flat_pool(leaf) for leaf in leaves),
     )
     return out.reshape(b, h * d)
@@ -907,6 +936,35 @@ def ragged_paged_block_attention(
     )
     out = out.reshape(b, hkv, s, group, d).transpose(0, 2, 1, 3, 4)
     return out.reshape(b, s, h * d)
+
+
+@_per_kv_head(4, kv_head_axis=2)
+def ragged_paged_selected_attention(
+    q: jax.Array,  # [B, H, D] single query per row
+    k: jax.Array,  # the page pool [L, P, Hkv, ps, D], read at `layer`
+    v: jax.Array,
+    lengths: jax.Array,  # [B] valid logical columns per row; 0 = no work
+    table: jax.Array,  # [B, Tp]
+    layer: jax.Array,
+    chosen: jax.Array,  # [B, Tp x ps] bool: the columns the row's query reads
+    config: ModelConfig,
+    page_size: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """`ragged_paged_decode_attention` under a row's SELECTION → [B, H*D]:
+    the row walks its ``cdiv(length, page_size)`` pages as ever and attends
+    to the columns of ``chosen`` alone, one selection for all heads (a model
+    with an indexer, models/transformer `_paged_selected_read`). The bytes
+    follow the row's length, not the selection's size: the pool gives a DMA
+    whole pages, and a gather of the selected tokens' 256 B rows costs 10 ns
+    a row whatever it holds, so the walk wins up to some 21 x ``index_topk``
+    tokens of context (the rule and both prices: `_paged_selected_read`). A
+    page with nothing chosen adds nothing; a row with nothing chosen comes
+    back zeros. Under its own name on the `pallas_call`."""
+    return _paged_decode_call(
+        "ragged_paged_selected_attention", _page_bf16, q, [k, v], [], lengths,
+        table, layer, config, page_size, interpret, chosen=chosen,
+    )
 
 
 @_per_kv_head(3, kv_head_axis=2)

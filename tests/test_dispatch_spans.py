@@ -623,10 +623,18 @@ def test_engine_reports_moe_counts_on_spans_and_in_stats(moe_params):
 
 def lowered_scopes(fn, *args) -> set:
     """Every name-stack component in the lowered program's locations
-    (`loc("attention/dot_general")`, nested calls each with their own)."""
+    (`loc("attention/dot_general")`, nested calls each with their own). A
+    location that names a FRAME of the traceback (`loc("block_choice"(#loc3))`
+    where `#loc3` is a file position) is no scope: a helper jitted once a
+    process keeps the frames of whoever traced it first, another test's
+    engine among them."""
     text = jax.jit(fn).lower(*args).as_text(debug_info=True)
+    positions = set(re.findall(r'^(#loc\d+) = loc\("[^"]+":\d+:\d+', text, re.M))
     return {
-        part for loc in re.findall(r'loc\("([^"]+)"', text) for part in loc.split("/")
+        part
+        for loc, inner in re.findall(r'loc\("([^"]+)"(?:\((#loc\d+)\))?', text)
+        if inner not in positions
+        for part in loc.split("/")
     }
 
 

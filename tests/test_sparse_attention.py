@@ -11,7 +11,8 @@ size, float32 on the CPU) against the plain reference of
       the rotary there is;
 (iv)  the ranking by counting against `lax.top_k`'s set, ties included;
 (v)   the kernels against their jnp (Pallas in interpret mode): the indexer's
-      scores in tiles, the segment walk under a packed selection;
+      scores in tiles, the segment walk under a packed selection, the decode
+      walk under a row's selection as a mask over its pages;
 (vi)  the page pool's third leaf: made, written where K and V are, inserted
       from a local cache.
 """
@@ -147,29 +148,48 @@ def test_the_selected_sets_are_the_references(params, tokens):
     assert (np.asarray(got).sum(-1) == np.minimum(np.arange(LENGTH) + 1, TINY.index_topk)).all()
 
 
-def _pool_and_tables(n_rows: int, config: ModelConfig = TINY):
-    per_row = 64 // PAGE
+def _pool_and_tables(n_rows: int, config: ModelConfig = TINY, per_row: int = 64 // PAGE):
     pool = T.make_page_pool(config, n_rows * per_row, PAGE)
     return pool, jnp.arange(n_rows * per_row, dtype=jnp.int32).reshape(n_rows, per_row)
 
 
-def test_prefill_then_decode_through_the_page_pool(params, tokens, want):
+# the decode step's three reads of one selection (`_paged_selected_read`):
+# without the kernels the gather; with them (interpret mode) the walk under
+# the mask, up to 16 x top-k columns of table; past that the gather again
+# (a table length each: `attention_paths()` is keyed by it, process-wide)
+DECODE_READS = {
+    "gather": ("auto", 8, "xla top_k + gather", None),
+    "walk": ("pallas", 9, T._WALK_LABEL, "ragged_paged_selected_attention"),
+    "gather-past-the-rule": ("pallas", 40, "xla top_k + gather", None),
+}
+
+
+@pytest.mark.parametrize("read", sorted(DECODE_READS))
+def test_prefill_then_decode_through_the_page_pool(params, tokens, want, read):
     """A prompt of 24 tokens (three times the top-k) through `prefill` and
     `paged_insert_cache`, then 16 decode steps through the table: each
-    step's logits are the reference's at that position."""
+    step's logits are the reference's at that position, by either read and
+    on both sides of the rule that chooses between them."""
+    impl, per_row, label, kernel = DECODE_READS[read]
+    config = dataclasses.replace(TINY, attention_impl=impl)
     n = 24
     logits, local = T.prefill(
         params, tokens[:, :n], jnp.full((2,), n, jnp.int32), T.make_kv_cache(TINY, 2, n), TINY
     )
     assert rel_err(logits, want[:, n - 1]) < SOUND
-    pool, tables = _pool_and_tables(2)
+    pool, tables = _pool_and_tables(2, per_row=per_row)
+    past = per_row * PAGE > T._WALK_TABLE_PER_TOPK * TINY.index_topk
+    assert past == (read == "gather-past-the-rule")
     pool = T.paged_insert_cache(pool, local, tables, PAGE, TINY)
     for position in range(n, LENGTH):
         logits, pool = T.paged_decode_step_inplace(
             params, tokens[:, position], jnp.full((2,), position, jnp.int32), pool, tables,
-            TINY, PAGE,
+            config, PAGE,
         )
         assert rel_err(logits, want[:, position]) < SOUND, position
+    paths, at = A.attention_paths(), f"[s=1,t={per_row * PAGE}]"
+    assert paths["paged-decode-sparse" + at] == label
+    assert paths.get("paged-decode-selected" + at) == kernel
 
 
 def test_a_prompt_chunked_into_segments_that_cross_the_topk(params, tokens, want):
@@ -307,15 +327,97 @@ def test_the_segment_walk_under_a_packed_selection_is_masked_attention():
     assert float(jnp.max(jnp.abs(got - want)[some])) < 1e-4
 
 
+# the decode walk under a selection: one batch a case, (rows' lengths, how
+# the rows' scores are drawn); 6 pages of 8 a row, a top-k of 8
+WALK_ROWS = {
+    "a-row-of-length-0": ([0, 29, 0], "normal"),
+    "under-the-topk": ([8, 5, 1], "normal"),  # the selection is the identity
+    "past-the-topk": ([48, 17, 33], "normal"),
+    "a-last-page-partly-filled": ([21, 43, 9], "normal"),
+    "a-page-with-nothing-selected": ([48, 40, 24], "second-page-low"),
+    "ties-at-the-threshold": ([48, 30, 12], "few-values"),
+}
+
+
+@pytest.mark.parametrize("heads", [(32, 4), (8, 2)], ids=lambda h: f"gqa{h[0]}-{h[1]}")
+@pytest.mark.parametrize("case", sorted(WALK_ROWS))
+def test_the_decode_walk_under_a_selection_is_masked_attention(case, heads):
+    """`ragged_paged_selected_attention` (interpret mode) against masked jnp
+    attention over the row's gathered pages, the mask `_select_mask`'s."""
+    lengths, draw = WALK_ROWS[case]
+    rng = np.random.default_rng(sorted(WALK_ROWS).index(case))
+    h, hkv = heads
+    b, tp, d, layers, topk, layer = len(lengths), 6, 16, 2, 8, 1
+    t = tp * PAGE
+    config = dataclasses.replace(TINY, n_heads=h, n_kv_heads=hkv, attention_impl="pallas")
+    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
+    pk, pv = (
+        jnp.asarray(rng.standard_normal((layers, b * tp + 1, hkv, PAGE, d)), jnp.float32)
+        for _ in range(2)
+    )
+    table = jnp.asarray(rng.permutation(b * tp).reshape(b, tp) + 1, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    visible = jnp.arange(t)[None, :] < lengths[:, None]
+    if draw == "few-values":
+        scores = rng.choice(np.asarray([-0.0, 0.0, 0.5, 0.5, 2.0], np.float32), (b, t))
+    else:
+        scores = rng.standard_normal((b, t)).astype(np.float32)
+    if draw == "second-page-low":
+        scores[:, PAGE:2 * PAGE] = -9.0
+    scores = jnp.asarray(scores) + 0.0
+    chosen = T._select_mask(scores, visible, topk)
+    # the set is lax.top_k's, ties and all
+    for row in range(b):
+        n = min(topk, int(lengths[row]))
+        top = jax.lax.top_k(jnp.where(visible[row], scores[row], -jnp.inf), topk)[1][:n]
+        assert set(np.flatnonzero(chosen[row])) == set(np.asarray(top).tolist())
+    if draw == "second-page-low":
+        assert not bool(chosen[:, PAGE:2 * PAGE].any())
+    if draw == "few-values":
+        kth = jnp.sort(jnp.where(visible, scores, -jnp.inf), axis=-1)[:, -topk]
+        assert bool(((scores == kth[:, None]) & visible & ~chosen).any())  # a tie cut
+    args = (q, pk, pv, lengths, table, jnp.int32(layer))
+    # what lies past a row's length is masked whatever the selection says of it
+    given = chosen | ~visible if case == "a-last-page-partly-filled" else chosen
+    got = A.ragged_paged_selected_attention(*args, given, config, PAGE, interpret=True)
+    k_all, v_all = (T._paged_gather(leaf, layer, table, PAGE) for leaf in (pk, pv))
+    want = T.attention(q[:, None], k_all, v_all, chosen[:, None, :], config)[:, 0]
+    live = np.asarray(lengths) > 0
+    assert got.shape == (b, h * d)
+    assert float(jnp.max(jnp.abs(got - want)[live])) < 1e-5
+    assert float(jnp.abs(got[~live]).max(initial=0.0)) == 0.0  # a row of no pages: zeros
+    if case == "under-the-topk":
+        plain = A.ragged_paged_decode_attention(*args, config, PAGE, interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(plain))
+
+
 def test_forward_through_the_kernels_is_the_references(params):
     """128 tokens, a multiple of the kernels' lane width: the scores in
-    tiles, the ranking, the walk under the selection (interpret mode)."""
+    tiles, the ranking, the walk under the selection (interpret mode); then
+    four decode steps through the page pool, whose read is the decode
+    kernel's walk under the selection as a mask."""
     config = dataclasses.replace(TINY, attention_impl="pallas", index_topk=32)
     dims = {**DIMS, "index_topk": 32}
-    row = jnp.asarray(np.random.default_rng(4).integers(1, 500, (128,)), jnp.int32)
-    got = T.forward(params, row[None], config)[0]
-    assert rel_err(got, ref.forward(params, row, dims)) < SOUND
+    row = jnp.asarray(np.random.default_rng(4).integers(1, 500, (132,)), jnp.int32)
+    want = ref.forward(params, row, dims)
+    got = T.forward(params, row[None, :128], config)[0]
+    assert rel_err(got, want[:128]) < SOUND
     assert A.attention_paths()["prefill-sparse[s=128,t=128]"] == "sparse_segment_attention"
+    _, local = T.prefill(
+        params, row[None, :128], jnp.full((1,), 128, jnp.int32),
+        T.make_kv_cache(config, 1, 128), config,
+    )
+    pool, tables = _pool_and_tables(1, config, per_row=24)
+    pool = T.paged_insert_cache(pool, local, tables, PAGE, config)
+    for position in range(128, 132):
+        logits, pool = T.paged_decode_step_inplace(
+            params, row[None, position], jnp.full((1,), position, jnp.int32), pool, tables,
+            config, PAGE,
+        )
+        assert rel_err(logits[0], want[position]) < SOUND, position
+    paths = A.attention_paths()
+    assert paths["paged-decode-selected[s=1,t=192]"] == "ragged_paged_selected_attention"
+    assert paths["paged-decode-sparse[s=1,t=192]"] == T._WALK_LABEL
 
 
 # -- (vi) the third leaf ---------------------------------------------------------------

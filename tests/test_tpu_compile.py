@@ -4,7 +4,9 @@ scoped-VMEM overflow, tiling, a Mosaic call GSPMD cannot partition — fails
 here, on the CPU tier, instead of on the chip. Nothing runs, so these say
 nothing about results or times (chip_smoke.py checks results on a chip)."""
 
+import base64
 import dataclasses
+import hashlib
 import os
 import re
 
@@ -253,6 +255,20 @@ def _windowed_decode(config, batch, table, pages, layers):
     )
 
 
+def _selected_decode(config, batch, table, pages, layers):
+    """The paged decode kernel under a row's selection: a mask over the
+    columns of its table beside its length."""
+    fn, (q, k, v, lengths, tab, layer) = _paged(
+        config, False, batch=batch, table=table, pages=pages, layers=layers
+    )
+    return (
+        lambda q, k, v, lengths, tab, layer, chosen: A.ragged_paged_selected_attention(
+            q, k, v, lengths, tab, layer, chosen, config, PAGE
+        ),
+        (q, k, v, lengths, tab, layer, SDS((batch, table * PAGE), jnp.bool_)),
+    )
+
+
 def _segment(config, s, t, window):
     """A prefill segment's attention over its row's gathered columns."""
     bf16 = lambda *shape: SDS(shape, jnp.bfloat16)  # noqa: E731
@@ -329,9 +345,11 @@ CASES = {
     "sdar-gate-up-grouped-matmul-256": _gate_up(SDAR, 256, 12),
     "sdar-grouped-matmul-2048": _grouped(SDAR, 2048, 12),
     "sdar-down-grouped-matmul-2048": _grouped(SDAR, 2048, 12, down=True),
-    # the Keye cell: a 2048-token segment against the row's 17,408 columns,
+    # the Keye cell: a decode step's walk of 8 rows x 272 pages under the
+    # selection as a mask, a 2048-token segment against the row's 17,408 columns,
     # its indexer's scores in tiles and its walk under the packed selection,
     # and the check's chain from offset 0: at its width, 2,432, and at 4,608
+    "keye8x272-selected-decode": _selected_decode(KEYE, 8, 272, 2176, 12),
     "keye-index-scores-2048": _index_scores(KEYE, 2048, 17408),
     "keye-sparse-segment-2048": _sparse_segment(KEYE, 2048, 17408),
     "keye-index-scores-4608": _index_scores(KEYE, 4608, 4608),
@@ -392,6 +410,7 @@ def _kernel_of(case: str) -> str:
         "paged-insert-pages": "paged_insert_pages",
         "gated-delta-update": "gated_delta_update",
         "windowed-decode": "ragged_paged_decode_attention",
+        "selected-decode": "ragged_paged_selected_attention",
         "segment": "flash_segment_attention",
         "window-segment": "flash_segment_attention",
         "grouped-matmul": "moe_grouped_matmul",
@@ -789,10 +808,10 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
     """The Keye cell's two device programs whole, at its sizes (8 slots x 272
     pages of a 2,176-page pool with the indexer's keys as a third leaf; a
     decode chunk, and a 2,048-token segment against 17,408 columns), int8
-    weights and the pool donated. The decode step holds no operand of a row's
-    whole table of K or V (the selected read is a gather of index_topk rows a
-    row), the segment never forms scores of [S, heads, T], and each fits the
-    chip beside its state."""
+    weights and the pool donated. The decode step's read is the paged decode
+    kernel under the selection as a mask: it holds no operand of a row's whole
+    table of K or V and no gather of index_topk rows a row; the segment never
+    forms scores of [S, heads, T]; and each fits the chip beside its state."""
     from langstream_tpu.models.quant import init_random_quantized_params
     from langstream_tpu.models.transformer import make_page_pool
     from langstream_tpu.serving import engine as E
@@ -808,8 +827,8 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         args = (params, i32(slots), i32(slots), pool, i32(slots, table), key,
                 f32(slots), i32(slots), f32(slots))
         static = (8, KEYE, PAGE)
-        kernels = ("ragged_paged_decode_attention", "paged_kv_write", "moe_grouped_matmul")
-        path = f"paged-decode-sparse[s=1,t={t}]"
+        kernels = ("ragged_paged_selected_attention", "paged_kv_write", "moe_grouped_matmul")
+        path = f"paged-decode-selected[s=1,t={t}]"
     else:
         args = (params, i32(1, seg), i32(1), i32(1), pool, i32(1, table), key,
                 f32(1), i32(1), f32(1))
@@ -831,7 +850,16 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         # K and V of a row's whole table, in either order of heads and columns
         for shape in ([slots, hkv, t, d], [slots, t, hkv, d], [slots, table, hkv, PAGE, d]):
             assert "[" + ",".join(map(str, shape)) + "]" not in text, shape
-        assert "[" + ",".join(map(str, [slots, KEYE.index_topk, hkv, d])) + "]" in text
+        assert "[" + ",".join(map(str, [slots, KEYE.index_topk, hkv, d])) + "]" not in text
+        assert not re.search(r"%ragged_paged_decode_attention(\.\d+)? = ", text)
+        # what was traced, and the harness's key with the harness's string
+        # (benchmark/families/keye_vl2.py `expected_kernels`), which guarded
+        # what the lines above now guard
+        paths = A.attention_paths()
+        assert paths[path] == "ragged_paged_selected_attention"
+        assert paths[f"paged-decode-sparse[s=1,t={t}]"] == (
+            "ragged_paged_decode_attention to index_topk, xla top_k + gather past it"
+        )
     else:
         for heads in (h, hkv, KEYE.index_n_heads):
             for shape in ([1, seg, heads, t], [1, heads, seg, t], [seg, heads, t], [heads, seg, t]):
@@ -850,3 +878,127 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
     for leaf in pool.values():
         dims = re.escape("[" + ",".join(map(str, leaf.shape)) + "]")
         assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text), leaf.shape
+
+
+# ---------------------------------------------------------------------------
+# The paged decode skeleton is shared: the selection is a static option of it,
+# and without one nothing of it is traced. Two pins of that, both the text the
+# parent gave (commit b2c5b1e, PR 43), both taken by the code below, in this
+# file (its autouse fixture sets the matmul precision a chip process has):
+#
+# 1. the KERNELS alone, for the described v5e: the Mosaic module each shared
+#    entry hands the chip's compiler, at its cell's sizes, as text without
+#    debug locations (a line that moves in ops/attention.py moves none of it).
+#    This is what a change to `_paged_decode_kernel` / `_paged_decode_call`
+#    must hold still for the models it does not mean to touch;
+# 2. the other models' decode programs whole (Mistral's block plain and over
+#    an int8 pool, Mixtral's, Olmo-Hybrid's, command-a-plus's with its window
+#    bound, SDAR's block pass), lowered for the CPU with the kernels in
+#    interpret mode (ISSUE 44's acceptance). These six cover every line of a
+#    decode chunk, so a PR that changes a model's step ON PURPOSE, or a JAX
+#    bump, moves them for reasons the kernels have no part in: such a PR
+#    re-takes the hashes (the failure prints the new one) and says why in
+#    CHANGES.md. A PR that did not mean to change these programs does not.
+# ---------------------------------------------------------------------------
+
+KERNEL_BODIES_AT_PARENT = {
+    "chat64x20-paged-decode": "178024633ae8f3d4",
+    "docs16x33-paged-decode-int8": "6b6427585ace5e9d",
+    "drain64x10-paged-decode": "a5c6d968d9af6508",
+    "cmdaplus16x196-paged-decode": "c0e5b8ef23935735",
+    "cmdaplus16x196-windowed-decode": "3bdeae2c481e0a2d",
+    "sdardrain64x11-paged-block": "a1141be57f9003ff",
+    "olmodrain40x10-paged-decode": "e3d02f9b3ac55bc2",
+}
+
+
+def _kernel_bodies(fn, args, device) -> list[str]:
+    """The Mosaic module of every `pallas_call` of ``fn`` lowered for
+    ``device``, as text without debug locations."""
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    text = jax.jit(fn).lower(*_placed(args, SingleDeviceSharding(device))).as_text()
+    bodies = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', text):
+        context = jax_mlir.make_ir_context()
+        context.allow_unregistered_dialects = True  # `stable_mosaic`, the serialised form
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            bodies.append(module.operation.get_asm(enable_debug_info=False))
+    return bodies
+
+
+def _short_hash(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_BODIES_AT_PARENT))
+def test_the_shared_kernels_hand_mosaic_what_they_did(v5e, case):
+    (body,) = _kernel_bodies(*CASES[case], v5e[0])
+    assert f"module @{_kernel_of(case)} " in body
+    assert _short_hash(body) == KERNEL_BODIES_AT_PARENT[case]
+
+
+def test_the_selection_is_the_only_difference_of_its_kernel(v5e):
+    """The selected walk's Mosaic module against the plain decode kernel's
+    at the same sizes: one more operand (a row's block, float32
+    [1, 272, 1, 64], with its index map), a page of it compared with 0 and
+    one `select` on the scores; no line of the plain kernel is gone."""
+    import difflib
+
+    sizes = dict(batch=8, table=272, pages=2176, layers=12)
+    (plain,) = _kernel_bodies(*_paged(KEYE, False, **sizes), v5e[0])
+    (selected,) = _kernel_bodies(*CASES["keye8x272-selected-decode"], v5e[0])
+    # SSA numbers and argument numbers shift behind the new operand
+    blank = lambda text: re.sub(r"%(arg)?\d+", "%_", text).splitlines()  # noqa: E731
+    delta = [
+        line for line in difflib.ndiff(blank(plain), blank(selected))
+        if line[0] in "+-" and not line.startswith(("- module @", "+ module @"))
+    ]
+    # lines are added, none goes but the signatures the new operand is part of
+    gone = [line for line in delta if line[0] == "-"]
+    assert all("^bb0(" in line or "function_type = " in line for line in gone), gone[:3]
+    assert 0 < len(delta) - len(gone) < 40, len(delta)
+    assert sum("memref<1x272x1x64xf32" in line for line in delta) >= 2  # the row's block
+
+
+DECODE_PROGRAMS_AT_PARENT = {
+    "tiny-test": "ce8f6d008283903c",
+    "tiny-test-int8": "e3732a44bc613bc6",
+    "tiny-moe-test": "05bdf61a6e78117c",
+    "tiny-hybrid-test": "fd1f94910bd1ddb5",
+    "tiny-window-moe-test": "30e6fb6317606f48",
+    "tiny-blockfill-moe-test": "cc8a25b2bf097fec",
+}
+
+
+def _decode_program_text(case: str) -> str:
+    from langstream_tpu.models.transformer import init_params, make_page_pool
+    from langstream_tpu.serving import engine as E
+
+    name, int8 = case.removesuffix("-int8"), case.endswith("-int8")
+    config = dataclasses.replace(
+        MODEL_PRESETS[name], attention_impl="pallas",
+        kv_cache_dtype="int8" if int8 else MODEL_PRESETS[name].kv_cache_dtype,
+    )
+    b, page, table, pages = 4, 8, 6, 24
+    key = SDS((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: init_params(config, k), key)
+    pool = jax.eval_shape(lambda: make_page_pool(config, pages, page, state_rows=b))
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    tables = i32(2, b, table) if config.n_layers_of("sliding_attention") else i32(b, table)
+    if config.fills_blocks:
+        s = config.block_length
+        block = {"tokens": i32(b, s), "open": SDS((b, s), jnp.bool_), "step": i32(b)}
+        return E._paged_block_chunk.lower(
+            params, block, i32(b), pool, tables, key, f32(b), i32(b), f32(b), 2, config, page
+        ).as_text()
+    return E._paged_decode_chunk.lower(
+        params, i32(b), i32(b), pool, tables, key, f32(b), i32(b), f32(b), 2, config, page
+    ).as_text()
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_PROGRAMS_AT_PARENT))
+def test_the_other_models_decode_programs_lower_as_they_did(case):
+    assert _short_hash(_decode_program_text(case)) == DECODE_PROGRAMS_AT_PARENT[case]
