@@ -796,7 +796,8 @@ def moe_ffn_counted(
 # third leaf a token of the cache and of the page pool, ``"ik"``. Three
 # scopes inside ``attention``: ``attention.index`` (projections, norm,
 # rotary, scores; the scores alone ``attention.index.scores`` inside it),
-# ``attention.select`` (the ranking), ``attention.sparse`` (the selected read
+# ``attention.select`` (the ranking; with the kernels a segment's scores are
+# made and ranked there in one call), ``attention.sparse`` (the selected read
 # and the attention over it).
 # ---------------------------------------------------------------------------
 
@@ -825,25 +826,19 @@ def _index_proj(u, lp, positions, config):
 
 def _selection_kernels(config, s: int, t: int) -> bool:
     """Whether S > 1 queries over T columns take the selection's kernels
-    (`index_scores`, `sparse_segment_attention`): the prefill kernel's gate
+    (`segment_select`, `sparse_segment_attention`): the prefill kernel's gate
     and whole lane tiles of columns."""
     from langstream_tpu.ops.attention import pallas_ok
 
     return s > 1 and pallas_ok(config, s) and t % min(128, t) == 0
 
 
-def _index_scores(q_idx, w, k_idx, offsets, config):
-    """[B, S, T] float32: every query's score of every column. In tiles on
-    the chip (`ops/attention.index_scores`: nothing of [S, Hi, T] is formed),
-    one einsum where the kernel's tiles do not fit (the tests' sizes). A
-    score of -0.0 (every head's ReLU shut) reads +0.0: one order for floats
-    and for their bits."""
-    from langstream_tpu.ops import attention as ops
-
-    if _selection_kernels(config, q_idx.shape[1], k_idx.shape[1]):
-        return ops.index_scores(
-            q_idx, w, k_idx, offsets, interpret=jax.default_backend() != "tpu"
-        )
+def _index_scores(q_idx, w, k_idx):
+    """[B, S, T] float32: every query's score of every column, one einsum
+    (a decode step's row, and a segment where the kernels' tiles do not fit:
+    with them `ops/attention.segment_select` scores in tiles and ranks where
+    they lie). A score of -0.0 (every head's ReLU shut) reads +0.0: one order
+    for floats and for their bits."""
     dots = jnp.einsum("bshd,btd->bsht", q_idx, k_idx, preferred_element_type=jnp.float32)
     return jnp.einsum("bsht,bsh->bst", jax.nn.relu(dots), w) + 0.0
 
@@ -885,25 +880,35 @@ def _select_mask(scores: jax.Array, visible: jax.Array, k: int) -> jax.Array:
 def _selected_attention(q, q_idx, w, k_idx_all, k_all, v_all, mask, positions, config, what):
     """S > 1 queries a row under the selection: ``k_all``/``v_all``
     [B, Hkv, T, D] and ``k_idx_all`` [B, T, Di] the row's columns, ``mask``
-    [B, S, T] what each query may see at all. The scores in tiles, the
-    ranking by counting, and the attention a walk over key blocks with the
-    selection as a packed mask (`ops/attention.sparse_segment_attention`:
-    the scores of [S, heads, T] are never held); masked jnp where the
+    [B, S, T] what each query may see at all: causal, column <= position, in
+    every caller (`_paged_mask`, `forward`, `prefill`). With the kernels, the
+    scores and the ranking in ONE call whose tiles of scores never leave
+    VMEM (`ops/attention.segment_select`: `_select_mask`'s set to the bit,
+    the mask read off ``positions``), and the attention a walk over key
+    blocks with the selection as a packed mask
+    (`ops/attention.sparse_segment_attention`: the scores of [S, heads, T]
+    are never held); the einsum, `_select_mask` and masked jnp where the
     kernels' tiles do not fit."""
     from langstream_tpu.ops import attention as ops
 
     s, t = q.shape[1], k_all.shape[2]
+    if _selection_kernels(config, s, t):
+        interpret = jax.default_backend() != "tpu"
+        with jax.named_scope("attention.select"):
+            ops.note_path(f"{what}-select", "segment_select", config, s=s, t=t)
+            chosen = ops.segment_select(
+                q_idx, w, k_idx_all, positions[:, 0], config.index_topk, interpret=interpret
+            )
+        with jax.named_scope("attention.sparse"):
+            ops.note_path(f"{what}-sparse", "sparse_segment_attention", config, s=s, t=t)
+            return ops.sparse_segment_attention(
+                q, k_all, v_all, positions[:, 0], chosen, config, interpret=interpret
+            )
     with jax.named_scope("attention.index"), jax.named_scope("attention.index.scores"):
-        scores = _index_scores(q_idx, w, k_idx_all, positions[:, 0], config)
+        scores = _index_scores(q_idx, w, k_idx_all)
     with jax.named_scope("attention.select"):
         chosen = _select_mask(scores, mask, config.index_topk)
     with jax.named_scope("attention.sparse"):
-        if _selection_kernels(config, s, t):
-            ops.note_path(f"{what}-sparse", "sparse_segment_attention", config, s=s, t=t)
-            return ops.sparse_segment_attention(
-                q, k_all, v_all, positions[:, 0], chosen, config,
-                interpret=jax.default_backend() != "tpu",
-            )
         ops.note_path(f"{what}-sparse", "jnp", config, s=s, t=t)
         return attention(q, k_all, v_all, chosen, config)
 
@@ -915,7 +920,7 @@ def _decode_index_scores(q_idx, w, pik, table, layer, config, page_size):
     b, t = table.shape[0], table.shape[1] * page_size
     with jax.named_scope("attention.index"), jax.named_scope("attention.index.scores"):
         k_idx = pik.at[layer, table].get(mode="clip").reshape(b, t, -1)[..., :q_idx.shape[-1]]
-        return _index_scores(q_idx[:, None], w[:, None], k_idx, None, config)[:, 0]
+        return _index_scores(q_idx[:, None], w[:, None], k_idx)[:, 0]
 
 
 def _sparse_decode_attention(

@@ -18,9 +18,11 @@ blocks are naturally aligned, and a per-head kv block is a contiguous
 - ``flash_segment_attention``: a prefill segment's queries over the row's
   gathered columns, causal (windowed), a query block visiting only the key
   blocks it can see; ``sparse_segment_attention`` is that walk under a
-  packed [S, T] selection (a model with an indexer), and ``index_scores``
-  the indexer's scores of a segment in tiles, so nothing of
-  [S, heads, T] is ever held.
+  packed [S, T] selection (a model with an indexer), and ``segment_select``
+  makes that selection in one call: the indexer's scores of a segment in
+  tiles, ranked where they lie in VMEM, so nothing of [S, heads, T] is ever
+  held and no [S, T] of scores reaches HBM (``index_scores``: the scores
+  alone, what it is held to).
 - ``paged_kv_write``: that step's new K and V rows into the bf16 pool where
   it lies, a copy per live row (a scatter pays per (row, kv head), dropped
   rows included).
@@ -514,6 +516,20 @@ def sparse_segment_attention(
     )
 
 
+def _index_score_tile(q_ref, w_ref, k):
+    """[block_q, block_k] float32: the indexer's scores of one tile, the Hi
+    products summed where they are made."""
+    w = w_ref[0]
+    acc = jnp.zeros((q_ref.shape[2], k.shape[0]), jnp.float32)
+    for head in range(q_ref.shape[1]):
+        dots = jax.lax.dot_general(
+            q_ref[0, head], k, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [block_q, block_k]
+        acc = acc + jnp.maximum(dots, 0.0) * w[:, head:head + 1]
+    return acc + 0.0  # -0.0 reads +0.0
+
+
 def _index_score_kernel(
     offsets_ref,  # scalar-prefetch [B]
     q_ref,  # [1, Hi, block_q, Di]
@@ -531,16 +547,7 @@ def _index_score_kernel(
 
     @pl.when(j * block_k <= last_query)
     def _body():
-        k = k_ref[0]
-        w = w_ref[0]
-        acc = jnp.zeros((block_q, block_k), jnp.float32)
-        for head in range(q_ref.shape[1]):
-            dots = jax.lax.dot_general(
-                q_ref[0, head], k, dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [block_q, block_k]
-            acc = acc + jnp.maximum(dots, 0.0) * w[:, head:head + 1]
-        o_ref[0] = acc + 0.0  # -0.0 reads +0.0
+        o_ref[0] = _index_score_tile(q_ref, w_ref, k_ref[0])
 
 
 def index_scores(
@@ -574,6 +581,199 @@ def index_scores(
             out_specs=pl.BlockSpec((1, block_q, block_k), lambda b, i, j, off: (b, i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((b, s, t), jnp.float32),
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(offsets.astype(jnp.int32), q_idx.transpose(0, 2, 1, 3), w.astype(jnp.float32), k_idx)
+
+
+# ---------------------------------------------------------------------------
+# A segment's SELECTION in one call a layer (`segment_select`): what
+# `index_scores` and models/transformer `_select_mask` compute between them,
+# with a query tile's scores never leaving VMEM. XLA ranks by 32 counts of a
+# [S, T] key in HBM, the columns no query of the segment can see included (its
+# shapes are static): 36 passes over 142 MB a layer at 2,048 x 17,408, 6.2 ms.
+# Here a tile of ``block_q`` queries walks the key blocks up to its diagonal
+# alone, keeps each tile of scores as its sortable key in a [block_q, T]
+# scratch, and bisects the k-th largest over the key's 32 bits with the tile
+# resident: the counts are VPU work over VMEM and follow what the tile sees.
+# The set is `_select_mask`'s to the bit: the same products in the same order
+# (`_index_score_tile`), the same fold of a float's bits, the same threshold,
+# and its tie rule (a tie to the lower column), run only in a tile where some
+# row holds more columns AT its threshold than it may keep, as a second
+# bisection by the same counting, over the column below which a row keeps its
+# ties. Visibility is the segment's: column <= position (`_paged_mask`,
+# `forward`'s and `prefill`'s causal masks), from ``offsets``.
+# On a v5e at 2,048 queries x 16 heads of 64 over 17,408 columns
+# (`dev/bench_segment_select.py`; PERF.md section 6, PR 45): 0.62 ms a layer
+# at offset 2,048, 1.29 at 8,192, 2.06 at 15,360, against 8.1-8.5 for
+# `index_scores` + `_select_mask`; ranking `index_scores`'s output read back
+# from HBM instead of scoring in the call costs 0.2 ms more at every offset.
+# `attention_paths()` says where it was traced, "paged-segment-select[s=..,
+# t=..]" -> "segment_select" (models/transformer `_selected_attention`), and
+# the grid it got, "segment-select[s=2048,t=17408]" -> "block_q 128, block_k
+# 512, to the diagonal". `index_scores` above stays as what this call is held
+# to (tests/test_sparse_attention.py, the bench); no program calls it. A
+# DECODE step still ranks by `_select_mask` in XLA, on purpose: its
+# [8, 17408] key is VMEM-resident as it is (32 counts cost 0.23 ms a step).
+# ---------------------------------------------------------------------------
+
+_INT32_MIN = jnp.iinfo(jnp.int32).min
+
+# VMEM a query tile's row of the table may take: its int32 keys and its int8
+# output in two buffers, 6 B a (query, column). Half the stated limit, the
+# rest for the tiles in flight (q, w and K blocks, a tile of scores and its
+# products): 128 queries at 17,408 columns (13.4 MB), 64 at 34,816.
+_SELECT_VMEM_BYTES = _VMEM_LIMIT_BYTES // 2
+
+
+def select_blocks(s: int, t: int) -> tuple[int, int]:
+    """(block_q, block_k) of a `segment_select` call: the key blocks are
+    `index_scores`'s, the query tile the largest whose row of the table fits
+    ``_SELECT_VMEM_BYTES``, never under 8 rows."""
+    block_q, block_k = _fit_block(512, s), _fit_block(512, t)
+    while block_q > 8 and block_q % 2 == 0 and block_q * t * 6 > _SELECT_VMEM_BYTES:
+        block_q //= 2
+    return block_q, block_k
+
+
+def _segment_select_kernel(
+    offsets_ref,  # scalar-prefetch [B]
+    q_ref,  # [1, Hi, block_q, Di]
+    w_ref,  # [1, block_q, Hi] float32
+    k_ref,  # [1, block_k, Di]
+    o_ref,  # [1, block_q, T] int8
+    keys_scr,  # [T / block_k, block_q, block_k] int32
+    *, block_q: int, block_k: int, n_t: int, topk: int,
+):
+    b, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    q_start = offsets_ref[b] + i * block_q
+    last = jnp.minimum((q_start + block_q - 1) // block_k, n_t - 1)  # the diagonal's block
+    position = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+
+    def column(c):
+        return c * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+
+    @pl.when(j <= last)
+    def _score():
+        # a float's order is its bits' once the sign is folded: as SIGNED
+        # int32, `_select_mask`'s uint32 key with its top bit flipped. A
+        # column the query cannot see sorts below every score
+        bits = jax.lax.bitcast_convert_type(_index_score_tile(q_ref, w_ref, k_ref[0]), jnp.int32)
+        key = bits ^ ((bits >> 31) & 0x7FFFFFFF)
+        keys_scr[j] = jnp.where(column(j) <= position, key, _INT32_MIN)
+
+    @pl.when(j == n_t - 1)
+    def _rank():
+        lanes = min(128, block_k)
+
+        def count(hit):
+            """[block_q, 1]: a row's columns, of the blocks up to the
+            diagonal, where ``hit(keys, block)``: summed lane on lane, one
+            reduction across lanes a count."""
+            def block(c, acc):
+                ones = hit(keys_scr[c], c).astype(jnp.int32)
+                for at in range(0, block_k, lanes):
+                    acc = acc + ones[:, at:at + lanes]
+                return acc
+
+            acc = jax.lax.fori_loop(0, last + 1, block, jnp.zeros((block_q, lanes), jnp.int32))
+            return acc.sum(axis=-1, keepdims=True)
+
+        keep = jnp.minimum(jnp.minimum(position + 1, n_t * block_k), topk)
+
+        def bit(n, state):
+            found, at_least = state
+            tried = found | jnp.left_shift(jnp.int32(1), 31 - n)
+            seen = count(lambda keys, c: keys >= (tried ^ _INT32_MIN))
+            ok = seen >= keep
+            return jnp.where(ok, tried, found), jnp.where(ok, seen, at_least)
+
+        found, at_least = jax.lax.fori_loop(0, 32, bit, (jnp.zeros_like(keep), keep))
+        kth = found ^ _INT32_MIN  # [block_q, 1], signed like the keys
+        tied = jnp.max(at_least - keep) > 0  # some row may not keep all AT its threshold
+
+        def write(chosen):
+            def columns(c):
+                return pl.ds(pl.multiple_of(c * block_k, block_k), block_k)
+
+            def visited(c, _):
+                o_ref[0, :, columns(c)] = chosen(keys_scr[c], c).astype(jnp.int8)
+                return _
+
+            def unseen(c, _):
+                o_ref[0, :, columns(c)] = jnp.zeros((block_q, block_k), jnp.int8)
+                return _
+
+            jax.lax.fori_loop(0, last + 1, visited, 0)
+            jax.lax.fori_loop(last + 1, n_t, unseen, 0)
+
+        @pl.when(jnp.logical_not(tied))
+        def _without_ties():
+            write(lambda keys, c: keys >= kth)
+
+        @pl.when(tied)
+        def _with_ties():
+            # of the columns AT the threshold a row keeps the lowest ``room``:
+            # those below the largest bound with no more than ``room`` of them
+            # under it (every one of them where the row keeps them all)
+            room = keep - count(lambda keys, c: keys > kth)
+            bits = (n_t * block_k).bit_length()
+
+            def bit(n, bound):
+                tried = bound | jnp.left_shift(jnp.int32(1), bits - 1 - n)
+                under = count(lambda keys, c: (keys == kth) & (column(c) < tried))
+                return jnp.where(under <= room, tried, bound)
+
+            bound = jax.lax.fori_loop(0, bits, bit, jnp.zeros_like(keep))
+            write(lambda keys, c: (keys > kth) | ((keys == kth) & (column(c) < bound)))
+
+
+def segment_select(
+    q_idx: jax.Array,  # [B, S, Hi, Di] the indexer's queries at offsets[b] + (0 .. S-1)
+    w: jax.Array,  # [B, S, Hi] float32, the heads' weights
+    k_idx: jax.Array,  # [B, T, Di] the row's indexer keys, columns 0 .. T-1
+    offsets: jax.Array,  # [B]
+    topk: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """The selection of a segment → [B, S, T] int8, what
+    `sparse_segment_attention` takes: 1 where the query at ``offsets[b] + i``
+    attends to the column, of the columns it sees (column <= its position)
+    the ``min(topk, their number)`` of largest `index_scores`, a tie to the
+    lower column; zeros past the diagonal. `_select_mask`'s set of
+    `index_scores`'s scores under the causal mask, to the bit, padding
+    queries included."""
+    b, s, hi, di = q_idx.shape
+    t = k_idx.shape[1]
+    block_q, block_k = select_blocks(s, t)
+    assert s % block_q == 0 and t % block_k == 0, "caller gates divisibility"
+    n_t = t // block_k
+
+    def k_index(b, i, j, offsets):  # past the diagonal nothing is fetched
+        last = (offsets[b] + (i + 1) * block_q - 1) // block_k
+        return (b, jnp.minimum(j, jnp.minimum(last, n_t - 1)), 0)
+
+    note_grid(
+        f"segment-select[s={s},t={t}]",
+        f"block_q {block_q}, block_k {block_k}, to the diagonal",
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _segment_select_kernel, block_q=block_q, block_k=block_k, n_t=n_t, topk=topk
+        ),
+        name="segment_select",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, s // block_q, n_t),
+            in_specs=[
+                pl.BlockSpec((1, hi, block_q, di), lambda b, i, j, off: (b, 0, i, 0)),
+                pl.BlockSpec((1, block_q, hi), lambda b, i, j, off: (b, i, 0)),
+                pl.BlockSpec((1, block_k, di), k_index),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, t), lambda b, i, j, off: (b, i, 0)),
+            scratch_shapes=[pltpu.VMEM((n_t, block_q, block_k), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, s, t), jnp.int8),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(offsets.astype(jnp.int32), q_idx.transpose(0, 2, 1, 3), w.astype(jnp.float32), k_idx)

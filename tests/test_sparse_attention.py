@@ -136,7 +136,7 @@ def test_the_selected_sets_are_the_references(params, tokens):
     u = T.rms_norm(x, lp["attn_norm"], TINY.rms_norm_eps)
     positions = jnp.arange(LENGTH)[None]
     q_idx, k_idx, w = T._index_proj(u, lp, positions, TINY)
-    scores = T._index_scores(q_idx, w, k_idx, positions[:, 0], TINY)
+    scores = T._index_scores(q_idx, w, k_idx)
     causal = jnp.tril(jnp.ones((LENGTH, LENGTH), jnp.bool_))[None]
     got = T._select_mask(scores, causal, TINY.index_topk)[0]
     with jax.default_matmul_precision("highest"):
@@ -302,11 +302,76 @@ def test_index_scores_in_tiles_are_the_einsums():
     offsets = jnp.asarray([256, 768], jnp.int32)
     got = A.index_scores(q, w, k, offsets, interpret=True)
     with jax.default_matmul_precision("highest"):
-        want = T._index_scores(q, w, k, offsets, dataclasses.replace(TINY, attention_impl="jnp"))
+        want = T._index_scores(q, w, k)
     seen = jnp.arange(t)[None, None, :] <= (offsets[:, None] + jnp.arange(s))[:, :, None]
     assert float(jnp.max(jnp.abs(jnp.where(seen, got - want, 0.0)))) < 1e-4
     # a tile wholly past the diagonal is zeros, not computed
     assert float(jnp.abs(got[0, :, 512:]).max()) == 0.0
+
+
+# a segment's selection in one call: (queries, columns, offsets a row, top-k,
+# how the indexer's inputs are drawn). "few-values": small whole numbers, so
+# that scores tie (a third of the heads' weights are 0 and a row's ReLUs shut
+# together: its threshold is +0.0 with tens of ties) and the tie rule runs
+SELECT_CASES = {
+    "offset-0": (128, 512, [0], 16, "normal"),  # its first rows see fewer columns than k
+    "mid-table": (128, 1024, [384, 256], 64, "normal"),  # two rows at two diagonals
+    "the-last-segment-of-a-full-table": (128, 1024, [896], 64, "normal"),
+    "fewer-visible-than-k": (128, 512, [0, 100], 200, "normal"),
+    "a-threshold-of-zero-with-many-ties": (128, 1024, [896], 300, "few-values"),
+    # positive heads' weights, so a row's 40th score is no shut ReLU's 0.0; an
+    # offset that is no block's edge
+    "no-row-ties": (128, 512, [300], 40, "positive-weights"),
+    "a-table-that-forces-a-smaller-query-tile": (128, 34816, [17000], 64, "normal"),
+    "padding-queries-past-the-table": (128, 512, [448], 32, "few-values"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECT_CASES))
+def test_a_segments_selection_in_one_call_is_select_masks_set(case):
+    """`segment_select` (Pallas in interpret mode) against `_select_mask` of
+    `index_scores` under the causal mask, to the bit, every query of the tile
+    a padding query included (a segment's queries past `seg_lengths` are
+    ranked like the others: positions ``offset + i``, past the table's end
+    in the last case)."""
+    s, t, offsets, k, draw = SELECT_CASES[case]
+    rng = np.random.default_rng(sorted(SELECT_CASES).index(case))
+    b, hi, di = len(offsets), 3, 16
+    if draw == "few-values":
+        q, key = rng.integers(-2, 3, (b, s, hi, di)), rng.integers(-2, 3, (b, t, di))
+        w = rng.integers(-1, 2, (b, s, hi))
+    else:
+        q, key = rng.standard_normal((b, s, hi, di)), rng.standard_normal((b, t, di))
+        w = rng.standard_normal((b, s, hi))
+        w = np.abs(w) + 0.1 if draw == "positive-weights" else w
+    q, w, key = (jnp.asarray(a, jnp.float32) for a in (q, w, key))
+    offsets = jnp.asarray(offsets, jnp.int32)
+    block_q, block_k = A.select_blocks(s, t)
+    assert (block_q < 128) == (case == "a-table-that-forces-a-smaller-query-tile")
+    got = np.asarray(A.segment_select(q, w, key, offsets, k, interpret=True))
+    scores = A.index_scores(q, w, key, offsets, interpret=True)
+    causal = jnp.arange(t)[None, None, :] <= (offsets[:, None] + jnp.arange(s))[:, :, None]
+    want = np.asarray(T._select_mask(scores, causal, k))
+    np.testing.assert_array_equal(got, want.astype(np.int8))
+    # the case is the case it says
+    seen = np.minimum(np.asarray(offsets)[:, None] + np.arange(s) + 1, t)
+    kept = np.minimum(seen, k)
+    np.testing.assert_array_equal(got.sum(-1), kept)
+    keyed = np.where(np.asarray(causal), np.asarray(scores), -np.inf)
+    kth = np.take_along_axis(np.sort(keyed, axis=-1)[..., ::-1], kept[..., None] - 1, axis=-1)
+    at_threshold = (keyed == kth).sum(-1)
+    more_than_kept = (keyed >= kth).sum(-1) > kept
+    if case == "a-threshold-of-zero-with-many-ties":
+        assert ((kth[..., 0] == 0) & (at_threshold > 20) & more_than_kept).any()
+    if case == "padding-queries-past-the-table":
+        assert more_than_kept.any() and (np.asarray(offsets)[0] + s > t)
+    if case == "no-row-ties":
+        assert not more_than_kept.any()
+    if case == "fewer-visible-than-k":
+        assert (seen < k).any() and (seen > k).any()
+    assert A.attention_paths()[f"segment-select[s={s},t={t}]"] == (
+        f"block_q {block_q}, block_k {block_k}, to the diagonal"
+    )
 
 
 def test_the_segment_walk_under_a_packed_selection_is_masked_attention():

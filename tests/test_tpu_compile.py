@@ -207,6 +207,12 @@ def _index_scores(config, s, t):
     )
 
 
+def _segment_select(config, s, t):
+    """A segment's selection in one call: scores in tiles, ranked where they lie."""
+    _, args = _index_scores(config, s, t)
+    return lambda q, w, k, offsets: A.segment_select(q, w, k, offsets, config.index_topk), args
+
+
 def _sparse_segment(config, s, t):
     """A segment's attention under a packed selection."""
     fn, (q, k, v, offsets) = _segment(config, s, t, 0)
@@ -347,15 +353,21 @@ CASES = {
     "sdar-down-grouped-matmul-2048": _grouped(SDAR, 2048, 12, down=True),
     # the Keye cell: a decode step's walk of 8 rows x 272 pages under the
     # selection as a mask, a 2048-token segment against the row's 17,408 columns,
-    # its indexer's scores in tiles and its walk under the packed selection,
-    # and the check's chain from offset 0: at its width, 2,432, and at 4,608
+    # its selection in one call (and the scores in tiles that call is held to)
+    # and its walk under the packed selection, and the check's chain from
+    # offset 0: at its width, 2,432, and at 4,608; the selection over a table
+    # twice the cell's, where a query tile is 64 rows
     "keye8x272-selected-decode": _selected_decode(KEYE, 8, 272, 2176, 12),
     "keye-index-scores-2048": _index_scores(KEYE, 2048, 17408),
+    "keye-segment-select-2048": _segment_select(KEYE, 2048, 17408),
     "keye-sparse-segment-2048": _sparse_segment(KEYE, 2048, 17408),
     "keye-index-scores-4608": _index_scores(KEYE, 4608, 4608),
+    "keye-segment-select-4608": _segment_select(KEYE, 4608, 4608),
     "keye-sparse-segment-4608": _sparse_segment(KEYE, 4608, 4608),
     "keye-index-scores-2432": _index_scores(KEYE, 2432, 2432),
+    "keye-segment-select-2432": _segment_select(KEYE, 2432, 2432),
     "keye-sparse-segment-2432": _sparse_segment(KEYE, 2432, 2432),
+    "keye34816-segment-select-2048": _segment_select(KEYE, 2048, 34816),
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
     **{f"gemma-prefill-{s}": _prefill(GEMMA, s) for s in (512, 1024, 2048)},
@@ -417,6 +429,7 @@ def _kernel_of(case: str) -> str:
         "down-grouped-matmul": "moe_grouped_matmul",
         "gate-up-grouped-matmul": "moe_grouped_matmul",
         "index-scores": "index_scores",
+        "segment-select": "segment_select",
         "sparse-segment": "sparse_segment_attention",
     }[kind]
 
@@ -810,8 +823,10 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
     decode chunk, and a 2,048-token segment against 17,408 columns), int8
     weights and the pool donated. The decode step's read is the paged decode
     kernel under the selection as a mask: it holds no operand of a row's whole
-    table of K or V and no gather of index_topk rows a row; the segment never
-    forms scores of [S, heads, T]; and each fits the chip beside its state."""
+    table of K or V and no gather of index_topk rows a row; the segment ranks
+    in one call (`segment_select`): it never forms scores of [S, heads, T],
+    writes no [S, T] of float32 scores or of uint32 keys and loops over none;
+    and each fits the chip beside its state."""
     from langstream_tpu.models.quant import init_random_quantized_params
     from langstream_tpu.models.transformer import make_page_pool
     from langstream_tpu.serving import engine as E
@@ -834,7 +849,7 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
                 f32(1), i32(1), f32(1))
         static = (KEYE, PAGE)
         kernels = (
-            "flash_segment_attention", "sparse_segment_attention", "index_scores",
+            "flash_segment_attention", "sparse_segment_attention", "segment_select",
             "moe_grouped_matmul",
         )
         path = f"paged-segment-sparse[s={seg},t={t}]"
@@ -864,6 +879,18 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         for heads in (h, hkv, KEYE.index_n_heads):
             for shape in ([1, seg, heads, t], [1, heads, seg, t], [seg, heads, t], [heads, seg, t]):
                 assert "[" + ",".join(map(str, shape)) + "]" not in text, shape
+        # the ranking is the kernel's: the scores and their keys stay in VMEM
+        # (the one [S, T] the program holds is the int8 selection), and XLA's
+        # 32 counts of a key that size are gone with the loop that made them
+        paths = A.attention_paths()
+        assert paths[f"paged-segment-select[s={seg},t={t}]"] == "segment_select"
+        assert paths[f"segment-select[s={seg},t={t}]"] == "block_q 128, block_k 512, to the diagonal"
+        assert not re.search(r"%index_scores(\.\d+)? = ", text)
+        whole = f"[1,{seg},{t}]"
+        assert f"s8{whole}" in text
+        for dtype in ("f32", "u32", "s32", "pred"):
+            assert dtype + whole not in text, dtype
+        assert not [line for line in text.splitlines() if " while(" in line and whole in line]
     memory = compiled.memory_analysis()
     pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
     assert memory.alias_size_in_bytes >= pool_bytes  # the pool, updated in place
