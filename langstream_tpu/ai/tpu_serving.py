@@ -1,6 +1,6 @@
 """TPU-native AI service provider: local JAX serving instead of remote APIs.
 
-This is the component the whole rebuild exists for (BASELINE.md north star):
+This is the component the whole rebuild exists for (the build's north star):
 it implements the reference's ServiceProvider/CompletionsService/
 EmbeddingsService SPI surface (`services/ServiceProvider.java:24`,
 `completions/CompletionsService.java:22-33`, `embeddings/EmbeddingsService.java:24-36`)

@@ -1,6 +1,6 @@
 """Model architecture configs + presets for the supported families.
 
-Families cover BASELINE.json configs: Gemma-2B (single chip), Llama-3-8B
+Families cover the build's target configs: Gemma-2B (single chip), Llama-3-8B
 (TP over v5e-8), Mixtral-8x7B (MoE, expert-parallel), plus tiny test configs.
 Field semantics follow the HF config.json conventions so `models.loader` can
 map checkpoints mechanically.
@@ -97,7 +97,7 @@ class ModelConfig:
     norm: str = "rms"
     logit_scale: float = 1.0  # logits = logit_scale * h @ E^T
     # The block of a model with window layers is ONE block (transformer
-    # `_scan_window_periods`): parallel, x + Attn(u) + MoE(u) with u =
+    # `_parallel_layer`): parallel, x + Attn(u) + MoE(u) with u =
     # norm(x), one norm a layer, and an expert layer that holds a share
     # (`moe_ffn_held`). ``sliding_window``, ``rope_interleaved``, ``norm``,
     # ``moe_scoring`` and ``n_shared_experts`` are read by that block alone,
@@ -467,8 +467,9 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         # mixtral-8x7b architecture (8 experts, top-2, 3.5x ffn ratio,
         # GQA kv=8, rope 1e6) scaled to what ONE 16GiB v5e chip serves in
         # int8 (~8.9B total / ~1.06B per expert): the single-chip bench row
-        # for BASELINE config #5 — the full-size preset above shards over
-        # dp×ep×tp instead (see __graft_entry__._mixtral_sharding_lower_check)
+        # for the Mixtral MoE serving path — the full-size preset above
+        # shards over dp×ep×tp instead (see
+        # __graft_entry__._mixtral_sharding_lower_check)
         name="mixtral-8x1b",
         vocab_size=32000,
         d_model=2048,

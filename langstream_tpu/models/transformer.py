@@ -67,7 +67,7 @@ MOE_HELD_COUNTS = MOE_COUNTS + ("local", "touched")
 
 
 def moe_count_names(config: ModelConfig) -> tuple:
-    """What the programs of ``config`` count: `_scan_window_periods` runs
+    """What the programs of ``config`` count: the parallel block runs
     `moe_ffn_held` in every layer, the sequential block's loops where the
     model holds its experts so (``experts_held``), else `moe_ffn_counted`."""
     return MOE_HELD_COUNTS if config.holds_experts else MOE_COUNTS
@@ -627,41 +627,73 @@ def attention(
     return out.reshape(b, s, h * d)
 
 
+def _seen(positions: jax.Array, t: int, window: int = 0, kv_limit=None) -> jax.Array:
+    """[B, S, T] bool, what the masked jnp path reads by: the query at
+    ``positions[b, j]`` sees the columns up to its own, the last ``window`` of
+    them under a window, none from ``kv_limit`` on (a cache wider than what
+    was written)."""
+    kv_pos = jnp.arange(t)[None, None, :]
+    mask = kv_pos <= positions[:, :, None]
+    if window:
+        mask = mask & (kv_pos > positions[:, :, None] - window)
+    if kv_limit is not None:
+        mask = mask & (kv_pos < kv_limit)
+    return mask
+
+
 def _dispatch_attention(
     q: jax.Array,  # [B, S, H, D]
     k_all,  # [B, Hkv, T, D] array, or int8 {"q","s"} dict (cache width or S)
     v_all,
-    mask: jax.Array,
+    mask: Optional[jax.Array],  # [B, S, T]; None: `_seen`, made where it is read
     config: ModelConfig,
     causal: bool,
+    what: Optional[str] = None,  # `note_path`'s kind; None: by the shapes
+    positions: Optional[jax.Array] = None,  # [B, S]: the queries', where they may start past 0
+    window: int = 0,
+    from_zero: bool = True,  # query j stands at column j
+    kv_limit=None,
 ) -> jax.Array:
-    """The prefill kernel where the shapes fit TPU tiling, else the jnp
-    reference path. Semantics identical; ops/attention has the kernel."""
+    """The one chooser of what S > 1 queries over a row's columns read
+    through: a kernel that never holds the scores where the shapes fit TPU
+    tiling, else the masked jnp reference (a single query's path always).
+    Semantics identical; ops/attention has the kernels. Causal queries from
+    column 0 that see all of one another (no window, or one that holds them)
+    take the prefill kernel over the first S columns; queries at
+    ``positions`` take the segment kernel over whole lane tiles of columns,
+    under the window if there is one."""
     from langstream_tpu.ops.attention import (
         flash_prefill_attention,
+        flash_segment_attention,
         note_path,
         pallas_ok,
     )
 
     s = q.shape[1]
     t = (k_all["q"] if isinstance(k_all, dict) else k_all).shape[2]
-    if s > 1 and causal and pallas_ok(config, s):
+    kernels = s > 1 and causal and pallas_ok(config, s)
+    interpret = jax.default_backend() != "tpu"
+    if kernels and from_zero and (not window or s <= window):
         # prefill/full forward: causal over the first s cache columns (int8
         # caches dequantize just the prompt-wide slice — prefill is
         # compute-bound, the materialized slice is small)
         ksl = jax.tree.map(lambda x: x[:, :, :s], k_all)
         vsl = jax.tree.map(lambda x: x[:, :, :s], v_all)
-        note_path("prefill", "flash_prefill_attention", config, s=s, t=t)
+        note_path(what or "prefill", "flash_prefill_attention", config, s=s, t=t)
         return flash_prefill_attention(
-            q,
-            _dequantize_kv(ksl, q.dtype),
-            _dequantize_kv(vsl, q.dtype),
-            config,
-            interpret=jax.default_backend() != "tpu",
+            q, _dequantize_kv(ksl, q.dtype), _dequantize_kv(vsl, q.dtype), config,
+            interpret=interpret,
+        )
+    if kernels and positions is not None and t % min(128, t) == 0:
+        note_path(what, "flash_segment_attention", config, s=s, t=t)
+        return flash_segment_attention(
+            q, k_all, v_all, positions[:, 0], config, window=window, interpret=interpret
         )
     # jnp path handles int8 cache dicts natively (hoisted-scale einsums)
-    kind = "decode" if s == 1 else "prefill" if causal else "encode"
-    note_path(kind, "jnp", config, s=s, t=t)
+    what = what or ("decode" if s == 1 else "prefill" if causal else "encode")
+    note_path(what, "jnp", config, s=s, t=t)
+    if mask is None:
+        mask = _seen(positions, t, window, kv_limit)
     return attention(q, k_all, v_all, mask, config)
 
 
@@ -1009,27 +1041,16 @@ def _attention_block(
     """The attention half of a block (norm, QKV, rotary, cache write, the
     kernel or jnp path, output projection, residual): the layer's input in,
     the FFN's input and the layer's new cache entry out. It names its own
-    scopes: all of it is ``attention``, but for the paged branch's write
-    of the new K/V rows (``paged_kv_write`` where the decode kernel runs
-    over a bf16 pool, else ``_paged_scatter``), the pool's only write,
-    which is ``kv_pool.write`` and (a scope cannot be left from inside)
-    outside ``attention``. With
-    ``paged_table`` set, ``cache_kv`` is the WHOLE pool and comes back
-    whole: nothing of a layer's size is formed. ``block``: the S queries of
-    a row are one block of a model that fills blocks; all of them see keys
-    ``0 .. start + S - 1``, so where the decode kernel runs the read is that
-    kernel with S x group query rows a KV head and no mask among them
-    (``ragged_paged_block_attention``), and the write the decode write over
-    S rows of one aligned tile. A model with an indexer
-    (``config.has_indexer``) carries a third pool leaf in ``cache_kv``, the
-    indexer's keys, written in ``kv_pool.write`` with K and V; its decode
-    step attends to the selected tokens alone (`_paged_selected_read`: the
-    decode kernel's walk under the selection as a mask where it runs, a
-    gather of the selected rows elsewhere and past 16 x ``index_topk``
-    columns of table) and its segment goes through
-    ``flash_segment_attention`` over the row's gathered columns, under the
-    selection once a query sees more than ``index_topk`` keys
-    (`_selected_attention`)."""
+    scopes: all of it is ``attention``, but for the paged branch's write of
+    the new K/V rows, which is ``kv_pool.write``. With ``paged_table`` set,
+    ``cache_kv`` is the WHOLE pool and comes back whole, written and read at
+    ``layer`` by `_paged_attention`: nothing of a layer's size is formed.
+    ``block``: the S queries of a row are one block of a model that fills
+    blocks; all of them see keys ``0 .. start + S - 1``. A model with an
+    indexer (``config.has_indexer``) carries a third pool leaf in
+    ``cache_kv``, the indexer's keys; its decode step attends to the selected
+    tokens alone and its segment reads under the selection once a query sees
+    more than ``index_topk`` keys (`_paged_selected_read`)."""
     if paged_table is None:
         with jax.named_scope("attention"):
             return _dense_attention(
@@ -1037,107 +1058,146 @@ def _attention_block(
                 causal, collect_kv, lora, lora_scale, adapter_rows,
             )
     assert cache_kv is not None and cache_positions is not None
-    from langstream_tpu.ops.attention import (
-        note_path,
-        block_write_ok,
-        paged_kv_write,
-        paged_pallas_ok,
-        ragged_paged_block_attention,
-        ragged_paged_decode_attention,
-        ragged_paged_decode_attention_int8,
-    )
-
-    s = x.shape[1]
     with jax.named_scope("attention"):
         q, k, v = _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows)
+        index = None
         if config.has_indexer:
             with jax.named_scope("attention.index"):
-                q_idx, k_idx, w_idx = _index_proj(
+                index = _index_proj(
                     rms_norm(x, lp["attn_norm"], config.rms_norm_eps), lp,
                     cache_positions, config,
                 )
-    pk, pv, *pik = cache_kv  # [L, P, Hkv, ps, D], read and written at `layer`
-    num_pages = (pk["q"] if isinstance(pk, dict) else pk).shape[1]
-    decode_kernels = s == 1 and paged_pallas_ok(config, page_size)
-    block_kernels = (
-        block and not isinstance(pk, dict) and paged_pallas_ok(config, page_size)
+    # the caller's mask, not the core's own: this block's segment reads masked
+    # jnp, as it always has (handing it to the segment kernel where the pool
+    # is bf16 changes Mistral's and Mixtral's programs: ROADMAP D15)
+    attn, leaves = _paged_attention(
+        q, k, v, cache_kv, paged_table, cache_positions, layer, page_size, config,
+        mask=mask, index=index, block=block, verify=verify,
     )
-    with jax.named_scope("kv_pool.write"):
-        if block_kernels and block_write_ok(s, page_size):
-            # the block starts on a multiple of S inside a page: its S rows
-            # lie in one aligned tile of the pool, one copy a live row
-            pages, offs = _page_index(
-                paged_table, cache_positions[:, :1], page_size, num_pages
-            )
-            pk, pv = paged_kv_write(
-                (k.reshape(k.shape[0], -1, k.shape[-1]),
-                 v.reshape(v.shape[0], -1, v.shape[-1])),
-                pk, pv, pages[:, 0], offs[:, 0], layer, config,
-                interpret=jax.default_backend() != "tpu",
-            )
-        elif decode_kernels and not isinstance(pk, dict):
-            # a decode step into the bf16 pool: a copy per LIVE row. The
-            # int8 pool (a token's scales are Hkv scattered words, no DMA
-            # Mosaic takes) and the S > 1 writers keep the scatter
-            pages, offs = _page_index(
-                paged_table, cache_positions, page_size, num_pages
-            )
-            pk, pv = paged_kv_write(
-                (k[:, 0], v[:, 0]), pk, pv, pages[:, 0], offs[:, 0], layer,
-                config, interpret=jax.default_backend() != "tpu",
-            )
-        else:
-            kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            pk = _paged_scatter(pk, layer, kt, paged_table, cache_positions, page_size)
-            pv = _paged_scatter(pv, layer, vt, paged_table, cache_positions, page_size)
-        if config.has_indexer:
-            pik = [_write_index_key(
-                pik[0], layer, k_idx, paged_table, cache_positions, page_size
-            )]
     with jax.named_scope("attention"):
-        t = paged_table.shape[1] * page_size
-        if config.has_indexer:
-            attn = _paged_selected_read(
-                q, q_idx, w_idx, pk, pv, pik[0], paged_table, layer, mask,
-                cache_positions, config, page_size, decode_kernels,
-            )
-        elif decode_kernels:
-            lengths = _paged_lengths(
-                paged_table, cache_positions[:, 0], page_size, num_pages
-            )
-            kernel = (
-                ragged_paged_decode_attention_int8 if isinstance(pk, dict)
-                else ragged_paged_decode_attention
-            )
-            note_path("paged-decode", kernel.__name__, config, s=s, t=t)
-            out = kernel(
-                q[:, 0], pk, pv, lengths, paged_table, layer, config, page_size,
-                interpret=jax.default_backend() != "tpu",
-            )
-            attn = out[:, None, :]
-        elif block_kernels:
-            lengths = _paged_lengths(
-                paged_table, cache_positions[:, -1], page_size, num_pages
-            )
-            note_path("paged-block", "ragged_paged_block_attention", config, s=s, t=t)
-            attn = ragged_paged_block_attention(
-                q, pk, pv, lengths, paged_table, layer, config, page_size,
-                interpret=jax.default_backend() != "tpu",
-            )
-        else:
-            kind = (
-                "block" if block else "decode" if s == 1
-                else "verify" if verify else "segment"
-            )
-            note_path(f"paged-{kind}", "jnp", config, s=s, t=t)
-            k_all = _paged_gather(pk, layer, paged_table, page_size)
-            v_all = _paged_gather(pv, layer, paged_table, page_size)
-            attn = attention(q, k_all, v_all, mask, config)
         x = _attn_residual(
             x, quantized_matmul(attn, lp["wo"]), lp, config,
             _lora_proj(attn, "wo", lora, lora_scale, adapter_rows),
         )
-    return x, (pk, pv, *pik)
+    return x, leaves
+
+
+@contextlib.contextmanager
+def _attention_scope(sub: Optional[str] = None):
+    """``attention``, and inside it ``sub`` (``attention.window``, ``.full``)."""
+    with jax.named_scope("attention"), (
+        jax.named_scope(sub) if sub else contextlib.nullcontext()
+    ):
+        yield
+
+
+def _paged_attention(
+    q: jax.Array,  # [B, S, H, D]
+    k: jax.Array,  # [B, S, Hkv, D]: the S tokens' new rows, as are ``v``
+    v: jax.Array,
+    leaves: tuple,  # the pool's K and V [L, P, Hkv, ps, D] (int8: dicts), the indexer's keys
+    table: jax.Array,  # [B, Tp] physical page per logical page
+    positions: jax.Array,  # [B, S]
+    layer: jax.Array,  # scalar: the pool's layer read and written
+    page_size: int,
+    config: ModelConfig,
+    mask: Optional[jax.Array] = None,  # [B, S, T]; None: causal, within ``window``
+    window: int = 0,  # 0: none
+    lengths: Optional[jax.Array] = None,  # [B], where the caller has them
+    index: Optional[tuple] = None,  # the indexer's (queries, key, weights) of the S tokens
+    block: bool = False,  # a block pass: the S queries of a row see one another
+    verify: bool = False,
+    sub_scope: Optional[str] = None,  # the read's scope inside ``attention``
+) -> tuple[jax.Array, tuple]:
+    """A layer's new K/V rows reach the page pool and its queries read it:
+    (the attention's output [B, S, H*D], the pool's leaves whole, written at
+    ``layer``). The ONE place that decides, from S, the page size, the
+    pool's dtype and `paged_pallas_ok`, which kernel writes and which reads.
+    The write is ``kv_pool.write`` (a scope cannot be left from inside, so it
+    is outside ``attention``): ``paged_kv_write`` where the
+    decode kernel runs over a bf16 pool, a copy a live row, else
+    `_paged_scatter`; the indexer's key with them. The read: one query a row
+    through the paged decode kernel, from ``lengths - window`` on under a
+    window; a block pass's S queries (``block``: all see keys ``0 .. start +
+    S - 1``) through that kernel with S x group query rows a KV head
+    and no mask among them, their write the decode write over S rows of one
+    aligned tile; a model with an indexer through `_paged_selected_read`;
+    everything else over the row's gathered columns: S > 1 causal queries
+    (``mask`` None) through `_dispatch_attention`'s kernels, a caller's mask
+    (a block pass, verify, the sequential block's segment) and the backends
+    without kernels through its masked jnp."""
+    from langstream_tpu.ops import attention as ops
+
+    pk, pv, *pik = leaves
+    s = q.shape[1]
+    int8 = isinstance(pk, dict)
+    num_pages = (pk["q"] if int8 else pk).shape[1]
+    interpret = jax.default_backend() != "tpu"
+    decode_kernels = s == 1 and ops.paged_pallas_ok(config, page_size)
+    block_kernels = block and not int8 and ops.paged_pallas_ok(config, page_size)
+    # a block starts on a multiple of S inside a page: its S rows lie in one
+    # aligned tile of the pool
+    tile = block_kernels and ops.block_write_ok(s, page_size)
+    with jax.named_scope("kv_pool.write"):
+        if tile or (decode_kernels and not int8):
+            # a copy per LIVE row. The int8 pool (a token's scales are Hkv
+            # scattered words, no DMA Mosaic takes) and the other S > 1
+            # writers keep the scatter
+            pages, offs = _page_index(
+                table, positions[:, :1] if tile else positions, page_size, num_pages
+            )
+            rows = (
+                (k.reshape(k.shape[0], -1, k.shape[-1]), v.reshape(v.shape[0], -1, v.shape[-1]))
+                if tile else (k[:, 0], v[:, 0])
+            )
+            pk, pv = ops.paged_kv_write(
+                rows, pk, pv, pages[:, 0], offs[:, 0], layer, config, interpret=interpret
+            )
+        else:
+            kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            pk = _paged_scatter(pk, layer, kt, table, positions, page_size)
+            pv = _paged_scatter(pv, layer, vt, table, positions, page_size)
+        if index is not None:
+            pik = [_write_index_key(pik[0], layer, index[1], table, positions, page_size)]
+    with _attention_scope(sub_scope):
+        t = table.shape[1] * page_size
+        if index is not None:
+            attn = _paged_selected_read(
+                q, index[0], index[2], pk, pv, pik[0], table, layer, mask,
+                positions, config, page_size, decode_kernels,
+            )
+        elif decode_kernels or block_kernels:
+            if lengths is None:  # up to the query, or a block's last
+                lengths = _paged_lengths(
+                    table, positions[:, 0 if decode_kernels else -1], page_size, num_pages
+                )
+            if not decode_kernels:
+                ops.note_path("paged-block", "ragged_paged_block_attention", config, s=s, t=t)
+                attn = ops.ragged_paged_block_attention(
+                    q, pk, pv, lengths, table, layer, config, page_size, interpret=interpret
+                )
+            else:
+                kernel = (
+                    ops.ragged_paged_decode_attention_int8 if int8
+                    else ops.ragged_paged_decode_attention
+                )
+                ops.note_path("paged-decode", kernel.__name__, config, s=s, t=t)
+                q0 = q[:, 0]
+                # (no window model keeps an int8 pool, whose kernel takes no ``lower``)
+                bound = {"lower": jnp.maximum(lengths - window, 0)} if window else {}
+                attn = kernel(
+                    q0, pk, pv, lengths, table, layer, config, page_size,
+                    interpret=interpret, **bound,
+                )[:, None, :]
+        else:
+            k_all = _paged_gather(pk, layer, table, page_size)
+            v_all = _paged_gather(pv, layer, table, page_size)
+            what = "block" if block else "decode" if s == 1 else "verify" if verify else "segment"
+            attn = _dispatch_attention(
+                q, k_all, v_all, mask, config, mask is None, what=f"paged-{what}",
+                positions=positions, window=window, from_zero=False,
+            )
+    return attn, (pk, pv, *pik)
 
 
 # Columns of table a selected token: up to here a decode step WALKS the row's
@@ -1607,76 +1667,6 @@ def _linear_layer(x, lp, config, rec, layer, rctx):
     return y, rec
 
 
-def _scan_periods(
-    params, x, sin, cos, mask, config, *, cache=None, pool=None, rec=None,
-    rctx=None, cache_positions=None, paged_table=None, page_size=0,
-):
-    """The layer loop of a model with a layer pattern: a scan over its
-    periods whose body runs the period's layers in order, each kind from a
-    stack of its own. The carry is x, the page pool of the full-attention
-    layers (``pool``: updated in place, as in `_scan_layers_inplace`) and
-    the recurrent state of the linear ones; a local cache (``cache``, the
-    admit group's temporary) rides the xs and comes back as ys. Returns
-    (x, cache or pool's {"k", "v"}, rec)."""
-    pattern = config.layer_pattern
-    per = {kind: pattern.count(kind) for kind in set(pattern)}
-    periods = config.n_periods
-    stacks = params["layers"]
-    if cache is not None:
-        cache = jax.tree.map(
-            lambda a: a.reshape(periods, per["full_attention"], *a.shape[1:]), cache
-        )
-
-    def body(carry, inputs):
-        x, kv, rec = carry
-        cache_p, p = inputs
-        at = dict.fromkeys(per, 0)
-        new_cache = []
-        for kind in pattern:
-            i = at[kind]
-            at[kind] += 1
-            layer = p * per[kind] + i
-            # ONE layer's weights, sliced where they are used: the stacks are
-            # closed over, not scanned. A period's slice [per, ...] of a
-            # scanned stack is a buffer of its own, all of a period's weights
-            # copied once more a step (a third of the decode step on a v5e,
-            # PERF.md section 6, PR 32)
-            lp = jax.tree.map(
-                lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), stacks[kind]
-            )
-            if kind == "linear_attention":
-                x, rec = _linear_layer(x, lp, config, rec, layer, rctx)
-            elif kv is not None:
-                x, (nk, nv), _ = _layer_counted(
-                    x, lp, sin, cos, mask, config, cache_kv=(kv["k"], kv["v"]),
-                    cache_positions=cache_positions, paged_table=paged_table,
-                    page_size=page_size, layer=layer,
-                )
-                kv = {"k": nk, "v": nv}
-            else:
-                entry = None if cache_p is None else (
-                    jax.tree.map(lambda a: a[i], cache_p["k"]),
-                    jax.tree.map(lambda a: a[i], cache_p["v"]),
-                )
-                x, entry, _ = _layer_counted(
-                    x, lp, sin, cos, mask, config, cache_kv=entry,
-                    cache_positions=cache_positions,
-                )
-                new_cache.append(entry)
-        ys = None
-        if cache_p is not None:
-            ys = {
-                "k": jax.tree.map(lambda *a: jnp.stack(a), *[e[0] for e in new_cache]),
-                "v": jax.tree.map(lambda *a: jnp.stack(a), *[e[1] for e in new_cache]),
-            }
-        return (x, kv, rec), ys
-
-    (x, kv, rec), ys = lax.scan(body, (x, pool, rec), (cache, jnp.arange(periods)))
-    if cache is not None:
-        kv = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
-    return x, kv, rec
-
-
 # ---------------------------------------------------------------------------
 # Window and full attention layers in a parallel block, an expert layer that
 # holds a share (``config.has_window``; command-a-plus is the model). Each
@@ -1769,7 +1759,7 @@ def moe_ffn_held(
         ])
 
     def held_w(name: str) -> dict:
-        """[L, held, K, N]: the stack as `_scan_window_periods` hands it on
+        """[L, held, K, N]: the stack as `_scan_periods` hands it on
         (the product finds its layer there), or one layer's experts as a
         stack of one."""
         w = lp[name]
@@ -1814,58 +1804,26 @@ def moe_ffn_held(
     return out.reshape(b, s, d), counts
 
 
-def _kind_attention(u, lp, kind, sin, cos, config, positions, entry, layer, ctx):
-    """One layer's attention over the block's normed input ``u`` [B, S, d]:
-    (the output projection's result, before any residual; the kind's cache
-    leaves, written). A window layer turns q and k and sees keys
-    ``position - sliding_window + 1 .. position``; a full layer turns
-    nothing and sees everything behind it. ``entry`` None: no cache; with
-    ``ctx["table"]`` None a local cache [L, B, Hkv, T, D] written at
-    ``positions``; else the kind's page group, read and written through
-    ``ctx["table"]`` [B, Tp]."""
-    from langstream_tpu.ops import attention as ops
-
+def _parallel_layer(x, lp, kind, sin, cos, config, positions, entry, layer, ctx,
+                    token_valid=None):
+    """x + Attn(u) + MoE(u), u = norm(x): the one norm of a parallel block.
+    A window layer turns q and k and sees keys ``position - sliding_window +
+    1 .. position``; a full layer turns nothing and sees everything behind
+    it. ``entry``: the kind's cache leaves, and they come back written.
+    None: no cache; with ``ctx["table"]`` None a local cache [L, B, Hkv, T, D]
+    written at ``positions``; else the kind's page group, read and written
+    through ``ctx["table"]`` [B, Tp] (`_paged_attention`)."""
+    # the norm is computed in float32 either way: the router reads it before
+    # it is rounded to the activation dtype (a bf16 input moves a router logit
+    # by 0.002, enough to swap the 8th and 9th of 128 experts for one token in
+    # 65), every matrix product after it
+    u32 = _norm(x.astype(jnp.float32), lp["attn_norm"], config)
+    u = u32.astype(x.dtype)
     b, s, _ = u.shape
     hd, h, hkv = config.resolved_head_dim, config.n_heads, config.n_kv_heads
     window = config.sliding_window if kind == "sliding_attention" else 0
-    table, page_size = ctx.get("table"), ctx.get("page_size", 0)
-    interpret = jax.default_backend() != "tpu"
-
-    @contextlib.contextmanager
-    def scope():
-        with jax.named_scope("attention"), jax.named_scope(
-            "attention.window" if window else "attention.full"
-        ):
-            yield
-
-    def seen(t):  # [B, S, T]: what the jnp paths mask by
-        kv_pos = jnp.arange(t)[None, None, :]
-        mask = kv_pos <= positions[:, :, None]
-        if window:
-            mask = mask & (kv_pos > positions[:, :, None] - window)
-        if ctx.get("kv_limit") is not None:
-            mask = mask & (kv_pos < ctx["kv_limit"])
-        return mask
-
-    def blocked(k_all, v_all, what):
-        """S > 1 queries over a row's columns [B, Hkv, T, D]: a kernel that
-        never holds the scores where the shapes fit, else the masked jnp."""
-        t = k_all.shape[2]
-        if ops.pallas_ok(config, s) and t % min(128, t) == 0:
-            from_zero = ctx.get("from_zero", False)
-            if from_zero and (not window or s <= window) and t >= s:
-                ops.note_path(what, "flash_prefill_attention", config, s=s, t=t)
-                return ops.flash_prefill_attention(
-                    q, k_all[:, :, :s], v_all[:, :, :s], config, interpret=interpret
-                )
-            ops.note_path(what, "flash_segment_attention", config, s=s, t=t)
-            return ops.flash_segment_attention(
-                q, k_all, v_all, positions[:, 0], config, window=window,
-                interpret=interpret,
-            )
-        ops.note_path(what, "jnp", config, s=s, t=t)
-        return attention(q, k_all, v_all, seen(t), config)
-
+    sub_scope = "attention.window" if window else "attention.full"
+    scope = functools.partial(_attention_scope, sub_scope)
     with scope():
         q = quantized_matmul(u, lp["wq"]).reshape(b, s, h, hd)
         k = quantized_matmul(u, lp["wk"]).reshape(b, s, hkv, hd)
@@ -1873,126 +1831,169 @@ def _kind_attention(u, lp, kind, sin, cos, config, positions, entry, layer, ctx)
         if window:
             turn = apply_rope_interleaved if config.rope_interleaved else apply_rope
             q, k = turn(q, sin, cos), turn(k, sin, cos)
-        kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-    if entry is None:
-        with scope():
-            attn = blocked(kt, vt, "prefill")
-    elif table is None:
-        ck, cv = entry
-        with scope():
-            at = (
-                layer, jnp.arange(b)[:, None, None], jnp.arange(hkv)[None, :, None],
-                positions[:, None, :],
-            )
-            ck, cv = ck.at[at].set(kt), cv.at[at].set(vt)
-            k_all = lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
-            v_all = lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
-            attn = blocked(k_all, v_all, "prefill") if s > 1 else attention(
-                q, k_all, v_all, seen(k_all.shape[2]), config
-            )
-        entry = (ck, cv)
+    if entry is not None and ctx.get("table") is not None:
+        attn, entry = _paged_attention(
+            q, k, v, entry, ctx["table"], positions, layer, ctx["page_size"], config,
+            window=window, lengths=ctx["lengths"], sub_scope=sub_scope,
+        )
     else:
-        pk, pv = entry
-        num_pages = pk.shape[1]
-        decode = s == 1 and ops.paged_pallas_ok(config, page_size)
-        with jax.named_scope("kv_pool.write"):
-            if decode:
-                pages, offs = _page_index(table, positions, page_size, num_pages)
-                pk, pv = ops.paged_kv_write(
-                    (k[:, 0], v[:, 0]), pk, pv, pages[:, 0], offs[:, 0], layer, config,
-                    interpret=interpret,
-                )
-            else:
-                pk = _paged_scatter(pk, layer, kt, table, positions, page_size)
-                pv = _paged_scatter(pv, layer, vt, table, positions, page_size)
         with scope():
-            t = table.shape[1] * page_size
-            if decode:
-                lengths = ctx["lengths"]
-                ops.note_path(
-                    "paged-decode", "ragged_paged_decode_attention", config, s=s, t=t
+            k_all, v_all = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
+            if entry is not None:
+                # the local cache rides the period loop's carry whole and is
+                # written in place at ``layer``. `_dense_attention` writes an
+                # entry a layer (the sequential block's rides the scan's xs
+                # and ys) and forms its indices before the transposes: one
+                # code for both reorders one model's admission program
+                at = (
+                    layer, jnp.arange(b)[:, None, None], jnp.arange(hkv)[None, :, None],
+                    positions[:, None, :],
                 )
-                attn = ops.ragged_paged_decode_attention(
-                    q[:, 0], pk, pv, lengths, table, layer, config, page_size,
-                    interpret=interpret,
-                    lower=jnp.maximum(lengths - window, 0) if window else None,
-                )[:, None, :]
-            else:
-                k_all = _paged_gather(pk, layer, table, page_size)
-                v_all = _paged_gather(pv, layer, table, page_size)
-                if s > 1:
-                    attn = blocked(k_all, v_all, "paged-segment")
-                else:
-                    ops.note_path("paged-decode", "jnp", config, s=s, t=t)
-                    attn = attention(q, k_all, v_all, seen(t), config)
-        entry = (pk, pv)
+                ck, cv = entry[0].at[at].set(k_all), entry[1].at[at].set(v_all)
+                k_all = lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
+                v_all = lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
+                entry = (ck, cv)
+            attn = _dispatch_attention(
+                q, k_all, v_all, None, config, True, what="prefill", positions=positions,
+                window=window, from_zero=ctx.get("from_zero", False),
+                kv_limit=ctx.get("kv_limit"),
+            )
     with scope():
-        return quantized_matmul(attn, lp["wo"]), entry
-
-
-def _parallel_layer(x, lp, kind, sin, cos, config, positions, entry, layer, ctx,
-                    token_valid=None):
-    """x + Attn(u) + MoE(u), u = norm(x): the one norm of a parallel block."""
-    # the norm is computed in float32 either way: the router reads it before
-    # it is rounded to the activation dtype (a bf16 input moves a router logit
-    # by 0.002, enough to swap the 8th and 9th of 128 experts for one token in
-    # 65), every matrix product after it
-    u32 = _norm(x.astype(jnp.float32), lp["attn_norm"], config)
-    u = u32.astype(x.dtype)
-    attn, entry = _kind_attention(u, lp, kind, sin, cos, config, positions, entry, layer, ctx)
+        attn = quantized_matmul(attn, lp["wo"])
     with jax.named_scope("moe_ffn"):
         ffn, counts = moe_ffn_held(u, lp, config, token_valid, layer, route_on=u32)
     return x + attn + ffn, entry, counts
 
 
-def _scan_window_periods(
-    params, x, sin, cos, config, positions, state=None, tables=None,
-    page_size=0, token_valid=None, lengths=None, kv_limit=None, from_zero=False,
-):
-    """The layer loop of a model with window layers: a scan over its periods
-    whose body runs the period's layers in order, each kind from a stack of
-    its own (sliced where it is used, as `_scan_periods` does). ``state``:
-    None, a local cache or the page pool, both kinds' entries, carried and
-    written in place. Returns (x, state, the layers' summed
-    MOE_HELD_COUNTS)."""
+# ---------------------------------------------------------------------------
+# The period loop: how the layers of a model with ``config.layer_pattern``
+# are walked. A KIND of layer is a function (x, lp, kind, layer, entry, rec,
+# config, walk) -> (x, entry, rec, the layer's expert counts or None) and a
+# row of `_kind_layers`: ``lp`` the layer's weights, ``layer`` its index among
+# its kind's, ``entry`` its kind's cache leaves (`_kind_entry`; None without a
+# cache and for a kind that keeps none), ``rec`` the recurrent state, ``walk``
+# what is the same for every layer (`_run_layers`).
+# ---------------------------------------------------------------------------
+
+
+def _linear_kind(x, lp, kind, layer, entry, rec, config, walk):
+    x, rec = _linear_layer(x, lp, config, rec, layer, walk["rctx"])
+    return x, entry, rec, None
+
+
+def _sequential_kind(x, lp, kind, layer, entry, rec, config, walk):
+    """The sequential block as a pattern's full-attention layer
+    (Olmo-Hybrid's): over the pool's pages where there is a table, else over
+    ITS entry of the local cache."""
+    x, entry, _ = _layer_counted(
+        x, lp, walk["sin"], walk["cos"], walk["mask"], config, cache_kv=entry,
+        cache_positions=walk["positions"], paged_table=walk["tables"],
+        page_size=walk["page_size"], layer=layer,
+    )
+    return x, entry, rec, None
+
+
+def _parallel_kind(x, lp, kind, layer, entry, rec, config, walk):
+    """The parallel block (command-a-plus's), a window or a full layer, each
+    through its kind's table."""
+    tables = walk["tables"]
+    ctx = {
+        "table": None if tables is None else tables[WINDOW if _KIND_KEY[kind] else FULL],
+        "page_size": walk["page_size"], "lengths": walk["lengths"],
+        "kv_limit": walk["kv_limit"], "from_zero": walk["from_zero"],
+    }
+    x, entry, counts = _parallel_layer(
+        x, lp, kind, walk["sin"], walk["cos"], config, walk["positions"], entry, layer,
+        ctx, walk["token_valid"],
+    )
+    return x, entry, rec, counts
+
+
+def _kind_layers(config: ModelConfig) -> dict:
+    """kind -> its layer function. What an attention kind is follows the
+    model's block: the parallel one beside window layers, else the
+    sequential one."""
+    attention = _parallel_kind if config.has_window else _sequential_kind
+    return {
+        "linear_attention": _linear_kind, "full_attention": attention,
+        "sliding_attention": attention,
+    }
+
+
+def _scan_periods(params, x, config, state=None, rec=None, **walk):
+    """The layer loop of a model with a layer pattern: a scan over its
+    periods whose body runs the period's layers in order, each kind from a
+    stack of its own and through its function (`_kind_layers`). The carry is
+    (x, state, rec, the summed expert counts), None where a model has none:
+    the page pool, or the parallel block's local cache, carried and written
+    in place as in `_scan_layers_inplace`, the recurrent state beside it.
+    The sequential block writes a local cache an entry a layer: its local
+    cache (``state`` without tables, the admit group's temporary) rides the
+    xs and comes back as ys. Returns (x, state, rec, counts)."""
     pattern = config.layer_pattern
     per = {kind: pattern.count(kind) for kind in set(pattern)}
+    periods = config.n_periods
     stacks = params["layers"]
-    group = {"full_attention": FULL, "sliding_attention": WINDOW}
+    layers = _kind_layers(config)
+    # held experts' weights go on as the stack: the grouped product reads its
+    # blocks at (layer, expert) where they lie
+    whole = _HELD_EXPERTS if config.holds_experts else ()
+    local = None
+    if state is not None and walk["tables"] is None and not config.has_window:
+        local, state = jax.tree.map(
+            lambda a: a.reshape(periods, per["full_attention"], *a.shape[1:]), state
+        ), None
 
-    def body(carry, p):
-        x, state, counts = carry
+    def body(carry, inputs):
+        x, state, rec, counts = carry
+        local_p, p = inputs
         at = dict.fromkeys(per, 0)
+        written = []
         for kind in pattern:
-            layer = p * per[kind] + at[kind]
+            i = at[kind]
             at[kind] += 1
-            # the held experts' weights go on as the stack: the grouped
-            # product reads its blocks at (layer, expert) where they lie
+            layer = p * per[kind] + i
+            # ONE layer's weights, sliced where they are used: the stacks are
+            # closed over, not scanned. A period's slice [per, ...] of a
+            # scanned stack is a buffer of its own, all of a period's weights
+            # copied once more a step (a third of the decode step on a v5e,
+            # PERF.md section 6, PR 32)
             lp = {
-                key: leaf if key in _HELD_EXPERTS else jax.tree.map(
+                key: leaf if key in whole else jax.tree.map(
                     lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), leaf
                 )
                 for key, leaf in stacks[kind].items()
             }
-            ctx = {
-                "table": None if tables is None else tables[group[kind]],
-                "page_size": page_size, "lengths": lengths, "kv_limit": kv_limit,
-                "from_zero": from_zero,
-            }
-            entry = None if state is None else _kind_entry(state, kind)
-            x, entry, c = _parallel_layer(
-                x, lp, kind, sin, cos, config, positions, entry, layer, ctx, token_valid
-            )
-            if state is not None:
+            entry = None
+            if kind in _KIND_KEY and local_p is not None:
+                entry = (
+                    jax.tree.map(lambda a: a[i], local_p["k"]),
+                    jax.tree.map(lambda a: a[i], local_p["v"]),
+                )
+            elif kind in _KIND_KEY and state is not None:
+                entry = _kind_entry(state, kind)
+            x, entry, rec, c = layers[kind](x, lp, kind, layer, entry, rec, config, walk)
+            if c is not None:
+                counts = counts + c
+            if entry is not None and local_p is not None:
+                written.append(entry)
+            elif entry is not None:
                 state = _with_entry(state, kind, entry)
-            counts = counts + c
-        return (x, state, counts), None
+        ys = None
+        if local_p is not None:
+            ys = {
+                "k": jax.tree.map(lambda *a: jnp.stack(a), *[e[0] for e in written]),
+                "v": jax.tree.map(lambda *a: jnp.stack(a), *[e[1] for e in written]),
+            }
+        return (x, state, rec, counts), ys
 
-    zero = jnp.zeros(len(MOE_HELD_COUNTS), jnp.int32)
-    (x, state, counts), _ = lax.scan(
-        body, (x, state, zero), jnp.arange(config.n_periods)
+    counts = jnp.zeros(len(moe_count_names(config)), jnp.int32) if config.is_moe else None
+    (x, state, rec, counts), ys = lax.scan(
+        body, (x, state, rec, counts), (local, jnp.arange(periods))
     )
-    return x, state, counts
+    if local is not None:
+        state = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
+    return x, state, rec, counts
 
 
 def _embed(params: Params, tokens: jax.Array, config: ModelConfig) -> jax.Array:
@@ -2038,7 +2039,7 @@ def _split_lora(lora: Optional[dict]):
 def _split_held(layers: dict, config: ModelConfig):
     """(the leaves a layer scan slices, the leaves it hands on whole). Where
     the sequential block holds its experts (``experts_held``) their weights
-    go on as the stack [L, held, K, N], as `_scan_window_periods` hands them
+    go on as the stack [L, held, K, N], as `_scan_periods` hands them
     on: the grouped product reads its blocks at (layer, expert) where they
     lie, and a kernel's operand sliced by the scan would be copied first, a
     layer's experts a layer. Every other model: all of them sliced, as ever."""
@@ -2067,40 +2068,23 @@ def _scan_layers(
     moe = config.is_moe
     # the layer's index rides the scan only where held experts need it
     index = None if held is None else jnp.arange(config.n_layers)
-
-    def whole(lp):
-        return lp if held is None else {**lp, **held}
-
-    if cache is None:
-
-        def body(carry, inputs):
-            lp, l = inputs
-            y, kv, counts = _layer_counted(
-                carry, whole(lp), sin, cos, mask, config, causal=causal,
-                collect_kv=collect_kv, token_valid=token_valid, moe_layer=l,
-                cache_positions=cache_positions,
-            )
-            return y, (kv, counts if moe else None)
-
-        x, (kvs, counts) = lax.scan(body, x, (layers, index))
-        return x, kvs, counts.sum(0) if moe else _no_moe_counts()
-
     leaves = config.page_leaves
 
-    def body_cached(carry, inputs):
+    def body(carry, inputs):
         lp, entry, ll, l = inputs
         y, new_kv, counts = _layer_counted(
-            carry, whole(lp), sin, cos, mask, config, cache_kv=entry,
-            cache_positions=cache_positions, lora=ll, lora_scale=lora_scale,
+            carry, lp if held is None else {**lp, **held}, sin, cos, mask, config,
+            cache_kv=entry, cache_positions=cache_positions, causal=causal,
+            collect_kv=collect_kv, lora=ll, lora_scale=lora_scale,
             adapter_rows=adapter_rows, token_valid=token_valid, moe_layer=l,
         )
         return y, (new_kv, counts if moe else None)
 
-    x, (new_kv, counts) = lax.scan(
-        body_cached, x, (layers, tuple(cache[leaf] for leaf in leaves), lora_layers, index)
-    )
-    counts = counts.sum(0) if moe else _no_moe_counts()
-    return x, dict(zip(leaves, new_kv)), counts
+    entries = None if cache is None else tuple(cache[leaf] for leaf in leaves)
+    x, (new_kv, counts) = lax.scan(body, x, (layers, entries, lora_layers, index))
+    if cache is not None:
+        new_kv = dict(zip(leaves, new_kv))
+    return x, new_kv, counts.sum(0) if moe else _no_moe_counts()
 
 
 def _scan_layers_inplace(
@@ -2148,6 +2132,62 @@ def _scan_layers_inplace(
     return x, pool, counts
 
 
+def _run_layers(
+    params, x, sin, cos, mask, config, positions, state=None, *, table=None, page_size=0,
+    row_positions=None, valid=None, counted=None, rec_rows=None, fresh=True,
+    from_zero=False, kv_limit=None, lora=None, adapter_rows=None,
+):
+    """Walk a model's layers, whichever loop is theirs: the ONE place the
+    entry points' paths part. ``state``: None, a local cache, or (with
+    ``table``) the page pool, a recurrent state riding with either as
+    ``"rec"`` (`join_rec`); it comes back written. ``valid`` [B, S]: each
+    row's real tokens, a prefix (what a recurrent state may take in), and
+    ``counted`` those the expert counts call real; None: all of them. A
+    decode step gives ``row_positions`` [B] instead, and a pattern model's
+    row is real where its table maps its position (the uniform loop counts
+    every row, as it has: S5). ``rec_rows``, ``fresh``: the recurrent
+    state's rows and which start from zero (`_linear_attention_block`).
+    Returns (x, state, the layers' summed counts, `moe_count_names`)."""
+    if not config.layer_pattern:
+        # the stacks ride the scan's xs here and are closed over in the
+        # period loop: folding the two is a change of these models' programs
+        # (ROADMAP D15)
+        if table is None:
+            return _scan_layers(
+                params, x, sin, cos, mask, config, cache=state, cache_positions=positions,
+                lora=lora, adapter_rows=adapter_rows, token_valid=counted,
+            )
+        # a segment's counts reach no result where the model returns none
+        # (`paged_prefill_segment_inplace`): dead code, and none of it lowers
+        return _scan_layers_inplace(
+            params, x, sin, cos, mask, config, state, positions, table, page_size,
+            lora=lora, adapter_rows=adapter_rows, token_valid=counted,
+        )
+    kv, rec = (None, None) if state is None else split_rec(state)
+    lengths = None
+    if row_positions is not None:
+        # the row's live length, from the full group's table (a prefix of
+        # mapped pages); a window layer reads its last ``sliding_window``. A
+        # row whose table maps nothing, or that has stepped past its pages,
+        # is idle: its recurrent state stays as it is
+        pages = kv["k"]["q"] if isinstance(kv["k"], dict) else kv["k"]
+        lengths = _paged_lengths(
+            table[FULL] if config.has_window else table, row_positions, page_size,
+            pages.shape[1],
+        )
+        valid = counted = (lengths > row_positions)[:, None]
+    x, kv, rec, counts = _scan_periods(
+        params, x, config, kv, rec, sin=sin, cos=cos, mask=mask, positions=positions,
+        tables=table, page_size=page_size, lengths=lengths, token_valid=counted,
+        rctx=None if valid is None else {"rows": rec_rows, "valid": valid, "fresh": fresh},
+        from_zero=from_zero, kv_limit=kv_limit,
+    )
+    return (
+        x, None if state is None else join_rec(kv, rec),
+        _no_moe_counts() if counts is None else counts,
+    )
+
+
 # ---------------------------------------------------------------------------
 # Public entry points (all jittable; config is static)
 # ---------------------------------------------------------------------------
@@ -2189,17 +2229,9 @@ def forward(
         mask = _visible(jnp.arange(s)[:, None], jnp.arange(s)[None, :], config)[None]
     mask = jnp.broadcast_to(mask, (b, s, s))
     x = _embed(params, tokens, config)
-    if config.has_window:
-        x, _, _ = _scan_window_periods(
-            params, x, sin, cos, config, positions, from_zero=True
-        )
-    elif config.layer_pattern:
-        x, _, _ = _scan_periods(params, x, sin, cos, mask, config)
-    else:
-        x, _, _ = _scan_layers(
-            params, x, sin, cos, mask, config,
-            cache_positions=_text_positions(positions) if config.has_indexer else None,
-        )
+    x, _, _ = _run_layers(
+        params, x, sin, cos, mask, config, _text_positions(positions), from_zero=True
+    )
     return _unembed(params, x, config)
 
 
@@ -2240,34 +2272,34 @@ def make_kv_cache(
     symmetric scales; ~2x less decode cache bandwidth).
     """
     dtype = dtype or _dtype(config)
-    shape = (
-        config.n_layers_of("full_attention"), batch, config.n_kv_heads, max_len,
-        config.resolved_head_dim,
-    )
-    if config.has_window:
-        # the window layers' entries beside the full layers', ``"win"``: the
-        # same leaves over ``window_batch`` rows (a page pool's window group
-        # has its own number of pages)
+
+    def leaves(kind: str, rows: int) -> KVCache:
+        shape = (
+            config.n_layers_of(kind), rows, config.n_kv_heads, max_len,
+            config.resolved_head_dim,
+        )
         if config.kv_cache_dtype == "int8":
-            raise NotImplementedError(f"an int8 KV cache for window layers ({config.name})")
-        win = (config.n_layers_of("sliding_attention"), window_batch or batch) + shape[2:]
-        return {
-            "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            "win": {"k": jnp.zeros(win, dtype), "v": jnp.zeros(win, dtype)},
-        }
-    if config.has_indexer:
-        # the indexer's key a token beside K and V: [L, B, T, Di], one head
-        return {
-            "k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype),
-            "ik": jnp.zeros((*shape[:2], max_len, config.index_key_width), dtype),
-        }
-    if config.kv_cache_dtype == "int8":
-        entry = lambda: {  # noqa: E731
-            "q": jnp.zeros(shape, jnp.int8),
-            "s": jnp.full(shape[:-1], 1e-8 / 127.0, jnp.float32),
-        }
-        return {"k": entry(), "v": entry()}
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+            if _KIND_KEY[kind]:
+                raise NotImplementedError(f"an int8 KV cache for window layers ({config.name})")
+            entry = lambda: {  # noqa: E731
+                "q": jnp.zeros(shape, jnp.int8),
+                "s": jnp.full(shape[:-1], 1e-8 / 127.0, jnp.float32),
+            }
+            return {"k": entry(), "v": entry()}
+        cache = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+        if config.has_indexer:
+            # the indexer's key a token beside K and V: [L, B, T, Di], one head
+            cache["ik"] = jnp.zeros((*shape[:2], max_len, config.index_key_width), dtype)
+        return cache
+
+    cache = leaves("full_attention", batch)
+    for kind, key in _KIND_KEY.items():
+        if key and config.n_layers_of(kind):
+            # the window layers' entries beside the full layers', ``"win"``:
+            # the same leaves over ``window_batch`` rows (a page pool's
+            # window group has its own number of pages)
+            cache[key] = leaves(kind, window_batch or batch)
+    return cache
 
 
 @functools.partial(
@@ -2306,30 +2338,12 @@ def prefill(
     mask = _visible(q_pos[:, :, None], kv_pos, config)
     mask = mask & (kv_pos < s)
     x = _embed(params, tokens, config)
-    if config.has_window:
-        x, cache, counts = _scan_window_periods(
-            params, x, sin, cos, config, positions, state=cache, kv_limit=s,
-            from_zero=True,
-            token_valid=positions
-            < (lengths if real_lengths is None else real_lengths)[:, None],
-        )
-    elif config.layer_pattern:
-        # the recurrent state rides in with the cache and out with it, as it
-        # rides with the page pool: ``cache["rec"]`` (`join_rec`)
-        cache, rec = split_rec(cache)
-        rctx = {"rows": rec_rows, "valid": positions < lengths[:, None], "fresh": True}
-        x, cache, rec = _scan_periods(
-            params, x, sin, cos, mask, config, cache=cache, rec=rec, rctx=rctx,
-            cache_positions=positions,
-        )
-        cache, counts = join_rec(cache, rec), _no_moe_counts()
-    else:
-        x, cache, counts = _scan_layers(
-            params, x, sin, cos, mask, config, cache=cache, cache_positions=positions,
-            lora=lora, adapter_rows=adapter_rows,
-            token_valid=positions
-            < (lengths if real_lengths is None else real_lengths)[:, None],
-        )
+    real = lengths if real_lengths is None else real_lengths
+    x, cache, counts = _run_layers(
+        params, x, sin, cos, mask, config, positions, cache,
+        valid=positions < lengths[:, None], counted=positions < real[:, None],
+        rec_rows=rec_rows, from_zero=True, kv_limit=s, lora=lora, adapter_rows=adapter_rows,
+    )
     last = jnp.clip(lengths - 1, 0, s - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]  # [B, D]
     logits = _unembed(params, x_last[:, None, :], config)[:, 0]
@@ -2350,8 +2364,7 @@ def decode_step(
     t = cache_width(cache)
     pos2 = positions[:, None]  # [B, 1]
     sin, cos = _rope_freqs(pos2, config)
-    kv_pos = jnp.arange(t)[None, None, :]
-    mask = kv_pos <= pos2[:, :, None]  # attend to everything written ≤ position
+    mask = _seen(pos2, t)  # attend to everything written ≤ position
     x = _embed(params, tokens[:, None], config)
     x, cache, _ = _scan_layers(
         params, x, sin, cos, mask, config, cache=cache, cache_positions=pos2
@@ -2374,9 +2387,7 @@ def _paged_mask(table: jax.Array, page_size: int, positions: jax.Array):
     is visible to query j iff t <= positions[b, j]. Columns backed by
     unmapped (clamp-gathered garbage) pages always sit past the written
     frontier, so the mask is also what makes the clamped gather safe."""
-    t = table.shape[1] * page_size
-    kv_pos = jnp.arange(t)[None, None, :]
-    return kv_pos <= positions[:, :, None]
+    return _seen(positions, table.shape[1] * page_size)
 
 
 def paged_decode_step_inplace(
@@ -2400,32 +2411,10 @@ def paged_decode_step_inplace(
     sin, cos = _rope_freqs(pos2, config)
     mask = _paged_mask(table, page_size, pos2)
     x = _embed(params, tokens[:, None], config)
-    if config.has_window:
-        # the row's live length, from the full group's table (a prefix of
-        # mapped pages); a window layer reads its last ``sliding_window``
-        lengths = _paged_lengths(table[FULL], positions, page_size, pool["k"].shape[1])
-        x, pool, counts = _scan_window_periods(
-            params, x, sin, cos, config, pos2, state=pool, tables=table,
-            page_size=page_size, lengths=lengths,
-            token_valid=(lengths > positions)[:, None],
-        )
-    elif config.layer_pattern:
-        # batch row b steps state row b. A row whose table maps nothing, or
-        # that has stepped past its pages, is idle: its state stays as it is
-        kv, rec = split_rec(pool)
-        num_pages = (kv["k"]["q"] if isinstance(kv["k"], dict) else kv["k"]).shape[1]
-        live = _paged_lengths(table, positions, page_size, num_pages) > positions
-        x, kv, rec = _scan_periods(
-            params, x, sin, cos, mask, config, pool=kv, rec=rec,
-            rctx={"rows": None, "valid": live[:, None], "fresh": None},
-            cache_positions=pos2, paged_table=table, page_size=page_size,
-        )
-        pool, counts = join_rec(kv, rec), _no_moe_counts()
-    else:
-        x, pool, counts = _scan_layers_inplace(
-            params, x, sin, cos, mask, config, pool, pos2, table, page_size,
-            lora=lora, adapter_rows=adapter_rows,
-        )
+    x, pool, counts = _run_layers(
+        params, x, sin, cos, mask, config, pos2, pool, table=table, page_size=page_size,
+        row_positions=positions, fresh=None, lora=lora, adapter_rows=adapter_rows,
+    )
     logits = _unembed(params, x, config)[:, 0]
     return (logits, pool, counts) if moe_counts else (logits, pool)
 
@@ -2547,44 +2536,18 @@ def paged_prefill_segment_inplace(
     sin, cos = _rope_freqs(positions, config)
     mask = _paged_mask(table, page_size, positions)
     x = _embed(params, tokens, config)
-    counts = None
-    if config.has_window:
-        x, pool, counts = _scan_window_periods(
-            params, x, sin, cos, config, positions, state=pool, tables=table,
-            page_size=page_size,
-            token_valid=jnp.arange(s)[None, :] < seg_lengths[:, None],
-        )
-    elif config.layer_pattern:
-        # the recurrent state carries over from the row's earlier segments;
-        # a segment at offset 0 starts it from zero
-        kv, rec = split_rec(pool)
-        rctx = {
-            "rows": state_rows,
-            "valid": jnp.arange(s)[None, :] < seg_lengths[:, None],
-            "fresh": offsets == 0,
-        }
-        x, kv, rec = _scan_periods(
-            params, x, sin, cos, mask, config, pool=kv, rec=rec, rctx=rctx,
-            cache_positions=positions, paged_table=table, page_size=page_size,
-        )
-        pool = join_rec(kv, rec)
-    else:
-        x, pool, held_counts = _scan_layers_inplace(
-            params, x, sin, cos, mask, config, pool, positions, table, page_size,
-            lora=lora, adapter_rows=adapter_rows,
-            # the sequential block's no-drop expert layer counts real tokens;
-            # every other model's segment is the program it was
-            token_valid=jnp.arange(s)[None, :] < seg_lengths[:, None]
-            if config.experts_held else None,
-        )
-        if config.experts_held:
-            counts = held_counts
+    valid = jnp.arange(s)[None, :] < seg_lengths[:, None]
+    # the recurrent state carries over from the row's earlier segments; a
+    # segment at offset 0 starts it from zero
+    x, pool, counts = _run_layers(
+        params, x, sin, cos, mask, config, positions, pool, table=table,
+        page_size=page_size, valid=valid, counted=valid, rec_rows=state_rows,
+        fresh=offsets == 0, lora=lora, adapter_rows=adapter_rows,
+    )
     last = jnp.clip(seg_lengths - 1, 0, s - 1)
     x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
     logits = _unembed(params, x_last[:, None, :], config)[:, 0]
-    if moe_counts:
-        return logits, pool, _no_moe_counts() if counts is None else counts
-    return logits, pool
+    return (logits, pool, counts) if moe_counts else (logits, pool)
 
 
 def insert_copies_pages(

@@ -310,25 +310,27 @@ def test_no_assignment_of_a_real_token_is_dropped(params):
 # -- (v) faults: each fails by a number ---------------------------------------
 
 
-def _kind_attention_with(change):
-    sound = T._kind_attention
+def _parallel_layer_with(change):
+    """The layer under another kind or window: neither is read outside its
+    attention (the norm, the router and the experts read neither)."""
+    sound = T._parallel_layer
 
-    def faulted(u, lp, kind, sin, cos, config, *rest):
+    def faulted(x, lp, kind, sin, cos, config, *rest):
         kind, config = change(kind, config)
-        return sound(u, lp, kind, sin, cos, config, *rest)
+        return sound(x, lp, kind, sin, cos, config, *rest)
 
     return faulted
 
 
 def window_mask_off(monkeypatch):
-    monkeypatch.setattr(T, "_kind_attention", _kind_attention_with(
+    monkeypatch.setattr(T, "_parallel_layer", _parallel_layer_with(
         lambda kind, config: (kind, dataclasses.replace(config, sliding_window=1 << 20))
     ))
 
 
 def rotary_on_a_full_layer(monkeypatch):
     # a full layer run as a window layer whose window holds everything
-    monkeypatch.setattr(T, "_kind_attention", _kind_attention_with(
+    monkeypatch.setattr(T, "_parallel_layer", _parallel_layer_with(
         lambda kind, config: ("sliding_attention", config) if kind == "sliding_attention"
         else ("sliding_attention", dataclasses.replace(config, sliding_window=1 << 20))
     ))

@@ -352,7 +352,7 @@ def test_prefill_exception_fails_request_not_engine(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# MoE serving (BASELINE config #5: mixtral-style expert routing under the
+# MoE serving (mixtral-style expert routing under the
 # continuous batcher — KV slots, admission, and capacity-factor dispatch
 # interacting, not just the exactness-tested moe_ffn forward)
 # ---------------------------------------------------------------------------

@@ -1,4 +1,4 @@
-"""BASELINE config #4 — the full RAG app on-platform with zero external
+"""The full RAG app on-platform with zero external
 calls: directory source → text extract → split → TPU embeddings → embedded
 vector store; then question → embed → vector search → MMR re-rank → TPU
 chat completion. (The shipped example uses webcrawler-source; this test

@@ -158,7 +158,7 @@ def test_loopback_moe_lockstep_on_expert_mesh():
     """MoE decode under SPMD: leader + follower engines on the SAME
     expert×model mesh (mixtral-style ep×tp sharding), every dispatch
     announced over the channel — device state bit-identical after serving.
-    This is the multi-host story for BASELINE config #5."""
+    This is the multi-host story for the Mixtral MoE serving path."""
     from langstream_tpu.parallel.mesh import build_mesh
     from langstream_tpu.parallel.sharding import shard_params
 

@@ -1000,32 +1000,233 @@ DECODE_PROGRAMS_AT_PARENT = {
 }
 
 
-def _decode_program_text(case: str) -> str:
+# Every other engine program a cell runs, for the same cases where the model
+# has the program, and the indexer's preset in every row: the text the parent
+# gave (commit 0089f57, PR 45), taken by the code below before ISSUE 46 moved
+# a line of models/transformer.py. "segment": `_paged_segment_and_sample`;
+# "admit": the admission group (`_make_paged_admit_group()`; a model that
+# fills blocks has `_block_admit_group` and no segment).
+ENGINE_PROGRAMS_AT_PARENT = {
+    "tiny-sparse-moe-test": "2148456a5a9418be",
+    "segment/tiny-test": "17b4532d195db002",
+    "segment/tiny-test-int8": "5442e93148fd62a0",
+    "segment/tiny-moe-test": "4d69d76d663c6419",
+    "segment/tiny-hybrid-test": "88f390ad18e03100",
+    "segment/tiny-window-moe-test": "5faf99eba088cd8f",
+    "segment/tiny-sparse-moe-test": "6150db6ae34db846",
+    "admit/tiny-test": "a8dfcebd92d7ec65",
+    "admit/tiny-test-int8": "2a0a61a7fd0843a9",
+    "admit/tiny-moe-test": "498b293e7ebb210d",
+    "admit/tiny-hybrid-test": "86241862dfcc1760",
+    "admit/tiny-window-moe-test": "21f120a3f856fe24",
+    "admit/tiny-sparse-moe-test": "b2db2c60957a39ee",
+    "admit/tiny-blockfill-moe-test": "5a6863a3f918125c",
+}
+
+
+TINY_ROWS, TINY_PAGE = 4, 8
+
+
+def _i32(*shape):
+    return SDS(shape, jnp.int32)
+
+
+def _tiny_case(case: str, impl: str):
+    """(config, params, pool, tables) of a tiny preset ("-int8": over an int8
+    pool) as shapes: 4 slots, 24 pages of 8, a table of 6 pages a row;
+    ``tables(rows)`` is the paged entry points' table argument."""
     from langstream_tpu.models.transformer import init_params, make_page_pool
-    from langstream_tpu.serving import engine as E
 
     name, int8 = case.removesuffix("-int8"), case.endswith("-int8")
     config = dataclasses.replace(
-        MODEL_PRESETS[name], attention_impl="pallas",
+        MODEL_PRESETS[name], attention_impl=impl,
         kv_cache_dtype="int8" if int8 else MODEL_PRESETS[name].kv_cache_dtype,
     )
-    b, page, table, pages = 4, 8, 6, 24
-    key = SDS((2,), jnp.uint32)
-    params = jax.eval_shape(lambda k: init_params(config, k), key)
-    pool = jax.eval_shape(lambda: make_page_pool(config, pages, page, state_rows=b))
-    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
-    tables = i32(2, b, table) if config.n_layers_of("sliding_attention") else i32(b, table)
+    params = jax.eval_shape(lambda k: init_params(config, k), SDS((2,), jnp.uint32))
+    pool = jax.eval_shape(
+        lambda: make_page_pool(config, 24, TINY_PAGE, state_rows=TINY_ROWS)
+    )
+
+    def tables(rows):
+        return _i32(2, rows, 6) if config.has_window else _i32(rows, 6)
+
+    return config, params, pool, tables
+
+
+def _engine_program_text(case: str) -> str:
+    """The lowered text of one engine program of one tiny preset, kernels in
+    interpret mode: ``case`` is a preset's name ("-int8": over an int8 pool),
+    the decode (or block) chunk, or "segment/<name>", "admit/<name>"."""
+    from langstream_tpu.serving import engine as E
+
+    program, _, case = case.rpartition("/")
+    config, params, pool, tables = _tiny_case(case, "pallas")
+    b, page, i32, key = TINY_ROWS, TINY_PAGE, _i32, SDS((2,), jnp.uint32)
+    f32 = lambda *s: SDS(s, jnp.float32)  # noqa: E731
+
+    if program == "segment":
+        return E._paged_segment_and_sample.lower(
+            params, i32(1, 16), i32(1), i32(1), pool, tables(1), key, f32(1), i32(1),
+            f32(1), config, page,
+            **({"state_rows": i32(1)} if config.is_recurrent else {}),
+        ).as_text()
+    if program == "admit" and config.fills_blocks:
+        s = config.block_length
+        block = {"tokens": i32(b, s), "open": SDS((b, s), jnp.bool_), "step": i32(b)}
+        return E._block_admit_group.lower(
+            params, pool, block, i32(b), f32(b), i32(b), f32(b), i32(2, 16), f32(5, 2),
+            i32(2, s), i32(2), tables(2), config, page,
+        ).as_text()
+    if program == "admit":
+        return E._make_paged_admit_group().lower(
+            params, pool, i32(b), i32(b), f32(b), i32(b), f32(b), key, i32(2, 16),
+            f32(4, 2), i32(2), tables(2), config, page,
+        ).as_text()
     if config.fills_blocks:
         s = config.block_length
         block = {"tokens": i32(b, s), "open": SDS((b, s), jnp.bool_), "step": i32(b)}
         return E._paged_block_chunk.lower(
-            params, block, i32(b), pool, tables, key, f32(b), i32(b), f32(b), 2, config, page
+            params, block, i32(b), pool, tables(b), key, f32(b), i32(b), f32(b), 2, config, page
         ).as_text()
     return E._paged_decode_chunk.lower(
-        params, i32(b), i32(b), pool, tables, key, f32(b), i32(b), f32(b), 2, config, page
+        params, i32(b), i32(b), pool, tables(b), key, f32(b), i32(b), f32(b), 2, config, page
     ).as_text()
 
 
-@pytest.mark.parametrize("case", sorted(DECODE_PROGRAMS_AT_PARENT))
+ENGINE_PROGRAMS = {**DECODE_PROGRAMS_AT_PARENT, **ENGINE_PROGRAMS_AT_PARENT}
+
+
+@pytest.mark.parametrize("case", sorted(ENGINE_PROGRAMS))
 def test_the_other_models_decode_programs_lower_as_they_did(case):
-    assert _short_hash(_decode_program_text(case)) == DECODE_PROGRAMS_AT_PARENT[case]
+    assert _short_hash(_engine_program_text(case)) == ENGINE_PROGRAMS[case]
+
+
+# What `attention_paths()` says after a prefill over a local cache, a segment
+# and a decode step (a model that fills blocks: its prefill and a block pass)
+# of each tiny preset, kernels forced ("pallas") and as the CPU chooses
+# ("auto"): what the parent said (commit 0089f57, PR 45), as data. The
+# families' `expected_kernels` hold a chip run to such strings letter for
+# letter; this holds a refactor of the callers of `note_path` to them here.
+PATHS_AT_PARENT = {
+    "tiny-blockfill-moe-test/auto": {
+        "paged-block[s=4,t=48]": "jnp",
+        "prefill[s=16,t=16]": "jnp",
+    },
+    "tiny-blockfill-moe-test/pallas": {
+        "paged-block[s=4,t=48]": "ragged_paged_block_attention",
+        "prefill[s=16,t=16]": "flash_prefill_attention",
+    },
+    "tiny-hybrid-test/auto": {
+        "linear-decode[s=1,t=0]": "jnp",
+        "linear-prefill[s=16,t=16]": "gated_delta_chunk_prefill",
+        "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment[s=16,t=48]": "jnp",
+        "prefill[s=16,t=16]": "jnp",
+    },
+    "tiny-hybrid-test/pallas": {
+        "linear-decode[s=1,t=0]": "gated_delta_update",
+        "linear-prefill[s=16,t=16]": "gated_delta_chunk_prefill",
+        "paged-decode[s=1,t=48]": "ragged_paged_decode_attention",
+        "paged-segment[s=16,t=48]": "jnp",
+        "prefill[s=16,t=16]": "flash_prefill_attention",
+    },
+    "tiny-moe-test/auto": {
+        "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment[s=16,t=48]": "jnp",
+        "prefill[s=16,t=16]": "jnp",
+    },
+    "tiny-moe-test/pallas": {
+        "paged-decode[s=1,t=48]": "ragged_paged_decode_attention",
+        "paged-segment[s=16,t=48]": "jnp",
+        "prefill[s=16,t=16]": "flash_prefill_attention",
+    },
+    "tiny-sparse-moe-test/auto": {
+        "paged-decode-sparse[s=1,t=48]": "xla top_k + gather",
+        "paged-segment-sparse[s=16,t=48]": "jnp",
+        "prefill-sparse[s=16,t=16]": "jnp",
+    },
+    "tiny-sparse-moe-test/pallas": {
+        "paged-decode-selected[s=1,t=48]": "ragged_paged_selected_attention",
+        "paged-decode-sparse[s=1,t=48]": "ragged_paged_decode_attention to index_topk, xla top_k + gather past it",
+        "paged-segment-select[s=16,t=48]": "segment_select",
+        "paged-segment-sparse[s=16,t=48]": "sparse_segment_attention",
+        "paged-segment[s=16,t=48]": "flash_segment_attention",
+        "prefill-select[s=16,t=16]": "segment_select",
+        "prefill-sparse[s=16,t=16]": "sparse_segment_attention",
+        "segment-select[s=16,t=16]": "block_q 16, block_k 16, to the diagonal",
+        "segment-select[s=16,t=48]": "block_q 16, block_k 48, to the diagonal",
+    },
+    "tiny-test-int8/auto": {
+        "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment[s=16,t=48]": "jnp",
+        "prefill[s=16,t=16]": "jnp",
+    },
+    "tiny-test-int8/pallas": {
+        "paged-decode[s=1,t=48]": "ragged_paged_decode_attention_int8",
+        "paged-segment[s=16,t=48]": "jnp",
+        "prefill[s=16,t=16]": "flash_prefill_attention",
+    },
+    "tiny-test/auto": {
+        "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment[s=16,t=48]": "jnp",
+        "prefill[s=16,t=16]": "jnp",
+    },
+    "tiny-test/pallas": {
+        "paged-decode[s=1,t=48]": "ragged_paged_decode_attention",
+        "paged-segment[s=16,t=48]": "jnp",
+        "prefill[s=16,t=16]": "flash_prefill_attention",
+    },
+    "tiny-window-moe-test/auto": {
+        "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment[s=16,t=48]": "jnp",
+        "prefill[s=16,t=16]": "jnp",
+    },
+    "tiny-window-moe-test/pallas": {
+        "paged-decode[s=1,t=48]": "ragged_paged_decode_attention",
+        "paged-segment[s=16,t=48]": "flash_segment_attention",
+        "prefill[s=16,t=16]": "flash_prefill_attention",
+    },
+}
+
+
+def _traced_paths(case: str) -> dict:
+    from langstream_tpu.models import transformer as T
+
+    case, _, impl = case.rpartition("/")
+    config, params, pool, tables = _tiny_case(case, impl)
+    b, page, width, i32 = TINY_ROWS, TINY_PAGE, 16, _i32
+    was = dict(A._PATHS)
+    A._PATHS.clear()
+    try:
+        # the functions themselves, not their jits: a cached trace notes nothing
+        jax.eval_shape(
+            lambda p, tokens, lengths, rec: T.prefill.__wrapped__(
+                p, tokens, lengths, T.join_rec(T.make_kv_cache(config, 2, width), rec),
+                config, rec_rows=lengths,
+            ),
+            params, i32(2, width), i32(2), pool.get("rec"),
+        )
+        if config.fills_blocks:
+            jax.eval_shape(
+                lambda *a: T.paged_block_step_inplace(*a, config, page),
+                params, i32(b, config.block_length), i32(b), pool, tables(b),
+            )
+        else:
+            jax.eval_shape(
+                lambda *a: T.paged_prefill_segment_inplace(
+                    *a, config, page, state_rows=jnp.zeros(1, jnp.int32)
+                ),
+                params, i32(1, width), i32(1), i32(1), pool, tables(1),
+            )
+            jax.eval_shape(
+                lambda *a: T.paged_decode_step_inplace(*a, config, page),
+                params, i32(b), i32(b), pool, tables(b),
+            )
+        return A.attention_paths()
+    finally:
+        A._PATHS.update(was)
+
+
+@pytest.mark.parametrize("case", sorted(PATHS_AT_PARENT))
+def test_every_preset_notes_the_paths_it_did(case):
+    assert _traced_paths(case) == PATHS_AT_PARENT[case]

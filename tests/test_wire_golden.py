@@ -2,7 +2,7 @@
 published protocol specifications, asserted byte-identical against the
 codecs.
 
-Why this exists (VERDICT r3 weak #3): the Kafka/Pulsar/CQL clients have only
+Why this exists: the Kafka/Pulsar/CQL clients have only
 ever been exercised against fakes written by the same hand, so a shared
 misreading of a wire format would pass every integration test. These tests
 break that loop as far as a no-egress image allows: the EXPECTED bytes are
