@@ -12,10 +12,11 @@ from dataclasses import dataclass, field
 from typing import Any, Optional
 
 
-# every leaf a page pool may hold a token's state in, by page: K, V and an
-# indexer's key (`ModelConfig.page_leaves` says which a model has; the engine's
-# page copies, zeroes and snapshots go over exactly these)
-PAGE_LEAVES = ("k", "v", "ik")
+# every leaf a page pool may hold a token's state in, by page: K, V, an
+# indexer's key and, for a model that keeps a LATENT in place of K and V, that
+# latent (`ModelConfig.page_leaves` says which a model has; the engine's page
+# copies, zeroes and snapshots go over exactly these)
+PAGE_LEAVES = ("k", "v", "ik", "lat")
 
 
 @dataclass(frozen=True)
@@ -154,10 +155,85 @@ class ModelConfig:
     # width. (): one stream. Text has the three equal, which is the plain
     # rotary: a [B, S] position is that
     mrope_section: tuple = ()
+    # The indexer's rotary and input, where they are not the above: its heads'
+    # FIRST ``index_rope_dim`` lanes are turned, in the attention's own pairs
+    # and frequencies (0: the whole head, pairs (i, i + Di/2), frequencies
+    # theta^(-2i/Di)), and its queries read the attention's normed input
+    # ("hidden") or the model's query latent ("query_latent": a model with
+    # ``q_lora_rank``)
+    index_rope_dim: int = 0
+    index_query_input: str = "hidden"
+    # LATENT attention (docs/SERVING.md "A model that keeps a latent, not keys
+    # and values"): the query through a normed latent of ``q_lora_rank``, keys
+    # and values through ONE normed latent of ``kv_lora_rank`` a token and one
+    # rotary key of ``qk_rope_head_dim`` shared by all heads; a head's q.k is
+    # over ``qk_nope_head_dim + qk_rope_head_dim``, its value ``v_head_dim``
+    # wide. A token's cache is (latent | rotary key), ONE leaf ``"lat"`` of the
+    # cache and the page pool in place of ``"k"`` and ``"v"``; a decode step
+    # attends in the latent space (the up-projection absorbed into the query
+    # and the output), a segment over the latents re-expanded. ``kv_lora_rank``
+    # 0: none, and none of the five is read
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # the first ``n_leading_dense`` layers carry a dense FFN of ``d_ff`` in
+    # place of the expert layer: a stack of their own, ``params["dense_layers"]``,
+    # run before the expert layers' scan (pool layers 0 .. n_leading_dense - 1)
+    n_leading_dense: int = 0
+    # the no-drop expert layer's router (`_route_all`): ``router_bias`` adds a
+    # float32 vector a layer, ``lp["router_bias"]``, to the scores that CHOOSE
+    # the experts and not to the weights; the chosen weights, normalised, are
+    # multiplied by ``routed_scaling``
+    router_bias: bool = False
+    routed_scaling: float = 1.0
 
     @property
     def has_indexer(self) -> bool:
         return self.index_topk > 0
+
+    @property
+    def has_latent(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """What a token's latent holds: the key-value latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_key_width(self) -> int:
+        """The width the latent is KEPT at in the cache and the page pool:
+        ``latent_width`` rounded up to whole 128-lane rows, the tail zeros (576
+        is kept at 640). The chip's tiled layouts pad a minor dimension to 128
+        lanes in HBM as in VMEM, so 576 lanes occupy 640 either way; kept
+        explicitly, the padding is the program's, the memory plan counts it
+        and a page is one aligned DMA (``index_key_width`` has the case where
+        it was measured)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def rope_dim(self) -> int:
+        """The width the rotary turns: the head's, or a latent model's one
+        rotary key's."""
+        return self.qk_rope_head_dim if self.has_latent else self.resolved_head_dim
+
+    def kv_bytes_per_token(self, itemsize: int = 2) -> int:
+        """Bytes a token holds in the page pool's full-attention group, over
+        all layers, at ``itemsize`` a value (an int8 pool's float32 scale a
+        head beside its values): what `make_page_pool`'s leaves come to a
+        token."""
+        layers = self.n_layers_of("full_attention")
+        if self.has_latent:
+            token = self.latent_key_width * itemsize
+        elif self.kv_cache_dtype == "int8":
+            token = 2 * self.n_kv_heads * (self.resolved_head_dim + 4)
+        else:
+            token = 2 * self.n_kv_heads * self.resolved_head_dim * itemsize
+        if self.has_indexer:
+            token += self.index_key_width * itemsize
+        return layers * token
 
     @property
     def index_key_width(self) -> int:
@@ -170,8 +246,12 @@ class ModelConfig:
 
     @property
     def page_leaves(self) -> tuple:
-        """The leaves a token has in the page pool's full-attention group."""
-        return PAGE_LEAVES if self.has_indexer else PAGE_LEAVES[:2]
+        """The leaves a token has in the page pool's full-attention group:
+        ``"k"`` and ``"v"``, or for a model that keeps a latent ``"lat"`` alone
+        in their place; after them the indexer's key, ``"ik"``, where the
+        model has an indexer."""
+        kept = ("lat",) if self.has_latent else ("k", "v")
+        return kept + (("ik",) if self.has_indexer else ())
 
     @property
     def has_window(self) -> bool:
@@ -262,6 +342,9 @@ class ModelConfig:
             "moe_scoring": self.moe_scoring != "softmax",
             "n_shared_experts": self.n_shared_experts > 0,
         }
+        if self.has_latent:  # its sequential block reads these three too
+            for key in ("rope_interleaved", "moe_scoring", "n_shared_experts"):
+                window_only[key] = False
         if not self.has_window and any(window_only.values()):
             raise ValueError(
                 f"{self.name}: {', '.join(k for k, on in window_only.items() if on)} "
@@ -333,6 +416,73 @@ class ModelConfig:
                 f"{self.name}: index_n_heads and index_head_dim belong to a model "
                 "with an indexer (index_topk > 0)"
             )
+        if self.index_rope_dim or self.index_query_input != "hidden":
+            contradicts = {
+                "no indexer (index_topk 0)": not self.has_indexer,
+                f"index_rope_dim {self.index_rope_dim} odd or over index_head_dim":
+                    self.index_rope_dim % 2 == 1 or self.index_rope_dim > self.index_head_dim,
+                f"index_rope_dim {self.index_rope_dim} apart from the rotary's width "
+                f"{self.rope_dim} (the indexer turns by the attention's own angles)":
+                    bool(self.index_rope_dim) and self.index_rope_dim != self.rope_dim,
+                f"index_query_input {self.index_query_input!r} (hidden | query_latent)":
+                    self.index_query_input not in ("hidden", "query_latent"),
+                "index_query_input query_latent without a query latent (q_lora_rank 0)":
+                    self.index_query_input == "query_latent" and self.q_lora_rank < 1,
+            }
+            if any(contradicts.values()):
+                raise ValueError(
+                    f"{self.name}: the indexer's rotary and input with "
+                    + "; ".join(k for k, on in contradicts.items() if on)
+                )
+        if self.has_latent:
+            contradicts = {
+                "a layer pattern, a window or a recurrent layer": bool(self.layer_pattern),
+                "fills_blocks (block_length > 0)": self.fills_blocks,
+                "m-rope (mrope_section)": bool(self.mrope_section),
+                "an int8 KV cache": self.kv_cache_dtype == "int8",
+                "an output norm": self.output_norm,
+                "qk_norm or qk_norm_heads (the latents are normed, not the heads)":
+                    self.qk_norm or self.qk_norm_heads,
+                "ring_axis": self.ring_axis is not None,
+                "an attention soft cap": self.attn_logit_softcap is not None,
+                "no indexer (index_topk 0): the latent decode read is the selected one":
+                    not self.has_indexer,
+                f"q_lora_rank {self.q_lora_rank}, qk_nope_head_dim "
+                f"{self.qk_nope_head_dim} or qk_rope_head_dim {self.qk_rope_head_dim} "
+                "under 1, or an odd qk_rope_head_dim":
+                    min(self.q_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim) < 1
+                    or self.qk_rope_head_dim % 2 == 1,
+                f"v_head_dim {self.v_head_dim} apart from qk_nope_head_dim + "
+                "qk_rope_head_dim (the expanded form's kernels take one head width)":
+                    self.v_head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim,
+                f"head_dim {self.head_dim} (a latent model's head is qk_nope_head_dim "
+                "+ qk_rope_head_dim: leave it unset)": self.head_dim is not None,
+                f"n_kv_heads {self.n_kv_heads} apart from n_heads (the expanded "
+                "form has a key and a value a head)": self.n_kv_heads != self.n_heads,
+            }
+            if any(contradicts.values()):
+                raise ValueError(
+                    f"{self.name}: a latent (kv_lora_rank {self.kv_lora_rank}) with "
+                    + "; ".join(k for k, on in contradicts.items() if on)
+                )
+        elif self.q_lora_rank or self.qk_nope_head_dim or self.qk_rope_head_dim or self.v_head_dim:
+            raise ValueError(
+                f"{self.name}: q_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
+                "v_head_dim belong to a model with a latent (kv_lora_rank > 0)"
+            )
+        if self.n_leading_dense and not (
+            self.has_latent and self.experts_held and 0 < self.n_leading_dense < self.n_layers
+        ):
+            raise ValueError(
+                f"{self.name}: n_leading_dense {self.n_leading_dense} belongs to a model "
+                "with a latent whose later layers hold experts (experts_held), and "
+                "leaves an expert layer"
+            )
+        if (self.router_bias or self.routed_scaling != 1.0) and not self.holds_experts:
+            raise ValueError(
+                f"{self.name}: router_bias and routed_scaling are read by the no-drop "
+                "expert layer's router (window layers, or experts_held)"
+            )
         if self.mrope_section and (
             len(self.mrope_section) != 3
             or sum(self.mrope_section) != self.resolved_head_dim // 2
@@ -344,6 +494,8 @@ class ModelConfig:
 
     @property
     def resolved_head_dim(self) -> int:
+        if self.has_latent:  # the expanded form's head: q.k over this, v as wide
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
 
     @property
@@ -351,6 +503,13 @@ class ModelConfig:
         """Rough parameter count (placement decisions, not accounting)."""
         d, hd = self.d_model, self.resolved_head_dim
         attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        if self.has_latent:
+            attn = (
+                d * self.q_lora_rank + self.q_lora_rank * self.n_heads * hd
+                + d * self.latent_width
+                + self.kv_lora_rank * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
+                + self.n_heads * self.v_head_dim * d
+            )
         if self.layer_pattern:
             linear = 2 * d * (self.linear_key_dim + self.linear_value_dim) + (
                 self.linear_value_dim * d
@@ -366,11 +525,12 @@ class ModelConfig:
             return mixers + self.n_layers * ffn + embed
         if self.is_moe:  # what is HELD here, where the layer holds a share
             held = self.held_experts[1] if self.experts_held else self.n_experts
-            ffn = held * 3 * d * self.expert_d_ff + d * self.n_experts
+            ffn = (held + self.n_shared_experts) * 3 * d * self.expert_d_ff + d * self.n_experts
         else:
             ffn = 3 * d * self.d_ff
         embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        return self.n_layers * (attn + ffn) + embed
+        dense = self.n_leading_dense * (3 * d * self.d_ff - ffn)
+        return self.n_layers * (attn + ffn) + dense + embed
 
     @property
     def is_moe(self) -> bool:
@@ -597,6 +757,46 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         index_head_dim=16,
         index_topk=8,
         mrope_section=(2, 3, 3),
+    ),
+    "tiny-latent-moe-test": _preset(
+        # a model that keeps a latent in place of K and V, at test size
+        # (tests/test_latent_attention.py): a leading dense layer and three
+        # expert layers, a query latent of 32, a key-value latent of 16 and a
+        # rotary key of 8 for 4 heads of 8 + 8 (values 16), interleaved
+        # rotary, an indexer of 2 heads of 16 (8 turned) that reads the query
+        # latent and keeps 8 tokens (a 40-token sequence selects), 8
+        # sigmoid-routed experts top-2 chosen under a bias, scaled by 2.5, of
+        # which this share holds 4, one shared expert, an untied head
+        name="tiny-latent-moe-test",
+        vocab_size=512,
+        d_model=64,
+        n_layers=4,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=128,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-5,
+        max_seq_len=256,
+        rope_interleaved=True,
+        n_experts=8,
+        n_experts_per_tok=2,
+        moe_d_ff=32,
+        experts_held=(0, 4),
+        moe_scoring="sigmoid",
+        n_shared_experts=1,
+        router_bias=True,
+        routed_scaling=2.5,
+        n_leading_dense=1,
+        q_lora_rank=32,
+        kv_lora_rank=16,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
+        index_n_heads=2,
+        index_head_dim=16,
+        index_topk=8,
+        index_rope_dim=8,
+        index_query_input="query_latent",
     ),
     "olmo-hybrid-7b": _preset(
         # allenai/Olmo-Hybrid-7B config.json: (gated delta-rule x3, full

@@ -28,6 +28,8 @@ _QUANT_LAYER_KEYS = (
     "wq", "wk", "wv", "wqkv", "wg", "wo", "w_gate", "w_up", "w_down",
     "wq_idx", "wk_idx",
     "ws_gate", "ws_up", "ws_down",
+    # latent attention: the two down-projections and the two up-projections
+    "wq_a", "wq_b", "wkv_a", "wkv_b",
 )
 
 
@@ -78,6 +80,8 @@ def quantize_params(params: Params, config: ModelConfig) -> Params:
         out["layers"] = {kind: stack(s) for kind, s in params["layers"].items()}
     else:
         out["layers"] = stack(params["layers"])
+    if "dense_layers" in params:  # the leading dense layers' stack
+        out["dense_layers"] = stack(params["dense_layers"])
     if "lm_head" in params:
         out["lm_head"] = quantize_weight(params["lm_head"])
     if config.tie_embeddings:
@@ -95,6 +99,13 @@ def init_random_quantized_params(config: ModelConfig, key: jax.Array) -> Params:
     finite."""
     import jax.numpy as jnp
 
+    if config.has_latent:
+        # no direct form for the latent's leaves and the leading dense stack:
+        # the tree's shapes are what this exists for there (serving/memory.py
+        # plans under eval_shape; the benchmark makes its own weights)
+        from langstream_tpu.models.transformer import init_params
+
+        return quantize_params(init_params(config, key), config)
     d, h, hkv = config.d_model, config.n_heads, config.n_kv_heads
     hd = config.resolved_head_dim
     f, L, v = config.d_ff, config.n_layers, config.vocab_size

@@ -22,6 +22,7 @@ there is deliberately no architectural counterpart.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import math
 from typing import Any, Optional
@@ -50,6 +51,7 @@ SCOPES = (
     "attention.window", "attention.full", "moe_ffn.shared",
     "block_choice",
     "attention.index", "attention.index.scores", "attention.select", "attention.sparse",
+    "attention.latent", "attention.latent.expand",
 )
 
 # What `moe_ffn_counted` counts, per call, as one int32 vector: expert
@@ -103,14 +105,21 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
         scale = scale if scale is not None else (shape[-2] if len(shape) >= 2 else d)
         return (jax.random.normal(k, shape, jnp.float32) * (scale**-0.5)).astype(dtype)
 
+    # a model with leading dense layers keeps them in a stack of their own
+    # (`_init_dense_layers`); ``layers`` is then the expert layers' alone
+    L = L - config.n_leading_dense
     layers: dict[str, jax.Array] = {
-        "attn_norm": jnp.ones((L, d), dtype),
-        "wq": norm(keys[0], L, d, h * hd, scale=d),
-        "wk": norm(keys[1], L, d, hkv * hd, scale=d),
-        "wv": norm(keys[2], L, d, hkv * hd, scale=d),
-        "wo": norm(keys[3], L, h * hd, d, scale=h * hd),
-        "ffn_norm": jnp.ones((L, d), dtype),
+        "attn_norm": jnp.ones((L, d), dtype), "ffn_norm": jnp.ones((L, d), dtype),
     }
+    if config.has_latent:
+        layers.update(_init_latent_attention(config, jax.random.fold_in(key, 13), L, dtype))
+    else:
+        layers.update(
+            wq=norm(keys[0], L, d, h * hd, scale=d),
+            wk=norm(keys[1], L, d, hkv * hd, scale=d),
+            wv=norm(keys[2], L, d, hkv * hd, scale=d),
+            wo=norm(keys[3], L, h * hd, d, scale=h * hd),
+        )
     if config.qk_norm_heads:
         layers["q_norm"] = jnp.ones((L, hd), dtype)
         layers["k_norm"] = jnp.ones((L, hd), dtype)
@@ -119,7 +128,8 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
         # projection, the per-head weights float32 like a router, the
         # LayerNorm of its key (the one bias of the model)
         hi, di = config.index_n_heads, config.index_head_dim
-        layers["wq_idx"] = norm(keys[10], L, d, hi * di, scale=d)
+        d_q = config.q_lora_rank if config.index_query_input == "query_latent" else d
+        layers["wq_idx"] = norm(keys[10], L, d_q, hi * di, scale=d_q)
         layers["wk_idx"] = norm(keys[11], L, d, di, scale=d)
         layers["w_idx"] = (
             jax.random.normal(jax.random.fold_in(key, 12), (L, d, hi), jnp.float32) * d**-0.5
@@ -129,11 +139,22 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
     if config.is_moe:
         e = config.n_experts
         layers["router"] = norm(keys[4], L, d, e, scale=d)
+        if config.router_bias:  # float32, beside a float32 router's scores
+            layers["router"] = layers["router"].astype(jnp.float32)
+            layers["router_bias"] = 0.05 * jax.random.normal(
+                jax.random.fold_in(key, 14), (L, e), jnp.float32
+            )
         if config.experts_held:  # the router is whole, the experts a share
             e, f = config.held_experts[1], config.expert_d_ff
         layers["w_gate"] = norm(keys[5], L, e, d, f, scale=d)
         layers["w_up"] = norm(keys[6], L, e, d, f, scale=d)
         layers["w_down"] = norm(keys[7], L, e, f, d, scale=f)
+        if config.n_shared_experts and not config.has_window:
+            # side by side, as `_init_window_params` keeps them
+            ns, ks = config.n_shared_experts, jax.random.split(jax.random.fold_in(key, 15), 3)
+            layers["ws_gate"] = norm(ks[0], L, d, ns * f, scale=d)
+            layers["ws_up"] = norm(ks[1], L, d, ns * f, scale=d)
+            layers["ws_down"] = norm(ks[2], L, ns * f, d, scale=f)
     else:
         layers["w_gate"] = norm(keys[5], L, d, f, scale=d)
         layers["w_up"] = norm(keys[6], L, d, f, scale=d)
@@ -144,9 +165,47 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
         "layers": layers,
         "final_norm": jnp.ones((d,), dtype),
     }
+    if config.n_leading_dense:
+        params["dense_layers"] = _init_dense_layers(config, jax.random.fold_in(key, 16), dtype)
     if not config.tie_embeddings:
         params["lm_head"] = norm(keys[9], d, v, scale=d)
     return params
+
+
+def _init_latent_attention(config: ModelConfig, key: jax.Array, n: int, dtype) -> dict:
+    """The latent attention's weights of ``n`` layers (HF's names in
+    brackets): ``wq_a`` [d, q_lora_rank] (q_a_proj) and its norm, ``wq_b``
+    [q_lora_rank, H x (nope + rope)] (q_b_proj), ``wkv_a`` [d, kv_lora_rank +
+    rope] (kv_a_proj_with_mqa) and the latent's norm, ``wkv_b`` [kv_lora_rank,
+    H x (nope + v)] (kv_b_proj: a head's key part, then its value), ``wo``
+    [H x v, d]."""
+    d, h, hd = config.d_model, config.n_heads, config.resolved_head_dim
+    ql, kl = config.q_lora_rank, config.kv_lora_rank
+    keys = iter(jax.random.split(key, 5))
+
+    def normal(*shape):  # [..., in, out]: N(0, 1 / in)
+        w = jax.random.normal(next(keys), shape, jnp.float32) * shape[-2] ** -0.5
+        return w.astype(dtype)
+
+    return {
+        "wq_a": normal(n, d, ql), "q_a_norm": jnp.ones((n, ql), dtype),
+        "wq_b": normal(n, ql, h * hd),
+        "wkv_a": normal(n, d, config.latent_width), "kv_a_norm": jnp.ones((n, kl), dtype),
+        "wkv_b": normal(n, kl, h * (config.qk_nope_head_dim + config.v_head_dim)),
+        "wo": normal(n, h * config.v_head_dim, d),
+    }
+
+
+def _init_dense_layers(config: ModelConfig, key: jax.Array, dtype) -> dict:
+    """The leading dense layers' stack, ``params["dense_layers"]``
+    [n_leading_dense, ...]: the attention half as every layer's (indexer and
+    all), a dense FFN of ``d_ff`` in place of router and experts."""
+    dense = dataclasses.replace(
+        config, n_layers=config.n_leading_dense, n_leading_dense=0, n_experts=0,
+        experts_held=(), moe_d_ff=0, moe_scoring="softmax", n_shared_experts=0,
+        router_bias=False, routed_scaling=1.0,
+    )
+    return init_params(dense, key, dtype)["layers"]
 
 
 def _init_pattern_params(config: ModelConfig, key: jax.Array, dtype) -> Params:
@@ -298,7 +357,7 @@ def _rope_freqs(
     # positions: [B, S] → sin/cos [B, S, head_dim/2], fp32. [3, B, S]: a
     # position triple a token (m-rope): frequency i turns by the stream its
     # section of ``config.mrope_section`` names; equal triples are [B, S]
-    half = config.resolved_head_dim // 2
+    half = config.rope_dim // 2
     freqs = config.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     if config.rope_scaling_factor:
         freqs = _llama3_rope_scale(freqs, config)
@@ -389,7 +448,7 @@ def _dequantize_kv(c, dtype) -> jax.Array:
 
 
 def cache_width(cache: KVCache) -> int:
-    leaf = cache["k"]
+    leaf = cache["k"] if "k" in cache else cache["lat"]
     return (leaf["q"] if isinstance(leaf, dict) else leaf).shape[3]
 
 
@@ -460,11 +519,14 @@ def make_page_pool(
     config: ModelConfig, num_pages: int, page_size: int, dtype=None,
     state_rows: int = 0, window_pages: int = 0,
 ) -> KVCache:
-    """Device page pool: ``{"k","v"}`` with leaves [L, P, Hkv, ps, D] (or the
-    int8 ``{"q","s"}`` dicts with scales [L, P, Hkv, ps]); for a model with an
-    indexer a third leaf a token, ``"ik"`` [L, P, ps, index_key_width], the
-    indexer's key, addressed by the same table and page index as K and V and
-    written where they are (``config.page_leaves``) — structurally a
+    """Device page pool, the leaves ``config.page_leaves`` names: ``{"k","v"}``
+    [L, P, Hkv, ps, D] (or the int8 ``{"q","s"}`` dicts with scales
+    [L, P, Hkv, ps]), or, for a model that keeps a latent in place of K and V,
+    ``"lat"`` alone [L, P, 1, ps, latent_key_width] (K's layout with one head:
+    a token's normed latent and its rotary key in one row); for a model with an
+    indexer one more leaf a token, ``"ik"`` [L, P, ps, index_key_width], the
+    indexer's key, addressed by the same table and page index as the others
+    and written where they are — structurally a
     make_kv_cache with B = pages and T = page_size, so every tree-shaped
     helper (sharding specs, byte accounting, donation) applies unchanged.
     L counts the full-attention layers. A model with recurrent layers keeps
@@ -834,26 +896,43 @@ def moe_ffn_counted(
 # ---------------------------------------------------------------------------
 
 
-def _index_proj(u, lp, positions, config):
-    """The indexer's three projections of the attention's normed input ``u``
-    [B, S, d] at text positions [B, S]: queries [B, S, Hi, Di] and the key
-    [B, S, Di], both turned by the rotary rule over the indexer's whole head
-    (pairs (i, i + Di/2), frequencies theta^(-2i/Di)), the key through its
-    LayerNorm first; the heads' weights [B, S, Hi] in float32, scaled by
-    1 / sqrt(Hi Di)."""
+def _index_proj(u, lp, positions, config, c_q=None, rotary=None):
+    """The indexer's three projections at text positions [B, S]: queries
+    [B, S, Hi, Di], read from the attention's normed input ``u`` [B, S, d] or,
+    with ``config.index_query_input`` "query_latent", from the model's normed
+    query latent ``c_q``; the key [B, S, Di] from ``u`` through its LayerNorm;
+    the heads' weights [B, S, Hi] from ``u`` in float32, scaled by
+    1 / sqrt(Hi Di). What turns: the indexer's WHOLE head by a rotary of its
+    own (pairs (i, i + Di/2), frequencies theta^(-2i/Di)), or, with
+    ``config.index_rope_dim``, each head's FIRST that many lanes by the
+    attention's own angles ``rotary`` = (sin, cos) in the attention's pairs
+    (interleaved where ``rope_interleaved``), the rest unturned."""
     b, s, _ = u.shape
     hi, di = config.index_n_heads, config.index_head_dim
-    freqs = config.rope_theta ** (-jnp.arange(0, di // 2, dtype=jnp.float32) / (di // 2))
-    angles = positions.astype(jnp.float32)[..., None] * freqs
-    sin, cos = jnp.sin(angles), jnp.cos(angles)
-    q = quantized_matmul(u, lp["wq_idx"]).reshape(b, s, hi, di)
+    turned = config.index_rope_dim
+    if not turned:
+        freqs = config.rope_theta ** (-jnp.arange(0, di // 2, dtype=jnp.float32) / (di // 2))
+        angles = positions.astype(jnp.float32)[..., None] * freqs
+        sin, cos = jnp.sin(angles), jnp.cos(angles)
+    q_in = c_q if config.index_query_input == "query_latent" else u
+    q = quantized_matmul(q_in, lp["wq_idx"]).reshape(b, s, hi, di)
     k = layer_norm(quantized_matmul(u, lp["wk_idx"]), lp["idx_norm"], config.rms_norm_eps)
     k = k + lp["idx_bias"].astype(k.dtype)
     w = jnp.dot(
         u.astype(jnp.float32), lp["w_idx"].astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     ) * (hi * di) ** -0.5
-    return apply_rope(q, sin, cos), apply_rope(k[:, :, None, :], sin, cos)[:, :, 0], w
+    if not turned:
+        return apply_rope(q, sin, cos), apply_rope(k[:, :, None, :], sin, cos)[:, :, 0], w
+    turn = functools.partial(_turn_first, width=turned, rotary=rotary, config=config)
+    return turn(q), turn(k[:, :, None, :])[:, :, 0], w
+
+
+def _turn_first(x, width, rotary, config):
+    """x [B, S, H, D] with its first ``width`` lanes turned by ``rotary`` =
+    (sin, cos) [B, S, width / 2], in the model's pairs."""
+    turn = apply_rope_interleaved if config.rope_interleaved else apply_rope
+    return jnp.concatenate([turn(x[..., :width], *rotary), x[..., width:]], axis=-1)
 
 
 def _selection_kernels(config, s: int, t: int) -> bool:
@@ -1008,12 +1087,13 @@ def _write_index_key(pik, layer, k_idx, table, positions, page_size):
     [L, P, ps, Di] at ``[layer, table[b, pos // ps], pos % ps]``; an unmapped
     page drops the write, as K's and V's does."""
     pages, offs = _page_index(table, positions, page_size, pik.shape[1])
-    return pik.at[layer, pages, offs].set(_kept_index_key(k_idx, pik), mode="drop")
+    return pik.at[layer, pages, offs].set(_kept_width(k_idx, pik), mode="drop")
 
 
-def _kept_index_key(k_idx, leaf):
-    """The indexer's key [.., Di] as the cache keeps it: padded with zeros
-    to the leaf's width (``config.index_key_width``), in its dtype."""
+def _kept_width(k_idx, leaf):
+    """The indexer's key [.., Di], or a token's latent, as the cache keeps
+    it: padded with zeros to the leaf's width (``config.index_key_width``,
+    ``config.latent_key_width``), in its dtype."""
     pad = leaf.shape[-1] - k_idx.shape[-1]
     return jnp.pad(k_idx.astype(leaf.dtype), [(0, 0)] * (k_idx.ndim - 1) + [(0, pad)])
 
@@ -1050,7 +1130,19 @@ def _attention_block(
     indexer (``config.has_indexer``) carries a third pool leaf in
     ``cache_kv``, the indexer's keys; its decode step attends to the selected
     tokens alone and its segment reads under the selection once a query sees
-    more than ``index_topk`` keys (`_paged_selected_read`)."""
+    more than ``index_topk`` keys (`_paged_selected_read`). A model that
+    keeps a LATENT (``config.has_latent``) has an attention half of its own
+    behind this entry, `_latent_attention_block`: ``cache_kv`` is then the
+    latent leaf and the indexer's keys."""
+    if config.has_latent:
+        if lora is not None or verify or block:
+            raise NotImplementedError(
+                f"{config.name}: no adapter terms, verify or block pass over a latent"
+            )
+        return _latent_attention_block(
+            x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal, collect_kv,
+            paged_table, page_size, layer,
+        )
     if paged_table is None:
         with jax.named_scope("attention"):
             return _dense_attention(
@@ -1275,12 +1367,31 @@ def _paged_selected_read(
             )[:, None, :]
     k_all = _paged_gather(pk, layer, table, page_size)
     v_all = _paged_gather(pv, layer, table, page_size)
+    return _selected_segment_read(
+        q, q_idx, w_idx, pik, k_all, v_all, table, layer, mask, positions, config,
+        "paged-segment",
+    )
+
+
+def _selected_segment_read(
+    q, q_idx, w_idx, pik, k_all, v_all, table, layer, mask, positions, config, what
+):
+    """S > 1 queries a row over its gathered columns ``k_all``/``v_all``
+    [B, Hkv, T, D] (a latent model's: re-expanded from its latents) under the
+    selection: the row's indexer keys gathered through the table, the segment
+    kernel while no query sees more than ``index_topk`` keys,
+    `_selected_attention` once one does."""
+    from langstream_tpu.ops import attention as ops
+
+    b, s = q.shape[:2]
+    t = k_all.shape[2]
+    interpret = jax.default_backend() != "tpu"
     with jax.named_scope("attention.index"):
         k_idx_all = pik.at[layer, table].get(mode="clip").reshape(b, t, -1)[
             ..., :config.index_head_dim]
     selected = functools.partial(
         _selected_attention, q, q_idx, w_idx, k_idx_all, k_all, v_all, mask,
-        positions, config, "paged-segment",
+        positions, config, what,
     )
     if not _selection_kernels(config, s, t):
         return selected()
@@ -1291,8 +1402,250 @@ def _paged_selected_read(
                 q, k_all, v_all, positions[:, 0], config, interpret=interpret
             )
 
-    ops.note_path("paged-segment", "flash_segment_attention", config, s=s, t=t)
-    return lax.cond(jnp.max(positions) >= topk, selected, plain)
+    ops.note_path(what, "flash_segment_attention", config, s=s, t=t)
+    return lax.cond(jnp.max(positions) >= config.index_topk, selected, plain)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (docs/SERVING.md "A model that keeps a latent, not keys and
+# values"; ``config.has_latent``). The query goes through a normed latent
+# ``c_q``; keys and values through ONE normed latent ``c_kv`` a token, and one
+# rotary key ``k_rope`` serves every head. A token's cache is the row
+# ``[c_kv | k_rope]`` (kept ``config.latent_key_width`` wide, the tail zeros):
+# the leaf ``"lat"`` [L, B, 1, T, W] of a local cache and [L, P, 1, ps, W] of
+# the page pool, K's layout with one head, in place of ``"k"`` and ``"v"``.
+# Two forms of one attention over the SAME int8 ``wkv_b`` and scales:
+#   expanded   k_h = [c_kv W_uk,h | k_rope], v_h = c_kv W_uv,h for every column
+#              (`_latent_expand`), then the model's attention of H heads: the
+#              dense path, and a paged segment over its row's gathered latents;
+#   absorbed   q~_h = [q_nope,h W_uk,h^T | q_rope,h] against the rows as they
+#              lie, o_h = (sum_s p_s c_kv,s) W_uv,h: a paged decode step, which
+#              forms nothing of a head's keys or values.
+# Scopes inside ``attention``: ``attention.latent`` (the down-projections and
+# their norms, ``wq_b``, the absorb of q_nope, W_uv), ``attention.latent.expand``
+# (``wkv_b`` over columns), the indexer's three as they are.
+# ---------------------------------------------------------------------------
+
+
+def _latent_proj(x, lp, sin, cos, config):
+    """(u, c_q, q, lat) of the layer's input ``x`` [B, S, d]: the normed
+    input, the normed query latent [B, S, q_lora_rank], the queries
+    [B, S, H, nope + rope] with each head's last ``qk_rope_head_dim`` turned,
+    and the tokens' cache rows [B, S, kv_lora_rank + rope]: the normed
+    key-value latent, then the one rotary key, turned."""
+    b, s, _ = x.shape
+    eps, kl, nope = config.rms_norm_eps, config.kv_lora_rank, config.qk_nope_head_dim
+    turn = apply_rope_interleaved if config.rope_interleaved else apply_rope
+    with jax.named_scope("attention.latent"):
+        u = rms_norm(x, lp["attn_norm"], eps)
+        c_q = rms_norm(quantized_matmul(u, lp["wq_a"]), lp["q_a_norm"], eps)
+        q = quantized_matmul(c_q, lp["wq_b"]).reshape(b, s, config.n_heads, -1)
+        q = jnp.concatenate([q[..., :nope], turn(q[..., nope:], sin, cos)], axis=-1)
+        kv = quantized_matmul(u, lp["wkv_a"])
+        c_kv = rms_norm(kv[..., :kl], lp["kv_a_norm"], eps)
+        k_rope = turn(kv[:, :, None, kl:], sin, cos)[:, :, 0]
+        lat = jnp.concatenate([c_kv, k_rope], axis=-1)
+    return u, c_q, q, lat
+
+
+def _wkv_b(lp, config):
+    """``wkv_b`` as (its values [kv_lora_rank, H, nope + v]: int8 where it is
+    quantized, its scales [H, nope + v] float32 or None): ONE matrix and one
+    rounding behind the expanded and the absorbed form."""
+    w = lp["wkv_b"]
+    shape = (config.kv_lora_rank, config.n_heads, config.qk_nope_head_dim + config.v_head_dim)
+    if is_quantized(w):
+        return w["q"].reshape(shape), w["s"].reshape(shape[1:]).astype(jnp.float32)
+    return w.reshape(shape), None
+
+
+def _latent_expand(lat, lp, config):
+    """The expanded form's keys and values of the columns ``lat`` [B, T, W]
+    (cache rows, or tokens' own) -> k, v [B, H, T, nope + rope] head-major:
+    ``k_h = [c_kv W_uk,h | k_rope]``, ``v_h = c_kv W_uv,h``."""
+    kl, nope, rope = config.kv_lora_rank, config.qk_nope_head_dim, config.qk_rope_head_dim
+    b, t, _ = lat.shape
+    with jax.named_scope("attention.latent.expand"):
+        w, scale = _wkv_b(lp, config)
+        if scale is not None:  # the dequantised matrix, as `quantized_matmul` forms it
+            w = (w.astype(jnp.float32) * scale).astype(lat.dtype)
+        c_kv = lat[..., :kl]
+        k_nope = jnp.einsum("btc,chj->bhtj", c_kv, w[..., :nope])
+        v = jnp.einsum("btc,chj->bhtj", c_kv, w[..., nope:])
+        k_rope = jnp.broadcast_to(lat[:, None, :, kl:kl + rope], (b, config.n_heads, t, rope))
+        return jnp.concatenate([k_nope, k_rope], axis=-1), v
+
+
+def _latent_absorb(q, lp, config, width):
+    """The absorbed queries of one query a row, ``q`` [B, H, nope + rope] ->
+    [B, H, width]: ``[q_nope,h W_uk,h^T | q_rope,h | zeros]``, what scores a
+    cache row as it lies. Over the int8 ``wkv_b`` the contraction runs over
+    output channels: the query takes the channels' scales, then the integer
+    product (int8 is exact in bf16)."""
+    kl, nope, rope = config.kv_lora_rank, config.qk_nope_head_dim, config.qk_rope_head_dim
+    w, scale = _wkv_b(lp, config)
+    q_nope = q[..., :nope]
+    if scale is not None:
+        q_nope = (q_nope.astype(jnp.float32) * scale[:, :nope]).astype(q.dtype)
+    absorbed = jnp.einsum(
+        "bhj,chj->bhc", q_nope, w[..., :nope].astype(q.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(q.dtype)
+    pad = jnp.zeros((*q.shape[:2], width - kl - rope), q.dtype)
+    return jnp.concatenate([absorbed, q[..., nope:], pad], axis=-1)
+
+
+def _latent_value_out(mixed, lp, config):
+    """``mixed`` [B, H, kv_lora_rank], each head's probability-weighted sum of
+    the latents it read -> the heads' outputs [B, H x v]: ``o_h = mixed_h
+    W_uv,h``, the integer product, then the channels' scales."""
+    nope = config.qk_nope_head_dim
+    w, scale = _wkv_b(lp, config)
+    out = jnp.einsum(
+        "bhc,chj->bhj", mixed, w[..., nope:].astype(mixed.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    if scale is not None:
+        out = out * scale[:, nope:]
+    return out.astype(mixed.dtype).reshape(mixed.shape[0], -1)
+
+
+def _latent_decode_read(q, index, plat, pik, table, layer, positions, lp, config, page_size,
+                        kernels):
+    """A decode step's read of a latent model -> [B, 1, H x v]: the row's
+    cached indexer keys scored by whole pages and ranked (skipped whole while
+    no row is past ``index_topk``), and the attention IN THE LATENT SPACE
+    over the selected rows: absorbed queries against ``[c_kv | k_rope]`` as
+    the pool holds them, the value the same row's first ``kv_lora_rank``
+    lanes. With the kernels ONE call of the paged decode kernel over the
+    latent leaf under the selection as a mask over the row's pages
+    (`ops/attention.ragged_paged_latent_attention`: a page is fetched once
+    for key and value; a row of no more than ``index_topk`` tokens takes the
+    same kernel with nothing masked), whatever the table's length (a gather
+    of 2,048 rows of 1,280 B a row and layer pays the gather's 10 ns a row
+    five times over Keye's 256 B and loses sooner: `_WALK_TABLE_PER_TOPK`'s
+    prices); without them the row's latents gathered through the table and
+    masked jnp. Nothing of [B, H, T, nope + v] is formed either way."""
+    from langstream_tpu.ops import attention as ops
+
+    q_idx, _, w_idx = index
+    b, kl, topk = q.shape[0], config.kv_lora_rank, config.index_topk
+    t = table.shape[1] * page_size
+    lengths = _paged_lengths(table, positions[:, 0], page_size, plat.shape[1])
+    visible = jnp.arange(t)[None, :] < lengths[:, None]
+
+    # (`_paged_selected_read`'s six lines, kept there as they stand: factored
+    # out, the branches' names move in that model's lowered decode program)
+    def ranked():
+        scores = _decode_index_scores(
+            q_idx[:, 0], w_idx[:, 0], pik, table, layer, config, page_size
+        )
+        with jax.named_scope("attention.select"):
+            return _select_mask(scores, visible, topk)
+
+    chosen = lax.cond(jnp.any(lengths > topk), ranked, lambda: visible)
+    with jax.named_scope("attention.latent"):
+        absorbed = _latent_absorb(q[:, 0], lp, config, plat.shape[-1])
+    with jax.named_scope("attention.sparse"):
+        if kernels:
+            ops.note_path(
+                "paged-decode-latent", "ragged_paged_latent_attention", config, s=1, t=t
+            )
+            mixed = ops.ragged_paged_latent_attention(
+                absorbed, plat, lengths, table, layer, chosen, config, page_size,
+                interpret=jax.default_backend() != "tpu",
+            ).reshape(b, config.n_heads, kl)
+        else:
+            ops.note_path("paged-decode-latent", "jnp", config, s=1, t=t)
+            rows = _paged_gather(plat, layer, table, page_size)[:, 0]  # [B, T, W]
+            logits = jnp.einsum(
+                "bhw,btw->bht", absorbed, rows, preferred_element_type=jnp.float32
+            ) * config.resolved_head_dim**-0.5
+            logits = jnp.where(chosen[:, None, :], logits, -1e30)
+            probs = jnp.where(
+                chosen[:, None, :], jnp.exp(logits - logits.max(axis=-1, keepdims=True)), 0.0
+            )
+            mixed = jnp.einsum(
+                "bht,btc->bhc", probs.astype(q.dtype), rows[..., :kl],
+                preferred_element_type=jnp.float32,
+            ) / jnp.maximum(probs.sum(axis=-1, keepdims=True), 1e-30)
+            mixed = mixed.astype(q.dtype)
+    with jax.named_scope("attention.latent"):
+        return _latent_value_out(mixed, lp, config)[:, None, :]
+
+
+def _latent_attention_block(
+    x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal, collect_kv,
+    paged_table, page_size, layer,
+):
+    """`_attention_block` of a model that keeps a latent: (the FFN's input,
+    the layer's cache leaves ``("lat", "ik")``, written). Without a table the
+    EXPANDED form over a local cache entry [B, 1, T, W] written at
+    ``cache_positions`` (or over the tokens' own rows), under the selection
+    once a query sees more than ``index_topk`` columns; with one, the pool's
+    leaves whole, written at ``layer``: one query a row reads in the latent
+    space (`_latent_decode_read`), a segment re-expands its row's gathered
+    latents, cached columns and its own alike, into a temporary that never
+    enters the pool and reads as a model with an indexer does
+    (`_selected_segment_read`)."""
+    from langstream_tpu.ops import attention as ops
+
+    b, s = x.shape[:2]
+    positions = (
+        jnp.broadcast_to(jnp.arange(s), (b, s)) if cache_positions is None else cache_positions
+    )
+    with jax.named_scope("attention"):
+        u, c_q, q, lat = _latent_proj(x, lp, sin, cos, config)
+        with jax.named_scope("attention.index"):
+            index = _index_proj(u, lp, positions, config, c_q=c_q, rotary=(sin, cos))
+    q_idx, k_idx, w_idx = index
+    if paged_table is not None:
+        plat, pik = cache_kv
+        with jax.named_scope("kv_pool.write"):
+            plat = _paged_scatter(
+                plat, layer, _kept_width(lat, plat)[:, None], paged_table, positions, page_size
+            )
+            pik = _write_index_key(pik, layer, k_idx, paged_table, positions, page_size)
+        with jax.named_scope("attention"):
+            if s == 1:
+                attn = _latent_decode_read(
+                    q, index, plat, pik, paged_table, layer, positions, lp, config, page_size,
+                    ops.paged_pallas_ok(config, page_size),
+                )
+            else:
+                rows = _paged_gather(plat, layer, paged_table, page_size)[:, 0]
+                k_all, v_all = _latent_expand(rows, lp, config)
+                attn = _selected_segment_read(
+                    q, q_idx, w_idx, pik, k_all, v_all, paged_table, layer, mask, positions,
+                    config, "paged-segment-latent",
+                )
+            return x + quantized_matmul(attn, lp["wo"]), (plat, pik)
+    with jax.named_scope("attention"):
+        new_cache = None
+        if cache_kv is not None:
+            clat, cik = cache_kv  # [B, 1, T, W], [B, T, Wi]
+            rows_at = jnp.arange(b)[:, None]
+            clat = clat.at[rows_at, 0, cache_positions].set(_kept_width(lat, clat))
+            cik = cik.at[rows_at, cache_positions].set(_kept_width(k_idx, cik))
+            new_cache = (clat, cik)
+            lat_all, k_idx = clat[:, 0], cik[..., :config.index_head_dim]
+        else:
+            lat_all = lat
+            if collect_kv:
+                new_cache = (lat[:, None], k_idx)
+        k_all, v_all = _latent_expand(lat_all, lp, config)
+        if k_all.shape[2] > config.index_topk:
+            if not causal:
+                raise NotImplementedError(
+                    f"{config.name}: the indexer ranks what a causal query sees"
+                )
+            attn = _selected_attention(
+                q, q_idx, w_idx, k_idx, k_all, v_all, mask, positions, config,
+                "prefill" if s > 1 else "decode",
+            )
+        else:
+            attn = _dispatch_attention(q, k_all, v_all, mask, config, causal)
+        return x + quantized_matmul(attn, lp["wo"]), new_cache
 
 
 def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
@@ -1364,7 +1717,7 @@ def _dense_attention(
             )
         if cache_kv is not None:  # the indexer's key [B, T, Di] beside K and V
             *cache_kv, ik = cache_kv
-            ik = ik.at[jnp.arange(b)[:, None], cache_positions].set(_kept_index_key(k_idx, ik))
+            ik = ik.at[jnp.arange(b)[:, None], cache_positions].set(_kept_width(k_idx, ik))
             k_idx = ik[..., :config.index_head_dim]
             cik = (ik,)
         else:
@@ -1449,6 +1802,7 @@ def _layer_counted(
     layer: Optional[jax.Array] = None,  # scalar layer index (paged only)
     block: bool = False,  # a block pass: S queries a row that see one another
     moe_layer: Optional[jax.Array] = None,  # with held experts' stacks in ``lp``
+    dense: bool = False,  # a leading dense layer of an expert model
 ) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]], jax.Array]:
     """One transformer block, and its MOE_COUNTS (zeros when dense; only
     ``token_valid`` feeds them). If cache_kv given, k/v are written at
@@ -1472,32 +1826,35 @@ def _layer_counted(
     )
     y, counts = _ffn_half(
         x, lp, config, config.output_norm, token_valid, lora, lora_scale,
-        adapter_rows, layer if moe_layer is None else moe_layer,
+        adapter_rows, layer if moe_layer is None else moe_layer, dense,
     )
     return y, new_cache, counts
 
 
 def _ffn_half(
     x, lp, config, output_norm=False, token_valid=None, lora=None, lora_scale=None,
-    adapter_rows=None, layer=None,
+    adapter_rows=None, layer=None, dense=False,
 ):
     """The feed-forward half of a block and its counts (`moe_count_names`):
     x + f(norm(x)), or, for a dense FFN, x + norm(f(x)) with ``output_norm``.
     An expert layer is `moe_ffn` and its capacity rule, or, where the model
     holds its experts so (``experts_held``), `moe_ffn_held`, which drops
     nothing: ``lp`` then carries the held experts' whole stacks and ``layer``
-    says which of them is this block's (`_split_held`)."""
+    says which of them is this block's (`_split_held`). ``dense``: a leading
+    dense layer of an expert model (``config.n_leading_dense``), whose ``lp``
+    holds a dense FFN of ``d_ff``; its counts are the dense model's zeros."""
     eps = config.rms_norm_eps
-    if config.is_moe and output_norm:
+    moe = config.is_moe and not dense
+    if moe and output_norm:
         raise NotImplementedError(f"an expert FFN under an output norm ({config.name})")
-    if config.is_moe and config.experts_held:
+    if moe and config.experts_held:
         with jax.named_scope("moe_ffn"):
             # the router reads the norm before it is rounded (`_parallel_layer`)
             u32 = rms_norm(x.astype(jnp.float32), lp["ffn_norm"], eps)
             ffn_out, counts = moe_ffn_held(
                 u32.astype(x.dtype), lp, config, token_valid, layer, route_on=u32
             )
-    elif config.is_moe:
+    elif moe:
         with jax.named_scope("moe_ffn"):
             ffn_in = rms_norm(x, lp["ffn_norm"], eps)
             ffn_out, counts = moe_ffn_counted(ffn_in, lp, config, token_valid)
@@ -1697,20 +2054,35 @@ def _with_entry(state, kind, entry):
     return {**state, **new} if _KIND_KEY[kind] is None else {**state, _KIND_KEY[kind]: new}
 
 
-def _route_all(xf: jax.Array, router: jax.Array, config: ModelConfig):
+def _route_all(xf: jax.Array, router: jax.Array, config: ModelConfig, bias=None):
     """(weights [T, k] float32, chosen [T, k]) over ALL ``n_experts``, held
     here or not: the scores in float32 at the highest matmul precision (a
     bf16 product moves which experts a token near a tie takes), the k
-    largest chosen, their weights normalised over the k chosen."""
+    largest chosen, their weights normalised over the k chosen. With
+    ``bias`` [E] float32 (``config.router_bias``) the k largest of score +
+    bias are chosen and weighed by their scores alone: the bias balances the
+    experts' load and is no part of the mixture. The weights times
+    ``config.routed_scaling``."""
     logits = jnp.dot(
         xf.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )  # [T, E]
     if config.moe_scoring == "sigmoid":
-        top, chosen = lax.top_k(jax.nn.sigmoid(logits), config.n_experts_per_tok)
-        return top / jnp.sum(top, axis=-1, keepdims=True), chosen
-    top, chosen = lax.top_k(logits, config.n_experts_per_tok)
-    return jax.nn.softmax(top, axis=-1), chosen
+        scores = jax.nn.sigmoid(logits)
+        if bias is None:
+            top, chosen = lax.top_k(scores, config.n_experts_per_tok)
+        else:
+            _, chosen = lax.top_k(scores + bias.astype(jnp.float32), config.n_experts_per_tok)
+            top = jnp.take_along_axis(scores, chosen, axis=-1)
+        weights = top / jnp.sum(top, axis=-1, keepdims=True)
+    elif bias is not None:
+        raise NotImplementedError(f"{config.name}: a router bias under softmax scoring")
+    else:
+        top, chosen = lax.top_k(logits, config.n_experts_per_tok)
+        weights = jax.nn.softmax(top, axis=-1)
+    if config.routed_scaling != 1.0:
+        weights = weights * config.routed_scaling
+    return weights, chosen
 
 
 def moe_ffn_held(
@@ -1741,7 +2113,8 @@ def moe_ffn_held(
 
     with jax.named_scope("moe_ffn.route"):
         weights, chosen = _route_all(
-            xf if route_on is None else route_on.reshape(t, d), lp["router"], config
+            xf if route_on is None else route_on.reshape(t, d), lp["router"], config,
+            *((lp["router_bias"],) if config.router_bias else ()),
         )
 
     tile = gm.row_tile(t, k, config.n_experts)
@@ -2066,8 +2439,9 @@ def _scan_layers(
     # a dense model's zeros stay out of the scan: its programs are the
     # ones they were, and the counts a constant beside them
     moe = config.is_moe
-    # the layer's index rides the scan only where held experts need it
-    index = None if held is None else jnp.arange(config.n_layers)
+    # the layer's index rides the scan only where held experts need it (the
+    # scan is over the layers behind the leading dense ones)
+    index = None if held is None else jnp.arange(config.n_layers - config.n_leading_dense)
     leaves = config.page_leaves
 
     def body(carry, inputs):
@@ -2110,6 +2484,9 @@ def _scan_layers_inplace(
     layers, held = _split_held(params["layers"], config)
     lora_layers, lora_scale = _split_lora(lora)
     leaves = config.page_leaves
+    # behind leading dense layers (`_run_behind_dense_layers` ran them) a layer's
+    # place in the pool and its place among the experts' stacks part
+    first = config.n_leading_dense
 
     def body(carry, inputs):
         x, pool = carry
@@ -2119,17 +2496,63 @@ def _scan_layers_inplace(
             cache_kv=tuple(pool[leaf] for leaf in leaves),
             cache_positions=cache_positions, verify=verify,
             paged_table=paged_table, page_size=page_size, lora=ll,
-            lora_scale=lora_scale, adapter_rows=adapter_rows, layer=l,
-            block=block, token_valid=token_valid,
+            lora_scale=lora_scale, adapter_rows=adapter_rows,
+            layer=l + first if first else l, block=block, token_valid=token_valid,
+            moe_layer=l if first else None,
         )
         return (y, dict(zip(leaves, entry))), (counts if config.is_moe else None)
 
     (x, pool), counts = lax.scan(
-        body, (x, pool), (layers, jnp.arange(config.n_layers), lora_layers)
+        body, (x, pool), (layers, jnp.arange(config.n_layers - first), lora_layers)
     )
     # a dense model's zeros stay out of the scan (see _scan_layers)
     counts = counts.sum(0) if config.is_moe else _no_moe_counts()
     return x, pool, counts
+
+
+def _run_behind_dense_layers(
+    params, x, sin, cos, mask, config, positions, state, table, page_size, counted
+):
+    """`_run_layers` of a model whose first ``n_leading_dense`` layers carry a
+    dense FFN: those run first, each ONE `_layer_counted` on a layer of their
+    own stack (``params["dense_layers"]``; the page pool's layers 0 ..), then
+    the uniform loop over the expert layers (the pool's layers behind them).
+    Nothing is added to the loops: a local cache's leading entries are cut
+    off before the scan and joined on after it."""
+    first, leaves = config.n_leading_dense, config.page_leaves
+    written = []
+    for i in range(first):
+        lp = jax.tree.map(lambda a: a[i], params["dense_layers"])
+        if table is not None:
+            x, entry, _ = _layer_counted(
+                x, lp, sin, cos, mask, config, cache_kv=tuple(state[leaf] for leaf in leaves),
+                cache_positions=positions, paged_table=table, page_size=page_size,
+                layer=jnp.int32(i), dense=True,
+            )
+            state = dict(zip(leaves, entry))
+        else:
+            entry = None if state is None else tuple(state[leaf][i] for leaf in leaves)
+            x, entry, _ = _layer_counted(
+                x, lp, sin, cos, mask, config, cache_kv=entry, cache_positions=positions,
+                dense=True,
+            )
+            written.append(entry)
+    if table is not None:
+        return _scan_layers_inplace(
+            params, x, sin, cos, mask, config, state, positions, table, page_size,
+            token_valid=counted,
+        )
+    rest = None if state is None else {leaf: state[leaf][first:] for leaf in leaves}
+    x, rest, counts = _scan_layers(
+        params, x, sin, cos, mask, config, cache=rest, cache_positions=positions,
+        token_valid=counted,
+    )
+    if state is not None:
+        rest = {
+            leaf: jnp.concatenate([jnp.stack([w[j] for w in written]), rest[leaf]])
+            for j, leaf in enumerate(leaves)
+        }
+    return x, rest, counts
 
 
 def _run_layers(
@@ -2148,6 +2571,10 @@ def _run_layers(
     every row, as it has: S5). ``rec_rows``, ``fresh``: the recurrent
     state's rows and which start from zero (`_linear_attention_block`).
     Returns (x, state, the layers' summed counts, `moe_count_names`)."""
+    if config.n_leading_dense:
+        return _run_behind_dense_layers(
+            params, x, sin, cos, mask, config, positions, state, table, page_size, counted
+        )
     if not config.layer_pattern:
         # the stacks ride the scan's xs here and are closed over in the
         # period loop: folding the two is a change of these models' programs
@@ -2278,6 +2705,13 @@ def make_kv_cache(
             config.n_layers_of(kind), rows, config.n_kv_heads, max_len,
             config.resolved_head_dim,
         )
+        if config.has_latent:
+            # ONE row a token in place of K and V, K's layout with one head:
+            # [L, B, 1, T, latent_key_width]; the indexer's key beside it
+            return {
+                "lat": jnp.zeros((*shape[:2], 1, max_len, config.latent_key_width), dtype),
+                "ik": jnp.zeros((*shape[:2], max_len, config.index_key_width), dtype),
+            }
         if config.kv_cache_dtype == "int8":
             if _KIND_KEY[kind]:
                 raise NotImplementedError(f"an int8 KV cache for window layers ({config.name})")
@@ -2563,8 +2997,8 @@ def insert_copies_pages(
     gates are those of ``attention_impl: auto``."""
     from langstream_tpu.ops.attention import paged_pallas_ok, paged_tiles_ok
 
-    k = pool["k"]
-    if "win" in pool or isinstance(k, dict) or width % page_size:
+    k = pool.get("k")  # None: a latent's leaf, written by the scatter
+    if "win" in pool or k is None or isinstance(k, dict) or width % page_size:
         return False
     if config is None:
         return paged_tiles_ok(k.shape[-1], page_size)

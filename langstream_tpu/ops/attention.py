@@ -15,6 +15,11 @@ blocks are naturally aligned, and a per-head kv block is a contiguous
   continuous batcher packs rows of very different lengths into one step, so
   a masked read over a fixed width wastes bandwidth proportional to
   max_len - mean_len).
+  ``ragged_paged_selected_attention`` is that walk under a row's selection
+  (a model with an indexer), and ``ragged_paged_latent_attention`` the same
+  over a pool that holds ONE row a token for every head, a latent in place of
+  K and V: absorbed queries of 64 heads against the row as it lies, a page
+  fetched once and read as key and, its first lanes, as value.
 - ``flash_segment_attention``: a prefill segment's queries over the row's
   gathered columns, causal (windowed), a query block visiting only the key
   blocks it can see; ``sparse_segment_attention`` is that walk under a
@@ -857,6 +862,21 @@ def _page_int8(q, page, scales, j, scale):
     return s, vs, vq.astype(jnp.float32)
 
 
+def _page_latent(q, page, scales, j, scale, value_width):
+    """`_page_bf16` over a pool of LATENTS: a page is ONE VMEM block
+    [1, ps, W], a token's row its key for every head and, its first
+    ``value_width`` lanes, its value: read once, used twice. The products
+    take the page as it lies (bf16 in, float32 out): 64 query heads against
+    one key head put the kernel near the chip's ridge, where a float32
+    product would bind before the bytes do."""
+    (rows,) = page
+    s = jax.lax.dot_general(
+        q.astype(rows.dtype), rows, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * scale  # [1, H, ps]
+    return s, None, rows[..., :value_width]
+
+
 # Pages of K and V held in VMEM at once: the one computed on and the next
 # three live pages of the batch in flight behind it (1 MB of bf16 at 8 kv
 # heads x 64 x 128). One page in flight left the copy's latency in the
@@ -878,6 +898,7 @@ def _paged_decode_kernel(
     softcap,
     windowed: bool = False,
     selected: bool = False,
+    value_width: int = 0,  # of the output where it is not q's (a latent's value)
 ):
     # a window layer's rows read [lower, length): one more prefetched vector
     lower_ref = refs[0] if windowed else None
@@ -969,7 +990,8 @@ def _paged_decode_kernel(
         p = jnp.where(s <= _NEG, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
         pv = jax.lax.dot_general(
-            p if p_scale is None else p * p_scale,
+            # (a float32 page, every loader's but the latent's: no cast)
+            (p if p_scale is None else p * p_scale).astype(v.dtype),
             v,
             dimension_numbers=(((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -981,7 +1003,7 @@ def _paged_decode_kernel(
         (
             jnp.full((hkv, group, 1), _NEG, jnp.float32),
             jnp.zeros((hkv, group, 1), jnp.float32),
-            jnp.zeros((hkv, group, d), jnp.float32),
+            jnp.zeros((hkv, group, value_width or d), jnp.float32),
         ),
     )
     # a row of no pages: acc 0 over the floor of l, zeros and not NaN
@@ -1014,6 +1036,8 @@ def _paged_decode_call(
     config: ModelConfig, page_size: int, interpret: bool,
     lower: jax.Array | None = None,
     chosen: jax.Array | None = None,
+    scale: float | None = None,  # the scores'; None: 1 / sqrt(q's width)
+    value_width: int = 0,  # the output's; 0: q's
 ) -> jax.Array:
     """The one `pallas_call` of the paged kernels. ``leaves`` are the
     pool's arrays [L, P, Hkv, ps, D] whose pages the kernel fetches itself;
@@ -1036,11 +1060,13 @@ def _paged_decode_call(
         n_leaves=len(leaves),
         page_size=page_size,
         table_len=tp,
-        scale=1.0 / (d**0.5),
+        scale=1.0 / (d**0.5) if scale is None else scale,
         softcap=config.attn_logit_softcap,
         windowed=lower is not None,
         selected=chosen is not None,
+        value_width=value_width,
     )
+    dv = value_width or d
     bounds = [lengths.astype(jnp.int32)]
     if lower is not None:
         bounds.append(jnp.minimum(lower.astype(jnp.int32), bounds[0]))
@@ -1051,7 +1077,7 @@ def _paged_decode_call(
         + [pl.BlockSpec((1, tp, hkv, page_size), _paged_row_index)] * len(scales)
         + [pl.BlockSpec((1, tp, 1, page_size), _paged_row_index)] * (chosen is not None)
         + [pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)] * len(leaves),
-        out_specs=pl.BlockSpec((1, hkv, group, d), _paged_row_index),
+        out_specs=pl.BlockSpec((1, hkv, group, dv), _paged_row_index),
         scratch_shapes=[
             pltpu.VMEM((_PAGE_SLOTS,) + leaf.shape[2:], leaf.dtype)
             for leaf in leaves
@@ -1064,7 +1090,7 @@ def _paged_decode_call(
         kernel,
         name=name,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, dv), q.dtype),
         # rows in order on one core: the walk along the batch's live pages
         # is carried from one row to the next
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
@@ -1078,7 +1104,7 @@ def _paged_decode_call(
           else [chosen.astype(jnp.float32).reshape(b, tp, 1, page_size)]),
         *(_flat_pool(leaf) for leaf in leaves),
     )
-    return out.reshape(b, h * d)
+    return out.reshape(b, h * dv)
 
 
 @_per_kv_head(3, kv_head_axis=2)
@@ -1164,6 +1190,38 @@ def ragged_paged_selected_attention(
     return _paged_decode_call(
         "ragged_paged_selected_attention", _page_bf16, q, [k, v], [], lengths,
         table, layer, config, page_size, interpret, chosen=chosen,
+    )
+
+
+def ragged_paged_latent_attention(
+    q: jax.Array,  # [B, H, W]: a row's absorbed queries, [q_nope W_uk^T | q_rope | 0]
+    latents: jax.Array,  # the page pool's latent leaf [L, P, 1, ps, W], read at `layer`
+    lengths: jax.Array,  # [B] valid logical columns per row; 0 = no work
+    table: jax.Array,  # [B, Tp]
+    layer: jax.Array,
+    chosen: jax.Array,  # [B, Tp x ps] bool: the columns the row's query reads
+    config: ModelConfig,
+    page_size: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """A decode step's attention IN THE LATENT SPACE, under the row's
+    selection -> [B, H x kv_lora_rank]: `ragged_paged_selected_attention`
+    over a pool that holds ONE row a token, [normed latent | rotary key |
+    zeros], which is the key of all H heads and, its first
+    ``config.kv_lora_rank`` lanes, their value (models/transformer
+    `_latent_decode_read` absorbs the up-projection into the query before
+    and into the output after: nothing of a head's keys or values is
+    formed). A page is fetched once and serves both products; the scores'
+    scale is the expanded head's, 1 / sqrt(qk_nope_head_dim +
+    qk_rope_head_dim). The bytes follow the row's length, as the selected
+    walk's do. Under its own name on the `pallas_call`; no mesh
+    (`ServingEngine` refuses one for such a model)."""
+    return _paged_decode_call(
+        "ragged_paged_latent_attention",
+        functools.partial(_page_latent, value_width=config.kv_lora_rank),
+        q, [latents], [], lengths, table, layer, config, page_size, interpret,
+        chosen=chosen, scale=config.resolved_head_dim**-0.5,
+        value_width=config.kv_lora_rank,
     )
 
 

@@ -605,9 +605,10 @@ def _paged_segment_and_sample(
 
 
 def _on_pages(fn, pool, *rest):
-    """``fn`` over the pool's PAGE leaves: "k", "v" and, for a model with an
-    indexer, its keys "ik" (a copied or a zeroed page is whole: a page that
-    left its indexer keys behind would serve stale keys to the selection).
+    """``fn`` over the pool's PAGE leaves: "k" and "v" (for a model that
+    keeps a latent "lat" in their place) and, for a model with an indexer,
+    its keys "ik" (a copied or a zeroed page is whole: a page that left its
+    indexer keys behind would serve stale keys to the selection).
     Every leaf has its pages on axis 1. What lies beside them passes
     through: a recurrent state ("rec", a row a slot, no page axis), and a
     window group's pages ("win"), which are never copied or restored: the
@@ -1290,7 +1291,13 @@ class ServingEngine:
             # pool has no third leaf; the indexer takes no adapter terms and
             # its gathers are not sharded. Prefix reuse is served: a cached
             # page holds its tokens' indexer keys, which depend on nothing
-            # after them, and a copied page is whole (`_on_pages`).
+            # after them, and a copied page is whole (`_on_pages`). A model
+            # that keeps a LATENT in place of K and V (docs/SERVING.md "A
+            # model that keeps a latent, not keys and values") has an indexer
+            # and is refused the same options for the same reasons, its
+            # latent leaf "lat" standing where "k" and "v" do: no tier, wire
+            # or checkpoint format carries it, no verify or adapter term
+            # reaches its attention half, its decode kernel takes no mesh.
             on = lambda v: v is True or str(v).lower() in ("auto", "on", "true", "1")  # noqa: E731
             refused = {
                 "host_kv_fraction": float(host_kv_fraction) > 0,
@@ -1305,6 +1312,10 @@ class ServingEngine:
             asked = [name for name, is_on in refused.items() if is_on]
             if asked:
                 raise ValueError(
+                    f"{config.name} keeps a latent under a learned selection: "
+                    f"{', '.join(asked)} cannot be used with a latent and an "
+                    "indexer's keys in the page pool"
+                    if config.has_latent else
                     f"{config.name} reads a learned selection: {', '.join(asked)} "
                     "cannot be used with an indexer's keys in the page pool"
                 )
@@ -1600,6 +1611,7 @@ class ServingEngine:
         # a layer (stats "index-tokens-scored-total", "kv-tokens-selected-total")
         self.index_tokens_scored_total = 0
         self.kv_tokens_selected_total = 0
+        self.latent_tokens_expanded_total = 0
         # slots freed since the last dispatch: their device temp must be
         # zeroed, else sample()'s batch-wide any_sample/any_filter predicates
         # keep paying the full-vocab sort for a slot that no longer exists
@@ -2649,6 +2661,12 @@ class ServingEngine:
                 }
                 if self.config.has_indexer else {}
             ),
+            # a model that keeps a latent: the cached columns its segments
+            # re-expanded a layer (a decode chunk expands none)
+            **(
+                {"latent-tokens-expanded-total": self.latent_tokens_expanded_total}
+                if self.config.has_latent else {}
+            ),
             # a model with window layers: its second page group's use
             **(
                 {
@@ -2684,6 +2702,9 @@ class ServingEngine:
             "kv-pages-total": self._pagepool.num_pages,
             "kv-pages-in-use": self._pagepool.pages_in_use,
             "kv-bytes-per-page": self._pagepool.bytes_per_page,
+            # what a token's leaves hold over all layers, in the pool's dtype
+            # (K and V; a latent in their place; an indexer's key)
+            "kv-bytes-per-token": self._pagepool.bytes_per_page // self.page_size,
             # the recurrent state beside the pages: one row a slot (zeros
             # for a model without recurrent layers)
             "recurrent-state-bytes": self._pagepool.state_bytes_total,
@@ -6273,7 +6294,9 @@ class ServingEngine:
         if self.config.has_indexer:
             raise MigrationError(
                 "KV-page migration carries K and V only: a page's indexer "
-                "keys have no wire format yet"
+                "keys"
+                + (" and its latent" if self.config.has_latent else "")
+                + " have no wire format yet"
             )
         reply: "queue.SimpleQueue" = queue.SimpleQueue()
         self._migrate_cmds.put((kind, payload, reply))
@@ -6661,6 +6684,15 @@ class ServingEngine:
                     offset=s0, kv_tokens_read=selected, index_tokens_scored=scored,
                     kv_tokens_selected=selected,
                 )
+            if self.config.has_latent:
+                # the cached columns (earlier segments') whose latents this
+                # segment re-expanded into keys and values, a layer: every one
+                # behind it (the program expands its table's whole width: the
+                # rest is the segment's own tokens and unmapped columns)
+                with self._stats_lock:
+                    self.latent_tokens_expanded_total += s0
+                if disp is not None:
+                    disp.attrs.update(latent_tokens_expanded=s0)
         if not final:
             if per_segment:  # nothing to deliver: the fetch lands the span
                 return [(
@@ -6813,6 +6845,8 @@ class ServingEngine:
         return {
             "kv_tokens_read": selected, "index_tokens_scored": scored,
             "kv_tokens_selected": selected,
+            # a decode step attends in the latent space: it expands nothing
+            **({"latent_tokens_expanded": 0} if self.config.has_latent else {}),
         }
 
     def _advance_window_rows(self, steps: int) -> int:

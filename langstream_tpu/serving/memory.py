@@ -44,7 +44,9 @@ class ServingMemoryPlan:
     # entry, so a decode chunk holds nothing of a layer's size beside it.
     # A model with an indexer keeps its indexer's key a token in the same
     # pages (a third leaf, [L, P, page_size, index_key_width]): this term
-    # counts it, since it is `make_page_pool`'s whole tree.
+    # counts it, since it is `make_page_pool`'s whole tree; a model that
+    # keeps a latent in place of K and V has one row a token there
+    # ([L, P, 1, page_size, latent_key_width]) and is counted the same way.
     # Sized by pages_for_fraction: every slot's max_seq_len plus the
     # prefix-cache-fraction alias headroom.
     page_pool_bytes: int = 0

@@ -309,7 +309,10 @@ class PagePool:
         # a model with an indexer: a third leaf a token beside K and V,
         # ``dev["ik"]`` [L, P, ps, index_key_width], its indexer's keys, under
         # the SAME table and page index: it is part of a page
-        # (``bytes_per_page`` counts it), so nothing here tells it apart
+        # (``bytes_per_page`` counts it), so nothing here tells it apart; a
+        # model that keeps a latent has ``dev["lat"]`` [L, P, 1, ps,
+        # latent_key_width] where the others have "k" and "v": pages all the
+        # same, under the same table
         # a model with window layers: their pages are a group of their own
         # (``dev["win"]``, `WindowPageGroup`), reserved with the full
         # group's in `reserve` and freed with them in `free_slot`

@@ -15,7 +15,11 @@ from tokens and labels, the cost and the metric files) and of
 catalog's keys with the nested groups whole and the stated cut, the cell's
 sizing, the refusals, the seeded tree against the program's own, the costs
 and the metric files; its names all say `keye`, since the cases here share
-one namespace) and the cases of
+one namespace) and of
+`benchmark/tests/test_glm_moe_dsa_family.py` (GLM-5: the contract, the
+catalog's keys and the stated cut of four, the cell's sizing, the refusals,
+the seeded tree against the program's own, the chain's halves by kind, the
+costs and the metric files; its names all say `glm`) and the cases of
 `benchmark/tests/test_request_readers.py` (the clock between a profile and the
 spans, a first token's stages, the device's idle by what the engine held; one
 of them records a profile of a small engine) run here as they stand
@@ -51,4 +55,5 @@ globals().update(_cases("test_olmo_hybrid_family"))
 globals().update(_cases("test_cohere2_moe_family"))
 globals().update(_cases("test_sdar_moe_family"))
 globals().update(_cases("test_keye_vl2_family"))
+globals().update(_cases("test_glm_moe_dsa_family"))
 globals().update(_cases("test_request_readers"))

@@ -760,6 +760,32 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
     assert not selection & (
         texts["dense"] | texts["moe"] | texts["hybrid"] | texts["window"] | texts["blocks"]
     )
+    # a model that keeps a latent in place of K and V: the projections, the
+    # absorb and W_uv under `attention.latent` (a decode chunk), a segment's
+    # re-expansion under `attention.latent.expand`, the selection's scopes as
+    # they are, the leading dense layer's `ffn` beside the expert layers'
+    latent = MODEL_PRESETS["tiny-latent-moe-test"]
+
+    def latent_programs(params, pool):
+        chunk = E._paged_decode_chunk(
+            params, tokens[:, 0], lengths, pool, table, key, ones, zeros, ones, 2, latent, page,
+        )
+        return chunk, E._paged_segment_and_sample(
+            params, tokens[:1, :16], lengths[:1], lengths[:1], chunk[3], table[:1], key,
+            ones[:1], zeros[:1], ones[:1], latent, page,
+        )
+
+    texts["latent"] = lowered_scopes(
+        latent_programs, T.init_params(latent, jax.random.PRNGKey(6)),
+        T.make_page_pool(latent, 8, page),
+    )
+    kept = {"attention.latent", "attention.latent.expand"}
+    assert kept | selection | {"attention", "kv_pool.write", "ffn", "moe_ffn", "moe_ffn.shared",
+                               "head"} <= texts["latent"]
+    assert not kept & (
+        texts["dense"] | texts["moe"] | texts["hybrid"] | texts["window"] | texts["blocks"]
+        | texts["sparse"]
+    )
     assert set(T.SCOPES) <= set().union(*texts.values())
     assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
     assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",

@@ -197,6 +197,37 @@ KEYE = dataclasses.replace(
 )
 
 
+# GLM-5 as the benchmark cuts it (`tiny-latent-moe-test`'s block at the
+# published widths): a query latent of 2,048, a key-value latent of 512 and a
+# rotary key of 64 for 64 heads of 192 + 64 (values 256), an indexer of 32
+# heads x 128 (64 turned) that keeps 2,048 tokens, one leading dense layer of
+# 12,288 and six expert layers that hold 16 of 256 experts of 6144 x 2048 and
+# a shared one, a slice of 19,360 rows of the vocabulary; the cell: 16 slots x
+# 272 pages, a token of the pool one row of 640 lanes and one of 128
+GLM = dataclasses.replace(
+    MODEL_PRESETS["tiny-latent-moe-test"], name="glm-widths", d_model=6144, d_ff=12288,
+    moe_d_ff=2048, n_layers=7, n_heads=64, n_kv_heads=64, n_experts=256, n_experts_per_tok=8,
+    experts_held=(0, 16), vocab_size=19360, q_lora_rank=2048, kv_lora_rank=512,
+    qk_nope_head_dim=192, qk_rope_head_dim=64, v_head_dim=256, index_n_heads=32,
+    index_head_dim=128, index_topk=2048, index_rope_dim=64, max_seq_len=202752,
+)
+
+
+def _latent_decode(config, batch, table, pages, layers):
+    """A decode step's attention in the latent space: absorbed queries
+    against ONE leaf of rows, a page fetched once for key and value."""
+    width = config.latent_key_width
+    return (
+        lambda q, rows, lengths, tab, layer, chosen: A.ragged_paged_latent_attention(
+            q, rows, lengths, tab, layer, chosen, config, PAGE
+        ),
+        (SDS((batch, config.n_heads, width), jnp.bfloat16),
+         SDS((layers, pages, 1, PAGE, width), jnp.bfloat16), SDS((batch,), jnp.int32),
+         SDS((batch, table), jnp.int32), SDS((), jnp.int32),
+         SDS((batch, table * PAGE), jnp.bool_)),
+    )
+
+
 def _index_scores(config, s, t):
     """The indexer's scores of a segment, in tiles."""
     hi, di = config.index_n_heads, config.index_head_dim
@@ -368,6 +399,16 @@ CASES = {
     "keye-segment-select-2432": _segment_select(KEYE, 2432, 2432),
     "keye-sparse-segment-2432": _sparse_segment(KEYE, 2432, 2432),
     "keye34816-segment-select-2048": _segment_select(KEYE, 2048, 34816),
+    # the GLM-5 cell: 16 slots x 272 pages of a 4,352-page pool of latents, a
+    # 2,048-token segment against 17,408 columns at 64 expanded heads of 256
+    # (one query head a key head: `_vmem_block_q` keeps 512-row query blocks)
+    # with an indexer of 32 heads x 128, and the check's width (6,528 = 51 x
+    # 128 from offset 0)
+    "glm16x272-latent-decode": _latent_decode(GLM, 16, 272, 4352, 7),
+    "glm-segment-select-2048": _segment_select(GLM, 2048, 17408),
+    "glm-sparse-segment-2048": _sparse_segment(GLM, 2048, 17408),
+    "glm-segment-select-6528": _segment_select(GLM, 6528, 6528),
+    "glm-sparse-segment-6528": _sparse_segment(GLM, 6528, 6528),
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
     **{f"gemma-prefill-{s}": _prefill(GEMMA, s) for s in (512, 1024, 2048)},
@@ -423,6 +464,7 @@ def _kernel_of(case: str) -> str:
         "gated-delta-update": "gated_delta_update",
         "windowed-decode": "ragged_paged_decode_attention",
         "selected-decode": "ragged_paged_selected_attention",
+        "latent-decode": "ragged_paged_latent_attention",
         "segment": "flash_segment_attention",
         "window-segment": "flash_segment_attention",
         "grouped-matmul": "moe_grouped_matmul",
@@ -907,6 +949,96 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text), leaf.shape
 
 
+@pytest.mark.parametrize("program", ["_paged_decode_chunk", "_paged_segment_and_sample"])
+def test_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatch, program):
+    """The GLM-5 cell's two device programs whole, at its sizes (16 slots x
+    272 pages of a 4,352-page pool whose leaves are the latent, 640 lanes a
+    token, and the indexer's key; a decode chunk, and a 2,048-token segment
+    against 17,408 columns), int8 weights and the pool donated. The decode
+    step attends in the latent space: its program holds NO operand or
+    temporary of a row's expanded cache (keys or values of 64 heads over a
+    table, nope + v or 256 wide, in any order) and no gather of a row's
+    latents; the segment expands its row's latents into keys and values of 64
+    heads, ranks in one call and never forms scores of [S, heads, T]; each
+    fits the chip beside its state, and no leaf of the pool is relaid."""
+    from langstream_tpu.models.quant import init_random_quantized_params
+    from langstream_tpu.models.transformer import make_page_pool
+    from langstream_tpu.serving import engine as E
+
+    slots, pages, table, seg = 16, 4352, 272, 2048
+    t = table * PAGE
+    key = SDS((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: init_random_quantized_params(GLM, k), key)
+    assert set(params) == {"embed", "layers", "dense_layers", "final_norm", "lm_head"}
+    assert params["dense_layers"]["w_gate"]["q"].shape == (1, 6144, 12288)
+    assert params["layers"]["w_gate"]["q"].shape == (6, 16, 6144, 2048)
+    assert params["layers"]["wkv_b"]["q"].shape == (6, 512, 64 * 448)
+    pool = jax.eval_shape(lambda: make_page_pool(GLM, pages, PAGE))
+    assert {k: v.shape for k, v in pool.items()} == {
+        "lat": (7, pages, 1, PAGE, 640), "ik": (7, pages, PAGE, 128),
+    }
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    if program == "_paged_decode_chunk":
+        args = (params, i32(slots), i32(slots), pool, i32(slots, table), key,
+                f32(slots), i32(slots), f32(slots))
+        static = (8, GLM, PAGE)
+        kernels = ("ragged_paged_latent_attention", "moe_grouped_matmul")
+        path = f"paged-decode-latent[s=1,t={t}]"
+    else:
+        args = (params, i32(1, seg), i32(1), i32(1), pool, i32(1, table), key,
+                f32(1), i32(1), f32(1))
+        static = (GLM, PAGE)
+        kernels = (
+            "flash_segment_attention", "sparse_segment_attention", "segment_select",
+            "moe_grouped_matmul",
+        )
+        path = f"paged-segment-latent-sparse[s={seg},t={t}]"
+    compiled = _compile_as_on_chip(
+        monkeypatch, getattr(E, program), _placed(args, SingleDeviceSharding(v5e[0])), static
+    )
+    text = compiled.as_text()
+    paths = A.attention_paths()
+    assert path in paths
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    h, width = GLM.n_heads, GLM.latent_key_width
+    has = lambda shape: "[" + ",".join(map(str, shape)) + "]" in text  # noqa: E731
+    if program == "_paged_decode_chunk":
+        assert paths[path] == "ragged_paged_latent_attention"
+        for d in (448, 256, 192):  # a row's expanded keys or values, either order
+            for shape in ([slots, h, t, d], [slots, t, h, d], [slots, t, h * d], [h, t, d]):
+                assert not has(shape), shape
+        # nor its latents gathered: the kernel reads the pages where they lie
+        for shape in ([slots, 1, t, width], [slots, t, width], [slots, table, 1, PAGE, width]):
+            assert not has(shape), shape
+        assert not re.search(r"%ragged_paged_(decode|selected)_attention(\.\d+)? = ", text)
+    else:
+        assert paths[path] == "sparse_segment_attention"
+        assert paths[f"paged-segment-latent-select[s={seg},t={t}]"] == "segment_select"
+        assert paths[f"paged-segment-latent[s={seg},t={t}]"] == "flash_segment_attention"
+        assert has([1, h, t, 256])  # the expanded keys and values of the row's columns
+        for heads in (h, GLM.index_n_heads):
+            for shape in ([1, seg, heads, t], [1, heads, seg, t], [seg, heads, t], [heads, seg, t]):
+                assert not has(shape), shape
+        whole = f"[1,{seg},{t}]"
+        assert f"s8{whole}" in text
+        for dtype in ("f32", "u32", "s32", "pred"):
+            assert dtype + whole not in text, dtype
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
+    assert pool_bytes == pages * PAGE * GLM.kv_bytes_per_token()
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pool, updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    print(program, "temp", memory.temp_size_in_bytes, "args", memory.argument_size_in_bytes)
+    assert held <= V5E_HBM_BYTES
+    for leaf in pool.values():
+        dims = re.escape("[" + ",".join(map(str, leaf.shape)) + "]")
+        assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text), leaf.shape
+
+
 # ---------------------------------------------------------------------------
 # The paged decode skeleton is shared: the selection is a static option of it,
 # and without one nothing of it is traced. Two pins of that, both the text the
@@ -1093,7 +1225,17 @@ def _engine_program_text(case: str) -> str:
     ).as_text()
 
 
-ENGINE_PROGRAMS = {**DECODE_PROGRAMS_AT_PARENT, **ENGINE_PROGRAMS_AT_PARENT}
+# The latent model's three engine programs (`tiny-latent-moe-test`), as PR 47
+# left them: what a later PR that does not mean to touch them holds still.
+LATENT_PROGRAMS_AT_PR47 = {
+    "tiny-latent-moe-test": "7e30a4ca8989f33e",
+    "segment/tiny-latent-moe-test": "5d841ea49914bce2",
+    "admit/tiny-latent-moe-test": "7ccaa668ff6f12fc",
+}
+
+ENGINE_PROGRAMS = {
+    **DECODE_PROGRAMS_AT_PARENT, **ENGINE_PROGRAMS_AT_PARENT, **LATENT_PROGRAMS_AT_PR47,
+}
 
 
 @pytest.mark.parametrize("case", sorted(ENGINE_PROGRAMS))
@@ -1129,6 +1271,22 @@ PATHS_AT_PARENT = {
         "paged-decode[s=1,t=48]": "ragged_paged_decode_attention",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "flash_prefill_attention",
+    },
+    # (PR 47's rows: the latent model's entries are its own)
+    "tiny-latent-moe-test/auto": {
+        "paged-decode-latent[s=1,t=48]": "jnp",
+        "paged-segment-latent-sparse[s=16,t=48]": "jnp",
+        "prefill-sparse[s=16,t=16]": "jnp",
+    },
+    "tiny-latent-moe-test/pallas": {
+        "paged-decode-latent[s=1,t=48]": "ragged_paged_latent_attention",
+        "paged-segment-latent-select[s=16,t=48]": "segment_select",
+        "paged-segment-latent-sparse[s=16,t=48]": "sparse_segment_attention",
+        "paged-segment-latent[s=16,t=48]": "flash_segment_attention",
+        "prefill-select[s=16,t=16]": "segment_select",
+        "prefill-sparse[s=16,t=16]": "sparse_segment_attention",
+        "segment-select[s=16,t=16]": "block_q 16, block_k 16, to the diagonal",
+        "segment-select[s=16,t=48]": "block_q 16, block_k 48, to the diagonal",
     },
     "tiny-moe-test/auto": {
         "paged-decode[s=1,t=48]": "jnp",
