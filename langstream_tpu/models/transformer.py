@@ -1098,6 +1098,87 @@ def _kept_width(k_idx, leaf):
     return jnp.pad(k_idx.astype(leaf.dtype), [(0, 0)] * (k_idx.ndim - 1) + [(0, pad)])
 
 
+def _copies_pages(leaf, width: int, page_size: int, config: Optional[ModelConfig]) -> bool:
+    """Whether ``width`` columns a row reach ``leaf`` of the page pool as
+    copies of whole pages (`ops/attention.paged_insert_pages` and its
+    one-layer form), the rule an admission group's insert and a segment's
+    write share: a leaf that holds values alone (an int8 pool's scales are
+    words scattered over a page, no copy Mosaic takes), a width of whole
+    pages, no mesh (``config.kernel_mesh``: GSPMD cannot partition the
+    kernel), and where the paged decode kernel runs (``paged_pallas_ok``).
+    Without a ``config`` (a caller off the engine, which holds no mesh) the
+    gates are those of ``attention_impl: auto``."""
+    from langstream_tpu.ops.attention import paged_pallas_ok, paged_tiles_ok
+
+    if isinstance(leaf, dict) or width % page_size:
+        return False
+    if config is None:
+        return paged_tiles_ok(leaf.shape[-1], page_size)
+    return config.kernel_mesh is None and paged_pallas_ok(config, page_size)
+
+
+def segment_copies_pages(pool: KVCache, width: int, page_size: int, config: ModelConfig) -> bool:
+    """Whether a causal prefill segment of ``width`` tokens a row that starts
+    on a page's edge writes its rows into ``pool`` by whole pages
+    (`_paged_write_rows`, which asks the same `_copies_pages` of the leaves it
+    is handed; where the segment starts is a runtime value, a `lax.cond`
+    there): the engine, which knows each segment's offset, counts its
+    segments by writer with this."""
+    return _copies_pages(pool[config.page_leaves[0]], width, page_size, config)
+
+
+def _paged_write_rows(pools, rows, layer, table, positions, page_size, config, segment):
+    """S new tokens a row into the page pool's leaves ``pools`` at ``layer``,
+    where they lie, the leaves back in their order: ``rows`` hold a leaf
+    each, K, V and a latent head-major [B, Hkv, S, D], the indexer's key
+    [B, S, Di] (it comes last). The scatter (`_paged_scatter`,
+    `_write_index_key`: an update a (row, kv head, position), dropped or not)
+    is every writer's reference and the write of a verify step, a block pass
+    into an int8 pool, the int8 pool, a mesh and every backend without the
+    kernels. A causal prefill ``segment`` of whole pages into leaves that
+    hold values alone (`_copies_pages`) goes by whole pages instead, ONE copy
+    HBM → HBM a (row, mapped page) and leaf from inside the layer loop
+    (`ops/attention.paged_insert_layer_pages`: 64 copies a layer for a
+    2,048-token segment's K and V where the scatter runs 8,192 to 16,384
+    updates of 256 B), provided every row starts on a page's edge: a runtime
+    value (a warm suffix may start inside a page), so where one does not the
+    kernel is handed the sentinel for every page, which copies nothing, and
+    the scatter runs after it, the body of a loop of 0 or 1 trips. (Not a
+    `lax.cond` with the two writers as its branches: compiled for a v5e, that
+    relays the latent's one-head leaf `[L, P, 1, ps, 640]` for its branch and
+    copies it whole, in and out, every layer.) The pool after either is the
+    same to the bit: the same rows to the same places, the padded tail's with
+    them, an unmapped page dropped."""
+    from langstream_tpu.ops import attention as ops
+
+    def scatter(pools):
+        return tuple(
+            (_paged_scatter if new.ndim == 4 else _write_index_key)(
+                pool, layer, new, table, positions, page_size
+            )
+            for pool, new in zip(pools, rows)
+        )
+
+    s = positions.shape[1]
+    by_page = segment and _copies_pages(pools[0], s, page_size, config)
+    if segment:
+        ops.note_grid(
+            f"paged-segment-write[s={s}]", "paged_insert_pages" if by_page else "scatter"
+        )
+    if not by_page:
+        return scatter(pools)
+    num_pages = pools[0].shape[1]
+    pages, offs = _page_index(table, positions[:, ::page_size], page_size, num_pages)
+    on_edge = jnp.all(offs[:, 0] == 0)
+    pools = ops.paged_insert_layer_pages(
+        [r if r.ndim == 4 else _kept_width(r, pools[-1]) for r in rows], pools,
+        jnp.where(on_edge, pages, num_pages), layer, interpret=jax.default_backend() != "tpu",
+    )
+    return lax.fori_loop(
+        0, 1 - on_edge.astype(jnp.int32), lambda _, pools: scatter(pools), tuple(pools)
+    )
+
+
 def _attention_block(
     x: jax.Array,
     lp: dict,
@@ -1245,12 +1326,16 @@ def _paged_attention(
             pk, pv = ops.paged_kv_write(
                 rows, pk, pv, pages[:, 0], offs[:, 0], layer, config, interpret=interpret
             )
+            if index is not None:
+                pik = [_write_index_key(pik[0], layer, index[1], table, positions, page_size)]
         else:
-            kt, vt = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
-            pk = _paged_scatter(pk, layer, kt, table, positions, page_size)
-            pv = _paged_scatter(pv, layer, vt, table, positions, page_size)
-        if index is not None:
-            pik = [_write_index_key(pik[0], layer, index[1], table, positions, page_size)]
+            rows = [k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)]
+            if index is not None:
+                rows.append(index[1])
+            pk, pv, *pik = _paged_write_rows(
+                (pk, pv, *pik), rows, layer, table, positions, page_size, config,
+                segment=s > 1 and not block and not verify,
+            )
     with _attention_scope(sub_scope):
         t = table.shape[1] * page_size
         if index is not None:
@@ -1602,10 +1687,10 @@ def _latent_attention_block(
     if paged_table is not None:
         plat, pik = cache_kv
         with jax.named_scope("kv_pool.write"):
-            plat = _paged_scatter(
-                plat, layer, _kept_width(lat, plat)[:, None], paged_table, positions, page_size
+            plat, pik = _paged_write_rows(
+                (plat, pik), [_kept_width(lat, plat)[:, None], k_idx], layer, paged_table,
+                positions, page_size, config, segment=s > 1,
             )
-            pik = _write_index_key(pik, layer, k_idx, paged_table, positions, page_size)
         with jax.named_scope("attention"):
             if s == 1:
                 attn = _latent_decode_read(
@@ -2989,20 +3074,11 @@ def insert_copies_pages(
 ) -> bool:
     """Whether `paged_insert_cache` writes a local cache of ``width`` columns
     into ``pool`` by whole pages (``ops/attention.paged_insert_pages``): one
-    page group whose leaves hold values alone (an int8 pool's scales are
-    words scattered over a page, no copy Mosaic takes), a width of whole
-    pages, no mesh (``config.kernel_mesh``: GSPMD cannot partition the
-    kernel), and where the paged decode kernel runs (``paged_pallas_ok``).
-    Without a ``config`` (a caller off the engine, which holds no mesh) the
-    gates are those of ``attention_impl: auto``."""
-    from langstream_tpu.ops.attention import paged_pallas_ok, paged_tiles_ok
-
+    page group of K and V (a window model's two and a latent's leaf keep the
+    scatter below) that `_copies_pages` takes, the rule a segment's write
+    shares."""
     k = pool.get("k")  # None: a latent's leaf, written by the scatter
-    if "win" in pool or k is None or isinstance(k, dict) or width % page_size:
-        return False
-    if config is None:
-        return paged_tiles_ok(k.shape[-1], page_size)
-    return config.kernel_mesh is None and paged_pallas_ok(config, page_size)
+    return "win" not in pool and k is not None and _copies_pages(k, width, page_size, config)
 
 
 def paged_insert_cache(

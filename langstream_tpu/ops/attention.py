@@ -1415,24 +1415,24 @@ def paged_kv_write(
 _INSERT_IN_FLIGHT = 32
 
 
-def _paged_insert_pages_kernel(
-    table_ref,  # scalar-prefetch [n * W/ps]: row r's logical page p at r * W/ps + p
-    k_loc,  # the prefill's local cache [L, n, Hkv, W, D] in HBM
-    v_loc,
-    _k_in,  # the pool leaves [L*P, Hkv, ps, D] in HBM, aliased to the outputs
-    _v_in,
-    k_pool,
-    v_pool,
-    sems,  # DMA [2, _INSERT_IN_FLIGHT]
-    live,  # SMEM [n * W/ps]
-    *,
-    num_pages: int,
-):
-    layers, _, _, width, _ = k_loc.shape
-    page_size = k_pool.shape[2]
+def _paged_insert_pages_kernel(*refs, num_pages: int, leaves: int, every_layer: bool):
+    """Copies of whole pages into the pool, HBM → HBM. Operands: the mapped
+    pages, scalar-prefetched [n * W/ps] (row r's page of the write c at
+    r * W/ps + c) and, where ONE layer is written, that layer [1] after them;
+    ``leaves`` sources in HBM, every layer's [L, n, .., W, D] (``every_layer``:
+    an admission group's local cache) or one layer's [n, .., W, D] (a
+    segment's new rows), ``..`` the kv heads or, the indexer's key, nothing;
+    the pool's leaves [L*P, .., ps, D], aliased to the outputs; the DMA
+    semaphores [leaves, _INSERT_IN_FLIGHT] and SMEM [n * W/ps]."""
+    scalars = 1 if every_layer else 2
+    table_ref, layer_ref = refs[0], None if every_layer else refs[1]
+    sources = refs[scalars:scalars + leaves]
+    pools = refs[scalars + 2 * leaves:scalars + 3 * leaves]
+    sems, live = refs[-2:]
+    layers = sources[0].shape[0] if every_layer else 1
+    width, page_size = sources[0].shape[-2], pools[0].shape[-2]
     per_row = width // page_size
     in_flight = sems.shape[1]
-    leaves = ((k_loc, k_pool), (v_loc, v_pool))
 
     # the mapped entries, compacted: a page the row does not hold (or a
     # padding row's, the sentinel) costs this loop's iteration and no byte
@@ -1445,19 +1445,25 @@ def _paged_insert_pages_kernel(
 
     def copies(layer, row, col, page, slot):
         # a pool page is the contiguous [Hkv, ps, D]; its source is Hkv runs
-        # of ps x D, strided by the local cache's width
+        # of ps x D, strided by the source's width (one run of the indexer's
+        # key, which has no head)
         start = pl.multiple_of(col * page_size, page_size)
+        at, pool_layer = ((layer, row), layer) if every_layer else ((row,), layer_ref[0])
         return [
             pltpu.make_async_copy(
-                loc.at[layer, row, :, pl.ds(start, page_size), :],
-                pool.at[layer * num_pages + page],
+                src.at[(
+                    *at, *(slice(None),) * (src.ndim - len(at) - 2),
+                    pl.ds(start, page_size), slice(None),
+                )],
+                pool.at[pool_layer * num_pages + page],
                 sems.at[leaf, slot],
             )
-            for leaf, (loc, pool) in enumerate(leaves)
+            for leaf, (src, pool) in enumerate(zip(sources, pools))
         ]
 
     def wait(slot):
-        # every copy moves one page: any page's descriptor waits for a slot
+        # every copy of a leaf moves one page: any page's descriptor waits
+        # for a slot
         for copy in copies(0, 0, 0, 0, slot):
             copy.wait()
 
@@ -1477,7 +1483,9 @@ def _paged_insert_pages_kernel(
                 copy.start()
             return carry
 
-        return jax.lax.fori_loop(0, layers, layer_copy, carry)
+        if every_layer:
+            return jax.lax.fori_loop(0, layers, layer_copy, carry)
+        return layer_copy(0, carry)
 
     # no two mapped entries name one page, so no two copies overlap
     jax.lax.fori_loop(0, n_live, entry, 0)
@@ -1487,6 +1495,46 @@ def _paged_insert_pages_kernel(
         return carry
 
     jax.lax.fori_loop(0, jnp.minimum(n_live * layers, in_flight), drain, 0)
+
+
+def _paged_insert_pages_call(
+    new: tuple, pools: tuple, pages: jax.Array, layer, interpret: bool
+) -> list[jax.Array]:
+    """`_paged_insert_pages_kernel` over ``pools`` [L, P, .., ps, D], each
+    written in place and given back: every layer from ``new`` [L, n, .., W, D]
+    (``layer`` None) or the one ``layer`` from ``new`` [n, .., W, D];
+    ``pages`` [n, W/ps], the pool's page of each page of the write (outside
+    [0, P): dropped)."""
+    leaves = len(pools)
+    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
+    flat = [_flat_pool(leaf) for leaf in pools]
+    scalars = [pages.astype(jnp.int32).reshape(-1)]
+    if layer is not None:
+        scalars.append(jnp.asarray(layer, jnp.int32).reshape(1))
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_insert_pages_kernel, num_pages=pools[0].shape[1], leaves=leaves,
+            every_layer=layer is None,
+        ),
+        name="paged_insert_pages",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(1,),
+            in_specs=[hbm] * (2 * leaves),
+            out_specs=[hbm] * leaves,
+            scratch_shapes=[
+                pltpu.SemaphoreType.DMA((leaves, _INSERT_IN_FLIGHT)),
+                pltpu.SMEM((pages.size,), jnp.int32),
+            ],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype) for leaf in flat],
+        # the operands after the prefetched scalars and the sources are the
+        # pool: written in place
+        input_output_aliases={len(scalars) + leaves + i: i for i in range(leaves)},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(*scalars, *(src.astype(pool.dtype) for src, pool in zip(new, pools)), *flat)
+    return [o.reshape(pool.shape) for o, pool in zip(out, pools)]
 
 
 def paged_insert_pages(
@@ -1515,29 +1563,42 @@ def paged_insert_pages(
     table = jnp.pad(
         table, ((0, 0), (0, per_row - table.shape[1])), constant_values=num_pages
     )
-    hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
-    flat = [_flat_pool(leaf) for leaf in (k, v)]
-    out = pl.pallas_call(
-        functools.partial(_paged_insert_pages_kernel, num_pages=num_pages),
-        name="paged_insert_pages",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(1,),
-            in_specs=[hbm, hbm, hbm, hbm],
-            out_specs=[hbm, hbm],
-            scratch_shapes=[
-                pltpu.SemaphoreType.DMA((2, _INSERT_IN_FLIGHT)),
-                pltpu.SMEM((n * per_row,), jnp.int32),
-            ],
-        ),
-        out_shape=[jax.ShapeDtypeStruct(leaf.shape, leaf.dtype) for leaf in flat],
-        # operands 3 and 4 (after the prefetched table and the two local
-        # leaves) are the pool: written in place
-        input_output_aliases={3: 0, 4: 1},
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(table.reshape(-1), *(loc.astype(k.dtype) for loc in new), *flat)
-    return out[0].reshape(k.shape), out[1].reshape(v.shape)
+    k, v = _paged_insert_pages_call(new, (k, v), table, None, interpret)
+    return k, v
+
+
+def paged_insert_layer_pages(
+    new: tuple,  # ONE layer's new rows, a leaf each: [n, Hkv, W, D], or [n, W, Di]
+    pools: tuple,  # the pool's leaves [L, P, Hkv, ps, D] / [L, P, ps, Di], written at `layer`
+    pages: jax.Array,  # [n, W/ps] the pool's page of each page of the write
+    layer: jax.Array,  # scalar: which layer of the pool
+    interpret: bool = False,
+) -> list[jax.Array]:
+    """`paged_insert_pages` for the rows ONE layer has just made (a prefill
+    segment's, inside the layer loop: that layer's attention then reads the
+    pool): row ``r``'s columns ``[c * ps, (c + 1) * ps)`` of every leaf go to
+    page ``pages[r, c]`` of ``layer`` where the pool lies, one copy HBM → HBM
+    a (row, mapped page) and leaf, and the leaves come back. The write starts
+    on a page's edge and ``W`` is a whole number of pages; K and V
+    (head-major, the pool's order), a latent's one leaf and the indexer's key
+    (no head axis) ride one call. An entry outside [0, P) (a padding row, a
+    page past the table or past the row's reservation: `models/transformer.
+    _page_index`'s sentinel) DROPS at no copy."""
+    for src, pool in zip(new, pools):
+        assert src.shape[-2] % pool.shape[-2] == 0 and src.shape[1:-2] == pool.shape[2:-2], (
+            src.shape, pool.shape,
+        )
+    # a leaf of ONE head (a latent's) goes as a leaf of none, the same bytes:
+    # with the axis of 1 the chip's compiler gives the scan's carry a layout
+    # of its own (that axis outermost) and copies the whole leaf into the
+    # kernel's and back, every layer
+    lone = [pool.ndim == 5 and pool.shape[2] == 1 for pool in pools]
+    out = _paged_insert_pages_call(
+        [src[:, 0] if one else src for src, one in zip(new, lone)],
+        [pool[:, :, 0] if one else pool for pool, one in zip(pools, lone)],
+        pages, layer, interpret,
+    )
+    return [leaf[:, :, None] if one else leaf for leaf, one in zip(out, lone)]
 
 
 def block_write_ok(block_length: int, page_size: int) -> bool:

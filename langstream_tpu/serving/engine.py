@@ -47,6 +47,7 @@ from langstream_tpu.models.transformer import (
     paged_prefill_segment_inplace,
     paged_verify_step_inplace,
     prefill,
+    segment_copies_pages,
     split_rec,
 )
 from langstream_tpu.parallel import spmd_serving as wire
@@ -1954,6 +1955,10 @@ class ServingEngine:
         # result already on the host, so the device had run dry.
         self._wake = threading.Event()
         self._launches = dict.fromkeys(LAUNCH_REASONS + ("late",), 0)
+        # prefill segments dispatched, by how their new rows reach the pool
+        # (stats "segment-writes", restarted with "launches"): by whole
+        # pages, or by the scatter (`_count_segment_write`)
+        self._segment_writes = {"pages": 0, "scatter": 0}
         self._late_probe = False
         self._launched_late = False
         # dedicated device→host token fetch thread (started with the loop);
@@ -2509,6 +2514,7 @@ class ServingEngine:
             self._unfed_s = self._unfed_request_s = 0.0
             self._account_t0 = time.monotonic()
         self._launches = dict.fromkeys(self._launches, 0)
+        self._segment_writes = dict.fromkeys(self._segment_writes, 0)
 
     def prefix_advertisement(
         self, top_k: int = 32,
@@ -2695,6 +2701,11 @@ class ServingEngine:
             # the late ones among them (docs/SERVING.md, "When the engine
             # launches"), since the engine was built or `reset_histograms`
             "launches": dict(self._launches),
+            # prefill segments by the writer of their new rows: whole pages
+            # copied where the pool lies, or the scatter (a segment that
+            # starts inside a page, an int8 pool, a mesh, no kernels;
+            # docs/SERVING.md, "The pool's writers"), since the same
+            "segment-writes": dict(self._segment_writes),
             "decode-step-ms": round(self._step_time_ema_s * 1e3, 3),
             "hbm-gbps-decode": self._achieved_hbm_gbps(),
             # the page pool, the engine's only KV state
@@ -5253,8 +5264,9 @@ class ServingEngine:
         *, final: bool, prompt_len: int, agentic_rows=None,
     ):
         """Device layer of one paged prefill segment (warm suffix OR one
-        chunk of a long prompt): K/V scatter straight into the slot's
-        pages, attention reads the prefix through the table. On ``final``
+        chunk of a long prompt): K/V go straight into the slot's pages (by
+        whole pages where the program can, else the scatter:
+        `_count_segment_write`), attention reads the prefix through the table. On ``final``
         the decode chain scatters — there is no insert/splice: the pages
         ARE the cache. The DFA state scatter only lands on ``final`` (the
         segment whose sampled first token actually seeds the chain)."""
@@ -5265,6 +5277,7 @@ class ServingEngine:
         self._window_recycled = pool.window_advance(idx, s0, s0 + tokens.shape[1] - 1)
         table = pool.rows_tables([idx])
         self._record_program("paged-segment", tokens.shape[1])
+        self._count_segment_write(tokens.shape[1], s0)
         kw = self._segment_agentic_kwargs(
             agentic_rows, idx if final else self.max_batch
         )
@@ -6892,6 +6905,17 @@ class ServingEngine:
             ),
             "window_pages_recycled": self._window_recycled,
         }
+
+    def _count_segment_write(self, width: int, s0: int) -> None:
+        """One more segment under the writer its program takes for it: the
+        program decides by `segment_copies_pages` (what it can see of the
+        pool, the width and the config) and by whether the segment starts on
+        a page's edge, which it asks of its positions and the host knows as
+        ``s0`` (models/transformer `_paged_write_rows`)."""
+        pages = s0 % self.page_size == 0 and segment_copies_pages(
+            self._pagepool.dev, width, self.page_size, self.config
+        )
+        self._segment_writes["pages" if pages else "scatter"] += 1
 
     def _kv_pages_written(self, slots, width: int) -> int:
         """kv_pages_written of an admission group dispatched now, per layer

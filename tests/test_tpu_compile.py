@@ -131,6 +131,23 @@ def _insert_pages(config, rows, width, pages, layers, table):
     )
 
 
+def _insert_layer_pages(leaves, width, pages, layers):
+    """(a segment's write of ONE layer by page, its arguments' shapes):
+    ``leaves`` name each leaf's (kv heads, row width), 0 heads the indexer's
+    key (no head axis); one row of ``width`` new tokens, head-major, the
+    pool's leaves of ``layers`` x ``pages`` pages, the pool's page of each
+    page of the write, and the layer."""
+    new, pools = [], []
+    for hkv, d in leaves:
+        heads = (hkv,) if hkv else ()
+        new.append(SDS((1, *heads, width, d), jnp.bfloat16))
+        pools.append(SDS((layers, pages, *heads, PAGE, d), jnp.bfloat16))
+    return (
+        lambda new, pools, at, layer: A.paged_insert_layer_pages(new, pools, at, layer),
+        (new, pools, SDS((1, width // PAGE), jnp.int32), SDS((), jnp.int32)),
+    )
+
+
 # The benchmark's three cells (BENCHMARK.json; benchmark/workloads/*.json):
 # slots x table pages, the pool's pages, the layers. Mistral-7B and Mixtral
 # have llama-3-8b's attention (32 q / 8 kv heads of 128).
@@ -431,6 +448,18 @@ CASES = {
     "chat1x1024-paged-insert-pages": _insert_pages(LLAMA, 1, 1024, 512, 32, 20),
     "docs4x2048-paged-insert-pages": _insert_pages(LLAMA, 4, 2048, 528, 32, 33),
     "olmodrain8x256-paged-insert-pages": _insert_pages(OLMO, 8, 256, 480, 8, 10),
+    # a 2,048-token segment's write of one layer by page (PR 48), at the three
+    # segment cells' pools: Keye's K, V and indexer's key in one call,
+    # command-a-plus's full group and its window group (8 kv heads), GLM's
+    # latent (one head of 640 lanes) with its indexer's key
+    "keye1x2048-paged-insert-layer-pages": _insert_layer_pages(
+        [(4, 128), (4, 128), (0, 128)], 2048, 2176, 12
+    ),
+    "cmdaplus1x2048-paged-insert-layer-pages": _insert_layer_pages([(8, 128)] * 2, 2048, 3136, 2),
+    "cmdapluswin1x2048-paged-insert-layer-pages": _insert_layer_pages(
+        [(8, 128)] * 2, 2048, 1552, 6
+    ),
+    "glm1x2048-paged-insert-layer-pages": _insert_layer_pages([(1, 640), (0, 128)], 2048, 4352, 7),
     # the Olmo-Hybrid cell (40 slots x 10 pages, 400 pages, 8 full layers of
     # 30 kv heads in groups of ONE; 24 linear layers of 30 x 96 x 192)
     "olmodrain40x10-paged-decode": _paged(OLMO, False, batch=40, table=10, pages=400, layers=8),
@@ -461,6 +490,7 @@ def _kernel_of(case: str) -> str:
         "paged-block": "ragged_paged_block_attention",
         "block-kv-write": "paged_kv_write",
         "paged-insert-pages": "paged_insert_pages",
+        "paged-insert-layer-pages": "paged_insert_pages",
         "gated-delta-update": "gated_delta_update",
         "windowed-decode": "ragged_paged_decode_attention",
         "selected-decode": "ragged_paged_selected_attention",
@@ -644,8 +674,20 @@ def test_paged_program_holds_no_per_layer_pool_entry(v5e, monkeypatch, program, 
         t = STEP_TABLE * PAGE
         assert A.attention_paths()[f"paged-decode[s=1,t={t}]"] == kernel
         _assert_decode_write(text, pool_shapes, bf16_pool=kv == "model")
+    elif program == "_paged_segment_and_sample" and kv == "model":
+        # a causal segment of whole pages into a bf16 pool: its rows reach the
+        # pool by whole pages (PR 48), the one kernel of the program (the
+        # dense model's read stays jnp); the scatter of the same leaves is
+        # the branch of a segment that starts inside a page
+        calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+        assert calls and all(
+            re.match(r"\s*(ROOT )?%paged_insert_pages(\.\d+)? = ", line) for line in calls
+        ), calls[0][:200]
+        assert A.attention_paths()["paged-segment-write[s=128]"] == "paged_insert_pages"
     else:
-        assert "tpu_custom_call" not in text  # verify and segments: jnp
+        assert "tpu_custom_call" not in text  # verify, the int8 pool's segments: jnp
+        if program == "_paged_segment_and_sample":
+            assert A.attention_paths()["paged-segment-write[s=128]"] == "scatter"
 
 
 # olmo-hybrid's linear layers (heads of 96 x 192, the folded state whole
@@ -892,7 +934,7 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         static = (KEYE, PAGE)
         kernels = (
             "flash_segment_attention", "sparse_segment_attention", "segment_select",
-            "moe_grouped_matmul",
+            "moe_grouped_matmul", "paged_insert_pages",
         )
         path = f"paged-segment-sparse[s={seg},t={t}]"
     compiled = _compile_as_on_chip(
@@ -925,6 +967,8 @@ def test_sparse_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         # (the one [S, T] the program holds is the int8 selection), and XLA's
         # 32 counts of a key that size are gone with the loop that made them
         paths = A.attention_paths()
+        # K, V and the indexer's key reach the pool by whole pages (PR 48)
+        assert paths[f"paged-segment-write[s={seg}]"] == "paged_insert_pages"
         assert paths[f"paged-segment-select[s={seg},t={t}]"] == "segment_select"
         assert paths[f"segment-select[s={seg},t={t}]"] == "block_q 128, block_k 512, to the diagonal"
         assert not re.search(r"%index_scores(\.\d+)? = ", text)
@@ -990,7 +1034,7 @@ def test_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         static = (GLM, PAGE)
         kernels = (
             "flash_segment_attention", "sparse_segment_attention", "segment_select",
-            "moe_grouped_matmul",
+            "moe_grouped_matmul", "paged_insert_pages",
         )
         path = f"paged-segment-latent-sparse[s={seg},t={t}]"
     compiled = _compile_as_on_chip(
@@ -1014,6 +1058,8 @@ def test_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         assert not re.search(r"%ragged_paged_(decode|selected)_attention(\.\d+)? = ", text)
     else:
         assert paths[path] == "sparse_segment_attention"
+        # the latent and the indexer's key reach the pool by whole pages (PR 48)
+        assert paths[f"paged-segment-write[s={seg}]"] == "paged_insert_pages"
         assert paths[f"paged-segment-latent-select[s={seg},t={t}]"] == "segment_select"
         assert paths[f"paged-segment-latent[s={seg},t={t}]"] == "flash_segment_attention"
         assert has([1, h, t, 256])  # the expanded keys and values of the row's columns
@@ -1035,6 +1081,51 @@ def test_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
     print(program, "temp", memory.temp_size_in_bytes, "args", memory.argument_size_in_bytes)
     assert held <= V5E_HBM_BYTES
     for leaf in pool.values():
+        dims = re.escape("[" + ",".join(map(str, leaf.shape)) + "]")
+        assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text), leaf.shape
+
+
+def test_window_segment_program_compiles_for_v5e_beside_the_cell_s_state(v5e, monkeypatch):
+    """The command-a-plus cell's segment program whole, at its sizes (a
+    2,048-token segment of one row against 196 pages a table, the full
+    layers' group of 3,136 pages and the window layers' of 1,552, each
+    through its own table), int8 weights and the pool donated: every layer's
+    K and V reach their group by whole pages (`paged_insert_pages`, PR 48),
+    the window and the full layers read through `flash_segment_attention`,
+    no leaf of either group is copied or relaid, and the program fits the
+    chip beside its state."""
+    from langstream_tpu.models.quant import quantize_params
+    from langstream_tpu.models.transformer import init_params, make_page_pool
+    from langstream_tpu.serving import engine as E
+
+    pages, window_pages, table, seg = 3136, 1552, 196, 2048
+    key = SDS((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: quantize_params(init_params(CMDA, k), CMDA), key)
+    pool = jax.eval_shape(lambda: make_page_pool(CMDA, pages, PAGE, window_pages=window_pages))
+    assert pool["k"].shape == (2, pages, 8, PAGE, 128)
+    assert pool["win"]["k"].shape == (6, window_pages, 8, PAGE, 128)
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    args = (params, i32(1, seg), i32(1), i32(1), pool, i32(2, 1, table), key,
+            f32(1), i32(1), f32(1))
+    compiled = _compile_as_on_chip(
+        monkeypatch, E._paged_segment_and_sample, _placed(args, SingleDeviceSharding(v5e[0])),
+        (CMDA, PAGE),
+    )
+    text = compiled.as_text()
+    paths = A.attention_paths()
+    assert paths[f"paged-segment-write[s={seg}]"] == "paged_insert_pages"
+    assert paths[f"paged-segment[s={seg},t={table * PAGE}]"] == "flash_segment_attention"
+    for kernel in ("paged_insert_pages", "flash_segment_attention", "moe_grouped_matmul"):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
+    assert memory.alias_size_in_bytes >= pool_bytes  # both groups, updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    assert held <= V5E_HBM_BYTES
+    for leaf in jax.tree.leaves(pool):
         dims = re.escape("[" + ",".join(map(str, leaf.shape)) + "]")
         assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text), leaf.shape
 
@@ -1097,6 +1188,24 @@ def test_the_shared_kernels_hand_mosaic_what_they_did(v5e, case):
     (body,) = _kernel_bodies(*CASES[case], v5e[0])
     assert f"module @{_kernel_of(case)} " in body
     assert _short_hash(body) == KERNEL_BODIES_AT_PARENT[case]
+
+
+# An admission group's `paged_insert_pages` module, as the parent (PR 47) handed
+# it to Mosaic: PR 48 gave the kernel a one-layer form for a segment's write
+# (`every_layer=False`), and the every-layer form's module is the parent's.
+INSERT_BODIES_AT_PARENT = {
+    "chat1x64-paged-insert-pages": "6215463845a0dd9c",
+    "chat1x1024-paged-insert-pages": "2851d67ff9ea116a",
+    "docs4x2048-paged-insert-pages": "d9f4384d6b7736ea",
+    "olmodrain8x256-paged-insert-pages": "f0907edbec4831a6",
+}
+
+
+@pytest.mark.parametrize("case", sorted(INSERT_BODIES_AT_PARENT))
+def test_an_admission_group_s_page_writer_hands_mosaic_what_it_did(v5e, case):
+    (body,) = _kernel_bodies(*CASES[case], v5e[0])
+    assert "module @paged_insert_pages " in body
+    assert _short_hash(body) == INSERT_BODIES_AT_PARENT[case]
 
 
 def test_the_selection_is_the_only_difference_of_its_kernel(v5e):
@@ -1237,10 +1346,40 @@ ENGINE_PROGRAMS = {
     **DECODE_PROGRAMS_AT_PARENT, **ENGINE_PROGRAMS_AT_PARENT, **LATENT_PROGRAMS_AT_PR47,
 }
 
+# PR 48 changes the SEGMENT programs and no other, on purpose: a causal
+# segment of whole pages writes its rows into a bf16 pool by whole pages
+# (`paged_insert_pages` a layer, the scatter behind a trip count of 0 or 1),
+# so with the kernels forced these six lower anew, as PR 48 left them. The
+# tables above are NOT re-taken: every decode, block and admit row holds as it
+# is, `segment/tiny-test-int8` too (an int8 pool keeps the scatter), and each
+# of these six still lowers to its hash THERE once `_copies_pages` says no:
+# the scatter's branch is the parent's program byte for byte.
+SEGMENT_PROGRAMS_AT_PR48 = {
+    "segment/tiny-test": "e436eed989adc2fd",
+    "segment/tiny-moe-test": "8fb8ffb110d43a60",
+    "segment/tiny-hybrid-test": "41ea43aa7585926d",
+    "segment/tiny-window-moe-test": "dca68855cfb508c3",
+    "segment/tiny-sparse-moe-test": "cb4b43784ed5ccfa",
+    "segment/tiny-latent-moe-test": "33335341cfab4025",
+}
+
 
 @pytest.mark.parametrize("case", sorted(ENGINE_PROGRAMS))
-def test_the_other_models_decode_programs_lower_as_they_did(case):
+def test_the_other_models_decode_programs_lower_as_they_did(case, monkeypatch):
+    if case in SEGMENT_PROGRAMS_AT_PR48:
+        from langstream_tpu.models import transformer as T
+
+        monkeypatch.setattr(T, "_copies_pages", lambda *a: False)
+        jax.clear_caches()  # the jitted program's trace is cached by its arguments' shapes
     assert _short_hash(_engine_program_text(case)) == ENGINE_PROGRAMS[case]
+    if case in SEGMENT_PROGRAMS_AT_PR48:
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("case", sorted(SEGMENT_PROGRAMS_AT_PR48))
+def test_the_segment_programs_lower_as_pr48_left_them(case):
+    assert _short_hash(_engine_program_text(case)) == SEGMENT_PROGRAMS_AT_PR48[case]
+    assert A.attention_paths()["paged-segment-write[s=16]"] == "paged_insert_pages"
 
 
 # What `attention_paths()` says after a prefill over a local cache, a segment
@@ -1249,6 +1388,11 @@ def test_the_other_models_decode_programs_lower_as_they_did(case):
 # ("auto"): what the parent said (commit 0089f57, PR 45), as data. The
 # families' `expected_kernels` hold a chip run to such strings letter for
 # letter; this holds a refactor of the callers of `note_path` to them here.
+# ONE key is PR 48's and here on purpose: every preset that traces a segment
+# now says how the segment's new rows reach the pool,
+# `paged-segment-write[s=16]`: by whole pages where the kernels are forced
+# over a bf16 pool, by the scatter on the CPU's own choice and into an int8
+# pool. Every other entry is the parent's.
 PATHS_AT_PARENT = {
     "tiny-blockfill-moe-test/auto": {
         "paged-block[s=4,t=48]": "jnp",
@@ -1262,6 +1406,7 @@ PATHS_AT_PARENT = {
         "linear-decode[s=1,t=0]": "jnp",
         "linear-prefill[s=16,t=16]": "gated_delta_chunk_prefill",
         "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment-write[s=16]": "scatter",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "jnp",
     },
@@ -1269,6 +1414,7 @@ PATHS_AT_PARENT = {
         "linear-decode[s=1,t=0]": "gated_delta_update",
         "linear-prefill[s=16,t=16]": "gated_delta_chunk_prefill",
         "paged-decode[s=1,t=48]": "ragged_paged_decode_attention",
+        "paged-segment-write[s=16]": "paged_insert_pages",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "flash_prefill_attention",
     },
@@ -1276,6 +1422,7 @@ PATHS_AT_PARENT = {
     "tiny-latent-moe-test/auto": {
         "paged-decode-latent[s=1,t=48]": "jnp",
         "paged-segment-latent-sparse[s=16,t=48]": "jnp",
+        "paged-segment-write[s=16]": "scatter",
         "prefill-sparse[s=16,t=16]": "jnp",
     },
     "tiny-latent-moe-test/pallas": {
@@ -1283,6 +1430,7 @@ PATHS_AT_PARENT = {
         "paged-segment-latent-select[s=16,t=48]": "segment_select",
         "paged-segment-latent-sparse[s=16,t=48]": "sparse_segment_attention",
         "paged-segment-latent[s=16,t=48]": "flash_segment_attention",
+        "paged-segment-write[s=16]": "paged_insert_pages",
         "prefill-select[s=16,t=16]": "segment_select",
         "prefill-sparse[s=16,t=16]": "sparse_segment_attention",
         "segment-select[s=16,t=16]": "block_q 16, block_k 16, to the diagonal",
@@ -1290,17 +1438,20 @@ PATHS_AT_PARENT = {
     },
     "tiny-moe-test/auto": {
         "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment-write[s=16]": "scatter",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "jnp",
     },
     "tiny-moe-test/pallas": {
         "paged-decode[s=1,t=48]": "ragged_paged_decode_attention",
+        "paged-segment-write[s=16]": "paged_insert_pages",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "flash_prefill_attention",
     },
     "tiny-sparse-moe-test/auto": {
         "paged-decode-sparse[s=1,t=48]": "xla top_k + gather",
         "paged-segment-sparse[s=16,t=48]": "jnp",
+        "paged-segment-write[s=16]": "scatter",
         "prefill-sparse[s=16,t=16]": "jnp",
     },
     "tiny-sparse-moe-test/pallas": {
@@ -1308,6 +1459,7 @@ PATHS_AT_PARENT = {
         "paged-decode-sparse[s=1,t=48]": "ragged_paged_decode_attention to index_topk, xla top_k + gather past it",
         "paged-segment-select[s=16,t=48]": "segment_select",
         "paged-segment-sparse[s=16,t=48]": "sparse_segment_attention",
+        "paged-segment-write[s=16]": "paged_insert_pages",
         "paged-segment[s=16,t=48]": "flash_segment_attention",
         "prefill-select[s=16,t=16]": "segment_select",
         "prefill-sparse[s=16,t=16]": "sparse_segment_attention",
@@ -1316,31 +1468,37 @@ PATHS_AT_PARENT = {
     },
     "tiny-test-int8/auto": {
         "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment-write[s=16]": "scatter",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "jnp",
     },
     "tiny-test-int8/pallas": {
         "paged-decode[s=1,t=48]": "ragged_paged_decode_attention_int8",
+        "paged-segment-write[s=16]": "scatter",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "flash_prefill_attention",
     },
     "tiny-test/auto": {
         "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment-write[s=16]": "scatter",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "jnp",
     },
     "tiny-test/pallas": {
         "paged-decode[s=1,t=48]": "ragged_paged_decode_attention",
+        "paged-segment-write[s=16]": "paged_insert_pages",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "flash_prefill_attention",
     },
     "tiny-window-moe-test/auto": {
         "paged-decode[s=1,t=48]": "jnp",
+        "paged-segment-write[s=16]": "scatter",
         "paged-segment[s=16,t=48]": "jnp",
         "prefill[s=16,t=16]": "jnp",
     },
     "tiny-window-moe-test/pallas": {
         "paged-decode[s=1,t=48]": "ragged_paged_decode_attention",
+        "paged-segment-write[s=16]": "paged_insert_pages",
         "paged-segment[s=16,t=48]": "flash_segment_attention",
         "prefill[s=16,t=16]": "flash_prefill_attention",
     },
