@@ -1502,7 +1502,9 @@ def _selected_segment_read(
 # Two forms of one attention over the SAME int8 ``wkv_b`` and scales:
 #   expanded   k_h = [c_kv W_uk,h | k_rope], v_h = c_kv W_uv,h for every column
 #              (`_latent_expand`), then the model's attention of H heads: the
-#              dense path, and a paged segment over its row's gathered latents;
+#              dense path, and a paged segment over its row's gathered latents
+#              (`_latent_expand_seen`: the columns its queries can see, where
+#              the read is the walk over key blocks);
 #   absorbed   q~_h = [q_nope,h W_uk,h^T | q_rope,h] against the rows as they
 #              lie, o_h = (sum_s p_s c_kv,s) W_uv,h: a paged decode step, which
 #              forms nothing of a head's keys or values.
@@ -1559,6 +1561,42 @@ def _latent_expand(lat, lp, config):
         v = jnp.einsum("btc,chj->bhtj", c_kv, w[..., nope:])
         k_rope = jnp.broadcast_to(lat[:, None, :, kl:kl + rope], (b, config.n_heads, t, rope))
         return jnp.concatenate([k_nope, k_rope], axis=-1), v
+
+
+def latent_columns_expanded(offset, s: int, t: int, config):
+    """The columns of its row's table of ``t`` that a segment of ``s`` queries
+    at ``offset .. offset + s - 1`` expands, a layer (``offset`` an int, or an
+    array of the rows'): up to its last query's, in whole blocks of
+    `ops/attention.latent_expand_block`, where the segment's read is the walk
+    over key blocks, which stops at its diagonal; the whole table where it is
+    masked jnp, which multiplies every column's value by its probability."""
+    from langstream_tpu.ops import attention as ops
+
+    if not _selection_kernels(config, s, t):
+        return jnp.full_like(offset, t) if isinstance(offset, jax.Array) else t
+    block = ops.latent_expand_block(s, t, config)
+    seen = (offset + s + block - 1) // block * block
+    return jnp.minimum(seen, t) if isinstance(offset, jax.Array) else min(seen, t)
+
+
+def _latent_expand_seen(rows, lp, offsets, s, config):
+    """`_latent_expand` of a row's gathered latents ``rows`` [B, T, W] for a
+    segment of ``s`` queries at ``offsets[b] ..``: the columns
+    `latent_columns_expanded` names and no others, straight into the
+    head-major layout the walk reads (`ops/attention.latent_expand_blocks`).
+    What lies past them is not written, and the walk does not read it."""
+    from langstream_tpu.ops import attention as ops
+
+    t = rows.shape[1]
+    if not _selection_kernels(config, s, t):
+        return _latent_expand(rows, lp, config)
+    with jax.named_scope("attention.latent.expand"):
+        ops.note_path("paged-segment-latent-expand", "latent_expand_blocks", config, s=s, t=t)
+        return ops.latent_expand_blocks(
+            rows, *_wkv_b(lp, config), latent_columns_expanded(offsets, s, t, config),
+            ops.latent_expand_block(s, t, config), config,
+            interpret=jax.default_backend() != "tpu",
+        )
 
 
 def _latent_absorb(q, lp, config, width):
@@ -1670,9 +1708,9 @@ def _latent_attention_block(
     once a query sees more than ``index_topk`` columns; with one, the pool's
     leaves whole, written at ``layer``: one query a row reads in the latent
     space (`_latent_decode_read`), a segment re-expands its row's gathered
-    latents, cached columns and its own alike, into a temporary that never
-    enters the pool and reads as a model with an indexer does
-    (`_selected_segment_read`)."""
+    latents, cached columns and its own alike and none past its last query
+    (`_latent_expand_seen`), into a temporary that never enters the pool and
+    reads as a model with an indexer does (`_selected_segment_read`)."""
     from langstream_tpu.ops import attention as ops
 
     b, s = x.shape[:2]
@@ -1699,7 +1737,7 @@ def _latent_attention_block(
                 )
             else:
                 rows = _paged_gather(plat, layer, paged_table, page_size)[:, 0]
-                k_all, v_all = _latent_expand(rows, lp, config)
+                k_all, v_all = _latent_expand_seen(rows, lp, positions[:, 0], s, config)
                 attn = _selected_segment_read(
                     q, q_idx, w_idx, pik, k_all, v_all, paged_table, layer, mask, positions,
                     config, "paged-segment-latent",

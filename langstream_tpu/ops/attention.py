@@ -27,7 +27,9 @@ blocks are naturally aligned, and a per-head kv block is a contiguous
   makes that selection in one call: the indexer's scores of a segment in
   tiles, ranked where they lie in VMEM, so nothing of [S, heads, T] is ever
   held and no [S, T] of scores reaches HBM (``index_scores``: the scores
-  alone, what it is held to).
+  alone, what it is held to). ``latent_expand_blocks`` makes the K and V a
+  latent model's segment walks, from its row's latents: the key blocks the
+  segment's queries can see, every head's, head-major.
 - ``paged_kv_write``: that step's new K and V rows into the bf16 pool where
   it lies, a copy per live row (a scatter pays per (row, kv head), dropped
   rows included).
@@ -782,6 +784,112 @@ def segment_select(
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(offsets.astype(jnp.int32), q_idx.transpose(0, 2, 1, 3), w.astype(jnp.float32), k_idx)
+
+
+# ---------------------------------------------------------------------------
+# A latent model's segment EXPANDS its row's latents into the keys and values
+# the walk above reads (models/transformer `_latent_expand`: ``k_h = [c_kv
+# W_uk,h | k_rope]``, ``v_h = c_kv W_uv,h``). In XLA the expansion is as wide
+# as the table, the shapes being static, and its output [t, h, j] is relaid
+# head-major by two copies of the whole. Here a grid step takes one block of
+# the row's latents (fetched once a block: the head axis innermost) and one
+# head's share of the int8 ``wkv_b``, dequantised in VMEM as `_latent_expand`
+# forms it, and writes that head's keys and values where the walk reads them,
+# [B, H, T, D]; the blocks are whole key blocks of the walk and those past the
+# segment's last query are not expanded: neither written nor, the walk
+# stopping at its diagonal, read.
+# ---------------------------------------------------------------------------
+
+
+def latent_expand_block(s: int, t: int, config: ModelConfig) -> int:
+    """Columns a grid step of `latent_expand_blocks` expands for ``s`` queries
+    over a table of ``t``: two of the walk's key blocks where they divide the
+    table (a head's weights are dequantised once a step), else one."""
+    block_k = segment_key_blocks(s, t, config.resolved_head_dim, 1, 0)[1]
+    return _fit_block(2 * block_k, t)
+
+
+def _latent_expand_kernel(
+    blocks_ref,  # scalar-prefetch [B]: the blocks a row expands
+    lat_ref,  # [1, block, W]: [c_kv | k_rope | zeros]
+    *refs,  # wk [1, kl, nope], wv [1, kl, v] (, their scales [1, 1, nope], [1, 1, v]), k, v
+    kl: int, rope: int, quantized: bool,
+):
+    wk_ref, wv_ref = refs[:2]
+    sk_ref, sv_ref = refs[2:4] if quantized else (None, None)
+    k_ref, v_ref = refs[-2:]
+
+    @pl.when(pl.program_id(1) < blocks_ref[pl.program_id(0)])
+    def _body():
+        lat = lat_ref[0]
+        c_kv = lat[:, :kl]
+
+        def product(w_ref, s_ref):
+            w = w_ref[0]
+            if quantized:
+                w = (w.astype(jnp.float32) * s_ref[0]).astype(lat.dtype)
+            return jnp.dot(c_kv, w, preferred_element_type=jnp.float32).astype(lat.dtype)
+
+        k_ref[0, 0] = jnp.concatenate([product(wk_ref, sk_ref), lat[:, kl:kl + rope]], axis=-1)
+        v_ref[0, 0] = product(wv_ref, sv_ref)
+
+
+def latent_expand_blocks(
+    lat: jax.Array,  # [B, T, W] the row's latents, columns 0 .. T-1
+    w: jax.Array,  # [kl, H, nope + v] ``wkv_b``'s values (int8 with ``scale``)
+    scale: jax.Array | None,  # [H, nope + v] float32
+    seen: jax.Array,  # [B] the columns to expand: whole blocks of ``block``
+    block: int,
+    config: ModelConfig,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """`_latent_expand` of the first ``seen[b]`` columns of each row → k, v
+    [B, H, T, nope + rope], [B, H, T, v] head-major. A column past
+    ``seen[b]`` is NOT written: what a caller reads there is undefined."""
+    kl, nope, rope = config.kv_lora_rank, config.qk_nope_head_dim, config.qk_rope_head_dim
+    b, t, width = lat.shape
+    h, v_dim = config.n_heads, w.shape[-1] - nope
+    assert t % block == 0, "caller gates divisibility"
+    blocks = (seen // block).astype(jnp.int32)
+
+    # past a row's last block a step does nothing and every index stays where
+    # the last step that did left it (a block index that does not change is
+    # neither fetched nor written again)
+    def lat_index(b, j, h_at, blocks):
+        return (b, jnp.minimum(j, blocks[b] - 1), 0)
+
+    def w_index(b, j, h_at, blocks):
+        return (jnp.where(j < blocks[b], h_at, h - 1), 0, 0)
+
+    def out_index(b, j, h_at, blocks):
+        return (b, w_index(b, j, h_at, blocks)[0], lat_index(b, j, h_at, blocks)[1], 0)
+
+    # a head's share of the matrix as one block: [H, kl, nope], [H, kl, v]
+    heads_first = w.transpose(1, 0, 2)
+    weights = [heads_first[..., :nope], heads_first[..., nope:]]
+    specs = [pl.BlockSpec((1, kl, nope), w_index), pl.BlockSpec((1, kl, v_dim), w_index)]
+    if scale is not None:
+        weights += [scale[:, None, :nope], scale[:, None, nope:]]
+        specs += [pl.BlockSpec((1, 1, nope), w_index), pl.BlockSpec((1, 1, v_dim), w_index)]
+    return pl.pallas_call(
+        functools.partial(_latent_expand_kernel, kl=kl, rope=rope, quantized=scale is not None),
+        name="latent_expand_blocks",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, t // block, h),
+            in_specs=[pl.BlockSpec((1, block, width), lat_index), *specs],
+            out_specs=[
+                pl.BlockSpec((1, 1, block, nope + rope), out_index),
+                pl.BlockSpec((1, 1, block, v_dim), out_index),
+            ],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, h, t, nope + rope), lat.dtype),
+            jax.ShapeDtypeStruct((b, h, t, v_dim), lat.dtype),
+        ],
+        compiler_params=_COMPILER_PARAMS,
+        interpret=interpret,
+    )(blocks, lat, *weights)
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,7 @@ from langstream_tpu.models.transformer import (
     moe_count_names,
     insert_copies_pages,
     join_rec,
+    latent_columns_expanded,
     make_kv_cache,
     paged_block_step_inplace,
     paged_decode_step_inplace,
@@ -1613,6 +1614,7 @@ class ServingEngine:
         self.index_tokens_scored_total = 0
         self.kv_tokens_selected_total = 0
         self.latent_tokens_expanded_total = 0
+        self.latent_columns_expanded_total = 0
         # slots freed since the last dispatch: their device temp must be
         # zeroed, else sample()'s batch-wide any_sample/any_filter predicates
         # keep paying the full-vocab sort for a slot that no longer exists
@@ -2668,9 +2670,13 @@ class ServingEngine:
                 if self.config.has_indexer else {}
             ),
             # a model that keeps a latent: the cached columns its segments
-            # re-expanded a layer (a decode chunk expands none)
+            # re-expanded a layer, and the columns of their tables they
+            # expanded in all (a decode chunk expands none)
             **(
-                {"latent-tokens-expanded-total": self.latent_tokens_expanded_total}
+                {
+                    "latent-tokens-expanded-total": self.latent_tokens_expanded_total,
+                    "latent-columns-expanded-total": self.latent_columns_expanded_total,
+                }
                 if self.config.has_latent else {}
             ),
             # a model with window layers: its second page group's use
@@ -6700,12 +6706,21 @@ class ServingEngine:
             if self.config.has_latent:
                 # the cached columns (earlier segments') whose latents this
                 # segment re-expanded into keys and values, a layer: every one
-                # behind it (the program expands its table's whole width: the
-                # rest is the segment's own tokens and unmapped columns)
+                # behind it; and the columns of its table the program expanded
+                # in all, by the program's own rule: those and the segment's
+                # own, in whole blocks (the table's whole width where the
+                # read is not the walk over key blocks)
+                pool = self._pagepool
+                columns = latent_columns_expanded(
+                    s0, width, pool.table_len * pool.page_size, self.config
+                )
                 with self._stats_lock:
                     self.latent_tokens_expanded_total += s0
+                    self.latent_columns_expanded_total += columns
                 if disp is not None:
-                    disp.attrs.update(latent_tokens_expanded=s0)
+                    disp.attrs.update(
+                        latent_tokens_expanded=s0, latent_columns_expanded=columns
+                    )
         if not final:
             if per_segment:  # nothing to deliver: the fetch lands the span
                 return [(

@@ -7,7 +7,8 @@ reference `benchmark/reference/glm_moe_dsa.py` (expanded form only):
        reference's full forward pass (logits); the selected sets equal away
        from ties;
 (ii)   the absorbed decode read equals the expanded attention on the same
-       int8 `wkv_b`; the interpret-mode kernels against their jnp;
+       int8 `wkv_b`; the interpret-mode kernels against their jnp; a segment
+       expands the columns its queries can see, to the bit, and reads no other;
 (iii)  the router: the bias chooses and does not weigh, the scaling;
 (iv)   the share tied to the model: the parts that all shares of the experts
        give, the shared expert counted once, add up to the uncut reference's;
@@ -195,6 +196,123 @@ def test_the_latent_kernel_in_interpret_mode_is_its_jnp(params):
     )
     assert float(jnp.abs(got[0]).max()) == 0.0  # a row of nothing: zeros, not NaN
     assert err(got[1:], want[1:]) < 1e-5
+
+
+# -- (ii b) a segment expands the columns its queries can see -----------------------------
+
+KERNELS = dataclasses.replace(CONFIG, attention_impl="pallas")
+
+
+@pytest.mark.parametrize(
+    "t, s, offsets, seen",
+    [
+        # the cell's table and segment (key blocks of 512, expanded two at a time)
+        (17408, 2048, [0], [2048]),
+        (17408, 2048, [2048], [4096]),
+        (17408, 2048, [6144], [8192]),
+        (17408, 2048, [17408 - 2048], [17408]),
+        # a warm suffix: it starts inside a key block, and ends inside one
+        (17408, 2048, [2348], [5120]),
+        # the check's table (51 x 128): a last segment whose padded width passes the table
+        (6528, 2048, [4096], [6144]),
+        (6528, 2048, [6144], [6528]),
+        # two rows at two offsets: each expands its own
+        (17408, 2048, [2048, 10240], [4096, 12288]),
+    ],
+    ids=lambda v: "-".join(map(str, v)) if isinstance(v, list) else str(v),
+)
+def test_a_segment_expands_the_columns_it_sees_as_the_whole_expansion_does(
+    params, t, s, offsets, seen
+):
+    """`latent_expand_blocks` (interpret mode) against `_latent_expand` of the
+    whole table, on the columns `latent_columns_expanded` names, every row to
+    its own bound. To the BIT where no sum rounds (the layer's int8 matrix
+    under scales that are powers of two, latents that are small whole
+    numbers: the two differ in nothing but the order of a float32 sum, which
+    the CPU's two products do not share); to that order's 1e-5 with the
+    layer's own scales and normal latents."""
+    lp = jax.tree.map(lambda a: a[1], params["layers"])
+    assert lp["wkv_b"]["q"].dtype == jnp.int8
+    width = CONFIG.latent_key_width
+    keys = jax.random.split(jax.random.PRNGKey(t + offsets[0]), 3)
+    exact = {**lp, "wkv_b": {
+        "q": lp["wkv_b"]["q"],
+        "s": 2.0 ** jax.random.randint(keys[0], lp["wkv_b"]["s"].shape, -6, -1).astype(jnp.float32),
+    }}
+    whole = jax.random.randint(keys[1], (len(offsets), t, width), -8, 9).astype(jnp.float32)
+    normal = jax.random.normal(keys[2], (len(offsets), t, width))
+    at = jnp.asarray(offsets, jnp.int32)
+    assert T.latent_columns_expanded(at, s, t, KERNELS).tolist() == seen
+    assert [T.latent_columns_expanded(o, s, t, KERNELS) for o in offsets] == seen  # the host's
+    # what the segment's walk reads last lies inside: `_segment_blocks`' last key block
+    from langstream_tpu.ops import attention as ops
+
+    _, block_k, _ = ops.segment_key_blocks(s, t, CONFIG.resolved_head_dim, 1, 0)
+    assert ops.latent_expand_block(s, t, KERNELS) % block_k == 0
+    for offset, bound in zip(offsets, seen):
+        assert (min((offset + s - 1) // block_k, t // block_k - 1) + 1) * block_k <= bound
+    for weights, rows, tol in ((exact, whole, 0.0), (lp, normal, 1e-5)):
+        rows = rows.at[..., CONFIG.latent_width:].set(0.0)
+        want = T._latent_expand(rows, weights, CONFIG)
+        got = T._latent_expand_seen(rows, weights, at, s, KERNELS)
+        for mine, all_of_it in zip(got, want):
+            assert mine.shape == all_of_it.shape and float(jnp.abs(all_of_it).max()) > 1.0
+            for row, bound in enumerate(seen):
+                np.testing.assert_allclose(
+                    np.asarray(mine[row, :, :bound]), np.asarray(all_of_it[row, :, :bound]),
+                    rtol=tol, atol=tol,
+                )
+    # where the read is masked jnp every column is multiplied: the whole table
+    assert T.latent_columns_expanded(offsets[0], s, t, CONFIG) == t
+    assert T.latent_columns_expanded(at, s, t, CONFIG).tolist() == [t] * len(offsets)
+
+
+@pytest.mark.parametrize(
+    "s, offset, seen",
+    [
+        (8, 0, 128),  # no query past the top-k: `flash_segment_attention`
+        (16, 0, 128),
+        (16, 200, 256),  # inside a key block
+        (16, 368, 384),
+        (16, 640 - 16, 640),
+    ],
+    ids=lambda v: str(v),
+)
+def test_what_a_segment_does_not_expand_it_does_not_read(params, s, offset, seen):
+    """A segment through a pool of random latents and indexer keys, a table
+    of 640 columns (key blocks of 128): with every latent PAST the segment's
+    bound poisoned (NaN: an expanded NaN read under a probability of 0 is
+    NaN), the logits and the pool's new rows are the unpoisoned run's."""
+    page, pages = PAGE, 640 // PAGE
+    table = jnp.arange(pages)[None]
+    assert T.latent_columns_expanded(offset, s, 640, KERNELS) == seen
+    keys = jax.random.split(jax.random.PRNGKey(offset + s), 2)
+    pool = T.make_page_pool(KERNELS, pages, page)
+    pool = {
+        "lat": jax.random.normal(keys[0], pool["lat"].shape).at[..., CONFIG.latent_width:].set(0.0),
+        "ik": jax.random.normal(keys[1], pool["ik"].shape),
+    }
+    poisoned = {**pool, "lat": pool["lat"].at[:, seen // page:].set(jnp.nan)}
+    tokens = tokens_of(s, seed=offset)[None]
+
+    def run(pool):
+        return T.paged_prefill_segment_inplace(
+            params, tokens, jnp.array([offset]), jnp.array([s]), pool, table, KERNELS, page
+        )
+
+    logits, after = run(pool)
+    got, got_after = run(poisoned)
+    assert bool(jnp.isfinite(logits).all())
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(logits))
+    mine = slice(offset // page, (offset + s) // page)
+    for leaf in ("lat", "ik"):
+        np.testing.assert_array_equal(
+            np.asarray(got_after[leaf][:, mine]), np.asarray(after[leaf][:, mine])
+        )
+    from langstream_tpu.ops import attention as ops
+
+    traced = ops.attention_paths()[f"paged-segment-latent-expand[s={s},t=640]"]
+    assert traced == "latent_expand_blocks"
 
 
 # -- (iii) the router -------------------------------------------------------------------

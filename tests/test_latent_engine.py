@@ -109,11 +109,25 @@ def test_a_copied_and_a_zeroed_page_carry_both_leaves():
     assert set(E._page_snapshot(pool, 3)) == {"lat", "ik"}
 
 
-def test_spans_and_counters_say_what_was_scored_read_and_expanded(params):
+@pytest.mark.parametrize(
+    "impl, table, columns",
+    [
+        # masked jnp multiplies every column of the table: all of it, a segment
+        ("auto", 128, [128, 128, 128]),
+        # the walk over key blocks (of 128 in a table of 640) stops at its
+        # diagonal: a segment expands up to its last query's block
+        ("pallas", 640, [128, 128, 128]),
+    ],
+    ids=["jnp", "kernels"],
+)
+def test_spans_and_counters_say_what_was_scored_read_and_expanded(params, impl, table, columns):
     from langstream_tpu.serving import observability
 
     spans = []
-    engine = make_engine(dataclasses.replace(CONFIG, name="tiny-latent-spans"), params)
+    engine = make_engine(
+        dataclasses.replace(CONFIG, name=f"tiny-latent-spans-{impl}", attention_impl=impl),
+        params, max_seq_len=table, kv_pages=4 * table // 8,
+    )
     emit = observability.emit_dispatch_span
     record = lambda name, start, end, attrs: spans.append((name, dict(attrs)))  # noqa: E731
     try:
@@ -131,6 +145,9 @@ def test_spans_and_counters_say_what_was_scored_read_and_expanded(params):
     assert [a["offset"] for a in segments] == [0, 16, 32]
     # a segment re-expands every cached column behind it, a layer
     assert [a["latent_tokens_expanded"] for a in segments] == [0, 16, 32]
+    # and the columns of its table the program expanded in all, by its own rule
+    assert [a["latent_columns_expanded"] for a in segments] == columns
+    assert columns == [T.latent_columns_expanded(o, 16, table, engine.config) for o in (0, 16, 32)]
     for attrs in segments:
         lengths = attrs["offset"] + 1 + np.arange(attrs["real_tokens"])
         assert attrs["index_tokens_scored"] == lengths.sum()
@@ -141,7 +158,9 @@ def test_spans_and_counters_say_what_was_scored_read_and_expanded(params):
     for attrs in chunks:
         assert attrs["latent_tokens_expanded"] == 0  # a decode step attends in the latent space
         assert attrs["kv_tokens_selected"] == attrs["kv_tokens_read"] == TOPK * attrs["row_steps"]
+        assert "latent_columns_expanded" not in attrs
     assert stats["latent-tokens-expanded-total"] == 48
+    assert stats["latent-columns-expanded-total"] == sum(columns)
     assert stats["kv-bytes-per-token"] == CONFIG.kv_bytes_per_token(itemsize=4)
     assert stats["kv-bytes-per-token"] == 4 * (128 + 128) * 4
     assert stats["index-tokens-scored-total"] >= sum(a["index_tokens_scored"] for a in segments + chunks)
@@ -156,6 +175,7 @@ def test_a_model_with_k_and_v_says_its_bytes_a_token_and_expands_nothing(params)
     finally:
         engine.stop()
     assert "latent-tokens-expanded-total" not in stats
+    assert "latent-columns-expanded-total" not in stats
     assert stats["kv-bytes-per-token"] == moe.kv_bytes_per_token() == 2 * 2 * 4 * 8 * 2
 
 

@@ -245,6 +245,19 @@ def _latent_decode(config, batch, table, pages, layers):
     )
 
 
+def _latent_expand(config, s, t):
+    """A segment's expansion of its row's latents into the keys and values
+    of every head, head-major, up to the columns its queries can see."""
+    kl, h = config.kv_lora_rank, config.n_heads
+    out = config.qk_nope_head_dim + config.v_head_dim
+    block = A.latent_expand_block(s, t, config)
+    return (
+        lambda lat, w, scale, seen: A.latent_expand_blocks(lat, w, scale, seen, block, config),
+        (SDS((1, t, config.latent_key_width), jnp.bfloat16), SDS((kl, h, out), jnp.int8),
+         SDS((h, out), jnp.float32), SDS((1,), jnp.int32)),
+    )
+
+
 def _index_scores(config, s, t):
     """The indexer's scores of a segment, in tiles."""
     hi, di = config.index_n_heads, config.index_head_dim
@@ -426,6 +439,11 @@ CASES = {
     "glm-sparse-segment-2048": _sparse_segment(GLM, 2048, 17408),
     "glm-segment-select-6528": _segment_select(GLM, 6528, 6528),
     "glm-sparse-segment-6528": _sparse_segment(GLM, 6528, 6528),
+    # a segment's expansion (PR 49): 1 row, 17,408 columns of 640-lane latents
+    # into 64 heads' keys and values, two key blocks a step; the check's table
+    # at its key block of 128
+    "glm1x2048-latent-expand": _latent_expand(GLM, 2048, 17408),
+    "glm1x6528-latent-expand": _latent_expand(GLM, 6528, 6528),
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
     **{f"gemma-prefill-{s}": _prefill(GEMMA, s) for s in (512, 1024, 2048)},
@@ -503,6 +521,7 @@ def _kernel_of(case: str) -> str:
         "index-scores": "index_scores",
         "segment-select": "segment_select",
         "sparse-segment": "sparse_segment_attention",
+        "latent-expand": "latent_expand_blocks",
     }[kind]
 
 
@@ -1003,7 +1022,9 @@ def test_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
     temporary of a row's expanded cache (keys or values of 64 heads over a
     table, nope + v or 256 wide, in any order) and no gather of a row's
     latents; the segment expands its row's latents into keys and values of 64
-    heads, ranks in one call and never forms scores of [S, heads, T]; each
+    heads in one kernel that leaves them head-major (no transpose, copy or
+    fill of a `bf16[1,64,17408,256]` outside it), ranks in one call and
+    never forms scores of [S, heads, T]; each
     fits the chip beside its state, and no leaf of the pool is relaid."""
     from langstream_tpu.models.quant import init_random_quantized_params
     from langstream_tpu.models.transformer import make_page_pool
@@ -1034,7 +1055,7 @@ def test_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         static = (GLM, PAGE)
         kernels = (
             "flash_segment_attention", "sparse_segment_attention", "segment_select",
-            "moe_grouped_matmul", "paged_insert_pages",
+            "moe_grouped_matmul", "paged_insert_pages", "latent_expand_blocks",
         )
         path = f"paged-segment-latent-sparse[s={seg},t={t}]"
     compiled = _compile_as_on_chip(
@@ -1063,6 +1084,18 @@ def test_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         assert paths[f"paged-segment-latent-select[s={seg},t={t}]"] == "segment_select"
         assert paths[f"paged-segment-latent[s={seg},t={t}]"] == "flash_segment_attention"
         assert has([1, h, t, 256])  # the expanded keys and values of the row's columns
+        # which leave their kernel head-major, as the walk reads them (PR 49):
+        # nothing of that size is relaid, copied or filled outside it, and the
+        # einsum's [t, h, j] is formed in no order
+        assert paths[f"paged-segment-latent-expand[s={seg},t={t}]"] == "latent_expand_blocks"
+        # under the scope `latent_ms_per_1k_segment_tokens.drain` finds its events by
+        calls = re.findall(r"%latent_expand_blocks(?:\.\d+)? = .*", text)
+        assert calls and all("/attention.latent.expand/" in call for call in calls), calls
+        expanded = re.escape(f"bf16[1,{h},{t},256]")
+        made = re.findall(rf"= {expanded}\S* ([\w-]+)\(", text)
+        assert made and set(made) <= {"custom-call", "get-tuple-element", "parameter"}, set(made)
+        for shape in ([1, t, h, 256], [1, t, h, 192], [1, t, h, 448], [t, h, 448], [1, h, t, 192]):
+            assert not has(shape), shape
         for heads in (h, GLM.index_n_heads):
             for shape in ([1, seg, heads, t], [1, heads, seg, t], [seg, heads, t], [heads, seg, t]):
                 assert not has(shape), shape
@@ -1208,6 +1241,19 @@ def test_an_admission_group_s_page_writer_hands_mosaic_what_it_did(v5e, case):
     assert _short_hash(body) == INSERT_BODIES_AT_PARENT[case]
 
 
+# The segment's expansion kernel of a latent model, new in PR 49, as that PR
+# handed it to Mosaic at the GLM cell's shapes: a later PR that does not mean
+# to touch it holds it still.
+LATENT_EXPAND_BODY_AT_PR49 = {"glm1x2048-latent-expand": "17e09ee2548379aa"}
+
+
+@pytest.mark.parametrize("case", sorted(LATENT_EXPAND_BODY_AT_PR49))
+def test_the_latent_expansion_hands_mosaic_what_it_did(v5e, case):
+    (body,) = _kernel_bodies(*CASES[case], v5e[0])
+    assert "module @latent_expand_blocks " in body
+    assert _short_hash(body) == LATENT_EXPAND_BODY_AT_PR49[case]
+
+
 def test_the_selection_is_the_only_difference_of_its_kernel(v5e):
     """The selected walk's Mosaic module against the plain decode kernel's
     at the same sizes: one more operand (a row's block, float32
@@ -1336,9 +1382,13 @@ def _engine_program_text(case: str) -> str:
 
 # The latent model's three engine programs (`tiny-latent-moe-test`), as PR 47
 # left them: what a later PR that does not mean to touch them holds still.
+# ONE row is PR 49's, re-taken on purpose: with the kernels forced the SEGMENT
+# expands the columns its queries can see in `latent_expand_blocks`, in place
+# of `_latent_expand` of the whole table ("5d841ea49914bce2" at PR 47, under
+# the scatter); the decode chunk and the admit group are PR 47's.
 LATENT_PROGRAMS_AT_PR47 = {
     "tiny-latent-moe-test": "7e30a4ca8989f33e",
-    "segment/tiny-latent-moe-test": "5d841ea49914bce2",
+    "segment/tiny-latent-moe-test": "f92ebd1992a5fe51",
     "admit/tiny-latent-moe-test": "7ccaa668ff6f12fc",
 }
 
@@ -1360,7 +1410,9 @@ SEGMENT_PROGRAMS_AT_PR48 = {
     "segment/tiny-hybrid-test": "41ea43aa7585926d",
     "segment/tiny-window-moe-test": "dca68855cfb508c3",
     "segment/tiny-sparse-moe-test": "cb4b43784ed5ccfa",
-    "segment/tiny-latent-moe-test": "33335341cfab4025",
+    # (PR 49's, re-taken on purpose with `LATENT_PROGRAMS_AT_PR47`'s row: the
+    # bounded expansion; "33335341cfab4025" at PR 48)
+    "segment/tiny-latent-moe-test": "21c00b25ad4d8841",
 }
 
 
@@ -1392,7 +1444,9 @@ def test_the_segment_programs_lower_as_pr48_left_them(case):
 # now says how the segment's new rows reach the pool,
 # `paged-segment-write[s=16]`: by whole pages where the kernels are forced
 # over a bf16 pool, by the scatter on the CPU's own choice and into an int8
-# pool. Every other entry is the parent's.
+# pool. And ONE is PR 49's: the latent preset, kernels forced, says which call
+# expands a segment's columns, `paged-segment-latent-expand[..]`. Every other
+# entry is the parent's.
 PATHS_AT_PARENT = {
     "tiny-blockfill-moe-test/auto": {
         "paged-block[s=4,t=48]": "jnp",
@@ -1427,6 +1481,7 @@ PATHS_AT_PARENT = {
     },
     "tiny-latent-moe-test/pallas": {
         "paged-decode-latent[s=1,t=48]": "ragged_paged_latent_attention",
+        "paged-segment-latent-expand[s=16,t=48]": "latent_expand_blocks",  # (PR 49's key)
         "paged-segment-latent-select[s=16,t=48]": "segment_select",
         "paged-segment-latent-sparse[s=16,t=48]": "sparse_segment_attention",
         "paged-segment-latent[s=16,t=48]": "flash_segment_attention",
