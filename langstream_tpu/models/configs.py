@@ -8,6 +8,7 @@ map checkpoints mechanically.
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -67,6 +68,21 @@ class ModelConfig:
     rope_scaling_low_freq_factor: float = 1.0
     rope_scaling_high_freq_factor: float = 4.0
     rope_scaling_original_max_seq_len: int = 8192
+    # ``rope_scaling_type`` "yarn" (HF rope_scaling type "yarn", in DeepSeek-V3's
+    # form; the factor and the original length are the two fields above):
+    # frequency i of a rotary of width d is blended from f_i towards f_i /
+    # factor along a ramp between the dimensions that turn ``beta_fast`` and
+    # ``beta_slow`` times over the original length (`yarn_blend`), the tables
+    # are multiplied by mscale(factor, mscale) / mscale(factor, mscale_all_dim)
+    # and the softmax scale by mscale(factor, mscale_all_dim)^2 where
+    # ``mscale_all_dim`` is set (``attn_scale``), mscale(f, m) = 0.1 m ln f + 1.
+    # Read by a model that keeps a latent alone: ``__post_init__`` refuses it
+    # elsewhere, whose kernels scale by 1 / sqrt(head_dim)
+    rope_scaling_type: str = "llama3"
+    rope_scaling_beta_fast: float = 32.0
+    rope_scaling_beta_slow: float = 1.0
+    rope_scaling_mscale: float = 1.0
+    rope_scaling_mscale_all_dim: float = 0.0
     # layer pattern (hybrid models): the kinds of one PERIOD of layers,
     # each "linear_attention" (gated delta rule, a recurrent state per
     # sequence: ops/gated_delta.py) or "full_attention"; n_layers is a whole
@@ -168,11 +184,15 @@ class ModelConfig:
     # and values through ONE normed latent of ``kv_lora_rank`` a token and one
     # rotary key of ``qk_rope_head_dim`` shared by all heads; a head's q.k is
     # over ``qk_nope_head_dim + qk_rope_head_dim``, its value ``v_head_dim``
-    # wide. A token's cache is (latent | rotary key), ONE leaf ``"lat"`` of the
-    # cache and the page pool in place of ``"k"`` and ``"v"``; a decode step
-    # attends in the latent space (the up-projection absorbed into the query
-    # and the output), a segment over the latents re-expanded. ``kv_lora_rank``
-    # 0: none, and none of the five is read
+    # wide, which need not be the key's width. A token's cache is (latent |
+    # rotary key), ONE leaf ``"lat"`` of the cache and the page pool in place
+    # of ``"k"`` and ``"v"``; a decode step attends in the latent space (the
+    # up-projection absorbed into the query and the output), a segment over
+    # the latents re-expanded. Two reads: under a learned selection where the
+    # model has an indexer (``index_topk`` > 0, its key a second leaf
+    # ``"ik"``), else DENSE, every cached latent of the row read a step and
+    # nothing of an indexer traced. ``kv_lora_rank`` 0: none, and none of the
+    # five is read
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -196,6 +216,42 @@ class ModelConfig:
     @property
     def has_latent(self) -> bool:
         return self.kv_lora_rank > 0
+
+    @property
+    def yarn(self) -> bool:
+        return bool(self.rope_scaling_factor) and self.rope_scaling_type == "yarn"
+
+    def yarn_mscale(self, mscale: float) -> float:
+        """YaRN's ``0.1 x mscale x ln(factor) + 1`` (1 up to a factor of 1)."""
+        factor = float(self.rope_scaling_factor or 1.0)
+        return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+    @property
+    def yarn_blend(self) -> tuple:
+        """(low, high) of YaRN's ramp over a rotary's ``rope_dim / 2``
+        frequencies: up to ``low`` a frequency stays f_i, from ``high`` on it
+        is f_i / factor, between them the two are blended linearly. The
+        dimensions that turn ``beta_fast`` and ``beta_slow`` times over the
+        original length, rounded outwards and kept inside the rotary."""
+        d, base = self.rope_dim, float(self.rope_theta)
+        original = float(self.rope_scaling_original_max_seq_len)
+
+        def turns(rotations: float) -> float:
+            return d * math.log(original / (rotations * 2.0 * math.pi)) / (2.0 * math.log(base))
+
+        low = max(math.floor(turns(self.rope_scaling_beta_fast)), 0)
+        high = min(math.ceil(turns(self.rope_scaling_beta_slow)), d - 1)
+        return low, high
+
+    @property
+    def attn_scale(self) -> float:
+        """What a latent model's scores are multiplied by before the softmax:
+        1 / sqrt(the expanded head's q.k width), times YaRN's
+        mscale(factor, ``mscale_all_dim``)^2 where that is set."""
+        scale = self.resolved_head_dim**-0.5
+        if self.yarn and self.rope_scaling_mscale_all_dim:
+            scale *= self.yarn_mscale(self.rope_scaling_mscale_all_dim) ** 2
+        return scale
 
     @property
     def latent_width(self) -> int:
@@ -445,16 +501,17 @@ class ModelConfig:
                     self.qk_norm or self.qk_norm_heads,
                 "ring_axis": self.ring_axis is not None,
                 "an attention soft cap": self.attn_logit_softcap is not None,
-                "no indexer (index_topk 0): the latent decode read is the selected one":
-                    not self.has_indexer,
                 f"q_lora_rank {self.q_lora_rank}, qk_nope_head_dim "
-                f"{self.qk_nope_head_dim} or qk_rope_head_dim {self.qk_rope_head_dim} "
-                "under 1, or an odd qk_rope_head_dim":
-                    min(self.q_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim) < 1
+                f"{self.qk_nope_head_dim}, qk_rope_head_dim {self.qk_rope_head_dim} or "
+                f"v_head_dim {self.v_head_dim} under 1, or an odd qk_rope_head_dim":
+                    min(self.q_lora_rank, self.qk_nope_head_dim, self.qk_rope_head_dim,
+                        self.v_head_dim) < 1
                     or self.qk_rope_head_dim % 2 == 1,
                 f"v_head_dim {self.v_head_dim} apart from qk_nope_head_dim + "
-                "qk_rope_head_dim (the expanded form's kernels take one head width)":
-                    self.v_head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim,
+                "qk_rope_head_dim under an indexer (the selected read's kernels are held "
+                "at one head width; a latent with no indexer takes a value of its own width)":
+                    self.has_indexer
+                    and self.v_head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim,
                 f"head_dim {self.head_dim} (a latent model's head is qk_nope_head_dim "
                 "+ qk_rope_head_dim: leave it unset)": self.head_dim is not None,
                 f"n_kv_heads {self.n_kv_heads} apart from n_heads (the expanded "
@@ -469,6 +526,19 @@ class ModelConfig:
             raise ValueError(
                 f"{self.name}: q_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
                 "v_head_dim belong to a model with a latent (kv_lora_rank > 0)"
+            )
+        if self.rope_scaling_type not in ("llama3", "yarn"):
+            raise ValueError(
+                f"{self.name}: rope_scaling_type {self.rope_scaling_type!r} (llama3 | yarn)"
+            )
+        if self.rope_scaling_type == "yarn" and not (
+            self.has_latent and self.rope_scaling_factor
+            and self.rope_scaling_beta_fast > self.rope_scaling_beta_slow > 0
+        ):
+            raise ValueError(
+                f"{self.name}: rope_scaling_type yarn belongs to a model with a latent "
+                "(kv_lora_rank > 0: its block alone reads the softmax factor, attn_scale), "
+                "with a rope_scaling_factor and beta_fast > beta_slow > 0"
             )
         if self.n_leading_dense and not (
             self.has_latent and self.experts_held and 0 < self.n_leading_dense < self.n_layers
@@ -494,7 +564,7 @@ class ModelConfig:
 
     @property
     def resolved_head_dim(self) -> int:
-        if self.has_latent:  # the expanded form's head: q.k over this, v as wide
+        if self.has_latent:  # the expanded form's head: q.k over this, v over v_head_dim
             return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
 
@@ -797,6 +867,51 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         index_topk=8,
         index_rope_dim=8,
         index_query_input="query_latent",
+    ),
+    "tiny-latent-dense-moe-test": _preset(
+        # a model that keeps a latent and has NO indexer, at test size
+        # (tests/test_latent_dense_attention.py): every cached latent is read
+        # a step. A leading dense layer and three expert layers, a query
+        # latent of 32, a key-value latent of 16 and a rotary key of 8 for 4
+        # heads whose q.k is 16 + 8 = 24 wide and whose value 16 (Dv != Dk),
+        # interleaved rotary under YaRN: of the rotary's 4 frequencies the
+        # ramp (low 1, high 3) keeps 0 and 1, blends 2 and divides 3 by the
+        # factor 8, and the softmax scale carries (0.1 ln 8 + 1)^2 = 1.459; 8
+        # sigmoid-routed experts top-2 chosen under a non-zero bias, scaled
+        # by 2.5, of which this share holds 4, one shared expert, an untied
+        # head
+        name="tiny-latent-dense-moe-test",
+        vocab_size=512,
+        d_model=64,
+        n_layers=4,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=128,
+        rope_theta=100.0,
+        rms_norm_eps=1e-5,
+        max_seq_len=256,
+        rope_interleaved=True,
+        rope_scaling_type="yarn",
+        rope_scaling_factor=8.0,
+        rope_scaling_original_max_seq_len=128,
+        rope_scaling_beta_fast=4.0,
+        rope_scaling_beta_slow=1.0,
+        rope_scaling_mscale=1.0,
+        rope_scaling_mscale_all_dim=1.0,
+        n_experts=8,
+        n_experts_per_tok=2,
+        moe_d_ff=32,
+        experts_held=(0, 4),
+        moe_scoring="sigmoid",
+        n_shared_experts=1,
+        router_bias=True,
+        routed_scaling=2.5,
+        n_leading_dense=1,
+        q_lora_rank=32,
+        kv_lora_rank=16,
+        qk_nope_head_dim=16,
+        qk_rope_head_dim=8,
+        v_head_dim=16,
     ),
     "olmo-hybrid-7b": _preset(
         # allenai/Olmo-Hybrid-7B config.json: (gated delta-rule x3, full

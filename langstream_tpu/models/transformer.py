@@ -51,7 +51,7 @@ SCOPES = (
     "attention.window", "attention.full", "moe_ffn.shared",
     "block_choice",
     "attention.index", "attention.index.scores", "attention.select", "attention.sparse",
-    "attention.latent", "attention.latent.expand",
+    "attention.latent", "attention.latent.expand", "attention.latent.read",
 )
 
 # What `moe_ffn_counted` counts, per call, as one int32 vector: expert
@@ -359,6 +359,8 @@ def _rope_freqs(
     # section of ``config.mrope_section`` names; equal triples are [B, S]
     half = config.rope_dim // 2
     freqs = config.rope_theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if config.yarn:
+        return _yarn_tables(positions, freqs, config)
     if config.rope_scaling_factor:
         freqs = _llama3_rope_scale(freqs, config)
     if positions.ndim == 3:
@@ -374,6 +376,28 @@ def _text_positions(positions: jax.Array) -> jax.Array:
     """[B, S] of a [3, B, S] triple: the temporal stream, which is a text
     token's position (the indexer's rotary turns by it)."""
     return positions[0] if positions.ndim == 3 else positions
+
+
+def _yarn_tables(positions, freqs, config: ModelConfig) -> tuple[jax.Array, jax.Array]:
+    """sin/cos [B, S, half] under YaRN (HF rope_scaling type "yarn", DeepSeek-V3's
+    form): frequency i is ``f_i (1 - ramp_i) + (f_i / factor) ramp_i`` with
+    ``ramp_i = clip((i - low) / (high - low), 0, 1)`` (``config.yarn_blend``:
+    the fast dimensions keep their frequency, the slow ones are interpolated),
+    and the tables carry mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim), which is 1 where the two are equal; the softmax's factor
+    is ``config.attn_scale``'s."""
+    low, high = config.yarn_blend
+    ramp = jnp.clip(
+        (jnp.arange(freqs.shape[0], dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0
+    )
+    freqs = freqs * (1.0 - ramp) + freqs / jnp.float32(config.rope_scaling_factor) * ramp
+    angles = positions.astype(jnp.float32)[..., None] * freqs
+    factor = config.yarn_mscale(config.rope_scaling_mscale) / config.yarn_mscale(
+        config.rope_scaling_mscale_all_dim
+    )
+    if factor == 1.0:
+        return jnp.sin(angles), jnp.cos(angles)
+    return jnp.sin(angles) * factor, jnp.cos(angles) * factor
 
 
 def _llama3_rope_scale(freqs: jax.Array, config: ModelConfig) -> jax.Array:
@@ -671,7 +695,10 @@ def attention(
         scores = scores * k["s"][:, :, None, None, :]
     else:
         scores = jnp.einsum("bshgd,bhtd->bhgst", qg, k).astype(jnp.float32)
-    scores = scores / jnp.sqrt(jnp.float32(d))
+    if config.has_latent:  # its scale may carry YaRN's softmax factor
+        scores = scores * config.attn_scale
+    else:
+        scores = scores / jnp.sqrt(jnp.float32(d))
     scores = _softcap(scores, config.attn_logit_softcap)
     scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
@@ -686,7 +713,7 @@ def attention(
         out = (out * ps.transpose(0, 3, 1, 2)[..., None]).astype(q.dtype)
     else:
         out = jnp.einsum("bhgst,bhtd->bshgd", probs.astype(q.dtype), v)
-    return out.reshape(b, s, h * d)
+    return out.reshape(b, s, -1)  # H x the value's width (a latent model's is its own)
 
 
 def _seen(positions: jax.Array, t: int, window: int = 0, kv_limit=None) -> jax.Array:
@@ -1214,7 +1241,7 @@ def _attention_block(
     more than ``index_topk`` keys (`_paged_selected_read`). A model that
     keeps a LATENT (``config.has_latent``) has an attention half of its own
     behind this entry, `_latent_attention_block`: ``cache_kv`` is then the
-    latent leaf and the indexer's keys."""
+    latent leaf and, where the model has an indexer, its keys."""
     if config.has_latent:
         if lora is not None or verify or block:
             raise NotImplementedError(
@@ -1508,9 +1535,27 @@ def _selected_segment_read(
 #   absorbed   q~_h = [q_nope,h W_uk,h^T | q_rope,h] against the rows as they
 #              lie, o_h = (sum_s p_s c_kv,s) W_uv,h: a paged decode step, which
 #              forms nothing of a head's keys or values.
+# A head's q.k is ``qk_nope_head_dim + qk_rope_head_dim`` wide and its value
+# ``v_head_dim``, which need not be equal (192 against 128): the expanded
+# form's kernels take the two widths as they are. The scores' scale is
+# ``config.attn_scale`` in both forms (YaRN's softmax factor rides there).
+# Two READS of the cached latents, by what the model is:
+#   selected   a model with an indexer (``config.has_indexer``): the indexer's
+#              key is a second pool leaf ``"ik"``, a decode step walks the
+#              row's pages under its selection as a mask, a segment reads
+#              under the packed selection once a query sees more than
+#              ``index_topk`` keys (`_selected_segment_read`);
+#   dense      a model without: the pool holds ``"lat"`` alone, a decode step
+#              walks the row's pages with NO mask operand (every byte walked
+#              is a byte read), a segment is causal over its expanded columns
+#              (`_dispatch_attention`'s kernels), and nothing of an indexer,
+#              a ranking or a `lax.cond` on the lengths is traced.
 # Scopes inside ``attention``: ``attention.latent`` (the down-projections and
 # their norms, ``wq_b``, the absorb of q_nope, W_uv), ``attention.latent.expand``
-# (``wkv_b`` over columns), the indexer's three as they are.
+# (``wkv_b`` over columns), ``attention.latent.read`` (the DENSE read: the
+# decode kernel over the latent leaf, a segment's causal kernel over the
+# expanded columns), the indexer's three as they are (``attention.index``,
+# ``attention.select``, ``attention.sparse``: the selected read's alone).
 # ---------------------------------------------------------------------------
 
 
@@ -1548,7 +1593,7 @@ def _wkv_b(lp, config):
 
 def _latent_expand(lat, lp, config):
     """The expanded form's keys and values of the columns ``lat`` [B, T, W]
-    (cache rows, or tokens' own) -> k, v [B, H, T, nope + rope] head-major:
+    (cache rows, or tokens' own) -> k [B, H, T, nope + rope], v [B, H, T, v] head-major:
     ``k_h = [c_kv W_uk,h | k_rope]``, ``v_h = c_kv W_uv,h``."""
     kl, nope, rope = config.kv_lora_rank, config.qk_nope_head_dim, config.qk_rope_head_dim
     b, t, _ = lat.shape
@@ -1635,41 +1680,46 @@ def _latent_value_out(mixed, lp, config):
 
 def _latent_decode_read(q, index, plat, pik, table, layer, positions, lp, config, page_size,
                         kernels):
-    """A decode step's read of a latent model -> [B, 1, H x v]: the row's
-    cached indexer keys scored by whole pages and ranked (skipped whole while
-    no row is past ``index_topk``), and the attention IN THE LATENT SPACE
-    over the selected rows: absorbed queries against ``[c_kv | k_rope]`` as
-    the pool holds them, the value the same row's first ``kv_lora_rank``
-    lanes. With the kernels ONE call of the paged decode kernel over the
-    latent leaf under the selection as a mask over the row's pages
-    (`ops/attention.ragged_paged_latent_attention`: a page is fetched once
-    for key and value; a row of no more than ``index_topk`` tokens takes the
-    same kernel with nothing masked), whatever the table's length (a gather
-    of 2,048 rows of 1,280 B a row and layer pays the gather's 10 ns a row
-    five times over Keye's 256 B and loses sooner: `_WALK_TABLE_PER_TOPK`'s
-    prices); without them the row's latents gathered through the table and
-    masked jnp. Nothing of [B, H, T, nope + v] is formed either way."""
+    """A decode step's read of a latent model -> [B, 1, H x v]: the attention
+    IN THE LATENT SPACE, absorbed queries against ``[c_kv | k_rope]`` as the
+    pool holds them, the value the same row's first ``kv_lora_rank`` lanes.
+    Under a learned selection (``index`` the indexer's projections, ``pik``
+    its pool leaf): the row's cached indexer keys scored by whole pages and
+    ranked (skipped whole while no row is past ``index_topk``), the selected
+    rows read. ``index`` None (a model with no indexer): the DENSE read,
+    every cached row up to the query's, and no score, ranking or mask is
+    traced. With the kernels ONE call of the paged decode kernel over the
+    latent leaf (`ops/attention.ragged_paged_latent_attention`: a page is
+    fetched once for key and value), under the selection as a mask over the
+    row's pages or with no mask operand at all, whatever the table's length
+    (a gather of 2,048 rows of 1,280 B a row and layer pays the gather's
+    10 ns a row five times over Keye's 256 B and loses sooner:
+    `_WALK_TABLE_PER_TOPK`'s prices); without them the row's latents
+    gathered through the table and masked jnp. Nothing of [B, H, T, nope + v]
+    is formed either way."""
     from langstream_tpu.ops import attention as ops
 
-    q_idx, _, w_idx = index
     b, kl, topk = q.shape[0], config.kv_lora_rank, config.index_topk
     t = table.shape[1] * page_size
     lengths = _paged_lengths(table, positions[:, 0], page_size, plat.shape[1])
     visible = jnp.arange(t)[None, :] < lengths[:, None]
+    chosen = None
+    if index is not None:
+        q_idx, _, w_idx = index
 
-    # (`_paged_selected_read`'s six lines, kept there as they stand: factored
-    # out, the branches' names move in that model's lowered decode program)
-    def ranked():
-        scores = _decode_index_scores(
-            q_idx[:, 0], w_idx[:, 0], pik, table, layer, config, page_size
-        )
-        with jax.named_scope("attention.select"):
-            return _select_mask(scores, visible, topk)
+        # (`_paged_selected_read`'s six lines, kept there as they stand: factored
+        # out, the branches' names move in that model's lowered decode program)
+        def ranked():
+            scores = _decode_index_scores(
+                q_idx[:, 0], w_idx[:, 0], pik, table, layer, config, page_size
+            )
+            with jax.named_scope("attention.select"):
+                return _select_mask(scores, visible, topk)
 
-    chosen = lax.cond(jnp.any(lengths > topk), ranked, lambda: visible)
+        chosen = lax.cond(jnp.any(lengths > topk), ranked, lambda: visible)
     with jax.named_scope("attention.latent"):
         absorbed = _latent_absorb(q[:, 0], lp, config, plat.shape[-1])
-    with jax.named_scope("attention.sparse"):
+    with jax.named_scope("attention.latent.read" if index is None else "attention.sparse"):
         if kernels:
             ops.note_path(
                 "paged-decode-latent", "ragged_paged_latent_attention", config, s=1, t=t
@@ -1680,13 +1730,14 @@ def _latent_decode_read(q, index, plat, pik, table, layer, positions, lp, config
             ).reshape(b, config.n_heads, kl)
         else:
             ops.note_path("paged-decode-latent", "jnp", config, s=1, t=t)
+            seen = visible if chosen is None else chosen
             rows = _paged_gather(plat, layer, table, page_size)[:, 0]  # [B, T, W]
             logits = jnp.einsum(
                 "bhw,btw->bht", absorbed, rows, preferred_element_type=jnp.float32
-            ) * config.resolved_head_dim**-0.5
-            logits = jnp.where(chosen[:, None, :], logits, -1e30)
+            ) * config.attn_scale
+            logits = jnp.where(seen[:, None, :], logits, -1e30)
             probs = jnp.where(
-                chosen[:, None, :], jnp.exp(logits - logits.max(axis=-1, keepdims=True)), 0.0
+                seen[:, None, :], jnp.exp(logits - logits.max(axis=-1, keepdims=True)), 0.0
             )
             mixed = jnp.einsum(
                 "bht,btc->bhc", probs.astype(q.dtype), rows[..., :kl],
@@ -1702,15 +1753,19 @@ def _latent_attention_block(
     paged_table, page_size, layer,
 ):
     """`_attention_block` of a model that keeps a latent: (the FFN's input,
-    the layer's cache leaves ``("lat", "ik")``, written). Without a table the
+    the layer's cache leaves ``config.page_leaves``, written: ``("lat",
+    "ik")`` with an indexer, ``("lat",)`` without). Without a table the
     EXPANDED form over a local cache entry [B, 1, T, W] written at
     ``cache_positions`` (or over the tokens' own rows), under the selection
-    once a query sees more than ``index_topk`` columns; with one, the pool's
-    leaves whole, written at ``layer``: one query a row reads in the latent
-    space (`_latent_decode_read`), a segment re-expands its row's gathered
-    latents, cached columns and its own alike and none past its last query
-    (`_latent_expand_seen`), into a temporary that never enters the pool and
-    reads as a model with an indexer does (`_selected_segment_read`)."""
+    once a query of a model with an indexer sees more than ``index_topk``
+    columns; with one, the pool's leaves whole, written at ``layer``: one
+    query a row reads in the latent space (`_latent_decode_read`), a segment
+    re-expands its row's gathered latents, cached columns and its own alike
+    and none past its last query (`_latent_expand_seen`), into a temporary
+    that never enters the pool, and reads it as a model with an indexer does
+    (`_selected_segment_read`) or, with none, causally through
+    `_dispatch_attention`'s kernels under ``attention.latent.read``. A model
+    with no indexer traces none of its projections, leaf or branches."""
     from langstream_tpu.ops import attention as ops
 
     b, s = x.shape[:2]
@@ -1719,55 +1774,74 @@ def _latent_attention_block(
     )
     with jax.named_scope("attention"):
         u, c_q, q, lat = _latent_proj(x, lp, sin, cos, config)
-        with jax.named_scope("attention.index"):
-            index = _index_proj(u, lp, positions, config, c_q=c_q, rotary=(sin, cos))
-    q_idx, k_idx, w_idx = index
+        index = None
+        if config.has_indexer:
+            with jax.named_scope("attention.index"):
+                index = _index_proj(u, lp, positions, config, c_q=c_q, rotary=(sin, cos))
+    # the dense read's scope; the selected read names its own
+    read_scope = (
+        contextlib.nullcontext() if index is not None
+        else jax.named_scope("attention.latent.read")
+    )
     if paged_table is not None:
-        plat, pik = cache_kv
+        plat, *pik = cache_kv
         with jax.named_scope("kv_pool.write"):
-            plat, pik = _paged_write_rows(
-                (plat, pik), [_kept_width(lat, plat)[:, None], k_idx], layer, paged_table,
-                positions, page_size, config, segment=s > 1,
+            plat, *pik = _paged_write_rows(
+                (plat, *pik),
+                [_kept_width(lat, plat)[:, None], *([] if index is None else [index[1]])],
+                layer, paged_table, positions, page_size, config, segment=s > 1,
             )
         with jax.named_scope("attention"):
             if s == 1:
                 attn = _latent_decode_read(
-                    q, index, plat, pik, paged_table, layer, positions, lp, config, page_size,
-                    ops.paged_pallas_ok(config, page_size),
+                    q, index, plat, pik[0] if pik else None, paged_table, layer, positions,
+                    lp, config, page_size, ops.paged_pallas_ok(config, page_size),
                 )
             else:
                 rows = _paged_gather(plat, layer, paged_table, page_size)[:, 0]
                 k_all, v_all = _latent_expand_seen(rows, lp, positions[:, 0], s, config)
-                attn = _selected_segment_read(
-                    q, q_idx, w_idx, pik, k_all, v_all, paged_table, layer, mask, positions,
-                    config, "paged-segment-latent",
-                )
-            return x + quantized_matmul(attn, lp["wo"]), (plat, pik)
+                if index is None:
+                    with read_scope:
+                        attn = _dispatch_attention(
+                            q, k_all, v_all, mask, config, True, what="paged-segment-latent",
+                            positions=positions, from_zero=False,
+                        )
+                else:
+                    attn = _selected_segment_read(
+                        q, index[0], index[2], pik[0], k_all, v_all, paged_table, layer, mask,
+                        positions, config, "paged-segment-latent",
+                    )
+            return x + quantized_matmul(attn, lp["wo"]), (plat, *pik)
     with jax.named_scope("attention"):
         new_cache = None
+        k_idx = None if index is None else index[1]
         if cache_kv is not None:
-            clat, cik = cache_kv  # [B, 1, T, W], [B, T, Wi]
+            clat, *cik = cache_kv  # [B, 1, T, W], [B, T, Wi]
             rows_at = jnp.arange(b)[:, None]
             clat = clat.at[rows_at, 0, cache_positions].set(_kept_width(lat, clat))
-            cik = cik.at[rows_at, cache_positions].set(_kept_width(k_idx, cik))
-            new_cache = (clat, cik)
-            lat_all, k_idx = clat[:, 0], cik[..., :config.index_head_dim]
+            if index is not None:
+                cik = [cik[0].at[rows_at, cache_positions].set(_kept_width(k_idx, cik[0]))]
+            new_cache = (clat, *cik)
+            lat_all = clat[:, 0]
+            if index is not None:
+                k_idx = cik[0][..., :config.index_head_dim]
         else:
             lat_all = lat
             if collect_kv:
-                new_cache = (lat[:, None], k_idx)
+                new_cache = (lat[:, None], *([] if index is None else [k_idx]))
         k_all, v_all = _latent_expand(lat_all, lp, config)
-        if k_all.shape[2] > config.index_topk:
+        if index is not None and k_all.shape[2] > config.index_topk:
             if not causal:
                 raise NotImplementedError(
                     f"{config.name}: the indexer ranks what a causal query sees"
                 )
             attn = _selected_attention(
-                q, q_idx, w_idx, k_idx, k_all, v_all, mask, positions, config,
+                q, index[0], index[2], k_idx, k_all, v_all, mask, positions, config,
                 "prefill" if s > 1 else "decode",
             )
         else:
-            attn = _dispatch_attention(q, k_all, v_all, mask, config, causal)
+            with read_scope:
+                attn = _dispatch_attention(q, k_all, v_all, mask, config, causal)
         return x + quantized_matmul(attn, lp["wo"]), new_cache
 
 
@@ -2831,10 +2905,11 @@ def make_kv_cache(
         if config.has_latent:
             # ONE row a token in place of K and V, K's layout with one head:
             # [L, B, 1, T, latent_key_width]; the indexer's key beside it
-            return {
-                "lat": jnp.zeros((*shape[:2], 1, max_len, config.latent_key_width), dtype),
-                "ik": jnp.zeros((*shape[:2], max_len, config.index_key_width), dtype),
-            }
+            # where the model has an indexer
+            cache = {"lat": jnp.zeros((*shape[:2], 1, max_len, config.latent_key_width), dtype)}
+            if config.has_indexer:
+                cache["ik"] = jnp.zeros((*shape[:2], max_len, config.index_key_width), dtype)
+            return cache
         if config.kv_cache_dtype == "int8":
             if _KIND_KEY[kind]:
                 raise NotImplementedError(f"an int8 KV cache for window layers ({config.name})")

@@ -66,6 +66,12 @@ _VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT_BYTES)
 
 
+def _score_scale(config: ModelConfig, d: int) -> float:
+    """What the expanded form's kernels multiply q.k by: 1 / sqrt(the key's
+    width), or a latent model's own scale (YaRN's softmax factor with it)."""
+    return config.attn_scale if config.has_latent else 1.0 / (d**0.5)
+
+
 def _fit_block(block: int, n: int) -> int:
     """Largest block ≤ ``block`` that divides ``n``. pallas_ok blesses any
     128-multiple length, so a 512 default block must step down (512 → 256 →
@@ -286,7 +292,7 @@ def flash_prefill_attention(
     """Causal GQA attention → [B, S, H*D]; for a model that fills blocks
     (``config.block_length``) causal across blocks and two-way inside one."""
     b, s, h, d = q.shape
-    hkv = k.shape[1]
+    hkv, dv = k.shape[1], v.shape[-1]  # a latent model's value has a width of its own
     group = h // hkv
     block_k = _fit_block(block_k, s)
     block_q = _fit_block(
@@ -304,7 +310,7 @@ def flash_prefill_attention(
         _prefill_kernel,
         block_q=block_q,
         block_k=block_k,
-        scale=1.0 / (d**0.5),
+        scale=_score_scale(config, d),
         softcap=config.attn_logit_softcap,
         **extra,
     )
@@ -317,22 +323,22 @@ def flash_prefill_attention(
                 (1, 1, group, block_q, d), lambda b, h, i, j: (b, h, 0, i, 0)
             ),
             pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b, h, i, j: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda b, h, i, j: (b, h, j, 0)),
         ],
         out_specs=pl.BlockSpec(
-            (1, 1, group, block_q, d), lambda b, h, i, j: (b, h, 0, i, 0)
+            (1, 1, group, block_q, dv), lambda b, h, i, j: (b, h, 0, i, 0)
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((group, block_q, 128), jnp.float32),
             pltpu.VMEM((group, block_q, 128), jnp.float32),
-            pltpu.VMEM((group, block_q, d), jnp.float32),
+            pltpu.VMEM((group, block_q, dv), jnp.float32),
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(qg, k, v)
-    # [B, Hkv, G, S, D] → [B, S, H*D]
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * d)
+    # [B, Hkv, G, S, Dv] → [B, S, H*Dv]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * dv)
 
 
 # ---------------------------------------------------------------------------
@@ -446,7 +452,7 @@ def flash_segment_attention(
     caller writes the segment's own K/V first. A column past T is never
     seen (a query past T sees its window's part below T)."""
     b, s, h, d = q.shape
-    hkv, t = k.shape[1], k.shape[2]
+    hkv, t, dv = k.shape[1], k.shape[2], v.shape[-1]  # (a latent model's value: its own width)
     group = h // hkv
     block_q, block_k, n_k = segment_key_blocks(
         s, t, d, group, window, jnp.dtype(q.dtype).itemsize
@@ -475,7 +481,7 @@ def flash_segment_attention(
     out = pl.pallas_call(
         functools.partial(
             _segment_kernel, block_q=block_q, block_k=block_k, window=window,
-            n_t=n_t, scale=1.0 / (d**0.5), softcap=config.attn_logit_softcap,
+            n_t=n_t, scale=_score_scale(config, d), softcap=config.attn_logit_softcap,
             **extra,
         ),
         name="flash_segment_attention" if chosen is None else "sparse_segment_attention",
@@ -485,21 +491,21 @@ def flash_segment_attention(
             in_specs=[
                 pl.BlockSpec((1, 1, group, block_q, d), q_index),
                 pl.BlockSpec((1, 1, block_k, d), kv_index),
-                pl.BlockSpec((1, 1, block_k, d), kv_index),
+                pl.BlockSpec((1, 1, block_k, dv), kv_index),
                 *selection,
             ],
-            out_specs=pl.BlockSpec((1, 1, group, block_q, d), q_index),
+            out_specs=pl.BlockSpec((1, 1, group, block_q, dv), q_index),
             scratch_shapes=[
                 pltpu.VMEM((group, block_q, 128), jnp.float32),
                 pltpu.VMEM((group, block_q, 128), jnp.float32),
-                pltpu.VMEM((group, block_q, d), jnp.float32),
+                pltpu.VMEM((group, block_q, dv), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, dv), q.dtype),
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(offsets.astype(jnp.int32), qg, k, v, *([] if chosen is None else [chosen]))
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * d)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * dv)
 
 
 def sparse_segment_attention(
@@ -1307,28 +1313,32 @@ def ragged_paged_latent_attention(
     lengths: jax.Array,  # [B] valid logical columns per row; 0 = no work
     table: jax.Array,  # [B, Tp]
     layer: jax.Array,
-    chosen: jax.Array,  # [B, Tp x ps] bool: the columns the row's query reads
+    chosen: jax.Array | None,  # [B, Tp x ps] bool: the columns the row's query reads
     config: ModelConfig,
     page_size: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """A decode step's attention IN THE LATENT SPACE, under the row's
-    selection -> [B, H x kv_lora_rank]: `ragged_paged_selected_attention`
-    over a pool that holds ONE row a token, [normed latent | rotary key |
-    zeros], which is the key of all H heads and, its first
-    ``config.kv_lora_rank`` lanes, their value (models/transformer
-    `_latent_decode_read` absorbs the up-projection into the query before
-    and into the output after: nothing of a head's keys or values is
-    formed). A page is fetched once and serves both products; the scores'
-    scale is the expanded head's, 1 / sqrt(qk_nope_head_dim +
-    qk_rope_head_dim). The bytes follow the row's length, as the selected
-    walk's do. Under its own name on the `pallas_call`; no mesh
-    (`ServingEngine` refuses one for such a model)."""
+    """A decode step's attention IN THE LATENT SPACE -> [B, H x
+    kv_lora_rank]: the paged decode walk over a pool that holds ONE row a
+    token, [normed latent | rotary key | zeros], which is the key of all H
+    heads and, its first ``config.kv_lora_rank`` lanes, their value
+    (models/transformer `_latent_decode_read` absorbs the up-projection into
+    the query before and into the output after: nothing of a head's keys or
+    values is formed). Under the row's selection ``chosen``
+    (`ragged_paged_selected_attention`'s mask, a model with an indexer), or
+    with ``chosen`` None DENSE: every row up to the length, and no mask
+    operand rides the call at all (not an all-true one: its float32 copy is
+    among what the selected read pays). A page is fetched once and serves
+    both products; the scores' scale is ``config.attn_scale``, the expanded
+    head's 1 / sqrt(qk_nope_head_dim + qk_rope_head_dim) and YaRN's factor
+    where the model has one. The bytes follow the row's length either way.
+    Under its own name on the `pallas_call`; no mesh (`ServingEngine` refuses
+    one for such a model)."""
     return _paged_decode_call(
         "ragged_paged_latent_attention",
         functools.partial(_page_latent, value_width=config.kv_lora_rank),
         q, [latents], [], lengths, table, layer, config, page_size, interpret,
-        chosen=chosen, scale=config.resolved_head_dim**-0.5,
+        chosen=chosen, scale=config.attn_scale,
         value_width=config.kv_lora_rank,
     )
 
@@ -1740,12 +1750,26 @@ def paged_pallas_ok(config: ModelConfig, page_size: int) -> bool:
         return False
     if config.attention_impl == "pallas":
         return page_size % 8 == 0
-    return paged_tiles_ok(config.resolved_head_dim, page_size)
+    # what a page's rows are wide: a head's K, or a latent model's kept row
+    width = config.latent_key_width if config.has_latent else config.resolved_head_dim
+    return paged_tiles_ok(width, page_size)
 
 
 # ---------------------------------------------------------------------------
 # Dispatch gate
 # ---------------------------------------------------------------------------
+
+
+def _head_tiles_ok(config: ModelConfig) -> bool:
+    """``auto``'s gate on a head's width: whole 128-lane tiles. A latent
+    model's expanded key is ``[k_nope | k_rope]``, the one rotary key behind
+    the head's own part, and its value has a width of its own: the value in
+    whole tiles and the key in whole HALF tiles (192 = 128 + 64 is the
+    block's whole minor dimension, which Mosaic takes and keeps at 256 lanes
+    in VMEM; tests/test_tpu_compile.py compiles both widths for a v5e)."""
+    if config.has_latent:
+        return config.resolved_head_dim % 64 == 0 and config.v_head_dim % 128 == 0
+    return config.resolved_head_dim % 128 == 0
 
 
 def pallas_ok(config: ModelConfig, seq_len: int) -> bool:
@@ -1765,7 +1789,7 @@ def pallas_ok(config: ModelConfig, seq_len: int) -> bool:
         return seq_len == 1 or seq_len % min(128, seq_len) == 0
     if jax.default_backend() != "tpu":
         return False
-    if config.resolved_head_dim % 128 != 0:
+    if not _head_tiles_ok(config):
         return False
     if seq_len > 1 and seq_len % 128 != 0:
         return False

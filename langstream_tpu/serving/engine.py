@@ -1281,25 +1281,28 @@ class ServingEngine:
                     f"{config.name} has window layers: {', '.join(asked)} "
                     "cannot be used with two page groups"
                 )
-        if config.has_indexer:
-            # Refused at build, by the option's name (docs/SERVING.md "A
-            # model whose attention reads a learned selection"). The page
-            # pool holds a third leaf a token, the indexer's key: the host
-            # tier, migration and the durable checkpoint snapshot and
-            # restore K and V alone (`_page_snapshot`, serving/wire.py,
-            # serving/durable.py), and a page that came back without its
-            # indexer keys would be ranked by stale ones. The verify path
-            # is jnp over the whole table and knows no selection; an int8
-            # pool has no third leaf; the indexer takes no adapter terms and
-            # its gathers are not sharded. Prefix reuse is served: a cached
-            # page holds its tokens' indexer keys, which depend on nothing
-            # after them, and a copied page is whole (`_on_pages`). A model
-            # that keeps a LATENT in place of K and V (docs/SERVING.md "A
-            # model that keeps a latent, not keys and values") has an indexer
-            # and is refused the same options for the same reasons, its
-            # latent leaf "lat" standing where "k" and "v" do: no tier, wire
-            # or checkpoint format carries it, no verify or adapter term
-            # reaches its attention half, its decode kernel takes no mesh.
+        if config.has_indexer or config.has_latent:
+            # Refused at build, by the option's name. Two reasons, each its
+            # own, and a model may have both.
+            # An INDEXER (docs/SERVING.md "A model whose attention reads a
+            # learned selection"): the page pool holds one more leaf a token,
+            # the indexer's key: the host tier, migration and the durable
+            # checkpoint snapshot and restore K and V alone (`_page_snapshot`,
+            # serving/wire.py, serving/durable.py), and a page that came back
+            # without its indexer keys would be ranked by stale ones. The
+            # verify path is jnp over the whole table and knows no selection;
+            # an int8 pool has no third leaf; the indexer takes no adapter
+            # terms and its gathers are not sharded.
+            # A LATENT in place of K and V (docs/SERVING.md "A model that
+            # keeps a latent, not keys and values"), with an indexer or
+            # without one: no tier, wire or checkpoint format carries the
+            # leaf "lat" that stands where "k" and "v" do; the verify path is
+            # jnp over K and V and no verify or adapter term reaches the
+            # latent's attention half; an int8 pool is K's and V's; the
+            # latent decode kernel takes no mesh.
+            # Prefix reuse is served by both: a cached page holds its tokens'
+            # latents (and indexer keys), which depend on nothing after them,
+            # and a copied page is whole (`_on_pages`).
             on = lambda v: v is True or str(v).lower() in ("auto", "on", "true", "1")  # noqa: E731
             refused = {
                 "host_kv_fraction": float(host_kv_fraction) > 0,
@@ -1312,14 +1315,18 @@ class ServingEngine:
                 "spmd": spmd is not None,
             }
             asked = [name for name, is_on in refused.items() if is_on]
-            if asked:
+            if asked and not config.has_latent:
                 raise ValueError(
-                    f"{config.name} keeps a latent under a learned selection: "
-                    f"{', '.join(asked)} cannot be used with a latent and an "
-                    "indexer's keys in the page pool"
-                    if config.has_latent else
                     f"{config.name} reads a learned selection: {', '.join(asked)} "
                     "cannot be used with an indexer's keys in the page pool"
+                )
+            if asked:
+                raise ValueError(
+                    f"{config.name} keeps a latent"
+                    + (" under a learned selection" if config.has_indexer else "")
+                    + f": {', '.join(asked)} cannot be used with a latent"
+                    + (" and an indexer's keys" if config.has_indexer else "")
+                    + " in the page pool"
                 )
         if config.fills_blocks:
             # Refused at build, by the option's name (docs/SERVING.md "A
@@ -1614,6 +1621,9 @@ class ServingEngine:
         self.index_tokens_scored_total = 0
         self.kv_tokens_selected_total = 0
         self.latent_tokens_expanded_total = 0
+        # a latent model with NO indexer: the cached latents its decode chunks
+        # and segments read a layer (every one a query sees: `kv_tokens_read`)
+        self.kv_tokens_read_total = 0
         self.latent_columns_expanded_total = 0
         # slots freed since the last dispatch: their device temp must be
         # zeroed, else sample()'s batch-wide any_sample/any_filter predicates
@@ -2678,6 +2688,12 @@ class ServingEngine:
                     "latent-columns-expanded-total": self.latent_columns_expanded_total,
                 }
                 if self.config.has_latent else {}
+            ),
+            # ... and has no indexer: what its decode chunks and segments READ
+            # a layer, which is every cached latent a query sees
+            **(
+                {"kv-tokens-read-total": self.kv_tokens_read_total}
+                if self.config.has_latent and not self.config.has_indexer else {}
             ),
             # a model with window layers: its second page group's use
             **(
@@ -6317,6 +6333,11 @@ class ServingEngine:
                 + (" and its latent" if self.config.has_latent else "")
                 + " have no wire format yet"
             )
+        if self.config.has_latent:
+            raise MigrationError(
+                "KV-page migration carries K and V only: a page's latent has "
+                "no wire format yet"
+            )
         reply: "queue.SimpleQueue" = queue.SimpleQueue()
         self._migrate_cmds.put((kind, payload, reply))
         self._wake.set()
@@ -6703,24 +6724,32 @@ class ServingEngine:
                     offset=s0, kv_tokens_read=selected, index_tokens_scored=scored,
                     kv_tokens_selected=selected,
                 )
-            if self.config.has_latent:
-                # the cached columns (earlier segments') whose latents this
-                # segment re-expanded into keys and values, a layer: every one
-                # behind it; and the columns of its table the program expanded
-                # in all, by the program's own rule: those and the segment's
-                # own, in whole blocks (the table's whole width where the
-                # read is not the walk over key blocks)
-                pool = self._pagepool
-                columns = latent_columns_expanded(
-                    s0, width, pool.table_len * pool.page_size, self.config
-                )
+        if self.config.has_latent:
+            if not self.config.has_indexer:
+                # the DENSE read: every query reads every column up to its
+                # own, a layer (a window model's span counts the same sum)
+                read = int((s0 + 1 + np.arange(len(seg), dtype=np.int64)).sum())
                 with self._stats_lock:
-                    self.latent_tokens_expanded_total += s0
-                    self.latent_columns_expanded_total += columns
+                    self.kv_tokens_read_total += read
                 if disp is not None:
-                    disp.attrs.update(
-                        latent_tokens_expanded=s0, latent_columns_expanded=columns
-                    )
+                    disp.attrs.update(offset=s0, kv_tokens_read=read)
+            # the cached columns (earlier segments') whose latents this
+            # segment re-expanded into keys and values, a layer: every one
+            # behind it; and the columns of its table the program expanded
+            # in all, by the program's own rule: those and the segment's
+            # own, in whole blocks (the table's whole width where the
+            # read is not the walk over key blocks)
+            pool = self._pagepool
+            columns = latent_columns_expanded(
+                s0, width, pool.table_len * pool.page_size, self.config
+            )
+            with self._stats_lock:
+                self.latent_tokens_expanded_total += s0
+                self.latent_columns_expanded_total += columns
+            if disp is not None:
+                disp.attrs.update(
+                    latent_tokens_expanded=s0, latent_columns_expanded=columns
+                )
         if not final:
             if per_segment:  # nothing to deliver: the fetch lands the span
                 return [(
@@ -6867,7 +6896,13 @@ class ServingEngine:
         model's ``kv_tokens_read``) and what it READS, which is what
         ``kv_tokens_read`` then says too."""
         if not self.config.has_indexer:
-            return {}
+            if not self.config.has_latent:
+                return {}
+            # the dense latent read: `kv_tokens_read` (there for every model)
+            # is what this model reads; summed into `stats()` here
+            with self._stats_lock:
+                self.kv_tokens_read_total += self._kv_tokens_read(live, steps)
+            return {"latent_tokens_expanded": 0}
         first = np.asarray([slot.position + slot.ahead + 1 for slot in live], np.int64)
         scored, selected = self._index_counts(first[:, None] + np.arange(steps)[None, :])
         return {

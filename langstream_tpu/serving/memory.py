@@ -46,7 +46,9 @@ class ServingMemoryPlan:
     # pages (a third leaf, [L, P, page_size, index_key_width]): this term
     # counts it, since it is `make_page_pool`'s whole tree; a model that
     # keeps a latent in place of K and V has one row a token there
-    # ([L, P, 1, page_size, latent_key_width]) and is counted the same way.
+    # ([L, P, 1, page_size, latent_key_width]) and is counted the same way:
+    # a token is priced at ``latent_key_width x 2 B`` a layer, plus the
+    # indexer's key only where the model has an indexer (a one-leaf pool).
     # Sized by pages_for_fraction: every slot's max_seq_len plus the
     # prefix-cache-fraction alias headroom.
     page_pool_bytes: int = 0

@@ -312,7 +312,7 @@ class PagePool:
         # (``bytes_per_page`` counts it), so nothing here tells it apart; a
         # model that keeps a latent has ``dev["lat"]`` [L, P, 1, ps,
         # latent_key_width] where the others have "k" and "v": pages all the
-        # same, under the same table
+        # same, under the same table (with no indexer it is the pool's ONE leaf)
         # a model with window layers: their pages are a group of their own
         # (``dev["win"]``, `WindowPageGroup`), reserved with the full
         # group's in `reserve` and freed with them in `free_slot`

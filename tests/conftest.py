@@ -65,6 +65,36 @@ def pytest_sessionfinish(session, exitstatus):
         session.exitstatus = 1
 
 
+def _memory_maps() -> tuple[int, int]:
+    """(this process's memory mappings, the kernel's limit a process)."""
+    try:
+        with open("/proc/self/maps") as maps:
+            held = sum(1 for _ in maps)
+        with open("/proc/sys/vm/max_map_count") as limit:
+            return held, int(limit.read())
+    except (OSError, ValueError):  # no procfs: nothing to watch
+        return 0, 1
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _release_compiled_programs():
+    """Every compiled program a worker keeps in JAX's caches holds a few
+    memory mappings (its JIT code), a worker runs many test files, and the
+    kernel caps a process's mappings (`vm.max_map_count`, 65,530 by default):
+    past it `mmap` fails inside XLA's compile and the worker dies with a
+    segmentation fault or an abort, in whatever test compiles next (seen at
+    some 1,770 tests, two whole runs out of two; `jax.clear_caches()` gives
+    the mappings back). After a test file, a worker that holds over a third
+    of the limit drops its caches: the next file compiles what it needs."""
+    yield
+    held, limit = _memory_maps()
+    if 3 * held > limit:
+        import gc
+
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture(autouse=True)
 def _restore_compile_cache():
     """Building an engine through tpu-serving turns JAX's persistent compile

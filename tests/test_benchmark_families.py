@@ -19,7 +19,14 @@ one namespace) and of
 `benchmark/tests/test_glm_moe_dsa_family.py` (GLM-5: the contract, the
 catalog's keys and the stated cut of four, the cell's sizing, the refusals,
 the seeded tree against the program's own, the chain's halves by kind, the
-costs and the metric files; its names all say `glm`) and the cases of
+costs and the metric files; its names all say `glm`) and of
+`benchmark/tests/test_kimi_k2_family.py` (Kimi-K2.5: the contract, the
+catalog's keys with `rope_scaling` whole and the stated cut of three, the
+cell's sizing and its metrics, the refusals, the seeded tree against the
+program's own, the chain's halves; its names all say `kimi`) with
+`benchmark/tests/test_latent_dense_attention_cost.py` (the dense latent
+read's two cost functions and the new metric files, against hand counts) and
+the cases of
 `benchmark/tests/test_request_readers.py` (the clock between a profile and the
 spans, a first token's stages, the device's idle by what the engine held; one
 of them records a profile of a small engine) run here as they stand
@@ -56,4 +63,6 @@ globals().update(_cases("test_cohere2_moe_family"))
 globals().update(_cases("test_sdar_moe_family"))
 globals().update(_cases("test_keye_vl2_family"))
 globals().update(_cases("test_glm_moe_dsa_family"))
+globals().update(_cases("test_kimi_k2_family"))
+globals().update(_cases("test_latent_dense_attention_cost"))
 globals().update(_cases("test_request_readers"))

@@ -786,6 +786,29 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
         texts["dense"] | texts["moe"] | texts["hybrid"] | texts["window"] | texts["blocks"]
         | texts["sparse"]
     )
+    # a latent with NO indexer: the dense read under its own scope, in the
+    # decode chunk and in a segment, and nothing of a selection anywhere
+    dense_latent = MODEL_PRESETS["tiny-latent-dense-moe-test"]
+
+    def dense_latent_programs(params, pool):
+        chunk = E._paged_decode_chunk(
+            params, tokens[:, 0], lengths, pool, table, key, ones, zeros, ones, 2, dense_latent,
+            page,
+        )
+        return chunk, E._paged_segment_and_sample(
+            params, tokens[:1, :16], lengths[:1], lengths[:1], chunk[3], table[:1], key,
+            ones[:1], zeros[:1], ones[:1], dense_latent, page,
+        )
+
+    others = set().union(*texts.values())
+    texts["dense-latent"] = lowered_scopes(
+        dense_latent_programs, T.init_params(dense_latent, jax.random.PRNGKey(7)),
+        T.make_page_pool(dense_latent, 8, page),
+    )
+    assert kept | {"attention.latent.read", "attention", "kv_pool.write", "ffn", "moe_ffn",
+                   "moe_ffn.shared", "head"} <= texts["dense-latent"]
+    assert not selection & texts["dense-latent"]
+    assert "attention.latent.read" not in others
     assert set(T.SCOPES) <= set().union(*texts.values())
     assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
     assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",

@@ -61,10 +61,14 @@ def _as_on_the_chip():
 
 
 def _prefill_args(config, s):
-    """(q, k, v) shapes of a prefill call."""
+    """(q, k, v) shapes of a prefill call (a latent model's value has a width
+    of its own)."""
     h, hkv, d = config.n_heads, config.n_kv_heads, config.resolved_head_dim
-    kv = SDS((1, hkv, s, d), jnp.bfloat16)
-    return SDS((1, s, h, d), jnp.bfloat16), kv, kv
+    dv = config.v_head_dim if config.has_latent else d
+    return (
+        SDS((1, s, h, d), jnp.bfloat16), SDS((1, hkv, s, d), jnp.bfloat16),
+        SDS((1, hkv, s, dv), jnp.bfloat16),
+    )
 
 
 def _paged_args(config, int8, batch=BATCH, table=TABLE, pages=PAGES, layers=POOL_LAYERS):
@@ -230,18 +234,46 @@ GLM = dataclasses.replace(
 )
 
 
+# Kimi-K2.5's language model as the benchmark cuts it
+# (`tiny-latent-dense-moe-test`'s block at the published widths): a query
+# latent of 1,536, a key-value latent of 512 and a rotary key of 64 for 64
+# heads whose q.k is 128 + 64 = 192 wide and whose value 128, NO indexer, YaRN
+# (factor 64 over 4,096), one leading dense layer of 18,432 and six expert
+# layers that hold 12 of 384 experts of 7168 x 2048 and a shared one, a slice
+# of 20,480 rows of the vocabulary; the cell: 16 slots x 272 pages, a token of
+# the pool ONE row of 640 lanes
+KIMI = dataclasses.replace(
+    MODEL_PRESETS["tiny-latent-dense-moe-test"], name="kimi-widths", d_model=7168, d_ff=18432,
+    moe_d_ff=2048, n_layers=7, n_heads=64, n_kv_heads=64, n_experts=384, n_experts_per_tok=8,
+    experts_held=(0, 12), vocab_size=20480, q_lora_rank=1536, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, routed_scaling=2.827,
+    rope_theta=50000.0, rope_scaling_factor=64.0, rope_scaling_original_max_seq_len=4096,
+    rope_scaling_beta_fast=32.0, max_seq_len=262144,
+)
+
+
 def _latent_decode(config, batch, table, pages, layers):
     """A decode step's attention in the latent space: absorbed queries
-    against ONE leaf of rows, a page fetched once for key and value."""
+    against ONE leaf of rows, a page fetched once for key and value; under a
+    row's selection, or (a model with no indexer) with no mask operand."""
     width = config.latent_key_width
+    shapes = (
+        SDS((batch, config.n_heads, width), jnp.bfloat16),
+        SDS((layers, pages, 1, PAGE, width), jnp.bfloat16), SDS((batch,), jnp.int32),
+        SDS((batch, table), jnp.int32), SDS((), jnp.int32),
+    )
+    if not config.has_indexer:
+        return (
+            lambda q, rows, lengths, tab, layer: A.ragged_paged_latent_attention(
+                q, rows, lengths, tab, layer, None, config, PAGE
+            ),
+            shapes,
+        )
     return (
         lambda q, rows, lengths, tab, layer, chosen: A.ragged_paged_latent_attention(
             q, rows, lengths, tab, layer, chosen, config, PAGE
         ),
-        (SDS((batch, config.n_heads, width), jnp.bfloat16),
-         SDS((layers, pages, 1, PAGE, width), jnp.bfloat16), SDS((batch,), jnp.int32),
-         SDS((batch, table), jnp.int32), SDS((), jnp.int32),
-         SDS((batch, table * PAGE), jnp.bool_)),
+        (*shapes, SDS((batch, table * PAGE), jnp.bool_)),
     )
 
 
@@ -340,12 +372,13 @@ def _segment(config, s, t, window):
     """A prefill segment's attention over its row's gathered columns."""
     bf16 = lambda *shape: SDS(shape, jnp.bfloat16)  # noqa: E731
     hd = config.resolved_head_dim
+    dv = config.v_head_dim if config.has_latent else hd  # a latent model's value: its own width
     return (
         lambda q, k, v, offsets: A.flash_segment_attention(
             q, k, v, offsets, config, window=window
         ),
         (bf16(1, s, config.n_heads, hd), bf16(1, config.n_kv_heads, t, hd),
-         bf16(1, config.n_kv_heads, t, hd), SDS((1,), jnp.int32)),
+         bf16(1, config.n_kv_heads, t, dv), SDS((1,), jnp.int32)),
     )
 
 
@@ -444,6 +477,17 @@ CASES = {
     # at its key block of 128
     "glm1x2048-latent-expand": _latent_expand(GLM, 2048, 17408),
     "glm1x6528-latent-expand": _latent_expand(GLM, 6528, 6528),
+    # the Kimi-K2.5 cell: 16 slots x 272 pages of a 4,352-page pool of latents
+    # walked with NO mask operand, a 2,048-token segment against 17,408
+    # columns at 64 expanded heads whose keys are 192 wide and whose values
+    # 128 (no lane of a value padded to the key's width), the expansion to
+    # those two widths, and the admit group's and the check's causal prefill
+    # (2,048; 6,528 = 51 x 128 from offset 0)
+    "kimi16x272-latent-decode": _latent_decode(KIMI, 16, 272, 4352, 7),
+    "kimi-segment-2048": _segment(KIMI, 2048, 17408, 0),
+    "kimi1x2048-latent-expand": _latent_expand(KIMI, 2048, 17408),
+    "kimi1x6528-latent-expand": _latent_expand(KIMI, 6528, 6528),
+    **{f"kimi-prefill-{s}": _prefill(KIMI, s) for s in (2048, 6528)},
     # the shapes the compiler refused before _vmem_block_q counted the K/V
     # buffers and the score tiles (gemma-2b: G=8, D=256)
     **{f"gemma-prefill-{s}": _prefill(GEMMA, s) for s in (512, 1024, 2048)},
@@ -1118,6 +1162,116 @@ def test_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatc
         assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text), leaf.shape
 
 
+@pytest.mark.parametrize("program", ["_paged_decode_chunk", "_paged_segment_and_sample"])
+def test_dense_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatch, program):
+    """The Kimi-K2.5 cell's two device programs whole, at its sizes (16 slots
+    x 272 pages of a 4,352-page pool whose ONE leaf is the latent, 640 lanes a
+    token; a decode chunk, and a 2,048-token segment against 17,408 columns),
+    int8 weights and the pool donated. NOTHING of a selection is in either:
+    no indexer's scope, no mask's float32 copy of a row's table, none of the
+    selection's kernels. The decode step attends in the latent space over
+    every cached row: no operand or temporary of a row's expanded cache and no
+    gather of its latents. The segment expands its row's latents to keys 192
+    wide and values 128, head-major, in one kernel (nothing of 256 lanes: no
+    value padded to the key's width), and reads them through the causal
+    segment kernel; each fits the chip beside its state, and the pool's leaf
+    is not relaid."""
+    from langstream_tpu.models.quant import init_random_quantized_params
+    from langstream_tpu.models.transformer import make_page_pool
+    from langstream_tpu.serving import engine as E
+
+    slots, pages, table, seg = 16, 4352, 272, 2048
+    t = table * PAGE
+    key = SDS((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: init_random_quantized_params(KIMI, k), key)
+    assert set(params) == {"embed", "layers", "dense_layers", "final_norm", "lm_head"}
+    assert params["dense_layers"]["w_gate"]["q"].shape == (1, 7168, 18432)
+    assert params["layers"]["w_gate"]["q"].shape == (6, 12, 7168, 2048)
+    assert params["layers"]["wq_b"]["q"].shape == (6, 1536, 64 * 192)
+    assert params["layers"]["wkv_b"]["q"].shape == (6, 512, 64 * 256)
+    assert params["layers"]["wo"]["q"].shape == (6, 64 * 128, 7168)
+    assert params["layers"]["router"].shape == (6, 7168, 384)
+    assert "wq_idx" not in params["layers"]
+    pool = jax.eval_shape(lambda: make_page_pool(KIMI, pages, PAGE))
+    assert {k: v.shape for k, v in pool.items()} == {"lat": (7, pages, 1, PAGE, 640)}
+    assert KIMI.yarn_blend == (8, 20) and abs(KIMI.attn_scale - 0.144680) < 1e-6
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    if program == "_paged_decode_chunk":
+        args = (params, i32(slots), i32(slots), pool, i32(slots, table), key,
+                f32(slots), i32(slots), f32(slots))
+        static = (8, KIMI, PAGE)
+        kernels = ("ragged_paged_latent_attention", "moe_grouped_matmul")
+        path = f"paged-decode-latent[s=1,t={t}]"
+    else:
+        args = (params, i32(1, seg), i32(1), i32(1), pool, i32(1, table), key,
+                f32(1), i32(1), f32(1))
+        static = (KIMI, PAGE)
+        kernels = (
+            "flash_segment_attention", "moe_grouped_matmul", "paged_insert_pages",
+            "latent_expand_blocks",
+        )
+        path = f"paged-segment-latent[s={seg},t={t}]"
+    compiled = _compile_as_on_chip(
+        monkeypatch, getattr(E, program), _placed(args, SingleDeviceSharding(v5e[0])), static
+    )
+    text = compiled.as_text()
+    paths = A.attention_paths()
+    assert path in paths
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    # nothing of a selection: its scopes, its kernels, a row's mask
+    for scope in ("attention.sparse", "attention.select", "attention.index"):
+        assert f"/{scope}/" not in text and f"/{scope}\"" not in text, scope
+    for kernel in ("segment_select", "sparse_segment_attention", "index_scores",
+                   "ragged_paged_selected_attention", "ragged_paged_decode_attention"):
+        assert not re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    assert "/attention.latent.read/" in text  # where the new per-layer metrics look
+    h, width = KIMI.n_heads, KIMI.latent_key_width
+    has = lambda shape: "[" + ",".join(map(str, shape)) + "]" in text  # noqa: E731
+    assert not has([slots, table, 1, PAGE]) and not has([slots, t])  # no mask over a row's table
+    if program == "_paged_decode_chunk":
+        assert paths[path] == "ragged_paged_latent_attention"
+        for d in (256, 192, 128):  # a row's expanded keys or values, either order
+            for shape in ([slots, h, t, d], [slots, t, h, d], [slots, t, h * d], [h, t, d]):
+                assert not has(shape), shape
+        # nor its latents gathered: the kernel reads the pages where they lie
+        for shape in ([slots, 1, t, width], [slots, t, width], [slots, table, 1, PAGE, width]):
+            assert not has(shape), shape
+        calls = re.findall(r"%ragged_paged_latent_attention(?:\.\d+)? = .*", text)
+        assert calls and all("/attention.latent.read/" in call for call in calls), calls[:1]
+    else:
+        assert paths[path] == "flash_segment_attention"
+        assert paths[f"paged-segment-write[s={seg}]"] == "paged_insert_pages"
+        assert paths[f"paged-segment-latent-expand[s={seg},t={t}]"] == "latent_expand_blocks"
+        # the expanded keys and values of the row's columns, each at its own width
+        assert has([1, h, t, 192]) and has([1, h, t, 128])
+        for d in (256, 320):  # no value padded to the key's width, no [k | v] formed whole
+            for shape in ([1, h, t, d], [1, t, h, d], [t, h, d]):
+                assert not has(shape), shape
+        for name, d in (("keys", 192), ("values", 128)):
+            made = re.findall(rf"= {re.escape(f'bf16[1,{h},{t},{d}]')}\S* ([\w-]+)\(", text)
+            assert made and set(made) <= {"custom-call", "get-tuple-element", "parameter"}, (
+                name, set(made))
+        calls = re.findall(r"%latent_expand_blocks(?:\.\d+)? = .*", text)
+        assert calls and all("/attention.latent.expand/" in call for call in calls), calls[:1]
+        calls = re.findall(r"%flash_segment_attention(?:\.\d+)? = .*", text)
+        assert calls and all("/attention.latent.read/" in call for call in calls), calls[:1]
+        for shape in ([1, seg, h, t], [1, h, seg, t], [seg, h, t], [h, seg, t]):
+            assert not has(shape), shape  # the scores are never held
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
+    assert pool_bytes == pages * PAGE * KIMI.kv_bytes_per_token() == pages * PAGE * 8960
+    assert memory.alias_size_in_bytes >= pool_bytes  # the pool, updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    print(program, "temp", memory.temp_size_in_bytes, "args", memory.argument_size_in_bytes)
+    assert held <= V5E_HBM_BYTES
+    dims = re.escape("[" + ",".join(map(str, pool["lat"].shape)) + "]")
+    assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text)
+
+
 def test_window_segment_program_compiles_for_v5e_beside_the_cell_s_state(v5e, monkeypatch):
     """The command-a-plus cell's segment program whole, at its sizes (a
     2,048-token segment of one row against 196 pages a table, the full
@@ -1392,8 +1546,18 @@ LATENT_PROGRAMS_AT_PR47 = {
     "admit/tiny-latent-moe-test": "7ccaa668ff6f12fc",
 }
 
+# The latent model with NO indexer (`tiny-latent-dense-moe-test`, PR 50): its
+# three engine programs as that PR left them, kernels forced; the segment row
+# under the scatter like the others (its page-writing form is
+# `SEGMENT_PROGRAMS_AT_PR48`'s last row).
+LATENT_DENSE_PROGRAMS_AT_PR50 = {
+    "tiny-latent-dense-moe-test": "f5ac304cf5c728e3",
+    "admit/tiny-latent-dense-moe-test": "bdec01f32b991102",
+}
+
 ENGINE_PROGRAMS = {
     **DECODE_PROGRAMS_AT_PARENT, **ENGINE_PROGRAMS_AT_PARENT, **LATENT_PROGRAMS_AT_PR47,
+    **LATENT_DENSE_PROGRAMS_AT_PR50,
 }
 
 # PR 48 changes the SEGMENT programs and no other, on purpose: a causal
@@ -1413,6 +1577,8 @@ SEGMENT_PROGRAMS_AT_PR48 = {
     # (PR 49's, re-taken on purpose with `LATENT_PROGRAMS_AT_PR47`'s row: the
     # bounded expansion; "33335341cfab4025" at PR 48)
     "segment/tiny-latent-moe-test": "21c00b25ad4d8841",
+    # (PR 50's own: the latent model with no indexer, as that PR left it)
+    "segment/tiny-latent-dense-moe-test": "887bf6290c0f3b0d",
 }
 
 
@@ -1490,6 +1656,20 @@ PATHS_AT_PARENT = {
         "prefill-sparse[s=16,t=16]": "sparse_segment_attention",
         "segment-select[s=16,t=16]": "block_q 16, block_k 16, to the diagonal",
         "segment-select[s=16,t=48]": "block_q 16, block_k 48, to the diagonal",
+    },
+    # (PR 50's rows: a latent with no indexer notes nothing of a selection)
+    "tiny-latent-dense-moe-test/auto": {
+        "paged-decode-latent[s=1,t=48]": "jnp",
+        "paged-segment-latent[s=16,t=48]": "jnp",
+        "paged-segment-write[s=16]": "scatter",
+        "prefill[s=16,t=16]": "jnp",
+    },
+    "tiny-latent-dense-moe-test/pallas": {
+        "paged-decode-latent[s=1,t=48]": "ragged_paged_latent_attention",
+        "paged-segment-latent-expand[s=16,t=48]": "latent_expand_blocks",
+        "paged-segment-latent[s=16,t=48]": "flash_segment_attention",
+        "paged-segment-write[s=16]": "paged_insert_pages",
+        "prefill[s=16,t=16]": "flash_prefill_attention",
     },
     "tiny-moe-test/auto": {
         "paged-decode[s=1,t=48]": "jnp",
