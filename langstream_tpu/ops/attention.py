@@ -46,6 +46,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -907,10 +908,27 @@ def latent_expand_blocks(
 # rows only, the pool's values stay in HBM, and a row walks its own
 # `cdiv(length, page_size)` pages in a loop inside the kernel. The fetches
 # (page table[b, j], every kv head of it, one fat block, by async copy into
-# `_PAGE_SLOTS` VMEM slots) run ahead of the arithmetic along the batch's
+# VMEM slots) run ahead of the arithmetic along the batch's
 # live pages taken as ONE sequence: over a row's end into the next row
 # that holds anything, so a row's first page is there when its grid step
-# starts. A row of length 0 (the caller gives one to every row whose table
+# starts. Where a page is SMALL a loop step takes a GROUP of the row's pages
+# (PR 52; `_walk_shape`: 8 at the long-document cells' 80 KB and 128 KB pages,
+# 4 under tables of 10 or 11): their scores come from ONE product
+# [G, n x 64] and the pages are folded into the running softmax one after the
+# other in the row's order (`_fold_pages`), so every sum is the one a walk of
+# single pages makes and the output is that walk's to the bit. What a small
+# page cost before was its loop step, not its bytes: the fetch's scalar
+# `while_loop` and `pl.when` cut every page's serial chain (product, two lane
+# reductions, two exponentials, product, rescale) into basic blocks of its
+# own; a latent page of 80 KB read 0.47 us alone on a v5e where its bytes
+# take 0.10, and reads 0.18 at 8 a step (K and V pages of 128 KB under a
+# selection 0.34 -> 0.20, their bytes 0.16; my chip runs, PR 52, PERF.md
+# section 6). Only pages that a row's bounds cannot cut ride a group: a
+# window row's first page and a row's last one to n pages go one a step
+# under the masks of the length and the lower bound, and the groups between
+# carry none of that arithmetic (a selection's mask rides every step). A pool
+# whose page is 256 KB or more walks a page a step as it did, in the module
+# it had. A row of length 0 (the caller gives one to every row whose table
 # maps nothing: inactive, padding, warm-up; models/transformer
 # `_paged_lengths`) costs its grid step, some 0.3 us, and
 # returns zeros. Before PR 28 the grid was (B, table_len): 0.20 us for
@@ -948,26 +966,38 @@ def latent_expand_blocks(
 # ---------------------------------------------------------------------------
 
 
-def _page_bf16(q, page, scales, j, scale):
-    """Scores of one page and its values: ``page`` is the (k, v) pair of
-    VMEM blocks [Hkv, ps, D] an iteration waited for."""
+def _page_bf16(q, page, scales, j, n, scale):
+    """Scores of a loop step's ``n`` pages, ``j`` the first, and their
+    values: ``page`` is the (k, v) pair of VMEM blocks [Hkv, n x ps, D] the
+    step waited for."""
     k, v = (leaf.astype(jnp.float32) for leaf in page)
     s = jax.lax.dot_general(
         q, k, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
-    ) * scale  # [Hkv, G, ps]
+    ) * scale  # [Hkv, G, n x ps]
     return s, None, v
 
 
-def _page_int8(q, page, scales, j, scale):
+def _row_block_pages(ref, j, n):
+    """Pages ``j .. j + n - 1`` of a row block [1, Tp, X, ps] whose tokens
+    lie on lanes (the int8 pool's scales, a selection's mask), side by side
+    as the step's scores lie: [X, n x ps]."""
+    if n == 1:
+        return ref[0, j]
+    return jnp.concatenate([ref[0, j + i] for i in range(n)], axis=-1)
+
+
+def _page_int8(q, page, scales, j, n, scale):
     """`_page_bf16` over the int8 pool: a page is (kq, vq), read raw int8
     from HBM, with the row's per-token f32 scales (ks, vs) [1, Tp, Hkv, ps]
-    beside it, page ``j`` of them. The scales ride the [.., ps]-shaped
-    scores and probabilities (tokens on lanes, as the pool stores them),
-    not the [.., ps, D] operands: the same product, D times less scale
-    math."""
+    beside it, pages ``j .. j + n - 1`` of them. The scales ride the
+    [.., n x ps]-shaped scores and, a page at a time, the probabilities
+    (tokens on lanes, as the pool stores them), not the [.., n x ps, D]
+    operands: the same product, D times less scale math."""
     kq, vq = page
-    ks, vs = (ref[0, j][:, None, :] for ref in scales)  # [Hkv, 1, ps]
+    ks, vs = scales
+    ks = _row_block_pages(ks, j, n)[:, None, :]  # [Hkv, 1, n x ps]
+    vs = [vs[0, j + i][:, None, :] for i in range(n)]  # [Hkv, 1, ps] a page
     s = jax.lax.dot_general(
         q, kq.astype(jnp.float32),
         dimension_numbers=(((2,), (2,)), ((0,), (0,))),
@@ -976,9 +1006,9 @@ def _page_int8(q, page, scales, j, scale):
     return s, vs, vq.astype(jnp.float32)
 
 
-def _page_latent(q, page, scales, j, scale, value_width):
+def _page_latent(q, page, scales, j, n, scale, value_width):
     """`_page_bf16` over a pool of LATENTS: a page is ONE VMEM block
-    [1, ps, W], a token's row its key for every head and, its first
+    [1, n x ps, W], a token's row its key for every head and, its first
     ``value_width`` lanes, its value: read once, used twice. The products
     take the page as it lies (bf16 in, float32 out): 64 query heads against
     one key head put the kernel near the chip's ridge, where a float32
@@ -987,29 +1017,95 @@ def _page_latent(q, page, scales, j, scale, value_width):
     s = jax.lax.dot_general(
         q.astype(rows.dtype), rows, dimension_numbers=(((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
-    ) * scale  # [1, H, ps]
+    ) * scale  # [1, H, n x ps]
     return s, None, rows[..., :value_width]
 
 
-# Pages of K and V held in VMEM at once: the one computed on and the next
-# three live pages of the batch in flight behind it (1 MB of bf16 at 8 kv
-# heads x 64 x 128). One page in flight left the copy's latency in the
-# open: 196 us a call at two slots, 146 at three, 140 at four (decode
-# drain's shape: 64 rows, 330 live pages; my chip runs, PR 28).
+# Pages of K and V held in VMEM at once by the walk of ONE page a loop step:
+# the one computed on and the next three live pages of the batch in flight
+# behind it (1 MB of bf16 at 8 kv heads x 64 x 128). One page in flight left
+# the copy's latency in the open: 196 us a call at two slots, 146 at three,
+# 140 at four (decode drain's shape: 64 rows, 330 live pages; my chip runs,
+# PR 28).
 _PAGE_SLOTS = 4
+# A loop step of the walk has a fixed price, some 0.32 us on a v5e whatever
+# the page holds (the fetch's scalar loop and branch cut each page's serial
+# chain of product, reductions, exponentials, product and rescale into basic
+# blocks of its own; PR 51's chip runs, PERF.md section 6): the time the
+# chip's 819 GB/s take over 256 KB. Under that a page's STEP sets the walk's
+# time and a step takes a group of pages; from there on its COPY does, the
+# step hides behind it and the walk is the one-page walk it was. (Groups at
+# 256 KB pages and above read 3-10% faster on the walk alone, which moved no
+# end-to-end metric of four cells in PR 51 and, at the size PR 51 traced them,
+# cost their set-up 5-14 s: a later PR's, with ROADMAP S14 (4), what a kernel
+# instance costs to lower; PERF.md section 6, PR 52, has both readings.)
+_WALK_PAGE_BYTES = 256 * 1024
+# Pages a loop step takes at most: at 8 the latent walk reads within a fifth
+# of its bytes' time and K and V pages of 128 KB are on it (PERF.md section
+# 6, PR 51).
+_WALK_GROUP = 8
+
+
+def _walk_slots(group: int) -> int:
+    """Page slots for loop steps of ``group`` pages: the step computed on and
+    as many pages in flight, three at least; the one-page walk's four."""
+    return group + max(group, 3) if group > 1 else _PAGE_SLOTS
+
+
+def _walk_shape(page_bytes: int, table_len: int) -> tuple[int, int]:
+    """(pages a loop step of the walk takes inside a row, page slots held in
+    VMEM) for a pool whose page (every leaf of it) is ``page_bytes`` under
+    tables of ``table_len`` pages: `_WALK_GROUP` pages where a page is under
+    `_WALK_PAGE_BYTES`, halved while a row that fills its table would not
+    hold two steps (16 slots of under 256 KB: 4 MiB at most of the 16 the
+    chip's compiler grants a kernel). Else 1: the one-page walk, whose Mosaic
+    module is PR 50's byte for byte."""
+    group = _WALK_GROUP if page_bytes < _WALK_PAGE_BYTES else 1
+    while group > 1 and 2 * group > table_len:
+        group //= 2
+    return group, _walk_slots(group)
+
+
+def _fold_pages(carry, s, p_scale, v, masked: bool, page_size: int):
+    """A loop step's scores ``s`` [Hkv, G, n x ps] and values ``v``
+    [Hkv, n x ps, D] folded into the running softmax ``carry`` (m, l
+    [Hkv, G, 1], acc [Hkv, G, D]) a PAGE at a time, in the row's order: every
+    sum is the one a walk of single pages makes, to the bit. ``p_scale``: the
+    int8 pool's value scales [Hkv, 1, ps], a page each, or None; ``masked``:
+    some score may be `_NEG`."""
+    n = s.shape[-1] // page_size
+    for i in range(n):
+        at = slice(i * page_size, (i + 1) * page_size)
+        m_prev, l_prev, acc = carry  # [Hkv, G, 1] twice, [Hkv, G, D]
+        s_i, v_i = (s, v) if n == 1 else (s[..., at], v[:, at])
+        m_new = jnp.maximum(m_prev, s_i.max(axis=-1, keepdims=True))
+        p = jnp.exp(s_i - m_new)
+        if masked:  # a row of nothing but masked columns: exp(0) each
+            p = jnp.where(s_i <= _NEG, 0.0, p)
+        corr = jnp.exp(m_prev - m_new)
+        pv = jax.lax.dot_general(
+            # (a float32 page, every loader's but the latent's: no cast)
+            (p if p_scale is None else p * p_scale[i]).astype(v_i.dtype),
+            v_i,
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [Hkv, G, D]
+        carry = m_new, l_prev * corr + p.sum(axis=-1, keepdims=True), acc * corr + pv
+    return carry
 
 
 def _paged_decode_kernel(
     lengths_ref,  # scalar-prefetch [B]
     *refs,  # [lower_ref], pages_ref, q_ref, n_scales row blocks, [chosen_ref],
     # n_leaves pool leaves in HBM, o_ref, scratch
-    load,  # _page_bf16 | _page_int8
+    load,  # _page_bf16 | _page_int8 | _page_latent
     n_scales: int,
     n_leaves: int,
     page_size: int,
     table_len: int,
     scale: float,
     softcap,
+    group: int = 1,  # pages a loop step takes inside a row (`_walk_shape`)
     windowed: bool = False,
     selected: bool = False,
     value_width: int = 0,  # of the output where it is not q's (a latent's value)
@@ -1021,8 +1117,9 @@ def _paged_decode_kernel(
     if selected:  # the row's selection [1, Tp, 1, ps], 1.0 where a token is read
         chosen_ref, *refs = refs
     pool, o_ref = refs[:n_leaves], refs[n_leaves]
-    bufs = refs[n_leaves + 1: 2 * n_leaves + 1]  # per leaf [_PAGE_SLOTS, a page]
+    bufs = refs[n_leaves + 1: 2 * n_leaves + 1]  # per leaf [slots, a page]
     sems, walk = refs[2 * n_leaves + 1:]
+    slots = bufs[0].shape[0]
     b = pl.program_id(0)
     nb = pl.num_programs(0)
 
@@ -1047,7 +1144,8 @@ def _paged_decode_kernel(
     # step to the next: the grid runs in order on one core) holds how many
     # pages were computed on, how many fetched, and the row and the page
     # the next fetch is at. The n-th page of the sequence lands in slot
-    # n % _PAGE_SLOTS.
+    # n % slots; a loop step fetches as many pages as it computes on, so the
+    # fetches stay `slots - group` pages ahead.
     computed, fetched, at_row, at_page = range(4)
 
     def fetch_next():
@@ -1060,12 +1158,44 @@ def _paged_decode_kernel(
         @pl.when(row < nb)
         def _start():
             page = pages_ref[row * table_len + j]
-            for copy in copies(page, walk[fetched] % _PAGE_SLOTS):
+            for copy in copies(page, walk[fetched] % slots):
                 copy.start()
             walk[fetched] = walk[fetched] + 1
 
         walk[at_row] = row
         walk[at_page] = j + 1
+
+    def times(n, fn):
+        """``fn(i)`` for i in [0, n): traced ONCE where a step takes a group
+        (what a kernel lowers to is what every start pays for it, ROADMAP
+        S14), unrolled in the one-page walk, whose module is what it was."""
+        if group == 1 or n == 1:
+            for i in range(n):
+                fn(i)
+        else:
+            jax.lax.fori_loop(0, n, lambda i, c: (fn(i), c)[1], 0)
+
+    def fetch_ahead(n):
+        """The next ``n`` pages of the sequence: in one run where the row the
+        fetches are at still holds them all, a page at a time over rows' ends."""
+        if n == 1:
+            return fetch_next()
+        row, j = walk[at_row], walk[at_page]
+
+        def whole():
+            def start(i):
+                page = pages_ref[row * table_len + j + i]
+                for copy in copies(page, jax.lax.rem(walk[fetched] + i, slots)):
+                    copy.start()
+
+            times(n, start)
+            walk[fetched] = walk[fetched] + n
+            walk[at_page] = j + n
+
+        jax.lax.cond(
+            (row < nb) & (j + n <= pages_of(row)), whole,
+            lambda: times(n, lambda _: fetch_next()),
+        )
 
     @pl.when(b == 0)
     def _first_row():
@@ -1073,53 +1203,87 @@ def _paged_decode_kernel(
             walk[i] = 0
         if windowed:
             walk[at_page] = first_of(0)
-        for _ in range(_PAGE_SLOTS - 1):
-            fetch_next()
+        times(slots - group, lambda _: fetch_next())
 
     length = lengths_ref[b]
     q = q_ref[0].astype(jnp.float32)  # [Hkv, G, D]
-    hkv, group, d = q.shape
+    hkv, heads, d = q.shape
 
-    def body(j, carry):
-        m_prev, l_prev, acc = carry  # [Hkv, G, 1] twice, [Hkv, G, D]
-        fetch_next()
-        slot = walk[computed] % _PAGE_SLOTS
-        for copy in copies(0, slot):  # a wait names the slot, not the page
-            copy.wait()
-        walk[computed] = walk[computed] + 1
-        s, p_scale, v = load(q, [buf[slot] for buf in bufs], scales, j, scale)
+    def step(j, carry, n, edge):
+        """Pages ``j .. j + n - 1`` of the row, the next ``n`` of the
+        sequence, folded into the carry. ``edge``: pages that a row's bounds
+        can cut, its last ones and a window row's first."""
+        fetch_ahead(n)
+        first = walk[computed]
+        one = first % slots if n == 1 else None  # (`%`, once: the module it was)
+
+        def slot_of(i):
+            return one if n == 1 else jax.lax.rem(first + i, slots)
+
+        def wait(i):
+            for copy in copies(0, slot_of(i)):  # a wait names the slot, not the page
+                copy.wait()
+
+        times(n, wait)
+        walk[computed] = walk[computed] + n
+        page = [
+            buf[slot_of(0)] if n == 1
+            else jnp.concatenate([buf[slot_of(i)] for i in range(n)], axis=-2)
+            for buf in bufs
+        ]
+        s, p_scale, v = load(q, page, scales, j, n, scale)
         if softcap is not None:
             s = jnp.tanh(s / softcap) * softcap
-        k_pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, 1, page_size), 2
-        )
-        s = jnp.where(k_pos < length, s, _NEG)  # the last page's tail
-        if windowed:  # and the first page's head
-            s = jnp.where(k_pos >= lower_ref[b], s, _NEG)
+        if edge:
+            k_pos = j * page_size + jax.lax.broadcasted_iota(
+                jnp.int32, (1, 1, n * page_size), 2
+            )
+            s = jnp.where(k_pos < length, s, _NEG)  # the last page's tail
+            if windowed:  # and the first page's head
+                s = jnp.where(k_pos >= lower_ref[b], s, _NEG)
         if selected:  # and every token the row's query did not choose
-            s = jnp.where(chosen_ref[0, j][None] > 0, s, _NEG)
+            s = jnp.where(_row_block_pages(chosen_ref, j, n)[None] > 0, s, _NEG)
+        return _fold_pages(carry, s, p_scale, v, edge or selected, page_size)
 
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(s <= _NEG, 0.0, p)
-        corr = jnp.exp(m_prev - m_new)
-        pv = jax.lax.dot_general(
-            # (a float32 page, every loader's but the latent's: no cast)
-            (p if p_scale is None else p * p_scale).astype(v.dtype),
-            v,
-            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [Hkv, G, D]
-        return m_new, l_prev * corr + p.sum(axis=-1, keepdims=True), acc * corr + pv
-
-    _, l, acc = jax.lax.fori_loop(
-        first_of(b), pages_of(b), body,
-        (
-            jnp.full((hkv, group, 1), _NEG, jnp.float32),
-            jnp.zeros((hkv, group, 1), jnp.float32),
-            jnp.zeros((hkv, group, value_width or d), jnp.float32),
-        ),
+    edge_step = functools.partial(step, n=1, edge=True)
+    start, end = first_of(b), pages_of(b)
+    carry = (
+        jnp.full((hkv, heads, 1), _NEG, jnp.float32),
+        jnp.zeros((hkv, heads, 1), jnp.float32),
+        jnp.zeros((hkv, heads, value_width or d), jnp.float32),
     )
+    if group == 1:
+        carry = jax.lax.fori_loop(start, end, edge_step, carry)
+    else:
+        # Only pages that the row's bounds cannot cut ride a group: a window
+        # row's first page goes alone (its lower bound cuts it), then whole
+        # groups with none of the bounds' arithmetic, then the last one to
+        # ``group`` pages, one a step, under the length's mask. Each kind of
+        # step is traced once: a window row's first page is a pass of its own
+        # through the one pair of loops, with no group.
+        head = jnp.minimum(start + 1, end) if windowed else start
+        groups = jnp.maximum(end - 1 - head, 0) // group
+
+        def run(first, n_groups, last, carry):
+            """``n_groups`` whole groups from page ``first``, then single
+            pages up to ``last``."""
+            carry = jax.lax.fori_loop(
+                0, n_groups, lambda g, c: step(first + g * group, c, group, False), carry
+            )
+            return jax.lax.fori_loop(first + n_groups * group, last, edge_step, carry)
+
+        if windowed:
+            carry = jax.lax.fori_loop(
+                0, 2,
+                lambda rest, c: run(
+                    jnp.where(rest == 1, head, start), jnp.where(rest == 1, groups, 0),
+                    jnp.where(rest == 1, end, head), c,
+                ),
+                carry,
+            )
+        else:
+            carry = run(start, groups, end, carry)
+    _, l, acc = carry
     # a row of no pages: acc 0 over the floor of l, zeros and not NaN
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
@@ -1167,6 +1331,10 @@ def _paged_decode_call(
     tp = table.shape[1]
     hkv = leaves[0].shape[2]
     group = h // hkv
+    step_pages, slots = _walk_shape(
+        sum(math.prod(leaf.shape[2:]) * leaf.dtype.itemsize for leaf in leaves), tp
+    )
+    note_grid(f"paged-walk[{name},ps={page_size}]", f"pages/step {step_pages}, slots {slots}")
     kernel = functools.partial(
         _paged_decode_kernel,
         load=load,
@@ -1176,6 +1344,7 @@ def _paged_decode_call(
         table_len=tp,
         scale=1.0 / (d**0.5) if scale is None else scale,
         softcap=config.attn_logit_softcap,
+        group=step_pages,
         windowed=lower is not None,
         selected=chosen is not None,
         value_width=value_width,
@@ -1193,10 +1362,10 @@ def _paged_decode_call(
         + [pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)] * len(leaves),
         out_specs=pl.BlockSpec((1, hkv, group, dv), _paged_row_index),
         scratch_shapes=[
-            pltpu.VMEM((_PAGE_SLOTS,) + leaf.shape[2:], leaf.dtype)
+            pltpu.VMEM((slots,) + leaf.shape[2:], leaf.dtype)
             for leaf in leaves
         ] + [
-            pltpu.SemaphoreType.DMA((len(leaves), _PAGE_SLOTS)),
+            pltpu.SemaphoreType.DMA((len(leaves), slots)),
             pltpu.SMEM((4,), jnp.int32),
         ],
     )

@@ -348,3 +348,221 @@ def test_the_pool_write_lands_and_drops_as_the_scatter_did(case, kv, s):
         assert not np.delete(differs, layer, axis=0).any(), "another layer was touched"
         changed = {(int(p), int(o)) for p, _, o in zip(*np.nonzero(differs[layer]))}
         assert changed == landed
+
+
+# -- a loop step of the paged decode walk takes a GROUP of a row's pages (PR 52) --
+# Every entry point of the one skeleton, its group forced to 2, 4 and 8 pages
+# (the rule itself gives 8, 4 or 2 by the table's length, `_walk_shape`),
+# against `_paged_gather` and the stock attention math, and against the same
+# call at a page a step: the fold takes a group's pages in the row's order, so
+# every float32 sum is the one-page walk's and the two outputs are BIT-equal.
+# (The one sum a group does take in another SHAPE is a score's own, q . k over
+# the head's width, one product for the group's pages: the chip's MXU sums it
+# alike, the CPU's dot need not, so queries and keys here are quarters in
+# [-1, 1] and every such sum is exact in whatever order.
+# dev/bench_paged_walk.py `against_single` reads 0.0 on the chip at the
+# cells' shapes with normal draws.)
+
+WALK_PS, WALK_LAYERS, WALK_D = 8, 2, 8
+UNFUSED = {"xla_disable_hlo_passes": "fusion"}
+WALK_ENTRIES = [
+    "decode", "softcap", "int8", "block", "selected", "latent", "latent-selected", "windowed",
+]
+
+
+def _walk_rows(batch: str, n: int):
+    """(pages a row, tokens its last page lacks, a window row's lower bound
+    in pages + tokens): a batch of rows around a group of ``n`` pages."""
+    if batch == "edges":
+        # 0, 1, n - 1, n, n + 1, 2n + 3 pages; rows of length 0 between live
+        # rows, which the fetch-ahead crosses; lengths that end mid-page
+        pages = [0, 1, n - 1, 0, n, n + 1, 0, 2 * n + 3]
+        short = [0, 3, 0, 0, 5, 0, 0, 1]
+        # a lower bound inside the first page, inside the row's last page, on
+        # a page's edge (so the row's first page is whole), none
+        lower = [(0, 0), (0, 2), (0, 0), (0, 0), (n - 1, 4), (1, 0), (0, 0), (0, 5)]
+    else:
+        # an odd and an even long row in one batch, one that ends mid-page
+        pages = [4 * n + 1, 0, 4 * n, 3 * n + 2]
+        short = [0, 0, 0, 6]
+        # a lower bound in the second page of an aligned group of n, one on
+        # the edge that makes the row's first page the last of such a group
+        lower = [(n + 1, 3), (0, 0), (n - 1, 0), (2, 7)]
+    return pages, short, lower
+
+
+def _quarters(x):
+    return jnp.clip(jnp.round(x * 4), -4, 4) / 4
+
+
+def _walk_case(entry: str, batch: str, n: int):
+    """(call() -> [B, ...] float32, reference [B, ...], live rows)."""
+    from langstream_tpu.ops import attention as A
+
+    ps, d = WALK_PS, WALK_D
+    per_row, short, lower = _walk_rows(batch, n)
+    b, tp = len(per_row), max(per_row) + 1
+    rng = np.random.default_rng(7)
+    pages = b * tp + 1  # the last page holds NaN and no table names it
+    table = np.full((b, tp), pages - 1, np.int32)
+    free = iter(rng.permutation(pages - 1))
+    for r, p in enumerate(per_row):
+        table[r, :p] = [next(free) for _ in range(p)]
+    table = jnp.asarray(table)
+    lengths = jnp.asarray([p * ps - s for p, s in zip(per_row, short)], jnp.int32)
+    t = tp * ps
+    cols = jnp.arange(t)[None, :]
+    seen = cols < lengths[:, None]
+    if entry == "windowed":
+        low = jnp.minimum(jnp.asarray([p * ps + o for p, o in lower], jnp.int32), lengths)
+        seen &= cols >= low[:, None]
+    chosen = None
+    if entry.endswith("selected"):
+        chosen = jnp.asarray(rng.random((b, t)) < 0.4)
+        # nothing chosen in a row's first whole group (all-masked pages inside
+        # a group and alone); in the last row, only in its short last page
+        chosen = chosen.at[:, : n * ps].set(False).at[-1, : (per_row[-1] - 1) * ps].set(False)
+        chosen = chosen.at[-1, (per_row[-1] - 1) * ps].set(True)
+        seen &= chosen
+    layer = jnp.int32(1)
+    key = jax.random.PRNGKey(3)
+    if entry.startswith("latent"):
+        config = dataclasses.replace(
+            MODEL_PRESETS["tiny-latent-dense-moe-test"], attention_impl="pallas"
+        )
+        h, width, kl = config.n_heads, config.latent_key_width, config.kv_lora_rank
+        k1, k2 = jax.random.split(key)
+        pool = _quarters(jax.random.normal(k1, (WALK_LAYERS, pages, 1, ps, width)))
+        pool = pool.at[:, -1].set(jnp.nan)
+        q = _quarters(jax.random.normal(k2, (b, h, width)))
+        call = lambda: A.ragged_paged_latent_attention(  # noqa: E731
+            q, pool, lengths, table, layer, chosen, config, ps, interpret=True
+        )
+        rows = T._paged_gather(pool.at[:, -1].set(0.0), layer, table, ps)[:, 0]
+        logits = jnp.einsum("bhw,btw->bht", q, rows) * config.attn_scale
+        probs = jnp.where(seen[:, None], jnp.exp(logits - logits.max(-1, keepdims=True)), 0.0)
+        want = jnp.einsum("bht,btc->bhc", probs, rows[..., :kl]) / jnp.maximum(
+            probs.sum(-1, keepdims=True), 1e-30
+        )
+        return call, want.reshape(b, h * kl), seen.any(-1)
+    config = dataclasses.replace(
+        MODEL_PRESETS["tiny-test"], attention_impl="pallas", head_dim=d,
+        attn_logit_softcap=1.5 if entry == "softcap" else None,
+    )
+    h, hkv = config.n_heads, config.n_kv_heads
+    shape = (WALK_LAYERS, pages, hkv, ps)
+    keys = jax.random.split(key, 5)
+    if entry == "int8":
+        # the scales differ page by page: a group reads each page's own
+        pool = [
+            {
+                "q": jax.random.randint(kq, shape + (d,), -127, 127, jnp.int8),
+                "s": (jax.random.uniform(ks, shape) * 0.05 + 0.01).at[:, -1].set(jnp.inf),
+            }
+            for kq, ks in (keys[:2], keys[2:4])
+        ]
+        dense = [
+            leaf["q"].astype(jnp.float32) * leaf["s"].at[:, -1].set(0.0)[..., None]
+            for leaf in pool
+        ]
+    else:
+        pool = [
+            fit(jax.random.normal(k, shape + (d,))).at[:, -1].set(jnp.nan)
+            for fit, k in zip((_quarters, lambda x: x), keys[:2])
+        ]
+        dense = [leaf.at[:, -1].set(0.0) for leaf in pool]
+    s = 4 if entry == "block" else 1
+    q = _quarters(jax.random.normal(keys[4], (b, s, h, d)))
+    want = T.attention(
+        q, *(T._paged_gather(leaf, layer, table, ps) for leaf in dense),
+        jnp.broadcast_to(seen[:, None, :], (b, s, t)), config,
+    ).reshape(b, -1)
+    if entry == "block":
+        call = lambda: A.ragged_paged_block_attention(  # noqa: E731
+            q, *pool, lengths, table, layer, config, ps, interpret=True
+        )
+    elif entry == "int8":
+        call = lambda: A.ragged_paged_decode_attention_int8(  # noqa: E731
+            q[:, 0], *pool, lengths, table, layer, config, ps, interpret=True
+        )
+    elif entry == "selected":
+        call = lambda: A.ragged_paged_selected_attention(  # noqa: E731
+            q[:, 0], *pool, lengths, table, layer, chosen, config, ps, interpret=True
+        )
+    else:
+        bound = {"lower": low} if entry == "windowed" else {}
+        call = lambda: A.ragged_paged_decode_attention(  # noqa: E731
+            q[:, 0], *pool, lengths, table, layer, config, ps, interpret=True, **bound
+        )
+    return call, want, seen.any(-1)
+
+
+@pytest.mark.parametrize("n", [2, 4, 8])
+@pytest.mark.parametrize("batch", ["edges", "long"])
+@pytest.mark.parametrize("entry", WALK_ENTRIES)
+def test_a_loop_step_of_the_walk_takes_a_group_of_pages(monkeypatch, entry, batch, n):
+    """Rows of 0, 1, n - 1, n, n + 1 and 2n + 3 pages, long rows odd and even,
+    lengths that end mid-page, rows of length 0 between live rows, a window's
+    lower bound inside a page and on a page's edge, anywhere in a group, a
+    selection that leaves whole pages and a whole group out and one that reads
+    a row's short end alone, the int8 pool's scales page by page, a softcap:
+    every entry point is the gathered reference on the live rows, zeros on the
+    rest, never computes on a page a row does not hold (the clamped sentinel's
+    page is NaN), and is the walk of single pages TO THE BIT."""
+    from langstream_tpu.ops import attention as A
+
+    call, want, live = _walk_case(entry, batch, n)
+    live = np.asarray(live)
+    outs = {}
+    for pages_a_step in (n, 1):
+        monkeypatch.setattr(A, "_walk_shape", lambda *a, g=pages_a_step: (g, A._walk_slots(g)))
+        jax.clear_caches()  # a trace is cached by the function, not by the patch
+        out = jax.jit(call).lower().compile(compiler_options=UNFUSED)()
+        outs[pages_a_step] = np.asarray(out.astype(jnp.float32)).reshape(len(live), -1)
+    got = outs[n]
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got[~live], 0.0)
+    np.testing.assert_allclose(
+        got[live], np.asarray(want)[live], atol=2e-4 if entry == "int8" else 2e-5
+    )
+    np.testing.assert_array_equal(got, outs[1])
+    walks = {k: v for k, v in A.attention_paths().items() if k.startswith("paged-walk[")}
+    assert any(f"ps={WALK_PS}]" in k and v.startswith("pages/step ") for k, v in walks.items())
+
+
+KV_PAGE = 2 * 64 * 128 * 2  # K and V of one KV head, 64 tokens of 128 bf16 lanes
+
+
+@pytest.mark.parametrize(
+    "cell,page_bytes,table,want",
+    [
+        # the nine cells' pools (pages of 64 tokens) and tables. A latent's one
+        # leaf of 640 lanes; K and V of 4 heads: a step takes a group
+        ("kimik25-ep32-d7-longdoc-drain", 64 * 640 * 2, 272, (8, 16)),
+        ("glm5-ep16-d7-longdoc-drain", 64 * 640 * 2, 272, (8, 16)),
+        ("keyevl2-d12-longdoc-drain", 4 * KV_PAGE, 272, (8, 16)),
+        ("sdar30b-d12-blockdecode-drain", 4 * KV_PAGE, 11, (4, 8)),
+        ("an int8 pool of 8 heads under docs' table", 8 * KV_PAGE // 2, 33, (8, 16)),
+        # K and V of 8 and of 30 heads, 256 KB and 983 KB: the one-page walk
+        ("mistral7b-docs-drain (its pool is bf16)", 8 * KV_PAGE, 33, (1, 4)),
+        ("mistral7b-chat-steady", 8 * KV_PAGE, 20, (1, 4)),
+        ("mixtral8x7b-d6-decode-drain", 8 * KV_PAGE, 10, (1, 4)),
+        ("olmohybrid7b-decode-drain", 30 * KV_PAGE, 10, (1, 4)),
+        ("cmdaplus-ep8-d8-ragdocs-drain: full", 8 * KV_PAGE, 196, (1, 4)),
+        ("cmdaplus-ep8-d8-ragdocs-drain: window", 8 * KV_PAGE, 196, (1, 4)),
+        # tables too short for two steps of 4, and for two of 2
+        ("the tiny presets' table of 6", 2 * 2 * 8 * 8 * 4, 6, (2, 5)),
+        ("a table of three pages", 4 * KV_PAGE, 3, (1, 4)),
+    ],
+)
+def test_the_walk_s_group_follows_the_page_and_the_table(cell, page_bytes, table, want):
+    """`_walk_shape` at the cells' shapes: 8 pages a step where a page is
+    under 256 KB (4 and 2 where the table is too short to hold two steps),
+    with slots for the step computed on and as many pages in flight, three at
+    least; at 256 KB and above, and under tables that hold no two steps, the
+    one-page walk with the four slots it had."""
+    from langstream_tpu.ops import attention as A
+
+    assert A._walk_shape(page_bytes, table) == want
+    group, slots = want
+    assert slots - group >= 3 and slots * page_bytes <= 16 * A._WALK_PAGE_BYTES

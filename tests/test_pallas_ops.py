@@ -339,21 +339,27 @@ def test_a_row_past_its_mapped_pages_reads_only_the_mapped_ones(int8):
     )
 
 
+@pytest.mark.parametrize("step_pages", [1, 4], ids=["page-a-step", "4-pages-a-step"])
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_ragged_paged_decode_under_a_model_mesh_matches_one_device(int8):
+def test_ragged_paged_decode_under_a_model_mesh_matches_one_device(int8, step_pages):
     """Under `config.kernel_mesh` the paged kernels shard_map themselves
     over the pool's kv heads, which lie on axis 2 of [L, P, Hkv, ps(, D)]:
     each of the four shards runs the kernel on its own head, and the
-    stitched output is the unsharded kernel's."""
+    stitched output is the unsharded kernel's. Under a table of three pages
+    the walk takes a page a step; under one of nine a group of 4 (PR 52,
+    `_walk_shape` by a SHARD's page and the table), and the last row's eight
+    pages are one group and four single steps."""
     from jax.sharding import Mesh
 
     from langstream_tpu.ops.attention import (
+        _walk_shape,
+        attention_paths,
         ragged_paged_decode_attention,
         ragged_paged_decode_attention_int8,
     )
     from langstream_tpu.parallel.mesh import AXIS_ORDER
 
-    b, h, hkv, d, ps, pages, layers = 3, 8, 4, 8, 8, 8, 2
+    b, h, hkv, d, ps, pages, layers = 3, 8, 4, 8, 8, 16, 2
     shape = (layers, pages, hkv, ps)
     q = rand(0, b, h, d)
     if int8:
@@ -369,10 +375,14 @@ def test_ragged_paged_decode_under_a_model_mesh_matches_one_device(int8):
         kernel = ragged_paged_decode_attention
         k, v = rand(1, *shape, d), rand(2, *shape, d)
     # a live row, a row without a table (length 0), a row with a full one
-    table = jnp.asarray(
-        np.array([[2, 0, pages], [pages, pages, pages], [5, 4, 1]], np.int32)
-    )
+    table = np.array([[2, 0, pages], [pages, pages, pages], [5, 4, 1]], np.int32)
     lengths = jnp.asarray([11, 0, 24], jnp.int32)
+    if step_pages > 1:
+        table = np.concatenate([table, np.full((3, 6), pages, np.int32)], axis=1)
+        table[2, 3:8] = [9, 3, 12, 7, 10]
+        lengths = jnp.asarray([11, 0, 61], jnp.int32)
+    table = jnp.asarray(table)
+    assert _walk_shape(2 * ps * d * 4, table.shape[1])[0] == step_pages
     mesh = Mesh(np.array(jax.devices()[:4]).reshape(1, 1, 1, 4), AXIS_ORDER)
 
     def run(config):
@@ -383,3 +393,5 @@ def test_ragged_paged_decode_under_a_model_mesh_matches_one_device(int8):
     sharded = np.asarray(run(dataclasses.replace(CFG, kernel_mesh=mesh)))
     np.testing.assert_allclose(sharded, np.asarray(run(CFG)), atol=1e-6)
     np.testing.assert_array_equal(sharded[1], 0.0)
+    walk = attention_paths()[f"paged-walk[{kernel.__name__},ps={ps}]"]
+    assert walk.startswith(f"pages/step {step_pages},")

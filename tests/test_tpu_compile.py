@@ -620,6 +620,9 @@ def test_model_sharded_paged_decode_compiles_for_four_chips(v5e):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "%ragged_paged_decode_attention_int8" in text
+    # a shard's page is 2 of the 8 kv heads, 32 KB of int8: a step takes a group
+    walk = A.attention_paths()[f"paged-walk[ragged_paged_decode_attention_int8,ps={PAGE}]"]
+    assert walk == "pages/step 8, slots 16"
     # independent per kv head: the shard_map body needs no collective
     assert "all-reduce" not in text and "all-gather" not in text
     # each chip holds a quarter of the pool (k and v: int8 values + scales)
@@ -1338,15 +1341,31 @@ def test_window_segment_program_compiles_for_v5e_beside_the_cell_s_state(v5e, mo
 #    CHANGES.md. A PR that did not mean to change these programs does not.
 # ---------------------------------------------------------------------------
 
+# PR 52 holds FIVE of the seven and re-takes two on purpose. A loop step of the
+# skeleton takes a group of the row's pages where a page is under 256 KB
+# (`ops/attention._walk_shape`). At 256 KB and above the walk is the one-page
+# walk and its module the parent's byte for byte: chat's, Mixtral's ("drain"),
+# both of command-a-plus's page groups' and Olmo's are the hashes PR 46 took,
+# which is the proof that those four cells' programs cannot move. The int8
+# pool's pages (the `docs16x33` case's, 128 KB) and SDAR's (128 KB, the block pass) ride groups of
+# 8 and 4: re-taken, as PR 52 left them, with the selected and the latent
+# entries (Keye's 128 KB, GLM's and Kimi's 80 KB latent pages), pinned here
+# for the first time.
 KERNEL_BODIES_AT_PARENT = {
     "chat64x20-paged-decode": "178024633ae8f3d4",
-    "docs16x33-paged-decode-int8": "6b6427585ace5e9d",
     "drain64x10-paged-decode": "a5c6d968d9af6508",
     "cmdaplus16x196-paged-decode": "c0e5b8ef23935735",
     "cmdaplus16x196-windowed-decode": "3bdeae2c481e0a2d",
-    "sdardrain64x11-paged-block": "a1141be57f9003ff",
     "olmodrain40x10-paged-decode": "e3d02f9b3ac55bc2",
 }
+KERNEL_BODIES_AT_PR52 = {
+    "docs16x33-paged-decode-int8": "a2df74e2c7710972",
+    "sdardrain64x11-paged-block": "8bf5541686d68d3e",
+    "keye8x272-selected-decode": "f52667d126a7f0e1",
+    "glm16x272-latent-decode": "6b1de6ed21dea290",
+    "kimi16x272-latent-decode": "a4e20884c5c42957",
+}
+KERNEL_BODIES = {**KERNEL_BODIES_AT_PARENT, **KERNEL_BODIES_AT_PR52}
 
 
 def _kernel_bodies(fn, args, device) -> list[str]:
@@ -1370,11 +1389,28 @@ def _short_hash(text: str) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
-@pytest.mark.parametrize("case", sorted(KERNEL_BODIES_AT_PARENT))
+@pytest.mark.parametrize("case", sorted(KERNEL_BODIES))
 def test_the_shared_kernels_hand_mosaic_what_they_did(v5e, case):
     (body,) = _kernel_bodies(*CASES[case], v5e[0])
     assert f"module @{_kernel_of(case)} " in body
-    assert _short_hash(body) == KERNEL_BODIES_AT_PARENT[case]
+    assert _short_hash(body) == KERNEL_BODIES[case]
+
+
+# What a kernel instance hands Mosaic is what every start of an engine pays to
+# lower and to hash, warm or cold, once a program and a period's layer
+# (ROADMAP S14): PR 51's grouped kernels were 5 x the one-page module's text
+# (unrolled copy starts, waits and fetches, three loops a row) and cost
+# command-a-plus 14 s of set-up. PR 52's trace each kind of step once: 1.8 to
+# 2.0 x at 8 pages a step, 1.5 x at 4 (PERF.md section 6, PR 52). A later edit
+# that doubles the trace fails here, on the CPU tier.
+@pytest.mark.parametrize("case", sorted(KERNEL_BODIES_AT_PR52))
+def test_a_grouped_walk_s_module_stays_near_the_one_page_module_s(v5e, monkeypatch, case):
+    (grouped,) = _kernel_bodies(*CASES[case], v5e[0])
+    monkeypatch.setattr(A, "_walk_shape", lambda *a: (1, A._walk_slots(1)))
+    jax.clear_caches()  # a trace is cached by the function, not by the patch
+    (single,) = _kernel_bodies(*CASES[case], v5e[0])
+    jax.clear_caches()
+    assert len(single) < len(grouped) < 2.1 * len(single), (len(grouped), len(single))
 
 
 # An admission group's `paged_insert_pages` module, as the parent (PR 47) handed
@@ -1427,17 +1463,27 @@ def test_the_selection_is_the_only_difference_of_its_kernel(v5e):
     # lines are added, none goes but the signatures the new operand is part of
     gone = [line for line in delta if line[0] == "-"]
     assert all("^bb0(" in line or "function_type = " in line for line in gone), gone[:3]
-    assert 0 < len(delta) - len(gone) < 40, len(delta)
+    # (PR 52: a loop step takes 8 pages here, so the mask is read a page at a
+    # time and laid side by side in the group's step as well as in a single
+    # page's: 149 lines where the one-page walk added under 40)
+    assert 0 < len(delta) - len(gone) < 160, len(delta)
     assert sum("memref<1x272x1x64xf32" in line for line in delta) >= 2  # the row's block
 
 
+# PR 52 re-took these six, the indexer preset's decode chunk below
+# (`ENGINE_PROGRAMS_AT_PARENT`'s first row) and the latent presets' two
+# (`LATENT_PROGRAMS_AT_PR47`'s and `LATENT_DENSE_PROGRAMS_AT_PR50`'s first rows)
+# on purpose: the tiny presets' pages are a few hundred bytes, so every decode
+# (and block) chunk that holds a paged decode kernel walks its rows a group of
+# 2 pages a step (`ops/attention._walk_shape` under tables of 6). The segment
+# and admit programs hold no such kernel and are the parent's, every row.
 DECODE_PROGRAMS_AT_PARENT = {
-    "tiny-test": "ce8f6d008283903c",
-    "tiny-test-int8": "e3732a44bc613bc6",
-    "tiny-moe-test": "05bdf61a6e78117c",
-    "tiny-hybrid-test": "fd1f94910bd1ddb5",
-    "tiny-window-moe-test": "30e6fb6317606f48",
-    "tiny-blockfill-moe-test": "cc8a25b2bf097fec",
+    "tiny-test": "08678b69c9038965",
+    "tiny-test-int8": "b49cfa793f35c4f0",
+    "tiny-moe-test": "ee109ccd5746e99e",
+    "tiny-hybrid-test": "3dee33c893d6e5f5",
+    "tiny-window-moe-test": "7dc9536f59709e87",
+    "tiny-blockfill-moe-test": "b780241efec3ab66",
 }
 
 
@@ -1448,7 +1494,7 @@ DECODE_PROGRAMS_AT_PARENT = {
 # "admit": the admission group (`_make_paged_admit_group()`; a model that
 # fills blocks has `_block_admit_group` and no segment).
 ENGINE_PROGRAMS_AT_PARENT = {
-    "tiny-sparse-moe-test": "2148456a5a9418be",
+    "tiny-sparse-moe-test": "1f8e0060c26342db",
     "segment/tiny-test": "17b4532d195db002",
     "segment/tiny-test-int8": "5442e93148fd62a0",
     "segment/tiny-moe-test": "4d69d76d663c6419",
@@ -1541,7 +1587,7 @@ def _engine_program_text(case: str) -> str:
 # of `_latent_expand` of the whole table ("5d841ea49914bce2" at PR 47, under
 # the scatter); the decode chunk and the admit group are PR 47's.
 LATENT_PROGRAMS_AT_PR47 = {
-    "tiny-latent-moe-test": "7e30a4ca8989f33e",
+    "tiny-latent-moe-test": "11d5d124bbfe31f9",
     "segment/tiny-latent-moe-test": "f92ebd1992a5fe51",
     "admit/tiny-latent-moe-test": "7ccaa668ff6f12fc",
 }
@@ -1551,7 +1597,7 @@ LATENT_PROGRAMS_AT_PR47 = {
 # under the scatter like the others (its page-writing form is
 # `SEGMENT_PROGRAMS_AT_PR48`'s last row).
 LATENT_DENSE_PROGRAMS_AT_PR50 = {
-    "tiny-latent-dense-moe-test": "f5ac304cf5c728e3",
+    "tiny-latent-dense-moe-test": "6de4d5a93e313f96",
     "admit/tiny-latent-dense-moe-test": "bdec01f32b991102",
 }
 
@@ -1778,6 +1824,18 @@ def _traced_paths(case: str) -> dict:
         A._PATHS.update(was)
 
 
+# PR 52's keys, on purpose: every paged entry point a preset traces with the
+# kernels forced says how its walk takes the row's pages (`_walk_shape`: the
+# tiny presets' pages are a few hundred bytes and their tables hold 6, so a
+# step takes 2 and five slots hold them); the CPU's own choice reads through
+# no kernel and says nothing. Every other key and value is `PATHS_AT_PARENT`'s.
 @pytest.mark.parametrize("case", sorted(PATHS_AT_PARENT))
 def test_every_preset_notes_the_paths_it_did(case):
-    assert _traced_paths(case) == PATHS_AT_PARENT[case]
+    walk = {
+        f"paged-walk[{kernel},ps={TINY_PAGE}]": "pages/step 2, slots 5"
+        for key, kernel in PATHS_AT_PARENT[case].items()
+        if key.startswith(("paged-decode", "paged-block"))
+        and re.fullmatch(r"ragged_paged_\w+", kernel)
+    }
+    assert len(walk) == case.endswith("/pallas")
+    assert _traced_paths(case) == {**PATHS_AT_PARENT[case], **walk}
