@@ -81,6 +81,11 @@ class _BaseCompletionsStep(Step):
             "distinct device programs dispatched (growth after warmup = "
             "a mid-traffic XLA compile stall)",
         )
+        self._m_startup = metrics.gauge(
+            "engine_startup_seconds",
+            "the engine's time to ready: constructor to warm-up's end "
+            "(docs/SERVING.md §12, Start-up)",
+        )
         # prefix KV reuse (serving/pagepool.PrefixPageIndex) — all sourced from the
         # engine's cumulative stats, so gauges (not counters) carry them
         self._m_prefix_hit = metrics.gauge(
@@ -479,6 +484,7 @@ class _BaseCompletionsStep(Step):
         self._m_hbm.set(stats.get("hbm-gbps-decode", 0))
         self._m_step.set(stats.get("decode-step-ms", 0))
         self._m_programs.set(stats.get("compiled_programs", 0))
+        self._m_startup.set(stats.get("startup-s", 0))
         self._m_prefix_hit.set(stats.get("prefix-cache-hit-rate", 0))
         self._m_prefix_saved.set(stats.get("prefill-tokens-saved-total", 0))
         self._m_prefix_bytes.set(stats.get("prefix-pool-bytes-in-use", 0))

@@ -289,11 +289,30 @@ class _EngineHolder:
     def tokenizer(self):
         if self._tokenizer is None:
             from langstream_tpu.serving.tokenizer import get_tokenizer
+            from langstream_tpu.tracing import TRACER
 
-            self._tokenizer = get_tokenizer(self.config.get("tokenizer", "byte"))
+            # an `hf:` tokenizer imports `transformers` before it reads a
+            # file: seconds a replica's owner waits for, ahead of the
+            # engine's own start-up (docs/SERVING.md §12, "Start-up")
+            name = str(self.config.get("tokenizer", "byte"))
+            with TRACER.span("engine.startup.tokenizer", tokenizer=name.partition(":")[0]):
+                self._tokenizer = get_tokenizer(name)
         return self._tokenizer
 
     def params(self):
+        if self._params is None:
+            from langstream_tpu.tracing import TRACER
+
+            # what a replica's owner waits for before the engine's own
+            # start-up begins (docs/SERVING.md §12, "Start-up"); a tree that
+            # was handed in never comes here
+            with TRACER.span(
+                "engine.startup.weights", weights=str(self.config.get("weights", "random"))
+            ):
+                self._load_params()
+        return self._params
+
+    def _load_params(self):
         import jax
 
         if self._params is None:

@@ -54,6 +54,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
+from langstream_tpu.compile_account import note_kernel
 from langstream_tpu.models.configs import ModelConfig
 
 _NEG = -1e30
@@ -315,6 +316,7 @@ def flash_prefill_attention(
         softcap=config.attn_logit_softcap,
         **extra,
     )
+    note_kernel("flash_prefill_attention")
     out = pl.pallas_call(
         kernel,
         name="flash_prefill_attention",
@@ -479,13 +481,15 @@ def flash_segment_attention(
         selection = [pl.BlockSpec((1, block_q, block_k), chosen_index)]
         extra["selected"] = True
 
+    name = "flash_segment_attention" if chosen is None else "sparse_segment_attention"
+    note_kernel(name)
     out = pl.pallas_call(
         functools.partial(
             _segment_kernel, block_q=block_q, block_k=block_k, window=window,
             n_t=n_t, scale=_score_scale(config, d), softcap=config.attn_logit_softcap,
             **extra,
         ),
-        name="flash_segment_attention" if chosen is None else "sparse_segment_attention",
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, hkv, s // block_q, n_k),
@@ -581,6 +585,7 @@ def index_scores(
     b, s, hi, di = q_idx.shape
     t = k_idx.shape[1]
     block_q, block_k = _fit_block(512, s), _fit_block(512, t)
+    note_kernel("index_scores")
     return pl.pallas_call(
         functools.partial(_index_score_kernel, block_q=block_q, block_k=block_k),
         name="index_scores",
@@ -771,6 +776,7 @@ def segment_select(
         f"segment-select[s={s},t={t}]",
         f"block_q {block_q}, block_k {block_k}, to the diagonal",
     )
+    note_kernel("segment_select")
     return pl.pallas_call(
         functools.partial(
             _segment_select_kernel, block_q=block_q, block_k=block_k, n_t=n_t, topk=topk
@@ -878,6 +884,7 @@ def latent_expand_blocks(
     if scale is not None:
         weights += [scale[:, None, :nope], scale[:, None, nope:]]
         specs += [pl.BlockSpec((1, 1, nope), w_index), pl.BlockSpec((1, 1, v_dim), w_index)]
+    note_kernel("latent_expand_blocks")
     return pl.pallas_call(
         functools.partial(_latent_expand_kernel, kl=kl, rope=rope, quantized=scale is not None),
         name="latent_expand_blocks",
@@ -1369,6 +1376,7 @@ def _paged_decode_call(
             pltpu.SMEM((4,), jnp.int32),
         ],
     )
+    note_kernel(name)
     out = pl.pallas_call(
         kernel,
         name=name,
@@ -1666,6 +1674,7 @@ def paged_kv_write(
     hbm = pl.BlockSpec(memory_space=pltpu.MemorySpace.HBM)
     row_block = pl.BlockSpec((block, rows, d), lambda i, *_: (i, 0, 0))
     flat = [_flat_pool(leaf) for leaf in (k, v)]
+    note_kernel("paged_kv_write")
     out = pl.pallas_call(
         functools.partial(_paged_kv_write_kernel, num_pages=num_pages),
         name="paged_kv_write",
@@ -1798,6 +1807,7 @@ def _paged_insert_pages_call(
     scalars = [pages.astype(jnp.int32).reshape(-1)]
     if layer is not None:
         scalars.append(jnp.asarray(layer, jnp.int32).reshape(1))
+    note_kernel("paged_insert_pages")
     out = pl.pallas_call(
         functools.partial(
             _paged_insert_pages_kernel, num_pages=pools[0].shape[1], leaves=leaves,

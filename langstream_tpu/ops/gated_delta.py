@@ -39,6 +39,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from langstream_tpu.compile_account import note_kernel
 from langstream_tpu.models.configs import ModelConfig
 
 CHUNK = 64
@@ -266,6 +267,7 @@ def gated_delta_update(q, k, v, g, beta, state, layer, rows, live, interpret: bo
     flat = state.reshape(n_layers * n_rows, dk, hv)
     row = lambda i, srow, live, nlive: (i, 0, 0)  # noqa: E731
     own = lambda i, srow, live, nlive: (srow[i], 0, 0)  # noqa: E731
+    note_kernel("gated_delta_update")
     o, flat = pl.pallas_call(
         functools.partial(_update_kernel, tile=tile, dv=dv),
         name="gated_delta_update",

@@ -40,6 +40,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from langstream_tpu.compile_account import note_kernel
+
 # Scoped VMEM stated to Mosaic, over the largest blocks `_blocks` can pick.
 # A tile of 64 rows or fewer at the budget's edge (4096 x 1024): w 4 MiB twice
 # buffered, its converted copy 8 MiB, x (64 x 4096 bf16) 0.5 MiB twice, the
@@ -238,6 +240,7 @@ def _call(kernel, stacks, x, layer, tile_expert, used, tile: int, interpret: boo
         _, on = live(i, used)
         return (jnp.where(on, i, tiles - 1), jnp.where(on, j, 0))
 
+    note_kernel("moe_grouped_matmul")
     return pl.pallas_call(
         kernel,
         name="moe_grouped_matmul",

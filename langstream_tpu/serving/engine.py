@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from langstream_tpu.compile_account import register as register_compile_account
 from langstream_tpu.models.configs import PAGE_LEAVES, GenerationOptions, ModelConfig
 from langstream_tpu.models.transformer import (
     MOE_COUNTS,
@@ -62,6 +63,7 @@ from langstream_tpu.serving.observability import (
 )
 from langstream_tpu.serving.sampling import block_choice, sample, speculative_verify
 from langstream_tpu.serving.speculation import NGramIndex
+from langstream_tpu.serving.startup import StartupTrace, process_stats
 from langstream_tpu.serving.tenancy import (
     DEFAULT_TENANT,
     BrownoutController,
@@ -110,6 +112,9 @@ def enable_persistent_compile_cache(cache_dir: Optional[str] = None) -> Optional
     cold warmup slow. Idempotent; safe to call before any engine is built."""
     from jax.experimental.compilation_cache import compilation_cache as cc
 
+    # every caller is about to compile: the process's compile account hears
+    # of it from here on (docs/SERVING.md §12, "Start-up")
+    register_compile_account()
     before = jax.config.jax_compilation_cache_dir
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         if cache_dir is None and jax.default_backend() != "cpu":
@@ -1247,6 +1252,8 @@ class ServingEngine:
         the KV cache is sharded to match (kv heads on "model") so every
         decode step partitions over ICI with XLA-inserted collectives —
         one psum per layer, the Megatron schedule."""
+        # time to ready is counted from here (docs/SERVING.md §12, "Start-up")
+        self._startup = StartupTrace()
         if config.is_recurrent or config.has_window:
             # Refused at build, by the option's name (docs/SERVING.md §11).
             # A recurrent state is overwritten in place: it cannot be
@@ -2189,6 +2196,7 @@ class ServingEngine:
                 "auto) — off"
             )
             self._durable_on = False
+        self._startup.built()
 
     # -- public API ---------------------------------------------------------
 
@@ -2716,6 +2724,11 @@ class ServingEngine:
             # — tests and the metrics exporter consume it by this exact
             # name; do not "fix" the spelling
             "compiled_programs": len(self._programs),
+            # time to ready and what of it built programs: `startup-*` frozen
+            # when the warm-up ended (zeros before), `process-*` the whole
+            # process's compile account, live (docs/SERVING.md §12, "Start-up")
+            **self._startup.stats,
+            **process_stats(),
             # admission groups dispatched at each row count of the ladder
             # (admit_rungs): how far groups shrink to the prompts they hold
             "admit-group-rows": dict(self._admit_group_rows),
@@ -2993,6 +3006,8 @@ class ServingEngine:
 
     def _record_program(self, *signature) -> None:
         self._programs.add(tuple(signature))
+        if self._startup.warming:
+            self._startup.program(signature)
 
     @staticmethod
     def _fresh_block_state(rows: int, config: ModelConfig) -> Optional[dict]:
@@ -3095,7 +3110,6 @@ class ServingEngine:
         state is untouched except the PRNG key, which advances before any
         request is served. SPMD: the family replays whole (OP_WARMUP) so
         followers warm and key-advance identically."""
-        started = time.monotonic()
         for width in self.prefill_buckets:
             for n_pad in self._admit_rungs:
                 if self._stop.is_set():
@@ -3109,7 +3123,6 @@ class ServingEngine:
                 self._dev_prefill(
                     width, tokens, lengths, temps, top_ks, top_ps, slots
                 ).block_until_ready()
-        warmed_s = time.monotonic() - started
         # the decode-chain scatter (warm prefix admissions AND the final
         # chunked-prefill segment dispatch it): one traced-index program,
         # warmed with an all-dropped slot so its first real use — the first
@@ -3125,9 +3138,10 @@ class ServingEngine:
             jnp.zeros(1, jnp.int32), 0, 0.0, 0, 1.0,
         )
         jax.block_until_ready(self._tokens_dev)
+        # what it took is the family's span, and its log line (serving/startup.py)
         log.info(
-            "prefill buckets precompiled: widths %s, rows %s, %.1fs",
-            list(self.prefill_buckets), list(self._admit_rungs), warmed_s,
+            "prefill buckets precompiled: widths %s, rows %s",
+            list(self.prefill_buckets), list(self._admit_rungs),
         )
 
     def _run(self) -> None:
@@ -3146,10 +3160,12 @@ class ServingEngine:
         STOP."""
         backoff = self.restart_backoff_s
         restarts = 0
+        refused: Optional[BaseException] = None
         try:
             try:
                 self._warmup()
             except BaseException as e:  # noqa: BLE001 — fatal, see below
+                refused = e
                 # OUTSIDE the restart loop on purpose: a restart skips the
                 # warm-up, so recovering from a compile or lowering error
                 # here would serve with the refused program still unbuilt
@@ -3160,6 +3176,7 @@ class ServingEngine:
                 self._fail_all(e)
                 return
             finally:
+                self._startup.finish(refused)
                 self._ready.set()
             while True:
                 try:
@@ -3301,13 +3318,16 @@ class ServingEngine:
 
         # the decode-phase surface is ONE program (per step count)
         announce_warmup(wire.WARMUP_PAGED)
-        self._warmup_paged()
+        with self._startup.phase("paged"):
+            self._warmup_paged()
         announce_warmup(wire.WARMUP_PREFILL_BUCKETS)
-        self._warmup_prefill_buckets()
+        with self._startup.phase("prefill_buckets"):
+            self._warmup_prefill_buckets()
         if self._agentic:
             # no announce: the agentic tier is construction-disabled
             # under SPMD, so this warmup never runs on a replica
-            self._warmup_agentic()
+            with self._startup.phase("agentic"):
+                self._warmup_agentic()
         # what the process has built by now lives as long as it serves: put
         # it out of the collector's reach, so that a full collection costs
         # what was allocated since. A pass over the ~700,000 objects of a
@@ -7042,9 +7062,9 @@ class ServingEngine:
             return self._dev_block(steps, stale, mask)
         lora, arows, dfa, g = self._agentic_args()
         dstate = self._dfa_state_dev
-        self._record_program("paged-decode", steps)
         if len(stale):
             self._reset_stale_temps(stale)
+        self._record_program("paged-decode", steps)
         pool = self._pagepool
         (
             chunk,
@@ -7200,9 +7220,9 @@ class ServingEngine:
 
     def _dev_block(self, passes: int, stale, mask: Optional[np.ndarray] = None):
         """Device layer of one block chunk (`_paged_block_chunk`)."""
-        self._record_program("paged-block", passes)
         if len(stale):
             self._reset_stale_temps(stale)
+        self._record_program("paged-block", passes)
         pool = self._pagepool
         (
             reports, self._block_dev, self._positions_dev, pool.dev, self._key,
@@ -7412,9 +7432,9 @@ class ServingEngine:
                     (self.max_batch, drafts.shape[1] + 1), np.int32
                 )
             vstates_dev = jnp.asarray(vstates)
-        self._record_program("paged-verify", drafts.shape[1])
         if len(stale):
             self._reset_stale_temps(stale)
+        self._record_program("paged-verify", drafts.shape[1])
         pool = self._pagepool
         (
             packed,
