@@ -14,7 +14,10 @@ scope path]`, the longest first. Traced or not, `{"phase": "segments", ...}`
 holds `stats()["segment-writes"]` where the engine has the counter (PR 48;
 None at a parent without it) and the window's `engine.prefill_segment` spans:
 how many, and least, quartiles and most of their device milliseconds a thousand
-computed tokens (what `prefill_segment_ms_per_1k_tokens.drain` sums). The
+computed tokens (what `prefill_segment_ms_per_1k_tokens.drain` sums), and
+`moe_spilled_assignment_share` (PR 54: `moe_spilled` over `moe_local` x 100 by
+the harness's `span_ratio` from `layer_metrics/moe_spilled_assignment_share.json`,
+which `BENCHMARK.json` does not name yet; None where no span has the count). The
 reduction is the harness's own (`reduce/scoped.load`, `scope_seconds`' rule: an
 operation counts under the scope path of its `tf_op`), so the figure is what a
 per-layer metric over that scope would read. An operation the compiler made
@@ -50,11 +53,20 @@ def say_scope_ops(run, program: str, scope: str, match: str) -> None:
             a["device_ms"] * 1e3 / a["computed_tokens"]
             for a in spans if a.get("device_ms") and a.get("computed_tokens")
         )
+        from readers import span_ratio
+
+        try:  # by the harness's reader, from the file a `benchmark` PR will name (PERF.md §7)
+            spilled = span_ratio.read(
+                run.load_json("layer_metrics", "moe_spilled_assignment_share"), window
+            )
+        except FileNotFoundError:  # a checkout from before PR 54
+            spilled = None
         run.emit(
             phase="segments", segment_writes=window["stats"].get("segment-writes"),
             spans=len(spans), device_ms_per_1k_tokens=[
                 round(per_1k[int(q * (len(per_1k) - 1))], 3) for q in (0, 0.25, 0.5, 0.75, 1)
             ] if per_1k else None,
+            moe_spilled_assignment_share=spilled,
         )
         if window.get("trace_dir"):
             from reduce import scoped

@@ -61,11 +61,13 @@ SCOPES = (
 MOE_COUNTS = ("routed", "dropped", "routed_real", "dropped_real")
 
 
-# An expert layer that holds a share (`moe_ffn_held`) counts two more: the
-# real tokens' assignments that fell on the experts it holds, and the held
+# An expert layer that holds a share (`moe_ffn_held`) counts three more: the
+# real tokens' assignments that fell on the experts it holds, the held
 # experts that got at least one of them (a layer: whose weights the grouped
-# product has to read).
-MOE_HELD_COUNTS = MOE_COUNTS + ("local", "touched")
+# product has to read), and the local assignments computed in a pass past the
+# first (0 where the call is one pass: how often twice the even share did not
+# hold them).
+MOE_HELD_COUNTS = MOE_COUNTS + ("local", "touched", "spilled")
 
 
 def moe_count_names(config: ModelConfig) -> tuple:
@@ -2282,6 +2284,80 @@ def _route_all(xf: jax.Array, router: jax.Array, config: ModelConfig, bias=None)
     return weights, chosen
 
 
+def _held_passes(xf, order, by_expert, weights, n_local, k, held, tile, shape, experts):
+    """float32 [T, d]: every local assignment's expert output times its
+    weight, summed at its token, in passes of ``shape`` (`gm.pass_shape`:
+    assignments, tiles). ``order`` [T * k]: the assignments sorted by held
+    expert, the ``n_local`` local ones first; ``by_expert`` their experts
+    (``held``: not local). Pass p lays the window ``[p * C, (p + 1) * C)`` of
+    them out in its own buffer (an expert whose rows straddle two windows has
+    its weights read in both), so the rows moved follow C and the passes
+    follow the rows that are real: one under even routing, none where no
+    token chose a held expert, more under a skew; nothing is dropped.
+
+    The FIRST pass's rows leave the loop and are summed after it, by the
+    one-pass layer's own expression: inside the loop's body the compiler
+    rounds that same expression another way (a last place in a few rows a
+    call), and the cells' checks turn on such places (PERF.md section 6, PR
+    54: summed in the loop, one cell's check failed; summed here, the layer
+    as Kimi and GLM configure it gave the one buffer's output to the bit on
+    the chip, and every check held). A further pass, which only a skew
+    takes, adds into a float32 accumulator, and
+    the first pass's sum joins it rounded to the activation type."""
+    from langstream_tpu.ops import grouped_matmul as gm
+
+    size, tiles = shape
+    t, d = xf.shape
+    n_rows = tiles * tile
+    pad = -order.shape[0] % size  # a window never reads past the end
+    order = jnp.pad(order, (0, pad))
+    by_expert = jnp.pad(by_expert, (0, pad), constant_values=held)
+
+    def summed(out_rows, row_of):
+        """A pass's rows in [T, k]'s slots (out of bounds: not of this pass,
+        filled with zero and not fetched), weighed and summed over k."""
+        picked = out_rows.at[row_of].get(mode="fill", fill_value=0).reshape(t, k, -1)
+        w = jnp.where(row_of < n_rows, weights, 0.0).reshape(t, k)
+        return jnp.einsum("tkd,tk->td", picked.astype(jnp.float32), w)
+
+    def one_pass(carry):
+        p, acc, first_rows, first_row_of = carry
+        with jax.named_scope("moe_ffn.dispatch"):
+            assignment = lax.dynamic_slice(order, (p * size,), (size,))
+            expert = lax.dynamic_slice(by_expert, (p * size,), (size,))
+            # past the local ones: no token, no row (out of bounds both)
+            token = jnp.where(expert < held, assignment // k, t)
+            dest, tile_expert, used, _ = gm.plan_groups(expert, held, tile, tiles)
+            # a buffer row's token through the inverse of ``dest`` (a gather of
+            # rows goes at the memory's speed, a scatter of them costs by the
+            # update); a row no assignment lands on reads token 0 and is
+            # never read back
+            source = jnp.zeros(n_rows, jnp.int32).at[dest].set(token, mode="drop")
+            rows = jnp.take(xf, source, axis=0)
+        out_rows = experts(rows, tile_expert, used)
+        with jax.named_scope("moe_ffn.combine"):
+            # each assignment's row of this pass's buffer
+            row_of = jnp.full(t * k, n_rows, jnp.int32).at[
+                jnp.where(expert < held, assignment, t * k)
+            ].set(dest, mode="drop")
+            return lax.cond(
+                p == 0,
+                lambda: (p + 1, acc, out_rows, row_of),
+                lambda: (p + 1, acc + summed(out_rows, row_of), first_rows, first_row_of),
+            )
+
+    _, acc, first_rows, first_row_of = lax.while_loop(
+        lambda carry: carry[0] * size < n_local, one_pass,
+        (
+            jnp.int32(0), jnp.zeros((t, d), jnp.float32),
+            jnp.zeros((n_rows, d), xf.dtype), jnp.full(t * k, n_rows, jnp.int32),
+        ),
+    )
+    with jax.named_scope("moe_ffn.combine"):
+        first = summed(first_rows, first_row_of).astype(xf.dtype).astype(jnp.float32)
+        return jnp.where(n_local > size, first + acc, first)
+
+
 def moe_ffn_held(
     x: jax.Array, lp: dict, config: ModelConfig,
     token_valid: Optional[jax.Array] = None,  # [B, S] bool — real tokens
@@ -2298,7 +2374,13 @@ def moe_ffn_held(
     the held experts, ops/grouped_matmul.py); what an absent expert would
     add is left out, as on the chip that holds the others its own part is.
     The shared experts' mean is added once. A padding token is routed but
-    holds no row: it gets the shared part only."""
+    holds no row: it gets the shared part only. The rows are laid out in ONE
+    buffer that holds every case, or, where the layer holds a share small
+    enough that twice its even share of the call's assignments is a smaller
+    buffer (`gm.pass_shape`, from shapes alone: a prefill segment at 12 of
+    384 experts, never a decode step, never a layer that holds every
+    expert), in passes of that (`_held_passes`): the same products, and
+    where one pass holds them all the one buffer's sum over a token's k."""
     from langstream_tpu.ops import grouped_matmul as gm
     from langstream_tpu.ops.attention import note_grid
 
@@ -2315,18 +2397,34 @@ def moe_ffn_held(
         )
 
     tile = gm.row_tile(t, k, config.n_experts)
-    tiles = gm.buffer_tiles(t, k, held, tile)
+    passes = gm.pass_shape(t, k, held, config.n_experts, tile)
+    note_grid(*gm.dispatch_note(t, k, held, config.n_experts, tile))
     with jax.named_scope("moe_ffn.dispatch"):
         local = (chosen >= first) & (chosen < first + held) & real[:, None]  # [T, k]
         expert = jnp.where(local, chosen - first, held).reshape(t * k)
-        dest, tile_expert, used, sizes = gm.plan_groups(expert, held, tile, tiles)
-        rows = jnp.zeros((tiles * tile, d), xf.dtype).at[dest].set(
-            jnp.repeat(xf, k, axis=0), mode="drop"
+        if passes is None:
+            tiles = gm.buffer_tiles(t, k, held, tile)
+            dest, tile_expert, used, sizes = gm.plan_groups(expert, held, tile, tiles)
+            rows = jnp.zeros((tiles * tile, d), xf.dtype).at[dest].set(
+                jnp.repeat(xf, k, axis=0), mode="drop"
+            )
+        else:
+            # ONE stable sort puts the local assignments first, by expert and
+            # then by token: a pass is a window of it (`_held_passes`)
+            by_expert, order = lax.sort(
+                (expert, jnp.arange(t * k, dtype=jnp.int32)), num_keys=1, is_stable=True
+            )
+            sizes = jnp.diff(jnp.searchsorted(by_expert, jnp.arange(held + 1)))
+        named = dict(
+            routed=jnp.int32(t * k), dropped=jnp.int32(0),
+            routed_real=real.sum(dtype=jnp.int32) * k, dropped_real=jnp.int32(0),
+            local=local.sum(dtype=jnp.int32), touched=(sizes > 0).sum(dtype=jnp.int32),
         )
-        counts = jnp.stack([
-            jnp.int32(t * k), jnp.int32(0), real.sum(dtype=jnp.int32) * k,
-            jnp.int32(0), local.sum(dtype=jnp.int32), (sizes > 0).sum(dtype=jnp.int32),
-        ])
+        # what the first pass does not hold goes through a further one
+        named["spilled"] = (
+            jnp.int32(0) if passes is None else jnp.maximum(named["local"] - passes[0], 0)
+        )
+        counts = jnp.stack([named[name] for name in MOE_HELD_COUNTS])
 
     def held_w(name: str) -> dict:
         """[L, held, K, N]: the stack as `_scan_periods` hands it on
@@ -2345,24 +2443,35 @@ def moe_ffn_held(
     kernel = gm.grouped_matmul_ok(tile, d, f, config.attention_impl) and (
         gm.grouped_matmul_ok(tile, f, d, config.attention_impl)
     )
-    grouped = dict(
-        layer=layer, tile_expert=tile_expert, used=used, tile=tile,
-        kernel=kernel, interpret=jax.default_backend() != "tpu",
-    )
     if kernel:  # which grid each product got, a fact of its shape
         note_grid(*gm.grid_note(tile, d, f, gate_up=True))
         note_grid(*gm.grid_note(tile, f, d))
-    with jax.named_scope("moe_ffn.experts"):
-        hidden = gm.grouped_gate_up(
-            rows, w_gate, w_up, functools.partial(_activation, kind=config.activation),
-            **grouped,
+
+    def experts(rows, tile_expert, used):
+        """[rows, d]: the buffer's rows through their tiles' experts."""
+        grouped = dict(
+            layer=layer, tile_expert=tile_expert, used=used, tile=tile,
+            kernel=kernel, interpret=jax.default_backend() != "tpu",
         )
-        out_rows = gm.grouped_matmul(hidden, w_down, **grouped)  # [tiles * tile, d]
-    with jax.named_scope("moe_ffn.combine"):
-        # an assignment without a row reads out of bounds: zero
-        picked = out_rows.at[dest].get(mode="fill", fill_value=0).reshape(t, k, d)
-        w = jnp.where(local, weights, 0.0)
-        out = jnp.einsum("tkd,tk->td", picked.astype(jnp.float32), w).astype(xf.dtype)
+        with jax.named_scope("moe_ffn.experts"):
+            hidden = gm.grouped_gate_up(
+                rows, w_gate, w_up, functools.partial(_activation, kind=config.activation),
+                **grouped,
+            )
+            return gm.grouped_matmul(hidden, w_down, **grouped)
+
+    if passes is None:
+        out_rows = experts(rows, tile_expert, used)  # [tiles * tile, d]
+        with jax.named_scope("moe_ffn.combine"):
+            # an assignment without a row reads out of bounds: zero
+            picked = out_rows.at[dest].get(mode="fill", fill_value=0).reshape(t, k, d)
+            w = jnp.where(local, weights, 0.0)
+            out = jnp.einsum("tkd,tk->td", picked.astype(jnp.float32), w).astype(xf.dtype)
+    else:
+        out = _held_passes(
+            xf, order, by_expert, weights.reshape(t * k), named["local"], k, held, tile, passes,
+            experts,
+        ).astype(xf.dtype)
     if config.n_shared_experts:
         with jax.named_scope("moe_ffn.shared"):
             gate = _activation(quantized_matmul(xf, lp["ws_gate"]), config.activation)

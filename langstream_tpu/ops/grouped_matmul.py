@@ -6,8 +6,15 @@ rows out in one buffer, each expert's rows starting at a multiple of the row
 tile (`plan_groups`): a tile of rows then belongs to ONE expert, and the
 product is a tiled matmul whose weight block is found through a prefetched
 vector, the tile's expert. Work is done for the tiles that hold rows; the
-buffer is bounded by the assignments (T x k rows and a tile of padding an
-expert), never by T x experts. The weights stay int8 in HBM: a block is
+buffer is bounded by the assignments, never by T x experts. Which buffer:
+where every expert is held, or the call is a decode step, the one that
+"holds every case" (`buffer_tiles`: T x min(k, held) rows and a tile of
+padding an expert); where a layer holds a SHARE of the experts a PASS's
+(`pass_shape`: twice the even share of the call's assignments, 21 tiles
+where 141 hold every case at 12 of 384 experts), and what is over goes
+through a further pass (`transformer.moe_ffn_held`): moving rows costs by
+the row the buffer could hold, and the product's grid by its tiles, whether
+they are used or skipped. The weights stay int8 in HBM: a block is
 converted in VMEM on its way into the MXU and the per-output-channel scale
 multiplies the float32 accumulator once, at the last block of K (what
 `quantized_matmul` does for a dense weight).
@@ -69,13 +76,44 @@ def row_tile(tokens: int, k: int, n_experts: int) -> int:
 
 
 def buffer_tiles(tokens: int, k: int, held: int, tile: int) -> int:
-    """Tiles of the buffer: what holds every case (an expert has at most one
-    row a token, and the experts together at most ``tokens x min(k, held)``
-    rows and a partly filled tile each) and one spare, the last, that never
-    holds a row: the product's skipped tiles all write there."""
+    """Tiles of the buffer that holds EVERY case in one pass (an expert has
+    at most one row a token, and the experts together at most ``tokens x
+    min(k, held)`` rows and a partly filled tile each) and one spare, the
+    last, that never holds a row: the product's skipped tiles all write
+    there. What a call lays out where `pass_shape` finds nothing smaller: a
+    decode step, a layer that holds every expert."""
     per_expert = -(-tokens // tile)
     together = tokens * min(k, held) // tile + held
     return max(1, min(held * per_expert, together)) + 1
+
+
+def pass_shape(tokens: int, k: int, held: int, n_experts: int, tile: int):
+    """(assignments, tiles) of one PASS of a layer that holds a share of the
+    experts, or None where that is no smaller than `buffer_tiles` and the
+    call keeps its one pass. A pass takes twice the even share of the call's
+    assignments (`row_tile`'s rule for a tile), whole tiles of them and never
+    more than there are, in a buffer of a partly filled tile a held expert
+    more, and the spare: what holds even routing twice over, where
+    `buffer_tiles` holds every token's every choice landing here. What is
+    over goes through a further pass (`transformer.moe_ffn_held`)."""
+    assignments = tokens * k
+    even_twice = -(-2 * assignments * held // n_experts)
+    rows = min(assignments, -(-even_twice // tile) * tile)
+    tiles = -(-rows // tile) + held + 1
+    return (rows, tiles) if tiles < buffer_tiles(tokens, k, held, tile) else None
+
+
+def dispatch_note(tokens: int, k: int, held: int, n_experts: int, tile: int) -> tuple[str, str]:
+    """(key, value) for `attention_paths()`: how a call of these shapes lays
+    its rows out, e.g. ``moe-dispatch[t=2048,k=8,held=12/384]`` -> ``passes
+    of 1024, 21 tiles (141 hold every case)``, or ``one pass, 13 tiles``."""
+    every = buffer_tiles(tokens, k, held, tile)
+    shape = pass_shape(tokens, k, held, n_experts, tile)
+    what = (
+        f"one pass, {every} tiles" if shape is None
+        else f"passes of {shape[0]}, {shape[1]} tiles ({every} hold every case)"
+    )
+    return f"moe-dispatch[t={tokens},k={k},held={held}/{n_experts}]", what
 
 
 def plan_groups(expert: jax.Array, held: int, tile: int, tiles: int):

@@ -406,7 +406,7 @@ def test_the_block_chunks_span_says_what_its_passes_did(params):
         "passes", "active_rows", "row_passes", "idle_row_passes", "denoise_row_passes",
         "commit_row_passes", "tokens_fixed", "tokens_delivered", "fixed_over_threshold",
         "kv_tokens_read", "kv_rows_written", "device_ms", "moe_routed", "moe_dropped",
-        "moe_local", "moe_touched", "seq", "program",
+        "moe_local", "moe_touched", "moe_spilled", "seq", "program",
     }
     assert chunks and all(wanted <= set(attrs) for attrs in chunks)
     assert sum(c["tokens_fixed"] for c in chunks) == 8

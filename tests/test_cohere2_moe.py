@@ -139,7 +139,7 @@ def test_the_config_says_what_it_holds():
     assert TINY.has_window and TINY.is_moe and not TINY.is_recurrent
     assert TINY.held_experts == (0, 4) and UNCUT.held_experts == (0, 16)
     assert (TINY.n_layers_of("sliding_attention"), TINY.n_layers_of("full_attention")) == (6, 2)
-    assert T.moe_count_names(TINY) == T.MOE_COUNTS + ("local", "touched")
+    assert T.moe_count_names(TINY) == T.MOE_COUNTS + ("local", "touched", "spilled")
     # mixtral keeps its one-hot dispatch and its four counts
     assert not MODEL_PRESETS["tiny-moe-test"].has_window
     assert T.moe_count_names(MODEL_PRESETS["tiny-moe-test"]) == T.MOE_COUNTS
@@ -305,6 +305,75 @@ def test_no_assignment_of_a_real_token_is_dropped(params):
     assert named["local"] >= 68  # every real token holds a row of expert 2
     want, _ = ref.moe(u[0], lp, family._dims_of(TINY))
     assert rel_err(out[0], want) < SOUND
+
+
+# 4 of 64 experts held: a call of 256 tokens lays out twice its even share
+# (128 assignments a pass, 9 tiles where 33 hold every case) and takes a
+# further pass for what is over (`ops/grouped_matmul.pass_shape`); "pallas":
+# the kernels in interpret mode at lane-aligned widths, "jnp": the einsum
+PASSES = {
+    impl: dataclasses.replace(TINY, n_experts=64, attention_impl=impl, **widths)
+    for impl, widths in (("jnp", {}), ("pallas", {"d_model": 128, "d_ff": 256}))
+}
+# what the held experts' router columns are set to, the local assignments and
+# the spilled ones of 2 x 128 tokens whose second row has 100 real: every
+# real token's four choices on the four held experts, the router as it is
+# seeded, and no token's choice on any of them
+ROUTINGS = {
+    "every-choice-local": (1.0, 228 * 4, 228 * 4 - 128),
+    "as-seeded": (None, None, 0),
+    "none-local": (-1.0, 0, 0),
+}
+
+
+@pytest.mark.parametrize("routing", sorted(ROUTINGS))
+@pytest.mark.parametrize("impl", sorted(PASSES))
+def test_passes_compute_what_one_pass_computes(impl, routing, monkeypatch):
+    """The layer in passes of twice its even share against the same layer
+    over the one buffer that holds every case: the same products, nothing
+    dropped, `spilled` what the first pass did not hold; where one pass holds
+    every local assignment the one buffer's output to the bit (the first
+    pass is summed after the loop by the one buffer's own expression), and
+    under a spill a token's local assignments summed in another order."""
+    from langstream_tpu.ops import grouped_matmul as gm
+    from langstream_tpu.ops.attention import attention_paths
+
+    config = PASSES[impl]
+    column, local, spilled = ROUTINGS[routing]
+    d = config.d_model
+    lp = _take(T.init_params(config, jax.random.PRNGKey(5))["layers"]["full_attention"], 0)
+    if column is not None:
+        lp = {**lp, "router": lp["router"].at[:, :4].set(column)}
+    # all positive, so that a raised column is every token's largest score
+    u = jnp.abs(jax.random.normal(jax.random.PRNGKey(6), (2, 128, d), jnp.float32))
+    valid = jnp.arange(128)[None, :] < jnp.asarray([128, 100])[:, None]
+    out, counts = T.moe_ffn_held(u, lp, config, valid)
+    assert attention_paths()["moe-dispatch[t=256,k=4,held=4/64]"] == (
+        "passes of 128, 9 tiles (33 hold every case)"
+    )
+    named = dict(zip(T.MOE_HELD_COUNTS, (int(c) for c in counts)))
+    assert named["dropped"] == named["dropped_real"] == 0
+    assert named["routed_real"] == 228 * 4
+    assert named["spilled"] == max(named["local"] - 128, 0) == spilled
+    if local is not None:
+        assert (named["local"], named["touched"]) == (local, 4 if local else 0)
+
+    monkeypatch.setattr(gm, "pass_shape", lambda *a: None)
+    whole, counts_whole = T.moe_ffn_held(u, lp, config, valid)
+    assert attention_paths()["moe-dispatch[t=256,k=4,held=4/64]"] == "one pass, 33 tiles"
+    assert [int(c) for c in counts_whole] == [*(int(c) for c in counts[:6]), 0]
+    if named["spilled"]:  # a float32 sum regrouped: a rounding of the sum, no more
+        assert float(jnp.abs(out - whole).max()) <= 4e-7 * float(jnp.abs(whole).max())
+    else:
+        assert bool(jnp.array_equal(out, whole))
+    if routing == "none-local":  # no pass at all: the shared experts' mean alone
+        xf = u.reshape(256, d)
+        gate = T._activation(T.quantized_matmul(xf, lp["ws_gate"]), config.activation)
+        shared = T.quantized_matmul(gate * T.quantized_matmul(xf, lp["ws_up"]), lp["ws_down"])
+        assert bool(jnp.array_equal(out.reshape(256, d), shared * (1.0 / config.n_shared_experts)))
+        assert bool(jnp.array_equal(out, whole))
+    else:
+        assert float(jnp.abs(out).max()) > 0.1
 
 
 # -- (v) faults: each fails by a number ---------------------------------------
@@ -483,6 +552,7 @@ def test_the_engine_serves_it_end_to_end(engine, params):
         assert attrs["moe_dropped"] == 0 and 0 <= attrs["moe_local"] <= attrs["moe_routed_real"]
         assert {"kv_tokens_read", "kv_tokens_read_window", "window_pages_recycled",
                 "moe_touched", "device_ms"} <= set(attrs)
+        assert attrs["moe_spilled"] == 0  # segments of 32 tokens, steps of 2 rows: one pass
     first = next(a for a in segments if a["offset"] == 0)
     assert first["kv_tokens_read"] == 32 * 33 // 2  # query i reads i + 1 columns
     assert first["kv_tokens_read_window"] == 16 * 17 // 2 + 16 * 16  # at most the window's 16

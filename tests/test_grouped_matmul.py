@@ -72,6 +72,56 @@ def test_a_shape_says_its_grid(shape, shared, says):
     assert "shared" not in gm.grid_note(*shape)[1]
 
 
+# (tokens, top-k, held, experts) of the benchmark's configurations at the widths
+# their cells run, and what `pass_shape` makes of each: (assignments a pass,
+# its tiles) where a layer that holds a share lays out twice its even share,
+# None where that is no smaller than the buffer that holds every case
+# (`buffer_tiles`, the second number) and the call keeps its one pass
+THE_RULE = {
+    "kimi-segment": ((2048, 8, 12, 384), (1024, 21), 141),
+    "glm-segment": ((2048, 8, 16, 256), (2048, 33), 145),
+    "cmdaplus-segment": ((2048, 8, 16, 128), (4096, 33), 81),
+    "kimi-decode": ((16, 8, 12, 384), None, 13),
+    "glm-decode": ((16, 8, 16, 256), None, 17),
+    "cmdaplus-decode": ((16, 8, 16, 128), None, 17),
+    # every expert held: twice the even share is every assignment
+    "keye-segment": ((2048, 8, 128, 128), None, 193),
+    "keye-decode": ((8, 8, 128, 128), None, 129),
+    "sdar-block-pass": ((64 * 4, 8, 128, 128), None, 193),
+    "sdar-admit-group": ((8 * 256, 8, 128, 128), None, 193),
+    # a one-row admission group of a short prompt, and a half-held layer
+    "kimi-admit-256": ((256, 8, 12, 384), (128, 21), 141),
+    "half-held-segment": ((2048, 2, 4, 8), None, 13),
+}
+
+
+@pytest.mark.parametrize("case", sorted(THE_RULE))
+def test_shapes_alone_say_who_takes_the_passes(case):
+    (tokens, k, held, n_experts), passes, every = THE_RULE[case]
+    tile = gm.row_tile(tokens, k, n_experts)
+    assert gm.buffer_tiles(tokens, k, held, tile) == every
+    assert gm.pass_shape(tokens, k, held, n_experts, tile) == passes
+    key, says = gm.dispatch_note(tokens, k, held, n_experts, tile)
+    assert key == f"moe-dispatch[t={tokens},k={k},held={held}/{n_experts}]"
+    if passes is None:
+        assert says == f"one pass, {every} tiles"
+    else:
+        size, tiles = passes
+        assert says == f"passes of {size}, {tiles} tiles ({every} hold every case)"
+        # twice the even share in whole tiles, a partly filled tile an expert, the spare
+        assert size % tile == 0 and size >= 2 * tokens * k * held / n_experts > size - tile
+        assert tiles == size // tile + held + 1 < every
+
+
+def test_the_models_that_hold_no_share_never_ask():
+    """Mixtral keeps its one-hot dispatch, the dense models have no expert:
+    none of them reaches `moe_ffn_held` (or counts its seven)."""
+    from langstream_tpu.models.configs import MODEL_PRESETS
+
+    for name in ("mixtral-8x7b", "tiny-moe-test", "llama-3-8b", "olmo-hybrid-7b"):
+        assert not MODEL_PRESETS[name].holds_experts, name
+
+
 def _buffer(tile, k_dim, n_dim, held=6, tokens=40, k=2, layers=2):
     """Rows routed over ``held`` experts (one of them empty), laid out by
     `plan_groups`, and three int8 stacks: more tiles than the rows use."""

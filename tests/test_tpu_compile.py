@@ -389,7 +389,9 @@ def _grouped(config, tokens, layers, down=False):
 
     held, k = config.held_experts[1], config.n_experts_per_tok
     tile = gm.row_tile(tokens, k, config.n_experts)
-    tiles = gm.buffer_tiles(tokens, k, held, tile)
+    # a pass's buffer where the layer holds a share (PR 54), else every case's
+    passes = gm.pass_shape(tokens, k, held, config.n_experts, tile)
+    tiles = passes[1] if passes else gm.buffer_tiles(tokens, k, held, tile)
     d, f = (config.expert_d_ff, config.d_model) if down else (config.d_model, config.expert_d_ff)
     w = {"q": SDS((layers, held, d, f), jnp.int8), "s": SDS((layers, held, 1, f), jnp.float32)}
     return (
@@ -1628,22 +1630,95 @@ SEGMENT_PROGRAMS_AT_PR48 = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(ENGINE_PROGRAMS))
-def test_the_other_models_decode_programs_lower_as_they_did(case, monkeypatch):
-    if case in SEGMENT_PROGRAMS_AT_PR48:
-        from langstream_tpu.models import transformer as T
+# PR 54 gives `moe_ffn_held` a seventh count, `spilled`, and every program of a
+# model that holds its experts returns it. At these tables' widths (segments
+# of 16 tokens, steps of 4 rows) `ops/grouped_matmul.pass_shape` keeps the one
+# pass, so the count is a constant 0 and the ONLY difference of such a
+# program: with `MOE_HELD_COUNTS` patched to its six the tables above and
+# below hold, every row, as they stand (the two tests that read them do so).
+# As the programs are, the seventeen rows lower to what PR 54 left:
+HELD_PRESETS = (
+    "tiny-window-moe-test", "tiny-blockfill-moe-test", "tiny-sparse-moe-test",
+    "tiny-latent-moe-test", "tiny-latent-dense-moe-test",
+)
+HELD_PROGRAMS_AT_PR54 = {
+    "admit/tiny-blockfill-moe-test": "83a8dd64040c2c92",
+    "admit/tiny-latent-dense-moe-test": "8230c7d44364ca62",
+    "admit/tiny-latent-moe-test": "6a1126273c90d921",
+    "admit/tiny-sparse-moe-test": "e1c04d8670b121de",
+    "admit/tiny-window-moe-test": "998fb2590ba979f8",
+    "segment/tiny-latent-moe-test": "f9cb662eb45dabb5",
+    "segment/tiny-sparse-moe-test": "8cb7ab4fc0f2a8cb",
+    "segment/tiny-window-moe-test": "9d29306b35fc5fb4",
+    "tiny-blockfill-moe-test": "bb966f526c25a285",
+    "tiny-latent-dense-moe-test": "013b35a046c1315e",
+    "tiny-latent-moe-test": "770651baa99765f6",
+    "tiny-sparse-moe-test": "1468003eef5a46e9",
+    "tiny-window-moe-test": "1a6f06b4fb927126",
+}
+# the same rows with a segment's rows written by whole pages (PR 48)
+HELD_SEGMENT_PROGRAMS_AT_PR54 = {
+    "segment/tiny-latent-dense-moe-test": "580914ad0aa698a3",
+    "segment/tiny-latent-moe-test": "d07ad1be78f76cc7",
+    "segment/tiny-sparse-moe-test": "6700671fea72817d",
+    "segment/tiny-window-moe-test": "e9c92f7360098646",
+}
 
-        monkeypatch.setattr(T, "_copies_pages", lambda *a: False)
-        jax.clear_caches()  # the jitted program's trace is cached by its arguments' shapes
-    assert _short_hash(_engine_program_text(case)) == ENGINE_PROGRAMS[case]
+
+def _holds_experts(case: str) -> bool:
+    return case.rpartition("/")[2] in HELD_PRESETS
+
+
+@pytest.fixture
+def six_counts(monkeypatch):
+    """The held models' programs without PR 54's count."""
+    from langstream_tpu.models import transformer as T
+
+    monkeypatch.setattr(T, "MOE_HELD_COUNTS", T.MOE_HELD_COUNTS[:6])
+    jax.clear_caches()  # the jitted program's trace is cached by its arguments' shapes
+    yield
+    jax.clear_caches()
+
+
+@pytest.fixture
+def under_the_scatter(monkeypatch):
+    """A segment's rows written by the scatter, as before PR 48."""
+    from langstream_tpu.models import transformer as T
+
+    monkeypatch.setattr(T, "_copies_pages", lambda *a: False)
+    jax.clear_caches()  # the jitted program's trace is cached by its arguments' shapes
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("case", sorted(ENGINE_PROGRAMS))
+def test_the_other_models_decode_programs_lower_as_they_did(case, request):
+    if _holds_experts(case):
+        request.getfixturevalue("six_counts")
     if case in SEGMENT_PROGRAMS_AT_PR48:
-        jax.clear_caches()
+        request.getfixturevalue("under_the_scatter")
+    assert _short_hash(_engine_program_text(case)) == ENGINE_PROGRAMS[case]
 
 
 @pytest.mark.parametrize("case", sorted(SEGMENT_PROGRAMS_AT_PR48))
-def test_the_segment_programs_lower_as_pr48_left_them(case):
+def test_the_segment_programs_lower_as_pr48_left_them(case, request):
+    if _holds_experts(case):
+        request.getfixturevalue("six_counts")
     assert _short_hash(_engine_program_text(case)) == SEGMENT_PROGRAMS_AT_PR48[case]
     assert A.attention_paths()["paged-segment-write[s=16]"] == "paged_insert_pages"
+
+
+@pytest.mark.parametrize("case", sorted(c for c in ENGINE_PROGRAMS if _holds_experts(c)))
+def test_the_held_models_programs_lower_as_pr54_left_them(case, request):
+    """With the seventh count; a segment's row under the scatter as above."""
+    if case in SEGMENT_PROGRAMS_AT_PR48:
+        request.getfixturevalue("under_the_scatter")
+    assert _short_hash(_engine_program_text(case)) == HELD_PROGRAMS_AT_PR54[case]
+
+
+@pytest.mark.parametrize("case", sorted(c for c in SEGMENT_PROGRAMS_AT_PR48 if _holds_experts(c)))
+def test_the_held_models_segments_lower_as_pr54_left_them(case):
+    assert _short_hash(_engine_program_text(case)) == HELD_SEGMENT_PROGRAMS_AT_PR54[case]
 
 
 # What `attention_paths()` says after a prefill over a local cache, a segment
@@ -1829,6 +1904,35 @@ def _traced_paths(case: str) -> dict:
 # tiny presets' pages are a few hundred bytes and their tables hold 6, so a
 # step takes 2 and five slots hold them); the CPU's own choice reads through
 # no kernel and says nothing. Every other key and value is `PATHS_AT_PARENT`'s.
+# And PR 54's, on purpose: an expert layer that holds its experts says how each
+# call lays its rows out, kernels forced or not (`ops/grouped_matmul.
+# dispatch_note`: a fact of the call's tokens, top-k and share). A prefill of 2 x
+# 16 tokens, a segment of 16, a step of 4 rows (a block pass: 4 rows x 4
+# positions): at these widths every call keeps its one pass.
+DISPATCH_AT_PR54 = {
+    "tiny-blockfill-moe-test": {
+        "moe-dispatch[t=32,k=4,held=16/16]": "one pass, 25 tiles",
+        "moe-dispatch[t=16,k=4,held=16/16]": "one pass, 17 tiles",
+    },
+    "tiny-sparse-moe-test": {
+        "moe-dispatch[t=32,k=4,held=16/16]": "one pass, 25 tiles",
+        "moe-dispatch[t=16,k=4,held=16/16]": "one pass, 17 tiles",
+        "moe-dispatch[t=4,k=4,held=16/16]": "one pass, 17 tiles",
+    },
+    "tiny-window-moe-test": {
+        "moe-dispatch[t=32,k=4,held=4/16]": "one pass, 9 tiles",
+        "moe-dispatch[t=16,k=4,held=4/16]": "one pass, 5 tiles",
+        "moe-dispatch[t=4,k=4,held=4/16]": "one pass, 5 tiles",
+    },
+    "tiny-latent-moe-test": {
+        "moe-dispatch[t=32,k=2,held=4/8]": "one pass, 9 tiles",
+        "moe-dispatch[t=16,k=2,held=4/8]": "one pass, 5 tiles",
+        "moe-dispatch[t=4,k=2,held=4/8]": "one pass, 5 tiles",
+    },
+}
+DISPATCH_AT_PR54["tiny-latent-dense-moe-test"] = DISPATCH_AT_PR54["tiny-latent-moe-test"]
+
+
 @pytest.mark.parametrize("case", sorted(PATHS_AT_PARENT))
 def test_every_preset_notes_the_paths_it_did(case):
     walk = {
@@ -1838,4 +1942,30 @@ def test_every_preset_notes_the_paths_it_did(case):
         and re.fullmatch(r"ragged_paged_\w+", kernel)
     }
     assert len(walk) == case.endswith("/pallas")
-    assert _traced_paths(case) == {**PATHS_AT_PARENT[case], **walk}
+    dispatch = DISPATCH_AT_PR54.get(case.rpartition("/")[0], {})
+    assert bool(dispatch) == _holds_experts(case.rpartition("/")[0])
+    assert _traced_paths(case) == {**PATHS_AT_PARENT[case], **walk, **dispatch}
+
+
+# The tables' segments are 16 tokens wide and keep the one pass. ONE program
+# whose shapes take the passes (`moe_ffn_held`'s `lax.while_loop` over windows
+# of the sorted assignments): the window preset's segment at 2,048 tokens (4 of
+# 16 experts held, top-4: twice the even share is 4,096 of its 8,192
+# assignments), as PR 54 left it, and what it says of itself.
+def test_a_segment_wide_enough_takes_the_passes():
+    from langstream_tpu.models.transformer import make_page_pool
+    from langstream_tpu.serving import engine as E
+
+    config, params, _, _ = _tiny_case("tiny-window-moe-test", "pallas")
+    width, table = 2048, 2048 // TINY_PAGE
+    pool = jax.eval_shape(lambda: make_page_pool(config, 2 * table, TINY_PAGE, state_rows=1))
+    f32 = lambda *s: SDS(s, jnp.float32)  # noqa: E731
+    A._PATHS.clear()
+    text = E._paged_segment_and_sample.lower(
+        params, _i32(1, width), _i32(1), _i32(1), pool, _i32(2, 1, table), SDS((2,), jnp.uint32),
+        f32(1), _i32(1), f32(1), config, TINY_PAGE,
+    ).as_text()
+    assert A.attention_paths()["moe-dispatch[t=2048,k=4,held=4/16]"] == (
+        "passes of 4096, 13 tiles (17 hold every case)"
+    )
+    assert _short_hash(text) == "0b5d43e29de6db3a"
