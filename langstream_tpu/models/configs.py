@@ -85,7 +85,8 @@ class ModelConfig:
     rope_scaling_mscale_all_dim: float = 0.0
     # layer pattern (hybrid models): the kinds of one PERIOD of layers,
     # each "linear_attention" (gated delta rule, a recurrent state per
-    # sequence: ops/gated_delta.py) or "full_attention"; n_layers is a whole
+    # sequence: ops/gated_delta.py), "conv" (a gated short convolution, whose
+    # state is its tail alone: ``conv_kernel``) or "full_attention"; n_layers is a whole
     # number of periods and every kind is a stack of its own under
     # params["layers"][kind]. Empty: every layer is the one block above, in
     # one stack. The full layers of a pattern are the OLMo block: the norm
@@ -98,6 +99,11 @@ class ModelConfig:
     linear_conv_kernel: int = 4
     # beta in (0, 2) instead of (0, 1): the state's transition may flip sign
     linear_allow_neg_eigval: bool = False
+    # a "conv" layer (LFM2's): [B | C | u] = in_proj(norm(x)), a causal
+    # depthwise convolution of ``conv_kernel`` taps over B * u with no bias and
+    # no activation, out_proj(C * that); a sequence keeps the last
+    # ``conv_kernel - 1`` inputs of the convolution, d_model wide, a layer
+    conv_kernel: int = 0
     output_norm: bool = False  # full layers: x + norm(f(x)), not x + f(norm(x))
     qk_norm: bool = False
     rope: bool = True  # False: attention turns nothing
@@ -200,14 +206,20 @@ class ModelConfig:
     v_head_dim: int = 0
     # the first ``n_leading_dense`` layers carry a dense FFN of ``d_ff`` in
     # place of the expert layer: a stack of their own, ``params["dense_layers"]``,
-    # run before the expert layers' scan (pool layers 0 .. n_leading_dense - 1)
+    # run before the expert layers' scan (pool layers 0 .. n_leading_dense - 1).
+    # In a model with a ``layer_pattern`` they are layers of their KIND
+    # (``params["dense_layers"][kind]``, a stack a kind that has one): the
+    # periods that hold them run ahead of the period loop, each layer at its
+    # own place in its kind's pages and state (`dense_of`)
     n_leading_dense: int = 0
     # the no-drop expert layer's router (`_route_all`): ``router_bias`` adds a
     # float32 vector a layer, ``lp["router_bias"]``, to the scores that CHOOSE
     # the experts and not to the weights; the chosen weights, normalised, are
-    # multiplied by ``routed_scaling``
+    # multiplied by ``routed_scaling``; ``router_norm_eps`` is added to the sum
+    # the sigmoid scores are divided by (0: nothing is added, and traced)
     router_bias: bool = False
     routed_scaling: float = 1.0
+    router_norm_eps: float = 0.0
 
     @property
     def has_indexer(self) -> bool:
@@ -274,6 +286,24 @@ class ModelConfig:
         """The width the rotary turns: the head's, or a latent model's one
         rotary key's."""
         return self.qk_rope_head_dim if self.has_latent else self.resolved_head_dim
+
+    @property
+    def kv_head_pack(self) -> int:
+        """KV heads that share a 128-lane row of the cache and the page pool:
+        2 for heads of 64 (an even number of them; K and V alone, in the
+        activation dtype, one page group), else 1. A leaf is then
+        [.., Hkv / 2, T, 128], heads 2j and 2j + 1 side by side: the chip's
+        tiled layouts keep a minor dimension of 64 at 128 lanes (a pool twice
+        its bytes, and at a width under a lane row the compiler may lay a leaf
+        out pages-minor: ``index_key_width``), and every paged and prefill
+        kernel takes the packed leaf as a head of 128 (`ops/attention.
+        pair_queries`: a query reads its own half)."""
+        narrow = self.resolved_head_dim == 64 and self.n_kv_heads % 2 == 0
+        plain = not (
+            self.has_latent or self.has_indexer or self.has_window or self.fills_blocks
+            or self.ring_axis is not None or self.kv_cache_dtype == "int8"
+        )
+        return 2 if narrow and plain else 1
 
     def kv_bytes_per_token(self, itemsize: int = 2) -> int:
         """Bytes a token holds in the page pool's full-attention group, over
@@ -347,7 +377,15 @@ class ModelConfig:
 
     @property
     def is_recurrent(self) -> bool:
-        return "linear_attention" in self.layer_pattern
+        """A sequence keeps a row of state beside its pages: the delta rule's
+        state and its convolution's tail, or a "conv" layer's tail alone."""
+        return "linear_attention" in self.layer_pattern or "conv" in self.layer_pattern
+
+    def dense_of(self, kind: str) -> int:
+        """How many of the leading dense layers of a pattern model are of
+        ``kind``: where the kind's expert stack starts among its layers."""
+        pattern, n = self.layer_pattern, self.n_leading_dense
+        return sum(pattern[i % len(pattern)] == kind for i in range(n)) if pattern else 0
 
     @property
     def n_periods(self) -> int:
@@ -376,12 +414,19 @@ class ModelConfig:
     def __post_init__(self) -> None:
         if self.layer_pattern:
             unknown = set(self.layer_pattern) - {
-                "linear_attention", "full_attention", "sliding_attention"
+                "linear_attention", "conv", "full_attention", "sliding_attention"
             }
             if unknown or self.n_layers % len(self.layer_pattern):
                 raise ValueError(
                     f"{self.name}: layer_pattern {self.layer_pattern} over "
                     f"{self.n_layers} layers"
+                )
+            if "conv" in self.layer_pattern and (
+                self.conv_kernel < 2 or "linear_attention" in self.layer_pattern
+            ):
+                raise ValueError(
+                    f"{self.name}: conv layers need conv_kernel >= 2 and no "
+                    "linear_attention layer beside them (one tail a state row)"
                 )
             if self.has_window and (
                 self.sliding_window < 1 or self.is_recurrent or not self.is_moe
@@ -391,6 +436,10 @@ class ModelConfig:
                     "recurrent layer beside them and an expert layer "
                     "(n_experts > 0): their block is the parallel one"
                 )
+        if self.conv_kernel and "conv" not in self.layer_pattern:
+            raise ValueError(
+                f"{self.name}: conv_kernel belongs to a layer_pattern with conv layers"
+            )
         window_only = {
             "sliding_window": self.sliding_window > 0,
             "rope_interleaved": self.rope_interleaved,
@@ -401,6 +450,8 @@ class ModelConfig:
         if self.has_latent:  # its sequential block reads these three too
             for key in ("rope_interleaved", "moe_scoring", "n_shared_experts"):
                 window_only[key] = False
+        if "conv" in self.layer_pattern:  # the router of its sequential block
+            window_only["moe_scoring"] = False
         if not self.has_window and any(window_only.values()):
             raise ValueError(
                 f"{self.name}: {', '.join(k for k, on in window_only.items() if on)} "
@@ -413,11 +464,11 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name}: experts_held {self.experts_held} of {self.n_experts}"
                 )
-            if self.is_recurrent or self.output_norm:
+            if "linear_attention" in self.layer_pattern or self.output_norm:
                 raise ValueError(
                     f"{self.name}: experts_held belongs to a pre-norm block "
                     "(the sequential or the parallel one), not to a layer "
-                    "pattern with recurrent layers or an output norm"
+                    "pattern with delta-rule layers or an output norm"
                 )
         if self.moe_d_ff > 0 and not self.holds_experts:
             raise ValueError(
@@ -541,17 +592,20 @@ class ModelConfig:
                 "with a rope_scaling_factor and beta_fast > beta_slow > 0"
             )
         if self.n_leading_dense and not (
-            self.has_latent and self.experts_held and 0 < self.n_leading_dense < self.n_layers
+            (self.has_latent or "conv" in self.layer_pattern)
+            and self.experts_held and 0 < self.n_leading_dense < self.n_layers
         ):
             raise ValueError(
                 f"{self.name}: n_leading_dense {self.n_leading_dense} belongs to a model "
-                "with a latent whose later layers hold experts (experts_held), and "
-                "leaves an expert layer"
+                "with a latent, or with a pattern of conv layers, whose later layers "
+                "hold experts (experts_held), and leaves an expert layer"
             )
-        if (self.router_bias or self.routed_scaling != 1.0) and not self.holds_experts:
+        if (
+            self.router_bias or self.routed_scaling != 1.0 or self.router_norm_eps
+        ) and not self.holds_experts:
             raise ValueError(
-                f"{self.name}: router_bias and routed_scaling are read by the no-drop "
-                "expert layer's router (window layers, or experts_held)"
+                f"{self.name}: router_bias and routed_scaling (router_norm_eps with them) "
+                "are read by the no-drop expert layer's router (window layers, or experts_held)"
             )
         if self.mrope_section and (
             len(self.mrope_section) != 3
@@ -585,14 +639,17 @@ class ModelConfig:
                 self.linear_value_dim * d
             )
             n_lin = self.n_layers_of("linear_attention")
-            mixers = n_lin * linear + (self.n_layers - n_lin) * attn
+            n_conv = self.n_layers_of("conv")  # in_proj d x 3d, out_proj d x d
+            mixers = (
+                n_lin * linear + n_conv * 4 * d * d + (self.n_layers - n_lin - n_conv) * attn
+            )
             embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
-            ffn = 3 * d * self.d_ff
+            ffn = dense = 3 * d * self.d_ff
             if self.is_moe:  # what is HELD here, not the published count
                 ffn = 3 * d * self.expert_d_ff * (
                     self.held_experts[1] + self.n_shared_experts
                 ) + d * self.n_experts
-            return mixers + self.n_layers * ffn + embed
+            return mixers + self.n_layers * ffn + self.n_leading_dense * (dense - ffn) + embed
         if self.is_moe:  # what is HELD here, where the layer holds a share
             held = self.held_experts[1] if self.experts_held else self.n_experts
             ffn = (held + self.n_shared_experts) * 3 * d * self.expert_d_ff + d * self.n_experts
@@ -912,6 +969,36 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         qk_nope_head_dim=16,
         qk_rope_head_dim=8,
         v_head_dim=16,
+    ),
+    "tiny-lfm2-test": _preset(
+        # the LFM2-MoE block at test size (tests/test_lfm2_moe.py): (conv,
+        # conv, full, conv) x2, the first two layers dense; a convolution of 3
+        # taps; 4 heads of 64 over 2 KV heads (two to a lane row in cache and
+        # pool), per-head q/k norm; 8 sigmoid-routed experts top-2 of width
+        # 32 chosen under a bias, all held, the sum under + 1e-6; a tied head
+        name="tiny-lfm2-test",
+        vocab_size=512,
+        d_model=256,
+        n_layers=8,
+        n_heads=4,
+        n_kv_heads=2,
+        d_ff=128,
+        head_dim=64,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-5,
+        max_seq_len=1024,
+        tie_embeddings=True,
+        layer_pattern=("conv", "conv", "full_attention", "conv"),
+        conv_kernel=3,
+        qk_norm_heads=True,
+        n_experts=8,
+        n_experts_per_tok=2,
+        moe_d_ff=32,
+        experts_held=(0, 8),
+        moe_scoring="sigmoid",
+        router_bias=True,
+        router_norm_eps=1e-6,
+        n_leading_dense=2,
     ),
     "olmo-hybrid-7b": _preset(
         # allenai/Olmo-Hybrid-7B config.json: (gated delta-rule x3, full

@@ -23,9 +23,10 @@ from langstream_tpu.models.configs import ModelConfig
 Params = dict
 
 # stacked-layer matmul weights that dominate HBM traffic
-# "wqkv", "wg": a linear-attention layer's q, k, v side by side and its output gate
+# "wqkv", "wg": a linear-attention layer's q, k, v side by side and its output gate;
+# "w_in", "w_out": a conv layer's two projections
 _QUANT_LAYER_KEYS = (
-    "wq", "wk", "wv", "wqkv", "wg", "wo", "w_gate", "w_up", "w_down",
+    "wq", "wk", "wv", "wqkv", "wg", "wo", "w_gate", "w_up", "w_down", "w_in", "w_out",
     "wq_idx", "wk_idx",
     "ws_gate", "ws_up", "ws_down",
     # latent attention: the two down-projections and the two up-projections
@@ -80,7 +81,9 @@ def quantize_params(params: Params, config: ModelConfig) -> Params:
         out["layers"] = {kind: stack(s) for kind, s in params["layers"].items()}
     else:
         out["layers"] = stack(params["layers"])
-    if "dense_layers" in params:  # the leading dense layers' stack
+    if "dense_layers" in params and config.layer_pattern:  # a stack a kind
+        out["dense_layers"] = {kind: stack(s) for kind, s in params["dense_layers"].items()}
+    elif "dense_layers" in params:  # the leading dense layers' stack
         out["dense_layers"] = stack(params["dense_layers"])
     if "lm_head" in params:
         out["lm_head"] = quantize_weight(params["lm_head"])

@@ -48,6 +48,7 @@ SCOPES = (
     "kv_pool.write", "head", "sample",
     "linear_attention", "linear_attention.proj", "linear_attention.conv",
     "linear_attention.state", "linear_attention.out",
+    "short_conv", "short_conv.proj", "short_conv.conv", "short_conv.out",
     "attention.window", "attention.full", "moe_ffn.shared",
     "block_choice",
     "attention.index", "attention.index.scores", "attention.select", "attention.sparse",
@@ -230,7 +231,8 @@ def _init_pattern_params(config: ModelConfig, key: jax.Array, dtype) -> Params:
         }
 
     n_lin = config.n_layers_of("linear_attention")
-    n_full = config.n_layers_of("full_attention")
+    # (a pattern of conv layers makes all its stacks below)
+    n_full = 0 if "conv" in config.layer_pattern else config.n_layers_of("full_attention")
     # the published initialisation's draw (fla's GatedDeltaNet): A uniform
     # in (0, 16), the step log-uniform in (0.001, 0.1) through the inverse
     # softplus, so that the decay spans a real range
@@ -278,7 +280,74 @@ def _init_pattern_params(config: ModelConfig, key: jax.Array, dtype) -> Params:
     }
     if not config.tie_embeddings:
         params["lm_head"] = normal(d, v)
+    if "conv" in config.layer_pattern:
+        params.update(_init_conv_pattern_layers(config, jax.random.fold_in(key, 17), dtype))
     return params
+
+
+def _init_conv_pattern_layers(config: ModelConfig, key: jax.Array, dtype) -> dict:
+    """The stacks of a pattern of conv and full-attention layers (LFM2's):
+    ``{"layers": {kind: ...}, "dense_layers": {kind: ...}}``. A kind's stack
+    under ``"layers"`` holds its layers BEHIND the leading dense ones
+    (``config.dense_of``), each a mixer (conv: ``attn_norm`` (the published
+    operator_norm), ``w_in`` [d, 3d] for B | C | u, ``conv_w`` [K, d], ``w_out``
+    [d, d]; attention: the four projections and the per-head q/k norms) and
+    its FFN: the router over all experts, its bias, the held experts; the
+    kind's leading dense layers lie under ``"dense_layers"``, the same mixer
+    with a dense FFN of ``d_ff``."""
+    d, h, hkv, hd = config.d_model, config.n_heads, config.n_kv_heads, config.resolved_head_dim
+    e, held, f = config.n_experts, config.held_experts[1], config.expert_d_ff
+    keys = iter(jax.random.split(key, 64))
+
+    def normal(*shape, dt=dtype):  # [..., in, out]: N(0, 1 / in)
+        w = jax.random.normal(next(keys), shape, jnp.float32) * shape[-2] ** -0.5
+        return w.astype(dt)
+
+    def mixer(kind, n):
+        if kind == "conv":
+            return {
+                "attn_norm": jnp.ones((n, d), dtype), "w_in": normal(n, d, 3 * d),
+                "conv_w": (
+                    jax.random.normal(next(keys), (n, config.conv_kernel, d), jnp.float32)
+                    * config.conv_kernel ** -0.5
+                ).astype(dtype),
+                "w_out": normal(n, d, d),
+            }
+        norms = {"q_norm": jnp.ones((n, hd), dtype), "k_norm": jnp.ones((n, hd), dtype)}
+        return {
+            "attn_norm": jnp.ones((n, d), dtype),
+            "wq": normal(n, d, h * hd), "wk": normal(n, d, hkv * hd),
+            "wv": normal(n, d, hkv * hd), "wo": normal(n, h * hd, d),
+            **(norms if config.qk_norm_heads else {}),
+        }
+
+    def ffn(n, dense):
+        if dense or not config.is_moe:
+            f_d = config.d_ff
+            return {
+                "ffn_norm": jnp.ones((n, d), dtype), "w_gate": normal(n, d, f_d),
+                "w_up": normal(n, d, f_d), "w_down": normal(n, f_d, d),
+            }
+        out = {
+            "ffn_norm": jnp.ones((n, d), dtype),
+            "router": normal(n, d, e, dt=jnp.float32 if config.router_bias else dtype),
+            "w_gate": normal(n, held, d, f), "w_up": normal(n, held, d, f),
+            "w_down": normal(n, held, f, d),
+        }
+        if config.router_bias:
+            out["router_bias"] = 0.05 * jax.random.normal(next(keys), (n, e), jnp.float32)
+        return out
+
+    stacks: dict = {"layers": {}, "dense_layers": {}}
+    for kind in dict.fromkeys(config.layer_pattern):
+        first = config.dense_of(kind)
+        if first:
+            stacks["dense_layers"][kind] = {**mixer(kind, first), **ffn(first, True)}
+        stacks["layers"][kind] = {
+            **mixer(kind, config.n_layers_of(kind) - first),
+            **ffn(config.n_layers_of(kind) - first, False),
+        }
+    return {k: v for k, v in stacks.items() if v}
 
 
 def _init_window_params(config: ModelConfig, key: jax.Array, dtype) -> Params:
@@ -785,7 +854,7 @@ def _dispatch_attention(
     note_path(what, "jnp", config, s=s, t=t)
     if mask is None:
         mask = _seen(positions, t, window, kv_limit)
-    return attention(q, k_all, v_all, mask, config)
+    return attention(q, _unpacked(k_all, config), _unpacked(v_all, config), mask, config)
 
 
 def _activation(x: jax.Array, kind: str) -> jax.Array:
@@ -1355,6 +1424,11 @@ def _paged_attention(
             pk, pv = ops.paged_kv_write(
                 rows, pk, pv, pages[:, 0], offs[:, 0], layer, config, interpret=interpret
             )
+            if config.kv_head_pack > 1:
+                # a model new with its packed rows says who writes them (every
+                # other model's notes are the ones they were: the write rides
+                # the paged decode's entry, the same gate admits both)
+                ops.note_grid(f"paged-decode-write[s={s}]", "paged_kv_write")
             if index is not None:
                 pik = [_write_index_key(pik[0], layer, index[1], table, positions, page_size)]
         else:
@@ -1879,8 +1953,23 @@ def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
     if config.rope:
         q = apply_rope(q, sin, cos)
         k = apply_rope(k, sin, cos)
-
+    if config.kv_head_pack > 1:
+        # heads of 64 travel two to a lane row from here on, as the cache and
+        # the pool keep them: [B, S, Hkv / 2, 128]
+        k, v = (a.reshape(b, s, -1, config.kv_head_pack * hd) for a in (k, v))
     return q, k, v
+
+
+def _unpacked(leaf, config):
+    """A cache leaf [B, Hkv / pack, T, pack x D] as the jnp reference reads
+    it, [B, Hkv, T, D] (``config.kv_head_pack`` 1: as it is)."""
+    pack = config.kv_head_pack
+    if pack == 1:
+        return leaf
+    b, rows, t, width = leaf.shape
+    return leaf.reshape(b, rows, t, pack, width // pack).transpose(0, 1, 3, 2, 4).reshape(
+        b, rows * pack, t, width // pack
+    )
 
 
 def _attn_residual(x, attn_out, lp, config, adapter_term=None):
@@ -1924,7 +2013,7 @@ def _dense_attention(
     if cache_kv is not None:
         ck, cv = cache_kv  # [B, Hkv, T, D] head-major (maybe int8-quantized)
         # scatter this step's k/v into the cache at cache_positions [B, S]
-        hkv = config.n_kv_heads
+        hkv = k.shape[2]  # (heads of 64: packed rows)
         bidx = jnp.arange(b)[:, None, None]
         hidx = jnp.arange(hkv)[None, :, None]
         pidx = cache_positions[:, None, :]  # [B, 1, S]
@@ -2082,7 +2171,9 @@ def _layer(*args, **kwargs):
 # layers, a RECURRENT STATE a sequence: ``{"s": [Ll, R, dk, H * dv] float32,
 # "conv": [Ll, R, (K - 1) * C]}`` over its Ll linear layers and R rows (a row
 # belongs to a serving slot), the rule's state and the last K - 1 inputs of
-# the short convolution. It travels as ``pool["rec"]``, beside the pool's
+# the short convolution; a model of "conv" layers (a gated short convolution,
+# `_short_conv_block`) keeps that tail ALONE, ``{"conv": [Lc, R, (K - 1) *
+# d_model]}``. It travels as ``pool["rec"]``, beside the pool's
 # "k" and "v", donated and carried like them. What a call does to it is told
 # by ``rctx``: ``rows`` [B] (each batch row's state row; out of bounds drops
 # the write; None: batch row b IS state row b), ``valid`` [B, S] (a prefix of
@@ -2093,6 +2184,15 @@ def _layer(*args, **kwargs):
 
 
 def make_recurrent_state(config: ModelConfig, rows: int, dtype=None) -> dict:
+    """The leaves of the kinds the model has: the delta rule's state and its
+    convolution's tail, or a conv layer's tail alone."""
+    if "conv" in config.layer_pattern:
+        return {
+            "conv": jnp.zeros(
+                (config.n_layers_of("conv"), rows, (config.conv_kernel - 1) * config.d_model),
+                dtype or _dtype(config),
+            ),
+        }
     n = config.n_layers_of("linear_attention")
     return {
         "s": jnp.zeros(
@@ -2125,6 +2225,44 @@ def _set_rows(leaf, layer, rows, new):
     return leaf.at[layer, rows].set(new.astype(leaf.dtype), mode="drop")
 
 
+def _short_conv(inputs, taps, rec, layer, rows, valid, fresh, activation=None):
+    """A causal depthwise convolution of K taps over each row's last K - 1
+    inputs and these: (float32 [B, S, C], the state with this layer's tails
+    written). ``inputs`` [B, S, C], ``taps`` [K, C]; the tail a row carries in
+    is its state's (``rec["conv"]`` [L, R, (K - 1) * C] at ``layer``,
+    ``rows``), zeros for a ``fresh`` row and without a state; the tail it
+    leaves is its last K - 1 REAL inputs (``valid`` [B, S], a prefix a row):
+    a ragged row's come from before its padding, a row shorter than K - 1
+    keeps what it carried in behind its own, and a decode step (S = 1) moves a
+    live row's tail on by one and leaves an idle row's as it is. The one
+    place both recurrent kinds (the delta rule's q, k, v and a conv layer's
+    gated input) take and leave their tails."""
+    b, s, _ = inputs.shape
+    width = taps.shape[0]
+    keep_old = rec is not None and fresh is not True
+    tail = jnp.zeros((b, width - 1, inputs.shape[-1]), inputs.dtype)
+    if keep_old:
+        tail = _rows_of(rec["conv"], layer, rows).reshape(tail.shape)
+        if fresh is not None:
+            tail = jnp.where(fresh[:, None, None], jnp.zeros((), tail.dtype), tail)
+    window = jnp.concatenate([tail, inputs], axis=1)  # [B, K - 1 + S, C]
+    taps = taps.astype(jnp.float32)
+    mixed = sum(
+        window[:, i : i + s].astype(jnp.float32) * taps[i] for i in range(width)
+    )
+    if activation is not None:
+        mixed = activation(mixed)
+    if rec is not None:
+        # the last K - 1 REAL inputs: the window from the row's count on
+        if s == 1:  # a live row's window moves on by one, an idle row's stays
+            new_tail = jnp.where(valid[:, :, None], window[:, 1:], window[:, :-1])
+        else:
+            at = valid.sum(axis=1, dtype=jnp.int32)[:, None] + jnp.arange(width - 1)
+            new_tail = jnp.take_along_axis(window, at[:, :, None], axis=1)
+        rec = {**rec, "conv": _set_rows(rec["conv"], layer, rows, new_tail.reshape(b, -1))}
+    return mixed, rec
+
+
 def _linear_attention_block(x, lp, config, rec, layer, rctx):
     """x + GDN(norm(x)) for [B, S, d], and the state with this layer's rows
     written (``rec`` None: from the zero state, nothing kept)."""
@@ -2133,7 +2271,7 @@ def _linear_attention_block(x, lp, config, rec, layer, rctx):
 
     b, s, _ = x.shape
     h, dk, dv = config.linear_n_heads, config.linear_key_head_dim, config.linear_value_head_dim
-    kd, width = config.linear_key_dim, config.linear_conv_kernel
+    kd = config.linear_key_dim
     valid = rctx["valid"] if rctx else jnp.ones((b, s), jnp.bool_)
     rows = rctx["rows"] if rctx else None
     # True: every row starts from the zero state; [B] bool: those rows do
@@ -2154,26 +2292,9 @@ def _linear_attention_block(x, lp, config, rec, layer, rctx):
         g = jnp.where(valid[..., None], g, 0.0)
         beta = jnp.where(valid[..., None], beta, 0.0)
     with jax.named_scope("linear_attention.conv"):
-        # causal depthwise convolution over the last K - 1 inputs and these
-        tail = jnp.zeros((b, width - 1, qkv.shape[-1]), qkv.dtype)
-        if keep_old:
-            tail = _rows_of(rec["conv"], layer, rows).reshape(tail.shape)
-            if fresh is not None:
-                tail = jnp.where(fresh[:, None, None], jnp.zeros((), tail.dtype), tail)
-        window = jnp.concatenate([tail, qkv], axis=1)  # [B, K - 1 + S, C]
-        taps = lp["conv_w"].astype(jnp.float32)
-        mixed = sum(
-            window[:, i : i + s].astype(jnp.float32) * taps[i] for i in range(width)
+        mixed, rec = _short_conv(
+            qkv, lp["conv_w"], rec, layer, rows, valid, fresh, activation=jax.nn.silu
         )
-        mixed = jax.nn.silu(mixed)
-        if rec is not None:
-            # the last K - 1 REAL inputs: the window from the row's count on
-            if s == 1:  # a live row's window moves on by one, an idle row's stays
-                new_tail = jnp.where(valid[:, :, None], window[:, 1:], window[:, :-1])
-            else:
-                at = valid.sum(axis=1, dtype=jnp.int32)[:, None] + jnp.arange(width - 1)
-                new_tail = jnp.take_along_axis(window, at[:, :, None], axis=1)
-            rec = {**rec, "conv": _set_rows(rec["conv"], layer, rows, new_tail.reshape(b, -1))}
         q = gd.l2norm(mixed[..., :kd].reshape(b, s, h, dk)) * dk**-0.5
         k = gd.l2norm(mixed[..., kd : 2 * kd].reshape(b, s, h, dk))
         v = mixed[..., 2 * kd :].reshape(b, s, h, dv)
@@ -2221,6 +2342,28 @@ def _linear_layer(x, lp, config, rec, layer, rctx):
         x, rec = _linear_attention_block(x, lp, config, rec, layer, rctx)
     y, _ = _ffn_half(x, lp, config)
     return y, rec
+
+
+def _short_conv_block(x, lp, config, rec, layer, rctx):
+    """x + out_proj(C * conv(B * u)), [B | C | u] = in_proj(norm(x)), for
+    [B, S, d] (LFM2's conv mixer: no bias, no activation), and the state with
+    this layer's tails written (``rec`` None: from zeros, nothing kept)."""
+    from langstream_tpu.ops.attention import note_path
+
+    b, s, d = x.shape
+    valid = rctx["valid"] if rctx else jnp.ones((b, s), jnp.bool_)
+    rows = rctx["rows"] if rctx else None
+    fresh = rctx.get("fresh") if rctx else True
+    note_path("short-conv", "short_conv", config, s=s, t=0)
+    with jax.named_scope("short_conv.proj"):
+        gates = quantized_matmul(rms_norm(x, lp["attn_norm"], config.rms_norm_eps), lp["w_in"])
+        gate_b, gate_c, u = gates[..., :d], gates[..., d : 2 * d], gates[..., 2 * d :]
+    with jax.named_scope("short_conv.conv"):
+        mixed, rec = _short_conv(gate_b * u, lp["conv_w"], rec, layer, rows, valid, fresh)
+        gated = (gate_c.astype(jnp.float32) * mixed).astype(x.dtype)
+    with jax.named_scope("short_conv.out"):
+        x = x + quantized_matmul(gated, lp["w_out"])
+    return x, rec
 
 
 # ---------------------------------------------------------------------------
@@ -2273,7 +2416,10 @@ def _route_all(xf: jax.Array, router: jax.Array, config: ModelConfig, bias=None)
         else:
             _, chosen = lax.top_k(scores + bias.astype(jnp.float32), config.n_experts_per_tok)
             top = jnp.take_along_axis(scores, chosen, axis=-1)
-        weights = top / jnp.sum(top, axis=-1, keepdims=True)
+        total = jnp.sum(top, axis=-1, keepdims=True)
+        if config.router_norm_eps:  # (0: the division every other model traces)
+            total = total + config.router_norm_eps
+        weights = top / total
     elif bias is not None:
         raise NotImplementedError(f"{config.name}: a router bias under softmax scoring")
     else:
@@ -2560,16 +2706,31 @@ def _linear_kind(x, lp, kind, layer, entry, rec, config, walk):
     return x, entry, rec, None
 
 
-def _sequential_kind(x, lp, kind, layer, entry, rec, config, walk):
+def _conv_kind(x, lp, kind, layer, entry, rec, config, walk, dense=False, stack_at=None):
+    """A ``conv`` layer: the gated short convolution, then the pre-norm FFN,
+    the expert layer's (its experts' stacks read at ``stack_at``, its place
+    behind the kind's leading dense layers) or, ``dense``, a leading layer's."""
+    with jax.named_scope("short_conv"):
+        x, rec = _short_conv_block(x, lp, config, rec, layer, walk["rctx"])
+    y, counts = _ffn_half(
+        x, lp, config, token_valid=walk["token_valid"],
+        layer=layer if stack_at is None else stack_at, dense=dense,
+    )
+    return y, entry, rec, counts if config.is_moe and not dense else None
+
+
+def _sequential_kind(x, lp, kind, layer, entry, rec, config, walk, dense=False, stack_at=None):
     """The sequential block as a pattern's full-attention layer
-    (Olmo-Hybrid's): over the pool's pages where there is a table, else over
-    ITS entry of the local cache."""
-    x, entry, _ = _layer_counted(
+    (Olmo-Hybrid's, LFM2's): over the pool's pages where there is a table,
+    else over ITS entry of the local cache. ``dense``, ``stack_at``: as
+    `_conv_kind`'s."""
+    x, entry, counts = _layer_counted(
         x, lp, walk["sin"], walk["cos"], walk["mask"], config, cache_kv=entry,
         cache_positions=walk["positions"], paged_table=walk["tables"],
-        page_size=walk["page_size"], layer=layer,
+        page_size=walk["page_size"], layer=layer, token_valid=walk["token_valid"],
+        moe_layer=stack_at, dense=dense,
     )
-    return x, entry, rec, None
+    return x, entry, rec, counts if config.is_moe and not dense else None
 
 
 def _parallel_kind(x, lp, kind, layer, entry, rec, config, walk):
@@ -2594,7 +2755,7 @@ def _kind_layers(config: ModelConfig) -> dict:
     sequential one."""
     attention = _parallel_kind if config.has_window else _sequential_kind
     return {
-        "linear_attention": _linear_kind, "full_attention": attention,
+        "linear_attention": _linear_kind, "conv": _conv_kind, "full_attention": attention,
         "sliding_attention": attention,
     }
 
@@ -2608,7 +2769,13 @@ def _scan_periods(params, x, config, state=None, rec=None, **walk):
     in place as in `_scan_layers_inplace`, the recurrent state beside it.
     The sequential block writes a local cache an entry a layer: its local
     cache (``state`` without tables, the admit group's temporary) rides the
-    xs and comes back as ys. Returns (x, state, rec, counts)."""
+    xs and comes back as ys. Leading dense layers (``n_leading_dense``) are
+    layers of their kinds, the first of them: the periods that hold them run
+    AHEAD of the scan, the same body with those layers' weights from
+    ``params["dense_layers"][kind]``, at their own places in their kind's
+    pages and state; a kind's stack under ``params["layers"]`` then starts
+    behind its dense layers (``config.dense_of``). Returns (x, state, rec,
+    counts)."""
     pattern = config.layer_pattern
     per = {kind: pattern.count(kind) for kind in set(pattern)}
     periods = config.n_periods
@@ -2617,18 +2784,21 @@ def _scan_periods(params, x, config, state=None, rec=None, **walk):
     # held experts' weights go on as the stack: the grouped product reads its
     # blocks at (layer, expert) where they lie
     whole = _HELD_EXPERTS if config.holds_experts else ()
+    ahead = -(-config.n_leading_dense // len(pattern))  # periods with a dense layer
+    behind = {kind: config.dense_of(kind) for kind in per}
     local = None
     if state is not None and walk["tables"] is None and not config.has_window:
         local, state = jax.tree.map(
             lambda a: a.reshape(periods, per["full_attention"], *a.shape[1:]), state
         ), None
 
-    def body(carry, inputs):
+    def period(carry, local_p, p, dense=0):
+        """Period ``p``'s layers, the first ``dense`` of them leading dense
+        layers (a period of the scan has none)."""
         x, state, rec, counts = carry
-        local_p, p = inputs
         at = dict.fromkeys(per, 0)
         written = []
-        for kind in pattern:
+        for place, kind in enumerate(pattern):
             i = at[kind]
             at[kind] += 1
             layer = p * per[kind] + i
@@ -2637,11 +2807,18 @@ def _scan_periods(params, x, config, state=None, rec=None, **walk):
             # scanned stack is a buffer of its own, all of a period's weights
             # copied once more a step (a third of the decode step on a v5e,
             # PERF.md section 6, PR 32)
+            if place < dense:
+                stack, kept, at_stack = params["dense_layers"][kind], (), layer
+                how = {"dense": True}
+            else:
+                stack, kept = stacks[kind], whole
+                at_stack = layer - behind[kind] if behind[kind] else layer
+                how = {"stack_at": at_stack} if behind[kind] else {}
             lp = {
-                key: leaf if key in whole else jax.tree.map(
-                    lambda a: lax.dynamic_index_in_dim(a, layer, 0, keepdims=False), leaf
+                key: leaf if key in kept else jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(a, at_stack, 0, keepdims=False), leaf
                 )
-                for key, leaf in stacks[kind].items()
+                for key, leaf in stack.items()
             }
             entry = None
             if kind in _KIND_KEY and local_p is not None:
@@ -2651,7 +2828,7 @@ def _scan_periods(params, x, config, state=None, rec=None, **walk):
                 )
             elif kind in _KIND_KEY and state is not None:
                 entry = _kind_entry(state, kind)
-            x, entry, rec, c = layers[kind](x, lp, kind, layer, entry, rec, config, walk)
+            x, entry, rec, c = layers[kind](x, lp, kind, layer, entry, rec, config, walk, **how)
             if c is not None:
                 counts = counts + c
             if entry is not None and local_p is not None:
@@ -2667,10 +2844,23 @@ def _scan_periods(params, x, config, state=None, rec=None, **walk):
         return (x, state, rec, counts), ys
 
     counts = jnp.zeros(len(moe_count_names(config)), jnp.int32) if config.is_moe else None
+    carry, first = (x, state, rec, counts), []
+    for p in range(ahead):
+        carry, ys = period(
+            carry, None if local is None else jax.tree.map(lambda a: a[p], local), p,
+            dense=min(config.n_leading_dense - p * len(pattern), len(pattern)),
+        )
+        first.append(ys)
     (x, state, rec, counts), ys = lax.scan(
-        body, (x, state, rec, counts), (local, jnp.arange(periods))
+        lambda carry, inputs: period(carry, *inputs), carry,
+        (
+            local if not ahead or local is None else jax.tree.map(lambda a: a[ahead:], local),
+            jnp.arange(ahead, periods) if ahead else jnp.arange(periods),
+        ),
     )
     if local is not None:
+        if ahead:
+            ys = jax.tree.map(lambda *a: jnp.concatenate([jnp.stack(a[:-1]), a[-1]]), *first, ys)
         state = jax.tree.map(lambda a: a.reshape(-1, *a.shape[2:]), ys)
     return x, state, rec, counts
 
@@ -2877,7 +3067,7 @@ def _run_layers(
     every row, as it has: S5). ``rec_rows``, ``fresh``: the recurrent
     state's rows and which start from zero (`_linear_attention_block`).
     Returns (x, state, the layers' summed counts, `moe_count_names`)."""
-    if config.n_leading_dense:
+    if config.n_leading_dense and not config.layer_pattern:
         return _run_behind_dense_layers(
             params, x, sin, cos, mask, config, positions, state, table, page_size, counted
         )
@@ -3007,9 +3197,10 @@ def make_kv_cache(
     dtype = dtype or _dtype(config)
 
     def leaves(kind: str, rows: int) -> KVCache:
+        pack = config.kv_head_pack  # heads of 64: two to a lane row
         shape = (
-            config.n_layers_of(kind), rows, config.n_kv_heads, max_len,
-            config.resolved_head_dim,
+            config.n_layers_of(kind), rows, config.n_kv_heads // pack, max_len,
+            config.resolved_head_dim * pack,
         )
         if config.has_latent:
             # ONE row a token in place of K and V, K's layout with one head:
@@ -3334,7 +3525,12 @@ def paged_insert_cache(
         }
     n = tables.shape[0]
     width = jax.tree.leaves(local_cache)[0].shape[3]
-    if insert_copies_pages(pool, width, page_size, config):
+    by_page = insert_copies_pages(pool, width, page_size, config)
+    if config is not None and config.kv_head_pack > 1:  # (as `paged-decode-write`)
+        from langstream_tpu.ops.attention import note_grid
+
+        note_grid(f"paged-insert[w={width}]", "paged_insert_pages" if by_page else "scatter")
+    if by_page:
         from langstream_tpu.ops.attention import paged_insert_pages
 
         kv, rec = split_rec(pool)

@@ -74,6 +74,46 @@ def _score_scale(config: ModelConfig, d: int) -> float:
     return config.attn_scale if config.has_latent else 1.0 / (d**0.5)
 
 
+# ---------------------------------------------------------------------------
+# Heads of 64: two KV heads to a lane row (``ModelConfig.kv_head_pack``). The
+# cache and the pool keep heads 2j and 2j + 1 side by side, a leaf
+# [.., Hkv / 2, T, 128], and every kernel here takes that leaf as Hkv / 2
+# heads of 128 with twice the query group: a query is laid in ITS half of the
+# 128 lanes and zeros in the other (`pair_queries`), so q . k over 128 lanes
+# is its own head's 64 products, and of the 128 lanes p . v gives it keeps its
+# half (`own_half`). The kernels' bodies are the 128-wide ones to the line;
+# the MXU multiplies zeros for half its lanes, which a read bound by the
+# pool's bytes does not see, and a page holds what its tokens take and no
+# padding. What a call packs is read from its own sizes: the leaf's width
+# over the query's.
+# ---------------------------------------------------------------------------
+
+
+def _halves(h: int, hkv_packed: int, pack: int) -> jax.Array:
+    """[H, pack] one-hot: which part of the packed row query head h reads
+    (its KV head j = h // G lies in part j % pack of row j // pack)."""
+    group = h // (hkv_packed * pack)
+    return jax.nn.one_hot((jnp.arange(h) // group) % pack, pack, dtype=jnp.float32)
+
+
+def pair_queries(q: jax.Array, hkv_packed: int, pack: int) -> jax.Array:
+    """q [.., H, D] -> [.., H, pack x D], each head in its own part of the
+    packed row, zeros in the others. The heads keep their order: seen as
+    [Hkv / pack, pack x G] they are each packed row's own."""
+    *lead, h, d = q.shape
+    part = _halves(h, hkv_packed, pack).astype(q.dtype)
+    return (q[..., None, :] * part[:, :, None]).reshape(*lead, h, pack * d)
+
+
+def own_half(out: jax.Array, h: int, hkv_packed: int, pack: int) -> jax.Array:
+    """The inverse on a kernel's output [.., H x pack x D]: each head's own
+    part, [.., H x D]."""
+    lead = out.shape[:-1]
+    out = out.reshape(*lead, h, pack, -1)
+    part = _halves(h, hkv_packed, pack).astype(out.dtype)
+    return (out * part[:, :, None]).sum(axis=-2).reshape(*lead, -1)
+
+
 def _fit_block(block: int, n: int) -> int:
     """Largest block ≤ ``block`` that divides ``n``. pallas_ok blesses any
     128-multiple length, so a 512 default block must step down (512 → 256 →
@@ -164,7 +204,8 @@ def _mesh_ok(config: ModelConfig) -> bool:
     is replicated (serving_cache_specs) and attention stays on the jnp
     path, which GSPMD partitions by itself."""
     mesh = config.kernel_mesh
-    return mesh is None or config.n_kv_heads % mesh.shape.get("model", 1) == 0
+    rows = config.n_kv_heads // config.kv_head_pack  # of the cache's head axis
+    return mesh is None or rows % mesh.shape.get("model", 1) == 0
 
 
 # Which implementation each attention call shape was traced with, keyed by
@@ -294,7 +335,12 @@ def flash_prefill_attention(
     """Causal GQA attention → [B, S, H*D]; for a model that fills blocks
     (``config.block_length``) causal across blocks and two-way inside one."""
     b, s, h, d = q.shape
+    scale = _score_scale(config, d)
     hkv, dv = k.shape[1], v.shape[-1]  # a latent model's value has a width of its own
+    pack = k.shape[-1] // d  # heads of 64 lie two to a lane row (`pair_queries`)
+    if pack > 1:
+        q = pair_queries(q, hkv, pack)
+        d = pack * d
     group = h // hkv
     block_k = _fit_block(block_k, s)
     block_q = _fit_block(
@@ -312,7 +358,7 @@ def flash_prefill_attention(
         _prefill_kernel,
         block_q=block_q,
         block_k=block_k,
-        scale=_score_scale(config, d),
+        scale=scale,
         softcap=config.attn_logit_softcap,
         **extra,
     )
@@ -341,7 +387,8 @@ def flash_prefill_attention(
         interpret=interpret,
     )(qg, k, v)
     # [B, Hkv, G, S, Dv] → [B, S, H*Dv]
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * dv)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, s, h * dv)
+    return own_half(out, h, hkv, pack) if pack > 1 else out
 
 
 # ---------------------------------------------------------------------------
@@ -1337,6 +1384,14 @@ def _paged_decode_call(
     b, h, d = q.shape
     tp = table.shape[1]
     hkv = leaves[0].shape[2]
+    if scale is None:
+        scale = 1.0 / (d**0.5)
+    # heads of 64 lie two to a lane row of K and V (`pair_queries`); a latent's
+    # row is wider than its absorbed query's by its padding alone
+    pack = leaves[0].shape[-1] // d if len(leaves) == 2 else 1
+    if pack > 1:
+        q = pair_queries(q, hkv, pack)
+        d = pack * d
     group = h // hkv
     step_pages, slots = _walk_shape(
         sum(math.prod(leaf.shape[2:]) * leaf.dtype.itemsize for leaf in leaves), tp
@@ -1349,7 +1404,7 @@ def _paged_decode_call(
         n_leaves=len(leaves),
         page_size=page_size,
         table_len=tp,
-        scale=1.0 / (d**0.5) if scale is None else scale,
+        scale=scale,
         softcap=config.attn_logit_softcap,
         group=step_pages,
         windowed=lower is not None,
@@ -1395,7 +1450,8 @@ def _paged_decode_call(
           else [chosen.astype(jnp.float32).reshape(b, tp, 1, page_size)]),
         *(_flat_pool(leaf) for leaf in leaves),
     )
-    return out.reshape(b, h * dv)
+    out = out.reshape(b, h * dv)
+    return own_half(out, h, hkv, pack) if pack > 1 else out
 
 
 @_per_kv_head(3, kv_head_axis=2)
@@ -1668,6 +1724,9 @@ def paged_kv_write(
     ``b`` writes offsets ``offsets[b] .. offsets[b] + S - 1``, which lie in
     one aligned tile (`block_write_ok`): still one copy in and one out."""
     del config  # `_per_kv_head`'s: the mesh to split the heads over
+    # heads of 64 lie two to a lane row: a step's rows [B, Hkv, 64] are the
+    # pool's [B, Hkv / 2, 128] as they stand
+    new = tuple(leaf.reshape(leaf.shape[0], -1, k.shape[-1]) for leaf in new)
     b, rows, d = new[0].shape
     num_pages, hkv = k.shape[1], k.shape[2]
     block = _fit_block(_WRITE_BLOCK, b)
@@ -1929,8 +1988,12 @@ def paged_pallas_ok(config: ModelConfig, page_size: int) -> bool:
         return False
     if config.attention_impl == "pallas":
         return page_size % 8 == 0
-    # what a page's rows are wide: a head's K, or a latent model's kept row
-    width = config.latent_key_width if config.has_latent else config.resolved_head_dim
+    # what a page's rows are wide: a head's K (two heads of 64 to a row), or
+    # a latent model's kept row
+    width = (
+        config.latent_key_width if config.has_latent
+        else config.resolved_head_dim * config.kv_head_pack
+    )
     return paged_tiles_ok(width, page_size)
 
 
@@ -1948,7 +2011,8 @@ def _head_tiles_ok(config: ModelConfig) -> bool:
     in VMEM; tests/test_tpu_compile.py compiles both widths for a v5e)."""
     if config.has_latent:
         return config.resolved_head_dim % 64 == 0 and config.v_head_dim % 128 == 0
-    return config.resolved_head_dim % 128 == 0
+    # (two heads of 64 share a lane row: ``kv_head_pack``)
+    return config.resolved_head_dim * config.kv_head_pack % 128 == 0
 
 
 def pallas_ok(config: ModelConfig, seq_len: int) -> bool:
@@ -1971,5 +2035,8 @@ def pallas_ok(config: ModelConfig, seq_len: int) -> bool:
     if not _head_tiles_ok(config):
         return False
     if seq_len > 1 and seq_len % 128 != 0:
-        return False
+        # a bucket of 64: whole sublane tiles, one block a head. Taken where
+        # the heads are packed (a model new with them); every other model's
+        # 64-wide bucket keeps the masked jnp it has always traced
+        return config.kv_head_pack > 1 and seq_len % 64 == 0
     return True

@@ -748,7 +748,7 @@ def _make_paged_admit_group(mesh=None):
             )
 
             local_cache = constrain_serving_local_cache(
-                local_cache, config.n_kv_heads, mesh
+                local_cache, config.n_kv_heads // config.kv_head_pack, mesh
             )
         kv, rec = split_rec(pool)
         logits, local_cache, moe = prefill(
@@ -2755,6 +2755,10 @@ class ServingEngine:
             # for a model without recurrent layers)
             "recurrent-state-bytes": self._pagepool.state_bytes_total,
             "recurrent-state-rows-in-use": self._pagepool.state_rows_in_use,
+            # what a slot's convolution tails hold, over its layers (a conv
+            # model's whole state row; beside the rule's state in a delta-rule
+            # model; 0 for a model without)
+            "conv-state-bytes-per-slot": self._pagepool.conv_state_bytes_per_row,
             "kv-page-alias-rate": round(
                 self._pagepool.aliased_pages_total
                 / max(1, self._pagepool.reserved_pages_total),

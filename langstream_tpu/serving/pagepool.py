@@ -333,6 +333,10 @@ class PagePool:
 
         self.state_bytes_total = nbytes(self.dev.get("rec"))
         self.state_bytes_per_row = self.state_bytes_total // self.max_batch
+        # of which the convolution tails (all of it for a model of conv layers)
+        self.conv_state_bytes_per_row = (
+            nbytes((self.dev.get("rec") or {}).get("conv")) // self.max_batch
+        )
         self.window_bytes_total = nbytes(self.dev.get("win"))
         self.window_bytes_per_page = self.window_bytes_total // max(1, self._window_pages)
         self.bytes_total = nbytes(self.dev) - self.state_bytes_total - self.window_bytes_total

@@ -26,6 +26,12 @@ cell's sizing and its metrics, the refusals, the seeded tree against the
 program's own, the chain's halves; its names all say `kimi`) with
 `benchmark/tests/test_latent_dense_attention_cost.py` (the dense latent
 read's two cost functions and the new metric files, against hand counts) and
+of `benchmark/tests/test_lfm2_moe_family.py` (LFM2-24B-A2B: the contract, the
+catalog's keys and the stated cut of two, the deployment's bytes against the
+family's tree, the cell's sizing and its metrics, the refusals, the seeded
+tree against the program's own, the chain's halves in three kinds, the costs
+against hand counts, the metric files against the program's scopes, the
+engine's state against the check block; its names all say `lfm2`) and
 the cases of
 `benchmark/tests/test_request_readers.py` (the clock between a profile and the
 spans, a first token's stages, the device's idle by what the engine held; one
@@ -47,13 +53,33 @@ BY_HAND = ("test_sound_system_passes_with_room", "test_known_fault_fails_by_a_nu
            "test_the_tiny_cell_end_to_end_traced")
 
 
-def _cases(file: str) -> dict:
+def _cases(file: str, bench_as_of: int = 0) -> dict:
+    """``bench_as_of``: the file's cases read BENCHMARK.json as it stood when
+    the benchmark held that many cells (a family's file that counts the
+    benchmark's cells and names its own metrics "the last five" is a file of
+    the benchmark, which a later PR adds to and may not edit)."""
     spec = importlib.util.spec_from_file_location(f"benchmark_{file}", BENCH / "tests" / f"{file}.py")
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
+    if bench_as_of:
+        module.BENCH = _benchmark_at(module.BENCH, bench_as_of)
     return {
         name: case for name, case in vars(module).items()
         if name.startswith("test_") and name not in BY_HAND
+    }
+
+
+def _benchmark_at(bench: dict, cells: int) -> dict:
+    """BENCHMARK.json without what was appended behind its first ``cells``
+    cells: their configurations, and the metrics that only they report."""
+    kept = bench["workloads"][:cells]
+    names, configs = {w["name"] for w in kept}, {w["config"] for w in kept}
+    return {
+        **bench, "workloads": kept,
+        "configs": [c for c in bench["configs"] if c["name"] in configs],
+        "per_layer": [
+            m for m in bench["per_layer"] if "workloads" not in m or names & set(m["workloads"])
+        ],
     }
 
 
@@ -63,6 +89,8 @@ globals().update(_cases("test_cohere2_moe_family"))
 globals().update(_cases("test_sdar_moe_family"))
 globals().update(_cases("test_keye_vl2_family"))
 globals().update(_cases("test_glm_moe_dsa_family"))
-globals().update(_cases("test_kimi_k2_family"))
+# (its cell's case counts nine cells and eight configurations: PR 50's benchmark)
+globals().update(_cases("test_kimi_k2_family", bench_as_of=9))
+globals().update(_cases("test_lfm2_moe_family"))
 globals().update(_cases("test_latent_dense_attention_cost"))
 globals().update(_cases("test_request_readers"))

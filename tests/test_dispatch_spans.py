@@ -695,6 +695,33 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
     assert len(linear) == 5 and linear <= texts["hybrid"]
     assert {"attention", "ffn", "kv_pool.write"} <= texts["hybrid"]  # its full layers
     assert not linear & (texts["dense"] | texts["moe"])
+    # a model of conv layers (a gated short convolution, its tail a slot's whole
+    # state): the mixer under its own scopes, prefill and decode; its attention
+    # layers keep `attention`, its leading dense layers `ffn`, the rest `moe_ffn`
+    conv = MODEL_PRESETS["tiny-lfm2-test"]
+    conv_params = T.init_params(conv, jax.random.PRNGKey(8))
+
+    def conv_admit(params, pool):
+        kv, rec = T.split_rec(pool)
+        return T.prefill(
+            params, tokens, lengths, T.join_rec(T.make_kv_cache(conv, b, 32), rec), conv,
+            rec_rows=jnp.arange(b),
+        )
+
+    def conv_decode(params, pool):
+        return E._paged_decode_chunk(
+            params, tokens[:, 0], lengths, pool, table, key, ones, zeros, ones, 2, conv, page,
+        )
+
+    conv_pool = T.make_page_pool(conv, 8, page, state_rows=b)
+    texts["conv"] = lowered_scopes(conv_admit, conv_params, conv_pool) | lowered_scopes(
+        conv_decode, conv_params, conv_pool
+    )
+    short = {s for s in T.SCOPES if s.startswith("short_conv")}
+    assert len(short) == 4 and short <= texts["conv"]
+    assert {"attention", "ffn", "moe_ffn", "moe_ffn.route", "kv_pool.write"} <= texts["conv"]
+    assert not short & (texts["dense"] | texts["moe"] | texts["hybrid"])
+    assert not linear & texts["conv"]
     # a model of window and full layers with an expert layer that holds a
     # share: each kind's attention under its own scope, the shared experts'
     window = MODEL_PRESETS["tiny-window-moe-test"]

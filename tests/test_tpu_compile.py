@@ -65,9 +65,10 @@ def _prefill_args(config, s):
     of its own)."""
     h, hkv, d = config.n_heads, config.n_kv_heads, config.resolved_head_dim
     dv = config.v_head_dim if config.has_latent else d
+    pack = config.kv_head_pack  # heads of 64: two to a lane row of K and V
     return (
-        SDS((1, s, h, d), jnp.bfloat16), SDS((1, hkv, s, d), jnp.bfloat16),
-        SDS((1, hkv, s, dv), jnp.bfloat16),
+        SDS((1, s, h, d), jnp.bfloat16), SDS((1, hkv // pack, s, d * pack), jnp.bfloat16),
+        SDS((1, hkv // pack, s, dv * pack), jnp.bfloat16),
     )
 
 
@@ -75,11 +76,12 @@ def _paged_args(config, int8, batch=BATCH, table=TABLE, pages=PAGES, layers=POOL
     """(q, k, v, lengths, table, layer) shapes of a paged decode call."""
     h, hkv, d = config.n_heads, config.n_kv_heads, config.resolved_head_dim
     q = SDS((batch, h, d), jnp.bfloat16)
-    pool = (layers, pages, hkv, PAGE)
+    pack = config.kv_head_pack
+    pool = (layers, pages, hkv // pack, PAGE)
     if int8:
         kv = {"q": SDS(pool + (d,), jnp.int8), "s": SDS(pool, jnp.float32)}
     else:
-        kv = SDS(pool + (d,), jnp.bfloat16)
+        kv = SDS(pool + (d * pack,), jnp.bfloat16)
     return (
         q, kv, kv, SDS((batch,), jnp.int32), SDS((batch, table), jnp.int32),
         SDS((), jnp.int32),
@@ -110,7 +112,8 @@ def _kv_write(config, batch, pages, layers, table=None):
     """(the decode step's pool write, its arguments' shapes): K and V rows
     [B, Hkv, D], both bf16 pool leaves, a write page, an offset a row, and
     the layer (``table`` is the attention kernel's, not an operand here)."""
-    hkv, d = config.n_kv_heads, config.resolved_head_dim
+    pack = config.kv_head_pack
+    hkv, d = config.n_kv_heads // pack, config.resolved_head_dim * pack
     rows = SDS((batch, hkv, d), jnp.bfloat16)
     pool = SDS((layers, pages, hkv, PAGE, d), jnp.bfloat16)
     at = SDS((batch,), jnp.int32)
@@ -126,7 +129,8 @@ def _insert_pages(config, rows, width, pages, layers, table):
     """(an admission group's insert by page, its arguments' shapes): the
     prefill's local K and V [L, rows, Hkv, width, D], both bf16 pool leaves
     and the rows' tables."""
-    hkv, d = config.n_kv_heads, config.resolved_head_dim
+    pack = config.kv_head_pack
+    hkv, d = config.n_kv_heads // pack, config.resolved_head_dim * pack
     local = SDS((layers, rows, hkv, width, d), jnp.bfloat16)
     pool = SDS((layers, pages, hkv, PAGE, d), jnp.bfloat16)
     return (
@@ -249,6 +253,19 @@ KIMI = dataclasses.replace(
     qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, routed_scaling=2.827,
     rope_theta=50000.0, rope_scaling_factor=64.0, rope_scaling_original_max_seq_len=4096,
     rope_scaling_beta_fast=32.0, max_seq_len=262144,
+)
+
+
+# LFM2-24B-A2B as the benchmark cuts it (`tiny-lfm2-test`'s block at the
+# published widths): 32 Q / 8 KV heads x 64, two KV heads to a lane row of the
+# cache and the pool ([L, P, 4, 64, 128]), 12 conv layers of 2,048 with a
+# convolution of 3 taps and 4 attention layers, two leading dense layers of
+# 11,776 and 14 expert layers of 64 experts of 2048 x 1536 top-4, the whole
+# vocabulary on a tied head; the cell: 256 slots x 10 pages
+LFM2 = dataclasses.replace(
+    MODEL_PRESETS["tiny-lfm2-test"], name="lfm2-widths", d_model=2048, d_ff=11776,
+    moe_d_ff=1536, n_layers=16, n_heads=32, n_kv_heads=8, head_dim=64, n_experts=64,
+    n_experts_per_tok=4, experts_held=(0, 64), vocab_size=65536, max_seq_len=128000,
 )
 
 
@@ -530,6 +547,18 @@ CASES = {
     "olmodrain40x10-paged-kv-write": _kv_write(OLMO, batch=40, pages=400, layers=8),
     **{f"olmo-prefill-{s}": _prefill(OLMO, s) for s in (128, 256, 384)},
     "olmodrain40-gated-delta-update": _delta_update(OLMO, 40, 24),
+    # the LFM2 cell (256 slots x 10 pages, 2,560 pages, 4 attention layers of
+    # 8 kv heads of 64, two to a lane row): the decode step's read and write,
+    # the admit group's prefill at EVERY bucket (64 too) and its insert, the
+    # grouped product of a step (256 rows x top-4 over 64 experts) and of an
+    # admission group (8 x 256 tokens)
+    "lfm2drain256x10-paged-decode": _paged(LFM2, False, batch=256, table=10, pages=2560, layers=4),
+    "lfm2drain256x10-paged-kv-write": _kv_write(LFM2, batch=256, pages=2560, layers=4),
+    **{f"lfm2-prefill-{s}": _prefill(LFM2, s) for s in (64, 128, 256, 384)},
+    "lfm2drain8x256-paged-insert-pages": _insert_pages(LFM2, 8, 256, 2560, 4, 10),
+    "lfm2-grouped-matmul-256": _grouped(LFM2, 256, 14),
+    "lfm2-down-grouped-matmul-256": _grouped(LFM2, 256, 14, down=True),
+    "lfm2-grouped-matmul-2048": _grouped(LFM2, 2048, 14),
 }
 
 
@@ -914,6 +943,82 @@ def test_one_row_admit_group_compiles_for_v5e_beside_the_cell_s_state(v5e, monke
         memory.argument_size_in_bytes + memory.temp_size_in_bytes
         + memory.output_size_in_bytes - memory.alias_size_in_bytes
     )
+    assert held <= V5E_HBM_BYTES
+
+
+# The LFM2 cell's two programs whole, at its sizes: 256 slots x 10 pages of 64
+# (2,560 pages of [4, 64, 128] bf16 in 4 layers), 12 conv layers' tails a slot,
+# 8.85 GB of int8 weights. Both must fit the chip beside the pool, take the
+# kernels at two heads a lane row and move no whole leaf of pool or tails.
+@pytest.mark.parametrize("program", ["_paged_decode_chunk", "admit-1x64", "admit-8x256"])
+def test_lfm2_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatch, program):
+    from langstream_tpu.models.quant import quantize_params
+    from langstream_tpu.models.transformer import init_params, make_page_pool
+    from langstream_tpu.serving import engine as E
+    from langstream_tpu.serving.pagepool import table_len_for
+
+    config, slots, seq_len, pages = LFM2, 256, 640, 2560
+    key = SDS((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: quantize_params(init_params(config, k), config), key)
+    assert params["dense_layers"]["conv"]["w_gate"]["q"].shape == (2, 2048, 11776)
+    assert params["layers"]["conv"]["w_gate"]["q"].shape == (10, 64, 2048, 1536)
+    assert params["layers"]["full_attention"]["w_gate"]["q"].shape == (4, 64, 2048, 1536)
+    pool = jax.eval_shape(lambda: make_page_pool(config, pages, PAGE, state_rows=slots))
+    assert {k: v.shape for k, v in pool.items() if k != "rec"} == {
+        "k": (4, pages, 4, PAGE, 128), "v": (4, pages, 4, PAGE, 128)}
+    assert {k: v.shape for k, v in pool["rec"].items()} == {"conv": (12, slots, 2 * 2048)}
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    table = table_len_for(seq_len, PAGE)
+    one_chip = SingleDeviceSharding(v5e[0])
+    A._PATHS.clear()
+    if program == "_paged_decode_chunk":
+        args = (params, i32(slots), i32(slots), pool, i32(slots, table), key, f32(slots),
+                i32(slots), f32(slots))
+        compiled = _compile_as_on_chip(
+            monkeypatch, E._paged_decode_chunk, _placed(args, one_chip), (4, config, PAGE)
+        )
+        paths = A.attention_paths()
+        assert paths[f"paged-decode[s=1,t={table * PAGE}]"] == "ragged_paged_decode_attention"
+        assert paths["paged-decode-write[s=1]"] == "paged_kv_write"
+        assert paths["short-conv[s=1,t=0]"] == "short_conv"
+        assert paths["moe-dispatch[t=256,k=4,held=64/64]"].startswith("one pass")
+        wanted = ("ragged_paged_decode_attention", "paged_kv_write", "moe_grouped_matmul")
+    else:
+        rows, width = map(int, program.split("-")[1].split("x"))
+        args = (
+            params, pool, i32(slots), i32(slots), f32(slots), i32(slots), f32(slots), key,
+            i32(rows, width), f32(4, rows), i32(rows), i32(rows, table),
+        )
+        compiled = _compile_as_on_chip(
+            monkeypatch, E._make_paged_admit_group(), _placed(args, one_chip), (config, PAGE)
+        )
+        paths = A.attention_paths()
+        assert paths[f"prefill[s={width},t={width}]"] == "flash_prefill_attention"
+        assert paths[f"paged-insert[w={width}]"] == "paged_insert_pages"
+        wanted = ("flash_prefill_attention", "paged_insert_pages", "moe_grouped_matmul")
+    text = compiled.as_text()
+    for kernel in wanted:
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    # no whole leaf of the pool is copied, relaid or scattered; the tails (25 MB,
+    # which the compiler keeps rows-minor inside the step loop) are relaid once
+    # into a chunk and once out of it, never a step or a layer
+    dims = lambda leaf: re.escape("[" + ",".join(map(str, leaf.shape)) + "]")  # noqa: E731
+    assert not re.search(rf"= \w+{dims(pool['k'])}\S* (copy|scatter|transpose)\(", text)
+    # (an admit group scatters its rows' tails into the leaf where it lies)
+    tails = re.findall(rf"= \w+{dims(pool['rec']['conv'])}\S* (?:copy|transpose)\(", text)
+    assert len(tails) <= 2, tails
+    # nor a layer's experts out of their stack
+    experts = "[64,2048,1536]"
+    assert not re.search(rf"= \w+{re.escape(experts)}\S* (copy|dynamic-slice)\(", text)
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
+    assert memory.alias_size_in_bytes >= pool_bytes  # pool and tails, updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    print(program, "arguments", memory.argument_size_in_bytes, "temporaries",
+          memory.temp_size_in_bytes, "held", held)
     assert held <= V5E_HBM_BYTES
 
 
