@@ -1700,6 +1700,36 @@ def latent_columns_expanded(offset, s: int, t: int, config):
     return jnp.minimum(seen, t) if isinstance(offset, jax.Array) else min(seen, t)
 
 
+def segment_blocks_visited(offset: int, s: int, t: int, config) -> dict:
+    """The key blocks ONE KV head's walk visits, a layer call, in a segment of
+    ``s`` queries at ``offset .. offset + s - 1`` over a table of ``t``
+    columns (`ops/attention.segment_blocks_visited`: the kernel's own range,
+    on the host): ``{"key_blocks": n}`` where the segment's read is the walk
+    over key blocks (a latent's re-expanded heads, one query head a key head;
+    a learned selection's and a window model's full layers, a group a KV
+    head), with ``"key_blocks_window"`` beside it for a window layer's call;
+    ``{}`` where it is masked jnp (every other model's segment, and where the
+    kernels' tiles do not fit). What a segment's attention time is divided by
+    for its time a key block."""
+    from langstream_tpu.ops import attention as ops
+
+    walks = config.has_latent or config.has_indexer or config.has_window
+    if not (walks and _selection_kernels(config, s, t)):
+        return {}
+    if config.has_latent:
+        d, group = config.qk_nope_head_dim + config.qk_rope_head_dim, 1
+    else:
+        d, group = config.resolved_head_dim, config.n_heads // config.n_kv_heads
+    windows = {"key_blocks": 0}
+    if config.has_window:
+        windows["key_blocks_window"] = config.sliding_window
+    itemsize = jnp.dtype(config.dtype).itemsize
+    return {
+        name: ops.segment_blocks_visited(offset, s, t, d, group, window, itemsize)
+        for name, window in windows.items()
+    }
+
+
 def _latent_expand_seen(rows, lp, offsets, s, config):
     """`_latent_expand` of a row's gathered latents ``rows`` [B, T, W] for a
     segment of ``s`` queries at ``offsets[b] ..``: the columns

@@ -50,6 +50,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
@@ -445,32 +446,39 @@ def _segment_kernel(
         if selected:
             seen = seen & (chosen_ref[...].astype(jnp.int32) != 0)
         s = jnp.where(seen, s, _NEG)
-        m_prev = m_scr[:, :, 0]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, :, None])
+        # the running maximum and sum stay COLUMNS [G, block_q, 1], a row of
+        # the tile a sublane, as the reductions leave them and the tile and
+        # the accumulator take them: read as [G, block_q] (`m_scr[:, :, 0]`)
+        # Mosaic lays block_q along the lanes, and turning 512 values from
+        # sublanes to lanes and back cost 7,300 of a key block's 11,800
+        # operations on a v5e (PERF.md section 6, PR 56). The same float32
+        # values in the same order: the output is PR 55's to the bit
+        m_prev = m_scr[:, :, :1]
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         p = jnp.where(s <= _NEG, 0.0, p)
         corr = jnp.exp(m_prev - m_new)
-        l_scr[:, :, 0] = l_scr[:, :, 0] * corr + p.sum(axis=-1)
+        l_scr[:, :, :1] = l_scr[:, :, :1] * corr + p.sum(axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v, dimension_numbers=(((2,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        acc_scr[...] = acc_scr[...] * corr[:, :, None] + pv
-        m_scr[:, :, 0] = m_new
+        acc_scr[...] = acc_scr[...] * corr + pv
+        m_scr[:, :, :1] = m_new
 
     @pl.when(j == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[:, :, 0], 1e-30)[:, :, None]
+        l = jnp.maximum(l_scr[:, :, :1], 1e-30)  # [G, block_q, 1]
         o_ref[0, 0, :, :, :] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def _segment_blocks(q_start, block_q: int, block_k: int, window: int, n_t: int):
+def _segment_blocks(q_start, block_q: int, block_k: int, window: int, n_t: int, xp=jnp):
     """First and last key block, of ``n_t``, that the query block at
-    ``q_start`` sees."""
-    last = jnp.minimum((q_start + block_q - 1) // block_k, n_t - 1)
+    ``q_start`` sees (``xp``: numpy where the host counts them)."""
+    last = xp.minimum((q_start + block_q - 1) // block_k, n_t - 1)
     if not window:
-        return jnp.int32(0), last
-    return jnp.maximum(q_start - window + 1, 0) // block_k, last
+        return xp.int32(0), last
+    return xp.maximum(q_start - window + 1, 0) // block_k, last
 
 
 def segment_key_blocks(s: int, t: int, d: int, group: int, window: int,
@@ -484,6 +492,18 @@ def segment_key_blocks(s: int, t: int, d: int, group: int, window: int,
     if window:
         n_k = min(n_k, (window + block_q - 2) // block_k + 2)
     return block_q, block_k, n_k
+
+
+def segment_blocks_visited(offset: int, s: int, t: int, d: int, group: int, window: int,
+                           itemsize: int = 2) -> int:
+    """Key blocks ONE KV head's walk of a segment call runs its body for: the
+    kernel's own rule (`_segment_blocks`) on the host, summed over the query
+    blocks of ``s`` queries at ``offset .. offset + s - 1`` over ``t``
+    columns. What a call's time is divided by for its time a key block."""
+    block_q, block_k, _ = segment_key_blocks(s, t, d, group, window, itemsize)
+    q_start = offset + block_q * np.arange(s // block_q)
+    first, last = _segment_blocks(q_start, block_q, block_k, window, t // block_k, xp=np)
+    return int(np.maximum(last - first + 1, 0).sum())
 
 
 @_per_kv_head(1)

@@ -49,6 +49,7 @@ from langstream_tpu.models.transformer import (
     paged_prefill_segment_inplace,
     paged_verify_step_inplace,
     prefill,
+    segment_blocks_visited,
     segment_copies_pages,
     split_rec,
 )
@@ -1978,6 +1979,11 @@ class ServingEngine:
         # (stats "segment-writes", restarted with "launches"): by whole
         # pages, or by the scatter (`_count_segment_write`)
         self._segment_writes = {"pages": 0, "scatter": 0}
+        # and the key blocks their attention walked, a KV head and a layer
+        # call (stats "segment-key-blocks"; `_count_segment_key_blocks`): the
+        # last segment's, for its span, and the sum since the same restart
+        self._segment_key_blocks_last: dict = {}
+        self._segment_key_blocks: dict = {}
         self._late_probe = False
         self._launched_late = False
         # dedicated device→host token fetch thread (started with the loop);
@@ -2533,6 +2539,7 @@ class ServingEngine:
         with self._stats_lock:  # and the device's unfed account
             self._unfed_s = self._unfed_request_s = 0.0
             self._account_t0 = time.monotonic()
+            self._segment_key_blocks = {}
         self._launches = dict.fromkeys(self._launches, 0)
         self._segment_writes = dict.fromkeys(self._segment_writes, 0)
 
@@ -2741,6 +2748,12 @@ class ServingEngine:
             # starts inside a page, an int8 pool, a mesh, no kernels;
             # docs/SERVING.md, "The pool's writers"), since the same
             "segment-writes": dict(self._segment_writes),
+            # key blocks those segments' attention walked, a KV head and a
+            # layer call, where the read is the walk over key blocks
+            # (`key-blocks`; a window model's window layers beside its full
+            # ones, `key-blocks-window`): what their attention time is divided
+            # by for its time a key block; {} where segments read masked jnp
+            "segment-key-blocks": dict(self._segment_key_blocks),
             "decode-step-ms": round(self._step_time_ema_s * 1e3, 3),
             "hbm-gbps-decode": self._achieved_hbm_gbps(),
             # the page pool, the engine's only KV state
@@ -5287,6 +5300,7 @@ class ServingEngine:
                 ttft_s=0, total_s=0, error=e,
             ))
             return
+        self._note_key_blocks(disp)
         slot = self._slots[idx]
         slot.request = request
         slot.position = len(prompt)
@@ -5324,6 +5338,7 @@ class ServingEngine:
         table = pool.rows_tables([idx])
         self._record_program("paged-segment", tokens.shape[1])
         self._count_segment_write(tokens.shape[1], s0)
+        self._count_segment_key_blocks(tokens.shape[1], s0)
         kw = self._segment_agentic_kwargs(
             agentic_rows, idx if final else self.max_batch
         )
@@ -6738,6 +6753,7 @@ class ServingEngine:
             ))
             return []
         st["seg"] += 1
+        self._note_key_blocks(disp)
         if disp is not None and self.config.has_window:
             disp.attrs.update(self._segment_window_attrs(s0, len(seg)))
         if self.config.has_indexer:
@@ -6990,6 +7006,27 @@ class ServingEngine:
             self._pagepool.dev, width, self.page_size, self.config
         )
         self._segment_writes["pages" if pages else "scatter"] += 1
+
+    def _count_segment_key_blocks(self, width: int, s0: int) -> None:
+        """The key blocks this segment's attention walks, by the program's
+        own rule (models/transformer `segment_blocks_visited`): kept for its
+        span (`_note_key_blocks`) and summed into `stats()`."""
+        pool = self._pagepool
+        blocks = self._segment_key_blocks_last = segment_blocks_visited(
+            s0, width, pool.table_len * pool.page_size, self.config
+        )
+        with self._stats_lock:  # `stats()` copies the sums under it
+            for name, n in blocks.items():
+                key = name.replace("_", "-")
+                self._segment_key_blocks[key] = self._segment_key_blocks.get(key, 0) + n
+
+    def _note_key_blocks(self, disp: Optional[Dispatch]) -> None:
+        """``key_blocks`` (and a window model's ``key_blocks_window``) of the
+        segment just dispatched onto its span, added to what a stream's
+        earlier segments put there."""
+        if disp is not None:
+            for name, n in self._segment_key_blocks_last.items():
+                disp.attrs[name] = disp.attrs.get(name, 0) + n
 
     def _kv_pages_written(self, slots, width: int) -> int:
         """kv_pages_written of an admission group dispatched now, per layer

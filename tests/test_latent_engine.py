@@ -130,6 +130,8 @@ def test_spans_and_counters_say_what_was_scored_read_and_expanded(params, impl, 
     )
     emit = observability.emit_dispatch_span
     record = lambda name, start, end, attrs: spans.append((name, dict(attrs)))  # noqa: E731
+    engine.reset_histograms()  # the warm-up's segment is not the prompt's
+    assert engine.stats()["segment-key-blocks"] == {}
     try:
         E.emit_dispatch_span = record
         engine.generate(prompt_of(40, 9), GenerationOptions(max_new_tokens=8), timeout=120)
@@ -148,6 +150,12 @@ def test_spans_and_counters_say_what_was_scored_read_and_expanded(params, impl, 
     # and the columns of its table the program expanded in all, by its own rule
     assert [a["latent_columns_expanded"] for a in segments] == columns
     assert columns == [T.latent_columns_expanded(o, 16, table, engine.config) for o in (0, 16, 32)]
+    # and the key blocks its attention walked, a head and a layer (PR 56): a
+    # tile of 16 queries lies inside the first block of 128 keys; masked jnp
+    # walks none
+    walked = [1, 1, 1] if impl == "pallas" else [None] * 3
+    assert [a.get("key_blocks") for a in segments] == walked
+    assert stats["segment-key-blocks"] == ({"key-blocks": 3} if impl == "pallas" else {})
     for attrs in segments:
         lengths = attrs["offset"] + 1 + np.arange(attrs["real_tokens"])
         assert attrs["index_tokens_scored"] == lengths.sum()

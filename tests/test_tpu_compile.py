@@ -6,9 +6,13 @@ nothing about results or times (chip_smoke.py checks results on a chip)."""
 
 import base64
 import dataclasses
+import functools
 import hashlib
+import importlib.util
 import os
 import re
+import sys
+from pathlib import Path
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
@@ -1551,6 +1555,68 @@ def test_the_latent_expansion_hands_mosaic_what_it_did(v5e, case):
     assert _short_hash(body) == LATENT_EXPAND_BODY_AT_PR49[case]
 
 
+# The segment walk's module at the four segment cells' shapes. PR 56 keeps the
+# walk's running maximum and sum as columns `[G, block_q, 1]`: the five modules
+# as PR 56 handed them to Mosaic, for a later PR that does not mean to touch the
+# walk to hold still. PR 55's kernel (which `dev/bench_segment_walk.py` carries
+# for the chip's comparison: its rows `[G, block_q]` lie along the lanes, eight
+# turns of 512 values a key block) still lowers to the module PR 55 handed
+# Mosaic, hash for hash, so the copy is the parent; and the new module's text
+# is no longer than that one's (what a start pays to lower and to hash an
+# instance, ROADMAP S14 (4); command-a-plus traces four a segment program).
+SEGMENT_BODIES_AT_PR55 = {
+    "kimi-segment-2048": "f816eb2490353386",
+    "cmdaplus-segment-2048": "81755607ed3d7e3c",
+    "cmdaplus-window-segment-2048": "bb8414c787ae90a0",
+    "glm-sparse-segment-2048": "08c4006ee2f127eb",
+    "keye-sparse-segment-2048": "02fda9013aae60cc",
+}
+SEGMENT_BODIES_AT_PR56 = {
+    "kimi-segment-2048": "f613a6218b390e14",
+    "cmdaplus-segment-2048": "6707a07407535b6b",
+    "cmdaplus-window-segment-2048": "616a75ecab0baa9d",
+    "glm-sparse-segment-2048": "00aa4a0b83181e3c",
+    "keye-sparse-segment-2048": "cf0d48af1eb99d59",
+}
+
+
+@functools.cache
+def _segment_kernel_pr55():
+    """PR 55's `_segment_kernel`, from dev/bench_segment_walk.py."""
+    path = Path(__file__).resolve().parents[1] / "dev" / "bench_segment_walk.py"
+    spec = importlib.util.spec_from_file_location("bench_segment_walk", path)
+    # (registered before it runs: a dataclass looks its module up)
+    module = sys.modules[spec.name] = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.segment_kernel_pr55
+
+
+@pytest.fixture
+def the_walk_pr55_had(monkeypatch):
+    """The segment walk with its running maximum and sum as rows, as before PR 56."""
+    monkeypatch.setattr(A, "_segment_kernel", _segment_kernel_pr55())
+    jax.clear_caches()  # a trace is cached by the function, not by the patch
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("case", sorted(SEGMENT_BODIES_AT_PR56))
+def test_the_segment_walk_hands_mosaic_what_pr56_left(v5e, case, request):
+    (body,) = _kernel_bodies(*CASES[case], v5e[0])
+    assert f"module @{_kernel_of(case)} " in body
+    assert _short_hash(body) == SEGMENT_BODIES_AT_PR56[case]
+    # no arithmetic on the running maximum or sum as a ROW, block_q along the
+    # lanes: a reduction's result goes straight back to a column
+    block_q = 256 if case.startswith("cmdaplus") else 512
+    on_rows = rf"stable_mosaic\.(arith\.(?!constant)|math\.)\w+\"\(.* -> vector<\d+x{block_q}xf32>"
+    assert not re.search(on_rows, body)
+    request.getfixturevalue("the_walk_pr55_had")
+    (parent,) = _kernel_bodies(*CASES[case], v5e[0])
+    assert _short_hash(parent) == SEGMENT_BODIES_AT_PR55[case]
+    assert re.search(on_rows, parent)  # the maximum, the rescale's exponential, the sum
+    assert len(body) <= len(parent), (len(body), len(parent))
+
+
 def test_the_selection_is_the_only_difference_of_its_kernel(v5e):
     """The selected walk's Mosaic module against the plain decode kernel's
     at the same sizes: one more operand (a row's block, float32
@@ -1796,12 +1862,34 @@ def under_the_scatter(monkeypatch):
     jax.clear_caches()
 
 
+# PR 56 keeps the segment walk's (`ops/attention._segment_kernel`) running
+# maximum and sum as columns, and every program that holds the kernel lowers
+# anew, in interpret mode too: the four presets' segment programs whose
+# segments walk key blocks, and the two indexer presets' admit groups (their
+# prefill under the selection is the same walk). Those six, as PR 56 left them
+# (the pages' writer, the seventh count). The tables above are NOT re-taken:
+# with PR 55's kernel patched back (`the_walk_pr55_had`: the copy
+# dev/bench_segment_walk.py holds the chip's comparison by) each of the six
+# still lowers to every hash it had THERE, which is the proof that nothing
+# else of these programs moved.
+PROGRAMS_AT_PR56 = {
+    "admit/tiny-latent-moe-test": "8cac4c6f2c77c039",
+    "admit/tiny-sparse-moe-test": "c6b76e33fc9d4a00",
+    "segment/tiny-latent-dense-moe-test": "ab05293bc5ad887e",
+    "segment/tiny-latent-moe-test": "1fe546a047e877b0",
+    "segment/tiny-sparse-moe-test": "7325dbc1995988f8",
+    "segment/tiny-window-moe-test": "833f10e44c7df4a3",
+}
+
+
 @pytest.mark.parametrize("case", sorted(ENGINE_PROGRAMS))
 def test_the_other_models_decode_programs_lower_as_they_did(case, request):
     if _holds_experts(case):
         request.getfixturevalue("six_counts")
     if case in SEGMENT_PROGRAMS_AT_PR48:
         request.getfixturevalue("under_the_scatter")
+    if case in PROGRAMS_AT_PR56:
+        request.getfixturevalue("the_walk_pr55_had")
     assert _short_hash(_engine_program_text(case)) == ENGINE_PROGRAMS[case]
 
 
@@ -1809,6 +1897,8 @@ def test_the_other_models_decode_programs_lower_as_they_did(case, request):
 def test_the_segment_programs_lower_as_pr48_left_them(case, request):
     if _holds_experts(case):
         request.getfixturevalue("six_counts")
+    if case in PROGRAMS_AT_PR56:
+        request.getfixturevalue("the_walk_pr55_had")
     assert _short_hash(_engine_program_text(case)) == SEGMENT_PROGRAMS_AT_PR48[case]
     assert A.attention_paths()["paged-segment-write[s=16]"] == "paged_insert_pages"
 
@@ -1818,12 +1908,24 @@ def test_the_held_models_programs_lower_as_pr54_left_them(case, request):
     """With the seventh count; a segment's row under the scatter as above."""
     if case in SEGMENT_PROGRAMS_AT_PR48:
         request.getfixturevalue("under_the_scatter")
+    if case in PROGRAMS_AT_PR56:
+        request.getfixturevalue("the_walk_pr55_had")
     assert _short_hash(_engine_program_text(case)) == HELD_PROGRAMS_AT_PR54[case]
 
 
 @pytest.mark.parametrize("case", sorted(c for c in SEGMENT_PROGRAMS_AT_PR48 if _holds_experts(c)))
-def test_the_held_models_segments_lower_as_pr54_left_them(case):
+def test_the_held_models_segments_lower_as_pr54_left_them(case, request):
+    if case in PROGRAMS_AT_PR56:
+        request.getfixturevalue("the_walk_pr55_had")
     assert _short_hash(_engine_program_text(case)) == HELD_SEGMENT_PROGRAMS_AT_PR54[case]
+
+
+@pytest.mark.parametrize("case", sorted(PROGRAMS_AT_PR56))
+def test_the_programs_that_walk_key_blocks_lower_as_pr56_left_them(case):
+    """As the programs are. The admit rows under the seventh count
+    (`HELD_PROGRAMS_AT_PR54`'s with PR 55's walk), the segment rows with their
+    rows written by whole pages (`HELD_SEGMENT_PROGRAMS_AT_PR54`'s)."""
+    assert _short_hash(_engine_program_text(case)) == PROGRAMS_AT_PR56[case]
 
 
 # What `attention_paths()` says after a prefill over a local cache, a segment
@@ -2057,8 +2159,15 @@ def test_every_preset_notes_the_paths_it_did(case):
 # of the sorted assignments): the window preset's segment at 2,048 tokens (4 of
 # 16 experts held, top-4: twice the even share is 4,096 of its 8,192
 # assignments), as PR 54 left it, and what it says of itself.
-def test_a_segment_wide_enough_takes_the_passes():
+@pytest.mark.parametrize("walk", ["pr55", "pr56"])
+def test_a_segment_wide_enough_takes_the_passes(walk, request):
+    """(PR 56: the window preset's segment holds the segment walk, so the
+    program PR 54 left is the one with PR 55's walk; as it is, it lowers to
+    what PR 56 left.)"""
     from langstream_tpu.models.transformer import make_page_pool
+
+    if walk == "pr55":
+        request.getfixturevalue("the_walk_pr55_had")
     from langstream_tpu.serving import engine as E
 
     config, params, _, _ = _tiny_case("tiny-window-moe-test", "pallas")
@@ -2073,4 +2182,4 @@ def test_a_segment_wide_enough_takes_the_passes():
     assert A.attention_paths()["moe-dispatch[t=2048,k=4,held=4/16]"] == (
         "passes of 4096, 13 tiles (17 hold every case)"
     )
-    assert _short_hash(text) == "0b5d43e29de6db3a"
+    assert _short_hash(text) == {"pr55": "0b5d43e29de6db3a", "pr56": "0b6f9a196effb440"}[walk]
