@@ -8,6 +8,8 @@ map checkpoints mechanically.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Any, Optional
@@ -18,6 +20,12 @@ from typing import Any, Optional
 # latent (`ModelConfig.page_leaves` says which a model has; the engine's page
 # copies, zeroes and snapshots go over exactly these)
 PAGE_LEAVES = ("k", "v", "ik", "lat")
+
+# what a kind of attention layer may have of its own (`ModelConfig.window_attention`)
+KIND_FIELDS = (
+    "n_heads", "q_lora_rank", "kv_lora_rank", "qk_nope_head_dim", "qk_rope_head_dim",
+    "v_head_dim", "rope_theta", "attn_gate",
+)
 
 
 @dataclass(frozen=True)
@@ -220,6 +228,57 @@ class ModelConfig:
     router_bias: bool = False
     routed_scaling: float = 1.0
     router_norm_eps: float = 0.0
+    # An attention geometry A KIND (docs/SERVING.md "A model whose kinds of
+    # layer keep different latents"): in a model that keeps a latent, a
+    # ``layer_pattern`` of "full_attention" and "sliding_attention" layers is
+    # the SEQUENTIAL pre-norm block for both, and the window kind may have its
+    # own heads, latent ranks, head widths and rotary base: ``window_attention``
+    # holds them as (field, value) pairs over `KIND_FIELDS`, what it leaves out
+    # is the model's. The fields above are the FULL kind's, and the indexer is
+    # the full kind's alone; `of_kind` gives a kind's geometry as a config of
+    # its own, which every function below the layer reads (``attn_scale``,
+    # ``latent_width``, ``latent_key_width``, ``rope_dim``, ``page_leaves``,
+    # ``kv_bytes_per_token`` answer for the kind they are asked of). The
+    # window kind's tokens live in the pool's window group, ONE leaf ``"lat"``
+    # of its own width.
+    window_attention: tuple = ()
+    # the normed latents are multiplied by sqrt(d_model / rank) (the query's by
+    # ``q_lora_rank``, the key-value latent's by ``kv_lora_rank``; the rotary
+    # key is not): a latent's up-projection sees the variance a full-width
+    # input would give. A token's cached latent is the rescaled one
+    latent_rescale: bool = False
+    # "" | "headwise": o_h <- sigmoid(u W_g)_h o_h, one scalar a head and token
+    # from the layer's normed input, on the head's output before ``wo``
+    attn_gate: str = ""
+    # set by `of_kind` alone: this config IS the named kind's view of a model
+    kind_view: str = ""
+
+    def of_kind(self, kind: str) -> "ModelConfig":
+        """The attention geometry of the model's layers of ``kind``: the model
+        itself but for the window kind of a model that keeps a latent, whose
+        view carries ``window_attention``'s fields in the model's and no
+        indexer."""
+        if kind != "sliding_attention" or self.kind_view or not self.latent_kinds:
+            return self
+        return _kind_view(self, kind)
+
+    @property
+    def attn_window(self) -> int:
+        """What a query of THIS kind's layers sees behind it (a kind's view,
+        `of_kind`): ``sliding_window`` positions with itself for the window
+        kind of a model whose kinds keep latents, else 0, everything."""
+        return self.sliding_window if self.kind_view == "sliding_attention" else 0
+
+    @property
+    def latent_kinds(self) -> bool:
+        """Window layers beside full ones in a model that keeps a LATENT:
+        both are the sequential block, each kind at its own geometry."""
+        return self.has_window and self.has_latent
+
+    @property
+    def parallel_block(self) -> bool:
+        """Window layers over K and V: the parallel block (command-a-plus's)."""
+        return self.has_window and not self.has_latent
 
     @property
     def has_indexer(self) -> bool:
@@ -305,20 +364,20 @@ class ModelConfig:
         )
         return 2 if narrow and plain else 1
 
-    def kv_bytes_per_token(self, itemsize: int = 2) -> int:
-        """Bytes a token holds in the page pool's full-attention group, over
-        all layers, at ``itemsize`` a value (an int8 pool's float32 scale a
-        head beside its values): what `make_page_pool`'s leaves come to a
-        token."""
-        layers = self.n_layers_of("full_attention")
-        if self.has_latent:
-            token = self.latent_key_width * itemsize
-        elif self.kv_cache_dtype == "int8":
-            token = 2 * self.n_kv_heads * (self.resolved_head_dim + 4)
+    def kv_bytes_per_token(self, itemsize: int = 2, kind: str = "full_attention") -> int:
+        """Bytes a token holds in the page pool's group of ``kind`` (the
+        full-attention group, or the window group), over that kind's layers,
+        at ``itemsize`` a value (an int8 pool's float32 scale a head beside
+        its values): what `make_page_pool`'s leaves come to a token."""
+        layers, of = self.n_layers_of(kind), self.of_kind(kind)
+        if of.has_latent:
+            token = of.latent_key_width * itemsize
+        elif of.kv_cache_dtype == "int8":
+            token = 2 * of.n_kv_heads * (of.resolved_head_dim + 4)
         else:
-            token = 2 * self.n_kv_heads * self.resolved_head_dim * itemsize
-        if self.has_indexer:
-            token += self.index_key_width * itemsize
+            token = 2 * of.n_kv_heads * of.resolved_head_dim * itemsize
+        if of.has_indexer:
+            token += of.index_key_width * itemsize
         return layers * token
 
     @property
@@ -341,10 +400,11 @@ class ModelConfig:
 
     @property
     def has_window(self) -> bool:
-        """Window layers, and with them (``__post_init__``) the parallel
-        block whose expert layer holds a share: the one predicate of that
-        path, of its second page group and of the expert counts its decode
-        chunks AND prefill segments return (MOE_HELD_COUNTS)."""
+        """Window layers, and with them a second page group. Over K and V
+        (``parallel_block``) they imply the parallel block whose expert layer
+        holds a share, and the expert counts its decode chunks AND prefill
+        segments return (MOE_HELD_COUNTS); over a latent (``latent_kinds``)
+        the block stays the sequential one."""
         return "sliding_attention" in self.layer_pattern
 
     @property
@@ -352,7 +412,7 @@ class ModelConfig:
         """The expert layer is `moe_ffn_held` (and the programs count
         MOE_HELD_COUNTS): the parallel block's always, the sequential
         block's where ``experts_held`` says so."""
-        return self.has_window or bool(self.experts_held)
+        return self.parallel_block or bool(self.experts_held)
 
     @property
     def fills_blocks(self) -> bool:
@@ -383,13 +443,25 @@ class ModelConfig:
 
     def dense_of(self, kind: str) -> int:
         """How many of the leading dense layers of a pattern model are of
-        ``kind``: where the kind's expert stack starts among its layers."""
+        ``kind``: where the kind's expert stack starts among its layers.
+        Leading dense layer i is of kind ``layer_pattern[i % period]``, whether
+        it lies inside the first periods or stands before them (``dense_ahead``)."""
         pattern, n = self.layer_pattern, self.n_leading_dense
         return sum(pattern[i % len(pattern)] == kind for i in range(n)) if pattern else 0
 
     @property
+    def dense_ahead(self) -> int:
+        """Leading dense layers that stand BEFORE the first period: all of
+        them where ``n_layers`` is ``n_leading_dense`` + whole periods and not
+        whole periods itself (LFM2's lie INSIDE its first period: 0)."""
+        pattern = self.layer_pattern
+        return self.n_leading_dense if pattern and self.n_layers % len(pattern) else 0
+
+    @property
     def n_periods(self) -> int:
-        return self.n_layers // len(self.layer_pattern) if self.layer_pattern else 0
+        if not self.layer_pattern:
+            return 0
+        return (self.n_layers - self.dense_ahead) // len(self.layer_pattern)
 
     def n_layers_of(self, kind: str) -> int:
         """Layers of ``kind`` in the model; the page pool's layer axis is
@@ -397,7 +469,8 @@ class ModelConfig:
         ``n_layers_of("sliding_attention")``."""
         if not self.layer_pattern:
             return self.n_layers if kind == "full_attention" else 0
-        return self.n_periods * self.layer_pattern.count(kind)
+        ahead = self.dense_of(kind) if self.dense_ahead else 0
+        return ahead + self.n_periods * self.layer_pattern.count(kind)
 
     @property
     def linear_key_dim(self) -> int:
@@ -412,14 +485,29 @@ class ModelConfig:
         return 2 * self.linear_key_dim + self.linear_value_dim
 
     def __post_init__(self) -> None:
+        if self.kind_view:  # a kind's view of a model that passed what follows
+            return
         if self.layer_pattern:
             unknown = set(self.layer_pattern) - {
                 "linear_attention", "conv", "full_attention", "sliding_attention"
             }
-            if unknown or self.n_layers % len(self.layer_pattern):
+            # whole periods, the leading dense layers inside the first of them
+            # or standing before it (``dense_ahead``)
+            period = len(self.layer_pattern)
+            if unknown or (
+                self.n_layers % period and (self.n_layers - self.n_leading_dense) % period
+            ):
                 raise ValueError(
                     f"{self.name}: layer_pattern {self.layer_pattern} over "
                     f"{self.n_layers} layers"
+                    + (f" ({self.n_leading_dense} leading dense)" if self.n_leading_dense else "")
+                )
+            if self.n_layers % period and not (self.has_window and self.kv_lora_rank > 0):
+                raise ValueError(
+                    f"{self.name}: layer_pattern {self.layer_pattern} over {self.n_layers} "
+                    f"layers: {self.n_leading_dense} leading dense layers BEFORE the first "
+                    "period belong to a model whose attention kinds keep latents (a "
+                    "sequential local cache is cut by whole periods)"
                 )
             if "conv" in self.layer_pattern and (
                 self.conv_kernel < 2 or "linear_attention" in self.layer_pattern
@@ -428,13 +516,18 @@ class ModelConfig:
                     f"{self.name}: conv layers need conv_kernel >= 2 and no "
                     "linear_attention layer beside them (one tail a state row)"
                 )
-            if self.has_window and (
+            if self.parallel_block and (
                 self.sliding_window < 1 or self.is_recurrent or not self.is_moe
             ):
                 raise ValueError(
                     f"{self.name}: window layers need sliding_window >= 1, no "
                     "recurrent layer beside them and an expert layer "
                     "(n_experts > 0): their block is the parallel one"
+                )
+            if self.latent_kinds and (self.sliding_window < 1 or self.is_recurrent):
+                raise ValueError(
+                    f"{self.name}: window layers over a latent need sliding_window >= 1 "
+                    "and no recurrent layer beside them"
                 )
         if self.conv_kernel and "conv" not in self.layer_pattern:
             raise ValueError(
@@ -501,9 +594,14 @@ class ModelConfig:
                 "that fills blocks (block_length > 0)"
             )
 
+        # a pattern of attention kinds alone over a latent (``latent_kinds``, or
+        # full layers alone) is the one pattern a latent and an indexer take
+        other_kinds = bool(set(self.layer_pattern) - {"full_attention", "sliding_attention"})
         if self.has_indexer:
             contradicts = {
-                "a layer pattern, a window or a recurrent layer": bool(self.layer_pattern),
+                # (but the pattern of latent kinds: window layers over a latent)
+                "a layer pattern, a window or a recurrent layer":
+                    bool(self.layer_pattern) and (other_kinds or not self.has_latent),
                 "fills_blocks (block_length > 0)": self.fills_blocks,
                 "an output norm": self.output_norm,
                 "an int8 KV cache": self.kv_cache_dtype == "int8",
@@ -543,7 +641,10 @@ class ModelConfig:
                 )
         if self.has_latent:
             contradicts = {
-                "a layer pattern, a window or a recurrent layer": bool(self.layer_pattern),
+                # (but window layers beside full ones, each kind a latent of its own)
+                "a layer pattern, a window or a recurrent layer":
+                    bool(self.layer_pattern) and (other_kinds or not self.has_window),
+                "a norm other than rms (the parallel block's)": self.norm != "rms",
                 "fills_blocks (block_length > 0)": self.fills_blocks,
                 "m-rope (mrope_section)": bool(self.mrope_section),
                 "an int8 KV cache": self.kv_cache_dtype == "int8",
@@ -559,9 +660,10 @@ class ModelConfig:
                         self.v_head_dim) < 1
                     or self.qk_rope_head_dim % 2 == 1,
                 f"v_head_dim {self.v_head_dim} apart from qk_nope_head_dim + "
-                "qk_rope_head_dim under an indexer (the selected read's kernels are held "
-                "at one head width; a latent with no indexer takes a value of its own width)":
-                    self.has_indexer
+                "qk_rope_head_dim and no multiple of 8 under an indexer (the selected read's "
+                "kernels take a value of its own width in whole sublane rows, as the dense "
+                "read's do)":
+                    self.has_indexer and self.v_head_dim % 8 != 0
                     and self.v_head_dim != self.qk_nope_head_dim + self.qk_rope_head_dim,
                 f"head_dim {self.head_dim} (a latent model's head is qk_nope_head_dim "
                 "+ qk_rope_head_dim: leave it unset)": self.head_dim is not None,
@@ -578,6 +680,36 @@ class ModelConfig:
                 f"{self.name}: q_lora_rank, qk_nope_head_dim, qk_rope_head_dim and "
                 "v_head_dim belong to a model with a latent (kv_lora_rank > 0)"
             )
+        if self.latent_rescale or self.attn_gate:
+            contradicts = {
+                "no latent (kv_lora_rank 0: the latent's block alone reads them)":
+                    not self.has_latent,
+                f"attn_gate {self.attn_gate!r} ('' | headwise)":
+                    self.attn_gate not in ("", "headwise"),
+            }
+            if any(contradicts.values()):
+                raise ValueError(
+                    f"{self.name}: latent_rescale / attn_gate with "
+                    + "; ".join(k for k, on in contradicts.items() if on)
+                )
+        if self.window_attention:
+            own = dict(self.window_attention)
+            view = self.of_kind("sliding_attention")
+            contradicts = {
+                "no window layers over a latent (layer_pattern with sliding_attention "
+                "and kv_lora_rank > 0)": not self.latent_kinds,
+                f"fields outside {KIND_FIELDS}": bool(set(own) - set(KIND_FIELDS))
+                    or len(own) != len(self.window_attention),
+                "a rank, a head width or n_heads under 1, or an odd qk_rope_head_dim":
+                    min(view.n_heads, view.q_lora_rank, view.kv_lora_rank,
+                        view.qk_nope_head_dim, view.qk_rope_head_dim, view.v_head_dim) < 1
+                    or view.qk_rope_head_dim % 2 == 1,
+            }
+            if any(contradicts.values()):
+                raise ValueError(
+                    f"{self.name}: window_attention {self.window_attention} with "
+                    + "; ".join(k for k, on in contradicts.items() if on)
+                )
         if self.rope_scaling_type not in ("llama3", "yarn"):
             raise ValueError(
                 f"{self.name}: rope_scaling_type {self.rope_scaling_type!r} (llama3 | yarn)"
@@ -623,16 +755,32 @@ class ModelConfig:
         return self.head_dim if self.head_dim is not None else self.d_model // self.n_heads
 
     @property
+    def _attn_params(self) -> int:
+        d, hd = self.d_model, self.resolved_head_dim
+        if not self.has_latent:
+            return d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
+        return (
+            d * self.q_lora_rank + self.q_lora_rank * self.n_heads * hd
+            + d * self.latent_width
+            + self.kv_lora_rank * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
+            + self.n_heads * self.v_head_dim * d
+        )
+
+    @property
     def approx_params(self) -> int:
         """Rough parameter count (placement decisions, not accounting)."""
-        d, hd = self.d_model, self.resolved_head_dim
-        attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) + self.n_heads * hd * d
-        if self.has_latent:
-            attn = (
-                d * self.q_lora_rank + self.q_lora_rank * self.n_heads * hd
-                + d * self.latent_width
-                + self.kv_lora_rank * self.n_heads * (self.qk_nope_head_dim + self.v_head_dim)
-                + self.n_heads * self.v_head_dim * d
+        d = self.d_model
+        attn = self._attn_params
+        if self.latent_kinds:  # each attention kind at its own geometry
+            window = self.n_layers_of("sliding_attention")
+            embed = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+            ffn = 3 * d * self.expert_d_ff * (
+                self.held_experts[1] + self.n_shared_experts
+            ) + d * self.n_experts if self.is_moe else 3 * d * self.d_ff
+            return (
+                (self.n_layers - window) * attn
+                + window * self.of_kind("sliding_attention")._attn_params
+                + self.n_layers * ffn + self.n_leading_dense * (3 * d * self.d_ff - ffn) + embed
             )
         if self.layer_pattern:
             linear = 2 * d * (self.linear_key_dim + self.linear_value_dim) + (
@@ -662,6 +810,17 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+
+@functools.lru_cache(maxsize=None)
+def _kind_view(config: ModelConfig, kind: str) -> ModelConfig:
+    """`ModelConfig.of_kind`'s view, built once a (model, kind)."""
+    own = {k: v for k, v in config.window_attention if k in KIND_FIELDS}
+    return dataclasses.replace(
+        config, **own, n_kv_heads=own.get("n_heads", config.n_heads), index_topk=0,
+        index_n_heads=0, index_head_dim=0, index_rope_dim=0, index_query_input="hidden",
+        window_attention=(), kind_view=kind,
+    )
 
 
 def _preset(**kw) -> ModelConfig:
@@ -969,6 +1128,57 @@ MODEL_PRESETS: dict[str, ModelConfig] = {
         qk_nope_head_dim=16,
         qk_rope_head_dim=8,
         v_head_dim=16,
+    ),
+    "tiny-dots3-test": _preset(
+        # two KINDS of latent layer in one model, at test size
+        # (tests/test_dots3_note.py): a leading dense layer of the full kind
+        # BEFORE the first period, then (full, window x 3) x 2. The full kind:
+        # 4 heads of 8 + 8 (values 8: narrower than the key), a key-value
+        # latent of 16, base 1e6, an indexer of 2 heads of 16 (8 turned) on the
+        # query latent that keeps 16 tokens. The window kind: 2 heads of 24 + 8
+        # (values 8), a key-value latent of 32, base 1e3, the last 9 tokens.
+        # Both rescale their normed latents and gate each head's output. 8
+        # sigmoid-routed experts top-2 under a non-zero bias, of which this
+        # share holds 4, one shared expert, an untied head
+        name="tiny-dots3-test",
+        vocab_size=512,
+        d_model=64,
+        n_layers=9,
+        n_heads=4,
+        n_kv_heads=4,
+        d_ff=128,
+        rope_theta=1000000.0,
+        rms_norm_eps=1e-5,
+        max_seq_len=256,
+        rope_interleaved=True,
+        layer_pattern=(
+            "full_attention", "sliding_attention", "sliding_attention", "sliding_attention",
+        ),
+        sliding_window=9,
+        n_experts=8,
+        n_experts_per_tok=2,
+        moe_d_ff=32,
+        experts_held=(0, 4),
+        moe_scoring="sigmoid",
+        n_shared_experts=1,
+        router_bias=True,
+        n_leading_dense=1,
+        q_lora_rank=32,
+        kv_lora_rank=16,
+        qk_nope_head_dim=8,
+        qk_rope_head_dim=8,
+        v_head_dim=8,
+        index_n_heads=2,
+        index_head_dim=16,
+        index_topk=16,
+        index_rope_dim=8,
+        index_query_input="query_latent",
+        window_attention=(
+            ("n_heads", 2), ("kv_lora_rank", 32), ("qk_nope_head_dim", 24),
+            ("rope_theta", 1000.0),
+        ),
+        latent_rescale=True,
+        attn_gate="headwise",
     ),
     "tiny-lfm2-test": _preset(
         # the LFM2-MoE block at test size (tests/test_lfm2_moe.py): (conv,

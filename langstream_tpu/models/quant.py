@@ -31,6 +31,8 @@ _QUANT_LAYER_KEYS = (
     "ws_gate", "ws_up", "ws_down",
     # latent attention: the two down-projections and the two up-projections
     "wq_a", "wq_b", "wkv_a", "wkv_b",
+    # and its heads' gate, a scalar a head
+    "w_attn_gate",
 )
 
 

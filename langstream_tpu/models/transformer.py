@@ -53,6 +53,7 @@ SCOPES = (
     "block_choice",
     "attention.index", "attention.index.scores", "attention.select", "attention.sparse",
     "attention.latent", "attention.latent.expand", "attention.latent.read",
+    "attention.latent.window", "attention.gate",
 )
 
 # What `moe_ffn_counted` counts, per call, as one int32 vector: expert
@@ -98,8 +99,10 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
     hd = config.resolved_head_dim
     f, L, v = config.d_ff, config.n_layers, config.vocab_size
 
-    if config.has_window:
+    if config.parallel_block:
         return _init_window_params(config, key, dtype)
+    if config.latent_kinds:
+        return _init_latent_kinds_params(config, key, dtype)
     if config.layer_pattern:
         return _init_pattern_params(config, key, dtype)
     keys = jax.random.split(key, 12)
@@ -152,7 +155,7 @@ def init_params(config: ModelConfig, key: jax.Array, dtype: Optional[Any] = None
         layers["w_gate"] = norm(keys[5], L, e, d, f, scale=d)
         layers["w_up"] = norm(keys[6], L, e, d, f, scale=d)
         layers["w_down"] = norm(keys[7], L, e, f, d, scale=f)
-        if config.n_shared_experts and not config.has_window:
+        if config.n_shared_experts and not config.parallel_block:
             # side by side, as `_init_window_params` keeps them
             ns, ks = config.n_shared_experts, jax.random.split(jax.random.fold_in(key, 15), 3)
             layers["ws_gate"] = norm(ks[0], L, d, ns * f, scale=d)
@@ -190,13 +193,17 @@ def _init_latent_attention(config: ModelConfig, key: jax.Array, n: int, dtype) -
         w = jax.random.normal(next(keys), shape, jnp.float32) * shape[-2] ** -0.5
         return w.astype(dtype)
 
-    return {
+    weights = {
         "wq_a": normal(n, d, ql), "q_a_norm": jnp.ones((n, ql), dtype),
         "wq_b": normal(n, ql, h * hd),
         "wkv_a": normal(n, d, config.latent_width), "kv_a_norm": jnp.ones((n, kl), dtype),
         "wkv_b": normal(n, kl, h * (config.qk_nope_head_dim + config.v_head_dim)),
         "wo": normal(n, h * config.v_head_dim, d),
     }
+    if config.attn_gate:  # a scalar a head from the normed input (`_head_gate`)
+        w = jax.random.normal(jax.random.fold_in(key, 5), (n, d, h), jnp.float32)
+        weights["w_attn_gate"] = (w * d**-0.5).astype(dtype)
+    return weights
 
 
 def _init_dense_layers(config: ModelConfig, key: jax.Array, dtype) -> dict:
@@ -209,6 +216,39 @@ def _init_dense_layers(config: ModelConfig, key: jax.Array, dtype) -> dict:
         router_bias=False, routed_scaling=1.0,
     )
     return init_params(dense, key, dtype)["layers"]
+
+
+def _init_latent_kinds_params(config: ModelConfig, key: jax.Array, dtype) -> Params:
+    """A model whose attention kinds each keep a latent of their own
+    (``config.latent_kinds``): ``params["layers"][kind]`` a stack a kind, each
+    layer what a uniform latent model's is AT ITS KIND'S GEOMETRY (the full
+    kind's with the indexer, the window kind's without), experts held as a
+    share; the leading dense layers under ``params["dense_layers"][kind]``."""
+
+    def stack(kind, n, seed, **dense):
+        flat = dataclasses.replace(
+            config.of_kind(kind), layer_pattern=(), sliding_window=0, window_attention=(),
+            n_layers=n, n_leading_dense=0, kind_view=kind, **dense,
+        )
+        return init_params(flat, jax.random.fold_in(key, seed), dtype)
+
+    kinds = [k for k in ("full_attention", "sliding_attention") if config.n_layers_of(k)]
+    outer = stack("full_attention", 1, 0)  # embedding, final norm and head
+    params: Params = {k: v for k, v in outer.items() if k != "layers"}
+    params["layers"] = {
+        kind: stack(kind, config.n_layers_of(kind) - config.dense_of(kind), 1 + i)["layers"]
+        for i, kind in enumerate(kinds)
+    }
+    no_experts = dict(
+        n_experts=0, experts_held=(), moe_d_ff=0, moe_scoring="softmax", n_shared_experts=0,
+        router_bias=False, routed_scaling=1.0,
+    )
+    if config.n_leading_dense:
+        params["dense_layers"] = {
+            kind: stack(kind, config.dense_of(kind), 8 + i, **no_experts)["layers"]
+            for i, kind in enumerate(kinds) if config.dense_of(kind)
+        }
+    return params
 
 
 def _init_pattern_params(config: ModelConfig, key: jax.Array, dtype) -> Params:
@@ -1296,6 +1336,7 @@ def _attention_block(
     adapter_rows: Optional[jax.Array] = None,  # [B] pool row per slot
     layer: Optional[jax.Array] = None,  # scalar: this block's layer of the pool
     block: bool = False,  # a block pass (`paged_block_step_inplace`)
+    lengths: Optional[jax.Array] = None,  # [B]: a latent window kind's decode step's
 ) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]]]:
     """The attention half of a block (norm, QKV, rotary, cache write, the
     kernel or jnp path, output projection, residual): the layer's input in,
@@ -1320,7 +1361,7 @@ def _attention_block(
             )
         return _latent_attention_block(
             x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal, collect_kv,
-            paged_table, page_size, layer,
+            paged_table, page_size, layer, lengths=lengths,
         )
     if paged_table is None:
         with jax.named_scope("attention"):
@@ -1635,6 +1676,24 @@ def _selected_segment_read(
 # ---------------------------------------------------------------------------
 
 
+def _rescaled(c, ratio: float):
+    return (c.astype(jnp.float32) * jnp.float32(ratio**0.5)).astype(c.dtype)
+
+
+def _head_gate(attn, u, lp, config):
+    """``attn`` [B, S, H x v] with each head's output multiplied by its gate,
+    ``sigmoid(u W_g)`` [B, S, H] in float32: one scalar a head and token from
+    the layer's normed input ``u``, after the softmax's mix and before ``wo``
+    (``config.attn_gate`` "headwise"; "": ``attn`` as it is)."""
+    if not config.attn_gate:
+        return attn
+    b, s, _ = attn.shape
+    with jax.named_scope("attention.gate"):
+        gate = jax.nn.sigmoid(quantized_matmul(u, lp["w_attn_gate"]).astype(jnp.float32))
+        gated = attn.reshape(b, s, config.n_heads, -1).astype(jnp.float32) * gate[..., None]
+        return gated.astype(attn.dtype).reshape(b, s, -1)
+
+
 def _latent_proj(x, lp, sin, cos, config):
     """(u, c_q, q, lat) of the layer's input ``x`` [B, S, d]: the normed
     input, the normed query latent [B, S, q_lora_rank], the queries
@@ -1647,10 +1706,18 @@ def _latent_proj(x, lp, sin, cos, config):
     with jax.named_scope("attention.latent"):
         u = rms_norm(x, lp["attn_norm"], eps)
         c_q = rms_norm(quantized_matmul(u, lp["wq_a"]), lp["q_a_norm"], eps)
+        if config.latent_rescale:
+            # the normed latents at the variance a full-width input would give
+            # their up-projections; the rotary key is not rescaled
+            # (in float32: the factor rounded to bf16 would be off by up to 0.2%
+            # for every token alike)
+            c_q = _rescaled(c_q, config.d_model / config.q_lora_rank)
         q = quantized_matmul(c_q, lp["wq_b"]).reshape(b, s, config.n_heads, -1)
         q = jnp.concatenate([q[..., :nope], turn(q[..., nope:], sin, cos)], axis=-1)
         kv = quantized_matmul(u, lp["wkv_a"])
         c_kv = rms_norm(kv[..., :kl], lp["kv_a_norm"], eps)
+        if config.latent_rescale:
+            c_kv = _rescaled(c_kv, config.d_model / kl)
         k_rope = turn(kv[:, :, None, kl:], sin, cos)[:, :, 0]
         lat = jnp.concatenate([c_kv, k_rope], axis=-1)
     return u, c_q, q, lat
@@ -1724,13 +1791,19 @@ def segment_blocks_visited(offset: int, s: int, t: int, config) -> dict:
     if config.has_window:
         windows["key_blocks_window"] = config.sliding_window
     itemsize = jnp.dtype(config.dtype).itemsize
+    # (a latent window kind's walk is over its band, whose first column lies
+    # on a page's edge and not on a key block's: counted here as if over the
+    # table, which differs by a block a query block at most)
+    widths = {"key_blocks": d, "key_blocks_window": (
+        config.of_kind("sliding_attention").resolved_head_dim if config.has_latent else d
+    )}
     return {
-        name: ops.segment_blocks_visited(offset, s, t, d, group, window, itemsize)
+        name: ops.segment_blocks_visited(offset, s, t, widths[name], group, window, itemsize)
         for name, window in windows.items()
     }
 
 
-def _latent_expand_seen(rows, lp, offsets, s, config):
+def _latent_expand_seen(rows, lp, offsets, s, config, whole=False):
     """`_latent_expand` of a row's gathered latents ``rows`` [B, T, W] for a
     segment of ``s`` queries at ``offsets[b] ..``: the columns
     `latent_columns_expanded` names and no others, straight into the
@@ -1743,8 +1816,10 @@ def _latent_expand_seen(rows, lp, offsets, s, config):
         return _latent_expand(rows, lp, config)
     with jax.named_scope("attention.latent.expand"):
         ops.note_path("paged-segment-latent-expand", "latent_expand_blocks", config, s=s, t=t)
+        # ``whole``: a window kind's band, every column of which is visited
         return ops.latent_expand_blocks(
-            rows, *_wkv_b(lp, config), latent_columns_expanded(offsets, s, t, config),
+            rows, *_wkv_b(lp, config),
+            jnp.full_like(offsets, t) if whole else latent_columns_expanded(offsets, s, t, config),
             ops.latent_expand_block(s, t, config), config,
             interpret=jax.default_backend() != "tpu",
         )
@@ -1785,7 +1860,7 @@ def _latent_value_out(mixed, lp, config):
 
 
 def _latent_decode_read(q, index, plat, pik, table, layer, positions, lp, config, page_size,
-                        kernels):
+                        kernels, lengths=None):
     """A decode step's read of a latent model -> [B, 1, H x v]: the attention
     IN THE LATENT SPACE, absorbed queries against ``[c_kv | k_rope]`` as the
     pool holds them, the value the same row's first ``kv_lora_rank`` lanes.
@@ -1797,7 +1872,11 @@ def _latent_decode_read(q, index, plat, pik, table, layer, positions, lp, config
     traced. With the kernels ONE call of the paged decode kernel over the
     latent leaf (`ops/attention.ragged_paged_latent_attention`: a page is
     fetched once for key and value), under the selection as a mask over the
-    row's pages or with no mask operand at all, whatever the table's length
+    row's pages or with no mask operand at all, or, under a window
+    (``config.attn_window``: a window kind's layer, no indexer, ``lengths`` the
+    rows' from the full group's table, this table a ring), from ``length -
+    window`` on with the pages behind it never walked (scope
+    ``attention.latent.window``), whatever the table's length
     (a gather of 2,048 rows of 1,280 B a row and layer pays the gather's
     10 ns a row five times over Keye's 256 B and loses sooner:
     `_WALK_TABLE_PER_TOPK`'s prices); without them the row's latents
@@ -1806,9 +1885,14 @@ def _latent_decode_read(q, index, plat, pik, table, layer, positions, lp, config
     from langstream_tpu.ops import attention as ops
 
     b, kl, topk = q.shape[0], config.kv_lora_rank, config.index_topk
-    t = table.shape[1] * page_size
-    lengths = _paged_lengths(table, positions[:, 0], page_size, plat.shape[1])
+    t, window = table.shape[1] * page_size, config.attn_window
+    if lengths is None:
+        lengths = _paged_lengths(table, positions[:, 0], page_size, plat.shape[1])
     visible = jnp.arange(t)[None, :] < lengths[:, None]
+    bound = {}
+    if window:
+        bound["lower"] = jnp.maximum(lengths - window, 0)
+        visible = visible & (jnp.arange(t)[None, :] >= bound["lower"][:, None])
     chosen = None
     if index is not None:
         q_idx, _, w_idx = index
@@ -1825,17 +1909,19 @@ def _latent_decode_read(q, index, plat, pik, table, layer, positions, lp, config
         chosen = lax.cond(jnp.any(lengths > topk), ranked, lambda: visible)
     with jax.named_scope("attention.latent"):
         absorbed = _latent_absorb(q[:, 0], lp, config, plat.shape[-1])
-    with jax.named_scope("attention.latent.read" if index is None else "attention.sparse"):
+    what = "paged-decode-latent-window" if window else "paged-decode-latent"
+    with jax.named_scope(
+        "attention.latent.window" if window
+        else "attention.latent.read" if index is None else "attention.sparse"
+    ):
         if kernels:
-            ops.note_path(
-                "paged-decode-latent", "ragged_paged_latent_attention", config, s=1, t=t
-            )
+            ops.note_path(what, "ragged_paged_latent_attention", config, s=1, t=t)
             mixed = ops.ragged_paged_latent_attention(
                 absorbed, plat, lengths, table, layer, chosen, config, page_size,
-                interpret=jax.default_backend() != "tpu",
+                interpret=jax.default_backend() != "tpu", **bound,
             ).reshape(b, config.n_heads, kl)
         else:
-            ops.note_path("paged-decode-latent", "jnp", config, s=1, t=t)
+            ops.note_path(what, "jnp", config, s=1, t=t)
             seen = visible if chosen is None else chosen
             rows = _paged_gather(plat, layer, table, page_size)[:, 0]  # [B, T, W]
             logits = jnp.einsum(
@@ -1854,9 +1940,46 @@ def _latent_decode_read(q, index, plat, pik, table, layer, positions, lp, config
         return _latent_value_out(mixed, lp, config)[:, None, :]
 
 
+def latent_window_band(s: int, t: int, window: int, page_size: int) -> int:
+    """Columns of the BAND a window kind's segment of ``s`` queries gathers
+    and re-expands, a layer: the segment, the ``window - 1`` columns before
+    it and what a page's edge adds, in whole key blocks of the segment walk
+    (512 columns) where the table of ``t`` is longer, else the whole table."""
+    band = -(-(s + window - 1 + page_size - 1) // page_size) * page_size
+    band = -(-band // 512) * 512 if band >= 512 else band
+    return min(band, t)
+
+
+def _latent_window_segment(q, plat, table, layer, positions, lp, config, page_size):
+    """A window kind's segment read -> [B, S, H x v]: the row's latents of the
+    band its queries see gathered through the ring's table (whole pages from
+    the one that holds column ``offset - window + 1``; `latent_window_band`),
+    re-expanded (`_latent_expand_seen`: every column of the band, which is all
+    a walk under the window visits) and read through the segment kernel's
+    window walk at positions counted from the band's first column, masked jnp
+    where the tiles do not fit. A page of the band that the ring no longer
+    maps, or that lies past the table, reads the sentinel's clamp: columns no
+    query of the segment sees."""
+    s, tp, window = q.shape[1], table.shape[1], config.attn_window
+    band = latent_window_band(s, tp * page_size, window, page_size)
+    # [B] the band's first logical page, the band kept inside the table
+    first = jnp.maximum(positions[:, 0] - (window - 1), 0) // page_size
+    first = jnp.minimum(first, tp - band // page_size)
+    pages = jnp.take_along_axis(
+        table, first[:, None] + jnp.arange(band // page_size)[None, :], axis=1
+    )
+    rows = _paged_gather(plat, layer, pages, page_size)[:, 0]  # [B, band, W]
+    at = positions - (first * page_size)[:, None]  # the queries' columns in the band
+    k_all, v_all = _latent_expand_seen(rows, lp, at[:, 0], s, config, whole=True)
+    return _dispatch_attention(
+        q, k_all, v_all, None, config, True, what="paged-segment-latent-window",
+        positions=at, window=window, from_zero=False,
+    )
+
+
 def _latent_attention_block(
     x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal, collect_kv,
-    paged_table, page_size, layer,
+    paged_table, page_size, layer, lengths=None,
 ):
     """`_attention_block` of a model that keeps a latent: (the FFN's input,
     the layer's cache leaves ``config.page_leaves``, written: ``("lat",
@@ -1871,10 +1994,20 @@ def _latent_attention_block(
     that never enters the pool, and reads it as a model with an indexer does
     (`_selected_segment_read`) or, with none, causally through
     `_dispatch_attention`'s kernels under ``attention.latent.read``. A model
-    with no indexer traces none of its projections, leaf or branches."""
+    with no indexer traces none of its projections, leaf or branches.
+    ``config`` is the layer's KIND's geometry (`ModelConfig.of_kind`); under
+    its window (``config.attn_window``: a window kind's layer, which has no
+    indexer) a query sees the last ``window`` columns with its own: the decode
+    read walks its ring
+    from ``length - window`` (``lengths``: the rows', from the full group's
+    table), a segment gathers and re-expands the BAND its queries see and no
+    other column (`_latent_window_segment`), both under
+    ``attention.latent.window``. Where the kind gates its heads
+    (``config.attn_gate``) the read's output passes `_head_gate` before ``wo``."""
     from langstream_tpu.ops import attention as ops
 
     b, s = x.shape[:2]
+    window = config.attn_window
     positions = (
         jnp.broadcast_to(jnp.arange(s), (b, s)) if cache_positions is None else cache_positions
     )
@@ -1887,8 +2020,10 @@ def _latent_attention_block(
     # the dense read's scope; the selected read names its own
     read_scope = (
         contextlib.nullcontext() if index is not None
-        else jax.named_scope("attention.latent.read")
+        else jax.named_scope("attention.latent.window" if window else "attention.latent.read")
     )
+    if window and mask is not None:  # what the masked jnp reads by
+        mask = mask & _seen(positions, mask.shape[-1], window)
     if paged_table is not None:
         plat, *pik = cache_kv
         with jax.named_scope("kv_pool.write"):
@@ -1902,7 +2037,13 @@ def _latent_attention_block(
                 attn = _latent_decode_read(
                     q, index, plat, pik[0] if pik else None, paged_table, layer, positions,
                     lp, config, page_size, ops.paged_pallas_ok(config, page_size),
+                    lengths=lengths,
                 )
+            elif window:
+                with read_scope:
+                    attn = _latent_window_segment(
+                        q, plat, paged_table, layer, positions, lp, config, page_size
+                    )
             else:
                 rows = _paged_gather(plat, layer, paged_table, page_size)[:, 0]
                 k_all, v_all = _latent_expand_seen(rows, lp, positions[:, 0], s, config)
@@ -1917,7 +2058,7 @@ def _latent_attention_block(
                         q, index[0], index[2], pik[0], k_all, v_all, paged_table, layer, mask,
                         positions, config, "paged-segment-latent",
                     )
-            return x + quantized_matmul(attn, lp["wo"]), (plat, *pik)
+            return x + quantized_matmul(_head_gate(attn, u, lp, config), lp["wo"]), (plat, *pik)
     with jax.named_scope("attention"):
         new_cache = None
         k_idx = None if index is None else index[1]
@@ -1947,8 +2088,12 @@ def _latent_attention_block(
             )
         else:
             with read_scope:
-                attn = _dispatch_attention(q, k_all, v_all, mask, config, causal)
-        return x + quantized_matmul(attn, lp["wo"]), new_cache
+                bound = (
+                    {"window": window, "positions": positions, "what": "prefill-latent-window"}
+                    if window else {}
+                )
+                attn = _dispatch_attention(q, k_all, v_all, mask, config, causal, **bound)
+        return x + quantized_matmul(_head_gate(attn, u, lp, config), lp["wo"]), new_cache
 
 
 def _qkv(x, lp, sin, cos, config, lora, lora_scale, adapter_rows):
@@ -2121,6 +2266,7 @@ def _layer_counted(
     block: bool = False,  # a block pass: S queries a row that see one another
     moe_layer: Optional[jax.Array] = None,  # with held experts' stacks in ``lp``
     dense: bool = False,  # a leading dense layer of an expert model
+    lengths: Optional[jax.Array] = None,  # [B]: a latent window kind's decode step's rows'
 ) -> tuple[jax.Array, Optional[tuple[jax.Array, jax.Array]], jax.Array]:
     """One transformer block, and its MOE_COUNTS (zeros when dense; only
     ``token_valid`` feeds them). If cache_kv given, k/v are written at
@@ -2140,7 +2286,7 @@ def _layer_counted(
     x, new_cache = _attention_block(
         x, lp, sin, cos, mask, config, cache_kv, cache_positions, causal,
         collect_kv, verify, paged_table, page_size,
-        lora, lora_scale, adapter_rows, layer, block,
+        lora, lora_scale, adapter_rows, layer, block, lengths,
     )
     y, counts = _ffn_half(
         x, lp, config, config.output_norm, token_valid, lora, lora_scale,
@@ -2416,13 +2562,20 @@ _HELD_EXPERTS = ("w_gate", "w_up", "w_down")
 _KIND_KEY = {"full_attention": None, "sliding_attention": "win"}
 
 
-def _kind_entry(state, kind):
+def _kind_leaves(config, kind) -> tuple:
+    """The cache leaves a layer of ``kind`` reads and writes: "k" and "v", or
+    its kind's own (``"lat"``, with the full kind's ``"ik"``) in a model whose
+    kinds each keep a latent."""
+    return config.of_kind(kind).page_leaves if config.latent_kinds else ("k", "v")
+
+
+def _kind_entry(state, kind, config):
     tree = state if _KIND_KEY[kind] is None else state[_KIND_KEY[kind]]
-    return tree["k"], tree["v"]
+    return tuple(tree[leaf] for leaf in _kind_leaves(config, kind))
 
 
-def _with_entry(state, kind, entry):
-    new = {"k": entry[0], "v": entry[1]}
+def _with_entry(state, kind, entry, config):
+    new = dict(zip(_kind_leaves(config, kind), entry))
     return {**state, **new} if _KIND_KEY[kind] is None else {**state, _KIND_KEY[kind]: new}
 
 
@@ -2754,12 +2907,28 @@ def _sequential_kind(x, lp, kind, layer, entry, rec, config, walk, dense=False, 
     (Olmo-Hybrid's, LFM2's): over the pool's pages where there is a table,
     else over ITS entry of the local cache. ``dense``, ``stack_at``: as
     `_conv_kind`'s."""
+    sin, cos, table, how, whole = walk["sin"], walk["cos"], walk["tables"], {}, None
+    if config.latent_kinds:
+        # the layer at ITS KIND's geometry, rotary table and bound, through its
+        # kind's table; a local cache rides the carry whole (the parallel
+        # block's way) and the layer's entry is cut out of it and put back
+        sin, cos = walk["rotary"][kind]
+        how = {"lengths": walk["lengths"]}
+        if table is not None:
+            table = table[WINDOW if _KIND_KEY[kind] else FULL]
+        elif entry is not None:
+            whole = entry
+            entry = tuple(lax.dynamic_index_in_dim(a, layer, 0, keepdims=False) for a in whole)
     x, entry, counts = _layer_counted(
-        x, lp, walk["sin"], walk["cos"], walk["mask"], config, cache_kv=entry,
-        cache_positions=walk["positions"], paged_table=walk["tables"],
+        x, lp, sin, cos, walk["mask"], config.of_kind(kind), cache_kv=entry,
+        cache_positions=walk["positions"], paged_table=table,
         page_size=walk["page_size"], layer=layer, token_valid=walk["token_valid"],
-        moe_layer=stack_at, dense=dense,
+        moe_layer=stack_at, dense=dense, **how,
     )
+    if whole is not None:
+        entry = tuple(
+            lax.dynamic_update_index_in_dim(a, new, layer, 0) for a, new in zip(whole, entry)
+        )
     return x, entry, rec, counts if config.is_moe and not dense else None
 
 
@@ -2781,9 +2950,9 @@ def _parallel_kind(x, lp, kind, layer, entry, rec, config, walk):
 
 def _kind_layers(config: ModelConfig) -> dict:
     """kind -> its layer function. What an attention kind is follows the
-    model's block: the parallel one beside window layers, else the
-    sequential one."""
-    attention = _parallel_kind if config.has_window else _sequential_kind
+    model's block: the parallel one beside window layers over K and V, else
+    the sequential one (window layers over a latent with it)."""
+    attention = _parallel_kind if config.parallel_block else _sequential_kind
     return {
         "linear_attention": _linear_kind, "conv": _conv_kind, "full_attention": attention,
         "sliding_attention": attention,
@@ -2814,24 +2983,35 @@ def _scan_periods(params, x, config, state=None, rec=None, **walk):
     # held experts' weights go on as the stack: the grouped product reads its
     # blocks at (layer, expert) where they lie
     whole = _HELD_EXPERTS if config.holds_experts else ()
-    ahead = -(-config.n_leading_dense // len(pattern))  # periods with a dense layer
+    # leading dense layers INSIDE the first periods, or (``lead``) standing
+    # before the first of them: the kind's layers then start behind those
+    lead = config.dense_ahead
+    ahead = 0 if lead else -(-config.n_leading_dense // len(pattern))  # periods with one
     behind = {kind: config.dense_of(kind) for kind in per}
+    shift = behind if lead else dict.fromkeys(per, 0)
+    if config.latent_kinds:  # a rotary table a kind
+        walk["rotary"] = {
+            kind: _rope_freqs(walk["positions"], config.of_kind(kind)) for kind in per
+        }
     local = None
     if state is not None and walk["tables"] is None and not config.has_window:
         local, state = jax.tree.map(
             lambda a: a.reshape(periods, per["full_attention"], *a.shape[1:]), state
         ), None
 
-    def period(carry, local_p, p, dense=0):
+    def period(carry, local_p, p, dense=0, places=pattern):
         """Period ``p``'s layers, the first ``dense`` of them leading dense
-        layers (a period of the scan has none)."""
+        layers (a period of the scan has none); ``places``: the kinds run, the
+        period's or, before the first period, the leading layers'."""
         x, state, rec, counts = carry
         at = dict.fromkeys(per, 0)
         written = []
-        for place, kind in enumerate(pattern):
+        for place, kind in enumerate(places):
             i = at[kind]
             at[kind] += 1
             layer = p * per[kind] + i
+            if places is pattern and shift[kind]:  # behind the kind's leading layers
+                layer = layer + shift[kind]
             # ONE layer's weights, sliced where they are used: the stacks are
             # closed over, not scanned. A period's slice [per, ...] of a
             # scanned stack is a buffer of its own, all of a period's weights
@@ -2857,14 +3037,14 @@ def _scan_periods(params, x, config, state=None, rec=None, **walk):
                     jax.tree.map(lambda a: a[i], local_p["v"]),
                 )
             elif kind in _KIND_KEY and state is not None:
-                entry = _kind_entry(state, kind)
+                entry = _kind_entry(state, kind, config)
             x, entry, rec, c = layers[kind](x, lp, kind, layer, entry, rec, config, walk, **how)
             if c is not None:
                 counts = counts + c
             if entry is not None and local_p is not None:
                 written.append(entry)
             elif entry is not None:
-                state = _with_entry(state, kind, entry)
+                state = _with_entry(state, kind, entry, config)
         ys = None
         if local_p is not None:
             ys = {
@@ -2875,6 +3055,10 @@ def _scan_periods(params, x, config, state=None, rec=None, **walk):
 
     counts = jnp.zeros(len(moe_count_names(config)), jnp.int32) if config.is_moe else None
     carry, first = (x, state, rec, counts), []
+    if lead:
+        carry, _ = period(
+            carry, None, 0, dense=lead, places=[pattern[i % len(pattern)] for i in range(lead)]
+        )
     for p in range(ahead):
         carry, ys = period(
             carry, None if local is None else jax.tree.map(lambda a: a[p], local), p,
@@ -3118,12 +3302,16 @@ def _run_layers(
         )
     kv, rec = (None, None) if state is None else split_rec(state)
     lengths = None
+    if config.latent_kinds and table is not None:
+        # the entry points' mask is over ONE table: here it is the full group's
+        mask = _paged_mask(table[FULL], page_size, positions)
     if row_positions is not None:
         # the row's live length, from the full group's table (a prefix of
         # mapped pages); a window layer reads its last ``sliding_window``. A
         # row whose table maps nothing, or that has stepped past its pages,
         # is idle: its recurrent state stays as it is
-        pages = kv["k"]["q"] if isinstance(kv["k"], dict) else kv["k"]
+        pages = kv[config.page_leaves[0]]
+        pages = pages["q"] if isinstance(pages, dict) else pages
         lengths = _paged_lengths(
             table[FULL] if config.has_window else table, row_positions, page_size,
             pages.shape[1],
@@ -3235,10 +3423,12 @@ def make_kv_cache(
         if config.has_latent:
             # ONE row a token in place of K and V, K's layout with one head:
             # [L, B, 1, T, latent_key_width]; the indexer's key beside it
-            # where the model has an indexer
-            cache = {"lat": jnp.zeros((*shape[:2], 1, max_len, config.latent_key_width), dtype)}
-            if config.has_indexer:
-                cache["ik"] = jnp.zeros((*shape[:2], max_len, config.index_key_width), dtype)
+            # where the model has an indexer. Each at the KIND's own width
+            # (a window kind of its own geometry, which has no indexer)
+            of = config.of_kind(kind)
+            cache = {"lat": jnp.zeros((*shape[:2], 1, max_len, of.latent_key_width), dtype)}
+            if of.has_indexer:
+                cache["ik"] = jnp.zeros((*shape[:2], max_len, of.index_key_width), dtype)
             return cache
         if config.kv_cache_dtype == "int8":
             if _KIND_KEY[kind]:
@@ -3542,8 +3732,9 @@ def paged_insert_cache(
         # the indexer's keys [L, n, W, Di] to [L, P, ps, Di] by the same table;
         # K and V as for every model
         w = local_cache["ik"].shape[2]
-        positions = jnp.broadcast_to(jnp.arange(w)[None, :], (tables.shape[0], w))
-        pages, offs = _page_index(tables, positions, page_size, pool["ik"].shape[1])
+        full = tables[FULL] if "win" in pool else tables  # ([2, n, Tp] with a window group)
+        positions = jnp.broadcast_to(jnp.arange(w)[None, :], (full.shape[0], w))
+        pages, offs = _page_index(full, positions, page_size, pool["ik"].shape[1])
         with jax.named_scope("kv_pool.write"):
             ik = pool["ik"].at[:, pages, offs].set(
                 local_cache["ik"].astype(pool["ik"].dtype), mode="drop"
@@ -3590,7 +3781,10 @@ def paged_insert_cache(
         both, n = tables, tables.shape[1]
         with jax.named_scope("kv_pool.write"):
             tables = both[FULL]
-            out = {leaf: put(pool[leaf], local_cache[leaf]) for leaf in ("k", "v")}
+            out = {
+                leaf: put(pool[leaf], local_cache[leaf]) for leaf in pool
+                if leaf not in ("win", "rec")  # "k" and "v", or a latent kind's "lat"
+            }
             tables = both[WINDOW]
             out["win"] = jax.tree.map(put, pool["win"], local_cache["win"])
         return out

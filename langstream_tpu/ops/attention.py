@@ -1570,6 +1570,7 @@ def ragged_paged_latent_attention(
     config: ModelConfig,
     page_size: int,
     interpret: bool = False,
+    lower: jax.Array | None = None,  # [B]: a window kind's first visible column
 ) -> jax.Array:
     """A decode step's attention IN THE LATENT SPACE -> [B, H x
     kv_lora_rank]: the paged decode walk over a pool that holds ONE row a
@@ -1585,13 +1586,17 @@ def ragged_paged_latent_attention(
     both products; the scores' scale is ``config.attn_scale``, the expanded
     head's 1 / sqrt(qk_nope_head_dim + qk_rope_head_dim) and YaRN's factor
     where the model has one. The bytes follow the row's length either way.
+    With ``lower`` (a window kind's layer, ``config`` that kind's geometry and
+    ``table`` its ring's) the row reads columns [lower, length): the walk
+    starts at page ``lower // page_size``, as `ragged_paged_decode_attention`'s
+    does under a window, and the bytes follow the window.
     Under its own name on the `pallas_call`; no mesh (`ServingEngine` refuses
     one for such a model)."""
     return _paged_decode_call(
         "ragged_paged_latent_attention",
         functools.partial(_page_latent, value_width=config.kv_lora_rank),
         q, [latents], [], lengths, table, layer, config, page_size, interpret,
-        chosen=chosen, scale=config.attn_scale,
+        lower=lower, chosen=chosen, scale=config.attn_scale,
         value_width=config.kv_lora_rank,
     )
 

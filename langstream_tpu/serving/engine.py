@@ -42,6 +42,7 @@ from langstream_tpu.models.transformer import (
     insert_copies_pages,
     join_rec,
     latent_columns_expanded,
+    latent_window_band,
     make_kv_cache,
     paged_block_step_inplace,
     paged_decode_step_inplace,
@@ -6790,6 +6791,12 @@ class ServingEngine:
                 disp.attrs.update(
                     latent_tokens_expanded=s0, latent_columns_expanded=columns
                 )
+                if self.config.latent_kinds:
+                    # a window kind's layer expands the band its queries see
+                    disp.attrs["latent_expanded_window"] = latent_window_band(
+                        width, pool.table_len * pool.page_size, self.config.sliding_window,
+                        pool.page_size,
+                    )
         if not final:
             if per_segment:  # nothing to deliver: the fetch lands the span
                 return [(

@@ -31,7 +31,12 @@ catalog's keys and the stated cut of two, the deployment's bytes against the
 family's tree, the cell's sizing and its metrics, the refusals, the seeded
 tree against the program's own, the chain's halves in three kinds, the costs
 against hand counts, the metric files against the program's scopes, the
-engine's state against the check block; its names all say `lfm2`) and
+engine's state against the check block; its names all say `lfm2`) and of
+`benchmark/tests/test_dots3_note_family.py` (dots3-note-prev: the contract, the
+catalog's keys and the stated cut of four with the first nine `layer_types`,
+each kind's geometry off one file, the cell's sizing and its metrics, the
+refusals, the seeded tree against the program's own, the chain's halves in
+three kinds, the costs against hand counts; its names all say `dots3`) and
 the cases of
 `benchmark/tests/test_request_readers.py` (the clock between a profile and the
 spans, a first token's stages, the device's idle by what the engine held; one
@@ -91,6 +96,8 @@ globals().update(_cases("test_keye_vl2_family"))
 globals().update(_cases("test_glm_moe_dsa_family"))
 # (its cell's case counts nine cells and eight configurations: PR 50's benchmark)
 globals().update(_cases("test_kimi_k2_family", bench_as_of=9))
-globals().update(_cases("test_lfm2_moe_family"))
+# (its cell's case holds its metrics to the END of `per_layer`: PR 55's benchmark, ten cells)
+globals().update(_cases("test_lfm2_moe_family", bench_as_of=10))
+globals().update(_cases("test_dots3_note_family"))
 globals().update(_cases("test_latent_dense_attention_cost"))
 globals().update(_cases("test_request_readers"))

@@ -836,6 +836,30 @@ def test_lowered_programs_carry_every_scope(dense_params, moe_params):
                    "moe_ffn.shared", "head"} <= texts["dense-latent"]
     assert not selection & texts["dense-latent"]
     assert "attention.latent.read" not in others
+    # a model whose KINDS of layer keep different latents: the window kind's
+    # read under its own scope in the decode chunk and in a segment, the
+    # heads' gate under its own, the full kind's selection as it is
+    kinds_latent = MODEL_PRESETS["tiny-dots3-test"]
+
+    def kinds_programs(params, pool):
+        chunk = E._paged_decode_chunk(
+            params, tokens[:, 0], lengths, pool, both, key, ones, zeros, ones, 2, kinds_latent,
+            page,
+        )
+        return chunk, E._paged_segment_and_sample(
+            params, tokens[:1, :16], lengths[:1], lengths[:1], chunk[3], both[:, :1], key,
+            ones[:1], zeros[:1], ones[:1], kinds_latent, page,
+        )
+
+    others = set().union(*texts.values())
+    texts["latent-kinds"] = lowered_scopes(
+        kinds_programs, T.init_params(kinds_latent, jax.random.PRNGKey(8)),
+        T.make_page_pool(kinds_latent, 8, page, window_pages=8),
+    )
+    own = {"attention.latent.window", "attention.gate"}
+    assert own | kept | selection | {"attention", "kv_pool.write", "ffn", "moe_ffn",
+                                     "moe_ffn.shared", "head"} <= texts["latent-kinds"]
+    assert not own & others
     assert set(T.SCOPES) <= set().union(*texts.values())
     assert "ffn" in texts["dense"] and "moe_ffn" not in texts["dense"]
     assert {"moe_ffn", "moe_ffn.route", "moe_ffn.dispatch", "moe_ffn.experts",

@@ -260,6 +260,29 @@ KIMI = dataclasses.replace(
 )
 
 
+# dots3-note-prev's language model as the benchmark cuts it (`tiny-dots3-test`'s
+# block at the published widths): TWO kinds of latent layer. Full: 128 heads
+# of 128 + 64 (values 128) over a key-value latent of 512, base 8e7, an indexer
+# of 64 heads x 128 that keeps 2,048. Window (513): 64 heads of 192 + 64
+# (values 128) over a key-value latent of 1,024, base 5e4. A leading dense
+# layer of 13,824 before two periods of (full, window x 3) that hold 16 of 256
+# experts of 5120 x 1536 and a shared one, a slice of 19,008 rows of the
+# vocabulary; the cell: 16 slots x 272 pages, a ring of 41 pages a row
+DOTS3 = dataclasses.replace(
+    MODEL_PRESETS["tiny-dots3-test"], name="dots3-widths", d_model=5120, d_ff=13824,
+    moe_d_ff=1536, n_layers=9, n_heads=128, n_kv_heads=128, n_experts=256, n_experts_per_tok=8,
+    experts_held=(0, 16), vocab_size=19008, q_lora_rank=1024, kv_lora_rank=512,
+    qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, index_n_heads=64,
+    index_head_dim=128, index_topk=2048, index_rope_dim=64, rope_theta=80000000.0,
+    sliding_window=513, max_seq_len=524288,
+    window_attention=(
+        ("n_heads", 64), ("q_lora_rank", 1024), ("kv_lora_rank", 1024),
+        ("qk_nope_head_dim", 192), ("qk_rope_head_dim", 64), ("v_head_dim", 128),
+        ("rope_theta", 50000.0),
+    ),
+)
+
+
 # LFM2-24B-A2B as the benchmark cuts it (`tiny-lfm2-test`'s block at the
 # published widths): 32 Q / 8 KV heads x 64, two KV heads to a lane row of the
 # cache and the pool ([L, P, 4, 64, 128]), 12 conv layers of 2,048 with a
@@ -1384,6 +1407,117 @@ def test_dense_latent_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monk
     assert held <= V5E_HBM_BYTES
     dims = re.escape("[" + ",".join(map(str, pool["lat"].shape)) + "]")
     assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text)
+
+
+@pytest.mark.parametrize("program", ["_paged_decode_chunk", "_paged_segment_and_sample"])
+def test_latent_kinds_programs_compile_for_v5e_beside_the_cell_s_state(v5e, monkeypatch, program):
+    """The dots3 cell's two device programs whole, at its sizes (16 slots x
+    272 pages of a 4,352-page full group whose leaves are the 640-lane latent
+    and the indexer's key, and a window group of 16 rings of 41 pages whose
+    one leaf is the 1,152-lane latent; a decode chunk, and a 2,048-token
+    segment against 17,408 columns), int8 weights and the pool donated. Each
+    of the four reads is a kernel: the decode step attends in the latent space
+    in both kinds (one `pallas_call` name, two geometries) and holds no
+    gathered or expanded cache of either; the segment's full kind expands,
+    ranks and walks under the selection as GLM-5's does, its window kind
+    expands a BAND of 3,072 columns and no more and walks it under the window.
+    Each fits the chip beside its state, and no leaf of either group is
+    relaid."""
+    from langstream_tpu.models.quant import init_random_quantized_params
+    from langstream_tpu.models.transformer import latent_window_band, make_page_pool
+    from langstream_tpu.serving import engine as E
+    from langstream_tpu.serving.pagepool import window_group_pages
+
+    slots, pages, table, seg = 16, 4352, 272, 2048
+    t = table * PAGE
+    window_pages, ring = window_group_pages(DOTS3, slots, t, PAGE, seg)
+    assert (window_pages, ring) == (16 * 41, 41)
+    band = latent_window_band(seg, t, 513, PAGE)
+    assert band == 3072
+    key = SDS((2,), jnp.uint32)
+    params = jax.eval_shape(lambda k: init_random_quantized_params(DOTS3, k), key)
+    full, window = (params["layers"][k] for k in ("full_attention", "sliding_attention"))
+    assert params["dense_layers"]["full_attention"]["w_gate"]["q"].shape == (1, 5120, 13824)
+    assert full["w_gate"]["q"].shape == (2, 16, 5120, 1536)
+    assert window["w_gate"]["q"].shape == (6, 16, 5120, 1536)
+    assert full["wkv_b"]["q"].shape == (2, 512, 128 * 256) and "wq_idx" in full
+    assert window["wkv_b"]["q"].shape == (6, 1024, 64 * 320) and "wq_idx" not in window
+    assert full["w_attn_gate"]["q"].shape == (2, 5120, 128)
+    pool = jax.eval_shape(lambda: make_page_pool(DOTS3, pages, PAGE, window_pages=window_pages))
+    assert {k: v.shape for k, v in pool.items() if k != "win"} == {
+        "lat": (3, pages, 1, PAGE, 640), "ik": (3, pages, PAGE, 128),
+    }
+    assert {k: v.shape for k, v in pool["win"].items()} == {
+        "lat": (6, window_pages, 1, PAGE, 1152),
+    }
+    i32, f32 = (lambda *s: SDS(s, jnp.int32)), (lambda *s: SDS(s, jnp.float32))
+    if program == "_paged_decode_chunk":
+        args = (params, i32(slots), i32(slots), pool, i32(2, slots, table), key,
+                f32(slots), i32(slots), f32(slots))
+        static = (8, DOTS3, PAGE)
+        kernels = ("ragged_paged_latent_attention", "moe_grouped_matmul")
+    else:
+        args = (params, i32(1, seg), i32(1), i32(1), pool, i32(2, 1, table), key,
+                f32(1), i32(1), f32(1))
+        static = (DOTS3, PAGE)
+        kernels = (
+            "flash_segment_attention", "sparse_segment_attention", "segment_select",
+            "moe_grouped_matmul", "paged_insert_pages", "latent_expand_blocks",
+        )
+    compiled = _compile_as_on_chip(
+        monkeypatch, getattr(E, program), _placed(args, SingleDeviceSharding(v5e[0])), static
+    )
+    text = compiled.as_text()
+    paths = A.attention_paths()
+    for kernel in kernels:
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    has = lambda shape: "[" + ",".join(map(str, shape)) + "]" in text  # noqa: E731
+    if program == "_paged_decode_chunk":
+        assert paths[f"paged-decode-latent[s=1,t={t}]"] == "ragged_paged_latent_attention"
+        assert paths[f"paged-decode-latent-window[s=1,t={t}]"] == "ragged_paged_latent_attention"
+        # both kinds' calls, each under its scope
+        calls = re.findall(r"%ragged_paged_latent_attention(?:\.\d+)? = .*", text)
+        assert any("/attention.latent.window/" in c for c in calls)
+        assert any("/attention.sparse/" in c for c in calls)
+        for width in (640, 1152):  # no row's latents gathered: the kernel reads the pages
+            for shape in ([slots, 1, t, width], [slots, t, width], [slots, table, 1, PAGE, width]):
+                assert not has(shape), shape
+        for h, d in ((128, 192), (128, 128), (64, 256), (64, 128), (64, 320), (128, 256)):
+            for shape in ([slots, h, t, d], [slots, t, h, d], [h, t, d]):
+                assert not has(shape), shape
+    else:
+        assert paths[f"paged-segment-write[s={seg}]"] == "paged_insert_pages"
+        assert paths[f"paged-segment-latent-select[s={seg},t={t}]"] == "segment_select"
+        assert paths[f"paged-segment-latent-sparse[s={seg},t={t}]"] == "sparse_segment_attention"
+        assert paths[f"paged-segment-latent[s={seg},t={t}]"] == "flash_segment_attention"
+        assert paths[f"paged-segment-latent-window[s={seg},t={band}]"] == "flash_segment_attention"
+        assert paths[f"paged-segment-latent-expand[s={seg},t={band}]"] == "latent_expand_blocks"
+        # the full kind's expanded keys 192 and values 128 wide over the table,
+        # the window kind's 256 and 128 over its BAND and never over the table
+        assert has([1, 128, t, 192]) and has([1, 128, t, 128])
+        assert has([1, 64, band, 256]) and has([1, 64, band, 128])
+        assert not has([1, 64, t, 256]) and not has([1, 64, t, 128]) and not has([1, t, 1152])
+        walks = re.findall(r"%flash_segment_attention(?:\.\d+)? = .*", text)
+        assert any("/attention.latent.window/" in c for c in walks)
+        for heads in (128, 64, DOTS3.index_n_heads):
+            for shape in ([1, seg, heads, t], [1, heads, seg, t], [seg, heads, t]):
+                assert not has(shape), shape
+    memory = compiled.memory_analysis()
+    pool_bytes = sum(leaf.size * leaf.dtype.itemsize for leaf in jax.tree.leaves(pool))
+    assert pool_bytes == (
+        pages * PAGE * DOTS3.kv_bytes_per_token()
+        + window_pages * PAGE * DOTS3.kv_bytes_per_token(kind="sliding_attention")
+    )
+    assert memory.alias_size_in_bytes >= pool_bytes  # both groups, updated in place
+    held = (
+        memory.argument_size_in_bytes + memory.temp_size_in_bytes
+        + memory.output_size_in_bytes - memory.alias_size_in_bytes
+    )
+    print(program, "temp", memory.temp_size_in_bytes, "args", memory.argument_size_in_bytes)
+    assert held <= V5E_HBM_BYTES
+    for leaf in jax.tree.leaves(pool):
+        dims = re.escape("[" + ",".join(map(str, leaf.shape)) + "]")
+        assert not re.search(rf"= \w+{dims}\S* (copy|transpose)\(", text), leaf.shape
 
 
 def test_window_segment_program_compiles_for_v5e_beside_the_cell_s_state(v5e, monkeypatch):
