@@ -20,6 +20,7 @@ import functools
 import gc
 import logging
 import math
+import operator
 import os
 import queue
 import sys
@@ -708,8 +709,56 @@ def admit_rungs(prefill_batch: int) -> tuple[int, ...]:
     every warm start and 11–14 s of a cold one (v5e, PERF.md §6, PR 33),
     which is why the powers of two between the two rungs are not here:
     groups of one prompt are what open-loop arrivals and a drain's freed
-    slots make, and they carry nearly all of the padding."""
+    slots make, and they carried nearly all of the padding (chat's groups
+    held 1.08 real rows of 8 before the rung of one)."""
     return (1, prefill_batch) if prefill_batch > 1 else (1,)
+
+
+def admission_groups(
+    by_width: dict[int, list], rungs: tuple[int, ...], widen: bool = True,
+) -> list[tuple[int, list]]:
+    """The groups ONE iteration's cold admissions leave as, narrowest first:
+    ``by_width`` is each bucket width's ``(slot, request)`` rows in the order
+    the queue gave them up (slots rise with it, so a row's first item is its
+    age); a width's rows are cut into groups of at most ``rungs[-1]``, and a
+    group of ``n`` rows dispatches at ``rung(n)``, the smallest rung that
+    holds it, with ``rung(n) - n`` padding rows computed whatever they hold.
+
+    ``widen``: rows of a NARROWER width move into those free rows, the widest
+    group filled first. A narrow group gives up rows only where that takes it
+    down a rung (to nothing, the dispatch gone, or to the rows a lower rung
+    holds exactly), its oldest first, the narrow group with the fewest rows
+    first so that one is emptied before another is shrunk. No group's rung or
+    width grows, so no new (rung, width) is dispatched and neither the
+    iteration's computed tokens nor its dispatches can rise. False (a model
+    whose expert layer hands capacity out in row order, padding included:
+    `ServingEngine.__init__`) leaves every width its own groups."""
+    batch = rungs[-1]
+
+    def rung(n: int) -> int:
+        return next(r for r in rungs if r >= n) if n > 0 else 0
+
+    groups = [
+        (width, rows[start : start + batch])
+        for width, rows in sorted(by_width.items())
+        for start in range(0, len(rows), batch)
+    ]
+    if not widen:
+        return groups
+    for at in range(len(groups) - 1, 0, -1):
+        width, rows = groups[at]
+        free = rung(len(rows)) - len(rows)
+        narrower = [g for g in reversed(groups[:at]) if g[0] < width]
+        # the fewest rows first (the sort is stable: of two as small, the wider)
+        for _, narrow in sorted(narrower, key=lambda g: len(g[1])):
+            keep = rung(len(narrow) - free)
+            if free and keep < rung(len(narrow)):
+                moved = len(narrow) - keep
+                rows.extend(narrow[:moved])
+                del narrow[:moved]
+                free -= moved
+        rows.sort(key=operator.itemgetter(0))
+    return [g for g in groups if g[1]]
 
 
 def _make_paged_admit_group(mesh=None):
@@ -1671,6 +1720,14 @@ class ServingEngine:
         )
         # admission groups dispatched at each rung (stats "admit-group-rows")
         self._admit_group_rows = dict.fromkeys(self._admit_rungs, 0)
+        # a narrow prompt rides a wider group's free row (`admission_groups`)
+        # where padding costs a real token nothing: every layer but moe_ffn,
+        # where a wider row's padding ahead of a later real row takes the
+        # capacity that row's tokens would have had (S5 lifts this too)
+        self._admit_widens = not config.is_moe or config.holds_experts
+        # rows that rode a group wider than their own bucket (stats
+        # "admit-rows-widened")
+        self._admit_rows_widened = 0
         # fused prefill–decode scheduling: every iteration dispatches a
         # token-budgeted slice of pending prefill work (admission groups +
         # chunked-prefill segments) IMMEDIATELY followed by the decode chunk
@@ -2740,6 +2797,10 @@ class ServingEngine:
             # admission groups dispatched at each row count of the ladder
             # (admit_rungs): how far groups shrink to the prompts they hold
             "admit-group-rows": dict(self._admit_group_rows),
+            # rows of those groups whose own bucket is narrower than the
+            # group's width: prompts that rode a wider group's free row
+            # (admission_groups) and saved their own group a rung
+            "admit-rows-widened": self._admit_rows_widened,
             # iterations that launched, by how the launch was decided, and
             # the late ones among them (docs/SERVING.md, "When the engine
             # launches"), since the engine was built or `reset_histograms`
@@ -4668,9 +4729,10 @@ class ServingEngine:
         (overlap off).
 
         ``prefill_batch`` is the LARGEST group: each width's admissions are
-        cut into sub-batches of at most that many, and ``_prefill_group``
-        dispatches each at the smallest rung of ``admit_rungs`` that holds
-        it."""
+        cut into sub-batches of at most that many, a narrow prompt takes the
+        free row of a wider group that is dispatched anyway
+        (``admission_groups``), and ``_prefill_group`` dispatches each group
+        at the smallest rung of ``admit_rungs`` that holds it."""
         free = [
             i
             for i, slot in enumerate(self._slots)
@@ -4763,53 +4825,55 @@ class ServingEngine:
             if self._paged_admit_one(idx, request, entries) == "cold":
                 cold_paged.append((idx, request))
         pairs = cold_paged
-        groups: dict[int, list[tuple[int, GenerationRequest]]] = {}
+        by_width: dict[int, list[tuple[int, GenerationRequest]]] = {}
         for idx, request in pairs:
             width = self._bucket(len(request.prompt_tokens))
-            groups.setdefault(width, []).append((idx, request))
-        for width, group in sorted(groups.items()):
-            # each distinct (rows, width) shape is a separate XLA compile:
-            # a sub-batch holds at most prefill_batch rows and dispatches at
-            # one of the warmed rungs
-            for start in range(0, len(group), self.prefill_batch):
-                sub = group[start : start + self.prefill_batch]
-                try:
-                    new = self._prefill_group(width, sub)
-                except Exception as e:  # noqa: BLE001 — fail the group, not the engine
-                    if self._spmd is not None:
-                        # multi-host: an announced dispatch that failed here
-                        # may have diverged (or killed) the followers —
-                        # catch-and-continue would wedge every collective.
-                        # Raise: the supervisor escalates to the coordinated
-                        # OP_RECOVER (both sides rebuild in place, §20).
-                        raise
-                    log.exception("prefill failed for a batch of %d requests", len(sub))
-                    for idx, request in sub:
-                        self._free_slot_pages(idx)  # reserved at admit
-                        request._finish(GenerationResult(
-                            tokens=[], finish_reason="error", prompt_tokens=0,
-                            ttft_s=0, total_s=0, error=e,
-                        ))
-                    continue
-                # NEVER fetch here: blocking on a group's first tokens waits
-                # out the in-flight decode chunk with the engine thread
-                # stalled, so the next chunk dispatches late and the device
-                # idles (measured: admit fetches ate ~30% of steady-state
-                # wall at B=96). Entries ride the same ready-gated pending
-                # pipeline as decode chunks; on a cold start _run processes
-                # them immediately (progressive group-by-group delivery).
-                entries.extend(new)
+            by_width.setdefault(width, []).append((idx, request))
+        # each distinct (rows, width) shape is a separate XLA compile: a
+        # group holds at most prefill_batch rows and dispatches at one of
+        # the warmed rungs, a narrow prompt in a wider group's free row
+        # where there is one
+        for width, sub in admission_groups(
+            by_width, self._admit_rungs, self._admit_widens
+        ):
+            try:
+                new = self._prefill_group(width, sub)
+            except Exception as e:  # noqa: BLE001 — fail the group, not the engine
+                if self._spmd is not None:
+                    # multi-host: an announced dispatch that failed here
+                    # may have diverged (or killed) the followers —
+                    # catch-and-continue would wedge every collective.
+                    # Raise: the supervisor escalates to the coordinated
+                    # OP_RECOVER (both sides rebuild in place, §20).
+                    raise
+                log.exception("prefill failed for a batch of %d requests", len(sub))
+                for idx, request in sub:
+                    self._free_slot_pages(idx)  # reserved at admit
+                    request._finish(GenerationResult(
+                        tokens=[], finish_reason="error", prompt_tokens=0,
+                        ttft_s=0, total_s=0, error=e,
+                    ))
+                continue
+            # NEVER fetch here: blocking on a group's first tokens waits
+            # out the in-flight decode chunk with the engine thread
+            # stalled, so the next chunk dispatches late and the device
+            # idles (measured: admit fetches ate ~30% of steady-state
+            # wall at B=96). Entries ride the same ready-gated pending
+            # pipeline as decode chunks; on a cold start _run processes
+            # them immediately (progressive group-by-group delivery).
+            entries.extend(new)
         return entries
 
     def _prefill_group(
         self, width: int, group: list[tuple[int, GenerationRequest]]
     ) -> list[tuple]:
-        """One batched prefill for every (slot, request) pair of one prompt
-        bucket, padded to the smallest rung of the ladder that holds the
-        group (``admit_rungs``: one row, or prefill_batch; one compiled
-        shape per (rung, width), all warmed), so a lone prompt computes one
-        row of its bucket. An expert model's ladder is the one rung
-        ``prefill_batch`` (``__init__`` says why)."""
+        """One batched prefill for every (slot, request) pair of one group
+        of ``admission_groups`` (prompts of the bucket ``width``, and
+        narrower ones in rows it would pad), padded to the smallest rung of
+        the ladder that holds the group (``admit_rungs``: one row, or
+        prefill_batch; one compiled shape per (rung, width), all warmed), so
+        a lone prompt computes one row of its bucket. An expert model's
+        ladder is the one rung ``prefill_batch`` (``__init__`` says why)."""
         assert len(group) <= self.prefill_batch
         if self.config.fills_blocks:
             return self._block_prefill_group(width, group)
@@ -4842,11 +4906,10 @@ class ServingEngine:
         arows, g_rows, g_state0 = self._agentic_row_args(
             [r for _, r in group], n_pad
         )
-        with self._stats_lock:
-            self._admit_group_rows[n_pad] += 1
+        widened = self._count_admit_group(n_pad, width, group)
         disp = self._new_dispatch(
             "engine.admit_group", program="admit_group", rows=n_pad,
-            real_rows=len(group), width=width,
+            real_rows=len(group), width=width, widened_rows=widened,
             real_tokens=sum(len(r.prompt_tokens) for _, r in group),
             computed_tokens=n_pad * width,
             trace_ids=[r.trace_id for _, r in group],
@@ -4881,6 +4944,18 @@ class ServingEngine:
         return [(
             "prefill", self._submit_fetch(first, seq, counts), list(group), disp,
         )]
+
+    def _count_admit_group(
+        self, n_pad: int, width: int, group: list[tuple[int, GenerationRequest]]
+    ) -> int:
+        """One more group at the rung ``n_pad`` (stats "admit-group-rows");
+        returns its ``widened_rows``, the rows whose own bucket is narrower
+        than the group's width (stats "admit-rows-widened")."""
+        widened = sum(self._bucket(len(r.prompt_tokens)) < width for _, r in group)
+        with self._stats_lock:
+            self._admit_group_rows[n_pad] += 1
+            self._admit_rows_widened += widened
+        return widened
 
     def _agentic_admit_kwargs(
         self, n: int, arows, g_rows, g_state0=None,
@@ -7177,11 +7252,10 @@ class ServingEngine:
             top_ks[j] = request.options.top_k
             top_ps[j] = request.options.top_p
             slots[j] = idx
-        with self._stats_lock:
-            self._admit_group_rows[n_pad] += 1
+        widened = self._count_admit_group(n_pad, width, group)
         disp = self._new_dispatch(
             "engine.admit_group", program="_block_admit_group", rows=n_pad,
-            real_rows=len(group), width=width,
+            real_rows=len(group), width=width, widened_rows=widened,
             real_tokens=int(whole.sum()), computed_tokens=n_pad * width,
             trace_ids=[r.trace_id for _, r in group],
             kv_pages_written=self._kv_pages_written(slots, width),

@@ -154,6 +154,46 @@ def test_admit_group_rows_stat_matches_a_hand_count(dense_params, moe_params):
     drain(experts, deque([entries]))
 
 
+def test_widened_rows_and_the_stat_read_what_the_schedule_did(dense_params, moe_params):
+    """`widened_rows` on the span and `admit-rows-widened` count the rows that
+    rode a group wider than their own bucket (`engine.admission_groups`),
+    under buckets 16 and 32 and the ladder 1, 4. A lone 32-wide prompt rides
+    the rung of one and has no free row: the two 16-wide ones keep their
+    group. Two 32-wide prompts ride 4 rows: the 16-wide one takes a free row
+    and its own dispatch is gone. The capacity layer keeps its groups."""
+    opts = GenerationOptions(max_new_tokens=20, temperature=0.0)
+
+    def admit(engine, lengths):
+        for n in lengths:
+            engine.submit(GenerationRequest(prompt_tokens=[9] * n, options=opts))
+        return engine._admit()
+
+    def served(config, params):
+        TRACER.clear()
+        engine = ServingEngine(
+            config, params, max_batch=8, max_seq_len=64, decode_chunk=4,
+            prefill_buckets=(16, 32), prefill_batch=4,
+        )
+        entries = admit(engine, (20, 5, 7)) + admit(engine, (20, 30, 5))
+        stats = engine.stats()
+        drain(engine, deque([entries]))
+        groups = [g["attributes"] for g in spans_named("engine.admit_group")]
+        return stats, [
+            (g["width"], g["rows"], g["real_rows"], g["widened_rows"], g["real_tokens"])
+            for g in groups
+        ]
+
+    stats, groups = served(DENSE, dense_params)
+    assert groups == [(16, 4, 2, 0, 12), (32, 1, 1, 0, 20), (32, 4, 3, 1, 55)]
+    assert stats["admit-rows-widened"] == 1
+    assert stats["admit-group-rows"] == {1: 1, 4: 2}
+    stats, groups = served(MOE, moe_params)
+    assert groups == [
+        (16, 4, 2, 0, 12), (32, 4, 1, 0, 20), (16, 4, 1, 0, 5), (32, 4, 2, 0, 50)
+    ]
+    assert stats["admit-rows-widened"] == 0 and stats["admit-group-rows"] == {4: 4}
+
+
 def test_kv_pages_written_is_the_mapped_pages_of_the_group_s_rows(dense_params):
     """Where the insert copies pages (the kernels forced: interpret mode) a
     group's span counts the copies a layer and leaf: Σ over its rows of the

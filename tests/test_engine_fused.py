@@ -524,29 +524,34 @@ def test_the_ladder_is_one_row_and_the_largest_group(prefill_batch, rungs):
     assert admit_rungs(prefill_batch) == rungs
 
 
-def _serve_burst(config, params, k, full_rows=False):
-    """k prompts of one bucket width queued before ONE iteration of a warmed
-    engine, driven by hand so that they form one admission group whatever
-    the host's timing; served to the end. ``full_rows`` holds the engine to
-    the one shape of `prefill_batch` rows (what every engine did before the
-    ladder; no option selects it). Returns (tokens, the groups' span
-    attributes, programs after warm-up, programs after the burst)."""
+def _serve_burst(config, params, k, full_rows=False, buckets=(16,), lengths=None, widen=True):
+    """k prompts (of one bucket width, 3 + i tokens, unless ``lengths`` says
+    otherwise) queued before ONE iteration of a warmed engine, driven by hand
+    so that they form one iteration's admission groups whatever the host's
+    timing; served to the end. ``full_rows`` holds the engine to the one shape
+    of `prefill_batch` rows (what every engine did before the ladder), ``widen``
+    False keeps every width its own groups (what every engine did before
+    `admission_groups`); no option selects either. Returns (tokens, the
+    groups' span attributes, programs after warm-up, programs after the
+    burst)."""
     engine = ServingEngine(
         config, params, max_batch=8, max_seq_len=64, decode_chunk=4,
-        prefill_buckets=(16,), prefill_batch=8, overlap=True, precompile=True,
+        prefill_buckets=buckets, prefill_batch=8, overlap=True, precompile=True,
     )
     if full_rows:
         engine._admit_rungs = (engine.prefill_batch,)
+    if not widen:
+        engine._admit_widens = False
     engine._warmup()  # what the engine thread runs before it serves
     warmed = engine.stats()["compiled_programs"]
     TRACER.clear()
     opts = GenerationOptions(max_new_tokens=9, temperature=0.0)
     requests = [
         engine.submit(GenerationRequest(
-            prompt_tokens=[(5 * i + j) % config.vocab_size for j in range(3 + i)],
+            prompt_tokens=[(5 * i + j) % config.vocab_size for j in range(n)],
             options=opts,
         ))
-        for i in range(k)
+        for i, n in enumerate(lengths or [3 + i for i in range(k)])
     ]
     pending: deque = deque()
     try:
@@ -581,6 +586,24 @@ def test_a_burst_dispatches_at_the_smallest_rung_that_holds_it(k):
     assert tokens == full_tokens and all(len(t) == 9 for t in tokens)
     # one admit program a rung and width: one more than the one shape
     assert warmed == full_warmed + 1 and full_after == full_warmed
+
+
+def test_a_widened_burst_dispatches_no_program_the_warm_up_had_not_compiled():
+    """Five prompts of two buckets queued before ONE iteration of a warmed
+    engine: the two 16-wide ones take free rows of the 32-wide group (3 real
+    rows at the rung of 8), ONE dispatch where every width alone makes two;
+    no (rung, width) beyond the warm-up's is dispatched, and every request's
+    tokens are those of the same engine with every width kept its own group."""
+    burst = dict(buckets=(16, 32), lengths=(20, 9, 31, 4, 17))
+    shape = lambda g: (g["width"], g["rows"], g["real_rows"], g["widened_rows"])  # noqa: E731
+    tokens, groups, warmed, after = _serve_burst(CFG, PARAMS, 5, **burst)
+    assert [shape(g) for g in groups] == [(32, 8, 5, 2)] and after == warmed
+    alone_tokens, alone_groups, alone_warmed, alone_after = _serve_burst(
+        CFG, PARAMS, 5, widen=False, **burst
+    )
+    assert [shape(g) for g in alone_groups] == [(16, 8, 2, 0), (32, 8, 3, 0)]
+    assert alone_after == alone_warmed == warmed
+    assert tokens == alone_tokens and all(len(t) == 9 for t in tokens)
 
 
 @pytest.mark.parametrize("k", [1, 2, 3, 5, 8])
