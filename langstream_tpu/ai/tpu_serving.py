@@ -42,13 +42,12 @@ Resource configuration:
     sizes a page; `kv-pages` overrides the pool's page count (default:
     every slot's max-seq-len + `prefix-cache-fraction` alias headroom —
     see docs/SERVING.md §11 for the memory-plan math)
-  overlap: true (default) → fused prefill–decode iterations (every device
-    dispatch carries a token-budgeted slice of pending prefill work plus
-    the decode chunk — the gateway-TTFT lever, PERF.md round 6)
-  prefill-token-budget: prefill tokens per fused iteration (default: the
-    chunked-prefill segment width = the largest prefill bucket)
-  max-prefill-streams: concurrent chunked-prefill streams (default 2
-    with overlap, 1 without; each holds its reserved slot's pages)
+  prefill-token-budget: prefill tokens per fused prefill–decode iteration
+    (every device dispatch carries a token-budgeted slice of pending
+    prefill work plus the decode chunk; default: the chunked-prefill
+    segment width = the largest prefill bucket)
+  max-prefill-streams: concurrent chunked-prefill streams (default 2;
+    each holds its reserved slot's pages)
   prefix-cache: auto | off (default off) → automatic cross-request prefix
     KV reuse (serving/pagepool.PrefixPageIndex): shared prompt preambles
     prefill once, later admissions alias the cached pages and prefill
@@ -461,6 +460,12 @@ class _EngineHolder:
                 "kv-layout: dense is gone: the page pool is the engine's "
                 "only KV state (remove the key)"
             )
+        overlap = self.config.get("overlap", True)
+        if not overlap or str(overlap).lower() in ("false", "off", "no"):
+            raise ValueError(
+                "overlap: false is gone: every iteration is the fused "
+                "prefill–decode one (remove the key)"
+            )
         page_size = int(self.config.get("page-size", 64))
         if page_size < 1:
             raise ValueError(f"page-size must be >= 1, got {page_size}")
@@ -565,11 +570,9 @@ class _EngineHolder:
             prefill_batch=prefill_batch,
             spmd=spmd,
             pipeline_depth=int(self.config.get("pipeline-depth", 1)),
-            ttft_chunk_floor=int(self.config.get("ttft-chunk-floor", 4)),
             # default (None): precompile the decode ladder on TPU backends
             # so no XLA compile ever lands mid-traffic (PERF.md round 5b)
             precompile=self.config.get("precompile"),
-            overlap=bool(self.config.get("overlap", True)),
             prefill_token_budget=(
                 int(self.config["prefill-token-budget"])
                 if self.config.get("prefill-token-budget") is not None

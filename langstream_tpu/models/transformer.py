@@ -25,10 +25,11 @@ import contextlib
 import dataclasses
 import functools
 import math
-from typing import Any, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from langstream_tpu.models.configs import ModelConfig
@@ -1801,6 +1802,97 @@ def segment_blocks_visited(offset: int, s: int, t: int, config) -> dict:
         name: ops.segment_blocks_visited(offset, s, t, widths[name], group, window, itemsize)
         for name, window in windows.items()
     }
+
+
+class LaunchReads(NamedTuple):
+    """What a launch of this model's programs reads, counted on the host from
+    positions: the attributes its span carries and the increments of the
+    `stats()` sums, by the rule of each kind of attention, beside the masks
+    and walks that make the rule true. Pure: the configuration and a row's
+    table (``table_columns`` = its length x ``page_size``), no engine state.
+    The per-layer rooflines divide by these (benchmark/layer_metrics). A new
+    kind of attention says here what it reads; the engine carries what it is
+    handed."""
+
+    config: ModelConfig
+    page_size: int
+    table_columns: int
+
+    def totals(self) -> dict:
+        """The sums this model's launches add to (`stats()` keys), at zero:
+        what an indexer scored and its attention then read; what a latent's
+        segments re-expanded; what a latent with no indexer read."""
+        c, names = self.config, []
+        if c.has_indexer:
+            names += ["index-tokens-scored-total", "kv-tokens-selected-total"]
+        if c.has_latent:
+            names += ["latent-tokens-expanded-total", "latent-columns-expanded-total"]
+            if not c.has_indexer:
+                names.append("kv-tokens-read-total")
+        return dict.fromkeys(names, 0)
+
+    def _seen(self, lengths) -> tuple[dict, dict]:
+        """(attributes, increments) of queries that see ``lengths`` columns
+        each (an int64 array), a layer. A full layer reads every one
+        (``kv_tokens_read``), a window layer at most the window's beside it
+        (``kv_tokens_read_window``); an indexer scores every one and the
+        attention READS ``index_topk`` at most, which is what
+        ``kv_tokens_read`` then says."""
+        c = self.config
+        attrs, sums = {"kv_tokens_read": int(lengths.sum())}, {}
+        if c.has_window:
+            attrs["kv_tokens_read_window"] = int(np.minimum(lengths, c.sliding_window).sum())
+        if c.has_indexer:
+            scored = attrs["kv_tokens_read"]
+            selected = int(np.minimum(lengths, c.index_topk).sum())
+            attrs.update(
+                kv_tokens_read=selected, index_tokens_scored=scored, kv_tokens_selected=selected
+            )
+            sums = {"index-tokens-scored-total": scored, "kv-tokens-selected-total": selected}
+        elif c.has_latent:  # the dense latent read: every cached latent a query sees
+            sums = {"kv-tokens-read-total": attrs["kv_tokens_read"]}
+        return attrs, sums
+
+    def segment(self, offset: int, real: int, width: int) -> tuple[dict, dict]:
+        """A prefill segment of ``real`` tokens (``width`` computed) at
+        ``offset``: real query i sees offset + i + 1 columns (a last
+        segment's padding queries are no work asked for). A latent's segment
+        re-expands the cached columns behind it (``latent_tokens_expanded``)
+        among the columns of its table the program expands in all
+        (`latent_columns_expanded`; a window kind's layer its band,
+        `latent_window_band`). A model of none of these kinds reads K and V
+        as every segment does and says nothing."""
+        c = self.config
+        if not (c.has_window or c.has_indexer or c.has_latent):
+            return {}, {}
+        attrs, sums = self._seen(offset + 1 + np.arange(real, dtype=np.int64))
+        attrs["offset"] = offset
+        if c.has_latent:
+            columns = latent_columns_expanded(offset, width, self.table_columns, c)
+            attrs.update(latent_tokens_expanded=offset, latent_columns_expanded=columns)
+            sums.update({
+                "latent-tokens-expanded-total": offset, "latent-columns-expanded-total": columns,
+            })
+            if c.latent_kinds:
+                attrs["latent_expanded_window"] = latent_window_band(
+                    width, self.table_columns, c.sliding_window, self.page_size
+                )
+        return attrs, sums
+
+    def decode(self, first, steps: int) -> tuple[dict, dict]:
+        """A decode chunk (or a verify) of ``steps`` over live rows whose
+        first step sees ``first[i]`` columns (the position being written,
+        plus one), one more each step. A decode step attends in the latent
+        space: it expands nothing."""
+        lengths = np.asarray(first, np.int64).reshape(-1, 1) + np.arange(steps)[None, :]
+        attrs, sums = self._seen(lengths)
+        if self.config.has_latent:
+            attrs["latent_tokens_expanded"] = 0
+        return attrs, sums
+
+    def key_blocks(self, offset: int, width: int) -> dict:
+        """`segment_blocks_visited` of a segment of ``width`` at ``offset``."""
+        return segment_blocks_visited(offset, width, self.table_columns, self.config)
 
 
 def _latent_expand_seen(rows, lp, offsets, s, config, whole=False):

@@ -40,10 +40,9 @@ from langstream_tpu.models.configs import PAGE_LEAVES, GenerationOptions, ModelC
 from langstream_tpu.models.transformer import (
     MOE_COUNTS,
     moe_count_names,
+    LaunchReads,
     insert_copies_pages,
     join_rec,
-    latent_columns_expanded,
-    latent_window_band,
     make_kv_cache,
     paged_block_step_inplace,
     paged_decode_step_inplace,
@@ -51,7 +50,6 @@ from langstream_tpu.models.transformer import (
     paged_prefill_segment_inplace,
     paged_verify_step_inplace,
     prefill,
-    segment_blocks_visited,
     segment_copies_pages,
     split_rec,
 )
@@ -64,6 +62,7 @@ from langstream_tpu.serving.observability import (
     emit_request_spans,
     load_score,
 )
+from langstream_tpu.serving.pagepool import migration_refused, refuse_for_state
 from langstream_tpu.serving.sampling import block_choice, sample, speculative_verify
 from langstream_tpu.serving.speculation import NGramIndex
 from langstream_tpu.serving.startup import StartupTrace, process_stats
@@ -1253,9 +1252,7 @@ class ServingEngine:
         prefill_batch: Optional[int] = None,
         spmd: Optional[Any] = None,
         pipeline_depth: int = 1,
-        ttft_chunk_floor: int = 4,
         precompile: Optional[bool] = None,
-        overlap: bool = True,
         prefill_token_budget: Optional[int] = None,
         max_prefill_streams: Optional[int] = None,
         page_size: int = 64,
@@ -1305,123 +1302,15 @@ class ServingEngine:
         one psum per layer, the Megatron schedule."""
         # time to ready is counted from here (docs/SERVING.md §12, "Start-up")
         self._startup = StartupTrace()
-        if config.is_recurrent or config.has_window:
-            # Refused at build, by the option's name (docs/SERVING.md §11).
-            # A recurrent state is overwritten in place: it cannot be
-            # aliased between slots (prefix reuse), has no spill, migrate or
-            # durable format, cannot be rolled back past a rejected draft,
-            # takes no adapter terms and is not sharded. Two page groups, one
-            # a ring: a window row's pages behind the window are gone, so a
-            # prefix cannot be aliased out of them, spilled, migrated or
-            # checkpointed whole; a rejected draft's rows may already have
-            # recycled a page; the parallel block takes no adapter terms;
-            # the window group is not sharded and has no int8 pages
-            refused = {
-                "prefix_cache": prefix_cache is True
-                or str(prefix_cache).lower() in ("auto", "on", "true", "1"),
-                "host_kv_fraction": float(host_kv_fraction) > 0,
-                "migrate_staging": bool(migrate_staging),
-                "durable_dir": bool(durable_dir),
-                "speculation": speculation is True
-                or str(speculation).lower() in ("auto", "on", "true", "1"),
-                "adapters": bool(adapters),
-                "mesh": mesh is not None,
-                "spmd": spmd is not None,
-                "ring_axis": config.ring_axis is not None,
-                "kv_cache_dtype": config.has_window and config.kv_cache_dtype == "int8",
-            }
-            asked = [name for name, on in refused.items() if on]
-            if asked:
-                raise ValueError(
-                    f"{config.name} has recurrent layers: {', '.join(asked)} "
-                    "cannot be used with a recurrent state"
-                    if config.is_recurrent else
-                    f"{config.name} has window layers: {', '.join(asked)} "
-                    "cannot be used with two page groups"
-                )
-        if config.has_indexer or config.has_latent:
-            # Refused at build, by the option's name. Two reasons, each its
-            # own, and a model may have both.
-            # An INDEXER (docs/SERVING.md "A model whose attention reads a
-            # learned selection"): the page pool holds one more leaf a token,
-            # the indexer's key: the host tier, migration and the durable
-            # checkpoint snapshot and restore K and V alone (`_page_snapshot`,
-            # serving/wire.py, serving/durable.py), and a page that came back
-            # without its indexer keys would be ranked by stale ones. The
-            # verify path is jnp over the whole table and knows no selection;
-            # an int8 pool has no third leaf; the indexer takes no adapter
-            # terms and its gathers are not sharded.
-            # A LATENT in place of K and V (docs/SERVING.md "A model that
-            # keeps a latent, not keys and values"), with an indexer or
-            # without one: no tier, wire or checkpoint format carries the
-            # leaf "lat" that stands where "k" and "v" do; the verify path is
-            # jnp over K and V and no verify or adapter term reaches the
-            # latent's attention half; an int8 pool is K's and V's; the
-            # latent decode kernel takes no mesh.
-            # Prefix reuse is served by both: a cached page holds its tokens'
-            # latents (and indexer keys), which depend on nothing after them,
-            # and a copied page is whole (`_on_pages`).
-            on = lambda v: v is True or str(v).lower() in ("auto", "on", "true", "1")  # noqa: E731
-            refused = {
-                "host_kv_fraction": float(host_kv_fraction) > 0,
-                "migrate_staging": bool(migrate_staging),
-                "durable_dir": bool(durable_dir),
-                "speculation": on(speculation),
-                "kv_cache_dtype": config.kv_cache_dtype == "int8",
-                "adapters": bool(adapters),
-                "mesh": mesh is not None,
-                "spmd": spmd is not None,
-            }
-            asked = [name for name, is_on in refused.items() if is_on]
-            if asked and not config.has_latent:
-                raise ValueError(
-                    f"{config.name} reads a learned selection: {', '.join(asked)} "
-                    "cannot be used with an indexer's keys in the page pool"
-                )
-            if asked:
-                raise ValueError(
-                    f"{config.name} keeps a latent"
-                    + (" under a learned selection" if config.has_indexer else "")
-                    + f": {', '.join(asked)} cannot be used with a latent"
-                    + (" and an indexer's keys" if config.has_indexer else "")
-                    + " in the page pool"
-                )
+        # what the model's kinds of state cannot be used with is refused
+        # here, by the option's name (serving/pagepool STATE_KINDS)
+        refuse_for_state(
+            config, page_size, prefix_cache=prefix_cache,
+            host_kv_fraction=host_kv_fraction, migrate_staging=migrate_staging,
+            durable_dir=durable_dir, speculation=speculation, adapters=adapters,
+            mesh=mesh, spmd=spmd, constrained_decoding=constrained_decoding,
+        )
         if config.fills_blocks:
-            # Refused at build, by the option's name (docs/SERVING.md "A
-            # model that fills blocks"). A grammar advances left to right, a
-            # block's tokens are fixed out of order. A verify yields
-            # autoregressive tokens. The block pass reads and writes a bf16
-            # pool where it lies (an int8 page's scales are scattered words).
-            # A block may not straddle two pages (its K/V go down as one
-            # aligned tile), and only then is a page-aligned prefix whole
-            # blocks. Between passes a row's last block holds K/V of tokens
-            # not final yet, so the row's pages cannot be spilled, migrated
-            # or checkpointed; a prefix hit's warm suffix and a prompt beyond
-            # the largest bucket would go through the segment program, which
-            # has no block-causal mask. The block pass carries no adapter
-            # terms and its kernel call no mesh.
-            on = lambda v: v is True or str(v).lower() in ("on", "true", "1")  # noqa: E731
-            refused = {
-                "constrained_decoding": on(constrained_decoding),
-                "speculation": on(speculation) or str(speculation).lower() == "auto",
-                "kv_cache_dtype": config.kv_cache_dtype == "int8",
-                "page_size": int(page_size) % config.block_length != 0,
-                "prefix_cache": on(prefix_cache) or str(prefix_cache).lower() == "auto",
-                "host_kv_fraction": float(host_kv_fraction) > 0,
-                "migrate_staging": bool(migrate_staging),
-                "durable_dir": bool(durable_dir),
-                "adapters": bool(adapters),
-                "mesh": mesh is not None,
-                "spmd": spmd is not None,
-            }
-            asked = [name for name, is_on in refused.items() if is_on]
-            if asked:
-                raise ValueError(
-                    f"{config.name} fills blocks of {config.block_length} tokens by "
-                    f"denoising: {', '.join(asked)} cannot be used with a row "
-                    "that advances by a block"
-                    + (f" (page_size {page_size})" if "page_size" in asked else "")
-                )
             constrained_decoding = "off"  # `auto`: on where it is supported
         if mesh is not None:
             # the Pallas kernels cannot be partitioned by GSPMD: they read
@@ -1674,15 +1563,6 @@ class ServingEngine:
         # (stats "block-*"; docs/SERVING.md §12)
         self._block_dev = self._fresh_block_state(max_batch, config)
         self._block_totals = dict.fromkeys(BLOCK_COUNTERS, 0)
-        # a model with an indexer: the tokens its dispatches scored and read
-        # a layer (stats "index-tokens-scored-total", "kv-tokens-selected-total")
-        self.index_tokens_scored_total = 0
-        self.kv_tokens_selected_total = 0
-        self.latent_tokens_expanded_total = 0
-        # a latent model with NO indexer: the cached latents its decode chunks
-        # and segments read a layer (every one a query sees: `kv_tokens_read`)
-        self.kv_tokens_read_total = 0
-        self.latent_columns_expanded_total = 0
         # slots freed since the last dispatch: their device temp must be
         # zeroed, else sample()'s batch-wide any_sample/any_filter predicates
         # keep paying the full-vocab sort for a slot that no longer exists
@@ -1699,8 +1579,6 @@ class ServingEngine:
         # first-token fetches by a full chunk. (Default tuned for a device
         # link that is gone; not re-measured: ROADMAP D5.)
         self.pipeline_depth = max(1, int(pipeline_depth))
-        # smallest chunk the TTFT shrink may pick when admissible work waits
-        self.ttft_chunk_floor = max(1, int(ttft_chunk_floor))
         # total steps of the currently in-flight (dispatched, unfetched)
         # chunks, summed over the pipeline
         self._inflight_steps = 0
@@ -1738,19 +1616,16 @@ class ServingEngine:
         # progress (one admission group / one segment per active stream) per
         # iteration; beyond that, prefill work past the budget waits for the
         # next iteration so decode chunks keep interleaving.
-        self.overlap = bool(overlap)
-        # tokens of prefill work per fused iteration, sized off the
+        # Tokens of prefill work per fused iteration, sized off the
         # chunked-prefill segment width (= the largest prefill bucket): one
         # full-width segment or one admission group rides every iteration
         self.prefill_token_budget = max(
             1, int(prefill_token_budget or self.prefill_buckets[-1])
         )
-        # concurrent chunked-prefill streams: with overlap on, two long
-        # prompts may interleave their segments (each holds its own local
-        # cache — serving/memory.py accounts the per-stream term)
-        self.max_prefill_streams = max(
-            1, int(max_prefill_streams or (2 if self.overlap else 1))
-        )
+        # concurrent chunked-prefill streams: two long prompts may
+        # interleave their segments (each holds its own local cache —
+        # serving/memory.py accounts the per-stream term)
+        self.max_prefill_streams = max(1, int(max_prefill_streams or 2))
         # chunked prefill (long-context): prompts wider than the largest
         # bucket loop bucket-width segments straight into the reserved
         # slot's pages, budgeted segments per engine iteration so decode
@@ -2187,6 +2062,12 @@ class ServingEngine:
             config, self._kv_pages, self.page_size, max_batch,
             self.max_seq_len, window_in_flight=self._window_in_flight,
         )
+        # what a launch reads, by the model's own rules over this pool's
+        # geometry, and the sums of it `stats()` reports (`_count_reads`)
+        self._reads = LaunchReads(
+            config, self.page_size, self._pagepool.table_len * self.page_size
+        )
+        self._read_totals = self._reads.totals()
         if mesh is not None:
             # kv heads on "model" (replicated when they don't divide) —
             # every paged program then propagates the sharding from the
@@ -2743,31 +2624,10 @@ class ServingEngine:
                 {f"block-{k.replace('_', '-')}": v for k, v in self._block_totals.items()}
                 if self.config.fills_blocks else {}
             ),
-            # a model with an indexer: what its decode chunks and segments
-            # scored and read a layer, summed over the dispatches launched
-            **(
-                {
-                    "index-tokens-scored-total": self.index_tokens_scored_total,
-                    "kv-tokens-selected-total": self.kv_tokens_selected_total,
-                }
-                if self.config.has_indexer else {}
-            ),
-            # a model that keeps a latent: the cached columns its segments
-            # re-expanded a layer, and the columns of their tables they
-            # expanded in all (a decode chunk expands none)
-            **(
-                {
-                    "latent-tokens-expanded-total": self.latent_tokens_expanded_total,
-                    "latent-columns-expanded-total": self.latent_columns_expanded_total,
-                }
-                if self.config.has_latent else {}
-            ),
-            # ... and has no indexer: what its decode chunks and segments READ
-            # a layer, which is every cached latent a query sees
-            **(
-                {"kv-tokens-read-total": self.kv_tokens_read_total}
-                if self.config.has_latent and not self.config.has_indexer else {}
-            ),
+            # what the model's decode chunks and segments read a layer, summed
+            # over the dispatches launched: the sums its kinds of attention
+            # name (models/transformer `LaunchReads.totals`)
+            **self._read_totals,
             # a model with window layers: its second page group's use
             **(
                 {
@@ -2781,7 +2641,6 @@ class ServingEngine:
                 if self._pagepool.window is not None else {}
             ),
             "busy-steps": self._busy_steps,
-            "overlap": self.overlap,
             "prefill-token-budget": self.prefill_token_budget,
             # distinct device programs dispatched (= XLA compiles): flat
             # after warmup ⇔ no mid-traffic compile stalls. Underscore key
@@ -3122,11 +2981,6 @@ class ServingEngine:
             self._dev_decode(
                 self.decode_chunk, [self.max_batch]
             ).block_until_ready()
-            floor = min(self.ttft_chunk_floor, self.decode_chunk)
-            if floor != self.decode_chunk and not self.overlap:
-                # the TTFT-shrunk chunk is its own (steps,) program, but
-                # only the legacy (overlap off) scheduler dispatches it
-                self._dev_decode(floor, []).block_until_ready()
         # a long prompt's chunks run at the LARGEST bucket width; the
         # narrower widths serve only warm suffixes behind a prefix hit,
         # which an engine without a prefix index never makes
@@ -3666,12 +3520,11 @@ class ServingEngine:
             # under live rows (the speculative loop drains by design)
             self._late_probe = had_active and not spec_on
             self._launched_late = False
-            # the fused-iteration prefill budget (overlap off: unbounded, the
-            # pre-overlap whole-backlog admission). Long prefill FIRST: it
+            # the fused-iteration prefill budget. Long prefill FIRST: it
             # claims a freed slot before _admit hands them all to short
             # requests, so a long prompt can't be starved forever under
             # sustained short traffic.
-            budget = self.prefill_token_budget if self.overlap else None
+            budget = self.prefill_token_budget
             # _mid_iteration marks drain()'s pop-to-slot blind spot: a request
             # get_nowait()'d here but not yet visible as an active slot exists
             # only inside this admission phase, so _quiesced() (sampling from
@@ -3681,9 +3534,7 @@ class ServingEngine:
             try:
                 new_pending, spent = self._long_step(budget)
                 n_long_entries = len(new_pending)
-                if budget is not None:
-                    budget = max(0, budget - spent)
-                new_pending.extend(self._admit(budget))  # deferred first-token fetches
+                new_pending.extend(self._admit(max(0, budget - spent)))  # deferred first-token fetches
             finally:
                 self._mid_iteration = False
             # prefill dispatched this iteration rides the in-order stream AHEAD
@@ -4725,8 +4576,8 @@ class ServingEngine:
         wave-admission win); past both the budget and a group boundary,
         further queued requests stay queued so the decode chunk dispatched
         right after is never separated from its predecessor by more than
-        ~max(budget, one group) of prefill work. None = unbounded
-        (overlap off).
+        ~max(budget, one group) of prefill work. None = unbounded (a
+        caller that drives one admission by hand).
 
         ``prefill_batch`` is the LARGEST group: each width's admissions are
         cut into sub-batches of at most that many, a narrow prompt takes the
@@ -6431,28 +6282,9 @@ class ServingEngine:
                 "KV-page migration is not on the SPMD wire yet (the bind/"
                 "restore dispatches would need follower replay)"
             )
-        if self.config.is_recurrent:
-            raise MigrationError(
-                "KV-page migration carries pages only: a recurrent state "
-                "row has no wire format yet"
-            )
-        if self.config.fills_blocks:
-            raise MigrationError(
-                "KV-page migration: a row that advances by a block holds, "
-                "between passes, K/V of tokens that are not final yet"
-            )
-        if self.config.has_indexer:
-            raise MigrationError(
-                "KV-page migration carries K and V only: a page's indexer "
-                "keys"
-                + (" and its latent" if self.config.has_latent else "")
-                + " have no wire format yet"
-            )
-        if self.config.has_latent:
-            raise MigrationError(
-                "KV-page migration carries K and V only: a page's latent has "
-                "no wire format yet"
-            )
+        refused = migration_refused(self.config)
+        if refused:
+            raise MigrationError(refused)
         reply: "queue.SimpleQueue" = queue.SimpleQueue()
         self._migrate_cmds.put((kind, payload, reply))
         self._wake.set()
@@ -6585,82 +6417,6 @@ class ServingEngine:
             # are stable — positions only grow — so the copy is valid
             # even while the publisher keeps decoding)
             self._spill_candidates.append(entry)
-
-    def _chunk_steps(self) -> int:
-        """Power-of-two chunk bounded by every active slot's cache headroom.
-
-        Host positions lag the device by the one in-flight pipelined chunk
-        (its results are fetched AFTER the next dispatch), so the bound
-        subtracts that chunk's steps — otherwise the tail of a long request
-        burns whole chunks on out-of-bounds scatters that XLA drops.
-
-        TTFT lever (overlap OFF only): when admissible work is waiting
-        (queued request + a free slot, or a chunked prefill in flight), the
-        chunk shrinks so the next admit/segment runs within a few decode
-        steps instead of a full chunk — at decode_chunk=64 and ~15ms/step a
-        full chunk is ~1s of first-token latency for whoever just arrived.
-        Full-size chunks resume once the queue drains (or all slots are
-        busy, when admitting sooner is impossible anyway).
-
-        With overlap ON the shrink is RETIRED: the fused scheduler already
-        rides a budget of prefill on every iteration, so shrinking buys
-        little — and the shrunk size is a whole extra compiled program
-        whose first dispatch lands exactly when the first real burst does
-        (the gateway bench's first burst sat behind ONE ('decode', 4, 0)
-        compile). Full chunks
-        only ⇒ the decode compile surface is ONE program, period —
-        tail/headroom overshoot lands on OOB scatters XLA drops, and the
-        host stops delivering at max_new_tokens / cache end as always.
-        The conscious cost: the legacy remaining-tokens clamp is gone too,
-        so when EVERY active slot is within decode_chunk of its token
-        budget, up to decode_chunk-1 steps of that final chunk are
-        dropped-scatter waste — bounded per REQUEST, ≤6% of steps at the
-        bench shapes (chunk=16, ≥128 new tokens; under continuous batching
-        the max-remaining across slots rarely let the clamp bind anyway),
-        but material for big-chunk/short-generation configs (chunk=64,
-        max_new=8 wastes ~87% of its one chunk): size decode_chunk to the
-        workload, or run overlap=False to get the clamp back."""
-        if self.overlap:
-            return self.decode_chunk
-        want = self.decode_chunk
-        if self._longs:
-            want = min(want, 8)
-        elif self._queue.qsize() > 0 and any(
-            not s.active and i not in self._reserved
-            for i, s in enumerate(self._slots)
-        ):
-            want = min(want, self.ttft_chunk_floor)
-        # never dispatch (much) past the longest remaining token budget: a
-        # full chunk for slots about to finish wastes its tail on device AND
-        # sits in front of whatever arrives next (a burst admission right
-        # after a lone request drains used to queue ~a full chunk behind it)
-        remaining = max(
-            (
-                s.request.options.max_new_tokens - len(s.generated)
-                for s in self._slots
-                if s.active and s.request is not None
-            ),
-            default=1,
-        )
-        cap = 1
-        while cap < remaining:
-            cap *= 2
-        want = min(want, cap)
-        headroom = min(
-            self.max_seq_len - 1 - s.position - self._inflight_steps
-            for s in self._slots
-            if s.active
-        )
-        # QUANTIZE to exactly two step counts: every distinct step count
-        # is a separate XLA program, and a mid-traffic
-        # compile of a novel shrunk size stalled every active stream (the
-        # 96-session gateway wave of r5 sat behind ONE steps=4 compile).
-        # Tail/headroom overshoot is bounded by the floor and
-        # lands on OOB scatters XLA drops.
-        target = min(want, max(1, headroom))
-        if target >= self.decode_chunk:
-            return self.decode_chunk
-        return min(self.ttft_chunk_floor, self.decode_chunk)
 
     # -- chunked prefill (long-context) -------------------------------------
 
@@ -6830,48 +6586,9 @@ class ServingEngine:
             return []
         st["seg"] += 1
         self._note_key_blocks(disp)
-        if disp is not None and self.config.has_window:
-            disp.attrs.update(self._segment_window_attrs(s0, len(seg)))
-        if self.config.has_indexer:
-            # counted whether or not a span carries them: `stats()` totals
-            scored, selected = self._index_counts(s0 + 1 + np.arange(len(seg)))
-            if disp is not None:
-                disp.attrs.update(
-                    offset=s0, kv_tokens_read=selected, index_tokens_scored=scored,
-                    kv_tokens_selected=selected,
-                )
-        if self.config.has_latent:
-            if not self.config.has_indexer:
-                # the DENSE read: every query reads every column up to its
-                # own, a layer (a window model's span counts the same sum)
-                read = int((s0 + 1 + np.arange(len(seg), dtype=np.int64)).sum())
-                with self._stats_lock:
-                    self.kv_tokens_read_total += read
-                if disp is not None:
-                    disp.attrs.update(offset=s0, kv_tokens_read=read)
-            # the cached columns (earlier segments') whose latents this
-            # segment re-expanded into keys and values, a layer: every one
-            # behind it; and the columns of its table the program expanded
-            # in all, by the program's own rule: those and the segment's
-            # own, in whole blocks (the table's whole width where the
-            # read is not the walk over key blocks)
-            pool = self._pagepool
-            columns = latent_columns_expanded(
-                s0, width, pool.table_len * pool.page_size, self.config
-            )
-            with self._stats_lock:
-                self.latent_tokens_expanded_total += s0
-                self.latent_columns_expanded_total += columns
-            if disp is not None:
-                disp.attrs.update(
-                    latent_tokens_expanded=s0, latent_columns_expanded=columns
-                )
-                if self.config.latent_kinds:
-                    # a window kind's layer expands the band its queries see
-                    disp.attrs["latent_expanded_window"] = latent_window_band(
-                        width, pool.table_len * pool.page_size, self.config.sliding_window,
-                        pool.page_size,
-                    )
+        self._count_reads(disp, *self._reads.segment(s0, len(seg), width))
+        if disp is not None and self._pagepool.window is not None:
+            disp.attrs["window_pages_recycled"] = self._window_recycled
         if not final:
             if per_segment:  # nothing to deliver: the fetch lands the span
                 return [(
@@ -6929,9 +6646,13 @@ class ServingEngine:
         self._adapter_integrity_check()
         if self.config.fills_blocks:
             return self._dispatch_block_chunk(clean, pipelined)
-        # the page table is the bound on what a row reads: the decode
-        # surface is ONE program per step count
-        steps = self._chunk_steps()
+        # the page table is the bound on what a row reads, and every chunk
+        # is a full one: the decode surface is ONE program. Tail and
+        # headroom overshoot lands on out-of-bounds scatters XLA drops, and
+        # the host stops delivering at max_new_tokens / cache end as always
+        # (up to decode_chunk - 1 wasted steps a REQUEST: size decode_chunk
+        # to the workload)
+        steps = self.decode_chunk
         stale = self._collect_stale()
         mask = self._active_mask()
         if self._spmd is not None:
@@ -6956,17 +6677,14 @@ class ServingEngine:
             row_steps=steps * len(live),
             clean=clean, pipelined=pipelined,
             kv_pages_visited=pages_visited, kv_rows_written=rows_written,
-            kv_tokens_read=self._kv_tokens_read(live, steps),
             # (row, step) pairs whose recurrent state is updated, a linear
             # layer: the pairs that write a K/V row, idle rows move none
             **({"state_rows": rows_written} if self.config.is_recurrent else {}),
-            **self._decode_window_attrs(steps, recycled),
+            **({} if recycled is None else {"window_pages_recycled": recycled}),
         )
-        # a model with an indexer READS the selected: over `kv_tokens_read`;
-        # counted into `stats()` whether or not a span carries it
-        index_attrs = self._decode_index_attrs(live, steps)
-        if disp is not None:
-            disp.attrs.update(index_attrs)
+        # what the chunk's attention reads, by the model's own rule: counted
+        # into `stats()` whether or not a span carries it
+        self._count_reads(disp, *self._reads.decode(self._live_lengths(live), steps))
         with jax.profiler.TraceAnnotation(
             "engine.decode_chunk", seq=self._dispatch_seq, steps=steps,
             t_mono_ns=_mono_ns(disp),
@@ -6989,94 +6707,42 @@ class ServingEngine:
         )
 
     @staticmethod
-    def _kv_tokens_read(live: list, steps: int) -> int:
-        """KV tokens the attention of one dispatch has to read: over its
-        ``steps`` and its active rows, the row's live length at that step
-        (the position being written, plus one). The device's position
-        leads the host's by the row's steps still in flight (``ahead``)."""
-        return sum(
-            steps * (slot.position + slot.ahead + 1) + steps * (steps - 1) // 2
-            for slot in live
-        )
+    def _live_lengths(live: list) -> list[int]:
+        """The columns each live row's next step sees: the position being
+        written, plus one. The device's position leads the host's by the
+        row's steps still in flight (``ahead``)."""
+        return [slot.position + slot.ahead + 1 for slot in live]
 
-    def _index_counts(self, lengths) -> tuple[int, int]:
-        """(index_tokens_scored, kv_tokens_selected) of queries that see
-        ``lengths`` columns each, a layer: the indexer scores every one, the
-        attention reads ``index_topk`` of them at most. Counted on the host
-        from positions, as ``kv_tokens_read`` is, and summed into `stats()`."""
-        lengths = np.asarray(lengths, np.int64)
-        scored = int(lengths.sum())
-        selected = int(np.minimum(lengths, self.config.index_topk).sum())
-        with self._stats_lock:
-            self.index_tokens_scored_total += scored
-            self.kv_tokens_selected_total += selected
-        return scored, selected
-
-    def _decode_index_attrs(self, live: list, steps: int) -> dict:
-        """The decode chunk's span attributes of a model with an indexer:
-        what it scores (every live row's length at every step, the dense
-        model's ``kv_tokens_read``) and what it READS, which is what
-        ``kv_tokens_read`` then says too."""
-        if not self.config.has_indexer:
-            if not self.config.has_latent:
-                return {}
-            # the dense latent read: `kv_tokens_read` (there for every model)
-            # is what this model reads; summed into `stats()` here
+    def _count_reads(self, disp: Optional[Dispatch], attrs: dict, sums: dict) -> None:
+        """What a launch read (models/transformer `LaunchReads`) onto its
+        span, if it has one, and into the sums `stats()` reports. The
+        attributes are the model's to name: "offset", "kv_tokens_read",
+        "kv_tokens_read_window", "index_tokens_scored", "kv_tokens_selected",
+        "latent_tokens_expanded", "latent_columns_expanded",
+        "latent_expanded_window" (listed because the harness's family tests
+        look for a span attribute's name in THIS file's text)."""
+        if sums:
             with self._stats_lock:
-                self.kv_tokens_read_total += self._kv_tokens_read(live, steps)
-            return {"latent_tokens_expanded": 0}
-        first = np.asarray([slot.position + slot.ahead + 1 for slot in live], np.int64)
-        scored, selected = self._index_counts(first[:, None] + np.arange(steps)[None, :])
-        return {
-            "kv_tokens_read": selected, "index_tokens_scored": scored,
-            "kv_tokens_selected": selected,
-            # a decode step attends in the latent space: it expands nothing
-            **({"latent_tokens_expanded": 0} if self.config.has_latent else {}),
-        }
+                for name, n in sums.items():
+                    self._read_totals[name] += n
+        if disp is not None:
+            disp.attrs.update(attrs)
 
-    def _advance_window_rows(self, steps: int) -> int:
+    def _advance_window_rows(self, steps: int) -> Optional[int]:
         """A model with window layers, before a decode chunk of ``steps``:
         every active row's window pages move on to where the chunk writes
         (`WindowPageGroup.advance`; the device's position leads the host's
-        by ``ahead``). Returns the pages recycled; 0 without window layers."""
+        by ``ahead``). Returns the pages recycled, for the chunk's span; None
+        without window layers."""
         pool = self._pagepool
         if pool.window is None:
-            return 0
+            return None
         recycled = 0
         for i, slot in enumerate(self._slots):
             if slot.active:
                 first = slot.position + slot.ahead
                 recycled += pool.window_advance(i, first, first + steps - 1)
         return recycled
-
-    def _decode_window_attrs(self, steps: int, recycled: int) -> dict:
-        """The decode chunk's span attributes of a model with window layers:
-        the pages `_advance_window_rows` recycled for it, and what the
-        WINDOW layers' attention has to read, the live length of
-        ``_kv_tokens_read`` capped by the window a (row, step)."""
-        if self._pagepool.window is None:
-            return {}
-        window = self._pagepool.window.window
-        read = sum(
-            int(np.minimum(slot.position + slot.ahead + 1 + np.arange(steps), window).sum())
-            for slot in self._slots if slot.active
-        )
-        return {"kv_tokens_read_window": read, "window_pages_recycled": recycled}
-
-    def _segment_window_attrs(self, s0: int, real: int) -> dict:
-        """The same two of a prefill segment of ``real`` tokens at offset
-        ``s0``, and the full layers' count beside them: real query i reads
-        s0 + i + 1 columns in a full layer, at most the window's in a window
-        layer (a last segment's padding queries are no work asked for)."""
-        lengths = s0 + 1 + np.arange(real)
-        return {
-            "offset": s0,
-            "kv_tokens_read": int(lengths.sum()),
-            "kv_tokens_read_window": int(
-                np.minimum(lengths, self._pagepool.window.window).sum()
-            ),
-            "window_pages_recycled": self._window_recycled,
-        }
 
     def _count_segment_write(self, width: int, s0: int) -> None:
         """One more segment under the writer its program takes for it: the
@@ -7093,10 +6759,7 @@ class ServingEngine:
         """The key blocks this segment's attention walks, by the program's
         own rule (models/transformer `segment_blocks_visited`): kept for its
         span (`_note_key_blocks`) and summed into `stats()`."""
-        pool = self._pagepool
-        blocks = self._segment_key_blocks_last = segment_blocks_visited(
-            s0, width, pool.table_len * pool.page_size, self.config
-        )
+        blocks = self._segment_key_blocks_last = self._reads.key_blocks(s0, width)
         with self._stats_lock:  # `stats()` copies the sums under it
             for name, n in blocks.items():
                 key = name.replace("_", "-")
@@ -7125,7 +6788,7 @@ class ServingEngine:
     def _kv_page_counts(self, steps: int) -> tuple[int, int]:
         """(kv_pages_visited, kv_rows_written) of a decode chunk dispatched
         now, per layer, from one set of lengths: each active row's live
-        length at every step (`_kv_tokens_read`'s: the position being
+        length at every step (`_live_lengths`'s: the position being
         written, plus one) and the pages its table maps at dispatch.
         Inactive rows have no table and count for nothing.
 
@@ -7138,10 +6801,7 @@ class ServingEngine:
         per live row; a warm-up chunk writes none."""
         rows = [i for i, slot in enumerate(self._slots) if slot.active]
         pool = self._pagepool
-        first = np.asarray(
-            [self._slots[i].position + self._slots[i].ahead + 1 for i in rows],
-            np.int64,
-        )
+        first = np.asarray(self._live_lengths([self._slots[i] for i in rows]), np.int64)
         mapped = (pool.tables[rows] != pool.oob).sum(axis=1)[:, None]
         lengths = first[:, None] + np.arange(steps)[None, :]
         pages = np.minimum(-(-lengths // self.page_size), mapped)
@@ -7516,10 +7176,10 @@ class ServingEngine:
             "engine.verify",
             program="_paged_verify_chunk",
             steps=k + 1, active_rows=len(live),
-            # a verify scores k+1 positions a row, each over its own prefix
-            kv_tokens_read=self._kv_tokens_read(live, k + 1),
             clean=clean, pipelined=False,
         )
+        # a verify scores k+1 positions a row, each over its own prefix
+        self._count_reads(disp, *self._reads.decode(self._live_lengths(live), k + 1))
         packed = self._dev_verify(drafts, stale, mask=mask, vstates=vstates)
         counts = self._moe_counts()
         snapshot = [

@@ -54,7 +54,7 @@ import math
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -120,6 +120,149 @@ def split_page_bytes(raw: bytes, specs) -> list:
             f"past its {off}-byte leaf layout"
         )
     return out
+
+
+# -- what a kind of state refuses (docs/SERVING.md §11 is written from this) --
+#
+# The pool's formats are those of K and V a page: the host tier, the migration
+# wire and the durable checkpoint snapshot and restore those two leaves
+# (`_page_snapshot`, serving/wire.py, serving/durable.py), a prefix is reused
+# by aliasing pages, the verify path is jnp over K and V and can be rolled
+# back, an int8 pool is K's and V's, adapter terms reach the K/V projections
+# and the pool shards its KV heads. A row that keeps another kind of state
+# beside or in place of that is refused, at build and by the option's name,
+# whatever of it no format carries yet. A kind new to the engine adds a row
+# here; the engine's build and its migration commands read nothing else.
+
+
+class StateKind(NamedTuple):
+    """One kind of state a row keeps, and what cannot be used with it."""
+
+    has: Callable[[Any], bool]  # of a ModelConfig
+    does: str  # "<model> <does>: <options> cannot be used with <state>"
+    state: str
+    refuses: tuple[str, ...]  # in the order the message names them
+    migration: Optional[str]  # what a migration command is told, if refused
+
+
+_TIERS = ("host_kv_fraction", "migrate_staging", "durable_dir")
+_SHAPES = ("adapters", "mesh", "spmd")
+_OTHER_LEAVES = (*_TIERS, "speculation", "kv_cache_dtype", *_SHAPES)  # a latent's, an indexer's
+_NO_WIRE = "KV-page migration carries K and V only: a page's {} no wire format yet"
+
+STATE_KINDS: tuple[StateKind, ...] = (
+    # overwritten in place: it cannot be aliased between slots, has no spill,
+    # migrate or durable format, cannot be rolled back past a rejected draft,
+    # takes no adapter terms and is not sharded
+    StateKind(
+        lambda c: c.is_recurrent, "has recurrent layers", "a recurrent state",
+        ("prefix_cache", *_TIERS, "speculation", *_SHAPES, "ring_axis"),
+        "KV-page migration carries pages only: a recurrent state row has no "
+        "wire format yet",
+    ),
+    # a ring a row beside the pages: what lies behind the window is gone, so
+    # a prefix cannot be aliased out of it, spilled, migrated or checkpointed
+    # whole; a rejected draft's rows may already have recycled a page; the
+    # parallel block takes no adapter terms; the window group is not sharded
+    # and has no int8 pages
+    StateKind(
+        lambda c: c.has_window, "has window layers", "two page groups",
+        ("prefix_cache", *_TIERS, "speculation", *_SHAPES, "ring_axis", "kv_cache_dtype"),
+        None,
+    ),
+    # a leaf "lat" where "k" and "v" stand, with the indexer's key beside it or
+    # without: no tier, wire or checkpoint format carries it; no verify or
+    # adapter term reaches the latent's attention half; its decode kernel takes
+    # no mesh. A prefix IS reused by all three: a cached page holds its tokens'
+    # latents and indexer keys, which depend on nothing after them
+    StateKind(
+        lambda c: c.has_latent and c.has_indexer,
+        "keeps a latent under a learned selection",
+        "a latent and an indexer's keys in the page pool",
+        _OTHER_LEAVES,
+        _NO_WIRE.format("indexer keys and its latent have"),
+    ),
+    StateKind(
+        lambda c: c.has_latent, "keeps a latent", "a latent in the page pool",
+        _OTHER_LEAVES,
+        _NO_WIRE.format("latent has"),
+    ),
+    # one more leaf a token: a page that came back without its indexer keys
+    # would be ranked by stale ones; the verify path knows no selection; an
+    # int8 pool has no third leaf; the indexer takes no adapter terms and its
+    # gathers are not sharded
+    StateKind(
+        lambda c: c.has_indexer, "reads a learned selection",
+        "an indexer's keys in the page pool",
+        _OTHER_LEAVES,
+        _NO_WIRE.format("indexer keys have"),
+    ),
+    # a grammar advances left to right, a block's tokens are fixed out of
+    # order; a verify yields autoregressive tokens; the block pass reads and
+    # writes a bf16 pool where it lies; a block may not straddle two pages;
+    # between passes a row's last block holds K/V of tokens not final yet, so
+    # its pages cannot be spilled, migrated or checkpointed; a prefix hit's
+    # warm suffix would go through the segment program, which has no
+    # block-causal mask; the block pass carries no adapter terms, its kernel
+    # call no mesh
+    StateKind(
+        lambda c: c.fills_blocks, "fills blocks of {block} tokens by denoising",
+        "a row that advances by a block",
+        ("constrained_decoding", "speculation", "kv_cache_dtype", "page_size",
+         "prefix_cache", *_TIERS, *_SHAPES),
+        "KV-page migration: a row that advances by a block holds, between "
+        "passes, K/V of tokens that are not final yet",
+    ),
+)
+
+# the options read as a switch, and whether `auto` asks for one: it does where
+# `auto` turns the feature on when it can, and a refusal then beats a silent
+# off; `constrained-decoding: auto` means "where it is supported", which a
+# model that fills blocks takes as off
+_SWITCHES = {"prefix_cache": True, "speculation": True, "constrained_decoding": False}
+
+
+def options_asked(config: Any, page_size: int, **options: Any) -> dict[str, bool]:
+    """What of STATE_KINDS' options a build asks for: the engine's keywords
+    as it got them (``options``), and the three the configuration carries."""
+
+    def switched(name: str) -> bool:
+        said = str(options[name]).lower()
+        return said in ("on", "true", "1") or (_SWITCHES[name] and said == "auto")
+
+    return {
+        **{name: switched(name) for name in _SWITCHES},
+        "host_kv_fraction": float(options["host_kv_fraction"]) > 0,
+        "migrate_staging": bool(options["migrate_staging"]),
+        "durable_dir": bool(options["durable_dir"]),
+        "adapters": bool(options["adapters"]),
+        "mesh": options["mesh"] is not None,
+        "spmd": options["spmd"] is not None,
+        "ring_axis": config.ring_axis is not None,
+        "kv_cache_dtype": config.kv_cache_dtype == "int8",
+        "page_size": config.fills_blocks and int(page_size) % config.block_length != 0,
+    }
+
+
+def refuse_for_state(config: Any, page_size: int, **options: Any) -> None:
+    """Raise for the first kind of state of ``config`` that refuses something
+    asked for, naming every such option (`ServingEngine.__init__`)."""
+    asked = options_asked(config, page_size, **options)
+    for kind in STATE_KINDS:
+        named = [name for name in kind.refuses if asked[name]] if kind.has(config) else []
+        if named:
+            raise ValueError(
+                f"{config.name} {kind.does.format(block=config.block_length)}: "
+                f"{', '.join(named)} cannot be used with {kind.state}"
+                + (f" (page_size {page_size})" if "page_size" in named else "")
+            )
+
+
+def migration_refused(config: Any) -> Optional[str]:
+    """What a migration command is told of ``config``'s state, or None."""
+    return next(
+        (k.migration for k in STATE_KINDS if k.migration and k.has(config)), None
+    )
 
 
 def table_len_for(max_seq_len: int, page_size: int) -> int:

@@ -90,6 +90,22 @@ def settled_stats(engine, delivered: int) -> dict:
     raise AssertionError(f"block-tokens-delivered never reached {delivered}: {stats}")
 
 
+def quiet_stats(engine) -> dict:
+    """`stats()` of an engine that has nothing left to count. The request
+    before (another test's) woke its waiter inside the delivery, before its
+    chunk's totals were added, and the chunk launched behind that one has
+    still to land: both are counted once two more iterations have begun
+    after the engine went quiet, however long a loaded host takes over them."""
+    deadline, quiet_at = time.monotonic() + 60, None
+    while time.monotonic() < deadline:
+        if engine._quiesced():
+            quiet_at = engine._iterations_total if quiet_at is None else quiet_at
+            if engine._iterations_total >= quiet_at + 2:
+                return engine.stats()
+        time.sleep(0.001)
+    raise AssertionError("the engine never went quiet")
+
+
 def prompt_of(n: int, seed: int = 0) -> list[int]:
     return np.random.default_rng(seed).integers(0, MASK, n).tolist()
 
@@ -367,7 +383,7 @@ def test_a_stop_token_cuts_at_the_blocks_delivery(engine):
 
 
 def test_spans_and_counters_of_a_block_chunk(engine):
-    before = engine.stats()
+    before = quiet_stats(engine)
     result = engine.generate(prompt_of(21, 6), GenerationOptions(max_new_tokens=8), timeout=120)
     after = settled_stats(engine, before["block-tokens-delivered"] + 8)
     delta = {k: after[k] - before[k] for k in after if k.startswith("block-")}

@@ -225,7 +225,6 @@ def test_kv_tokens_read_matches_a_hand_count(dense_params):
     TRACER.clear()
     engine = ServingEngine(
         DENSE, dense_params, max_batch=2, max_seq_len=128, decode_chunk=8,
-        overlap=True,
     )
     pending: deque = deque()
     opts = GenerationOptions(max_new_tokens=60, temperature=0.0)
@@ -256,7 +255,7 @@ def test_kv_pages_visited_matches_a_hand_count(dense_params):
     TRACER.clear()
     engine = ServingEngine(
         DENSE, dense_params, max_batch=3, max_seq_len=128, decode_chunk=8,
-        overlap=True, page_size=8,
+        page_size=8,
     )
     # B's chunk must still be in flight when its table is read below: on a
     # loaded host the device can finish it inside the second iteration,
@@ -394,7 +393,7 @@ def test_ttft_stages_add_up_and_behind_is_what_was_in_flight(dense_params):
     TRACER.clear()
     engine = ServingEngine(
         DENSE, dense_params, max_batch=2, max_seq_len=128, decode_chunk=4,
-        prefill_buckets=(16,), overlap=True,
+        prefill_buckets=(16,),
     )
     in_flight = [True]
     engine._batch_ready = lambda batch: not in_flight[0]
@@ -970,7 +969,7 @@ def test_hot_loop_cost_of_dispatch_spans_and_annotations(dense_params):
                 disp = engine._new_dispatch(
                     "engine.decode_chunk", program="_paged_decode_chunk", steps=8,
                     active_rows=4, row_steps=32,
-                    kv_tokens_read=engine._kv_tokens_read(live, 8),
+                    **engine._reads.decode(engine._live_lengths(live), 8)[0],
                     clean=True, pipelined=True,
                 )
                 with jax.profiler.TraceAnnotation(

@@ -370,7 +370,7 @@ def test_the_shares_add_up_to_the_uncut_layer(uncut_params, tokens):
 
 
 def _fault(monkeypatch, name):
-    """One wrong line of the program, each a fault of dev/dots3_check_faults.py."""
+    """One wrong line of the program, each a fault of dev/check_faults.py dots3."""
     if name == "no-gate":
         monkeypatch.setattr(T, "_head_gate", lambda attn, u, lp, config: attn)
     elif name == "no-rescale":
@@ -386,7 +386,7 @@ def _fault(monkeypatch, name):
 
 
 def test_a_window_latent_of_8_bits_fails_by_a_number(params, tokens, want, monkeypatch):
-    """No level of the chip's check sees it (`dev/dots3_check_faults.py`
+    """No level of the chip's check sees it (`dev/check_faults.py dots3`
     `winlat8`: a token's 8 bits under one scale are bf16's precision): in
     float32 it reads a hundred times the sound path, through the ring."""
     kept = T._kept_width

@@ -223,31 +223,6 @@ def test_eos_as_first_token():
         engine.stop()
 
 
-def test_adaptive_chunk_shrinks_under_queued_work():
-    """Legacy (overlap off) scheduler: with a queued request and a free
-    slot the next chunk is capped small (TTFT lever); with the queue empty
-    it returns to full size. Fused scheduling retires the shrink — full
-    chunks only, prefill rides every iteration (test_engine_fused)."""
-    from langstream_tpu.serving.engine import GenerationRequest
-
-    engine = make_engine(
-        max_batch=4, max_seq_len=256, decode_chunk=64, overlap=False
-    )
-    engine.stop()  # drive _chunk_steps directly, no device loop
-    engine._dead = None
-    engine._slots[0].request = GenerationRequest(
-        prompt_tokens=[1], options=GenerationOptions(max_new_tokens=200)
-    )  # fake an active slot with plenty of budget left
-    engine._slots[0].position = 10
-    assert engine._chunk_steps() == 64
-    engine._queue.put(object())
-    # shrinks to the configured floor (small chunk = TTFT lever; the ready-
-    # polled depth-2 pipeline keeps the device saturated despite it)
-    assert engine._chunk_steps() == engine.ttft_chunk_floor == 4
-    engine._queue.get_nowait()
-    assert engine._chunk_steps() == 64
-
-
 def test_8k_prompt_serves_on_llama31_style_preset():
     """An 8k-token prompt generates via chunked prefill under the llama-3.1
     NTK-by-parts RoPE config (dims shrunk for CPU; the rope-scaling math and

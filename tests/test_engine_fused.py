@@ -31,8 +31,8 @@ def make_engine(start=True, **kw):
 def test_mixed_prefill_decode_matches_serialized_reference():
     """Greedy tokens from a fused mixed load (active decode + two long
     prompts chunk-prefilling concurrently + short admissions) are identical
-    to each request served ALONE on a serialized (overlap off) engine —
-    the fused iterations change scheduling, never math."""
+    to each request served ALONE on a one-slot engine, where nothing can
+    ride beside it — the fused iterations change scheduling, never math."""
     opts = GenerationOptions(max_new_tokens=16, temperature=0.0)
     short_prompt = [5, 6, 7]
     long_a = [(3 + i) % CFG.vocab_size for i in range(70)]  # 5 segments @16
@@ -41,7 +41,6 @@ def test_mixed_prefill_decode_matches_serialized_reference():
     ref = {}
     serial = make_engine(
         max_batch=1, max_seq_len=128, decode_chunk=4, prefill_buckets=(16,),
-        overlap=False,
     )
     try:
         for name, prompt in (("s", short_prompt), ("a", long_a), ("b", long_b)):
@@ -51,7 +50,7 @@ def test_mixed_prefill_decode_matches_serialized_reference():
 
     fused = make_engine(
         max_batch=4, max_seq_len=128, decode_chunk=4, prefill_buckets=(16,),
-        overlap=True, max_prefill_streams=2, prefill_token_budget=32,
+        max_prefill_streams=2, prefill_token_budget=32,
     )
     try:
         short_req = fused.submit(
@@ -73,7 +72,6 @@ def test_admission_rides_the_very_next_iteration_under_load():
     (no engine thread) so 'one iteration' is exact, not a timing guess."""
     engine = make_engine(
         start=False, max_batch=2, max_seq_len=128, decode_chunk=8,
-        overlap=True,
     )
     pending: deque = deque()
     opts = GenerationOptions(max_new_tokens=60, temperature=0.0)
@@ -100,7 +98,7 @@ def test_prefill_token_budget_bounds_per_iteration_admission():
     whole wave."""
     engine = make_engine(
         start=False, max_batch=8, max_seq_len=128, decode_chunk=4,
-        prefill_buckets=(16,), prefill_batch=2, overlap=True,
+        prefill_buckets=(16,), prefill_batch=2,
         prefill_token_budget=32,
     )
     # long enough that nothing finishes within the iterations driven below
@@ -199,7 +197,7 @@ def decoding_engine(monkeypatch, chunk_s=CHUNK_S, **kw):
     clock = FakeTime(T0)
     monkeypatch.setattr(engine_mod, "time", clock)
     engine = make_engine(
-        start=False, max_batch=4, max_seq_len=128, decode_chunk=8, overlap=True,
+        start=False, max_batch=4, max_seq_len=128, decode_chunk=8,
         prefill_buckets=(16,), **kw,
     )
     engine._wake = wake = FakeWake(clock)
@@ -236,7 +234,7 @@ def test_admission_grace_only_behind_a_long_chunk(monkeypatch, chunk_s, in_fligh
     from langstream_tpu.serving import engine as engine_mod
 
     engine = make_engine(
-        start=False, max_batch=2, max_seq_len=128, decode_chunk=8, overlap=True,
+        start=False, max_batch=2, max_seq_len=128, decode_chunk=8,
     )
     engine._step_time_ema_s = chunk_s / engine.decode_chunk
     sleeps = []
@@ -430,7 +428,6 @@ def test_a_live_engine_that_waits_serves_the_same_tokens_and_counts_every_launch
     def serve():
         engine = make_engine(
             max_batch=4, max_seq_len=128, decode_chunk=16, prefill_buckets=(16,),
-            overlap=True,
         )
         try:
             engine.generate(prompts[0], opts, timeout=300)  # compiles; times a step
@@ -444,8 +441,12 @@ def test_a_live_engine_that_waits_serves_the_same_tokens_and_counts_every_launch
         finally:
             engine.stop()
 
-    plain, counted, _ = serve()
-    assert counted["arrival"] == counted["deadline"] == 0  # a 10 ms chunk: never waits
+    # the engine that never waits: whatever a loaded host makes of tiny-test's
+    # chunk, it is not worth a switch interval
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sys, "getswitchinterval", lambda: float("inf"))
+        plain, counted, _ = serve()
+    assert counted["arrival"] == counted["deadline"] == 0
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
@@ -485,8 +486,8 @@ def test_compiled_programs_flat_after_warmup_mixed_load():
     Overlap retires the shrunk-chunk program entirely, so
     the surface is exactly {ladder} ∪ {prefill buckets}."""
     engine = make_engine(
-        max_batch=4, max_seq_len=256, decode_chunk=8, ttft_chunk_floor=4,
-        prefill_buckets=(16, 32), precompile=True, overlap=True,
+        max_batch=4, max_seq_len=256, decode_chunk=8,
+        prefill_buckets=(16, 32), precompile=True,
     )
     try:
         # first request completes ⇒ warmup finished (the loop warms before
@@ -536,7 +537,7 @@ def _serve_burst(config, params, k, full_rows=False, buckets=(16,), lengths=None
     burst)."""
     engine = ServingEngine(
         config, params, max_batch=8, max_seq_len=64, decode_chunk=4,
-        prefill_buckets=buckets, prefill_batch=8, overlap=True, precompile=True,
+        prefill_buckets=buckets, prefill_batch=8, precompile=True,
     )
     if full_rows:
         engine._admit_rungs = (engine.prefill_batch,)
@@ -639,28 +640,6 @@ def test_segment_programs_warmed_are_the_ones_the_engine_can_dispatch(prefix_cac
         engine.stop()
 
 
-def test_overlap_off_preserves_single_stream_behavior():
-    """overlap=False keeps the pre-fusion scheduler: unbounded admission,
-    one chunked-prefill stream."""
-    engine = make_engine(
-        start=False, max_batch=4, max_seq_len=128, decode_chunk=4,
-        prefill_buckets=(16,), overlap=False,
-    )
-    assert engine.max_prefill_streams == 1
-    opts = GenerationOptions(max_new_tokens=60, temperature=0.0)
-    for _ in range(4):
-        engine.submit(GenerationRequest(prompt_tokens=[3, 4], options=opts))
-    pending: deque = deque()
-    engine._iterate(pending)
-    # no budget: the whole backlog admits in one iteration
-    assert sum(1 for s in engine._slots if s.active) == 4
-    engine._stop.set()
-    while pending:
-        for entry in pending.popleft():
-            engine._process_entry(entry)
-    engine._fail_all(RuntimeError("test torn down"))
-
-
 def test_concurrent_long_prefill_streams_share_iterations():
     """Two long prompts prefill CONCURRENTLY (two streams, round-robin
     segments) and both finish with correct token counts while a short
@@ -668,7 +647,7 @@ def test_concurrent_long_prefill_streams_share_iterations():
     prompt."""
     engine = make_engine(
         max_batch=3, max_seq_len=256, decode_chunk=4, prefill_buckets=(16,),
-        overlap=True, max_prefill_streams=2, prefill_token_budget=64,
+        max_prefill_streams=2, prefill_token_budget=64,
     )
     try:
         opts = GenerationOptions(max_new_tokens=20, temperature=0.0)
@@ -708,21 +687,22 @@ def test_bandwidth_gauge_reports_after_decode():
 
 
 def test_overlap_runs_full_chunks_only():
-    """Fused scheduling retires the TTFT chunk shrink: queued work no
-    longer shrinks the chunk (prefill rides every iteration instead), so
-    the decode compile surface is exactly ONE program — the shrunk
-    size was a whole extra program whose first dispatch landed on the first
-    real burst (the r5b mid-traffic stall class)."""
-    engine = make_engine(
-        start=False, max_batch=4, max_seq_len=256, decode_chunk=64,
-        overlap=True,
-    )
+    """Queued work does not shrink the chunk (prefill rides every iteration
+    instead): a chunk dispatched with a request waiting and a slot free is
+    ``decode_chunk`` steps, so the decode compile surface is exactly ONE
+    program — a shrunk size was a whole extra program whose first dispatch
+    landed on the first real burst (the r5b mid-traffic stall class)."""
+    engine = make_engine(start=False, max_batch=4, max_seq_len=256, decode_chunk=64)
+    engine._dev_decode = lambda steps, stale, mask=None: None  # nothing compiles
+    engine._submit_fetch = lambda *a, **kw: None
     engine._slots[0].request = GenerationRequest(
         prompt_tokens=[1], options=GenerationOptions(max_new_tokens=200)
     )
     engine._slots[0].position = 10
-    assert engine._chunk_steps() == 64
     engine._queue.put(object())
-    assert engine._chunk_steps() == 64  # no shrink under overlap
+    entry = engine._dispatch_chunk()
+    assert entry[3] == entry[-1].attrs["steps"] == engine.decode_chunk == 64
     engine._queue.get_nowait()
     engine._slots[0].request = None
+
+

@@ -114,6 +114,20 @@ def test_tpu_serving_refuses_the_dense_layout_by_name():
         asyncio.run(kept.close())
 
 
+def test_tpu_serving_refuses_the_scheduler_that_is_gone_by_name():
+    """`overlap: false` asked for the scheduler from before the fused
+    iteration: refused at build by that name, not served by another
+    scheduler in silence (`overlap: true` asked for nothing and is read past)."""
+    import pytest
+
+    from langstream_tpu.ai.tpu_serving import TpuServingProvider
+
+    base = {"model": "tiny-test", "tokenizer": "byte", "max-seq-len": 64}
+    for off in (False, "false", "off"):
+        with pytest.raises(ValueError, match="overlap: false is gone"):
+            TpuServingProvider({**base, "overlap": off}).engine()
+
+
 def test_int8_kv_cache_matches_bf16_cache():
     """Prefill + decode with the int8 KV cache tracks the fp32 cache closely
     (per-token per-head symmetric quant; rtol bounded by 1/127)."""

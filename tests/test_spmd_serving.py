@@ -225,12 +225,12 @@ def test_loopback_lockstep_with_precompiled_ladder():
     leader = ServingEngine(
         CFG, params, max_batch=2, max_seq_len=64, decode_chunk=4,
         prefill_buckets=(16, 32), prefill_batch=4, spmd=channel,
-        precompile=True, ttft_chunk_floor=2, page_size=PAGE,
+        precompile=True, page_size=PAGE,
     )
     follower = ServingEngine(
         CFG, params, max_batch=2, max_seq_len=64, decode_chunk=4,
         prefill_buckets=(16, 32), prefill_batch=4,
-        ttft_chunk_floor=2, page_size=PAGE,
+        page_size=PAGE,
     )
     follower_thread = threading.Thread(
         target=follower_loop, args=(follower, channel), daemon=True
